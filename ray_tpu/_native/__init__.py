@@ -1,14 +1,19 @@
 """Build + load the native runtime library (libraytpu.so).
 
 The C++ sources live in ``src/`` at the repo root. We compile them on first
-import (cached by source mtime) — the environment guarantees g++. This keeps
-the native components buildable without a packaging step, like the
-reference's bazel-built core but without requiring bazel at runtime.
+import — the environment guarantees g++ — and rebuild whenever the sources'
+content hash differs from the one recorded beside the library
+(``<lib>.srchash``). The libraries themselves are not tracked by git: a
+fresh checkout or a copy to another machine builds from what ``src/`` says.
+This keeps the native components buildable without a packaging step, like
+the reference's bazel-built core but without requiring bazel at runtime.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import hashlib
 import os
 import subprocess
 import threading
@@ -35,25 +40,60 @@ def _sources() -> list[str]:
     return out
 
 
-def _needs_build(sources: list[str]) -> bool:
-    if not os.path.exists(_LIB_PATH):
-        return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in sources)
+def _build_key(flags: list[str], sources: list[str]) -> str:
+    """Hash of everything the library is made from: the compiler flags and
+    each source's name and bytes. Recorded beside the library, it is what
+    decides a rebuild — mtimes say nothing after a checkout or a copy."""
+    h = hashlib.sha256("\0".join(flags).encode())
+    for src in sources:
+        h.update(os.path.basename(src).encode() + b"\0")
+        # rtlint: disable=blocking-in-async - one-time lazy toolchain build, memoized per process in load(); cold-start only, never on the steady-state loop
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _recorded_key(lib_path: str) -> str | None:
+    if not os.path.exists(lib_path):
+        return None
+    try:
+        # rtlint: disable=blocking-in-async - one-time lazy toolchain build, memoized per process in load(); cold-start only, never on the steady-state loop
+        with open(lib_path + ".srchash") as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def _compile(lib_path: str, flags: list[str], sources: list[str], force: bool) -> str:
+    key = _build_key(flags, sources)
+    if not force and _recorded_key(lib_path) == key:
+        return lib_path
+    # Every process of a cluster (and every xdist worker) imports this at
+    # once: one builds under the lock, the rest wait and find the hash.
+    # rtlint: disable=blocking-in-async,non-atomic-write - one-time lazy toolchain build, memoized per process in load(); cold-start only, never on the steady-state loop; the lock file is empty, only flock()ed
+    with open(lib_path + ".lock", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if force or _recorded_key(lib_path) != key:
+            tmp = f"{lib_path}.tmp.{os.getpid()}"
+            # rtlint: disable=blocking-in-async - one-time lazy toolchain build, memoized per process in load(); cold-start only, never on the steady-state loop
+            subprocess.run(
+                ["g++", *flags, "-o", tmp, *sources],
+                check=True, capture_output=True, text=True,
+            )
+            os.replace(tmp, lib_path)
+            # rtlint: disable=blocking-in-async - one-time lazy toolchain build, memoized per process in load(); cold-start only, never on the steady-state loop
+            with open(f"{tmp}.srchash", "w") as f:
+                f.write(key)
+            os.replace(f"{tmp}.srchash", lib_path + ".srchash")
+    return lib_path
 
 
 def build(force: bool = False) -> str:
     sources = _sources()
     if not sources:
         raise RuntimeError(f"no native sources found under {_SRC_DIRS}")
-    if force or _needs_build(sources):
-        cmd = [
-            "g++", "-std=c++17", "-O2", "-fPIC", "-shared", "-pthread",
-            "-o", _LIB_PATH, *sources,
-        ]
-        # rtlint: disable=blocking-in-async - one-time lazy toolchain compile, memoized on source mtimes; cold-start only, never on the steady-state loop
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-    return _LIB_PATH
+    flags = ["-std=c++17", "-O2", "-fPIC", "-shared", "-pthread"]
+    return _compile(_LIB_PATH, flags, sources, force)
 
 
 def load() -> ctypes.CDLL:
@@ -274,19 +314,11 @@ _fastlane_failed = False
 def build_fastlane(force: bool = False) -> str:
     import sysconfig
 
-    if (
-        force
-        or not os.path.exists(_FASTLANE_PATH)
-        or os.path.getmtime(_FASTLANE_SRC) > os.path.getmtime(_FASTLANE_PATH)
-    ):
-        cmd = [
-            "g++", "-std=c++17", "-O2", "-fPIC", "-shared",
-            f"-I{sysconfig.get_paths()['include']}",
-            "-o", _FASTLANE_PATH, _FASTLANE_SRC,
-        ]
-        # rtlint: disable=blocking-in-async - one-time lazy toolchain compile, memoized on source mtimes; cold-start only, never on the steady-state loop
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-    return _FASTLANE_PATH
+    flags = [
+        "-std=c++17", "-O2", "-fPIC", "-shared",
+        f"-I{sysconfig.get_paths()['include']}",
+    ]
+    return _compile(_FASTLANE_PATH, flags, [_FASTLANE_SRC], force)
 
 
 def load_fastlane():

@@ -31,7 +31,7 @@ import sys
 import time
 from typing import Any
 
-from ray_tpu._private import chaos
+from ray_tpu._private import accel, chaos
 from ray_tpu._private.config import global_config
 from ray_tpu._private.ids import WorkerID
 from ray_tpu._private.object_store import ObjectStoreClient, ObjectStoreServer
@@ -44,10 +44,11 @@ def detect_tpu_resources() -> dict:
     """TPU topology detection (SURVEY §2.1 'TPU build implication').
 
     Order: (1) RAY_TPU_tpu_slice_override flag (resource lying for tests,
-    §4.4.3), (2) /dev/accel* | /dev/vfio device nodes (TPU VM), (3) opt-in
-    jax probe in a throwaway subprocess (RAY_TPU_DETECT_TPU=1) — never in
-    this process: initializing the TPU backend here would hold the chip lock
-    the workers need, and costs ~20s of agent startup.
+    §4.4.3), (2) nothing on a host forced to the CPU (JAX_PLATFORMS=cpu),
+    (3) the chips' device nodes, counted by accel.tpu_device_nodes():
+    /dev/accel<N>, else the numbered /dev/vfio/<N> groups a v5e host
+    shows. Never a jax call in this process: initializing the TPU backend
+    here would hold the chip the workers need.
     """
     override = global_config().tpu_slice_override
     if override:
@@ -60,29 +61,8 @@ def detect_tpu_resources() -> dict:
             return {}
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
         return {}
-    try:
-        accels = [d for d in os.listdir("/dev") if d.startswith("accel")]
-        if accels:
-            return {"TPU": float(len(accels))}
-    except OSError:
-        pass
-    if os.environ.get("RAY_TPU_DETECT_TPU") == "1":  # pragma: no cover
-        import subprocess as sp
-
-        try:
-            out = sp.run(
-                [sys.executable, "-c",
-                 "import jax,json;print(json.dumps([d.device_kind for d in "
-                 "jax.devices() if d.platform=='tpu']))"],
-                capture_output=True, text=True, timeout=60,
-            )
-            kinds = json.loads(out.stdout.strip().splitlines()[-1])
-            if kinds:
-                kind = kinds[0].replace(" ", "-")
-                return {"TPU": float(len(kinds)), f"TPU-{kind}": float(len(kinds))}
-        except Exception:  # rtlint: disable=swallowed-exception - TPU probe: any failure means no TPUs to advertise
-            pass
-    return {}
+    chips = len(accel.tpu_device_nodes())
+    return {"TPU": float(chips)} if chips else {}
 
 
 def _gc_stale_arenas() -> None:
@@ -452,10 +432,10 @@ class NodeAgent:
 
     def _hbm_stats(self) -> dict:
         """TPU HBM used/total via jax.local_devices() memory_stats() —
-        only when jax is ALREADY imported in this process. The agent never
-        imports jax itself: initializing the TPU backend here would steal
-        the chip lock from workers (see detect_tpu_resources)."""
-        mod = sys.modules.get("jax")
+        only when this process has ALREADY initialised a jax backend. The
+        agent never does so itself: initializing the TPU backend here
+        would take the chip from the workers (see detect_tpu_resources)."""
+        mod = accel.live_jax()
         if mod is None:
             return {}
         try:
@@ -1243,6 +1223,14 @@ class NodeAgent:
         try:
             worker.proc.kill()
         except ProcessLookupError:
+            pass
+        # Answer once the process is gone, so that what it held is free
+        # when ray_tpu.kill() returns: a TPU chip belongs to one process
+        # at a time, and the next gang worker needs it. A worker that owns
+        # four chips takes over 10 s to be torn down after SIGKILL.
+        try:
+            await asyncio.wait_for(worker.proc.wait(), timeout=60.0)
+        except asyncio.TimeoutError:
             pass
         return {"status": "ok"}
 

@@ -25,7 +25,7 @@ import traceback
 from typing import Any
 
 from ray_tpu import exceptions
-from ray_tpu._private import serialization
+from ray_tpu._private import accel, serialization
 from ray_tpu._private.config import global_config
 from ray_tpu.util import tracing
 from ray_tpu._private.core_context import CoreContext
@@ -814,12 +814,14 @@ class WorkerRuntime:
             self._running_exec.pop(task_id, None)
 
     def _hbm_used(self) -> int | None:
-        """Local-TPU HBM bytes in use, or None when not on TPU. The probe
-        is tri-state cached: once jax is loaded without TPU devices this
-        is a single attribute check per task forever after."""
+        """Local-TPU HBM bytes in use, or None when not on TPU. Read only
+        once user code of this worker has initialised a jax backend
+        itself: probing sooner would take the chip for a worker that was
+        leased none. Tri-state cached: once a backend is up without TPU
+        devices this is a single attribute check per task forever after."""
         if self._hbm_probe is False:
             return None
-        mod = sys.modules.get("jax")
+        mod = accel.live_jax()
         if mod is None:
             return None
         try:
@@ -1523,6 +1525,8 @@ class WorkerRuntime:
 def main() -> None:
     from ray_tpu._private import chaos
 
+    # Before any user code can import jax (jax reads the variable then).
+    accel.place_compile_cache()
     chaos.set_identity(f"worker:{os.environ.get('RAYTPU_WORKER_ID', '')}")
     runtime = WorkerRuntime()
     runtime.start()

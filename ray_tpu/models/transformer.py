@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.parallel.mesh import LogicalRules
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,11 +176,32 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
     }
 
 
+def _flash_over_mesh(q, k, v, causal):
+    """The flash kernel, per shard when traced under a device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under the mesh that build_sharded_train_step traces
+    in, each device runs the kernel on its own [batch, heads] block —
+    attention needs nothing from another batch row or head. The specs come
+    from the same logical rules that shard the params: batch over
+    (dp, fsdp), heads over tp. No mesh in scope (one device, or a caller
+    that places everything itself): the plain call."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal)
+    spec = LogicalRules().spec(("batch", "heads", None, None), mesh)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
 def _attention_impl(config: TransformerConfig) -> Callable:
     if callable(config.attention):
         return config.attention
     if config.attention == "flash":
-        return lambda q, k, v, causal: flash_attention(q, k, v, causal=causal)
+        return _flash_over_mesh
     return lambda q, k, v, causal: attention_reference(q, k, v, causal=causal)
 
 

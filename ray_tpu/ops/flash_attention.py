@@ -23,6 +23,8 @@ masked skip all compute (≈2× for causal training).
 
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
+``flash_attention`` resolves ``interpret`` once (ops.resolve_interpret); the
+kernels below it take a plain bool.
 """
 
 from __future__ import annotations
@@ -32,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ray_tpu.ops import resolve_interpret
 
 _NEG_INF = -1e30
 
@@ -213,10 +217,6 @@ def _flash_dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -242,7 +242,7 @@ def flash_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _flash_vjp(q, k, v, causal, float(scale), block_q, block_k,
-                      interpret, precision)
+                      resolve_interpret(interpret), precision)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -306,7 +306,7 @@ def _flash_forward(
     scale: float | None = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool | None = None,
+    interpret: bool,
     precision: jax.lax.Precision | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     batch, heads, seq_q, dim = q.shape
@@ -315,8 +315,6 @@ def _flash_forward(
     if scale is None:
         scale = dim ** -0.5
     block_q, block_k = _block_sizes(seq_q, seq_k, block_q, block_k)
-    if interpret is None:
-        interpret = _should_interpret()
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
@@ -378,8 +376,6 @@ def _flash_backward(
     batch, heads, seq_q, dim = q.shape
     seq_k = k.shape[2]
     block_q, block_k = _block_sizes(seq_q, seq_k, block_q, block_k)
-    if interpret is None:
-        interpret = _should_interpret()
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)

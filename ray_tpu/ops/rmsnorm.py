@@ -1,8 +1,9 @@
 """Fused RMSNorm Pallas kernel (+ jax reference).
 
 One VMEM pass instead of separate square/mean/rsqrt/mul HLOs — the classic
-HBM-bandwidth fusion (SURVEY 'HBM bandwidth' guidance). Falls back to
-interpreter mode off-TPU.
+HBM-bandwidth fusion (SURVEY 'HBM bandwidth' guidance). Interpreted off-TPU
+(ops.resolve_interpret); on a TPU backend the kernel is what runs — no branch
+gives way to ``rmsnorm_reference``.
 """
 
 from __future__ import annotations
@@ -12,6 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ray_tpu.ops import resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -32,28 +35,29 @@ def rmsnorm(
     interpret: bool | None = None,
 ) -> jax.Array:
     """x: [..., dim]; weight: [dim]."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     orig_shape = x.shape
     dim = orig_shape[-1]
     rows = x.size // dim
     xr = x.reshape(rows, dim)
     block_rows = min(block_rows, rows)
-    if rows % block_rows != 0:
-        # Odd row counts: plain jax fallback keeps semantics.
-        return rmsnorm_reference(x, weight, eps=eps)
+    # Odd row counts: pad with zero rows up to a whole block (a zero row
+    # normalizes to zero) and drop them after — the kernel always runs.
+    padded = -(-rows // block_rows) * block_rows
+    if padded != rows:
+        xr = jnp.pad(xr, ((0, padded - rows), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
-        grid=(rows // block_rows,),
+        grid=(padded // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, dim), lambda i: (i, 0)),
             pl.BlockSpec((dim,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, dim), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, dim), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((padded, dim), x.dtype),
         interpret=interpret,
     )(xr, weight)
-    return out.reshape(orig_shape)
+    return out[:rows].reshape(orig_shape)
 
 
 def rmsnorm_reference(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6) -> jax.Array:

@@ -18,9 +18,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ray_tpu.parallel._compat import shard_map
 
 
 # ---------------------------------------------------------------------------

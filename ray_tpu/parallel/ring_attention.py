@@ -22,10 +22,10 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.flash_attention import attention_reference
-from ray_tpu.parallel._compat import shard_map
 
 _NEG_INF = -1e30
 

@@ -108,22 +108,22 @@ class BackendExecutor:
         return backend
 
     def _worker_local_devices(self) -> int:
-        """Local device count a gang WORKER will see — from the worker
-        env's host-platform flag (CPU twin) when present, else this
-        process's jax runtime (real TPU hosts: driver and worker see the
-        same per-host chip count)."""
+        """Local device count a gang WORKER will see, learnt without jax:
+        the driver never initialises a backend (on a TPU host that would
+        take the chip its worker needs). On the CPU twin it is the
+        host-platform flag of the worker's environment (``worker_env``,
+        else the environment workers inherit from this process); on a TPU
+        host it is the ``TPU`` resource each worker is leased."""
         import re
 
-        flags = dict(self.scaling_config.worker_env).get("XLA_FLAGS", "")
-        m = re.search(r"xla_force_host_platform_device_count=(\d+)", flags)
-        if m:
-            return int(m.group(1))
-        try:
-            import jax
-
-            return int(jax.local_device_count())
-        except Exception:  # rtlint: disable=swallowed-exception - no jax on the driver: assume 1 local device
-            return 1
+        for flags in (
+            dict(self.scaling_config.worker_env).get("XLA_FLAGS", ""),
+            os.environ.get("XLA_FLAGS", ""),
+        ):
+            m = re.search(r"xla_force_host_platform_device_count=(\d+)", flags)
+            if m:
+                return int(m.group(1))
+        return max(1, int(self.scaling_config.worker_resources().get("TPU", 1)))
 
     def start(
         self,

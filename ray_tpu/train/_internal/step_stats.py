@@ -135,11 +135,12 @@ def _drain_phases() -> dict[str, float]:
 
 
 def _device_info() -> tuple[str, int]:
-    """(device_kind, local device count) — probed from jax only when the
-    worker already imported it (never force a jax init for telemetry)."""
-    import sys
+    """(device_kind, local device count) — read from jax only when the
+    train loop has already initialised a backend itself (telemetry never
+    takes the chip: accel.live_jax)."""
+    from ray_tpu._private import accel
 
-    jax = sys.modules.get("jax")
+    jax = accel.live_jax()
     if jax is None:
         return "", 1
     try:
@@ -211,7 +212,8 @@ class StepRecorder:
         elif sub > 0.0 and compute <= 0.0:
             fwd = bwd = opt = 0.0
             sub = 0.0
-        if self._device_kind is None:
+        if not self._device_kind:
+            # Empty until the loop itself has initialised a jax backend.
             self._device_kind, self._devices = _device_info()
         self.step += 1
         rec = {

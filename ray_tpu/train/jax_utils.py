@@ -406,48 +406,35 @@ def _optimizer_state_shardings(
 ):
     """Shardings for the optimizer state, matching the params'.
 
-    Primary path: compile ``optimizer.init`` with the params' shardings
-    and read XLA's propagated ``output_shardings`` — Adam moments come
-    out sharded exactly like their params, counters replicated. Fallback
-    (older jax without output_shardings, exotic optimizers): match
-    optimizer leaves to param leaves by (shape, dtype), replicating
-    anything unmatched."""
+    Read from structure: a state leaf that mirrors a param — its tree
+    path ends in the param's path and its shape is the param's (Adam's
+    mu/nu) — takes that param's sharding; everything else (step
+    counters) replicates. Not read from XLA's propagation: optax makes
+    its moments with ``zeros_like``, which has no data dependence on the
+    params, so a compiled ``optimizer.init`` returns them replicated
+    (jax 0.9.0) and every device would hold the whole optimizer state."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    def normalize(s):
-        # Leaves with no data dependence on the params (step counters)
-        # come back single-device from the propagation probe; pin every
-        # sharding that doesn't span the mesh to replicated-on-mesh.
-        if getattr(s, "num_devices", 0) == mesh.devices.size:
-            return s
-        return NamedSharding(mesh, P())
-
-    try:
-        compiled = (
-            jax.jit(optimizer.init, in_shardings=(param_shardings,))
-            .lower(param_shapes)
-            .compile()
+    by_path = {
+        path: (tuple(leaf.shape), sh)
+        for (path, leaf), sh in zip(
+            jax.tree_util.tree_flatten_with_path(param_shapes)[0],
+            jax.tree.leaves(param_shardings),
         )
-        return jax.tree.map(normalize, compiled.output_shardings)
-    except Exception:  # rtlint: disable=swallowed-exception - propagation probe failed: shape-match fallback below
-        logger.debug(
-            "optimizer sharding propagation failed; using shape match",
-            exc_info=True,
-        )
-    by_shape: dict[tuple, Any] = {}
-    for leaf, sh in zip(
-        jax.tree.leaves(param_shapes), jax.tree.leaves(param_shardings)
-    ):
-        key = (tuple(leaf.shape), np.dtype(leaf.dtype))
-        by_shape.setdefault(key, sh)
-    opt_shapes = jax.eval_shape(optimizer.init, param_shapes)
+    }
+    replicated = NamedSharding(mesh, P())
 
-    def pick(leaf):
-        key = (tuple(leaf.shape), np.dtype(leaf.dtype))
-        return by_shape.get(key, NamedSharding(mesh, P()))
+    def pick(path, leaf):
+        for i in range(len(path)):
+            shape, sh = by_path.get(path[i:], (None, None))
+            if shape == tuple(leaf.shape):
+                return sh
+        return replicated
 
-    return jax.tree.map(pick, opt_shapes)
+    return jax.tree_util.tree_map_with_path(
+        pick, jax.eval_shape(optimizer.init, param_shapes)
+    )
 
 
 def setup_sharded_training(
@@ -555,6 +542,13 @@ def build_sharded_train_step(
     donate_args = (0, 1) if donate else ()
     param_sh, opt_sh = setup.param_shardings, setup.opt_shardings
 
+    def meshed_loss(params, batch):
+        # Trace the model with the mesh in scope: code that must know it
+        # (a Pallas kernel, which GSPMD cannot partition and which so
+        # runs per shard under shard_map) reads get_abstract_mesh().
+        with jax.sharding.use_abstract_mesh(setup.mesh.abstract_mesh):
+            return loss_fn(params, batch)
+
     def apply_update(params, opt_state, grads):
         updates, new_opt = optimizer.update(grads, opt_state, params)
         new_params = jax.tree.map(
@@ -570,7 +564,7 @@ def build_sharded_train_step(
 
     if not cross_worker:
         def fused(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = jax.value_and_grad(meshed_loss)(params, batch)
             new_params, new_opt = apply_update(params, opt_state, grads)
             return new_params, new_opt, loss
 
@@ -580,18 +574,13 @@ def build_sharded_train_step(
             donate_argnums=donate_args,
         )
 
-    if _vjp_through_jit_supported():
-        # vjp residuals may shard differently from params; out_shardings
-        # stays default on fwd so GSPMD propagates them. The unused batch
-        # cotangent inside bwd is dead code XLA eliminates.
-        fwd_fn = jax.jit(lambda p, b: jax.vjp(loss_fn, p, b))
-        bwd_fn = jax.jit(lambda vf, ct: vf(ct)[0], out_shardings=param_sh)
-        grad_fn = None
-    else:
-        fwd_fn = bwd_fn = None
-        grad_fn = jax.jit(
-            jax.value_and_grad(loss_fn), out_shardings=(None, param_sh)
-        )
+    # jax 0.9 returns the vjp closure as a ``tree_util.Partial`` pytree,
+    # so it crosses the jit boundary. Its residuals may shard differently
+    # from params; out_shardings stays default on fwd so GSPMD propagates
+    # them. The unused batch cotangent inside bwd is dead code XLA
+    # eliminates.
+    fwd_fn = jax.jit(lambda p, b: jax.vjp(meshed_loss, p, b))
+    bwd_fn = jax.jit(lambda vf, ct: vf(ct)[0], out_shardings=param_sh)
     apply_fn = jax.jit(
         apply_update,
         out_shardings=(param_sh, opt_sh),
@@ -605,19 +594,12 @@ def build_sharded_train_step(
     # enforces anyway, moving the wait INTO the phase that caused it
     # instead of smearing it into the next annotation.
     def step(params, opt_state, batch):
-        if grad_fn is not None:
-            # Probe said vjp can't cross this jit boundary: fwd+bwd stay
-            # one program, attributed to bwd (backward dominates it).
-            with step_annotation("bwd", phase="bwd"):
-                loss, grads = grad_fn(params, batch)
-                jax.block_until_ready(grads)  # rtlint: disable=host-sync-in-step - attribution boundary; sync_gradients blocks on grads next anyway
-        else:
-            with step_annotation("fwd", phase="fwd"):
-                loss, vjp_fn = fwd_fn(params, batch)
-                jax.block_until_ready(loss)  # rtlint: disable=host-sync-in-step - attribution boundary; bwd consumes the residuals next anyway
-            with step_annotation("bwd", phase="bwd"):
-                grads = bwd_fn(vjp_fn, jax.numpy.ones_like(loss))
-                jax.block_until_ready(grads)  # rtlint: disable=host-sync-in-step - attribution boundary; sync_gradients blocks on grads next anyway
+        with step_annotation("fwd", phase="fwd"):
+            loss, vjp_fn = fwd_fn(params, batch)
+            jax.block_until_ready(loss)  # rtlint: disable=host-sync-in-step - attribution boundary; bwd consumes the residuals next anyway
+        with step_annotation("bwd", phase="bwd"):
+            grads = bwd_fn(vjp_fn, jax.numpy.ones_like(loss))
+            jax.block_until_ready(grads)  # rtlint: disable=host-sync-in-step - attribution boundary; sync_gradients blocks on grads next anyway
         with step_annotation("grad_sync"):
             # Phase accounting happens inside the collective layer
             # (collective_s / comm_exposed_s) — the annotation only names
@@ -630,30 +612,6 @@ def build_sharded_train_step(
         return params, opt_state, loss
 
     return step
-
-
-_VJP_THROUGH_JIT: bool | None = None
-
-
-def _vjp_through_jit_supported() -> bool:
-    """One cached probe: can a ``jax.vjp`` closure cross a jit boundary
-    (returned from one jit program, applied inside another)? Modern jax
-    returns it as a ``tree_util.Partial`` pytree, so yes — but the split
-    train step must degrade to fused value_and_grad, not crash, on a
-    runtime where it can't."""
-    global _VJP_THROUGH_JIT
-    if _VJP_THROUGH_JIT is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-
-            x = jnp.arange(2.0)
-            loss, vf = jax.jit(lambda v: jax.vjp(lambda u: (u * u).sum(), v))(x)
-            (grad,) = jax.jit(lambda f, ct: f(ct))(vf, jnp.ones_like(loss))
-            _VJP_THROUGH_JIT = bool(abs(float(grad[1]) - 2.0) < 1e-5)
-        except Exception:  # rtlint: disable=swallowed-exception - feature probe: any failure means "use the fused fallback"
-            _VJP_THROUGH_JIT = False
-    return _VJP_THROUGH_JIT
 
 
 def save_sharded_state(

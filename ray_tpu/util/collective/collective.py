@@ -562,7 +562,7 @@ class XlaGroup(BaseGroup):
         shift = self._p2p_cache.get(key)
         if shift is None:
             import jax.numpy as jnp
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
             mesh = Mesh(np.array(self._rank_devices), ("ranks",))
@@ -680,7 +680,7 @@ class HierarchicalGroup(BaseGroup):
 
     def _local_reduce(self, per_device_arrays: list, op: str) -> np.ndarray:
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         if op not in self._TIER1:

@@ -103,9 +103,12 @@ def plan_1b() -> dict:
     }
 
 
-def _bench_row(mode: str) -> dict:
+def _child_json(argv: list[str]) -> dict:
+    """Run one jax-using phase in a child that has exited before the next
+    starts, and parse its last JSON line. This parent never touches jax:
+    a chip belongs to one process at a time."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--sharding", mode],
+        [sys.executable, *argv],
         capture_output=True, text=True, timeout=1500, cwd=REPO,
     )
     line = next(
@@ -113,18 +116,17 @@ def _bench_row(mode: str) -> dict:
         None,
     )
     if proc.returncode != 0 or line is None:
-        raise RuntimeError(
-            f"bench.py --sharding {mode} failed: {proc.stderr[-1000:]}"
-        )
-    data = json.loads(line)
-    if "error" in (data.get("detail") or {}):
-        raise RuntimeError(f"bench row {mode}: {data['detail']['error']}")
-    return data
+        raise RuntimeError(f"{' '.join(argv)} failed: {proc.stderr[-1000:]}")
+    return json.loads(line)
+
+
+def _bench_row(mode: str) -> dict:
+    return _child_json([os.path.join(REPO, "bench.py"), "--sharding", mode])
 
 
 def main() -> None:
     result = {"benchmark": "sharded_training", "smoke": int(SMOKE)}
-    result.update(plan_1b())
+    result.update(_child_json([os.path.abspath(__file__), "--plan"]))
 
     fsdp = _bench_row("fsdp")
     pp = _bench_row("pp")
@@ -145,4 +147,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["--plan"]:
+        print(json.dumps(plan_1b()), flush=True)
+    else:
+        main()

@@ -112,13 +112,11 @@ def main() -> int:
         if only and entry["name"] != only:
             continue
         if entry.get("requires_tpu"):
-            try:
-                import jax
+            # Read /dev, never jax: a parent that initialises the TPU
+            # backend holds the chip its _run_entry children need.
+            from ray_tpu._private.node_agent import detect_tpu_resources
 
-                on_tpu = jax.devices()[0].platform == "tpu"
-            except Exception:
-                on_tpu = False
-            if not on_tpu:
+            if not detect_tpu_resources().get("TPU"):
                 results.append(
                     {"benchmark": entry["name"], "skipped": "no TPU"}
                 )

@@ -1,0 +1,107 @@
+"""Compile the main path's Pallas kernels for a TPU v5e that is described,
+not attached (``on-chip-measurement`` guide, section 2).
+
+The TPU's compiler is installed wherever jax's TPU plugin is, so Mosaic
+refuses here what it would refuse on the chip — a block not aligned to the
+tiling, too much VMEM — at real widths (Llama-2-7B attention,
+``[2, 32, 4096, 128]`` bf16) and at no chip time. A compile that passes is
+not a chip run: nothing executes, so these say nothing about results or
+times.
+
+All in ONE file and in the test's own process: only one process at a time
+may load the TPU's library, and the xdist worker that is handed this file
+is the one that loads it. The topology is described inside a fixture that
+skips when it cannot be — never at import, in a ``skipif`` or in
+``parametrize``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.rmsnorm import rmsnorm
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; the next one would warn.
+    Module-scoped autouse is local to this file."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _qkv(one_chip, seq):
+    return [
+        jax.ShapeDtypeStruct((2, 32, seq, 128), jnp.bfloat16, sharding=one_chip)
+    ] * 3
+
+
+def _custom_calls(fn, *shapes) -> int:
+    return jax.jit(fn).lower(*shapes).compile().as_text().count("tpu_custom_call")
+
+
+# interpret=False: jax.default_backend() is the CPU here, and the platform
+# rule (ops.resolve_interpret) would pick the interpreter.
+_flash = functools.partial(flash_attention, causal=True, interpret=False)
+
+
+def _flash_grads(q, k, v):
+    return jax.grad(
+        lambda q, k, v: _flash(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+
+
+def test_flash_forward_compiles_for_v5e(one_chip):
+    assert _custom_calls(_flash, *_qkv(one_chip, 4096)) == 1
+
+
+def test_flash_backward_compiles_for_v5e(one_chip):
+    # forward (for the residuals) + dq + dkv
+    assert _custom_calls(_flash_grads, *_qkv(one_chip, 4096)) == 3
+
+
+def test_flash_small_block_length_compiles_for_v5e(one_chip):
+    """seq 1000 is not a multiple of the 512 default: _block_sizes halves
+    down to 8, the smallest block the (8, 128) tiling accepts."""
+    from ray_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(1000, 1000, 512, 512) == (8, 8)
+    assert _custom_calls(_flash_grads, *_qkv(one_chip, 1000)) == 3
+
+
+def test_rmsnorm_compiles_for_v5e(one_chip):
+    x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
+    assert _custom_calls(functools.partial(rmsnorm, interpret=False), x, w) == 1

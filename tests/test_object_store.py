@@ -122,3 +122,33 @@ def test_store_full_without_spill(tmp_path):
     finally:
         client.close()
         server.stop()
+
+
+def test_native_rebuild_is_keyed_on_source_hash_not_mtime(tmp_path):
+    """The libraries are not in git; what decides a rebuild is the hash of
+    the sources recorded beside the library. mtimes say nothing after a
+    checkout or a copy to another machine: a touched source with the same
+    bytes is not rebuilt, and changed bytes are — even under a source
+    mtime OLDER than the library's."""
+    from ray_tpu import _native
+
+    src = tmp_path / "a.cc"
+    src.write_text('extern "C" int f() { return 1; }\n')
+    lib = str(tmp_path / "liba.so")
+    flags = ["-shared", "-fPIC"]
+
+    def built():
+        _native._compile(lib, flags, [str(src)], False)
+        with open(lib + ".srchash") as fh:
+            return os.stat(lib).st_ino, fh.read()
+
+    first = built()
+    os.utime(src, (time.time() + 3600, time.time() + 3600))
+    assert built() == first  # newer mtime, same bytes: kept
+    src.write_text('extern "C" int f() { return 2; }\n')
+    os.utime(src, (0, 0))
+    rebuilt = built()  # older mtime, other bytes: rebuilt
+    assert rebuilt[0] != first[0] and rebuilt[1] != first[1]
+    os.remove(lib)
+    assert built()[1] == rebuilt[1]  # library gone, hash file left: rebuilt
+    assert os.path.exists(lib)

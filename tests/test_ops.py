@@ -56,7 +56,7 @@ def test_rmsnorm_matches_reference():
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
 
 
-def test_rmsnorm_odd_rows_falls_back():
+def test_rmsnorm_odd_rows_padded_to_a_block():
     key = jax.random.PRNGKey(4)
     x = jax.random.normal(key, (7, 512))
     w = jnp.ones((512,))

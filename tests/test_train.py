@@ -478,3 +478,94 @@ def test_trainer_mpmd_pipeline_matches_fused(ray_start_shared, tmp_path):
         params, opt, l = fused_step(params, opt, batch)
         fused_losses.append(float(l))
     np.testing.assert_allclose(pp_losses, fused_losses, rtol=2e-6, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# One process for the chip (ISSUE 21): the driver stays off jax, telemetry
+# never initialises a backend, and chip_smoke.py's one-chip phase rehearsed
+# on the CPU.
+# ---------------------------------------------------------------------------
+def _tiny_report_loop(config):
+    for i in range(config["steps"]):
+        train.report({"i": i})
+
+
+def test_fit_never_asks_jax_for_devices_in_the_driver(
+    ray_start_shared, tmp_path, monkeypatch
+):
+    """On a TPU host a driver that initialises a backend takes the chip its
+    gang worker needs. With every device query raising IN THE DRIVER, a
+    fit() on the default backend still succeeds (the worker's local device
+    count comes from the environment / the TPU resource, not from jax)."""
+    import jax
+
+    def boom(*args, **kwargs):
+        raise AssertionError("the driver asked jax for its devices")
+
+    for name in ("devices", "local_devices", "local_device_count", "default_backend"):
+        monkeypatch.setattr(jax, name, boom)
+    trainer = JaxTrainer(
+        _tiny_report_loop,
+        train_loop_config={"steps": 2},
+        scaling_config=ScalingConfig(num_workers=2),
+        run_config=RunConfig(name="driver-off-jax", storage_path=str(tmp_path)),
+    )
+    result = trainer.fit()
+    assert result.error is None, result.error
+    assert len(result.metrics_history) == 2
+    # 8 forced host devices per worker: still the hierarchical upgrade.
+    assert result.metrics["collective_backend"] == "hier"
+
+
+def test_import_jax_in_a_worker_initialises_no_backend(ray_start_shared):
+    """``import jax`` takes nothing; the first devices() call takes the
+    chip. Per-task telemetry (worker_proc._hbm_used) runs around every
+    task: after a task that only imports jax, and a second task for its
+    telemetry to have run around, that worker still has no backend."""
+
+    @ray_tpu.remote
+    class Importer:
+        def import_only(self):
+            import jax  # noqa: F401
+
+            return os.getpid()
+
+        def backend_state(self):
+            import jax._src.xla_bridge as xb
+
+            from ray_tpu._private import accel
+            from ray_tpu.train._internal import step_stats
+
+            info = step_stats._device_info()  # the train-side probe too
+            return os.getpid(), xb.backends_are_initialized(), accel.live_jax() is None, info
+
+    actor = Importer.remote()
+    pid = ray_tpu.get(actor.import_only.remote(), timeout=120)
+    pid2, initialised, live_is_none, info = ray_tpu.get(
+        actor.backend_state.remote(), timeout=120
+    )
+    ray_tpu.kill(actor)
+    assert pid2 == pid
+    assert not initialised
+    assert live_is_none
+    assert info == ("", 1)
+
+
+def test_chip_smoke_one_chip_phase_on_cpu(ray_start_shared, monkeypatch, tmp_path):
+    """Rehearsal of chip_smoke.py's one-chip phase (on-chip-measurement
+    guide, section 2): the same functions, TransformerConfig.tiny(), the
+    forced CPU devices, the expected platform passed by this test — the
+    program has no option for it. The cluster-detection phase is not
+    rehearsed: this cluster's TPU resource is a lie by design."""
+    import chip_smoke
+    from ray_tpu.models.transformer import TransformerConfig
+
+    monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path))
+    facts = chip_smoke.one_chip_phase(
+        TransformerConfig.tiny(), platform="cpu", steps=5
+    )
+    assert len(facts["losses"]) == 6
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert facts["worker"]["pid"] != os.getpid()
+    assert facts["worker"]["device"]["platform"] == "cpu"
+    assert facts["first"]["mesh"] == {"dp": 1}
