@@ -33,6 +33,15 @@ from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.mesh import LogicalRules
 
+# The names the model and the optimizer give their work (jax.named_scope:
+# HLO metadata, the compiled program is the same instruction for
+# instruction). Every device op's ``op_name`` carries the scope it was
+# traced under, through jvp / transpose / remat, so a device trace can be
+# read per block (benchmarks/harness/scopes.py). "optimizer" is opened in
+# train/jax_utils.py::build_sharded_train_step. A rename here renames a
+# metric: tests/test_named_scopes.py holds the vocabulary to the program.
+SCOPES = ("embed", "attention", "mlp", "head", "loss", "optimizer")
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -212,22 +221,23 @@ def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
 
 
 def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
-    batch, seq, d = x.shape
-    hd = config.head_dim
-    h = _rmsnorm_ckpt(x, layer["attn_norm"])
-    q = (h @ layer["wq"]).reshape(batch, seq, config.n_heads, hd)
-    k = (h @ layer["wk"]).reshape(batch, seq, config.n_kv_heads, hd)
-    v = (h @ layer["wv"]).reshape(batch, seq, config.n_kv_heads, hd)
-    q = q.transpose(0, 2, 1, 3)
-    k = k.transpose(0, 2, 1, 3)
-    v = v.transpose(0, 2, 1, 3)
-    cos, sin = cos_sin
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    rep = config.n_heads // config.n_kv_heads
-    o = attention_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep), True)
-    o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * hd)
-    return x + (o @ layer["wo"]).astype(x.dtype)
+    with jax.named_scope("attention"):
+        batch, seq, d = x.shape
+        hd = config.head_dim
+        h = _rmsnorm_ckpt(x, layer["attn_norm"])
+        q = (h @ layer["wq"]).reshape(batch, seq, config.n_heads, hd)
+        k = (h @ layer["wk"]).reshape(batch, seq, config.n_kv_heads, hd)
+        v = (h @ layer["wv"]).reshape(batch, seq, config.n_kv_heads, hd)
+        q = q.transpose(0, 2, 1, 3)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        cos, sin = cos_sin
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        rep = config.n_heads // config.n_kv_heads
+        o = attention_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep), True)
+        o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * hd)
+        return x + (o @ layer["wo"]).astype(x.dtype)
 
 
 @functools.partial(jax.checkpoint, prevent_cse=False)
@@ -308,6 +318,26 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     return out.reshape(batch, seq, d)
 
 
+def _mlp_block(x, layer, config: TransformerConfig):
+    with jax.named_scope("mlp"):
+        h = _rmsnorm_ckpt(x, layer["mlp_norm"])
+        if config.moe:
+            return x + _moe_mlp(h, layer, config).astype(x.dtype)
+        return x + _dense_mlp(h, layer).astype(x.dtype)
+
+
+def _embed(params, tokens):
+    with jax.named_scope("embed"):
+        return params["embed"][tokens]
+
+
+def _head(params, x):
+    """final_norm + lm_head: f32 logits."""
+    with jax.named_scope("head"):
+        x = rmsnorm_reference(x, params["final_norm"])
+        return (x @ params["lm_head"]).astype(jnp.float32)
+
+
 def forward(
     params: dict,
     tokens: jax.Array,
@@ -317,17 +347,12 @@ def forward(
     """tokens: [batch, seq] int32 -> logits [batch, seq, vocab] (f32)."""
     attention_fn = _attention_impl(config)
     cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
 
     def layer_step(carry, layer):
         x = carry
         x = _attention_block(x, layer, config, (cos, sin), positions, attention_fn)
-        h = _rmsnorm_ckpt(x, layer["mlp_norm"])
-        if config.moe:
-            x = x + _moe_mlp(h, layer, config).astype(x.dtype)
-        else:
-            x = x + _dense_mlp(h, layer).astype(x.dtype)
-        return x, None
+        return _mlp_block(x, layer, config), None
 
     if config.remat == "full":
         layer_step = jax.checkpoint(
@@ -342,8 +367,7 @@ def forward(
         raise ValueError(f"unknown remat policy {config.remat!r}")
 
     x, _ = jax.lax.scan(layer_step, x, params["layers"])
-    x = rmsnorm_reference(x, params["final_norm"])
-    return (x @ params["lm_head"]).astype(jnp.float32)
+    return _head(params, x)
 
 
 def logits_loss(
@@ -353,11 +377,12 @@ def logits_loss(
 ) -> jax.Array:
     """Token cross-entropy from logits — shared by the fused loss_fn and
     the pipeline's last stage (which receives logits over the wire)."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if mask is not None:
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+    with jax.named_scope("loss"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        if mask is not None:
+            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return jnp.mean(nll)
 
 
 def loss_fn(
@@ -470,24 +495,17 @@ def stage_forward(
     attention_fn = _attention_impl(config)
     cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
     if first:
-        x = stage_params["embed"][x]
+        x = _embed(stage_params, x)
 
     def layer_step(carry, layer):
-        h_in = carry
         h_in = _attention_block(
-            h_in, layer, config, (cos, sin), positions, attention_fn
+            carry, layer, config, (cos, sin), positions, attention_fn
         )
-        h = _rmsnorm_ckpt(h_in, layer["mlp_norm"])
-        if config.moe:
-            h_in = h_in + _moe_mlp(h, layer, config).astype(h_in.dtype)
-        else:
-            h_in = h_in + _dense_mlp(h, layer).astype(h_in.dtype)
-        return h_in, None
+        return _mlp_block(h_in, layer, config), None
 
     x, _ = jax.lax.scan(layer_step, x, stage_params["layers"])
     if last:
-        x = rmsnorm_reference(x, stage_params["final_norm"])
-        x = (x @ stage_params["lm_head"]).astype(jnp.float32)
+        x = _head(stage_params, x)
     return x
 
 
@@ -515,41 +533,44 @@ def decode_step(
     hd = config.head_dim
     length = cache["length"]
     positions = jnp.full((batch, 1), length, jnp.int32)
-    x = params["embed"][tokens]
+    x = _embed(params, tokens)
 
     def layer_step(carry, inputs):
         x = carry
         layer, k_cache, v_cache = inputs
-        h = rmsnorm_reference(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(batch, 1, config.n_heads, hd).transpose(0, 2, 1, 3)
-        k = (h @ layer["wk"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-        v = (h @ layer["wv"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, 0, length, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, 0, length, 0)
-        )
-        rep = config.n_heads // config.n_kv_heads
-        keys = _repeat_kv(k_cache, rep).astype(jnp.float32)
-        vals = _repeat_kv(v_cache, rep).astype(jnp.float32)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), keys) * hd ** -0.5
-        idx = jnp.arange(keys.shape[2])
-        s = jnp.where(idx[None, None, None, :] <= length, s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p, vals)
-        o = o.transpose(0, 2, 1, 3).reshape(batch, 1, config.n_heads * hd)
-        x = x + (o.astype(x.dtype) @ layer["wo"])
-        h2 = rmsnorm_reference(x, layer["mlp_norm"])
-        x = x + _dense_mlp(h2, layer).astype(x.dtype)
+        with jax.named_scope("attention"):
+            h = rmsnorm_reference(x, layer["attn_norm"])
+            q = (h @ layer["wq"]).reshape(batch, 1, config.n_heads, hd).transpose(0, 2, 1, 3)
+            k = (h @ layer["wk"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
+            v = (h @ layer["wv"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k.astype(k_cache.dtype), (0, 0, length, 0)
+            )
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v.astype(v_cache.dtype), (0, 0, length, 0)
+            )
+            rep = config.n_heads // config.n_kv_heads
+            keys = _repeat_kv(k_cache, rep).astype(jnp.float32)
+            vals = _repeat_kv(v_cache, rep).astype(jnp.float32)
+            s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), keys) * hd ** -0.5
+            idx = jnp.arange(keys.shape[2])
+            s = jnp.where(idx[None, None, None, :] <= length, s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhqk,bhkd->bhqd", p, vals)
+            o = o.transpose(0, 2, 1, 3).reshape(batch, 1, config.n_heads * hd)
+            x = x + (o.astype(x.dtype) @ layer["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rmsnorm_reference(x, layer["mlp_norm"])
+            x = x + _dense_mlp(h2, layer).astype(x.dtype)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
         layer_step, x, (params["layers"], cache["k"], cache["v"])
     )
-    x = rmsnorm_reference(x, params["final_norm"])
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rmsnorm_reference(x, params["final_norm"])
+        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     new_cache = {"k": new_k, "v": new_v, "length": length + 1}
     return logits, new_cache

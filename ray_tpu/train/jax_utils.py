@@ -534,7 +534,12 @@ def build_sharded_train_step(
     programs wrapped in ``step_annotation`` scopes. The fused
     single-runtime path stays ONE program (GSPMD inserts the collectives
     there; splitting it would forfeit cross-phase fusion), so it reports
-    an unsplit ``compute`` remainder."""
+    an unsplit ``compute`` remainder on the host. Its split is on the
+    device side: every op of the program is named by the scope it was
+    traced under (``models.transformer.SCOPES``: the model's blocks, and
+    ``optimizer`` around ``apply_update`` here) and by jax's own
+    ``transpose(`` / ``rematted_computation`` for backward and recompute;
+    ``benchmarks/harness/scopes.py`` reads them back from a device trace."""
     import jax
 
     from ray_tpu.train._internal.step_stats import step_annotation
@@ -550,11 +555,13 @@ def build_sharded_train_step(
             return loss_fn(params, batch)
 
     def apply_update(params, opt_state, grads):
-        updates, new_opt = optimizer.update(grads, opt_state, params)
-        new_params = jax.tree.map(
-            lambda p, u: (p + u.astype(p.dtype)), params, updates
-        )
-        return new_params, new_opt
+        # The last name of models.transformer.SCOPES.
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, opt_state, params)
+            new_params = jax.tree.map(
+                lambda p, u: (p + u.astype(p.dtype)), params, updates
+            )
+            return new_params, new_opt
 
     cross_worker = False
     if group_name:
