@@ -19,7 +19,9 @@ the MXU's native multiply) with float32 accumulation via
 preferred_element_type; only softmax/statistics math runs in f32 vectors.
 
 Causal block skipping: grid steps whose (q_block, kv_block) tile is entirely
-masked skip all compute (≈2× for causal training).
+masked (``_tile_needed``) skip all compute, and fetch nothing either: the
+index maps clamp a skipped step to its row's nearest needed block, which is
+already resident, so Pallas issues no DMA for it (≈2× for causal training).
 
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
@@ -38,6 +40,7 @@ from jax.experimental import pallas as pl
 from ray_tpu.ops import resolve_interpret
 
 _NEG_INF = -1e30
+_LANES = 128  # a vreg's lane count: the forward's statistics fill it
 
 
 def _mxu(x, precision):
@@ -48,11 +51,64 @@ def _mxu(x, precision):
     return x.astype(jnp.float32)
 
 
+def _across(stat, width):
+    """A lane-replicated ``[rows, _LANES]`` statistic at ``width`` lanes,
+    without a lane broadcast where ``width`` is whole vregs."""
+    if width % _LANES == 0:
+        return jnp.tile(stat, (1, width // _LANES))
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
 def _tile_needed(causal, causal_offset, q_index, kv_index, block_q, block_k):
-    """False only for tiles that the causal mask zeroes entirely."""
+    """False only for tiles that the causal mask zeroes entirely (the tile's
+    last query sits before its first key). A comparison only, so it serves
+    the kernels' ``program_id``s and ``causal_tile_counts``'s Python ints
+    alike; the index maps below hold the same boundary in closed form
+    (tests/test_ops.py holds them to it)."""
     if not causal:
         return True
     return causal_offset + (q_index + 1) * block_q - 1 >= kv_index * block_k
+
+
+def causal_tile_counts(seq_q, seq_k, block_q, block_k):
+    """How many tiles of one causal call (per head instance) are executed
+    and how many skipped: a property of the shapes alone."""
+    tiles = (seq_q // block_q) * (seq_k // block_k)
+    executed = sum(
+        _tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k)
+        for q_index in range(seq_q // block_q)
+        for kv_index in range(seq_k // block_k)
+    )
+    return {"skipped": tiles - executed, "executed": executed}
+
+
+def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks):
+    """K/V block of grid step (i, j, kv) in the fwd and dq kernels. A q
+    row's skipped steps are clamped to its last needed kv block: they name
+    the block already resident, and Pallas issues no DMA for them."""
+    def index_map(i, j, kv):
+        if causal:
+            last_key = jnp.maximum(causal_offset + (j + 1) * block_q - 1, 0)
+            kv = jnp.minimum(
+                kv, jnp.minimum(last_key // block_k, num_kv_blocks - 1)
+            )
+        return (i, kv, 0)
+
+    return index_map
+
+
+def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks):
+    """Q/dO/lse/delta block of grid step (i, j, qi) in the dkv kernel: a kv
+    row's skipped steps come first, clamped to its first needed q block."""
+    def index_map(i, j, qi):
+        if causal:
+            first_query = jnp.maximum(j * block_k - causal_offset, 0)
+            qi = jnp.maximum(
+                qi, jnp.minimum(first_query // block_q, num_q_blocks - 1)
+            )
+        return (i, qi, 0)
+
+    return index_map
 
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
@@ -105,14 +161,20 @@ def _flash_fwd_kernel(
             causal_offset=causal_offset,
         )
 
-        m_prev = m_scr[:]                        # [block_q, 1]
+        # Running max and sum are kept replicated across a vreg's lanes:
+        # a [block_q, 1] statistic would cost the same 64 vregs an
+        # operation with one lane in 128 used, plus a lane broadcast
+        # against every score tile.
+        m_prev = m_scr[:]                        # [block_q, _LANES]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                   # [block_q, block_k] f32
-        correction = jnp.exp(m_prev - m_new)     # [block_q, 1]
+        p = jnp.exp(s - _across(m_new, block_k))  # [block_q, block_k] f32
+        correction = jnp.exp(m_prev - m_new)     # [block_q, _LANES]
         l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
         v = v_ref[0]                             # [block_k, d]
-        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
+        acc_scr[:] = acc_scr[:] * _across(
+            correction, acc_scr.shape[1]
+        ) + jax.lax.dot_general(
             _mxu(p.astype(v.dtype), precision), _mxu(v, precision),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
@@ -122,8 +184,10 @@ def _flash_fwd_kernel(
     @pl.when(kv_index == num_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l)
+        o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
+            o_ref.dtype
+        )
+        lse_ref[0] = (m_scr[:] + jnp.log(l))[:, :1]
 
 
 def _flash_dq_kernel(
@@ -224,8 +288,8 @@ def flash_attention(
     *,
     causal: bool = True,
     scale: float | None = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
     precision: jax.lax.Precision | None = None,
 ) -> jax.Array:
@@ -235,6 +299,9 @@ def flash_attention(
     Fully differentiable with Pallas kernels on BOTH passes: the forward
     saves (q, k, v, out, lse) and the backward recomputes P blockwise —
     attention memory stays O(seq), never O(seq²).
+
+    block_q / block_k: None picks the block shape from the shapes
+    (``_block_sizes``); an int is an upper bound on it.
 
     precision=None keeps the MXU's fast bf16 multiply for bf16 inputs;
     tests pass Precision.HIGHEST for tight reference comparison.
@@ -275,10 +342,23 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, precision,
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def _block_sizes(seq_q, seq_k, block_q, block_k):
+def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype):
+    """The kernels' block shape, from the shapes they see. ``block_q`` /
+    ``block_k`` of None (the default) ask for the block that measured
+    fastest on a v5e (PERF.md, PR 25); an int is an upper bound."""
+    # 1024 x 1024 measured fastest at every length from 1024 to 16384
+    # (head_dim 128, bfloat16): a grid step's fixed cost is spread over four
+    # times the score elements of 512 x 512. Operand rows over 512 bytes
+    # (head_dim 256 in float32) do not fit the 16 MiB of scoped VMEM beside
+    # a 1024 x 1024 float32 score tile.
+    fitting = 1024 if head_dim * jnp.dtype(dtype).itemsize <= 512 else 512
+    if block_q is None:
+        block_q = fitting
+    if block_k is None:
+        block_k = fitting
     # Shrink to the largest power-of-two block that divides the sequence so
-    # callers never trip over the default block size (e.g. seq=768 with the
-    # 512 default halves to 256).
+    # callers never trip over the block asked for (e.g. seq=768 with 512
+    # halves to 256).
     block_q = min(block_q, seq_q)
     block_k = min(block_k, seq_k)
     while block_q > 1 and seq_q % block_q:
@@ -304,8 +384,8 @@ def _flash_forward(
     *,
     causal: bool = True,
     scale: float | None = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool,
     precision: jax.lax.Precision | None = None,
 ) -> tuple[jax.Array, jax.Array]:
@@ -314,7 +394,9 @@ def _flash_forward(
     assert kv_heads == heads, "repeat kv heads before calling (GQA)"
     if scale is None:
         scale = dim ** -0.5
-    block_q, block_k = _block_sizes(seq_q, seq_k, block_q, block_k)
+    block_q, block_k = _block_sizes(
+        seq_q, seq_k, block_q, block_k, dim, q.dtype
+    )
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
@@ -322,6 +404,7 @@ def _flash_forward(
     vr = v.reshape(bh, seq_k, dim)
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
+    causal_offset = seq_k - seq_q
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -331,7 +414,10 @@ def _flash_forward(
         block_k=block_k,
         num_kv_blocks=num_kv_blocks,
         precision=precision,
-        causal_offset=seq_k - seq_q,
+        causal_offset=causal_offset,
+    )
+    kv_map = _kv_index_map(
+        causal, causal_offset, block_q, block_k, num_kv_blocks
     )
     from jax.experimental.pallas import tpu as pltpu
 
@@ -340,8 +426,8 @@ def _flash_forward(
         grid=(bh, num_q_blocks, num_kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, kv: (i, kv, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, kv: (i, kv, 0)),
+            pl.BlockSpec((1, block_k, dim), kv_map),
+            pl.BlockSpec((1, block_k, dim), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
@@ -352,8 +438,8 @@ def _flash_forward(
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),    # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),    # running sum
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
             pltpu.VMEM((block_q, dim), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
@@ -375,7 +461,9 @@ def _flash_backward(
 ):
     batch, heads, seq_q, dim = q.shape
     seq_k = k.shape[2]
-    block_q, block_k = _block_sizes(seq_q, seq_k, block_q, block_k)
+    block_q, block_k = _block_sizes(
+        seq_q, seq_k, block_q, block_k, dim, q.dtype
+    )
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
@@ -403,13 +491,16 @@ def _flash_backward(
         num_kv_blocks=num_kv_blocks, precision=precision,
         causal_offset=causal_offset,
     )
+    kv_map = _kv_index_map(
+        causal, causal_offset, block_q, block_k, num_kv_blocks
+    )
     dq = pl.pallas_call(
         dq_kernel,
         grid=(bh, num_q_blocks, num_kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, kv: (i, kv, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, kv: (i, kv, 0)),
+            pl.BlockSpec((1, block_k, dim), kv_map),
+            pl.BlockSpec((1, block_k, dim), kv_map),
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
@@ -426,16 +517,19 @@ def _flash_backward(
         num_q_blocks=num_q_blocks, precision=precision,
         causal_offset=causal_offset,
     )
+    q_map = _q_index_map(
+        causal, causal_offset, block_q, block_k, num_q_blocks
+    )
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid=(bh, num_kv_blocks, num_q_blocks),
         in_specs=[
-            pl.BlockSpec((1, block_q, dim), lambda i, j, qi: (i, qi, 0)),
+            pl.BlockSpec((1, block_q, dim), q_map),
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_q, dim), lambda i, j, qi: (i, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, qi: (i, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, qi: (i, qi, 0)),
+            pl.BlockSpec((1, block_q, dim), q_map),
+            pl.BlockSpec((1, block_q, 1), q_map),
+            pl.BlockSpec((1, block_q, 1), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),

@@ -76,9 +76,11 @@ def _custom_calls(fn, *shapes) -> int:
 _flash = functools.partial(flash_attention, causal=True, interpret=False)
 
 
-def _flash_grads(q, k, v):
+def _flash_grads(q, k, v, block=None):
     return jax.grad(
-        lambda q, k, v: _flash(q, k, v).astype(jnp.float32).sum(),
+        lambda q, k, v: _flash(
+            q, k, v, block_q=block, block_k=block
+        ).astype(jnp.float32).sum(),
         argnums=(0, 1, 2),
     )(q, k, v)
 
@@ -92,13 +94,51 @@ def test_flash_backward_compiles_for_v5e(one_chip):
     assert _custom_calls(_flash_grads, *_qkv(one_chip, 4096)) == 3
 
 
-def test_flash_small_block_length_compiles_for_v5e(one_chip):
-    """seq 1000 is not a multiple of the 512 default: _block_sizes halves
-    down to 8, the smallest block the (8, 128) tiling accepts."""
+@pytest.mark.parametrize("dtype,head_dim,heads,block", [
+    ("bfloat16", 128, 32, 1024),   # the 16k cell's call
+    ("float32", 128, 4, 1024),     # 512-byte operand rows: the last that fit
+    ("bfloat16", 256, 4, 1024),
+    ("float32", 256, 4, 512),      # 1024-byte rows: _block_sizes falls back
+])
+def test_flash_long_sequence_compiles_for_v5e(one_chip, dtype, head_dim,
+                                              heads, block):
+    """seq 16384 takes the block shape _block_sizes picks for its head_dim
+    and dtype (1024 x 1024 is a 4 MiB float32 score tile): it has to fit
+    the chip's scoped VMEM, forward and backward."""
     from ray_tpu.ops.flash_attention import _block_sizes
 
-    assert _block_sizes(1000, 1000, 512, 512) == (8, 8)
-    assert _custom_calls(_flash_grads, *_qkv(one_chip, 1000)) == 3
+    dtype = jnp.dtype(dtype)
+    assert _block_sizes(16384, 16384, None, None, head_dim, dtype) == (
+        block, block)
+    shapes = [
+        jax.ShapeDtypeStruct((1, heads, 16384, head_dim), dtype, sharding=one_chip)
+    ] * 3
+    assert _custom_calls(_flash, *shapes) == 1
+    assert _custom_calls(_flash_grads, *shapes) == 3
+
+
+def test_flash_block_too_large_for_vmem_is_refused(one_chip):
+    """Why _block_sizes falls back at 1024-byte operand rows: asked for
+    1024 x 1024 there, the backward does not fit the scoped VMEM."""
+    shapes = [
+        jax.ShapeDtypeStruct((1, 4, 16384, 256), jnp.float32, sharding=one_chip)
+    ] * 3
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _custom_calls(functools.partial(_flash_grads, block=1024), *shapes)
+
+
+def test_flash_small_block_length_compiles_for_v5e(one_chip):
+    """seq 1000 is not a multiple of 512: asked for 512, _block_sizes halves
+    down to 8, the smallest block the (8, 128) tiling accepts. Left to
+    itself it takes the sequence as one block."""
+    from ray_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(1000, 1000, 512, 512, 128, jnp.bfloat16) == (8, 8)
+    assert _block_sizes(1000, 1000, None, None, 128, jnp.bfloat16) == (
+        1000, 1000)
+    for block in (512, None):
+        grads = functools.partial(_flash_grads, block=block)
+        assert _custom_calls(grads, *_qkv(one_chip, 1000)) == 3
 
 
 def test_rmsnorm_compiles_for_v5e(one_chip):
