@@ -194,7 +194,10 @@ def _list_array_to_numpy(arr) -> np.ndarray:
         values = arr
         while pa.types.is_fixed_size_list(atype):
             shape.append(atype.list_size)
-            values = values.values
+            # flatten(), not .values: a batch is a SLICE of its block, and
+            # .values ignores the slice's offset and length (the reshape
+            # below then failed and every batch fell back to to_pylist()).
+            values = values.flatten()
             atype = atype.value_type
         flat = values.to_numpy(zero_copy_only=False)
         return flat.reshape((len(arr), *shape))

@@ -8,10 +8,15 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     time in depth, XLA-friendly control flow.
   * attention = in-tree Pallas flash kernel (ops/flash_attention.py); ring /
     Ulysses sequence parallelism plug in via `attention_fn` (parallel/).
-  * MoE blocks use dense dispatch/combine einsums with an "expert" logical
-    dim — under pjit, GSPMD partitions the expert matmuls over the ep axis
-    and inserts the token all_to_alls (first-class EP, which the reference
-    lacks entirely — SURVEY §2.9).
+  * MoE blocks are dropless (no capacity, no token ever dropped): the
+    (token, choice) pairs are sorted by expert and the three expert matmuls
+    run as grouped matmuls over the ragged groups (ops/grouped_matmul.py), one
+    static-shaped program whatever the routing. Under a mesh the block runs
+    per data shard inside a shard_map, as the flash kernel does (GSPMD
+    cannot partition a Mosaic kernel: _moe_over_mesh). Expert weights carry
+    the "expert" logical dim, so an ep mesh axis shards them at rest; they
+    are all-gathered for the block: the all_to_all exchange over ep that
+    would leave them in place is not written yet.
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
 
 Reference parity: the reference has no model zoo of its own (models arrive
@@ -29,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention
+from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.mesh import LogicalRules
@@ -41,13 +47,23 @@ from ray_tpu.parallel.mesh import LogicalRules
 # train/jax_utils.py::build_sharded_train_step. A rename here renames a
 # metric: tests/test_named_scopes.py holds the vocabulary to the program.
 SCOPES = ("embed", "attention", "mlp", "head", "loss", "optimizer")
+# Inside "mlp", what a mixture-of-experts block names (_moe_mlp): "router"
+# (logits, softmax, top-k, the balancing statistics), "dispatch" (sort by
+# expert, gather the rows, weigh and sum them back per token), "experts"
+# (the three grouped matmuls and _silu_mul).
+MOE_SCOPES = ("router", "dispatch", "experts")
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # Divide the top-k softmax weights by their sum (Mixtral does; OLMoE
+    # does not: its eight weights sum to less than 1).
+    norm_topk_prob: bool = False
+    # Weight of the load-balancing loss added to the cross-entropy in
+    # loss_fn (Switch / Hugging Face load_balancing_loss_func); 0 = none.
+    aux_loss_coef: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +76,10 @@ class TransformerConfig:
     hidden_dim: int = 11008
     max_seq: int = 4096
     rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    # RMSNorm with a learned weight over the WHOLE projected q and k
+    # vectors, before the split into heads and before RoPE (OLMoE).
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16
     moe: MoEConfig | None = None
     # "flash" | "reference" | callable(q,k,v,causal)->o supplied by
@@ -129,6 +149,7 @@ def param_logical_dims(config: TransformerConfig) -> dict:
             "wk": ("layer", "embed", "kv"),
             "wv": ("layer", "embed", "kv"),
             "wo": ("layer", "heads", "embed"),
+            **({"q_norm": ("layer", None), "k_norm": ("layer", None)} if config.qk_norm else {}),
             "mlp_norm": ("layer", None),
             **(moe_mlp if config.moe else dense_mlp),
         },
@@ -177,6 +198,10 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
             "wk": dense(next(keys), nl, d, kv_out),
             "wv": dense(next(keys), nl, d, kv_out),
             "wo": dense(next(keys), nl, q_out, d, scale=q_out ** -0.5),
+            **(
+                {"q_norm": jnp.ones((nl, q_out), dt), "k_norm": jnp.ones((nl, kv_out), dt)}
+                if config.qk_norm else {}
+            ),
             "mlp_norm": jnp.ones((nl, d), dt),
             **mlp,
         },
@@ -220,17 +245,31 @@ def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
     return jnp.repeat(x, repeats, axis=1)
 
 
+def _qkv(h, layer, config: TransformerConfig):
+    """The q / k / v projections of the normed ``h`` as [batch, heads, seq,
+    head_dim], before RoPE. With ``qk_norm`` an RMSNorm with a learned
+    weight runs over the whole projected q and k vectors, before the split
+    into heads. Shared by training, the pipeline stages and decode."""
+    batch, seq, _ = h.shape
+
+    def project(weight, heads, norm=None):
+        x = h @ layer[weight]
+        if config.qk_norm and norm:
+            x = _rmsnorm_ckpt(x, layer[norm], config.rms_norm_eps)
+        return x.reshape(batch, seq, heads, config.head_dim)
+
+    q = project("wq", config.n_heads, "q_norm")
+    k = project("wk", config.n_kv_heads, "k_norm")
+    v = project("wv", config.n_kv_heads)
+    return tuple(x.transpose(0, 2, 1, 3) for x in (q, k, v))
+
+
 def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
     with jax.named_scope("attention"):
         batch, seq, d = x.shape
         hd = config.head_dim
-        h = _rmsnorm_ckpt(x, layer["attn_norm"])
-        q = (h @ layer["wq"]).reshape(batch, seq, config.n_heads, hd)
-        k = (h @ layer["wk"]).reshape(batch, seq, config.n_kv_heads, hd)
-        v = (h @ layer["wv"]).reshape(batch, seq, config.n_kv_heads, hd)
-        q = q.transpose(0, 2, 1, 3)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
+        h = _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
+        q, k, v = _qkv(h, layer, config)
         cos, sin = cos_sin
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
@@ -258,7 +297,9 @@ def _silu_mul(gate, up):
 # bf16 input instead of saving the f32 normalized tensor per layer.
 # prevent_cse=False on both: these only run under lax.scan, where the CSE
 # barriers are unnecessary and would block epilogue fusion.
-_rmsnorm_ckpt = jax.checkpoint(rmsnorm_reference, prevent_cse=False)
+@functools.partial(jax.checkpoint, prevent_cse=False, static_argnums=(2,))
+def _rmsnorm_ckpt(x, weight, eps):
+    return rmsnorm_reference(x, weight, eps=eps)
 
 
 def _dense_mlp(h, layer):
@@ -270,60 +311,171 @@ def _dense_mlp(h, layer):
     return _silu_mul(gate, up) @ layer["w_down"]
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _rows_by_expert(top_k, x, order, inverse):
+    """``x`` [tokens, d] -> [tokens * top_k, d]: row ``i`` is the token of
+    the ``i``-th (token, choice) pair in expert order. ``order`` is a
+    permutation (``inverse`` its inverse), so the transpose of this gather
+    is a gather too, and a sum over each token's ``top_k`` rows. Autodiff's
+    own transpose of ``x[order // top_k]`` and ``y[inverse]`` is a
+    scatter-add of ``tokens * top_k`` rows: 24.0 ms a step on a v5e at
+    OLMoE's 65,536 x 2048 rows and 2 layers, against 8.8 for these gathers
+    (PERF.md section 6, PR 26)."""
+    return x[jax.lax.div(order, jnp.int32(top_k))]
+
+
+def _rows_by_expert_fwd(top_k, x, order, inverse):
+    return x[jax.lax.div(order, jnp.int32(top_k))], inverse
+
+
+def _rows_by_expert_bwd(top_k, inverse, g):
+    per_token = g[inverse].reshape(-1, top_k, g.shape[-1])
+    return per_token.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
+
+
+@jax.custom_vjp
+def _rows_by_token(y, order, inverse):
+    """``y`` [tokens * top_k, d] in expert order -> the same rows in
+    (token, choice) order; the transpose permutes back."""
+    return y[inverse]
+
+
+def _rows_by_token_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _rows_by_token_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+
+
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _weighted_sum(per_token, weights):
+    """``sum_j weights[t, j] * per_token[t, j]``: float32 math, and (as
+    ``_silu_mul``) backward re-derives the float32 products from the model
+    dtype's ``per_token`` instead of keeping a 4-byte copy of every
+    (token, choice) row alive per layer (0.5 GiB a layer at OLMoE's
+    65,536 x 2048, where the step already needs 89.8 % of a v5e)."""
+    weighted = per_token.astype(jnp.float32) * weights.astype(jnp.float32)[:, :, None]
+    return jnp.sum(weighted, axis=1).astype(per_token.dtype)
+
+
 def _moe_mlp(h, layer, config: TransformerConfig):
-    """Dense dispatch/combine MoE (Mesh-TF style). Static shapes via
-    capacity buckets; expert dim carries the "expert" logical annotation so
-    GSPMD shards the expert matmuls over ep and inserts all_to_alls."""
+    """Dropless mixture of experts: every token reaches each of its
+    ``top_k`` experts whatever the routing. Returns ``(out, routing)``.
+
+    The ``tokens x top_k`` (token, choice) pairs are sorted by expert
+    (stable), the tokens' rows gathered in that order, and gate / up / down
+    run as grouped matmuls over the ragged groups (``ops/grouped_matmul.py``:
+    each row against its own expert only); the results go back to token
+    order, are weighted by the router's probabilities and summed per token.
+    Every shape is static (``[tokens * top_k, ...]``; the group sizes are
+    data), so one compiled program serves every routing. The router runs
+    in float32.
+
+    ``routing`` is what the balancing loss and a check need: ``prob_sum``
+    [experts] (softmax probabilities summed over tokens), ``counts``
+    [top_k, experts] (tokens whose j-th choice is expert e), ``experts``
+    and ``weights`` [tokens, top_k] (the choices and their weights).
+
+    One device's view: ``h`` and the experts are whole here. Under a mesh
+    ``_moe_over_mesh`` calls this once per data shard."""
     moe = config.moe
     batch, seq, d = h.shape
     tokens = batch * seq
     ht = h.reshape(tokens, d)
-    logits = (ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)              # [T, E]
-    capacity = max(
-        1, int(moe.capacity_factor * moe.top_k * tokens / moe.num_experts)
-    )
+    with jax.named_scope("router"):
+        logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                  # [T, E]
+        weights, experts = jax.lax.top_k(probs, moe.top_k)       # [T, K]
+        chosen = experts[:, :, None] == jnp.arange(moe.num_experts, dtype=experts.dtype)
+        if moe.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # [K, E]
+        routing = {
+            "prob_sum": jnp.sum(probs, axis=0), "counts": counts,
+            "experts": experts, "weights": weights,
+        }
+    with jax.named_scope("dispatch"):
+        pairs = jnp.arange(tokens * moe.top_k, dtype=jnp.int32)
+        _, order = jax.lax.sort((experts.reshape(-1), pairs), num_keys=1, is_stable=True)
+        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
+        group_sizes = jnp.sum(counts, axis=0)
+        rows = _rows_by_expert(moe.top_k, ht, order, inverse)    # [T*K, d]
+    with jax.named_scope("experts"):
+        gate = grouped_matmul(rows, layer["w_gate"], group_sizes)
+        up = grouped_matmul(rows, layer["w_up"], group_sizes)
+        out = grouped_matmul(_silu_mul(gate, up), layer["w_down"], group_sizes)
+    with jax.named_scope("dispatch"):
+        per_token = _rows_by_token(out, order, inverse)
+        out = _weighted_sum(per_token.reshape(tokens, moe.top_k, d), weights.astype(h.dtype))
+    return out.reshape(batch, seq, d), routing
 
-    combine = jnp.zeros((tokens, moe.num_experts, capacity), jnp.float32)
-    remaining = probs
-    # Per-expert slots already claimed by earlier top-k iterations: a token's
-    # 2nd-choice position must start AFTER every 1st-choice pick for that
-    # expert (GShard-style offset), or slots collide and tokens get summed.
-    occupancy = jnp.zeros((moe.num_experts,), jnp.float32)
-    for _ in range(moe.top_k):
-        gate, choice = jnp.max(remaining, axis=-1), jnp.argmax(remaining, axis=-1)
-        onehot = jax.nn.one_hot(choice, moe.num_experts, dtype=jnp.float32)
-        position = (
-            jnp.cumsum(onehot, axis=0) - 1.0 + occupancy[None, :]
-        ) * onehot
-        pos_idx = jnp.sum(position, axis=-1).astype(jnp.int32)
-        keep = pos_idx < capacity
-        slot = jax.nn.one_hot(pos_idx, capacity, dtype=jnp.float32)
-        contribution = (
-            gate[:, None, None] * keep[:, None, None]
-            * onehot[:, :, None] * slot[:, None, :]
-        )
-        combine = combine + contribution
-        occupancy = occupancy + jnp.sum(onehot, axis=0)
-        remaining = remaining * (1.0 - onehot)
-    dispatch = (combine > 0).astype(h.dtype)             # [T, E, C]
 
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, ht)  # [E, C, D]
-    gate_o = jnp.einsum("ecd,edm->ecm", expert_in, layer["w_gate"]).astype(h.dtype)
-    up_o = jnp.einsum("ecd,edm->ecm", expert_in, layer["w_up"]).astype(h.dtype)
-    expert_out = jnp.einsum(
-        "ecm,emd->ecd", _silu_mul(gate_o, up_o), layer["w_down"]
-    )
-    out = jnp.einsum("tec,ecd->td", combine.astype(h.dtype), expert_out)
-    return out.reshape(batch, seq, d)
+def _moe_over_mesh(h, layer, config: TransformerConfig):
+    """``_moe_mlp``, per data shard when traced under a device mesh.
+
+    GSPMD cannot partition a Mosaic kernel (``_flash_over_mesh`` has the
+    same trap), and a token's experts need nothing from another batch row:
+    each device routes, sorts and multiplies its own ``batch`` block
+    (dp, fsdp) against ALL the experts. The expert weights enter
+    replicated, so under ``ep`` / ``fsdp`` / ``tp`` they are all-gathered
+    before the call and every device of those axes repeats its data
+    shard's work: right on every mesh, and as fast as data parallelism
+    alone. The all_to_all exchange that would keep ``ep`` shards of the
+    experts in place is not written yet. ``prob_sum`` and ``counts`` are
+    summed over the data shards, so the balancing loss sees every token."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return _moe_mlp(h, layer, config)
+    rows = LogicalRules().spec(("batch", None, None), mesh)
+    shards = rows[0]
+
+    def per_shard(h, experts):
+        out, routing = _moe_mlp(h, experts, config)
+        if shards:
+            for name in ("prob_sum", "counts"):
+                routing[name] = jax.lax.psum(routing[name], shards)
+        return out, routing
+
+    whole = jax.sharding.PartitionSpec()
+    per_token = jax.sharding.PartitionSpec(shards, None)
+    experts = {name: layer[name] for name in ("router", "w_gate", "w_up", "w_down")}
+    return jax.shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(rows, {name: whole for name in experts}),
+        out_specs=(rows, {
+            "prob_sum": whole, "counts": whole,
+            "experts": per_token, "weights": per_token,
+        }),
+        check_vma=False,
+    )(h, experts)
+
+
+def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
+    """Hugging Face ``load_balancing_loss_func`` over the layer scan's
+    stacked ``routing`` (leading dim: layers): with f[j, e] the share of
+    (layer, token) pairs whose j-th choice is expert e and P[e] the mean
+    router probability of e, ``num_experts * sum_je f[j, e] P[e]``."""
+    pairs = routing["experts"].shape[0] * routing["experts"].shape[1]
+    f = jnp.sum(routing["counts"], axis=0).astype(jnp.float32) / pairs   # [K, E]
+    p = jnp.sum(routing["prob_sum"], axis=0) / pairs                      # [E]
+    return moe.num_experts * jnp.sum(f * p[None, :])
 
 
 def _mlp_block(x, layer, config: TransformerConfig):
+    """``(x + mlp(norm(x)), routing)``; ``routing`` is None for a dense MLP."""
     with jax.named_scope("mlp"):
-        h = _rmsnorm_ckpt(x, layer["mlp_norm"])
+        h = _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
         if config.moe:
-            return x + _moe_mlp(h, layer, config).astype(x.dtype)
-        return x + _dense_mlp(h, layer).astype(x.dtype)
+            out, routing = _moe_over_mesh(h, layer, config)
+            return x + out.astype(x.dtype), routing
+        return x + _dense_mlp(h, layer).astype(x.dtype), None
 
 
 def _embed(params, tokens):
@@ -331,10 +483,10 @@ def _embed(params, tokens):
         return params["embed"][tokens]
 
 
-def _head(params, x):
+def _head(params, x, config: TransformerConfig):
     """final_norm + lm_head: f32 logits."""
     with jax.named_scope("head"):
-        x = rmsnorm_reference(x, params["final_norm"])
+        x = rmsnorm_reference(x, params["final_norm"], eps=config.rms_norm_eps)
         return (x @ params["lm_head"]).astype(jnp.float32)
 
 
@@ -345,6 +497,18 @@ def forward(
     positions: jax.Array | None = None,
 ) -> jax.Array:
     """tokens: [batch, seq] int32 -> logits [batch, seq, vocab] (f32)."""
+    return forward_with_routing(params, tokens, config, positions)[0]
+
+
+def forward_with_routing(
+    params: dict,
+    tokens: jax.Array,
+    config: TransformerConfig,
+    positions: jax.Array | None = None,
+) -> tuple[jax.Array, dict | None]:
+    """``forward`` and the layer scan's stacked MoE ``routing`` (leading
+    dim: layers; see ``_moe_mlp``), None for a dense model: what
+    ``loss_fn``'s balancing loss and a reference check read."""
     attention_fn = _attention_impl(config)
     cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
     x = _embed(params, tokens)
@@ -352,7 +516,7 @@ def forward(
     def layer_step(carry, layer):
         x = carry
         x = _attention_block(x, layer, config, (cos, sin), positions, attention_fn)
-        return _mlp_block(x, layer, config), None
+        return _mlp_block(x, layer, config)
 
     if config.remat == "full":
         layer_step = jax.checkpoint(
@@ -366,8 +530,8 @@ def forward(
     elif config.remat is not None:
         raise ValueError(f"unknown remat policy {config.remat!r}")
 
-    x, _ = jax.lax.scan(layer_step, x, params["layers"])
-    return _head(params, x)
+    x, routing = jax.lax.scan(layer_step, x, params["layers"])
+    return _head(params, x, config), routing
 
 
 def logits_loss(
@@ -392,7 +556,12 @@ def loss_fn(
     config: TransformerConfig,
     mask: jax.Array | None = None,
 ) -> jax.Array:
-    return logits_loss(forward(params, tokens, config), targets, mask)
+    logits, routing = forward_with_routing(params, tokens, config)
+    loss = logits_loss(logits, targets, mask)
+    if config.moe and config.moe.aux_loss_coef:
+        with jax.named_scope("loss"):
+            loss = loss + config.moe.aux_loss_coef * load_balancing_loss(routing, config.moe)
+    return loss
 
 
 def num_params(params: dict) -> int:
@@ -404,6 +573,8 @@ def config_num_params(config: TransformerConfig) -> int:
     refuse a config before any array is materialized."""
     d, hd = config.dim, config.head_dim
     attn = d * hd * (config.n_heads * 2 + config.n_kv_heads * 2)
+    if config.qk_norm:
+        attn += hd * (config.n_heads + config.n_kv_heads)
     if config.moe:
         e = config.moe.num_experts
         mlp = d * e + 3 * e * d * config.hidden_dim
@@ -501,11 +672,12 @@ def stage_forward(
         h_in = _attention_block(
             carry, layer, config, (cos, sin), positions, attention_fn
         )
-        return _mlp_block(h_in, layer, config), None
+        # The MoE balancing loss is not carried across stages.
+        return _mlp_block(h_in, layer, config)[0], None
 
     x, _ = jax.lax.scan(layer_step, x, stage_params["layers"])
     if last:
-        x = _head(stage_params, x)
+        x = _head(stage_params, x, config)
     return x
 
 
@@ -539,10 +711,8 @@ def decode_step(
         x = carry
         layer, k_cache, v_cache = inputs
         with jax.named_scope("attention"):
-            h = rmsnorm_reference(x, layer["attn_norm"])
-            q = (h @ layer["wq"]).reshape(batch, 1, config.n_heads, hd).transpose(0, 2, 1, 3)
-            k = (h @ layer["wk"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-            v = (h @ layer["wv"]).reshape(batch, 1, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
+            h = rmsnorm_reference(x, layer["attn_norm"], eps=config.rms_norm_eps)
+            q, k, v = _qkv(h, layer, config)
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
             k_cache = jax.lax.dynamic_update_slice(
@@ -561,16 +731,12 @@ def decode_step(
             o = jnp.einsum("bhqk,bhkd->bhqd", p, vals)
             o = o.transpose(0, 2, 1, 3).reshape(batch, 1, config.n_heads * hd)
             x = x + (o.astype(x.dtype) @ layer["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rmsnorm_reference(x, layer["mlp_norm"])
-            x = x + _dense_mlp(h2, layer).astype(x.dtype)
+        x, _ = _mlp_block(x, layer, config)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = jax.lax.scan(
         layer_step, x, (params["layers"], cache["k"], cache["v"])
     )
-    with jax.named_scope("head"):
-        x = rmsnorm_reference(x, params["final_norm"])
-        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    logits = _head(params, x, config)[:, 0]
     new_cache = {"k": new_k, "v": new_v, "length": length + 1}
     return logits, new_cache

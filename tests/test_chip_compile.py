@@ -15,7 +15,10 @@ skips when it cannot be — never at import, in a ``skipif`` or in
 ``parametrize``.
 """
 
+import contextlib
 import functools
+import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -145,3 +148,86 @@ def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
     assert _custom_calls(functools.partial(rmsnorm, interpret=False), x, w) == 1
+
+
+def _grouped_loss(lhs, rhs, group_sizes, tile=None):
+    import ray_tpu.ops.grouped_matmul as gm
+
+    ctx = mock.patch.object(gm, "TILE", tile) if tile else contextlib.nullcontext()
+    with ctx:
+        out = gm.grouped_matmul(lhs, rhs, group_sizes, interpret=False)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, k, n):
+    """OLMoE's expert matmuls at the benchmark cell's size: 65,536 (token,
+    choice) rows over 64 experts, gate / up (2048 -> 1024) and down (1024 ->
+    2048), forward, and both gradients (the input's is ``gmm`` on the
+    transposed experts, the weights' is ``tgmm``)."""
+    shapes = (
+        jax.ShapeDtypeStruct((65536, k), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+    )
+    assert _custom_calls(_grouped_loss, *shapes) == 1
+    grads = jax.grad(_grouped_loss, argnums=(0, 1))
+    text = jax.jit(grads).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 1
+
+
+def test_grouped_matmul_tile_too_large_for_vmem_is_refused(one_chip):
+    """Why TILE stops at 512 x 1024 x 1024: the next size up needs more
+    VMEM than a kernel may use on a v5e (the chip refused it too: my chip
+    run, PR 26)."""
+    shapes = (
+        jax.ShapeDtypeStruct((65536, 2048), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+    )
+    big = functools.partial(_grouped_loss, tile=(1024, 2048, 1024))
+    with pytest.raises(Exception, match="(?i)vmem|memory"):
+        jax.jit(jax.grad(big, argnums=(0, 1))).lower(*shapes).compile()
+
+
+@pytest.mark.parametrize("axes", [
+    {"dp": 4}, {"dp": 2, "ep": 2}, {"fsdp": 2, "tp": 2},
+], ids=lambda axes: "-".join(f"{k}{v}" for k, v in axes.items()))
+def test_moe_step_compiles_for_a_v5e_mesh(topo, axes):
+    """A MoE model's loss and gradients across four chips: GSPMD refuses to
+    partition the Mosaic grouped matmuls ("wrap the call in a shard_map"),
+    which the CPU, interpreting them as plain HLO, never shows. Traced
+    under the mesh as ``build_sharded_train_step`` traces it, the block
+    runs per data shard (``transformer._moe_over_mesh``), data parallel
+    alone, with the experts sharded over ep, and under fsdp x tp."""
+    import ray_tpu.ops.flash_attention as flash_mod
+    import ray_tpu.ops.grouped_matmul as gm
+    from ray_tpu.models import transformer as T
+    from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
+
+    config = T.TransformerConfig(
+        vocab_size=512, dim=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        hidden_dim=128, max_seq=512, qk_norm=True, attention="flash",
+        moe=T.MoEConfig(num_experts=4, top_k=2, aux_loss_coef=0.01),
+    )
+    mesh = MeshSpec(axes).build(topo.devices)
+    rules = LogicalRules()
+    params = jax.tree.map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        jax.eval_shape(lambda: T.init_params(config, jax.random.PRNGKey(0))),
+        rules.tree_shardings(T.param_logical_dims(config), mesh),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (4, 512), jnp.int32, sharding=rules.sharding(["batch", None], mesh))
+
+    def loss(params, tokens):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return T.loss_fn(params, tokens, tokens, config)
+
+    with mock.patch.object(flash_mod, "resolve_interpret", lambda _i: False), \
+            mock.patch.object(gm, "resolve_interpret", lambda _i: False):
+        text = jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile().as_text()
+    # three flash kernels; gate / up / down forward, input and weight gradients
+    assert text.count("tpu_custom_call") == 12
+    assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 3

@@ -30,6 +30,21 @@ def test_block_tensor_columns():
     np.testing.assert_array_equal(out, arr)
 
 
+@pytest.mark.parametrize("shape", [(6, 4), (6, 2, 3)])
+def test_block_tensor_column_of_a_slice_is_an_ndarray(shape):
+    """A batch is a slice of its block: its tensor column comes back as the
+    stacked ndarray of ITS rows, not as an object array of Python lists
+    (the fallback every sliced batch took: 2.4 ms a batch of 2 x 4097
+    ids, inside every training step that reads ``iter_batches``)."""
+    from ray_tpu.data.block import BlockAccessor
+
+    arr = np.arange(np.prod(shape), dtype=np.int64).reshape(shape)
+    acc = BlockAccessor.for_block({"x": arr})
+    out = BlockAccessor.for_block(acc.slice(2, 5)).to_numpy()["x"]
+    assert out.dtype == np.int64 and out.shape == (3, *shape[1:])
+    np.testing.assert_array_equal(out, arr[2:5])
+
+
 def test_plan_fusion():
     from ray_tpu.data._internal.plan import (
         Filter, LogicalPlan, MapRows, MapStage, plan_stages, RandomShuffle, Read,

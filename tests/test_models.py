@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models.transformer import (
     MoEConfig, TransformerConfig, decode_step, forward, init_kv_cache,
@@ -42,59 +43,25 @@ def test_causality():
     )
 
 
-def test_moe_forward_and_capacity():
+def test_moe_forward_and_grad():
     config = TransformerConfig.tiny(moe=MoEConfig(num_experts=4, top_k=2))
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
-    loss = loss_fn(params, tokens, tokens, config)
+    loss, grads = jax.value_and_grad(loss_fn)(params, tokens, tokens, config)
     assert np.isfinite(float(loss))
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        assert float(jnp.abs(grads["layers"][name]).max()) > 0, name
 
 
-def test_moe_no_slot_collision():
-    """Regression: a token's 2nd-choice slot must not collide with another
-    token's 1st-choice slot in the same expert — every (expert, slot) pair
-    holds at most one token."""
-    from ray_tpu.models.transformer import _moe_mlp
-
-    config = TransformerConfig.tiny(
-        moe=MoEConfig(num_experts=4, top_k=2, capacity_factor=2.0)
-    )
-    layer_key = jax.random.PRNGKey(3)
-    d, hidden, experts = config.dim, config.hidden_dim, config.moe.num_experts
-    layer = {
-        "router": jax.random.normal(layer_key, (d, experts)) * 0.5,
-        "w_gate": jax.random.normal(layer_key, (experts, d, hidden)) * 0.05,
-        "w_up": jax.random.normal(layer_key, (experts, d, hidden)) * 0.05,
-        "w_down": jax.random.normal(layer_key, (experts, hidden, d)) * 0.05,
-    }
-    h = jax.random.normal(jax.random.PRNGKey(4), (2, 16, d))
-
-    captured = {}
-    import ray_tpu.models.transformer as T
-
-    orig_einsum = jnp.einsum
-
-    def spy_einsum(spec, *args, **kw):
-        if spec == "tec,td->ecd":
-            captured["dispatch"] = args[0]
-        return orig_einsum(spec, *args, **kw)
-
-    T.jnp.einsum, einsum_saved = spy_einsum, orig_einsum
-    try:
-        _moe_mlp(h, layer, config)
-    finally:
-        T.jnp.einsum = einsum_saved
-    dispatch = np.asarray(captured["dispatch"])  # [T, E, C]
-    per_slot = dispatch.sum(axis=0)  # tokens per (expert, slot)
-    assert per_slot.max() <= 1.0, (
-        f"slot collision: {per_slot.max()} tokens share one capacity slot"
-    )
-    # top_k=2 with generous capacity: nearly all 2T assignments should land
-    assert dispatch.sum() >= dispatch.shape[0] * 1.5
+OLMOE_SHAPED = dict(
+    n_kv_heads=4, qk_norm=True, rms_norm_eps=1e-5,
+    moe=MoEConfig(num_experts=8, top_k=2, aux_loss_coef=0.01),
+)
 
 
-def test_decode_matches_forward():
-    config = TransformerConfig.tiny()
+@pytest.mark.parametrize("overrides", [{}, OLMOE_SHAPED], ids=["dense", "olmoe"])
+def test_decode_matches_forward(overrides):
+    config = TransformerConfig.tiny(**overrides)
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 8), 0, 256)
     cache = init_kv_cache(config, 2, 16)

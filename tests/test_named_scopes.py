@@ -31,12 +31,21 @@ _OPERATION = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
+MOE = re.compile(r"(?:^|[/(])(" + "|".join(T.MOE_SCOPES) + r")(?:[/)]|$)")
+OLMOE_SHAPED = dict(
+    n_kv_heads=4, qk_norm=True, rms_norm_eps=1e-5,
+    moe=T.MoEConfig(num_experts=4, top_k=2, aux_loss_coef=0.01),
+)
+
+
 @functools.lru_cache(maxsize=None)
-def instructions(remat, scoped=True):
+def instructions(remat, scoped=True, moe=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
-    ``jax.named_scope`` of the program turned into a no-op."""
-    config = T.TransformerConfig.tiny(remat=remat)
+    ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
+    OLMoE-shaped tiny configuration (q/k norms, a dropless expert layer,
+    the balancing loss)."""
+    config = T.TransformerConfig.tiny(remat=remat, **(OLMOE_SHAPED if moe else {}))
     optimizer = optax.adamw(1e-3)
     patch = contextlib.nullcontext() if scoped else mock.patch.object(
         jax, "named_scope", lambda _name: contextlib.nullcontext()
@@ -104,6 +113,46 @@ def test_scopes_change_names_never_the_program(remat):
     assert not any(BLOCKS.search(n) for _op, n in plain)
 
 
+@pytest.mark.parametrize("remat", POLICIES)
+def test_every_moe_matmul_is_under_mlp_and_its_own_scope(remat):
+    """The expert matmuls (the grouped-matmul kernel is interpreted here:
+    its dots carry the ``pallas_call``'s name) under ``mlp`` AND
+    ``experts``, the router's under ``mlp`` AND ``router``, forward, backward
+    (``transpose(``) and, under full remat, recompute; nothing of the MoE
+    block outside ``mlp``."""
+    named = instructions(remat, moe=True)
+    matmuls = [n for op, n in named if op in ("dot", "convolution")]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    in_mlp = [n for n in matmuls if BLOCKS.search(n).group(1) == "mlp"]
+    assert in_mlp and not [n for n in in_mlp if not MOE.search(n)], in_mlp
+    by_scope = {scope: [n for n in in_mlp if MOE.search(n).group(1) == scope] for scope in T.MOE_SCOPES}
+    assert not by_scope["dispatch"]           # gathers and sums, no matmul
+    for scope in ("router", "experts"):
+        forward = [n for n in by_scope[scope] if "transpose(" not in n]
+        backward = [n for n in by_scope[scope] if "transpose(" in n and "rematted_computation" not in n]
+        recompute = [n for n in by_scope[scope] if "rematted_computation" in n]
+        assert forward and backward, scope
+        assert bool(recompute) == (remat == "full"), (scope, recompute)
+    # every op that carries a MoE scope lies inside the mlp block
+    assert not [n for _op, n in named if MOE.search(n) and BLOCKS.search(n).group(1) != "mlp"]
+
+
+@pytest.mark.parametrize("scope", T.MOE_SCOPES)
+def test_moe_scope_occurs_forward_and_backward(scope):
+    names = [n for _op, n in instructions(None, moe=True) if MOE.search(n) and MOE.search(n).group(1) == scope]
+    assert [n for n in names if "transpose(" not in n], scope
+    assert [n for n in names if "transpose(" in n], scope
+
+
+def test_moe_scopes_change_names_never_the_program():
+    scoped, plain = instructions(None, moe=True), instructions(None, scoped=False, moe=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+    assert not any(MOE.search(n) for _op, n in plain)
+
+
 def test_vocabulary():
     # benchmarks/harness/scopes.py repeats it: a rename renames metrics.
     assert T.SCOPES == ("embed", "attention", "mlp", "head", "loss", "optimizer")
+    # benchmarks/harness/moe_scopes.py repeats these; none is a block's name
+    assert T.MOE_SCOPES == ("router", "dispatch", "experts")
+    assert not set(T.MOE_SCOPES) & set(T.SCOPES)

@@ -121,6 +121,44 @@ def test_moe_expert_parallel_gspmd(cpu_mesh_devices):
     assert np.isfinite(float(loss))
 
 
+@pytest.mark.parametrize("axes", [
+    {"dp": 4}, {"dp": 2, "ep": 4}, {"fsdp": 2, "tp": 2, "ep": 2}, {"ep": 4},
+], ids=lambda axes: "-".join(f"{k}{v}" for k, v in axes.items()))
+def test_moe_under_the_step_mesh_matches_one_device(cpu_mesh_devices, axes):
+    """Traced under the mesh, as ``build_sharded_train_step`` traces it, the
+    dropless block runs per data shard inside a shard_map
+    (``transformer._moe_over_mesh``: GSPMD cannot partition the Mosaic
+    grouped matmuls on a chip). Loss, balancing term included, and every
+    gradient are one device's: each shard routes its own tokens, the
+    statistics are summed over the shards, and the axes that only repeat
+    the work (ep, tp) count it once."""
+    config = TransformerConfig.tiny(
+        dtype=jnp.float32, qk_norm=True,
+        moe=MoEConfig(num_experts=4, top_k=2, aux_loss_coef=0.01),
+    )
+    params = init_params(config, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, 256)
+    want_loss, want = jax.jit(jax.value_and_grad(loss_fn), static_argnums=3)(
+        params, tokens, tokens, config
+    )
+    mesh = MeshSpec(axes).build(cpu_mesh_devices)
+    rules = LogicalRules()
+
+    def under_mesh(params, tokens):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return loss_fn(params, tokens, tokens, config)
+
+    text = jax.jit(under_mesh).lower(params, tokens).as_text()
+    assert "shard_map" in text or "manual" in text
+    got_loss, got = jax.jit(jax.value_and_grad(under_mesh))(
+        jax.device_put(params, rules.tree_shardings(T.param_logical_dims(config), mesh)),
+        jax.device_put(tokens, rules.sharding(["batch", None], mesh)),
+    )
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-5 * float(jnp.max(jnp.abs(w))) + 1e-9
+
+
 def test_ring_attention_trains_in_model(cpu_mesh_devices):
     """config.attention plug-in: ring attention inside the scanned model."""
     mesh = MeshSpec({"dp": 2, "sp": 4}).build(cpu_mesh_devices)
