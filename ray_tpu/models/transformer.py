@@ -33,7 +33,9 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import attention_reference, flash_attention
+from ray_tpu.ops.flash_attention import (
+    RESIDUAL_NAMES, attention_reference, flash_attention,
+)
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -86,9 +88,13 @@ class TransformerConfig:
     # parallel/ (ring attention, ulysses).
     attention: str = "flash"
     # Rematerialization policy for the layer scan: None (save everything),
-    # "dots" (save matmul outputs only), "full" (save nothing — recompute
-    # the whole layer in backward). Trades HBM for FLOPs (SURVEY §7.0 HBM
-    # bullet); pick per chip memory at bench/train-config level.
+    # "dots" (save matmul outputs only), "full" (a layer's activations are
+    # recomputed in the backward). Trades HBM for FLOPs (SURVEY §7.0 HBM
+    # bullet); pick per chip memory at bench/train-config level. Under
+    # both strings the flash kernel's own outputs are the exception
+    # (``_remat_policy``): ``out`` and ``lse`` are kept, since recomputing
+    # them costs a whole kernel and keeping them seq x hidden bytes a
+    # layer, what the scan's carry already costs.
     remat: str | None = None
 
     @property
@@ -490,6 +496,22 @@ def _head(params, x, config: TransformerConfig):
         return (x @ params["lm_head"]).astype(jnp.float32)
 
 
+def _remat_policy(remat: str) -> Callable:
+    """What the layer scan's ``jax.checkpoint`` saves under ``remat``: the
+    flash kernel's named residuals in either case (a ``pallas_call`` is no
+    dot, so "dots" alone would run the forward kernel again too), and
+    under "dots" the matmul outputs besides."""
+    policies = jax.checkpoint_policies
+    flash = policies.save_only_these_names(*RESIDUAL_NAMES)
+    if remat == "full":
+        return flash
+    if remat == "dots":
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, flash
+        )
+    raise ValueError(f"unknown remat policy {remat!r}")
+
+
 def forward(
     params: dict,
     tokens: jax.Array,
@@ -518,17 +540,10 @@ def forward_with_routing(
         x = _attention_block(x, layer, config, (cos, sin), positions, attention_fn)
         return _mlp_block(x, layer, config)
 
-    if config.remat == "full":
+    if config.remat is not None:
         layer_step = jax.checkpoint(
-            layer_step, policy=jax.checkpoint_policies.nothing_saveable
+            layer_step, policy=_remat_policy(config.remat)
         )
-    elif config.remat == "dots":
-        layer_step = jax.checkpoint(
-            layer_step,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    elif config.remat is not None:
-        raise ValueError(f"unknown remat policy {config.remat!r}")
 
     x, routing = jax.lax.scan(layer_step, x, params["layers"])
     return _head(params, x, config), routing

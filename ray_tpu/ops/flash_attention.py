@@ -35,9 +35,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from ray_tpu.ops import resolve_interpret
+
+# The names the forward's two residuals carry (checkpoint_name): a
+# jax.checkpoint whose policy saves these names keeps ``out`` and ``lse``
+# and its backward does not run the forward kernel again
+# (models/transformer.py, remat). Under no such policy a name is an
+# identity and leaves no instruction.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 _NEG_INF = -1e30
 _LANES = 128  # a vreg's lane count: the forward's statistics fill it
@@ -327,6 +335,8 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=interpret, precision=precision,
     )
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, out, lse)
 
 

@@ -191,6 +191,37 @@ def test_grouped_matmul_tile_too_large_for_vmem_is_refused(one_chip):
         jax.jit(jax.grad(big, argnums=(0, 1))).lower(*shapes).compile()
 
 
+def _loss_and_grads_text(topo, config, axes, batch, seq) -> str:
+    """The optimized program of ``loss_fn`` and its gradients, compiled from
+    shapes for the described chips under ``axes``, traced under the mesh as
+    ``build_sharded_train_step`` traces it and with the Mosaic kernels
+    themselves (the platform rule would pick the interpreter: the backend
+    here is the CPU)."""
+    import ray_tpu.ops.flash_attention as flash_mod
+    import ray_tpu.ops.grouped_matmul as gm
+    from ray_tpu.models import transformer as T
+    from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
+
+    spec = MeshSpec(axes)
+    mesh = spec.build(topo.devices[:spec.size])
+    rules = LogicalRules()
+    params = jax.tree.map(
+        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+        jax.eval_shape(lambda: T.init_params(config, jax.random.PRNGKey(0))),
+        rules.tree_shardings(T.param_logical_dims(config), mesh),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (batch, seq), jnp.int32, sharding=rules.sharding(["batch", None], mesh))
+
+    def loss(params, tokens):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return T.loss_fn(params, tokens, tokens, config)
+
+    with mock.patch.object(flash_mod, "resolve_interpret", lambda _i: False), \
+            mock.patch.object(gm, "resolve_interpret", lambda _i: False):
+        return jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile().as_text()
+
+
 @pytest.mark.parametrize("axes", [
     {"dp": 4}, {"dp": 2, "ep": 2}, {"fsdp": 2, "tp": 2},
 ], ids=lambda axes: "-".join(f"{k}{v}" for k, v in axes.items()))
@@ -201,33 +232,41 @@ def test_moe_step_compiles_for_a_v5e_mesh(topo, axes):
     under the mesh as ``build_sharded_train_step`` traces it, the block
     runs per data shard (``transformer._moe_over_mesh``), data parallel
     alone, with the experts sharded over ep, and under fsdp x tp."""
-    import ray_tpu.ops.flash_attention as flash_mod
-    import ray_tpu.ops.grouped_matmul as gm
     from ray_tpu.models import transformer as T
-    from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
 
     config = T.TransformerConfig(
         vocab_size=512, dim=256, n_layers=2, n_heads=2, n_kv_heads=2,
         hidden_dim=128, max_seq=512, qk_norm=True, attention="flash",
         moe=T.MoEConfig(num_experts=4, top_k=2, aux_loss_coef=0.01),
     )
-    mesh = MeshSpec(axes).build(topo.devices)
-    rules = LogicalRules()
-    params = jax.tree.map(
-        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
-        jax.eval_shape(lambda: T.init_params(config, jax.random.PRNGKey(0))),
-        rules.tree_shardings(T.param_logical_dims(config), mesh),
-    )
-    tokens = jax.ShapeDtypeStruct(
-        (4, 512), jnp.int32, sharding=rules.sharding(["batch", None], mesh))
-
-    def loss(params, tokens):
-        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-            return T.loss_fn(params, tokens, tokens, config)
-
-    with mock.patch.object(flash_mod, "resolve_interpret", lambda _i: False), \
-            mock.patch.object(gm, "resolve_interpret", lambda _i: False):
-        text = jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile().as_text()
+    text = _loss_and_grads_text(topo, config, axes, batch=4, seq=512)
     # three flash kernels; gate / up / down forward, input and weight gradients
     assert text.count("tpu_custom_call") == 12
     assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 3
+
+
+@pytest.mark.parametrize("axes,batch", [
+    ({"dp": 1}, 1), ({"fsdp": 2, "tp": 2}, 2),
+], ids=["one-chip", "fsdp2-tp2"])
+def test_full_remat_runs_the_forward_kernel_once(topo, axes, batch):
+    """Two scanned layers at the 16k cell's attention shape (a device's
+    call is ``[1, 32, 16384, 128]`` on one chip, hidden 4096) under
+    ``remat="full"``: the layer checkpoint keeps the kernel's ``out`` and
+    ``lse`` by name, so loss and gradients hold exactly three Mosaic calls
+    (forward, dq, dkv; per shard under ``shard_map`` on the 2 x 2 mesh) and
+    not a fourth, the forward again in the backward."""
+    from ray_tpu.models import transformer as T
+
+    config = T.TransformerConfig(
+        vocab_size=512, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        hidden_dim=1024, max_seq=16384, attention="flash", remat="full",
+    )
+
+    def custom_calls():
+        return _loss_and_grads_text(topo, config, axes, batch, 16384).count("tpu_custom_call")
+
+    assert custom_calls() == 3
+    with mock.patch.object(
+        T, "_remat_policy", lambda _r: jax.checkpoint_policies.nothing_saveable
+    ):
+        assert custom_calls() == 4   # what the names are for

@@ -38,22 +38,37 @@ OLMOE_SHAPED = dict(
 )
 
 
+ONE_DEVICE = (("dp", 1),)
+MESH_2X2 = (("fsdp", 2), ("tp", 2))
+
+
 @functools.lru_cache(maxsize=None)
-def instructions(remat, scoped=True, moe=False):
+def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
     OLMoE-shaped tiny configuration (q/k norms, a dropless expert layer,
-    the balancing loss)."""
+    the balancing loss); ``axes`` the mesh, over as many of the virtual CPU
+    devices; ``keep_flash=False`` puts the layer checkpoint's policy back
+    to ``nothing_saveable``, what it was before it kept the flash
+    kernel's residuals."""
     config = T.TransformerConfig.tiny(remat=remat, **(OLMOE_SHAPED if moe else {}))
+    assert config.attention == "flash"
     optimizer = optax.adamw(1e-3)
-    patch = contextlib.nullcontext() if scoped else mock.patch.object(
-        jax, "named_scope", lambda _name: contextlib.nullcontext()
-    )
-    with patch:
+    mesh = MeshSpec(dict(axes))
+    with contextlib.ExitStack() as patches:
+        if not scoped:
+            patches.enter_context(mock.patch.object(
+                jax, "named_scope", lambda _name: contextlib.nullcontext()
+            ))
+        if not keep_flash:
+            patches.enter_context(mock.patch.object(
+                T, "_remat_policy",
+                lambda _remat: jax.checkpoint_policies.nothing_saveable,
+            ))
         setup = jax_utils.setup_sharded_training(
             lambda: T.init_params(config, jax.random.PRNGKey(0)), optimizer,
-            mesh=MeshSpec({"dp": 1}).build(jax.devices()[:1]),
+            mesh=mesh.build(jax.devices()[:mesh.size]),
             logical_dims=T.param_logical_dims(config),
         )
         step = jax_utils.build_sharded_train_step(
@@ -104,6 +119,42 @@ def test_backward_and_recompute_keep_the_block(remat):
         assert any(BLOCKS.search(n).group(1) == block for n in backward), block
     blocks = {BLOCKS.search(n).group(1) for n in recompute}
     assert blocks == ({"attention", "mlp"} if remat == "full" else set())
+
+
+def _recomputed_flash_forward(named):
+    """Instructions of the forward kernel (interpreted here: its ops carry
+    the jit's name) that run again in the backward."""
+    return [n for _op, n in named if "rematted_computation" in n and "_flash_forward" in n]
+
+
+@pytest.mark.parametrize("axes", [ONE_DEVICE, MESH_2X2], ids=["one-device", "fsdp2-tp2"])
+@pytest.mark.parametrize("remat", ["full", "dots"])
+def test_layer_remat_keeps_the_flash_residuals(remat, axes):
+    """What a checkpointed layer recomputes in the backward and what it
+    keeps: the flash kernel's ``out`` and ``lse`` are saved by name
+    (``flash_attention.RESIDUAL_NAMES``), so no instruction of the forward
+    kernel is under ``rematted_computation``, per shard under the mesh's
+    ``shard_map`` as on one device. The rest of the attention
+    block still is: under "full" its projections (``dot_general``), under
+    "dots", which keeps matmul outputs, what is elementwise around them."""
+    named = instructions(remat, axes=axes)
+    assert [n for _op, n in named if "_flash_forward" in n]     # the forward pass runs it
+    assert not _recomputed_flash_forward(named)
+    attention = [
+        (op, n) for op, n in named
+        if "rematted_computation" in n and BLOCKS.search(n) and BLOCKS.search(n).group(1) == "attention"
+    ]
+    assert attention
+    projections = [n for op, n in attention if op == "dot" and "dot_general" in n]
+    assert bool(projections) == (remat == "full"), projections
+
+
+@pytest.mark.parametrize("axes", [ONE_DEVICE, MESH_2X2], ids=["one-device", "fsdp2-tp2"])
+@pytest.mark.parametrize("remat", ["full", "dots"])
+def test_without_the_names_the_forward_kernel_runs_twice(remat, axes):
+    """Guards the test above: with the policy put back to
+    ``nothing_saveable`` the same search finds the recomputed kernel."""
+    assert _recomputed_flash_forward(instructions(remat, axes=axes, keep_flash=False))
 
 
 @pytest.mark.parametrize("remat", POLICIES)
