@@ -367,7 +367,11 @@ def main(argv: list[str] | None = None) -> None:
     from ray_tpu._private import accel
     from ray_tpu.models.transformer import TransformerConfig
 
-    config = TransformerConfig.llama2_7b(n_layers=2)
+    # Llama-2-7B's widths, depth cut to 2.
+    config = TransformerConfig(
+        vocab_size=32000, dim=4096, n_layers=2, n_heads=32, n_kv_heads=32,
+        hidden_dim=11008, max_seq=4096,
+    )
     try:
         start_cluster(chips)
         if chips == 1:

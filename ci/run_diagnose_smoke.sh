@@ -6,9 +6,10 @@
 #
 # Two layers:
 #   1. tests/test_workload.py — aggregator math under dup/replay chaos,
-#      deterministic straggler naming, MFU agreement with bench.py's
-#      formula, goodput sum-exactness, latency-histogram percentiles,
-#      the diagnose rule set, and the live end-to-end run (train ->
+#      deterministic straggler naming, MFU agreement with the
+#      6 * params * tokens formula, goodput sum-exactness,
+#      latency-histogram percentiles, the diagnose rule set, and the
+#      live end-to-end run (train ->
 #      workload series -> goodput -> /api/workload -> CLI);
 #   2. the workload_recorder_overhead release entry under --smoke,
 #      which enforces the smoke_criteria floors from

@@ -52,9 +52,9 @@ SUB_PHASES = (
     "opt_s",
 )
 
-# Peak bf16 FLOP/s per chip kind — must match release/bench_mfu.py
-# (bench.py), which is the acceptance reference: in-framework MFU and
-# the out-of-band benchmark must agree within 2% on the same run.
+# Peak bf16 FLOP/s per chip kind: the program's one peak table. Where
+# benchmarks/harness/peaks.json lists a kind, tests/test_workload.py
+# holds this entry to it.
 PEAK_FLOPS_BY_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -65,7 +65,7 @@ PEAK_FLOPS_BY_KIND = {
 
 
 def peak_flops_per_chip(device_kind: str | None) -> float | None:
-    """bench.py's peaks table, matched by prefix. None for unknown kinds
+    """PEAK_FLOPS_BY_KIND, matched by prefix. None for unknown kinds
     (CPU test runs): MFU is then simply not reported rather than wrong."""
     if not device_kind:
         return None
@@ -76,7 +76,7 @@ def peak_flops_per_chip(device_kind: str | None) -> float | None:
 
 
 def flops_for_tokens(params: int, tokens: float) -> float:
-    """The fwd+bwd rule of thumb bench.py uses: 6 * params * tokens."""
+    """The fwd+bwd rule of thumb: 6 * params * tokens."""
     return 6.0 * float(params) * float(tokens)
 
 

@@ -934,7 +934,7 @@ class CompiledDAG:
         self._retained.clear()
         try:
             self._ctx.io.run(self._teardown_async(), timeout=15)
-        except Exception:  # rtlint: disable=swallowed-exception - dead workers can't ack teardown; driver-side slot frees already ran
+        except Exception:  # rtlint: disable=swallowed-exception - dead workers can't ack teardown; the driver-side slot frees run within _teardown_async's own 10 s bound
             pass
         self._destroy_group(sync=True)
 
@@ -1043,8 +1043,16 @@ class CompiledDAG:
                 pass
 
         # Concurrent: one dead actor's timeout must not serialize the
-        # survivors' teardown behind it (failure-path latency).
-        await asyncio.gather(*[one(aid) for aid in self._actor_ids])
+        # survivors' teardown behind it (failure-path latency). And
+        # bounded by the RPC's own timeout: the redial of a DEAD actor
+        # backs off for up to ~26 s (rpc_retry_*), longer than
+        # _fail_cleanup waits for this coroutine, and the frees below
+        # must have run by the time it re-raises. A straggler is left to
+        # finish on its own; it swallows its own failure.
+        await asyncio.wait(
+            [asyncio.ensure_future(one(aid)) for aid in self._actor_ids],
+            timeout=10,
+        )
         # Driver-side backstop: every shm ring slot of this DAG (input,
         # inter-stage, and output rings) — a dead worker must not leak
         # its consumer-owned slots, and the driver-owned output ring is

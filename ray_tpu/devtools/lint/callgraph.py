@@ -121,7 +121,7 @@ def module_name(relpath: str) -> str | None:
 
     ``ray_tpu/util/gang.py`` -> ``ray_tpu.util.gang``;
     ``ray_tpu/data/__init__.py`` -> ``ray_tpu.data``. Top-level scripts
-    (``bench.py``) map to their bare stem.
+    (``chip_smoke.py``) map to their bare stem.
     """
     if not relpath.endswith(".py"):
         return None

@@ -5,7 +5,7 @@ fails ``ray_tpu lint`` instead of hanging a gang at 3am.
 Grid sources:
 
 * literal call sites of ``schedule_1f1b`` / ``schedule_interleaved_1f1b``
-  in scanned Python (``bench.py``, ``release/*.py``, tests) — argument
+  in scanned Python (``ray_tpu/``, ``release/*.py``, tests) — argument
   names resolve through same-function literal assignments
   (``num_stages, microbatches, virtual = 2, 8, 2``) and literal
   ``for s in (2, 4):`` loop iterables, cartesian-product style;

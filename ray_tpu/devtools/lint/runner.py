@@ -237,10 +237,9 @@ def add_lint_arguments(p: argparse.ArgumentParser) -> None:
 
 def default_paths(root: str) -> list[str]:
     paths = [os.path.join(root, "ray_tpu")]
-    for extra in ("release", "bench.py"):
-        cand = os.path.join(root, extra)
-        if os.path.exists(cand):
-            paths.append(cand)
+    release = os.path.join(root, "release")
+    if os.path.exists(release):
+        paths.append(release)
     return paths
 
 

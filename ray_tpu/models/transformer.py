@@ -110,29 +110,6 @@ class TransformerConfig:
         base.update(overrides)
         return TransformerConfig(**base)
 
-    @staticmethod
-    def llama2_7b(**overrides) -> "TransformerConfig":
-        base = dict(
-            vocab_size=32000, dim=4096, n_layers=32, n_heads=32,
-            n_kv_heads=32, hidden_dim=11008, max_seq=4096,
-        )
-        base.update(overrides)
-        return TransformerConfig(**base)
-
-    @staticmethod
-    def llama_1b(**overrides) -> "TransformerConfig":
-        """~1.2B params (16 layers × 67M + 131M embed/head) — the smallest
-        config a replicated f32 train state (params+grads+Adam ≈ 19 GB)
-        cannot fit on one 16 GB chip, and the fit-at-1B release gate's
-        subject. Shapes keep every shardable dim divisible by 8 so any
-        (dp, fsdp, tp) factorization of a v4-8 slice tiles evenly."""
-        base = dict(
-            vocab_size=32000, dim=2048, n_layers=16, n_heads=16,
-            n_kv_heads=16, hidden_dim=8192, max_seq=2048, remat="dots",
-        )
-        base.update(overrides)
-        return TransformerConfig(**base)
-
 
 # Logical dim names per param leaf (layer-stacked leaves lead with "layer").
 def param_logical_dims(config: TransformerConfig) -> dict:

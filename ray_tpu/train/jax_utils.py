@@ -2,20 +2,23 @@
 
 Role-equivalent of python/ray/train/torch/train_loop_utils.py ::
 prepare_model / prepare_data_loader, TPU-first: instead of wrapping a model
-in DDP, we build the device mesh, place params with NamedSharding, and sync
-gradients — in-jit (psum over ICI, the "xla" path) or eagerly through the
-collective group (the "ring" CPU twin).
+in DDP, one mesh expresses data, FSDP and tensor parallelism. In the order
+of the file:
 
-GSPMD-first training (ISSUE 10): :func:`setup_sharded_training` +
-:func:`build_sharded_train_step` make ONE ScalingConfig express data, FSDP,
-and tensor parallelism with no user-code changes — the mesh comes from the
-config's named axes, per-leaf NamedShardings from parallel.mesh logical
-dims + the FSDP shard-largest-axis auto-policy, and the whole step (grads,
-optimizer update, new state) compiles as one jax.jit program with explicit
-in/out shardings and *sharded optimizer state*. The replicated
-:func:`shard_params` path survives as the degenerate pure-data-parallel
-case — and refuses models whose train state cannot fit a chip, which is
-exactly where the sharded path takes over.
+* budget planning (:func:`ensure_train_state_fits`): a train state that
+  cannot fit a chip is refused from shapes alone, before any array exists;
+* the mesh (:func:`build_mesh`) over this jax runtime's devices;
+* the host-wire gradient sync (:func:`sync_gradients`,
+  :func:`sync_gradients_sharded`, :func:`begin_gradient_sync`) for gangs
+  of SEVERAL jax runtimes, whose workers each own a private mesh: an
+  eager mean through the collective group, outside any jit;
+* the fused GSPMD step (:func:`setup_sharded_training` +
+  :func:`build_sharded_train_step`): per-leaf NamedShardings from
+  parallel.mesh logical dims + the FSDP shard-largest-axis policy, and
+  the whole step (grads, optimizer update, new state) as ONE jax.jit
+  program with sharded optimizer state, in which GSPMD places every
+  collective;
+* save / restore of that state onto any (dp, fsdp, tp) factorization.
 """
 
 from __future__ import annotations
@@ -23,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
-from typing import Any, Callable, Iterable, Iterator
+from typing import Any, Callable
 
 import numpy as np
 
@@ -133,35 +136,6 @@ def build_mesh(axes: dict[str, int] | None = None, topology=None):
     if not axes:
         axes = {"dp": len(devices)}
     return MeshSpec(dict(axes)).build(devices)
-
-
-def shard_params(
-    params: Any, mesh, logical_dims: Any = None, *, enforce_budget: bool = True
-):
-    """Place a param pytree onto the mesh. With logical_dims (see
-    parallel.mesh.LogicalRules), params get TP/FSDP shardings; without,
-    they are replicated — the degenerate pure-data-parallel case.
-
-    The replicated path refuses models whose training residency (params
-    + grads + Adam moments) exceeds the per-device budget: replication
-    cannot fit them by construction, and the failure should be a clear
-    refusal pointing at the sharded path, not a mid-init host OOM."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from ray_tpu.parallel.mesh import LogicalRules
-
-    if logical_dims is not None:
-        shardings = LogicalRules().tree_shardings(logical_dims, mesh)
-        if enforce_budget:
-            ensure_train_state_fits(
-                params, shardings, what="sharded train state"
-            )
-        return jax.device_put(params, shardings)
-    if enforce_budget:
-        ensure_train_state_fits(params, None, what="replicated train state")
-    return jax.device_put(
-        params, jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
-    )
 
 
 def _flatten_tree(grads: Any):
@@ -319,42 +293,8 @@ def sync_gradients_sharded(
     return _unflatten_tree(flat, leaves, treedef)
 
 
-def grad_psum(x, axis: str = "dp", topology=None):
-    """The default in-jit gradient reduce (use inside shard_map/jit).
-
-    Single-slice meshes psum over ``axis``; with a SliceTopology the
-    reduce is placed tier by tier via ``hierarchical_psum`` — ICI first,
-    then DCN — so the compiler never routes a collective-heavy reduce
-    over the slow tier. build_mesh(topology=...) callers pass the same
-    topology here to get the matching reduction order."""
-    import jax
-
-    if topology is not None:
-        return topology.hierarchical_psum(x)
-    return jax.lax.psum(x, axis)
-
-
-def shard_batch(batch: Any, mesh, axis: str = "dp"):
-    """device_put a host batch with batch-dim sharding over `axis`."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    spec = NamedSharding(mesh, P(axis))
-    return jax.tree.map(lambda x: jax.device_put(x, spec), batch)
-
-
-def iter_global_batches(
-    it: Iterable, *, world_rank: int, world_size: int
-) -> Iterator:
-    """Stride an iterable of batches across ranks (the ring-backend data
-    path; ray_tpu.data shards upstream instead)."""
-    for i, batch in enumerate(it):
-        if i % world_size == world_rank:
-            yield batch
-
-
 # ---------------------------------------------------------------------------
-# GSPMD-first training (ISSUE 10)
+# The fused GSPMD step
 # ---------------------------------------------------------------------------
 def mesh_factorization(mesh) -> dict[str, int]:
     """The (dp, fsdp, tp, pp) factorization a mesh expresses — stamped
@@ -456,7 +396,7 @@ def setup_sharded_training(
       2. per-leaf NamedShardings via parallel.mesh.auto_shard_specs
          (logical-dim TP rules + the FSDP shard-largest-axis policy;
          axes absent from the mesh degrade to replication, so a pure-dp
-         mesh reproduces the replicated path);
+         mesh replicates every leaf);
       3. memory-budget check on the PLAN — a config that cannot fit is
          refused before any init work happens;
       4. ``jax.jit(init_fn, out_shardings=...)`` — every device
@@ -511,41 +451,18 @@ def build_sharded_train_step(
     loss_fn: Callable[[Any, Any], Any],
     optimizer: Any,
     setup: ShardedTrainSetup,
-    *,
-    group_name: str | None = None,
-    donate: bool = True,
 ) -> Callable[[Any, Any, Any], tuple[Any, Any, Any]]:
     """Compile ``loss_fn(params, batch) -> scalar`` into one train step.
 
     Returns ``step(params, opt_state, batch) -> (params, opt_state,
-    loss)``. On one jax runtime (real slices via jax.distributed, or the
-    in-worker mesh) the WHOLE step — grads, cross-device reductions,
-    optimizer update — is one jit program with explicit out_shardings
-    and donated state: GSPMD inserts every collective.
-
-    ``group_name`` handles the ring CPU twin's multi-process gangs: each
-    worker owns a private mesh, so cross-WORKER gradient averaging runs
-    eagerly through the collective group between a grad jit and an
-    apply jit (still sharded within the worker). That eager seam is also
-    where the step profiler's fwd/bwd/opt attribution lives (ISSUE 20):
-    the forward runs as ``jax.vjp`` THROUGH jit — the returned vjp
-    closure is a ``tree_util.Partial`` pytree carrying the residuals
-    across the jit boundary — so forward and backward are separate
-    programs wrapped in ``step_annotation`` scopes. The fused
-    single-runtime path stays ONE program (GSPMD inserts the collectives
-    there; splitting it would forfeit cross-phase fusion), so it reports
-    an unsplit ``compute`` remainder on the host. Its split is on the
-    device side: every op of the program is named by the scope it was
-    traced under (``models.transformer.SCOPES``: the model's blocks, and
-    ``optimizer`` around ``apply_update`` here) and by jax's own
-    ``transpose(`` / ``rematted_computation`` for backward and recompute;
-    ``benchmarks/harness/scopes.py`` reads them back from a device trace."""
+    loss)``: grads, cross-device reductions and the optimizer update are
+    ONE jit program over ``setup``'s mesh, with the planned out_shardings
+    and donated state; GSPMD inserts every collective. The program's ops
+    are named by the scope they were traced under
+    (``models.transformer.SCOPES``: the model's blocks, and ``optimizer``
+    around ``apply_update`` here), which is how a device trace is split
+    by block (``benchmarks/harness/scopes.py``)."""
     import jax
-
-    from ray_tpu.train._internal.step_stats import step_annotation
-
-    donate_args = (0, 1) if donate else ()
-    param_sh, opt_sh = setup.param_shardings, setup.opt_shardings
 
     def meshed_loss(params, batch):
         # Trace the model with the mesh in scope: code that must know it
@@ -563,62 +480,16 @@ def build_sharded_train_step(
             )
             return new_params, new_opt
 
-    cross_worker = False
-    if group_name:
-        from ray_tpu.util.collective import collective
+    def fused(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(meshed_loss)(params, batch)
+        new_params, new_opt = apply_update(params, opt_state, grads)
+        return new_params, new_opt, loss
 
-        cross_worker = collective.get_group(group_name).world_size > 1
-
-    if not cross_worker:
-        def fused(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(meshed_loss)(params, batch)
-            new_params, new_opt = apply_update(params, opt_state, grads)
-            return new_params, new_opt, loss
-
-        return jax.jit(
-            fused,
-            out_shardings=(param_sh, opt_sh, None),
-            donate_argnums=donate_args,
-        )
-
-    # jax 0.9 returns the vjp closure as a ``tree_util.Partial`` pytree,
-    # so it crosses the jit boundary. Its residuals may shard differently
-    # from params; out_shardings stays default on fwd so GSPMD propagates
-    # them. The unused batch cotangent inside bwd is dead code XLA
-    # eliminates.
-    fwd_fn = jax.jit(lambda p, b: jax.vjp(meshed_loss, p, b))
-    bwd_fn = jax.jit(lambda vf, ct: vf(ct)[0], out_shardings=param_sh)
-    apply_fn = jax.jit(
-        apply_update,
-        out_shardings=(param_sh, opt_sh),
-        donate_argnums=donate_args,
+    return jax.jit(
+        fused,
+        out_shardings=(setup.param_shardings, setup.opt_shardings, None),
+        donate_argnums=(0, 1),
     )
-
-    # Attribution syncs below sit on boundaries that are already serial:
-    # bwd consumes fwd's residuals, sync_gradients blocks on the grads,
-    # and next step's fwd consumes the applied params — so each
-    # block_until_ready closes a dependency edge the device queue
-    # enforces anyway, moving the wait INTO the phase that caused it
-    # instead of smearing it into the next annotation.
-    def step(params, opt_state, batch):
-        with step_annotation("fwd", phase="fwd"):
-            loss, vjp_fn = fwd_fn(params, batch)
-            jax.block_until_ready(loss)  # rtlint: disable=host-sync-in-step - attribution boundary; bwd consumes the residuals next anyway
-        with step_annotation("bwd", phase="bwd"):
-            grads = bwd_fn(vjp_fn, jax.numpy.ones_like(loss))
-            jax.block_until_ready(grads)  # rtlint: disable=host-sync-in-step - attribution boundary; sync_gradients blocks on grads next anyway
-        with step_annotation("grad_sync"):
-            # Phase accounting happens inside the collective layer
-            # (collective_s / comm_exposed_s) — the annotation only names
-            # the scope on the merged trace.
-            grads = sync_gradients(grads, group_name)
-            grads = jax.device_put(grads, param_sh)
-        with step_annotation("opt", phase="opt"):
-            params, opt_state = apply_fn(params, opt_state, grads)
-            jax.block_until_ready(params)  # rtlint: disable=host-sync-in-step - attribution boundary; next fwd consumes params anyway
-        return params, opt_state, loss
-
-    return step
 
 
 def save_sharded_state(

@@ -1,7 +1,7 @@
 """lint_clean release entry — the repo must lint clean, with teeth.
 
-Runs rtlint over the default paths (the ray_tpu package + release/ +
-bench.py) against the committed baseline and emits one JSON metrics
+Runs rtlint over the default paths (the ray_tpu package + release/)
+against the committed baseline and emits one JSON metrics
 line for release/run_all.py:
 
   * findings_new   — findings not covered by .rtlint-baseline.json

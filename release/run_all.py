@@ -111,16 +111,6 @@ def main() -> int:
     for entry in entries:
         if only and entry["name"] != only:
             continue
-        if entry.get("requires_tpu"):
-            # Read /dev, never jax: a parent that initialises the TPU
-            # backend holds the chip its _run_entry children need.
-            from ray_tpu._private.node_agent import detect_tpu_resources
-
-            if not detect_tpu_resources().get("TPU"):
-                results.append(
-                    {"benchmark": entry["name"], "skipped": "no TPU"}
-                )
-                continue
         print(f"== {entry['name']}", file=sys.stderr)
         result = _run_entry(entry, env)
         failures = _evaluate(entry, result, smoke)

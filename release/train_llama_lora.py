@@ -43,7 +43,11 @@ def main(full: bool = False):
     mesh = Mesh(devices.reshape(dp, tp), ("dp", "tp"))
 
     if full:
-        config = TransformerConfig.llama2_7b(max_seq=2048, dtype=jnp.bfloat16)
+        # Llama-2-7B's widths.
+        config = TransformerConfig(
+            vocab_size=32000, dim=4096, n_layers=32, n_heads=32,
+            n_kv_heads=32, hidden_dim=11008, max_seq=2048, dtype=jnp.bfloat16,
+        )
         batch, seq, steps = dp * 1, 2048, 10
         import bench_env
         if bench_env.smoke():

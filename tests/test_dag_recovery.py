@@ -210,7 +210,9 @@ def test_unsupervised_failure_cleans_up_and_carries_evidence(
     time.sleep(0.5)
     ref = dag.execute(1)
     with pytest.raises(exceptions.DAGActorDiedError) as excinfo:
-        ref.get(timeout=6.0)
+        # An unsupervised reader blocks for the whole timeout and probes
+        # liveness only then, so this is time the test always spends.
+        ref.get(timeout=15.0)
     err = excinfo.value
     # The error names the edge it was detected on, not just the actor.
     assert err.actor_id == b._actor_id

@@ -144,7 +144,7 @@ def test_bubble_fraction_shrinks_with_virtual_stages():
     assert bubble_fraction(4, 8, 2) == pytest.approx(3 / 19)
     for s, m in ((2, 4), (4, 8)):
         assert bubble_fraction(s, m, 2) < bubble_fraction(s, m, 1)
-    # The release gate's exact shape: S=2, v=2, M=8 sits under 0.10.
+    # The shape docs/sharding.md names: S=2, v=2, M=8 sits under 0.10.
     assert bubble_fraction(2, 8, 2) <= 0.10
 
 

@@ -189,9 +189,10 @@ def test_sharded_training_factorization_parity(cpu_mesh_devices):
 
 
 def test_replicated_path_refuses_over_budget(cpu_mesh_devices, monkeypatch):
-    """The degenerate pure-DP path (shard_params) refuses a train state
-    that can't fit replicated; the sharded planner accepts the same model
-    because per-device bytes shrink with the fsdp factor."""
+    """On a pure-dp mesh every leaf is replicated, and the planner refuses
+    a train state that can't fit that way before any array exists; it
+    accepts the same model on an fsdp mesh because per-device bytes shrink
+    with the fsdp factor."""
     optax = _optax()
     config = _tiny_config()
     params_shapes = jax.eval_shape(
@@ -200,11 +201,15 @@ def test_replicated_path_refuses_over_budget(cpu_mesh_devices, monkeypatch):
     replicated = jax_utils.state_bytes_per_device(params_shapes) * 12 // 10
     budget = replicated * 3  # < the x(2+slots) residency estimate
     monkeypatch.setenv("RAY_TPU_HBM_BYTES", str(budget))
-    mesh = MeshSpec({"dp": 8}).build(cpu_mesh_devices)
+    before = {id(a) for a in jax.live_arrays()}
     with pytest.raises(jax_utils.MemoryBudgetError):
-        jax_utils.shard_params(
-            T.init_params(config, jax.random.PRNGKey(0)), mesh
+        jax_utils.setup_sharded_training(
+            lambda: T.init_params(config, jax.random.PRNGKey(0)),
+            optax.sgd(0.1),
+            mesh=MeshSpec({"dp": 8}).build(cpu_mesh_devices),
+            logical_dims=T.param_logical_dims(config),
         )
+    assert not [a for a in jax.live_arrays() if id(a) not in before]
     # Same budget, fsdp mesh: the planner accepts (setup doesn't raise)
     # and the params really are fsdp-sharded, not replicated.
     mesh_fsdp = MeshSpec({"dp": 2, "fsdp": 4}).build(cpu_mesh_devices)

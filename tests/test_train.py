@@ -180,7 +180,8 @@ def _jax_dp_loop(config):
     the ring-backend twin of the in-jit psum path."""
     import jax
     import jax.numpy as jnp
-    from ray_tpu.train.jax_utils import build_mesh, shard_batch, sync_gradients
+    from ray_tpu.parallel.mesh import shard_batch
+    from ray_tpu.train.jax_utils import build_mesh, sync_gradients
 
     ctx = train.get_context()
     mesh = build_mesh()
