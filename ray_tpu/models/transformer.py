@@ -28,10 +28,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops.flash_attention import (
     RESIDUAL_NAMES, attention_reference, flash_attention,
@@ -506,8 +508,16 @@ def forward_with_routing(
     positions: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None]:
     """``forward`` and the layer scan's stacked MoE ``routing`` (leading
-    dim: layers; see ``_moe_mlp``), None for a dense model: what
-    ``loss_fn``'s balancing loss and a reference check read."""
+    dim: layers; see ``_moe_mlp``), None for a dense model: what a
+    reference check reads."""
+    x, routing = _hidden_with_routing(params, tokens, config, positions)
+    return _head(params, x, config), routing
+
+
+def _hidden_with_routing(params, tokens, config, positions=None):
+    """The last layer's output ``[batch, seq, hidden]``, before the final
+    norm, and the stacked ``routing`` that ``loss_fn``'s balancing loss
+    reads."""
     attention_fn = _attention_impl(config)
     cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
     x = _embed(params, tokens)
@@ -522,8 +532,7 @@ def forward_with_routing(
             layer_step, policy=_remat_policy(config.remat)
         )
 
-    x, routing = jax.lax.scan(layer_step, x, params["layers"])
-    return _head(params, x, config), routing
+    return jax.lax.scan(layer_step, x, params["layers"])
 
 
 def logits_loss(
@@ -541,6 +550,155 @@ def logits_loss(
         return jnp.mean(nll)
 
 
+# What one chunk of the head's logits may weigh on a device, counted in
+# float32: the chunk rule of ``_head_chunks``. Found on a v5e among 256 MiB,
+# 512 MiB, 1 GiB and one chunk (PERF.md section 6, PR 29).
+_LOGITS_CHUNK_BYTES = 512 << 20
+
+
+def _head_chunks(batch: int, seq: int, vocab: int) -> tuple[int, int]:
+    """``(chunks, length)`` of ``head_loss``'s walk along the sequence: the
+    fewest chunks of equal length (the last may hold padding) whose float32
+    logits, ``[batch * length, vocab]`` as ONE DEVICE holds them under the
+    mesh in scope (batch over dp / fsdp, vocabulary over tp), stay under
+    ``_LOGITS_CHUNK_BYTES``. The shapes decide, so ``[1, 16384]`` and
+    ``[2, 4096]`` tokens walk in chunks of the same 4096 rows."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        rows, _, columns = LogicalRules().spec(("batch", None, "vocab"), mesh)
+        shards = lambda axes: math.prod(
+            mesh.shape[a] for a in (axes if isinstance(axes, tuple) else (axes,)) if a
+        )
+        batch, vocab = -(-batch // shards(rows)), -(-vocab // shards(columns))
+    longest = max(_LOGITS_CHUNK_BYTES // (4 * batch * vocab), 1)
+    chunks = -(-seq // longest)
+    return chunks, -(-seq // chunks)
+
+
+def _by_chunk(a: jax.Array, chunks: int, length: int) -> jax.Array:
+    """``[batch, seq, ...]`` -> ``[chunks, batch * length, ...]``, zeros after
+    ``seq``: a chunk's rows are its ``length`` positions of every sequence.
+    The batch dimension stays ahead of the positions inside a chunk: under
+    a mesh it is the sharded one, and a chunk of flattened tokens would lie
+    on one data shard."""
+    batch, seq = a.shape[:2]
+    a = jnp.pad(a, ((0, 0), (0, chunks * length - seq)) + ((0, 0),) * (a.ndim - 2))
+    a = jnp.moveaxis(a.reshape(batch, chunks, length, *a.shape[2:]), 1, 0)
+    return a.reshape(chunks, batch * length, *a.shape[3:])
+
+
+def _from_chunks(a: jax.Array, batch: int, seq: int) -> jax.Array:
+    """``_by_chunk``'s inverse."""
+    chunks, rows = a.shape[:2]
+    a = jnp.moveaxis(a.reshape(chunks, batch, rows // batch, *a.shape[2:]), 0, 1)
+    return a.reshape(batch, chunks * (rows // batch), *a.shape[3:])[:, :seq]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _head_loss(eps, final_norm, lm_head, x, targets, weights):
+    """``sum(weights * nll)`` of the head's logits, with its own backward:
+    see ``head_loss``."""
+    return _head_loss_fwd(eps, final_norm, lm_head, x, targets, weights)[0]
+
+
+def _head_loss_fwd(eps, final_norm, lm_head, x, targets, weights):
+    batch, seq, _ = x.shape
+    vocab = lm_head.shape[1]
+    chunks, length = _head_chunks(batch, seq, vocab)
+    by_chunk = tuple(_by_chunk(a, chunks, length) for a in (x, targets, weights))
+
+    def one_chunk(i, carry):
+        total, nll, dlogits = carry
+        x, wanted, weight = (jax.lax.dynamic_index_in_dim(a, i, keepdims=False) for a in by_chunk)
+        with jax.named_scope("head"):
+            h = rmsnorm_reference(x, final_norm, eps=eps)
+            logits = (h @ lm_head).astype(jnp.float32)
+        with jax.named_scope("loss"):
+            wanted, weight = wanted[:, None], weight[:, None]
+            top = jnp.max(logits, axis=-1, keepdims=True)
+            exps = jnp.exp(logits - top)
+            norm = jnp.sum(exps, axis=-1, keepdims=True)
+            hit = jnp.arange(vocab, dtype=wanted.dtype)[None, :] == wanted
+            rows_nll = jnp.log(norm) + top - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1, keepdims=True)
+            # The one place the softmax is taken: the loss's gradient with
+            # respect to the logits, in the dtype the two matmuls of the
+            # backward take their operands in.
+            rows = ((exps / norm - hit) * weight).astype(lm_head.dtype)
+            total = total + jnp.sum(rows_nll * weight)
+            nll = jax.lax.dynamic_update_index_in_dim(nll, rows_nll[:, 0], i, axis=0)
+            dlogits = jax.lax.dynamic_update_index_in_dim(dlogits, rows, i, axis=0)
+        return total, nll, dlogits
+
+    loss, nll, dlogits = jax.lax.fori_loop(0, chunks, one_chunk, (
+        jnp.zeros((), jnp.float32),
+        jnp.zeros((chunks, batch * length), jnp.float32),
+        jnp.zeros((chunks, batch * length, vocab), lm_head.dtype),
+    ))
+    return loss, (final_norm, lm_head, x, targets, nll, dlogits)
+
+
+def _head_loss_bwd(eps, residuals, g):
+    final_norm, lm_head, x, targets, nll, dlogits = residuals
+    batch, seq = targets.shape
+    chunks, rows, _ = dlogits.shape
+    with jax.named_scope("head"):
+        h, norm_vjp = jax.vjp(
+            lambda x, weight: rmsnorm_reference(x, weight, eps=eps),
+            _by_chunk(x, chunks, rows // batch), final_norm,
+        )
+        # Two plain matmuls over every token at once, in the chunks' order,
+        # their results in the weights' dtype: under a mesh that is what the
+        # tp all-reduce of dh and the fsdp reduction of dhead carry. The
+        # incoming cotangent scales the results, not dlogits: no further
+        # pass over [tokens, vocab].
+        dh = jnp.einsum("crv,hv->crh", dlogits, lm_head)
+        dhead = jnp.einsum("crh,crv->hv", h, dlogits)
+        dx, dnorm = norm_vjp((dh * g).astype(h.dtype))
+        dhead = (dhead * g).astype(lm_head.dtype)
+        # dx waits for lm_head's gradient; the optimizer still takes dhead
+        # from before the barrier, so XLA goes on fusing AdamW's update of
+        # lm_head into this matmul. Left to itself the scheduler sinks that
+        # fusion (nothing but the step's outputs uses it) below the layers'
+        # backward and keeps dlogits alive all through it: 15.71 GiB for
+        # 14.12 in the OLMoE cell (PERF.md section 6, PR 29).
+        _, dx = jax.lax.optimization_barrier((dhead, dx))
+    with jax.named_scope("loss"):
+        dweights = g * _from_chunks(nll, batch, seq)
+    no_gradient = np.zeros(targets.shape, jax.dtypes.float0)
+    return dnorm, dhead, _from_chunks(dx, batch, seq), no_gradient, dweights
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def head_loss(
+    params: dict,
+    x: jax.Array,
+    targets: jax.Array,
+    config: TransformerConfig,
+    mask: jax.Array | None = None,
+) -> jax.Array:
+    """``logits_loss(_head(params, x, config), targets, mask)`` for training:
+    final norm, lm_head, cross-entropy and the gradient of the logits as
+    ONE function, walked along the sequence in chunks (``_head_chunks``).
+
+    A chunk works on a 2-D ``[batch * length, vocab]`` array: ``_head``'s
+    logits (accumulated in float32 by the matmul, rounded to the weights'
+    dtype as there), row maximum and log-sum-exp in float32, and, the
+    softmax being at hand, ``dlogits = (softmax - onehot) * mask / count``
+    in the weights' dtype, kept for the backward in place of the logits.
+    The backward is then two matmuls and the norm's: nothing reads a
+    ``[batch, seq, vocab]`` array again to derive the softmax a second and
+    a third time, and no float32 array of that size exists."""
+    if mask is None:
+        weights = jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
+    else:
+        weights = mask.astype(jnp.float32) / jnp.maximum(jnp.sum(mask), 1.0)
+    return _head_loss(
+        config.rms_norm_eps, params["final_norm"], params["lm_head"], x, targets, weights
+    )
+
+
 def loss_fn(
     params: dict,
     tokens: jax.Array,
@@ -548,8 +706,8 @@ def loss_fn(
     config: TransformerConfig,
     mask: jax.Array | None = None,
 ) -> jax.Array:
-    logits, routing = forward_with_routing(params, tokens, config)
-    loss = logits_loss(logits, targets, mask)
+    x, routing = _hidden_with_routing(params, tokens, config)
+    loss = head_loss(params, x, targets, config, mask)
     if config.moe and config.moe.aux_loss_coef:
         with jax.named_scope("loss"):
             loss = loss + config.moe.aux_loss_coef * load_balancing_loss(routing, config.moe)

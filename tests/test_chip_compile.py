@@ -270,3 +270,57 @@ def test_full_remat_runs_the_forward_kernel_once(topo, axes, batch):
         T, "_remat_policy", lambda _r: jax.checkpoint_policies.nothing_saveable
     ):
         assert custom_calls() == 4   # what the names are for
+
+
+def _mistral_7b_step(topo, batch, seq, remat):
+    """The compiled one-chip training step at the benchmark's Mistral-7B
+    widths (hidden 4096, 32 / 8 heads, MLP 14336, vocabulary 32768, depth
+    cut to 2, bfloat16, AdamW), built and compiled from shapes as the
+    benchmark's own rehearsal builds a cell's
+    (``benchmarks/harness/described.py``), with the Mosaic kernels."""
+    import types
+
+    from benchmarks.harness import described
+    from ray_tpu.models import transformer as T
+
+    config = T.TransformerConfig(
+        vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
+        hidden_dim=14336, max_seq=seq, rope_theta=1e6, attention="flash", remat=remat,
+    )
+    family = types.SimpleNamespace(
+        init=lambda key: T.init_params(config, key),
+        logical_dims=T.param_logical_dims(config),
+        loss=lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], config),
+    )
+    return described.compile_step(family, topo.devices, {"dp": 1}, batch, seq)[1]
+
+
+@pytest.mark.parametrize("batch,seq,remat,parent_gib", [
+    (2, 4096, None, 11.5391),      # mistral7b-seq4k-ingest
+    (1, 16384, "full", 11.1715),   # mistral7b-seq16k-fixed
+], ids=["seq4k", "seq16k"])
+def test_head_loss_keeps_no_float32_logits_of_the_whole_batch(topo, batch, seq, remat, parent_gib):
+    """``loss_fn`` ends in ``head_loss``: in the optimized step no float32
+    array with the vocabulary as its last dimension has ``batch * seq``
+    rows (what there is of float32 at that width is a chunk's, inside the
+    loop's fusions, and AdamW's update of ``lm_head``, 4096 rows both);
+    what is kept for the backward is the bfloat16 ``dlogits``, chunks of
+    4096 rows in both cells. The step needs no more of the chip than its
+    parent's did (``parent_gib``: the cell's ``hbm_step_gib``, ledger, PR
+    28, where ``loss_fn`` was ``logits_loss(_head(...))``; arguments +
+    temporaries + outputs - aliased)."""
+    import math
+
+    compiled = _mistral_7b_step(topo, batch, seq, remat)
+    tokens, vocab = batch * seq, 32768
+    of_vocab = {
+        (dtype, dims) for dtype, dims in (
+            (dtype, tuple(int(d) for d in dims.split(",")))
+            for dtype, dims in re.findall(r"\b(f32|bf16)\[([\d,]+)\]", compiled.as_text())
+        ) if len(dims) > 1 and dims[-1] == vocab
+    }
+    assert not [dims for dtype, dims in of_vocab if dtype == "f32" and math.prod(dims[:-1]) >= tokens]
+    assert ("bf16", (tokens // 4096, 4096, vocab)) in of_vocab      # dlogits, by chunk
+    from benchmarks.harness import described
+
+    assert described.step_memory(compiled)["total_bytes"] / 2**30 <= parent_gib

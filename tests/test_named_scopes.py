@@ -121,6 +121,34 @@ def test_backward_and_recompute_keep_the_block(remat):
     assert blocks == ({"attention", "mlp"} if remat == "full" else set())
 
 
+@pytest.mark.parametrize("axes", [ONE_DEVICE, MESH_2X2], ids=["one-device", "fsdp2-tp2"])
+def test_head_loss_names_its_forward_and_its_backward(axes):
+    """``head_loss`` is a ``custom_vjp``: its forward (the chunk loop: norm,
+    logits, the softmax statistics and ``dlogits``) carries ``head`` /
+    ``loss`` and no ``transpose(``; its backward, which jax traces apart
+    from the forward, opens ``head`` again around its two matmuls. With the
+    chunk rule held to 32 rows the loop is a ``while`` of four steps."""
+    rows = 32 // dict(axes).get("fsdp", 1)
+    columns = 256 // dict(axes).get("tp", 1)
+    with mock.patch.object(T, "_LOGITS_CHUNK_BYTES", 4 * rows * columns):
+        named = instructions.__wrapped__(None, axes=axes)
+
+    def of(block):
+        return [(op, n) for op, n in named if BLOCKS.search(n) and BLOCKS.search(n).group(1) == block]
+
+    head_dots = [n for op, n in of("head") if op == "dot"]
+    forward = [n for n in head_dots if "transpose(" not in n]
+    backward = [n for n in head_dots if "transpose(" in n]
+    assert len(forward) == 1 and "while/body" in forward[0], forward    # the logits, in the chunk loop
+    assert len(backward) == 2, backward                                  # dx and lm_head's gradient
+    assert not [n for n in head_dots if "rematted_computation" in n]
+    loss = of("loss")
+    assert {"exponential", "reduce"} <= {op for op, _n in loss}
+    # the softmax is taken in the forward and nowhere else
+    softmax = [n for op, n in named if op == "exponential" and BLOCKS.search(n) and BLOCKS.search(n).group(1) == "loss"]
+    assert softmax and not [n for n in softmax if "transpose(" in n], softmax
+
+
 def _recomputed_flash_forward(named):
     """Instructions of the forward kernel (interpreted here: its ops carry
     the jit's name) that run again in the backward."""

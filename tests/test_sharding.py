@@ -6,6 +6,8 @@ sharded train step's cross-factorization parity, the memory-budget
 refusal, and elastic resize through the committed-checkpoint protocol.
 """
 
+from unittest import mock
+
 import numpy as np
 import pytest
 
@@ -21,7 +23,7 @@ from ray_tpu.parallel import (
     schedule_1f1b,
     validate_schedule,
 )
-from ray_tpu.parallel.mesh import MeshSpec
+from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
 from ray_tpu.train import jax_utils
 
 
@@ -186,6 +188,45 @@ def test_sharded_training_factorization_parity(cpu_mesh_devices):
     # TP re-associates reductions: trajectories agree to float tolerance.
     np.testing.assert_allclose(losses_a, losses_b, rtol=1e-5, atol=1e-5)
     assert losses_a[-1] < losses_a[0]
+
+
+@pytest.mark.parametrize("chunks", [1, 4], ids=["one-chunk", "four-chunks"])
+@pytest.mark.parametrize("masked", [False, True], ids=["every-token", "masked"])
+def test_loss_fn_on_fsdp_tp_mesh_matches_one_device(cpu_mesh_devices, chunks, masked):
+    """``loss_fn`` ends in ``head_loss``, which walks the sequence in chunks
+    with the batch dimension kept whole: traced under fsdp 2 x tp 2 (batch
+    over fsdp, ``lm_head``'s hidden over fsdp and its vocabulary over tp)
+    the value and every gradient are one device's, whether the sequence is
+    one chunk or four. The chunk rule counts what ONE device holds: a
+    quarter of the global logits."""
+    config = _tiny_config()
+    batch, seq = 4, 16
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, seq), 0, config.vocab_size)
+    targets = jax.random.randint(jax.random.PRNGKey(2), (batch, seq), 0, config.vocab_size)
+    mask = (jax.random.uniform(jax.random.PRNGKey(3), (batch, seq)) > 0.3).astype(jnp.float32) if masked else None
+    mesh = MeshSpec({"fsdp": 2, "tp": 2}).build(cpu_mesh_devices[:4])
+    rules = LogicalRules()
+
+    def under_mesh(params, tokens, targets, mask):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            assert T._head_chunks(batch, seq, config.vocab_size) == (chunks, seq // chunks)
+            return T.loss_fn(params, tokens, targets, config, mask)
+
+    # bytes of the float32 logits of ONE device's chunk: batch / fsdp rows of vocab / tp
+    one_device_chunk = 4 * (batch // 2) * (seq // chunks) * (config.vocab_size // 2)
+    with mock.patch.object(T, "_LOGITS_CHUNK_BYTES", one_device_chunk):
+        want_loss, want = jax.jit(jax.value_and_grad(T.loss_fn), static_argnums=3)(
+            params, tokens, targets, config, mask)
+        rows = rules.sharding(["batch", None], mesh)
+        got_loss, got = jax.jit(jax.value_and_grad(under_mesh))(
+            jax.device_put(params, rules.tree_shardings(T.param_logical_dims(config), mesh)),
+            jax.device_put(tokens, rows), jax.device_put(targets, rows),
+            None if mask is None else jax.device_put(mask, rows),
+        )
+    assert abs(float(got_loss) - float(want_loss)) < 1e-5
+    for (path, w), g in zip(jax.tree_util.tree_flatten_with_path(want)[0], jax.tree.leaves(got)):
+        assert float(jnp.max(jnp.abs(g - w))) <= 1e-5 * float(jnp.max(jnp.abs(w))) + 1e-8, path
 
 
 def test_replicated_path_refuses_over_budget(cpu_mesh_devices, monkeypatch):
