@@ -1,4 +1,22 @@
-"""Flagship model: LLaMA-style decoder-only transformer, TPU-first.
+"""Flagship model: a pre-norm decoder-only transformer, TPU-first, in the
+three shapes today's open models take.
+
+What one layer computes, by configuration (all under one layer scan, one
+checkpoint policy, one head and loss):
+  * attention: RMSNorm, then either grouped-query attention (q / k / v
+    projections to one ``head_dim``, RoPE over the whole head, optional
+    q/k norms: Llama, Mistral, OLMoE) or, with ``latent=``, multi-head latent
+    attention (DeepSeek-V2/V3, Moonlight: keys and values rebuilt from a
+    normed low-rank latent, one RoPE key shared by all heads, q / k of
+    ``qk_nope + qk_rope`` dims against v of ``v_head_dim``); both through
+    the in-tree Pallas flash kernels (ops/flash_attention.py); ring /
+    Ulysses sequence parallelism plug in via ``attention`` (parallel/).
+  * MLP: RMSNorm, then a dense SwiGLU or, with ``moe=``, a dropless mixture
+    of experts: softmax top-k (OLMoE, Mixtral) or sigmoid scores chosen
+    under a correction bias, renormalised and scaled, with shared experts
+    beside the routed ones (DeepSeek-V3, Moonlight). ``first_dense_layers``
+    puts dense layers before the expert layers (their own stacked tree,
+    ``params["dense_layers"]``).
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays; every leaf has a logical
@@ -6,8 +24,6 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     LogicalRules switchboard away — model code never mentions mesh axes.
   * layers are scanned (lax.scan over stacked layer params): O(1) compile
     time in depth, XLA-friendly control flow.
-  * attention = in-tree Pallas flash kernel (ops/flash_attention.py); ring /
-    Ulysses sequence parallelism plug in via `attention_fn` (parallel/).
   * MoE blocks are dropless (no capacity, no token ever dropped): the
     (token, choice) pairs are sorted by expert and the three expert matmuls
     run as grouped matmuls over the ragged groups (ops/grouped_matmul.py), one
@@ -18,6 +34,8 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     are all-gathered for the block: the all_to_all exchange over ep that
     would leave them in place is not written yet.
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
+  * serving (init_kv_cache / decode_step) covers grouped-query attention
+    only: the latent cache is not written yet.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -56,6 +74,12 @@ SCOPES = ("embed", "attention", "mlp", "head", "loss", "optimizer")
 # expert, gather the rows, weigh and sum them back per token), "experts"
 # (the three grouped matmuls and _silu_mul).
 MOE_SCOPES = ("router", "dispatch", "experts")
+# What a DeepSeek-V3-shaped layer names besides: "latent", inside
+# "attention" (what latent attention costs beside W_q, W_o and the kernels:
+# the W_kv_a projection, the latent norm, W_kv_b, the rope on the shared
+# key, its broadcast to the heads and the concatenation), and "shared",
+# inside "mlp" beside MOE_SCOPES (the shared experts' SwiGLU).
+LATENT_SCOPES = ("latent", "shared")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,8 +90,38 @@ class MoEConfig:
     # does not: its eight weights sum to less than 1).
     norm_topk_prob: bool = False
     # Weight of the load-balancing loss added to the cross-entropy in
-    # loss_fn (Switch / Hugging Face load_balancing_loss_func); 0 = none.
+    # loss_fn (``load_balancing_loss``: by ``scoring``); 0 = none.
     aux_loss_coef: float = 0.0
+    # Width of one routed expert; None = the model's ``hidden_dim`` (OLMoE:
+    # the published ``intermediate_size`` IS the expert's). DeepSeek-V3's
+    # ``moe_intermediate_size`` beside the dense layers' ``hidden_dim``.
+    expert_dim: int | None = None
+    # Shared experts: one SwiGLU of width ``shared_experts * expert_dim`` on
+    # the same normed input, added to the routed experts' weighted sum.
+    shared_experts: int = 0
+    # "softmax": probabilities over all experts, the top-k of them are the
+    # weights (OLMoE, Mixtral). "sigmoid" (DeepSeek-V3 ``noaux_tc`` with one
+    # group): per-expert sigmoid scores; the top-k is taken of score +
+    # ``router_bias`` (a buffer no gradient reaches), the weights are the
+    # unbiased scores of the chosen.
+    scoring: str = "softmax"
+    # The weights are multiplied by this after renormalisation
+    # (``routed_scaling_factor``).
+    routed_scaling: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionConfig:
+    """Multi-head latent attention as DeepSeek-V3's ``config.json`` states
+    it with ``q_lora_rank`` null (the query is a plain projection)."""
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -86,6 +140,12 @@ class TransformerConfig:
     qk_norm: bool = False
     dtype: Any = jnp.bfloat16
     moe: MoEConfig | None = None
+    # Latent attention in place of the q / k / v projections (then
+    # ``n_kv_heads`` and ``qk_norm`` mean nothing); None = grouped-query.
+    latent: LatentAttentionConfig | None = None
+    # With ``moe``: this many leading layers keep the dense MLP of width
+    # ``hidden_dim`` (``first_k_dense_replace``).
+    first_dense_layers: int = 0
     # "flash" | "reference" | callable(q,k,v,causal)->o supplied by
     # parallel/ (ring attention, ulysses).
     attention: str = "flash"
@@ -126,73 +186,130 @@ def param_logical_dims(config: TransformerConfig) -> dict:
         "w_up": ("layer", "expert", "embed", "mlp"),
         "w_down": ("layer", "expert", "mlp", "embed"),
     }
-    return {
-        "embed": ("vocab", "embed"),
-        "layers": {
-            "attn_norm": ("layer", None),
+    if config.moe and config.moe.scoring == "sigmoid":
+        moe_mlp["router_bias"] = ("layer", None)
+    if config.moe and config.moe.shared_experts:
+        # Sharded as a dense MLP is: GSPMD partitions it, outside the
+        # per-shard call of the routed experts.
+        moe_mlp.update({"shared_" + name[2:]: dims for name, dims in dense_mlp.items()})
+    if config.latent:
+        # tp shards whole heads (W_q's and W_kv_b's columns are laid out
+        # head by head) and leaves the latent and the shared rope key whole.
+        attention = {
+            "wq": ("layer", "embed", "heads"),
+            "wkv_a": ("layer", "embed", None),
+            "kv_norm": ("layer", None),
+            "wkv_b": ("layer", None, "heads"),
+            "wo": ("layer", "heads", "embed"),
+        }
+    else:
+        attention = {
             "wq": ("layer", "embed", "heads"),
             "wk": ("layer", "embed", "kv"),
             "wv": ("layer", "embed", "kv"),
             "wo": ("layer", "heads", "embed"),
             **({"q_norm": ("layer", None), "k_norm": ("layer", None)} if config.qk_norm else {}),
-            "mlp_norm": ("layer", None),
-            **(moe_mlp if config.moe else dense_mlp),
-        },
+        }
+
+    def stack(mlp):
+        return {"attn_norm": ("layer", None), **attention, "mlp_norm": ("layer", None), **mlp}
+
+    return {
+        "embed": ("vocab", "embed"),
+        **({"dense_layers": stack(dense_mlp)} if config.first_dense_layers else {}),
+        "layers": stack(moe_mlp if config.moe else dense_mlp),
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
 
 
+def _projection_shapes(config: TransformerConfig) -> dict:
+    """``{leaf: (in, out)}`` of one layer's attention projections."""
+    d = config.dim
+    if config.latent:
+        la = config.latent
+        return {
+            "wq": (d, config.n_heads * la.qk_head_dim),
+            "wkv_a": (d, la.kv_lora_rank + la.qk_rope_head_dim),
+            "wkv_b": (la.kv_lora_rank, config.n_heads * (la.qk_nope_head_dim + la.v_head_dim)),
+            "wo": (config.n_heads * la.v_head_dim, d),
+        }
+    q_out, kv_out = config.n_heads * config.head_dim, config.n_kv_heads * config.head_dim
+    return {"wq": (d, q_out), "wk": (d, kv_out), "wv": (d, kv_out), "wo": (q_out, d)}
+
+
+def _norm_shapes(config: TransformerConfig) -> dict:
+    """``{leaf: width}`` of one layer's norm vectors."""
+    norms = {"attn_norm": config.dim, "mlp_norm": config.dim}
+    if config.latent:
+        norms["kv_norm"] = config.latent.kv_lora_rank
+    elif config.qk_norm:
+        shapes = _projection_shapes(config)
+        norms.update(q_norm=shapes["wq"][1], k_norm=shapes["wk"][1])
+    return norms
+
+
+def _expert_dim(config: TransformerConfig) -> int:
+    return config.moe.expert_dim or config.hidden_dim
+
+
 def init_params(config: TransformerConfig, key: jax.Array) -> dict:
     keys = iter(jax.random.split(key, 16))
+    # The dense prefix draws from a split of its own: a seed goes on giving
+    # the stack after it, and every older configuration, the same weights.
+    prefix_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 8))
     dt = config.dtype
-    d, hd = config.dim, config.head_dim
-    nl = config.n_layers
-    q_out = config.n_heads * hd
-    kv_out = config.n_kv_heads * hd
+    d = config.dim
+    prefix = config.first_dense_layers
+    nl = config.n_layers - prefix
 
     def dense(key, *shape, scale=None):
         scale = scale if scale is not None else shape[-2] ** -0.5
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
 
+    def swiglu(keys, *lead, width, names=("w_gate", "w_up", "w_down")):
+        return {
+            names[0]: dense(next(keys), *lead, d, width),
+            names[1]: dense(next(keys), *lead, d, width),
+            names[2]: dense(next(keys), *lead, width, d, scale=width ** -0.5),
+        }
+
+    def attention(keys, nl):
+        return {
+            **{name: jnp.ones((nl, width), dt) for name, width in _norm_shapes(config).items()},
+            **{
+                name: dense(next(keys), nl, *shape)
+                for name, shape in _projection_shapes(config).items()
+            },
+        }
+
     if config.moe:
-        experts = config.moe.num_experts
+        moe = config.moe
         mlp = {
-            "router": dense(next(keys), nl, d, experts).astype(jnp.float32),
-            "w_gate": dense(next(keys), nl, experts, d, config.hidden_dim),
-            "w_up": dense(next(keys), nl, experts, d, config.hidden_dim),
-            "w_down": dense(
-                next(keys), nl, experts, config.hidden_dim, d,
-                scale=config.hidden_dim ** -0.5,
-            ),
+            "router": dense(next(keys), nl, d, moe.num_experts).astype(jnp.float32),
+            **swiglu(keys, nl, moe.num_experts, width=_expert_dim(config)),
         }
+        if moe.scoring == "sigmoid":
+            mlp["router_bias"] = jnp.zeros((nl, moe.num_experts), jnp.float32)
     else:
-        mlp = {
-            "w_gate": dense(next(keys), nl, d, config.hidden_dim),
-            "w_up": dense(next(keys), nl, d, config.hidden_dim),
-            "w_down": dense(
-                next(keys), nl, config.hidden_dim, d,
-                scale=config.hidden_dim ** -0.5,
-            ),
-        }
-    return {
+        mlp = swiglu(keys, nl, width=config.hidden_dim)
+    params = {
         "embed": dense(next(keys), config.vocab_size, d, scale=0.02),
-        "layers": {
-            "attn_norm": jnp.ones((nl, d), dt),
-            "wq": dense(next(keys), nl, d, q_out),
-            "wk": dense(next(keys), nl, d, kv_out),
-            "wv": dense(next(keys), nl, d, kv_out),
-            "wo": dense(next(keys), nl, q_out, d, scale=q_out ** -0.5),
-            **(
-                {"q_norm": jnp.ones((nl, q_out), dt), "k_norm": jnp.ones((nl, kv_out), dt)}
-                if config.qk_norm else {}
-            ),
-            "mlp_norm": jnp.ones((nl, d), dt),
-            **mlp,
-        },
+        "layers": {**attention(keys, nl), **mlp},
         "final_norm": jnp.ones((d,), dt),
         "lm_head": dense(next(keys), d, config.vocab_size, scale=d ** -0.5),
     }
+    if config.moe and config.moe.shared_experts:
+        params["layers"].update(swiglu(
+            keys, nl, width=config.moe.shared_experts * _expert_dim(config),
+            names=("shared_gate", "shared_up", "shared_down"),
+        ))
+    if prefix:
+        params["dense_layers"] = {
+            **attention(prefix_keys, prefix),
+            **swiglu(prefix_keys, prefix, width=config.hidden_dim),
+        }
+    return params
 
 
 def _flash_over_mesh(q, k, v, causal):
@@ -249,19 +366,59 @@ def _qkv(h, layer, config: TransformerConfig):
     return tuple(x.transpose(0, 2, 1, 3) for x in (q, k, v))
 
 
+def _latent_qkv(h, layer, config: TransformerConfig, cos_sin, positions):
+    """Multi-head latent attention's q, k ``[batch, heads, seq, qk_nope +
+    qk_rope]`` and v ``[batch, heads, seq, v_head_dim]`` of the normed
+    ``h``, RoPE applied (DeepSeek-V3 with ``q_lora_rank`` null):
+
+    ``q = h W_q``, per head ``[q_nope, q_rope]``; ``h W_kv_a`` splits into
+    the latent ``c`` and ONE ``k_rope`` shared by all heads; ``RMSNorm(c)
+    W_kv_b`` gives per head ``[k_nope, v]``; RoPE (rotate-half) turns
+    ``q_rope`` and ``k_rope`` only; ``k = [k_nope, k_rope]``."""
+    la = config.latent
+    batch, seq, _ = h.shape
+    heads, nope = config.n_heads, la.qk_nope_head_dim
+    cos, sin = cos_sin
+    q = (h @ layer["wq"]).reshape(batch, seq, heads, la.qk_head_dim).transpose(0, 2, 1, 3)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)], axis=-1
+    )
+    with jax.named_scope("latent"):
+        kv_a = h @ layer["wkv_a"]
+        c = _rmsnorm_ckpt(kv_a[..., :la.kv_lora_rank], layer["kv_norm"], config.rms_norm_eps)
+        kv = (c @ layer["wkv_b"]).reshape(batch, seq, heads, nope + la.v_head_dim)
+        kv = kv.transpose(0, 2, 1, 3)
+        k_rope = apply_rope(kv_a[:, None, :, la.kv_lora_rank:], cos, sin, positions)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (batch, heads, seq, la.qk_rope_head_dim))],
+            axis=-1,
+        )
+        return q, k, kv[..., nope:]
+
+
 def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
     with jax.named_scope("attention"):
         batch, seq, d = x.shape
-        hd = config.head_dim
         h = _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
-        q, k, v = _qkv(h, layer, config)
-        cos, sin = cos_sin
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        rep = config.n_heads // config.n_kv_heads
-        o = attention_fn(q, _repeat_kv(k, rep), _repeat_kv(v, rep), True)
-        o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * hd)
+        if config.latent:
+            q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
+        else:
+            q, k, v = _qkv(h, layer, config)
+            cos, sin = cos_sin
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+            rep = config.n_heads // config.n_kv_heads
+            k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
+        o = attention_fn(q, k, v, True)
+        o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
         return x + (o @ layer["wo"]).astype(x.dtype)
+
+
+def _rope_tables(config: TransformerConfig):
+    """(cos, sin) over the dims RoPE turns: the whole head, or latent
+    attention's ``qk_rope_head_dim``."""
+    rotated = config.latent.qk_rope_head_dim if config.latent else config.head_dim
+    return rope_frequencies(rotated, config.max_seq, config.rope_theta)
 
 
 @functools.partial(jax.checkpoint, prevent_cse=False)
@@ -287,13 +444,13 @@ def _rmsnorm_ckpt(x, weight, eps):
     return rmsnorm_reference(x, weight, eps=eps)
 
 
-def _dense_mlp(h, layer):
+def _dense_mlp(h, w_gate, w_up, w_down):
     # silu math in f32 for accuracy but residuals stored in the model dtype
     # (bf16): halves the dominant activation-memory term vs keeping the
     # f32 intermediates live for backward.
-    gate = (h @ layer["w_gate"]).astype(h.dtype)
-    up = (h @ layer["w_up"]).astype(h.dtype)
-    return _silu_mul(gate, up) @ layer["w_down"]
+    gate = (h @ w_gate).astype(h.dtype)
+    up = (h @ w_up).astype(h.dtype)
+    return _silu_mul(gate, up) @ w_down
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -364,9 +521,12 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     in float32.
 
     ``routing`` is what the balancing loss and a check need: ``prob_sum``
-    [experts] (softmax probabilities summed over tokens), ``counts``
-    [top_k, experts] (tokens whose j-th choice is expert e), ``experts``
-    and ``weights`` [tokens, top_k] (the choices and their weights).
+    [experts] (what ``load_balancing_loss`` is linear in: under "softmax"
+    the probabilities summed over tokens; under "sigmoid", per sequence,
+    the tokens that chose e times the mean over the sequence of e's
+    normalised score, summed over sequences), ``counts`` [top_k, experts]
+    (tokens whose j-th choice is expert e), ``experts`` and ``weights``
+    [tokens, top_k] (the choices and their weights).
 
     One device's view: ``h`` and the experts are whole here. Under a mesh
     ``_moe_over_mesh`` calls this once per data shard."""
@@ -376,14 +536,28 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     ht = h.reshape(tokens, d)
     with jax.named_scope("router"):
         logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                  # [T, E]
-        weights, experts = jax.lax.top_k(probs, moe.top_k)       # [T, K]
+        if moe.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)                      # [T, E]
+            bias = jax.lax.stop_gradient(layer["router_bias"])
+            _, experts = jax.lax.top_k(scores + bias, moe.top_k)
+            weights = jnp.take_along_axis(scores, experts, axis=-1)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)             # [T, E]
+            weights, experts = jax.lax.top_k(scores, moe.top_k)  # [T, K]
         chosen = experts[:, :, None] == jnp.arange(moe.num_experts, dtype=experts.dtype)
         if moe.norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        if moe.routed_scaling != 1.0:
+            weights = weights * moe.routed_scaling
         counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # [K, E]
+        if moe.scoring == "sigmoid":
+            share = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(batch, seq, -1)
+            chose = jnp.sum(chosen.reshape(batch, seq, moe.top_k, -1), axis=(1, 2))
+            prob_sum = jnp.sum(chose * jnp.mean(share, axis=1), axis=0)
+        else:
+            prob_sum = jnp.sum(scores, axis=0)
         routing = {
-            "prob_sum": jnp.sum(probs, axis=0), "counts": counts,
+            "prob_sum": prob_sum, "counts": counts,
             "experts": experts, "weights": weights,
         }
     with jax.named_scope("dispatch"):
@@ -430,7 +604,10 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
 
     whole = jax.sharding.PartitionSpec()
     per_token = jax.sharding.PartitionSpec(shards, None)
-    experts = {name: layer[name] for name in ("router", "w_gate", "w_up", "w_down")}
+    experts = {
+        name: layer[name]
+        for name in ("router", "router_bias", "w_gate", "w_up", "w_down") if name in layer
+    }
     return jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(rows, {name: whole for name in experts}),
@@ -443,24 +620,44 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
 
 
 def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
-    """Hugging Face ``load_balancing_loss_func`` over the layer scan's
-    stacked ``routing`` (leading dim: layers): with f[j, e] the share of
-    (layer, token) pairs whose j-th choice is expert e and P[e] the mean
-    router probability of e, ``num_experts * sum_je f[j, e] P[e]``."""
+    """The balancing loss over the layer scan's stacked ``routing`` (leading
+    dim: layers), by the router's scoring.
+
+    "softmax": Hugging Face ``load_balancing_loss_func``: with f[j, e] the
+    share of (layer, token) pairs whose j-th choice is expert e and P[e] the
+    mean router probability of e, ``num_experts * sum_je f[j, e] P[e]``.
+
+    "sigmoid": the sequence-wise loss of the DeepSeek-V3 report: per
+    sequence of T tokens, ``f[e] = num_experts / (top_k T)`` x the tokens
+    that chose e, ``P[e]`` the mean over the sequence of e's score divided
+    by the token's sum of scores; ``sum_e f[e] P[e]``, averaged over
+    sequences and layers (``prob_sum`` holds ``sum_sequences chose[e] P[e]``)."""
     pairs = routing["experts"].shape[0] * routing["experts"].shape[1]
+    if moe.scoring == "sigmoid":
+        return moe.num_experts / moe.top_k * jnp.sum(routing["prob_sum"]) / pairs
     f = jnp.sum(routing["counts"], axis=0).astype(jnp.float32) / pairs   # [K, E]
     p = jnp.sum(routing["prob_sum"], axis=0) / pairs                      # [E]
     return moe.num_experts * jnp.sum(f * p[None, :])
 
 
 def _mlp_block(x, layer, config: TransformerConfig):
-    """``(x + mlp(norm(x)), routing)``; ``routing`` is None for a dense MLP."""
+    """``(x + mlp(norm(x)), routing)``; ``routing`` is None for a dense
+    MLP. The layer's own leaves say which it is: a stack with a ``router``
+    is a mixture of experts."""
     with jax.named_scope("mlp"):
         h = _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
-        if config.moe:
-            out, routing = _moe_over_mesh(h, layer, config)
-            return x + out.astype(x.dtype), routing
-        return x + _dense_mlp(h, layer).astype(x.dtype), None
+        if "router" not in layer:
+            out = _dense_mlp(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+            return x + out.astype(x.dtype), None
+        out, routing = _moe_over_mesh(h, layer, config)
+        if config.moe.shared_experts:
+            # Outside the per-shard call: a plain SwiGLU that GSPMD shards
+            # as it shards a dense MLP.
+            with jax.named_scope("shared"):
+                out = out + _dense_mlp(
+                    h, layer["shared_gate"], layer["shared_up"], layer["shared_down"]
+                ).astype(out.dtype)
+        return x + out.astype(x.dtype), routing
 
 
 def _embed(params, tokens):
@@ -516,15 +713,16 @@ def forward_with_routing(
 
 def _hidden_with_routing(params, tokens, config, positions=None):
     """The last layer's output ``[batch, seq, hidden]``, before the final
-    norm, and the stacked ``routing`` that ``loss_fn``'s balancing loss
-    reads."""
+    norm, and the expert layers' stacked ``routing`` that ``loss_fn``'s
+    balancing loss reads. A dense prefix (``params["dense_layers"]``) is
+    scanned first, under the same checkpoint policy."""
     attention_fn = _attention_impl(config)
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
+    cos_sin = _rope_tables(config)
     x = _embed(params, tokens)
 
     def layer_step(carry, layer):
         x = carry
-        x = _attention_block(x, layer, config, (cos, sin), positions, attention_fn)
+        x = _attention_block(x, layer, config, cos_sin, positions, attention_fn)
         return _mlp_block(x, layer, config)
 
     if config.remat is not None:
@@ -532,6 +730,8 @@ def _hidden_with_routing(params, tokens, config, positions=None):
             layer_step, policy=_remat_policy(config.remat)
         )
 
+    if "dense_layers" in params:
+        x, _ = jax.lax.scan(layer_step, x, params["dense_layers"])
     return jax.lax.scan(layer_step, x, params["layers"])
 
 
@@ -721,21 +921,35 @@ def num_params(params: dict) -> int:
 def config_num_params(config: TransformerConfig) -> int:
     """Parameter count from shapes alone — lets the memory-budget check
     refuse a config before any array is materialized."""
-    d, hd = config.dim, config.head_dim
-    attn = d * hd * (config.n_heads * 2 + config.n_kv_heads * 2)
-    if config.qk_norm:
-        attn += hd * (config.n_heads + config.n_kv_heads)
+    d = config.dim
+    attn = (
+        sum(math.prod(shape) for shape in _projection_shapes(config).values())
+        + sum(_norm_shapes(config).values())
+    )
+    dense_mlp = 3 * d * config.hidden_dim
     if config.moe:
-        e = config.moe.num_experts
-        mlp = d * e + 3 * e * d * config.hidden_dim
+        moe = config.moe
+        e = moe.num_experts
+        mlp = d * e + 3 * d * _expert_dim(config) * (e + moe.shared_experts)
+        if moe.scoring == "sigmoid":
+            mlp += e  # router_bias
     else:
-        mlp = 3 * d * config.hidden_dim
-    per_layer = attn + mlp + 2 * d
+        mlp = dense_mlp
+    prefix = config.first_dense_layers
     return (
-        config.n_layers * per_layer
+        (config.n_layers - prefix) * (attn + mlp)
+        + prefix * (attn + dense_mlp)
         + 2 * config.vocab_size * d  # embed + lm_head
         + d  # final_norm
     )
+
+
+def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
+    if config.first_dense_layers:
+        raise NotImplementedError(
+            f"{what} splits ONE stacked layer tree; a config with first_dense_layers "
+            "keeps two (dense_layers, layers): train it fused (loss_fn)"
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -748,6 +962,7 @@ def partition_stages(params: dict, config: TransformerConfig, num_stages: int) -
     final norm + lm_head. Stage trees are disjoint, so per-stage optimizer
     updates compose to exactly the fused update.
     """
+    _refuse_dense_prefix(config, "partition_stages")
     if config.n_layers % num_stages != 0:
         raise ValueError(
             f"n_layers={config.n_layers} not divisible by {num_stages} stages"
@@ -813,14 +1028,15 @@ def stage_forward(
     received over the collective p2p plane. Last stage: also applies
     final_norm + lm_head, returning f32 logits.
     """
+    _refuse_dense_prefix(config, "stage_forward")
     attention_fn = _attention_impl(config)
-    cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
+    cos_sin = _rope_tables(config)
     if first:
         x = _embed(stage_params, x)
 
     def layer_step(carry, layer):
         h_in = _attention_block(
-            carry, layer, config, (cos, sin), positions, attention_fn
+            carry, layer, config, cos_sin, positions, attention_fn
         )
         # The MoE balancing loss is not carried across stages.
         return _mlp_block(h_in, layer, config)[0], None
@@ -834,7 +1050,16 @@ def stage_forward(
 # ---------------------------------------------------------------------------
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
+def _refuse_latent_cache(config: TransformerConfig) -> None:
+    if config.latent:
+        raise NotImplementedError(
+            "decode with latent attention needs the latent KV cache (c and the shared "
+            "rope key a token, not k and v per head), which is ROADMAP Queue 2 item 7's"
+        )
+
+
 def init_kv_cache(config: TransformerConfig, batch: int, max_seq: int) -> dict:
+    _refuse_latent_cache(config)
     hd = config.head_dim
     shape = (config.n_layers, batch, config.n_kv_heads, max_seq, hd)
     return {
@@ -850,6 +1075,7 @@ def decode_step(
     """One greedy decode step. tokens: [batch, 1] -> (logits [batch, vocab],
     new cache). Static shapes: cache is a fixed-size ring the XLA compiler
     can tile; `length` is a traced scalar."""
+    _refuse_latent_cache(config)
     cos, sin = rope_frequencies(config.head_dim, config.max_seq, config.rope_theta)
     batch = tokens.shape[0]
     hd = config.head_dim

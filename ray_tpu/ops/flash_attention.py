@@ -301,8 +301,12 @@ def flash_attention(
     interpret: bool | None = None,
     precision: jax.lax.Precision | None = None,
 ) -> jax.Array:
-    """q,k,v: [batch, heads, seq, head_dim] (kv heads may be fewer: GQA is
-    handled by the caller repeating kv heads). Returns same shape as q.
+    """q, k: [batch, heads, seq, head_dim]; v: [batch, heads, seq, v_dim]
+    (GQA is handled by the caller repeating kv heads). Returns [batch, heads,
+    seq_q, v_dim]. ``v_dim`` may differ from ``head_dim`` (latent attention:
+    q / k of 192, v of 128): scores, ``dq`` and ``dk`` run over ``head_dim``;
+    ``P v``, ``dP = dO v^T``, ``dv`` and the output accumulator over
+    ``v_dim``; ``scale`` defaults to ``head_dim ** -0.5``.
 
     Fully differentiable with Pallas kernels on BOTH passes: the forward
     saves (q, k, v, out, lse) and the backward recomputes P blockwise —
@@ -353,9 +357,10 @@ _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype):
-    """The kernels' block shape, from the shapes they see. ``block_q`` /
-    ``block_k`` of None (the default) ask for the block that measured
-    fastest on a v5e (PERF.md, PR 25); an int is an upper bound."""
+    """The kernels' block shape, from the shapes they see (``head_dim``:
+    the larger of q / k's and v's). ``block_q`` / ``block_k`` of None (the
+    default) ask for the block that measured fastest on a v5e (PERF.md,
+    PR 25); an int is an upper bound."""
     # 1024 x 1024 measured fastest at every length from 1024 to 16384
     # (head_dim 128, bfloat16): a grid step's fixed cost is spread over four
     # times the score elements of 512 x 512. Operand rows over 512 bytes
@@ -401,17 +406,18 @@ def _flash_forward(
 ) -> tuple[jax.Array, jax.Array]:
     batch, heads, seq_q, dim = q.shape
     _, kv_heads, seq_k, _ = k.shape
+    v_dim = v.shape[-1]
     assert kv_heads == heads, "repeat kv heads before calling (GQA)"
     if scale is None:
         scale = dim ** -0.5
     block_q, block_k = _block_sizes(
-        seq_q, seq_k, block_q, block_k, dim, q.dtype
+        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype
     )
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
     kr = k.reshape(bh, seq_k, dim)
-    vr = v.reshape(bh, seq_k, dim)
+    vr = v.reshape(bh, seq_k, v_dim)
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
@@ -437,24 +443,24 @@ def _flash_forward(
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_k, dim), kv_map),
-            pl.BlockSpec((1, block_k, dim), kv_map),
+            pl.BlockSpec((1, block_k, v_dim), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+            pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, dim), q.dtype),
+            jax.ShapeDtypeStruct((bh, seq_q, v_dim), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, dim), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, v_dim), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return out.reshape(batch, heads, seq_q, dim), lse.reshape(
+    return out.reshape(batch, heads, seq_q, v_dim), lse.reshape(
         batch, heads, seq_q
     )
 
@@ -470,20 +476,20 @@ def _flash_backward(
     precision
 ):
     batch, heads, seq_q, dim = q.shape
-    seq_k = k.shape[2]
+    seq_k, v_dim = v.shape[2:]
     block_q, block_k = _block_sizes(
-        seq_q, seq_k, block_q, block_k, dim, q.dtype
+        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype
     )
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
     kr = k.reshape(bh, seq_k, dim)
-    vr = v.reshape(bh, seq_k, dim)
-    dor = g.astype(q.dtype).reshape(bh, seq_q, dim)
+    vr = v.reshape(bh, seq_k, v_dim)
+    dor = g.astype(q.dtype).reshape(bh, seq_q, v_dim)
     lser = lse.reshape(bh, seq_q, 1)
     # delta_i = rowsum(dO_i ⊙ O_i): tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(
-        dor.astype(jnp.float32) * out.reshape(bh, seq_q, dim).astype(
+        dor.astype(jnp.float32) * out.reshape(bh, seq_q, v_dim).astype(
             jnp.float32
         ),
         axis=-1,
@@ -510,8 +516,8 @@ def _flash_backward(
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_k, dim), kv_map),
-            pl.BlockSpec((1, block_k, dim), kv_map),
-            pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+            pl.BlockSpec((1, block_k, v_dim), kv_map),
+            pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
         ],
@@ -536,32 +542,30 @@ def _flash_backward(
         in_specs=[
             pl.BlockSpec((1, block_q, dim), q_map),
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_q, dim), q_map),
+            pl.BlockSpec((1, block_k, v_dim), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, block_q, v_dim), q_map),
             pl.BlockSpec((1, block_q, 1), q_map),
             pl.BlockSpec((1, block_q, 1), q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, block_k, v_dim), lambda i, j, qi: (i, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_k, dim), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, dim), v.dtype),
+            jax.ShapeDtypeStruct((bh, seq_k, v_dim), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dim), jnp.float32),
-            pltpu.VMEM((block_k, dim), jnp.float32),
+            pltpu.VMEM((block_k, v_dim), jnp.float32),
         ],
         interpret=interpret,
     )(qr, kr, vr, dor, lser, delta)
 
-    shape = (batch, heads, seq_q, dim)
-    kshape = (batch, heads, seq_k, dim)
     return (
-        dq.reshape(shape),
-        dk.reshape(kshape).astype(k.dtype),
-        dv.reshape(kshape).astype(v.dtype),
+        dq.reshape(q.shape),
+        dk.reshape(k.shape).astype(k.dtype),
+        dv.reshape(v.shape).astype(v.dtype),
     )
 
 

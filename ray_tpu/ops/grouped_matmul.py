@@ -9,8 +9,10 @@ The kernels are jax's own Pallas "megablox" (``jax.experimental.pallas.ops
 row tile that straddles two groups is visited once per group under a row
 mask), with its custom VJP: the input gradient is the same kernel on the
 transposed experts, the weight gradient the transposed grouped matmul
-``tgmm``. What this file adds is the tile choice and the repo's platform
-rule (interpreted off-TPU, ``ops.resolve_interpret``).
+``tgmm``. What this file adds is the tile choice, EACH of the three calls
+tiled for its own dimensions (megablox's own VJP hands the forward call's
+tiles to both gradients, where contraction and columns have swapped), and
+the repo's platform rule (interpreted off-TPU, ``ops.resolve_interpret``).
 
 Why not ``jax.lax.ragged_dot``, which the TPU compiler also turns into a
 grouped-matmul kernel of its own (``ragged-dot-none``, active rows only):
@@ -25,6 +27,8 @@ a Mosaic call lacks is that GSPMD can partition it; under a mesh
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
 from ray_tpu.ops import resolve_interpret
@@ -37,17 +41,68 @@ TILE = (512, 1024, 1024)
 
 def _tile(size: int, limit: int, least: int = 128) -> int:
     """The largest tile that divides ``size`` among ``limit``, ``limit / 2``,
-    ... down to ``least``; ``size`` itself when it is no larger than
-    ``limit`` or none divides (the kernels want whole tiles in all three
-    dimensions)."""
+    ... above ``least`` (the kernels want whole tiles in all three
+    dimensions). Where none does, ``size`` itself while it is at most one
+    and a half limits, then ``least`` if that divides it, else ``size``.
+    1408 = 11 x 128, Moonlight's expert width, is so one tile: as 128-wide
+    tiles it took 2.1 times as long in all six calls at [49152, 2048] x
+    [64, 2048, 1408] (PERF.md section 6, PR 30)."""
     if size <= limit:
         return size
     tile = limit
-    while tile >= least:
+    while tile > least:
         if size % tile == 0:
             return tile
         tile //= 2
+    if 2 * size > 3 * limit and size % least == 0:
+        return least
     return size
+
+
+def _tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    """One call's (rows, contraction, columns) tile. Rows halve when a tile
+    beside them is wider than its limit, to fit a v5e's scoped VMEM (the
+    weight gradient at 512 x 1024 x 1408 does not)."""
+    tk, tn = _tile(k, TILE[1]), _tile(n, TILE[2])
+    rows = TILE[0] // 2 if tk > TILE[1] or tn > TILE[2] else TILE[0]
+    return _tile(m, rows, 8), tk, tn
+
+
+def _kernels():
+    """megablox's ``(gmm, tgmm)``, the kernels under its own VJP."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    return gmm, tgmm
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped(lhs, rhs, group_sizes, interpret):
+    (m, k), n = lhs.shape, rhs.shape[-1]
+    gmm, _ = _kernels()
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, _tiling(m, k, n), interpret=interpret)
+
+
+def _grouped_fwd(lhs, rhs, group_sizes, interpret):
+    return _grouped(lhs, rhs, group_sizes, interpret), (lhs, rhs, group_sizes)
+
+
+def _grouped_bwd(interpret, residuals, grad):
+    lhs, rhs, group_sizes = residuals
+    (m, k), n = lhs.shape, rhs.shape[-1]
+    gmm, tgmm = _kernels()
+    # The input gradient contracts over n and writes k columns.
+    dlhs = gmm(
+        grad, rhs, group_sizes, lhs.dtype, _tiling(m, n, k), transpose_rhs=True,
+        interpret=interpret,
+    )
+    drhs = tgmm(
+        lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype, _tiling(m, k, n),
+        interpret=interpret,
+    )
+    return dlhs, drhs, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def grouped_matmul(
@@ -60,15 +115,4 @@ def grouped_matmul(
     """lhs: [m, k]; rhs: [groups, k, n]; group_sizes: [groups] int32,
     summing to m -> [m, n] in lhs's dtype (float32 accumulation).
     Differentiable in ``lhs`` and ``rhs``."""
-    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-
-    m, k = lhs.shape
-    n = rhs.shape[-1]
-    # One tiling for the forward call and both gradients (megablox hands it
-    # on): k and n swap roles in the input gradient, so both are held to
-    # the same limit.
-    tiling = (_tile(m, TILE[0], 8), _tile(k, TILE[1]), _tile(n, TILE[2]))
-    return megablox.gmm(
-        lhs, rhs, group_sizes, lhs.dtype, tiling, None, None, False,
-        resolve_interpret(interpret),
-    )
+    return _grouped(lhs, rhs, group_sizes, resolve_interpret(interpret))

@@ -144,6 +144,24 @@ def test_flash_small_block_length_compiles_for_v5e(one_chip):
         assert _custom_calls(grads, *_qkv(one_chip, 1000)) == 3
 
 
+def test_flash_two_head_dims_compile_for_v5e(one_chip):
+    """Latent attention's call at the Moonlight cell's size: q / k of 192
+    (a lane tile and a half), v / out / dO of 128, one sequence of 8192
+    and 16 heads, in the 1024 x 1024 blocks ``_block_sizes`` picks from the
+    larger dim: forward, and forward + dq + dkv, fit the scoped VMEM."""
+    from ray_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(8192, 8192, None, None, 192, jnp.bfloat16) == (1024, 1024)
+    wide = jax.ShapeDtypeStruct((1, 16, 8192, 192), jnp.bfloat16, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((1, 16, 8192, 128), jnp.bfloat16, sharding=one_chip)
+    out = jax.eval_shape(_flash, wide, wide, narrow)
+    assert out.shape == narrow.shape
+    assert _custom_calls(_flash, wide, wide, narrow) == 1
+    assert _custom_calls(_flash_grads, wide, wide, narrow) == 3
+    grads = jax.eval_shape(_flash_grads, wide, wide, narrow)
+    assert [g.shape[-1] for g in grads] == [192, 192, 128]
+
+
 def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
@@ -159,14 +177,18 @@ def _grouped_loss(lhs, rhs, group_sizes, tile=None):
     return jnp.sum(out.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
-def test_grouped_matmul_compiles_for_v5e(one_chip, k, n):
-    """OLMoE's expert matmuls at the benchmark cell's size: 65,536 (token,
-    choice) rows over 64 experts, gate / up (2048 -> 1024) and down (1024 ->
-    2048), forward, and both gradients (the input's is ``gmm`` on the
-    transposed experts, the weights' is ``tgmm``)."""
+@pytest.mark.parametrize("rows,k,n", [
+    (65536, 2048, 1024), (65536, 1024, 2048),     # OLMoE
+    (49152, 2048, 1408), (49152, 1408, 2048),     # Moonlight: 1408 = 11 x 128
+])
+def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
+    """The expert matmuls at the benchmark cells' sizes: OLMoE's 65,536
+    (token, choice) rows over 64 experts of width 1024 and Moonlight's
+    49,152 over 64 of width 1408, gate / up and down, forward, and both
+    gradients (the input's is ``gmm`` on the transposed experts, the
+    weights' is ``tgmm``), at the tiles ``grouped_matmul`` picks."""
     shapes = (
-        jax.ShapeDtypeStruct((65536, k), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
         jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip),
         jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
     )
@@ -188,7 +210,9 @@ def test_grouped_matmul_tile_too_large_for_vmem_is_refused(one_chip):
     )
     big = functools.partial(_grouped_loss, tile=(1024, 2048, 1024))
     with pytest.raises(Exception, match="(?i)vmem|memory"):
-        jax.jit(jax.grad(big, argnums=(0, 1))).lower(*shapes).compile()
+        # the value keeps the forward call, the one tiled 1024 x 2048 x 1024
+        # (each gradient is tiled for its own dimensions)
+        jax.jit(jax.value_and_grad(big, argnums=(0, 1))).lower(*shapes).compile()
 
 
 def _loss_and_grads_text(topo, config, axes, batch, seq) -> str:
@@ -242,6 +266,35 @@ def test_moe_step_compiles_for_a_v5e_mesh(topo, axes):
     text = _loss_and_grads_text(topo, config, axes, batch=4, seq=512)
     # three flash kernels; gate / up / down forward, input and weight gradients
     assert text.count("tpu_custom_call") == 12
+    assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 3
+
+
+@pytest.mark.parametrize("axes", [
+    {"dp": 2, "ep": 2}, {"fsdp": 2, "tp": 2},
+], ids=lambda axes: "-".join(f"{k}{v}" for k, v in axes.items()))
+def test_latent_attention_moe_step_compiles_for_a_v5e_mesh(topo, axes):
+    """DeepSeek-V3's block across four chips: the two-dim flash kernels per
+    (batch, head) shard under ``shard_map`` (tp shards ``W_q``, ``W_kv_b``
+    and ``W_o`` by whole heads, the latent and the shared rope key stay
+    whole), a dense first layer in a scan of its own, then the expert layer
+    per data shard with its shared experts outside the per-shard call, where
+    GSPMD shards them as a dense MLP."""
+    from ray_tpu.models import transformer as T
+
+    config = T.TransformerConfig(
+        vocab_size=512, dim=256, n_layers=2, n_heads=2, n_kv_heads=2,
+        hidden_dim=384, max_seq=512, rms_norm_eps=1e-5, attention="flash",
+        latent=T.LatentAttentionConfig(
+            kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
+        first_dense_layers=1,
+        moe=T.MoEConfig(
+            num_experts=4, top_k=2, norm_topk_prob=True, aux_loss_coef=0.001, expert_dim=128,
+            shared_experts=2, scoring="sigmoid", routed_scaling=2.446),
+    )
+    text = _loss_and_grads_text(topo, config, axes, batch=4, seq=512)
+    # three flash kernels in each of the two scans; gate / up / down forward,
+    # input and weight gradients in the expert layer's
+    assert text.count("tpu_custom_call") == 15
     assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 3
 
 

@@ -38,12 +38,23 @@ OLMOE_SHAPED = dict(
 )
 
 
+LATENT = re.compile(r"(?:^|[/(])(" + "|".join(T.LATENT_SCOPES) + r")(?:[/)]|$)")
+MOONLIGHT_SHAPED = dict(
+    n_layers=3, n_kv_heads=4, hidden_dim=160, rms_norm_eps=1e-5, first_dense_layers=1,
+    latent=T.LatentAttentionConfig(
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16),
+    moe=T.MoEConfig(
+        num_experts=4, top_k=2, norm_topk_prob=True, aux_loss_coef=0.001, expert_dim=32,
+        shared_experts=2, scoring="sigmoid", routed_scaling=2.446),
+)
+
+
 ONE_DEVICE = (("dp", 1),)
 MESH_2X2 = (("fsdp", 2), ("tp", 2))
 
 
 @functools.lru_cache(maxsize=None)
-def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True):
+def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
@@ -51,8 +62,11 @@ def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True
     the balancing loss); ``axes`` the mesh, over as many of the virtual CPU
     devices; ``keep_flash=False`` puts the layer checkpoint's policy back
     to ``nothing_saveable``, what it was before it kept the flash
-    kernel's residuals."""
-    config = T.TransformerConfig.tiny(remat=remat, **(OLMOE_SHAPED if moe else {}))
+    kernel's residuals; ``latent`` the Moonlight-shaped tiny configuration
+    (latent attention, a dense first layer, sigmoid-routed experts with
+    shared experts)."""
+    shaped = MOONLIGHT_SHAPED if latent else OLMOE_SHAPED if moe else {}
+    config = T.TransformerConfig.tiny(remat=remat, **shaped)
     assert config.attention == "flash"
     optimizer = optax.adamw(1e-3)
     mesh = MeshSpec(dict(axes))
@@ -229,9 +243,43 @@ def test_moe_scopes_change_names_never_the_program():
     assert not any(MOE.search(n) for _op, n in plain)
 
 
+@pytest.mark.parametrize("remat", POLICIES)
+def test_latent_and_shared_matmuls_are_under_their_scopes(remat):
+    """``latent`` lies inside ``attention`` and holds ``W_kv_a`` and
+    ``W_kv_b`` (forward, and two gradients each) but not ``W_q`` / ``W_o``;
+    ``shared`` lies inside ``mlp`` beside the experts' scopes and holds the
+    shared experts' three matmuls; the dense first layer's MLP carries
+    neither. Both scans (the dense prefix, the expert layers) name their
+    work: no matmul without a block."""
+    named = instructions(remat, latent=True)
+    matmuls = [n for op, n in named if op in ("dot", "convolution")]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    for scope, block in (("latent", "attention"), ("shared", "mlp")):
+        mine = [n for n in matmuls if LATENT.search(n) and LATENT.search(n).group(1) == scope]
+        assert mine and {BLOCKS.search(n).group(1) for n in mine} == {block}, scope
+        assert [n for n in mine if "transpose(" not in n] and [n for n in mine if "transpose(" in n]
+        assert bool([n for n in mine if "rematted_computation" in n]) == (remat == "full")
+    attention = [n for n in matmuls if BLOCKS.search(n).group(1) == "attention"]
+    assert [n for n in attention if not LATENT.search(n)]        # W_q, W_o
+    mlp = [n for n in matmuls if BLOCKS.search(n).group(1) == "mlp"]
+    assert [n for n in mlp if not LATENT.search(n) and not MOE.search(n)]   # layer 0's dense MLP
+    assert [n for n in mlp if MOE.search(n) and MOE.search(n).group(1) == "experts"]
+    # no op carries both an expert scope and ``shared``
+    assert not [n for _op, n in named if LATENT.search(n) and MOE.search(n)]
+
+
+def test_latent_scopes_change_names_never_the_program():
+    scoped, plain = instructions(None, latent=True), instructions(None, scoped=False, latent=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+    assert not any(LATENT.search(n) for _op, n in plain)
+
+
 def test_vocabulary():
     # benchmarks/harness/scopes.py repeats it: a rename renames metrics.
     assert T.SCOPES == ("embed", "attention", "mlp", "head", "loss", "optimizer")
     # benchmarks/harness/moe_scopes.py repeats these; none is a block's name
     assert T.MOE_SCOPES == ("router", "dispatch", "experts")
     assert not set(T.MOE_SCOPES) & set(T.SCOPES)
+    # benchmarks/harness/latent_scopes.py repeats these
+    assert T.LATENT_SCOPES == ("latent", "shared")
+    assert not set(T.LATENT_SCOPES) & (set(T.SCOPES) | set(T.MOE_SCOPES))
