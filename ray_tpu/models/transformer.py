@@ -22,8 +22,9 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays; every leaf has a logical
     dim annotation in PARAM_LOGICAL_DIMS, so DP/FSDP/TP/EP sharding is one
     LogicalRules switchboard away — model code never mentions mesh axes.
-  * layers are scanned (lax.scan over stacked layer params): O(1) compile
-    time in depth, XLA-friendly control flow.
+  * layers are scanned (lax.scan over stacked layer params, _scan_layers):
+    O(1) compile time in depth, XLA-friendly control flow. The expert
+    kernels read a layer's weights in the stack itself, not a slice of it.
   * MoE blocks are dropless (no capacity, no token ever dropped): the
     (token, choice) pairs are sorted by expert and the three expert matmuls
     run as grouped matmuls over the ragged groups (ops/grouped_matmul.py), one
@@ -80,6 +81,9 @@ MOE_SCOPES = ("router", "dispatch", "experts")
 # key, its broadcast to the heads and the concatenation), and "shared",
 # inside "mlp" beside MOE_SCOPES (the shared experts' SwiGLU).
 LATENT_SCOPES = ("latent", "shared")
+# A mixture-of-experts layer's leaves that the grouped matmuls read:
+# [experts, k, n] each, [layers, experts, k, n] in the layer stack.
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -529,7 +533,14 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     [tokens, top_k] (the choices and their weights).
 
     One device's view: ``h`` and the experts are whole here. Under a mesh
-    ``_moe_over_mesh`` calls this once per data shard."""
+    ``_moe_over_mesh`` calls this once per data shard.
+
+    Where ``layer`` comes from ``_scan_layers`` it carries ``"stack"``, for
+    each expert leaf the scan's stack of it and this layer's number, and
+    the kernels read the weights there (``grouped_matmul``'s ``within``):
+    ``layer["w_gate"]`` and its like, slices of the stack, are then only
+    what the weight gradients are for. A ``layer`` without it (the
+    per-shard call, a single layer) is a stack of one."""
     moe = config.moe
     batch, seq, d = h.shape
     tokens = batch * seq
@@ -566,10 +577,13 @@ def _moe_mlp(h, layer, config: TransformerConfig):
         _, inverse = jax.lax.sort((order, pairs), num_keys=1)
         group_sizes = jnp.sum(counts, axis=0)
         rows = _rows_by_expert(moe.top_k, ht, order, inverse)    # [T*K, d]
+    in_stack = layer.get("stack", {})
+
+    def expert(rows, name):
+        return grouped_matmul(rows, layer[name], group_sizes, within=in_stack.get(name))
+
     with jax.named_scope("experts"):
-        gate = grouped_matmul(rows, layer["w_gate"], group_sizes)
-        up = grouped_matmul(rows, layer["w_up"], group_sizes)
-        out = grouped_matmul(_silu_mul(gate, up), layer["w_down"], group_sizes)
+        out = expert(_silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
     with jax.named_scope("dispatch"):
         per_token = _rows_by_token(out, order, inverse)
         out = _weighted_sum(per_token.reshape(tokens, moe.top_k, d), weights.astype(h.dtype))
@@ -588,7 +602,12 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     shard's work: right on every mesh, and as fast as data parallelism
     alone. The all_to_all exchange that would keep ``ep`` shards of the
     experts in place is not written yet. ``prob_sum`` and ``counts`` are
-    summed over the data shards, so the balancing loss sees every token."""
+    summed over the data shards, so the balancing loss sees every token.
+
+    One layer's experts enter, the scan's slice, not the scan's stack
+    (``layer["stack"]`` stays outside): replicating the stack would gather
+    every layer's experts at once. On one device the stack goes through
+    and the kernels read it in place."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return _moe_mlp(h, layer, config)
@@ -606,7 +625,7 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     per_token = jax.sharding.PartitionSpec(shards, None)
     experts = {
         name: layer[name]
-        for name in ("router", "router_bias", "w_gate", "w_up", "w_down") if name in layer
+        for name in ("router", "router_bias", *_EXPERT_WEIGHTS) if name in layer
     }
     return jax.shard_map(
         per_shard, mesh=mesh,
@@ -658,6 +677,34 @@ def _mlp_block(x, layer, config: TransformerConfig):
                     h, layer["shared_gate"], layer["shared_up"], layer["shared_down"]
                 ).astype(out.dtype)
         return x + out.astype(x.dtype), routing
+
+
+def _scan_layers(step, carry, layers, *xs):
+    """``jax.lax.scan`` of ``step(carry, layer, *xs)`` over the stacked
+    ``layers`` (and ``xs``, stacked alike).
+
+    A scan hands its body ``layers[i]`` as a slice of the stack. XLA fuses
+    that slice into its own matmuls, but a Mosaic call needs a buffer, so
+    every grouped matmul of a mixture-of-experts layer first copied its
+    ``[experts, k, n]`` weights out, forward and backward (805 MB a layer
+    and a pass at OLMoE's widths; PERF.md section 6, PR 31). Here the body
+    closes over the expert stacks, loop invariants under ``stop_gradient``,
+    and finds them with its own number under ``layer["stack"]``:
+    ``_moe_mlp`` has the kernels read layer ``i`` in place
+    (``grouped_matmul``'s ``within``). The slices stay what the weight
+    gradients are for, so gradients leave the backward scan a layer at a
+    time as before; their values are dead code."""
+    if "router" not in layers:
+        return jax.lax.scan(lambda carry, scanned: step(carry, *scanned), carry, (layers, *xs))
+    stacks = {name: jax.lax.stop_gradient(layers[name]) for name in _EXPERT_WEIGHTS}
+
+    def body(carry, scanned):
+        index, layer, *rest = scanned
+        in_stack = {name: (stack, index) for name, stack in stacks.items()}
+        return step(carry, {**layer, "stack": in_stack}, *rest)
+
+    index = jnp.arange(layers["router"].shape[0], dtype=jnp.int32)
+    return jax.lax.scan(body, carry, (index, layers, *xs))
 
 
 def _embed(params, tokens):
@@ -731,8 +778,8 @@ def _hidden_with_routing(params, tokens, config, positions=None):
         )
 
     if "dense_layers" in params:
-        x, _ = jax.lax.scan(layer_step, x, params["dense_layers"])
-    return jax.lax.scan(layer_step, x, params["layers"])
+        x, _ = _scan_layers(layer_step, x, params["dense_layers"])
+    return _scan_layers(layer_step, x, params["layers"])
 
 
 def logits_loss(
@@ -1041,7 +1088,7 @@ def stage_forward(
         # The MoE balancing loss is not carried across stages.
         return _mlp_block(h_in, layer, config)[0], None
 
-    x, _ = jax.lax.scan(layer_step, x, stage_params["layers"])
+    x, _ = _scan_layers(layer_step, x, stage_params["layers"])
     if last:
         x = _head(stage_params, x, config)
     return x
@@ -1083,9 +1130,8 @@ def decode_step(
     positions = jnp.full((batch, 1), length, jnp.int32)
     x = _embed(params, tokens)
 
-    def layer_step(carry, inputs):
+    def layer_step(carry, layer, k_cache, v_cache):
         x = carry
-        layer, k_cache, v_cache = inputs
         with jax.named_scope("attention"):
             h = rmsnorm_reference(x, layer["attn_norm"], eps=config.rms_norm_eps)
             q, k, v = _qkv(h, layer, config)
@@ -1110,8 +1156,8 @@ def decode_step(
         x, _ = _mlp_block(x, layer, config)
         return x, (k_cache, v_cache)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"])
+    x, (new_k, new_v) = _scan_layers(
+        layer_step, x, params["layers"], cache["k"], cache["v"]
     )
     logits = _head(params, x, config)[:, 0]
     new_cache = {"k": new_k, "v": new_v, "length": length + 1}

@@ -325,27 +325,38 @@ def test_full_remat_runs_the_forward_kernel_once(topo, axes, batch):
         assert custom_calls() == 4   # what the names are for
 
 
-def _mistral_7b_step(topo, batch, seq, remat):
-    """The compiled one-chip training step at the benchmark's Mistral-7B
-    widths (hidden 4096, 32 / 8 heads, MLP 14336, vocabulary 32768, depth
-    cut to 2, bfloat16, AdamW), built and compiled from shapes as the
-    benchmark's own rehearsal builds a cell's
-    (``benchmarks/harness/described.py``), with the Mosaic kernels."""
+def _one_chip_step(topo, config, batch, seq):
+    """The compiled one-chip training step of ``config`` (bfloat16, AdamW),
+    built and compiled from shapes as the benchmark's own rehearsal builds
+    a cell's (``benchmarks/harness/described.py``), with the Mosaic
+    kernels."""
     import types
 
+    import ray_tpu.ops.grouped_matmul as gm
     from benchmarks.harness import described
+    from ray_tpu.models import transformer as T
+
+    family = types.SimpleNamespace(
+        init=lambda key: T.init_params(config, key),
+        logical_dims=T.param_logical_dims(config),
+        loss=lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], config),
+    )
+    # described.compile_step steers the flash kernels off the interpreter;
+    # the grouped matmul asks the same platform rule from its own module.
+    with mock.patch.object(gm, "resolve_interpret", lambda _i: False):
+        return described.compile_step(family, topo.devices, {"dp": 1}, batch, seq)[1]
+
+
+def _mistral_7b_step(topo, batch, seq, remat):
+    """At the benchmark's Mistral-7B widths (hidden 4096, 32 / 8 heads, MLP
+    14336, vocabulary 32768, depth cut to 2)."""
     from ray_tpu.models import transformer as T
 
     config = T.TransformerConfig(
         vocab_size=32768, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
         hidden_dim=14336, max_seq=seq, rope_theta=1e6, attention="flash", remat=remat,
     )
-    family = types.SimpleNamespace(
-        init=lambda key: T.init_params(config, key),
-        logical_dims=T.param_logical_dims(config),
-        loss=lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], config),
-    )
-    return described.compile_step(family, topo.devices, {"dp": 1}, batch, seq)[1]
+    return _one_chip_step(topo, config, batch, seq)
 
 
 @pytest.mark.parametrize("batch,seq,remat,parent_gib", [
@@ -376,4 +387,39 @@ def test_head_loss_keeps_no_float32_logits_of_the_whole_batch(topo, batch, seq, 
     assert ("bf16", (tokens // 4096, 4096, vocab)) in of_vocab      # dlogits, by chunk
     from benchmarks.harness import described
 
+    assert described.step_memory(compiled)["total_bytes"] / 2**30 <= parent_gib
+
+
+def test_expert_kernels_read_the_layer_stack_in_place(topo):
+    """``olmoe-seq4k-ingest``'s step (hidden 2048, 16 heads with q/k norm,
+    64 experts of width 1024, 8 a token, vocabulary 50304, depth cut to 2;
+    2 x 4096 tokens, no remat): the six ``gmm`` calls, forward and input
+    gradient of gate / up / down, take the layer STACK seen as
+    ``[layers x experts, k, n]`` (a bitcast of the loop's invariant), so no
+    instruction of either scan's body, slice or copy, produces an
+    ``[experts, k, n]`` array: only the three ``tgmm`` calls, whose results
+    the weight gradients are, and the parameters of the fusions that stack
+    those. The parent had six ``dynamic-slice_bitcast_fusion`` copies of 268
+    MB in the loop bodies, each run once a layer. Twelve Mosaic calls as
+    the parent, and no more of the chip than the parent's step needed
+    (``parent_gib``: the cell's ``hbm_step_gib``, ledger, PR 30)."""
+    from benchmarks.harness import described
+    from ray_tpu.models import transformer as T
+
+    parent_gib = 14.118
+    layers, experts, dim, width = 2, 64, 2048, 1024
+    config = T.TransformerConfig(
+        vocab_size=50304, dim=dim, n_layers=layers, n_heads=16, n_kv_heads=16,
+        hidden_dim=width, max_seq=4096, rope_theta=1e4, rms_norm_eps=1e-5, qk_norm=True,
+        moe=T.MoEConfig(num_experts=experts, top_k=8, aux_loss_coef=0.01),
+        attention="flash",
+    )
+    compiled = _one_chip_step(topo, config, batch=2, seq=4096)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 12
+    in_place = rf"bf16\[{layers * experts},({dim},{width}|{width},{dim})\]"
+    gmm = re.findall(r"%gmm\S* = \S+ custom-call\(.*?operand_layout_constraints=(.*?)frontend_attributes", text)
+    assert len(gmm) == 6 and all(re.search(in_place, operands) for operands in gmm)
+    one_layer = rf"= bf16\[{experts},(?:{dim},{width}|{width},{dim})\]\S* ([\w-]+)\("
+    assert sorted(re.findall(one_layer, text)) == ["custom-call"] * 3 + ["parameter"] * 3
     assert described.step_memory(compiled)["total_bytes"] / 2**30 <= parent_gib

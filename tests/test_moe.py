@@ -258,3 +258,61 @@ def test_grouped_matmul_is_each_row_against_its_own_expert(sizes):
     want_grads = jax.grad(lambda a, b: jnp.sum(dense(a, b) * weigh), (0, 1))(lhs, rhs)
     close(got_grads[0], want_grads[0], "d lhs")
     close(got_grads[1], want_grads[1], "d rhs")   # an empty group's gradient is zero, not stale memory
+
+
+# A layer scan's stack of expert weights, and which layer reads it. The
+# routings: two of four experts get no row; one expert gets them all.
+IN_STACK = [(depth, layer) for depth in (1, 2, 3) for layer in range(depth)]
+ROUTINGS = {"empty_groups": [700, 0, 836, 0], "one_full_group": [0, 1536, 0, 0]}
+
+
+@pytest.mark.parametrize("sizes", list(ROUTINGS.values()), ids=list(ROUTINGS))
+@pytest.mark.parametrize("depth,layer", IN_STACK, ids=[f"layer{l}of{d}" for d, l in IN_STACK])
+def test_grouped_matmul_reads_a_layer_where_the_stack_holds_it(depth, layer, sizes):
+    """``grouped_matmul(..., within=(stack, layer))`` IS the per-layer call
+    on ``stack[layer]``: values, input gradient and weight gradient bit for
+    bit (the same tiles in the same grid steps), with the weights read from
+    the stack (``rhs`` is handed zeros: only its gradient is its own). And
+    through ``jax.grad`` of a scan over the stack, as ``_scan_layers``
+    builds it (the stack closed over under ``stop_gradient``, the layer's
+    number scanned beside its slice), a layer's weight gradient is its own
+    and a layer whose output the loss does not read gets exactly zero:
+    nothing is added up across the other layers' windows."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+
+    m, k, n = sum(sizes), 128, 256
+    keys = jax.random.split(jax.random.PRNGKey(7 * depth + layer), 3)
+    lhs = jax.random.normal(keys[0], (m, k), jnp.float32)
+    stack = jax.random.normal(keys[1], (depth, len(sizes), k, n), jnp.float32)
+    weigh = jax.random.normal(keys[2], (m, n), jnp.float32)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+
+    def alone(lhs, rhs):
+        out = grouped_matmul(lhs, rhs, group_sizes)
+        return jnp.sum(out * weigh), out
+
+    def in_stack(lhs, rhs, stack, layer):
+        out = grouped_matmul(lhs, rhs, group_sizes, within=(stack, layer))
+        return jnp.sum(out * weigh), out
+
+    grads = lambda f: jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))  # noqa: E731
+    (_, want), (want_dlhs, want_drhs) = grads(alone)(lhs, stack[layer])
+    (_, got), (got_dlhs, got_drhs) = grads(in_stack)(
+        lhs, jnp.zeros_like(stack[layer]), stack, jnp.int32(layer))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got_dlhs, want_dlhs)
+    np.testing.assert_array_equal(got_drhs, want_drhs)
+
+    def scanned(stack):
+        held = jax.lax.stop_gradient(stack)
+
+        def body(carry, scanned):
+            index, rhs = scanned
+            return carry, grouped_matmul(lhs, rhs, group_sizes, within=(held, index))
+
+        _, outs = jax.lax.scan(body, 0.0, (jnp.arange(depth, dtype=jnp.int32), stack))
+        return jnp.sum(outs[layer] * weigh)
+
+    dstack = np.asarray(jax.jit(jax.grad(scanned))(stack))
+    np.testing.assert_array_equal(dstack[layer], want_drhs)
+    assert not np.delete(dstack, layer, axis=0).any()
