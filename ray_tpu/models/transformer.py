@@ -1,5 +1,5 @@
-"""Flagship model: a pre-norm decoder-only transformer, TPU-first, in the
-three shapes today's open models take.
+"""Flagship model: a decoder-only transformer, TPU-first, in the four
+shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
 checkpoint policy, one head and loss):
@@ -17,6 +17,19 @@ checkpoint policy, one head and loss):
     beside the routed ones (DeepSeek-V3, Moonlight). ``first_dense_layers``
     puts dense layers before the expert layers (their own stacked tree,
     ``params["dense_layers"]``).
+  * the fourth shape, a hybrid (Olmo-Hybrid): ``layer_pattern`` names one
+    PERIOD of unlike layers, "linear" ones three to one with "full" ones.
+    A linear layer's mixer (``linear=``, ``_linear_mixer``) is Gated
+    DeltaNet's: q / k / v through a causal depthwise convolution and SiLU,
+    L2-normalised q and k, a per-head decay and write strength, the gated
+    delta rule over a ``[d_k, d_v]`` state a head (the chunked-scan kernels
+    of ops/gated_delta_rule.py), a gated RMSNorm a head, ``W_o``. The full
+    layers are the grouped-query block above with ``rope_theta=None`` (no
+    rotary embedding). ``norm_placement="post"`` is OLMo's reordered norm,
+    ``x + norm(branch(x))``. The parameters are stacked by period
+    (``params["layers"][kind]``: ``[periods, count in a period, ...]``) and
+    ONE scan walks the periods, its body a period's layers in order, each
+    under the one checkpoint policy.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays; every leaf has a logical
@@ -36,7 +49,11 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     would leave them in place is not written yet.
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
   * serving (init_kv_cache / decode_step) covers grouped-query attention
-    only: the latent cache is not written yet.
+    only: the latent cache and the recurrent-state cache of a patterned
+    model are not written yet, and both refuse by name. So do the pipeline
+    (partition_stages / stage_forward split ONE stacked tree) and a mesh
+    with tp or sp over a patterned model (the scan kernel runs per data
+    shard under shard_map, as flash does: dp / fsdp work).
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -56,6 +73,10 @@ import numpy as np
 
 from ray_tpu.ops.flash_attention import (
     RESIDUAL_NAMES, attention_reference, flash_attention,
+)
+from ray_tpu.ops.gated_delta_rule import (
+    RESIDUAL_NAMES as DELTA_RULE_RESIDUAL_NAMES,
+    gated_delta_rule, gated_delta_rule_reference, kept_bytes,
 )
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
@@ -81,6 +102,15 @@ MOE_SCOPES = ("router", "dispatch", "experts")
 # key, its broadcast to the heads and the concatenation), and "shared",
 # inside "mlp" beside MOE_SCOPES (the shared experts' SwiGLU).
 LATENT_SCOPES = ("latent", "shared")
+# What a linear-attention layer names: "linear_attention", inside
+# "attention" (the whole mixer: five projections and what follows), and
+# within it "short_conv" (the three causal depthwise convolutions and their
+# SiLU), "delta_rule" (q / k normalisation, the two gates, the chunk
+# preparation and the two scan kernels) and "gate_norm" (the per-head
+# RMSNorm and its SiLU gate).
+LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
+# The kinds of layer a ``layer_pattern`` may name.
+LAYER_KINDS = ("linear", "full")
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -129,6 +159,28 @@ class LatentAttentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearAttentionConfig:
+    """A gated-delta-rule linear-attention mixer as ``olmo_hybrid``'s
+    ``config.json`` states it (the ``linear_*`` keys)."""
+    num_key_heads: int = 30
+    num_value_heads: int = 30
+    key_head_dim: int = 96
+    value_head_dim: int = 192
+    conv_kernel: int = 4
+    # ``beta = 2 sigmoid(.)`` in (0, 2): the state's transition may have
+    # negative eigenvalues. False: ``beta`` in (0, 1).
+    allow_neg_eigval: bool = True
+
+    @property
+    def key_dim(self) -> int:
+        return self.num_key_heads * self.key_head_dim
+
+    @property
+    def value_dim(self) -> int:
+        return self.num_value_heads * self.value_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     dim: int = 4096
@@ -137,7 +189,8 @@ class TransformerConfig:
     n_kv_heads: int = 8
     hidden_dim: int = 11008
     max_seq: int = 4096
-    rope_theta: float = 10000.0
+    # None: no rotary embedding (Olmo-Hybrid's full layers).
+    rope_theta: float | None = 10000.0
     rms_norm_eps: float = 1e-6
     # RMSNorm with a learned weight over the WHOLE projected q and k
     # vectors, before the split into heads and before RoPE (OLMoE).
@@ -150,8 +203,18 @@ class TransformerConfig:
     # With ``moe``: this many leading layers keep the dense MLP of width
     # ``hidden_dim`` (``first_k_dense_replace``).
     first_dense_layers: int = 0
+    # One PERIOD of layer kinds (``LAYER_KINDS``), e.g. ("linear", "linear",
+    # "linear", "full"); ``n_layers`` is a multiple of it. None: every
+    # layer is the one kind the fields above describe.
+    layer_pattern: tuple[str, ...] | None = None
+    # The mixer of the pattern's "linear" layers.
+    linear: LinearAttentionConfig | None = None
+    # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
+    # ``x + norm(branch(x))``, the norm on the branch's OUTPUT.
+    norm_placement: str = "pre"
     # "flash" | "reference" | callable(q,k,v,causal)->o supplied by
-    # parallel/ (ring attention, ulysses).
+    # parallel/ (ring attention, ulysses). "reference" also selects the
+    # per-token recurrence for a linear layer's delta rule.
     attention: str = "flash"
     # Rematerialization policy for the layer scan: None (save everything),
     # "dots" (save matmul outputs only), "full" (a layer's activations are
@@ -160,12 +223,46 @@ class TransformerConfig:
     # both strings the flash kernel's own outputs are the exception
     # (``_remat_policy``): ``out`` and ``lse`` are kept, since recomputing
     # them costs a whole kernel and keeping them seq x hidden bytes a
-    # layer, what the scan's carry already costs.
+    # layer, what the scan's carry already costs. So is the output of a
+    # linear layer's scan kernel.
     remat: str | None = None
+
+    def __post_init__(self):
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.layer_pattern is None:
+            return
+        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        if unknown or not self.layer_pattern:
+            raise ValueError(f"layer_pattern {self.layer_pattern!r}: kinds are {LAYER_KINDS}")
+        if self.n_layers % len(self.layer_pattern):
+            raise ValueError(
+                f"n_layers={self.n_layers} is no multiple of the period {self.layer_pattern!r}"
+            )
+        if "linear" in self.layer_pattern:
+            la = self.linear
+            if la is None:
+                raise ValueError("a pattern with linear layers needs linear=")
+            if la.num_key_heads != la.num_value_heads:
+                raise NotImplementedError(
+                    "linear attention with fewer key heads than value heads (keys repeated "
+                    "to the value heads) is not written: num_key_heads "
+                    f"{la.num_key_heads} != num_value_heads {la.num_value_heads}"
+                )
+        if self.moe or self.latent or self.first_dense_layers:
+            raise NotImplementedError(
+                "a layer_pattern over mixture-of-experts, latent-attention or dense-prefix "
+                "layers is not written: the pattern's kinds are linear and full attention "
+                "with a dense MLP"
+            )
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def periods(self) -> int:
+        return self.n_layers // len(self.layer_pattern)
 
     @staticmethod
     def tiny(**overrides) -> "TransformerConfig":
@@ -215,13 +312,28 @@ def param_logical_dims(config: TransformerConfig) -> dict:
             **({"q_norm": ("layer", None), "k_norm": ("layer", None)} if config.qk_norm else {}),
         }
 
-    def stack(mlp):
+    def stack(mlp, attention=attention):
         return {"attn_norm": ("layer", None), **attention, "mlp_norm": ("layer", None), **mlp}
 
+    layers = stack(moe_mlp if config.moe else dense_mlp)
+    if config.layer_pattern:
+        # Stacked by period: [periods, count in a period, ...].
+        linear = {
+            **{name: ("layer", "embed", "heads") for name in ("wq", "wk", "wv", "wg")},
+            "wa": ("layer", "embed", None), "wb": ("layer", "embed", None),
+            **{name: ("layer", None, "heads") for name in ("conv_q", "conv_k", "conv_v")},
+            "a_log": ("layer", None), "dt_bias": ("layer", None), "o_norm": ("layer", None),
+            "wo": ("layer", "heads", "embed"),
+        }
+        by_kind = {"linear": stack(dense_mlp, linear), "full": layers}
+        layers = {
+            kind: {name: (dims[0], None, *dims[1:]) for name, dims in by_kind[kind].items()}
+            for kind in dict.fromkeys(config.layer_pattern)
+        }
     return {
         "embed": ("vocab", "embed"),
         **({"dense_layers": stack(dense_mlp)} if config.first_dense_layers else {}),
-        "layers": stack(moe_mlp if config.moe else dense_mlp),
+        "layers": layers,
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
     }
@@ -278,11 +390,11 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
             names[2]: dense(next(keys), *lead, width, d, scale=width ** -0.5),
         }
 
-    def attention(keys, nl):
+    def attention(keys, *lead):
         return {
-            **{name: jnp.ones((nl, width), dt) for name, width in _norm_shapes(config).items()},
+            **{name: jnp.ones((*lead, width), dt) for name, width in _norm_shapes(config).items()},
             **{
-                name: dense(next(keys), nl, *shape)
+                name: dense(next(keys), *lead, *shape)
                 for name, shape in _projection_shapes(config).items()
             },
         }
@@ -295,11 +407,14 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
         }
         if moe.scoring == "sigmoid":
             mlp["router_bias"] = jnp.zeros((nl, moe.num_experts), jnp.float32)
-    else:
+    elif not config.layer_pattern:
         mlp = swiglu(keys, nl, width=config.hidden_dim)
     params = {
         "embed": dense(next(keys), config.vocab_size, d, scale=0.02),
-        "layers": {**attention(keys, nl), **mlp},
+        "layers": (
+            _init_patterned_layers(config, next(keys), dense, swiglu, attention)
+            if config.layer_pattern else {**attention(keys, nl), **mlp}
+        ),
         "final_norm": jnp.ones((d,), dt),
         "lm_head": dense(next(keys), d, config.vocab_size, scale=d ** -0.5),
     }
@@ -314,6 +429,54 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
             **swiglu(prefix_keys, prefix, width=config.hidden_dim),
         }
     return params
+
+
+def _init_patterned_layers(config, key, dense, swiglu, attention) -> dict:
+    """``{kind: leaves of [periods, count in a period, ...]}`` for a
+    ``layer_pattern``; each kind draws from a split of its own.
+
+    A linear layer's own leaves: the convolution filters ``[kernel,
+    channels]`` uniform in +-kernel^-1/2 (a depthwise Conv1d's default),
+    ``a_log = log(A)`` with A uniform in (0, 16), ``dt_bias`` the inverse
+    softplus of a step log-uniform in (0.001, 0.1) (Gated DeltaNet's and
+    Mamba2's initialisation: a per-token decay between 0.2 and 0.9999),
+    the gated norm's weight ones; both gates' parameters in float32."""
+    la, d = config.linear, config.dim
+    periods = config.periods
+    out = {}
+    for number, kind in enumerate(dict.fromkeys(config.layer_pattern)):
+        lead = (periods, config.layer_pattern.count(kind))
+        keys = iter(jax.random.split(jax.random.fold_in(key, number), 16))
+        if kind == "full":
+            leaves = attention(keys, *lead)
+        else:
+            a = jax.random.uniform(next(keys), (*lead, la.num_value_heads), jnp.float32, 1e-3, 16.0)
+            dt = jnp.exp(jax.random.uniform(
+                next(keys), (*lead, la.num_value_heads), jnp.float32,
+                math.log(1e-3), math.log(1e-1),
+            ))
+            conv = lambda channels: jax.random.uniform(
+                next(keys), (*lead, la.conv_kernel, channels), jnp.float32,
+                -la.conv_kernel ** -0.5, la.conv_kernel ** -0.5,
+            ).astype(config.dtype)
+            leaves = {
+                "attn_norm": jnp.ones((*lead, d), config.dtype),
+                "mlp_norm": jnp.ones((*lead, d), config.dtype),
+                "wq": dense(next(keys), *lead, d, la.key_dim),
+                "wk": dense(next(keys), *lead, d, la.key_dim),
+                "wv": dense(next(keys), *lead, d, la.value_dim),
+                "wg": dense(next(keys), *lead, d, la.value_dim),
+                "wa": dense(next(keys), *lead, d, la.num_value_heads),
+                "wb": dense(next(keys), *lead, d, la.num_value_heads),
+                "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim),
+                "conv_v": conv(la.value_dim),
+                "a_log": jnp.log(a),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "o_norm": jnp.ones((*lead, la.value_head_dim), config.dtype),
+                "wo": dense(next(keys), *lead, la.value_dim, d),
+            }
+        out[kind] = {**leaves, **swiglu(keys, *lead, width=config.hidden_dim)}
+    return out
 
 
 def _flash_over_mesh(q, k, v, causal):
@@ -400,27 +563,130 @@ def _latent_qkv(h, layer, config: TransformerConfig, cos_sin, positions):
         return q, k, kv[..., nope:]
 
 
+def _short_conv(x, filters):
+    """``SiLU(conv(x))``: a causal depthwise convolution over time, one
+    filter ``filters[:, c]`` a channel, no bias; the LAST tap multiplies the
+    current token (a Conv1d padded on the left). ``x``: [batch, seq,
+    channels]; float32 math, the model dtype's residency."""
+    taps, seq = filters.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
+    filters = filters.astype(jnp.float32)
+    out = sum(padded[:, j:j + seq] * filters[j] for j in range(taps))
+    return jax.nn.silu(out).astype(x.dtype)
+
+
+def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
+    """The gated delta rule of a linear layer: the per-token recurrence
+    under ``attention="reference"``, else the chunked-scan kernels, per
+    data shard when traced under a device mesh (``_flash_over_mesh`` says
+    why). A head's scan needs the whole sequence and the kernels are not
+    written to run on a slice of the heads' parameters: tp and sp refuse."""
+    if config.attention == "reference":
+        return gated_delta_rule_reference
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return gated_delta_rule
+    for axis in ("tp", "sp"):
+        if dict(mesh.shape).get(axis, 1) > 1:
+            raise NotImplementedError(
+                f"a layer_pattern with linear layers over a mesh with {axis} > 1 is not "
+                "written: the scan kernel runs per data shard (dp / fsdp) with every head "
+                "and the whole sequence"
+            )
+    rows = LogicalRules().spec(("batch", None, None, None), mesh)
+    gates = jax.sharding.PartitionSpec(*rows[:3])
+    return jax.shard_map(
+        gated_delta_rule, mesh=mesh,
+        in_specs=(rows, rows, rows, gates, gates), out_specs=rows, check_vma=False,
+    )
+
+
+# The epsilon under the square root of q's and k's L2 norm.
+_L2_EPS = 1e-6
+
+
+def _linear_mixer(h, layer, config: TransformerConfig):
+    """A linear-attention layer's mixer on the branch input ``h`` [batch,
+    seq, hidden], before ``W_o``'s residual add (heads ``i``, ``d_k`` /
+    ``d_v`` the key / value head dims)::
+
+        q~, k~, v = SiLU(conv(h W_q)), SiLU(conv(h W_k)), SiLU(conv(h W_v))
+        q = q~ / |q~|_2 * d_k^-1/2,  k = k~ / |k~|_2            (per head)
+        beta = (2 if allow_neg_eigval else 1) sigmoid(h W_b)
+        log alpha = -exp(a_log) softplus(h W_a + dt_bias)       (float32)
+        o = gated_delta_rule(q, k, v, log alpha, beta)
+        y = RMSNorm_{d_v}(o; o_norm) * SiLU(h W_g)              (per head)
+        out = concat_i(y) W_o"""
+    la = config.linear
+    batch, seq, _ = h.shape
+    heads = la.num_value_heads
+    f32 = jnp.float32
+
+    def by_head(x, width):
+        return x.reshape(batch, seq, heads, width).transpose(0, 2, 1, 3)
+
+    with jax.named_scope("linear_attention"):
+        q, k, v = (h @ layer[name] for name in ("wq", "wk", "wv"))
+        with jax.named_scope("short_conv"):
+            q = _short_conv(q, layer["conv_q"])
+            k = _short_conv(k, layer["conv_k"])
+            v = _short_conv(v, layer["conv_v"])
+        with jax.named_scope("delta_rule"):
+            q, k = (by_head(x, la.key_head_dim).astype(f32) for x in (q, k))
+            unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+            q, k = unit(q) * la.key_head_dim ** -0.5, unit(k)
+            beta = jax.nn.sigmoid((h @ layer["wb"]).astype(f32))
+            if la.allow_neg_eigval:
+                beta = 2.0 * beta
+            step = jax.nn.softplus((h @ layer["wa"]).astype(f32) + layer["dt_bias"].astype(f32))
+            log_alpha = -jnp.exp(layer["a_log"].astype(f32)) * step
+            o = _delta_rule_over_mesh(config)(
+                q, k, by_head(v, la.value_head_dim),
+                log_alpha.transpose(0, 2, 1), beta.transpose(0, 2, 1),
+            )
+        with jax.named_scope("gate_norm"):
+            o = o.transpose(0, 2, 1, 3)                          # [batch, seq, heads, d_v]
+            gate = (h @ layer["wg"]).reshape(o.shape).astype(f32)
+            y = rmsnorm_reference(o, layer["o_norm"], eps=config.rms_norm_eps)
+            y = (y.astype(f32) * jax.nn.silu(gate)).astype(h.dtype)
+        return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
+
+
 def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
+    """``x + mixer(norm(x))``, or under ``norm_placement="post"`` ``x +
+    norm(mixer(x))``. The layer's own leaves say which mixer it is: one
+    with convolution filters is a linear-attention layer."""
+    post = config.norm_placement == "post"
     with jax.named_scope("attention"):
         batch, seq, d = x.shape
-        h = _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
-        if config.latent:
-            q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
+        h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
+        if "conv_q" in layer:
+            out = _linear_mixer(h, layer, config)
         else:
-            q, k, v = _qkv(h, layer, config)
-            cos, sin = cos_sin
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            rep = config.n_heads // config.n_kv_heads
-            k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
-        o = attention_fn(q, k, v, True)
-        o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
-        return x + (o @ layer["wo"]).astype(x.dtype)
+            if config.latent:
+                q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
+            else:
+                q, k, v = _qkv(h, layer, config)
+                if cos_sin is not None:
+                    cos, sin = cos_sin
+                    q = apply_rope(q, cos, sin, positions)
+                    k = apply_rope(k, cos, sin, positions)
+                rep = config.n_heads // config.n_kv_heads
+                k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
+            o = attention_fn(q, k, v, True)
+            o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
+            out = o @ layer["wo"]
+        if post:
+            out = _rmsnorm_ckpt(out.astype(x.dtype), layer["attn_norm"], config.rms_norm_eps)
+        return x + out.astype(x.dtype)
 
 
 def _rope_tables(config: TransformerConfig):
     """(cos, sin) over the dims RoPE turns: the whole head, or latent
-    attention's ``qk_rope_head_dim``."""
+    attention's ``qk_rope_head_dim``; None where ``rope_theta`` is None (no
+    rotary embedding)."""
+    if config.rope_theta is None:
+        return None
     rotated = config.latent.qk_rope_head_dim if config.latent else config.head_dim
     return rope_frequencies(rotated, config.max_seq, config.rope_theta)
 
@@ -663,11 +929,18 @@ def _mlp_block(x, layer, config: TransformerConfig):
     """``(x + mlp(norm(x)), routing)``; ``routing`` is None for a dense
     MLP. The layer's own leaves say which it is: a stack with a ``router``
     is a mixture of experts."""
+    post = config.norm_placement == "post"
     with jax.named_scope("mlp"):
-        h = _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
+        h = x if post else _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
         if "router" not in layer:
-            out = _dense_mlp(h, layer["w_gate"], layer["w_up"], layer["w_down"])
-            return x + out.astype(x.dtype), None
+            out = _dense_mlp(h, layer["w_gate"], layer["w_up"], layer["w_down"]).astype(x.dtype)
+            if post:
+                out = _rmsnorm_ckpt(out, layer["mlp_norm"], config.rms_norm_eps)
+            return x + out, None
+        if post:
+            raise NotImplementedError(
+                'norm_placement="post" over a mixture-of-experts layer is not written'
+            )
         out, routing = _moe_over_mesh(h, layer, config)
         if config.moe.shared_experts:
             # Outside the per-shard call: a plain SwiGLU that GSPMD shards
@@ -707,6 +980,24 @@ def _scan_layers(step, carry, layers, *xs):
     return jax.lax.scan(body, carry, (index, layers, *xs))
 
 
+def _scan_periods(step, carry, layers, pattern):
+    """``jax.lax.scan`` over the PERIODS of a patterned model: ``layers`` is
+    ``{kind: leaves of [periods, count in a period, ...]}`` and the body
+    runs ``step(carry, layer)`` for the period's layers in ``pattern``'s
+    order, each layer the next of its kind. ``step`` is the one (possibly
+    checkpointed) layer step of every other model, so a period keeps what
+    one layer keeps, once a layer."""
+    def body(carry, period):
+        taken = dict.fromkeys(period, 0)
+        for kind in pattern:
+            number = taken[kind]
+            taken[kind] += 1
+            carry, _ = step(carry, jax.tree.map(lambda leaf: leaf[number], period[kind]))
+        return carry, None
+
+    return jax.lax.scan(body, carry, layers)
+
+
 def _embed(params, tokens):
     with jax.named_scope("embed"):
         return params["embed"][tokens]
@@ -721,11 +1012,12 @@ def _head(params, x, config: TransformerConfig):
 
 def _remat_policy(remat: str) -> Callable:
     """What the layer scan's ``jax.checkpoint`` saves under ``remat``: the
-    flash kernel's named residuals in either case (a ``pallas_call`` is no
-    dot, so "dots" alone would run the forward kernel again too), and
-    under "dots" the matmul outputs besides."""
+    flash kernel's and the delta-rule scan kernel's named residuals in
+    either case (a ``pallas_call`` is no dot, so "dots" alone would run the
+    forward kernels again too), and under "dots" the matmul outputs
+    besides."""
     policies = jax.checkpoint_policies
-    flash = policies.save_only_these_names(*RESIDUAL_NAMES)
+    flash = policies.save_only_these_names(*RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES)
     if remat == "full":
         return flash
     if remat == "dots":
@@ -777,6 +1069,8 @@ def _hidden_with_routing(params, tokens, config, positions=None):
             layer_step, policy=_remat_policy(config.remat)
         )
 
+    if config.layer_pattern:
+        return _scan_periods(layer_step, x, params["layers"], config.layer_pattern)
     if "dense_layers" in params:
         x, _ = _scan_layers(layer_step, x, params["dense_layers"])
     return _scan_layers(layer_step, x, params["layers"])
@@ -983,15 +1277,44 @@ def config_num_params(config: TransformerConfig) -> int:
     else:
         mlp = dense_mlp
     prefix = config.first_dense_layers
+    layers = (config.n_layers - prefix) * (attn + mlp) + prefix * (attn + dense_mlp)
+    if config.layer_pattern and "linear" in config.layer_pattern:
+        la = config.linear
+        mixer = (
+            2 * d * la.key_dim + 3 * d * la.value_dim            # wq wk, wv wg wo
+            + 2 * d * la.num_value_heads                         # wa, wb
+            + la.conv_kernel * (2 * la.key_dim + la.value_dim)   # the three filters
+            + 2 * la.num_value_heads + la.value_head_dim         # a_log, dt_bias, o_norm
+            + 2 * d                                              # the two block norms
+        )
+        linear_layers = config.periods * config.layer_pattern.count("linear")
+        layers += linear_layers * (mixer + dense_mlp - attn - mlp)
     return (
-        (config.n_layers - prefix) * (attn + mlp)
-        + prefix * (attn + dense_mlp)
+        layers
         + 2 * config.vocab_size * d  # embed + lm_head
         + d  # final_norm
     )
 
 
+def linear_state_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
+    """Bytes the delta-rule scan kernels of one training step keep for the
+    backward (chunk-start states and outputs, every linear layer): 0 for a
+    model with no linear layer."""
+    if not config.layer_pattern or "linear" not in config.layer_pattern:
+        return 0
+    la = config.linear
+    layers = config.periods * config.layer_pattern.count("linear")
+    return layers * kept_bytes(
+        batch, la.num_value_heads, seq, la.value_head_dim, jnp.dtype(config.dtype).itemsize
+    )
+
+
 def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
+    if config.layer_pattern:
+        raise NotImplementedError(
+            f"{what} splits ONE stacked layer tree; a config with a layer_pattern stacks "
+            "its layers by period and kind: train it fused (loss_fn)"
+        )
     if config.first_dense_layers:
         raise NotImplementedError(
             f"{what} splits ONE stacked layer tree; a config with first_dense_layers "
@@ -1098,6 +1421,12 @@ def stage_forward(
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 def _refuse_latent_cache(config: TransformerConfig) -> None:
+    if config.layer_pattern:
+        raise NotImplementedError(
+            "decode with a layer_pattern needs a recurrent-state cache beside the KV cache "
+            "(a [d_k, d_v] state and the convolution's last inputs a linear layer and "
+            "head), which is not written yet"
+        )
     if config.latent:
         raise NotImplementedError(
             "decode with latent attention needs the latent KV cache (c and the shared "

@@ -162,6 +162,42 @@ def test_flash_two_head_dims_compile_for_v5e(one_chip):
     assert [g.shape[-1] for g in grads] == [192, 192, 128]
 
 
+def _delta_rule_shapes(one_chip, heads=30, seq=16384):
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return (
+        shape(1, heads, seq, 96), shape(1, heads, seq, 96),
+        shape(1, heads, seq, 192, dtype=jnp.bfloat16),
+        shape(1, heads, seq), shape(1, heads, seq),
+    )
+
+
+def test_delta_rule_kernels_compile_for_v5e(one_chip):
+    """The gated delta rule at the Olmo-Hybrid cell's size, ``[1, 30, 16384,
+    96 | 192]``: float32 q / k and gates, bfloat16 v, chunks of 64, eight
+    chunks (512 rows) a grid step, two heads a call. The forward is one
+    Mosaic call; a gradient runs it again for the chunk-start states, then
+    the backward kernel, whose eight float32 operands and six results of
+    512 rows fit the scoped VMEM."""
+    from ray_tpu.ops import gated_delta_rule as G
+
+    assert G._heads_per_call(30, 16384) == 2 and G._per_step(256, 64) == 8
+    rule = functools.partial(G.gated_delta_rule, interpret=False)
+    shapes = _delta_rule_shapes(one_chip)
+    assert jax.eval_shape(rule, *shapes).shape == (1, 30, 16384, 192)
+    assert _custom_calls(rule, *shapes) == 1
+
+    def grads(*args):
+        loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+    assert _custom_calls(grads, *shapes) == 3
+    assert [g.dtype for g in jax.eval_shape(grads, *shapes)] == [
+        jnp.float32, jnp.float32, jnp.bfloat16, jnp.float32, jnp.float32,
+    ]
+
+
 def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)

@@ -1,0 +1,134 @@
+"""The gated delta rule's chunked form (``ops/gated_delta_rule.py``: the
+chunk preparation in XLA and the scan over chunks, in plain jax.numpy and
+as the two Pallas kernels in interpret mode) against the per-token
+recurrence ``gated_delta_rule_reference``: the output and all five
+gradients, in float32 on the CPU.
+
+Tolerance 2e-4 of the largest value: both sides compute in float32; what
+is left is summation order (a chunk's 16 to 64 tokens at once against one
+at a time; the cases' keys share a common part, as SiLU leaves them). A
+wrong term (a decay taken to the wrong token, a missing
+beta) is off by 1e-1 or more.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import gated_delta_rule as G
+
+TOL = 2e-4
+DECAYS = {
+    # alpha per token: a mix of nearly 0 (the state is wiped), nearly 1
+    # (nothing is forgotten) and everything between
+    "mixed": lambda u: jnp.where(u < 0.1, 1e-4, jnp.where(u > 0.6, 0.9995, u)),
+    "near_one": lambda u: 1.0 - 1e-3 * u,
+    "near_zero": lambda u: 1e-6 + 1e-3 * u,
+}
+
+
+def inputs(seq, d_k, d_v, decay, batch=1, heads=2, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (batch, heads, seq, d_k))) * d_k ** -0.5
+    k = unit(jax.random.normal(keys[1], (batch, heads, seq, d_k)) + 0.5)
+    v = jax.random.normal(keys[2], (batch, heads, seq, d_v))
+    beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(keys[3], (batch, heads, seq)))  # (0, 2)
+    log_alpha = jnp.log(DECAYS[decay](jax.random.uniform(keys[4], (batch, heads, seq))))
+    return q, k, v, log_alpha, beta
+
+
+def close(got, want, what):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.all(np.isfinite(got)), what
+    # the floor: a gradient that is itself 1e-3 (log_alpha's where the decay
+    # wipes the state) is a float32 sum of terms of order 1
+    assert np.max(np.abs(got - want)) <= TOL * np.max(np.abs(want)) + 1e-6, what
+
+
+CASES = {
+    "padded_to_four_chunks_dk_gt_dv": (50, 16, 16, 8, "near_one"),
+    "three_chunks_dk_lt_dv": (96, 32, 16, 24, "near_zero"),
+    "default_chunk_of_48": (40, None, 8, 8, "mixed"),
+    "the_cells_chunk_of_64": (128, 64, 32, 16, "mixed"),
+}
+_WANTED = {}
+
+
+def output_and_gradients(fn, args, weights):
+    """``fn(*args)`` and the gradients of ``sum(out * weights)`` in all
+    five, as one compiled program."""
+    def both(args, weights):
+        out, vjp = jax.vjp(fn, *args)
+        return out, vjp(weights)
+
+    return jax.jit(both)(args, weights)
+
+
+def wanted(case):
+    """The recurrence's output and gradients, once a case."""
+    if case not in _WANTED:
+        seq, _chunk, d_k, d_v, decay = CASES[case]
+        args = inputs(seq, d_k, d_v, decay)
+        weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+        _WANTED[case] = (args, weights, *output_and_gradients(G.gated_delta_rule_reference, args, weights))
+    return _WANTED[case]
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["chunked", "kernels"])
+@pytest.mark.parametrize("case", list(CASES))
+def test_output_and_five_gradients_match_the_recurrence(case, kernels):
+    args, weights, want, want_grads = wanted(case)
+    assert float(jnp.min(args[4])) > 0.0 and float(jnp.max(args[4])) < 2.0
+    chunked = lambda *a: G.gated_delta_rule(*a, chunk=CASES[case][1], kernels=kernels)
+    got, got_grads = output_and_gradients(chunked, args, weights)
+    close(got, want, "output")
+    for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got_grads, want_grads):
+        close(g, w, f"d{name}")
+
+
+def test_the_recurrence_is_the_equations():
+    """Two tokens by hand, d_k = d_v = 1: S_1 = beta_1 v_1 k_1; S_2 = alpha_2
+    S_1 + beta_2 (v_2 - alpha_2 S_1 k_2) k_2; o_t = S_t q_t."""
+    q = jnp.array([[[[2.0], [3.0]]]]); k = jnp.array([[[[1.0], [0.5]]]])
+    v = jnp.array([[[[4.0], [1.0]]]]); beta = jnp.array([[[0.5, 1.5]]])
+    log_alpha = jnp.log(jnp.array([[[0.9, 0.5]]]))
+    s1 = 0.5 * 4.0 * 1.0
+    s2 = 0.5 * s1 + 1.5 * (1.0 - 0.5 * s1 * 0.5) * 0.5
+    want = np.array([s1 * 2.0, s2 * 3.0])
+    for fn in (G.gated_delta_rule_reference, G.gated_delta_rule):
+        np.testing.assert_allclose(np.asarray(fn(q, k, v, log_alpha, beta))[0, 0, :, 0], want, rtol=1e-5)
+
+
+@pytest.mark.parametrize("size,strength", [(16, 0.9), (64, 0.5), (64, 1.0), (48, 2.0), (64, 2.0)])
+def test_the_unit_lower_inverse_holds_where_a_chunks_keys_are_alike(size, strength):
+    """``A = strength x`` (all ones below the diagonal: a chunk of identical
+    keys) plus noise. A Neumann product over the whole matrix is off by 8e2
+    of the largest entry at strength 0.5 and by 2e19 at 2; the doubling is
+    exact but for rounding."""
+    noise = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (3, size, size))
+    a = jnp.tril(strength + noise, -1)
+    got = G._unit_lower_inverse(a)
+    want = np.linalg.inv(np.eye(size) + np.asarray(a, np.float64))
+    assert np.max(np.abs(np.asarray(got) - want)) <= 1e-5 * np.max(np.abs(want))
+
+
+def test_heads_are_walked_in_groups_and_nothing_changes(monkeypatch):
+    args = inputs(32, 8, 8, "mixed", batch=2, heads=3)
+    loss = lambda *a: jnp.sum(G.gated_delta_rule(*a, chunk=16) ** 2)
+    both = lambda: jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+    whole = both()
+    monkeypatch.setattr(G, "_TOKENS_PER_CALL", 2 * 32)      # two (batch, head) rows a call
+    assert G._heads_per_call(6, 32) == 2 and G._heads_per_call(6, 33) == 1
+    grouped = both()
+    for got, want in zip(jax.tree.leaves(grouped), jax.tree.leaves(whole)):
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_what_is_kept_for_the_backward_is_the_output():
+    # 30 heads x 16384 tokens x d_v 192 in bfloat16; the chunk-start states
+    # (566 MB a layer in float32) are made again, not kept
+    assert G.kept_bytes(1, 30, 16384, 192, 2) == 30 * 16384 * 192 * 2
+    assert G.kept_bytes(1, 2, 50, 8, 4, chunk=16) == 2 * 64 * 8 * 4
+    assert G.RESIDUAL_NAMES == ("delta_rule_out",)
