@@ -25,22 +25,35 @@ alpha_t S_{t-1}^T k_t)`` the value each token really writes::
     O  = (e^G Q) S + (Q K^T . e^{G_t - G_i})_{i <= t} U
     S' = e^{G_C} S + (e^{G_C - G} K)^T U
 
-Two halves, by what each is good at:
+Two halves, four Mosaic kernels, and ``_prepare`` beside them as what the
+first two are held to:
 
-* the chunk preparation, XLA, float32, differentiated by jax itself
-  (``_prepare``): ``T = (I + A)^-1`` and from it ``W = T (beta e^G K)``,
-  ``U0 = T (beta V)``, and ``Qg``, ``P``, ``Kd``, ``gamma`` of the other
-  two lines. Every product is a matmul batched over chunks; every decay is
-  ``exp`` of a difference that is <= 0, so a decay near 0 underflows to 0
-  and never divides. ``T`` is built by doubling (``_unit_lower_inverse``),
-  exactly, block pairs at a time: nothing in it cancels.
-* the scan over chunks, two Mosaic kernels (``_delta_rule_forward``,
-  ``_delta_rule_backward``): ``U = U0 - W S``, ``O = Qg S + P U``, ``S' =
-  gamma S + Kd^T U`` with the ``[d_k, d_v]`` state carried in float32 in
-  VMEM from one grid step to the next, and the same walk backwards for the
-  six operands' gradients with ``dS`` carried (``_scan_reference`` is the
-  same scan in plain jax.numpy). Within a chunk everything
-  is a matmul for the MXU.
+* the chunk preparation (``_delta_prepare_forward``): ``T = (I + A)^-1``
+  and from it ``W = T (beta e^G K)``, ``U0 = T (beta V)``, and ``Qg``,
+  ``P``, ``Kd``, ``gamma`` of the other two lines, float32 throughout.
+  ``gap``, the two decay masks, ``K K^T``, ``Q K^T``, ``A``, every level of
+  the inverse and ``T`` are ``[rows, rows]`` values that live and die in
+  VMEM; every decay is ``exp`` of a difference that is <= 0, masked BEFORE
+  the ``exp``, so a decay near 0 underflows to 0 and never divides. ``T`` is
+  built by doubling (``_Masks.inverses``, ``_unit_lower_inverse``'s own
+  steps), exactly, block pairs at a time: nothing in it cancels. Its
+  transpose is written by hand (``_delta_prepare_backward``): jax never
+  sees the chain. ``_prepare`` is the same mathematics in XLA,
+  differentiated by jax: the oracle of both (equal to the last bit forward
+  on the chip, 6e-7 of the largest gradient backward: my chip runs, PR 33)
+  and the ``kernels=False`` path. In XLA every ``[.., 64, 64]`` intermediate
+  went out to HBM and back between fusions and jax's transpose ran the
+  chain a second time: 50.1 ms a layer of the Olmo-Hybrid cell's ``[30,
+  16384, 96 | 192]`` beside 19.9 of scan kernels; the two kernels take 17.4
+  (two forward calls) + 7.4 (device trace of the rule alone, my chip runs,
+  PR 33), bound by the MXU's float32 rate: a ``[128, 128]`` product at
+  fp32 contract precision is six bfloat16 passes, 0.128 us.
+* the scan over chunks (``_delta_rule_forward``, ``_delta_rule_backward``):
+  ``U = U0 - W S``, ``O = Qg S + P U``, ``S' = gamma S + Kd^T U`` with the
+  ``[d_k, d_v]`` state carried in float32 in VMEM from one grid step to the
+  next, and the same walk backwards for the six operands' gradients with
+  ``dS`` carried (``_scan_reference`` is the same scan in plain jax.numpy).
+  Within a chunk everything is a matmul for the MXU.
 
 What the backward needs of the forward: the state at every chunk's START
 (``[chunks, d_k, d_v]`` float32 a head: 566 MB a layer at 30 heads x 16384
@@ -50,8 +63,7 @@ Olmo-Hybrid cell 2.75 GiB of a v5e's 15.75 and the step then needs 17.8
 kernel once more, for the states alone (``_chunked_bwd``). The output
 carries ``RESIDUAL_NAMES`` (checkpoint_name): a layer checkpoint whose
 policy saves that name keeps ``O`` for the layers after and runs no kernel
-for it again; the preparation is XLA work and is recomputed like the
-projections before it. ``kept_bytes`` counts what is kept.
+for it again. ``kept_bytes`` counts what is kept.
 
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
@@ -81,15 +93,20 @@ _CHUNK_MULTIPLE = 16
 _CHUNKS_PER_STEP = (8, 4, 2, 1)
 # ... as far as a step's rows fit the 16 MiB of scoped VMEM: the backward
 # kernel holds eight float32 operands and six results of them, twice (1024
-# rows in chunks of 128 asked for 17.5 MiB: compile for a described v5e, PR 32).
+# rows in chunks of 128 asked for 17.5 MiB: compile for a described v5e, PR
+# 32). The two preparation kernels take the same steps: 1024 rows are
+# refused for both (the forward with its seven results; the chip refused
+# the backward too, PR 33), and 512, 256 and 128 rows a step cost the same
+# to 2 % (my chip runs, PR 33).
 _ROWS_PER_STEP = 512
 # The state the kernels carry from chunk to chunk (and the forward hands
 # the backward); the preparation computes in float32 too.
 _STATE_DTYPE = jnp.float32
-# (batch x head) rows x tokens one preparation takes at once.
+# (batch x head) rows x tokens one group of kernel calls takes at once
+# (``_heads_per_call`` has the readings it was chosen from).
 _TOKENS_PER_CALL = 2 * 16384
 _HIGHEST = jax.lax.Precision.HIGHEST
-# The chunk preparation's matmuls (XLA, float32 operands).
+# The oracle's matmuls (``_prepare``: XLA, float32 operands).
 _PREPARE_PRECISION = _HIGHEST
 
 
@@ -131,7 +148,8 @@ def kept_bytes(batch: int, heads: int, seq: int, d_v: int, itemsize: int,
 
 
 # ---------------------------------------------------------------------------
-# The chunk preparation: XLA, differentiated by jax.
+# The chunk preparation in XLA, differentiated by jax: the oracle of the two
+# preparation kernels, and the ``kernels=False`` path.
 # ---------------------------------------------------------------------------
 def _matmul(a, b):
     return jnp.matmul(a, b, precision=_PREPARE_PRECISION)
@@ -154,7 +172,12 @@ def _unit_lower_inverse(a):
 
     Its transpose is the inverse's own, ``da = -T^T dT T^T``: two matmuls,
     where jax's transpose of the ten above is twenty and their operands'
-    copies (105 of 1250 ms a step in the Olmo-Hybrid cell, my chip run, PR 32)."""
+    copies (105 of 1250 ms a step in the Olmo-Hybrid cell, my chip run, PR 32).
+
+    Since PR 33 this is the ORACLE's inverse (``_prepare``, ``kernels=False``
+    and the tests): the timed path runs the same steps on VMEM values
+    (``_Masks.inverses``) and the same identity in
+    ``_prepare_backward_kernel``."""
     size = a.shape[-1]
     at = jnp.arange(size)
     joined = lambda block: (at[:, None] // block) == (at[None, :] // block)
@@ -397,49 +420,350 @@ def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, *, chunk, interp
     )(w, u0, qg, p, kd, gamma, states, dout)
 
 
-def _prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret):
-    operands = _prepare(q, k, v, log_alpha, beta, chunk)
+# ---------------------------------------------------------------------------
+# The chunk preparation as Mosaic work: forward and its transpose by hand.
+# ---------------------------------------------------------------------------
+def _rows_sum(x):
+    return jnp.sum(x, axis=1, keepdims=True)
+
+
+def _columns_sum(x):
+    return jnp.sum(x, axis=0, keepdims=True)
+
+
+# Rows one product of the preparation kernels takes: the MXU's tile. A
+# 64-row float32 product costs the MXU what a 128-row one does (six bf16
+# passes, each bound by loading a 128 x 128 tile of the right-hand operand,
+# not by the rows streamed past it), so two chunks of 64 are multiplied as
+# ONE block-diagonal matrix: the forward kernel took 24.7 ms a layer and
+# call at [30, 16384] one chunk a product, 14.2 two (my chip runs, PR 33).
+_PRODUCT_ROWS = 128
+
+
+def _together(chunk: int, per_step: int) -> int:
+    """Chunks one product holds: as many as ``_PRODUCT_ROWS`` has room for
+    and a grid step has whole groups of."""
+    room = max(_PRODUCT_ROWS // chunk, 1)
+    return max(n for n in range(1, room + 1) if per_step % n == 0)
+
+
+def _product_rows(seq: int, chunk: int) -> int:
+    return chunk * _together(chunk, _per_step(seq // chunk, chunk))
+
+
+class _Masks:
+    """Where the chunks of one product lie in its ``[rows, rows]`` matrices
+    (the same for every product of a grid step): ``together`` chunks down
+    the diagonal, nothing between two of them."""
+
+    def __init__(self, chunk, together):
+        rows = chunk * together
+        self.chunk = chunk
+
+        def inside(shape, dim):
+            """Each position's chunk and its place inside that chunk."""
+            at = jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+            of = jax.lax.div(at, jnp.int32(chunk))
+            return of, at - chunk * of
+
+        t_of, self.t = inside((rows, rows), 0)
+        i_of, self.i = inside((rows, rows), 1)
+        self.lane_of, lane = inside((1, rows), 1)
+        self.same = t_of == i_of
+        self.eye = self.same & (self.t == self.i)
+        self.strictly = self.same & (self.t > self.i)
+        self.upto = self.same & (self.t >= self.i)
+        self.ends = lane == chunk - 1                             # [1, rows]
+
+    def down(self, row):
+        """``[1, rows]`` -> ``[rows, 1]``: broadcast, masked to the
+        diagonal, summed along the lanes: exact, no matmul, no 128-fold
+        padded operand."""
+        return _rows_sum(jnp.where(self.eye, row, 0.0))
+
+    def across(self, column):
+        return _columns_sum(jnp.where(self.eye, column, 0.0))
+
+    def of_chunk(self, c, row):
+        """The ``[1, 1]`` entry of a ``[1, rows]`` row at chunk ``c``'s end."""
+        return _rows_sum(jnp.where(self.ends & (self.lane_of == c), row, 0.0))
+
+    def joined(self, shift):
+        """Entries inside one diagonal block of ``2 ** shift``."""
+        return self.same & ((self.t >> shift) == (self.i >> shift))
+
+    def inverses(self, several):
+        """``_unit_lower_inverse``'s doubling on VMEM values: block pairs,
+        ``T_2b = T_b - T_b a_b T_b``, two products a level, for SEVERAL
+        independent matrices level by level. A level's second product waits
+        for its first and the next level for both; issued one matrix after
+        the other the MXU waits with them (14.2 ms a layer and call at [30,
+        16384], where the backward's fifteen independent products take 7.4:
+        my chip runs, PR 33), side by side its pipeline stays full."""
+        inner, shift = self.joined(1), 1
+        inverses = [jnp.where(self.eye, 1.0, 0.0) - jnp.where(inner, a, 0.0) for a in several]
+        while (1 << shift) < self.chunk:
+            shift += 1
+            outer = self.joined(shift)
+            right = [
+                _dot(jnp.where(outer & ~inner, a, 0.0), inverse, _NN)
+                for a, inverse in zip(several, inverses)
+            ]
+            inverses = [inverse - _dot(inverse, r, _NN) for inverse, r in zip(inverses, right)]
+            inner = outer
+        return inverses
+
+
+class _Span:
+    """What both preparation kernels make of the gates of the chunks of one
+    product, in VMEM: ``total`` / ``beta`` down the rows ``[rows, 1]`` from
+    their lane-dense ``[1, rows]`` form and the decays that need only the
+    gates. Every decay is ``exp`` of a difference masked to <= 0 BEFORE the
+    ``exp``, as in ``_prepare``; between two chunks it is 0."""
+
+    def __init__(self, masks, total_row, beta_row):
+        self.total_row = total_row
+        self.total, self.beta = masks.down(total_row), masks.down(beta_row)
+        gap = self.total - total_row                              # G_t - G_i
+        decay = lambda mask: jnp.exp(jnp.where(mask, gap, -jnp.inf))
+        self.strictly, self.upto = decay(masks.strictly), decay(masks.upto)
+        self.grown = jnp.exp(self.total)                          # e^{G_t}
+        # G_C of each row's own chunk, down the rows
+        last = _rows_sum(jnp.where(masks.same & masks.ends, total_row, 0.0))
+        self.left = jnp.exp(last - self.total)                    # e^{G_C - G_t}
+
+
+def _prepare_forward_kernel(q_ref, k_ref, v_ref, gates_ref,
+                            w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, *inverse_ref,
+                            chunk, per_step, together):
+    """``_prepare`` for ``per_step`` chunks of one head, ``together`` to a
+    product; ``gates_ref``: ``[1, per_step / together, 2, together x
+    chunk]``, the running sum ``G`` and ``beta`` along the lanes. With a
+    seventh result, ``T`` itself as the block-diagonal matrices it was
+    computed as (the backward's)."""
+    f32 = jnp.float32
+    width = together * chunk
+    masks = _Masks(chunk, together)
+    products = range(per_step // together)
+    rows = [slice(s * width, (s + 1) * width) for s in products]
+    spans = [_Span(masks, gates_ref[0, s, 0:1, :], gates_ref[0, s, 1:2, :]) for s in products]
+    keys = [k_ref[0, rows[s], :].astype(f32) for s in products]
+    inverses = masks.inverses(
+        [at.beta * at.strictly * _dot(k, k, _NT) for at, k in zip(spans, keys)]
+    )
+    for s, at, k, inverse in zip(products, spans, keys, inverses):
+        q, v = q_ref[0, rows[s], :].astype(f32), v_ref[0, rows[s], :].astype(f32)
+        w_ref[0, rows[s], :] = _dot(inverse, at.beta * at.grown * k, _NN)
+        u0_ref[0, rows[s], :] = _dot(inverse, at.beta * v, _NN)
+        qg_ref[0, rows[s], :] = at.grown * q
+        kd_ref[0, rows[s], :] = at.left * k
+        p = at.upto * _dot(q, k, _NT)
+        for c in range(together):
+            own = slice(c * chunk, (c + 1) * chunk)
+            p_ref[0, s * width + c * chunk:s * width + (c + 1) * chunk, :] = p[own, own]
+            gamma_ref[0, s * together + c] = jnp.exp(masks.of_chunk(c, at.total_row))
+        if inverse_ref:
+            inverse_ref[0][0, rows[s], :] = inverse
+
+
+def _prepare_backward_kernel(q_ref, k_ref, v_ref, gates_ref, inverse_ref,
+                             dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref, dgamma_ref,
+                             dq_ref, dk_ref, dv_ref, dgates_ref, *, chunk, per_step, together):
+    """The transpose of ``_prepare_forward_kernel`` by hand, ``together``
+    chunks at a time: ``dT = dW (beta e^G K)^T + dU0 (beta V)^T``, ``dA =
+    -T^T dT T^T`` (``_unit_lower_inverse_bwd``'s identity; ``T`` is read,
+    the forward call of the same backward wrote it), then the product rules
+    of ``A = beta . decay . K K^T`` and ``P = decay . Q K^T``: the gap ``G_t
+    - G_i`` gets ``dA . A + dP . P``, whose row sums less column sums are
+    ``dG``. ``dgates_ref``: ``dG`` and ``dbeta`` along the lanes."""
+    f32 = jnp.float32
+    width = together * chunk
+    masks = _Masks(chunk, together)
+    for s in range(per_step // together):
+        rows = slice(s * width, (s + 1) * width)
+        q, k, v, inverse, dw, du0, dqg, dp, dkd = (
+            ref[0, rows, :].astype(f32) for ref in
+            (q_ref, k_ref, v_ref, inverse_ref, dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref)
+        )
+        at = _Span(masks, gates_ref[0, s, 0:1, :], gates_ref[0, s, 1:2, :])
+        kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)
+        dinverse = _dot(dw, at.beta * at.grown * k, _NT) + _dot(du0, at.beta * v, _NT)
+        dkb, dvb = _dot(inverse, dw, _TN), _dot(inverse, du0, _TN)
+        da = -_dot(_dot(inverse, dinverse, _TN), inverse, _NT)
+        weighed = da * at.strictly * kk                          # dA . A / beta
+        dkk = da * at.beta * at.strictly
+        # dP of every chunk beside its own columns; the decay is 0 elsewhere
+        dqk = jnp.tile(dp, (1, together)) * at.upto
+        dgap = at.beta * weighed + dqk * qk                      # dA . A + dP . P
+        dscale = _rows_sum(dkb * k)                              # of beta e^G
+        dleft = at.left * _rows_sum(dkd * k)                     # of G_C - G_t
+        dq = at.grown * dqg + _dot(dqk, k, _NN)
+        dk = (at.beta * at.grown * dkb + at.left * dkd + _dot(dkk, k, _NN)
+              + _dot(dkk, k, _TN) + _dot(dqk, q, _TN))
+        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = (at.beta * dvb).astype(dv_ref.dtype)
+        dbeta = _rows_sum(weighed) + at.grown * dscale + _rows_sum(dvb * v)
+        dtotal = _rows_sum(dgap) + at.grown * (at.beta * dscale + _rows_sum(dqg * q)) - dleft
+        # G_C is its chunk's last G: what reached it through ``left`` and
+        # through gamma = e^{G_C} lands on that token
+        dgamma = sum(
+            jnp.where(masks.lane_of == c, dgamma_ref[0, s * together + c], 0.0)
+            for c in range(together)
+        )
+        dlast = _columns_sum(jnp.where(masks.same, dleft, 0.0)) + jnp.exp(at.total_row) * dgamma
+        dgates_ref[0, s, 0:1, :] = (
+            masks.across(dtotal) - _columns_sum(dgap) + jnp.where(masks.ends, dlast, 0.0)
+        )
+        dgates_ref[0, s, 1:2, :] = masks.across(dbeta)
+
+
+def _parallel():
+    """Both grid axes of a preparation call are independent: no state is
+    carried from one step to the next."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
+
+
+def _prepare_layout(q, v, gates, chunk):
+    """(grid, chunks a step, chunks a product, the specs of q, k, v and the
+    gates) of both preparation calls."""
+    bh, seq, d_k = q.shape
+    chunks = seq // chunk
+    per_step = _per_step(chunks, chunk)
+    width = gates.shape[-1]
+    together = width // chunk
+    in_specs = [
+        *_specs(chunk, per_step, (d_k, d_k, v.shape[-1]), lambda n: n),
+        pl.BlockSpec((1, per_step // together, 2, width), lambda i, n: (i, n, 0, 0)),
+    ]
+    return (bh, chunks // per_step), per_step, together, in_specs
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse"))
+def _delta_prepare_forward(q, k, v, gates, *, chunk, interpret, inverse=False):
+    """``_prepare``'s six operands from q, k ``[heads, seq, d_k]``, v
+    ``[heads, seq, d_v]`` and ``gates`` (``_gates``); with ``inverse`` a
+    seventh, ``T`` ``[heads, seq, chunks a product x chunk]``."""
+    bh, seq, d_k = q.shape
+    grid, per_step, together, in_specs = _prepare_layout(q, v, gates, chunk)
+    widths = (d_k, v.shape[-1], d_k, chunk, d_k) + ((gates.shape[-1],) if inverse else ())
+    specs = _specs(chunk, per_step, widths, lambda n: n)
+    shapes = [jax.ShapeDtypeStruct((bh, seq, width), jnp.float32) for width in widths]
+    return pl.pallas_call(
+        functools.partial(
+            _prepare_forward_kernel, chunk=chunk, per_step=per_step, together=together
+        ),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[
+            *specs[:5], pl.BlockSpec((1, per_step, 1, 1), lambda i, n: (i, n, 0, 0)), *specs[5:],
+        ],
+        out_shape=[
+            *shapes[:5], jax.ShapeDtypeStruct((bh, seq // chunk, 1, 1), jnp.float32), *shapes[5:],
+        ],
+        interpret=interpret,
+        compiler_params=_parallel(),
+    )(q, k, v, gates)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _delta_prepare_backward(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma,
+                            *, chunk, interpret):
+    """``dq``, ``dk``, ``dv`` in their operands' dtypes and the gates'
+    gradients in ``gates``' layout (of the running sum ``G``, not yet of
+    ``log_alpha``) from the scan backward's six."""
+    d_k, d_v = q.shape[-1], v.shape[-1]
+    grid, per_step, together, in_specs = _prepare_layout(q, v, gates, chunk)
+    return pl.pallas_call(
+        functools.partial(
+            _prepare_backward_kernel, chunk=chunk, per_step=per_step, together=together
+        ),
+        grid=grid,
+        in_specs=[
+            *in_specs,
+            *_specs(chunk, per_step, (gates.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
+            pl.BlockSpec((1, per_step, 1, 1), lambda i, n: (i, n, 0, 0)),
+        ],
+        out_specs=in_specs,
+        out_shape=[
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)),
+            jax.ShapeDtypeStruct(gates.shape, jnp.float32),
+        ],
+        interpret=interpret,
+        compiler_params=_parallel(),
+    )(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma)
+
+
+def _gates(log_alpha, beta, chunk: int):
+    """``[heads, seq / width, 2, width]`` float32: the running sum of
+    ``log_alpha`` inside each chunk (``_prepare``'s ``total``) and ``beta``,
+    the tokens of the ``width / chunk`` chunks of one product
+    (``_product_rows``) along the lanes: 8 bytes a head and token, where a
+    ``[.., seq, 1]`` operand pads 128-fold."""
+    bh, seq = log_alpha.shape
+    width = _product_rows(seq, chunk)
+    total = jnp.cumsum(log_alpha.astype(jnp.float32).reshape(bh, -1, chunk), axis=-1)
+    by_product = lambda x: x.reshape(bh, -1, width)
+    return jnp.stack([by_product(total), by_product(beta.astype(jnp.float32))], axis=2)
+
+
+def _prepare_and_scan(q, k, v, gates, chunk, interpret):
+    operands = _delta_prepare_forward(q, k, v, gates, chunk=chunk, interpret=interpret)
     return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
 
 
-# Preparation and forward kernel of ``[heads, seq, .]`` operands; the
-# backward below is the whole of what a gradient runs.
-_chunked = jax.custom_vjp(_prepare_and_scan, nondiff_argnums=(5, 6))
+# Preparation and forward kernel of ``[heads, seq, .]`` operands and their
+# gates (``_gates``, which jax differentiates itself); the backward below is
+# the whole of what a gradient runs.
+_chunked = jax.custom_vjp(_prepare_and_scan, nondiff_argnums=(4, 5))
 
 
-def _chunked_fwd(q, k, v, log_alpha, beta, chunk, interpret):
-    out = _prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret)
-    return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, log_alpha, beta)
+def _chunked_fwd(q, k, v, gates, chunk, interpret):
+    out = _prepare_and_scan(q, k, v, gates, chunk, interpret)
+    return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, gates)
 
 
 def _chunked_bwd(chunk, interpret, inputs, dout):
-    """Nothing of the forward is kept but its inputs: the preparation runs
-    again (XLA work, as a layer checkpoint would run it), the forward
-    kernel once more for the chunk-start states, then the backward kernel
-    and jax's own transpose of the preparation."""
-    operands, prepare_vjp = jax.vjp(
-        lambda *inputs: _prepare(*inputs, chunk), *inputs
+    """Nothing of the forward is kept but its inputs: the preparation
+    kernel runs again (and hands over ``T``), the forward kernel once more
+    for the chunk-start states, then the backward kernel and the
+    preparation's own."""
+    *operands, inverse = _delta_prepare_forward(
+        *inputs, chunk=chunk, interpret=interpret, inverse=True
     )
     states = _delta_rule_forward(
         *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
     )
     grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
-    return prepare_vjp(tuple(grads))
+    return tuple(
+        _delta_prepare_backward(*inputs, inverse, *grads, chunk=chunk, interpret=interpret)
+    )
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 def _heads_per_call(heads: int, seq: int) -> int:
-    """How many (batch x head) rows one preparation and one pair of kernel
-    calls take: the most that divide ``heads`` with ``rows x seq`` under
-    ``_TOKENS_PER_CALL``. The preparation's float32 operands, their
-    gradients and what jax keeps of ``_prepare`` for its backward come to
-    about 17 KB a (head, token): 8 GiB at 30 heads x 16384 tokens at once
-    (compile for a described v5e, PR 32). Fewer at a time is also FASTER,
-    down to two: the Olmo-Hybrid cell reads 13,163 tokens/s ten heads a
-    call (13.82 GiB), 13,440 six (12.95), 13,854 three (12.15), 13,979 two
-    (11.82), 13,863 one (12.09) (my chip runs, PR 32, one seed)."""
+    """How many (batch x head) rows one group of kernel calls takes: the
+    most that divide ``heads`` with ``rows x seq`` under
+    ``_TOKENS_PER_CALL``. What a head and token costs between the calls of
+    ``_chunked_bwd``: the six float32 operands and their gradients (2 x
+    2,176 bytes as counted, 2 x 3,072 as HBM tiles them: 96 and 64 columns
+    take 128 lanes, 192 take 256), ``T`` (512) and the chunk-start states
+    (1,536); the rule's temporaries alone are 1.19 GiB two heads a call,
+    1.79 six, 4.69 all thirty at once (compiles for a described v5e, PR
+    33). Fewer at a time is still FASTER in the step, down to two: the
+    Olmo-Hybrid cell reads 14,573 tokens/s two heads a call (``hbm_step_gib``
+    11.76), 14,557 three (12.12), 14,497 five (12.70), 14,540 six (12.93),
+    14,446 fifteen (14.51), 14,509 thirty (14.38) (my chip runs, PR 33, one
+    seed; the parent 13,988), though the rule ALONE is faster in one call
+    (49.5 ms a layer against 52.9): in the step the larger temporaries
+    move what the scheduler keeps where. One call of every head also loses
+    the kernels their jitted names in the compiled step
+    (``transpose_jvp_jit__delta_rule_backward___``), which the benchmark's
+    readers find them by."""
     fitting = max(_TOKENS_PER_CALL // seq, 1)
     return max(n for n in range(1, heads + 1) if heads % n == 0 and n <= fitting)
 
@@ -465,8 +789,8 @@ def gated_delta_rule(
     tokens that write nothing (``beta`` 0, ``log_alpha`` 0). Heads need
     nothing of one another: they are walked ``_heads_per_call`` at a time
     (``lax.map``), and a call keeps nothing for its backward but its
-    inputs, so the preparation's intermediates live for one group of heads
-    at a time, forward and backward.
+    inputs, so the kernels' operands, their gradients and the chunk-start
+    states live for one group of heads at a time, forward and backward.
     ``kernels=False`` runs the scan over chunks in plain jax.numpy (the
     chunked form with no kernel, for tests)."""
     batch, heads, seq, _ = q.shape
@@ -474,19 +798,24 @@ def gated_delta_rule(
     padded = -(-seq // chunk) * chunk
     interpret = resolve_interpret(interpret)
 
-    def one_call(q, k, v, log_alpha, beta):
+    def one_call(q, k, v, *gates):
         if kernels:
-            return _chunked(q, k, v, log_alpha, beta, chunk, interpret)
-        return _scan_reference(*_prepare(q, k, v, log_alpha, beta, chunk), chunk, v.dtype)
+            return _chunked(q, k, v, *gates, chunk, interpret)
+        return _scan_reference(*_prepare(q, k, v, *gates, chunk), chunk, v.dtype)
 
     rows = batch * heads
     per_call = _heads_per_call(rows, padded)
 
-    def grouped(x):
+    def flat(x):
         x = jnp.pad(x, ((0, 0), (0, 0), (0, padded - seq)) + ((0, 0),) * (x.ndim - 3))
-        return x.reshape(rows // per_call, per_call, padded, *x.shape[3:])
+        return x.reshape(rows, padded, *x.shape[3:])
 
-    groups = tuple(grouped(x) for x in (q, k, v, log_alpha, beta))
+    q, k, v, log_alpha, beta = (flat(x) for x in (q, k, v, log_alpha, beta))
+    # the running sums are taken once, over every head, not once a group
+    gates = (_gates(log_alpha, beta, chunk),) if kernels else (log_alpha, beta)
+    groups = tuple(
+        x.reshape(rows // per_call, per_call, *x.shape[1:]) for x in (q, k, v, *gates)
+    )
     if per_call == rows:
         out = one_call(*(x[0] for x in groups))
     else:

@@ -162,6 +162,12 @@ def test_flash_two_head_dims_compile_for_v5e(one_chip):
     assert [g.shape[-1] for g in grads] == [192, 192, 128]
 
 
+# What the rule's value-and-gradient program may hold beside its arguments
+# and results at the cell's size: two heads a call need 1.19 GiB (three 1.32,
+# six 1.79, all thirty at once 4.69: compiles for a described v5e, PR 33).
+DELTA_RULE_TEMPORARIES = int(1.25 * 2**30)
+
+
 def _delta_rule_shapes(one_chip, heads=30, seq=16384):
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -173,29 +179,86 @@ def _delta_rule_shapes(one_chip, heads=30, seq=16384):
     )
 
 
+def _mosaic_calls(text: str) -> list[str]:
+    """The jitted names of a compiled program's Mosaic calls, in order."""
+    return re.findall(r"^\s*(?:ROOT )?%(\w+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text, re.M)
+
+
 def test_delta_rule_kernels_compile_for_v5e(one_chip):
     """The gated delta rule at the Olmo-Hybrid cell's size, ``[1, 30, 16384,
     96 | 192]``: float32 q / k and gates, bfloat16 v, chunks of 64, eight
-    chunks (512 rows) a grid step, two heads a call. The forward is one
-    Mosaic call; a gradient runs it again for the chunk-start states, then
-    the backward kernel, whose eight float32 operands and six results of
-    512 rows fit the scoped VMEM."""
+    chunks (512 rows) a grid step, two heads a call. The forward is the
+    preparation kernel and the scan kernel; a gradient runs both again (the
+    preparation hands over ``T``, the scan the chunk-start states), then
+    the scan's backward kernel, whose eight float32 operands and six
+    results of 512 rows fit the scoped VMEM, and the preparation's."""
     from ray_tpu.ops import gated_delta_rule as G
 
     assert G._heads_per_call(30, 16384) == 2 and G._per_step(256, 64) == 8
     rule = functools.partial(G.gated_delta_rule, interpret=False)
     shapes = _delta_rule_shapes(one_chip)
     assert jax.eval_shape(rule, *shapes).shape == (1, 30, 16384, 192)
-    assert _custom_calls(rule, *shapes) == 1
+    text = jax.jit(rule).lower(*shapes).compile().as_text()
+    assert _mosaic_calls(text) == ["_delta_prepare_forward", "_delta_rule_forward"]
 
     def grads(*args):
         loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
         return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
 
-    assert _custom_calls(grads, *shapes) == 3
+    assert sorted(_mosaic_calls(jax.jit(grads).lower(*shapes).compile().as_text())) == [
+        "_delta_prepare_backward", "_delta_prepare_forward", "_delta_prepare_forward",
+        "_delta_rule_backward", "_delta_rule_forward", "_delta_rule_forward",
+    ]
     assert [g.dtype for g in jax.eval_shape(grads, *shapes)] == [
         jnp.float32, jnp.float32, jnp.bfloat16, jnp.float32, jnp.float32,
     ]
+
+
+def test_delta_rule_preparation_kernels_compile_for_v5e(one_chip):
+    """The chunk preparation's two kernels alone at the cell's ``[30,
+    16384, 96 | 192]``, every head in one call: two chunks of 64 to a
+    128-row product, four products a grid step, ``T`` handed from the
+    forward call to the backward as ``[30, 16384, 128]``; and the rule's
+    value-and-gradient program at two heads a call holds all four kernels
+    by name with its temporaries under the figure ``_TOKENS_PER_CALL`` was
+    chosen for."""
+    from ray_tpu.ops import gated_delta_rule as G
+
+    assert G._product_rows(16384, 64) == 128 and G._together(64, 8) == 2
+    q, k, v, log_alpha, beta = (
+        jax.ShapeDtypeStruct(x.shape[1:], x.dtype, sharding=one_chip)
+        for x in _delta_rule_shapes(one_chip)
+    )
+    gates = jax.ShapeDtypeStruct((30, 128, 2, 128), jnp.float32, sharding=one_chip)
+    assert jax.eval_shape(functools.partial(G._gates, chunk=64), log_alpha, beta).shape == gates.shape
+    forward = functools.partial(G._delta_prepare_forward, chunk=64, interpret=False, inverse=True)
+    assert _custom_calls(forward, q, k, v, gates) == 1
+    *operands, inverse = (
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+        for x in jax.eval_shape(forward, q, k, v, gates)
+    )
+    assert [x.shape[-1] for x in operands] == [96, 192, 96, 64, 96, 1]
+    assert inverse.shape == (30, 16384, 128)
+    backward = functools.partial(G._delta_prepare_backward, chunk=64, interpret=False)
+    assert _custom_calls(backward, q, k, v, gates, inverse, *operands) == 1
+    got = jax.eval_shape(backward, q, k, v, gates, inverse, *operands)
+    assert [(x.shape, x.dtype) for x in got] == [
+        (x.shape, x.dtype) for x in (q, k, v, gates)
+    ]
+
+    def value_and_grads(*args):
+        loss = lambda *a: jnp.sum(
+            G.gated_delta_rule(*a, interpret=False).astype(jnp.float32) ** 2
+        )
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+    compiled = jax.jit(value_and_grads).lower(*_delta_rule_shapes(one_chip)).compile()
+    assert set(_mosaic_calls(compiled.as_text())) == {
+        "_delta_prepare_forward", "_delta_prepare_backward",
+        "_delta_rule_forward", "_delta_rule_backward",
+    }
+    # two heads a call: what the rule holds beside its inputs and gradients
+    assert compiled.memory_analysis().temp_size_in_bytes < DELTA_RULE_TEMPORARIES
 
 
 def test_rmsnorm_compiles_for_v5e(one_chip):

@@ -39,12 +39,15 @@ def inputs(seq, d_k, d_v, decay, batch=1, heads=2, seed=0):
     return q, k, v, log_alpha, beta
 
 
-def close(got, want, what):
+def close(got, want, what, tol=TOL, floor=1e-6):
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape, what
     assert np.all(np.isfinite(got)), what
     # the floor: a gradient that is itself 1e-3 (log_alpha's where the decay
     # wipes the state) is a float32 sum of terms of order 1
-    assert np.max(np.abs(got - want)) <= TOL * np.max(np.abs(want)) + 1e-6, what
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)) + floor, (
+        what, np.max(np.abs(got - want)), np.max(np.abs(want))
+    )
 
 
 CASES = {
@@ -132,3 +135,96 @@ def test_what_is_kept_for_the_backward_is_the_output():
     assert G.kept_bytes(1, 30, 16384, 192, 2) == 30 * 16384 * 192 * 2
     assert G.kept_bytes(1, 2, 50, 8, 4, chunk=16) == 2 * 64 * 8 * 4
     assert G.RESIDUAL_NAMES == ("delta_rule_out",)
+
+
+# ---------------------------------------------------------------------------
+# The preparation's two Mosaic kernels against ``_prepare``, the oracle.
+# ---------------------------------------------------------------------------
+def _alike(args):
+    """A chunk whose keys are alike at beta 2, nothing forgotten: ``A`` is 2
+    everywhere below the diagonal (where a Neumann product is off by 2e19)."""
+    q, k, v, log_alpha, beta = args
+    k = k[..., :1, :] + 1e-3 * k
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    return q, k, v, jnp.full_like(log_alpha, -1e-4), jnp.full_like(beta, 2.0)
+
+
+def _wiped(args):
+    """Gates so negative that a decay across two of every five tokens
+    underflows to 0 (``exp(-160)``): G falls to -1900 inside a chunk."""
+    q, k, v, log_alpha, beta = args
+    return q, k, v, jnp.where(log_alpha < -1.0, -80.0, log_alpha), beta
+
+
+# seq, d_k, d_v, decay, what is done to the inputs
+PREPARE_CASES = {
+    "cell_widths_four_chunks_a_step": (256, 96, 192, "mixed", None),
+    "keys_alike_at_beta_two": (128, 16, 24, "near_one", _alike),
+    "decays_underflow": (128, 16, 8, "mixed", _wiped),
+    "no_multiple_of_the_chunk": (100, 16, 24, "mixed", None),
+    "shorter_than_a_chunk": (20, 8, 16, "near_zero", None),
+}
+_PREPARED = {}
+
+
+def prepared(case):
+    """A case's inputs as ``gated_delta_rule`` hands them on (flat heads,
+    padded with tokens that write nothing, v in bfloat16 as the model's),
+    their chunk, and the oracle's operands with its transpose."""
+    if case not in _PREPARED:
+        seq, d_k, d_v, decay, change = PREPARE_CASES[case]
+        args = inputs(seq, d_k, d_v, decay, heads=3, seed=3)
+        args = change(args) if change else args
+        chunk = G._default_chunk(seq)
+        pad = -seq % chunk
+        flat = [
+            jnp.pad(x[0], ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3)) for x in args
+        ]
+        flat[2] = flat[2].astype(jnp.bfloat16)
+        oracle = jax.jit(lambda *a: jax.vjp(lambda *b: G._prepare(*b, chunk), *a))
+        _PREPARED[case] = (flat, chunk, *oracle(*flat))
+    return _PREPARED[case]
+
+
+# Kernel against oracle: the same float32 steps on both sides (the forward is
+# equal to the last bit here and on the chip; the transposes differ in order).
+NEAR = dict(tol=2e-5, floor=0.0)
+
+
+OPERANDS = ("w", "u0", "qg", "p", "kd", "gamma")
+
+
+@pytest.mark.parametrize("case", list(PREPARE_CASES))
+def test_the_preparation_kernel_writes_the_oracles_six_operands(case):
+    (q, k, v, log_alpha, beta), chunk, want, _ = prepared(case)
+    got = G._delta_prepare_forward(
+        q, k, v, G._gates(log_alpha, beta, chunk), chunk=chunk, interpret=True
+    )
+    assert len(got) == 6 and all(x.dtype == jnp.float32 for x in got)
+    for name, g, w in zip(OPERANDS, got, want):
+        close(g, w, name, **NEAR)
+
+
+@pytest.mark.parametrize("case", list(PREPARE_CASES))
+def test_the_preparations_transpose_by_hand_is_jaxs(case):
+    """Random cotangents for the six operands: the five gradients of the
+    hand-written kernel (``T`` handed over by the forward call, as in
+    ``_chunked_bwd``) against ``jax.vjp(_prepare)``."""
+    inputs_, chunk, operands, oracle_vjp = prepared(case)
+    q, k, v, log_alpha, beta = inputs_
+    keys = jax.random.split(jax.random.PRNGKey(11), len(operands))
+    cotangents = tuple(jax.random.normal(key, x.shape) for key, x in zip(keys, operands))
+    want = oracle_vjp(cotangents)
+
+    gates, gates_vjp = jax.vjp(lambda *a: G._gates(*a, chunk), log_alpha, beta)
+    *_, inverse = G._delta_prepare_forward(
+        q, k, v, gates, chunk=chunk, interpret=True, inverse=True
+    )
+    dq, dk, dv, dgates = G._delta_prepare_backward(
+        q, k, v, gates, inverse, *cotangents, chunk=chunk, interpret=True
+    )
+    got = (dq, dk, dv, *gates_vjp(dgates))
+    assert [g.dtype for g in got] == [w.dtype for w in want]
+    for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
+        # dv is rounded to v's bfloat16 on both sides: a last bit apart
+        close(g, w, f"d{name}", **(dict(NEAR, tol=1e-2) if name == "v" else NEAR))
