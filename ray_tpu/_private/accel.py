@@ -99,3 +99,24 @@ def live_jax():
     if backend.get_backend.cache_info().currsize == 0:
         return None
     return jax
+
+
+_annotation_cls = None
+
+
+def trace_annotation_cls():
+    """``jax.profiler.TraceAnnotation`` when the process already imported
+    jax (never force a jax init for telemetry), else None. Cached after
+    the first successful probe. The one guard for every host span the
+    program writes into a live profile: ``step_stats.step_annotation``
+    (train) and ``DataIterator`` (data, which may not import train)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            _annotation_cls = jax.profiler.TraceAnnotation
+        except Exception:  # rtlint: disable=swallowed-exception - ancient jax without profiler: annotations degrade to timers
+            return None
+    return _annotation_cls

@@ -170,15 +170,22 @@ class LocalCluster:
         resources: dict | None = None,
         store_capacity: int = 0,
     ) -> None:
-        self.controller_handle, self.controller_addr = start_controller(
-            self.session_dir
-        )
-        handle, addr, store, node_id = start_node_agent(
-            self.session_dir,
-            self.controller_addr,
-            resources=resources,
-            store_capacity=store_capacity,
-        )
+        from ray_tpu.util import tracing
+
+        # Lifecycle spans (children of ray_tpu.init): each covers the
+        # subprocess from Popen to its address file; the agent's holds its
+        # chip discovery and the object store's start.
+        with tracing.span("init.start_controller", lifecycle=True):
+            self.controller_handle, self.controller_addr = start_controller(
+                self.session_dir
+            )
+        with tracing.span("init.start_agent", lifecycle=True):
+            handle, addr, store, node_id = start_node_agent(
+                self.session_dir,
+                self.controller_addr,
+                resources=resources,
+                store_capacity=store_capacity,
+            )
         self.agents.append(handle)
         self.agent_addrs.append(addr)
         self.agent_node_ids.append(node_id)

@@ -72,6 +72,12 @@ def init(
     scheduler will believe you.
     """
     global _local_cluster
+    from ray_tpu.util import tracing as _tracing
+
+    # Lifecycle span (docs/observability.md): recorded at the end, by when
+    # the session directory is known. Ambient only around start_head: what
+    # connect() starts on the io loop lives on and must not inherit it.
+    init_span = _tracing.begin("ray_tpu.init")
     with _lock:
         if _global_ctx is not None:
             if ignore_reinit_error:
@@ -93,17 +99,20 @@ def init(
             if num_cpus is not None:
                 custom["CPU"] = num_cpus
             cluster = LocalCluster()
-            cluster.start_head(
-                resources=custom,
-                store_capacity=object_store_memory or 0,
-            )
+            # Before start_head, whose two subprocess starts are spans.
+            _tracing.configure(cluster.session_dir)
+            ambient = _tracing.set_current(init_span)
+            try:
+                cluster.start_head(
+                    resources=custom,
+                    store_capacity=object_store_memory or 0,
+                )
+            finally:
+                _tracing.reset_current(ambient)
             _local_cluster = cluster
             # Driver-side tracing/profile exports land in the session dir
             # (workers inherit it via RAYTPU_SESSION_DIR at spawn).
             os.environ["RAYTPU_SESSION_DIR"] = cluster.session_dir
-            from ray_tpu.util import tracing as _tracing
-
-            _tracing.configure(cluster.session_dir)
             controller_addr = cluster.controller_addr
             agent_addr = cluster.head_agent_addr
             store_info = cluster.head_store_info
@@ -121,7 +130,12 @@ def init(
             store_info=store_info,
             is_driver=True,
         )
+        connect_start_ns = time.time_ns()
         ctx.connect()
+        _tracing.emit(
+            "init.connect", _tracing.context_of(init_span),
+            start_ns=connect_start_ns, lifecycle=True,
+        )
         set_global_context(ctx, is_driver=True)
         _runtime_context_extras["namespace"] = namespace
         _runtime_context_extras["runtime_env"] = runtime_env or {}
@@ -143,6 +157,7 @@ def init(
             except Exception:
                 shutdown()  # RLock: safe to re-enter from init's lock
                 raise
+        _tracing.finish(init_span)
         return runtime_info()
 
 

@@ -52,6 +52,15 @@ SUB_PHASES = (
     "opt_s",
 )
 
+# Compile watcher (train worker, train/_internal/step_stats.py): programs
+# compiled or loaded in a record's interval and the seconds they took.
+# Absent from a record whose interval compiled nothing, as the sub-phases
+# are; not a phase of the wall partition (the time is inside compute_s).
+COMPILE_FIELDS = (
+    "compiles",
+    "compile_s",
+)
+
 # Peak bf16 FLOP/s per chip kind: the program's one peak table. Where
 # benchmarks/harness/peaks.json lists a kind, tests/test_workload.py
 # holds this entry to it.
@@ -161,7 +170,7 @@ class StepStatsAggregator:
                 "ts": 0.0,
                 "tokens": 0.0,
                 "flops": 0.0,
-                **{p: 0.0 for p in STEP_PHASES + SUB_PHASES},
+                **{p: 0.0 for p in STEP_PHASES + SUB_PHASES + COMPILE_FIELDS},
             }
             self.steps_ingested += 1
             while len(self._by_step) > self.window:
@@ -172,6 +181,8 @@ class StepStatsAggregator:
         entry["flops"] += _num(rec.get("flops"))
         for phase in STEP_PHASES + SUB_PHASES:
             entry[phase] += phases[phase]
+        for name in COMPILE_FIELDS:
+            entry[name] += max(0.0, _num(rec.get(name)))
         self.records_ingested += 1
         return True
 
@@ -202,6 +213,14 @@ class StepStatsAggregator:
                 phase_fracs[phase.replace("_s", "_frac")] = (
                     total / rank_wall_total
                 )
+        # Compiles in the window, only when there were any: a steady loop
+        # has none, and "compiles: 0" on every sample would be noise.
+        compiled = {}
+        if any(e.get("compiles") for e in steps):
+            compiled = {
+                name: sum(e.get(name, 0.0) for e in steps)
+                for name in COMPILE_FIELDS
+            }
         peak_total = sum(self._rank_peak.values()) or None
         mfu = None
         if peak_total and gang_wall > 0:
@@ -214,6 +233,7 @@ class StepStatsAggregator:
             "flops_per_s": flops / gang_wall if gang_wall > 0 else 0.0,
             "mfu": mfu,
             **phase_fracs,
+            **compiled,
             "records": self.records_ingested,
             "dropped_stale": self.dropped_stale,
             "clamped_negative": self.clamped_negative,

@@ -17,11 +17,14 @@ world size replays no committed sample and drops none.
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
+import time
 from typing import Any, Iterator, Optional
 
 import ray_tpu
+from ray_tpu._private import accel
 from ray_tpu.data.block import BlockAccessor
 from ray_tpu.data._internal.map_fn import batch_blocks, format_batch
 
@@ -57,6 +60,7 @@ class DataIterator:
         self._owner_name = owner_name
         self._stats = stats
         self._fetch_wait_s = 0.0
+        self._local_work_s = 0.0
         # Resume position: epoch counter, spans for the *current* pass
         # (differs from _base_spans only on the first pass after a resume),
         # rows to skip at the head of the current pass, and rows delivered
@@ -74,6 +78,15 @@ class DataIterator:
         ``train.report()`` interval attributes the delta to the step's
         ``data_wait_s`` phase."""
         return self._fetch_wait_s
+
+    @property
+    def local_work_s(self) -> float:
+        """Cumulative seconds the consumer's own thread spent producing
+        batches that it was NOT blocked on producers: slicing, batching,
+        ``format_batch``. The second half of a step's data wait (a
+        prefetching producer never blocks, the formatting still runs in
+        the loop); the ``data.next_batch`` host span times both."""
+        return self._local_work_s
 
     # -- resumable-ingest state ----------------------------------------
     @property
@@ -228,7 +241,27 @@ class DataIterator:
         self._resume_skip = 0
         self._pass_rows = 0
 
-    def _iter_batches_impl(
+    def _iter_batches_impl(self, **kwargs) -> Iterator[Any]:
+        """``_assemble_batches``, the production of each batch (from one
+        ``yield`` to the next: block fetch, slicing, ``format_batch``)
+        timed: a ``data.next_batch`` host span in a live profile, where
+        jax is already imported (free otherwise), and ``local_work_s``."""
+        inner = self._assemble_batches(**kwargs)
+        clock = time.perf_counter
+        done = object()
+        while True:
+            cls = accel.trace_annotation_cls()
+            t0, waited = clock(), self._fetch_wait_s
+            with cls("data.next_batch") if cls else contextlib.nullcontext():
+                batch = next(inner, done)
+            self._local_work_s += (clock() - t0) - (
+                self._fetch_wait_s - waited
+            )
+            if batch is done:
+                return
+            yield batch
+
+    def _assemble_batches(
         self,
         *,
         batch_size: Optional[int] = 256,

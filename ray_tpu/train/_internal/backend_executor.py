@@ -21,6 +21,7 @@ from typing import Any, Callable, Optional
 from ray_tpu.train._internal.session import TrainContext, init_session
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import ScalingConfig
+from ray_tpu.util import tracing
 from ray_tpu.util.gang import WorkerGang
 
 
@@ -35,6 +36,7 @@ def _start_session_fn(
     mesh_axes: dict,
     slice_topology=None,
     pipeline: dict | None = None,
+    trace_parent: dict | None = None,
 ) -> bool:
     if pipeline is not None:
         # MPMD stage assignment: gang rank r is stage r // gang_per_stage
@@ -61,7 +63,9 @@ def _start_session_fn(
         collective_group=gang_ctx.group_name,
         pipeline=pipeline,
     )
-    session = init_session(ctx, lambda: train_fn(dict(train_loop_config)))
+    session = init_session(
+        ctx, lambda: train_fn(dict(train_loop_config)), trace_parent
+    )
     gang_ctx.state["session"] = session
     session.start()
     return True
@@ -134,36 +138,46 @@ class BackendExecutor:
         attempt: int = 0,
     ) -> None:
         sc = self.scaling_config
-        self.gang = self._form_gang()
+        # Lifecycle spans, children of the caller's train.fit, whose
+        # context also rides to the workers as train.loop's parent.
+        fit_ctx = tracing.inject(lifecycle=True)
+        with tracing.span(
+            "train.form_gang", lifecycle=True, attempt=attempt
+        ) as formed:
+            self.gang = self._form_gang()
+            formed.attributes["world_size"] = self.gang.num_workers
         if callable(dataset_shards_per_rank):
             # Elastic path: shards depend on the world size actually formed.
-            dataset_shards_per_rank = dataset_shards_per_rank(
-                self.gang.num_workers
+            with tracing.span("train.split_datasets", lifecycle=True):
+                dataset_shards_per_rank = dataset_shards_per_rank(
+                    self.gang.num_workers
+                )
+        with tracing.span("train.start_sessions", lifecycle=True):
+            self.gang.run(
+                _start_session_fn,
+                train_fn=train_fn,
+                train_loop_config=train_loop_config,
+                experiment_name=self.experiment_name,
+                trial_dir=self.trial_dir,
+                latest_checkpoint=latest_checkpoint,
+                dataset_shards_per_rank=dataset_shards_per_rank,
+                mesh_axes=dict(sc.mesh_axes),
+                slice_topology=sc.slice_topology,
+                pipeline=(
+                    {
+                        "num_stages": int(sc.pipeline_stages),
+                        "microbatches": int(sc.microbatches),
+                        "virtual": int(getattr(sc, "virtual_stages", 1)),
+                        # Launch-attempt generation: the stage runner fences
+                        # its p2p wire tags per attempt, so a re-formed gang
+                        # never consumes a dead incarnation's frames.
+                        "attempt": int(attempt),
+                    }
+                    if int(getattr(sc, "pipeline_stages", 1)) > 1
+                    else None
+                ),
+                trace_parent=fit_ctx,
             )
-        self.gang.run(
-            _start_session_fn,
-            train_fn=train_fn,
-            train_loop_config=train_loop_config,
-            experiment_name=self.experiment_name,
-            trial_dir=self.trial_dir,
-            latest_checkpoint=latest_checkpoint,
-            dataset_shards_per_rank=dataset_shards_per_rank,
-            mesh_axes=dict(sc.mesh_axes),
-            slice_topology=sc.slice_topology,
-            pipeline=(
-                {
-                    "num_stages": int(sc.pipeline_stages),
-                    "microbatches": int(sc.microbatches),
-                    "virtual": int(getattr(sc, "virtual_stages", 1)),
-                    # Launch-attempt generation: the stage runner fences
-                    # its p2p wire tags per attempt, so a re-formed gang
-                    # never consumes a dead incarnation's frames.
-                    "attempt": int(attempt),
-                }
-                if int(getattr(sc, "pipeline_stages", 1)) > 1
-                else None
-            ),
-        )
 
     def _form_gang(self) -> WorkerGang:
         """Form the gang at the target size, stepping down to min_workers.

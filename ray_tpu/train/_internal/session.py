@@ -17,6 +17,7 @@ from typing import Any, Callable, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train._internal import step_stats as step_stats_mod
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -61,8 +62,18 @@ class TrainContext:
 
 
 class _Session:
-    def __init__(self, ctx: TrainContext, fn: Callable[[], Any]):
+    def __init__(
+        self,
+        ctx: TrainContext,
+        fn: Callable[[], Any],
+        trace_parent: dict | None = None,
+    ):
         self.ctx = ctx
+        # The driver's train.fit span: parent of this worker's train.loop.
+        self._trace_parent = trace_parent
+        # When fn started, until the first report has emitted
+        # train.first_report from it; then None.
+        self._loop_start_ns: int | None = None
         self._results: queue.Queue = queue.Queue(maxsize=1)
         self._consumed = threading.Event()
         self._consumed.set()
@@ -85,8 +96,16 @@ class _Session:
         self._thread.start()
 
     def _run(self, fn: Callable[[], Any]) -> None:
+        step_stats_mod.begin_startup()
         try:
-            fn()
+            # Lifecycle span, written when fn ends; what a reader of a
+            # killed worker finds is train.first_report.
+            with tracing.span(
+                "train.loop", parent=self._trace_parent, lifecycle=True,
+                rank=self.ctx.world_rank,
+            ) as loop:
+                self._loop_start_ns = loop.start_ns
+                fn()
         except Exception as exc:  # surfaced via next_result poll
             exc._traceback_str = traceback.format_exc()  # type: ignore[attr-defined]
             self.error = exc
@@ -97,6 +116,14 @@ class _Session:
     def report(
         self, metrics: dict, checkpoint: Checkpoint | None = None
     ) -> None:
+        if self._loop_start_ns is not None:
+            # The worker's own time to its first step, once.
+            tracing.emit(
+                "train.first_report", start_ns=self._loop_start_ns,
+                lifecycle=True, rank=self.ctx.world_rank,
+            )
+            self._loop_start_ns = None
+            step_stats_mod.end_startup()
         # Snapshot this rank's dataset-iterator positions alongside the
         # report: the driver stamps them into the committed checkpoint so a
         # restart (at any world size) resumes ingest exactly (ISSUE 6).
@@ -155,9 +182,13 @@ class _Session:
 _session: _Session | None = None
 
 
-def init_session(ctx: TrainContext, fn: Callable[[], Any]) -> _Session:
+def init_session(
+    ctx: TrainContext,
+    fn: Callable[[], Any],
+    trace_parent: dict | None = None,
+) -> _Session:
     global _session
-    _session = _Session(ctx, fn)
+    _session = _Session(ctx, fn, trace_parent)
     return _session
 
 

@@ -11,7 +11,9 @@ Worker half (runs inside each train worker process):
     the dataset iterators' fetch-wait clocks), collective + checkpoint
     time (drained from the accumulator), compute as the remainder, plus
     tokens/FLOPs when the user's metrics carry them (keys ``tokens`` and
-    ``flops``, per rank per step).
+    ``flops``, per rank per step), and ``compiles`` / ``compile_s`` when
+    a program was compiled or loaded in the interval (the compile watcher
+    below).
 
 Driver half:
   * :class:`FlightRecorder` — one per ``fit()``. Ingests every rank's
@@ -27,12 +29,13 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import sys
 import threading
 import time
 from typing import Any
 
+from ray_tpu._private import accel
 from ray_tpu._private import profiler as profiler_mod
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -83,25 +86,6 @@ def record_phase(phase: str, seconds: float) -> None:
     profiler_mod.note_phase(phase, seconds)
 
 
-_annotation_cls: Any = None
-
-
-def _trace_annotation_cls() -> Any:
-    """``jax.profiler.TraceAnnotation`` when the process already imported
-    jax (never force a jax init for telemetry), else None. Cached after
-    the first successful probe."""
-    global _annotation_cls
-    if _annotation_cls is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return None
-        try:
-            _annotation_cls = jax.profiler.TraceAnnotation
-        except Exception:  # rtlint: disable=swallowed-exception - ancient jax without profiler: annotations degrade to timers
-            return None
-    return _annotation_cls
-
-
 @contextlib.contextmanager
 def step_annotation(name: str, phase: str | None = None):
     """Named sub-step scope (ISSUE 20): times the block, opens a
@@ -110,7 +94,7 @@ def step_annotation(name: str, phase: str | None = None):
     when asked, and — only while a capture is live — buffers the slice
     for the merged Perfetto trace. Idle cost is one timer read pair plus
     a no-op TraceAnnotation."""
-    cls = _trace_annotation_cls()
+    cls = accel.trace_annotation_cls()
     ann = cls(name) if cls is not None else None
     wall0 = time.time()
     t0 = time.perf_counter()
@@ -127,6 +111,61 @@ def step_annotation(name: str, phase: str | None = None):
         profiler_mod.note_annotation(name, wall0, dt)
 
 
+# -- worker-side compile watcher ----------------------------------------
+# The jax.monitoring listeners that call these are registered where the
+# train loop first reaches jax (train/jax_utils.py::_watch_compiles):
+# this module never imports jax. A compile-or-load is one backdated
+# ``jax.compile`` span under the thread's current span, and one count in
+# the StepStats record of its interval: "which step recompiled".
+_compile_lock = threading.Lock()
+_compile_acc = [0, 0.0]            # compiles, seconds since the last drain
+_cache_hit = threading.local()     # set by a compile's hit event, read by its duration event
+# From the start of a session's loop to its first train.report the compile
+# spans are lifecycle spans (part of the time to the first step); after it
+# they are per-step spans, gated by tracing.enabled() like any other.
+_startup = False
+
+
+def begin_startup() -> None:
+    global _startup
+    _startup = True
+
+
+def end_startup() -> None:
+    global _startup
+    _startup = False
+
+
+def note_cache_hit() -> None:
+    """The persistent cache served the compile this thread is inside."""
+    _cache_hit.seen = True
+
+
+def note_compile(seconds: float, fun_name: str | None = None) -> None:
+    """One ``compile_or_get_cached`` ended on this thread, hit or miss. A
+    compile that no cache served is a miss, whether or not one is set up:
+    the program was built."""
+    hit = getattr(_cache_hit, "seen", False)
+    _cache_hit.seen = False
+    with _compile_lock:
+        _compile_acc[0] += 1
+        _compile_acc[1] += seconds
+    end_ns = time.time_ns()
+    attributes = {"fun_name": fun_name} if fun_name else {}
+    tracing.emit(
+        "jax.compile", start_ns=end_ns - int(seconds * 1e9), end_ns=end_ns,
+        lifecycle=_startup, seconds=seconds,
+        cache="hit" if hit else "miss", **attributes,
+    )
+
+
+def _drain_compiles() -> tuple[int, float]:
+    with _compile_lock:
+        out = (_compile_acc[0], _compile_acc[1])
+        _compile_acc[:] = [0, 0.0]
+    return out
+
+
 def _drain_phases() -> dict[str, float]:
     with _phase_lock:
         out = dict(_phase_acc)
@@ -138,8 +177,6 @@ def _device_info() -> tuple[str, int]:
     """(device_kind, local device count) — read from jax only when the
     train loop has already initialised a backend itself (telemetry never
     takes the chip: accel.live_jax)."""
-    from ray_tpu._private import accel
-
     jax = accel.live_jax()
     if jax is None:
         return "", 1
@@ -167,11 +204,16 @@ class StepRecorder:
         )
 
     def _data_wait_total(self) -> float:
+        """Both clocks of every dataset iterator: blocked on its producer
+        (``fetch_wait_s``) and its own slicing and formatting on the
+        loop's thread (``local_work_s``; a prefetching producer never
+        blocks, and the loop still waits for its batch)."""
         total = 0.0
         for shard in (self.ctx.dataset_shards or {}).values():
-            wait = getattr(shard, "fetch_wait_s", None)
-            if isinstance(wait, (int, float)):
-                total += float(wait)
+            for clock in ("fetch_wait_s", "local_work_s"):
+                wait = getattr(shard, clock, None)
+                if isinstance(wait, (int, float)):
+                    total += float(wait)
         return total
 
     def on_report(self, metrics: dict) -> dict:
@@ -233,6 +275,10 @@ class StepRecorder:
             rec["fwd_s"] = fwd
             rec["bwd_s"] = bwd
             rec["opt_s"] = opt
+        compiles, compile_s = _drain_compiles()
+        if compiles:
+            rec["compiles"] = compiles
+            rec["compile_s"] = compile_s
         # Step boundary for the capture plane: this report ends step
         # `self.step` — an armed capture starts/stops exactly here, so
         # every selected rank cuts on the same global step edge.
@@ -340,6 +386,15 @@ class FlightRecorder:
                 continue
             if self.agg.add(rec):
                 saw = True
+                if rec.get("compiles") and rec["step"] >= 2:
+                    # Records 0 and 1 hold the step's own first compile
+                    # (and a donated layout's second); later is a loop
+                    # that changed a shape or a static value.
+                    logger.warning(
+                        "rank %s recompiled in step %s: %d program(s), %.3f s",
+                        rec.get("rank"), rec["step"], rec["compiles"],
+                        float(rec.get("compile_s") or 0.0),
+                    )
                 max_ckpt = max(max_ckpt, float(rec.get("checkpoint_s") or 0.0))
                 rank = rec.get("rank", 0)
                 self._queue(f"train/{self.experiment}/rank{rank}", rec)

@@ -146,6 +146,15 @@ class DataParallelTrainer:
         return self.run_config.name or type(self).__name__.lower()
 
     def fit(self) -> Result:
+        from ray_tpu.util import tracing
+
+        # Lifecycle span: the root of the run's start-up tree.
+        with tracing.span(
+            "train.fit", lifecycle=True, experiment=self._experiment_name()
+        ):
+            return self._fit()
+
+    def _fit(self) -> Result:
         from ray_tpu._private import usage
 
         usage.record_feature("train")
@@ -356,10 +365,18 @@ class DataParallelTrainer:
         """Poll rounds until every rank is done, an error surfaces, a stop
         criterion is met, or a checkpoint boundary triggers a voluntary
         resize. Returns (done, last_metrics, error, resize, oom_seen)."""
+        from ray_tpu.util import tracing
+
         stop = self.run_config.stop or {}
         probe_state: dict = {}
+        # Lifecycle span: what the driver waits for while the workers set
+        # up, from sessions started to the first round that returns.
+        first_round = tracing.begin("train.first_round")
         while True:
             round_results = executor.poll_round()
+            if first_round is not None:
+                tracing.finish(first_round)
+                first_round = None
             errors = [r for r in round_results if "error" in r]
             if errors:
                 err = errors[0]["error"]

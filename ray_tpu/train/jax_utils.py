@@ -119,6 +119,37 @@ def ensure_train_state_fits(
     return estimate
 
 
+_compiles_watched = False
+
+
+def _watch_compiles() -> None:
+    """Register the train worker's compile watcher, once a process, where
+    the loop first reaches jax through this module (never import jax for
+    telemetry's sake). jax fires the duration event around every
+    ``compile_or_get_cached``, hit or miss, with the jitted function's
+    name, and the hit event inside it on the same thread; what they
+    become is ``step_stats.note_compile``'s. A loop that compiles before
+    it calls into this module is not seen until it does."""
+    global _compiles_watched
+    if _compiles_watched:
+        return
+    _compiles_watched = True
+    import jax
+
+    from ray_tpu.train._internal import step_stats
+
+    def on_event(name: str, **_kw) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            step_stats.note_cache_hit()
+
+    def on_duration(name: str, seconds: float, **kw) -> None:
+        if name == "/jax/core/compile/backend_compile_duration":
+            step_stats.note_compile(seconds, kw.get("fun_name"))
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def build_mesh(axes: dict[str, int] | None = None, topology=None):
     """Mesh over THIS jax runtime's devices. On a real multi-host gang
     (jax.distributed initialized) that is the whole slice; on the ring
@@ -130,6 +161,7 @@ def build_mesh(axes: dict[str, int] | None = None, topology=None):
     import jax
     from ray_tpu.parallel.mesh import MeshSpec
 
+    _watch_compiles()
     if topology is not None:
         return topology.build_mesh()
     devices = jax.devices()
@@ -325,8 +357,12 @@ class ShardedTrainSetup:
         """device_put a host batch with its leading dim split over the
         data axes (dp × fsdp) of this setup's mesh."""
         from ray_tpu.parallel.mesh import shard_batch as _shard
+        from ray_tpu.train._internal.step_stats import step_annotation
 
-        return _shard(batch, self.mesh)
+        # A host span in a live profile (free without one): the
+        # host-to-device part of a step's data.
+        with step_annotation("data.shard_batch"):
+            return _shard(batch, self.mesh)
 
 
 def _session_mesh():
@@ -408,43 +444,48 @@ def setup_sharded_training(
     import jax
 
     from ray_tpu.parallel.mesh import auto_shard_specs
+    from ray_tpu.util import tracing
 
-    # Sharding-invariant RNG (the modern jax default): without this, the
-    # SAME init_fn produces DIFFERENT weights under different
-    # out_shardings — breaking the contract that one config change
-    # refactorizes a run without changing its math (and the elastic
-    # resize-parity guarantee with it).
-    jax.config.update("jax_threefry_partitionable", True)
-    if mesh is None:
-        mesh = _session_mesh()
-    param_shapes = jax.eval_shape(init_fn)
-    param_shardings = auto_shard_specs(
-        param_shapes,
-        mesh,
-        logical_dims=logical_dims,
-        rules=rules,
-        fsdp_axis=fsdp_axis,
-    )
-    estimate = ensure_train_state_fits(
-        param_shapes,
-        param_shardings,
-        what="sharded train state",
-        budget=None if enforce_budget else float("inf"),
-    )
-    params = jax.jit(init_fn, out_shardings=param_shardings)()
-    opt_shardings = _optimizer_state_shardings(
-        optimizer, param_shapes, param_shardings, mesh
-    )
-    opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
-    return ShardedTrainSetup(
-        mesh=mesh,
-        params=params,
-        opt_state=opt_state,
-        param_shardings=param_shardings,
-        opt_shardings=opt_shardings,
-        factorization=mesh_factorization(mesh),
-        state_bytes_per_device=estimate,
-    )
+    _watch_compiles()
+    # Lifecycle span (child of the worker's train.loop): plan, shardings,
+    # init, optimizer state; its compiles are jax.compile spans under it.
+    with tracing.span("train.setup_state", lifecycle=True):
+        # Sharding-invariant RNG (the modern jax default): without this, the
+        # SAME init_fn produces DIFFERENT weights under different
+        # out_shardings — breaking the contract that one config change
+        # refactorizes a run without changing its math (and the elastic
+        # resize-parity guarantee with it).
+        jax.config.update("jax_threefry_partitionable", True)
+        if mesh is None:
+            mesh = _session_mesh()
+        param_shapes = jax.eval_shape(init_fn)
+        param_shardings = auto_shard_specs(
+            param_shapes,
+            mesh,
+            logical_dims=logical_dims,
+            rules=rules,
+            fsdp_axis=fsdp_axis,
+        )
+        estimate = ensure_train_state_fits(
+            param_shapes,
+            param_shardings,
+            what="sharded train state",
+            budget=None if enforce_budget else float("inf"),
+        )
+        params = jax.jit(init_fn, out_shardings=param_shardings)()
+        opt_shardings = _optimizer_state_shardings(
+            optimizer, param_shapes, param_shardings, mesh
+        )
+        opt_state = jax.jit(optimizer.init, out_shardings=opt_shardings)(params)
+        return ShardedTrainSetup(
+            mesh=mesh,
+            params=params,
+            opt_state=opt_state,
+            param_shardings=param_shardings,
+            opt_shardings=opt_shardings,
+            factorization=mesh_factorization(mesh),
+            state_bytes_per_device=estimate,
+        )
 
 
 def build_sharded_train_step(
@@ -463,6 +504,8 @@ def build_sharded_train_step(
     around ``apply_update`` here), which is how a device trace is split
     by block (``benchmarks/harness/scopes.py``)."""
     import jax
+
+    _watch_compiles()
 
     def meshed_loss(params, batch):
         # Trace the model with the mesh in scope: code that must know it

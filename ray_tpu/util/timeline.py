@@ -33,6 +33,10 @@ _ROLE_HINTS = (
     ("serve.replica", "worker"),
     ("submit", "driver"),
     ("serve.request", "serve_proxy"),
+    # Untraced runs record only the lifecycle spans.
+    ("train.first_report", "train_worker"),
+    ("ray_tpu.init", "driver"),
+    ("train.fit", "driver"),
 )
 
 

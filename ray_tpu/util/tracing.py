@@ -23,6 +23,14 @@ Span taxonomy (see docs/observability.md for the full table):
   serve.request      proxy    HTTP request as seen by the Serve proxy
   serve.replica      replica  replica-side handling of one request
 
+Those are per task, per collective or per request and gated by
+``enabled()``. A span opened with ``lifecycle=True`` is recorded whenever
+an export directory is known, enabled or not: the dozen once-a-run spans
+from ``ray_tpu.init`` to a train worker's first ``train.report`` (and its
+``jax.compile`` spans up to there), which say where the time to the
+first step went. After the last of them the flusher thread exits, so a
+run with tracing off pays nothing in its steady state.
+
 The exporter is a per-process JSONL file under
 ``<session_dir>/tracing/spans-<pid>.jsonl`` (the OTel span JSON shape:
 name, trace_id, span_id, parent_id, start/end unix-nanos, status,
@@ -54,7 +62,7 @@ from ray_tpu._private.config import global_config
 _current: contextvars.ContextVar[tuple[str, str] | None] = contextvars.ContextVar(
     "raytpu_trace_ctx", default=None
 )
-_lock = threading.Lock()       # guards _buffer / _flusher_started
+_lock = threading.Lock()       # guards _flusher_started
 _io_lock = threading.Lock()    # serializes file appends
 _dir: str | None = None
 
@@ -68,8 +76,13 @@ _dir: str | None = None
 # steady state the 0.2s tick drains first.
 _BUFFER_SPANS = 8192
 _FLUSH_AGE_S = 0.2
+# The flusher exits after this many ticks with nothing to write (a run
+# whose only spans are the lifecycle ones must not keep a thread waking
+# five times a second for good); the next span starts a new one.
+_IDLE_TICKS = 3
 _buffer: collections.deque = collections.deque()
 _flusher_started = False
+_atexit_registered = False
 
 # Cheap span/trace ids: one urandom() per process (fork-safe via the pid
 # key) + a counter, instead of two urandom syscalls per span. Same hex
@@ -207,8 +220,11 @@ def flush() -> None:
 
 
 def _flush_loop() -> None:
+    global _flusher_started
+    idle = 0
     while True:
         time.sleep(_FLUSH_AGE_S)
+        idle = 0 if _buffer else idle + 1
         try:
             flush()
         except Exception:
@@ -217,18 +233,31 @@ def _flush_loop() -> None:
             logging.getLogger(__name__).debug(
                 "trace flush failed", exc_info=True
             )
+        if idle >= _IDLE_TICKS:
+            # _record appends BEFORE it reads the flag, so a span that
+            # read "started" is in the buffer by the time it is looked at
+            # here, and one that reads "not started" starts a new thread.
+            with _lock:
+                _flusher_started = False
+                if not _buffer:
+                    return
+                _flusher_started = True
+            idle = 0
 
 
 def _ensure_flusher() -> None:
-    global _flusher_started
+    global _flusher_started, _atexit_registered
     with _lock:
         if _flusher_started:
             return
         _flusher_started = True
+        register = not _atexit_registered
+        _atexit_registered = True
     threading.Thread(
         target=_flush_loop, name="raytpu-span-flusher", daemon=True
     ).start()
-    atexit.register(flush)
+    if register:
+        atexit.register(flush)
 
 
 def _record(span: Span) -> None:
@@ -255,13 +284,16 @@ def _parent_ctx(
 def span(
     name: str,
     parent: tuple[str, str] | dict | None = None,
+    *,
+    lifecycle: bool = False,
     **attributes: Any,
 ) -> Iterator[Span | None]:
     """Open a span. ``parent`` may be an injected dict from a TaskSpec, an
     explicit (trace_id, span_id) tuple, or None (inherit the contextvar /
     start a new trace). If the body raises, the span still sets ``end_ns``
-    and flushes, with ``status: "error"`` + the exception type recorded."""
-    if not enabled():
+    and flushes, with ``status: "error"`` + the exception type recorded.
+    ``lifecycle=True``: a once-a-run span, recorded with tracing off too."""
+    if not (lifecycle or enabled()):
         yield None
         return
     parent_ctx = _parent_ctx(parent)
@@ -293,12 +325,14 @@ def emit(
     start_ns: int,
     end_ns: int | None = None,
     status: str = "ok",
+    lifecycle: bool = False,
     **attributes: Any,
 ) -> Span | None:
     """Record a pre-timed span (for phases whose start predates the call
     site: controller lease parking, in-actor queue wait). Returns the
-    recorded Span so callers can chain children off its span_id."""
-    if not enabled():
+    recorded Span so callers can chain children off its span_id.
+    ``lifecycle`` as for :func:`span`."""
+    if not (lifecycle or enabled()):
         return None
     parent_ctx = _parent_ctx(parent)
     record = Span(
@@ -331,7 +365,8 @@ def begin(
     READ for parentage (a task submitted inside a traced actor method
     must chain), just never written. (Parent resolution is inlined:
     this path runs per task and every call costs ~3-8x its raw time in
-    GIL handoffs with the io loop thread.)"""
+    GIL handoffs with the io loop thread.) Not gated: per-task callers
+    check ``enabled()`` themselves, a lifecycle caller does not."""
     if type(parent) is dict:
         parent_ctx = (parent["trace_id"], parent["span_id"])
     elif parent is not None:
@@ -373,9 +408,11 @@ def reset_current(token) -> None:
     _current.reset(token)
 
 
-def inject() -> dict | None:
-    """Current span context as a TaskSpec-embeddable dict."""
-    if not enabled():
+def inject(lifecycle: bool = False) -> dict | None:
+    """Current span context as a TaskSpec-embeddable dict. ``lifecycle``:
+    also with tracing off, for a lifecycle span's child in another
+    process (untraced TaskSpecs keep carrying no trace keys)."""
+    if not (lifecycle or enabled()):
         return None
     ctx = _current.get()
     if ctx is None:

@@ -1,0 +1,328 @@
+"""The time to the first step, told by the program: the lifecycle spans from
+``ray_tpu.init`` to a train worker's first ``train.report`` (recorded with
+tracing off), the train worker's compile watcher, and the data clocks of
+the flight recorder."""
+
+import logging
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import ray_tpu
+from ray_tpu._private.config import global_config
+from ray_tpu.train._internal import step_stats
+from ray_tpu.train._internal.session import TrainContext
+from ray_tpu.util import tracing
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STEPS = 6
+
+# span -> (its parent, the process that records it): docs/observability.md
+TREE = {
+    "ray_tpu.init": (None, "driver"),
+    "init.start_controller": ("ray_tpu.init", "driver"),
+    "init.start_agent": ("ray_tpu.init", "driver"),
+    "init.connect": ("ray_tpu.init", "driver"),
+    "train.fit": (None, "driver"),
+    "train.form_gang": ("train.fit", "driver"),
+    "train.split_datasets": ("train.fit", "driver"),
+    "train.start_sessions": ("train.fit", "driver"),
+    "train.first_round": ("train.fit", "driver"),
+    "train.loop": ("train.fit", "worker"),
+    "train.setup_state": ("train.loop", "worker"),
+    "train.first_report": ("train.loop", "worker"),
+}
+
+
+def _loop(config):
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu import train
+    from ray_tpu.train import jax_utils
+
+    setup = jax_utils.setup_sharded_training(
+        lambda: {"w": jnp.ones((8, 8))}, optax.sgd(0.1),
+        mesh=jax_utils.build_mesh({"dp": 1}),
+    )
+    step = jax_utils.build_sharded_train_step(
+        lambda p, b: ((b["x"] @ p["w"]) ** 2).mean(), optax.sgd(0.1), setup
+    )
+    params, opt_state = setup.params, setup.opt_state
+    batches = train.get_dataset_shard("train").iter_batches(batch_size=4)
+    for i in range(config["steps"]):
+        rows = np.stack(next(batches)["x"]).astype(np.float32)
+        if i == 3:      # a loop that changes a shape: one recompile
+            rows = np.concatenate([rows, rows])
+        params, opt_state, loss = step(params, opt_state, setup.shard_batch({"x": rows}))
+        train.report({"loss": float(loss)})
+
+
+@pytest.fixture(scope="module")
+def run(tmp_path_factory):
+    """One untraced one-worker ``JaxTrainer.fit`` on the CPU, after one plain
+    task: the session's spans, its timeline and rank 0's StepStats records."""
+    import ray_tpu.data
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    from ray_tpu.util import state
+
+    assert not ray_tpu.is_initialized()
+    os.environ.pop("RAY_TPU_tracing_enabled", None)
+    global_config().tracing_enabled = False
+    ray_tpu.init(num_cpus=4)
+    try:
+        @ray_tpu.remote
+        def add(a, b):
+            return a + b
+
+        assert ray_tpu.get(add.remote(1, 2), timeout=60) == 3
+        result = JaxTrainer(
+            _loop,
+            train_loop_config={"steps": STEPS},
+            scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(
+                name="lifecycle", storage_path=str(tmp_path_factory.mktemp("run"))
+            ),
+            datasets={"train": ray_tpu.data.from_numpy(
+                np.ones((64, 8), np.float32), column="x")},
+        ).fit()
+        assert result.error is None
+        session_dir = os.environ["RAYTPU_SESSION_DIR"]
+        deadline = time.monotonic() + 20
+        records = []
+        while time.monotonic() < deadline and len(records) < STEPS:
+            records = state.get_workload_timeline(
+                "train/lifecycle/rank0", "raw").get("raw") or []
+            time.sleep(0.2)
+        time.sleep(0.5)     # the worker's flusher tick
+        yield {
+            "spans": tracing.read_spans(session_dir),
+            "timeline": ray_tpu.timeline(),
+            "records": records,
+        }
+    finally:
+        ray_tpu.shutdown()
+
+
+def _named(run, name):
+    return [s for s in run["spans"] if s["name"] == name]
+
+
+def test_lifecycle_spans_are_recorded_untraced_and_per_task_spans_are_not(run):
+    names = {s["name"] for s in run["spans"]}
+    assert set(TREE) <= names
+    assert names <= set(TREE) | {"jax.compile"}, (
+        "a span gated by tracing.enabled() was recorded with tracing off"
+    )
+
+
+@pytest.mark.parametrize("name", sorted(TREE))
+def test_each_span_of_the_table_once_under_its_parent(run, name):
+    parent, process = TREE[name]
+    found = _named(run, name)
+    assert len(found) == 1
+    span = found[0]
+    driver_pid = _named(run, "ray_tpu.init")[0]["pid"]
+    assert (span["pid"] == driver_pid) == (process == "driver")
+    if parent is None:
+        assert span["parent_id"] is None
+        return
+    above = _named(run, parent)[0]
+    assert span["parent_id"] == above["span_id"]
+    assert span["trace_id"] == above["trace_id"]
+    # one host, one clock: a child lies inside its parent, across processes too
+    slack = 5_000_000
+    assert above["start_ns"] - slack <= span["start_ns"]
+    assert span["end_ns"] <= above["end_ns"] + slack
+
+
+def test_the_workers_loop_hangs_under_the_drivers_fit(run):
+    fit, loop = _named(run, "train.fit")[0], _named(run, "train.loop")[0]
+    assert loop["pid"] != fit["pid"]
+    assert loop["parent_id"] == fit["span_id"]
+    assert fit["attributes"]["experiment"] == "lifecycle"
+    gang = _named(run, "train.form_gang")[0]["attributes"]
+    assert gang == {"attempt": 0, "world_size": 1}
+    # the first report ends where the driver's first round returns
+    first_report = _named(run, "train.first_report")[0]
+    first_round = _named(run, "train.first_round")[0]
+    assert first_report["start_ns"] == loop["start_ns"]
+    assert first_report["end_ns"] <= first_round["end_ns"]
+
+
+def test_start_up_compiles_are_spans_under_the_span_that_compiled(run):
+    compiles = _named(run, "jax.compile")
+    assert compiles
+    loop = _named(run, "train.loop")[0]
+    setup = _named(run, "train.setup_state")[0]
+    first_report = _named(run, "train.first_report")[0]
+    assert {s["pid"] for s in compiles} == {loop["pid"]}
+    assert {s["parent_id"] for s in compiles} <= {loop["span_id"], setup["span_id"]}
+    assert any(s["parent_id"] == setup["span_id"] for s in compiles)
+    for s in compiles:
+        assert s["attributes"]["cache"] in ("hit", "miss")
+        assert s["attributes"]["seconds"] > 0 and s["attributes"]["fun_name"]
+        # with tracing off they stop at the first report: step 3's recompile
+        # is a counter of its record, not a span
+        assert s["end_ns"] <= first_report["end_ns"]
+
+
+def test_the_timeline_shows_driver_and_worker_on_one_axis(run):
+    events = run["timeline"]["traceEvents"]
+    spans = {e["name"]: e for e in events if e.get("cat") == "span"}
+    assert set(TREE) <= set(spans)
+    tracks = {e["pid"]: e["args"]["name"] for e in events if e["name"] == "process_name"}
+    assert tracks[spans["train.fit"]["pid"]].startswith("driver")
+    assert tracks[spans["train.loop"]["pid"]].startswith("train_worker")
+    fit, loop = spans["train.fit"], spans["train.loop"]
+    assert fit["ts"] <= loop["ts"] and loop["ts"] + loop["dur"] <= fit["ts"] + fit["dur"] + 5e3
+
+
+def test_the_record_of_the_step_that_recompiled_carries_compiles(run):
+    records = run["records"]
+    assert [r["step"] for r in records] == list(range(STEPS))
+    with_compiles = [r["step"] for r in records if "compiles" in r]
+    assert 3 in with_compiles and set(with_compiles) <= {0, 3}
+    third = records[3]
+    assert third["compiles"] >= 1 and 0 < third["compile_s"] <= third["wall_s"]
+    assert all("compile_s" not in r for r in records if "compiles" not in r)
+
+
+def test_data_wait_counts_the_iterators_own_work(run):
+    """A prefetching producer never blocks the loop, and the loop still
+    spends its time slicing and formatting: both clocks are data wait."""
+    later = run["records"][1:]
+    assert all(r["data_wait_s"] > 0 for r in later)
+    assert all(r["data_wait_s"] <= r["wall_s"] for r in later)
+
+
+class _Shard:
+    fetch_wait_s = 0.25
+    local_work_s = 0.5
+
+
+def test_the_recorder_adds_both_clocks_and_a_toy_loop_recompiles_once():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.train import jax_utils
+
+    jax_utils._watch_compiles()
+    recorder = step_stats.StepRecorder(TrainContext(dataset_shards={"train": _Shard()}))
+    assert recorder._data_wait_total() == pytest.approx(0.75)
+
+    double = jax.jit(lambda x: x * 2 + 1)
+    inputs = {n: np.ones(n, np.float32) for n in (4, 8)}
+    double(inputs[4]).block_until_ready()
+    step_stats._drain_compiles()
+    records = []
+    for i in range(5):
+        double(inputs[8 if i == 3 else 4]).block_until_ready()
+        records.append(recorder.on_report({}))
+    assert [r["step"] for r in records if "compiles" in r] == [3]
+    assert records[3]["compiles"] == 1 and records[3]["compile_s"] > 0
+
+
+def test_the_driver_logs_the_step_that_recompiled(caplog):
+    flight = step_stats.FlightRecorder("toy", enabled_=True)
+    base = {"rank": 0, "wall_s": 0.1, "compute_s": 0.1}
+    with caplog.at_level(logging.WARNING, logger=step_stats.__name__):
+        for step in range(5):
+            rec = dict(base, step=step)
+            if step in (0, 3):
+                rec.update(compiles=2, compile_s=0.05)
+            flight.on_round([{"step_stats": rec}])
+    lines = [r.getMessage() for r in caplog.records if "recompiled" in r.getMessage()]
+    assert len(lines) == 1 and "step 3" in lines[0] and "2 program(s)" in lines[0]
+
+
+def test_the_aggregator_carries_compiles_only_where_there_were_any():
+    from ray_tpu._private.workload import StepStatsAggregator
+
+    agg = StepStatsAggregator(window=4)
+    base = {"rank": 0, "wall_s": 0.1, "compute_s": 0.1}
+    for step in range(3):
+        agg.add(dict(base, step=step))
+    assert "compiles" not in agg.summary()
+    agg.add(dict(base, step=3, compiles=2, compile_s=0.05))
+    summary = agg.summary()
+    assert summary["compiles"] == 2 and summary["compile_s"] == pytest.approx(0.05)
+    for step in range(4, 8):        # the window moves past the recompile
+        agg.add(dict(base, step=step))
+    assert "compiles" not in agg.summary()
+
+
+_TWICE = """
+import os, sys, json
+import jax, jax.numpy as jnp
+from ray_tpu.train import jax_utils
+from ray_tpu.train._internal import step_stats
+from ray_tpu.util import tracing
+tracing.configure(sys.argv[1])
+jax_utils._watch_compiles()
+step_stats.begin_startup()
+x = jnp.ones((16, 16))
+step_stats._drain_compiles()
+for _ in range(2):
+    jax.jit(lambda a: (a @ a).sum() * 3).lower(x).compile()
+    jax.clear_caches()
+count, seconds = step_stats._drain_compiles()
+spans = [s for s in tracing.read_spans(sys.argv[1]) if s["name"] == "jax.compile"]
+print(json.dumps({"count": count, "seconds": seconds,
+                  "cache": [s["attributes"]["cache"] for s in spans][-2:],
+                  "fun": spans[-1]["attributes"]["fun_name"]}))
+"""
+
+
+def test_a_program_compiled_twice_is_a_miss_then_a_hit(tmp_path):
+    import json
+
+    env = dict(
+        os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu",
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
+    )
+    env.pop("RAY_TPU_tracing_enabled", None)
+    env.pop("RAYTPU_SESSION_DIR", None)
+    done = subprocess.run(
+        [sys.executable, "-c", _TWICE, str(tmp_path / "session")],
+        env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    seen = json.loads(done.stdout.strip().splitlines()[-1])
+    assert seen["count"] == 2 and seen["seconds"] > 0
+    assert seen["cache"] == ["miss", "hit"]
+    assert "lambda" in seen["fun"]
+
+
+def test_the_flusher_is_gone_a_second_after_the_last_span(tmp_path, monkeypatch):
+    def flushers():
+        return [t for t in threading.enumerate() if t.name == "raytpu-span-flusher"]
+
+    deadline = time.monotonic() + 5
+    while flushers() and time.monotonic() < deadline:
+        time.sleep(0.1)         # an earlier test's, on its way out
+    monkeypatch.setattr(tracing, "_dir", str(tmp_path / "tracing"))
+    assert not tracing.enabled()
+    with tracing.span("gated") as gated:
+        assert gated is None
+    with tracing.span("once.a.run", lifecycle=True, answer=42):
+        pass
+    assert len(flushers()) == 1
+    started = time.monotonic()
+    while flushers() and time.monotonic() - started < 3:
+        time.sleep(0.05)
+    assert not flushers(), "the flusher still ticks after its last span"
+    assert time.monotonic() - started < 2.0
+    written = tracing.read_spans(str(tmp_path))
+    assert [s["name"] for s in written] == ["once.a.run"]
+    assert written[0]["attributes"] == {"answer": 42}
+    # the next span starts a new one
+    tracing.emit("again", start_ns=time.time_ns(), lifecycle=True)
+    assert len(flushers()) == 1
