@@ -20,7 +20,8 @@ checkpoint policy, one head and loss):
   * the fourth shape, a hybrid (Olmo-Hybrid): ``layer_pattern`` names one
     PERIOD of unlike layers, "linear" ones three to one with "full" ones.
     A linear layer's mixer (``linear=``, ``_linear_mixer``) is Gated
-    DeltaNet's: q / k / v through a causal depthwise convolution and SiLU,
+    DeltaNet's: q / k / v through a causal depthwise convolution and SiLU
+    (the kernels of ops/short_conv.py, forward and backward one pass each),
     L2-normalised q and k, a per-head decay and write strength, the gated
     delta rule over a ``[d_k, d_v]`` state a head (the chunked-scan kernels
     of ops/gated_delta_rule.py), a gated RMSNorm a head, ``W_o``. The full
@@ -81,6 +82,7 @@ from ray_tpu.ops.gated_delta_rule import (
 from ray_tpu.ops.grouped_matmul import grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.parallel.mesh import LogicalRules
 
 # The names the model and the optimizer give their work (jax.named_scope:
@@ -105,9 +107,11 @@ LATENT_SCOPES = ("latent", "shared")
 # What a linear-attention layer names: "linear_attention", inside
 # "attention" (the whole mixer: five projections and what follows), and
 # within it "short_conv" (the three causal depthwise convolutions and their
-# SiLU), "delta_rule" (q / k normalisation, the two gates, the chunk
-# preparation and the two scan kernels) and "gate_norm" (the per-head
-# RMSNorm and its SiLU gate).
+# SiLU: two Mosaic kernels, _short_conv_forward and _short_conv_backward,
+# three calls each a layer and pass; XLA's shifted float32 fusions only
+# under attention="reference"), "delta_rule" (q / k normalisation, the two
+# gates, the chunk preparation and the two scan kernels) and "gate_norm"
+# (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # The kinds of layer a ``layer_pattern`` may name.
 LAYER_KINDS = ("linear", "full")
@@ -567,7 +571,9 @@ def _short_conv(x, filters):
     """``SiLU(conv(x))``: a causal depthwise convolution over time, one
     filter ``filters[:, c]`` a channel, no bias; the LAST tap multiplies the
     current token (a Conv1d padded on the left). ``x``: [batch, seq,
-    channels]; float32 math, the model dtype's residency."""
+    channels]; float32 math, the model dtype's residency. In XLA: the
+    ``attention="reference"`` path, and the oracle of the kernels that
+    compute it everywhere else (ops/short_conv.py)."""
     taps, seq = filters.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
     filters = filters.astype(jnp.float32)
@@ -575,17 +581,15 @@ def _short_conv(x, filters):
     return jax.nn.silu(out).astype(x.dtype)
 
 
-def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
-    """The gated delta rule of a linear layer: the per-token recurrence
-    under ``attention="reference"``, else the chunked-scan kernels, per
-    data shard when traced under a device mesh (``_flash_over_mesh`` says
-    why). A head's scan needs the whole sequence and the kernels are not
+def _per_data_shard(kernel: Callable, operands, result) -> Callable:
+    """A linear layer's Mosaic ``kernel``, per data shard when traced under
+    a device mesh (``_flash_over_mesh`` says why). ``operands`` and
+    ``result`` give each array's logical dims (None: the same on every
+    shard). A head's scan needs the whole sequence and the kernels are not
     written to run on a slice of the heads' parameters: tp and sp refuse."""
-    if config.attention == "reference":
-        return gated_delta_rule_reference
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
-        return gated_delta_rule
+        return kernel
     for axis in ("tp", "sp"):
         if dict(mesh.shape).get(axis, 1) > 1:
             raise NotImplementedError(
@@ -593,12 +597,33 @@ def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
                 "written: the scan kernel runs per data shard (dp / fsdp) with every head "
                 "and the whole sequence"
             )
-    rows = LogicalRules().spec(("batch", None, None, None), mesh)
-    gates = jax.sharding.PartitionSpec(*rows[:3])
-    return jax.shard_map(
-        gated_delta_rule, mesh=mesh,
-        in_specs=(rows, rows, rows, gates, gates), out_specs=rows, check_vma=False,
+    spec = lambda dims: (
+        jax.sharding.PartitionSpec() if dims is None else LogicalRules().spec(dims, mesh)
     )
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=tuple(spec(dims) for dims in operands),
+        out_specs=spec(result), check_vma=False,
+    )
+
+
+def _short_conv_over_mesh(config: TransformerConfig) -> Callable:
+    """A linear layer's convolutions: ``_short_conv`` under
+    ``attention="reference"``, else the kernels of ops/short_conv.py, per
+    data shard with the filters whole on each."""
+    if config.attention == "reference":
+        return _short_conv
+    rows = ("batch", None, None)
+    return _per_data_shard(short_conv, (rows, None), rows)
+
+
+def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
+    """The gated delta rule of a linear layer: the per-token recurrence
+    under ``attention="reference"``, else the chunked-scan kernels, per
+    data shard."""
+    if config.attention == "reference":
+        return gated_delta_rule_reference
+    rows, gates = ("batch", None, None, None), ("batch", None, None)
+    return _per_data_shard(gated_delta_rule, (rows, rows, rows, gates, gates), rows)
 
 
 # The epsilon under the square root of q's and k's L2 norm.
@@ -616,7 +641,12 @@ def _linear_mixer(h, layer, config: TransformerConfig):
         log alpha = -exp(a_log) softplus(h W_a + dt_bias)       (float32)
         o = gated_delta_rule(q, k, v, log alpha, beta)
         y = RMSNorm_{d_v}(o; o_norm) * SiLU(h W_g)              (per head)
-        out = concat_i(y) W_o"""
+        out = concat_i(y) W_o
+
+    The convolutions and the delta rule are Mosaic kernels (per data shard
+    under a mesh) unless ``attention="reference"``, which keeps both in
+    XLA: ``_short_conv`` and the per-token recurrence, the kernels'
+    oracles."""
     la = config.linear
     batch, seq, _ = h.shape
     heads = la.num_value_heads
@@ -628,9 +658,10 @@ def _linear_mixer(h, layer, config: TransformerConfig):
     with jax.named_scope("linear_attention"):
         q, k, v = (h @ layer[name] for name in ("wq", "wk", "wv"))
         with jax.named_scope("short_conv"):
-            q = _short_conv(q, layer["conv_q"])
-            k = _short_conv(k, layer["conv_k"])
-            v = _short_conv(v, layer["conv_v"])
+            conv = _short_conv_over_mesh(config)
+            q = conv(q, layer["conv_q"])
+            k = conv(k, layer["conv_k"])
+            v = conv(v, layer["conv_v"])
         with jax.named_scope("delta_rule"):
             q, k = (by_head(x, la.key_head_dim).astype(f32) for x in (q, k))
             unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)

@@ -1,4 +1,7 @@
-"""Pallas kernels of the model path, and the one rule for interpret mode."""
+"""Pallas kernels of the model path (flash attention, the grouped matmuls
+of a mixture of experts, the gated delta rule's chunk preparation and scan,
+the linear mixers' short convolutions, RMSNorm), and the one rule for
+interpret mode."""
 
 from __future__ import annotations
 

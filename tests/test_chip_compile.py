@@ -261,6 +261,44 @@ def test_delta_rule_preparation_kernels_compile_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < DELTA_RULE_TEMPORARIES
 
 
+@pytest.mark.parametrize("channels", [2880, 5760])
+def test_short_conv_kernels_compile_for_v5e(one_chip, channels):
+    """The linear mixers' convolutions at the Olmo-Hybrid cell's sizes,
+    ``[1, 16384, 2880]`` (q and k: 22.5 lane tiles, the last channel block
+    overhangs) and ``[1, 16384, 5760]`` (v) in bfloat16 with four taps, in
+    the blocks ``_blocks`` picks: each kernel is one Mosaic call named after
+    its jitted function, and a value-and-gradient program holds exactly the
+    two (nothing but ``x`` and the filters is kept, so no forward runs for
+    the gradient)."""
+    from ray_tpu.ops import short_conv as SC
+
+    x = jax.ShapeDtypeStruct((1, 16384, channels), jnp.bfloat16, sharding=one_chip)
+    filters = jax.ShapeDtypeStruct((4, channels), jnp.float32, sharding=one_chip)
+    assert SC._blocks(16384, channels, jnp.bfloat16) == (1024, 384)
+    forward = functools.partial(SC._short_conv_forward, interpret=False)
+    backward = functools.partial(SC._short_conv_backward, interpret=False)
+    assert _mosaic_calls(jax.jit(forward).lower(x, filters).compile().as_text()) == [
+        "_short_conv_forward"
+    ]
+    assert _mosaic_calls(jax.jit(backward).lower(x, filters, x).compile().as_text()) == [
+        "_short_conv_backward"
+    ]
+    dx, dfilters = jax.eval_shape(backward, x, filters, x)
+    assert (dx.shape, dx.dtype) == (x.shape, x.dtype)
+    assert (dfilters.shape, dfilters.dtype) == (filters.shape, jnp.float32)
+
+    def value_and_grads(x, filters):
+        conv = functools.partial(SC.short_conv, interpret=False)
+        loss = lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, filters)
+
+    # under jvp / transpose jax wraps the names: jvp_jit__short_conv_forward__
+    calls = _mosaic_calls(jax.jit(value_and_grads).lower(x, filters).compile().as_text())
+    assert len(calls) == 2
+    assert sum("_short_conv_forward" in name for name in calls) == 1
+    assert sum("_short_conv_backward" in name for name in calls) == 1
+
+
 def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
