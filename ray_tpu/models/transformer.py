@@ -1,4 +1,4 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the four
+"""Flagship model: a decoder-only transformer, TPU-first, in the five
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
@@ -14,9 +14,15 @@ checkpoint policy, one head and loss):
   * MLP: RMSNorm, then a dense SwiGLU or, with ``moe=``, a dropless mixture
     of experts: softmax top-k (OLMoE, Mixtral) or sigmoid scores chosen
     under a correction bias, renormalised and scaled, with shared experts
-    beside the routed ones (DeepSeek-V3, Moonlight). ``first_dense_layers``
-    puts dense layers before the expert layers (their own stacked tree,
-    ``params["dense_layers"]``).
+    beside the routed ones (DeepSeek-V3, Moonlight), the choice optionally
+    limited to the best ``topk_group`` of ``n_group`` groups of experts.
+    ``first_dense_layers`` puts dense layers before the expert layers (their
+    own stacked tree, ``params["dense_layers"]``). ``MoEConfig.held`` tells
+    an expert layer WHICH experts it holds: the router still scores and
+    chooses among all ``num_experts``, and the layer computes its own
+    experts' part of the weighted sum for the (token, choice) pairs that
+    chose them (a chip's share of a layer whose experts are divided over
+    chips, without the exchange: an absent pair adds nothing).
   * the fourth shape, a hybrid (Olmo-Hybrid): ``layer_pattern`` names one
     PERIOD of unlike layers, "linear" ones three to one with "full" ones.
     A linear layer's mixer (``linear=``, ``_linear_mixer``) is Gated
@@ -31,6 +37,23 @@ checkpoint policy, one head and loss):
     (``params["layers"][kind]``: ``[periods, count in a period, ...]``) and
     ONE scan walks the periods, its body a period's layers in order, each
     under the one checkpoint policy.
+  * the fifth, a hybrid over experts (Ling-3.0-flash-VL's language model):
+    the pattern's MLPs are mixture-of-experts layers (their routing comes
+    out of the period scan, their kernels read the period's expert stacks
+    in place), its "full" layers are latent attention where ``latent=`` is
+    set, with ``output_gate="head"`` each head's output times ``sigmoid(h
+    w_i)`` before ``W_o`` (scope ``attn_gate``), and the dense prefix takes
+    the mixer ``first_dense_kind`` names. Its linear layers are Kimi Delta
+    Attention, ``linear=`` under ``decay="channel"``: the delta rule with a
+    decay for each key CHANNEL of a head,
+
+        g_t = b sigmoid(exp(a_log) (h_t W_a + dt_bias))        [heads, d_k], in (b, 0)
+        S_t = Diag(e^{g_t}) S_{t-1} + k_t (beta_t (v_t - (Diag(e^{g_t}) S_{t-1})^T k_t))^T
+
+    beside the scalar rule's ``S_t = alpha_t S_{t-1} + ...`` (``b`` the
+    bound ``gate_lower_bound``, which is what lets the chunked form be
+    computed: ops/gated_delta_rule.py), and a sigmoid in place of SiLU on
+    the per-head norm's output.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays; every leaf has a logical
@@ -52,9 +75,10 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * serving (init_kv_cache / decode_step) covers grouped-query attention
     only: the latent cache and the recurrent-state cache of a patterned
     model are not written yet, and both refuse by name. So do the pipeline
-    (partition_stages / stage_forward split ONE stacked tree) and a mesh
+    (partition_stages / stage_forward split ONE stacked tree), a mesh
     with tp or sp over a patterned model (the scan kernel runs per data
-    shard under shard_map, as flash does: dp / fsdp work).
+    shard under shard_map, as flash does: dp / fsdp work) and
+    ``norm_placement="post"`` over expert layers.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -113,6 +137,11 @@ LATENT_SCOPES = ("latent", "shared")
 # gates, the chunk preparation and the two scan kernels) and "gate_norm"
 # (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
+# Two names outside the four vocabularies, read by name
+# (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
+# (the chunk preparation under a decay per channel, opened in
+# ops/gated_delta_rule.py: forward, and backward through its custom VJP), and
+# "attn_gate", inside "attention" (a latent layer's head-wise output gate).
 # The kinds of layer a ``layer_pattern`` may name.
 LAYER_KINDS = ("linear", "full")
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
@@ -146,6 +175,44 @@ class MoEConfig:
     # The weights are multiplied by this after renormalisation
     # (``routed_scaling_factor``).
     routed_scaling: float = 1.0
+    # DeepSeek-V3's group-limited choice (``n_group`` / ``topk_group``, under
+    # "sigmoid"): the experts lie in ``n_group`` contiguous groups, a group's
+    # score is the sum of its two largest ``score + router_bias``, and the
+    # top-k is taken inside the ``topk_group`` best groups. 1 / 1: no groups.
+    n_group: int = 1
+    topk_group: int = 1
+    # The experts HELD here, ``(first, count)``: a contiguous block of the
+    # ``num_experts`` the router scores (None: all). The layer routes over
+    # all of them and computes its own experts' part of the weighted sum for
+    # the (token, choice) pairs that chose them; a pair whose expert is
+    # absent adds nothing. This chip's share of a layer whose experts are
+    # divided over chips, WITHOUT the exchange that would bring it the other
+    # chips' tokens: the expert leaves are ``[count, ...]``.
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        if self.n_group > 1 or self.topk_group > 1:
+            if self.scoring != "sigmoid":
+                raise ValueError("routing in groups is DeepSeek-V3's sigmoid routine: scoring='sigmoid'")
+            per_group, rest = divmod(self.num_experts, self.n_group)
+            if rest or not 1 <= self.topk_group <= self.n_group or per_group < 2:
+                raise ValueError(
+                    f"{self.num_experts} experts in {self.n_group} groups of which "
+                    f"{self.topk_group}: groups of equal size >= 2, topk_group <= n_group"
+                )
+            if self.top_k > self.topk_group * per_group:
+                raise ValueError(
+                    f"top_k {self.top_k} exceeds the {self.topk_group * per_group} experts "
+                    "of the groups a token keeps"
+                )
+        if self.held is not None:
+            first, count = self.held
+            if not (0 <= first and 1 <= count and first + count <= self.num_experts):
+                raise ValueError(f"held {self.held!r} is no block of {self.num_experts} experts")
+
+    @property
+    def num_held(self) -> int:
+        return self.held[1] if self.held else self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,6 +223,10 @@ class LatentAttentionConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # "head": each head's output is multiplied by ``sigmoid(h w_i)``, one
+    # scalar a head and position, before ``W_o`` (gated attention at head
+    # granularity; leaf ``wg_head`` ``[hidden, heads]``). None: no gate.
+    output_gate: str | None = None
 
     @property
     def qk_head_dim(self) -> int:
@@ -165,7 +236,9 @@ class LatentAttentionConfig:
 @dataclasses.dataclass(frozen=True)
 class LinearAttentionConfig:
     """A gated-delta-rule linear-attention mixer as ``olmo_hybrid``'s
-    ``config.json`` states it (the ``linear_*`` keys)."""
+    ``config.json`` states it (the ``linear_*`` keys), or, with ``decay=
+    "channel"``, a bounded gate and a sigmoid output gate, Kimi Delta
+    Attention as ``bailing_hybrid``'s states it (the ``kda_*`` keys)."""
     num_key_heads: int = 30
     num_value_heads: int = 30
     key_head_dim: int = 96
@@ -174,6 +247,29 @@ class LinearAttentionConfig:
     # ``beta = 2 sigmoid(.)`` in (0, 2): the state's transition may have
     # negative eigenvalues. False: ``beta`` in (0, 1).
     allow_neg_eigval: bool = True
+    # "head": one decay a head and token (``W_a`` ``[hidden, heads]``,
+    # ``dt_bias`` a head). "channel": one a key channel (``W_a`` ``[hidden,
+    # heads x d_k]``, ``dt_bias`` a channel, ``a_log`` still a head).
+    decay: str = "head"
+    # None: ``log alpha = -exp(a_log) softplus(h W_a + dt_bias)``. A bound b <
+    # 0: ``log alpha = b sigmoid(exp(a_log) (h W_a + dt_bias))``, in (b, 0)
+    # (``kda_safe_gate`` / ``kda_lower_bound``).
+    gate_lower_bound: float | None = None
+    # The activation of the gate on the per-head norm's output.
+    output_gate: str = "silu"
+
+    def __post_init__(self):
+        if self.decay not in ("head", "channel") or self.output_gate not in ("silu", "sigmoid"):
+            raise ValueError(f"unknown decay {self.decay!r} or output_gate {self.output_gate!r}")
+        bound = self.gate_lower_bound
+        if bound is not None and not bound < 0:
+            raise ValueError(f"gate_lower_bound {bound!r} is no negative bound")
+        if self.decay == "channel" and (bound is None or 15 * -bound >= 88):
+            raise NotImplementedError(
+                "a decay per channel needs gate_lower_bound with 15 |bound| < 88: the chunked "
+                "form multiplies sub-blocks of 16 tokens whose key side carries e^(15 |bound|), "
+                f"which must stay under float32's e^88 (got {bound!r})"
+            )
 
     @property
     def key_dim(self) -> int:
@@ -207,9 +303,14 @@ class TransformerConfig:
     # With ``moe``: this many leading layers keep the dense MLP of width
     # ``hidden_dim`` (``first_k_dense_replace``).
     first_dense_layers: int = 0
+    # Under a ``layer_pattern``: the mixer of those leading dense layers
+    # (one of ``LAYER_KINDS``); the pattern starts after them.
+    first_dense_kind: str = "full"
     # One PERIOD of layer kinds (``LAYER_KINDS``), e.g. ("linear", "linear",
-    # "linear", "full"); ``n_layers`` is a multiple of it. None: every
-    # layer is the one kind the fields above describe.
+    # "linear", "full"); ``n_layers`` less the dense prefix is a multiple of
+    # it. "full" is latent attention where ``latent`` is set; the MLPs are
+    # expert layers where ``moe`` is. None: every layer is the one kind the
+    # fields above describe.
     layer_pattern: tuple[str, ...] | None = None
     # The mixer of the pattern's "linear" layers.
     linear: LinearAttentionConfig | None = None
@@ -239,11 +340,14 @@ class TransformerConfig:
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)
         if unknown or not self.layer_pattern:
             raise ValueError(f"layer_pattern {self.layer_pattern!r}: kinds are {LAYER_KINDS}")
-        if self.n_layers % len(self.layer_pattern):
+        if (self.n_layers - self.first_dense_layers) % len(self.layer_pattern):
             raise ValueError(
-                f"n_layers={self.n_layers} is no multiple of the period {self.layer_pattern!r}"
+                f"n_layers={self.n_layers} after {self.first_dense_layers} dense layers is no "
+                f"multiple of the period {self.layer_pattern!r}"
             )
-        if "linear" in self.layer_pattern:
+        if self.first_dense_kind not in LAYER_KINDS:
+            raise ValueError(f"first_dense_kind {self.first_dense_kind!r}: kinds are {LAYER_KINDS}")
+        if "linear" in self._kinds():
             la = self.linear
             if la is None:
                 raise ValueError("a pattern with linear layers needs linear=")
@@ -253,12 +357,11 @@ class TransformerConfig:
                     "to the value heads) is not written: num_key_heads "
                     f"{la.num_key_heads} != num_value_heads {la.num_value_heads}"
                 )
-        if self.moe or self.latent or self.first_dense_layers:
-            raise NotImplementedError(
-                "a layer_pattern over mixture-of-experts, latent-attention or dense-prefix "
-                "layers is not written: the pattern's kinds are linear and full attention "
-                "with a dense MLP"
-            )
+
+    def _kinds(self) -> tuple[str, ...]:
+        """The kinds of mixer a patterned model holds, the prefix's first."""
+        prefix = (self.first_dense_kind,) if self.first_dense_layers else ()
+        return tuple(dict.fromkeys(prefix + self.layer_pattern))
 
     @property
     def head_dim(self) -> int:
@@ -266,7 +369,14 @@ class TransformerConfig:
 
     @property
     def periods(self) -> int:
-        return self.n_layers // len(self.layer_pattern)
+        return (self.n_layers - self.first_dense_layers) // len(self.layer_pattern)
+
+    def linear_layers(self) -> int:
+        """How many layers carry the linear mixer."""
+        if not self.layer_pattern:
+            return 0
+        prefix = self.first_dense_layers if self.first_dense_kind == "linear" else 0
+        return prefix + self.periods * self.layer_pattern.count("linear")
 
     @staticmethod
     def tiny(**overrides) -> "TransformerConfig":
@@ -306,6 +416,7 @@ def param_logical_dims(config: TransformerConfig) -> dict:
             "kv_norm": ("layer", None),
             "wkv_b": ("layer", None, "heads"),
             "wo": ("layer", "heads", "embed"),
+            **({"wg_head": ("layer", "embed", None)} if config.latent.output_gate else {}),
         }
     else:
         attention = {
@@ -319,24 +430,29 @@ def param_logical_dims(config: TransformerConfig) -> dict:
     def stack(mlp, attention=attention):
         return {"attn_norm": ("layer", None), **attention, "mlp_norm": ("layer", None), **mlp}
 
-    layers = stack(moe_mlp if config.moe else dense_mlp)
+    mlp = moe_mlp if config.moe else dense_mlp
+    layers = stack(mlp)
+    prefix = stack(dense_mlp)
     if config.layer_pattern:
         # Stacked by period: [periods, count in a period, ...].
+        channel = config.linear is not None and config.linear.decay == "channel"
         linear = {
             **{name: ("layer", "embed", "heads") for name in ("wq", "wk", "wv", "wg")},
-            "wa": ("layer", "embed", None), "wb": ("layer", "embed", None),
+            "wa": ("layer", "embed", "heads" if channel else None), "wb": ("layer", "embed", None),
             **{name: ("layer", None, "heads") for name in ("conv_q", "conv_k", "conv_v")},
             "a_log": ("layer", None), "dt_bias": ("layer", None), "o_norm": ("layer", None),
             "wo": ("layer", "heads", "embed"),
         }
-        by_kind = {"linear": stack(dense_mlp, linear), "full": layers}
+        by_kind = {"linear": stack(mlp, linear), "full": layers}
         layers = {
             kind: {name: (dims[0], None, *dims[1:]) for name, dims in by_kind[kind].items()}
             for kind in dict.fromkeys(config.layer_pattern)
         }
+        if config.first_dense_kind == "linear":
+            prefix = stack(dense_mlp, linear)
     return {
         "embed": ("vocab", "embed"),
-        **({"dense_layers": stack(dense_mlp)} if config.first_dense_layers else {}),
+        **({"dense_layers": prefix} if config.first_dense_layers else {}),
         "layers": layers,
         "final_norm": (None,),
         "lm_head": ("embed", "vocab"),
@@ -353,6 +469,7 @@ def _projection_shapes(config: TransformerConfig) -> dict:
             "wkv_a": (d, la.kv_lora_rank + la.qk_rope_head_dim),
             "wkv_b": (la.kv_lora_rank, config.n_heads * (la.qk_nope_head_dim + la.v_head_dim)),
             "wo": (config.n_heads * la.v_head_dim, d),
+            **({"wg_head": (d, config.n_heads)} if la.output_gate else {}),
         }
     q_out, kv_out = config.n_heads * config.head_dim, config.n_kv_heads * config.head_dim
     return {"wq": (d, q_out), "wk": (d, kv_out), "wv": (d, kv_out), "wo": (q_out, d)}
@@ -403,31 +520,44 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
             },
         }
 
-    if config.moe:
+    def experts(keys, *lead):
+        """A mixture-of-experts layer's router and routed experts: the
+        router over ALL experts, the weights of those held here."""
         moe = config.moe
-        mlp = {
-            "router": dense(next(keys), nl, d, moe.num_experts).astype(jnp.float32),
-            **swiglu(keys, nl, moe.num_experts, width=_expert_dim(config)),
+        leaves = {
+            "router": dense(next(keys), *lead, d, moe.num_experts).astype(jnp.float32),
+            **swiglu(keys, *lead, moe.num_held, width=_expert_dim(config)),
         }
         if moe.scoring == "sigmoid":
-            mlp["router_bias"] = jnp.zeros((nl, moe.num_experts), jnp.float32)
-    elif not config.layer_pattern:
-        mlp = swiglu(keys, nl, width=config.hidden_dim)
+            leaves["router_bias"] = jnp.zeros((*lead, moe.num_experts), jnp.float32)
+        return leaves
+
+    def shared(keys, *lead):
+        return swiglu(
+            keys, *lead, width=config.moe.shared_experts * _expert_dim(config),
+            names=("shared_gate", "shared_up", "shared_down"),
+        )
+
+    if not config.layer_pattern:
+        mlp = experts(keys, nl) if config.moe else swiglu(keys, nl, width=config.hidden_dim)
     params = {
         "embed": dense(next(keys), config.vocab_size, d, scale=0.02),
         "layers": (
-            _init_patterned_layers(config, next(keys), dense, swiglu, attention)
+            _init_patterned_layers(config, next(keys), dense, swiglu, attention, experts, shared)
             if config.layer_pattern else {**attention(keys, nl), **mlp}
         ),
         "final_norm": jnp.ones((d,), dt),
         "lm_head": dense(next(keys), d, config.vocab_size, scale=d ** -0.5),
     }
-    if config.moe and config.moe.shared_experts:
-        params["layers"].update(swiglu(
-            keys, nl, width=config.moe.shared_experts * _expert_dim(config),
-            names=("shared_gate", "shared_up", "shared_down"),
-        ))
-    if prefix:
+    if config.moe and config.moe.shared_experts and not config.layer_pattern:
+        params["layers"].update(shared(keys, nl))
+    if prefix and config.layer_pattern and config.first_dense_kind == "linear":
+        wide_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
+        params["dense_layers"] = {
+            **_linear_mixer_leaves(config, wide_keys, dense, prefix),
+            **swiglu(wide_keys, prefix, width=config.hidden_dim),
+        }
+    elif prefix:
         params["dense_layers"] = {
             **attention(prefix_keys, prefix),
             **swiglu(prefix_keys, prefix, width=config.hidden_dim),
@@ -435,51 +565,74 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
     return params
 
 
-def _init_patterned_layers(config, key, dense, swiglu, attention) -> dict:
-    """``{kind: leaves of [periods, count in a period, ...]}`` for a
-    ``layer_pattern``; each kind draws from a split of its own.
-
-    A linear layer's own leaves: the convolution filters ``[kernel,
-    channels]`` uniform in +-kernel^-1/2 (a depthwise Conv1d's default),
-    ``a_log = log(A)`` with A uniform in (0, 16), ``dt_bias`` the inverse
-    softplus of a step log-uniform in (0.001, 0.1) (Gated DeltaNet's and
-    Mamba2's initialisation: a per-token decay between 0.2 and 0.9999),
-    the gated norm's weight ones; both gates' parameters in float32."""
+def _linear_mixer_leaves(config, keys, dense, *lead) -> dict:
+    """A linear layer's own leaves and its two block norms, ``lead`` the
+    stacking dims: the convolution filters ``[kernel, channels]`` uniform in
+    +-kernel^-1/2 (a depthwise Conv1d's default), ``a_log = log(A)`` with A
+    uniform in (0, 16), ``dt_bias`` the inverse softplus of a step
+    log-uniform in (0.001, 0.1) (Gated DeltaNet's and Mamba2's
+    initialisation: a per-token decay between 0.2 and 0.9999), the gated
+    norm's weight ones; both gates' parameters in float32. Under a decay per
+    channel ``W_a`` is ``[hidden, heads x d_k]`` and ``dt_bias`` one a
+    channel; ``a_log`` stays one a head. The recipe is kept under
+    ``gate_lower_bound`` too, where it leaves the bounded gate nearly shut
+    on fresh weights (``b sigmoid(A (h W_a + dt_bias))`` with ``dt_bias``
+    about ``log(step)``: a log-decay within 0.01 of 0 in most channels): a
+    bias that opens it makes a fresh gate of slope ``A`` up to 16 a coin
+    between 0 and ``b`` a token, a state that forgets the token before
+    (PERF.md section 6, PR 36)."""
     la, d = config.linear, config.dim
+    decays = la.key_dim if la.decay == "channel" else la.num_value_heads
+    a = jax.random.uniform(next(keys), (*lead, la.num_value_heads), jnp.float32, 1e-3, 16.0)
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (*lead, decays), jnp.float32, math.log(1e-3), math.log(1e-1),
+    ))
+    conv = lambda channels: jax.random.uniform(
+        next(keys), (*lead, la.conv_kernel, channels), jnp.float32,
+        -la.conv_kernel ** -0.5, la.conv_kernel ** -0.5,
+    ).astype(config.dtype)
+    return {
+        "attn_norm": jnp.ones((*lead, d), config.dtype),
+        "mlp_norm": jnp.ones((*lead, d), config.dtype),
+        "wq": dense(next(keys), *lead, d, la.key_dim),
+        "wk": dense(next(keys), *lead, d, la.key_dim),
+        "wv": dense(next(keys), *lead, d, la.value_dim),
+        "wg": dense(next(keys), *lead, d, la.value_dim),
+        "wa": dense(next(keys), *lead, d, decays),
+        "wb": dense(next(keys), *lead, d, la.num_value_heads),
+        "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim),
+        "conv_v": conv(la.value_dim),
+        "a_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "o_norm": jnp.ones((*lead, la.value_head_dim), config.dtype),
+        "wo": dense(next(keys), *lead, la.value_dim, d),
+    }
+
+
+def _init_patterned_layers(config, key, dense, swiglu, attention, experts, shared) -> dict:
+    """``{kind: leaves of [periods, count in a period, ...]}`` for a
+    ``layer_pattern``; each kind draws from a split of its own, and a
+    mixture-of-experts MLP from a further one (a dense MLP goes on drawing
+    from the kind's: the weights a seed gave before experts could sit under
+    a pattern)."""
     periods = config.periods
     out = {}
     for number, kind in enumerate(dict.fromkeys(config.layer_pattern)):
         lead = (periods, config.layer_pattern.count(kind))
-        keys = iter(jax.random.split(jax.random.fold_in(key, number), 16))
+        kind_key = jax.random.fold_in(key, number)
+        keys = iter(jax.random.split(kind_key, 16))
         if kind == "full":
             leaves = attention(keys, *lead)
         else:
-            a = jax.random.uniform(next(keys), (*lead, la.num_value_heads), jnp.float32, 1e-3, 16.0)
-            dt = jnp.exp(jax.random.uniform(
-                next(keys), (*lead, la.num_value_heads), jnp.float32,
-                math.log(1e-3), math.log(1e-1),
-            ))
-            conv = lambda channels: jax.random.uniform(
-                next(keys), (*lead, la.conv_kernel, channels), jnp.float32,
-                -la.conv_kernel ** -0.5, la.conv_kernel ** -0.5,
-            ).astype(config.dtype)
-            leaves = {
-                "attn_norm": jnp.ones((*lead, d), config.dtype),
-                "mlp_norm": jnp.ones((*lead, d), config.dtype),
-                "wq": dense(next(keys), *lead, d, la.key_dim),
-                "wk": dense(next(keys), *lead, d, la.key_dim),
-                "wv": dense(next(keys), *lead, d, la.value_dim),
-                "wg": dense(next(keys), *lead, d, la.value_dim),
-                "wa": dense(next(keys), *lead, d, la.num_value_heads),
-                "wb": dense(next(keys), *lead, d, la.num_value_heads),
-                "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim),
-                "conv_v": conv(la.value_dim),
-                "a_log": jnp.log(a),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                "o_norm": jnp.ones((*lead, la.value_head_dim), config.dtype),
-                "wo": dense(next(keys), *lead, la.value_dim, d),
-            }
-        out[kind] = {**leaves, **swiglu(keys, *lead, width=config.hidden_dim)}
+            leaves = _linear_mixer_leaves(config, keys, dense, *lead)
+        if config.moe:
+            moe_keys = iter(jax.random.split(jax.random.fold_in(kind_key, 1), 8))
+            mlp = experts(moe_keys, *lead)
+            if config.moe.shared_experts:
+                mlp.update(shared(moe_keys, *lead))
+        else:
+            mlp = swiglu(keys, *lead, width=config.hidden_dim)
+        out[kind] = {**leaves, **mlp}
     return out
 
 
@@ -623,7 +776,8 @@ def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
     if config.attention == "reference":
         return gated_delta_rule_reference
     rows, gates = ("batch", None, None, None), ("batch", None, None)
-    return _per_data_shard(gated_delta_rule, (rows, rows, rows, gates, gates), rows)
+    decay = rows if config.linear.decay == "channel" else gates
+    return _per_data_shard(gated_delta_rule, (rows, rows, rows, decay, gates), rows)
 
 
 # The epsilon under the square root of q's and k's L2 norm.
@@ -642,6 +796,15 @@ def _linear_mixer(h, layer, config: TransformerConfig):
         o = gated_delta_rule(q, k, v, log alpha, beta)
         y = RMSNorm_{d_v}(o; o_norm) * SiLU(h W_g)              (per head)
         out = concat_i(y) W_o
+
+    Kimi Delta Attention's differences, each its own field of ``linear=``:
+    ``decay="channel"``, ``h W_a`` is ``[.., heads x d_k]`` and ``log alpha``
+    one a head AND key channel (``S_t = Diag(alpha_t) S_{t-1} + ...``:
+    ops/gated_delta_rule.py has the recurrence); ``gate_lower_bound=b``::
+
+        log alpha = b sigmoid(exp(a_log) (h W_a + dt_bias))     (in (b, 0))
+
+    and ``output_gate="sigmoid"``, ``y = RMSNorm(o) * sigmoid(h W_g)``.
 
     The convolutions and the delta rule are Mosaic kernels (per data shard
     under a mesh) unless ``attention="reference"``, which keeps both in
@@ -669,17 +832,31 @@ def _linear_mixer(h, layer, config: TransformerConfig):
             beta = jax.nn.sigmoid((h @ layer["wb"]).astype(f32))
             if la.allow_neg_eigval:
                 beta = 2.0 * beta
-            step = jax.nn.softplus((h @ layer["wa"]).astype(f32) + layer["dt_bias"].astype(f32))
-            log_alpha = -jnp.exp(layer["a_log"].astype(f32)) * step
+            rate = jnp.exp(layer["a_log"].astype(f32))
+            if la.decay == "channel":
+                # the projection's float32 accumulator is kept: exp(a_log) up to
+                # 16 and the bound multiply what rounding its result to the
+                # model dtype would lose into a decay off by percents
+                raw = jnp.matmul(h, layer["wa"], preferred_element_type=f32)
+                raw = (raw + layer["dt_bias"].astype(f32)).reshape(batch, seq, heads, la.key_head_dim)
+                rate = rate[:, None]
+            else:
+                raw = (h @ layer["wa"]).astype(f32) + layer["dt_bias"].astype(f32)
+            if la.gate_lower_bound is None:
+                log_alpha = -rate * jax.nn.softplus(raw)
+            else:
+                log_alpha = la.gate_lower_bound * jax.nn.sigmoid(rate * raw)
+            heads_first = (0, 2, 1, 3) if la.decay == "channel" else (0, 2, 1)
             o = _delta_rule_over_mesh(config)(
                 q, k, by_head(v, la.value_head_dim),
-                log_alpha.transpose(0, 2, 1), beta.transpose(0, 2, 1),
+                log_alpha.transpose(heads_first), beta.transpose(0, 2, 1),
             )
         with jax.named_scope("gate_norm"):
             o = o.transpose(0, 2, 1, 3)                          # [batch, seq, heads, d_v]
             gate = (h @ layer["wg"]).reshape(o.shape).astype(f32)
             y = rmsnorm_reference(o, layer["o_norm"], eps=config.rms_norm_eps)
-            y = (y.astype(f32) * jax.nn.silu(gate)).astype(h.dtype)
+            act = jax.nn.silu if la.output_gate == "silu" else jax.nn.sigmoid
+            y = (y.astype(f32) * act(gate)).astype(h.dtype)
         return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
 
 
@@ -705,6 +882,11 @@ def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
                 rep = config.n_heads // config.n_kv_heads
                 k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
             o = attention_fn(q, k, v, True)
+            if "wg_head" in layer:
+                with jax.named_scope("attn_gate"):
+                    gate = jax.nn.sigmoid((h @ layer["wg_head"]).astype(jnp.float32))
+                    gate = gate.transpose(0, 2, 1)[..., None]    # [batch, heads, seq, 1]
+                    o = (o.astype(jnp.float32) * gate).astype(o.dtype)
             o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
             out = o @ layer["wo"]
         if post:
@@ -808,6 +990,19 @@ def _weighted_sum(per_token, weights):
     return jnp.sum(weighted, axis=1).astype(per_token.dtype)
 
 
+def _within_best_groups(biased, moe: MoEConfig):
+    """DeepSeek-V3's group-limited choice on ``biased`` ``[tokens, experts]``
+    (``score + router_bias``): a group's score is the sum of its two
+    largest entries, the ``topk_group`` best groups stay, every other
+    group's entries become ``-inf``, so the top-k that follows is taken
+    inside the kept groups."""
+    by_group = biased.reshape(biased.shape[0], moe.n_group, -1)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)              # [T, G]
+    _, best = jax.lax.top_k(group_score, moe.topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(moe.n_group, dtype=best.dtype), axis=1)
+    return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(biased.shape)
+
+
 def _moe_mlp(h, layer, config: TransformerConfig):
     """Dropless mixture of experts: every token reaches each of its
     ``top_k`` experts whatever the routing. Returns ``(out, routing)``.
@@ -827,7 +1022,17 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     the tokens that chose e times the mean over the sequence of e's
     normalised score, summed over sequences), ``counts`` [top_k, experts]
     (tokens whose j-th choice is expert e), ``experts`` and ``weights``
-    [tokens, top_k] (the choices and their weights).
+    [tokens, top_k] (the choices and their weights), and under ``held``
+    ``held_pairs`` (the pairs whose expert lives here).
+
+    With ``moe.held`` the router still scores and chooses among ALL
+    ``num_experts``; the pairs are sorted by the held experts' own numbers
+    with every absent pair behind them, the grouped matmuls run over the
+    held groups alone (rows behind the last group are touched by no tile)
+    and those rows are zero going in and coming out, so an absent pair adds
+    nothing to the sum and nothing to a gradient. The row buffers keep the
+    worst case's size, ``tokens x top_k``: exact for every routing, one
+    program; nothing stands in for the chips that hold the other experts.
 
     One device's view: ``h`` and the experts are whole here. Under a mesh
     ``_moe_over_mesh`` calls this once per data shard.
@@ -846,8 +1051,10 @@ def _moe_mlp(h, layer, config: TransformerConfig):
         logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
         if moe.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)                      # [T, E]
-            bias = jax.lax.stop_gradient(layer["router_bias"])
-            _, experts = jax.lax.top_k(scores + bias, moe.top_k)
+            biased = scores + jax.lax.stop_gradient(layer["router_bias"])
+            if moe.n_group > 1:
+                biased = _within_best_groups(biased, moe)
+            _, experts = jax.lax.top_k(biased, moe.top_k)
             weights = jnp.take_along_axis(scores, experts, axis=-1)
         else:
             scores = jax.nn.softmax(logits, axis=-1)             # [T, E]
@@ -870,10 +1077,21 @@ def _moe_mlp(h, layer, config: TransformerConfig):
         }
     with jax.named_scope("dispatch"):
         pairs = jnp.arange(tokens * moe.top_k, dtype=jnp.int32)
-        _, order = jax.lax.sort((experts.reshape(-1), pairs), num_keys=1, is_stable=True)
-        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
         group_sizes = jnp.sum(counts, axis=0)
+        sort_by = experts
+        if moe.held:
+            first, held = moe.held
+            here = (experts >= first) & (experts < first + held)
+            sort_by = jnp.where(here, experts - first, held)    # absent pairs last
+            group_sizes = group_sizes[first:first + held]
+            routing["held_pairs"] = jnp.sum(group_sizes)
+            # [T*K, 1]: the rows some held expert's group covers
+            covered = (pairs < routing["held_pairs"])[:, None]
+        _, order = jax.lax.sort((sort_by.reshape(-1), pairs), num_keys=1, is_stable=True)
+        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
         rows = _rows_by_expert(moe.top_k, ht, order, inverse)    # [T*K, d]
+        if moe.held:
+            rows = jnp.where(covered, rows, 0)
     in_stack = layer.get("stack", {})
 
     def expert(rows, name):
@@ -882,6 +1100,10 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     with jax.named_scope("experts"):
         out = expert(_silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
     with jax.named_scope("dispatch"):
+        if moe.held:
+            # no tile wrote the rows behind the last group, and none of their
+            # cotangents: both selects keep what is there out of the sums
+            out = jnp.where(covered, out, 0)
         per_token = _rows_by_token(out, order, inverse)
         out = _weighted_sum(per_token.reshape(tokens, moe.top_k, d), weights.astype(h.dtype))
     return out.reshape(batch, seq, d), routing
@@ -914,8 +1136,9 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     def per_shard(h, experts):
         out, routing = _moe_mlp(h, experts, config)
         if shards:
-            for name in ("prob_sum", "counts"):
-                routing[name] = jax.lax.psum(routing[name], shards)
+            for name in ("prob_sum", "counts", "held_pairs"):
+                if name in routing:
+                    routing[name] = jax.lax.psum(routing[name], shards)
         return out, routing
 
     whole = jax.sharding.PartitionSpec()
@@ -930,6 +1153,7 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
         out_specs=(rows, {
             "prob_sum": whole, "counts": whole,
             "experts": per_token, "weights": per_token,
+            **({"held_pairs": whole} if config.moe.held else {}),
         }),
         check_vma=False,
     )(h, experts)
@@ -1017,16 +1241,41 @@ def _scan_periods(step, carry, layers, pattern):
     runs ``step(carry, layer)`` for the period's layers in ``pattern``'s
     order, each layer the next of its kind. ``step`` is the one (possibly
     checkpointed) layer step of every other model, so a period keeps what
-    one layer keeps, once a layer."""
-    def body(carry, period):
+    one layer keeps, once a layer.
+
+    Over mixture-of-experts layers the body also hands out each layer's
+    ``routing``, stacked in the layers' order (``[periods x period, ...]``
+    as ``_scan_layers`` stacks them; None over dense MLPs), and the expert
+    kernels read a layer's weights where they lie, as there: the body closes
+    over each kind's expert stacks as ``[periods x count, experts, k, n]``
+    (a bitcast) under ``stop_gradient`` and finds layer ``period x count +
+    number`` in them."""
+    stacks = {
+        kind: {
+            name: jax.lax.stop_gradient(leaves[name]).reshape(-1, *leaves[name].shape[2:])
+            for name in (_EXPERT_WEIGHTS if "router" in leaves else ())
+        }
+        for kind, leaves in layers.items()
+    }
+
+    def body(carry, scanned):
+        index, period = scanned
         taken = dict.fromkeys(period, 0)
+        routings = []
         for kind in pattern:
             number = taken[kind]
             taken[kind] += 1
-            carry, _ = step(carry, jax.tree.map(lambda leaf: leaf[number], period[kind]))
-        return carry, None
+            layer = jax.tree.map(lambda leaf: leaf[number], period[kind])
+            if stacks[kind]:
+                at = index * pattern.count(kind) + number
+                layer["stack"] = {name: (stack, at) for name, stack in stacks[kind].items()}
+            carry, routing = step(carry, layer)
+            routings.append(routing)
+        return carry, jax.tree.map(lambda *leaves: jnp.stack(leaves), *routings)
 
-    return jax.lax.scan(body, carry, layers)
+    periods = next(iter(jax.tree.leaves(layers))).shape[0]
+    carry, routing = jax.lax.scan(body, carry, (jnp.arange(periods, dtype=jnp.int32), layers))
+    return carry, jax.tree.map(lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), routing)
 
 
 def _embed(params, tokens):
@@ -1100,10 +1349,10 @@ def _hidden_with_routing(params, tokens, config, positions=None):
             layer_step, policy=_remat_policy(config.remat)
         )
 
-    if config.layer_pattern:
-        return _scan_periods(layer_step, x, params["layers"], config.layer_pattern)
     if "dense_layers" in params:
         x, _ = _scan_layers(layer_step, x, params["dense_layers"])
+    if config.layer_pattern:
+        return _scan_periods(layer_step, x, params["layers"], config.layer_pattern)
     return _scan_layers(layer_step, x, params["layers"])
 
 
@@ -1292,34 +1541,44 @@ def num_params(params: dict) -> int:
 
 def config_num_params(config: TransformerConfig) -> int:
     """Parameter count from shapes alone — lets the memory-budget check
-    refuse a config before any array is materialized."""
+    refuse a config before any array is materialized. Of a mixture of
+    experts it counts the experts HELD here (``MoEConfig.held``)."""
     d = config.dim
-    attn = (
+    # the mixers, each with the layer's two block norms
+    mixer = {"full": (
         sum(math.prod(shape) for shape in _projection_shapes(config).values())
         + sum(_norm_shapes(config).values())
-    )
+    )}
+    if config.linear:
+        la = config.linear
+        decays = la.key_dim if la.decay == "channel" else la.num_value_heads
+        mixer["linear"] = (
+            2 * d * la.key_dim + 3 * d * la.value_dim            # wq wk, wv wg wo
+            + d * decays + d * la.num_value_heads                # wa, wb
+            + la.conv_kernel * (2 * la.key_dim + la.value_dim)   # the three filters
+            + la.num_value_heads + decays + la.value_head_dim    # a_log, dt_bias, o_norm
+            + 2 * d                                              # the two block norms
+        )
     dense_mlp = 3 * d * config.hidden_dim
     if config.moe:
         moe = config.moe
-        e = moe.num_experts
-        mlp = d * e + 3 * d * _expert_dim(config) * (e + moe.shared_experts)
+        mlp = (
+            d * moe.num_experts
+            + 3 * d * _expert_dim(config) * (moe.num_held + moe.shared_experts)
+        )
         if moe.scoring == "sigmoid":
-            mlp += e  # router_bias
+            mlp += moe.num_experts  # router_bias
     else:
         mlp = dense_mlp
     prefix = config.first_dense_layers
-    layers = (config.n_layers - prefix) * (attn + mlp) + prefix * (attn + dense_mlp)
-    if config.layer_pattern and "linear" in config.layer_pattern:
-        la = config.linear
-        mixer = (
-            2 * d * la.key_dim + 3 * d * la.value_dim            # wq wk, wv wg wo
-            + 2 * d * la.num_value_heads                         # wa, wb
-            + la.conv_kernel * (2 * la.key_dim + la.value_dim)   # the three filters
-            + 2 * la.num_value_heads + la.value_head_dim         # a_log, dt_bias, o_norm
-            + 2 * d                                              # the two block norms
+    if config.layer_pattern:
+        layers = prefix * (mixer[config.first_dense_kind] + dense_mlp) + config.periods * sum(
+            mixer[kind] + mlp for kind in config.layer_pattern
         )
-        linear_layers = config.periods * config.layer_pattern.count("linear")
-        layers += linear_layers * (mixer + dense_mlp - attn - mlp)
+    else:
+        layers = (config.n_layers - prefix) * (mixer["full"] + mlp) + prefix * (
+            mixer["full"] + dense_mlp
+        )
     return (
         layers
         + 2 * config.vocab_size * d  # embed + lm_head
@@ -1331,10 +1590,10 @@ def linear_state_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
     """Bytes the delta-rule scan kernels of one training step keep for the
     backward (chunk-start states and outputs, every linear layer): 0 for a
     model with no linear layer."""
-    if not config.layer_pattern or "linear" not in config.layer_pattern:
+    layers = config.linear_layers()
+    if not layers:
         return 0
     la = config.linear
-    layers = config.periods * config.layer_pattern.count("linear")
     return layers * kept_bytes(
         batch, la.num_value_heads, seq, la.value_head_dim, jnp.dtype(config.dtype).itemsize
     )

@@ -12,8 +12,14 @@ Per head, with a state ``S`` of ``[d_k, d_v]`` that starts at zero, a decay
 (``S`` here is the transpose of the ``[d_v, d_k]`` state of the papers; q
 arrives scaled, k arrives normalised: the caller's business.)
 
+A decay per CHANNEL (Kimi Delta Attention, arXiv:2510.26692): ``log_alpha``
+arrives as ``[.., seq, d_k]``, one decay a key channel, and ``alpha_t`` above
+becomes ``Diag(e^{g_t})``, scaling the state's ROWS::
+
+    S_t = Diag(e^{g_t}) S_{t-1} + k_t (beta_t (v_t - (Diag(e^{g_t}) S_{t-1})^T k_t))^T
+
 ``gated_delta_rule_reference`` is that recurrence as a ``lax.scan``, one
-token a step, float32: the CPU path of the model and the oracle.
+token a step, float32, either decay: the CPU path of the model and the oracle.
 
 ``gated_delta_rule`` is the chunked form (Yang et al., "Gated Delta
 Networks", the WY representation). A sequence is cut into chunks of
@@ -65,6 +71,24 @@ carries ``RESIDUAL_NAMES`` (checkpoint_name): a layer checkpoint whose
 policy saves that name keeps ``O`` for the layers after and runs no kernel
 for it again. ``kept_bytes`` counts what is kept.
 
+The channel decay in chunks, ``G`` now ``[chunk, d_k]``: the decay no longer
+factors out of the dot products, ``A[t, i] = beta_t sum_c k_tc k_ic e^{G_tc -
+G_ic}``, ``P[t, i] = sum_c q_tc k_ic e^{G_tc - G_ic}``; ``W = T (beta e^G .
+K)``, ``Qg = e^G . Q``, ``Kd = e^{G_C - G} . K`` as before with ``.`` per
+channel, and ``gamma = e^{G_C}`` a VECTOR over ``d_k``. ``A`` and ``P`` stay
+matmuls and must not overflow (``_prepare_channel``): the chunk is cut into
+sub-blocks of ``_SUB_CHUNK`` = 16 rows, and row block ``r`` multiplies ``x_t
+. e^{G_t - R_r}`` (``R_r`` the ``G`` of its first token: exponent <= 0) by
+``k_i . e^{R_r - G_i}``, whose exponent is <= 0 for every column before the
+block and at most ``15 |g|`` inside it. That is why the caller's decay must
+be BOUNDED below: at ``g >= -5`` a token and channel (the published
+``kda_lower_bound``) the largest factor is ``e^75``, under float32's ``e^88``,
+where the unsplit ``e^{G_t} . e^{-G_i}`` overflows inside one chunk. The
+preparation is XLA's in this form, differentiated by jax under scope
+``decay_prepare`` (a Mosaic kernel of it is not written yet); the scan
+kernels are the same two, told by ``gamma``'s shape to scale the state's
+rows: the scalar path's text is unchanged.
+
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
 """
@@ -113,15 +137,16 @@ _PREPARE_PRECISION = _HIGHEST
 def gated_delta_rule_reference(q, k, v, log_alpha, beta):
     """The recurrence of the module docstring, one token a step, float32.
     q, k: [batch, heads, seq, d_k]; v: [batch, heads, seq, d_v]; log_alpha,
-    beta: [batch, heads, seq]. Returns [batch, heads, seq, d_v] in v's
-    dtype."""
+    beta: [batch, heads, seq], or ``log_alpha`` [batch, heads, seq, d_k]: a
+    decay per key channel. Returns [batch, heads, seq, d_v] in v's dtype."""
     f32 = jnp.float32
     batch, heads, _, d_k = q.shape
     d_v = v.shape[-1]
+    channel = log_alpha.ndim == q.ndim
 
     def step(state, x):
         q_t, k_t, v_t, g_t, b_t = x
-        state = state * jnp.exp(g_t)[..., None, None]
+        state = state * (jnp.exp(g_t)[..., :, None] if channel else jnp.exp(g_t)[..., None, None])
         read = jnp.einsum("bhkv,bhk->bhv", state, k_t, precision=_HIGHEST)
         write = b_t[..., None] * (v_t - read)
         state = state + k_t[..., :, None] * write[..., None, :]
@@ -180,14 +205,18 @@ def _unit_lower_inverse(a):
     ``_prepare_backward_kernel``."""
     size = a.shape[-1]
     at = jnp.arange(size)
-    joined = lambda block: (at[:, None] // block) == (at[None, :] // block)
-    inverse = jnp.eye(size, dtype=a.dtype) - jnp.where(joined(2), a, 0.0)
-    block = 2
-    while block < size:
-        joining = jnp.where(joined(2 * block) & ~joined(block), a, 0.0)
-        inverse = inverse - _matmul(inverse, _matmul(joining, inverse))
-        block *= 2
-    return inverse
+    # rows and columns in one block of 2^level
+    joined = lambda level: (at[:, None] >> level) == (at[None, :] >> level)
+
+    def doubled(level, inverse):
+        joining = jnp.where(joined(level + 1) & ~joined(level), a, 0.0)
+        return inverse - _matmul(inverse, _matmul(joining, inverse))
+
+    # ONE level's two products in the compiled program, walked by a loop: ten
+    # unrolled at a chunk of 64 are 6.5 MiB of a v5e's generated code each
+    # time the inverse is taken (PERF.md section 6, PR 36)
+    inverse = jnp.eye(size, dtype=a.dtype) - jnp.where(joined(1), a, 0.0)
+    return jax.lax.fori_loop(1, max(size - 1, 1).bit_length(), doubled, inverse)
 
 
 def _unit_lower_inverse_fwd(a):
@@ -241,6 +270,79 @@ def _prepare(q, k, v, log_alpha, beta, chunk: int):
     return flat(w), flat(u0), flat(qg), flat(p), flat(kd), gamma
 
 
+# Rows of one sub-block of a chunk under a decay per channel: inside it the
+# key side's exponent reaches ``(_SUB_CHUNK - 1) |g|``, 75 at the bound of -5
+# a token, under float32's 88.
+_SUB_CHUNK = 16
+
+
+def _prepare_channel(q, k, v, log_alpha, beta, chunk: int):
+    """``_prepare`` under a decay per channel: the six operands of the scan
+    (``gamma``: ``[heads, chunks, 1, d_k]``) from q, k, ``log_alpha``
+    ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta`` ``[heads,
+    seq]``; ``seq`` a multiple of ``chunk``, ``chunk`` of ``_SUB_CHUNK``.
+
+    ``A`` and ``P`` are matmuls over the channels of operands that carry
+    the decay, split at a reference so that neither side overflows: row
+    block ``r`` (``_SUB_CHUNK`` tokens) takes the ``G`` of its first token,
+    ``R_r``; its rows are ``x_t . e^{G_t - R_r}`` (exponent <= 0) and its
+    columns ``k_i . e^{R_r - G_i}`` for ``i`` up to the block's end (<= 0
+    before the block, at most ``(_SUB_CHUNK - 1) |g|`` inside it), nothing
+    after. No ``[chunk, chunk, d_k]`` array exists: the columns are
+    ``chunk / _SUB_CHUNK`` decayed copies of ``K``.
+
+    On the chip the rule reads 1.4e-4 to 4.6e-4 of the recurrence's output
+    at the Ling cell's gates, where the scalar rule reads 3e-5, and NOT
+    because of this split: sub-blocks of 8, 16 or 32 rows, and exponents
+    summed span by span in place of differences of running sums, all read
+    the same to five digits (1.379e-4 on one seed). The rows a sub-block
+    takes were chosen for time and for the bound: value and gradient of the
+    rule alone at ``[32, 16384, 128]`` take 87 ms at 16 rows, 104 at 8, 77
+    at 32 (whose ``31 x 5`` passes 88), 113 with the summed spans (my chip
+    runs, PR 36)."""
+    f32 = jnp.float32
+    bh, seq, d_k = q.shape
+    chunks = seq // chunk
+    sub = min(_SUB_CHUNK, chunk)
+    blocks = chunk // sub
+
+    def by_chunk(x):
+        return x.astype(f32).reshape(bh, chunks, chunk, *x.shape[2:])
+
+    q, k, v, g, beta = (by_chunk(x) for x in (q, k, v, log_alpha, beta))
+    total = jnp.cumsum(g, axis=2)                                # G_t [bh, chunks, chunk, d_k]
+    first = total.reshape(bh, chunks, blocks, sub, d_k)[:, :, :, 0]          # R_r
+    rows = jnp.exp(total - jnp.repeat(first, sub, axis=2))       # e^{G_t - R_r(t)}
+    steps = jnp.arange(chunk)
+    upto = steps[None, :] < (jnp.arange(blocks)[:, None] + 1) * sub           # [blocks, chunk]
+    # masked BEFORE the exp, as in ``_prepare``: no inf, no nan behind a where
+    exponent = jnp.where(
+        upto[:, :, None], first[:, :, :, None, :] - total[:, :, None, :, :], -jnp.inf
+    )
+    columns = jnp.exp(exponent) * k[:, :, None]                  # [bh, chunks, blocks, chunk, d_k]
+
+    def decayed_products(x):
+        """``sum_c x_tc k_ic e^{G_tc - G_ic}`` ``[.., chunk, chunk]``, valid
+        for ``i`` up to the end of ``t``'s block."""
+        by_block = (x * rows).reshape(bh, chunks, blocks, sub, d_k)
+        out = jnp.einsum("...rtc,...ric->...rti", by_block, columns, precision=_PREPARE_PRECISION)
+        return out.reshape(bh, chunks, chunk, chunk)
+
+    strictly = steps[:, None] > steps[None, :]
+    a = jnp.where(strictly, beta[..., None] * decayed_products(k), 0.0)
+    t = _unit_lower_inverse(a)
+    grown = jnp.exp(total)                                       # e^{G_t}
+    w = _matmul(t, beta[..., None] * grown * k)
+    u0 = _matmul(t, beta[..., None] * v)
+    qg = grown * q
+    p = jnp.where(~strictly.T, decayed_products(q), 0.0)         # i <= t
+    last = total[:, :, -1:, :]
+    kd = jnp.exp(last - total) * k
+    gamma = jnp.exp(last)                                        # [bh, chunks, 1, d_k]
+    flat = lambda x: x.reshape(bh, seq, x.shape[-1])
+    return flat(w), flat(u0), flat(qg), flat(p), flat(kd), gamma
+
+
 def _scan_reference(w, u0, qg, p, kd, gamma, chunk: int, out_dtype):
     """The scan over chunks in plain jax.numpy: what the two kernels are
     held to operand by operand, and the chunked form with no kernel."""
@@ -252,7 +354,8 @@ def _scan_reference(w, u0, qg, p, kd, gamma, chunk: int, out_dtype):
         w, u0, qg, p, kd, gamma = x
         u = u0 - _matmul(w, state)
         out = _matmul(qg, state) + _matmul(p, u)
-        return gamma * state + _matmul(jnp.swapaxes(kd, 1, 2), u), out
+        # [.., 1, 1], or a channel decay's [.., 1, d_k] down the state's rows
+        return jnp.swapaxes(gamma, 1, 2) * state + _matmul(jnp.swapaxes(kd, 1, 2), u), out
 
     _, out = jax.lax.scan(
         step, jnp.zeros((bh, d_k, d_v), _STATE_DTYPE),
@@ -279,6 +382,28 @@ _NT = ((1,), (1,))      # a b^T
 _TN = ((0,), (0,))      # a^T b
 
 
+def _eye(n: int):
+    at = lambda dim: jax.lax.broadcasted_iota(jnp.int32, (n, n), dim)
+    return at(0) == at(1)
+
+
+def _down(row):
+    """``[1, n]`` -> ``[n, 1]``: broadcast, masked to the diagonal, summed
+    along the lanes (``_Masks.down``'s exact turn, no matmul)."""
+    return jnp.sum(jnp.where(_eye(row.shape[-1]), row, 0.0), axis=1, keepdims=True)
+
+
+def _across(column):
+    """``[n, 1]`` -> ``[1, n]``, as ``_down``."""
+    return jnp.sum(jnp.where(_eye(column.shape[0]), column, 0.0), axis=0, keepdims=True)
+
+
+def _decayed(gamma, state):
+    """``gamma S``: ``gamma`` the ``[1, 1]`` decay of a head, or the ``[1,
+    d_k]`` row of a decay per channel, which scales ``S``'s rows."""
+    return (gamma if gamma.shape[-1] == 1 else _down(gamma)) * state
+
+
 def _forward_kernel(w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref,
                     wanted_ref, state, *, chunk, per_step, states):
     """``wanted_ref``: the output ``[1, rows, d_v]``, or with ``states`` the
@@ -300,7 +425,9 @@ def _forward_kernel(w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref,
                 p_ref[0, rows, :], u.astype(p_ref.dtype), _NN
             )
             wanted_ref[0, rows, :] = out.astype(wanted_ref.dtype)
-        grown = gamma_ref[0, c] * s + _dot(kd_ref[0, rows, :], u.astype(kd_ref.dtype), _TN)
+        grown = _decayed(gamma_ref[0, c], s) + _dot(
+            kd_ref[0, rows, :], u.astype(kd_ref.dtype), _TN
+        )
         state[...] = grown.astype(state.dtype)
 
 
@@ -329,11 +456,13 @@ def _backward_kernel(w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, states_ref
         dp_ref[0, rows, :] = _dot(do, u, _NT).astype(dp_ref.dtype)
         dkd_ref[0, rows, :] = _dot(u, ds, _NT).astype(dkd_ref.dtype)
         product = states_ref[0, c].astype(jnp.float32) * dstate[...].astype(jnp.float32)
-        dgamma_ref[0, c] = jnp.sum(
-            jnp.sum(product, axis=1, keepdims=True), axis=0, keepdims=True
+        by_row = jnp.sum(product, axis=1, keepdims=True)
+        dgamma_ref[0, c] = (
+            jnp.sum(by_row, axis=0, keepdims=True) if gamma_ref.shape[-1] == 1
+            else _across(by_row)
         )
         before = (
-            _dot(qg, do, _TN) + gamma_ref[0, c] * dstate[...].astype(jnp.float32)
+            _dot(qg, do, _TN) + _decayed(gamma_ref[0, c], dstate[...].astype(jnp.float32))
             - _dot(w, du, _TN)
         )
         dstate[...] = before.astype(dstate.dtype)
@@ -378,7 +507,7 @@ def _delta_rule_forward(w, u0, qg, p, kd, gamma, *, chunk, interpret, out_dtype,
         grid=(bh, chunks // per_step),
         in_specs=[
             *_specs(chunk, per_step, (d_k, d_v, d_k, chunk, d_k), forward),
-            pl.BlockSpec((1, per_step, 1, 1), lambda i, n: (i, n, 0, 0)),
+            pl.BlockSpec((1, per_step, 1, gamma.shape[-1]), lambda i, n: (i, n, 0, 0)),
         ],
         out_specs=out_spec,
         out_shape=out_shape,
@@ -407,11 +536,13 @@ def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, *, chunk, interp
         grid=(bh, steps),
         in_specs=[
             *_specs(chunk, per_step, widths, backward),
-            per_chunk(1, 1),
+            per_chunk(1, gamma.shape[-1]),
             per_chunk(d_k, d_v),
             *_specs(chunk, per_step, (d_v,), backward),
         ],
-        out_specs=[*_specs(chunk, per_step, widths, backward), per_chunk(1, 1)],
+        out_specs=[
+            *_specs(chunk, per_step, widths, backward), per_chunk(1, gamma.shape[-1]),
+        ],
         out_shape=[
             *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (w, u0, qg, p, kd, gamma)),
         ],
@@ -745,6 +876,41 @@ def _chunked_bwd(chunk, interpret, inputs, dout):
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
+def _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret):
+    with jax.named_scope("decay_prepare"):
+        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk)
+    return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
+
+
+# ``_chunked`` under a decay per channel: XLA's preparation (scope
+# ``decay_prepare``) around the same scan kernels.
+_chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5, 6))
+
+
+def _chunked_channel_fwd(q, k, v, log_alpha, beta, chunk, interpret):
+    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret)
+    return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, log_alpha, beta)
+
+
+def _chunked_channel_bwd(chunk, interpret, inputs, dout):
+    """As ``_chunked_bwd``, nothing kept but the inputs: the preparation runs
+    again under ``jax.vjp``, the forward kernel for the chunk-start states,
+    the backward kernel, then the preparation's transpose."""
+    with jax.named_scope("decay_prepare"):
+        operands, transpose = jax.vjp(
+            lambda *inputs: _prepare_channel(*inputs, chunk), *inputs
+        )
+    states = _delta_rule_forward(
+        *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
+    )
+    grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
+    with jax.named_scope("decay_prepare"):
+        return transpose(tuple(grads))
+
+
+_chunked_channel.defvjp(_chunked_channel_fwd, _chunked_channel_bwd)
+
+
 def _heads_per_call(heads: int, seq: int) -> int:
     """How many (batch x head) rows one group of kernel calls takes: the
     most that divide ``heads`` with ``rows x seq`` under
@@ -783,7 +949,9 @@ def gated_delta_rule(
     [batch, heads, seq, d_v]`` and ``log_alpha, beta [batch, heads, seq]``
     (``log_alpha <= 0``), as chunks of ``chunk`` tokens (None: ``CHUNK``,
     or the sequence where that is shorter): ``[batch, heads, seq, d_v]`` in
-    v's dtype, differentiable in all five.
+    v's dtype, differentiable in all five. ``log_alpha [batch, heads, seq,
+    d_k]`` is a decay per key channel (the module docstring has what
+    changes); it must be bounded below, ``15 |log_alpha| < 88`` a token.
 
     A sequence that is no multiple of the chunk is padded at its end with
     tokens that write nothing (``beta`` 0, ``log_alpha`` 0). Heads need
@@ -798,10 +966,14 @@ def gated_delta_rule(
     padded = -(-seq // chunk) * chunk
     interpret = resolve_interpret(interpret)
 
+    channel = log_alpha.ndim == q.ndim
+
     def one_call(q, k, v, *gates):
         if kernels:
-            return _chunked(q, k, v, *gates, chunk, interpret)
-        return _scan_reference(*_prepare(q, k, v, *gates, chunk), chunk, v.dtype)
+            chunked = _chunked_channel if channel else _chunked
+            return chunked(q, k, v, *gates, chunk, interpret)
+        prepare = _prepare_channel if channel else _prepare
+        return _scan_reference(*prepare(q, k, v, *gates, chunk), chunk, v.dtype)
 
     rows = batch * heads
     per_call = _heads_per_call(rows, padded)
@@ -812,7 +984,7 @@ def gated_delta_rule(
 
     q, k, v, log_alpha, beta = (flat(x) for x in (q, k, v, log_alpha, beta))
     # the running sums are taken once, over every head, not once a group
-    gates = (_gates(log_alpha, beta, chunk),) if kernels else (log_alpha, beta)
+    gates = (_gates(log_alpha, beta, chunk),) if kernels and not channel else (log_alpha, beta)
     groups = tuple(
         x.reshape(rows // per_call, per_call, *x.shape[1:]) for x in (q, k, v, *gates)
     )

@@ -1,0 +1,161 @@
+"""The delta rule under a decay per key CHANNEL (Kimi Delta Attention;
+``ops/gated_delta_rule.py`` with ``log_alpha`` ``[batch, heads, seq, d_k]``):
+the chunked form (XLA's sub-chunked preparation, ``_prepare_channel``, around
+the two scan kernels told by ``gamma``'s shape to scale the state's rows)
+against the per-token recurrence, output and every operand's gradient, on
+the CPU in float32 with the kernels interpreted.
+
+The decays are drawn where the chunked form is hardest: AT the published
+bound (-5 a token and channel for a whole chunk of 64: ``G`` reaches -320
+inside it, ``e^{-G}`` overflows float32 after 18 tokens) and near 0. The
+unsplit product ``(x . e^G)(k . e^{-G})^T`` is computed beside it and must
+FAIL there; the sub-chunked one must not. The scalar path's kernels are the
+parent's: they never touch what the channel path added.
+"""
+
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import gated_delta_rule as gdr
+from ray_tpu.ops.gated_delta_rule import gated_delta_rule, gated_delta_rule_reference
+
+BOUND = -5.0
+
+
+def operands(seed, batch=1, heads=2, seq=200, d_k=32, d_v=48, decays="mixed"):
+    """q scaled, k normalised, ``beta`` in (0, 1), ``g`` in (BOUND, 0) a head,
+    token and channel: ``mixed`` draws it as the model does, ``bound`` pins
+    the second chunk of 64 to the bound, the third to ``1e-3 x`` its draw
+    (near 0) and leaves the rest mixed."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (batch, heads, seq, d_k))) * d_k ** -0.5
+    k = unit(jax.nn.silu(jax.random.normal(keys[1], (batch, heads, seq, d_k))))
+    v = jax.random.normal(keys[2], (batch, heads, seq, d_v))
+    g = BOUND * jax.nn.sigmoid(2.0 * jax.random.normal(keys[3], (batch, heads, seq, d_k)))
+    if decays == "bound":
+        g = g.at[:, :, 64:128].set(BOUND).at[:, :, 128:192].multiply(1e-3)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, heads, seq)))
+    return q, k, v, g, beta
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.all(np.isfinite(got))
+    return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+
+
+@pytest.mark.parametrize("decays", ["mixed", "bound"])
+@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "plain-scan"])
+def test_chunks_match_the_recurrence_in_output_and_every_gradient(decays, kernels):
+    args = operands(0, decays=decays)
+    want = gated_delta_rule_reference(*args)
+    got = gated_delta_rule(*args, kernels=kernels)
+    assert got.shape == want.shape == (1, 2, 200, 48)           # 200 is no multiple of 64
+    assert rel(got, want) < 5e-6
+    weigh = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    loss = lambda rule: lambda *a: jnp.sum(rule(*a) * weigh)
+    want_grads = jax.grad(loss(gated_delta_rule_reference), argnums=(0, 1, 2, 3, 4))(*args)
+    got_grads = jax.grad(
+        loss(lambda *a: gated_delta_rule(*a, kernels=kernels)), argnums=(0, 1, 2, 3, 4)
+    )(*args)
+    for name, mine, theirs in zip(("q", "k", "v", "log_alpha", "beta"), got_grads, want_grads):
+        assert mine.shape == theirs.shape, name
+        assert rel(mine, theirs) < 2e-5, (name, rel(mine, theirs))
+
+
+def _unsplit(x, k, total):
+    """``sum_c x_tc k_ic e^{G_tc - G_ic}`` as ONE matmul of ``x . e^G`` and
+    ``k . e^{-G}`` over a whole chunk: the form that must fail."""
+    return jnp.einsum(
+        "...tc,...ic->...ti", x * jnp.exp(total), k * jnp.exp(-total), precision="highest"
+    )
+
+
+def _exact(x, k, total):
+    """The same sum term by term, float64-free but overflow-free: every
+    exponent is a difference taken BEFORE the exp, ``[chunk, chunk, d_k]``."""
+    gap = total[..., :, None, :] - total[..., None, :, :]
+    lower = jnp.tril(jnp.ones(gap.shape[-3:-1], bool))[..., None]
+    return jnp.sum(
+        x[..., :, None, :] * k[..., None, :, :] * jnp.exp(jnp.where(lower, gap, -jnp.inf)), axis=-1
+    )
+
+
+def test_at_the_bound_the_unsplit_product_fails_and_the_subchunked_one_does_not():
+    _, k, _, g, _ = operands(1, decays="bound")
+    chunk = slice(64, 128)                                       # the chunk AT the bound
+    k, total = k[0, :, chunk], jnp.cumsum(g[0, :, chunk], axis=1)
+    assert float(total.min()) == 64 * BOUND
+    want = _exact(k, k, total)
+    lower = np.tril(np.ones((64, 64), bool))
+    unsplit = np.asarray(_unsplit(k, k, total))
+    # e^{-G} overflows from the 18th token on (18 x 5 > 88): inf x 0 = nan
+    assert not np.all(np.isfinite(unsplit[:, lower]))
+    # the operands of the scan, all six, through the sub-chunked preparation
+    q, k4, v, g4, beta = operands(1, decays="bound")
+    flat = lambda x: x.reshape(2, 200, *x.shape[3:])[:, :192]
+    prepared = gdr._prepare_channel(flat(q), flat(k4), flat(v), flat(g4), flat(beta), 64)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in prepared)
+    # and its products ARE the exact ones, strictly below the diagonal: A / beta
+    w, u0, qg, p, kd, gamma = prepared
+    want_p = _exact(flat(q)[:, 64:128], flat(k4)[:, 64:128], total)
+    got_p = np.asarray(p[:, 64:128])
+    assert np.max(np.abs(got_p[:, lower] - np.asarray(want_p)[:, lower])) < 1e-6
+    assert np.max(np.abs(np.asarray(want)[:, lower])) > 0.1      # not a comparison of zeros
+    assert gamma.shape == (2, 3, 1, 32) and float(gamma[:, 1].max()) == 0.0   # e^-320
+    # gradients through the chunk at the bound are finite too
+    grads = jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)), argnums=(0, 1, 2, 3, 4)
+    )(*operands(1, decays="bound"))
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in grads)
+
+
+def test_a_decay_equal_in_every_channel_is_the_scalar_rule():
+    q, k, v, g, beta = operands(2, d_k=16, d_v=24, seq=130)
+    scalar = g[..., 0]
+    same = jnp.broadcast_to(scalar[..., None], g.shape)
+    want = gated_delta_rule(q, k, v, scalar, beta)
+    assert rel(gated_delta_rule(q, k, v, same, beta), want) < 2e-6
+    assert rel(gated_delta_rule_reference(q, k, v, same, beta),
+               gated_delta_rule_reference(q, k, v, scalar, beta)) < 1e-6
+
+
+@pytest.mark.parametrize("d_k,d_v", [(96, 192)])
+def test_the_scalar_path_never_touches_what_the_channel_path_added(d_k, d_v):
+    """Olmo-Hybrid's head shapes through forward and backward with the two
+    helpers of the vector ``gamma`` made to raise: the scalar kernels are the
+    parent's text (``gamma_ref[0, c] * s``, a ``[1, 1]`` broadcast), bitwise
+    what they gave before, and only a decay per channel reaches the helpers."""
+    q, k, v, g, beta = operands(3, heads=2, seq=128, d_k=d_k, d_v=d_v)
+    scalar = g[..., 0]
+    loss = lambda *a: jnp.sum(gated_delta_rule(*a) ** 2)
+    before = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(q, k, v, scalar, beta)
+
+    def refuse(_x):
+        raise AssertionError("the scalar path reached a helper of the channel path")
+
+    jax.clear_caches()
+    with mock.patch.object(gdr, "_down", refuse), mock.patch.object(gdr, "_across", refuse):
+        after = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))(q, k, v, scalar, beta)
+        with pytest.raises(AssertionError, match="helper of the channel path"):
+            jax.jit(loss)(q, k, v, g, beta)
+    jax.clear_caches()
+    for mine, theirs in zip(jax.tree.leaves(after), jax.tree.leaves(before)):
+        assert np.array_equal(np.asarray(mine), np.asarray(theirs))
+    want = gated_delta_rule_reference(q, k, v, scalar, beta)
+    assert rel(gated_delta_rule(q, k, v, scalar, beta), want) < 5e-6
+
+
+def test_the_state_kept_for_the_backward_is_the_output_alone():
+    q, k, v, g, beta = operands(4, seq=128)
+    _, residuals = jax.vjp(lambda *a: gated_delta_rule(*a), q, k, v, g, beta)
+    kept = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(residuals))
+    inputs = sum(x.size * x.dtype.itemsize for x in (q, k, v, g, beta))
+    # the inputs (flattened and padded copies among them), never a [chunks, d_k, d_v] state
+    assert kept <= 3 * inputs
+    assert gdr.kept_bytes(1, 2, 128, 48, 4) == 2 * 128 * 48 * 4

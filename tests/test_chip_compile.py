@@ -261,6 +261,48 @@ def test_delta_rule_preparation_kernels_compile_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < DELTA_RULE_TEMPORARIES
 
 
+def test_channel_decay_kernels_compile_for_v5e(one_chip):
+    """The delta rule under a decay per key CHANNEL at the Ling cell's size,
+    ``[1, 32, 16384, 128 | 128]`` with ``log_alpha`` ``[1, 32, 16384, 128]``:
+    the preparation is XLA's (sub-blocks of 16 rows, no ``[64, 64, 128]``
+    array), the scan kernels are the scalar rule's two, handed ``gamma`` as a
+    ``[.., 1, 128]`` row a chunk and turning it down the state's rows in
+    VMEM; two heads a call, so what the rule holds beside its inputs stays
+    under a GiB and a half."""
+    from ray_tpu.ops import gated_delta_rule as G
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    shapes = (
+        shape(1, 32, 16384, 128), shape(1, 32, 16384, 128),
+        shape(1, 32, 16384, 128, dtype=jnp.bfloat16),
+        shape(1, 32, 16384, 128), shape(1, 32, 16384),
+    )
+    assert G._heads_per_call(32, 16384) == 2 and G._SUB_CHUNK == 16
+    rule = functools.partial(G.gated_delta_rule, interpret=False)
+    assert jax.eval_shape(rule, *shapes).shape == (1, 32, 16384, 128)
+    text = jax.jit(rule).lower(*shapes).compile().as_text()
+    assert _mosaic_calls(text) == ["_delta_rule_forward"]
+    assert "f32[2,256,1,128]" in text                            # gamma, a row a chunk
+
+    def value_and_grads(*args):
+        loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+    compiled = jax.jit(value_and_grads).lower(*shapes).compile()
+    assert sorted(_mosaic_calls(compiled.as_text())) == [
+        "_delta_rule_backward", "_delta_rule_forward", "_delta_rule_forward",
+    ]
+    assert [g.dtype for g in jax.eval_shape(value_and_grads, *shapes)[1]] == [
+        jnp.float32, jnp.float32, jnp.bfloat16, jnp.float32, jnp.float32,
+    ]
+    assert jax.eval_shape(value_and_grads, *shapes)[1][3].shape == (1, 32, 16384, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < int(1.5 * 2**30)
+    # no [.., 64, 64, 128] intermediate: 17 GB a layer at this size
+    assert "64,64,128]" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("channels", [2880, 5760])
 def test_short_conv_kernels_compile_for_v5e(one_chip, channels):
     """The linear mixers' convolutions at the Olmo-Hybrid cell's sizes,
@@ -433,6 +475,51 @@ def test_latent_attention_moe_step_compiles_for_a_v5e_mesh(topo, axes):
     # input and weight gradients in the expert layer's
     assert text.count("tpu_custom_call") == 15
     assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 3
+
+
+@pytest.mark.parametrize("axes", [
+    {"dp": 1}, {"dp": 2, "fsdp": 2},
+], ids=lambda axes: "-".join(f"{k}{v}" for k, v in axes.items()))
+def test_patterned_step_over_held_experts_compiles_for_a_v5e_mesh(topo, axes):
+    """A pattern over expert layers (Ling-3.0-flash-VL's shape at lane
+    widths) on one chip and across four under data parallelism: a dense
+    prefix with a linear mixer, then (linear, full) whose linear layers carry
+    a decay per channel and whose full layers are gated latent attention,
+    over 8 group-routed experts of which 4 are held. The convolutions, the
+    scan kernels and the expert layer run per data shard under ``shard_map``;
+    the grouped matmuls run over the held experts' groups alone."""
+    from ray_tpu.models import transformer as T
+    from ray_tpu.ops import gated_delta_rule as G, short_conv as S
+
+    config = T.TransformerConfig(
+        vocab_size=512, dim=256, n_layers=3, n_heads=2, n_kv_heads=2, hidden_dim=384,
+        max_seq=512, attention="flash", remat="full",
+        latent=T.LatentAttentionConfig(
+            kv_lora_rank=128, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            output_gate="head"),
+        first_dense_layers=1, first_dense_kind="linear", layer_pattern=("linear", "full"),
+        linear=T.LinearAttentionConfig(
+            num_key_heads=2, num_value_heads=2, key_head_dim=128, value_head_dim=128,
+            allow_neg_eigval=False, decay="channel", gate_lower_bound=-5.0,
+            output_gate="sigmoid"),
+        moe=T.MoEConfig(
+            num_experts=8, top_k=2, norm_topk_prob=True, expert_dim=128, shared_experts=1,
+            scoring="sigmoid", routed_scaling=2.5, n_group=4, topk_group=2, held=(4, 4)),
+    )
+    with mock.patch.object(G, "resolve_interpret", lambda _i: False), \
+            mock.patch.object(S, "resolve_interpret", lambda _i: False):
+        text = _loss_and_grads_text(topo, config, axes, batch=4, seq=512)
+    calls = _mosaic_calls(text)
+    # two linear layers (one in each scan): the scan's forward, the forward
+    # again for the chunk-start states, the backward
+    assert calls.count("_delta_rule_forward") == 4 and calls.count("_delta_rule_backward") == 2
+    assert not [c for c in calls if c.startswith("_delta_prepare")]     # XLA's, per channel
+    assert calls.count("_flash_forward") == 1
+    # two expert layers: nine grouped matmuls and the recompute's three forward ones
+    assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 6
+    assert len([c for c in calls if c.startswith("_short_conv")]) >= 12
+    # the held experts' stack, [periods x count, held, k, n] flattened: 4 of the 8
+    assert "bf16[4,256,128]" in text and "bf16[8,256,128]" not in text
 
 
 @pytest.mark.parametrize("axes,batch", [

@@ -255,8 +255,13 @@ def test_what_a_patterned_model_cannot_do_yet_is_refused_by_name(fam, params):
         jax.eval_shape(lambda p, t: T.forward(p, t, model), params, ids())
     with pytest.raises(NotImplementedError, match="fewer key heads"):
         T.dataclasses.replace(model, linear=T.LinearAttentionConfig(num_key_heads=2, num_value_heads=4))
-    with pytest.raises(NotImplementedError, match="layer_pattern over mixture-of-experts"):
-        T.dataclasses.replace(model, moe=T.MoEConfig())
+    # a pattern may sit over expert layers since PR 36; the reordered norm may not
+    sparse = T.dataclasses.replace(model, moe=T.MoEConfig())
+    with pytest.raises(NotImplementedError, match='norm_placement="post" over a mixture-of-experts'):
+        jax.eval_shape(
+            lambda key, t: T.forward(T.init_params(sparse, key), t, sparse),
+            jax.random.PRNGKey(0), ids(),
+        )
     with pytest.raises(ValueError, match="no multiple of the period"):
         T.dataclasses.replace(model, n_layers=6)
     with pytest.raises(ValueError, match="tie_word_embeddings"):
@@ -284,3 +289,49 @@ def test_a_changed_term_fails_the_check(what, fam, params):
     changed = jax.jit(lambda p, t: T.forward(p, t, model))(weights, x)
     check = fam.check(changed, params, x)
     assert not check["ok"] and check["published"]["rel_rms"] > 1e-1
+
+
+# Every setting of the three mixer fields a configuration may combine
+# (``LinearAttentionConfig``: a decay per channel needs the bound, and is
+# refused without it): Olmo-Hybrid's is the first, Ling's the last.
+MIXER_SETTINGS = [
+    ("head", None, "silu"), ("head", None, "sigmoid"), ("head", -5.0, "silu"),
+    ("head", -5.0, "sigmoid"), ("channel", -5.0, "silu"), ("channel", -5.0, "sigmoid"),
+]
+
+
+@pytest.mark.parametrize("decay,bound,gate", MIXER_SETTINGS)
+def test_every_allowed_setting_of_the_mixer_matches_the_recurrence(decay, bound, gate):
+    """The chunked kernels' path against ``attention="reference"`` (the
+    per-token recurrence, XLA's convolution) on one set of weights: logits
+    and every gradient leaf, for each combination of decay, bound and
+    output gate that ``LinearAttentionConfig`` lets through."""
+    linear = T.LinearAttentionConfig(
+        num_key_heads=4, num_value_heads=4, key_head_dim=16, value_head_dim=16,
+        decay=decay, gate_lower_bound=bound, output_gate=gate,
+    )
+    configs = {
+        attention: T.TransformerConfig.tiny(
+            n_layers=2, n_kv_heads=4, layer_pattern=("linear", "full"), linear=linear,
+            attention=attention,
+        )
+        for attention in ("flash", "reference")
+    }
+    params = T.init_params(configs["flash"], jax.random.PRNGKey(5))
+    width = 64 if decay == "channel" else 4
+    assert params["layers"]["linear"]["wa"].shape == (1, 1, 64, width)
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 81), 0, 256)
+
+    def loss_and_logits(config):
+        logits = T.forward(params, tokens[:, :-1], config)
+        loss, grads = jax.value_and_grad(T.loss_fn)(params, tokens[:, :-1], tokens[:, 1:], config)
+        return logits, loss, grads
+
+    got, want = (loss_and_logits(configs[a]) for a in ("flash", "reference"))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5)
+    for (path, g), (_, w) in zip(
+        jax.tree_util.tree_leaves_with_path(got[2]), jax.tree_util.tree_leaves_with_path(want[2])
+    ):
+        scale = float(jnp.abs(w).max()) + 1e-12
+        assert float(jnp.abs(g - w).max()) <= 2e-3 * scale + 1e-6, jax.tree_util.keystr(path)

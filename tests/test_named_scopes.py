@@ -49,12 +49,34 @@ MOONLIGHT_SHAPED = dict(
 )
 
 
+# A patterned model over expert layers (Ling-3.0-flash-VL's shape): a dense
+# prefix with a linear mixer, then (linear, full) over group-routed experts of
+# which a block is held; what it names beyond the vocabularies above.
+NEW_SCOPES = ("decay_prepare", "attn_gate")
+NEW = re.compile(r"(?:^|[/(])(" + "|".join(NEW_SCOPES) + r")(?:[/)]|$)")
+LINEAR = re.compile(r"(?:^|[/(])(" + "|".join(T.LINEAR_SCOPES) + r")(?=[/)]|$)")
+LING_SHAPED = dict(
+    n_layers=3, hidden_dim=160, first_dense_layers=1, first_dense_kind="linear",
+    layer_pattern=("linear", "full"),
+    linear=T.LinearAttentionConfig(
+        num_key_heads=4, num_value_heads=4, key_head_dim=16, value_head_dim=16,
+        allow_neg_eigval=False, decay="channel", gate_lower_bound=-5.0, output_gate="sigmoid"),
+    latent=T.LatentAttentionConfig(
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        output_gate="head"),
+    moe=T.MoEConfig(
+        num_experts=16, top_k=2, norm_topk_prob=True, expert_dim=32, shared_experts=1,
+        scoring="sigmoid", routed_scaling=2.5, n_group=4, topk_group=2, held=(4, 4)),
+)
+
+
 ONE_DEVICE = (("dp", 1),)
 MESH_2X2 = (("fsdp", 2), ("tp", 2))
 
 
 @functools.lru_cache(maxsize=None)
-def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False):
+def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False,
+                 ling=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
@@ -64,8 +86,8 @@ def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True
     to ``nothing_saveable``, what it was before it kept the flash
     kernel's residuals; ``latent`` the Moonlight-shaped tiny configuration
     (latent attention, a dense first layer, sigmoid-routed experts with
-    shared experts)."""
-    shaped = MOONLIGHT_SHAPED if latent else OLMOE_SHAPED if moe else {}
+    shared experts); ``ling`` the patterned one over held experts."""
+    shaped = LING_SHAPED if ling else MOONLIGHT_SHAPED if latent else OLMOE_SHAPED if moe else {}
     config = T.TransformerConfig.tiny(remat=remat, **shaped)
     assert config.attention == "flash"
     optimizer = optax.adamw(1e-3)
@@ -274,6 +296,44 @@ def test_latent_scopes_change_names_never_the_program():
     assert not any(LATENT.search(n) for _op, n in plain)
 
 
+@pytest.mark.parametrize("remat", POLICIES)
+@pytest.mark.parametrize("scope,inside", [
+    ("decay_prepare", ("attention", "linear_attention", "delta_rule")),
+    ("attn_gate", ("attention",)),
+])
+def test_a_patterned_model_over_experts_names_its_new_work(remat, scope, inside):
+    """``decay_prepare`` (the chunk preparation under a decay per channel)
+    lies inside ``delta_rule`` inside ``linear_attention`` inside
+    ``attention``, ``attn_gate`` (the latent layers' head-wise gate) inside
+    ``attention`` and outside the linear mixer: forward and, through the
+    custom VJP and the layer checkpoint, backward; every matmul of both
+    scans and of the period's two kinds of layer has a block."""
+    named = instructions(remat, ling=True)
+    # the scan kernels run interpreted here, and the interpreter's own dots
+    # carry no name at all (on a chip they are inside the Mosaic call)
+    matmuls = [n for op, n in named if op in ("dot", "convolution") and n]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    mine = [n for _op, n in named if NEW.search(n) and NEW.search(n).group(1) == scope]
+    assert [n for n in mine if "transpose(" not in n], scope
+    assert [n for n in mine if "transpose(" in n], scope
+    for n in mine:
+        assert BLOCKS.search(n).group(1) == "attention", n
+        assert set(LINEAR.findall(n)) >= set(inside[1:]), n
+        if scope == "attn_gate":
+            assert not LINEAR.search(n), n
+    # the preparation's matmuls (A, P, T's levels, W, U0) are under the scope
+    assert [n for n in matmuls if NEW.search(n) and NEW.search(n).group(1) == "decay_prepare"]
+    # the experts' scopes and ``shared`` are there under the pattern too
+    assert {MOE.search(n).group(1) for n in matmuls if MOE.search(n)} == {"router", "experts"}
+    assert [n for n in matmuls if LATENT.search(n) and LATENT.search(n).group(1) == "shared"]
+
+
+def test_the_new_scopes_change_names_never_the_program():
+    scoped, plain = instructions(None, ling=True), instructions(None, scoped=False, ling=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+    assert not any(NEW.search(n) for _op, n in plain)
+
+
 def test_vocabulary():
     # benchmarks/harness/scopes.py repeats it: a rename renames metrics.
     assert T.SCOPES == ("embed", "attention", "mlp", "head", "loss", "optimizer")
@@ -283,3 +343,9 @@ def test_vocabulary():
     # benchmarks/harness/latent_scopes.py repeats these
     assert T.LATENT_SCOPES == ("latent", "shared")
     assert not set(T.LATENT_SCOPES) & (set(T.SCOPES) | set(T.MOE_SCOPES))
+    # benchmarks/harness/linear_scopes.py repeats these; the two names PR 36
+    # added (read by name: harness/named_scope.py) are in none of the four
+    assert T.LINEAR_SCOPES == ("linear_attention", "short_conv", "delta_rule", "gate_norm")
+    assert not set(NEW_SCOPES) & (
+        set(T.SCOPES) | set(T.MOE_SCOPES) | set(T.LATENT_SCOPES) | set(T.LINEAR_SCOPES)
+    )
