@@ -2190,6 +2190,9 @@ def main() -> None:
     parser.add_argument("--port", type=int, default=0)
     parser.add_argument("--session-dir", required=True)
     args = parser.parse_args()
+    from ray_tpu._private.node import exit_with_parent
+
+    exit_with_parent()
 
     async def run() -> None:
         controller = Controller(args.session_dir)

@@ -162,6 +162,8 @@ class CoreContext:
         self._borrowers: dict[str, set[str]] = {}
         self._borrowed: dict[str, tuple] = {}  # obj_id -> owner_addr we registered with
         self._refs_lock = threading.Lock()
+        # Drops queued by ObjectRef finalizers: see remove_local_ref.
+        self._dropped_refs: collections.deque[str] = collections.deque()
         # lineage: obj_id -> PendingTask of creating task (kept while refs live)
         self._lineage: dict[str, PendingTask] = {}
         self._task_counter = 0
@@ -399,18 +401,39 @@ class CoreContext:
     def add_local_ref(self, object_id: str) -> None:
         with self._refs_lock:
             self._local_refs[object_id] = self._local_refs.get(object_id, 0) + 1
+        if self._dropped_refs:
+            self._drain_dropped_refs()
 
     def remove_local_ref(self, object_id: str) -> None:
+        """Called from ObjectRef.__del__, and the collector runs a finalizer
+        between any two bytecodes of whatever thread it interrupts — also
+        inside a block of that thread that holds _refs_lock. So never WAIT
+        for the lock here (that wait once hung a driver for good, this thread
+        blocked on itself and the io thread behind it): queue the drop and
+        apply it if the lock is free, else leave it to the next ref made or
+        dropped."""
         if self._shutdown:
             return
-        with self._refs_lock:
-            count = self._local_refs.get(object_id, 0) - 1
-            if count <= 0:
-                self._local_refs.pop(object_id, None)
-            else:
-                self._local_refs[object_id] = count
-                return
-        self._maybe_free(object_id)
+        self._dropped_refs.append(object_id)
+        self._drain_dropped_refs()
+
+    def _drain_dropped_refs(self) -> None:
+        while self._dropped_refs and self._refs_lock.acquire(blocking=False):
+            unreferenced = []
+            try:
+                while self._dropped_refs:
+                    object_id = self._dropped_refs.popleft()
+                    count = self._local_refs.get(object_id, 0) - 1
+                    if count <= 0:
+                        self._local_refs.pop(object_id, None)
+                        unreferenced.append(object_id)
+                    else:
+                        self._local_refs[object_id] = count
+            finally:
+                self._refs_lock.release()
+            # The lock was free, so this thread is in no block that holds it.
+            for object_id in unreferenced:
+                self._maybe_free(object_id)
 
     def _maybe_free(self, object_id: str) -> None:
         with self._refs_lock:
@@ -1969,6 +1992,10 @@ class CoreContext:
             "runtime_env": spec["runtime_env"],
             "job_id": spec["job_id"],
             "bundle": resp.get("bundle"),
+            # Sent again after a slow or lost reply, this request joins its
+            # own grant at the agent (rpc_lease_worker) and leases no second
+            # worker.
+            "mutation_token": f"lease:{os.urandom(8).hex()}",
         }
         if trace_ctx:
             worker_payload["trace_ctx"] = trace_ctx

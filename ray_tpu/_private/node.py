@@ -15,6 +15,7 @@ import signal
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 
 from ray_tpu._private.ids import NodeID
@@ -72,6 +73,21 @@ def _wait_for_file(path: str, timeout: float = 120.0) -> str:
                 return content
         time.sleep(0.02)
     raise TimeoutError(f"timed out waiting for {path}")
+
+
+def exit_with_parent() -> None:
+    """Called by the controller and the node agent as they start. The process
+    that spawned them reaps them when it exits (LocalCluster.shutdown, at
+    exit); one that was killed cannot, so they watch for its death themselves
+    and take their process group, the agent's workers, with them."""
+    parent = os.getppid()
+
+    def watch() -> None:
+        while os.getppid() == parent:
+            time.sleep(1.0)
+        os.killpg(os.getpgrp(), signal.SIGKILL)
+
+    threading.Thread(target=watch, daemon=True, name="parent-watch").start()
 
 
 class ProcessHandle:

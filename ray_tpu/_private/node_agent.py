@@ -179,6 +179,11 @@ class NodeAgent:
         )
         self.runtime_envs = RuntimeEnvManager(session_dir)
         self.leases: dict[str, Lease] = {}
+        # mutation_token -> the grant under way or made for that request
+        # (newest last, bounded): see rpc_lease_worker.
+        self._lease_grants: collections.OrderedDict[str, asyncio.Future] = (
+            collections.OrderedDict()
+        )
         self.bundles: dict[tuple, dict] = {}  # (pg_id, idx) -> {resources, available, committed}
         # Parked lease requests indexed by resource shape (sorted names):
         # a freed resource wakes only the shapes it can satisfy instead of
@@ -1015,6 +1020,26 @@ class NodeAgent:
         return {"status": "ok"}
 
     async def rpc_lease_worker(self, conn, payload) -> dict:
+        # One worker and one set of resources per REQUEST, however often it
+        # arrives: a caller sends it again when the reply is slow (a spawn on
+        # a loaded host outlasts a lossy link's per-attempt wait) or lost, and
+        # a link can deliver it twice. Every copy joins the one grant; without
+        # this each copy leased a worker that nobody would ever return.
+        token = payload.get("mutation_token")
+        if token is None:
+            return await self._grant_lease(payload)
+        grant = self._lease_grants.get(token)
+        if grant is None:
+            grant = self._lease_grants[token] = spawn_task(
+                self._grant_lease(payload)
+            )
+            while len(self._lease_grants) > 1024:
+                self._lease_grants.popitem(last=False)
+        # shield: one copy's connection going away must not cancel the grant
+        # the others wait for.
+        return await asyncio.shield(grant)
+
+    async def _grant_lease(self, payload) -> dict:
         resources = payload["resources"]
         runtime_env = payload.get("runtime_env") or {}
         bundle = payload.get("bundle")
@@ -1547,6 +1572,9 @@ def main() -> None:
     parser.add_argument("--store-capacity", type=int, default=0)
     parser.add_argument("--port", type=int, default=0)
     args = parser.parse_args()
+    from ray_tpu._private.node import exit_with_parent
+
+    exit_with_parent()
     host, port = args.controller.rsplit(":", 1)
 
     async def run() -> None:

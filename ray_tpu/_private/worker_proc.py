@@ -84,6 +84,7 @@ class WorkerRuntime:
         self._main_current_task: str | None = None
         self._cancel_target: str | None = None
         self._task_events_last_flush = 0.0
+        self._task_events_late_flush = False
         # compiled-graph state: dag_id → resident rtdag runtime (stage
         # loops + channels + per-dag device group), dag/executor.py
         self._dag_runtimes: dict = {}
@@ -883,10 +884,31 @@ class WorkerRuntime:
                 or now - self._task_events_last_flush > 1.0
             )
             if not due:
+                # A worker that now goes idle must not sit on these events
+                # until its next task: one late flush per batch window.
+                if not self._task_events_late_flush:
+                    self._task_events_late_flush = True
+                    self.ctx.io.spawn(self._flush_task_events_late())
                 return
-            slim = self.ctx._task_events[:]
-            self.ctx._task_events.clear()
-            self._task_events_last_flush = now
+            slim = self._take_task_events()
+        self.ctx.io.spawn(self._report_task_events(slim))
+
+    def _take_task_events(self) -> list[tuple]:
+        """The buffered batch, to be reported; _task_event_lock is held."""
+        slim = self.ctx._task_events[:]
+        self.ctx._task_events.clear()
+        self._task_events_last_flush = _time.monotonic()
+        return slim
+
+    async def _flush_task_events_late(self) -> None:
+        await asyncio.sleep(1.0)
+        with self._task_event_lock:
+            self._task_events_late_flush = False
+            slim = self._take_task_events()
+        if slim:
+            await self._report_task_events(slim)
+
+    async def _report_task_events(self, slim: list[tuple]) -> None:
         node_id = self.ctx.node_id
         worker_id = self.ctx.worker_id
         pid = os.getpid()
@@ -906,16 +928,12 @@ class WorkerRuntime:
             if extras:
                 event.update(extras)  # peak_rss / rss_delta / hbm_delta
             events.append(event)
-
-        async def _flush():
-            try:
-                await self.ctx.controller.call(
-                    "report_task_events", {"events": events}
-                )
-            except Exception:  # rtlint: disable=swallowed-exception - task-event uplink is advisory telemetry
-                pass
-
-        self.ctx.io.spawn(_flush())
+        try:
+            await self.ctx.controller.call(
+                "report_task_events", {"events": events}
+            )
+        except Exception:  # rtlint: disable=swallowed-exception - task-event uplink is advisory telemetry
+            pass
 
     # ------------------------------------------------------------------
     # RPC handlers

@@ -932,9 +932,15 @@ class CompiledDAG:
         self._unregister_stall_listener()
         self._inflight.clear()
         self._retained.clear()
+        # The controller has said an actor of this graph is DEAD. Forget
+        # every cached address, so the teardown resolves each actor anew:
+        # a DEAD one is refused at once (ConnectionLost) instead of being
+        # redialled, and only the living are called.
+        for aid in self._actor_ids:
+            self._ctx._actor_addr_cache.pop(aid, None)
         try:
             self._ctx.io.run(self._teardown_async(), timeout=15)
-        except Exception:  # rtlint: disable=swallowed-exception - dead workers can't ack teardown; the driver-side slot frees run within _teardown_async's own 10 s bound
+        except Exception:  # rtlint: disable=swallowed-exception - a worker that died since can't ack teardown; the driver-side slot frees run within _teardown_async's own 10 s bound
             pass
         self._destroy_group(sync=True)
 
@@ -1042,13 +1048,13 @@ class CompiledDAG:
             except Exception:  # rtlint: disable=swallowed-exception - actor may be dead; teardown is idempotent
                 pass
 
-        # Concurrent: one dead actor's timeout must not serialize the
-        # survivors' teardown behind it (failure-path latency). And
-        # bounded by the RPC's own timeout: the redial of a DEAD actor
-        # backs off for up to ~26 s (rpc_retry_*), longer than
-        # _fail_cleanup waits for this coroutine, and the frees below
-        # must have run by the time it re-raises. A straggler is left to
-        # finish on its own; it swallows its own failure.
+        # Concurrent: one stuck actor's timeout must not serialize the
+        # others' teardown behind it. And bounded by the RPC's own
+        # timeout: the redial of an actor that died unnoticed backs off
+        # for up to ~26 s (rpc_retry_*), longer than _fail_cleanup waits
+        # for this coroutine, and the frees below must have run by the
+        # time it re-raises. A straggler is left to finish on its own; it
+        # swallows its own failure.
         await asyncio.wait(
             [asyncio.ensure_future(one(aid)) for aid in self._actor_ids],
             timeout=10,

@@ -373,15 +373,16 @@ class ServeController:
             finally:
                 with self._lock:
                     self._pollers.discard(entry)
+        # The membership as it is NOW, not as the last reconcile pass left
+        # it: a replica turns RUNNING on its own thread (_await_ready) and
+        # get_status says so at once, while a pass can be seconds away
+        # (it health-checks every replica first).
+        self._publish_if_changed()
         with self._config_cond:
-            snapshot = self._last_snapshot
-            if snapshot is None:
-                snapshot = self._membership_snapshot()
-                self._last_snapshot = snapshot
             return {
                 "version": self._config_version,
                 "instance": self._instance,
-                **snapshot,
+                **self._last_snapshot,
             }
 
     def get_status(self) -> dict:

@@ -482,9 +482,10 @@ def read_spans(session_dir: str) -> list[dict]:
     ):
         try:
             with open(path) as fh:
-                for line in fh:
-                    line = line.strip()
-                    if line:
+                # A record ends with its newline: a tail without one is
+                # another process's append still on its way.
+                for line in fh.read().split("\n")[:-1]:
+                    if line.strip():
                         out.append(json.loads(line))
         except OSError:
             continue

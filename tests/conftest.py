@@ -7,9 +7,14 @@ Mirrors the reference's python/ray/tests/conftest.py patterns:
   * CPU-jax twin      — JAX runs on a virtual 8-device CPU mesh so all TPU
                         sharding/collective code is testable hostless
                         (SURVEY §4.4), including resource lying for TPUs.
+  * time_limit        — every test's whole protocol runs under TEST_LIMIT_S:
+                        a wait that never ends fails that one test by name.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
 
 # Pin the whole test process tree to a virtual 8-device CPU mesh (the CPU
 # twin of a TPU slice, SURVEY §4.4): spawned worker processes inherit
@@ -27,6 +32,62 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+# One limit for every test, set from the measurement (ROADMAP.md "The repo's
+# tests": the slowest test takes 59 to 73 s under the driver's six workers).
+# A test that needs more is a test to repair, so nothing lengthens it.
+TEST_LIMIT_S = 240.0
+# After the limit the test has failed and its fixtures tear down; if that (or
+# a wait inside C, which no Python signal handler interrupts) outlasts the
+# grace too, the process exits and xdist books the crash against the test.
+GRACE_S = 30.0
+
+
+@contextlib.contextmanager
+def time_limit(nodeid, limit_s, grace_s, stderr_fd):
+    """Bound one test: at `limit_s` every thread's stack goes to `stderr_fd`
+    and the main thread raises pytest's failure naming `nodeid`; `grace_s`
+    later the process dumps again and exits. Both timers are disarmed on exit."""
+
+    def on_alarm(signum, frame):
+        faulthandler.dump_traceback(file=stderr_fd, all_threads=True)
+        pytest.fail(f"{nodeid} exceeded {limit_s:g} s", pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    faulthandler.dump_traceback_later(limit_s + grace_s, exit=True, file=stderr_fd)
+    signal.setitimer(signal.ITIMER_REAL, limit_s)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, previous)
+
+
+def pytest_configure(config):
+    # Taken here, outside pytest's capture: the stacks must reach the log of
+    # the run even when the process exits with the test's output still captured.
+    config._time_limit_stderr_fd = os.dup(2)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    # The whole protocol, not the call alone: a module fixture (a cluster's
+    # start or shutdown) can hang as well as a body.
+    with time_limit(item.nodeid, TEST_LIMIT_S, GRACE_S, item.config._time_limit_stderr_fd):
+        return (yield)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_handlecrashitem(crashitem, report, sched):
+    # xdist's loadfile puts a crashed worker's file back on its queue with the
+    # test that crashed still pending, so a test ended by the backstop would
+    # hang the next worker as well, and the next. It has failed: it is done.
+    for scope, unit in list(getattr(sched, "workqueue", {}).items()):
+        if crashitem in unit:
+            unit[crashitem] = True
+            if all(unit.values()):
+                del sched.workqueue[scope]
 
 
 @pytest.fixture(scope="module")

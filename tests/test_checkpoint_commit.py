@@ -9,6 +9,7 @@ store); the commit-protocol tests are pure-filesystem.
 import json
 import os
 import pickle
+import time
 
 import numpy as np
 import pytest
@@ -353,6 +354,20 @@ def _midsave_kill_loop(config):
             if step == config["kill_step"] and not os.path.exists(
                 config["marker"]
             ):
+                # The kill is to find a committed checkpoint to fall back
+                # to, and this rank cannot count on one: a rank runs a
+                # report ahead of the driver, which commits a round only
+                # once every rank has reported it (and a rank that exits
+                # right after its poll was answered can take that answer
+                # with it). So wait for the commit itself.
+                deadline = time.monotonic() + 120
+                while not any(
+                    is_committed(os.path.join(config["trial_dir"], name))
+                    for name in os.listdir(config["trial_dir"])
+                    if name.startswith("checkpoint_")
+                ):
+                    assert time.monotonic() < deadline, "nothing was committed"
+                    time.sleep(0.05)
                 open(config["marker"], "w").close()
                 chaos_core.install(
                     FaultSchedule(
@@ -376,7 +391,10 @@ def test_trainer_recovers_from_midsave_kill(ray_start_shared, tmp_path):
     marker = str(tmp_path / "killed")
     trainer = JaxTrainer(
         _midsave_kill_loop,
-        train_loop_config={"steps": 6, "kill_step": 2, "marker": marker},
+        train_loop_config={
+            "steps": 6, "kill_step": 2, "marker": marker,
+            "trial_dir": StorageContext(str(tmp_path), "midsave").trial_dir,
+        },
         scaling_config=ScalingConfig(num_workers=2),
         run_config=RunConfig(
             name="midsave",

@@ -68,7 +68,6 @@ def test_killed_dag_actor_raises_typed_error_and_hang_report(dag_cluster):
             assert dag.execute(i).get(timeout=60) == i + 3
 
         ray_tpu.kill(b, no_restart=True)
-        time.sleep(0.5)
         ref = dag.execute(99)
         with pytest.raises(exceptions.DAGActorDiedError) as excinfo:
             ref.get(timeout=12.0)

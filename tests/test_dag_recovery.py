@@ -123,11 +123,13 @@ def test_supervised_dag_survives_kill_exactly_once(recovery_cluster):
             assert dag.execute(i).get(timeout=60) == i + 3
 
         refs = [dag.execute(i) for i in range(3, 7)]
-        ray_tpu.kill(b, no_restart=True)
-        time.sleep(0.5)
+        ray_tpu.kill(b, no_restart=True)  # returns once the process is gone
+        # Those four race the kill, and one that is past `b` when it dies
+        # needs no recovery. These two cannot complete without one.
+        refs += [dag.execute(i) for i in range(7, 9)]
         # Every in-flight seq arrives exactly once across the kill.
         assert [r.get(timeout=120) for r in refs] == [
-            i + 3 for i in range(3, 7)
+            i + 3 for i in range(3, 9)
         ]
         assert dag.recoveries == 1
         assert dag._epoch == 1
@@ -177,7 +179,6 @@ def test_stateful_actor_resumes_from_snapshot(recovery_cluster):
         assert dag.execute(1).get(timeout=60) == 5
 
         ray_tpu.kill(acc, no_restart=True)
-        time.sleep(0.5)
         # Detection + recovery happen inside get(): the replacement
         # restores total=4 from the commit, seq 4 (retained above the
         # snapshot floor) replays into a deduplicated result, and the
@@ -196,24 +197,35 @@ def test_unsupervised_failure_cleans_up_and_carries_evidence(
 ):
     """Unsupervised graphs keep the typed-failure contract, now with
     edge evidence on the error, and the failure path itself releases
-    every ring slot and parks no loop — WITHOUT a close() call."""
+    every ring slot and parks no loop — WITHOUT a close() call. It
+    tears down the living actors only: the one the controller has
+    declared DEAD is never dialled (a redial backs off for seconds)."""
     from ray_tpu._private.worker import get_global_context
 
+    ctx = get_global_context()
     a, b = Stage.remote(1), Stage.remote(2)
     with InputNode() as inp:
         out = b.add.bind(a.add.bind(inp))
     dag = out.experimental_compile()  # NOT supervised
     dag_id = dag.dag_id
     assert dag.execute(0).get(timeout=60) == 3
+    addr_of = {aid: ctx._actor_addr_cache[aid] for aid in (a._actor_id, b._actor_id)}
 
     ray_tpu.kill(b, no_restart=True)
-    time.sleep(0.5)
     ref = dag.execute(1)
-    with pytest.raises(exceptions.DAGActorDiedError) as excinfo:
-        # An unsupervised reader blocks for the whole timeout and probes
-        # liveness only then, so this is time the test always spends.
-        ref.get(timeout=15.0)
+    dialled = []
+    client_for = ctx._client_for
+    ctx._client_for = lambda addr: dialled.append(tuple(addr)) or client_for(addr)
+    try:
+        with pytest.raises(exceptions.DAGActorDiedError) as excinfo:
+            # An unsupervised reader blocks for the whole timeout and probes
+            # liveness only then, so this is time the test always spends.
+            ref.get(timeout=15.0)
+    finally:
+        del ctx._client_for
     err = excinfo.value
+    assert addr_of[a._actor_id] in dialled, "the survivor was not torn down"
+    assert addr_of[b._actor_id] not in dialled, "teardown dialled the dead actor"
     # The error names the edge it was detected on, not just the actor.
     assert err.actor_id == b._actor_id
     assert err.family == "shm"

@@ -201,6 +201,28 @@ def test_submitted_ref_pins_args_until_task_done(ray_start_shared):
     _poll(lambda: rid not in ctx._objects, msg="free after task completion")
 
 
+def test_ref_finalized_while_the_refs_lock_is_held_does_not_wait(ray_start_shared):
+    """The collector runs ObjectRef.__del__ between any two bytecodes of the
+    thread it interrupts, also inside a block of that thread that holds the
+    refs lock. A finalizer that waited for the lock there would wait for
+    its own thread, for ever: the drop is queued, and applied by the next
+    ref that is made or dropped."""
+    import threading
+
+    ctx = _ctx()
+    ref = ray_tpu.put("held")
+    rid = ref.id
+    ref._runtime = None  # the drop below stands in for this handle's finalizer
+    with ctx._refs_lock:  # what the interrupted block holds
+        dropper = threading.Thread(target=ctx.remove_local_ref, args=(rid,))
+        dropper.start()
+        dropper.join(timeout=30)
+        assert not dropper.is_alive(), "a finalizer waited for the refs lock"
+        assert rid in ctx._objects
+    ray_tpu.put("next")  # any later ref applies what was queued
+    assert rid not in ctx._objects
+
+
 def test_nested_ref_inside_put_value(ray_start_shared):
     """put([inner_ref]): the outer value pins the inner object; dropping
     the outer frees the chain (contained-borrow handling)."""

@@ -135,10 +135,18 @@ def test_chaosmonkey_actor_kill_replica_midload():
         # The monkey's "actor" target only needs the actor registry, not a
         # Cluster handle.
         monkey = ChaosMonkey(None, schedule).start()
-        answers = [handle.remote(i).result(timeout=60) for i in range(12)]
-        monkey.join(timeout=10)
-        assert [x for _, x in answers] == list(range(12))
-        assert monkey.events and monkey.events[0]["status"] == "ok"
+        # Load before, while and after the kill: requests go on until the
+        # monkey has recorded it and twelve more have been answered.
+        deadline = time.monotonic() + 120
+        sent = after_kill = 0
+        while after_kill < 12:
+            assert time.monotonic() < deadline, (
+                f"{sent} answered, the monkey's events: {monkey.events}"
+            )
+            assert handle.remote(sent).result(timeout=60)[1] == sent
+            sent += 1
+            after_kill += bool(monkey.events)
+        assert monkey.events[0]["status"] == "ok"
         assert monkey.events[0]["actor_name"] == names[0]
 
         # The controller notices the corpse and brings the deployment back

@@ -270,9 +270,9 @@ def test_admission_shed_http_503_with_retry_after(serve_instance):
         assert "shed" in r.text
 
 
-def test_deadline_header_rides_http(serve_instance):
-    """An X-RayTPU-Deadline header bounds the whole request: a slow
-    handler turns into a 504 at the client's budget."""
+def test_deadline_header_rides_http(serve_instance, tmp_path):
+    """An X-RayTPU-Deadline header bounds the whole request: a handler
+    that is still working at the client's budget turns into a 504."""
     import httpx
 
     from ray_tpu.serve._private.common import DEADLINE_HEADER
@@ -280,23 +280,32 @@ def test_deadline_header_rides_http(serve_instance):
     import asyncio
 
     @serve.deployment
-    class SlowHttp:
+    class HeldHttp:
         async def __call__(self, body):
-            await asyncio.sleep(5.0)
+            # Holds the request until the test lets go of it, so nothing
+            # but the deadline can answer a held request before then.
+            while body["hold"] and not os.path.exists(body["hold"]):
+                await asyncio.sleep(0.05)
             return {}
 
     serve.start(http_port=8184)
     serve.run(
-        SlowHttp.bind(), name="slowhttp", route_prefix="/slowhttp",
+        HeldHttp.bind(), name="heldhttp", route_prefix="/heldhttp",
         http_port=8184,
     )
-    t0 = time.monotonic()
-    resp = httpx.post(
-        "http://127.0.0.1:8184/slowhttp", json={},
-        headers={DEADLINE_HEADER: "0.5"}, timeout=60,
-    )
-    assert resp.status_code == 504, resp.text
-    assert time.monotonic() - t0 < 4.0
+    url = "http://127.0.0.1:8184/heldhttp"
+    # The route answers end to end first: a proxy that knows of no replica
+    # yet sheds (503) what it cannot place within half a second.
+    assert httpx.post(url, json={"hold": ""}, timeout=60).status_code == 200
+    release = tmp_path / "release"
+    try:
+        resp = httpx.post(
+            url, json={"hold": str(release)},
+            headers={DEADLINE_HEADER: "0.5"}, timeout=60,
+        )
+        assert resp.status_code == 504, resp.text
+    finally:
+        release.touch()
 
 
 def test_drain_bounces_traffic_without_errors(serve_instance):

@@ -233,29 +233,33 @@ def test_per_task_rss_attribution(telemetry_cluster):
 
     @ray_tpu.remote
     def eat(mb):
-        ballast = b"x" * (mb << 20)  # touched pages, counted in ru_maxrss
-        return len(ballast)
+        # ru_maxrss is a high-water mark, and a worker's start-up peak can
+        # stand far above what it holds now: fill that gap too, so that the
+        # ballast raises the mark by its own size whatever the start was.
+        import resource
 
-    @ray_tpu.remote
-    def noop():
-        return 0
+        import psutil
+
+        peak = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss << 10
+        gap = max(0, peak - psutil.Process().memory_info().rss)
+        ballast = b"x" * (gap + (mb << 20))  # touched pages
+        return len(ballast) - gap
 
     assert ray_tpu.get(eat.remote(192), timeout=60) == 192 << 20
 
     def attributed():
-        # Later events nudge the worker's time-batched event flush.
-        ray_tpu.get(noop.remote(), timeout=30)
+        # The worker is idle from here on: its batched events come of
+        # themselves, with no later task to push them out.
         rows = [r for r in state.summarize_task_memory()
                 if r.get("name") == "eat"]
         return rows or None
 
-    rows = _poll(attributed, period=1.1)
+    rows = _poll(attributed)
     assert rows, "eat task never showed up with attribution"
     row = rows[0]
     assert row["state"] == "FINISHED"
-    # ru_maxrss is a high-water mark: the worker's startup peak absorbs
-    # part of the ballast, so assert with a wide margin — 192 MiB of
-    # touched pages must raise the peak by well over 64 MiB.
+    # 192 MiB of touched pages above the old mark (less what the worker
+    # freed meanwhile) must raise it by well over 64 MiB.
     assert row["rss_delta"] >= 64 << 20
     assert row["peak_rss"] >= row["rss_delta"]
     # The ranking helper puts the hog first.
