@@ -1,11 +1,12 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the five
+"""Flagship model: a decoder-only transformer, TPU-first, in the six
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
 checkpoint policy, one head and loss):
   * attention: RMSNorm, then either grouped-query attention (q / k / v
     projections to one ``head_dim``, RoPE over the whole head, optional
-    q/k norms: Llama, Mistral, OLMoE) or, with ``latent=``, multi-head latent
+    q/k norms, over the whole projection with ``qk_norm`` or head by head
+    with ``qk_head_norm``: Llama, Mistral, OLMoE, LFM2) or, with ``latent=``, multi-head latent
     attention (DeepSeek-V2/V3, Moonlight: keys and values rebuilt from a
     normed low-rank latent, one RoPE key shared by all heads, q / k of
     ``qk_nope + qk_rope`` dims against v of ``v_head_dim``); both through
@@ -54,6 +55,19 @@ checkpoint policy, one head and loss):
     bound ``gate_lower_bound``, which is what lets the chunked form be
     computed: ops/gated_delta_rule.py), and a sigmoid in place of SiLU on
     the per-head norm's output.
+  * the sixth, gated short convolutions over experts (LFM2-8B-A1B): a
+    third kind of layer, "conv", in the pattern and as ``first_dense_kind``,
+    whose whole mixer (``_conv_mixer``, scope ``conv_mixer``) is
+
+        [B, C, x] = h W_in                       (three chunks of ``dim``)
+        z_t = sum_j f_j (B * x)_{t - (taps - 1 - j)}    (causal, depthwise)
+        y = (C * z) W_out
+
+    with ``conv_kernel`` taps a channel, no bias and NO activation (the
+    kernels of ops/short_conv.py with ``activation=None``), three to one
+    with grouped-query layers under ``qk_head_norm``, over sigmoid-and-bias
+    routed experts; and ``tie_embeddings``: the head is the transposed
+    embedding, one leaf whose gradient is the sum of its two uses.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays; every leaf has a logical
@@ -73,11 +87,13 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     would leave them in place is not written yet.
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
   * serving (init_kv_cache / decode_step) covers grouped-query attention
-    only: the latent cache and the recurrent-state cache of a patterned
-    model are not written yet, and both refuse by name. So do the pipeline
-    (partition_stages / stage_forward split ONE stacked tree), a mesh
-    with tp or sp over a patterned model (the scan kernel runs per data
-    shard under shard_map, as flash does: dp / fsdp work) and
+    only: the latent cache, the recurrent-state cache and the
+    convolution-state cache (the last ``taps - 1`` rows of a "conv" layer's
+    gated input) of a patterned model are not written yet, and each refuses
+    by name. So do the pipeline (partition_stages / stage_forward split ONE
+    stacked tree, and a tied head would sit on two stages), a mesh with tp
+    or sp over a patterned model (the scan and convolution kernels run per
+    data shard under shard_map, as flash does: dp / fsdp work) and
     ``norm_placement="post"`` over expert layers.
 
 Reference parity: the reference has no model zoo of its own (models arrive
@@ -137,13 +153,16 @@ LATENT_SCOPES = ("latent", "shared")
 # gates, the chunk preparation and the two scan kernels) and "gate_norm"
 # (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
-# Two names outside the four vocabularies, read by name
+# Three names outside the four vocabularies, read by name
 # (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
 # (the chunk preparation under a decay per channel, opened in
-# ops/gated_delta_rule.py: forward, and backward through its custom VJP), and
-# "attn_gate", inside "attention" (a latent layer's head-wise output gate).
+# ops/gated_delta_rule.py: forward, and backward through its custom VJP),
+# "attn_gate", inside "attention" (a latent layer's head-wise output gate),
+# and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
+# the two gates, W_out, and within it "short_conv", the convolution's two
+# kernels called with no activation).
 # The kinds of layer a ``layer_pattern`` may name.
-LAYER_KINDS = ("linear", "full")
+LAYER_KINDS = ("linear", "full", "conv")
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -172,6 +191,9 @@ class MoEConfig:
     # ``router_bias`` (a buffer no gradient reaches), the weights are the
     # unbiased scores of the chosen.
     scoring: str = "softmax"
+    # What ``norm_topk_prob`` adds to the chosen weights' sum before it
+    # divides by it (DeepSeek-V3's routine 1e-20, LFM2's 1e-6).
+    renorm_eps: float = 1e-20
     # The weights are multiplied by this after renormalisation
     # (``routed_scaling_factor``).
     routed_scaling: float = 1.0
@@ -295,6 +317,12 @@ class TransformerConfig:
     # RMSNorm with a learned weight over the WHOLE projected q and k
     # vectors, before the split into heads and before RoPE (OLMoE).
     qk_norm: bool = False
+    # RMSNorm with ONE learned weight of ``head_dim`` on each head of q and
+    # of k, after the split into heads and before RoPE (LFM2, Qwen3).
+    qk_head_norm: bool = False
+    # The head is the transposed embedding: no ``lm_head`` leaf, and the
+    # embedding's gradient is the sum of the gather's and the head's.
+    tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16
     moe: MoEConfig | None = None
     # Latent attention in place of the q / k / v projections (then
@@ -314,6 +342,8 @@ class TransformerConfig:
     layer_pattern: tuple[str, ...] | None = None
     # The mixer of the pattern's "linear" layers.
     linear: LinearAttentionConfig | None = None
+    # The taps of a "conv" layer's gated short convolution (``conv_L_cache``).
+    conv_kernel: int = 3
     # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
     # ``x + norm(branch(x))``, the norm on the branch's OUTPUT.
     norm_placement: str = "pre"
@@ -335,6 +365,8 @@ class TransformerConfig:
     def __post_init__(self):
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm (the whole projection) and qk_head_norm (head by head): one")
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)
@@ -424,7 +456,10 @@ def param_logical_dims(config: TransformerConfig) -> dict:
             "wk": ("layer", "embed", "kv"),
             "wv": ("layer", "embed", "kv"),
             "wo": ("layer", "heads", "embed"),
-            **({"q_norm": ("layer", None), "k_norm": ("layer", None)} if config.qk_norm else {}),
+            **(
+                {"q_norm": ("layer", None), "k_norm": ("layer", None)}
+                if config.qk_norm or config.qk_head_norm else {}
+            ),
         }
 
     def stack(mlp, attention=attention):
@@ -443,19 +478,24 @@ def param_logical_dims(config: TransformerConfig) -> dict:
             "a_log": ("layer", None), "dt_bias": ("layer", None), "o_norm": ("layer", None),
             "wo": ("layer", "heads", "embed"),
         }
-        by_kind = {"linear": stack(mlp, linear), "full": layers}
+        conv = {
+            "w_in": ("layer", "embed", "heads"), "conv": ("layer", None, "heads"),
+            "w_out": ("layer", "heads", "embed"),
+        }
+        mixers = {"linear": linear, "full": attention, "conv": conv}
         layers = {
-            kind: {name: (dims[0], None, *dims[1:]) for name, dims in by_kind[kind].items()}
+            kind: {
+                name: (dims[0], None, *dims[1:]) for name, dims in stack(mlp, mixers[kind]).items()
+            }
             for kind in dict.fromkeys(config.layer_pattern)
         }
-        if config.first_dense_kind == "linear":
-            prefix = stack(dense_mlp, linear)
+        prefix = stack(dense_mlp, mixers[config.first_dense_kind])
     return {
         "embed": ("vocab", "embed"),
         **({"dense_layers": prefix} if config.first_dense_layers else {}),
         "layers": layers,
         "final_norm": (None,),
-        "lm_head": ("embed", "vocab"),
+        **({} if config.tie_embeddings else {"lm_head": ("embed", "vocab")}),
     }
 
 
@@ -483,6 +523,8 @@ def _norm_shapes(config: TransformerConfig) -> dict:
     elif config.qk_norm:
         shapes = _projection_shapes(config)
         norms.update(q_norm=shapes["wq"][1], k_norm=shapes["wk"][1])
+    elif config.qk_head_norm:
+        norms.update(q_norm=config.head_dim, k_norm=config.head_dim)
     return norms
 
 
@@ -547,14 +589,16 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
             if config.layer_pattern else {**attention(keys, nl), **mlp}
         ),
         "final_norm": jnp.ones((d,), dt),
-        "lm_head": dense(next(keys), d, config.vocab_size, scale=d ** -0.5),
     }
+    if not config.tie_embeddings:
+        params["lm_head"] = dense(next(keys), d, config.vocab_size, scale=d ** -0.5)
     if config.moe and config.moe.shared_experts and not config.layer_pattern:
         params["layers"].update(shared(keys, nl))
-    if prefix and config.layer_pattern and config.first_dense_kind == "linear":
+    if prefix and config.layer_pattern and config.first_dense_kind != "full":
         wide_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
+        mixer = _linear_mixer_leaves if config.first_dense_kind == "linear" else _conv_mixer_leaves
         params["dense_layers"] = {
-            **_linear_mixer_leaves(config, wide_keys, dense, prefix),
+            **mixer(config, wide_keys, dense, prefix),
             **swiglu(wide_keys, prefix, width=config.hidden_dim),
         }
     elif prefix:
@@ -609,6 +653,23 @@ def _linear_mixer_leaves(config, keys, dense, *lead) -> dict:
     }
 
 
+def _conv_mixer_leaves(config, keys, dense, *lead) -> dict:
+    """A "conv" layer's own leaves and its two block norms, ``lead`` the
+    stacking dims: ``W_in`` ``[hidden, 3 hidden]`` (the chunks B, C, x in
+    that order), the filters ``[taps, hidden]`` uniform in +-taps^-1/2 (a
+    depthwise Conv1d's default), ``W_out`` ``[hidden, hidden]``."""
+    d, taps = config.dim, config.conv_kernel
+    return {
+        "attn_norm": jnp.ones((*lead, d), config.dtype),
+        "mlp_norm": jnp.ones((*lead, d), config.dtype),
+        "w_in": dense(next(keys), *lead, d, 3 * d),
+        "conv": jax.random.uniform(
+            next(keys), (*lead, taps, d), jnp.float32, -taps ** -0.5, taps ** -0.5,
+        ).astype(config.dtype),
+        "w_out": dense(next(keys), *lead, d, d),
+    }
+
+
 def _init_patterned_layers(config, key, dense, swiglu, attention, experts, shared) -> dict:
     """``{kind: leaves of [periods, count in a period, ...]}`` for a
     ``layer_pattern``; each kind draws from a split of its own, and a
@@ -623,6 +684,8 @@ def _init_patterned_layers(config, key, dense, swiglu, attention, experts, share
         keys = iter(jax.random.split(kind_key, 16))
         if kind == "full":
             leaves = attention(keys, *lead)
+        elif kind == "conv":
+            leaves = _conv_mixer_leaves(config, keys, dense, *lead)
         else:
             leaves = _linear_mixer_leaves(config, keys, dense, *lead)
         if config.moe:
@@ -675,14 +738,18 @@ def _qkv(h, layer, config: TransformerConfig):
     """The q / k / v projections of the normed ``h`` as [batch, heads, seq,
     head_dim], before RoPE. With ``qk_norm`` an RMSNorm with a learned
     weight runs over the whole projected q and k vectors, before the split
-    into heads. Shared by training, the pipeline stages and decode."""
+    into heads; with ``qk_head_norm`` one with a weight of ``head_dim`` over
+    each head, after it. Shared by training, the pipeline stages and decode."""
     batch, seq, _ = h.shape
 
     def project(weight, heads, norm=None):
         x = h @ layer[weight]
         if config.qk_norm and norm:
             x = _rmsnorm_ckpt(x, layer[norm], config.rms_norm_eps)
-        return x.reshape(batch, seq, heads, config.head_dim)
+        x = x.reshape(batch, seq, heads, config.head_dim)
+        if config.qk_head_norm and norm:
+            x = _rmsnorm_ckpt(x, layer[norm], config.rms_norm_eps)
+        return x
 
     q = project("wq", config.n_heads, "q_norm")
     k = project("wk", config.n_kv_heads, "k_norm")
@@ -720,18 +787,19 @@ def _latent_qkv(h, layer, config: TransformerConfig, cos_sin, positions):
         return q, k, kv[..., nope:]
 
 
-def _short_conv(x, filters):
-    """``SiLU(conv(x))``: a causal depthwise convolution over time, one
-    filter ``filters[:, c]`` a channel, no bias; the LAST tap multiplies the
-    current token (a Conv1d padded on the left). ``x``: [batch, seq,
-    channels]; float32 math, the model dtype's residency. In XLA: the
-    ``attention="reference"`` path, and the oracle of the kernels that
-    compute it everywhere else (ops/short_conv.py)."""
+def _short_conv(x, filters, activation="silu"):
+    """``SiLU(conv(x))``, or ``conv(x)`` with ``activation=None``: a causal
+    depthwise convolution over time, one filter ``filters[:, c]`` a channel,
+    no bias; the LAST tap multiplies the current token (a Conv1d padded on
+    the left). ``x``: [batch, seq, channels]; float32 math, the model
+    dtype's residency. In XLA: the ``attention="reference"`` path, and the
+    oracle of the kernels that compute it everywhere else
+    (ops/short_conv.py)."""
     taps, seq = filters.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
     filters = filters.astype(jnp.float32)
     out = sum(padded[:, j:j + seq] * filters[j] for j in range(taps))
-    return jax.nn.silu(out).astype(x.dtype)
+    return (jax.nn.silu(out) if activation == "silu" else out).astype(x.dtype)
 
 
 def _per_data_shard(kernel: Callable, operands, result) -> Callable:
@@ -746,9 +814,9 @@ def _per_data_shard(kernel: Callable, operands, result) -> Callable:
     for axis in ("tp", "sp"):
         if dict(mesh.shape).get(axis, 1) > 1:
             raise NotImplementedError(
-                f"a layer_pattern with linear layers over a mesh with {axis} > 1 is not "
-                "written: the scan kernel runs per data shard (dp / fsdp) with every head "
-                "and the whole sequence"
+                f"a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
+                "not written: the scan and convolution kernels run per data shard (dp / "
+                "fsdp) with every head, every channel and the whole sequence"
             )
     spec = lambda dims: (
         jax.sharding.PartitionSpec() if dims is None else LogicalRules().spec(dims, mesh)
@@ -759,14 +827,16 @@ def _per_data_shard(kernel: Callable, operands, result) -> Callable:
     )
 
 
-def _short_conv_over_mesh(config: TransformerConfig) -> Callable:
-    """A linear layer's convolutions: ``_short_conv`` under
+def _short_conv_over_mesh(config: TransformerConfig, activation="silu") -> Callable:
+    """A linear or conv layer's convolutions: ``_short_conv`` under
     ``attention="reference"``, else the kernels of ops/short_conv.py, per
     data shard with the filters whole on each."""
     if config.attention == "reference":
-        return _short_conv
+        return functools.partial(_short_conv, activation=activation)
     rows = ("batch", None, None)
-    return _per_data_shard(short_conv, (rows, None), rows)
+    return _per_data_shard(
+        functools.partial(short_conv, activation=activation), (rows, None), rows
+    )
 
 
 def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
@@ -860,16 +930,39 @@ def _linear_mixer(h, layer, config: TransformerConfig):
         return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
 
 
+def _conv_mixer(h, layer, config: TransformerConfig):
+    """A "conv" layer's mixer on the branch input ``h`` [batch, seq,
+    hidden], before the residual add: a gated short convolution (LFM2)::
+
+        [B, C, x] = h W_in                  (three chunks of hidden, that order)
+        z_t = sum_j f_j (B * x)_{t - (taps - 1 - j)}     (zeros before the sequence)
+        out = (C * z) W_out
+
+    a causal depthwise convolution of ``conv_kernel`` taps a channel, no
+    bias, no activation. The two gates are XLA's, in the model dtype (as the
+    source rounds them); the convolution is the Mosaic kernel pair of
+    ops/short_conv.py (per data shard under a mesh), XLA's ``_short_conv``
+    under ``attention="reference"``."""
+    with jax.named_scope("conv_mixer"):
+        b, c, x = jnp.split(h @ layer["w_in"], 3, axis=-1)
+        with jax.named_scope("short_conv"):
+            z = _short_conv_over_mesh(config, activation=None)(b * x, layer["conv"])
+        return (c * z) @ layer["w_out"]
+
+
 def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
     """``x + mixer(norm(x))``, or under ``norm_placement="post"`` ``x +
     norm(mixer(x))``. The layer's own leaves say which mixer it is: one
-    with convolution filters is a linear-attention layer."""
+    with three convolution filters is a linear-attention layer, one with
+    ``w_in`` a gated short convolution."""
     post = config.norm_placement == "post"
     with jax.named_scope("attention"):
         batch, seq, d = x.shape
         h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
         if "conv_q" in layer:
             out = _linear_mixer(h, layer, config)
+        elif "w_in" in layer:
+            out = _conv_mixer(h, layer, config)
         else:
             if config.latent:
                 q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
@@ -1061,7 +1154,7 @@ def _moe_mlp(h, layer, config: TransformerConfig):
             weights, experts = jax.lax.top_k(scores, moe.top_k)  # [T, K]
         chosen = experts[:, :, None] == jnp.arange(moe.num_experts, dtype=experts.dtype)
         if moe.norm_topk_prob:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + moe.renorm_eps)
         if moe.routed_scaling != 1.0:
             weights = weights * moe.routed_scaling
         counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # [K, E]
@@ -1283,11 +1376,18 @@ def _embed(params, tokens):
         return params["embed"][tokens]
 
 
+def _lm_head(params, config: TransformerConfig):
+    """``[hidden, vocab]``: the head's matrix, the transposed embedding
+    under ``tie_embeddings`` (autodiff then sums the head's gradient into
+    the embedding's)."""
+    return params["embed"].T if config.tie_embeddings else params["lm_head"]
+
+
 def _head(params, x, config: TransformerConfig):
     """final_norm + lm_head: f32 logits."""
     with jax.named_scope("head"):
         x = rmsnorm_reference(x, params["final_norm"], eps=config.rms_norm_eps)
-        return (x @ params["lm_head"]).astype(jnp.float32)
+        return (x @ _lm_head(params, config)).astype(jnp.float32)
 
 
 def _remat_policy(remat: str) -> Callable:
@@ -1515,9 +1615,9 @@ def head_loss(
         weights = jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
     else:
         weights = mask.astype(jnp.float32) / jnp.maximum(jnp.sum(mask), 1.0)
-    return _head_loss(
-        config.rms_norm_eps, params["final_norm"], params["lm_head"], x, targets, weights
-    )
+    with jax.named_scope("head"):
+        lm_head = _lm_head(params, config)
+    return _head_loss(config.rms_norm_eps, params["final_norm"], lm_head, x, targets, weights)
 
 
 def loss_fn(
@@ -1559,6 +1659,8 @@ def config_num_params(config: TransformerConfig) -> int:
             + la.num_value_heads + decays + la.value_head_dim    # a_log, dt_bias, o_norm
             + 2 * d                                              # the two block norms
         )
+    # W_in, the filters, W_out, the two block norms
+    mixer["conv"] = 3 * d * d + config.conv_kernel * d + d * d + 2 * d
     dense_mlp = 3 * d * config.hidden_dim
     if config.moe:
         moe = config.moe
@@ -1581,7 +1683,7 @@ def config_num_params(config: TransformerConfig) -> int:
         )
     return (
         layers
-        + 2 * config.vocab_size * d  # embed + lm_head
+        + (1 if config.tie_embeddings else 2) * config.vocab_size * d  # embed (+ lm_head)
         + d  # final_norm
     )
 
@@ -1623,6 +1725,12 @@ def partition_stages(params: dict, config: TransformerConfig, num_stages: int) -
     updates compose to exactly the fused update.
     """
     _refuse_dense_prefix(config, "partition_stages")
+    if config.tie_embeddings:
+        raise NotImplementedError(
+            "partition_stages gives the embedding to the first stage and the head to the "
+            "last; a tied head (tie_embeddings) is ONE leaf with two uses: train it fused "
+            "(loss_fn)"
+        )
     if config.n_layers % num_stages != 0:
         raise ValueError(
             f"n_layers={config.n_layers} not divisible by {num_stages} stages"
@@ -1711,6 +1819,12 @@ def stage_forward(
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 def _refuse_latent_cache(config: TransformerConfig) -> None:
+    if config.layer_pattern and "conv" in config._kinds():
+        raise NotImplementedError(
+            "decode with conv layers needs a convolution-state cache beside the KV cache "
+            "(the last conv_kernel - 1 rows of the gated input B * x a conv layer), which "
+            "is not written yet"
+        )
     if config.layer_pattern:
         raise NotImplementedError(
             "decode with a layer_pattern needs a recurrent-state cache beside the KV cache "

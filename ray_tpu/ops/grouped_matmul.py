@@ -52,18 +52,25 @@ TILE = (512, 1024, 1024)
 def _tile(size: int, limit: int, least: int = 128) -> int:
     """The largest tile that divides ``size`` among ``limit``, ``limit / 2``,
     ... above ``least`` (the kernels want whole tiles in all three
-    dimensions). Where none does, ``size`` itself while it is at most one
-    and a half limits, then ``least`` if that divides it, else ``size``.
-    1408 = 11 x 128, Moonlight's expert width, is so one tile: as 128-wide
-    tiles it took 2.1 times as long in all six calls at [49152, 2048] x
-    [64, 2048, 1408] (PERF.md section 6, PR 30)."""
+    dimensions); where that is under half the limit, the largest multiple of
+    ``least`` under the limit that divides ``size``, if one is wider (1792 =
+    2 x 896, LFM2's expert width, where the halving finds 256: 256-wide
+    tiles ran the forward call at 29 % of the MXU, PERF.md section 6, PR
+    39). Where no share of the limit divides, ``size`` itself while it is at
+    most one and a half limits, then ``least`` if that divides it, else
+    ``size``. 1408 = 11 x 128, Moonlight's expert width, is so one tile: as
+    128-wide tiles it took 2.1 times as long in all six calls at [49152,
+    2048] x [64, 2048, 1408] (PERF.md section 6, PR 30)."""
     if size <= limit:
         return size
     tile = limit
-    while tile > least:
-        if size % tile == 0:
-            return tile
+    while tile > least and size % tile:
         tile //= 2
+    if tile > least:
+        if 2 * tile >= limit:
+            return tile
+        wider = (w for w in range(limit - limit % least, tile, -least) if size % w == 0)
+        return next(wider, tile)
     if 2 * size > 3 * limit and size % least == 0:
         return least
     return size

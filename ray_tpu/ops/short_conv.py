@@ -1,14 +1,17 @@
 """``SiLU(causal depthwise convolution over time)``, the short convolution
-in front of a linear-attention layer's q, k and v (Gated DeltaNet, Mamba):
-two Pallas TPU kernels, forward and a hand-written backward, each ONE pass
-over its operands.
+in front of a linear-attention layer's q, k and v (Gated DeltaNet, Mamba),
+and the same convolution with NO activation, the one inside a gated
+short-convolution mixer (LFM2: ``activation=None``): two Pallas TPU
+kernels, forward and a hand-written backward, each ONE pass over its
+operands.
 
 With ``x`` ``[batch, seq, channels]`` and one filter ``filters[:, c]`` of
 ``taps`` weights a channel, the LAST tap on the current token (a Conv1d
 padded on the left, no bias)::
 
     pre[t] = sum_j filters[j] x[t - (taps - 1 - j)]       (x[t < 0] = 0)
-    y[t]   = pre[t] sigmoid(pre[t])
+    y[t]   = pre[t] sigmoid(pre[t])                       (activation "silu")
+    y[t]   = pre[t]                                       (activation None)
 
 float32 arithmetic, the taps summed oldest first, one rounding to
 ``x.dtype`` at the end: what ``models/transformer.py::_short_conv`` computes
@@ -29,7 +32,8 @@ forward again: 80.3 ms a step of the Olmo-Hybrid cell for 9.7 ms of bytes
 * ``_short_conv_backward``: the same walk with the rows AFTER the block
   too. Nothing is kept from the forward but ``x`` and ``filters``: ``pre``
   is recomputed for the block and ``_HALO`` rows after it,
-  ``g = dy SiLU'(pre)``, ``dx[t] = sum_j filters[j] g[t + taps - 1 - j]``
+  ``g = dy SiLU'(pre)`` (``g = dy`` with no activation, and ``pre`` is not
+  computed), ``dx[t] = sum_j filters[j] g[t + taps - 1 - j]``
   (``g`` past the sequence's end is zero), and ``dfilters[j] = sum_t g[t]
   x[t - (taps - 1 - j)]`` accumulates in float32 in an output block that
   stays resident across the sequence axis (eight partial rows a tap,
@@ -100,7 +104,7 @@ def _conv(shifted, filters, keep):
     return pre
 
 
-def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps):
+def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps, activation):
     """``xe``: float32 scratch ``[_HALO + rows, lanes]``, the block under
     the last rows of the one before it."""
     rows = x_ref.shape[1]
@@ -113,7 +117,8 @@ def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps):
         start = pl.multiple_of(i * _STRIP, _STRIP)
         shifted = _shifted(xe[pl.ds(start, _HALO + _STRIP)], taps)
         pre = _conv(shifted, filters, slice(_HALO, None))
-        y_ref[0, pl.ds(start, _STRIP)] = (pre * jax.nn.sigmoid(pre)).astype(y_ref.dtype)
+        y = pre * jax.nn.sigmoid(pre) if activation == "silu" else pre
+        y_ref[0, pl.ds(start, _STRIP)] = y.astype(y_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, rows // _STRIP, one_strip, None)
@@ -129,7 +134,7 @@ def _fold(x):
 
 
 def _backward_kernel(before_ref, x_ref, after_ref, dy_ref, dy_after_ref, filters_ref,
-                     dx_ref, dfilters_ref, xe, dye, *, taps, seq):
+                     dx_ref, dfilters_ref, xe, dye, *, taps, seq, activation):
     """``xe``: float32 scratch ``[_HALO + rows + _HALO, lanes]``, the block
     between its neighbours' rows; ``dye``: ``[rows + _HALO, lanes]``, the
     block of ``dy`` over the rows after it. Rows outside the sequence are
@@ -160,9 +165,11 @@ def _backward_kernel(before_ref, x_ref, after_ref, dy_ref, dy_after_ref, filters
         start = pl.multiple_of(i * _STRIP, _STRIP)
         # rows start - _HALO .. start + _STRIP + _HALO of the block
         shifted = _shifted(xe[pl.ds(start, _STRIP + 2 * _HALO)], taps)
-        pre = _conv(shifted, filters, slice(_HALO, None))
-        sig = jax.nn.sigmoid(pre)
-        g = dye[pl.ds(start, _STRIP + _HALO)] * (sig * (1.0 + pre * (1.0 - sig)))
+        g = dye[pl.ds(start, _STRIP + _HALO)]
+        if activation == "silu":
+            pre = _conv(shifted, filters, slice(_HALO, None))
+            sig = jax.nn.sigmoid(pre)
+            g = g * (sig * (1.0 + pre * (1.0 - sig)))
         dx = None
         for j in range(taps):
             ahead = taps - 1 - j
@@ -214,15 +221,15 @@ def _padded(filters):
     return jnp.pad(filters.astype(jnp.float32), ((0, _HALO - taps), (0, 0)))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _short_conv_forward(x, filters, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "activation"))
+def _short_conv_forward(x, filters, *, interpret, activation="silu"):
     from jax.experimental.pallas import tpu as pltpu
 
     padded = _padded(filters)
     grid, block, before, _, taps = _layout(x, padded)
     _, rows, lanes = block.block_shape
     return pl.pallas_call(
-        functools.partial(_forward_kernel, taps=filters.shape[0]),
+        functools.partial(_forward_kernel, taps=filters.shape[0], activation=activation),
         grid=grid,
         in_specs=[before, block, taps],
         out_specs=block,
@@ -235,8 +242,8 @@ def _short_conv_forward(x, filters, *, interpret):
     )(x, x, padded)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _short_conv_backward(x, filters, dy, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "activation"))
+def _short_conv_backward(x, filters, dy, *, interpret, activation="silu"):
     """``dx`` in ``x``'s dtype and ``dfilters`` in float32."""
     from jax.experimental.pallas import tpu as pltpu
 
@@ -246,7 +253,7 @@ def _short_conv_backward(x, filters, dy, *, interpret):
     grid, block, before, after, taps_spec = _layout(x, padded)
     _, rows, lanes = block.block_shape
     dx, partial = pl.pallas_call(
-        functools.partial(_backward_kernel, taps=taps, seq=seq),
+        functools.partial(_backward_kernel, taps=taps, seq=seq, activation=activation),
         grid=grid,
         in_specs=[before, block, after, block, after, taps_spec],
         out_specs=[block, pl.BlockSpec((1, taps * _HALO, lanes), lambda b, c, s: (b, 0, c))],
@@ -266,26 +273,31 @@ def _short_conv_backward(x, filters, dy, *, interpret):
     return dx, partial.reshape(batch, taps, _HALO, channels).sum(axis=(0, 2))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _vjp(x, filters, interpret):
-    return _short_conv_forward(x, filters, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _vjp(x, filters, interpret, activation):
+    return _short_conv_forward(x, filters, interpret=interpret, activation=activation)
 
 
-def _vjp_fwd(x, filters, interpret):
-    return _short_conv_forward(x, filters, interpret=interpret), (x, filters)
+def _vjp_fwd(x, filters, interpret, activation):
+    y = _short_conv_forward(x, filters, interpret=interpret, activation=activation)
+    return y, (x, filters)
 
 
-def _vjp_bwd(interpret, kept, dy):
+def _vjp_bwd(interpret, activation, kept, dy):
     x, filters = kept
-    dx, dfilters = _short_conv_backward(x, filters, dy, interpret=interpret)
+    dx, dfilters = _short_conv_backward(
+        x, filters, dy, interpret=interpret, activation=activation
+    )
     return dx, dfilters.astype(filters.dtype)
 
 
 _vjp.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def short_conv(x, filters, *, interpret: bool | None = None):
-    """``SiLU(conv(x))`` of the module docstring. ``x``: [batch, seq,
-    channels]; ``filters``: [taps, channels]. Returns ``x``'s shape and
-    dtype; differentiable in both."""
-    return _vjp(x, filters, resolve_interpret(interpret))
+def short_conv(x, filters, *, activation: str | None = "silu", interpret: bool | None = None):
+    """``SiLU(conv(x))`` of the module docstring, or with ``activation=None``
+    ``conv(x)`` alone. ``x``: [batch, seq, channels]; ``filters``: [taps,
+    channels]. Returns ``x``'s shape and dtype; differentiable in both."""
+    if activation not in ("silu", None):
+        raise ValueError(f"unknown activation {activation!r}: 'silu' or None")
+    return _vjp(x, filters, resolve_interpret(interpret), activation)

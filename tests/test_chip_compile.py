@@ -162,6 +162,25 @@ def test_flash_two_head_dims_compile_for_v5e(one_chip):
     assert [g.shape[-1] for g in grads] == [192, 192, 128]
 
 
+def test_flash_at_head_size_64_compiles_for_v5e(one_chip):
+    """The LFM2 cell's call, ``[1, 32, 16384, 64]`` in bfloat16: a last
+    dimension of HALF a lane tile and a contraction that half-fills the MXU,
+    in the 1024 x 1024 blocks ``_block_sizes`` picks: forward, and forward +
+    dq + dkv, compile and fit the scoped VMEM; the head size is not padded
+    (every gradient comes back 64 wide)."""
+    from ray_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(16384, 16384, None, None, 64, jnp.bfloat16) == (1024, 1024)
+    shapes = [jax.ShapeDtypeStruct((1, 32, 16384, 64), jnp.bfloat16, sharding=one_chip)] * 3
+    assert _custom_calls(_flash, *shapes) == 1
+    text = jax.jit(_flash_grads).lower(*shapes).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 3
+    assert sum("_flash_forward" in name for name in calls) == 1
+    assert sum("_flash_backward" in name for name in calls) == 2      # dq, and dk + dv
+    assert [g.shape for g in jax.eval_shape(_flash_grads, *shapes)] == [(1, 32, 16384, 64)] * 3
+
+
 # What the rule's value-and-gradient program may hold beside its arguments
 # and results at the cell's size: two heads a call need 1.19 GiB (three 1.32,
 # six 1.79, all thirty at once 4.69: compiles for a described v5e, PR 33).
@@ -341,6 +360,38 @@ def test_short_conv_kernels_compile_for_v5e(one_chip, channels):
     assert sum("_short_conv_backward" in name for name in calls) == 1
 
 
+@pytest.mark.parametrize("activation", [None, "silu"])
+def test_short_conv_kernels_compile_at_the_conv_mixers_size_for_v5e(one_chip, activation):
+    """A gated short-convolution mixer's call at the LFM2 cell's size, ``[1,
+    16384, 2048]`` in bfloat16 with THREE taps and no activation (and the
+    SiLU form beside it at the same size): 16 lane tiles in blocks of 384 (the
+    last overhangs by a third), each kernel one Mosaic call under its own
+    name, two in a value-and-gradient program."""
+    from ray_tpu.ops import short_conv as SC
+
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.bfloat16, sharding=one_chip)
+    filters = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip)
+    assert SC._blocks(16384, 2048, jnp.bfloat16) == (1024, 384)
+    forward = functools.partial(SC._short_conv_forward, interpret=False, activation=activation)
+    backward = functools.partial(SC._short_conv_backward, interpret=False, activation=activation)
+    assert _mosaic_calls(jax.jit(forward).lower(x, filters).compile().as_text()) == [
+        "_short_conv_forward"
+    ]
+    assert _mosaic_calls(jax.jit(backward).lower(x, filters, x).compile().as_text()) == [
+        "_short_conv_backward"
+    ]
+
+    def value_and_grads(x, filters):
+        conv = functools.partial(SC.short_conv, interpret=False, activation=activation)
+        loss = lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, filters)
+
+    calls = _mosaic_calls(jax.jit(value_and_grads).lower(x, filters).compile().as_text())
+    assert len(calls) == 2
+    assert sum("_short_conv_forward" in name for name in calls) == 1
+    assert sum("_short_conv_backward" in name for name in calls) == 1
+
+
 def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
@@ -359,11 +410,13 @@ def _grouped_loss(lhs, rhs, group_sizes, tile=None):
 @pytest.mark.parametrize("rows,k,n", [
     (65536, 2048, 1024), (65536, 1024, 2048),     # OLMoE
     (49152, 2048, 1408), (49152, 1408, 2048),     # Moonlight: 1408 = 11 x 128
+    (65536, 2048, 1792), (65536, 1792, 2048),     # LFM2: 1792 = 2 x 896, tiles of 896
 ])
 def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
     """The expert matmuls at the benchmark cells' sizes: OLMoE's 65,536
-    (token, choice) rows over 64 experts of width 1024 and Moonlight's
-    49,152 over 64 of width 1408, gate / up and down, forward, and both
+    (token, choice) rows over 64 experts of width 1024, Moonlight's 49,152
+    over 64 of width 1408 and LFM2's 65,536 at width 1792 (in tiles of 896,
+    which must fit the scoped VMEM in all three calls), gate / up and down, forward, and both
     gradients (the input's is ``gmm`` on the transposed experts, the
     weights' is ``tgmm``), at the tiles ``grouped_matmul`` picks."""
     shapes = (

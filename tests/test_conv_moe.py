@@ -1,0 +1,451 @@
+"""The patterned decoder with gated short-convolution layers
+(``models/transformer.py`` with a ``layer_pattern`` whose "conv" layers are
+``_conv_mixer``, whose "full" layers are grouped-query attention under
+``qk_head_norm``, over sigmoid-and-bias routed experts of which a block is
+HELD, behind a dense prefix with a conv mixer, under ``tie_embeddings``:
+LFM2-8B-A1B's shape) against an oracle WRITTEN HERE: the same mathematics in
+plain ``jax.numpy`` on the program's own parameter tree, float32, no kernel,
+no sort, no scan, the experts a loop. On the CPU at tiny widths with seeded
+weights: a dense conv layer, then ONE period (full, conv, conv, conv), 8
+experts of which 4 are held, 2 a token, head size 16, a tied head.
+
+Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
+gradients 2e-3 (``tests/test_hybrid_moe.py``'s and for its reasons: both
+sides float32, sums in another order). A wrong term is off by far more.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from ray_tpu import train
+from ray_tpu.models import transformer as T
+from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils
+
+EPS, THETA, HELD = 1e-5, 1e6, (0, 4)
+MODEL = T.TransformerConfig(
+    vocab_size=256, dim=64, n_layers=5, n_heads=4, n_kv_heads=2, hidden_dim=96, max_seq=40,
+    rope_theta=THETA, rms_norm_eps=EPS, qk_head_norm=True, tie_embeddings=True,
+    dtype=jnp.float32, first_dense_layers=1, first_dense_kind="conv",
+    layer_pattern=("full", "conv", "conv", "conv"), conv_kernel=3,
+    moe=T.MoEConfig(
+        num_experts=8, top_k=2, norm_topk_prob=True, renorm_eps=1e-6, expert_dim=32,
+        scoring="sigmoid", routed_scaling=1.0, held=HELD,
+    ),
+)
+TOKENS = 80
+
+
+def seeded(model=MODEL, seed=3):
+    """Weights from the program's initialiser, every norm weight moved off 1
+    and the routers' biases off 0 (no gradient reaches them: seeded here)."""
+    params = jax.jit(lambda key: T.init_params(model, key))(jax.random.PRNGKey(seed))
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 32))
+    for tree in (params["dense_layers"], *params["layers"].values()):
+        for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
+            if name in tree:
+                tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
+        if "router_bias" in tree:
+            tree["router_bias"] = 0.1 * jax.random.normal(next(keys), tree["router_bias"].shape)
+    params["final_norm"] = params["final_norm"] + 0.2 * jax.random.normal(next(keys), (64,))
+    return params
+
+
+def ids(seed=1, batch=2, seq=40):
+    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
+
+
+def close(got, want, tol, what=""):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert got.shape == want.shape and np.all(np.isfinite(got)), what
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
+        what, np.max(np.abs(got - want)), np.max(np.abs(want))
+    )
+
+
+# -- the oracle ---------------------------------------------------------------
+def _norm(x, weight):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + EPS) * weight
+
+
+def _rope(x):
+    """Rotate-half over the whole head. x: [batch, seq, heads, head]."""
+    seq, head = x.shape[1], x.shape[3]
+    inv = THETA ** (-jnp.arange(0, head, 2, dtype=jnp.float32) / head)
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None, :]
+    turned = jnp.concatenate([-x[..., head // 2:], x[..., :head // 2]], axis=-1)
+    return x * cos + turned * sin
+
+
+def _oracle_conv_mixer(h, layer):
+    b, c, u = jnp.split(h @ layer["w_in"], 3, axis=-1)
+    gated, taps, seq = b * u, layer["conv"].shape[0], h.shape[1]
+    z = 0.0
+    for j in range(taps):                                # tap j reads taps - 1 - j tokens back
+        back = taps - 1 - j
+        z = z + layer["conv"][j] * jnp.pad(gated, ((0, 0), (back, 0), (0, 0)))[:, :seq]
+    return (c * z) @ layer["w_out"]
+
+
+def _oracle_attention(h, layer, model, per_head=True):
+    batch, seq, _ = h.shape
+    heads, kv, hd = model.n_heads, model.n_kv_heads, model.head_dim
+    q = (h @ layer["wq"]).reshape(batch, seq, heads, hd)
+    k = (h @ layer["wk"]).reshape(batch, seq, kv, hd)
+    v = (h @ layer["wv"]).reshape(batch, seq, kv, hd)
+    if per_head:
+        q, k = _norm(q, layer["q_norm"]), _norm(k, layer["k_norm"])
+    q, k = _rope(q), _rope(k)
+    k, v = (jnp.repeat(t, heads // kv, axis=2) for t in (k, v))   # KV head j: query heads j * group ..
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) * hd ** -0.5
+    visible = jnp.arange(seq)[None, :] <= jnp.arange(seq)[:, None]
+    probs = jax.nn.softmax(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(batch, seq, heads * hd)
+    return out @ layer["wo"]
+
+
+def _oracle_swiglu(h, gate, up, down):
+    return (jax.nn.silu(h @ gate) * (h @ up)) @ down
+
+
+def _oracle_route(h, layer, moe):
+    scores = jax.nn.sigmoid(h @ layer["router"])
+    chosen = jax.lax.top_k(scores + layer["router_bias"], moe.top_k)[1]
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return chosen, weights * moe.routed_scaling
+
+
+def _oracle_experts(h, layer, moe):
+    """The HELD experts' part of the weighted sum: a loop, every expert
+    applied to all tokens, weight 0 where the token did not choose it."""
+    chosen, weights = _oracle_route(h, layer, moe)
+    first, count = moe.held or (0, moe.num_experts)
+    out = 0.0
+    for e in range(count):
+        weight = jnp.sum(jnp.where(chosen == first + e, weights, 0.0), axis=-1)
+        out = out + weight[..., None] * _oracle_swiglu(
+            h, layer["w_gate"][e], layer["w_up"][e], layer["w_down"][e]
+        )
+    return out
+
+
+def oracle_logits(params, tokens, model=MODEL):
+    x = params["embed"][tokens]
+
+    def layer_forward(x, layer):
+        h = _norm(x, layer["attn_norm"])
+        mix = _oracle_conv_mixer(h, layer) if "w_in" in layer else _oracle_attention(h, layer, model)
+        x = x + mix
+        h = _norm(x, layer["mlp_norm"])
+        if "router" in layer:
+            return x + _oracle_experts(h, layer, model.moe)
+        return x + _oracle_swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
+
+    at = lambda tree, *index: jax.tree.map(lambda leaf: leaf[index], tree)
+    x = layer_forward(x, at(params["dense_layers"], 0))
+    taken = dict.fromkeys(model.layer_pattern, 0)
+    for kind in model.layer_pattern:
+        x = layer_forward(x, at(params["layers"][kind], 0, taken[kind]))
+        taken[kind] += 1
+    return _norm(x, params["final_norm"]) @ params["embed"].T
+
+
+def oracle_loss(params, tokens, targets, model=MODEL):
+    logp = jax.nn.log_softmax(oracle_logits(params, tokens, model), axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return seeded()
+
+
+# -- the model ----------------------------------------------------------------
+def test_the_tree_is_stacked_by_period_and_counted(params):
+    assert (MODEL.periods, MODEL.first_dense_kind, MODEL.head_dim) == (1, "conv", 16)
+    assert "lm_head" not in params                                   # tied
+    assert params["dense_layers"]["w_in"].shape == (1, 64, 192)
+    assert params["dense_layers"]["conv"].shape == (1, 3, 64)
+    assert params["dense_layers"]["w_gate"].shape == (1, 64, 96)     # the dense SwiGLU
+    assert params["layers"]["conv"]["w_out"].shape == (1, 3, 64, 64)
+    assert params["layers"]["conv"]["w_gate"].shape == (1, 3, 4, 64, 32)     # the HELD experts
+    assert params["layers"]["conv"]["router"].shape == (1, 3, 64, 8)         # all are scored
+    assert params["layers"]["full"]["q_norm"].shape == (1, 1, 16)            # one weight a head dim
+    assert "wq" not in params["layers"]["conv"] and "w_in" not in params["layers"]["full"]
+    assert T.config_num_params(MODEL) == T.num_params(params)
+    untied = dataclasses.replace(MODEL, tie_embeddings=False)
+    assert T.config_num_params(untied) - T.config_num_params(MODEL) == 256 * 64
+    dims = T.param_logical_dims(MODEL)
+    is_dims = lambda x: isinstance(x, tuple)
+    assert jax.tree.structure(dims, is_leaf=is_dims) == jax.tree.structure(params)
+    for leaf, names in zip(jax.tree.leaves(params), jax.tree.leaves(dims, is_leaf=is_dims)):
+        assert leaf.ndim == len(names)
+
+
+def test_logits_match_the_oracle_on_both_paths(params):
+    x = ids()
+    with jax.default_matmul_precision("highest"):
+        want = oracle_logits(params, x)
+    for attention in ("flash", "reference"):
+        model = dataclasses.replace(MODEL, attention=attention)
+        got, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, model))(params, x)
+        close(got, want, 5e-4, attention)
+        assert routing["experts"].shape == (4, TOKENS, 2)
+        assert all(0 < int(n) < TOKENS * 2 for n in routing["held_pairs"])
+
+
+def test_loss_and_every_gradient_leaf_match_the_oracle(params):
+    x, y = ids(), ids(seed=2)
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = jax.value_and_grad(oracle_loss)(params, x, y)
+    for attention, remat in (("flash", None), ("flash", "full"), ("reference", None)):
+        model = dataclasses.replace(MODEL, attention=attention, remat=remat)
+        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), (attention, remat)
+        assert jax.tree.structure(grads) == jax.tree.structure(want_grads)
+        mine, theirs = (jax.tree_util.tree_leaves_with_path(g) for g in (grads, want_grads))
+        for (path, leaf), (_, wanted) in zip(mine, theirs):
+            name = jax.tree_util.keystr(path)
+            if "router_bias" in name:                    # a buffer: no gradient on either side
+                assert not np.any(np.asarray(leaf)) and not np.any(np.asarray(wanted)), name
+            else:
+                close(leaf, wanted, 2e-3, (attention, remat, name))
+
+
+def test_the_embeddings_gradient_is_the_sum_of_its_two_uses(params):
+    """The tied model against the SAME weights untied (``lm_head`` the
+    transposed table): the table's gradient is the gather's plus the head's,
+    and each of the two is far from the sum."""
+    x, y = ids(), ids(seed=2)
+    tied = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, MODEL)))(params)["embed"]
+    untied_model = dataclasses.replace(MODEL, tie_embeddings=False)
+    untied = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, untied_model)))(
+        dict(params, lm_head=params["embed"].T)
+    )
+    close(tied, untied["embed"] + untied["lm_head"].T, 1e-5, "gather + head")
+    for part in (untied["embed"], untied["lm_head"].T):
+        assert np.max(np.abs(np.asarray(tied - part))) > 0.1 * np.max(np.abs(np.asarray(tied)))
+    logits = jax.jit(lambda p, t: T.forward(p, t, MODEL))(params, x)
+    head = T.rmsnorm_reference(
+        jax.jit(lambda p, t: T._hidden_with_routing(p, t, MODEL)[0])(params, x),
+        params["final_norm"], eps=EPS,
+    ) @ params["embed"].T
+    close(logits, head, 1e-5, "the logits go through the transposed table")
+
+
+def test_the_conv_mixer_is_its_three_equations(params):
+    """One layer's mixer by hand on a short sequence: zeros before the
+    sequence, the LAST tap on the current token, no activation; the kernels'
+    path and XLA's agree with it."""
+    layer = jax.tree.map(lambda leaf: leaf[0], params["dense_layers"])
+    h = jax.random.normal(jax.random.PRNGKey(7), (2, 40, 64))
+    with jax.default_matmul_precision("highest"):
+        b, c, u = np.split(np.asarray(h @ layer["w_in"], np.float64), 3, axis=-1)
+        f = np.asarray(layer["conv"], np.float64)
+        gated = b * u
+        z = np.zeros_like(gated)
+        for t in range(40):
+            for j in range(3):
+                if t - (2 - j) >= 0:
+                    z[:, t] += f[j] * gated[:, t - (2 - j)]
+        want = (c * z) @ np.asarray(layer["w_out"], np.float64)
+        for attention in ("flash", "reference"):
+            model = dataclasses.replace(MODEL, attention=attention)
+            close(jax.jit(lambda h: T._conv_mixer(h, layer, model))(h), want, 2e-5, attention)
+        silu = T._short_conv(jnp.asarray(gated, jnp.float32), layer["conv"])
+        assert np.max(np.abs(np.asarray(silu) - z)) > 0.1 * np.max(np.abs(z))   # the activation is OFF
+
+
+def test_the_per_head_norm_is_not_the_whole_vector_norm(params):
+    """``qk_head_norm`` and ``qk_norm`` on the same weights (the head's
+    weight repeated over the heads for the whole-vector norm): each matches
+    its own formula and they differ."""
+    layer = jax.tree.map(lambda leaf: leaf[0, 0], params["layers"]["full"])
+    h = jax.random.normal(jax.random.PRNGKey(8), (2, 40, 64))
+    q_head, k_head, _ = T._qkv(h, layer, MODEL)
+    whole = dataclasses.replace(MODEL, qk_head_norm=False, qk_norm=True)
+    tiled = dict(layer, q_norm=jnp.tile(layer["q_norm"], 4), k_norm=jnp.tile(layer["k_norm"], 2))
+    q_whole, k_whole, _ = T._qkv(h, tiled, whole)
+    by_head = lambda x, heads: x.reshape(2, 40, heads, 16).transpose(0, 2, 1, 3)
+    with jax.default_matmul_precision("highest"):
+        q, k = h @ layer["wq"], h @ layer["wk"]
+        close(q_head, by_head(_norm(q.reshape(2, 40, 4, 16), layer["q_norm"]).reshape(2, 40, 64), 4), 1e-5)
+        close(k_head, by_head(_norm(k.reshape(2, 40, 2, 16), layer["k_norm"]).reshape(2, 40, 32), 2), 1e-5)
+        close(q_whole, by_head(_norm(q, tiled["q_norm"]), 4), 1e-5)
+        close(k_whole, by_head(_norm(k, tiled["k_norm"]), 2), 1e-5)
+    assert np.max(np.abs(np.asarray(q_head - q_whole))) > 0.05 * np.max(np.abs(np.asarray(q_head)))
+    with pytest.raises(ValueError, match="qk_norm.*qk_head_norm"):
+        dataclasses.replace(MODEL, qk_norm=True)
+
+
+def _expert_layer(params, held):
+    """Layer ``conv[0, 0]``'s leaves as a model holding ``held`` would store
+    them, of ALL 8 experts' seeded weights."""
+    layer = jax.tree.map(lambda leaf: leaf[0, 0], params["layers"]["conv"])
+    key = jax.random.PRNGKey(11)
+    full = {
+        name: jax.random.normal(jax.random.fold_in(key, n), (8, *layer[name].shape[1:]))
+        * layer[name].shape[1] ** -0.5
+        for n, name in enumerate(("w_gate", "w_up", "w_down"))
+    }
+    first, count = held or (0, 8)
+    share = dict(layer, **{name: full[name][first:first + count] for name in full})
+    return share, dataclasses.replace(MODEL, moe=dataclasses.replace(MODEL.moe, held=held))
+
+
+def test_the_shares_add_up(params):
+    """Held (0, 4) + held (4, 4) of 8 equal the uncut layer; there is no
+    shared expert, so nothing is counted twice."""
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, 40, 64))
+    routed, held_pairs = 0.0, 0
+    for held in ((0, 4), (4, 4)):
+        share, model = _expert_layer(params, held)
+        out, routing = jax.jit(lambda h, l: T._moe_mlp(h, l, model))(h, share)
+        with jax.default_matmul_precision("highest"):
+            close(out, _oracle_experts(h, share, model.moe), 2e-5, held)
+        routed, held_pairs = routed + out, held_pairs + int(routing["held_pairs"])
+    assert held_pairs == TOKENS * 2                      # every pair is some share's
+    whole, model = _expert_layer(params, None)
+    uncut, routing = jax.jit(lambda h, l: T._moe_mlp(h, l, model))(h, whole)
+    assert "held_pairs" not in routing
+    close(routed, uncut, 2e-5, "two shares")
+    with jax.default_matmul_precision("highest"):
+        close(routed, _oracle_experts(h, whole, model.moe), 2e-5, "the uncut oracle")
+    assert np.max(np.abs(np.asarray(out - uncut))) > 1e-2 * np.max(np.abs(np.asarray(uncut)))
+
+
+def test_the_expert_bias_changes_the_choice_and_not_the_weights(params):
+    h = jax.random.normal(jax.random.PRNGKey(6), (2, 40, 64))
+    layer, model = _expert_layer(params, None)
+    route = jax.jit(lambda l: T._moe_mlp(h, l, model)[1])
+    plain = route(dict(layer, router_bias=jnp.zeros(8)))
+    pushed = route(dict(layer, router_bias=jnp.zeros(8).at[5].set(10.0)))
+    assert np.all(np.any(np.asarray(pushed["experts"]) == 5, axis=-1))      # every token takes 5
+    assert not np.all(np.any(np.asarray(plain["experts"]) == 5, axis=-1))
+    with jax.default_matmul_precision("highest"):
+        scores = jax.nn.sigmoid(h.reshape(TOKENS, 64) @ layer["router"])
+    for routing in (plain, pushed):
+        own = jnp.take_along_axis(scores, routing["experts"], axis=-1)      # WITHOUT the bias
+        close(routing["weights"], own / (own.sum(-1, keepdims=True) + 1e-6), 1e-5)
+    # the default epsilon is DeepSeek-V3's: the field is what differs
+    assert T.MoEConfig().renorm_eps == 1e-20 and model.moe.renorm_eps == 1e-6
+
+
+def test_the_expert_width_of_1792_is_tiled_in_two_and_no_other_cell_moves():
+    """``ops/grouped_matmul.py``'s tile choice at each MoE cell's three
+    calls (forward and weight gradient ``(m, k, n)``, input gradient ``(m,
+    n, k)``): 1792 = 2 x 896 where halving from 1024 found 256; OLMoE's,
+    Moonlight's and Ling's tiles are what they were."""
+    from ray_tpu.ops.grouped_matmul import _tile, _tiling
+
+    assert (_tiling(65536, 2048, 1792), _tiling(65536, 1792, 2048)) == (
+        (512, 1024, 896), (512, 896, 1024))
+    assert (_tiling(65536, 2048, 1024), _tiling(65536, 1024, 2048)) == (
+        (512, 1024, 1024), (512, 1024, 1024))
+    assert (_tiling(49152, 2048, 1408), _tiling(49152, 1408, 2048)) == (
+        (256, 1024, 1408), (256, 1408, 1024))
+    assert (_tiling(131072, 2560, 768), _tiling(131072, 768, 2560)) == (
+        (512, 512, 768), (512, 768, 512))
+    # half the limit is kept as it is; where no share of the limit divides, the old rule
+    assert _tile(2560, 1024) == 512 and _tile(1152, 1024) == 1152 and _tile(4224, 1024) == 128
+
+
+def test_a_changed_term_moves_the_logits(params):
+    """What the comparison above would catch, term by term: each reads over
+    a hundred tolerances against the oracle."""
+    x = ids()
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(oracle_logits(params, x))
+    replace = dataclasses.replace
+    for what, model, weights in (
+        ("silu after the convolution", None, None),
+        ("no per-head norm", replace(MODEL, qk_head_norm=False), params),
+        ("another block held", replace(MODEL, moe=replace(MODEL.moe, held=(4, 4))), params),
+        ("no renormalisation", replace(MODEL, moe=replace(MODEL.moe, norm_topk_prob=False)), params),
+    ):
+        if model is None:
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(T, "_short_conv_over_mesh", lambda config, activation: T._short_conv)
+                got = T.forward(params, x, replace(MODEL, attention="reference"))
+        else:
+            got = jax.jit(lambda p, t: T.forward(p, t, model))(weights, x)
+        off = np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want))
+        assert off > 100 * 5e-4, (what, off)
+
+
+def test_what_this_model_cannot_do_yet_is_refused_by_name(params):
+    with pytest.raises(NotImplementedError, match="convolution-state cache"):
+        T.init_kv_cache(MODEL, 1, 16)
+    with pytest.raises(NotImplementedError, match="convolution-state cache"):
+        T.decode_step(params, {}, ids(batch=1, seq=1), MODEL)
+    with pytest.raises(NotImplementedError, match="partition_stages.*layer_pattern"):
+        T.partition_stages(params, MODEL, 2)
+    plain = T.TransformerConfig.tiny(tie_embeddings=True)
+    with pytest.raises(NotImplementedError, match="tied head"):
+        T.partition_stages(T.init_params(plain, jax.random.PRNGKey(0)), plain, 2)
+    for axis in ("tp", "sp"):
+        mesh = jax.sharding.AbstractMesh((2, 2), ("dp", axis))
+        with jax.sharding.use_abstract_mesh(mesh), pytest.raises(
+            NotImplementedError, match=f"{axis} > 1"
+        ):
+            jax.eval_shape(lambda p, t: T.forward(p, t, MODEL), params, ids())
+    with pytest.raises(ValueError, match="kinds are"):
+        dataclasses.replace(MODEL, layer_pattern=("full", "convolution"))
+    with pytest.raises(ValueError, match="kinds are"):
+        dataclasses.replace(MODEL, first_dense_kind="convolution")
+
+
+def test_a_tied_head_decodes_through_the_kv_cache():
+    """Decode refuses a conv layer, not a tied head: a grouped-query model
+    with a tied head and the per-head norm decodes what ``forward`` gives."""
+    model = T.TransformerConfig.tiny(tie_embeddings=True, qk_head_norm=True, max_seq=16)
+    params = T.init_params(model, jax.random.PRNGKey(0))
+    x = ids(batch=1, seq=6) % model.vocab_size
+    want = T.forward(params, x, dataclasses.replace(model, attention="reference"))
+    cache = T.init_kv_cache(model, 1, 16)
+    for t in range(6):
+        logits, cache = T.decode_step(params, cache, x[:, t:t + 1], model)
+        close(logits, want[:, t], 1e-4, t)
+
+
+def _conv_moe_loop(config):
+    model = dataclasses.replace(MODEL, remat="full")
+    optimizer = optax.adamw(3e-3)
+    setup = jax_utils.setup_sharded_training(
+        lambda: T.init_params(model, jax.random.PRNGKey(0)), optimizer,
+        logical_dims=T.param_logical_dims(model),
+    )
+    step = jax_utils.build_sharded_train_step(
+        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], model), optimizer, setup
+    )
+    x = np.asarray(ids(seed=8, batch=4, seq=41))
+    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
+    params, opt_state = setup.params, setup.opt_state
+    for _ in range(config["steps"]):
+        params, opt_state, loss = step(params, opt_state, batch)
+        train.report({"loss": float(loss), "factorization": setup.factorization})
+
+
+def test_the_tiny_preset_trains_through_jax_trainer(ray_start_shared, tmp_path):
+    """The normal path: JaxTrainer -> setup_sharded_training ->
+    build_sharded_train_step -> loss_fn, over a dp 2 x fsdp 2 mesh (the
+    convolution kernels, flash and the held experts' block per data shard
+    under shard_map; the tied table sharded once), full remat."""
+    trainer = JaxTrainer(
+        _conv_moe_loop,
+        train_loop_config={"steps": 3},
+        scaling_config=ScalingConfig(num_workers=1, mesh_axes={"dp": 2, "fsdp": 2}),
+        run_config=RunConfig(name="conv-moe", storage_path=str(tmp_path)),
+    )
+    result = trainer.fit()
+    assert result.error is None, result.error
+    assert result.metrics["factorization"] == {"dp": 2, "fsdp": 2, "tp": 1, "pp": 1}
+    losses = [m["loss"] for m in result.metrics_history]
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]

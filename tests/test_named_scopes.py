@@ -52,7 +52,7 @@ MOONLIGHT_SHAPED = dict(
 # A patterned model over expert layers (Ling-3.0-flash-VL's shape): a dense
 # prefix with a linear mixer, then (linear, full) over group-routed experts of
 # which a block is held; what it names beyond the vocabularies above.
-NEW_SCOPES = ("decay_prepare", "attn_gate")
+NEW_SCOPES = ("decay_prepare", "attn_gate", "conv_mixer")
 NEW = re.compile(r"(?:^|[/(])(" + "|".join(NEW_SCOPES) + r")(?:[/)]|$)")
 LINEAR = re.compile(r"(?:^|[/(])(" + "|".join(T.LINEAR_SCOPES) + r")(?=[/)]|$)")
 LING_SHAPED = dict(
@@ -74,9 +74,21 @@ ONE_DEVICE = (("dp", 1),)
 MESH_2X2 = (("fsdp", 2), ("tp", 2))
 
 
+# Gated short convolutions over held experts (LFM2-8B-A1B's shape): a dense
+# prefix with a conv mixer, then (full, conv) with per-head q / k norms, a tied
+# head; what it names is ``conv_mixer`` and, inside it, ``short_conv``.
+LFM2_SHAPED = dict(
+    n_layers=3, hidden_dim=160, first_dense_layers=1, first_dense_kind="conv",
+    layer_pattern=("full", "conv"), qk_head_norm=True, tie_embeddings=True, rms_norm_eps=1e-5,
+    moe=T.MoEConfig(
+        num_experts=8, top_k=2, norm_topk_prob=True, renorm_eps=1e-6, expert_dim=32,
+        scoring="sigmoid", held=(0, 4)),
+)
+
+
 @functools.lru_cache(maxsize=None)
 def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False,
-                 ling=False):
+                 ling=False, lfm2=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
@@ -86,8 +98,12 @@ def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True
     to ``nothing_saveable``, what it was before it kept the flash
     kernel's residuals; ``latent`` the Moonlight-shaped tiny configuration
     (latent attention, a dense first layer, sigmoid-routed experts with
-    shared experts); ``ling`` the patterned one over held experts."""
-    shaped = LING_SHAPED if ling else MOONLIGHT_SHAPED if latent else OLMOE_SHAPED if moe else {}
+    shared experts); ``ling`` the patterned one over held experts; ``lfm2``
+    the one with conv mixers under a tied head."""
+    shaped = (
+        LFM2_SHAPED if lfm2 else LING_SHAPED if ling else MOONLIGHT_SHAPED if latent
+        else OLMOE_SHAPED if moe else {}
+    )
     config = T.TransformerConfig.tiny(remat=remat, **shaped)
     assert config.attention == "flash"
     optimizer = optax.adamw(1e-3)
@@ -328,6 +344,31 @@ def test_a_patterned_model_over_experts_names_its_new_work(remat, scope, inside)
     assert [n for n in matmuls if LATENT.search(n) and LATENT.search(n).group(1) == "shared"]
 
 
+@pytest.mark.parametrize("remat", POLICIES)
+def test_a_conv_layer_names_its_mixer(remat):
+    """``conv_mixer`` lies inside ``attention`` and holds the mixer's two
+    matmuls (``W_in``, ``W_out``) forward and backward; the convolution
+    inside it is under ``short_conv`` too; the attention layer of the same
+    period and the experts carry neither; the tied head's matmuls are under
+    ``head``."""
+    named = instructions(remat, lfm2=True)
+    matmuls = [n for op, n in named if op in ("dot", "convolution") and n]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    mine = [n for _op, n in named if NEW.search(n)]
+    assert mine and {NEW.search(n).group(1) for n in mine} == {"conv_mixer"}
+    assert [n for n in mine if "transpose(" not in n] and [n for n in mine if "transpose(" in n]
+    assert all(BLOCKS.search(n).group(1) == "attention" for n in mine)
+    assert len([n for n in matmuls if NEW.search(n)]) >= 2 * 3     # two layers x (fwd, dx, dw)
+    inside = [n for n in mine if LINEAR.search(n)]
+    assert inside and {LINEAR.search(n).group(1) for n in inside} == {"short_conv"}
+    assert all(NEW.search(n) for _op, n in named if LINEAR.search(n))
+    assert {MOE.search(n).group(1) for n in matmuls if MOE.search(n)} == {"router", "experts"}
+    assert [n for n in matmuls if BLOCKS.search(n).group(1) == "head"]
+    scoped = instructions(None, lfm2=True)
+    plain = instructions(None, scoped=False, lfm2=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+
+
 def test_the_new_scopes_change_names_never_the_program():
     scoped, plain = instructions(None, ling=True), instructions(None, scoped=False, ling=True)
     assert [op for op, _ in scoped] == [op for op, _ in plain]
@@ -344,7 +385,8 @@ def test_vocabulary():
     assert T.LATENT_SCOPES == ("latent", "shared")
     assert not set(T.LATENT_SCOPES) & (set(T.SCOPES) | set(T.MOE_SCOPES))
     # benchmarks/harness/linear_scopes.py repeats these; the two names PR 36
-    # added (read by name: harness/named_scope.py) are in none of the four
+    # added and PR 39's ``conv_mixer`` (read by name: harness/named_scope.py)
+    # are in none of the four
     assert T.LINEAR_SCOPES == ("linear_attention", "short_conv", "delta_rule", "gate_norm")
     assert not set(NEW_SCOPES) & (
         set(T.SCOPES) | set(T.MOE_SCOPES) | set(T.LATENT_SCOPES) | set(T.LINEAR_SCOPES)

@@ -87,6 +87,67 @@ def test_kernels_against_the_oracle(batch, seq, channels, taps, dtype):
             )
 
 
+@pytest.mark.parametrize("batch,seq,channels,taps,dtype", [
+    (1, 1024, 256, 3, "float32"),     # a conv mixer's 3 taps; the row block divides the sequence
+    (2, 1100, 256, 3, "float32"),     # ... and does not: the last block is masked
+    (1, 1100, 640, 3, "bfloat16"),    # two channel blocks, the last overhangs
+    (3, 200, 128, 4, "bfloat16"),
+    (1, 1040, 96, 4, "float32"),
+    (1, 7, 128, 3, "float32"),        # shorter than a sublane tile
+])
+def test_kernels_with_no_activation_against_the_oracle(batch, seq, channels, taps, dtype):
+    """``activation=None`` (a gated short-convolution mixer's): value,
+    ``dx`` and ``dfilters`` against XLA's ``_short_conv`` with the
+    activation off, which is linear, and NOT what the SiLU kernels give."""
+    dtype = jnp.dtype(dtype)
+    x, filters, dy = _inputs(batch, seq, channels, taps, dtype)
+    plain = lambda x, filters: short_conv(x, filters, activation=None)
+    oracle = lambda x, filters: _short_conv(x, filters, activation=None)
+    got = _value_and_grads(plain, x, filters, dy)
+    want = _value_and_grads(oracle, x, filters, dy)
+    assert [g.dtype for g in got] == [dtype, dtype, jnp.float32]
+    step = 2.0 ** -7 if dtype == jnp.bfloat16 else 2e-6
+    _close(got[0], want[0], step, "value")
+    _close(got[1], want[1], step, "dx")
+    _close(got[2], want[2], 1e-5, "dfilters")
+    # linear: twice the input, twice the output (float32: exactly)
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(np.asarray(plain(2 * x, filters)), 2 * np.asarray(got[0]))
+    with_silu = short_conv(x, filters)
+    assert np.abs(np.asarray(with_silu, np.float32) - np.asarray(got[0], np.float32)).max() > 0.1
+    with pytest.raises(ValueError, match="unknown activation"):
+        short_conv(x, filters, activation="gelu")
+
+
+def _summed_in_bfloat16(x, filters):
+    """The convolution with every product and every partial sum rounded to
+    bfloat16: the nearest precision below the kernels' float32."""
+    taps, seq = filters.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.bfloat16)
+    out = None
+    for j in range(taps):
+        term = padded[:, j:j + seq] * filters[j].astype(jnp.bfloat16)
+        out = term if out is None else out + term
+    return out.astype(x.dtype)
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+def test_taps_summed_in_bfloat16_fail_the_float32_limit(taps):
+    """The limit that holds the kernels to their oracle on float32 operands
+    (2e-6 of the largest value) is one a bfloat16 sum does NOT pass, by three
+    orders: what ``benchmarks/reference/conv_moe_decoder.py::check_conv``
+    rests on at the cell's size."""
+    x, filters, _ = _inputs(1, 1100, 256, taps, jnp.float32, seed=2)
+    want = np.asarray(_short_conv(x, filters, activation=None), np.float64)
+    kernel = np.asarray(short_conv(x, filters, activation=None), np.float64)
+    rounded = np.asarray(_summed_in_bfloat16(x, filters), np.float64)
+    worst = lambda got: np.abs(got - want).max() / np.abs(want).max()
+    assert worst(kernel) <= 2e-6
+    assert worst(rounded) > 1e-3
+    with pytest.raises(AssertionError, match="limit"):
+        _close(rounded, want, 2e-6, "value")
+
+
 def test_checkpointed_gradients_are_the_plain_ones():
     """Nothing but ``x`` and ``filters`` goes from the forward to the
     backward, so a ``jax.checkpoint`` that saves nothing hands the backward
