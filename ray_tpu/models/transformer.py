@@ -23,7 +23,8 @@ checkpoint policy, one head and loss):
     chooses among all ``num_experts``, and the layer computes its own
     experts' part of the weighted sum for the (token, choice) pairs that
     chose them (a chip's share of a layer whose experts are divided over
-    chips, without the exchange: an absent pair adds nothing).
+    chips, without the exchange: an absent pair adds nothing), in row
+    buffers sized for what held experts get (``held_row_bound``).
   * the fourth shape, a hybrid (Olmo-Hybrid): ``layer_pattern`` names one
     PERIOD of unlike layers, "linear" ones three to one with "full" ones.
     A linear layer's mixer (``linear=``, ``_linear_mixer``) is Gated
@@ -111,6 +112,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import (
     RESIDUAL_NAMES, attention_reference, flash_attention,
@@ -119,7 +121,7 @@ from ray_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as DELTA_RULE_RESIDUAL_NAMES,
     gated_delta_rule, gated_delta_rule_reference, kept_bytes,
 )
-from ray_tpu.ops.grouped_matmul import grouped_matmul
+from ray_tpu.ops.grouped_matmul import TILE, grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.ops.short_conv import short_conv
@@ -138,6 +140,11 @@ SCOPES = ("embed", "attention", "mlp", "head", "loss", "optimizer")
 # expert, gather the rows, weigh and sum them back per token), "experts"
 # (the three grouped matmuls and _silu_mul).
 MOE_SCOPES = ("router", "dispatch", "experts")
+# What a checkpointed layer keeps of a mixture-of-experts block under ``held``
+# (``_remat_policy``): the router's choice ``[tokens, top_k]`` and the held
+# pairs' order by expert and then by token, ``[bound]`` each, int32, so that
+# the backward's second forward runs neither the top-k's nor the sorts again.
+MOE_RESIDUAL_NAMES = ("moe_choice", "moe_order")
 # What a DeepSeek-V3-shaped layer names besides: "latent", inside
 # "attention" (what latent attention costs beside W_q, W_o and the kernels:
 # the W_kv_a projection, the latent norm, W_kv_b, the rope on the shared
@@ -209,7 +216,10 @@ class MoEConfig:
     # the (token, choice) pairs that chose them; a pair whose expert is
     # absent adds nothing. This chip's share of a layer whose experts are
     # divided over chips, WITHOUT the exchange that would bring it the other
-    # chips' tokens: the expert leaves are ``[count, ...]``.
+    # chips' tokens: the expert leaves are ``[count, ...]``. The block's row
+    # buffers follow it (``held_row_bound``, from the shapes alone: no field
+    # chooses the path); ``routing["held_pairs"]`` and ``["overflow"]`` count
+    # what a routing sent here and whether that passed the bound.
     held: tuple[int, int] | None = None
 
     def __post_init__(self):
@@ -1096,6 +1106,327 @@ def _within_best_groups(biased, moe: MoEConfig):
     return jnp.where(kept[:, :, None], by_group, -jnp.inf).reshape(biased.shape)
 
 
+# ``held_row_bound``: how many times an even routing's share of the pairs the
+# row buffers of a block that holds some of the experts have room for.
+_HELD_ROWS_OVER_EVEN = 8
+
+
+def held_row_bound(tokens: int, top_k: int, held: int, num_experts: int) -> int:
+    """Rows of the buffers ``_moe_mlp`` moves when it holds ``held`` of the
+    ``num_experts`` the router scores: eight times what an even routing
+    sends to the held experts, ``E = tokens * top_k * held / num_experts``,
+    rounded up to the grouped matmuls' row tile (to 8 under one tile) and
+    never above ``tokens * top_k``, the worst case's rows. From the shapes
+    alone: one rule for every configuration. A layer whose held pairs
+    exceed it takes the worst case's path (``routing["overflow"]``); where
+    it IS the worst case (an eighth of the experts held, or more) there is
+    one path, the worst case's.
+
+    Why eight and not two: a router that trains while only the held experts
+    add to the output learns to choose them. On one fixed batch a layer's
+    held pairs went from 1.0 E to 4.8 E in twenty steps at 16 of 512 held,
+    and from 50 % of all pairs to 90 % in fifty at 16 of 32 (PERF.md
+    section 6, PR 40)."""
+    pairs = tokens * top_k
+    bound = _HELD_ROWS_OVER_EVEN * (pairs * held // num_experts)
+    tile = TILE[0] if bound > TILE[0] else 8
+    return min(pairs, -(-bound // tile) * tile)
+
+
+def _sum_into_tokens(tokens, rows, token, by_token):
+    """``rows`` [bound, d] float32 summed into their ``token``s, float32
+    ``[tokens, d]``: a scatter-add of ``bound`` rows, taken in the order of
+    their tokens (``by_token`` sorts ``token``). XLA's scatter wants sorted
+    indices and otherwise sorts them itself and permutes the rows inside the
+    scatter's own fusion, where the permutation cost 9.1 ms a call at Ling's
+    32,768 rows and a third of that, as an instruction of its own, at 35,840
+    (PERF.md section 6, PR 40): the barrier keeps it one."""
+    ordered = jax.lax.optimization_barrier(rows[by_token])
+    into = jnp.zeros((tokens, rows.shape[-1]), jnp.float32)
+    return into.at[token[by_token]].add(ordered, indices_are_sorted=True)
+
+
+@jax.custom_vjp
+def _rows_of_held(x, token, by_token, covered):
+    """``x`` [tokens, d] -> [bound, d]: row ``i`` is the token of the
+    ``i``-th pair in expert order, the held pairs first. The transpose sums
+    the ``covered`` rows (the held pairs') into their tokens in float32
+    (``_sum_into_tokens``); the rows behind them, which no tile of the
+    grouped matmuls wrote a cotangent for, are kept out of the sum."""
+    return x[token]
+
+
+def _rows_of_held_fwd(x, token, by_token, covered):
+    return x[token], (token, by_token, covered, x.shape[0])
+
+
+def _rows_of_held_bwd(residuals, g):
+    token, by_token, covered, tokens = residuals
+    held = jnp.where(covered[:, None], g, 0).astype(jnp.float32)
+    return _sum_into_tokens(tokens, held, token, by_token).astype(g.dtype), None, None, None
+
+
+_rows_of_held.defvjp(_rows_of_held_fwd, _rows_of_held_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sum_by_token(tokens, y, weight, token, by_token, covered):
+    """``y`` [bound, d] in expert order, each row times its pair's
+    ``weight``, summed into its ``token``, float32 ``[tokens, d]``:
+    ``_weighted_sum`` taken from the held pairs' rows alone (float32
+    products and sums, ``_sum_into_tokens``; an absent pair adds nothing).
+    The rows that are not ``covered`` were written by no tile and are
+    selected out, here and in both cotangents. Backward keeps ``y`` in the
+    model dtype, and gathers the cotangent's rows in it: the cotangent of a
+    sum that is cast to the model dtype loses nothing there."""
+    weighted = y.astype(jnp.float32) * weight.astype(jnp.float32)[:, None]
+    return _sum_into_tokens(tokens, jnp.where(covered[:, None], weighted, 0), token, by_token)
+
+
+def _sum_by_token_fwd(tokens, y, weight, token, by_token, covered):
+    out = _sum_by_token(tokens, y, weight, token, by_token, covered)
+    return out, (y, weight, token, covered)
+
+
+def _sum_by_token_bwd(tokens, residuals, g):
+    y, weight, token, covered = residuals
+    g = g.astype(y.dtype)[token].astype(jnp.float32)              # [bound, d]
+    dy = jnp.where(covered[:, None], g * weight.astype(jnp.float32)[:, None], 0)
+    dweight = jnp.where(covered, jnp.sum(y.astype(jnp.float32) * g, axis=-1), 0)
+    return dy.astype(y.dtype), dweight.astype(weight.dtype), None, None, None
+
+
+_sum_by_token.defvjp(_sum_by_token_fwd, _sum_by_token_bwd)
+
+
+def _expert_mlps(rows, experts, group_sizes, stacks):
+    """The experts' SwiGLU on ``rows`` sorted into ``group_sizes``."""
+
+    def expert(rows, name):
+        return grouped_matmul(rows, experts[name], group_sizes, within=stacks.get(name))
+
+    with jax.named_scope("experts"):
+        return expert(_silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
+
+
+def _by_every_pair(top_k, ht, weights, experts, sorting, stacks):
+    """The experts' weighted sum per token through buffers of the worst
+    case's ``tokens * top_k`` rows: the gather by pair in expert order, the
+    grouped matmuls, the gather back to (token, choice) order and
+    ``_weighted_sum``. Under ``held`` (``sorting`` has ``covered``
+    ``[T*K, 1]``, the rows some held expert's group covers) two selects
+    zero the rows behind the held groups, which no tile wrote, and their
+    cotangents."""
+    tokens, d = ht.shape
+    covered = sorting.get("covered")
+    with jax.named_scope("dispatch"):
+        rows = _rows_by_expert(top_k, ht, sorting["order"], sorting["inverse"])    # [T*K, d]
+        if covered is not None:
+            rows = jnp.where(covered, rows, 0)
+    out = _expert_mlps(rows, experts, sorting["group_sizes"], stacks)
+    with jax.named_scope("dispatch"):
+        if covered is not None:
+            out = jnp.where(covered, out, 0)
+        per_token = _rows_by_token(out, sorting["order"], sorting["inverse"])
+        return _weighted_sum(per_token.reshape(tokens, top_k, d), weights.astype(ht.dtype))
+
+
+# Pairs a row of ``_first_of_the_order``'s sort holds.
+_SORTED_ROW = 1024
+
+
+def _first_of_the_order(sort_by, held, bound):
+    """The first ``bound`` entries of the pairs' stable order by ``sort_by``
+    (``[pairs]``: 0 .. ``held`` - 1 a held expert's number, ``held`` an
+    absent pair), WITHOUT a sort of all the pairs: the pairs are sorted in
+    rows of ``_SORTED_ROW`` (expert and pair number packed into one int32,
+    so stable), and entry ``i`` is found through two small tables, how many
+    pairs of each expert the rows before a row hold and where an expert's
+    run starts in a row. An entry behind the held pairs is some pair's
+    number, no matter whose (``covered`` masks it). One sort of ``[131072]``
+    keys and values is 0.70 MB of a program's cache entry on a v5e, and a
+    step holds it once an expert layer; the rows' sort is 0.16 (PERF.md
+    section 6, PR 40)."""
+    pairs = sort_by.shape[0]
+    index = jnp.arange(pairs, dtype=jnp.int32)
+    if (held + 1) * pairs >= 2**31:
+        return jax.lax.sort((sort_by, index), num_keys=1, is_stable=True)[1][:bound]
+    width = _SORTED_ROW if pairs % _SORTED_ROW == 0 else pairs
+    packed = jax.lax.sort((sort_by * pairs + index).reshape(-1, width), dimension=1)
+    expert, pair = packed // pairs, packed % pairs
+    experts = jnp.arange(held, dtype=jnp.int32)
+    in_row = jnp.sum(expert[:, :, None] == experts, axis=1, dtype=jnp.int32)     # [rows, held]
+    through_row = jnp.cumsum(in_row, axis=0)                   # ... in this row and those before
+    run_starts = jnp.cumsum(in_row, axis=1) - in_row           # where an expert's run starts in a row
+    ends = jnp.cumsum(through_row[-1])
+    slot = jnp.arange(bound, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(slot[:, None] >= ends, axis=1, dtype=jnp.int32), held - 1)
+    rank = slot - (ends - through_row[-1])[group]              # the slot's place in its group
+    row = jnp.sum(rank[:, None] >= through_row.T[group], axis=1, dtype=jnp.int32)
+    row = jnp.minimum(row, in_row.shape[0] - 1)
+    column = run_starts[row, group] + rank - (through_row - in_row)[row, group]
+    return pair.reshape(-1)[jnp.clip(row * width + column, 0, pairs - 1)]
+
+
+def _past(bound, sorting):
+    """Whether this routing's held pairs are more than ``bound`` rows hold."""
+    return sorting["held_pairs"] > bound
+
+
+def _by_held_pair(top_k, bound, ht, weights, experts, sorting, stacks):
+    """The same sum, in float32, through buffers of ``bound`` rows, for a
+    routing whose held pairs fit them: the sorted order's first ``bound``
+    pairs are the held ones and then absent ones; their tokens' rows are
+    gathered, the grouped matmuls run over the groups, and the weighted rows
+    are summed into their tokens from there. No array has ``tokens * top_k``
+    rows and more than one column, forward or backward. A routing with more
+    held pairs gets zeros (every weight is selected to 0, so that every
+    gradient is 0 too; the groups are cut at the bound for the kernels'
+    sake): ``_held_experts`` then takes the worst case's path."""
+    with jax.named_scope("dispatch"):
+        pair = sorting["order"]                                  # [bound]: the held pairs first
+        token = jax.lax.div(pair, jnp.int32(top_k))
+        covered = jnp.arange(bound, dtype=jnp.int32) < sorting["held_pairs"]
+        ends = jnp.cumsum(sorting["group_sizes"])
+        starts = ends - sorting["group_sizes"]
+        group_sizes = jnp.minimum(ends, bound) - jnp.minimum(starts, bound)
+        by_token = sorting["by_token"]
+        rows = _rows_of_held(ht, token, by_token, covered)        # [bound, d]
+    out = _expert_mlps(rows, experts, group_sizes, stacks)
+    with jax.named_scope("dispatch"):
+        weight = weights.astype(ht.dtype).reshape(-1)[pair]
+        weight = jnp.where(_past(bound, sorting), 0, weight)
+        return _sum_by_token(ht.shape[0], out, weight, token, by_token, covered)
+
+
+def _by_held_expert(first_expert, expert, ht, weights, chosen, w_gate_up, w_down):
+    """One held expert's part of the sum, float32 ``[tokens, d]``, the plain
+    way: its SwiGLU over EVERY token (``w_gate_up`` ``[2, d, width]``: gate
+    and up as one matmul), times the weight of the token's choice of it (0
+    where it was not chosen). No sort, no gather: a trip of the worst case's
+    loop (``_held_experts``)."""
+    with jax.named_scope("dispatch"):
+        mine = chosen == first_expert + expert
+        share = jnp.sum(jnp.where(mine, weights.astype(ht.dtype), 0).astype(jnp.float32), axis=-1)
+    with jax.named_scope("experts"):
+        both = jnp.einsum("td,gdf->tgf", ht, w_gate_up).astype(ht.dtype)
+        out = _silu_mul(both[:, 0], both[:, 1]) @ w_down
+    with jax.named_scope("dispatch"):
+        return share[:, None] * out.astype(jnp.float32)
+
+
+def _experts_like(stacks):
+    """A stand-in for each expert leaf, from its stack's shape: what the
+    kernels take as ``rhs`` beside ``within`` (whose value they never read,
+    and whose cotangent is the leaf's)."""
+    with jax.named_scope("experts"):
+        return {
+            name: jnp.zeros(stack.shape[1:], stack.dtype) for name, (stack, _) in stacks.items()
+        }
+
+
+def _one_experts_weights(stacks, expert):
+    """``(w_gate_up [2, d, width], w_down)`` of held expert ``expert``, out
+    of the stacks."""
+    with jax.named_scope("experts"):
+        gate, up, down = (stacks[name][0][stacks[name][1], expert] for name in _EXPERT_WEIGHTS)
+        return jnp.stack([gate, up]), down
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, stacks):
+    """The held experts' weighted sum per token, ``[tokens, d]``: through
+    buffers of ``bound`` rows (``_by_held_pair``) for a routing whose held
+    pairs fit them, else through the worst case's own path, a loop over the
+    held experts, each dense over every token (``_by_held_expert``). The
+    device's own count decides, as the loop's trip count: NO trip for a
+    routing that fits, ``held`` trips past the bound, where the bounded
+    path's weights are selected to zero. Exact for every routing, one
+    program; nothing is dropped, capped or approximated, and the step a
+    balanced router takes moves ``bound`` rows and no more. Past the bound a
+    layer costs about what it cost before there was a bound (three dense
+    matmuls an expert over all tokens: 16 x 16,384 rows where the worst
+    case's buffers moved 131,072), a token's float32 additions come in
+    another order and ``ht``'s gradient is summed over the experts in the
+    model dtype.
+
+    Why a loop and not ``lax.cond``, why dense and not a grouped kernel
+    (compiles of Ling's step for a described v5e, PERF.md section 6, PR 40):
+    with a conditional in the period's body, even one whose branches do
+    nothing, XLA gave up rematerialising the step around it and asked for
+    17.8 GB where the loop's form needs 14.4; and the path has to be SMALL in
+    generated code, since that cell's cache entries fit the machine's cache
+    by 8.8 MB: ``jax.lax.ragged_dot`` over the worst case's buffer (+7.0 MB
+    on the step's 112.8 MB entry, and 15.7 GB), dense products in windows
+    (+16.4) and this loop in its first form (+12.9) were all compiled; this
+    one, with the choice and the order kept by name and
+    ``_first_of_the_order`` in place of a whole sort, adds 2.2.
+
+    ``experts`` is here for its cotangent alone: both paths read the weights
+    in ``stacks`` (name -> (stack, layer), ``grouped_matmul``'s ``within``),
+    the bounded one through stand-ins whose cotangent is the leaves'.
+
+    Keeps nothing from forward to backward but its arguments: the backward
+    runs each path again and pulls the cotangent back through it (under a
+    layer checkpoint that IS the recomputation, and the checkpoint's own is
+    dead code). Autodiff cannot go back through a loop of a dynamic trip
+    count, and its rule for ``cond`` hands the union of both branches'
+    residuals out of the forward."""
+    del experts
+    out = _by_held_pair(top_k, bound, ht, weights, _experts_like(stacks), sorting, stacks)
+    held = sorting["group_sizes"].shape[0]
+
+    def one_expert(expert, out):
+        return out + _by_held_expert(
+            first_expert, expert, ht, weights, sorting["chosen"],
+            *_one_experts_weights(stacks, expert),
+        )
+
+    with jax.named_scope("dispatch"):
+        trips = jnp.where(_past(bound, sorting), held, 0)
+        out = jax.lax.fori_loop(0, trips, one_expert, out)
+    return out.astype(ht.dtype)
+
+
+def _held_experts_fwd(top_k, bound, first_expert, ht, weights, experts, sorting, stacks):
+    out = _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, stacks)
+    return out, (ht, weights, sorting, stacks)
+
+
+def _held_experts_bwd(top_k, bound, first_expert, operands, g):
+    ht, weights, sorting, stacks = operands
+    g = g.astype(jnp.float32)                 # the paths' sums are float32
+    _, pull = jax.vjp(
+        lambda *over: _by_held_pair(top_k, bound, *over, sorting, stacks),
+        ht, weights, _experts_like(stacks),
+    )
+    held = sorting["group_sizes"].shape[0]
+
+    def one_expert(expert, grads):
+        dht, dweights, dexperts = grads
+        _, pull = jax.vjp(
+            lambda *over: _by_held_expert(
+                first_expert, expert, *over[:2], sorting["chosen"], *over[2:]
+            ),
+            ht, weights, *_one_experts_weights(stacks, expert),
+        )
+        more_dht, more_dweights, dgate_up, ddown = pull(g)
+        dexperts = {
+            name: jax.lax.dynamic_update_index_in_dim(dexperts[name], dleaf, expert, 0)
+            for name, dleaf in zip(_EXPERT_WEIGHTS, (*dgate_up, ddown))
+        }
+        return dht + more_dht, dweights + more_dweights, dexperts
+
+    grads = pull(g)
+    with jax.named_scope("dispatch"):
+        trips = jnp.where(_past(bound, sorting), held, 0)
+        grads = jax.lax.fori_loop(0, trips, one_expert, grads)
+    return (*grads, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def _moe_mlp(h, layer, config: TransformerConfig):
     """Dropless mixture of experts: every token reaches each of its
     ``top_k`` experts whatever the routing. Returns ``(out, routing)``.
@@ -1116,16 +1447,25 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     normalised score, summed over sequences), ``counts`` [top_k, experts]
     (tokens whose j-th choice is expert e), ``experts`` and ``weights``
     [tokens, top_k] (the choices and their weights), and under ``held``
-    ``held_pairs`` (the pairs whose expert lives here).
+    ``held_pairs`` (the pairs whose expert lives here) and ``overflow`` (1
+    where they were more than the row bound).
 
     With ``moe.held`` the router still scores and chooses among ALL
     ``num_experts``; the pairs are sorted by the held experts' own numbers
     with every absent pair behind them, the grouped matmuls run over the
     held groups alone (rows behind the last group are touched by no tile)
-    and those rows are zero going in and coming out, so an absent pair adds
-    nothing to the sum and nothing to a gradient. The row buffers keep the
-    worst case's size, ``tokens x top_k``: exact for every routing, one
-    program; nothing stands in for the chips that hold the other experts.
+    and those rows are selected out of every sum, so an absent pair adds
+    nothing to the output and nothing to a gradient. The row buffers are
+    sized for what held experts get, ``held_row_bound`` rows (eight times an
+    even routing's held pairs: 25 % of ``tokens x top_k`` at 16 held of
+    512), and no array on the path a step takes has
+    ``tokens x top_k`` rows and more than one column; a routing that sends
+    the held experts more takes the worst case's own path inside
+    ``_held_experts``, and ``routing["overflow"]`` is 1 for that layer:
+    exact for every routing, one program; nothing stands in for the chips
+    that hold the other experts. Where the bound IS ``tokens x top_k`` (an
+    eighth of the experts held, or more) the block is ``_by_every_pair``,
+    the one path of a layer that holds every expert.
 
     One device's view: ``h`` and the experts are whole here. Under a mesh
     ``_moe_over_mesh`` calls this once per data shard.
@@ -1140,6 +1480,10 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     batch, seq, d = h.shape
     tokens = batch * seq
     ht = h.reshape(tokens, d)
+    bound = tokens * moe.top_k
+    if moe.held:
+        bound = held_row_bound(tokens, moe.top_k, moe.held[1], moe.num_experts)
+    bounded = bound < tokens * moe.top_k
     with jax.named_scope("router"):
         logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
         if moe.scoring == "sigmoid":
@@ -1148,6 +1492,8 @@ def _moe_mlp(h, layer, config: TransformerConfig):
             if moe.n_group > 1:
                 biased = _within_best_groups(biased, moe)
             _, experts = jax.lax.top_k(biased, moe.top_k)
+            if bounded:
+                experts = checkpoint_name(experts, MOE_RESIDUAL_NAMES[0])
             weights = jnp.take_along_axis(scores, experts, axis=-1)
         else:
             scores = jax.nn.softmax(logits, axis=-1)             # [T, E]
@@ -1178,27 +1524,34 @@ def _moe_mlp(h, layer, config: TransformerConfig):
             sort_by = jnp.where(here, experts - first, held)    # absent pairs last
             group_sizes = group_sizes[first:first + held]
             routing["held_pairs"] = jnp.sum(group_sizes)
-            # [T*K, 1]: the rows some held expert's group covers
-            covered = (pairs < routing["held_pairs"])[:, None]
-        _, order = jax.lax.sort((sort_by.reshape(-1), pairs), num_keys=1, is_stable=True)
-        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
-        rows = _rows_by_expert(moe.top_k, ht, order, inverse)    # [T*K, d]
-        if moe.held:
-            rows = jnp.where(covered, rows, 0)
-    in_stack = layer.get("stack", {})
-
-    def expert(rows, name):
-        return grouped_matmul(rows, layer[name], group_sizes, within=in_stack.get(name))
-
-    with jax.named_scope("experts"):
-        out = expert(_silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
-    with jax.named_scope("dispatch"):
-        if moe.held:
-            # no tile wrote the rows behind the last group, and none of their
-            # cotangents: both selects keep what is there out of the sums
-            out = jnp.where(covered, out, 0)
-        per_token = _rows_by_token(out, order, inverse)
-        out = _weighted_sum(per_token.reshape(tokens, moe.top_k, d), weights.astype(h.dtype))
+            routing["overflow"] = _past(bound, routing).astype(jnp.int32)
+        if bounded:
+            order = _first_of_the_order(sort_by.reshape(-1), held, bound)
+            # the same rows by token, for the sums into tokens (_sum_into_tokens)
+            _, by_token = jax.lax.sort(
+                (jax.lax.div(order, jnp.int32(moe.top_k)), pairs[:bound]), num_keys=1
+            )
+            order, by_token = checkpoint_name((order, by_token), MOE_RESIDUAL_NAMES[1])
+            sorting = {
+                "order": order, "by_token": by_token,
+                "group_sizes": group_sizes, "held_pairs": routing["held_pairs"], "chosen": experts,
+            }
+        else:
+            _, order = jax.lax.sort((sort_by.reshape(-1), pairs), num_keys=1, is_stable=True)
+            _, inverse = jax.lax.sort((order, pairs), num_keys=1)
+            sorting = {"order": order, "inverse": inverse, "group_sizes": group_sizes}
+            if moe.held:
+                sorting["covered"] = (pairs < routing["held_pairs"])[:, None]
+    expert_weights = {name: layer[name] for name in _EXPERT_WEIGHTS}
+    stacks = layer.get("stack", {})
+    if bounded:
+        # a layer that comes with no stack is a stack of one
+        stacks = stacks or {
+            name: (jax.lax.stop_gradient(leaf)[None], 0) for name, leaf in expert_weights.items()
+        }
+        out = _held_experts(moe.top_k, bound, first, ht, weights, expert_weights, sorting, stacks)
+    else:
+        out = _by_every_pair(moe.top_k, ht, weights, expert_weights, sorting, stacks)
     return out.reshape(batch, seq, d), routing
 
 
@@ -1214,7 +1567,9 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     shard's work: right on every mesh, and as fast as data parallelism
     alone. The all_to_all exchange that would keep ``ep`` shards of the
     experts in place is not written yet. ``prob_sum`` and ``counts`` are
-    summed over the data shards, so the balancing loss sees every token.
+    summed over the data shards, so the balancing loss sees every token;
+    so are ``held_pairs`` and ``overflow`` (each shard holds its own count
+    against its own bound: the data shards that passed theirs).
 
     One layer's experts enter, the scan's slice, not the scan's stack
     (``layer["stack"]`` stays outside): replicating the stack would gather
@@ -1229,7 +1584,7 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     def per_shard(h, experts):
         out, routing = _moe_mlp(h, experts, config)
         if shards:
-            for name in ("prob_sum", "counts", "held_pairs"):
+            for name in ("prob_sum", "counts", "held_pairs", "overflow"):
                 if name in routing:
                     routing[name] = jax.lax.psum(routing[name], shards)
         return out, routing
@@ -1246,7 +1601,7 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
         out_specs=(rows, {
             "prob_sum": whole, "counts": whole,
             "experts": per_token, "weights": per_token,
-            **({"held_pairs": whole} if config.moe.held else {}),
+            **({"held_pairs": whole, "overflow": whole} if config.moe.held else {}),
         }),
         check_vma=False,
     )(h, experts)
@@ -1397,7 +1752,9 @@ def _remat_policy(remat: str) -> Callable:
     forward kernels again too), and under "dots" the matmul outputs
     besides."""
     policies = jax.checkpoint_policies
-    flash = policies.save_only_these_names(*RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES)
+    flash = policies.save_only_these_names(
+        *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES
+    )
     if remat == "full":
         return flash
     if remat == "dots":

@@ -65,9 +65,21 @@ LING_SHAPED = dict(
         kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         output_gate="head"),
     moe=T.MoEConfig(
-        num_experts=16, top_k=2, norm_topk_prob=True, expert_dim=32, shared_experts=1,
+        num_experts=64, top_k=2, norm_topk_prob=True, expert_dim=32, shared_experts=1,
         scoring="sigmoid", routed_scaling=2.5, n_group=4, topk_group=2, held=(4, 4)),
 )
+
+
+# Under ``held`` the worst case's path is a loop inside ``dispatch`` (a held
+# expert a trip, ``_held_experts``): its matmuls lie under ``dispatch`` first.
+_WORST_CASE = re.compile(r"/mlp/dispatch/while/body/")
+
+
+def _bounded_matmuls_scopes(matmuls):
+    """The expert scopes of the matmuls outside the worst case's loop."""
+    return {
+        MOE.search(n).group(1) for n in matmuls if MOE.search(n) and not _WORST_CASE.search(n)
+    }
 
 
 ONE_DEVICE = (("dp", 1),)
@@ -340,7 +352,7 @@ def test_a_patterned_model_over_experts_names_its_new_work(remat, scope, inside)
     # the preparation's matmuls (A, P, T's levels, W, U0) are under the scope
     assert [n for n in matmuls if NEW.search(n) and NEW.search(n).group(1) == "decay_prepare"]
     # the experts' scopes and ``shared`` are there under the pattern too
-    assert {MOE.search(n).group(1) for n in matmuls if MOE.search(n)} == {"router", "experts"}
+    assert _bounded_matmuls_scopes(matmuls) == {"router", "experts"}
     assert [n for n in matmuls if LATENT.search(n) and LATENT.search(n).group(1) == "shared"]
 
 
@@ -362,11 +374,42 @@ def test_a_conv_layer_names_its_mixer(remat):
     inside = [n for n in mine if LINEAR.search(n)]
     assert inside and {LINEAR.search(n).group(1) for n in inside} == {"short_conv"}
     assert all(NEW.search(n) for _op, n in named if LINEAR.search(n))
-    assert {MOE.search(n).group(1) for n in matmuls if MOE.search(n)} == {"router", "experts"}
+    assert _bounded_matmuls_scopes(matmuls) == {"router", "experts"}
     assert [n for n in matmuls if BLOCKS.search(n).group(1) == "head"]
     scoped = instructions(None, lfm2=True)
     plain = instructions(None, scoped=False, lfm2=True)
     assert [op for op, _ in scoped] == [op for op, _ in plain]
+
+
+@pytest.mark.parametrize("remat", POLICIES)
+def test_the_worst_case_in_its_loop_names_its_work(remat):
+    """Under ``held`` (4 of 64 here: row buffers of half the pairs) the rows
+    behind the bound go through a loop of as many trips as the routing asks
+    for (none where it fits), forward and again in the backward of
+    ``_held_experts``: no conditional in ``mlp``, and every instruction of
+    the loops lies under ``dispatch``, their matmuls (``experts`` inside it)
+    and their own sums too: the benchmark's ``harness/moe_scopes.classify``
+    reads the outermost scope, so a trace that shows a trip reads as
+    dispatch time. The bounded path, outside the loops, keeps ``dispatch``
+    and ``experts`` apart, forward and backward. Half of the experts held
+    (the conv model's 4 of 8) is the worst case's one path: no loop."""
+    named = instructions(remat, ling=True)
+    assert not [n for op, n in named if op == "conditional" and n.endswith("/mlp/cond")]
+    in_loop = [n for _op, n in named if _WORST_CASE.search(n)]
+    matmuls = [n for op, n in named if op == "dot" and _WORST_CASE.search(n)]
+    assert matmuls and all("experts" in n for n in matmuls)
+    assert [n for n in in_loop if "transpose(" not in n]
+    assert [n for n in in_loop if "transpose(" in n]
+    assert {MOE.search(n).group(1) for n in in_loop} == {"dispatch"}
+    outside = [n for _op, n in named if MOE.search(n) and not _WORST_CASE.search(n)]
+    assert {MOE.search(n).group(1) for n in outside} == set(T.MOE_SCOPES)
+    for scope in ("dispatch", "experts"):         # the bounded path: forward and backward
+        mine = [n for n in outside if MOE.search(n).group(1) == scope]
+        assert [n for n in mine if "transpose(" not in n], scope
+        assert [n for n in mine if "transpose(" in n], scope
+    kernels = [n for n in outside if "jit(gmm)" in n or "jit(tgmm)" in n]
+    assert kernels and {MOE.search(n).group(1) for n in kernels} == {"experts"}
+    assert not [n for _op, n in instructions(remat, lfm2=True) if _WORST_CASE.search(n)]
 
 
 def test_the_new_scopes_change_names_never_the_program():
