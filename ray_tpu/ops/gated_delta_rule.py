@@ -31,8 +31,9 @@ alpha_t S_{t-1}^T k_t)`` the value each token really writes::
     O  = (e^G Q) S + (Q K^T . e^{G_t - G_i})_{i <= t} U
     S' = e^{G_C} S + (e^{G_C - G} K)^T U
 
-Two halves, four Mosaic kernels, and ``_prepare`` beside them as what the
-first two are held to:
+Two halves, four Mosaic kernels (six with the channel decay's own preparation
+pair, further down), and ``_prepare`` beside them as what the first two are
+held to:
 
 * the chunk preparation (``_delta_prepare_forward``): ``T = (I + A)^-1``
   and from it ``W = T (beta e^G K)``, ``U0 = T (beta V)``, and ``Qg``,
@@ -76,18 +77,42 @@ factors out of the dot products, ``A[t, i] = beta_t sum_c k_tc k_ic e^{G_tc -
 G_ic}``, ``P[t, i] = sum_c q_tc k_ic e^{G_tc - G_ic}``; ``W = T (beta e^G .
 K)``, ``Qg = e^G . Q``, ``Kd = e^{G_C - G} . K`` as before with ``.`` per
 channel, and ``gamma = e^{G_C}`` a VECTOR over ``d_k``. ``A`` and ``P`` stay
-matmuls and must not overflow (``_prepare_channel``): the chunk is cut into
-sub-blocks of ``_SUB_CHUNK`` = 16 rows, and row block ``r`` multiplies ``x_t
-. e^{G_t - R_r}`` (``R_r`` the ``G`` of its first token: exponent <= 0) by
-``k_i . e^{R_r - G_i}``, whose exponent is <= 0 for every column before the
+matmuls and must not overflow (``_prepare_channel_xla``): the chunk is cut
+into sub-blocks of ``_SUB_CHUNK`` = 16 rows, and row block ``r`` multiplies
+``x_t . e^{G_t - R_r}`` (``R_r`` the ``G`` of its first token: exponent <= 0)
+by ``k_i . e^{R_r - G_i}``, whose exponent is <= 0 for every column before the
 block and at most ``15 |g|`` inside it. That is why the caller's decay must
 be BOUNDED below: at ``g >= -5`` a token and channel (the published
 ``kda_lower_bound``) the largest factor is ``e^75``, under float32's ``e^88``,
-where the unsplit ``e^{G_t} . e^{-G_i}`` overflows inside one chunk. The
-preparation is XLA's in this form, differentiated by jax under scope
-``decay_prepare`` (a Mosaic kernel of it is not written yet); the scan
-kernels are the same two, told by ``gamma``'s shape to scale the state's
-rows: the scalar path's text is unchanged.
+where the unsplit ``e^{G_t} . e^{-G_i}`` overflows inside one chunk.
+
+Its preparation is a Mosaic pair of its own (``_channel_prepare_forward``,
+``_channel_prepare_backward``, under scope ``decay_prepare``; ``_prepare_channel``
+is the seam the timed forward takes its operands through), beside the scalar
+pair and sharing ``_Masks`` with it; ``_prepare_channel_xla`` is the same
+mathematics in XLA, differentiated by jax: the oracle of both and the
+``kernels=False`` path. What the kernels hold in VMEM and XLA wrote to HBM: the
+running sum ``G`` (taken in the kernel by doubling: sublane rotations and
+adds), the rows' decay ``e^{G_t - R_r(t)}``, the four decayed copies of K a
+chunk (XLA's ``f32[2, 256, 4, 64, 128]``, 67 MB a call), ``A``, every level of
+the inverse, and in the backward ``dT``, ``dA``, each block's ``dX_r`` and
+``dC_r`` (``_Blocks`` lays ``R_r`` down the rows). Two chunks ride one
+128-row product; row block ``r`` of both stacks ``k . e^{G - R_r}`` and ``q .
+e^{G - R_r}`` into ONE ``[64, d_k]`` left operand against one right tile,
+``k . e^{R_r - G}`` (``A`` and ``P`` read the same columns). Right-operand
+tiles a pair of chunks: forward 16 (4 row blocks, 10 for the inverse's five
+levels, 2 for ``W`` and ``U0``), backward 14 (``dT`` 2, ``T^T dW`` and ``T^T
+dU0`` 2, ``dA`` 2, ``dX_r = dM_r C_r`` 4, ``dC_r = dM_r^T X_r`` 4 at half the
+contraction); ``R_r`` drops out of the gradient exactly. At the Ling cell's
+``[32, 16384, 128 | 128]`` the forward takes 8.93 ms a layer and call and the
+backward 7.76 in the step (9.84 / 8.55 alone, thirty-two heads a call), where
+XLA took 18.4 / 43.2: 94 % and 95 % of what the MXU's float32 rate allows
+those tiles (0.128 us each); a layer's three calls 25.8 ms where XLA's took
+61.6 (device traces, my chip runs, PR 41). The forward is equal to the oracle
+to the last bit where both are given one running sum; with its own it is
+2e-5 from it at ``G = -320`` and as far from the oracle in float64 as the
+oracle in float32 is. The scan kernels are the same two, told by ``gamma``'s
+shape to scale the state's rows: the scalar path's text is unchanged.
 
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
@@ -121,7 +146,10 @@ _CHUNKS_PER_STEP = (8, 4, 2, 1)
 # 32). The two preparation kernels take the same steps: 1024 rows are
 # refused for both (the forward with its seven results; the chip refused
 # the backward too, PR 33), and 512, 256 and 128 rows a step cost the same
-# to 2 % (my chip runs, PR 33).
+# to 2 % (my chip runs, PR 33). The channel decay's pair fits at 512 too
+# (compile for a described v5e, PR 41); there 256 rows cost 3 % more and 128
+# cost the forward 66 % (9.59 / 9.91 / 15.90 ms a layer and call, the
+# backward 8.42 / 8.70 / 9.33: my chip runs, PR 41).
 _ROWS_PER_STEP = 512
 # The state the kernels carry from chunk to chunk (and the forward hands
 # the backward); the preparation computes in float32 too.
@@ -130,7 +158,7 @@ _STATE_DTYPE = jnp.float32
 # (``_heads_per_call`` has the readings it was chosen from).
 _TOKENS_PER_CALL = 2 * 16384
 _HIGHEST = jax.lax.Precision.HIGHEST
-# The oracle's matmuls (``_prepare``: XLA, float32 operands).
+# The oracles' matmuls (``_prepare``, ``_prepare_channel_xla``: XLA, float32 operands).
 _PREPARE_PRECISION = _HIGHEST
 
 
@@ -276,7 +304,7 @@ def _prepare(q, k, v, log_alpha, beta, chunk: int):
 _SUB_CHUNK = 16
 
 
-def _prepare_channel(q, k, v, log_alpha, beta, chunk: int):
+def _prepare_channel_xla(q, k, v, log_alpha, beta, chunk: int):
     """``_prepare`` under a decay per channel: the six operands of the scan
     (``gamma``: ``[heads, chunks, 1, d_k]``) from q, k, ``log_alpha``
     ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta`` ``[heads,
@@ -827,6 +855,291 @@ def _delta_prepare_backward(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgam
     )(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma)
 
 
+# ---------------------------------------------------------------------------
+# The chunk preparation under a decay per CHANNEL as Mosaic work: forward
+# and its transpose by hand, ``_prepare_channel_xla`` the oracle of both.
+# ---------------------------------------------------------------------------
+class _Blocks:
+    """Where the sub-blocks (``_SUB_CHUNK`` rows) of the ``together`` chunks
+    of one product lie down its ``[rows, d_k]`` values, and what
+    ``_prepare_channel_xla`` makes of the running sum ``total`` there: the
+    references ``R_r`` spread down the rows and the key side's decay, ``exp``
+    of a difference masked BEFORE the ``exp``."""
+
+    def __init__(self, chunk, together, d_k):
+        self.chunk, self.together, self.d_k = chunk, together, d_k
+        self.sub = min(_SUB_CHUNK, chunk)
+        self.blocks = chunk // self.sub
+        self.row = jax.lax.broadcasted_iota(jnp.int32, (chunk * together, d_k), 0)
+        # each row's place inside its chunk
+        self.inside = self.row - chunk * jax.lax.div(self.row, jnp.int32(chunk))
+
+    def running(self, x, back=False):
+        """The running sum of ``x`` down the rows of each chunk
+        (``_prepare_channel_xla``'s ``total`` of ``log_alpha``), by doubling:
+        sublane rotations and float32 adds, nothing of it on the MXU. With
+        ``back`` its transpose: each row's sum from itself to its chunk's end."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        rows, shift = self.chunk * self.together, 1
+        while shift < self.chunk:
+            # x[t - shift] (x[t + shift]) where that token is in t's chunk
+            moved = pltpu.roll(x, rows - shift if back else shift, 0)
+            inside = self.inside < self.chunk - shift if back else self.inside >= shift
+            x, shift = x + jnp.where(inside, moved, 0.0), 2 * shift
+        return x
+
+    def _spread(self, x, starts, count):
+        """Row ``s`` of ``x`` over ``count`` rows, for each ``s`` in turn."""
+        return jnp.concatenate(
+            [jnp.broadcast_to(x[s:s + 1], (count, self.d_k)) for s in starts], axis=0
+        )
+
+    def own_first(self, total):
+        """``R_r(t)``: the ``G`` of the first token of each row's own block."""
+        return self._spread(total, range(0, self.chunk * self.together, self.sub), self.sub)
+
+    def last(self, total):
+        """``G_C`` of each row's own chunk."""
+        ends = [(c + 1) * self.chunk - 1 for c in range(self.together)]
+        return self._spread(total, ends, self.chunk)
+
+    def columns(self, total, r):
+        """``e^{R_r - G_i}`` for ``i`` up to the end of block ``r`` of its
+        chunk (<= 0 before the block, at most ``(_SUB_CHUNK - 1) |g|`` inside
+        it), 0 after."""
+        firsts = [c * self.chunk + r * self.sub for c in range(self.together)]
+        gap = self._spread(total, firsts, self.chunk) - total
+        return jnp.exp(jnp.where(self.inside < (r + 1) * self.sub, gap, -jnp.inf))
+
+    def stacked(self, r, *several):
+        """Block ``r``'s rows of every chunk, of each array in turn: the left
+        operand of ONE product against block ``r``'s columns."""
+        rows = [
+            slice(c * self.chunk + r * self.sub, c * self.chunk + (r + 1) * self.sub)
+            for c in range(self.together)
+        ]
+        return jnp.concatenate([x[at] for x in several for at in rows], axis=0)
+
+    def unstacked(self, by_block, n):
+        """Array ``n`` of ``stacked``'s order back in its rows' order, from
+        one ``[arrays x together x sub, .]`` value a block."""
+        return jnp.concatenate([
+            by_block[r][(n * self.together + c) * self.sub:(n * self.together + c + 1) * self.sub]
+            for c in range(self.together) for r in range(self.blocks)
+        ], axis=0)
+
+    def at_ends(self, by_chunk):
+        """``[rows, d_k]``: chunk ``c``'s ``[1, d_k]`` row at its last token,
+        0 elsewhere."""
+        return sum(
+            jnp.where(self.row == (c + 1) * self.chunk - 1, row, 0.0)
+            for c, row in enumerate(by_chunk)
+        )
+
+
+def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
+                            w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, *inverse_ref,
+                            chunk, per_step, together):
+    """``_prepare_channel_xla`` for ``per_step`` chunks of one head,
+    ``together`` to a product. ``beta_ref``: ``[1, per_step / together, 1,
+    together x chunk]``, along the lanes; the running sum ``G`` of
+    ``log_alpha`` is taken here (``_Blocks.running``). Row block ``r`` of
+    every chunk of a product multiplies ``k . e^{G - R_r}`` and ``q . e^{G -
+    R_r}``, stacked, by ONE right operand, ``k . e^{R_r - G}`` (``A`` and
+    ``P`` read the same columns); the decayed copies of K live and die here.
+    With a seventh result, ``T`` as the block-diagonal matrices it was
+    computed as."""
+    f32 = jnp.float32
+    width = together * chunk
+    masks = _Masks(chunk, together)
+    at = _Blocks(chunk, together, q_ref.shape[-1])
+    products = range(per_step // together)
+    rows = [slice(s * width, (s + 1) * width) for s in products]
+    betas = [masks.down(beta_ref[0, s, 0:1, :]) for s in products]
+    several, weighed = [], []
+    for s in products:
+        q, k = q_ref[0, rows[s], :].astype(f32), k_ref[0, rows[s], :].astype(f32)
+        total = at.running(log_alpha_ref[0, rows[s], :].astype(f32))
+        grown = jnp.exp(total)                                   # e^{G_t}
+        weighed.append(betas[s] * grown * k)
+        decay = jnp.exp(total - at.own_first(total))             # e^{G_t - R_r(t)}
+        left = k * decay, q * decay
+        by_block = [
+            _dot(at.stacked(r, *left), k * at.columns(total, r), _NT) for r in range(at.blocks)
+        ]
+        several.append(jnp.where(masks.strictly, betas[s] * at.unstacked(by_block, 0), 0.0))
+        p = jnp.where(masks.upto, at.unstacked(by_block, 1), 0.0)
+        qg_ref[0, rows[s], :] = grown * q
+        kd_ref[0, rows[s], :] = jnp.exp(at.last(total) - total) * k
+        for c in range(together):
+            own = slice(c * chunk, (c + 1) * chunk)
+            p_ref[0, s * width + c * chunk:s * width + (c + 1) * chunk, :] = p[own, own]
+            gamma_ref[0, s * together + c] = jnp.exp(total[(c + 1) * chunk - 1:(c + 1) * chunk])
+    for s, inverse in zip(products, masks.inverses(several)):
+        w_ref[0, rows[s], :] = _dot(inverse, weighed[s], _NN)
+        u0_ref[0, rows[s], :] = _dot(inverse, betas[s] * v_ref[0, rows[s], :].astype(f32), _NN)
+        if inverse_ref:
+            inverse_ref[0][0, rows[s], :] = inverse
+
+
+def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inverse_ref,
+                             dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref, dgamma_ref,
+                             dq_ref, dk_ref, dv_ref, dlog_alpha_ref, dbeta_ref,
+                             *, chunk, per_step, together):
+    """The transpose of ``_channel_forward_kernel`` by hand, ``together``
+    chunks at a time: ``dT`` and ``dA = -T^T dT T^T`` as
+    ``_prepare_backward_kernel``'s, then the product rule of each row block's
+    ``M_r = mask . (X_r C_r^T)`` (``X_r`` the stacked ``k . e^{G - R_r}``, ``q
+    . e^{G - R_r}``; ``C_r = k . e^{R_r - G}``): ``dX_r = dM_r C_r``, ``dC_r =
+    dM_r^T X_r``, and ``dG`` gets ``X . dX - sum_r C_r . dC_r`` channel by
+    channel. ``R_r`` drops out exactly (what ``X . dX`` sends it, ``C_r .
+    dC_r`` takes back under the same mask), so nothing lands on a block's
+    first token. What reaches ``G`` goes back through the running sum
+    (``_Blocks.running`` backwards) to ``log_alpha``; ``dbeta_ref`` along the lanes."""
+    f32 = jnp.float32
+    width = together * chunk
+    masks = _Masks(chunk, together)
+    at = _Blocks(chunk, together, q_ref.shape[-1])
+    for s in range(per_step // together):
+        rows = slice(s * width, (s + 1) * width)
+        q, k, v, log_alpha, inverse, dw, du0, dqg, dp, dkd = (
+            ref[0, rows, :].astype(f32) for ref in
+            (q_ref, k_ref, v_ref, log_alpha_ref, inverse_ref, dw_ref, du0_ref, dqg_ref, dp_ref,
+             dkd_ref)
+        )
+        total = at.running(log_alpha)
+        beta = masks.down(beta_ref[0, s, 0:1, :])
+        grown = jnp.exp(total)                                   # e^{G_t}
+        scaled = grown * k
+        dinverse = _dot(dw, beta * scaled, _NT) + _dot(du0, beta * v, _NT)
+        dkb, dvb = _dot(inverse, dw, _TN), _dot(inverse, du0, _TN)
+        da = -_dot(_dot(inverse, dinverse, _TN), inverse, _NT)
+        # of A / beta and of P, each beside its own chunk's columns
+        da = jnp.where(masks.strictly, da, 0.0)
+        dp = jnp.where(masks.upto, jnp.tile(dp, (1, together)), 0.0)
+        decay = jnp.exp(total - at.own_first(total))             # e^{G_t - R_r(t)}
+        xk, xq = k * decay, q * decay
+        right = beta * xk, xq
+        dleft, dk_columns = [], 0.0
+        for r in range(at.blocks):
+            columns = at.columns(total, r)
+            dm = at.stacked(r, da, dp)
+            dleft.append(_dot(dm, columns * k, _NN))
+            dk_columns += columns * _dot(dm, at.stacked(r, *right), _TN)
+        dxk, dxq = at.unstacked(dleft, 0), at.unstacked(dleft, 1)  # dxk: of A / beta's X
+        through_beta = xk * dxk + scaled * dkb                   # d(beta) and, times beta, dG
+        left = jnp.exp(at.last(total) - total)                   # e^{G_C - G_t}
+        dq_ref[0, rows, :] = (decay * dxq + grown * dqg).astype(dq_ref.dtype)
+        dk_ref[0, rows, :] = (
+            beta * (decay * dxk + grown * dkb) + dk_columns + left * dkd
+        ).astype(dk_ref.dtype)
+        dv_ref[0, rows, :] = (beta * dvb).astype(dv_ref.dtype)
+        dbeta_ref[0, s, 0:1, :] = masks.across(_rows_sum(through_beta) + _rows_sum(dvb * v))
+        dkept = left * k * dkd                                   # of G_C - G_t
+        # G_C is its chunk's last G: what reached it through ``left`` and
+        # through gamma = e^{G_C} lands on that token
+        dlast = [
+            _columns_sum(dkept[c * chunk:(c + 1) * chunk])
+            + jnp.exp(total[(c + 1) * chunk - 1:(c + 1) * chunk]) * dgamma_ref[0, s * together + c]
+            for c in range(together)
+        ]
+        dtotal = (
+            beta * through_beta + xq * dxq + grown * q * dqg
+            - k * dk_columns - dkept + at.at_ends(dlast)
+        )
+        dlog_alpha_ref[0, rows, :] = at.running(dtotal, back=True).astype(dlog_alpha_ref.dtype)
+
+
+def _channel_layout(q, v, beta, chunk):
+    """(grid, chunks a step, chunks a product, the specs of q, k, v,
+    ``log_alpha`` and ``beta``) of both channel preparation calls."""
+    bh, seq, d_k = q.shape
+    per_step = _per_step(seq // chunk, chunk)
+    width = beta.shape[-1]
+    together = width // chunk
+    in_specs = [
+        *_specs(chunk, per_step, (d_k, d_k, v.shape[-1], d_k), lambda n: n),
+        pl.BlockSpec((1, per_step // together, 1, width), lambda i, n: (i, n, 0, 0)),
+    ]
+    return (bh, seq // chunk // per_step), per_step, together, in_specs
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse"))
+def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inverse=False):
+    """``_prepare_channel_xla``'s six operands from q, k, ``log_alpha``
+    ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta``
+    (``_beta_lanes``); with ``inverse`` a seventh, ``T`` ``[heads, seq, chunks
+    a product x chunk]``."""
+    bh, seq, d_k = q.shape
+    grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
+    widths = (d_k, v.shape[-1], d_k, chunk, d_k) + ((beta.shape[-1],) if inverse else ())
+    specs = _specs(chunk, per_step, widths, lambda n: n)
+    shapes = [jax.ShapeDtypeStruct((bh, seq, width), jnp.float32) for width in widths]
+    return pl.pallas_call(
+        functools.partial(
+            _channel_forward_kernel, chunk=chunk, per_step=per_step, together=together
+        ),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=[
+            *specs[:5], pl.BlockSpec((1, per_step, 1, d_k), lambda i, n: (i, n, 0, 0)), *specs[5:],
+        ],
+        out_shape=[
+            *shapes[:5], jax.ShapeDtypeStruct((bh, seq // chunk, 1, d_k), jnp.float32), *shapes[5:],
+        ],
+        interpret=interpret,
+        compiler_params=_parallel(),
+    )(q, k, v, log_alpha, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _channel_prepare_backward(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma,
+                              *, chunk, interpret):
+    """``dq``, ``dk``, ``dv``, ``dlog_alpha`` in their operands' dtypes and
+    ``dbeta`` in ``_beta_lanes``' layout from the scan backward's six."""
+    d_k, d_v = q.shape[-1], v.shape[-1]
+    grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
+    return pl.pallas_call(
+        functools.partial(
+            _channel_backward_kernel, chunk=chunk, per_step=per_step, together=together
+        ),
+        grid=grid,
+        in_specs=[
+            *in_specs,
+            *_specs(chunk, per_step, (beta.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
+            pl.BlockSpec((1, per_step, 1, d_k), lambda i, n: (i, n, 0, 0)),
+        ],
+        out_specs=in_specs,
+        out_shape=[
+            *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v, log_alpha)),
+            jax.ShapeDtypeStruct(beta.shape, jnp.float32),
+        ],
+        interpret=interpret,
+        compiler_params=_parallel(),
+    )(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma)
+
+
+def _beta_lanes(beta, chunk: int):
+    """``beta`` ``[heads, seq]`` as ``[heads, seq / width, 1, width]``
+    float32: the tokens of one product's chunks along the lanes (``_gates``
+    has why)."""
+    bh, seq = beta.shape
+    width = _product_rows(seq, chunk)
+    return beta.astype(jnp.float32).reshape(bh, seq // width, 1, width)
+
+
+def _prepare_channel(q, k, v, log_alpha, beta, chunk: int, interpret=None):
+    """The six operands of the scan under a decay per channel on the
+    kernels' path: ``_prepare_channel_xla``'s, from ``_channel_prepare_forward``.
+    The ONE place the timed forward takes its operands from, looked up as a
+    module global when ``_channel_prepare_and_scan`` is traced."""
+    return _channel_prepare_forward(
+        q, k, v, log_alpha, _beta_lanes(beta, chunk),
+        chunk=chunk, interpret=resolve_interpret(interpret),
+    )
+
+
 def _gates(log_alpha, beta, chunk: int):
     """``[heads, seq / width, 2, width]`` float32: the running sum of
     ``log_alpha`` inside each chunk (``_prepare``'s ``total``) and ``beta``,
@@ -878,11 +1191,11 @@ _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 def _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret):
     with jax.named_scope("decay_prepare"):
-        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk)
+        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk, interpret)
     return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
 
 
-# ``_chunked`` under a decay per channel: XLA's preparation (scope
+# ``_chunked`` under a decay per channel: its own preparation pair (scope
 # ``decay_prepare``) around the same scan kernels.
 _chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5, 6))
 
@@ -893,19 +1206,24 @@ def _chunked_channel_fwd(q, k, v, log_alpha, beta, chunk, interpret):
 
 
 def _chunked_channel_bwd(chunk, interpret, inputs, dout):
-    """As ``_chunked_bwd``, nothing kept but the inputs: the preparation runs
-    again under ``jax.vjp``, the forward kernel for the chunk-start states,
-    the backward kernel, then the preparation's transpose."""
+    """As ``_chunked_bwd``, nothing kept but the inputs: the preparation
+    kernel runs again (and hands over ``T``), the forward kernel for the
+    chunk-start states, the backward kernel, then the preparation's own."""
+    q, k, v, log_alpha, beta = inputs
+    lanes = _beta_lanes(beta, chunk)
     with jax.named_scope("decay_prepare"):
-        operands, transpose = jax.vjp(
-            lambda *inputs: _prepare_channel(*inputs, chunk), *inputs
+        *operands, inverse = _channel_prepare_forward(
+            q, k, v, log_alpha, lanes, chunk=chunk, interpret=interpret, inverse=True
         )
     states = _delta_rule_forward(
         *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
     )
     grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
     with jax.named_scope("decay_prepare"):
-        return transpose(tuple(grads))
+        *grads, dlanes = _channel_prepare_backward(
+            q, k, v, log_alpha, lanes, inverse, *grads, chunk=chunk, interpret=interpret
+        )
+    return (*grads, dlanes.reshape(beta.shape).astype(beta.dtype))
 
 
 _chunked_channel.defvjp(_chunked_channel_fwd, _chunked_channel_bwd)
@@ -972,7 +1290,7 @@ def gated_delta_rule(
         if kernels:
             chunked = _chunked_channel if channel else _chunked
             return chunked(q, k, v, *gates, chunk, interpret)
-        prepare = _prepare_channel if channel else _prepare
+        prepare = _prepare_channel_xla if channel else _prepare
         return _scan_reference(*prepare(q, k, v, *gates, chunk), chunk, v.dtype)
 
     rows = batch * heads

@@ -280,14 +280,22 @@ def test_delta_rule_preparation_kernels_compile_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < DELTA_RULE_TEMPORARIES
 
 
+# ... and under a decay per channel at ``[1, 32, 16384, 128 | 128]``, two heads
+# a call: 0.286 GiB and 4.5 MiB of generated code, where XLA's preparation
+# held 0.384 and 18.9 (compiles for a described v5e, PR 41).
+CHANNEL_RULE_TEMPORARIES = int(0.5 * 2**30)
+
+
 def test_channel_decay_kernels_compile_for_v5e(one_chip):
     """The delta rule under a decay per key CHANNEL at the Ling cell's size,
     ``[1, 32, 16384, 128 | 128]`` with ``log_alpha`` ``[1, 32, 16384, 128]``:
-    the preparation is XLA's (sub-blocks of 16 rows, no ``[64, 64, 128]``
-    array), the scan kernels are the scalar rule's two, handed ``gamma`` as a
-    ``[.., 1, 128]`` row a chunk and turning it down the state's rows in
-    VMEM; two heads a call, so what the rule holds beside its inputs stays
-    under a GiB and a half."""
+    the preparation is a Mosaic pair of its own since PR 41 (sub-blocks of 16
+    rows on VMEM values, no ``[64, 64, 128]`` array and no decayed copy of K
+    in HBM), both kernels at ``[2, 16384, 128 | 128]`` inside the scoped VMEM
+    at the 512 rows a grid step the scalar pair takes; the scan kernels are
+    the scalar rule's two, handed ``gamma`` as a ``[.., 1, 128]`` row a chunk
+    and turning it down the state's rows in VMEM; two heads a call, so what
+    the rule holds beside its inputs stays under half a GiB."""
     from ray_tpu.ops import gated_delta_rule as G
 
     def shape(*dims, dtype=jnp.float32):
@@ -299,10 +307,31 @@ def test_channel_decay_kernels_compile_for_v5e(one_chip):
         shape(1, 32, 16384, 128), shape(1, 32, 16384),
     )
     assert G._heads_per_call(32, 16384) == 2 and G._SUB_CHUNK == 16
+    assert G._per_step(256, 64) == 8 and G._product_rows(16384, 64) == 128
+
+    # the two preparation kernels alone, two heads a call: each ONE Mosaic
+    # call that fits (a kernel that asks for more VMEM is refused here)
+    q, k, v, log_alpha, beta = (shape(*x.shape[1:], dtype=x.dtype) for x in shapes)
+    lanes = shape(*jax.eval_shape(functools.partial(G._beta_lanes, chunk=64), beta).shape)
+    assert (q.shape, lanes.shape) == ((32, 16384, 128), (32, 128, 1, 128))
+    two = lambda x: shape(2, *x.shape[1:], dtype=x.dtype)
+    inputs = tuple(two(x) for x in (q, k, v, log_alpha, lanes))
+    forward = functools.partial(G._channel_prepare_forward, chunk=64, interpret=False, inverse=True)
+    assert _custom_calls(forward, *inputs) == 1
+    *operands, inverse = (two(x) for x in jax.eval_shape(forward, *inputs))
+    assert [x.shape[1:] for x in operands] == [
+        (16384, 128), (16384, 128), (16384, 128), (16384, 64), (16384, 128), (256, 1, 128),
+    ]
+    assert inverse.shape == (2, 16384, 128)                      # T: two chunks a product
+    backward = functools.partial(G._channel_prepare_backward, chunk=64, interpret=False)
+    assert _custom_calls(backward, *inputs, inverse, *operands) == 1
+    got = jax.eval_shape(backward, *inputs, inverse, *operands)
+    assert [(x.shape, x.dtype) for x in got] == [(x.shape, x.dtype) for x in inputs]
+
     rule = functools.partial(G.gated_delta_rule, interpret=False)
     assert jax.eval_shape(rule, *shapes).shape == (1, 32, 16384, 128)
     text = jax.jit(rule).lower(*shapes).compile().as_text()
-    assert _mosaic_calls(text) == ["_delta_rule_forward"]
+    assert _mosaic_calls(text) == ["_channel_prepare_forward", "_delta_rule_forward"]
     assert "f32[2,256,1,128]" in text                            # gamma, a row a chunk
 
     def value_and_grads(*args):
@@ -311,15 +340,18 @@ def test_channel_decay_kernels_compile_for_v5e(one_chip):
 
     compiled = jax.jit(value_and_grads).lower(*shapes).compile()
     assert sorted(_mosaic_calls(compiled.as_text())) == [
+        "_channel_prepare_backward", "_channel_prepare_forward", "_channel_prepare_forward",
         "_delta_rule_backward", "_delta_rule_forward", "_delta_rule_forward",
     ]
     assert [g.dtype for g in jax.eval_shape(value_and_grads, *shapes)[1]] == [
         jnp.float32, jnp.float32, jnp.bfloat16, jnp.float32, jnp.float32,
     ]
     assert jax.eval_shape(value_and_grads, *shapes)[1][3].shape == (1, 32, 16384, 128)
-    assert compiled.memory_analysis().temp_size_in_bytes < int(1.5 * 2**30)
-    # no [.., 64, 64, 128] intermediate: 17 GB a layer at this size
+    assert compiled.memory_analysis().temp_size_in_bytes < CHANNEL_RULE_TEMPORARIES
+    # no [.., 64, 64, 128] intermediate (17 GB a layer at this size), and none
+    # of XLA's four decayed copies of K a chunk (67 MB a call)
     assert "64,64,128]" not in compiled.as_text()
+    assert "f32[2,256,4,64,128]" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("channels", [2880, 5760])
@@ -566,7 +598,10 @@ def test_patterned_step_over_held_experts_compiles_for_a_v5e_mesh(topo, axes):
     # two linear layers (one in each scan): the scan's forward, the forward
     # again for the chunk-start states, the backward
     assert calls.count("_delta_rule_forward") == 4 and calls.count("_delta_rule_backward") == 2
-    assert not [c for c in calls if c.startswith("_delta_prepare")]     # XLA's, per channel
+    # per channel: the preparation's own pair, not the scalar rule's
+    assert not [c for c in calls if c.startswith("_delta_prepare")]
+    assert calls.count("_channel_prepare_forward") == 4
+    assert calls.count("_channel_prepare_backward") == 2
     assert calls.count("_flash_forward") == 1
     # two expert layers: nine grouped matmuls and the recompute's three forward ones
     assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 6

@@ -228,3 +228,124 @@ def test_the_preparations_transpose_by_hand_is_jaxs(case):
     for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
         # dv is rounded to v's bfloat16 on both sides: a last bit apart
         close(g, w, f"d{name}", **(dict(NEAR, tol=1e-2) if name == "v" else NEAR))
+
+
+# ---------------------------------------------------------------------------
+# The preparation under a decay per CHANNEL: its two Mosaic kernels against
+# ``_prepare_channel_xla``, the oracle.
+# ---------------------------------------------------------------------------
+BOUND = -5.0            # the published ``kda_lower_bound``: 15 x 5 < 88
+CHANNEL_DECAYS = {
+    # as the model draws it: every channel somewhere in (BOUND, 0)
+    "mixed": lambda u: BOUND * u,
+    # AT the bound in every channel: G reaches -320 inside a chunk of 64
+    "bound": lambda u: jnp.full_like(u, BOUND),
+    # nearly shut gates: almost nothing is forgotten
+    "shut": lambda u: 1e-3 * BOUND * u,
+}
+# seq, d_k, d_v, decay: chunks a grid step x chunks a product in the name
+CHANNEL_CASES = {
+    "cell_widths_4x2": (256, 128, 128, "mixed"),
+    "at_the_bound_4x2": (256, 32, 48, "bound"),
+    "nearly_shut_2x2": (128, 32, 48, "shut"),
+    "no_multiple_of_the_chunk_4x2": (200, 32, 48, "mixed"),
+    "three_chunks_each_a_product_1x1": (192, 16, 24, "mixed"),
+    "one_chunk_of_48_at_the_bound_1x1": (40, 16, 8, "bound"),
+}
+_CHANNEL_PREPARED = {}
+
+
+def channel_prepared(case):
+    """As ``prepared``: a case's inputs as ``gated_delta_rule`` hands them
+    on (``log_alpha`` ``[heads, seq, d_k]``), their chunk, and the oracle's
+    operands with its transpose."""
+    if case not in _CHANNEL_PREPARED:
+        seq, d_k, d_v, decay = CHANNEL_CASES[case]
+        q, k, v, _, beta = inputs(seq, d_k, d_v, "mixed", heads=3, seed=7)
+        u = jax.random.uniform(jax.random.PRNGKey(8), q.shape)
+        chunk = G._default_chunk(seq)
+        pad = -seq % chunk
+        flat = [
+            jnp.pad(x[0], ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 3))
+            for x in (q, k, v, CHANNEL_DECAYS[decay](u), beta)
+        ]
+        flat[2] = flat[2].astype(jnp.bfloat16)
+        oracle = jax.jit(lambda *a: jax.vjp(lambda *b: G._prepare_channel_xla(*b, chunk), *a))
+        _CHANNEL_PREPARED[case] = (flat, chunk, *oracle(*flat))
+    return _CHANNEL_PREPARED[case]
+
+
+# The same float32 steps on both sides but for the running sum of the
+# log-decay, which the kernels take by doubling (sublane rotations) and the
+# oracle in XLA's order: G differs in its last bit, 2e-5 at -320, and every
+# decay with it (against the oracle in float64 both read alike, 3e-6 to 1e-5).
+CHANNEL_NEAR = dict(tol=1e-4, floor=0.0)
+
+
+def _layout(case):
+    seq, chunk = channel_prepared(case)[0][0].shape[1], channel_prepared(case)[1]
+    per_step = G._per_step(seq // chunk, chunk)
+    return per_step, G._together(chunk, per_step)
+
+
+@pytest.mark.parametrize("case", list(CHANNEL_CASES))
+def test_the_channel_preparation_kernel_writes_the_oracles_six_operands_and_t(case):
+    (q, k, v, log_alpha, beta), chunk, want, _ = channel_prepared(case)
+    assert "{}x{}".format(*_layout(case)) == case.rsplit("_", 1)[1]
+    lanes = G._beta_lanes(beta, chunk)
+    *got, inverse = G._channel_prepare_forward(
+        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse=True
+    )
+    assert len(got) == 6 and all(x.dtype == jnp.float32 for x in (*got, inverse))
+    for name, g, w in zip(OPERANDS, got, want):
+        close(g, w, name, **CHANNEL_NEAR)
+    assert got[5].shape == (3, q.shape[1] // chunk, 1, q.shape[2])          # gamma: a row a chunk
+    # without ``inverse`` the same six, bit for bit
+    for g, w in zip(G._prepare_channel(q, k, v, log_alpha, beta, chunk, True), got):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    # T is (I + A)^-1 of each chunk, block-diagonal in its product: U0 = T (beta V)
+    width = lanes.shape[-1]
+    blocks = np.asarray(inverse, np.float64).reshape(3, -1, width, width)
+    same = np.kron(np.eye(width // chunk), np.ones((chunk, chunk)))
+    assert np.all(blocks * (1 - same) == 0.0)
+    weighed = (np.asarray(beta, np.float64)[..., None] * np.asarray(v, np.float64))
+    close(blocks @ weighed.reshape(3, -1, width, v.shape[-1]),
+          np.asarray(want[1]).reshape(3, -1, width, v.shape[-1]), "T beta V", **CHANNEL_NEAR)
+    # P is the exact sum term by term, every exponent a difference taken
+    # BEFORE the exp: finite and right at the bound, where e^{-G} overflows
+    shape = (3, -1, chunk, q.shape[2])
+    total = jnp.cumsum(log_alpha.reshape(shape), axis=2)
+    gap = total[:, :, :, None, :] - total[:, :, None, :, :]
+    lower = np.tril(np.ones((chunk, chunk), bool))
+    exact = jnp.sum(
+        q.reshape(shape)[:, :, :, None, :] * k.reshape(shape)[:, :, None, :, :]
+        * jnp.exp(jnp.where(lower[..., None], gap, -jnp.inf)), axis=-1
+    )
+    p = np.asarray(got[3]).reshape(3, -1, chunk, chunk)
+    assert np.max(np.abs(p - np.asarray(exact))) < 1e-6 and np.max(np.abs(p)) > 1e-2
+    assert np.all(p[..., ~lower] == 0.0)
+
+
+@pytest.mark.parametrize("case", list(CHANNEL_CASES))
+def test_the_channel_preparations_transpose_by_hand_is_jaxs(case):
+    """Random cotangents for the six operands: the five gradients of the
+    hand-written kernel (``T`` handed over by the forward call, as in
+    ``_chunked_channel_bwd``) against ``jax.vjp(_prepare_channel_xla)``."""
+    inputs_, chunk, operands, oracle_vjp = channel_prepared(case)
+    q, k, v, log_alpha, beta = inputs_
+    keys = jax.random.split(jax.random.PRNGKey(11), len(operands))
+    cotangents = tuple(jax.random.normal(key, x.shape) for key, x in zip(keys, operands))
+    want = oracle_vjp(cotangents)
+
+    lanes = G._beta_lanes(beta, chunk)
+    *_, inverse = G._channel_prepare_forward(
+        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse=True
+    )
+    *got, dlanes = G._channel_prepare_backward(
+        q, k, v, log_alpha, lanes, inverse, *cotangents, chunk=chunk, interpret=True
+    )
+    got = (*got, dlanes.reshape(beta.shape))
+    assert [g.dtype for g in got] == [w.dtype for w in want]
+    for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
+        # dv is rounded to v's bfloat16 on both sides: a last bit apart
+        close(g, w, f"d{name}", **(dict(CHANNEL_NEAR, tol=1e-2) if name == "v" else CHANNEL_NEAR))

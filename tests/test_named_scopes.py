@@ -330,7 +330,8 @@ def test_latent_scopes_change_names_never_the_program():
     ("attn_gate", ("attention",)),
 ])
 def test_a_patterned_model_over_experts_names_its_new_work(remat, scope, inside):
-    """``decay_prepare`` (the chunk preparation under a decay per channel)
+    """``decay_prepare`` (the chunk preparation under a decay per channel:
+    ``ops/gated_delta_rule.py``'s ``_channel_prepare_forward`` / ``_backward``)
     lies inside ``delta_rule`` inside ``linear_attention`` inside
     ``attention``, ``attn_gate`` (the latent layers' head-wise gate) inside
     ``attention`` and outside the linear mixer: forward and, through the
@@ -349,8 +350,18 @@ def test_a_patterned_model_over_experts_names_its_new_work(remat, scope, inside)
         assert set(LINEAR.findall(n)) >= set(inside[1:]), n
         if scope == "attn_gate":
             assert not LINEAR.search(n), n
-    # the preparation's matmuls (A, P, T's levels, W, U0) are under the scope
-    assert [n for n in matmuls if NEW.search(n) and NEW.search(n).group(1) == "decay_prepare"]
+    if scope == "decay_prepare":
+        # the preparation is a kernel pair since PR 41 (interpreted here: the
+        # interpreter's instructions carry the scope, on a chip the two Mosaic
+        # calls do, which is what ``decay_prepare_ms`` reads): the forward
+        # kernel forward and once more in the backward, its transpose there alone
+        forward = [n for n in mine if "jit(_channel_prepare_forward)" in n]
+        assert [n for n in forward if "transpose(" not in n]
+        assert [n for n in forward if "transpose(" in n]
+        backward = [n for n in mine if "jit(_channel_prepare_backward)" in n]
+        assert backward and all("transpose(" in n for n in backward)
+        # and nothing of the scan kernels is under it
+        assert not [n for n in mine if "_delta_rule_" in n]
     # the experts' scopes and ``shared`` are there under the pattern too
     assert _bounded_matmuls_scopes(matmuls) == {"router", "experts"}
     assert [n for n in matmuls if LATENT.search(n) and LATENT.search(n).group(1) == "shared"]
