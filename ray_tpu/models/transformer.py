@@ -6,7 +6,8 @@ checkpoint policy, one head and loss):
   * attention: RMSNorm, then either grouped-query attention (q / k / v
     projections to one ``head_dim``, RoPE over the whole head, optional
     q/k norms, over the whole projection with ``qk_norm`` or head by head
-    with ``qk_head_norm``: Llama, Mistral, OLMoE, LFM2) or, with ``latent=``, multi-head latent
+    with ``qk_head_norm``: Llama, Mistral, OLMoE, LFM2) or, with
+    ``latent=``, multi-head latent
     attention (DeepSeek-V2/V3, Moonlight: keys and values rebuilt from a
     normed low-rank latent, one RoPE key shared by all heads, q / k of
     ``qk_nope + qk_rope`` dims against v of ``v_head_dim``); both through
@@ -19,23 +20,19 @@ checkpoint policy, one head and loss):
     limited to the best ``topk_group`` of ``n_group`` groups of experts.
     ``first_dense_layers`` puts dense layers before the expert layers (their
     own stacked tree, ``params["dense_layers"]``). ``MoEConfig.held`` tells
-    an expert layer WHICH experts it holds: the router still scores and
-    chooses among all ``num_experts``, and the layer computes its own
-    experts' part of the weighted sum for the (token, choice) pairs that
-    chose them (a chip's share of a layer whose experts are divided over
-    chips, without the exchange: an absent pair adds nothing), in row
-    buffers sized for what held experts get (``held_row_bound``).
+    an expert layer WHICH experts it holds: a chip's share of a layer whose
+    experts are divided over chips, without the exchange, in row buffers
+    sized for what held experts get (``held_row_bound``).
   * the fourth shape, a hybrid (Olmo-Hybrid): ``layer_pattern`` names one
     PERIOD of unlike layers, "linear" ones three to one with "full" ones.
     A linear layer's mixer (``linear=``, ``_linear_mixer``) is Gated
-    DeltaNet's: q / k / v through a causal depthwise convolution and SiLU
-    (the kernels of ops/short_conv.py, forward and backward one pass each),
-    L2-normalised q and k, a per-head decay and write strength, the gated
-    delta rule over a ``[d_k, d_v]`` state a head (the chunked-scan kernels
-    of ops/gated_delta_rule.py), a gated RMSNorm a head, ``W_o``. The full
-    layers are the grouped-query block above with ``rope_theta=None`` (no
-    rotary embedding). ``norm_placement="post"`` is OLMo's reordered norm,
-    ``x + norm(branch(x))``. The parameters are stacked by period
+    DeltaNet's: convolved, L2-normalised q and k, a per-head decay and write
+    strength, the gated delta rule over a ``[d_k, d_v]`` state a head, a
+    gated RMSNorm a head (the kernels of ops/short_conv.py and
+    ops/gated_delta_rule.py). The full layers are the grouped-query block
+    above with ``rope_theta=None`` (no rotary embedding).
+    ``norm_placement="post"`` is OLMo's reordered norm, ``x +
+    norm(branch(x))``. The parameters are stacked by period
     (``params["layers"][kind]``: ``[periods, count in a period, ...]``) and
     ONE scan walks the periods, its body a period's layers in order, each
     under the one checkpoint policy.
@@ -47,55 +44,49 @@ checkpoint policy, one head and loss):
     w_i)`` before ``W_o`` (scope ``attn_gate``), and the dense prefix takes
     the mixer ``first_dense_kind`` names. Its linear layers are Kimi Delta
     Attention, ``linear=`` under ``decay="channel"``: the delta rule with a
-    decay for each key CHANNEL of a head,
-
-        g_t = b sigmoid(exp(a_log) (h_t W_a + dt_bias))        [heads, d_k], in (b, 0)
-        S_t = Diag(e^{g_t}) S_{t-1} + k_t (beta_t (v_t - (Diag(e^{g_t}) S_{t-1})^T k_t))^T
-
-    beside the scalar rule's ``S_t = alpha_t S_{t-1} + ...`` (``b`` the
-    bound ``gate_lower_bound``, which is what lets the chunked form be
-    computed: ops/gated_delta_rule.py), and a sigmoid in place of SiLU on
-    the per-head norm's output.
+    decay for each key CHANNEL of a head, bounded below by
+    ``gate_lower_bound`` (which is what lets the chunked form be computed:
+    ops/gated_delta_rule.py), and a sigmoid in place of SiLU on the per-head
+    norm's output (``_linear_mixer`` has the formulas).
   * the sixth, gated short convolutions over experts (LFM2-8B-A1B): a
     third kind of layer, "conv", in the pattern and as ``first_dense_kind``,
-    whose whole mixer (``_conv_mixer``, scope ``conv_mixer``) is
-
-        [B, C, x] = h W_in                       (three chunks of ``dim``)
-        z_t = sum_j f_j (B * x)_{t - (taps - 1 - j)}    (causal, depthwise)
-        y = (C * z) W_out
-
-    with ``conv_kernel`` taps a channel, no bias and NO activation (the
-    kernels of ops/short_conv.py with ``activation=None``), three to one
-    with grouped-query layers under ``qk_head_norm``, over sigmoid-and-bias
+    whose whole mixer (``_conv_mixer``, scope ``conv_mixer``) is ``(C *
+    conv(B * x)) W_out`` of ``[B, C, x] = h W_in``, with ``conv_kernel``
+    taps a channel, no bias and NO activation (the kernels of
+    ops/short_conv.py with ``activation=None``), three to one with
+    grouped-query layers under ``qk_head_norm``, over sigmoid-and-bias
     routed experts; and ``tie_embeddings``: the head is the transposed
     embedding, one leaf whose gradient is the sum of its two uses.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
-  * functional: params are a pytree of jnp arrays; every leaf has a logical
-    dim annotation in PARAM_LOGICAL_DIMS, so DP/FSDP/TP/EP sharding is one
-    LogicalRules switchboard away — model code never mentions mesh axes.
+  * functional: params are a pytree of jnp arrays. What a layer holds is
+    written down ONCE, in the table below the configs: one function a part
+    (each kind of mixer, the dense MLP, the routed and the shared experts)
+    gives every leaf's shape, logical dims and initialiser, and
+    ``param_logical_dims`` (so DP/FSDP/TP/EP sharding is one LogicalRules
+    switchboard away: model code never mentions mesh axes), ``init_params``
+    and ``config_num_params`` read it. A kind of mixer is one row of
+    ``_MIXERS``, its leaves and its function; a layer is told its kind by
+    whoever walks the stack it lies in.
   * layers are scanned (lax.scan over stacked layer params, _scan_layers):
     O(1) compile time in depth, XLA-friendly control flow. The expert
     kernels read a layer's weights in the stack itself, not a slice of it.
-  * MoE blocks are dropless (no capacity, no token ever dropped): the
-    (token, choice) pairs are sorted by expert and the three expert matmuls
-    run as grouped matmuls over the ragged groups (ops/grouped_matmul.py), one
-    static-shaped program whatever the routing. Under a mesh the block runs
-    per data shard inside a shard_map, as the flash kernel does (GSPMD
-    cannot partition a Mosaic kernel: _moe_over_mesh). Expert weights carry
-    the "expert" logical dim, so an ep mesh axis shards them at rest; they
-    are all-gathered for the block: the all_to_all exchange over ep that
-    would leave them in place is not written yet.
+  * MoE blocks are dropless (no capacity, no token ever dropped): grouped
+    matmuls over the (token, choice) pairs sorted by expert, one
+    static-shaped program whatever the routing (``_moe_mlp``). Expert
+    weights carry the "expert" logical dim, so an ep mesh axis shards them
+    at rest; they are all-gathered for the block: the all_to_all exchange
+    over ep that would leave them in place is not written yet.
+  * a Mosaic kernel (flash, the grouped matmuls, the convolutions, the
+    delta rule) runs per shard inside ONE shard_map wrapper under a mesh
+    (``_over_mesh``: GSPMD cannot partition it).
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
-  * serving (init_kv_cache / decode_step) covers grouped-query attention
-    only: the latent cache, the recurrent-state cache and the
-    convolution-state cache (the last ``taps - 1`` rows of a "conv" layer's
-    gated input) of a patterned model are not written yet, and each refuses
-    by name. So do the pipeline (partition_stages / stage_forward split ONE
-    stacked tree, and a tied head would sit on two stages), a mesh with tp
-    or sp over a patterned model (the scan and convolution kernels run per
-    data shard under shard_map, as flash does: dp / fsdp work) and
-    ``norm_placement="post"`` over expert layers.
+  * what is not written refuses by name: serving (init_kv_cache /
+    decode_step) beyond grouped-query attention (the latent, recurrent-state
+    and convolution-state caches), the pipeline (partition_stages /
+    stage_forward) over a dense prefix, a pattern or a tied head, tp or sp
+    over a patterned model (dp / fsdp work), ``norm_placement="post"`` over
+    expert layers.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -168,8 +159,7 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
 # the two gates, W_out, and within it "short_conv", the convolution's two
 # kernels called with no activation).
-# The kinds of layer a ``layer_pattern`` may name.
-LAYER_KINDS = ("linear", "full", "conv")
+
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
@@ -406,6 +396,12 @@ class TransformerConfig:
         return tuple(dict.fromkeys(prefix + self.layer_pattern))
 
     @property
+    def prefix_kind(self) -> str:
+        """The mixer of the leading dense layers: ``first_dense_kind`` says
+        under a pattern, and without one every layer is "full"."""
+        return self.first_dense_kind if self.layer_pattern else "full"
+
+    @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
@@ -430,205 +426,87 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
 
-# Logical dim names per param leaf (layer-stacked leaves lead with "layer").
-def param_logical_dims(config: TransformerConfig) -> dict:
-    dense_mlp = {
-        "w_gate": ("layer", "embed", "mlp"),
-        "w_up": ("layer", "embed", "mlp"),
-        "w_down": ("layer", "mlp", "embed"),
-    }
-    moe_mlp = {
-        "router": ("layer", "embed", None),
-        "w_gate": ("layer", "expert", "embed", "mlp"),
-        "w_up": ("layer", "expert", "embed", "mlp"),
-        "w_down": ("layer", "expert", "mlp", "embed"),
-    }
-    if config.moe and config.moe.scoring == "sigmoid":
-        moe_mlp["router_bias"] = ("layer", None)
-    if config.moe and config.moe.shared_experts:
-        # Sharded as a dense MLP is: GSPMD partitions it, outside the
-        # per-shard call of the routed experts.
-        moe_mlp.update({"shared_" + name[2:]: dims for name, dims in dense_mlp.items()})
-    if config.latent:
+# ---------------------------------------------------------------------------
+# The table: every leaf of every part of a layer, written down once
+# ---------------------------------------------------------------------------
+def _normal(keys, shape, dtype, scale=None):
+    """Normal draws times ``scale`` (None: fan-in^-1/2, the fan-in the dim
+    before the last), made in float32 and rounded to ``dtype``."""
+    scale = shape[-2] ** -0.5 if scale is None else scale
+    return (jax.random.normal(next(keys), shape, jnp.float32) * scale).astype(dtype)
+
+
+def _filters(keys, shape, dtype):
+    """``[taps, channels]`` uniform in +-taps^-1/2, a depthwise Conv1d's default."""
+    bound = shape[-2] ** -0.5
+    return jax.random.uniform(next(keys), shape, jnp.float32, -bound, bound).astype(dtype)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Leaf:
+    """One parameter: ``shape`` and the logical ``dims`` that shard it, both
+    WITHOUT the stacking dims a layer stack puts in front, and ``init(keys,
+    shape, dtype)``, which makes it at a stacked ``shape`` for a model of
+    ``dtype`` and takes the next of ``keys`` if it draws. One function a
+    part of a layer returns ``{name: _Leaf}`` from the config, in the order
+    ``init_params`` draws them (part of what a seed means);
+    ``param_logical_dims``, ``init_params`` and ``config_num_params`` read
+    those and state nothing themselves."""
+    shape: tuple[int, ...]
+    dims: tuple[str | None, ...]
+    init: Callable = _normal
+
+
+def _norm(width: int) -> _Leaf:
+    """A norm's weight: ones, whole on every shard."""
+    return _Leaf((width,), (None,), lambda keys, shape, dtype: jnp.ones(shape, dtype))
+
+
+def _model_leaves(config: TransformerConfig) -> dict:
+    """The leaves outside the layer stacks."""
+    d, vocab = config.dim, config.vocab_size
+    head = {} if config.tie_embeddings else {"lm_head": _Leaf((d, vocab), ("embed", "vocab"))}
+    embed = _Leaf((vocab, d), ("vocab", "embed"), functools.partial(_normal, scale=0.02))
+    return {"embed": embed, "final_norm": _norm(d), **head}
+
+
+def _full_leaves(config: TransformerConfig) -> dict:
+    """A "full" layer's mixer: latent attention where ``latent`` is set,
+    else grouped-query attention with its q / k norms' widths."""
+    d, heads, la = config.dim, config.n_heads, config.latent
+    if la:
         # tp shards whole heads (W_q's and W_kv_b's columns are laid out
         # head by head) and leaves the latent and the shared rope key whole.
-        attention = {
-            "wq": ("layer", "embed", "heads"),
-            "wkv_a": ("layer", "embed", None),
-            "kv_norm": ("layer", None),
-            "wkv_b": ("layer", None, "heads"),
-            "wo": ("layer", "heads", "embed"),
-            **({"wg_head": ("layer", "embed", None)} if config.latent.output_gate else {}),
+        kv_out = heads * (la.qk_nope_head_dim + la.v_head_dim)
+        return {
+            "kv_norm": _norm(la.kv_lora_rank),
+            "wq": _Leaf((d, heads * la.qk_head_dim), ("embed", "heads")),
+            "wkv_a": _Leaf((d, la.kv_lora_rank + la.qk_rope_head_dim), ("embed", None)),
+            "wkv_b": _Leaf((la.kv_lora_rank, kv_out), (None, "heads")),
+            "wo": _Leaf((heads * la.v_head_dim, d), ("heads", "embed")),
+            **({"wg_head": _Leaf((d, heads), ("embed", None))} if la.output_gate else {}),
         }
-    else:
-        attention = {
-            "wq": ("layer", "embed", "heads"),
-            "wk": ("layer", "embed", "kv"),
-            "wv": ("layer", "embed", "kv"),
-            "wo": ("layer", "heads", "embed"),
-            **(
-                {"q_norm": ("layer", None), "k_norm": ("layer", None)}
-                if config.qk_norm or config.qk_head_norm else {}
-            ),
-        }
-
-    def stack(mlp, attention=attention):
-        return {"attn_norm": ("layer", None), **attention, "mlp_norm": ("layer", None), **mlp}
-
-    mlp = moe_mlp if config.moe else dense_mlp
-    layers = stack(mlp)
-    prefix = stack(dense_mlp)
-    if config.layer_pattern:
-        # Stacked by period: [periods, count in a period, ...].
-        channel = config.linear is not None and config.linear.decay == "channel"
-        linear = {
-            **{name: ("layer", "embed", "heads") for name in ("wq", "wk", "wv", "wg")},
-            "wa": ("layer", "embed", "heads" if channel else None), "wb": ("layer", "embed", None),
-            **{name: ("layer", None, "heads") for name in ("conv_q", "conv_k", "conv_v")},
-            "a_log": ("layer", None), "dt_bias": ("layer", None), "o_norm": ("layer", None),
-            "wo": ("layer", "heads", "embed"),
-        }
-        conv = {
-            "w_in": ("layer", "embed", "heads"), "conv": ("layer", None, "heads"),
-            "w_out": ("layer", "heads", "embed"),
-        }
-        mixers = {"linear": linear, "full": attention, "conv": conv}
-        layers = {
-            kind: {
-                name: (dims[0], None, *dims[1:]) for name, dims in stack(mlp, mixers[kind]).items()
-            }
-            for kind in dict.fromkeys(config.layer_pattern)
-        }
-        prefix = stack(dense_mlp, mixers[config.first_dense_kind])
+    q_out, kv_out = heads * config.head_dim, config.n_kv_heads * config.head_dim
+    q_norm, k_norm = (q_out, kv_out) if config.qk_norm else (config.head_dim, config.head_dim)
+    normed = config.qk_norm or config.qk_head_norm
     return {
-        "embed": ("vocab", "embed"),
-        **({"dense_layers": prefix} if config.first_dense_layers else {}),
-        "layers": layers,
-        "final_norm": (None,),
-        **({} if config.tie_embeddings else {"lm_head": ("embed", "vocab")}),
+        "wq": _Leaf((d, q_out), ("embed", "heads")),
+        "wk": _Leaf((d, kv_out), ("embed", "kv")),
+        "wv": _Leaf((d, kv_out), ("embed", "kv")),
+        "wo": _Leaf((q_out, d), ("heads", "embed")),
+        **({"q_norm": _norm(q_norm), "k_norm": _norm(k_norm)} if normed else {}),
     }
 
 
-def _projection_shapes(config: TransformerConfig) -> dict:
-    """``{leaf: (in, out)}`` of one layer's attention projections."""
-    d = config.dim
-    if config.latent:
-        la = config.latent
-        return {
-            "wq": (d, config.n_heads * la.qk_head_dim),
-            "wkv_a": (d, la.kv_lora_rank + la.qk_rope_head_dim),
-            "wkv_b": (la.kv_lora_rank, config.n_heads * (la.qk_nope_head_dim + la.v_head_dim)),
-            "wo": (config.n_heads * la.v_head_dim, d),
-            **({"wg_head": (d, config.n_heads)} if la.output_gate else {}),
-        }
-    q_out, kv_out = config.n_heads * config.head_dim, config.n_kv_heads * config.head_dim
-    return {"wq": (d, q_out), "wk": (d, kv_out), "wv": (d, kv_out), "wo": (q_out, d)}
-
-
-def _norm_shapes(config: TransformerConfig) -> dict:
-    """``{leaf: width}`` of one layer's norm vectors."""
-    norms = {"attn_norm": config.dim, "mlp_norm": config.dim}
-    if config.latent:
-        norms["kv_norm"] = config.latent.kv_lora_rank
-    elif config.qk_norm:
-        shapes = _projection_shapes(config)
-        norms.update(q_norm=shapes["wq"][1], k_norm=shapes["wk"][1])
-    elif config.qk_head_norm:
-        norms.update(q_norm=config.head_dim, k_norm=config.head_dim)
-    return norms
-
-
-def _expert_dim(config: TransformerConfig) -> int:
-    return config.moe.expert_dim or config.hidden_dim
-
-
-def init_params(config: TransformerConfig, key: jax.Array) -> dict:
-    keys = iter(jax.random.split(key, 16))
-    # The dense prefix draws from a split of its own: a seed goes on giving
-    # the stack after it, and every older configuration, the same weights.
-    prefix_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 8))
-    dt = config.dtype
-    d = config.dim
-    prefix = config.first_dense_layers
-    nl = config.n_layers - prefix
-
-    def dense(key, *shape, scale=None):
-        scale = scale if scale is not None else shape[-2] ** -0.5
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
-
-    def swiglu(keys, *lead, width, names=("w_gate", "w_up", "w_down")):
-        return {
-            names[0]: dense(next(keys), *lead, d, width),
-            names[1]: dense(next(keys), *lead, d, width),
-            names[2]: dense(next(keys), *lead, width, d, scale=width ** -0.5),
-        }
-
-    def attention(keys, *lead):
-        return {
-            **{name: jnp.ones((*lead, width), dt) for name, width in _norm_shapes(config).items()},
-            **{
-                name: dense(next(keys), *lead, *shape)
-                for name, shape in _projection_shapes(config).items()
-            },
-        }
-
-    def experts(keys, *lead):
-        """A mixture-of-experts layer's router and routed experts: the
-        router over ALL experts, the weights of those held here."""
-        moe = config.moe
-        leaves = {
-            "router": dense(next(keys), *lead, d, moe.num_experts).astype(jnp.float32),
-            **swiglu(keys, *lead, moe.num_held, width=_expert_dim(config)),
-        }
-        if moe.scoring == "sigmoid":
-            leaves["router_bias"] = jnp.zeros((*lead, moe.num_experts), jnp.float32)
-        return leaves
-
-    def shared(keys, *lead):
-        return swiglu(
-            keys, *lead, width=config.moe.shared_experts * _expert_dim(config),
-            names=("shared_gate", "shared_up", "shared_down"),
-        )
-
-    if not config.layer_pattern:
-        mlp = experts(keys, nl) if config.moe else swiglu(keys, nl, width=config.hidden_dim)
-    params = {
-        "embed": dense(next(keys), config.vocab_size, d, scale=0.02),
-        "layers": (
-            _init_patterned_layers(config, next(keys), dense, swiglu, attention, experts, shared)
-            if config.layer_pattern else {**attention(keys, nl), **mlp}
-        ),
-        "final_norm": jnp.ones((d,), dt),
-    }
-    if not config.tie_embeddings:
-        params["lm_head"] = dense(next(keys), d, config.vocab_size, scale=d ** -0.5)
-    if config.moe and config.moe.shared_experts and not config.layer_pattern:
-        params["layers"].update(shared(keys, nl))
-    if prefix and config.layer_pattern and config.first_dense_kind != "full":
-        wide_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 16))
-        mixer = _linear_mixer_leaves if config.first_dense_kind == "linear" else _conv_mixer_leaves
-        params["dense_layers"] = {
-            **mixer(config, wide_keys, dense, prefix),
-            **swiglu(wide_keys, prefix, width=config.hidden_dim),
-        }
-    elif prefix:
-        params["dense_layers"] = {
-            **attention(prefix_keys, prefix),
-            **swiglu(prefix_keys, prefix, width=config.hidden_dim),
-        }
-    return params
-
-
-def _linear_mixer_leaves(config, keys, dense, *lead) -> dict:
-    """A linear layer's own leaves and its two block norms, ``lead`` the
-    stacking dims: the convolution filters ``[kernel, channels]`` uniform in
-    +-kernel^-1/2 (a depthwise Conv1d's default), ``a_log = log(A)`` with A
-    uniform in (0, 16), ``dt_bias`` the inverse softplus of a step
-    log-uniform in (0.001, 0.1) (Gated DeltaNet's and Mamba2's
-    initialisation: a per-token decay between 0.2 and 0.9999), the gated
-    norm's weight ones; both gates' parameters in float32. Under a decay per
-    channel ``W_a`` is ``[hidden, heads x d_k]`` and ``dt_bias`` one a
-    channel; ``a_log`` stays one a head. The recipe is kept under
+def _linear_leaves(config: TransformerConfig) -> dict:
+    """A linear layer's own leaves: the convolution filters ``[kernel,
+    channels]`` uniform in +-kernel^-1/2 (a depthwise Conv1d's default),
+    ``a_log = log(A)`` with A uniform in (0, 16), ``dt_bias`` the inverse
+    softplus of a step log-uniform in (0.001, 0.1) (Gated DeltaNet's and
+    Mamba2's initialisation: a per-token decay between 0.2 and 0.9999), the
+    gated norm's weight ones; both gates' parameters in float32. Under a
+    decay per channel ``W_a`` is ``[hidden, heads x d_k]`` and ``dt_bias``
+    one a channel; ``a_log`` stays one a head. The recipe is kept under
     ``gate_lower_bound`` too, where it leaves the bounded gate nearly shut
     on fresh weights (``b sigmoid(A (h W_a + dt_bias))`` with ``dt_bias``
     about ``log(step)``: a log-decay within 0.01 of 0 in most channels): a
@@ -636,98 +514,218 @@ def _linear_mixer_leaves(config, keys, dense, *lead) -> dict:
     between 0 and ``b`` a token, a state that forgets the token before
     (PERF.md section 6, PR 36)."""
     la, d = config.linear, config.dim
-    decays = la.key_dim if la.decay == "channel" else la.num_value_heads
-    a = jax.random.uniform(next(keys), (*lead, la.num_value_heads), jnp.float32, 1e-3, 16.0)
-    dt = jnp.exp(jax.random.uniform(
-        next(keys), (*lead, decays), jnp.float32, math.log(1e-3), math.log(1e-1),
+    channel = la.decay == "channel"
+    decays = la.key_dim if channel else la.num_value_heads
+    conv = lambda channels: _Leaf((la.conv_kernel, channels), (None, "heads"), _filters)
+    uniform = lambda keys, shape, low, high: jax.random.uniform(
+        next(keys), shape, jnp.float32, low, high
+    )
+
+    def dt_bias(keys, shape, dtype):
+        dt = jnp.exp(uniform(keys, shape, math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    a_log = lambda keys, shape, dtype: jnp.log(uniform(keys, shape, 1e-3, 16.0))
+    return {
+        "a_log": _Leaf((la.num_value_heads,), (None,), a_log),
+        "dt_bias": _Leaf((decays,), (None,), dt_bias),
+        "wq": _Leaf((d, la.key_dim), ("embed", "heads")),
+        "wk": _Leaf((d, la.key_dim), ("embed", "heads")),
+        "wv": _Leaf((d, la.value_dim), ("embed", "heads")),
+        "wg": _Leaf((d, la.value_dim), ("embed", "heads")),
+        "wa": _Leaf((d, decays), ("embed", "heads" if channel else None)),
+        "wb": _Leaf((d, la.num_value_heads), ("embed", None)),
+        "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim), "conv_v": conv(la.value_dim),
+        "o_norm": _norm(la.value_head_dim),
+        "wo": _Leaf((la.value_dim, d), ("heads", "embed")),
+    }
+
+
+def _conv_leaves(config: TransformerConfig) -> dict:
+    """A "conv" layer's own leaves: ``W_in`` ``[hidden, 3 hidden]`` (the
+    chunks B, C, x in that order), the filters ``[taps, hidden]``, ``W_out``."""
+    d = config.dim
+    return {
+        "w_in": _Leaf((d, 3 * d), ("embed", "heads")),
+        "conv": _Leaf((config.conv_kernel, d), (None, "heads"), _filters),
+        "w_out": _Leaf((d, d), ("heads", "embed")),
+    }
+
+
+def _expert_dim(config: TransformerConfig) -> int:
+    return config.moe.expert_dim or config.hidden_dim
+
+
+def _mlp_leaves(config: TransformerConfig, experts: bool) -> tuple[dict, dict]:
+    """``(mlp, shared)``: a dense SwiGLU and nothing or, with ``experts``,
+    the router over ALL experts with the weights of those held here, and
+    the shared experts (sharded as a dense MLP is: GSPMD partitions them,
+    outside the per-shard call of the routed experts)."""
+    d, moe = config.dim, config.moe
+
+    def swiglu(names, width, *count):
+        """Gate and up ``[d, width]`` and down ``[width, d]``, behind ``count`` experts."""
+        of_expert = ("expert",) * len(count)
+        wide = _Leaf((*count, d, width), (*of_expert, "embed", "mlp"))
+        narrow = _Leaf((*count, width, d), (*of_expert, "mlp", "embed"))
+        return dict(zip(names, (wide, wide, narrow)))
+
+    if not experts:
+        return swiglu(_EXPERT_WEIGHTS, config.hidden_dim), {}
+    # the router is drawn and rounded as every weight is, and kept in float32
+    router = lambda keys, shape, dtype: _normal(keys, shape, dtype).astype(jnp.float32)
+    no_bias = lambda keys, shape, dtype: jnp.zeros(shape, jnp.float32)
+    mlp = {
+        "router": _Leaf((d, moe.num_experts), ("embed", None), router),
+        **swiglu(_EXPERT_WEIGHTS, _expert_dim(config), moe.num_held),
+    }
+    if moe.scoring == "sigmoid":
+        mlp["router_bias"] = _Leaf((moe.num_experts,), (None,), no_bias)
+    if not moe.shared_experts:
+        return mlp, {}
+    names = ("shared_gate", "shared_up", "shared_down")
+    return mlp, swiglu(names, moe.shared_experts * _expert_dim(config))
+
+
+def _stacks(config: TransformerConfig) -> dict:
+    """The layer stacks in the parameter tree's layout, each ``(lead, mixer,
+    mlp, shared)``: the stacking dims and one layer's leaves in the three
+    parts ``init_params`` draws (the block norms go with the mixer).
+    ``dense_layers`` is the dense prefix, ``[count, ...]``; ``layers`` is
+    ``[count, ...]`` or, under a pattern, ``{kind: [periods, count in a
+    period, ...]}``, the kinds in the order the pattern first names them."""
+    def stack(kind, experts, *lead):
+        norm = _norm(config.dim)
+        mixer = {"attn_norm": norm, "mlp_norm": norm, **_MIXERS[kind][0](config)}
+        return lead, mixer, *_mlp_leaves(config, experts)
+
+    prefix, experts = config.first_dense_layers, config.moe is not None
+    stacks = {"dense_layers": stack(config.prefix_kind, False, prefix)} if prefix else {}
+    if not config.layer_pattern:
+        return {**stacks, "layers": stack("full", experts, config.n_layers - prefix)}
+    counts = {kind: config.layer_pattern.count(kind) for kind in config.layer_pattern}
+    return {**stacks, "layers": {
+        kind: stack(kind, experts, config.periods, count) for kind, count in counts.items()
+    }}
+
+
+def param_logical_dims(config: TransformerConfig) -> dict:
+    """Logical dim names per param leaf: the table's, a stack's leaves led
+    by "layer" (and by None for the place in a period)."""
+    def stacked(stack):
+        lead, *parts = stack
+        lead = ("layer", *(None,) * (len(lead) - 1))
+        return {name: (*lead, *leaf.dims) for part in parts for name, leaf in part.items()}
+
+    model = {name: leaf.dims for name, leaf in _model_leaves(config).items()}
+    stacks = jax.tree.map(stacked, _stacks(config), is_leaf=lambda node: isinstance(node, tuple))
+    return {**model, **stacks}
+
+
+def init_params(config: TransformerConfig, key: jax.Array) -> dict:
+    """Fresh weights: the table's initialisers at the stacks' shapes. Which
+    key each leaf draws decides what a seed gives, and that schedule is here
+    and nowhere else; it is what it is so that a seed goes on giving every
+    older configuration the weights it gave before the next shape came."""
+    def draw(leaves, keys, *lead):
+        return {
+            name: leaf.init(keys, (*lead, *leaf.shape), config.dtype)
+            for name, leaf in leaves.items()
+        }
+
+    split = lambda key, count: iter(jax.random.split(key, count))
+    model, stacks, keys = _model_leaves(config), _stacks(config), split(key, 16)
+    embed = {"embed": model.pop("embed")}
+    if config.layer_pattern:
+        # embed, one key for all the kinds, lm_head; kind number n draws its
+        # mixer (and a dense MLP) from one stream of its own and its experts
+        # (routed, then shared) from a further one.
+        params = draw(embed, keys)
+        kinds_key = next(keys)
+        params.update(draw(model, keys), layers={})
+        for number, (kind, (lead, mixer, mlp, shared)) in enumerate(stacks["layers"].items()):
+            kind_key = jax.random.fold_in(kinds_key, number)
+            kind_keys = split(kind_key, 16)
+            leaves = draw(mixer, kind_keys, *lead)
+            mlp_keys = split(jax.random.fold_in(kind_key, 1), 8) if config.moe else kind_keys
+            params["layers"][kind] = {**leaves, **draw({**mlp, **shared}, mlp_keys, *lead)}
+    else:
+        # the MLP first, then embed, the mixer, lm_head, the shared experts
+        lead, mixer, mlp, shared = stacks["layers"]
+        layers = draw(mlp, keys, *lead)
+        params = draw(embed, keys)
+        layers.update(draw(mixer, keys, *lead))
+        params.update(draw(model, keys), layers=layers)
+        layers.update(draw(shared, keys, *lead))
+    if "dense_layers" in stacks:
+        # The dense prefix draws from a split of its own, as wide as its kind
+        # needs: a seed goes on giving the stack after it the same weights.
+        lead, mixer, mlp, _ = stacks["dense_layers"]
+        keys = split(jax.random.fold_in(key, 1), 8 if config.prefix_kind == "full" else 16)
+        params["dense_layers"] = draw({**mixer, **mlp}, keys, *lead)
+    return params
+
+
+def _is_dims(node) -> bool:
+    return node is None or (
+        isinstance(node, tuple) and all(isinstance(dim, (str, type(None))) for dim in node)
+    )
+
+
+def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: tuple) -> Callable:
+    """``kernel``, per shard when traced under a device mesh: GSPMD cannot
+    partition a Mosaic kernel ("wrap the call in a shard_map"), so under the
+    mesh that build_sharded_train_step traces in, each device runs it on its
+    own block of the operands. ``operands`` and ``result`` give each array's
+    logical dims (None: the same on every shard; for a dict operand, a dict
+    naming the leaves a shard gets, and only those enter), turned into specs
+    by the rules that shard the params: batch over (dp, fsdp), heads over
+    tp. ``refuse`` names the mesh axes the kernel is not written for (a
+    head's scan needs the whole sequence, and neither it nor the convolution
+    runs on a slice of the heads' parameters). ``sums`` names the entries of
+    the kernel's second result, a dict, that are summed over the data
+    shards. No mesh in scope (one device, or a caller that places everything
+    itself): ``kernel`` itself, on the operands as they come."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel
+    for axis in refuse:
+        if dict(mesh.shape).get(axis, 1) > 1:
+            raise NotImplementedError(
+                f"a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
+                "not written: the scan and convolution kernels run per data shard (dp / "
+                "fsdp) with every head, every channel and the whole sequence"
+            )
+    rules = LogicalRules()
+    specs = lambda tree: jax.tree.map(
+        lambda dims: jax.sharding.PartitionSpec() if dims is None else rules.spec(dims, mesh),
+        tree, is_leaf=_is_dims,
+    )
+    shards = rules.spec(("batch",), mesh)[0]
+
+    def summed(*arrays):
+        out, named = kernel(*arrays)
+        return out, {
+            name: jax.lax.psum(value, shards) if name in sums else value
+            for name, value in named.items()
+        }
+
+    per_shard = jax.shard_map(
+        summed if sums and shards else kernel, mesh=mesh,
+        in_specs=specs(operands), out_specs=specs(result), check_vma=False,
+    )
+    return lambda *arrays: per_shard(*(
+        {name: array[name] for name in dims} if isinstance(dims, dict) else array
+        for array, dims in zip(arrays, operands)
     ))
-    conv = lambda channels: jax.random.uniform(
-        next(keys), (*lead, la.conv_kernel, channels), jnp.float32,
-        -la.conv_kernel ** -0.5, la.conv_kernel ** -0.5,
-    ).astype(config.dtype)
-    return {
-        "attn_norm": jnp.ones((*lead, d), config.dtype),
-        "mlp_norm": jnp.ones((*lead, d), config.dtype),
-        "wq": dense(next(keys), *lead, d, la.key_dim),
-        "wk": dense(next(keys), *lead, d, la.key_dim),
-        "wv": dense(next(keys), *lead, d, la.value_dim),
-        "wg": dense(next(keys), *lead, d, la.value_dim),
-        "wa": dense(next(keys), *lead, d, decays),
-        "wb": dense(next(keys), *lead, d, la.num_value_heads),
-        "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim),
-        "conv_v": conv(la.value_dim),
-        "a_log": jnp.log(a),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "o_norm": jnp.ones((*lead, la.value_head_dim), config.dtype),
-        "wo": dense(next(keys), *lead, la.value_dim, d),
-    }
-
-
-def _conv_mixer_leaves(config, keys, dense, *lead) -> dict:
-    """A "conv" layer's own leaves and its two block norms, ``lead`` the
-    stacking dims: ``W_in`` ``[hidden, 3 hidden]`` (the chunks B, C, x in
-    that order), the filters ``[taps, hidden]`` uniform in +-taps^-1/2 (a
-    depthwise Conv1d's default), ``W_out`` ``[hidden, hidden]``."""
-    d, taps = config.dim, config.conv_kernel
-    return {
-        "attn_norm": jnp.ones((*lead, d), config.dtype),
-        "mlp_norm": jnp.ones((*lead, d), config.dtype),
-        "w_in": dense(next(keys), *lead, d, 3 * d),
-        "conv": jax.random.uniform(
-            next(keys), (*lead, taps, d), jnp.float32, -taps ** -0.5, taps ** -0.5,
-        ).astype(config.dtype),
-        "w_out": dense(next(keys), *lead, d, d),
-    }
-
-
-def _init_patterned_layers(config, key, dense, swiglu, attention, experts, shared) -> dict:
-    """``{kind: leaves of [periods, count in a period, ...]}`` for a
-    ``layer_pattern``; each kind draws from a split of its own, and a
-    mixture-of-experts MLP from a further one (a dense MLP goes on drawing
-    from the kind's: the weights a seed gave before experts could sit under
-    a pattern)."""
-    periods = config.periods
-    out = {}
-    for number, kind in enumerate(dict.fromkeys(config.layer_pattern)):
-        lead = (periods, config.layer_pattern.count(kind))
-        kind_key = jax.random.fold_in(key, number)
-        keys = iter(jax.random.split(kind_key, 16))
-        if kind == "full":
-            leaves = attention(keys, *lead)
-        elif kind == "conv":
-            leaves = _conv_mixer_leaves(config, keys, dense, *lead)
-        else:
-            leaves = _linear_mixer_leaves(config, keys, dense, *lead)
-        if config.moe:
-            moe_keys = iter(jax.random.split(jax.random.fold_in(kind_key, 1), 8))
-            mlp = experts(moe_keys, *lead)
-            if config.moe.shared_experts:
-                mlp.update(shared(moe_keys, *lead))
-        else:
-            mlp = swiglu(keys, *lead, width=config.hidden_dim)
-        out[kind] = {**leaves, **mlp}
-    return out
 
 
 def _flash_over_mesh(q, k, v, causal):
-    """The flash kernel, per shard when traced under a device mesh.
-
-    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
-    shard_map"), so under the mesh that build_sharded_train_step traces
-    in, each device runs the kernel on its own [batch, heads] block —
-    attention needs nothing from another batch row or head. The specs come
-    from the same logical rules that shard the params: batch over
-    (dp, fsdp), heads over tp. No mesh in scope (one device, or a caller
-    that places everything itself): the plain call."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return flash_attention(q, k, v, causal=causal)
-    spec = LogicalRules().spec(("batch", "heads", None, None), mesh)
-    return jax.shard_map(
-        functools.partial(flash_attention, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
+    """The flash kernel, on each device's own [batch, heads] block under a
+    mesh: attention needs nothing from another batch row or head."""
+    block = ("batch", "heads", None, None)
+    kernel = functools.partial(flash_attention, causal=causal)
+    return _over_mesh(kernel, (block, block, block), block, (), ())(q, k, v)
 
 
 def _attention_impl(config: TransformerConfig) -> Callable:
@@ -781,9 +779,7 @@ def _latent_qkv(h, layer, config: TransformerConfig, cos_sin, positions):
     heads, nope = config.n_heads, la.qk_nope_head_dim
     cos, sin = cos_sin
     q = (h @ layer["wq"]).reshape(batch, seq, heads, la.qk_head_dim).transpose(0, 2, 1, 3)
-    q = jnp.concatenate(
-        [q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)], axis=-1
-    )
+    q = jnp.concatenate([q[..., :nope], apply_rope(q[..., nope:], cos, sin, positions)], axis=-1)
     with jax.named_scope("latent"):
         kv_a = h @ layer["wkv_a"]
         c = _rmsnorm_ckpt(kv_a[..., :la.kv_lora_rank], layer["kv_norm"], config.rms_norm_eps)
@@ -812,31 +808,6 @@ def _short_conv(x, filters, activation="silu"):
     return (jax.nn.silu(out) if activation == "silu" else out).astype(x.dtype)
 
 
-def _per_data_shard(kernel: Callable, operands, result) -> Callable:
-    """A linear layer's Mosaic ``kernel``, per data shard when traced under
-    a device mesh (``_flash_over_mesh`` says why). ``operands`` and
-    ``result`` give each array's logical dims (None: the same on every
-    shard). A head's scan needs the whole sequence and the kernels are not
-    written to run on a slice of the heads' parameters: tp and sp refuse."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return kernel
-    for axis in ("tp", "sp"):
-        if dict(mesh.shape).get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
-                "not written: the scan and convolution kernels run per data shard (dp / "
-                "fsdp) with every head, every channel and the whole sequence"
-            )
-    spec = lambda dims: (
-        jax.sharding.PartitionSpec() if dims is None else LogicalRules().spec(dims, mesh)
-    )
-    return jax.shard_map(
-        kernel, mesh=mesh, in_specs=tuple(spec(dims) for dims in operands),
-        out_specs=spec(result), check_vma=False,
-    )
-
-
 def _short_conv_over_mesh(config: TransformerConfig, activation="silu") -> Callable:
     """A linear or conv layer's convolutions: ``_short_conv`` under
     ``attention="reference"``, else the kernels of ops/short_conv.py, per
@@ -844,9 +815,8 @@ def _short_conv_over_mesh(config: TransformerConfig, activation="silu") -> Calla
     if config.attention == "reference":
         return functools.partial(_short_conv, activation=activation)
     rows = ("batch", None, None)
-    return _per_data_shard(
-        functools.partial(short_conv, activation=activation), (rows, None), rows
-    )
+    kernel = functools.partial(short_conv, activation=activation)
+    return _over_mesh(kernel, (rows, None), rows, ("tp", "sp"), ())
 
 
 def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
@@ -857,14 +827,15 @@ def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
         return gated_delta_rule_reference
     rows, gates = ("batch", None, None, None), ("batch", None, None)
     decay = rows if config.linear.decay == "channel" else gates
-    return _per_data_shard(gated_delta_rule, (rows, rows, rows, decay, gates), rows)
+    operands = (rows, rows, rows, decay, gates)
+    return _over_mesh(gated_delta_rule, operands, rows, ("tp", "sp"), ())
 
 
 # The epsilon under the square root of q's and k's L2 norm.
 _L2_EPS = 1e-6
 
 
-def _linear_mixer(h, layer, config: TransformerConfig):
+def _linear_mixer(h, layer, config: TransformerConfig, *_):
     """A linear-attention layer's mixer on the branch input ``h`` [batch,
     seq, hidden], before ``W_o``'s residual add (heads ``i``, ``d_k`` /
     ``d_v`` the key / value head dims)::
@@ -940,7 +911,7 @@ def _linear_mixer(h, layer, config: TransformerConfig):
         return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
 
 
-def _conv_mixer(h, layer, config: TransformerConfig):
+def _conv_mixer(h, layer, config: TransformerConfig, *_):
     """A "conv" layer's mixer on the branch input ``h`` [batch, seq,
     hidden], before the residual add: a gated short convolution (LFM2)::
 
@@ -960,38 +931,51 @@ def _conv_mixer(h, layer, config: TransformerConfig):
         return (c * z) @ layer["w_out"]
 
 
-def _attention_block(x, layer, config, cos_sin, positions, attention_fn):
+def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """A "full" layer's mixer on the branch input ``h``: latent attention
+    where ``latent`` is set (each head's output times ``sigmoid(h w_i)``
+    under ``output_gate``), else grouped-query attention; ``W_o``."""
+    batch, seq, _ = h.shape
+    if config.latent:
+        q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
+    else:
+        q, k, v = _qkv(h, layer, config)
+        if cos_sin is not None:
+            cos, sin = cos_sin
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        rep = config.n_heads // config.n_kv_heads
+        k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
+    o = attention_fn(q, k, v, True)
+    if config.latent and config.latent.output_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid((h @ layer["wg_head"]).astype(jnp.float32))
+            gate = gate.transpose(0, 2, 1)[..., None]    # [batch, heads, seq, 1]
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+    o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
+    return o @ layer["wo"]
+
+
+# The kinds of mixer: what a ``layer_pattern`` may name. A kind is one row:
+# its leaves (the table above) and ``apply(h, layer, config, cos_sin,
+# positions, attention_fn)``, the mixer on the branch input (a kind with no
+# use for the last three takes them as ``*_``). A layer is told its kind by
+# whoever walks the stack it lies in; nothing looks at its leaves to guess.
+_MIXERS = {
+    "linear": (_linear_leaves, _linear_mixer),
+    "full": (_full_leaves, _full_mixer),
+    "conv": (_conv_leaves, _conv_mixer),
+}
+LAYER_KINDS = tuple(_MIXERS)
+
+
+def _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn):
     """``x + mixer(norm(x))``, or under ``norm_placement="post"`` ``x +
-    norm(mixer(x))``. The layer's own leaves say which mixer it is: one
-    with three convolution filters is a linear-attention layer, one with
-    ``w_in`` a gated short convolution."""
+    norm(mixer(x))``, the mixer that of the layer's ``kind``."""
     post = config.norm_placement == "post"
     with jax.named_scope("attention"):
-        batch, seq, d = x.shape
         h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
-        if "conv_q" in layer:
-            out = _linear_mixer(h, layer, config)
-        elif "w_in" in layer:
-            out = _conv_mixer(h, layer, config)
-        else:
-            if config.latent:
-                q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
-            else:
-                q, k, v = _qkv(h, layer, config)
-                if cos_sin is not None:
-                    cos, sin = cos_sin
-                    q = apply_rope(q, cos, sin, positions)
-                    k = apply_rope(k, cos, sin, positions)
-                rep = config.n_heads // config.n_kv_heads
-                k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
-            o = attention_fn(q, k, v, True)
-            if "wg_head" in layer:
-                with jax.named_scope("attn_gate"):
-                    gate = jax.nn.sigmoid((h @ layer["wg_head"]).astype(jnp.float32))
-                    gate = gate.transpose(0, 2, 1)[..., None]    # [batch, heads, seq, 1]
-                    o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-            o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
-            out = o @ layer["wo"]
+        out = _MIXERS[kind][1](h, layer, config, cos_sin, positions, attention_fn)
         if post:
             out = _rmsnorm_ckpt(out.astype(x.dtype), layer["attn_norm"], config.rms_norm_eps)
         return x + out.astype(x.dtype)
@@ -1556,10 +1540,8 @@ def _moe_mlp(h, layer, config: TransformerConfig):
 
 
 def _moe_over_mesh(h, layer, config: TransformerConfig):
-    """``_moe_mlp``, per data shard when traced under a device mesh.
-
-    GSPMD cannot partition a Mosaic kernel (``_flash_over_mesh`` has the
-    same trap), and a token's experts need nothing from another batch row:
+    """``_moe_mlp``, per data shard when traced under a device mesh
+    (``_over_mesh``). A token's experts need nothing from another batch row:
     each device routes, sorts and multiplies its own ``batch`` block
     (dp, fsdp) against ALL the experts. The expert weights enter
     replicated, so under ``ep`` / ``fsdp`` / ``tp`` they are all-gathered
@@ -1575,36 +1557,12 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     (``layer["stack"]`` stays outside): replicating the stack would gather
     every layer's experts at once. On one device the stack goes through
     and the kernels read it in place."""
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
-        return _moe_mlp(h, layer, config)
-    rows = LogicalRules().spec(("batch", None, None), mesh)
-    shards = rows[0]
-
-    def per_shard(h, experts):
-        out, routing = _moe_mlp(h, experts, config)
-        if shards:
-            for name in ("prob_sum", "counts", "held_pairs", "overflow"):
-                if name in routing:
-                    routing[name] = jax.lax.psum(routing[name], shards)
-        return out, routing
-
-    whole = jax.sharding.PartitionSpec()
-    per_token = jax.sharding.PartitionSpec(shards, None)
-    experts = {
-        name: layer[name]
-        for name in ("router", "router_bias", *_EXPERT_WEIGHTS) if name in layer
-    }
-    return jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(rows, {name: whole for name in experts}),
-        out_specs=(rows, {
-            "prob_sum": whole, "counts": whole,
-            "experts": per_token, "weights": per_token,
-            **({"held_pairs": whole, "overflow": whole} if config.moe.held else {}),
-        }),
-        check_vma=False,
-    )(h, experts)
+    rows, per_token = ("batch", None, None), ("batch", None)
+    sums = ("prob_sum", "counts") + (("held_pairs", "overflow") if config.moe.held else ())
+    experts = dict.fromkeys(_mlp_leaves(config, True)[0])
+    routing = {**dict.fromkeys(sums), "experts": per_token, "weights": per_token}
+    block = lambda h, layer: _moe_mlp(h, layer, config)
+    return _over_mesh(block, (rows, experts), (rows, routing), (), sums)(h, layer)
 
 
 def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
@@ -1628,14 +1586,13 @@ def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
     return moe.num_experts * jnp.sum(f * p[None, :])
 
 
-def _mlp_block(x, layer, config: TransformerConfig):
-    """``(x + mlp(norm(x)), routing)``; ``routing`` is None for a dense
-    MLP. The layer's own leaves say which it is: a stack with a ``router``
-    is a mixture of experts."""
+def _mlp_block(x, layer, config: TransformerConfig, experts: bool):
+    """``(x + mlp(norm(x)), routing)``: a mixture of experts with
+    ``experts``, else a dense MLP, whose ``routing`` is None."""
     post = config.norm_placement == "post"
     with jax.named_scope("mlp"):
         h = x if post else _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
-        if "router" not in layer:
+        if not experts:
             out = _dense_mlp(h, layer["w_gate"], layer["w_up"], layer["w_down"]).astype(x.dtype)
             if post:
                 out = _rmsnorm_ckpt(out, layer["mlp_norm"], config.rms_norm_eps)
@@ -1683,13 +1640,13 @@ def _scan_layers(step, carry, layers, *xs):
     return jax.lax.scan(body, carry, (index, layers, *xs))
 
 
-def _scan_periods(step, carry, layers, pattern):
+def _scan_periods(steps, carry, layers, pattern):
     """``jax.lax.scan`` over the PERIODS of a patterned model: ``layers`` is
     ``{kind: leaves of [periods, count in a period, ...]}`` and the body
-    runs ``step(carry, layer)`` for the period's layers in ``pattern``'s
-    order, each layer the next of its kind. ``step`` is the one (possibly
-    checkpointed) layer step of every other model, so a period keeps what
-    one layer keeps, once a layer.
+    runs ``steps[kind](carry, layer)`` for the period's layers in
+    ``pattern``'s order, each layer the next of its kind. A kind's step is
+    the one (possibly checkpointed) layer step of every other model, so a
+    period keeps what one layer keeps, once a layer.
 
     Over mixture-of-experts layers the body also hands out each layer's
     ``routing``, stacked in the layers' order (``[periods x period, ...]``
@@ -1717,7 +1674,7 @@ def _scan_periods(step, carry, layers, pattern):
             if stacks[kind]:
                 at = index * pattern.count(kind) + number
                 layer["stack"] = {name: (stack, at) for name, stack in stacks[kind].items()}
-            carry, routing = step(carry, layer)
+            carry, routing = steps[kind](carry, layer)
             routings.append(routing)
         return carry, jax.tree.map(lambda *leaves: jnp.stack(leaves), *routings)
 
@@ -1758,27 +1715,19 @@ def _remat_policy(remat: str) -> Callable:
     if remat == "full":
         return flash
     if remat == "dots":
-        return policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable, flash
-        )
+        return policies.save_from_both_policies(policies.dots_with_no_batch_dims_saveable, flash)
     raise ValueError(f"unknown remat policy {remat!r}")
 
 
 def forward(
-    params: dict,
-    tokens: jax.Array,
-    config: TransformerConfig,
-    positions: jax.Array | None = None,
+    params: dict, tokens: jax.Array, config: TransformerConfig, positions: jax.Array | None = None,
 ) -> jax.Array:
     """tokens: [batch, seq] int32 -> logits [batch, seq, vocab] (f32)."""
     return forward_with_routing(params, tokens, config, positions)[0]
 
 
 def forward_with_routing(
-    params: dict,
-    tokens: jax.Array,
-    config: TransformerConfig,
-    positions: jax.Array | None = None,
+    params: dict, tokens: jax.Array, config: TransformerConfig, positions: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None]:
     """``forward`` and the layer scan's stacked MoE ``routing`` (leading
     dim: layers; see ``_moe_mlp``), None for a dense model: what a
@@ -1796,27 +1745,29 @@ def _hidden_with_routing(params, tokens, config, positions=None):
     cos_sin = _rope_tables(config)
     x = _embed(params, tokens)
 
-    def layer_step(carry, layer):
-        x = carry
-        x = _attention_block(x, layer, config, cos_sin, positions, attention_fn)
-        return _mlp_block(x, layer, config)
+    def layer_step(kind, experts, carry, layer):
+        x = _attention_block(carry, layer, kind, config, cos_sin, positions, attention_fn)
+        return _mlp_block(x, layer, config, experts)
 
-    if config.remat is not None:
-        layer_step = jax.checkpoint(
-            layer_step, policy=_remat_policy(config.remat)
-        )
+    policy = None if config.remat is None else _remat_policy(config.remat)
 
+    def step(kind, experts):
+        """The one layer step under the one policy, for a stack of ``kind``
+        layers."""
+        of_kind = functools.partial(layer_step, kind, experts)
+        return of_kind if policy is None else jax.checkpoint(of_kind, policy=policy)
+
+    experts = config.moe is not None
     if "dense_layers" in params:
-        x, _ = _scan_layers(layer_step, x, params["dense_layers"])
+        x, _ = _scan_layers(step(config.prefix_kind, False), x, params["dense_layers"])
     if config.layer_pattern:
-        return _scan_periods(layer_step, x, params["layers"], config.layer_pattern)
-    return _scan_layers(layer_step, x, params["layers"])
+        steps = {kind: step(kind, experts) for kind in params["layers"]}
+        return _scan_periods(steps, x, params["layers"], config.layer_pattern)
+    return _scan_layers(step("full", experts), x, params["layers"])
 
 
 def logits_loss(
-    logits: jax.Array,
-    targets: jax.Array,
-    mask: jax.Array | None = None,
+    logits: jax.Array, targets: jax.Array, mask: jax.Array | None = None,
 ) -> jax.Array:
     """Token cross-entropy from logits — shared by the fused loss_fn and
     the pipeline's last stage (which receives logits over the wire)."""
@@ -1950,10 +1901,7 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
 def head_loss(
-    params: dict,
-    x: jax.Array,
-    targets: jax.Array,
-    config: TransformerConfig,
+    params: dict, x: jax.Array, targets: jax.Array, config: TransformerConfig,
     mask: jax.Array | None = None,
 ) -> jax.Array:
     """``logits_loss(_head(params, x, config), targets, mask)`` for training:
@@ -1978,10 +1926,7 @@ def head_loss(
 
 
 def loss_fn(
-    params: dict,
-    tokens: jax.Array,
-    targets: jax.Array,
-    config: TransformerConfig,
+    params: dict, tokens: jax.Array, targets: jax.Array, config: TransformerConfig,
     mask: jax.Array | None = None,
 ) -> jax.Array:
     x, routing = _hidden_with_routing(params, tokens, config)
@@ -2000,48 +1945,10 @@ def config_num_params(config: TransformerConfig) -> int:
     """Parameter count from shapes alone — lets the memory-budget check
     refuse a config before any array is materialized. Of a mixture of
     experts it counts the experts HELD here (``MoEConfig.held``)."""
-    d = config.dim
-    # the mixers, each with the layer's two block norms
-    mixer = {"full": (
-        sum(math.prod(shape) for shape in _projection_shapes(config).values())
-        + sum(_norm_shapes(config).values())
-    )}
-    if config.linear:
-        la = config.linear
-        decays = la.key_dim if la.decay == "channel" else la.num_value_heads
-        mixer["linear"] = (
-            2 * d * la.key_dim + 3 * d * la.value_dim            # wq wk, wv wg wo
-            + d * decays + d * la.num_value_heads                # wa, wb
-            + la.conv_kernel * (2 * la.key_dim + la.value_dim)   # the three filters
-            + la.num_value_heads + decays + la.value_head_dim    # a_log, dt_bias, o_norm
-            + 2 * d                                              # the two block norms
-        )
-    # W_in, the filters, W_out, the two block norms
-    mixer["conv"] = 3 * d * d + config.conv_kernel * d + d * d + 2 * d
-    dense_mlp = 3 * d * config.hidden_dim
-    if config.moe:
-        moe = config.moe
-        mlp = (
-            d * moe.num_experts
-            + 3 * d * _expert_dim(config) * (moe.num_held + moe.shared_experts)
-        )
-        if moe.scoring == "sigmoid":
-            mlp += moe.num_experts  # router_bias
-    else:
-        mlp = dense_mlp
-    prefix = config.first_dense_layers
-    if config.layer_pattern:
-        layers = prefix * (mixer[config.first_dense_kind] + dense_mlp) + config.periods * sum(
-            mixer[kind] + mlp for kind in config.layer_pattern
-        )
-    else:
-        layers = (config.n_layers - prefix) * (mixer["full"] + mlp) + prefix * (
-            mixer["full"] + dense_mlp
-        )
-    return (
-        layers
-        + (1 if config.tie_embeddings else 2) * config.vocab_size * d  # embed (+ lm_head)
-        + d  # final_norm
+    size = lambda leaves, lead=(): sum(math.prod(lead + leaf.shape) for leaf in leaves.values())
+    stacks = jax.tree.leaves(_stacks(config), is_leaf=lambda node: isinstance(node, tuple))
+    return size(_model_leaves(config)) + sum(
+        size(part, lead) for lead, *parts in stacks for part in parts
     )
 
 
@@ -2089,15 +1996,11 @@ def partition_stages(params: dict, config: TransformerConfig, num_stages: int) -
             "(loss_fn)"
         )
     if config.n_layers % num_stages != 0:
-        raise ValueError(
-            f"n_layers={config.n_layers} not divisible by {num_stages} stages"
-        )
+        raise ValueError(f"n_layers={config.n_layers} not divisible by {num_stages} stages")
     per = config.n_layers // num_stages
     stages = []
     for s in range(num_stages):
-        layers = jax.tree.map(
-            lambda leaf: leaf[s * per : (s + 1) * per], params["layers"]
-        )
+        layers = jax.tree.map(lambda leaf: leaf[s * per : (s + 1) * per], params["layers"])
         tree = {"layers": layers}
         if s == 0:
             tree["embed"] = params["embed"]
@@ -2113,8 +2016,7 @@ def merge_stages(stage_trees: list[dict]) -> dict:
     (checkpoint save goes through the fused layout so restore works at any
     pipeline factorization, including pp=1)."""
     layers = jax.tree.map(
-        lambda *leaves: jnp.concatenate(leaves, axis=0),
-        *[t["layers"] for t in stage_trees],
+        lambda *leaves: jnp.concatenate(leaves, axis=0), *[t["layers"] for t in stage_trees],
     )
     return {
         "embed": stage_trees[0]["embed"],
@@ -2124,27 +2026,9 @@ def merge_stages(stage_trees: list[dict]) -> dict:
     }
 
 
-def stage_logical_dims(config: TransformerConfig, stage: int, num_stages: int) -> dict:
-    """param_logical_dims subset matching one stage's tree shape — so the
-    in-stage GSPMD (fsdp/tp inside a pipeline stage) reuses the same rules."""
-    full = param_logical_dims(config)
-    tree = {"layers": full["layers"]}
-    if stage == 0:
-        tree["embed"] = full["embed"]
-    if stage == num_stages - 1:
-        tree["final_norm"] = full["final_norm"]
-        tree["lm_head"] = full["lm_head"]
-    return tree
-
-
 def stage_forward(
-    stage_params: dict,
-    x: jax.Array,
-    config: TransformerConfig,
-    *,
-    first: bool,
-    last: bool,
-    positions: jax.Array | None = None,
+    stage_params: dict, x: jax.Array, config: TransformerConfig, *,
+    first: bool, last: bool, positions: jax.Array | None = None,
 ) -> jax.Array:
     """Apply one pipeline stage's layer slice.
 
@@ -2160,11 +2044,9 @@ def stage_forward(
         x = _embed(stage_params, x)
 
     def layer_step(carry, layer):
-        h_in = _attention_block(
-            carry, layer, config, cos_sin, positions, attention_fn
-        )
+        h_in = _attention_block(carry, layer, "full", config, cos_sin, positions, attention_fn)
         # The MoE balancing loss is not carried across stages.
-        return _mlp_block(h_in, layer, config)[0], None
+        return _mlp_block(h_in, layer, config, config.moe is not None)[0], None
 
     x, _ = _scan_layers(layer_step, x, stage_params["layers"])
     if last:
@@ -2227,12 +2109,9 @@ def decode_step(
             q, k, v = _qkv(h, layer, config)
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, 0, length, 0)
-            )
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, 0, length, 0)
-            )
+            at = (0, 0, length, 0)
+            k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype), at)
+            v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype), at)
             rep = config.n_heads // config.n_kv_heads
             keys = _repeat_kv(k_cache, rep).astype(jnp.float32)
             vals = _repeat_kv(v_cache, rep).astype(jnp.float32)
@@ -2243,12 +2122,8 @@ def decode_step(
             o = jnp.einsum("bhqk,bhkd->bhqd", p, vals)
             o = o.transpose(0, 2, 1, 3).reshape(batch, 1, config.n_heads * hd)
             x = x + (o.astype(x.dtype) @ layer["wo"])
-        x, _ = _mlp_block(x, layer, config)
+        x, _ = _mlp_block(x, layer, config, config.moe is not None)
         return x, (k_cache, v_cache)
 
-    x, (new_k, new_v) = _scan_layers(
-        layer_step, x, params["layers"], cache["k"], cache["v"]
-    )
-    logits = _head(params, x, config)[:, 0]
-    new_cache = {"k": new_k, "v": new_v, "length": length + 1}
-    return logits, new_cache
+    x, (new_k, new_v) = _scan_layers(layer_step, x, params["layers"], cache["k"], cache["v"])
+    return _head(params, x, config)[:, 0], {"k": new_k, "v": new_v, "length": length + 1}

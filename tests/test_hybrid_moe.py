@@ -240,7 +240,7 @@ def test_the_shares_add_up(fam, params):
     close(routed + shared, want - x, 2e-5, "four shares and the shared expert once")
     # the whole block under the layer's own path agrees too, and one share is NOT the layer
     share, _, model = _one_layer(fam, params, (8, 8))
-    one, _ = T._mlp_block(x, share, model)
+    one, _ = T._mlp_block(x, share, model, True)
     assert np.max(np.abs(np.asarray(one - want))) > 1e-2 * np.max(np.abs(np.asarray(want - x)))
 
 

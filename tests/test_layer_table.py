@@ -1,0 +1,122 @@
+"""The table of leaves in ``models/transformer.py`` (``_Leaf``, one function
+a part of a layer, ``_MIXERS``) and its three readers, over the six shapes
+the model takes, at tiny widths.
+
+``WEIGHTS`` holds a digest of ``init_params(config, PRNGKey(0))`` for each
+shape, RECORDED ON THE COMMIT BEFORE THE TABLE EXISTED (6f7a14c, where four
+functions spread the leaves and the schedule of keys between them): a seed
+gives the same weights bit for bit as it did there. The benchmark's held
+cells route by these weights and ``benchmarks/reference/`` sets its
+tolerances on them, so a digest that moves is a different benchmark. They
+may be regenerated only after a jax upgrade that moves the ``dense`` case
+too (one ``jax.random.normal`` a leaf: then the generator changed, not the
+table); print them with ``python tests/test_layer_table.py``.
+"""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import transformer as T
+
+_LATENT = dict(kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8)
+_SIGMOID = dict(num_experts=8, top_k=2, scoring="sigmoid", norm_topk_prob=True, expert_dim=32)
+
+SHAPES = {
+    "dense": lambda: T.TransformerConfig.tiny(),
+    "qk_norm_softmax_experts": lambda: T.TransformerConfig.tiny(
+        qk_norm=True, moe=T.MoEConfig(num_experts=4, top_k=2, expert_dim=32),
+    ),
+    "latent_sigmoid_shared_prefix": lambda: T.TransformerConfig.tiny(
+        n_layers=3, first_dense_layers=1, dtype=jnp.bfloat16,
+        latent=T.LatentAttentionConfig(**_LATENT),
+        moe=T.MoEConfig(**_SIGMOID, shared_experts=2, n_group=2, topk_group=1),
+    ),
+    "hybrid_post_norm": lambda: T.TransformerConfig.tiny(
+        n_layers=4, n_kv_heads=4, rope_theta=None, qk_norm=True, norm_placement="post",
+        layer_pattern=("linear", "linear", "linear", "full"),
+        linear=T.LinearAttentionConfig(
+            num_key_heads=2, num_value_heads=2, key_head_dim=8, value_head_dim=16,
+        ),
+    ),
+    "channel_decay_gated_latent_held": lambda: T.TransformerConfig.tiny(
+        n_layers=4, first_dense_layers=1, first_dense_kind="linear", dtype=jnp.bfloat16,
+        layer_pattern=("linear", "linear", "full"),
+        linear=T.LinearAttentionConfig(
+            num_key_heads=2, num_value_heads=2, key_head_dim=8, value_head_dim=8,
+            decay="channel", gate_lower_bound=-5.0, output_gate="sigmoid",
+        ),
+        latent=T.LatentAttentionConfig(**_LATENT, output_gate="head"),
+        moe=T.MoEConfig(**_SIGMOID, shared_experts=1, n_group=4, topk_group=2, held=(2, 2)),
+    ),
+    "conv_tied_head_norm": lambda: T.TransformerConfig.tiny(
+        n_layers=5, first_dense_layers=1, first_dense_kind="conv", dtype=jnp.bfloat16,
+        layer_pattern=("conv", "full", "conv", "conv"), conv_kernel=3,
+        qk_head_norm=True, tie_embeddings=True,
+        moe=T.MoEConfig(
+            num_experts=4, top_k=2, scoring="sigmoid", norm_topk_prob=True, renorm_eps=1e-6,
+            expert_dim=32, held=(0, 2),
+        ),
+    ),
+}
+
+WEIGHTS = {
+    "dense": "909a7d09effcd59d88d5721d367caac134fd38f6e1fd3498282f7d6ab87c203a",
+    "qk_norm_softmax_experts": "083c5943945e7f4362cef4005f9d0247a91ca5be96c279b024c9738443badaf1",
+    "latent_sigmoid_shared_prefix": "a8899cc57b31bc7d7c65ef6bfaa4f2815937583a9c6e6542c3a4b53025905c02",
+    "hybrid_post_norm": "c28bf7dcbdb2e2492b01b14b1edb0647e32c194c6548795c19ac252b788c927d",
+    "channel_decay_gated_latent_held": "6798f5e27bb456497e13f0f96b1816a60a28249a33df5ed3ec69b94390055015",
+    "conv_tied_head_norm": "33de65ffcdb6b1acf14b8bbbbeadcecb6eed535fa11dd9eac67c51e7272e6b74",
+}
+
+
+def digest(params) -> str:
+    """sha256 over every leaf's path, shape, dtype and bytes, paths sorted."""
+    h = hashlib.sha256()
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    for path, leaf in sorted((jax.tree_util.keystr(path), leaf) for path, leaf in leaves):
+        h.update(f"{path} {leaf.shape} {leaf.dtype}".encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+@pytest.fixture(scope="module", params=list(SHAPES))
+def shape(request):
+    config = SHAPES[request.param]()
+    return request.param, config, T.init_params(config, jax.random.PRNGKey(0))
+
+
+def test_a_seed_gives_the_weights_it_gave_before_the_table(shape):
+    name, _config, params = shape
+    assert digest(params) == WEIGHTS[name]
+
+
+def test_logical_dims_have_the_params_tree_and_each_leafs_rank(shape):
+    _name, config, params = shape
+    is_dims = lambda node: isinstance(node, tuple)
+    dims = T.param_logical_dims(config)
+    assert jax.tree.structure(dims, is_leaf=is_dims) == jax.tree.structure(params)
+    ranks = jax.tree.map(len, dims, is_leaf=is_dims)
+    assert ranks == jax.tree.map(jnp.ndim, params)
+    for name, subtree in dims.items():  # a stack's leaves, and no other, lead with "layer"
+        leads = {d[0] == "layer" for d in jax.tree.leaves(subtree, is_leaf=is_dims)}
+        assert leads == {name in ("layers", "dense_layers")}
+
+
+def test_the_count_from_shapes_is_the_count_of_the_arrays(shape):
+    _name, config, params = shape
+    assert T.config_num_params(config) == T.num_params(params)
+
+
+def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
+    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv")
+    with pytest.raises(ValueError, match="kinds are"):
+        T.TransformerConfig.tiny(layer_pattern=("full", "sliding"))
+
+
+if __name__ == "__main__":
+    for name, make in SHAPES.items():
+        print(f'    "{name}": "{digest(T.init_params(make(), jax.random.PRNGKey(0)))}",')

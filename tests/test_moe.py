@@ -139,7 +139,7 @@ def test_a_batch_routed_entirely_to_one_expert_keeps_every_token():
     fam = build()
     layer = one_expert_layer(fam.model)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(6), (2, 64, 64))) + 0.1
-    got, routing = T._mlp_block(x, layer, fam.model)
+    got, routing = T._mlp_block(x, layer, fam.model, True)
     assert np.asarray(routing["counts"]).tolist() == [[128] + [0] * 7, [0, 128] + [0] * 6]
     assert set(np.asarray(routing["experts"]).reshape(-1).tolist()) == {0, 1}
     want, _ = reference.moe_forward(
