@@ -1,10 +1,11 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the six
+"""Flagship model: a decoder-only transformer, TPU-first, in the seven
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
 checkpoint policy, one head and loss):
   * attention: RMSNorm, then either grouped-query attention (q / k / v
-    projections to one ``head_dim``, RoPE over the whole head, optional
+    projections to heads of ``head_dim``, which is ``dim // n_heads`` unless
+    the config states it apart, RoPE over the whole head, optional
     q/k norms, over the whole projection with ``qk_norm`` or head by head
     with ``qk_head_norm``: Llama, Mistral, OLMoE, LFM2) or, with
     ``latent=``, multi-head latent
@@ -57,6 +58,18 @@ checkpoint policy, one head and loss):
     grouped-query layers under ``qk_head_norm``, over sigmoid-and-bias
     routed experts; and ``tie_embeddings``: the head is the transposed
     embedding, one leaf whose gradient is the sum of its two uses.
+  * the seventh, window attention over ReLU experts routed ahead of
+    attention (SmallThinker-21BA3B): a fourth kind of layer, "window",
+    grouped-query attention whose query i sees the ``window`` keys up to its
+    own, the band native in the flash kernels (``_window_mixer``, scopes
+    ``window_attention`` and, around the kernel calls, ``window_flash``),
+    three to one with global "full" layers; ``rope_kinds`` says which kinds
+    the rotary embedding turns (there the window layers alone: the global
+    layers carry no position); ``head_dim`` stated apart from the stream's
+    width (28 heads of 128 on 2560); and in ``MoEConfig`` the experts'
+    ``activation`` ("relu": ReGLU), ``router_input="layer_input"`` (the
+    router reads the un-normed stream at the LAYER's input, before the
+    attention norm) and ``router_precision``.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
@@ -85,8 +98,10 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     decode_step) beyond grouped-query attention (the latent, recurrent-state
     and convolution-state caches), the pipeline (partition_stages /
     stage_forward) over a dense prefix, a pattern or a tied head, tp or sp
-    over a patterned model (dp / fsdp work), ``norm_placement="post"`` over
-    expert layers.
+    over a patterned model with linear or conv layers (dp / fsdp work),
+    ``norm_placement="post"`` over expert layers, a window layer under a
+    callable ``attention`` or through decode (a ring cache of ``window``
+    rows), a stated ``head_dim`` through decode or the pipeline.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -129,7 +144,7 @@ SCOPES = ("embed", "attention", "mlp", "head", "loss", "optimizer")
 # Inside "mlp", what a mixture-of-experts block names (_moe_mlp): "router"
 # (logits, softmax, top-k, the balancing statistics), "dispatch" (sort by
 # expert, gather the rows, weigh and sum them back per token), "experts"
-# (the three grouped matmuls and _silu_mul).
+# (the three grouped matmuls and the gated product, _silu_mul or _relu_mul).
 MOE_SCOPES = ("router", "dispatch", "experts")
 # What a checkpointed layer keeps of a mixture-of-experts block under ``held``
 # (``_remat_policy``): the router's choice ``[tokens, top_k]`` and the held
@@ -151,14 +166,18 @@ LATENT_SCOPES = ("latent", "shared")
 # gates, the chunk preparation and the two scan kernels) and "gate_norm"
 # (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
-# Three names outside the four vocabularies, read by name
+# Five names outside the four vocabularies, read by name
 # (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
 # (the chunk preparation under a decay per channel, opened in
 # ops/gated_delta_rule.py: forward, and backward through its custom VJP),
 # "attn_gate", inside "attention" (a latent layer's head-wise output gate),
 # and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
 # the two gates, W_out, and within it "short_conv", the convolution's two
-# kernels called with no activation).
+# kernels called with no activation), "window_attention", inside "attention"
+# (a "window" layer's whole mixer: q / k / v, RoPE, the repeat of K and V,
+# the kernels, W_o), and within it "window_flash" (the flash kernels called
+# with a window, forward and backward: they are the "full" layers' jitted
+# functions, so the scope is what tells a window layer's calls apart).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
@@ -211,8 +230,31 @@ class MoEConfig:
     # chooses the path); ``routing["held_pairs"]`` and ``["overflow"]`` count
     # what a routing sent here and whether that passed the bound.
     held: tuple[int, int] | None = None
+    # The gate's activation in every expert (routed and shared): "silu"
+    # (SwiGLU), or "relu" (ReGLU: ``relu(h W_gate) * (h W_up)``, whose zeros
+    # are what a sparse inference engine skips).
+    activation: str = "silu"
+    # What the router reads: "normed", the block's own normed input, as the
+    # experts do; or "layer_input", the residual stream at the LAYER's
+    # input, before the attention norm and before attention (a router placed
+    # ahead of attention, so that a layer's experts are known while its
+    # attention runs). Its gradient then reaches the stream directly and no
+    # norm weight.
+    router_input: str = "normed"
+    # The ``precision`` of the router's float32 matmul (``jax.lax.Precision``
+    # by name). None: the platform's default, which on a TPU rounds the
+    # float32 router weights to bfloat16 on their way into the MXU (one
+    # pass); "highest": float32 all through, for a router whose logits lie
+    # near one another (an un-normed stream of scale 0.02 gives 64 logits
+    # within 0.1, and the choice of six hangs on their fourth digit).
+    router_precision: str | None = None
 
     def __post_init__(self):
+        if self.activation not in _GATE_MUL or self.router_input not in ("normed", "layer_input"):
+            raise ValueError(
+                f"unknown activation {self.activation!r} (one of {tuple(_GATE_MUL)}) or "
+                f"router_input {self.router_input!r} ('normed' | 'layer_input')"
+            )
         if self.n_group > 1 or self.topk_group > 1:
             if self.scoring != "sigmoid":
                 raise ValueError("routing in groups is DeepSeek-V3's sigmoid routine: scoring='sigmoid'")
@@ -309,10 +351,23 @@ class TransformerConfig:
     n_layers: int = 32
     n_heads: int = 32
     n_kv_heads: int = 8
+    # The size of one attention head; None: ``dim // n_heads`` (and that is
+    # what the field reads afterwards). A model may state it apart from the
+    # stream's width: 28 heads of 128 on a stream of 2560 project q to 3584.
+    # ``dataclasses.replace`` of ``dim`` or ``n_heads`` keeps what it reads.
+    head_dim: int | None = None
     hidden_dim: int = 11008
     max_seq: int = 4096
     # None: no rotary embedding (Olmo-Hybrid's full layers).
     rope_theta: float | None = 10000.0
+    # The kinds of attention layer the rotary embedding turns ("full",
+    # "window"); None: every one. ("window",): the window layers turn, the
+    # global layers carry no position at all.
+    rope_kinds: tuple[str, ...] | None = None
+    # How many keys a "window" layer's query sees, its own position counted
+    # (query i sees ``i - window < j <= i``). None: no layer has a window,
+    # and a pattern may not name the kind.
+    window: int | None = None
     rms_norm_eps: float = 1e-6
     # RMSNorm with a learned weight over the WHOLE projected q and k
     # vectors, before the split into heads and before RoPE (OLMoE).
@@ -363,10 +418,18 @@ class TransformerConfig:
     remat: str | None = None
 
     def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (the whole projection) and qk_head_norm (head by head): one")
+        unturned = set(self.rope_kinds or ()) - {"full", "window"}
+        if unturned or (self.latent and self.rope_kinds is not None and "full" not in self.rope_kinds):
+            raise ValueError(
+                f"rope_kinds {self.rope_kinds!r}: the attention kinds are 'full' and 'window', "
+                "and latent attention always turns its shared rope key"
+            )
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)
@@ -379,6 +442,14 @@ class TransformerConfig:
             )
         if self.first_dense_kind not in LAYER_KINDS:
             raise ValueError(f"first_dense_kind {self.first_dense_kind!r}: kinds are {LAYER_KINDS}")
+        if "window" in self._kinds():
+            if self.window is None or self.window < 1:
+                raise ValueError("a pattern with window layers needs window= (keys a query sees)")
+            if callable(self.attention):
+                raise NotImplementedError(
+                    "a window layer under a callable attention= (ring, ulysses) is not written: "
+                    "the window is the flash kernels' (attention='flash' | 'reference')"
+                )
         if "linear" in self._kinds():
             la = self.linear
             if la is None:
@@ -400,10 +471,6 @@ class TransformerConfig:
         """The mixer of the leading dense layers: ``first_dense_kind`` says
         under a pattern, and without one every layer is "full"."""
         return self.first_dense_kind if self.layer_pattern else "full"
-
-    @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
 
     @property
     def periods(self) -> int:
@@ -470,22 +537,10 @@ def _model_leaves(config: TransformerConfig) -> dict:
     return {"embed": embed, "final_norm": _norm(d), **head}
 
 
-def _full_leaves(config: TransformerConfig) -> dict:
-    """A "full" layer's mixer: latent attention where ``latent`` is set,
-    else grouped-query attention with its q / k norms' widths."""
-    d, heads, la = config.dim, config.n_heads, config.latent
-    if la:
-        # tp shards whole heads (W_q's and W_kv_b's columns are laid out
-        # head by head) and leaves the latent and the shared rope key whole.
-        kv_out = heads * (la.qk_nope_head_dim + la.v_head_dim)
-        return {
-            "kv_norm": _norm(la.kv_lora_rank),
-            "wq": _Leaf((d, heads * la.qk_head_dim), ("embed", "heads")),
-            "wkv_a": _Leaf((d, la.kv_lora_rank + la.qk_rope_head_dim), ("embed", None)),
-            "wkv_b": _Leaf((la.kv_lora_rank, kv_out), (None, "heads")),
-            "wo": _Leaf((heads * la.v_head_dim, d), ("heads", "embed")),
-            **({"wg_head": _Leaf((d, heads), ("embed", None))} if la.output_gate else {}),
-        }
+def _gqa_leaves(config: TransformerConfig) -> dict:
+    """Grouped-query attention's leaves with its q / k norms' widths: a
+    "window" layer's mixer, and a "full" layer's without ``latent``."""
+    d, heads = config.dim, config.n_heads
     q_out, kv_out = heads * config.head_dim, config.n_kv_heads * config.head_dim
     q_norm, k_norm = (q_out, kv_out) if config.qk_norm else (config.head_dim, config.head_dim)
     normed = config.qk_norm or config.qk_head_norm
@@ -495,6 +550,25 @@ def _full_leaves(config: TransformerConfig) -> dict:
         "wv": _Leaf((d, kv_out), ("embed", "kv")),
         "wo": _Leaf((q_out, d), ("heads", "embed")),
         **({"q_norm": _norm(q_norm), "k_norm": _norm(k_norm)} if normed else {}),
+    }
+
+
+def _full_leaves(config: TransformerConfig) -> dict:
+    """A "full" layer's mixer: latent attention where ``latent`` is set,
+    else grouped-query attention."""
+    d, heads, la = config.dim, config.n_heads, config.latent
+    if not la:
+        return _gqa_leaves(config)
+    # tp shards whole heads (W_q's and W_kv_b's columns are laid out head by
+    # head) and leaves the latent and the shared rope key whole.
+    kv_out = heads * (la.qk_nope_head_dim + la.v_head_dim)
+    return {
+        "kv_norm": _norm(la.kv_lora_rank),
+        "wq": _Leaf((d, heads * la.qk_head_dim), ("embed", "heads")),
+        "wkv_a": _Leaf((d, la.kv_lora_rank + la.qk_rope_head_dim), ("embed", None)),
+        "wkv_b": _Leaf((la.kv_lora_rank, kv_out), (None, "heads")),
+        "wo": _Leaf((heads * la.v_head_dim, d), ("heads", "embed")),
+        **({"wg_head": _Leaf((d, heads), ("embed", None))} if la.output_gate else {}),
     }
 
 
@@ -720,20 +794,23 @@ def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: t
     ))
 
 
-def _flash_over_mesh(q, k, v, causal):
+def _flash_over_mesh(q, k, v, causal, window=None):
     """The flash kernel, on each device's own [batch, heads] block under a
     mesh: attention needs nothing from another batch row or head."""
     block = ("batch", "heads", None, None)
-    kernel = functools.partial(flash_attention, causal=causal)
+    kernel = functools.partial(flash_attention, causal=causal, window=window)
     return _over_mesh(kernel, (block, block, block), block, (), ())(q, k, v)
 
 
-def _attention_impl(config: TransformerConfig) -> Callable:
+def _attention_impl(config: TransformerConfig, window: int | None = None) -> Callable:
+    """``attend(q, k, v, causal)`` as ``config.attention`` says; with
+    ``window``, a window layer's (a callable is refused when the config is
+    made: the window is the flash kernels' and their oracle's)."""
     if callable(config.attention):
         return config.attention
     if config.attention == "flash":
-        return _flash_over_mesh
-    return lambda q, k, v, causal: attention_reference(q, k, v, causal=causal)
+        return functools.partial(_flash_over_mesh, window=window)
+    return lambda q, k, v, causal: attention_reference(q, k, v, causal=causal, window=window)
 
 
 def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
@@ -931,23 +1008,49 @@ def _conv_mixer(h, layer, config: TransformerConfig, *_):
         return (c * z) @ layer["w_out"]
 
 
+def _gqa_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """Grouped-query attention on the branch input ``h``: q / k / v, RoPE
+    where ``cos_sin`` is given, K and V repeated to the query heads, causal
+    attention through ``attention_fn``; ``W_o``."""
+    batch, seq, _ = h.shape
+    q, k, v = _qkv(h, layer, config)
+    if cos_sin is not None:
+        cos, sin = cos_sin
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+    rep = config.n_heads // config.n_kv_heads
+    k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
+    o = attention_fn(q, k, v, True)
+    o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
+    return o @ layer["wo"]
+
+
+def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
+    """A "window" layer's mixer: grouped-query attention whose query i sees
+    the ``config.window`` keys up to its own (``i - window < j <= i``), the
+    band native in the flash kernels (ops/flash_attention.py: the tiles
+    outside it are neither computed nor fetched). The kernel calls are the
+    "full" layers' jitted functions; scope ``window_flash`` tells them apart."""
+    window_fn = _attention_impl(config, config.window)
+
+    def attention_fn(q, k, v, causal):
+        with jax.named_scope("window_flash"):
+            return window_fn(q, k, v, causal)
+
+    with jax.named_scope("window_attention"):
+        return _gqa_mixer(h, layer, config, cos_sin, positions, attention_fn)
+
+
 def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
     """A "full" layer's mixer on the branch input ``h``: latent attention
     where ``latent`` is set (each head's output times ``sigmoid(h w_i)``
     under ``output_gate``), else grouped-query attention; ``W_o``."""
+    if not config.latent:
+        return _gqa_mixer(h, layer, config, cos_sin, positions, attention_fn)
     batch, seq, _ = h.shape
-    if config.latent:
-        q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
-    else:
-        q, k, v = _qkv(h, layer, config)
-        if cos_sin is not None:
-            cos, sin = cos_sin
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-        rep = config.n_heads // config.n_kv_heads
-        k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
+    q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
     o = attention_fn(q, k, v, True)
-    if config.latent and config.latent.output_gate:
+    if config.latent.output_gate:
         with jax.named_scope("attn_gate"):
             gate = jax.nn.sigmoid((h @ layer["wg_head"]).astype(jnp.float32))
             gate = gate.transpose(0, 2, 1)[..., None]    # [batch, heads, seq, 1]
@@ -965,6 +1068,7 @@ _MIXERS = {
     "linear": (_linear_leaves, _linear_mixer),
     "full": (_full_leaves, _full_mixer),
     "conv": (_conv_leaves, _conv_mixer),
+    "window": (_gqa_leaves, _window_mixer),
 }
 LAYER_KINDS = tuple(_MIXERS)
 
@@ -973,6 +1077,8 @@ def _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn):
     """``x + mixer(norm(x))``, or under ``norm_placement="post"`` ``x +
     norm(mixer(x))``, the mixer that of the layer's ``kind``."""
     post = config.norm_placement == "post"
+    if config.rope_kinds is not None and kind not in config.rope_kinds:
+        cos_sin = None
     with jax.named_scope("attention"):
         h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
         out = _MIXERS[kind][1](h, layer, config, cos_sin, positions, attention_fn)
@@ -1005,6 +1111,18 @@ def _silu_mul(gate, up):
     return (act * up.astype(jnp.float32)).astype(gate.dtype)
 
 
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _relu_mul(gate, up):
+    """relu(gate) * up (a ReGLU expert), ``_silu_mul``'s way: float32 math,
+    the model dtype's residency, nothing kept for the backward."""
+    act = jnp.maximum(gate.astype(jnp.float32), 0.0)
+    return (act * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+# ``MoEConfig.activation`` -> the gated product of an expert's two halves.
+_GATE_MUL = {"silu": _silu_mul, "relu": _relu_mul}
+
+
 # Same trick for the norm: backward recomputes the f32 normalize from the
 # bf16 input instead of saving the f32 normalized tensor per layer.
 # prevent_cse=False on both: these only run under lax.scan, where the CSE
@@ -1014,13 +1132,13 @@ def _rmsnorm_ckpt(x, weight, eps):
     return rmsnorm_reference(x, weight, eps=eps)
 
 
-def _dense_mlp(h, w_gate, w_up, w_down):
+def _dense_mlp(h, w_gate, w_up, w_down, gate_mul=_silu_mul):
     # silu math in f32 for accuracy but residuals stored in the model dtype
     # (bf16): halves the dominant activation-memory term vs keeping the
     # f32 intermediates live for backward.
     gate = (h @ w_gate).astype(h.dtype)
     up = (h @ w_up).astype(h.dtype)
-    return _silu_mul(gate, up) @ w_down
+    return gate_mul(gate, up) @ w_down
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -1183,17 +1301,18 @@ def _sum_by_token_bwd(tokens, residuals, g):
 _sum_by_token.defvjp(_sum_by_token_fwd, _sum_by_token_bwd)
 
 
-def _expert_mlps(rows, experts, group_sizes, stacks):
-    """The experts' SwiGLU on ``rows`` sorted into ``group_sizes``."""
+def _expert_mlps(gate_mul, rows, experts, group_sizes, stacks):
+    """The experts' gated MLP (``gate_mul``: SwiGLU's or ReGLU's product) on
+    ``rows`` sorted into ``group_sizes``."""
 
     def expert(rows, name):
         return grouped_matmul(rows, experts[name], group_sizes, within=stacks.get(name))
 
     with jax.named_scope("experts"):
-        return expert(_silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
+        return expert(gate_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
 
 
-def _by_every_pair(top_k, ht, weights, experts, sorting, stacks):
+def _by_every_pair(top_k, gate_mul, ht, weights, experts, sorting, stacks):
     """The experts' weighted sum per token through buffers of the worst
     case's ``tokens * top_k`` rows: the gather by pair in expert order, the
     grouped matmuls, the gather back to (token, choice) order and
@@ -1207,7 +1326,7 @@ def _by_every_pair(top_k, ht, weights, experts, sorting, stacks):
         rows = _rows_by_expert(top_k, ht, sorting["order"], sorting["inverse"])    # [T*K, d]
         if covered is not None:
             rows = jnp.where(covered, rows, 0)
-    out = _expert_mlps(rows, experts, sorting["group_sizes"], stacks)
+    out = _expert_mlps(gate_mul, rows, experts, sorting["group_sizes"], stacks)
     with jax.named_scope("dispatch"):
         if covered is not None:
             out = jnp.where(covered, out, 0)
@@ -1257,7 +1376,7 @@ def _past(bound, sorting):
     return sorting["held_pairs"] > bound
 
 
-def _by_held_pair(top_k, bound, ht, weights, experts, sorting, stacks):
+def _by_held_pair(top_k, bound, gate_mul, ht, weights, experts, sorting, stacks):
     """The same sum, in float32, through buffers of ``bound`` rows, for a
     routing whose held pairs fit them: the sorted order's first ``bound``
     pairs are the held ones and then absent ones; their tokens' rows are
@@ -1276,16 +1395,16 @@ def _by_held_pair(top_k, bound, ht, weights, experts, sorting, stacks):
         group_sizes = jnp.minimum(ends, bound) - jnp.minimum(starts, bound)
         by_token = sorting["by_token"]
         rows = _rows_of_held(ht, token, by_token, covered)        # [bound, d]
-    out = _expert_mlps(rows, experts, group_sizes, stacks)
+    out = _expert_mlps(gate_mul, rows, experts, group_sizes, stacks)
     with jax.named_scope("dispatch"):
         weight = weights.astype(ht.dtype).reshape(-1)[pair]
         weight = jnp.where(_past(bound, sorting), 0, weight)
         return _sum_by_token(ht.shape[0], out, weight, token, by_token, covered)
 
 
-def _by_held_expert(first_expert, expert, ht, weights, chosen, w_gate_up, w_down):
+def _by_held_expert(first_expert, gate_mul, expert, ht, weights, chosen, w_gate_up, w_down):
     """One held expert's part of the sum, float32 ``[tokens, d]``, the plain
-    way: its SwiGLU over EVERY token (``w_gate_up`` ``[2, d, width]``: gate
+    way: its gated MLP over EVERY token (``w_gate_up`` ``[2, d, width]``: gate
     and up as one matmul), times the weight of the token's choice of it (0
     where it was not chosen). No sort, no gather: a trip of the worst case's
     loop (``_held_experts``)."""
@@ -1294,7 +1413,7 @@ def _by_held_expert(first_expert, expert, ht, weights, chosen, w_gate_up, w_down
         share = jnp.sum(jnp.where(mine, weights.astype(ht.dtype), 0).astype(jnp.float32), axis=-1)
     with jax.named_scope("experts"):
         both = jnp.einsum("td,gdf->tgf", ht, w_gate_up).astype(ht.dtype)
-        out = _silu_mul(both[:, 0], both[:, 1]) @ w_down
+        out = gate_mul(both[:, 0], both[:, 1]) @ w_down
     with jax.named_scope("dispatch"):
         return share[:, None] * out.astype(jnp.float32)
 
@@ -1317,8 +1436,8 @@ def _one_experts_weights(stacks, expert):
         return jnp.stack([gate, up]), down
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, stacks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_experts(top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
     """The held experts' weighted sum per token, ``[tokens, d]``: through
     buffers of ``bound`` rows (``_by_held_pair``) for a routing whose held
     pairs fit them, else through the worst case's own path, a loop over the
@@ -1357,12 +1476,14 @@ def _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, sta
     count, and its rule for ``cond`` hands the union of both branches'
     residuals out of the forward."""
     del experts
-    out = _by_held_pair(top_k, bound, ht, weights, _experts_like(stacks), sorting, stacks)
+    out = _by_held_pair(
+        top_k, bound, gate_mul, ht, weights, _experts_like(stacks), sorting, stacks
+    )
     held = sorting["group_sizes"].shape[0]
 
     def one_expert(expert, out):
         return out + _by_held_expert(
-            first_expert, expert, ht, weights, sorting["chosen"],
+            first_expert, gate_mul, expert, ht, weights, sorting["chosen"],
             *_one_experts_weights(stacks, expert),
         )
 
@@ -1372,16 +1493,18 @@ def _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, sta
     return out.astype(ht.dtype)
 
 
-def _held_experts_fwd(top_k, bound, first_expert, ht, weights, experts, sorting, stacks):
-    out = _held_experts(top_k, bound, first_expert, ht, weights, experts, sorting, stacks)
+def _held_experts_fwd(top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
+    out = _held_experts(
+        top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks
+    )
     return out, (ht, weights, sorting, stacks)
 
 
-def _held_experts_bwd(top_k, bound, first_expert, operands, g):
+def _held_experts_bwd(top_k, bound, first_expert, gate_mul, operands, g):
     ht, weights, sorting, stacks = operands
     g = g.astype(jnp.float32)                 # the paths' sums are float32
     _, pull = jax.vjp(
-        lambda *over: _by_held_pair(top_k, bound, *over, sorting, stacks),
+        lambda *over: _by_held_pair(top_k, bound, gate_mul, *over, sorting, stacks),
         ht, weights, _experts_like(stacks),
     )
     held = sorting["group_sizes"].shape[0]
@@ -1390,7 +1513,7 @@ def _held_experts_bwd(top_k, bound, first_expert, operands, g):
         dht, dweights, dexperts = grads
         _, pull = jax.vjp(
             lambda *over: _by_held_expert(
-                first_expert, expert, *over[:2], sorting["chosen"], *over[2:]
+                first_expert, gate_mul, expert, *over[:2], sorting["chosen"], *over[2:]
             ),
             ht, weights, *_one_experts_weights(stacks, expert),
         )
@@ -1411,9 +1534,12 @@ def _held_experts_bwd(top_k, bound, first_expert, operands, g):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _moe_mlp(h, layer, config: TransformerConfig):
+def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
     """Dropless mixture of experts: every token reaches each of its
     ``top_k`` experts whatever the routing. Returns ``(out, routing)``.
+    The router reads ``routed_by`` ``[batch, seq, d]`` where one is given
+    (``MoEConfig.router_input="layer_input"``: the layer's input), else
+    ``h``, the experts' normed input.
 
     The ``tokens x top_k`` (token, choice) pairs are sorted by expert
     (stable), the tokens' rows gathered in that order, and gate / up / down
@@ -1468,8 +1594,13 @@ def _moe_mlp(h, layer, config: TransformerConfig):
     if moe.held:
         bound = held_row_bound(tokens, moe.top_k, moe.held[1], moe.num_experts)
     bounded = bound < tokens * moe.top_k
+    gate_mul = _GATE_MUL[moe.activation]
     with jax.named_scope("router"):
-        logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
+        read = ht if routed_by is None else routed_by.reshape(tokens, d)
+        logits = jnp.matmul(
+            read.astype(jnp.float32), layer["router"].astype(jnp.float32),
+            precision=moe.router_precision,
+        )
         if moe.scoring == "sigmoid":
             scores = jax.nn.sigmoid(logits)                      # [T, E]
             biased = scores + jax.lax.stop_gradient(layer["router_bias"])
@@ -1533,13 +1664,15 @@ def _moe_mlp(h, layer, config: TransformerConfig):
         stacks = stacks or {
             name: (jax.lax.stop_gradient(leaf)[None], 0) for name, leaf in expert_weights.items()
         }
-        out = _held_experts(moe.top_k, bound, first, ht, weights, expert_weights, sorting, stacks)
+        out = _held_experts(
+            moe.top_k, bound, first, gate_mul, ht, weights, expert_weights, sorting, stacks
+        )
     else:
-        out = _by_every_pair(moe.top_k, ht, weights, expert_weights, sorting, stacks)
+        out = _by_every_pair(moe.top_k, gate_mul, ht, weights, expert_weights, sorting, stacks)
     return out.reshape(batch, seq, d), routing
 
 
-def _moe_over_mesh(h, layer, config: TransformerConfig):
+def _moe_over_mesh(h, layer, config: TransformerConfig, routed_by=None):
     """``_moe_mlp``, per data shard when traced under a device mesh
     (``_over_mesh``). A token's experts need nothing from another batch row:
     each device routes, sorts and multiplies its own ``batch`` block
@@ -1561,8 +1694,12 @@ def _moe_over_mesh(h, layer, config: TransformerConfig):
     sums = ("prob_sum", "counts") + (("held_pairs", "overflow") if config.moe.held else ())
     experts = dict.fromkeys(_mlp_leaves(config, True)[0])
     routing = {**dict.fromkeys(sums), "experts": per_token, "weights": per_token}
-    block = lambda h, layer: _moe_mlp(h, layer, config)
-    return _over_mesh(block, (rows, experts), (rows, routing), (), sums)(h, layer)
+    # the streams a shard gets its rows of: ``h`` and, where the router reads
+    # another, that one
+    streams = (h,) if routed_by is None else (h, routed_by)
+    block = lambda *args: _moe_mlp(args[0], args[-1], config, *args[1:-1])
+    operands = (*(rows,) * len(streams), experts)
+    return _over_mesh(block, operands, (rows, routing), (), sums)(*streams, layer)
 
 
 def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
@@ -1586,9 +1723,11 @@ def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
     return moe.num_experts * jnp.sum(f * p[None, :])
 
 
-def _mlp_block(x, layer, config: TransformerConfig, experts: bool):
+def _mlp_block(x, layer, config: TransformerConfig, experts: bool, layer_input=None):
     """``(x + mlp(norm(x)), routing)``: a mixture of experts with
-    ``experts``, else a dense MLP, whose ``routing`` is None."""
+    ``experts``, else a dense MLP, whose ``routing`` is None. ``layer_input``
+    is the stream before the layer's attention block: what the router reads
+    under ``MoEConfig.router_input="layer_input"``."""
     post = config.norm_placement == "post"
     with jax.named_scope("mlp"):
         h = x if post else _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
@@ -1601,13 +1740,19 @@ def _mlp_block(x, layer, config: TransformerConfig, experts: bool):
             raise NotImplementedError(
                 'norm_placement="post" over a mixture-of-experts layer is not written'
             )
-        out, routing = _moe_over_mesh(h, layer, config)
+        routed_by = None
+        if config.moe.router_input == "layer_input":
+            if layer_input is None:
+                raise ValueError("router_input='layer_input': the block needs the layer's input")
+            routed_by = layer_input
+        out, routing = _moe_over_mesh(h, layer, config, routed_by)
         if config.moe.shared_experts:
-            # Outside the per-shard call: a plain SwiGLU that GSPMD shards
+            # Outside the per-shard call: a plain gated MLP that GSPMD shards
             # as it shards a dense MLP.
             with jax.named_scope("shared"):
                 out = out + _dense_mlp(
-                    h, layer["shared_gate"], layer["shared_up"], layer["shared_down"]
+                    h, layer["shared_gate"], layer["shared_up"], layer["shared_down"],
+                    _GATE_MUL[config.moe.activation],
                 ).astype(out.dtype)
         return x + out.astype(x.dtype), routing
 
@@ -1747,7 +1892,7 @@ def _hidden_with_routing(params, tokens, config, positions=None):
 
     def layer_step(kind, experts, carry, layer):
         x = _attention_block(carry, layer, kind, config, cos_sin, positions, attention_fn)
-        return _mlp_block(x, layer, config, experts)
+        return _mlp_block(x, layer, config, experts, carry)
 
     policy = None if config.remat is None else _remat_policy(config.remat)
 
@@ -1965,7 +2110,17 @@ def linear_state_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
     )
 
 
+def _refuse_stated_head_dim(config: TransformerConfig, what: str) -> None:
+    if config.n_heads * config.head_dim != config.dim:
+        raise NotImplementedError(
+            f"{what} with a head_dim stated apart from dim // n_heads ({config.n_heads} heads of "
+            f"{config.head_dim} on a stream of {config.dim}) is not written: nothing holds that "
+            "path to the fused forward at such a head size: train it fused (loss_fn)"
+        )
+
+
 def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
+    _refuse_stated_head_dim(config, what)
     if config.layer_pattern:
         raise NotImplementedError(
             f"{what} splits ONE stacked layer tree; a config with a layer_pattern stacks "
@@ -2046,7 +2201,7 @@ def stage_forward(
     def layer_step(carry, layer):
         h_in = _attention_block(carry, layer, "full", config, cos_sin, positions, attention_fn)
         # The MoE balancing loss is not carried across stages.
-        return _mlp_block(h_in, layer, config, config.moe is not None)[0], None
+        return _mlp_block(h_in, layer, config, config.moe is not None, carry)[0], None
 
     x, _ = _scan_layers(layer_step, x, stage_params["layers"])
     if last:
@@ -2058,6 +2213,12 @@ def stage_forward(
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 def _refuse_latent_cache(config: TransformerConfig) -> None:
+    _refuse_stated_head_dim(config, "decode")
+    if config.layer_pattern and "window" in config._kinds():
+        raise NotImplementedError(
+            "decode with window layers needs a ring cache of `window` rows a window layer "
+            "beside the full layers' caches, under one allocator, which is not written yet"
+        )
     if config.layer_pattern and "conv" in config._kinds():
         raise NotImplementedError(
             "decode with conv layers needs a convolution-state cache beside the KV cache "
@@ -2122,7 +2283,7 @@ def decode_step(
             o = jnp.einsum("bhqk,bhkd->bhqd", p, vals)
             o = o.transpose(0, 2, 1, 3).reshape(batch, 1, config.n_heads * hd)
             x = x + (o.astype(x.dtype) @ layer["wo"])
-        x, _ = _mlp_block(x, layer, config, config.moe is not None)
+        x, _ = _mlp_block(x, layer, config, config.moe is not None, carry)
         return x, (k_cache, v_cache)
 
     x, (new_k, new_v) = _scan_layers(layer_step, x, params["layers"], cache["k"], cache["v"])

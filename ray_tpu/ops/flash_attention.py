@@ -23,6 +23,19 @@ masked (``_tile_needed``) skip all compute, and fetch nothing either: the
 index maps clamp a skipped step to its row's nearest needed block, which is
 already resident, so Pallas issues no DMA for it (≈2× for causal training).
 
+A sliding ``window`` (query i sees keys j with ``i - window < j <= i``, the
+query's own position counted) is the same mechanism with a second edge: a
+tile whose LAST key lies at or before its first query's ``i - window`` is
+skipped as a tile above the diagonal is, and the mask gains ``k_pos > q_pos
+- window``. What runs is the band: at 16,384 positions, 1024-blocks and a
+window of 4096, 70 of the 136 causal tiles (rows of 1, 2, 3, 4, then twelve
+of 5), 44 % of the causal half's pairs. And the grid's sequential axis is
+as long as the band's longest row (``band_steps``: 5 steps there, not 16):
+step ``s`` of a row is its first needed block + ``s``, so a window layer
+walks 80 grid steps a head where a global layer walks 256 (a skipped step
+costs 0.42 us a kernel on a v5e: PERF.md section 6, PR 45). ``window=None``
+is the program it was before there was a window.
+
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
 ``flash_attention`` resolves ``interpret`` once (ops.resolve_interpret); the
@@ -35,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
@@ -67,35 +81,109 @@ def _across(stat, width):
     return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
 
 
-def _tile_needed(causal, causal_offset, q_index, kv_index, block_q, block_k):
-    """False only for tiles that the causal mask zeroes entirely (the tile's
-    last query sits before its first key). A comparison only, so it serves
-    the kernels' ``program_id``s and ``causal_tile_counts``'s Python ints
-    alike; the index maps below hold the same boundary in closed form
+def _tile_needed(causal, causal_offset, q_index, kv_index, block_q, block_k,
+                 window=None):
+    """False only for tiles that the mask zeroes entirely: the tile's last
+    query sits before its first key (causal), or its last key at or before
+    its first query's ``i - window``. A comparison only, so it serves the
+    kernels' ``program_id``s and ``causal_tile_counts``'s Python ints alike;
+    the index maps below hold the same boundaries in closed form
     (tests/test_ops.py holds them to it)."""
     if not causal:
         return True
-    return causal_offset + (q_index + 1) * block_q - 1 >= kv_index * block_k
+    needed = causal_offset + (q_index + 1) * block_q - 1 >= kv_index * block_k
+    if window is None:
+        return needed
+    first_query = causal_offset + q_index * block_q
+    return needed & ((kv_index + 1) * block_k - 1 > first_query - window)
 
 
-def causal_tile_counts(seq_q, seq_k, block_q, block_k):
+def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
     """How many tiles of one causal call (per head instance) are executed
-    and how many skipped: a property of the shapes alone."""
+    and how many skipped: a property of the shapes (and the window) alone."""
     tiles = (seq_q // block_q) * (seq_k // block_k)
     executed = sum(
-        _tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k)
+        bool(_tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window))
         for q_index in range(seq_q // block_q)
         for kv_index in range(seq_k // block_k)
     )
     return {"skipped": tiles - executed, "executed": executed}
 
 
-def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks):
+def _first_kv_block(causal_offset, q_index, block_q, block_k, num_kv_blocks,
+                    window):
+    """The kv block a q row's band starts in under ``window``: its first
+    query's key ``i - window + 1``'s."""
+    first_key = jnp.maximum(causal_offset + q_index * block_q - window + 1, 0)
+    return jnp.minimum(first_key // block_k, num_kv_blocks - 1)
+
+
+def _first_q_block(causal_offset, kv_index, block_q, block_k, num_q_blocks):
+    """The q block a kv row's causal tiles start in: its first key's own
+    query's."""
+    first_query = jnp.maximum(kv_index * block_k - causal_offset, 0)
+    return jnp.minimum(first_query // block_q, num_q_blocks - 1)
+
+
+def band_steps(seq_q, seq_k, block_q, block_k, window):
+    """The grid's sequential extent, ``{"kv": .., "q": ..}``. Without a
+    window every block of a row is a step. Under one: the most blocks from a
+    q row's first needed kv block to its last (the fwd and dq kernels), and
+    from a kv row's first needed q block to its last (dkv); from
+    ``_tile_needed`` itself, over the whole tile grid."""
+    if window is None:
+        return {"kv": seq_k // block_k, "q": seq_q // block_q}
+    q_index = np.arange(seq_q // block_q)[:, None]
+    kv_index = np.arange(seq_k // block_k)[None, :]
+    needed = np.broadcast_to(
+        _tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window),
+        (q_index.size, kv_index.size),
+    )
+
+    def longest(rows):
+        spans = [np.flatnonzero(row) for row in rows]
+        return max([int(span[-1] - span[0]) + 1 for span in spans if span.size] + [1])
+
+    return {"kv": longest(needed), "q": longest(needed.T)}
+
+
+def _band_kv_index(step, q_index, causal_offset, block_q, block_k,
+                   num_kv_blocks, window):
+    """The kv block of grid step ``step`` of q row ``q_index`` in the fwd
+    and dq kernels: the step itself, or under a window the row's first needed
+    block + the step (a block past the last is needed by no query:
+    ``_tile_needed``'s causal edge)."""
+    if window is None:
+        return step
+    return step + _first_kv_block(
+        causal_offset, q_index, block_q, block_k, num_kv_blocks, window
+    )
+
+
+def _band_q_index(step, kv_index, causal_offset, block_q, block_k,
+                  num_q_blocks, window):
+    """The q block of grid step ``step`` of kv row ``kv_index`` in the dkv
+    kernel: the step itself, or under a window the row's first needed block
+    + the step, which may lie past the last q block (the kernel skips such a
+    step, the index map clamps it)."""
+    if window is None:
+        return step
+    return step + _first_q_block(
+        causal_offset, kv_index, block_q, block_k, num_q_blocks
+    )
+
+
+def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks,
+                  window=None):
     """K/V block of grid step (i, j, kv) in the fwd and dq kernels. A q
     row's skipped steps are clamped to its last needed kv block: they name
-    the block already resident, and Pallas issues no DMA for them."""
+    the block already resident, and Pallas issues no DMA for them. Under a
+    window the third grid index is a STEP along the row's band: the row's
+    first needed block + the step, clamped the same way."""
     def index_map(i, j, kv):
         if causal:
+            kv = _band_kv_index(kv, j, causal_offset, block_q, block_k,
+                                num_kv_blocks, window)
             last_key = jnp.maximum(causal_offset + (j + 1) * block_q - 1, 0)
             kv = jnp.minimum(
                 kv, jnp.minimum(last_key // block_k, num_kv_blocks - 1)
@@ -105,24 +193,33 @@ def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks):
     return index_map
 
 
-def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks):
+def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
+                 window=None):
     """Q/dO/lse/delta block of grid step (i, j, qi) in the dkv kernel: a kv
-    row's skipped steps come first, clamped to its first needed q block."""
+    row's skipped steps come first, clamped to its first needed q block.
+    Under a window the third grid index is a STEP from that first needed
+    block on, and those behind the band are clamped to its last."""
     def index_map(i, j, qi):
-        if causal:
-            first_query = jnp.maximum(j * block_k - causal_offset, 0)
+        if causal and window is None:
             qi = jnp.maximum(
-                qi, jnp.minimum(first_query // block_q, num_q_blocks - 1)
+                qi, _first_q_block(causal_offset, j, block_q, block_k, num_q_blocks)
             )
+        elif causal:
+            qi = _band_q_index(qi, j, causal_offset, block_q, block_k,
+                               num_q_blocks, window)
+            # the last query that sees the row's last key
+            last_query = (j + 1) * block_k - 1 + window - 1 - causal_offset
+            qi = jnp.minimum(qi, jnp.clip(last_query // block_q, 0, num_q_blocks - 1))
         return (i, qi, 0)
 
     return index_map
 
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
-                   block_q, block_k, precision, causal_offset):
-    """scale * Q K^T with the causal mask applied — shared by all three
-    kernels so forward and backward can never desynchronize."""
+                   block_q, block_k, precision, causal_offset, window=None):
+    """scale * Q K^T with the causal mask (and the window's lower edge)
+    applied — shared by all three kernels so forward and backward can never
+    desynchronize."""
     q = _mxu(q_ref[0], precision)                # [block_q, d]
     k = _mxu(k_ref[0], precision)                # [block_k, d]
     s = jax.lax.dot_general(
@@ -140,18 +237,24 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
         k_pos = kv_index * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        visible = q_pos >= k_pos
+        if window is not None:
+            visible &= k_pos > q_pos - window
+        s = jnp.where(visible, s, _NEG_INF)
     return s, q, k
 
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
-    causal, block_q, block_k, num_kv_blocks, precision, causal_offset
+    causal, block_q, block_k, num_kv_blocks, precision, causal_offset, window,
+    num_steps
 ):
-    kv_index = pl.program_id(2)
+    step = pl.program_id(2)
     q_index = pl.program_id(1)
+    kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
+                              num_kv_blocks, window)
 
-    @pl.when(kv_index == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -159,14 +262,14 @@ def _flash_fwd_kernel(
 
     # Entirely-masked tiles contribute nothing: skip their compute.
     needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k)
+                          block_q, block_k, window)
 
     @pl.when(needed)
     def _compute():
         s, _, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset,
+            causal_offset=causal_offset, window=window,
         )
 
         # Running max and sum are kept replicated across a vreg's lanes:
@@ -189,7 +292,7 @@ def _flash_fwd_kernel(
         )
         m_scr[:] = m_new
 
-    @pl.when(kv_index == num_kv_blocks - 1)
+    @pl.when(step == num_steps - 1)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
@@ -200,24 +303,27 @@ def _flash_fwd_kernel(
 
 def _flash_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
-    scale, causal, block_q, block_k, num_kv_blocks, precision, causal_offset
+    scale, causal, block_q, block_k, num_kv_blocks, precision, causal_offset,
+    window, num_steps
 ):
-    kv_index = pl.program_id(2)
+    step = pl.program_id(2)
     q_index = pl.program_id(1)
+    kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
+                              num_kv_blocks, window)
 
-    @pl.when(kv_index == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k)
+                          block_q, block_k, window)
 
     @pl.when(needed)
     def _compute():
         s, _, k = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset,
+            causal_offset=causal_offset, window=window,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)                     # [block_q, block_k] f32
@@ -235,7 +341,7 @@ def _flash_dq_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )
 
-    @pl.when(kv_index == num_kv_blocks - 1)
+    @pl.when(step == num_steps - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -243,25 +349,29 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_q_blocks,
-    precision, causal_offset
+    precision, causal_offset, window, num_steps
 ):
-    q_index = pl.program_id(2)
+    step = pl.program_id(2)
     kv_index = pl.program_id(1)
+    q_index = _band_q_index(step, kv_index, causal_offset, block_q, block_k,
+                            num_q_blocks, window)
 
-    @pl.when(q_index == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k)
+                          block_q, block_k, window)
+    if window is not None:
+        needed &= q_index < num_q_blocks     # a step past the last q block
 
     @pl.when(needed)
     def _compute():
         s, q, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset,
+            causal_offset=causal_offset, window=window,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)
@@ -283,7 +393,7 @@ def _flash_dkv_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
 
-    @pl.when(q_index == num_q_blocks - 1)
+    @pl.when(step == num_steps - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -300,6 +410,7 @@ def flash_attention(
     block_k: int | None = None,
     interpret: bool | None = None,
     precision: jax.lax.Precision | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """q, k: [batch, heads, seq, head_dim]; v: [batch, heads, seq, v_dim]
     (GQA is handled by the caller repeating kv heads). Returns [batch, heads,
@@ -317,27 +428,39 @@ def flash_attention(
 
     precision=None keeps the MXU's fast bf16 multiply for bf16 inputs;
     tests pass Precision.HIGHEST for tight reference comparison.
+
+    window: None (every key up to the query's own), or how many keys a
+    query sees, its own position counted: query i sees ``i - window < j <=
+    i`` (positions aligned to the END of the keys where ``seq_q < seq_k``,
+    the decode convention). Static; the tiles wholly outside the band are
+    neither computed nor fetched. Needs ``causal=True``.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"flash_attention: window={window!r} needs causal=True and window >= 1: the window "
+            "is the causal mask's lower edge"
+        )
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _flash_vjp(q, k, v, causal, float(scale), block_q, block_k,
-                      resolve_interpret(interpret), precision)
+                      resolve_interpret(interpret), precision, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_vjp(q, k, v, causal, scale, block_q, block_k, interpret, precision):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_vjp(q, k, v, causal, scale, block_q, block_k, interpret, precision,
+               window):
     out, _ = _flash_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, precision=precision,
+        interpret=interpret, precision=precision, window=window,
     )
     return out
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   precision):
+                   precision, window):
     out, lse = _flash_forward(
         q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, precision=precision,
+        interpret=interpret, precision=precision, window=window,
     )
     out = checkpoint_name(out, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
@@ -345,11 +468,12 @@ def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, precision,
-                   residuals, g):
+                   window, residuals, g):
     q, k, v, out, lse = residuals
     return _flash_backward(
         q, k, v, out, lse, g, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, interpret=interpret, precision=precision,
+        window=window,
     )
 
 
@@ -389,7 +513,8 @@ def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype):
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "scale", "block_q", "block_k", "interpret", "precision"
+        "causal", "scale", "block_q", "block_k", "interpret", "precision",
+        "window",
     ),
 )
 def _flash_forward(
@@ -403,6 +528,7 @@ def _flash_forward(
     block_k: int | None = None,
     interpret: bool,
     precision: jax.lax.Precision | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     batch, heads, seq_q, dim = q.shape
     _, kv_heads, seq_k, _ = k.shape
@@ -421,6 +547,7 @@ def _flash_forward(
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
+    kv_steps = band_steps(seq_q, seq_k, block_q, block_k, window)["kv"]
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -431,15 +558,17 @@ def _flash_forward(
         num_kv_blocks=num_kv_blocks,
         precision=precision,
         causal_offset=causal_offset,
+        window=window,
+        num_steps=kv_steps,
     )
     kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks
+        causal, causal_offset, block_q, block_k, num_kv_blocks, window
     )
     from jax.experimental.pallas import tpu as pltpu
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, num_q_blocks, num_kv_blocks),
+        grid=(bh, num_q_blocks, kv_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_k, dim), kv_map),
@@ -468,12 +597,13 @@ def _flash_forward(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "scale", "block_q", "block_k", "interpret", "precision"
+        "causal", "scale", "block_q", "block_k", "interpret", "precision",
+        "window",
     ),
 )
 def _flash_backward(
     q, k, v, out, lse, g, *, causal, scale, block_q, block_k, interpret,
-    precision
+    precision, window=None
 ):
     batch, heads, seq_q, dim = q.shape
     seq_k, v_dim = v.shape[2:]
@@ -498,6 +628,7 @@ def _flash_backward(
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
+    steps = band_steps(seq_q, seq_k, block_q, block_k, window)
 
     from jax.experimental.pallas import tpu as pltpu
 
@@ -505,14 +636,14 @@ def _flash_backward(
         _flash_dq_kernel,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         num_kv_blocks=num_kv_blocks, precision=precision,
-        causal_offset=causal_offset,
+        causal_offset=causal_offset, window=window, num_steps=steps["kv"],
     )
     kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks
+        causal, causal_offset, block_q, block_k, num_kv_blocks, window
     )
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(bh, num_q_blocks, num_kv_blocks),
+        grid=(bh, num_q_blocks, steps["kv"]),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_k, dim), kv_map),
@@ -531,14 +662,14 @@ def _flash_backward(
         _flash_dkv_kernel,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         num_q_blocks=num_q_blocks, precision=precision,
-        causal_offset=causal_offset,
+        causal_offset=causal_offset, window=window, num_steps=steps["q"],
     )
     q_map = _q_index_map(
-        causal, causal_offset, block_q, block_k, num_q_blocks
+        causal, causal_offset, block_q, block_k, num_q_blocks, window
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, num_kv_blocks, num_q_blocks),
+        grid=(bh, num_kv_blocks, steps["q"]),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), q_map),
             pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
@@ -571,9 +702,12 @@ def _flash_backward(
 
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-    scale: float | None = None
+    scale: float | None = None, window: int | None = None
 ) -> jax.Array:
-    """Pure-jax reference used for kernel numerics tests."""
+    """Pure-jax reference used for kernel numerics tests; ``window`` as
+    ``flash_attention``'s: the ``window`` keys up to the query's own."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"attention_reference: window={window!r} needs causal=True and window >= 1")
     dim = q.shape[-1]
     if scale is None:
         scale = dim ** -0.5
@@ -581,6 +715,8 @@ def attention_reference(
     if causal:
         seq_q, seq_k = s.shape[-2], s.shape[-1]
         mask = jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool), seq_k - seq_q)
+        if window is not None:
+            mask &= ~jnp.tril(mask, seq_k - seq_q - window)
         s = jnp.where(mask, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)

@@ -181,6 +181,30 @@ def test_flash_at_head_size_64_compiles_for_v5e(one_chip):
     assert [g.shape for g in jax.eval_shape(_flash_grads, *shapes)] == [(1, 32, 16384, 64)] * 3
 
 
+def test_flash_under_a_window_compiles_for_v5e(one_chip):
+    """The window layers' call of ``smallthinker-21b-a3b``, ``[1, 28, 16384,
+    128]`` in bfloat16 under a window of 4096 keys: forward, and forward + dq
+    + dkv, compile for the chip in the 1024 x 1024 blocks (the index maps'
+    two clamps and the second mask are scalar and vector code Mosaic has to
+    take); the band is 70 of a head's 256 tiles, walked in a grid of 16 x 5
+    steps."""
+    from ray_tpu.ops.flash_attention import _block_sizes, band_steps, causal_tile_counts
+
+    blocks = _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16)
+    assert causal_tile_counts(16384, 16384, *blocks, 4096)["executed"] == 70
+    assert band_steps(16384, 16384, *blocks, 4096) == {"kv": 5, "q": 5}
+    shapes = [jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16, sharding=one_chip)] * 3
+    windowed = functools.partial(_flash, window=4096)
+    assert _custom_calls(windowed, *shapes) == 1
+    grads = jax.grad(
+        lambda q, k, v: windowed(q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)
+    )
+    calls = _mosaic_calls(jax.jit(grads).lower(*shapes).compile().as_text())
+    assert len(calls) == 3
+    assert sum("_flash_forward" in name for name in calls) == 1
+    assert sum("_flash_backward" in name for name in calls) == 2      # dq, and dk + dv
+
+
 # What the rule's value-and-gradient program may hold beside its arguments
 # and results at the cell's size: two heads a call need 1.19 GiB (three 1.32,
 # six 1.79, all thirty at once 4.69: compiles for a described v5e, PR 33).

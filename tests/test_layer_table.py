@@ -1,5 +1,5 @@
 """The table of leaves in ``models/transformer.py`` (``_Leaf``, one function
-a part of a layer, ``_MIXERS``) and its three readers, over the six shapes
+a part of a layer, ``_MIXERS``) and its three readers, over the seven shapes
 the model takes, at tiny widths.
 
 ``WEIGHTS`` holds a digest of ``init_params(config, PRNGKey(0))`` for each
@@ -10,7 +10,8 @@ cells route by these weights and ``benchmarks/reference/`` sets its
 tolerances on them, so a digest that moves is a different benchmark. They
 may be regenerated only after a jax upgrade that moves the ``dense`` case
 too (one ``jax.random.normal`` a leaf: then the generator changed, not the
-table); print them with ``python tests/test_layer_table.py``.
+table); print them with ``python tests/test_layer_table.py``. The seventh
+shape's ("window" layers) was recorded on the commit that added the kind.
 """
 
 import hashlib
@@ -61,6 +62,15 @@ SHAPES = {
             expert_dim=32, held=(0, 2),
         ),
     ),
+    "window_stated_head_relu_held": lambda: T.TransformerConfig.tiny(
+        dim=48, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, dtype=jnp.bfloat16,
+        layer_pattern=("full", "window", "window", "window"), window=8,
+        rope_kinds=("window",),
+        moe=T.MoEConfig(
+            num_experts=8, top_k=2, norm_topk_prob=True, expert_dim=24, held=(0, 4),
+            activation="relu", router_input="layer_input",
+        ),
+    ),
 }
 
 WEIGHTS = {
@@ -70,6 +80,7 @@ WEIGHTS = {
     "hybrid_post_norm": "c28bf7dcbdb2e2492b01b14b1edb0647e32c194c6548795c19ac252b788c927d",
     "channel_decay_gated_latent_held": "6798f5e27bb456497e13f0f96b1816a60a28249a33df5ed3ec69b94390055015",
     "conv_tied_head_norm": "33de65ffcdb6b1acf14b8bbbbeadcecb6eed535fa11dd9eac67c51e7272e6b74",
+    "window_stated_head_relu_held": "9e6e378b84504b61a8b994a8d36f507845d99ce51eac031e5045934926a2247e",
 }
 
 
@@ -112,9 +123,26 @@ def test_the_count_from_shapes_is_the_count_of_the_arrays(shape):
 
 
 def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
-    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv")
+    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv", "window")
     with pytest.raises(ValueError, match="kinds are"):
         T.TransformerConfig.tiny(layer_pattern=("full", "sliding"))
+
+
+def test_a_window_layers_leaves_are_grouped_query_attentions_at_the_stated_head():
+    """The "window" row: ``_gqa_leaves``, which is a "full" layer's too
+    without ``latent``; q is ``n_heads x head_dim`` wide, not the stream's
+    width; a pattern may name the kind only with ``window=`` set."""
+    config = SHAPES["window_stated_head_relu_held"]()
+    assert T._MIXERS["window"][0] is T._gqa_leaves
+    assert (config.head_dim, config.n_heads * config.head_dim, config.dim) == (16, 64, 48)
+    shapes = {name: leaf.shape for name, leaf in T._gqa_leaves(config).items()}
+    assert shapes == {"wq": (48, 64), "wk": (48, 32), "wv": (48, 32), "wo": (64, 48)}
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    assert params["layers"]["window"]["wq"].shape == (1, 3, 48, 64)
+    assert params["layers"]["full"]["wq"].shape == (1, 1, 48, 64)
+    assert T.TransformerConfig.tiny().head_dim == 16          # None: dim // n_heads
+    with pytest.raises(ValueError, match="window="):
+        T.TransformerConfig.tiny(layer_pattern=("full", "window"))
 
 
 if __name__ == "__main__":

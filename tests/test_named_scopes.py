@@ -52,7 +52,7 @@ MOONLIGHT_SHAPED = dict(
 # A patterned model over expert layers (Ling-3.0-flash-VL's shape): a dense
 # prefix with a linear mixer, then (linear, full) over group-routed experts of
 # which a block is held; what it names beyond the vocabularies above.
-NEW_SCOPES = ("decay_prepare", "attn_gate", "conv_mixer")
+NEW_SCOPES = ("decay_prepare", "attn_gate", "conv_mixer", "window_attention", "window_flash")
 NEW = re.compile(r"(?:^|[/(])(" + "|".join(NEW_SCOPES) + r")(?:[/)]|$)")
 LINEAR = re.compile(r"(?:^|[/(])(" + "|".join(T.LINEAR_SCOPES) + r")(?=[/)]|$)")
 LING_SHAPED = dict(
@@ -98,9 +98,22 @@ LFM2_SHAPED = dict(
 )
 
 
+# Window layers three to one with a global layer that carries no rotary
+# embedding, a head size stated apart from the stream's width, ReLU experts
+# routed from the layer's input, half of them held; what it names is
+# ``window_attention`` and, inside it, ``window_flash``.
+WINDOW_SHAPED = dict(
+    dim=48, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, hidden_dim=96,
+    layer_pattern=("full", "window", "window", "window"), window=16, rope_kinds=("window",),
+    moe=T.MoEConfig(
+        num_experts=8, top_k=2, norm_topk_prob=True, expert_dim=32, held=(0, 4),
+        activation="relu", router_input="layer_input"),
+)
+
+
 @functools.lru_cache(maxsize=None)
 def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False,
-                 ling=False, lfm2=False):
+                 ling=False, lfm2=False, window=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
@@ -111,9 +124,10 @@ def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True
     kernel's residuals; ``latent`` the Moonlight-shaped tiny configuration
     (latent attention, a dense first layer, sigmoid-routed experts with
     shared experts); ``ling`` the patterned one over held experts; ``lfm2``
-    the one with conv mixers under a tied head."""
+    the one with conv mixers under a tied head; ``window`` the one with
+    window layers."""
     shaped = (
-        LFM2_SHAPED if lfm2 else LING_SHAPED if ling else MOONLIGHT_SHAPED if latent
+        WINDOW_SHAPED if window else LFM2_SHAPED if lfm2 else LING_SHAPED if ling else MOONLIGHT_SHAPED if latent
         else OLMOE_SHAPED if moe else {}
     )
     config = T.TransformerConfig.tiny(remat=remat, **shaped)
@@ -393,6 +407,35 @@ def test_a_conv_layer_names_its_mixer(remat):
 
 
 @pytest.mark.parametrize("remat", POLICIES)
+def test_a_window_layer_names_its_attention_and_its_kernels(remat):
+    """``window_attention`` lies inside ``attention`` and holds a window
+    layer's four projections forward and backward; the kernel calls inside it
+    are under ``window_flash`` too, forward and backward, and nothing else is;
+    the global layer of the same period carries neither; the router reads the
+    layer's input under ``mlp`` / ``router`` all the same."""
+    named = instructions(remat, window=True)
+    matmuls = [n for op, n in named if op in ("dot", "convolution") and n]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    mine = [n for _op, n in named if NEW.search(n)]
+    assert mine and {NEW.search(n).group(1) for n in mine} == {"window_attention"}
+    assert all(BLOCKS.search(n).group(1) == "attention" for n in mine)
+    assert [n for n in mine if "transpose(" not in n] and [n for n in mine if "transpose(" in n]
+    kernels = [n for n in mine if "window_flash" in n]
+    assert kernels and all("/window_attention/window_flash/" in n for n in kernels)
+    assert [n for n in kernels if "_flash_forward" in n]
+    assert [n for n in kernels if "_flash_backward" in n]
+    # the global layer's kernels are the same jitted functions, outside the scope
+    assert [n for _op, n in named if "_flash_forward" in n and not NEW.search(n)]
+    projections = [n for n in matmuls if NEW.search(n) and "window_flash" not in n]
+    assert len(projections) >= 4 * 2           # q k v o, forward and backward
+    assert _bounded_matmuls_scopes(matmuls) == {"router", "experts"}
+    scoped = instructions(None, window=True)
+    plain = instructions(None, scoped=False, window=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+    assert not any(NEW.search(n) for _op, n in plain)
+
+
+@pytest.mark.parametrize("remat", POLICIES)
 def test_the_worst_case_in_its_loop_names_its_work(remat):
     """Under ``held`` (4 of 64 here: row buffers of half the pairs) the rows
     behind the bound go through a loop of as many trips as the routing asks
@@ -439,8 +482,10 @@ def test_vocabulary():
     assert T.LATENT_SCOPES == ("latent", "shared")
     assert not set(T.LATENT_SCOPES) & (set(T.SCOPES) | set(T.MOE_SCOPES))
     # benchmarks/harness/linear_scopes.py repeats these; the two names PR 36
-    # added and PR 39's ``conv_mixer`` (read by name: harness/named_scope.py)
-    # are in none of the four
+    # added, PR 39's ``conv_mixer`` and PR 45's ``window_attention`` and
+    # ``window_flash`` (read by name: harness/named_scope.py) are in none of
+    # the four
+    assert NEW_SCOPES[-2:] == ("window_attention", "window_flash")
     assert T.LINEAR_SCOPES == ("linear_attention", "short_conv", "delta_rule", "gate_norm")
     assert not set(NEW_SCOPES) & (
         set(T.SCOPES) | set(T.MOE_SCOPES) | set(T.LATENT_SCOPES) | set(T.LINEAR_SCOPES)

@@ -1,6 +1,8 @@
 """Pallas kernel numerics vs pure-jax references (CPU interpret mode — the
 same kernel code the TPU compiles, SURVEY §4.4 'CPU twin' trick)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -233,6 +235,169 @@ def test_causal_tile_counts_of_the_benchmark_cells(seq, counts):
     blocks = _block_sizes(seq, seq, None, None, 128, jnp.bfloat16)
     assert blocks == (1024, 1024)
     assert causal_tile_counts(seq, seq, *blocks) == counts
+
+
+# -- a sliding window: the band's tiles, its edge, its gradients ---------------
+def _window_mask(seq_q, seq_k, window):
+    import numpy as np
+
+    q_pos = (seq_k - seq_q) + np.arange(seq_q)[:, None]
+    k_pos = np.arange(seq_k)[None, :]
+    return (k_pos <= q_pos) & (k_pos > q_pos - window)
+
+
+# (seq_q, seq_k, block_q, block_k, window): the window under, at and over a
+# block, one key, seq_q < seq_k, rectangular blocks, a window over the whole
+# sequence, kv rows no query sees (seq_q << seq_k).
+_WINDOW_TILE_CASES = [
+    (256, 256, 64, 64, 8),
+    (256, 256, 64, 64, 63),
+    (256, 256, 64, 64, 64),
+    (256, 256, 64, 64, 65),
+    (256, 256, 64, 64, 1),
+    (256, 256, 64, 64, 150),
+    (256, 256, 64, 64, 256),
+    (256, 256, 64, 64, 1000),
+    (128, 256, 32, 64, 40),
+    (64, 256, 64, 32, 16),
+    (256, 256, 128, 32, 100),
+    (256, 256, 32, 128, 100),
+    (32, 512, 32, 64, 8),
+]
+
+
+@pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,window", _WINDOW_TILE_CASES)
+def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, window):
+    """``_tile_needed``, ``band_steps``, both index maps and
+    ``causal_tile_counts`` under a window against the mask itself. The grid's
+    third index is a STEP along a row's band: the steps of a row reach every
+    needed tile of it, each fetching its own blocks, and a step outside the
+    band names a needed block of the row (its last): no fetch."""
+    import numpy as np
+
+    from ray_tpu.ops.flash_attention import (
+        _first_kv_block, _first_q_block, _kv_index_map, _q_index_map, _tile_needed, band_steps,
+        causal_tile_counts,
+    )
+
+    nq, nk = seq_q // block_q, seq_k // block_k
+    offset = seq_k - seq_q
+    mask = _window_mask(seq_q, seq_k, window)
+    needed = np.array([
+        [mask[j * block_q:(j + 1) * block_q,
+              kv * block_k:(kv + 1) * block_k].any() for kv in range(nk)]
+        for j in range(nq)
+    ])
+    for j in range(nq):
+        for kv in range(nk):
+            assert bool(_tile_needed(
+                True, offset, j, kv, block_q, block_k, window)) == needed[j, kv], (j, kv)
+    steps = band_steps(seq_q, seq_k, block_q, block_k, window)
+    kv_map = _kv_index_map(True, offset, block_q, block_k, nk, window)
+    q_map = _q_index_map(True, offset, block_q, block_k, nq, window)
+    for j in range(nq):                      # the fwd and dq kernels: a q row's steps
+        row = np.flatnonzero(needed[j])
+        first = int(_first_kv_block(offset, j, block_q, block_k, nk, window))
+        assert first == row[0] and row[-1] - row[0] + 1 <= steps["kv"]
+        for step in range(steps["kv"]):
+            fetched = int(kv_map(0, j, step)[1])
+            assert fetched == min(first + step, row[-1]), (j, step)
+            if first + step < nk:            # what the kernel computes is what it fetched
+                assert (fetched == first + step) or not needed[j, first + step]
+    for kv in range(nk):                     # the dkv kernel: a kv row's steps
+        col = np.flatnonzero(needed[:, kv])
+        first = int(_first_q_block(offset, kv, block_q, block_k, nq))
+        fetched = [int(q_map(0, kv, step)[1]) for step in range(steps["q"])]
+        if not col.size:                     # a kv row no query sees: one q block, no fetch
+            assert len(set(fetched)) == 1
+            continue
+        assert first == col[0] and col[-1] - col[0] + 1 <= steps["q"]
+        assert fetched == [min(first + step, col[-1]) for step in range(steps["q"])]
+    assert max(len(np.flatnonzero(r)) for r in needed) <= steps["kv"] <= nk
+    assert max(len(np.flatnonzero(c)) for c in needed.T) <= steps["q"] <= nq
+    assert causal_tile_counts(seq_q, seq_k, block_q, block_k, window) == {
+        "skipped": int((~needed).sum()), "executed": int(needed.sum())}
+
+
+def test_window_tile_counts_of_the_benchmark_cell():
+    """[16384, 16384] in 1024-blocks under a window of 4096: rows of 1, 2,
+    3, 4, then twelve of 5 tiles; by pairs 43.75 % of the causal half."""
+    from ray_tpu.ops.flash_attention import _block_sizes, causal_tile_counts
+
+    from ray_tpu.ops.flash_attention import band_steps
+
+    blocks = _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16)
+    assert causal_tile_counts(16384, 16384, *blocks, 4096) == {"skipped": 186, "executed": 70}
+    # the grid walks 16 x 5 steps a head, 10 of them skipped, not 16 x 16
+    assert band_steps(16384, 16384, *blocks, 4096) == {"kv": 5, "q": 5}
+    mask = _window_mask(16384, 16384, 4096)
+    assert int(mask.sum()) == 58_722_304
+    assert int(mask.sum()) / (16384 * 16385 // 2) == pytest.approx(0.4375, abs=2e-4)
+
+
+# One block is 32 keys here: windows of 1, 8, a block, a block +- 1, and the
+# whole sequence or more, at seq_q == seq_k and at seq_q < seq_k.
+@pytest.mark.parametrize("seq_q", [128, 64])
+@pytest.mark.parametrize("window", [1, 8, 31, 32, 33, 128, 500])
+def test_flash_attention_window_matches_reference(seq_q, window):
+    seq_k, dim, block = 128, 16, 32
+    key = jax.random.PRNGKey(23)
+    q = jax.random.normal(key, (1, 2, seq_q, dim), jnp.float32)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, seq_k, dim), jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, seq_k, dim), jnp.float32)
+
+    def flash(q, k, v, window=window):
+        return flash_attention(
+            q, k, v, block_q=block, block_k=block, window=window,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+
+    def ref(q, k, v):
+        return attention_reference(q, k, v, window=window)
+
+    assert float(jnp.max(jnp.abs(flash(q, k, v) - ref(q, k, v)))) < 2e-5
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) ** 2)
+    grads = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    refs = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for g, r, name in zip(grads, refs, ("dq", "dk", "dv")):
+        assert float(jnp.max(jnp.abs(g - r))) < 2e-4, name
+    if window >= seq_k:
+        # a window over every key is no window: bit for bit
+        whole = functools.partial(flash, window=None)
+        assert jnp.array_equal(flash(q, k, v), whole(q, k, v))
+        for g, w in zip(grads, jax.grad(loss(whole), argnums=(0, 1, 2))(q, k, v)):
+            assert jnp.array_equal(g, w)
+
+
+@pytest.mark.parametrize("window", [1, 5, 32, 33])
+@pytest.mark.parametrize("kernel", [True, False])
+def test_window_edge_is_exact(window, kernel):
+    """With one-hot values (key j's value is e_j) and equal scores, query
+    i's output is 1 / count on the keys it sees: it holds key ``i - window +
+    1`` and not key ``i - window``, in the kernel and in the oracle."""
+    seq, block = 128, 32
+    q = jnp.zeros((1, 1, seq, 8), jnp.float32)
+    v = jnp.eye(seq, dtype=jnp.float32)[None, None]
+    if kernel:
+        out = flash_attention(
+            q, q, v, block_q=block, block_k=block, window=window,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+    else:
+        out = attention_reference(q, q, v, window=window)
+    seen = out[0, 0] > 0
+    assert jnp.array_equal(seen, _window_mask(seq, seq, window))
+    i = 100
+    assert seen[i, i - window + 1] and not seen[i, i - window] and not seen[i, i + 1]
+
+
+def test_window_needs_causal():
+    q = jnp.zeros((1, 1, 32, 8), jnp.float32)
+    for fn in (flash_attention, attention_reference):
+        with pytest.raises(ValueError, match="window"):
+            fn(q, q, q, causal=False, window=8)
+        with pytest.raises(ValueError, match="window"):
+            fn(q, q, q, window=0)
 
 
 # Shapes where one call holds skipped tiles, tiles the mask leaves whole and
