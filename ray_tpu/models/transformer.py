@@ -1141,58 +1141,127 @@ def _dense_mlp(h, w_gate, w_up, w_down, gate_mul=_silu_mul):
     return gate_mul(gate, up) @ w_down
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _rows_by_expert(top_k, x, order, inverse):
+def _sum_of_choices(rows, written, weights=None):
+    """A token's ``top_k`` rows summed in float32, ``[tokens, d]``: ``rows``
+    ``[top_k, tokens, d]`` in the model dtype, pair ``choice x tokens +
+    token`` (``_moe_mlp``'s numbering), so a choice is one contiguous slab
+    of the LEADING axis; each slab times its ``weights[choice]``
+    (``[top_k, tokens]``) where weights are given; under ``written``
+    (``[top_k, tokens, 1]``: the pairs whose row some tile wrote) every other
+    row is selected out, 0 x NaN being NaN. Written as a chain over the
+    slabs, choice 0 first: one fusion that reads every row once and keeps one
+    float32 accumulator. As ``jnp.sum`` over the axis XLA materialised the
+    float32 operands, and with ``top_k`` second-minor (``[tokens, top_k, d]``)
+    the reshape was a physical copy, padded from 6 to 8 (PERF.md section 6,
+    PR 46)."""
+    total = None
+    for choice in range(rows.shape[0]):
+        row = rows[choice] if written is None else jnp.where(written[choice], rows[choice], 0)
+        term = row.astype(jnp.float32)
+        if weights is not None:
+            term = term * weights[choice].astype(jnp.float32)[:, None]
+        total = term if total is None else total + term
+    return total
+
+
+def _by_choice(y, inverse):
+    """``y`` [tokens * top_k, d] in expert order -> ``[top_k, tokens, d]``:
+    pair ``p``'s row is ``y[inverse[p]]``, and the reshape of the leading
+    axis is free."""
+    return y[inverse.reshape(-1)].reshape(*inverse.shape, y.shape[-1])
+
+
+def _written(inverse, held_pairs):
+    """``[top_k, tokens, 1]``: the pairs whose row, ``inverse[p]``, lies in
+    some held expert's group, so that a tile wrote it; None where every
+    expert is here (``held_pairs`` None) and every row is written."""
+    return None if held_pairs is None else (inverse < held_pairs)[:, :, None]
+
+
+@jax.custom_vjp
+def _rows_by_expert(x, order, inverse, held_pairs):
     """``x`` [tokens, d] -> [tokens * top_k, d]: row ``i`` is the token of
-    the ``i``-th (token, choice) pair in expert order. ``order`` is a
-    permutation (``inverse`` its inverse), so the transpose of this gather
-    is a gather too, and a sum over each token's ``top_k`` rows. Autodiff's
-    own transpose of ``x[order // top_k]`` and ``y[inverse]`` is a
+    the ``i``-th pair in expert order (pair ``p``'s token is ``p % tokens``).
+    ``order`` is a permutation (``inverse`` ``[top_k, tokens]`` its inverse),
+    so the transpose of this gather is a gather too, and a sum over each
+    token's ``top_k`` rows (``_sum_of_choices``; under ``held`` the first of
+    the two selects lives there: the cotangent rows behind the last held
+    group, which no tile wrote, are selected out of the sum).
+    Autodiff's own transpose of ``x[token]`` and ``y[inverse]`` is a
     scatter-add of ``tokens * top_k`` rows: 24.0 ms a step on a v5e at
     OLMoE's 65,536 x 2048 rows and 2 layers, against 8.8 for these gathers
     (PERF.md section 6, PR 26)."""
-    return x[jax.lax.div(order, jnp.int32(top_k))]
+    return x[jax.lax.rem(order, jnp.int32(x.shape[0]))]
 
 
-def _rows_by_expert_fwd(top_k, x, order, inverse):
-    return x[jax.lax.div(order, jnp.int32(top_k))], inverse
+def _rows_by_expert_fwd(x, order, inverse, held_pairs):
+    return _rows_by_expert(x, order, inverse, held_pairs), (inverse, held_pairs)
 
 
-def _rows_by_expert_bwd(top_k, inverse, g):
-    per_token = g[inverse].reshape(-1, top_k, g.shape[-1])
-    return per_token.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+def _rows_by_expert_bwd(residuals, g):
+    inverse, held_pairs = residuals
+    dx = _sum_of_choices(_by_choice(g, inverse), _written(inverse, held_pairs))
+    return dx.astype(g.dtype), None, None, None
 
 
 _rows_by_expert.defvjp(_rows_by_expert_fwd, _rows_by_expert_bwd)
 
 
+def _weighted_sum(per_choice, weights):
+    """``sum_j weights[j, t] * rows[j, t]`` in the model dtype, ``[tokens,
+    d]``: ``per_choice`` is ``(rows [top_k, tokens, d], written)``, ``weights``
+    ``[top_k, tokens]``; float32 products and sums in one pass over the rows
+    (``_sum_of_choices``; under ``held`` the second of the two selects lives
+    here)."""
+    rows, written = per_choice
+    return _sum_of_choices(rows, written, weights).astype(rows.dtype)
+
+
 @jax.custom_vjp
-def _rows_by_token(y, order, inverse):
-    """``y`` [tokens * top_k, d] in expert order -> the same rows in
-    (token, choice) order; the transpose permutes back."""
-    return y[inverse]
+def _rows_by_token(y, weights, by_expert, order, inverse, held_pairs):
+    """``y`` [tokens * top_k, d] in expert order -> ``[tokens, d]``: the
+    rows gathered back by choice (``_by_choice``) and ``_weighted_sum`` over a
+    token's choices. ``weights`` is ``[top_k, tokens]``; ``by_expert`` the
+    same numbers in expert order, ``[tokens * top_k]``, for the transpose
+    alone, which stays in expert order: row ``i``'s cotangent is its token's
+    row of the ``[tokens, d]`` cotangent (a gather from the SMALL array)
+    times the pair's weight, and the weight's cotangent is that row's product
+    with ``y[i]`` summed over ``d``, float32 both, put back in pair order by a
+    sort of ``[tokens * top_k]`` scalars; a row no tile wrote (``i`` at or
+    behind ``held_pairs``) is selected out of that product and gives its
+    weight a zero cotangent, and its own cotangent no kernel reads. So
+    backward keeps ``y`` as the kernels wrote it, in the model dtype (never a
+    4-byte copy of every pair's row: 0.5 GiB a layer at OLMoE's 65,536 x
+    2048, where the step already needs 86.5 % of a v5e), the transpose makes
+    no array ``[top_k, tokens, d]``, and a layer checkpoint's recomputation
+    stops at ``y``: the gather back and the sum are not run again (PERF.md
+    section 6, PR 46)."""
+    out = _weighted_sum((_by_choice(y, inverse), _written(inverse, held_pairs)), weights)
+    # The barrier keeps the sum one fusion whatever follows: with a batch of 2 XLA moved the
+    # caller's reshape to [batch, seq, d] above the chain and wrote every slab out in float32
+    # (OLMoE's 2 x 4096 tokens: 2.3 ms a layer, my chip run, PR 46).
+    return jax.lax.optimization_barrier(out)
 
 
-def _rows_by_token_fwd(y, order, inverse):
-    return y[inverse], order
+def _rows_by_token_fwd(y, weights, by_expert, order, inverse, held_pairs):
+    out = _rows_by_token(y, weights, by_expert, order, inverse, held_pairs)
+    return out, (y, by_expert, order, held_pairs)
 
 
-def _rows_by_token_bwd(order, g):
-    return g[order], None, None
+def _rows_by_token_bwd(residuals, g):
+    y, by_expert, order, held_pairs = residuals
+    tokens, dtype = g.shape[0], y.dtype
+    g = g[jax.lax.rem(order, jnp.int32(tokens))].astype(jnp.float32)         # [T*K, d]
+    y = y.astype(jnp.float32)
+    if held_pairs is not None:
+        y = jnp.where((jnp.arange(order.shape[0], dtype=jnp.int32) < held_pairs)[:, None], y, 0)
+    dy = g * by_expert.astype(jnp.float32)[:, None]
+    dweight = jnp.sum(y * g, axis=-1).astype(by_expert.dtype)                # in expert order
+    _, dweights = jax.lax.sort((order, dweight), num_keys=1)                 # ... in pair order
+    return dy.astype(dtype), dweights.reshape(-1, tokens), None, None, None, None
 
 
 _rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
-
-
-@functools.partial(jax.checkpoint, prevent_cse=False)
-def _weighted_sum(per_token, weights):
-    """``sum_j weights[t, j] * per_token[t, j]``: float32 math, and (as
-    ``_silu_mul``) backward re-derives the float32 products from the model
-    dtype's ``per_token`` instead of keeping a 4-byte copy of every
-    (token, choice) row alive per layer (0.5 GiB a layer at OLMoE's
-    65,536 x 2048, where the step already needs 89.8 % of a v5e)."""
-    weighted = per_token.astype(jnp.float32) * weights.astype(jnp.float32)[:, :, None]
-    return jnp.sum(weighted, axis=1).astype(per_token.dtype)
 
 
 def _within_best_groups(biased, moe: MoEConfig):
@@ -1312,26 +1381,33 @@ def _expert_mlps(gate_mul, rows, experts, group_sizes, stacks):
         return expert(gate_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
 
 
-def _by_every_pair(top_k, gate_mul, ht, weights, experts, sorting, stacks):
+def _by_every_pair(gate_mul, ht, weights, experts, sorting, stacks):
     """The experts' weighted sum per token through buffers of the worst
-    case's ``tokens * top_k`` rows: the gather by pair in expert order, the
-    grouped matmuls, the gather back to (token, choice) order and
-    ``_weighted_sum``. Under ``held`` (``sorting`` has ``covered``
-    ``[T*K, 1]``, the rows some held expert's group covers) two selects
-    zero the rows behind the held groups, which no tile wrote, and their
-    cotangents."""
-    tokens, d = ht.shape
-    covered = sorting.get("covered")
+    case's ``tokens * top_k`` rows: the gather by pair in expert order
+    (``_rows_by_expert``), the grouped matmuls, the gather back to ``[top_k,
+    tokens, d]`` and ``_weighted_sum`` (``_rows_by_token``). The pairs are
+    numbered choice-major (``_moe_mlp``), so a token's ``top_k`` rows lie
+    ``tokens`` apart on a LEADING axis and each sum over them, the weighted
+    one forward and the first gather's transpose, is one pass over the rows
+    (``_sum_of_choices``): no array ``[tokens, top_k, d]`` exists, forward or
+    backward, in any dtype. Under ``held`` (``sorting`` has ``held_pairs``)
+    the rows behind the last held group are written by no tile, the experts'
+    output forward and the input cotangent backward; two selects keep them
+    out, each inside the sum that reads the rows anyway: ``_written`` (pair
+    ``p``'s row, ``inverse[p]``, lies in a held group) in ``_weighted_sum``
+    and in ``_rows_by_expert``'s transpose (and the weights' cotangents read
+    the experts' output through the same select, in expert order). The
+    gathered INPUT rows behind the groups are real tokens' rows, finite, and
+    no tile reads them or the output cotangent's rows there."""
+    order, inverse, held_pairs = sorting["order"], sorting["inverse"], sorting.get("held_pairs")
     with jax.named_scope("dispatch"):
-        rows = _rows_by_expert(top_k, ht, sorting["order"], sorting["inverse"])    # [T*K, d]
-        if covered is not None:
-            rows = jnp.where(covered, rows, 0)
+        rows = _rows_by_expert(ht, order, inverse, held_pairs)               # [T*K, d]
     out = _expert_mlps(gate_mul, rows, experts, sorting["group_sizes"], stacks)
     with jax.named_scope("dispatch"):
-        if covered is not None:
-            out = jnp.where(covered, out, 0)
-        per_token = _rows_by_token(out, sorting["order"], sorting["inverse"])
-        return _weighted_sum(per_token.reshape(tokens, top_k, d), weights.astype(ht.dtype))
+        return _rows_by_token(
+            out, weights.astype(ht.dtype).T, sorting["weight"].astype(ht.dtype),
+            order, inverse, held_pairs,
+        )
 
 
 # Pairs a row of ``_first_of_the_order``'s sort holds.
@@ -1376,7 +1452,7 @@ def _past(bound, sorting):
     return sorting["held_pairs"] > bound
 
 
-def _by_held_pair(top_k, bound, gate_mul, ht, weights, experts, sorting, stacks):
+def _by_held_pair(bound, gate_mul, ht, weights, experts, sorting, stacks):
     """The same sum, in float32, through buffers of ``bound`` rows, for a
     routing whose held pairs fit them: the sorted order's first ``bound``
     pairs are the held ones and then absent ones; their tokens' rows are
@@ -1388,7 +1464,7 @@ def _by_held_pair(top_k, bound, gate_mul, ht, weights, experts, sorting, stacks)
     sake): ``_held_experts`` then takes the worst case's path."""
     with jax.named_scope("dispatch"):
         pair = sorting["order"]                                  # [bound]: the held pairs first
-        token = jax.lax.div(pair, jnp.int32(top_k))
+        token = jax.lax.rem(pair, jnp.int32(ht.shape[0]))        # pair = choice x tokens + token
         covered = jnp.arange(bound, dtype=jnp.int32) < sorting["held_pairs"]
         ends = jnp.cumsum(sorting["group_sizes"])
         starts = ends - sorting["group_sizes"]
@@ -1397,7 +1473,7 @@ def _by_held_pair(top_k, bound, gate_mul, ht, weights, experts, sorting, stacks)
         rows = _rows_of_held(ht, token, by_token, covered)        # [bound, d]
     out = _expert_mlps(gate_mul, rows, experts, group_sizes, stacks)
     with jax.named_scope("dispatch"):
-        weight = weights.astype(ht.dtype).reshape(-1)[pair]
+        weight = weights.astype(ht.dtype).T.reshape(-1)[pair]
         weight = jnp.where(_past(bound, sorting), 0, weight)
         return _sum_by_token(ht.shape[0], out, weight, token, by_token, covered)
 
@@ -1436,8 +1512,8 @@ def _one_experts_weights(stacks, expert):
         return jnp.stack([gate, up]), down
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _held_experts(top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
     """The held experts' weighted sum per token, ``[tokens, d]``: through
     buffers of ``bound`` rows (``_by_held_pair``) for a routing whose held
     pairs fit them, else through the worst case's own path, a loop over the
@@ -1476,9 +1552,7 @@ def _held_experts(top_k, bound, first_expert, gate_mul, ht, weights, experts, so
     count, and its rule for ``cond`` hands the union of both branches'
     residuals out of the forward."""
     del experts
-    out = _by_held_pair(
-        top_k, bound, gate_mul, ht, weights, _experts_like(stacks), sorting, stacks
-    )
+    out = _by_held_pair(bound, gate_mul, ht, weights, _experts_like(stacks), sorting, stacks)
     held = sorting["group_sizes"].shape[0]
 
     def one_expert(expert, out):
@@ -1493,18 +1567,16 @@ def _held_experts(top_k, bound, first_expert, gate_mul, ht, weights, experts, so
     return out.astype(ht.dtype)
 
 
-def _held_experts_fwd(top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
-    out = _held_experts(
-        top_k, bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks
-    )
+def _held_experts_fwd(bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks):
+    out = _held_experts(bound, first_expert, gate_mul, ht, weights, experts, sorting, stacks)
     return out, (ht, weights, sorting, stacks)
 
 
-def _held_experts_bwd(top_k, bound, first_expert, gate_mul, operands, g):
+def _held_experts_bwd(bound, first_expert, gate_mul, operands, g):
     ht, weights, sorting, stacks = operands
     g = g.astype(jnp.float32)                 # the paths' sums are float32
     _, pull = jax.vjp(
-        lambda *over: _by_held_pair(top_k, bound, gate_mul, *over, sorting, stacks),
+        lambda *over: _by_held_pair(bound, gate_mul, *over, sorting, stacks),
         ht, weights, _experts_like(stacks),
     )
     held = sorting["group_sizes"].shape[0]
@@ -1541,11 +1613,17 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
     (``MoEConfig.router_input="layer_input"``: the layer's input), else
     ``h``, the experts' normed input.
 
-    The ``tokens x top_k`` (token, choice) pairs are sorted by expert
-    (stable), the tokens' rows gathered in that order, and gate / up / down
-    run as grouped matmuls over the ragged groups (``ops/grouped_matmul.py``:
-    each row against its own expert only); the results go back to token
-    order, are weighted by the router's probabilities and summed per token.
+    The ``tokens x top_k`` (token, choice) pairs are numbered CHOICE-MAJOR,
+    pair ``p = choice x tokens + token`` (a pair's token is ``p % tokens``):
+    one numbering for both paths below. They are sorted by expert (stable),
+    the tokens' rows gathered in that order, and gate / up / down run as
+    grouped matmuls over the ragged groups (``ops/grouped_matmul.py``: each
+    row against its own expert only); the results go back to ``[top_k,
+    tokens, d]``, a free reshape of the leading axis, are weighted by the
+    router's probabilities and summed per token, ``top_k`` contiguous slabs
+    into one float32 accumulator (``_sum_of_choices``: with the pairs
+    token-major the same sum was a padded float32 copy of every row, six
+    times its bytes at a ``top_k`` of 4 or 6; PERF.md section 6, PR 46).
     Every shape is static (``[tokens * top_k, ...]``; the group sizes are
     data), so one compiled program serves every routing. The router runs
     in float32.
@@ -1564,8 +1642,9 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
     ``num_experts``; the pairs are sorted by the held experts' own numbers
     with every absent pair behind them, the grouped matmuls run over the
     held groups alone (rows behind the last group are touched by no tile)
-    and those rows are selected out of every sum, so an absent pair adds
-    nothing to the output and nothing to a gradient. The row buffers are
+    and those rows are selected out of every sum, inside the sum's own pass
+    (``_by_every_pair`` says where its two selects live), so an absent pair
+    adds nothing to the output and nothing to a gradient. The row buffers are
     sized for what held experts get, ``held_row_bound`` rows (eight times an
     even routing's held pairs: 25 % of ``tokens x top_k`` at 16 held of
     512), and no array on the path a step takes has
@@ -1630,6 +1709,7 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
             "experts": experts, "weights": weights,
         }
     with jax.named_scope("dispatch"):
+        # pair p = choice x tokens + token: a token's pairs lie ``tokens`` apart
         pairs = jnp.arange(tokens * moe.top_k, dtype=jnp.int32)
         group_sizes = jnp.sum(counts, axis=0)
         sort_by = experts
@@ -1641,10 +1721,10 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
             routing["held_pairs"] = jnp.sum(group_sizes)
             routing["overflow"] = _past(bound, routing).astype(jnp.int32)
         if bounded:
-            order = _first_of_the_order(sort_by.reshape(-1), held, bound)
+            order = _first_of_the_order(sort_by.T.reshape(-1), held, bound)
             # the same rows by token, for the sums into tokens (_sum_into_tokens)
             _, by_token = jax.lax.sort(
-                (jax.lax.div(order, jnp.int32(moe.top_k)), pairs[:bound]), num_keys=1
+                (jax.lax.rem(order, jnp.int32(tokens)), pairs[:bound]), num_keys=1
             )
             order, by_token = checkpoint_name((order, by_token), MOE_RESIDUAL_NAMES[1])
             sorting = {
@@ -1652,11 +1732,18 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
                 "group_sizes": group_sizes, "held_pairs": routing["held_pairs"], "chosen": experts,
             }
         else:
-            _, order = jax.lax.sort((sort_by.reshape(-1), pairs), num_keys=1, is_stable=True)
+            # the weights ride through the sort: a gather of ``[pairs]`` scalars is oddly dear
+            _, order, weight = jax.lax.sort(
+                (sort_by.T.reshape(-1), pairs, jax.lax.stop_gradient(weights).T.reshape(-1)),
+                num_keys=1, is_stable=True,
+            )
             _, inverse = jax.lax.sort((order, pairs), num_keys=1)
-            sorting = {"order": order, "inverse": inverse, "group_sizes": group_sizes}
+            sorting = {
+                "order": order, "inverse": inverse.reshape(moe.top_k, tokens),
+                "weight": weight, "group_sizes": group_sizes,
+            }
             if moe.held:
-                sorting["covered"] = (pairs < routing["held_pairs"])[:, None]
+                sorting["held_pairs"] = routing["held_pairs"]
     expert_weights = {name: layer[name] for name in _EXPERT_WEIGHTS}
     stacks = layer.get("stack", {})
     if bounded:
@@ -1665,10 +1752,10 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
             name: (jax.lax.stop_gradient(leaf)[None], 0) for name, leaf in expert_weights.items()
         }
         out = _held_experts(
-            moe.top_k, bound, first, gate_mul, ht, weights, expert_weights, sorting, stacks
+            bound, first, gate_mul, ht, weights, expert_weights, sorting, stacks
         )
     else:
-        out = _by_every_pair(moe.top_k, gate_mul, ht, weights, expert_weights, sorting, stacks)
+        out = _by_every_pair(gate_mul, ht, weights, expert_weights, sorting, stacks)
     return out.reshape(batch, seq, d), routing
 
 

@@ -759,3 +759,84 @@ def test_expert_kernels_read_the_layer_stack_in_place(topo):
     one_layer = rf"= bf16\[{experts},(?:{dim},{width}|{width},{dim})\]\S* ([\w-]+)\("
     assert sorted(re.findall(one_layer, text)) == ["custom-call"] * 3 + ["parameter"] * 3
     assert described.step_memory(compiled)["total_bytes"] / 2**30 <= parent_gib
+
+
+def _fusions_that_only_select(text: str, rows: str) -> list[str]:
+    """Fused computations of ``text`` that produce a ``[rows]`` array and whose
+    only work is a ``select``: a pass over the buffer that does nothing but
+    zero some of it."""
+    idle = {"parameter", "constant", "broadcast", "bitcast", "convert", "compare", "iota",
+            "tuple", "reshape", "select"}
+    found = []
+    for block in re.split(r"\n(?=%?fused_computation[\w.\-]* \()", text):
+        head = re.match(r"%?(fused_computation[\w.\-]*) \(.*?\) -> (.*?) \{\n", block)
+        if head and rows in head.group(2):
+            ops = set(re.findall(r"= \S+\s+([\w-]+)\(", block.split("\n}")[0]))
+            if "select" in ops and ops <= idle:
+                found.append(head.group(1))
+    return found
+
+
+@pytest.mark.parametrize("dim,top_k,held,experts,width,parent_mib", [
+    (2560, 6, 32, 64, 768, 2431),      # smallthinker-seq16k-fixed's expert layer
+    (2048, 4, 16, 32, 1792, 1429),     # lfm2-moe-seq16k-fixed's
+], ids=["top6-of-2560", "top4-of-2048"])
+def test_every_pair_dispatch_has_no_array_with_top_k_second_minor(
+    one_chip, dim, top_k, held, experts, width, parent_mib
+):
+    """Value and gradient of ONE expert layer (``_moe_mlp``) at the two dear
+    cells' shapes, 16,384 tokens, half of the experts held, so the block is
+    ``_by_every_pair``: the pairs are numbered choice-major, so the compiled
+    program has no array ``[tokens, top_k, d]`` in any dtype (with ``top_k``
+    second-minor the TPU's (8, 128) tile pads 6 to 8 or is swapped for a
+    (4, 128) one: the parent's ``reshape f32[16384,6,2560]`` and its
+    ``broadcast`` were physical copies, 1.34 GB each), a token's rows by
+    choice are a BITCAST of the gathered ``[tokens x top_k, d]`` buffer, the
+    entry computation holds no float32 array of the buffer's size (the
+    backward stays in expert order: the cotangent's rows are gathered from
+    the ``[tokens, d]`` array), and no fusion's only work is a ``select`` over
+    the buffer (the held selects ride in the sums). Nine Mosaic calls as the
+    parent. Temporaries against the parent's (``parent_mib``: the same
+    function at commit 8d93ff2, compiled the same way; both printed): a sixth
+    less at ``top_k`` 6, where the padded copies were; at ``top_k`` 4 this
+    function ALONE reads 1 % over (1,446 against 1,429 MiB: the backward keeps
+    the experts' output beside the gathered cotangent for one fusion), while
+    the cell's whole step needs 8.56 GiB where the parent's needs 9.23
+    (PERF.md section 6, PR 46)."""
+    import ray_tpu.ops.grouped_matmul as gm
+    from ray_tpu.models import transformer as T
+
+    tokens, pairs = 16384, 16384 * top_k
+    config = T.TransformerConfig(
+        vocab_size=512, dim=dim, n_layers=1, n_heads=2, n_kv_heads=2, hidden_dim=width,
+        max_seq=tokens,
+        moe=T.MoEConfig(
+            num_experts=experts, top_k=top_k, norm_topk_prob=True, expert_dim=width,
+            scoring="sigmoid", held=(0, held)),
+    )
+    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    layer = {
+        "router": shaped(dim, experts), "router_bias": shaped(experts, dtype=jnp.float32),
+        "w_gate": shaped(held, dim, width), "w_up": shaped(held, dim, width),
+        "w_down": shaped(held, width, dim),
+    }
+    h = shaped(1, tokens, dim)
+
+    def probed(h, layer, probe):
+        out, _ = T._moe_mlp(h, layer, config)
+        return jnp.sum(out.astype(jnp.float32) * probe.astype(jnp.float32))
+
+    with mock.patch.object(gm, "resolve_interpret", lambda _i: False):
+        compiled = jax.jit(jax.value_and_grad(probed, argnums=(0, 1))).lower(h, layer, h).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 9
+    assert not re.findall(rf"\w+\[{tokens},{top_k},{dim}\]", text)
+    by_choice = rf"\[{top_k},{tokens},{dim}\]"
+    entry = text[text.index("ENTRY"):]
+    # forward and in the first gather's transpose: the gathered rows seen by choice, for free
+    assert len(re.findall(rf"= bf16{by_choice}\S* bitcast\(", entry)) == 2
+    assert not re.findall(rf"= f32(?:{by_choice}|\[{pairs},{dim}\])", entry)
+    assert _fusions_that_only_select(text, f"[{pairs},{dim}]") == []
+    temporaries = compiled.memory_analysis().temp_size_in_bytes / 2**20
+    print(f"temporaries {temporaries:.0f} MiB, the parent's {parent_mib} MiB")
+    assert temporaries <= 1.02 * parent_mib

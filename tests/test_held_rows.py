@@ -6,17 +6,21 @@ case's own path, a loop over the held experts, each dense over every token
 (``_by_held_expert``), whose trip count is the device's own verdict: no trip
 for a routing that fits, and ``routing["overflow"]`` says that it ran.
 
-Held to ``_parent_moe_mlp``, the function as it stood before the bound (one
-path, every buffer the worst case's), written out below: output, routing and
+Held to ``_parent_moe_mlp``, the block's formula written out below in plain
+``jax.numpy`` (every pair's own expert by index and ``einsum``, no sort, no
+gather by pair, none of the program's dispatch helpers): output, routing and
 every gradient leaf, over routings on both sides of the bound, the loop
 forced as well as chosen; per data shard under a mesh; and by the shapes in
 the jaxpr. On the CPU in float32, 80 tokens, 32 experts of which 3 are held,
 2 a token: 160 pairs, 15 of them an even routing's share, a bound of 120 rows.
+
+Where the bound is the worst case's ``tokens * top_k`` rows (no expert absent,
+or an eighth of them held and more) the block is ``_by_every_pair``: held to
+the same oracle over ``top_k`` 2 to 8, by its jaxpr (no ``cond``, no loop, no
+array ``[tokens, top_k, d]``), and with the rows no tile writes poisoned.
 """
 
-import collections
 import dataclasses
-import re
 from unittest import mock
 
 import jax
@@ -43,69 +47,49 @@ ROUTINGS = {0: 0, 50: 0, BOUND: 0, BOUND + 1: 1, PAIRS: 1}
 
 
 def _parent_moe_mlp(h, layer, config):
-    """``_moe_mlp`` before the bound (commit e526782), whole: every row
-    buffer ``[tokens * top_k, .]``, two selects under ``held``."""
+    """What ``_moe_mlp`` computes, the plain way: the router as the program
+    has it, then every (token, choice) pair through ITS expert's gated MLP,
+    the weights found by index, an absent pair's output zero, and the
+    weighted sum over a token's choices. Autodiff does the rest."""
     moe = config.moe
     batch, seq, d = h.shape
     tokens = batch * seq
     ht = h.reshape(tokens, d)
-    with jax.named_scope("router"):
-        logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
-        if moe.scoring == "sigmoid":
-            scores = jax.nn.sigmoid(logits)                      # [T, E]
-            biased = scores + jax.lax.stop_gradient(layer["router_bias"])
-            if moe.n_group > 1:
-                biased = T._within_best_groups(biased, moe)
-            _, experts = jax.lax.top_k(biased, moe.top_k)
-            weights = jnp.take_along_axis(scores, experts, axis=-1)
-        else:
-            scores = jax.nn.softmax(logits, axis=-1)             # [T, E]
-            weights, experts = jax.lax.top_k(scores, moe.top_k)  # [T, K]
-        chosen = experts[:, :, None] == jnp.arange(moe.num_experts, dtype=experts.dtype)
-        if moe.norm_topk_prob:
-            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + moe.renorm_eps)
-        if moe.routed_scaling != 1.0:
-            weights = weights * moe.routed_scaling
-        counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # [K, E]
-        if moe.scoring == "sigmoid":
-            share = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(batch, seq, -1)
-            chose = jnp.sum(chosen.reshape(batch, seq, moe.top_k, -1), axis=(1, 2))
-            prob_sum = jnp.sum(chose * jnp.mean(share, axis=1), axis=0)
-        else:
-            prob_sum = jnp.sum(scores, axis=0)
-        routing = {
-            "prob_sum": prob_sum, "counts": counts,
-            "experts": experts, "weights": weights,
-        }
-    with jax.named_scope("dispatch"):
-        pairs = jnp.arange(tokens * moe.top_k, dtype=jnp.int32)
-        group_sizes = jnp.sum(counts, axis=0)
-        sort_by = experts
-        if moe.held:
-            first, held = moe.held
-            here = (experts >= first) & (experts < first + held)
-            sort_by = jnp.where(here, experts - first, held)    # absent pairs last
-            group_sizes = group_sizes[first:first + held]
-            routing["held_pairs"] = jnp.sum(group_sizes)
-            covered = (pairs < routing["held_pairs"])[:, None]
-        _, order = jax.lax.sort((sort_by.reshape(-1), pairs), num_keys=1, is_stable=True)
-        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
-        rows = T._rows_by_expert(moe.top_k, ht, order, inverse)    # [T*K, d]
-        if moe.held:
-            rows = jnp.where(covered, rows, 0)
-    in_stack = layer.get("stack", {})
-
-    def expert(rows, name):
-        return T.grouped_matmul(rows, layer[name], group_sizes, within=in_stack.get(name))
-
-    with jax.named_scope("experts"):
-        out = expert(T._silu_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
-    with jax.named_scope("dispatch"):
-        if moe.held:
-            out = jnp.where(covered, out, 0)
-        per_token = T._rows_by_token(out, order, inverse)
-        out = T._weighted_sum(per_token.reshape(tokens, moe.top_k, d), weights.astype(h.dtype))
-    return out.reshape(batch, seq, d), routing
+    logits = ht.astype(jnp.float32) @ layer["router"].astype(jnp.float32)
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)                      # [T, E]
+        biased = scores + jax.lax.stop_gradient(layer["router_bias"])
+        if moe.n_group > 1:
+            biased = T._within_best_groups(biased, moe)
+        _, experts = jax.lax.top_k(biased, moe.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)             # [T, E]
+        weights, experts = jax.lax.top_k(scores, moe.top_k)  # [T, K]
+    chosen = experts[:, :, None] == jnp.arange(moe.num_experts, dtype=experts.dtype)
+    if moe.norm_topk_prob:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + moe.renorm_eps)
+    if moe.routed_scaling != 1.0:
+        weights = weights * moe.routed_scaling
+    counts = jnp.sum(chosen, axis=0, dtype=jnp.int32)        # [K, E]
+    if moe.scoring == "sigmoid":
+        share = (scores / jnp.sum(scores, axis=-1, keepdims=True)).reshape(batch, seq, -1)
+        chose = jnp.sum(chosen.reshape(batch, seq, moe.top_k, -1), axis=(1, 2))
+        prob_sum = jnp.sum(chose * jnp.mean(share, axis=1), axis=0)
+    else:
+        prob_sum = jnp.sum(scores, axis=0)
+    routing = {"prob_sum": prob_sum, "counts": counts, "experts": experts, "weights": weights}
+    first, held = moe.held or (0, moe.num_experts)
+    here = (experts >= first) & (experts < first + held)             # [T, K]
+    if moe.held:
+        routing["held_pairs"] = jnp.sum(here, dtype=jnp.int32)
+    mine = jnp.clip(experts - first, 0, held - 1)
+    gate = jnp.einsum("td,tkdf->tkf", ht, layer["w_gate"][mine])
+    up = jnp.einsum("td,tkdf->tkf", ht, layer["w_up"][mine])
+    per_pair = jnp.einsum("tkf,tkfd->tkd", jax.nn.silu(gate) * up, layer["w_down"][mine])
+    per_pair = jnp.where(here[:, :, None], per_pair, 0).astype(jnp.float32)
+    out = jnp.sum(per_pair * weights.astype(h.dtype).astype(jnp.float32)[:, :, None], axis=1)
+    return out.astype(h.dtype).reshape(batch, seq, d), routing
 
 
 def _weights(held=HELD):
@@ -304,8 +288,9 @@ def test_no_array_under_held_has_a_row_for_every_pair():
     an operand or result have ``tokens * top_k``
     (160) rows and more than one column, nor is one ``[tokens, top_k, d]``:
     every array of ``d`` or expert-width columns has the bound's 120 rows or
-    the tokens' 80. The parent's jaxpr is full of them, which is what the
-    search would find."""
+    the tokens' 80. With an eighth of the experts held the bound is the worst
+    case's and the jaxpr is full of such rows, which is what the search would
+    find."""
     layer = routed(50)
     probed = lambda h, layer: jnp.sum(T._moe_mlp(h, layer, MODEL)[0] * PROBE)
     jaxpr = jax.make_jaxpr(jax.value_and_grad(probed, argnums=(0, 1)))(H, layer).jaxpr
@@ -323,18 +308,8 @@ def test_no_array_under_held_has_a_row_for_every_pair():
         if len(getattr(v.aval, "shape", ())) == 2 and v.aval.shape[1] in (DIM, WIDTH)
     }
     assert {BOUND, TOKENS} <= rows and max(rows) == BOUND, rows         # the rest: weights
-    parents = lambda h, layer: jnp.sum(_parent_moe_mlp(h, layer, MODEL)[0] * PROBE)
-    found = _wide_arrays(jax.make_jaxpr(jax.value_and_grad(parents, argnums=(0, 1)))(H, layer).jaxpr)
-    assert {(PAIRS, DIM), (PAIRS, WIDTH), (TOKENS, TOP_K, DIM)} <= set(found)
-
-
-def _normalised(jaxpr) -> str:
-    """The jaxpr's text with its variables renamed in order of appearance."""
-    seen = {}
-
-    def rename(match):
-        return seen.setdefault(match.group(0), f"v{len(seen)}")
-    return re.sub(r"(?<![\w.=])[a-z]{1,3}(?=:[a-z]+\d|\s|,|\)|\]|$)", rename, str(jaxpr))
+    found = _wide_arrays(_traced(T._moe_mlp, _every_pair_model(held=(4, 8)), _weights(held=(4, 8))).jaxpr)
+    assert {(PAIRS, DIM), (PAIRS, WIDTH)} <= set(found)
 
 
 def _traced(moe_mlp, model, layer):
@@ -342,30 +317,111 @@ def _traced(moe_mlp, model, layer):
     return jax.make_jaxpr(jax.value_and_grad(probed, argnums=(0, 1)))(H, layer)
 
 
-def test_with_every_expert_held_the_jaxpr_is_the_parents():
-    """``held=None``: no ``cond`` and no loop, and value-and-gradient trace
-    to the parent's jaxpr, equation for equation."""
-    model = dataclasses.replace(MODEL, moe=dataclasses.replace(MODEL.moe, held=None))
-    layer = _weights(held=None)
-    ours, parents = _traced(T._moe_mlp, model, layer), _traced(_parent_moe_mlp, model, layer)
-    assert not [eqn for eqn in _equations(ours.jaxpr) if eqn.primitive.name in ("cond", "while")]
-    assert _normalised(ours) == _normalised(parents)
-    assert "overflow" not in jax.eval_shape(lambda: T._moe_mlp(H, layer, model)[1])
+def _every_pair_model(held, top_k=TOP_K):
+    """``MODEL`` with ``held`` (None: every expert here) and ``top_k``; an
+    eighth of the 32 experts held, or more, is ``_by_every_pair``'s."""
+    model = dataclasses.replace(MODEL, moe=dataclasses.replace(MODEL.moe, held=held, top_k=top_k))
+    assert held is None or T.held_row_bound(TOKENS, top_k, held[1], EXPERTS) == TOKENS * top_k
+    return model
 
 
-def test_with_an_eighth_of_the_experts_held_the_jaxpr_is_the_parents():
+def _held_to_the_oracle(model, layer):
+    """Output, routing and every gradient leaf of ``_moe_mlp`` under
+    ``model`` against the oracle's."""
+    run = lambda moe_mlp: value_and_grads(lambda h, layer, _config: moe_mlp(h, layer, model), layer)
+    got, want = run(T._moe_mlp), run(_parent_moe_mlp)
+    assert ("overflow" in got[1]) == bool(model.moe.held)      # a counter only where experts are absent
+    got[1].setdefault("overflow", 0)
+    agrees(got, want, overflow=0)
+
+
+def _on_the_every_pair_path(model, layer):
+    """No ``cond`` and no loop; the value-and-gradient jaxpr has the worst
+    case's row buffers and NO variable ``[tokens, top_k, d]``, of any dtype:
+    a token's rows by choice are ``[top_k, tokens, d]``, summed over the
+    leading axis. Returns the jaxpr's primitives by name."""
+    jaxpr = _traced(T._moe_mlp, model, layer).jaxpr
+    names = [eqn.primitive.name for eqn in _equations(jaxpr)]
+    assert not [name for name in names if name in ("cond", "while")]
+    shapes = {
+        getattr(v.aval, "shape", ()) for eqn in _equations(jaxpr) for v in (*eqn.invars, *eqn.outvars)
+    }
+    top_k = model.moe.top_k
+    assert {(TOKENS * top_k, DIM), (top_k, TOKENS, DIM)} <= shapes
+    assert not [s for s in shapes if len(s) == 3 and s[:2] == (TOKENS, top_k) and s[2] in (DIM, WIDTH)]
+    return names
+
+
+def test_with_every_expert_held_the_block_is_the_oracles():
+    """``held=None``: no ``cond`` and no loop, no ``overflow`` counter, no
+    array ``[tokens, top_k, d]``; output, routing and every gradient leaf
+    are the oracle's."""
+    model, layer = _every_pair_model(held=None), _weights(held=None)
+    _on_the_every_pair_path(model, layer)
+    _held_to_the_oracle(model, layer)
+
+
+def test_with_an_eighth_of_the_experts_held_the_block_is_the_oracles():
     """8 of 32 held: the bound is the worst case's rows, so there is no
-    bounded path, no loop and no kept residual: the parent's equations and
-    the counter's two (``held_pairs > bound``, which is 0 here whatever the
-    routing)."""
-    model = dataclasses.replace(MODEL, moe=dataclasses.replace(MODEL.moe, held=(4, 8)))
-    assert T.held_row_bound(TOKENS, TOP_K, 8, EXPERTS) == PAIRS
-    layer = _weights(held=(4, 8))
-    ours, parents = _traced(T._moe_mlp, model, layer), _traced(_parent_moe_mlp, model, layer)
-    names = lambda jaxpr: [eqn.primitive.name for eqn in _equations(jaxpr.jaxpr)]
-    assert [n for n in names(ours) if n in ("cond", "while", "name")] == []
-    more = collections.Counter(names(ours)) - collections.Counter(names(parents))
-    assert not collections.Counter(names(parents)) - collections.Counter(names(ours))
-    assert more == collections.Counter(["gt", "convert_element_type"])
-    out, routing, _ = value_and_grads(lambda h, layer, _config: T._moe_mlp(h, layer, model), layer)
-    assert int(routing["overflow"]) == 0 and np.all(np.isfinite(np.asarray(out)))
+    bounded path, no loop and no kept residual: ``_by_every_pair`` with its
+    two selects, and the counter (``held_pairs > bound``, which is 0 here
+    whatever the routing)."""
+    model, layer = _every_pair_model(held=(4, 8)), _weights(held=(4, 8))
+    assert "name" not in _on_the_every_pair_path(model, layer)
+    _held_to_the_oracle(model, layer)
+
+
+@pytest.mark.parametrize("held", [None, (4, 4), (8, 16)], ids=["all", "an-eighth", "half"])
+@pytest.mark.parametrize("top_k", [2, 4, 6, 8])
+def test_every_pair_is_the_oracles_formula(top_k, held):
+    """``_by_every_pair`` over ``top_k`` 2 to 8 (the chain over a token's
+    choices is ``top_k`` long; 4 and 6 are the two a ``[tokens, top_k, d]``
+    layout paid most for), with every expert here, an eighth and half of them
+    held: output, routing and every gradient leaf."""
+    model, layer = _every_pair_model(held, top_k), _weights(held=held)
+    _held_to_the_oracle(model, layer)
+
+
+def _spoil(rows, held_pairs):
+    """``NaN`` in the rows behind the last held group."""
+    return jnp.where(jnp.arange(rows.shape[0])[:, None] >= held_pairs, jnp.nan, rows)
+
+
+@jax.custom_vjp
+def _spoiled_cotangent(rows, held_pairs):
+    return rows
+
+
+_spoiled_cotangent.defvjp(
+    lambda rows, held_pairs: (rows, held_pairs),
+    lambda held_pairs, g: (_spoil(g, held_pairs), None),
+)
+
+
+def _poisoned(real):
+    """``_expert_mlps`` with ``NaN`` behind the last held group: in its
+    output forward, and backward in the cotangent it hands back for its
+    input rows. No tile writes those rows on the chip, so what they hold is
+    whatever the buffer held."""
+    def expert_mlps(gate_mul, rows, experts, group_sizes, stacks):
+        held_pairs = jnp.sum(group_sizes)
+        out = real(gate_mul, _spoiled_cotangent(rows, held_pairs), experts, group_sizes, stacks)
+        return _spoil(out, held_pairs)
+    return expert_mlps
+
+
+@pytest.mark.parametrize("top_k", [2, 6])
+def test_the_rows_no_tile_writes_may_hold_anything(top_k):
+    """Half of the experts held, so about half of the pairs are absent and
+    their rows lie behind the last held group: with ``NaN`` there in the
+    experts' output and in the grouped matmuls' input cotangent, output and
+    every gradient are finite and equal to the unpoisoned run's. Two selects,
+    one in each sum over a token's rows, are enough."""
+    model, layer = _every_pair_model((8, 16), top_k), _weights(held=(8, 16))
+    run = lambda: value_and_grads(lambda h, layer, _config: T._moe_mlp(h, layer, model), layer)
+    want = run()
+    assert 0 < int(want[1]["held_pairs"]) < TOKENS * top_k
+    with mock.patch.object(T, "_expert_mlps", _poisoned(T._expert_mlps)):
+        got = run()
+    assert np.all(np.isfinite(np.asarray(got[0])))
+    agrees(got, want, overflow=0)
