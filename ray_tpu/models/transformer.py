@@ -174,10 +174,10 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
 # the two gates, W_out, and within it "short_conv", the convolution's two
 # kernels called with no activation), "window_attention", inside "attention"
-# (a "window" layer's whole mixer: q / k / v, RoPE, the repeat of K and V,
-# the kernels, W_o), and within it "window_flash" (the flash kernels called
-# with a window, forward and backward: they are the "full" layers' jitted
-# functions, so the scope is what tells a window layer's calls apart).
+# (a "window" layer's whole mixer: q / k / v, RoPE, the kernels, W_o), and
+# within it "window_flash" (the flash kernels called with a window, forward
+# and backward: they are the "full" layers' jitted functions, so the scope is
+# what tells a window layer's calls apart).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
@@ -794,29 +794,56 @@ def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: t
     ))
 
 
+def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
+    if repeats == 1:
+        return x
+    return jnp.repeat(x, repeats, axis=1)
+
+
+def _head_shards() -> int:
+    """Into how many the mesh in scope cuts an array's "heads" (1: no mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return 1
+    axes = LogicalRules().spec(("heads",), mesh)[0] or ()
+    return math.prod(mesh.shape[axis] for axis in ((axes,) if isinstance(axes, str) else axes))
+
+
 def _flash_over_mesh(q, k, v, causal, window=None):
-    """The flash kernel, on each device's own [batch, heads] block under a
-    mesh: attention needs nothing from another batch row or head."""
+    """The flash kernels, on each device's own [batch, heads] block under a
+    mesh: attention needs nothing from another batch row or head. K and V
+    come with their own heads (``n_kv_heads``: the kernels' index maps pick a
+    query head's group) and are cut over ``tp`` as q is, a shard's KV heads
+    being its query heads' groups (8 over tp 2: 4 a shard beside 48 of 96
+    query heads); where ``tp`` does not divide them they are first repeated
+    by the least factor that makes it, from the shapes."""
     block = ("batch", "heads", None, None)
+    shards = _head_shards()
+    repeats = shards // math.gcd(k.shape[1], shards)
+    k, v = _repeat_kv(k, repeats), _repeat_kv(v, repeats)
     kernel = functools.partial(flash_attention, causal=causal, window=window)
     return _over_mesh(kernel, (block, block, block), block, (), ())(q, k, v)
 
 
 def _attention_impl(config: TransformerConfig, window: int | None = None) -> Callable:
-    """``attend(q, k, v, causal)`` as ``config.attention`` says; with
-    ``window``, a window layer's (a callable is refused when the config is
-    made: the window is the flash kernels' and their oracle's)."""
-    if callable(config.attention):
-        return config.attention
+    """``attend(q, k, v, causal)`` as ``config.attention`` says, K and V with
+    ``n_kv_heads``; with ``window``, a window layer's (a callable is refused
+    when the config is made: the window is the flash kernels' and their
+    oracle's). The flash kernels take the grouped heads as they are; the
+    oracle and a callable (ring, ulysses) are handed K and V repeated to the
+    query heads, the contract they have."""
     if config.attention == "flash":
         return functools.partial(_flash_over_mesh, window=window)
-    return lambda q, k, v, causal: attention_reference(q, k, v, causal=causal, window=window)
+    if callable(config.attention):
+        attend = config.attention
+    else:
+        attend = lambda q, k, v, causal: attention_reference(q, k, v, causal=causal, window=window)
 
+    def repeated(q, k, v, causal):
+        repeats = q.shape[1] // k.shape[1]
+        return attend(q, _repeat_kv(k, repeats), _repeat_kv(v, repeats), causal)
 
-def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
-    if repeats == 1:
-        return x
-    return jnp.repeat(x, repeats, axis=1)
+    return repeated
 
 
 def _qkv(h, layer, config: TransformerConfig):
@@ -1010,16 +1037,15 @@ def _conv_mixer(h, layer, config: TransformerConfig, *_):
 
 def _gqa_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
     """Grouped-query attention on the branch input ``h``: q / k / v, RoPE
-    where ``cos_sin`` is given, K and V repeated to the query heads, causal
-    attention through ``attention_fn``; ``W_o``."""
+    where ``cos_sin`` is given, causal attention through ``attention_fn``
+    (K and V at ``n_kv_heads``: ``_attention_impl`` says who repeats them);
+    ``W_o``."""
     batch, seq, _ = h.shape
     q, k, v = _qkv(h, layer, config)
     if cos_sin is not None:
         cos, sin = cos_sin
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-    rep = config.n_heads // config.n_kv_heads
-    k, v = _repeat_kv(k, rep), _repeat_kv(v, rep)
     o = attention_fn(q, k, v, True)
     o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
     return o @ layer["wo"]

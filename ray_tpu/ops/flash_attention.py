@@ -9,10 +9,22 @@ is in-tree). Blocked online-softmax attention:
             emits O and the logsumexp (LSE) residual.
   backward: two kernels (the standard flash-v2 split):
               dq:  grid = (batch*heads, q_blocks, kv_blocks)  # kv sequential
-              dkv: grid = (batch*heads, kv_blocks, q_blocks)  # q  sequential
+              dkv: grid = (batch*kv_heads, kv_blocks, [group,] q_blocks)
+                                                      # group, q sequential
             Both recompute P = exp(S - LSE) blockwise from (q, k) — O(S²)
             probabilities are never materialized in HBM, so long sequences
             train in memory linear in S.
+
+Grouped KV heads (GQA) are native: K and V come ``[batch, kv_heads, seq, d]``
+beside q's ``[batch, heads, seq, d]``, ``group = heads // kv_heads`` from the
+shapes, and nobody repeats them. In the forward and dq kernels the K / V
+index map of query row ``i = b * heads + h`` names row ``i // group = b *
+kv_heads + h // group``; the bodies are the ungrouped ones. The dkv kernel
+writes ONE row a KV head: its grid gains a sequential ``group`` axis whose
+step ``g`` reads query row ``kv_row * group + g``, the K / V tiles stay
+resident across the whole group, and the float32 scratch sums the group's
+``dK`` / ``dV`` and is rounded to the operand dtype once. ``group == 1`` has
+no such axis and no division: the three Mosaic modules are what they were.
 
 MXU discipline: matmul operands stay in the input dtype (bfloat16 on TPU —
 the MXU's native multiply) with float32 accumulation via
@@ -174,12 +186,14 @@ def _band_q_index(step, kv_index, causal_offset, block_q, block_k,
 
 
 def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks,
-                  window=None):
+                  window=None, group=1):
     """K/V block of grid step (i, j, kv) in the fwd and dq kernels. A q
     row's skipped steps are clamped to its last needed kv block: they name
     the block already resident, and Pallas issues no DMA for them. Under a
     window the third grid index is a STEP along the row's band: the row's
-    first needed block + the step, clamped the same way."""
+    first needed block + the step, clamped the same way. With ``group``
+    query heads to a KV head, query row ``i = b * heads + h`` reads K / V
+    row ``i // group = b * kv_heads + h // group``."""
     def index_map(i, j, kv):
         if causal:
             kv = _band_kv_index(kv, j, causal_offset, block_q, block_k,
@@ -188,18 +202,22 @@ def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks,
             kv = jnp.minimum(
                 kv, jnp.minimum(last_key // block_k, num_kv_blocks - 1)
             )
-        return (i, kv, 0)
+        return (i if group == 1 else i // group, kv, 0)
 
     return index_map
 
 
 def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
-                 window=None):
+                 window=None, group=1):
     """Q/dO/lse/delta block of grid step (i, j, qi) in the dkv kernel: a kv
     row's skipped steps come first, clamped to its first needed q block.
     Under a window the third grid index is a STEP from that first needed
-    block on, and those behind the band are clamped to its last."""
-    def index_map(i, j, qi):
+    block on, and those behind the band are clamped to its last. With
+    ``group`` query heads to a KV head the grid step is (i, j, g, qi), ``i``
+    a K / V row, and names query row ``i * group + g``: the band is per
+    ``g`` what it is per head."""
+    def index_map(i, j, *g_qi):
+        qi = g_qi[-1]
         if causal and window is None:
             qi = jnp.maximum(
                 qi, _first_q_block(causal_offset, j, block_q, block_k, num_q_blocks)
@@ -210,7 +228,7 @@ def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
             # the last query that sees the row's last key
             last_query = (j + 1) * block_k - 1 + window - 1 - causal_offset
             qi = jnp.minimum(qi, jnp.clip(last_query // block_q, 0, num_q_blocks - 1))
-        return (i, qi, 0)
+        return (i if group == 1 else i * group + g_qi[0], qi, 0)
 
     return index_map
 
@@ -349,14 +367,21 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_q_blocks,
-    precision, causal_offset, window, num_steps
+    precision, causal_offset, window, num_steps, group
 ):
-    step = pl.program_id(2)
+    # One output row a KV head: the scratch sums its ``group`` query heads'
+    # steps in float32 (grid axes 2 and 3, both sequential; the K / V tiles
+    # stay resident across them). group == 1: no such axis.
+    step = pl.program_id(2 if group == 1 else 3)
     kv_index = pl.program_id(1)
     q_index = _band_q_index(step, kv_index, causal_offset, block_q, block_k,
                             num_q_blocks, window)
 
-    @pl.when(step == 0)
+    first = step == 0
+    if group > 1:
+        first &= pl.program_id(2) == 0
+
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -393,7 +418,11 @@ def _flash_dkv_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
 
-    @pl.when(step == num_steps - 1)
+    last = step == num_steps - 1
+    if group > 1:
+        last &= pl.program_id(2) == group - 1
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -412,8 +441,12 @@ def flash_attention(
     precision: jax.lax.Precision | None = None,
     window: int | None = None,
 ) -> jax.Array:
-    """q, k: [batch, heads, seq, head_dim]; v: [batch, heads, seq, v_dim]
-    (GQA is handled by the caller repeating kv heads). Returns [batch, heads,
+    """q: [batch, heads, seq_q, head_dim]; k: [batch, kv_heads, seq_k,
+    head_dim]; v: [batch, kv_heads, seq_k, v_dim], ``kv_heads`` dividing
+    ``heads`` (grouped-query attention: query head ``h`` reads KV head ``h //
+    (heads // kv_heads)``, as K / V repeated ``heads // kv_heads`` times along
+    the head axis would give it; the kernels read the group's one K / V row
+    and ``dk`` / ``dv`` come back at ``kv_heads``). Returns [batch, heads,
     seq_q, v_dim]. ``v_dim`` may differ from ``head_dim`` (latent attention:
     q / k of 192, v of 128): scores, ``dq`` and ``dk`` run over ``head_dim``;
     ``P v``, ``dP = dO v^T``, ``dv`` and the output accumulator over
@@ -480,6 +513,16 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, precision,
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _kv_group(q, k, v):
+    """How many query heads read one KV head (1: as many of each), from the
+    operands' shapes."""
+    heads, kv_heads = q.shape[1], k.shape[1]
+    assert v.shape[1] == kv_heads and heads % kv_heads == 0, (
+        f"{heads} query heads do not group over K's {kv_heads} and V's {v.shape[1]}"
+    )
+    return heads // kv_heads
+
+
 def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype):
     """The kernels' block shape, from the shapes they see (``head_dim``:
     the larger of q / k's and v's). ``block_q`` / ``block_k`` of None (the
@@ -533,7 +576,7 @@ def _flash_forward(
     batch, heads, seq_q, dim = q.shape
     _, kv_heads, seq_k, _ = k.shape
     v_dim = v.shape[-1]
-    assert kv_heads == heads, "repeat kv heads before calling (GQA)"
+    group = _kv_group(q, k, v)
     if scale is None:
         scale = dim ** -0.5
     block_q, block_k = _block_sizes(
@@ -542,8 +585,8 @@ def _flash_forward(
 
     bh = batch * heads
     qr = q.reshape(bh, seq_q, dim)
-    kr = k.reshape(bh, seq_k, dim)
-    vr = v.reshape(bh, seq_k, v_dim)
+    kr = k.reshape(batch * kv_heads, seq_k, dim)
+    vr = v.reshape(batch * kv_heads, seq_k, v_dim)
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
@@ -562,7 +605,7 @@ def _flash_forward(
         num_steps=kv_steps,
     )
     kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks, window
+        causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
     )
     from jax.experimental.pallas import tpu as pltpu
 
@@ -606,15 +649,16 @@ def _flash_backward(
     precision, window=None
 ):
     batch, heads, seq_q, dim = q.shape
-    seq_k, v_dim = v.shape[2:]
+    kv_heads, seq_k, v_dim = v.shape[1:]
+    group = _kv_group(q, k, v)
     block_q, block_k = _block_sizes(
         seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype
     )
 
-    bh = batch * heads
+    bh, bkv = batch * heads, batch * kv_heads
     qr = q.reshape(bh, seq_q, dim)
-    kr = k.reshape(bh, seq_k, dim)
-    vr = v.reshape(bh, seq_k, v_dim)
+    kr = k.reshape(bkv, seq_k, dim)
+    vr = v.reshape(bkv, seq_k, v_dim)
     dor = g.astype(q.dtype).reshape(bh, seq_q, v_dim)
     lser = lse.reshape(bh, seq_q, 1)
     # delta_i = rowsum(dO_i ⊙ O_i): tiny elementwise pass, XLA fuses it.
@@ -639,7 +683,7 @@ def _flash_backward(
         causal_offset=causal_offset, window=window, num_steps=steps["kv"],
     )
     kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks, window
+        causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
     )
     dq = pl.pallas_call(
         dq_kernel,
@@ -663,28 +707,31 @@ def _flash_backward(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         num_q_blocks=num_q_blocks, precision=precision,
         causal_offset=causal_offset, window=window, num_steps=steps["q"],
+        group=group,
     )
     q_map = _q_index_map(
-        causal, causal_offset, block_q, block_k, num_q_blocks, window
+        causal, causal_offset, block_q, block_k, num_q_blocks, window, group
     )
+    kv_row = lambda i, j, *g_qi: (i, j, 0)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, num_kv_blocks, steps["q"]),
+        # one K / V row a KV head; a group axis only where a group is
+        grid=(bkv, num_kv_blocks, *((group,) if group > 1 else ()), steps["q"]),
         in_specs=[
             pl.BlockSpec((1, block_q, dim), q_map),
-            pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_k, v_dim), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dim), kv_row),
+            pl.BlockSpec((1, block_k, v_dim), kv_row),
             pl.BlockSpec((1, block_q, v_dim), q_map),
             pl.BlockSpec((1, block_q, 1), q_map),
             pl.BlockSpec((1, block_q, 1), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, dim), lambda i, j, qi: (i, j, 0)),
-            pl.BlockSpec((1, block_k, v_dim), lambda i, j, qi: (i, j, 0)),
+            pl.BlockSpec((1, block_k, dim), kv_row),
+            pl.BlockSpec((1, block_k, v_dim), kv_row),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_k, dim), k.dtype),
-            jax.ShapeDtypeStruct((bh, seq_k, v_dim), v.dtype),
+            jax.ShapeDtypeStruct((bkv, seq_k, dim), k.dtype),
+            jax.ShapeDtypeStruct((bkv, seq_k, v_dim), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, dim), jnp.float32),

@@ -12,6 +12,14 @@ from ray_tpu.ops.rmsnorm import rmsnorm, rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
+def _grouped_reference(q, k, v, **kwargs):
+    """``attention_reference`` on K and V repeated by hand to q's heads: the
+    oracle of the kernels' grouped KV heads (a group of 1 repeats nothing)."""
+    group = q.shape[1] // k.shape[1]
+    return attention_reference(
+        q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1), **kwargs)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_matches_reference(causal):
     key = jax.random.PRNGKey(0)
@@ -183,8 +191,32 @@ _TILE_CASES = [
 ]
 
 
+def _grouped_maps(kv_map, q_map, group):
+    """Both index maps as ``(j, step) -> block``, the rows they name held to
+    the grouping on the way: query row ``i = b * heads + h`` reads K / V row
+    ``i // group``; in the dkv grid K / V row ``i``'s step ``g`` of the group
+    axis (absent at a group of 1) names query row ``i * group + g``. The
+    BLOCK a step names does not depend on the row, so the callers hold it to
+    ``_tile_needed`` for every row at once."""
+    def kv_block(j, step):
+        rows, blocks = zip(*(kv_map(i, j, step)[:2] for i in range(2 * group)))
+        assert list(rows) == [i // group for i in range(2 * group)]
+        assert len({int(block) for block in blocks}) == 1
+        return int(blocks[0])
+
+    def q_block(j, step):
+        steps = [(g,) for g in range(group)] if group > 1 else [()]
+        named = [q_map(i, j, *g, step)[:2] for i in range(2) for g in steps]
+        assert [row for row, _ in named] == list(range(2 * group))
+        assert len({int(block) for _, block in named}) == 1
+        return int(named[0][1])
+
+    return kv_block, q_block
+
+
+@pytest.mark.parametrize("group", [1, 7])
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k", _TILE_CASES)
-def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k):
+def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, group):
     import numpy as np
 
     from ray_tpu.ops.flash_attention import (
@@ -202,8 +234,9 @@ def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k):
               kv * block_k:(kv + 1) * block_k].any() for kv in range(nk)]
         for j in range(nq)
     ])
-    kv_map = _kv_index_map(True, offset, block_q, block_k, nk)
-    q_map = _q_index_map(True, offset, block_q, block_k, nq)
+    kv_block, q_block = _grouped_maps(
+        _kv_index_map(True, offset, block_q, block_k, nk, group=group),
+        _q_index_map(True, offset, block_q, block_k, nq, group=group), group)
     for j in range(nq):
         for kv in range(nk):
             assert _tile_needed(
@@ -211,8 +244,8 @@ def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k):
             # An executed step fetches its own blocks; a skipped one names
             # a block of the same row that is needed (or, in a row with
             # none, one block for the whole row): no new fetch.
-            fetched_kv = int(kv_map(0, j, kv)[1])
-            fetched_q = int(q_map(0, kv, j)[1])
+            fetched_kv = kv_block(j, kv)
+            fetched_q = q_block(kv, j)
             if needed[j, kv]:
                 assert (fetched_kv, fetched_q) == (kv, j)
             else:
@@ -266,13 +299,15 @@ _WINDOW_TILE_CASES = [
 ]
 
 
+@pytest.mark.parametrize("group", [1, 4])
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,window", _WINDOW_TILE_CASES)
-def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, window):
+def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, window, group):
     """``_tile_needed``, ``band_steps``, both index maps and
     ``causal_tile_counts`` under a window against the mask itself. The grid's
-    third index is a STEP along a row's band: the steps of a row reach every
+    last index is a STEP along a row's band: the steps of a row reach every
     needed tile of it, each fetching its own blocks, and a step outside the
-    band names a needed block of the row (its last): no fetch."""
+    band names a needed block of the row (its last): no fetch. Under a
+    ``group`` the band is per query head of the group what it is per head."""
     import numpy as np
 
     from ray_tpu.ops.flash_attention import (
@@ -293,21 +328,22 @@ def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, win
             assert bool(_tile_needed(
                 True, offset, j, kv, block_q, block_k, window)) == needed[j, kv], (j, kv)
     steps = band_steps(seq_q, seq_k, block_q, block_k, window)
-    kv_map = _kv_index_map(True, offset, block_q, block_k, nk, window)
-    q_map = _q_index_map(True, offset, block_q, block_k, nq, window)
+    kv_block, q_block = _grouped_maps(
+        _kv_index_map(True, offset, block_q, block_k, nk, window, group),
+        _q_index_map(True, offset, block_q, block_k, nq, window, group), group)
     for j in range(nq):                      # the fwd and dq kernels: a q row's steps
         row = np.flatnonzero(needed[j])
         first = int(_first_kv_block(offset, j, block_q, block_k, nk, window))
         assert first == row[0] and row[-1] - row[0] + 1 <= steps["kv"]
         for step in range(steps["kv"]):
-            fetched = int(kv_map(0, j, step)[1])
+            fetched = kv_block(j, step)
             assert fetched == min(first + step, row[-1]), (j, step)
             if first + step < nk:            # what the kernel computes is what it fetched
                 assert (fetched == first + step) or not needed[j, first + step]
     for kv in range(nk):                     # the dkv kernel: a kv row's steps
         col = np.flatnonzero(needed[:, kv])
         first = int(_first_q_block(offset, kv, block_q, block_k, nq))
-        fetched = [int(q_map(0, kv, step)[1]) for step in range(steps["q"])]
+        fetched = [q_block(kv, step) for step in range(steps["q"])]
         if not col.size:                     # a kv row no query sees: one q block, no fetch
             assert len(set(fetched)) == 1
             continue
@@ -337,14 +373,18 @@ def test_window_tile_counts_of_the_benchmark_cell():
 
 # One block is 32 keys here: windows of 1, 8, a block, a block +- 1, and the
 # whole sequence or more, at seq_q == seq_k and at seq_q < seq_k.
+# (batch, heads, kv_heads): as many KV heads as query heads, then groups of 4
+# and 7 with two batch rows (the K / V row ``i // group`` crosses a batch row).
+@pytest.mark.parametrize("heads", [(1, 2, 2), (2, 8, 2), (2, 7, 1)])
 @pytest.mark.parametrize("seq_q", [128, 64])
 @pytest.mark.parametrize("window", [1, 8, 31, 32, 33, 128, 500])
-def test_flash_attention_window_matches_reference(seq_q, window):
+def test_flash_attention_window_matches_reference(seq_q, window, heads):
     seq_k, dim, block = 128, 16, 32
+    batch, heads, kv_heads = heads
     key = jax.random.PRNGKey(23)
-    q = jax.random.normal(key, (1, 2, seq_q, dim), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, seq_k, dim), jnp.float32)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, seq_k, dim), jnp.float32)
+    q = jax.random.normal(key, (batch, heads, seq_q, dim), jnp.float32)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (batch, kv_heads, seq_k, dim), jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(key, 2), (batch, kv_heads, seq_k, dim), jnp.float32)
 
     def flash(q, k, v, window=window):
         return flash_attention(
@@ -353,7 +393,7 @@ def test_flash_attention_window_matches_reference(seq_q, window):
         )
 
     def ref(q, k, v):
-        return attention_reference(q, k, v, window=window)
+        return _grouped_reference(q, k, v, window=window)
 
     assert float(jnp.max(jnp.abs(flash(q, k, v) - ref(q, k, v)))) < 2e-5
     loss = lambda fn: lambda *a: jnp.sum(fn(*a) ** 2)
@@ -401,15 +441,21 @@ def test_window_needs_causal():
 
 
 # Shapes where one call holds skipped tiles, tiles the mask leaves whole and
-# tiles it cuts (when causal): (batch, heads, seq_q, seq_k, dim, block_q,
-# block_k). The last has
-# whole-vreg widths (dim and block_k multiples of 128), the forward's
-# lane-dense statistics' other path.
+# tiles it cuts (when causal): (batch, heads, kv_heads, seq_q, seq_k, dim,
+# v_dim, block_q, block_k). The fourth has whole-vreg widths (dim and block_k
+# multiples of 128), the forward's lane-dense statistics' other path. Then
+# grouped KV heads, two batch rows each (query row ``b * heads + h`` reads K
+# / V row ``b * kv_heads + h // group``, dkv sums a group in its scratch):
+# groups of 4 and 7, the second with seq_q < seq_k and rectangular blocks;
+# and two head dims (d_v != d_k) at a group of 1 with seq_q < seq_k.
 _SKIP_SHAPES = [
-    (1, 2, 256, 256, 64, 64, 64),
-    (1, 2, 128, 256, 32, 32, 64),
-    (1, 2, 256, 256, 32, 128, 32),
-    (1, 1, 512, 512, 128, 128, 128),
+    (1, 2, 2, 256, 256, 64, 64, 64, 64),
+    (1, 2, 2, 128, 256, 32, 32, 32, 64),
+    (1, 2, 2, 256, 256, 32, 32, 128, 32),
+    (1, 1, 1, 512, 512, 128, 128, 128, 128),
+    (2, 8, 2, 256, 256, 64, 64, 64, 64),
+    (2, 7, 1, 128, 256, 32, 32, 32, 64),
+    (2, 2, 2, 128, 256, 48, 32, 64, 64),
 ]
 
 
@@ -419,14 +465,14 @@ _SKIP_SHAPES = [
 def test_flash_attention_skipping_matches_reference(shape, causal, dtype):
     from ray_tpu.ops.flash_attention import causal_tile_counts
 
-    batch, heads, seq_q, seq_k, dim, block_q, block_k = shape
+    batch, heads, kv_heads, seq_q, seq_k, dim, v_dim, block_q, block_k = shape
     assert all(causal_tile_counts(seq_q, seq_k, block_q, block_k).values())
     key = jax.random.PRNGKey(11)
     q = jax.random.normal(key, (batch, heads, seq_q, dim), dtype)
     k = jax.random.normal(
-        jax.random.fold_in(key, 1), (batch, heads, seq_k, dim), dtype)
+        jax.random.fold_in(key, 1), (batch, kv_heads, seq_k, dim), dtype)
     v = jax.random.normal(
-        jax.random.fold_in(key, 2), (batch, heads, seq_k, dim), dtype)
+        jax.random.fold_in(key, 2), (batch, kv_heads, seq_k, v_dim), dtype)
     exact = dtype == jnp.float32
     precision = jax.lax.Precision.HIGHEST if exact else None
 
@@ -437,7 +483,7 @@ def test_flash_attention_skipping_matches_reference(shape, causal, dtype):
         ).astype(jnp.float32)
 
     def ref(q, k, v):
-        return attention_reference(q, k, v, causal=causal).astype(jnp.float32)
+        return _grouped_reference(q, k, v, causal=causal).astype(jnp.float32)
 
     err = float(jnp.max(jnp.abs(flash(q, k, v) - ref(q, k, v))))
     assert err < (2e-5 if exact else 3e-2), err
@@ -447,4 +493,6 @@ def test_flash_attention_skipping_matches_reference(shape, causal, dtype):
         err = float(
             jnp.max(jnp.abs(g.astype(jnp.float32) - r.astype(jnp.float32)))
         )
-        assert err < (2e-4 if exact else 0.15), (name, err)
+        # a KV head's gradient is the sum of its group's: so is its rounding
+        sums = heads // kv_heads if name != "dq" else 1
+        assert err < (2e-4 if exact else 0.15) * sums, (name, err)

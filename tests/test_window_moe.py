@@ -27,6 +27,7 @@ import pytest
 
 from ray_tpu import train
 from ray_tpu.models import transformer as T
+from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils
 
 EPS, THETA, HELD, WINDOW = 1e-6, 1.5e6, (0, 4), 8
@@ -323,6 +324,24 @@ def test_what_this_model_cannot_do_yet_is_refused_by_name(params):
         T.MoEConfig(router_input="embedding")
 
 
+def _two_steps(model, mesh_axes, x):
+    """The losses of two AdamW steps of ``model`` on ``x`` over a mesh of
+    ``mesh_axes``, through the sharded training step."""
+    from ray_tpu.parallel.mesh import MeshSpec
+
+    mesh, optimizer = MeshSpec(dict(mesh_axes)), optax.adamw(1e-3)
+    setup = jax_utils.setup_sharded_training(
+        lambda: T.init_params(model, jax.random.PRNGKey(0)), optimizer,
+        mesh=mesh.build(jax.devices()[:mesh.size]), logical_dims=T.param_logical_dims(model),
+    )
+    step = jax_utils.build_sharded_train_step(
+        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], model), optimizer, setup
+    )
+    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
+    params, opt_state, first = step(setup.params, setup.opt_state, batch)
+    return float(first), float(step(params, opt_state, batch)[2])
+
+
 @pytest.mark.parametrize("axes", [{"fsdp": 2, "tp": 2}, {"dp": 2, "sp": 2}])
 def test_the_step_is_the_one_devices_over_a_tp_and_an_sp_mesh(axes):
     """A pattern of full and window layers has no kernel that refuses a mesh
@@ -330,24 +349,34 @@ def test_the_step_is_the_one_devices_over_a_tp_and_an_sp_mesh(axes):
     (heads over tp; under sp the kernel still sees the whole sequence: the
     window through ``parallel/``'s sequence-parallel attention is what is NOT
     written), and two steps give the one-device losses."""
-    from ray_tpu.parallel.mesh import MeshSpec
-
     x = np.asarray(ids(seed=8, batch=4, seq=41))
-    losses = {}
-    for name, mesh_axes in (("one", {"dp": 1}), ("mesh", axes)):
-        mesh, optimizer = MeshSpec(dict(mesh_axes)), optax.adamw(1e-3)
-        setup = jax_utils.setup_sharded_training(
-            lambda: T.init_params(MODEL, jax.random.PRNGKey(0)), optimizer,
-            mesh=mesh.build(jax.devices()[:mesh.size]), logical_dims=T.param_logical_dims(MODEL),
-        )
-        step = jax_utils.build_sharded_train_step(
-            lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], MODEL), optimizer, setup
-        )
-        batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
-        params, opt_state, first = step(setup.params, setup.opt_state, batch)
-        losses[name] = (float(first), float(step(params, opt_state, batch)[2]))
+    losses = {name: _two_steps(MODEL, mesh_axes, x) for name, mesh_axes in (("one", {"dp": 1}), ("mesh", axes))}
     assert losses["mesh"][1] < losses["mesh"][0]
     np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-6)
+
+
+@pytest.mark.parametrize("kv_heads", [2, 1])
+def test_flash_and_the_oracle_agree_on_grouped_heads_over_tp(kv_heads, monkeypatch):
+    """K and V reach the flash kernels at ``n_kv_heads`` and are cut over
+    ``tp`` as q's heads are: with 2 KV heads over tp 2 a shard holds one
+    beside its two query heads; ONE KV head is not divisible and is repeated
+    by the least factor that makes it (2, not the group's 4). The oracle is
+    handed K and V repeated to the query heads. Two steps' losses agree."""
+    x = np.asarray(ids(seed=9, batch=2, seq=41))
+    per_shard = set()
+
+    def recording(q, k, v, **kwargs):
+        per_shard.add((q.shape[1], k.shape[1], v.shape[1]))
+        return flash_attention(q, k, v, **kwargs)
+
+    monkeypatch.setattr(T, "flash_attention", recording)
+    losses = {
+        attention: _two_steps(dataclasses.replace(MODEL, n_kv_heads=kv_heads, attention=attention), {"tp": 2}, x)
+        for attention in ("flash", "reference")
+    }
+    assert per_shard == {(2, 1, 1)}      # a shard's kernels: two query heads on one KV head
+    assert losses["flash"][1] < losses["flash"][0]
+    np.testing.assert_allclose(losses["flash"], losses["reference"], rtol=1e-5)
 
 
 def _window_moe_loop(config):
