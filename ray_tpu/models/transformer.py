@@ -1,4 +1,4 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the seven
+"""Flagship model: a decoder-only transformer, TPU-first, in the eight
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
@@ -45,10 +45,18 @@ checkpoint policy, one head and loss):
     w_i)`` before ``W_o`` (scope ``attn_gate``), and the dense prefix takes
     the mixer ``first_dense_kind`` names. Its linear layers are Kimi Delta
     Attention, ``linear=`` under ``decay="channel"``: the delta rule with a
-    decay for each key CHANNEL of a head, bounded below by
-    ``gate_lower_bound`` (which is what lets the chunked form be computed:
-    ops/gated_delta_rule.py), and a sigmoid in place of SiLU on the per-head
-    norm's output (``_linear_mixer`` has the formulas).
+    decay for each key CHANNEL of a head, there bounded below by
+    ``gate_lower_bound`` (which buys the chunked form a cheaper preparation,
+    no more: ops/gated_delta_rule.py), and a sigmoid in place of SiLU on the
+    per-head norm's output (``_linear_mixer`` has the formulas).
+  * the eighth, Kimi Delta Attention under its own UNBOUNDED gate three to
+    one with gated grouped-query attention (Solar-Open2): ``linear=`` with
+    ``decay="channel"``, no ``gate_lower_bound``, ``allow_neg_eigval`` and
+    ``gate_rank`` (the decay's and the output gate's projections through a
+    rank, scope ``kda_gate``), and ``output_gate="element"`` on a "full"
+    layer that is grouped-query attention with ``rope_theta=None``: the
+    heads' outputs times ``sigmoid(h W_g)`` element by element before
+    ``W_o``, the same field and scope as the latent layer's head gate.
   * the sixth, gated short convolutions over experts (LFM2-8B-A1B): a
     third kind of layer, "conv", in the pattern and as ``first_dense_kind``,
     whose whole mixer (``_conv_mixer``, scope ``conv_mixer``) is ``(C *
@@ -95,8 +103,9 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     (``_over_mesh``: GSPMD cannot partition it).
   * weights default to bfloat16 (MXU-native); norms/softmax accumulate f32.
   * what is not written refuses by name: serving (init_kv_cache /
-    decode_step) beyond grouped-query attention (the latent, recurrent-state
-    and convolution-state caches), the pipeline (partition_stages /
+    decode_step) beyond ungated grouped-query attention (the latent,
+    recurrent-state and convolution-state caches, an output gate on a cached
+    step), the pipeline (partition_stages /
     stage_forward) over a dense prefix, a pattern or a tied head, tp or sp
     over a patterned model with linear or conv layers (dp / fsdp work),
     ``norm_placement="post"`` over expert layers, a window layer under a
@@ -166,11 +175,13 @@ LATENT_SCOPES = ("latent", "shared")
 # gates, the chunk preparation and the two scan kernels) and "gate_norm"
 # (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
-# Five names outside the four vocabularies, read by name
+# Six names outside the four vocabularies, read by name
 # (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
 # (the chunk preparation under a decay per channel, opened in
 # ops/gated_delta_rule.py: forward, and backward through its custom VJP),
-# "attn_gate", inside "attention" (a latent layer's head-wise output gate),
+# "attn_gate", inside "attention" (a "full" layer's output gate, by head or
+# by element), "kda_gate", inside "delta_rule" and "gate_norm" (a linear
+# layer's gate projections through ``gate_rank``),
 # and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
 # the two gates, W_out, and within it "short_conv", the convolution's two
 # kernels called with no activation), "window_attention", inside "attention"
@@ -287,9 +298,10 @@ class LatentAttentionConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
-    # "head": each head's output is multiplied by ``sigmoid(h w_i)``, one
-    # scalar a head and position, before ``W_o`` (gated attention at head
-    # granularity; leaf ``wg_head`` ``[hidden, heads]``). None: no gate.
+    # The "full" layers' output gate, stated here by the configurations from
+    # before grouped-query layers could carry one: ``TransformerConfig.
+    # output_gate`` has the kinds, and the model reads both as one
+    # (``TransformerConfig.full_gate``).
     output_gate: str | None = None
 
     @property
@@ -301,8 +313,10 @@ class LatentAttentionConfig:
 class LinearAttentionConfig:
     """A gated-delta-rule linear-attention mixer as ``olmo_hybrid``'s
     ``config.json`` states it (the ``linear_*`` keys), or, with ``decay=
-    "channel"``, a bounded gate and a sigmoid output gate, Kimi Delta
-    Attention as ``bailing_hybrid``'s states it (the ``kda_*`` keys)."""
+    "channel"`` and a sigmoid output gate, Kimi Delta Attention: under a
+    bounded gate with whole gate matrices as ``bailing_hybrid``'s states it
+    (the ``kda_*`` keys), or under Kimi Linear's own unbounded gate with both
+    gates' projections through ``gate_rank`` as ``solar_open2``'s does."""
     num_key_heads: int = 30
     num_value_heads: int = 30
     key_head_dim: int = 96
@@ -317,10 +331,20 @@ class LinearAttentionConfig:
     decay: str = "head"
     # None: ``log alpha = -exp(a_log) softplus(h W_a + dt_bias)``. A bound b <
     # 0: ``log alpha = b sigmoid(exp(a_log) (h W_a + dt_bias))``, in (b, 0)
-    # (``kda_safe_gate`` / ``kda_lower_bound``).
+    # (``kda_safe_gate`` / ``kda_lower_bound``). Either runs under either
+    # decay: the chunked form exponentiates nothing above 0 whatever the gate
+    # (ops/gated_delta_rule.py); a bound is handed on to it as a statement
+    # about ``log alpha``, which buys a cheaper preparation where it is
+    # shallow enough (``carries_bound``) and changes no value.
     gate_lower_bound: float | None = None
     # The activation of the gate on the per-head norm's output.
     output_gate: str = "silu"
+    # None: the decay's and the output gate's projections are whole matrices
+    # (``W_a``, ``W_g``). A rank r: each goes through r, ``(h W_a_down)
+    # W_a_up`` and ``(h W_g_down) W_g_up``, no bias, no activation between
+    # (Kimi Linear's ``f_a_proj`` / ``f_b_proj`` and ``g_a_proj`` /
+    # ``g_b_proj``; ``kda_use_full_proj`` false).
+    gate_rank: int | None = None
 
     def __post_init__(self):
         if self.decay not in ("head", "channel") or self.output_gate not in ("silu", "sigmoid"):
@@ -328,12 +352,8 @@ class LinearAttentionConfig:
         bound = self.gate_lower_bound
         if bound is not None and not bound < 0:
             raise ValueError(f"gate_lower_bound {bound!r} is no negative bound")
-        if self.decay == "channel" and (bound is None or 15 * -bound >= 88):
-            raise NotImplementedError(
-                "a decay per channel needs gate_lower_bound with 15 |bound| < 88: the chunked "
-                "form multiplies sub-blocks of 16 tokens whose key side carries e^(15 |bound|), "
-                f"which must stay under float32's e^88 (got {bound!r})"
-            )
+        if self.gate_rank is not None and self.gate_rank < 1:
+            raise ValueError(f"gate_rank {self.gate_rank!r} is no rank")
 
     @property
     def key_dim(self) -> int:
@@ -383,6 +403,15 @@ class TransformerConfig:
     # Latent attention in place of the q / k / v projections (then
     # ``n_kv_heads`` and ``qk_norm`` mean nothing); None = grouped-query.
     latent: LatentAttentionConfig | None = None
+    # A gate on a "full" layer's attention output before ``W_o``, computed
+    # from the layer's normed input ``h``, whichever attention the layer is
+    # (scope ``attn_gate``). "head": each head's output times ``sigmoid(h
+    # w_i)``, one scalar a head and position (leaf ``wg_head`` ``[hidden,
+    # heads]``). "element": the heads' outputs times ``sigmoid(h W_g)``
+    # element by element (leaf ``wg`` ``[hidden, heads x value head dim]``,
+    # sharded as ``W_q``'s columns). None: no gate, unless ``latent`` states
+    # one (``full_gate`` is what the model reads).
+    output_gate: str | None = None
     # With ``moe``: this many leading layers keep the dense MLP of width
     # ``hidden_dim`` (``first_k_dense_replace``).
     first_dense_layers: int = 0
@@ -422,6 +451,11 @@ class TransformerConfig:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         if self.norm_placement not in ("pre", "post"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        stated = self.latent.output_gate if self.latent else None
+        if stated and self.output_gate not in (None, stated):
+            raise ValueError(f"output_gate {self.output_gate!r} beside latent's {stated!r}: one")
+        if self.full_gate not in (None, "head", "element"):
+            raise ValueError(f"unknown output_gate {self.full_gate!r} ('head' | 'element')")
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (the whole projection) and qk_head_norm (head by head): one")
         unturned = set(self.rope_kinds or ()) - {"full", "window"}
@@ -465,6 +499,11 @@ class TransformerConfig:
         """The kinds of mixer a patterned model holds, the prefix's first."""
         prefix = (self.first_dense_kind,) if self.first_dense_layers else ()
         return tuple(dict.fromkeys(prefix + self.layer_pattern))
+
+    @property
+    def full_gate(self) -> str | None:
+        """The "full" layers' output gate, wherever it was stated."""
+        return self.output_gate or (self.latent.output_gate if self.latent else None)
 
     @property
     def prefix_kind(self) -> str:
@@ -537,9 +576,20 @@ def _model_leaves(config: TransformerConfig) -> dict:
     return {"embed": embed, "final_norm": _norm(d), **head}
 
 
+def _gate_leaves(config: TransformerConfig, value_head_dim: int) -> dict:
+    """A "full" layer's output gate (``TransformerConfig.full_gate``)."""
+    d, heads = config.dim, config.n_heads
+    return {
+        None: {},
+        "head": {"wg_head": _Leaf((d, heads), ("embed", None))},
+        "element": {"wg": _Leaf((d, heads * value_head_dim), ("embed", "heads"))},
+    }[config.full_gate]
+
+
 def _gqa_leaves(config: TransformerConfig) -> dict:
     """Grouped-query attention's leaves with its q / k norms' widths: a
-    "window" layer's mixer, and a "full" layer's without ``latent``."""
+    "window" layer's mixer, and a "full" layer's without ``latent`` (which
+    adds its output gate's)."""
     d, heads = config.dim, config.n_heads
     q_out, kv_out = heads * config.head_dim, config.n_kv_heads * config.head_dim
     q_norm, k_norm = (q_out, kv_out) if config.qk_norm else (config.head_dim, config.head_dim)
@@ -558,7 +608,7 @@ def _full_leaves(config: TransformerConfig) -> dict:
     else grouped-query attention."""
     d, heads, la = config.dim, config.n_heads, config.latent
     if not la:
-        return _gqa_leaves(config)
+        return {**_gqa_leaves(config), **_gate_leaves(config, config.head_dim)}
     # tp shards whole heads (W_q's and W_kv_b's columns are laid out head by
     # head) and leaves the latent and the shared rope key whole.
     kv_out = heads * (la.qk_nope_head_dim + la.v_head_dim)
@@ -568,7 +618,7 @@ def _full_leaves(config: TransformerConfig) -> dict:
         "wkv_a": _Leaf((d, la.kv_lora_rank + la.qk_rope_head_dim), ("embed", None)),
         "wkv_b": _Leaf((la.kv_lora_rank, kv_out), (None, "heads")),
         "wo": _Leaf((heads * la.v_head_dim, d), ("heads", "embed")),
-        **({"wg_head": _Leaf((d, heads), ("embed", None))} if la.output_gate else {}),
+        **_gate_leaves(config, la.v_head_dim),
     }
 
 
@@ -580,7 +630,10 @@ def _linear_leaves(config: TransformerConfig) -> dict:
     Mamba2's initialisation: a per-token decay between 0.2 and 0.9999), the
     gated norm's weight ones; both gates' parameters in float32. Under a
     decay per channel ``W_a`` is ``[hidden, heads x d_k]`` and ``dt_bias``
-    one a channel; ``a_log`` stays one a head. The recipe is kept under
+    one a channel; ``a_log`` stays one a head. Under ``gate_rank`` ``W_g``
+    and ``W_a`` are each two leaves, ``[hidden, rank]`` whole on every shard
+    and ``[rank, .]`` sharded as the whole matrix's columns, drawn where the
+    whole matrix was. The recipe is kept under
     ``gate_lower_bound`` too, where it leaves the bounded gate nearly shut
     on fresh weights (``b sigmoid(A (h W_a + dt_bias))`` with ``dt_bias``
     about ``log(step)``: a log-decay within 0.01 of 0 in most channels): a
@@ -600,14 +653,24 @@ def _linear_leaves(config: TransformerConfig) -> dict:
         return dt + jnp.log(-jnp.expm1(-dt))
 
     a_log = lambda keys, shape, dtype: jnp.log(uniform(keys, shape, 1e-3, 16.0))
+
+    def gate(name, out, columns):
+        """A gate's projection: ``name`` whole, or ``name_down``, ``name_up``."""
+        if la.gate_rank is None:
+            return {name: _Leaf((d, out), ("embed", columns))}
+        return {
+            f"{name}_down": _Leaf((d, la.gate_rank), ("embed", None)),
+            f"{name}_up": _Leaf((la.gate_rank, out), (None, columns)),
+        }
+
     return {
         "a_log": _Leaf((la.num_value_heads,), (None,), a_log),
         "dt_bias": _Leaf((decays,), (None,), dt_bias),
         "wq": _Leaf((d, la.key_dim), ("embed", "heads")),
         "wk": _Leaf((d, la.key_dim), ("embed", "heads")),
         "wv": _Leaf((d, la.value_dim), ("embed", "heads")),
-        "wg": _Leaf((d, la.value_dim), ("embed", "heads")),
-        "wa": _Leaf((d, decays), ("embed", "heads" if channel else None)),
+        **gate("wg", la.value_dim, "heads"),
+        **gate("wa", decays, "heads" if channel else None),
         "wb": _Leaf((d, la.num_value_heads), ("embed", None)),
         "conv_q": conv(la.key_dim), "conv_k": conv(la.key_dim), "conv_v": conv(la.value_dim),
         "o_norm": _norm(la.value_head_dim),
@@ -932,7 +995,9 @@ def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
     rows, gates = ("batch", None, None, None), ("batch", None, None)
     decay = rows if config.linear.decay == "channel" else gates
     operands = (rows, rows, rows, decay, gates)
-    return _over_mesh(gated_delta_rule, operands, rows, ("tp", "sp"), ())
+    # what the gate's bound says of ``log alpha`` (None: nothing)
+    kernel = functools.partial(gated_delta_rule, log_alpha_bound=config.linear.gate_lower_bound)
+    return _over_mesh(kernel, operands, rows, ("tp", "sp"), ())
 
 
 # The epsilon under the square root of q's and k's L2 norm.
@@ -960,6 +1025,12 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
         log alpha = b sigmoid(exp(a_log) (h W_a + dt_bias))     (in (b, 0))
 
     and ``output_gate="sigmoid"``, ``y = RMSNorm(o) * sigmoid(h W_g)``.
+    Without the bound the gate is Kimi Linear's own, the first formula with
+    ``dt_bias`` and ``h W_a`` a channel: unbounded below, and computed as it
+    stands. ``gate_rank=r`` puts ``(h W_a_down) W_a_up`` and ``(h W_g_down)
+    W_g_up`` (``W_*_down`` ``[hidden, r]``) where ``h W_a`` and ``h W_g``
+    stand, under scope ``kda_gate``; the decay's second product keeps its
+    float32 accumulator as ``W_a``'s does.
 
     The convolutions and the delta rule are Mosaic kernels (per data shard
     under a mesh) unless ``attention="reference"``, which keeps both in
@@ -972,6 +1043,13 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
 
     def by_head(x, width):
         return x.reshape(batch, seq, heads, width).transpose(0, 2, 1, 3)
+
+    def gate(name, **accumulator):
+        """``h W`` of a gate's projection, whole or through ``gate_rank``."""
+        if la.gate_rank is None:
+            return jnp.matmul(h, layer[name], **accumulator)
+        with jax.named_scope("kda_gate"):
+            return jnp.matmul(h @ layer[f"{name}_down"], layer[f"{name}_up"], **accumulator)
 
     with jax.named_scope("linear_attention"):
         q, k, v = (h @ layer[name] for name in ("wq", "wk", "wv"))
@@ -992,11 +1070,11 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
                 # the projection's float32 accumulator is kept: exp(a_log) up to
                 # 16 and the bound multiply what rounding its result to the
                 # model dtype would lose into a decay off by percents
-                raw = jnp.matmul(h, layer["wa"], preferred_element_type=f32)
+                raw = gate("wa", preferred_element_type=f32)
                 raw = (raw + layer["dt_bias"].astype(f32)).reshape(batch, seq, heads, la.key_head_dim)
                 rate = rate[:, None]
             else:
-                raw = (h @ layer["wa"]).astype(f32) + layer["dt_bias"].astype(f32)
+                raw = gate("wa").astype(f32) + layer["dt_bias"].astype(f32)
             if la.gate_lower_bound is None:
                 log_alpha = -rate * jax.nn.softplus(raw)
             else:
@@ -1008,10 +1086,10 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
             )
         with jax.named_scope("gate_norm"):
             o = o.transpose(0, 2, 1, 3)                          # [batch, seq, heads, d_v]
-            gate = (h @ layer["wg"]).reshape(o.shape).astype(f32)
+            opened = gate("wg").reshape(o.shape).astype(f32)
             y = rmsnorm_reference(o, layer["o_norm"], eps=config.rms_norm_eps)
             act = jax.nn.silu if la.output_gate == "silu" else jax.nn.sigmoid
-            y = (y.astype(f32) * act(gate)).astype(h.dtype)
+            y = (y.astype(f32) * act(opened)).astype(h.dtype)
         return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
 
 
@@ -1035,20 +1113,28 @@ def _conv_mixer(h, layer, config: TransformerConfig, *_):
         return (c * z) @ layer["w_out"]
 
 
-def _gqa_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
-    """Grouped-query attention on the branch input ``h``: q / k / v, RoPE
-    where ``cos_sin`` is given, causal attention through ``attention_fn``
-    (K and V at ``n_kv_heads``: ``_attention_impl`` says who repeats them);
-    ``W_o``."""
-    batch, seq, _ = h.shape
+def _gqa_heads(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """Grouped-query attention's output by head ``[batch, heads, seq,
+    head_dim]`` on the branch input ``h``: q / k / v, RoPE where ``cos_sin``
+    is given, causal attention through ``attention_fn`` (K and V at
+    ``n_kv_heads``: ``_attention_impl`` says who repeats them)."""
     q, k, v = _qkv(h, layer, config)
     if cos_sin is not None:
         cos, sin = cos_sin
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-    o = attention_fn(q, k, v, True)
-    o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
-    return o @ layer["wo"]
+    return attention_fn(q, k, v, True)
+
+
+def _heads_out(o, layer):
+    """``concat_heads(o) W_o`` of ``o`` ``[batch, heads, seq, value head dim]``."""
+    batch, heads, seq, width = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(batch, seq, heads * width) @ layer["wo"]
+
+
+def _gqa_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """Grouped-query attention on the branch input ``h``, ungated; ``W_o``."""
+    return _heads_out(_gqa_heads(h, layer, config, cos_sin, positions, attention_fn), layer)
 
 
 def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
@@ -1069,20 +1155,24 @@ def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
 
 def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
     """A "full" layer's mixer on the branch input ``h``: latent attention
-    where ``latent`` is set (each head's output times ``sigmoid(h w_i)``
-    under ``output_gate``), else grouped-query attention; ``W_o``."""
-    if not config.latent:
-        return _gqa_mixer(h, layer, config, cos_sin, positions, attention_fn)
-    batch, seq, _ = h.shape
-    q, k, v = _latent_qkv(h, layer, config, cos_sin, positions)
-    o = attention_fn(q, k, v, True)
-    if config.latent.output_gate:
+    where ``latent`` is set, else grouped-query attention; under
+    ``output_gate`` (either attention) each head's output times ``sigmoid(h
+    w_i)`` ("head") or the heads' outputs times ``sigmoid(h W_g)`` element
+    by element ("element"), float32, scope ``attn_gate``; ``W_o``."""
+    if config.latent:
+        o = attention_fn(*_latent_qkv(h, layer, config, cos_sin, positions), True)
+    else:
+        o = _gqa_heads(h, layer, config, cos_sin, positions, attention_fn)
+    if config.full_gate:
         with jax.named_scope("attn_gate"):
-            gate = jax.nn.sigmoid((h @ layer["wg_head"]).astype(jnp.float32))
-            gate = gate.transpose(0, 2, 1)[..., None]    # [batch, heads, seq, 1]
+            batch, heads, seq, _ = o.shape
+            if config.full_gate == "head":
+                gate = (h @ layer["wg_head"])[..., None]         # [batch, seq, heads, 1]
+            else:
+                gate = (h @ layer["wg"]).reshape(batch, seq, heads, -1)
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32)).transpose(0, 2, 1, 3)
             o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-    o = o.transpose(0, 2, 1, 3).reshape(batch, seq, config.n_heads * v.shape[-1])
-    return o @ layer["wo"]
+    return _heads_out(o, layer)
 
 
 # The kinds of mixer: what a ``layer_pattern`` may name. A kind is one row:
@@ -2343,6 +2433,11 @@ def _refuse_latent_cache(config: TransformerConfig) -> None:
             "decode with a layer_pattern needs a recurrent-state cache beside the KV cache "
             "(a [d_k, d_v] state and the convolution's last inputs a linear layer and "
             "head), which is not written yet"
+        )
+    if config.full_gate and not config.latent:
+        raise NotImplementedError(
+            "decode_step's grouped-query layer computes no output gate (output_gate="
+            f"{config.full_gate!r}): the gate on a cached step is not written yet"
         )
     if config.latent:
         raise NotImplementedError(

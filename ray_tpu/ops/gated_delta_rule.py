@@ -77,42 +77,66 @@ factors out of the dot products, ``A[t, i] = beta_t sum_c k_tc k_ic e^{G_tc -
 G_ic}``, ``P[t, i] = sum_c q_tc k_ic e^{G_tc - G_ic}``; ``W = T (beta e^G .
 K)``, ``Qg = e^G . Q``, ``Kd = e^{G_C - G} . K`` as before with ``.`` per
 channel, and ``gamma = e^{G_C}`` a VECTOR over ``d_k``. ``A`` and ``P`` stay
-matmuls and must not overflow (``_prepare_channel_xla``): the chunk is cut
-into sub-blocks of ``_SUB_CHUNK`` = 16 rows, and row block ``r`` multiplies
-``x_t . e^{G_t - R_r}`` (``R_r`` the ``G`` of its first token: exponent <= 0)
-by ``k_i . e^{R_r - G_i}``, whose exponent is <= 0 for every column before the
-block and at most ``15 |g|`` inside it. That is why the caller's decay must
-be BOUNDED below: at ``g >= -5`` a token and channel (the published
-``kda_lower_bound``) the largest factor is ``e^75``, under float32's ``e^88``,
-where the unsplit ``e^{G_t} . e^{-G_i}`` overflows inside one chunk.
+matmuls over the channels of operands that carry the decay, and must not
+overflow: the unsplit ``e^{G_t} . e^{-G_i}`` does inside one chunk at -5 a
+token. ANY ``log_alpha <= 0`` is computed (Kimi Linear's own gate, ``-exp(A)
+softplus(.)``, has no lower bound: at a rate of 16 single tokens reach -30 on
+fresh weights and -200 is a trained gate's "forget"), in one of two exact
+forms, neither of which clamps an exponent or leaves a term out:
 
-Its preparation is a Mosaic pair of its own (``_channel_prepare_forward``,
-``_channel_prepare_backward``, under scope ``decay_prepare``; ``_prepare_channel``
-is the seam the timed forward takes its operands through), beside the scalar
-pair and sharing ``_Masks`` with it; ``_prepare_channel_xla`` is the same
-mathematics in XLA, differentiated by jax: the oracle of both and the
-``kernels=False`` path. What the kernels hold in VMEM and XLA wrote to HBM: the
-running sum ``G`` (taken in the kernel by doubling: sublane rotations and
-adds), the rows' decay ``e^{G_t - R_r(t)}``, the four decayed copies of K a
-chunk (XLA's ``f32[2, 256, 4, 64, 128]``, 67 MB a call), ``A``, every level of
-the inverse, and in the backward ``dT``, ``dA``, each block's ``dX_r`` and
-``dC_r`` (``_Blocks`` lays ``R_r`` down the rows). Two chunks ride one
-128-row product; row block ``r`` of both stacks ``k . e^{G - R_r}`` and ``q .
-e^{G - R_r}`` into ONE ``[64, d_k]`` left operand against one right tile,
-``k . e^{R_r - G}`` (``A`` and ``P`` read the same columns). Right-operand
-tiles a pair of chunks: forward 16 (4 row blocks, 10 for the inverse's five
-levels, 2 for ``W`` and ``U0``), backward 14 (``dT`` 2, ``T^T dW`` and ``T^T
-dU0`` 2, ``dA`` 2, ``dX_r = dM_r C_r`` 4, ``dC_r = dM_r^T X_r`` 4 at half the
-contraction); ``R_r`` drops out of the gradient exactly. At the Ling cell's
-``[32, 16384, 128 | 128]`` the forward takes 8.93 ms a layer and call and the
-backward 7.76 in the step (9.84 / 8.55 alone, thirty-two heads a call), where
-XLA took 18.4 / 43.2: 94 % and 95 % of what the MXU's float32 rate allows
-those tiles (0.128 us each); a layer's three calls 25.8 ms where XLA's took
-61.6 (device traces, my chip runs, PR 41). The forward is equal to the oracle
-to the last bit where both are given one running sum; with its own it is
-2e-5 from it at ``G = -320`` and as far from the oracle in float64 as the
-oracle in float32 is. The scan kernels are the same two, told by ``gamma``'s
-shape to scale the state's rows: the scalar path's text is unchanged.
+* by HALVING (``_prepare_channel_xla``, ``_halved_products``; no bound
+  stated: the default). Level ``l`` owns the pairs ``(t, i)`` of an aligned
+  block of ``2^{l+1}`` tokens with ``t`` in its second half and ``i`` in its
+  first, and splits ``e^{G_t - G_i}`` BETWEEN the halves: rows ``x_t . e^{g_m +
+  .. + g_t}`` (``m`` the second half's first token), columns ``k_i . e^{g_{i+1}
+  + .. + g_{m-1}}``. Both exponents are sums of ``log_alpha`` (<= 0, whatever
+  the decay), taken inside a half by doubling, never differences of two
+  running sums: ``G_t - G_i`` from a float32 ``G`` of -12,800 (a chunk of
+  -200s) is off by 1e-3 where the true gap is -0.01, and the rule read 1.7e-5
+  from the recurrence where it now reads 1.7e-7 (tests/test_channel_decay.py's
+  ``steep`` draw, CPU, float32). Six levels at a chunk of 64, one decayed copy
+  of K each, and ``P``'s diagonal ``q_t . k_t`` beside them. ``Kd`` takes its
+  ``G_C - G_t`` as the sum of what follows ``t`` too (``_Blocks.to_end``).
+* split at a sub-block's first row (``_bounded_products``), where the caller
+  states a bound ``log_alpha_bound`` that ``carries_bound``: the chunk is cut
+  into sub-blocks of ``_SUB_CHUNK`` = 16 rows, and row block ``r`` multiplies
+  ``x_t . e^{G_t - R_r}`` (``R_r`` the ``G`` of its first token: exponent <= 0)
+  by ``k_i . e^{R_r - G_i}``, whose exponent is <= 0 for every column before the
+  block and at most ``15 |bound|`` inside it: ``e^75`` at Ling's published
+  ``kda_lower_bound`` of -5, under float32's ``e^88``. Four products a pair of
+  chunks where halving takes six of four times the rows: the bounded cell pays
+  nothing for the other's generality, and a bound too steep for it (-6) or none
+  takes the halving form. The two agree to rounding (tests/test_channel_decay.py).
+
+The preparation is a Mosaic pair of its own (``_channel_prepare_forward``,
+``_channel_prepare_backward``, under scope ``decay_prepare``, told the form by
+a static ``bounded``; ``_prepare_channel`` is the seam the timed forward takes
+its operands through), beside the scalar pair and sharing ``_Masks`` with it;
+``_prepare_channel_xla`` is the halving form in XLA, differentiated by jax: the
+oracle of both forms and the ``kernels=False`` path. What the kernels hold in
+VMEM and XLA wrote to HBM: the running sum ``G`` (taken in the kernel by
+doubling: sublane rotations and adds), the halves' sums or the rows' decay
+``e^{G_t - R_r(t)}``, the decayed copies of K, ``A``, every level of the
+inverse, and in the backward ``dT``, ``dA``, each level's or block's ``dX`` and
+``dC``. Two chunks ride one 128-row product. Bounded: row block ``r`` of both
+stacks ``k . e^{G - R_r}`` and ``q . e^{G - R_r}`` into ONE ``[64, d_k]`` left
+operand against one right tile, ``k . e^{R_r - G}`` (``A`` and ``P`` read the
+same columns); right-operand tiles a pair of chunks: forward 16 (4 row blocks,
+10 for the inverse's five levels, 2 for ``W`` and ``U0``), backward 14 (``dT``
+2, ``T^T dW`` and ``T^T dU0`` 2, ``dA`` 2, ``dX_r = dM_r C_r`` 4, ``dC_r =
+dM_r^T X_r`` 4 at half the contraction). Halving: a level stacks ``k . rows``
+and ``q . rows`` into one ``[256, d_k]`` left operand against ``k . columns``:
+forward 6 such products beside the same 12, backward 6 levels of ``dX = dM C``
+and ``dC = dM^T X`` (contraction 256) beside the same 6. In both the split
+point drops out of the gradient exactly: what reaches ``G`` is the same
+expression either way. At the Ling cell's ``[32, 16384, 128 | 128]`` the bounded
+forward takes 8.93 ms a layer and call and the backward 7.76 in the step (9.84
+/ 8.55 alone, thirty-two heads a call), where XLA took 18.4 / 43.2: 94 % and
+95 % of what the MXU's float32 rate allows those tiles (0.128 us each); a
+layer's three calls 25.8 ms where XLA's took 61.6 (device traces, my chip
+runs, PR 41); PERF.md section 6, PR 48, has the halving form's. The scan
+kernels are the same two, told by ``gamma``'s shape to scale the state's
+rows: the scalar path's text is unchanged.
 
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
@@ -298,77 +322,106 @@ def _prepare(q, k, v, log_alpha, beta, chunk: int):
     return flat(w), flat(u0), flat(qg), flat(p), flat(kd), gamma
 
 
-# Rows of one sub-block of a chunk under a decay per channel: inside it the
-# key side's exponent reaches ``(_SUB_CHUNK - 1) |g|``, 75 at the bound of -5
-# a token, under float32's 88.
+# Rows of one sub-block of a chunk in the BOUNDED form of the channel
+# preparation kernels (``_bounded_products``): inside it the key side's
+# exponent reaches ``(_SUB_CHUNK - 1) |g|``, 75 at a bound of -5 a token, under
+# float32's 88. A caller that states no such bound takes the halving form.
 _SUB_CHUNK = 16
+_EXP_LIMIT = 88.0
+
+
+def carries_bound(bound: float | None) -> bool:
+    """Whether a log-decay never below ``bound`` a token and channel lets a
+    diagonal sub-block be ONE product split at its first row (the bounded
+    form); None, or a bound too steep for it, takes the halving form."""
+    return bound is not None and (_SUB_CHUNK - 1) * -bound < _EXP_LIMIT
+
+
+def _halving_levels(chunk: int) -> int:
+    return max(chunk - 1, 1).bit_length()
 
 
 def _prepare_channel_xla(q, k, v, log_alpha, beta, chunk: int):
     """``_prepare`` under a decay per channel: the six operands of the scan
     (``gamma``: ``[heads, chunks, 1, d_k]``) from q, k, ``log_alpha``
     ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta`` ``[heads,
-    seq]``; ``seq`` a multiple of ``chunk``, ``chunk`` of ``_SUB_CHUNK``.
+    seq]``; ``seq`` a multiple of ``chunk``. ANY ``log_alpha <= 0``.
 
-    ``A`` and ``P`` are matmuls over the channels of operands that carry
-    the decay, split at a reference so that neither side overflows: row
-    block ``r`` (``_SUB_CHUNK`` tokens) takes the ``G`` of its first token,
-    ``R_r``; its rows are ``x_t . e^{G_t - R_r}`` (exponent <= 0) and its
-    columns ``k_i . e^{R_r - G_i}`` for ``i`` up to the block's end (<= 0
-    before the block, at most ``(_SUB_CHUNK - 1) |g|`` inside it), nothing
-    after. No ``[chunk, chunk, d_k]`` array exists: the columns are
-    ``chunk / _SUB_CHUNK`` decayed copies of ``K``.
+    ``A`` and ``P`` are matmuls over the channels of operands that carry the
+    decay, and every factor either side carries is ``exp`` of a SUM of
+    ``log_alpha`` (<= 0, and no difference of two running sums, which loses
+    its digits once a steep token lies before both): the lower triangle of
+    a chunk is cut by HALVING. Level ``l`` (half ``h = 2^l``) owns the pairs
+    ``(t, i)`` of one aligned block of ``2 h`` tokens with ``t`` in its
+    second half and ``i`` in its first, and splits ``e^{G_t - G_i}`` between
+    the halves: rows ``x_t . e^{g_m + .. + g_t}`` (``m`` the second half's
+    first token: the running sum inside ``t``'s own half), columns ``k_i .
+    e^{g_{i+1} + .. + g_{m-1}}`` (the sum of what follows ``i`` in its own
+    half), masked BEFORE the ``exp`` everywhere else. The levels ``h = 1 ..
+    chunk / 2`` cover every ``i < t`` once; ``i = t`` has no decay (``P``'s
+    diagonal is ``q_t . k_t``). No ``[chunk, chunk, d_k]`` array exists: a
+    level is one decayed copy of ``K``.
 
     On the chip the rule reads 1.4e-4 to 4.6e-4 of the recurrence's output
     at the Ling cell's gates, where the scalar rule reads 3e-5, and NOT
-    because of this split: sub-blocks of 8, 16 or 32 rows, and exponents
-    summed span by span in place of differences of running sums, all read
-    the same to five digits (1.379e-4 on one seed). The rows a sub-block
-    takes were chosen for time and for the bound: value and gradient of the
-    rule alone at ``[32, 16384, 128]`` take 87 ms at 16 rows, 104 at 8, 77
-    at 32 (whose ``31 x 5`` passes 88), 113 with the summed spans (my chip
-    runs, PR 36)."""
+    because of how the exponents are split: sub-blocks of 8, 16 or 32 rows
+    split at their first row, and exponents summed span by span in place of
+    differences of running sums, all read the same to five digits (1.379e-4
+    on one seed; my chip runs, PR 36); the halving kernels read 6.0e-5 to
+    2.1e-4 at Solar-Open2's unbounded gates (my chip runs, PR 48): on the
+    chip a float32 product is six bfloat16 passes, and that is the floor."""
     f32 = jnp.float32
     bh, seq, d_k = q.shape
     chunks = seq // chunk
-    sub = min(_SUB_CHUNK, chunk)
-    blocks = chunk // sub
+    levels = _halving_levels(chunk)
+    padded = 1 << levels                       # the halves are aligned: a power of two
 
     def by_chunk(x):
-        return x.astype(f32).reshape(bh, chunks, chunk, *x.shape[2:])
+        x = x.astype(f32).reshape(bh, chunks, chunk, *x.shape[2:])
+        return jnp.pad(x, ((0, 0), (0, 0), (0, padded - chunk)) + ((0, 0),) * (x.ndim - 3))
 
     q, k, v, g, beta = (by_chunk(x) for x in (q, k, v, log_alpha, beta))
-    total = jnp.cumsum(g, axis=2)                                # G_t [bh, chunks, chunk, d_k]
-    first = total.reshape(bh, chunks, blocks, sub, d_k)[:, :, :, 0]          # R_r
-    rows = jnp.exp(total - jnp.repeat(first, sub, axis=2))       # e^{G_t - R_r(t)}
-    steps = jnp.arange(chunk)
-    upto = steps[None, :] < (jnp.arange(blocks)[:, None] + 1) * sub           # [blocks, chunk]
-    # masked BEFORE the exp, as in ``_prepare``: no inf, no nan behind a where
-    exponent = jnp.where(
-        upto[:, :, None], first[:, :, :, None, :] - total[:, :, None, :, :], -jnp.inf
-    )
-    columns = jnp.exp(exponent) * k[:, :, None]                  # [bh, chunks, blocks, chunk, d_k]
+    steps = jnp.arange(padded)
+
+    def following(x, axis):
+        """The sum of what FOLLOWS each entry along ``axis`` (its own left out)."""
+        after = jnp.roll(x, -1, axis).at[(slice(None),) * axis + (-1,)].set(0.0)
+        return jnp.flip(jnp.cumsum(jnp.flip(after, axis), axis), axis)
+
+    def level(l):
+        """(the pairs level ``l`` owns, the rows' decay, the columns')."""
+        half = 1 << l
+        halves = g.reshape(bh, chunks, padded // half, half, d_k)
+        upper = ((steps & half) != 0)[:, None]
+        # masked BEFORE the exp, as in ``_prepare``: no inf, no nan behind a where
+        rows = jnp.exp(jnp.where(upper, jnp.cumsum(halves, axis=3).reshape(g.shape), -jnp.inf))
+        columns = jnp.exp(jnp.where(~upper, following(halves, 3).reshape(g.shape), -jnp.inf))
+        return (steps[:, None] >> (l + 1)) == (steps[None, :] >> (l + 1)), rows, columns
+
+    by_level = [level(l) for l in range(levels)]
 
     def decayed_products(x):
-        """``sum_c x_tc k_ic e^{G_tc - G_ic}`` ``[.., chunk, chunk]``, valid
-        for ``i`` up to the end of ``t``'s block."""
-        by_block = (x * rows).reshape(bh, chunks, blocks, sub, d_k)
-        out = jnp.einsum("...rtc,...ric->...rti", by_block, columns, precision=_PREPARE_PRECISION)
-        return out.reshape(bh, chunks, chunk, chunk)
+        """``sum_c x_tc k_ic e^{G_tc - G_ic}`` ``[.., chunk, chunk]`` for ``i < t``."""
+        return sum(
+            jnp.where(owned, jnp.einsum(
+                "...tc,...ic->...ti", x * rows, k * columns, precision=_PREPARE_PRECISION
+            ), 0.0)
+            for owned, rows, columns in by_level
+        )
 
-    strictly = steps[:, None] > steps[None, :]
-    a = jnp.where(strictly, beta[..., None] * decayed_products(k), 0.0)
+    a = beta[..., None] * decayed_products(k)
     t = _unit_lower_inverse(a)
+    total = jnp.cumsum(g, axis=2)                                # G_t [bh, chunks, chunk, d_k]
     grown = jnp.exp(total)                                       # e^{G_t}
     w = _matmul(t, beta[..., None] * grown * k)
     u0 = _matmul(t, beta[..., None] * v)
     qg = grown * q
-    p = jnp.where(~strictly.T, decayed_products(q), 0.0)         # i <= t
-    last = total[:, :, -1:, :]
-    kd = jnp.exp(last - total) * k
-    gamma = jnp.exp(last)                                        # [bh, chunks, 1, d_k]
-    flat = lambda x: x.reshape(bh, seq, x.shape[-1])
-    return flat(w), flat(u0), flat(qg), flat(p), flat(kd), gamma
+    own = jnp.sum(q * k, axis=-1)                                # i = t: no decay
+    p = decayed_products(q) + jnp.where(steps[:, None] == steps[None, :], own[..., None], 0.0)
+    kd = jnp.exp(following(g, 2)) * k                            # e^{G_C - G_t}
+    gamma = jnp.exp(total[:, :, -1:, :])                         # [bh, chunks, 1, d_k]
+    flat = lambda x: x[:, :, :chunk].reshape(bh, seq, -1)
+    return flat(w), flat(u0), flat(qg), flat(p[..., :chunk]), flat(kd), gamma
 
 
 def _scan_reference(w, u0, qg, p, kd, gamma, chunk: int, out_dtype):
@@ -929,6 +982,43 @@ class _Blocks:
             for c in range(self.together) for r in range(self.blocks)
         ], axis=0)
 
+    def to_end(self, log_alpha, total, bounded):
+        """``e^{G_C - G_t}``: under a bound the difference of the running sum
+        ``total`` as it stands; else the sum of what FOLLOWS each row in its
+        chunk, its own left out, with no difference taken."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        if bounded:
+            return jnp.exp(self.last(total) - total)
+        after = pltpu.roll(log_alpha, self.chunk * self.together - 1, 0)    # g[t + 1]
+        after = jnp.where(self.inside < self.chunk - 1, after, 0.0)
+        return jnp.exp(self.running(after, back=True))
+
+    def halves(self, log_alpha):
+        """``_prepare_channel_xla``'s levels on VMEM values: for each, ``(l,
+        the rows' decay, the columns')``, both ``[rows, d_k]``. ``inner`` is
+        each row's running sum inside its own aligned half, ``rest`` the sum
+        of what follows it there, ``whole`` the half's sum; a level doubles
+        the halves with two sublane rotations. Sums of ``log_alpha`` only:
+        every exponent is <= 0 whatever the decay, and exact."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        rows = self.chunk * self.together
+        inner, rest, whole = log_alpha, jnp.zeros_like(log_alpha), log_alpha
+        for l in range(_halving_levels(self.chunk)):
+            half = 1 << l
+            upper = (self.inside & half) != 0
+            # ... and the half after it begins inside the chunk (a chunk of 48)
+            lower = jnp.logical_not(upper) & ((self.inside | (half - 1)) + 1 < self.chunk)
+            yield (
+                l, jnp.exp(jnp.where(upper, inner, -jnp.inf)),
+                jnp.exp(jnp.where(lower, rest, -jnp.inf)),
+            )
+            before, after = pltpu.roll(whole, half, 0), pltpu.roll(whole, rows - half, 0)
+            inner = inner + jnp.where(upper, before, 0.0)
+            rest = rest + jnp.where(upper, 0.0, after)
+            whole = whole + jnp.where(upper, before, after)
+
     def at_ends(self, by_chunk):
         """``[rows, d_k]``: chunk ``c``'s ``[1, d_k]`` row at its last token,
         0 elsewhere."""
@@ -938,18 +1028,48 @@ class _Blocks:
         )
 
 
+def _bounded_products(at, q, k, total):
+    """``sum_c x_tc k_ic e^{G_tc - G_ic}`` of ``x = k`` and ``x = q``,
+    ``[rows, rows]`` each, valid inside a chunk up to the diagonal, where
+    ``log_alpha`` is BOUNDED (``carries_bound``). Row block ``r``
+    (``_SUB_CHUNK`` tokens) of every chunk of a product multiplies ``k .
+    e^{G - R_r}`` and ``q . e^{G - R_r}`` (``R_r`` the ``G`` of its first
+    token: exponent <= 0), stacked, by ONE right operand, ``k . e^{R_r - G}``
+    (``A`` and ``P`` read the same columns), whose exponent is <= 0 before
+    the block and at most ``(_SUB_CHUNK - 1) |bound|`` inside it."""
+    decay = jnp.exp(total - at.own_first(total))                 # e^{G_t - R_r(t)}
+    left = k * decay, q * decay
+    by_block = [
+        _dot(at.stacked(r, *left), k * at.columns(total, r), _NT) for r in range(at.blocks)
+    ]
+    return at.unstacked(by_block, 0), at.unstacked(by_block, 1)
+
+
+def _halved_products(at, masks, q, k, log_alpha):
+    """``_bounded_products`` for ANY ``log_alpha <= 0``, by halving
+    (``_prepare_channel_xla`` has the split): a level multiplies ``k .
+    rows`` and ``q . rows``, stacked, by ONE right operand, ``k . columns``,
+    and keeps the pairs it owns; ``P``'s diagonal is ``q_t . k_t``."""
+    a = p = 0.0
+    for l, rows, columns in at.halves(log_alpha):
+        both = _dot(jnp.concatenate([k * rows, q * rows], axis=0), k * columns, _NT)
+        owned = masks.joined(l + 1)
+        a = a + jnp.where(owned, both[:k.shape[0]], 0.0)
+        p = p + jnp.where(owned, both[k.shape[0]:], 0.0)
+    return a, jnp.where(masks.eye, _rows_sum(q * k), p)
+
+
 def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
                             w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, *inverse_ref,
-                            chunk, per_step, together):
+                            chunk, per_step, together, bounded):
     """``_prepare_channel_xla`` for ``per_step`` chunks of one head,
     ``together`` to a product. ``beta_ref``: ``[1, per_step / together, 1,
     together x chunk]``, along the lanes; the running sum ``G`` of
-    ``log_alpha`` is taken here (``_Blocks.running``). Row block ``r`` of
-    every chunk of a product multiplies ``k . e^{G - R_r}`` and ``q . e^{G -
-    R_r}``, stacked, by ONE right operand, ``k . e^{R_r - G}`` (``A`` and
-    ``P`` read the same columns); the decayed copies of K live and die here.
-    With a seventh result, ``T`` as the block-diagonal matrices it was
-    computed as."""
+    ``log_alpha`` is taken here (``_Blocks.running``). ``A / beta`` and ``P``
+    are ``_bounded_products``' where the caller stated a bound that form
+    carries, else ``_halved_products``'; the decayed copies of K live and
+    die here. With a seventh result, ``T`` as the block-diagonal matrices it
+    was computed as."""
     f32 = jnp.float32
     width = together * chunk
     masks = _Masks(chunk, together)
@@ -960,18 +1080,19 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
     several, weighed = [], []
     for s in products:
         q, k = q_ref[0, rows[s], :].astype(f32), k_ref[0, rows[s], :].astype(f32)
-        total = at.running(log_alpha_ref[0, rows[s], :].astype(f32))
+        log_alpha = log_alpha_ref[0, rows[s], :].astype(f32)
+        total = at.running(log_alpha)
         grown = jnp.exp(total)                                   # e^{G_t}
         weighed.append(betas[s] * grown * k)
-        decay = jnp.exp(total - at.own_first(total))             # e^{G_t - R_r(t)}
-        left = k * decay, q * decay
-        by_block = [
-            _dot(at.stacked(r, *left), k * at.columns(total, r), _NT) for r in range(at.blocks)
-        ]
-        several.append(jnp.where(masks.strictly, betas[s] * at.unstacked(by_block, 0), 0.0))
-        p = jnp.where(masks.upto, at.unstacked(by_block, 1), 0.0)
+        if bounded:
+            a, p = _bounded_products(at, q, k, total)
+        else:
+            a, p = _halved_products(at, masks, q, k, log_alpha)
+        left = at.to_end(log_alpha, total, bounded)
+        several.append(jnp.where(masks.strictly, betas[s] * a, 0.0))
+        p = jnp.where(masks.upto, p, 0.0)
         qg_ref[0, rows[s], :] = grown * q
-        kd_ref[0, rows[s], :] = jnp.exp(at.last(total) - total) * k
+        kd_ref[0, rows[s], :] = left * k
         for c in range(together):
             own = slice(c * chunk, (c + 1) * chunk)
             p_ref[0, s * width + c * chunk:s * width + (c + 1) * chunk, :] = p[own, own]
@@ -983,19 +1104,62 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
             inverse_ref[0][0, rows[s], :] = inverse
 
 
+def _bounded_transpose(at, q, k, beta, total, da, dp):
+    """The transpose of ``_bounded_products``: the product rule of each row
+    block's ``M_r = mask . (X_r C_r^T)`` (``X_r`` the stacked ``k . e^{G -
+    R_r}``, ``q . e^{G - R_r}``; ``C_r = k . e^{R_r - G}``): ``dX_r = dM_r
+    C_r``, ``dC_r = dM_r^T X_r``. From ``da`` (of ``A``) and ``dp``, each
+    masked to its own chunk's pairs: what reaches q and k through the rows,
+    k through the columns, ``sum_i dA_ti A_ti / beta_t`` channel by channel
+    (``dbeta``'s and, times ``beta``, ``dG``'s) and the rest of ``dG``: ``X .
+    dX - sum_r C_r . dC_r``. ``R_r`` drops out exactly (what ``X . dX`` sends
+    it, ``C_r . dC_r`` takes back under the same mask), so nothing lands on
+    a block's first token."""
+    decay = jnp.exp(total - at.own_first(total))                 # e^{G_t - R_r(t)}
+    xk, xq = k * decay, q * decay
+    right = beta * xk, xq
+    dleft, dk_columns = [], 0.0
+    for r in range(at.blocks):
+        columns = at.columns(total, r)
+        dm = at.stacked(r, da, dp)
+        dleft.append(_dot(dm, columns * k, _NN))
+        dk_columns += columns * _dot(dm, at.stacked(r, *right), _TN)
+    dxk, dxq = at.unstacked(dleft, 0), at.unstacked(dleft, 1)      # dxk: of A / beta's X
+    return decay * dxq, decay * dxk, dk_columns, xk * dxk, xq * dxq - k * dk_columns
+
+
+def _halved_transpose(at, masks, q, k, beta, log_alpha, da, dp):
+    """``_bounded_transpose`` of ``_halved_products``: level by level ``dX =
+    dM C``, ``dC = dM^T X`` over the pairs the level owns, and ``P``'s
+    diagonal. What the halves' sums send ``log_alpha`` is what the same
+    factors, written ``e^{G_t - G_m} e^{G_m - G_i}``, send ``G``: ``G_m``
+    drops out, and the caller's walk back from ``dG`` holds."""
+    rows = k.shape[0]
+    dq_rows = dk_rows = dk_columns = through = dgap = 0.0
+    for l, up, columns in at.halves(log_alpha):
+        owned = masks.joined(l + 1)
+        dm = jnp.concatenate([jnp.where(owned, da, 0.0), jnp.where(owned, dp, 0.0)], axis=0)
+        xk, xq, c = k * up, q * up, k * columns
+        dleft = _dot(dm, c, _NN)
+        dxk, dxq = dleft[:rows], dleft[rows:]                    # dxk: of A / beta's X
+        dc = _dot(dm, jnp.concatenate([beta * xk, xq], axis=0), _TN)
+        dq_rows, dk_rows = dq_rows + up * dxq, dk_rows + up * dxk
+        dk_columns = dk_columns + columns * dc
+        through = through + xk * dxk
+        dgap = dgap + xq * dxq - c * dc
+    own = _rows_sum(jnp.where(masks.eye, dp, 0.0))               # of P's diagonal, q_t . k_t
+    return dq_rows + own * k, dk_rows, dk_columns + own * q, through, dgap
+
+
 def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inverse_ref,
                              dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref, dgamma_ref,
                              dq_ref, dk_ref, dv_ref, dlog_alpha_ref, dbeta_ref,
-                             *, chunk, per_step, together):
+                             *, chunk, per_step, together, bounded):
     """The transpose of ``_channel_forward_kernel`` by hand, ``together``
     chunks at a time: ``dT`` and ``dA = -T^T dT T^T`` as
-    ``_prepare_backward_kernel``'s, then the product rule of each row block's
-    ``M_r = mask . (X_r C_r^T)`` (``X_r`` the stacked ``k . e^{G - R_r}``, ``q
-    . e^{G - R_r}``; ``C_r = k . e^{R_r - G}``): ``dX_r = dM_r C_r``, ``dC_r =
-    dM_r^T X_r``, and ``dG`` gets ``X . dX - sum_r C_r . dC_r`` channel by
-    channel. ``R_r`` drops out exactly (what ``X . dX`` sends it, ``C_r .
-    dC_r`` takes back under the same mask), so nothing lands on a block's
-    first token. What reaches ``G`` goes back through the running sum
+    ``_prepare_backward_kernel``'s, then ``_bounded_transpose`` or
+    ``_halved_transpose`` of the decayed products, and ``dG`` channel by
+    channel. What reaches ``G`` goes back through the running sum
     (``_Blocks.running`` backwards) to ``log_alpha``; ``dbeta_ref`` along the lanes."""
     f32 = jnp.float32
     width = together * chunk
@@ -1018,21 +1182,19 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inver
         # of A / beta and of P, each beside its own chunk's columns
         da = jnp.where(masks.strictly, da, 0.0)
         dp = jnp.where(masks.upto, jnp.tile(dp, (1, together)), 0.0)
-        decay = jnp.exp(total - at.own_first(total))             # e^{G_t - R_r(t)}
-        xk, xq = k * decay, q * decay
-        right = beta * xk, xq
-        dleft, dk_columns = [], 0.0
-        for r in range(at.blocks):
-            columns = at.columns(total, r)
-            dm = at.stacked(r, da, dp)
-            dleft.append(_dot(dm, columns * k, _NN))
-            dk_columns += columns * _dot(dm, at.stacked(r, *right), _TN)
-        dxk, dxq = at.unstacked(dleft, 0), at.unstacked(dleft, 1)  # dxk: of A / beta's X
-        through_beta = xk * dxk + scaled * dkb                   # d(beta) and, times beta, dG
-        left = jnp.exp(at.last(total) - total)                   # e^{G_C - G_t}
-        dq_ref[0, rows, :] = (decay * dxq + grown * dqg).astype(dq_ref.dtype)
+        if bounded:
+            dq_rows, dk_rows, dk_columns, through, dgap = _bounded_transpose(
+                at, q, k, beta, total, da, dp
+            )
+        else:
+            dq_rows, dk_rows, dk_columns, through, dgap = _halved_transpose(
+                at, masks, q, k, beta, log_alpha, da, dp
+            )
+        left = at.to_end(log_alpha, total, bounded)
+        through_beta = through + scaled * dkb                    # d(beta) and, times beta, dG
+        dq_ref[0, rows, :] = (dq_rows + grown * dqg).astype(dq_ref.dtype)
         dk_ref[0, rows, :] = (
-            beta * (decay * dxk + grown * dkb) + dk_columns + left * dkd
+            beta * (dk_rows + grown * dkb) + dk_columns + left * dkd
         ).astype(dk_ref.dtype)
         dv_ref[0, rows, :] = (beta * dvb).astype(dv_ref.dtype)
         dbeta_ref[0, s, 0:1, :] = masks.across(_rows_sum(through_beta) + _rows_sum(dvb * v))
@@ -1044,10 +1206,7 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inver
             + jnp.exp(total[(c + 1) * chunk - 1:(c + 1) * chunk]) * dgamma_ref[0, s * together + c]
             for c in range(together)
         ]
-        dtotal = (
-            beta * through_beta + xq * dxq + grown * q * dqg
-            - k * dk_columns - dkept + at.at_ends(dlast)
-        )
+        dtotal = beta * through_beta + dgap + grown * q * dqg - dkept + at.at_ends(dlast)
         dlog_alpha_ref[0, rows, :] = at.running(dtotal, back=True).astype(dlog_alpha_ref.dtype)
 
 
@@ -1065,12 +1224,14 @@ def _channel_layout(q, v, beta, chunk):
     return (bh, seq // chunk // per_step), per_step, together, in_specs
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse"))
-def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inverse=False):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse", "bounded"))
+def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inverse=False,
+                             bounded=False):
     """``_prepare_channel_xla``'s six operands from q, k, ``log_alpha``
     ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta``
     (``_beta_lanes``); with ``inverse`` a seventh, ``T`` ``[heads, seq, chunks
-    a product x chunk]``."""
+    a product x chunk]``. ``bounded``: the caller's ``log_alpha`` keeps a
+    bound that ``carries_bound``."""
     bh, seq, d_k = q.shape
     grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
     widths = (d_k, v.shape[-1], d_k, chunk, d_k) + ((beta.shape[-1],) if inverse else ())
@@ -1078,7 +1239,8 @@ def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inve
     shapes = [jax.ShapeDtypeStruct((bh, seq, width), jnp.float32) for width in widths]
     return pl.pallas_call(
         functools.partial(
-            _channel_forward_kernel, chunk=chunk, per_step=per_step, together=together
+            _channel_forward_kernel, chunk=chunk, per_step=per_step, together=together,
+            bounded=bounded,
         ),
         grid=grid,
         in_specs=in_specs,
@@ -1093,16 +1255,17 @@ def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inve
     )(q, k, v, log_alpha, beta)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "bounded"))
 def _channel_prepare_backward(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma,
-                              *, chunk, interpret):
+                              *, chunk, interpret, bounded=False):
     """``dq``, ``dk``, ``dv``, ``dlog_alpha`` in their operands' dtypes and
     ``dbeta`` in ``_beta_lanes``' layout from the scan backward's six."""
     d_k, d_v = q.shape[-1], v.shape[-1]
     grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
     return pl.pallas_call(
         functools.partial(
-            _channel_backward_kernel, chunk=chunk, per_step=per_step, together=together
+            _channel_backward_kernel, chunk=chunk, per_step=per_step, together=together,
+            bounded=bounded,
         ),
         grid=grid,
         in_specs=[
@@ -1129,14 +1292,14 @@ def _beta_lanes(beta, chunk: int):
     return beta.astype(jnp.float32).reshape(bh, seq // width, 1, width)
 
 
-def _prepare_channel(q, k, v, log_alpha, beta, chunk: int, interpret=None):
+def _prepare_channel(q, k, v, log_alpha, beta, chunk: int, interpret=None, bounded=False):
     """The six operands of the scan under a decay per channel on the
     kernels' path: ``_prepare_channel_xla``'s, from ``_channel_prepare_forward``.
     The ONE place the timed forward takes its operands from, looked up as a
     module global when ``_channel_prepare_and_scan`` is traced."""
     return _channel_prepare_forward(
         q, k, v, log_alpha, _beta_lanes(beta, chunk),
-        chunk=chunk, interpret=resolve_interpret(interpret),
+        chunk=chunk, interpret=resolve_interpret(interpret), bounded=bounded,
     )
 
 
@@ -1189,23 +1352,23 @@ def _chunked_bwd(chunk, interpret, inputs, dout):
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
-def _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret):
+def _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret, bounded):
     with jax.named_scope("decay_prepare"):
-        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk, interpret)
+        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk, interpret, bounded)
     return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
 
 
 # ``_chunked`` under a decay per channel: its own preparation pair (scope
 # ``decay_prepare``) around the same scan kernels.
-_chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5, 6))
+_chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5, 6, 7))
 
 
-def _chunked_channel_fwd(q, k, v, log_alpha, beta, chunk, interpret):
-    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret)
+def _chunked_channel_fwd(q, k, v, log_alpha, beta, chunk, interpret, bounded):
+    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret, bounded)
     return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, log_alpha, beta)
 
 
-def _chunked_channel_bwd(chunk, interpret, inputs, dout):
+def _chunked_channel_bwd(chunk, interpret, bounded, inputs, dout):
     """As ``_chunked_bwd``, nothing kept but the inputs: the preparation
     kernel runs again (and hands over ``T``), the forward kernel for the
     chunk-start states, the backward kernel, then the preparation's own."""
@@ -1213,7 +1376,8 @@ def _chunked_channel_bwd(chunk, interpret, inputs, dout):
     lanes = _beta_lanes(beta, chunk)
     with jax.named_scope("decay_prepare"):
         *operands, inverse = _channel_prepare_forward(
-            q, k, v, log_alpha, lanes, chunk=chunk, interpret=interpret, inverse=True
+            q, k, v, log_alpha, lanes, chunk=chunk, interpret=interpret, inverse=True,
+            bounded=bounded,
         )
     states = _delta_rule_forward(
         *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
@@ -1221,7 +1385,8 @@ def _chunked_channel_bwd(chunk, interpret, inputs, dout):
     grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
     with jax.named_scope("decay_prepare"):
         *grads, dlanes = _channel_prepare_backward(
-            q, k, v, log_alpha, lanes, inverse, *grads, chunk=chunk, interpret=interpret
+            q, k, v, log_alpha, lanes, inverse, *grads, chunk=chunk, interpret=interpret,
+            bounded=bounded,
         )
     return (*grads, dlanes.reshape(beta.shape).astype(beta.dtype))
 
@@ -1262,6 +1427,7 @@ def gated_delta_rule(
     chunk: int | None = None,
     interpret: bool | None = None,
     kernels: bool = True,
+    log_alpha_bound: float | None = None,
 ) -> jax.Array:
     """The gated delta rule over ``q, k [batch, heads, seq, d_k]``, ``v
     [batch, heads, seq, d_v]`` and ``log_alpha, beta [batch, heads, seq]``
@@ -1269,7 +1435,11 @@ def gated_delta_rule(
     or the sequence where that is shorter): ``[batch, heads, seq, d_v]`` in
     v's dtype, differentiable in all five. ``log_alpha [batch, heads, seq,
     d_k]`` is a decay per key channel (the module docstring has what
-    changes); it must be bounded below, ``15 |log_alpha| < 88`` a token.
+    changes), ANY ``log_alpha <= 0``: nothing the chunked form exponentiates
+    is above 0. ``log_alpha_bound`` is a caller's statement that no entry
+    lies below it; where ``carries_bound`` holds, the channel preparation
+    kernels multiply a diagonal sub-block as ONE product (a third fewer
+    tiles). It changes no value, and a scalar decay ignores it.
 
     A sequence that is no multiple of the chunk is padded at its end with
     tokens that write nothing (``beta`` 0, ``log_alpha`` 0). Heads need
@@ -1285,11 +1455,13 @@ def gated_delta_rule(
     interpret = resolve_interpret(interpret)
 
     channel = log_alpha.ndim == q.ndim
+    bounded = carries_bound(log_alpha_bound)
 
     def one_call(q, k, v, *gates):
         if kernels:
-            chunked = _chunked_channel if channel else _chunked
-            return chunked(q, k, v, *gates, chunk, interpret)
+            if channel:
+                return _chunked_channel(q, k, v, *gates, chunk, interpret, bounded)
+            return _chunked(q, k, v, *gates, chunk, interpret)
         prepare = _prepare_channel_xla if channel else _prepare
         return _scan_reference(*prepare(q, k, v, *gates, chunk), chunk, v.dtype)
 

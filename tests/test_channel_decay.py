@@ -6,14 +6,21 @@ and the plain scan's, around the two scan kernels told by ``gamma``'s shape
 to scale the state's rows) against the per-token recurrence, output and every operand's gradient, on
 the CPU in float32 with the kernels interpreted.
 
-The decays are drawn where the chunked form is hardest: AT the published
+The decays are drawn where the chunked form is hardest: AT Ling's published
 bound (-5 a token and channel for a whole chunk of 64: ``G`` reaches -320
-inside it, ``e^{-G}`` overflows float32 after 18 tokens) and near 0. The
-unsplit product ``(x . e^G)(k . e^{-G})^T`` is computed beside it and must
-FAIL there; the sub-chunked one must not. The scalar path's kernels are the
-parent's: they never touch what the channel path added.
+inside it, ``e^{-G}`` overflows float32 after 18 tokens), near 0, and with NO
+bound at all (``steep``: -0.01, -5, -30 and -200 a token and channel mixed
+inside every sub-block of 16, ``beta`` up to 2: what a split at a sub-block's
+first row overflows on, and what the halving preparation is for). The unsplit
+product ``(x . e^G)(k . e^{-G})^T`` is computed beside it and must FAIL at the
+bound; the chunked ones must not. Both forms of the preparation kernels are
+held to the same recurrence: the halving one (no bound stated: the default)
+on every draw, the bounded one (``log_alpha_bound=BOUND``) where the draw keeps
+the bound. The scalar path's kernels are the parent's: they never touch what
+the channel path added.
 """
 
+import functools
 from unittest import mock
 
 import jax
@@ -38,9 +45,12 @@ def operands(seed, batch=1, heads=2, seq=200, d_k=32, d_v=48, decays="mixed"):
     k = unit(jax.nn.silu(jax.random.normal(keys[1], (batch, heads, seq, d_k))))
     v = jax.random.normal(keys[2], (batch, heads, seq, d_v))
     g = BOUND * jax.nn.sigmoid(2.0 * jax.random.normal(keys[3], (batch, heads, seq, d_k)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, heads, seq)))
     if decays == "bound":
         g = g.at[:, :, 64:128].set(BOUND).at[:, :, 128:192].multiply(1e-3)
-    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, heads, seq)))
+    elif decays == "steep":
+        steps = jnp.array([-0.01, -5.0, -30.0, -200.0])
+        g, beta = steps[jax.random.randint(keys[3], g.shape, 0, 4)], 2.0 * beta
     return q, k, v, g, beta
 
 
@@ -50,20 +60,28 @@ def rel(got, want):
     return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
 
 
-@pytest.mark.parametrize("decays", ["mixed", "bound"])
-@pytest.mark.parametrize("kernels", [True, False], ids=["kernels", "plain-scan"])
-def test_chunks_match_the_recurrence_in_output_and_every_gradient(decays, kernels):
+@pytest.mark.parametrize("decays", ["mixed", "bound", "steep"])
+@pytest.mark.parametrize("path", ["kernels", "kernels-bounded", "plain-scan"])
+def test_chunks_match_the_recurrence_in_output_and_every_gradient(decays, path):
     args = operands(0, decays=decays)
+    kernels = path != "plain-scan"
+    # the bounded form is for a caller that states its bound; told a bound the
+    # draw does not keep (``steep``: -200), it is the caller that is wrong,
+    # and the output shows it: the halving form is what such gates need
+    bound = BOUND if path == "kernels-bounded" else None
+    rule = lambda *a: gated_delta_rule(*a, kernels=kernels, log_alpha_bound=bound)
     want = gated_delta_rule_reference(*args)
-    got = gated_delta_rule(*args, kernels=kernels)
+    got = rule(*args)
     assert got.shape == want.shape == (1, 2, 200, 48)           # 200 is no multiple of 64
+    if (decays, path) == ("steep", "kernels-bounded"):
+        assert float(jnp.min(args[3])) == -200.0 and float(jnp.max(args[4])) > 1.5
+        assert not np.all(np.isfinite(np.asarray(got)))        # e^{15 x 200}: what PR 48 lifted
+        return
     assert rel(got, want) < 5e-6
     weigh = jax.random.normal(jax.random.PRNGKey(9), want.shape)
     loss = lambda rule: lambda *a: jnp.sum(rule(*a) * weigh)
     want_grads = jax.grad(loss(gated_delta_rule_reference), argnums=(0, 1, 2, 3, 4))(*args)
-    got_grads = jax.grad(
-        loss(lambda *a: gated_delta_rule(*a, kernels=kernels)), argnums=(0, 1, 2, 3, 4)
-    )(*args)
+    got_grads = jax.grad(loss(rule), argnums=(0, 1, 2, 3, 4))(*args)
     for name, mine, theirs in zip(("q", "k", "v", "log_alpha", "beta"), got_grads, want_grads):
         assert mine.shape == theirs.shape, name
         assert rel(mine, theirs) < 2e-5, (name, rel(mine, theirs))
@@ -87,7 +105,7 @@ def _exact(x, k, total):
     )
 
 
-@pytest.mark.parametrize("prepare", ["_prepare_channel_xla", "_prepare_channel"])
+@pytest.mark.parametrize("prepare", ["_prepare_channel_xla", "_prepare_channel", "bounded"])
 def test_at_the_bound_the_unsplit_product_fails_and_the_subchunked_one_does_not(prepare):
     _, k, _, g, _ = operands(1, decays="bound")
     chunk = slice(64, 128)                                       # the chunk AT the bound
@@ -102,7 +120,12 @@ def test_at_the_bound_the_unsplit_product_fails_and_the_subchunked_one_does_not(
     # the oracle in XLA, and the kernel the timed path takes them from
     q, k4, v, g4, beta = operands(1, decays="bound")
     flat = lambda x: x.reshape(2, 200, *x.shape[3:])[:, :192]
-    prepared = getattr(gdr, prepare)(flat(q), flat(k4), flat(v), flat(g4), flat(beta), 64)
+    # ... in both its forms: by halving, and split at a sub-block's first row
+    prepare = (
+        functools.partial(gdr._prepare_channel, bounded=True) if prepare == "bounded"
+        else getattr(gdr, prepare)
+    )
+    prepared = prepare(flat(q), flat(k4), flat(v), flat(g4), flat(beta), 64)
     assert all(bool(jnp.all(jnp.isfinite(x))) for x in prepared)
     # and its products ARE the exact ones, strictly below the diagonal: A / beta
     w, u0, qg, p, kd, gamma = prepared

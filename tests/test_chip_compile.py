@@ -310,8 +310,13 @@ def test_delta_rule_preparation_kernels_compile_for_v5e(one_chip):
 CHANNEL_RULE_TEMPORARIES = int(0.5 * 2**30)
 
 
-def test_channel_decay_kernels_compile_for_v5e(one_chip):
-    """The delta rule under a decay per key CHANNEL at the Ling cell's size,
+@pytest.mark.parametrize("bound", [-5.0, None], ids=["bounded", "halving"])
+def test_channel_decay_kernels_compile_for_v5e(one_chip, bound):
+    """Both forms of the channel preparation (``log_alpha_bound=-5``: a
+    sub-block split at its first row, Ling's; None: by halving, what an
+    unbounded gate needs: PR 48), each under the same jitted names.
+
+    The delta rule under a decay per key CHANNEL at the Ling cell's size,
     ``[1, 32, 16384, 128 | 128]`` with ``log_alpha`` ``[1, 32, 16384, 128]``:
     the preparation is a Mosaic pair of its own since PR 41 (sub-blocks of 16
     rows on VMEM values, no ``[64, 64, 128]`` array and no decayed copy of K
@@ -340,19 +345,25 @@ def test_channel_decay_kernels_compile_for_v5e(one_chip):
     assert (q.shape, lanes.shape) == ((32, 16384, 128), (32, 128, 1, 128))
     two = lambda x: shape(2, *x.shape[1:], dtype=x.dtype)
     inputs = tuple(two(x) for x in (q, k, v, log_alpha, lanes))
-    forward = functools.partial(G._channel_prepare_forward, chunk=64, interpret=False, inverse=True)
+    bounded = G.carries_bound(bound)
+    assert bounded == (bound is not None)
+    forward = functools.partial(
+        G._channel_prepare_forward, chunk=64, interpret=False, inverse=True, bounded=bounded
+    )
     assert _custom_calls(forward, *inputs) == 1
     *operands, inverse = (two(x) for x in jax.eval_shape(forward, *inputs))
     assert [x.shape[1:] for x in operands] == [
         (16384, 128), (16384, 128), (16384, 128), (16384, 64), (16384, 128), (256, 1, 128),
     ]
     assert inverse.shape == (2, 16384, 128)                      # T: two chunks a product
-    backward = functools.partial(G._channel_prepare_backward, chunk=64, interpret=False)
+    backward = functools.partial(
+        G._channel_prepare_backward, chunk=64, interpret=False, bounded=bounded
+    )
     assert _custom_calls(backward, *inputs, inverse, *operands) == 1
     got = jax.eval_shape(backward, *inputs, inverse, *operands)
     assert [(x.shape, x.dtype) for x in got] == [(x.shape, x.dtype) for x in inputs]
 
-    rule = functools.partial(G.gated_delta_rule, interpret=False)
+    rule = functools.partial(G.gated_delta_rule, interpret=False, log_alpha_bound=bound)
     assert jax.eval_shape(rule, *shapes).shape == (1, 32, 16384, 128)
     text = jax.jit(rule).lower(*shapes).compile().as_text()
     assert _mosaic_calls(text) == ["_channel_prepare_forward", "_delta_rule_forward"]

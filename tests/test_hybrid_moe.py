@@ -348,10 +348,12 @@ def test_what_this_model_cannot_do_yet_is_refused_by_name(fam, params):
     post = T.dataclasses.replace(model, norm_placement="post")
     with pytest.raises(NotImplementedError, match='norm_placement="post" over a mixture-of-experts'):
         jax.eval_shape(lambda p, t: T.forward(p, t, post), params, ids())
-    with pytest.raises(NotImplementedError, match="15 .bound. < 88"):
-        T.LinearAttentionConfig(decay="channel")
-    with pytest.raises(NotImplementedError, match="15 .bound. < 88"):
-        T.LinearAttentionConfig(decay="channel", gate_lower_bound=-6.0)
+    # what PR 48 lifted: a decay per channel needs no bound, and a bound too
+    # steep for the bounded preparation (15 x 6 > 88) takes the halving one
+    from ray_tpu.ops.gated_delta_rule import carries_bound
+    assert T.LinearAttentionConfig(decay="channel").gate_lower_bound is None
+    assert T.LinearAttentionConfig(decay="channel", gate_lower_bound=-6.0).gate_lower_bound == -6.0
+    assert carries_bound(-5.0) and not carries_bound(-6.0) and not carries_bound(None)
     with pytest.raises(ValueError, match="scoring='sigmoid'"):
         T.MoEConfig(num_experts=32, n_group=4, topk_group=2)
     with pytest.raises(ValueError, match="no block of 32 experts"):
