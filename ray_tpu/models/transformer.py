@@ -134,7 +134,7 @@ from ray_tpu.ops.flash_attention import (
 )
 from ray_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as DELTA_RULE_RESIDUAL_NAMES,
-    gated_delta_rule, gated_delta_rule_reference, kept_bytes,
+    by_token, gated_delta_rule_by_token, gated_delta_rule_reference, kept_bytes, whole_lanes,
 )
 from ray_tpu.ops.grouped_matmul import TILE, grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
@@ -987,21 +987,55 @@ def _short_conv_over_mesh(config: TransformerConfig, activation="silu") -> Calla
 
 
 def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
-    """The gated delta rule of a linear layer: the per-token recurrence
-    under ``attention="reference"``, else the chunked-scan kernels, per
-    data shard."""
+    """The gated delta rule of a linear layer over TOKEN-MAJOR operands
+    (``[batch, seq, heads, .]``, as the projections write them): the
+    per-token recurrence under ``attention="reference"``, else the
+    chunked-scan kernels, per data shard, which read that layout as it
+    stands where the head widths fill whole lanes and turn it heads first
+    themselves where they do not (``gated_delta_rule_by_token`` has the
+    rule)."""
     if config.attention == "reference":
-        return gated_delta_rule_reference
+        return by_token(gated_delta_rule_reference)
     rows, gates = ("batch", None, None, None), ("batch", None, None)
     decay = rows if config.linear.decay == "channel" else gates
     operands = (rows, rows, rows, decay, gates)
     # what the gate's bound says of ``log alpha`` (None: nothing)
-    kernel = functools.partial(gated_delta_rule, log_alpha_bound=config.linear.gate_lower_bound)
+    kernel = functools.partial(
+        gated_delta_rule_by_token, log_alpha_bound=config.linear.gate_lower_bound
+    )
     return _over_mesh(kernel, operands, rows, ("tp", "sp"), ())
 
 
 # The epsilon under the square root of q's and k's L2 norm.
 _L2_EPS = 1e-6
+
+
+# Rows of a tile of 4-byte elements (narrower elements pack more rows).
+_SUBLANES = 8
+
+
+def _head_rows(x, heads: int):
+    """``x`` ``[batch, seq, heads x d]`` as ``[batch, seq / rows, heads, rows,
+    d]``, ``rows`` those of one tile of its dtype: the ``rows x d`` tiles the
+    array is stored in, one head's after the other, so a reduction over ``d``
+    and what it scales stay where they are in memory (as ``[batch, seq,
+    heads, d]`` XLA tiles heads x d, and a per-head norm costs a layout copy
+    in, a materialised broadcast and a copy out: compiles for a described
+    v5e, PR 50). Plain ``[batch, seq, heads, d]`` where a head does not fill
+    whole lanes or ``rows`` does not divide ``seq``: no such view exists."""
+    batch, seq, wide = x.shape
+    rows, d = _SUBLANES * 4 // x.dtype.itemsize, wide // heads
+    if seq % rows or not whole_lanes(d):
+        return x.reshape(batch, seq, heads, d)
+    return x.reshape(batch, seq // rows, rows, heads, d).swapaxes(2, 3)
+
+
+def _by_token(x):
+    """``_head_rows``' inverse: ``[batch, seq, heads, d]``."""
+    if x.ndim == 4:
+        return x
+    batch, blocks, heads, rows, d = x.shape
+    return x.swapaxes(2, 3).reshape(batch, blocks * rows, heads, d)
 
 
 def _linear_mixer(h, layer, config: TransformerConfig, *_):
@@ -1035,14 +1069,23 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
     The convolutions and the delta rule are Mosaic kernels (per data shard
     under a mesh) unless ``attention="reference"``, which keeps both in
     XLA: ``_short_conv`` and the per-token recurrence, the kernels'
-    oracles."""
+    oracles.
+
+    Layouts: everything here is TOKEN-MAJOR, the reshape of what a
+    projection or a convolution returns and no transpose: q, k (float32,
+    normalised), v and the rule's output ``[batch, seq, heads, d]``, a
+    channel decay ``[batch, seq, heads, d_k]`` (computed as ``[batch, seq,
+    heads x d_k]``), a scalar decay and ``beta`` ``[batch, seq, heads]``; the
+    rule is handed those and decides from the head widths what its kernels
+    read (``gated_delta_rule_by_token``). Around the two per-head norms a
+    head of whole lanes is viewed by its tiles (``_head_rows``)."""
     la = config.linear
     batch, seq, _ = h.shape
     heads = la.num_value_heads
     f32 = jnp.float32
 
     def by_head(x, width):
-        return x.reshape(batch, seq, heads, width).transpose(0, 2, 1, 3)
+        return x.reshape(batch, seq, heads, width)
 
     def gate(name, **accumulator):
         """``h W`` of a gate's projection, whole or through ``gate_rank``."""
@@ -1059,9 +1102,9 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
             k = conv(k, layer["conv_k"])
             v = conv(v, layer["conv_v"])
         with jax.named_scope("delta_rule"):
-            q, k = (by_head(x, la.key_head_dim).astype(f32) for x in (q, k))
+            q, k = (_head_rows(x.astype(f32), heads) for x in (q, k))
             unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
-            q, k = unit(q) * la.key_head_dim ** -0.5, unit(k)
+            q, k = _by_token(unit(q) * la.key_head_dim ** -0.5), _by_token(unit(k))
             beta = jax.nn.sigmoid((h @ layer["wb"]).astype(f32))
             if la.allow_neg_eigval:
                 beta = 2.0 * beta
@@ -1070,26 +1113,30 @@ def _linear_mixer(h, layer, config: TransformerConfig, *_):
                 # the projection's float32 accumulator is kept: exp(a_log) up to
                 # 16 and the bound multiply what rounding its result to the
                 # model dtype would lose into a decay off by percents
-                raw = gate("wa", preferred_element_type=f32)
-                raw = (raw + layer["dt_bias"].astype(f32)).reshape(batch, seq, heads, la.key_head_dim)
-                rate = rate[:, None]
+                # ... and the gate is computed as the projection wrote it,
+                # [batch, seq, heads x d_k] with a head's rate once a channel:
+                # as [.., heads, d_k] XLA tiles heads x d_k, a copy in and one out
+                raw = gate("wa", preferred_element_type=f32) + layer["dt_bias"].astype(f32)
+                rate = jnp.repeat(rate, la.key_head_dim)
             else:
                 raw = gate("wa").astype(f32) + layer["dt_bias"].astype(f32)
             if la.gate_lower_bound is None:
                 log_alpha = -rate * jax.nn.softplus(raw)
             else:
                 log_alpha = la.gate_lower_bound * jax.nn.sigmoid(rate * raw)
-            heads_first = (0, 2, 1, 3) if la.decay == "channel" else (0, 2, 1)
+            if la.decay == "channel":
+                log_alpha = by_head(log_alpha, la.key_head_dim)
             o = _delta_rule_over_mesh(config)(
-                q, k, by_head(v, la.value_head_dim),
-                log_alpha.transpose(heads_first), beta.transpose(0, 2, 1),
-            )
+                q, k, by_head(v, la.value_head_dim), log_alpha, beta
+            )                                                    # [batch, seq, heads, d_v]
         with jax.named_scope("gate_norm"):
-            o = o.transpose(0, 2, 1, 3)                          # [batch, seq, heads, d_v]
-            opened = gate("wg").reshape(o.shape).astype(f32)
-            y = rmsnorm_reference(o, layer["o_norm"], eps=config.rms_norm_eps)
+            # float32 before the view and the model dtype after it: the view is
+            # of float32 tiles (the norm's own rounding to ``o``'s dtype stays)
+            o, rounded = _head_rows(o.reshape(batch, seq, la.value_dim).astype(f32), heads), o.dtype
+            opened = _head_rows(gate("wg").astype(f32), heads)
+            y = rmsnorm_reference(o, layer["o_norm"], eps=config.rms_norm_eps).astype(rounded)
             act = jax.nn.silu if la.output_gate == "silu" else jax.nn.sigmoid
-            y = (y.astype(f32) * act(opened)).astype(h.dtype)
+            y = _by_token(y.astype(f32) * act(opened)).astype(h.dtype)
         return y.reshape(batch, seq, la.value_dim) @ layer["wo"]
 
 

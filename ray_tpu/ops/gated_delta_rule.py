@@ -138,6 +138,25 @@ runs, PR 41); PERF.md section 6, PR 48, has the halving form's. The scan
 kernels are the same two, told by ``gamma``'s shape to scale the state's
 rows: the scalar path's text is unchanged.
 
+Two entries, one implementation (``_rule``), the same values and gradients.
+``gated_delta_rule`` takes HEADS-FIRST arrays, ``[batch, heads, seq, .]``: a
+head's tokens together, which the six kernels read as ``[rows, seq, width]``
+blocks. ``gated_delta_rule_by_token`` takes TOKEN-MAJOR arrays, ``[batch, seq,
+heads, .]``, what a projection or a convolution writes: where BOTH head
+widths fill whole lanes (``d_k % 128 == 0 and d_v % 128 == 0``, read from the
+operands' shapes and from nothing else) the kernels read q, k, v and a
+channel decay and write the output and the gradients in that layout as it
+stands, head ``i``'s block ``(1, tokens, d)`` at ``(b, n, i)`` of ``[batch, seq,
+heads x d]`` (``_Held``); elsewhere (Olmo-Hybrid's 96 | 192: a head's block
+would begin inside a tile) it turns the arrays heads first and calls the
+other. Either way a GROUP of heads is an index that the kernels' index maps
+add (a prefetched scalar, ``_Walk.over_groups``), never a slice of the
+operands or a stack of results: the forward's calls write their rows of one
+output array, the backward's write a group's gradients where the group's
+inputs were. What the kernels hand one another (``w``, ``u0``, ``qg``, ``p``,
+``kd``, ``gamma``, ``T``, the chunk-start states, their gradients) is theirs
+alone and stays ``[rows a call, seq, .]``.
+
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
 """
@@ -145,6 +164,7 @@ On non-TPU backends the same kernels run in interpreter mode
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -557,18 +577,94 @@ def _per_step(chunks: int, chunk: int) -> int:
 
 
 def _specs(chunk, per_step, widths, index):
-    """BlockSpecs of ``[bh, seq, width]`` operands, ``per_step`` chunks a
-    grid step; ``index(i, n)`` is the step's position along the sequence."""
+    """BlockSpecs of the kernels' OWN operands, ``[rows a call, seq, width]``
+    (what one kernel writes and the next reads), ``per_step`` chunks a grid
+    step; ``index(n)`` is the step's position along the sequence."""
     return [
-        pl.BlockSpec((1, per_step * chunk, width), lambda i, n: (i, index(n), 0))
+        pl.BlockSpec((1, per_step * chunk, width), lambda i, n, _: (i, index(n), 0))
         for width in widths
     ]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "out_dtype", "states"))
-def _delta_rule_forward(w, u0, qg, p, kd, gamma, *, chunk, interpret, out_dtype, states=False):
-    """The output ``[heads, seq, d_v]`` in ``out_dtype``; with ``states``
-    the chunk-start states ``[heads, chunks, d_k, d_v]`` instead."""
+class _Held(NamedTuple):
+    """Where a call finds its ``per_call`` (batch x head) rows in the arrays
+    the CALLER holds, whole, every row of them: the index maps add the rows
+    of the groups before this one (``group``, a scalar the call prefetches),
+    so no group is sliced out of an operand or stacked into a result.
+    ``heads`` 0: ``[rows, seq, width]``, a row's tokens together (heads
+    first); else ``[batch, seq, heads x width]``, TOKEN-MAJOR, as a projection
+    writes it: row ``r``'s block is ``(1, tokens, width)`` at ``(r // heads,
+    n, r % heads)``, whole lanes where ``width`` is a multiple of 128."""
+    heads: int
+    per_call: int
+
+    def row(self, i, group):
+        return group[0] * self.per_call + i
+
+    def width(self, x) -> int:
+        return x.shape[2] // (self.heads or 1)
+
+    def specs(self, chunk, per_step, widths, index=lambda n: n):
+        """BlockSpecs of the caller's operands, ``per_step`` chunks a step."""
+        def at(i, n, group):
+            row = self.row(i, group)
+            if self.heads:
+                return row // self.heads, index(n), row % self.heads
+            return row, index(n), 0
+
+        return [pl.BlockSpec((1, per_step * chunk, width), at) for width in widths]
+
+    def lanes(self, products, kinds, width):
+        """The BlockSpec of the caller's scalar gates, ``[rows, products,
+        kinds, width]``: a row's tokens along the lanes, heads first whatever
+        ``heads`` says of the other operands."""
+        return pl.BlockSpec(
+            (1, products, kinds, width), lambda i, n, group: (self.row(i, group), n, 0, 0)
+        )
+
+
+def _grouped_call(kernel, group, operands, *, grid, in_specs, out_specs, out_shape,
+                  interpret, into=(), in_place=0, scratch_shapes=(), compiler_params=None):
+    """``pallas_call`` of ``kernel`` over ``operands`` with ``group`` (``[1]``
+    int32; None: the first) prefetched for the index maps. Results written
+    IN PLACE, so that whatever the call's blocks do not cover keeps what it
+    held: ``into``, a buffer for each of the first results (aliased to them;
+    they reach the kernel as nothing), or ``in_place``, how many of the first
+    operands the first results overwrite, block for block (their specs are
+    the results' own: a block is read before the same block is written, and
+    no other step touches it)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    taken = len(operands)
+    aliases = {1 + taken + n: n for n in range(len(into))}
+    aliases.update({1 + n: n for n in range(in_place)})
+
+    def body(_group, *refs):
+        kernel(*refs[:taken], *refs[taken + len(into):])
+
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[*in_specs, *(pl.BlockSpec(memory_space=pl.ANY) for _ in into)],
+            out_specs=out_specs, scratch_shapes=list(scratch_shapes),
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        interpret=interpret,
+        compiler_params=compiler_params,
+    )(jnp.zeros((1,), jnp.int32) if group is None else group, *operands, *into)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "interpret", "out_dtype", "states", "heads")
+)
+def _delta_rule_forward(w, u0, qg, p, kd, gamma, group=None, into=None, *, chunk, interpret,
+                        out_dtype, states=False, heads=0):
+    """The output of the call's rows in ``out_dtype``, written into ``into``
+    (the caller's array of every row, ``_Held``'s layout under ``heads``;
+    None: ``[rows a call, seq, d_v]`` of its own); with ``states`` the
+    chunk-start states ``[rows a call, chunks, d_k, d_v]`` instead."""
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq, d_k = w.shape
@@ -578,27 +674,34 @@ def _delta_rule_forward(w, u0, qg, p, kd, gamma, *, chunk, interpret, out_dtype,
     forward = lambda n: n
     kernel = functools.partial(_forward_kernel, chunk=chunk, per_step=per_step, states=states)
     if states:
-        out_spec = pl.BlockSpec((1, per_step, d_k, d_v), lambda i, n: (i, n, 0, 0))
+        out_spec = pl.BlockSpec((1, per_step, d_k, d_v), lambda i, n, _: (i, n, 0, 0))
         out_shape = jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _STATE_DTYPE)
     else:
-        (out_spec,) = _specs(chunk, per_step, (d_v,), forward)
-        out_shape = jax.ShapeDtypeStruct((bh, seq, d_v), out_dtype)
-    return pl.pallas_call(
-        kernel,
+        (out_spec,) = _Held(heads, bh).specs(chunk, per_step, (d_v,))
+        out_shape = jax.ShapeDtypeStruct(
+            (bh, seq, d_v) if into is None else into.shape, out_dtype
+        )
+    return _grouped_call(
+        kernel, group, (w, u0, qg, p, kd, gamma),
         grid=(bh, chunks // per_step),
         in_specs=[
             *_specs(chunk, per_step, (d_k, d_v, d_k, chunk, d_k), forward),
-            pl.BlockSpec((1, per_step, 1, gamma.shape[-1]), lambda i, n: (i, n, 0, 0)),
+            pl.BlockSpec((1, per_step, 1, gamma.shape[-1]), lambda i, n, _: (i, n, 0, 0)),
         ],
         out_specs=out_spec,
         out_shape=out_shape,
+        into=() if into is None else (into,),
         scratch_shapes=[pltpu.VMEM((d_k, d_v), _STATE_DTYPE)],
         interpret=interpret,
-    )(w, u0, qg, p, kd, gamma)
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, *, chunk, interpret):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "heads"))
+def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, group=None, *, chunk, interpret,
+                         heads=0):
+    """The six operands' gradients, ``[rows a call, seq, .]`` as they are,
+    from ``dout``: the caller's array of every row (``_Held``'s layout
+    under ``heads``), read where the call's rows lie."""
     from jax.experimental.pallas import tpu as pltpu
 
     bh, seq, d_k = w.shape
@@ -608,18 +711,18 @@ def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, *, chunk, interp
     steps = chunks // per_step
     backward = lambda n: steps - 1 - n
     per_chunk = lambda *tail: pl.BlockSpec(
-        (1, per_step, *tail), lambda i, n: (i, backward(n), 0, 0)
+        (1, per_step, *tail), lambda i, n, _: (i, backward(n), 0, 0)
     )
     kernel = functools.partial(_backward_kernel, chunk=chunk, per_step=per_step)
     widths = (d_k, d_v, d_k, chunk, d_k)
-    return pl.pallas_call(
-        kernel,
+    return _grouped_call(
+        kernel, group, (w, u0, qg, p, kd, gamma, states, dout),
         grid=(bh, steps),
         in_specs=[
             *_specs(chunk, per_step, widths, backward),
             per_chunk(1, gamma.shape[-1]),
             per_chunk(d_k, d_v),
-            *_specs(chunk, per_step, (d_v,), backward),
+            *_Held(heads, bh).specs(chunk, per_step, (d_v,), backward),
         ],
         out_specs=[
             *_specs(chunk, per_step, widths, backward), per_chunk(1, gamma.shape[-1]),
@@ -629,7 +732,7 @@ def _delta_rule_backward(w, u0, qg, p, kd, gamma, states, dout, *, chunk, interp
         ],
         scratch_shapes=[pltpu.VMEM((d_k, d_v), _STATE_DTYPE)],
         interpret=interpret,
-    )(w, u0, qg, p, kd, gamma, states, dout)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -838,74 +941,84 @@ def _parallel():
     return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"))
 
 
-def _prepare_layout(q, v, gates, chunk):
+def _prepare_layout(held, q, v, gates, chunk):
     """(grid, chunks a step, chunks a product, the specs of q, k, v and the
-    gates) of both preparation calls."""
-    bh, seq, d_k = q.shape
+    gates: the caller's, ``held`` says where) of both preparation calls."""
+    seq = q.shape[1]
     chunks = seq // chunk
     per_step = _per_step(chunks, chunk)
     width = gates.shape[-1]
     together = width // chunk
     in_specs = [
-        *_specs(chunk, per_step, (d_k, d_k, v.shape[-1]), lambda n: n),
-        pl.BlockSpec((1, per_step // together, 2, width), lambda i, n: (i, n, 0, 0)),
+        *held.specs(chunk, per_step, (held.width(q), held.width(q), held.width(v))),
+        held.lanes(per_step // together, 2, width),
     ]
-    return (bh, chunks // per_step), per_step, together, in_specs
+    return (held.per_call, chunks // per_step), per_step, together, in_specs
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse"))
-def _delta_prepare_forward(q, k, v, gates, *, chunk, interpret, inverse=False):
-    """``_prepare``'s six operands from q, k ``[heads, seq, d_k]``, v
-    ``[heads, seq, d_v]`` and ``gates`` (``_gates``); with ``inverse`` a
-    seventh, ``T`` ``[heads, seq, chunks a product x chunk]``."""
-    bh, seq, d_k = q.shape
-    grid, per_step, together, in_specs = _prepare_layout(q, v, gates, chunk)
-    widths = (d_k, v.shape[-1], d_k, chunk, d_k) + ((gates.shape[-1],) if inverse else ())
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse", "heads", "rows"))
+def _delta_prepare_forward(q, k, v, gates, group=None, *, chunk, interpret, inverse=False,
+                           heads=0, rows=None):
+    """``_prepare``'s six operands ``[rows, seq, .]`` of ``rows`` (None:
+    all) of the (batch x head) rows of q, k, v (``_Held``'s layout under
+    ``heads``) and ``gates`` (``_gates``), those of group ``group``; with
+    ``inverse`` a seventh, ``T`` ``[rows, seq, chunks a product x chunk]``."""
+    held = _Held(heads, rows or q.shape[0])
+    seq, d_k, d_v = q.shape[1], held.width(q), held.width(v)
+    grid, per_step, together, in_specs = _prepare_layout(held, q, v, gates, chunk)
+    widths = (d_k, d_v, d_k, chunk, d_k) + ((gates.shape[-1],) if inverse else ())
     specs = _specs(chunk, per_step, widths, lambda n: n)
-    shapes = [jax.ShapeDtypeStruct((bh, seq, width), jnp.float32) for width in widths]
-    return pl.pallas_call(
+    shapes = [jax.ShapeDtypeStruct((held.per_call, seq, width), jnp.float32) for width in widths]
+    per_chunk = pl.BlockSpec((1, per_step, 1, 1), lambda i, n, _: (i, n, 0, 0))
+    return _grouped_call(
         functools.partial(
             _prepare_forward_kernel, chunk=chunk, per_step=per_step, together=together
         ),
+        group, (q, k, v, gates),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            *specs[:5], pl.BlockSpec((1, per_step, 1, 1), lambda i, n: (i, n, 0, 0)), *specs[5:],
-        ],
+        out_specs=[*specs[:5], per_chunk, *specs[5:]],
         out_shape=[
-            *shapes[:5], jax.ShapeDtypeStruct((bh, seq // chunk, 1, 1), jnp.float32), *shapes[5:],
+            *shapes[:5],
+            jax.ShapeDtypeStruct((held.per_call, seq // chunk, 1, 1), jnp.float32),
+            *shapes[5:],
         ],
         interpret=interpret,
         compiler_params=_parallel(),
-    )(q, k, v, gates)
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "heads"))
 def _delta_prepare_backward(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma,
-                            *, chunk, interpret):
+                            group=None, *, chunk, interpret, heads=0):
     """``dq``, ``dk``, ``dv`` in their operands' dtypes and the gates'
     gradients in ``gates``' layout (of the running sum ``G``, not yet of
-    ``log_alpha``) from the scan backward's six."""
-    d_k, d_v = q.shape[-1], v.shape[-1]
-    grid, per_step, together, in_specs = _prepare_layout(q, v, gates, chunk)
-    return pl.pallas_call(
+    ``log_alpha``) from the scan backward's six: of the rows of group
+    ``group``, written where q, k, v and ``gates`` hold those rows (every
+    other row of the four results is its operand's)."""
+    held = _Held(heads, inverse.shape[0])
+    d_k, d_v = held.width(q), held.width(v)
+    grid, per_step, together, in_specs = _prepare_layout(held, q, v, gates, chunk)
+    return _grouped_call(
         functools.partial(
             _prepare_backward_kernel, chunk=chunk, per_step=per_step, together=together
         ),
+        group, (q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma),
         grid=grid,
         in_specs=[
             *in_specs,
             *_specs(chunk, per_step, (gates.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
-            pl.BlockSpec((1, per_step, 1, 1), lambda i, n: (i, n, 0, 0)),
+            pl.BlockSpec((1, per_step, 1, 1), lambda i, n, _: (i, n, 0, 0)),
         ],
         out_specs=in_specs,
         out_shape=[
             *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)),
             jax.ShapeDtypeStruct(gates.shape, jnp.float32),
         ],
+        in_place=4,
         interpret=interpret,
         compiler_params=_parallel(),
-    )(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgamma)
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1210,77 +1323,90 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inver
         dlog_alpha_ref[0, rows, :] = at.running(dtotal, back=True).astype(dlog_alpha_ref.dtype)
 
 
-def _channel_layout(q, v, beta, chunk):
+def _channel_layout(held, q, v, beta, chunk):
     """(grid, chunks a step, chunks a product, the specs of q, k, v,
-    ``log_alpha`` and ``beta``) of both channel preparation calls."""
-    bh, seq, d_k = q.shape
+    ``log_alpha`` and ``beta``: the caller's, ``held`` says where) of both
+    channel preparation calls."""
+    seq, d_k = q.shape[1], held.width(q)
     per_step = _per_step(seq // chunk, chunk)
     width = beta.shape[-1]
     together = width // chunk
     in_specs = [
-        *_specs(chunk, per_step, (d_k, d_k, v.shape[-1], d_k), lambda n: n),
-        pl.BlockSpec((1, per_step // together, 1, width), lambda i, n: (i, n, 0, 0)),
+        *held.specs(chunk, per_step, (d_k, d_k, held.width(v), d_k)),
+        held.lanes(per_step // together, 1, width),
     ]
-    return (bh, seq // chunk // per_step), per_step, together, in_specs
+    return (held.per_call, seq // chunk // per_step), per_step, together, in_specs
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse", "bounded"))
-def _channel_prepare_forward(q, k, v, log_alpha, beta, *, chunk, interpret, inverse=False,
-                             bounded=False):
-    """``_prepare_channel_xla``'s six operands from q, k, ``log_alpha``
-    ``[heads, seq, d_k]``, v ``[heads, seq, d_v]`` and ``beta``
-    (``_beta_lanes``); with ``inverse`` a seventh, ``T`` ``[heads, seq, chunks
-    a product x chunk]``. ``bounded``: the caller's ``log_alpha`` keeps a
-    bound that ``carries_bound``."""
-    bh, seq, d_k = q.shape
-    grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
-    widths = (d_k, v.shape[-1], d_k, chunk, d_k) + ((beta.shape[-1],) if inverse else ())
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "interpret", "inverse", "bounded", "heads", "rows")
+)
+def _channel_prepare_forward(q, k, v, log_alpha, beta, group=None, *, chunk, interpret,
+                             inverse=False, bounded=False, heads=0, rows=None):
+    """``_prepare_channel_xla``'s six operands ``[rows, seq, .]`` of ``rows``
+    (None: all) of the (batch x head) rows of q, k, ``log_alpha``, v
+    (``_Held``'s layout under ``heads``) and ``beta`` (``_beta_lanes``),
+    those of group ``group``; with ``inverse`` a seventh, ``T`` ``[rows, seq,
+    chunks a product x chunk]``. ``bounded``: the caller's ``log_alpha``
+    keeps a bound that ``carries_bound``."""
+    held = _Held(heads, rows or q.shape[0])
+    seq, d_k, d_v = q.shape[1], held.width(q), held.width(v)
+    grid, per_step, together, in_specs = _channel_layout(held, q, v, beta, chunk)
+    widths = (d_k, d_v, d_k, chunk, d_k) + ((beta.shape[-1],) if inverse else ())
     specs = _specs(chunk, per_step, widths, lambda n: n)
-    shapes = [jax.ShapeDtypeStruct((bh, seq, width), jnp.float32) for width in widths]
-    return pl.pallas_call(
+    shapes = [jax.ShapeDtypeStruct((held.per_call, seq, width), jnp.float32) for width in widths]
+    per_chunk = pl.BlockSpec((1, per_step, 1, d_k), lambda i, n, _: (i, n, 0, 0))
+    return _grouped_call(
         functools.partial(
             _channel_forward_kernel, chunk=chunk, per_step=per_step, together=together,
             bounded=bounded,
         ),
+        group, (q, k, v, log_alpha, beta),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            *specs[:5], pl.BlockSpec((1, per_step, 1, d_k), lambda i, n: (i, n, 0, 0)), *specs[5:],
-        ],
+        out_specs=[*specs[:5], per_chunk, *specs[5:]],
         out_shape=[
-            *shapes[:5], jax.ShapeDtypeStruct((bh, seq // chunk, 1, d_k), jnp.float32), *shapes[5:],
+            *shapes[:5],
+            jax.ShapeDtypeStruct((held.per_call, seq // chunk, 1, d_k), jnp.float32),
+            *shapes[5:],
         ],
         interpret=interpret,
         compiler_params=_parallel(),
-    )(q, k, v, log_alpha, beta)
+    )
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "bounded"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "bounded", "heads"))
 def _channel_prepare_backward(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma,
-                              *, chunk, interpret, bounded=False):
+                              group=None, *, chunk, interpret, bounded=False, heads=0):
     """``dq``, ``dk``, ``dv``, ``dlog_alpha`` in their operands' dtypes and
-    ``dbeta`` in ``_beta_lanes``' layout from the scan backward's six."""
-    d_k, d_v = q.shape[-1], v.shape[-1]
-    grid, per_step, together, in_specs = _channel_layout(q, v, beta, chunk)
-    return pl.pallas_call(
+    ``dbeta`` in ``_beta_lanes``' layout from the scan backward's six: of
+    the rows of group ``group``, written where q, k, v, ``log_alpha`` and
+    ``beta`` hold those rows (every other row of the five results is its
+    operand's)."""
+    held = _Held(heads, inverse.shape[0])
+    d_k, d_v = held.width(q), held.width(v)
+    grid, per_step, together, in_specs = _channel_layout(held, q, v, beta, chunk)
+    return _grouped_call(
         functools.partial(
             _channel_backward_kernel, chunk=chunk, per_step=per_step, together=together,
             bounded=bounded,
         ),
+        group, (q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma),
         grid=grid,
         in_specs=[
             *in_specs,
             *_specs(chunk, per_step, (beta.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
-            pl.BlockSpec((1, per_step, 1, d_k), lambda i, n: (i, n, 0, 0)),
+            pl.BlockSpec((1, per_step, 1, d_k), lambda i, n, _: (i, n, 0, 0)),
         ],
         out_specs=in_specs,
         out_shape=[
             *(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v, log_alpha)),
             jax.ShapeDtypeStruct(beta.shape, jnp.float32),
         ],
+        in_place=5,
         interpret=interpret,
         compiler_params=_parallel(),
-    )(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, dp, dkd, dgamma)
+    )
 
 
 def _beta_lanes(beta, chunk: int):
@@ -1292,14 +1418,19 @@ def _beta_lanes(beta, chunk: int):
     return beta.astype(jnp.float32).reshape(bh, seq // width, 1, width)
 
 
-def _prepare_channel(q, k, v, log_alpha, beta, chunk: int, interpret=None, bounded=False):
+def _prepare_channel(q, k, v, log_alpha, beta, chunk: int, interpret=None, bounded=False,
+                     group=None, held=None):
     """The six operands of the scan under a decay per channel on the
-    kernels' path: ``_prepare_channel_xla``'s, from ``_channel_prepare_forward``.
-    The ONE place the timed forward takes its operands from, looked up as a
-    module global when ``_channel_prepare_and_scan`` is traced."""
+    kernels' path: ``_prepare_channel_xla``'s, from ``_channel_prepare_forward``,
+    of the rows ``held`` (None: every row of ``[rows, seq, .]`` operands)
+    and ``group`` name; ``beta`` ``[rows, seq]`` of every row. The ONE place
+    the timed forward takes its operands from, looked up as a module global
+    when ``_channel_prepare_and_scan`` is traced."""
+    held = held or _Held(0, q.shape[0])
     return _channel_prepare_forward(
-        q, k, v, log_alpha, _beta_lanes(beta, chunk),
+        q, k, v, log_alpha, _beta_lanes(beta, chunk), group,
         chunk=chunk, interpret=resolve_interpret(interpret), bounded=bounded,
+        heads=held.heads, rows=held.per_call,
     )
 
 
@@ -1316,78 +1447,130 @@ def _gates(log_alpha, beta, chunk: int):
     return jnp.stack([by_product(total), by_product(beta.astype(jnp.float32))], axis=2)
 
 
-def _prepare_and_scan(q, k, v, gates, chunk, interpret):
-    operands = _delta_prepare_forward(q, k, v, gates, chunk=chunk, interpret=interpret)
-    return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
+class _Walk(NamedTuple):
+    """What is static of one rule: the chunk, whether the interpreter runs
+    the kernels, where the caller's arrays hold a row (``_Held``), how many
+    groups of ``held.per_call`` rows they hold, and what the caller stated
+    of a channel decay (``carries_bound``)."""
+    chunk: int
+    interpret: bool
+    held: _Held
+    groups: int
+    bounded: bool = False
+
+    def over_groups(self, one_group, carried):
+        """``carried = one_group(group, carried)`` group after group, ``group``
+        a ``[1]`` int32 for the index maps: the kernels' own operands, their
+        gradients and the chunk-start states live for one group at a time,
+        and what is carried (arrays of EVERY row, each call writing its rows
+        in place) is never sliced, stacked or copied."""
+        return jax.lax.fori_loop(
+            0, self.groups,
+            lambda g, carried: one_group(jnp.reshape(g, (1,)).astype(jnp.int32), carried),
+            carried,
+        )
+
+    @property
+    def static(self):
+        return dict(chunk=self.chunk, interpret=self.interpret, heads=self.held.heads)
 
 
-# Preparation and forward kernel of ``[heads, seq, .]`` operands and their
-# gates (``_gates``, which jax differentiates itself); the backward below is
-# the whole of what a gradient runs.
-_chunked = jax.custom_vjp(_prepare_and_scan, nondiff_argnums=(4, 5))
+def _unwritten(v):
+    """An array like ``v`` for the groups' forward calls to write the output
+    into, with nothing in it yet (on a TPU an allocation and no pass): every
+    block of it is written by its group's call before anything reads it."""
+    return jax.lax.empty(v.shape, v.dtype)
 
 
-def _chunked_fwd(q, k, v, gates, chunk, interpret):
-    out = _prepare_and_scan(q, k, v, gates, chunk, interpret)
+def _prepare_and_scan(q, k, v, gates, walk):
+    def one_group(group, out):
+        operands = _delta_prepare_forward(
+            q, k, v, gates, group, rows=walk.held.per_call, **walk.static
+        )
+        return _delta_rule_forward(*operands, group, out, out_dtype=v.dtype, **walk.static)
+
+    return walk.over_groups(one_group, _unwritten(v))
+
+
+# Preparation and forward kernel over every group of the caller's q, k, v
+# (``_Held``'s layout) and their gates (``_gates``, which jax differentiates
+# itself); the backward below is the whole of what a gradient runs.
+_chunked = jax.custom_vjp(_prepare_and_scan, nondiff_argnums=(4,))
+
+
+def _chunked_fwd(q, k, v, gates, walk):
+    out = _prepare_and_scan(q, k, v, gates, walk)
     return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, gates)
 
 
-def _chunked_bwd(chunk, interpret, inputs, dout):
-    """Nothing of the forward is kept but its inputs: the preparation
-    kernel runs again (and hands over ``T``), the forward kernel once more
-    for the chunk-start states, then the backward kernel and the
-    preparation's own."""
-    *operands, inverse = _delta_prepare_forward(
-        *inputs, chunk=chunk, interpret=interpret, inverse=True
-    )
-    states = _delta_rule_forward(
-        *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
-    )
-    grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
-    return tuple(
-        _delta_prepare_backward(*inputs, inverse, *grads, chunk=chunk, interpret=interpret)
-    )
+def _chunked_bwd(walk, inputs, dout):
+    """Nothing of the forward is kept but its inputs: group by group the
+    preparation kernel runs again (and hands over ``T``), the forward kernel
+    once more for the chunk-start states, then the backward kernel and the
+    preparation's own, which writes the group's rows of the four gradients
+    where the group's rows of the inputs were: ``held`` is the inputs in the
+    groups still to come and their gradients in those done (a group's rows
+    are read by that group's calls alone), so the gradients need no arrays
+    of their own and nothing to fill them."""
+    def one_group(group, held):
+        *operands, inverse = _delta_prepare_forward(
+            *held, group, rows=walk.held.per_call, inverse=True, **walk.static
+        )
+        states = _delta_rule_forward(
+            *operands, out_dtype=dout.dtype, states=True, **walk.static
+        )
+        inner = _delta_rule_backward(*operands, states, dout, group, **walk.static)
+        return tuple(_delta_prepare_backward(*held, inverse, *inner, group, **walk.static))
+
+    return walk.over_groups(one_group, tuple(inputs))
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
-def _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret, bounded):
-    with jax.named_scope("decay_prepare"):
-        operands = _prepare_channel(q, k, v, log_alpha, beta, chunk, interpret, bounded)
-    return _delta_rule_forward(*operands, chunk=chunk, interpret=interpret, out_dtype=v.dtype)
+def _channel_prepare_and_scan(q, k, v, log_alpha, beta, walk):
+    def one_group(group, out):
+        with jax.named_scope("decay_prepare"):
+            operands = _prepare_channel(
+                q, k, v, log_alpha, beta, walk.chunk, walk.interpret, walk.bounded, group,
+                walk.held,
+            )
+        return _delta_rule_forward(*operands, group, out, out_dtype=v.dtype, **walk.static)
+
+    return walk.over_groups(one_group, _unwritten(v))
 
 
 # ``_chunked`` under a decay per channel: its own preparation pair (scope
-# ``decay_prepare``) around the same scan kernels.
-_chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5, 6, 7))
+# ``decay_prepare``) around the same scan kernels; ``beta`` ``[rows, seq]``.
+_chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5,))
 
 
-def _chunked_channel_fwd(q, k, v, log_alpha, beta, chunk, interpret, bounded):
-    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, chunk, interpret, bounded)
+def _chunked_channel_fwd(q, k, v, log_alpha, beta, walk):
+    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, walk)
     return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, log_alpha, beta)
 
 
-def _chunked_channel_bwd(chunk, interpret, bounded, inputs, dout):
-    """As ``_chunked_bwd``, nothing kept but the inputs: the preparation
-    kernel runs again (and hands over ``T``), the forward kernel for the
-    chunk-start states, the backward kernel, then the preparation's own."""
-    q, k, v, log_alpha, beta = inputs
-    lanes = _beta_lanes(beta, chunk)
-    with jax.named_scope("decay_prepare"):
-        *operands, inverse = _channel_prepare_forward(
-            q, k, v, log_alpha, lanes, chunk=chunk, interpret=interpret, inverse=True,
-            bounded=bounded,
-        )
-    states = _delta_rule_forward(
-        *operands, chunk=chunk, interpret=interpret, out_dtype=dout.dtype, states=True
-    )
-    grads = _delta_rule_backward(*operands, states, dout, chunk=chunk, interpret=interpret)
-    with jax.named_scope("decay_prepare"):
-        *grads, dlanes = _channel_prepare_backward(
-            q, k, v, log_alpha, lanes, inverse, *grads, chunk=chunk, interpret=interpret,
-            bounded=bounded,
-        )
+def _chunked_channel_bwd(walk, inputs, dout):
+    """As ``_chunked_bwd``, nothing kept but the inputs: group by group the
+    preparation kernel runs again (and hands over ``T``), the forward kernel
+    for the chunk-start states, the backward kernel, then the preparation's
+    own, which writes the group's rows of the five gradients where the
+    group's rows of the inputs were (``held``, as in ``_chunked_bwd``)."""
+    *inputs, beta = inputs
+    rule = walk.static
+    prepare = dict(rule, bounded=walk.bounded)
+
+    def one_group(group, held):
+        with jax.named_scope("decay_prepare"):
+            *operands, inverse = _channel_prepare_forward(
+                *held, group, rows=walk.held.per_call, inverse=True, **prepare
+            )
+        states = _delta_rule_forward(*operands, out_dtype=dout.dtype, states=True, **rule)
+        inner = _delta_rule_backward(*operands, states, dout, group, **rule)
+        with jax.named_scope("decay_prepare"):
+            return tuple(_channel_prepare_backward(*held, inverse, *inner, group, **prepare))
+
+    *grads, dlanes = walk.over_groups(one_group, (*inputs, _beta_lanes(beta, walk.chunk)))
     return (*grads, dlanes.reshape(beta.shape).astype(beta.dtype))
 
 
@@ -1401,7 +1584,7 @@ def _heads_per_call(heads: int, seq: int) -> int:
     ``_chunked_bwd``: the six float32 operands and their gradients (2 x
     2,176 bytes as counted, 2 x 3,072 as HBM tiles them: 96 and 64 columns
     take 128 lanes, 192 take 256), ``T`` (512) and the chunk-start states
-    (1,536); the rule's temporaries alone are 1.19 GiB two heads a call,
+    (1,536); the rule's temporaries alone were 1.19 GiB two heads a call,
     1.79 six, 4.69 all thirty at once (compiles for a described v5e, PR
     33). Fewer at a time is still FASTER in the step, down to two: the
     Olmo-Hybrid cell reads 14,573 tokens/s two heads a call (``hbm_step_gib``
@@ -1409,12 +1592,62 @@ def _heads_per_call(heads: int, seq: int) -> int:
     14,446 fifteen (14.51), 14,509 thirty (14.38) (my chip runs, PR 33, one
     seed; the parent 13,988), though the rule ALONE is faster in one call
     (49.5 ms a layer against 52.9): in the step the larger temporaries
-    move what the scheduler keeps where. One call of every head also loses
+    move what the scheduler keeps where. Those are PR 33's readings, taken
+    when a group was sliced out of every operand by a ``lax.map`` and
+    stacked back; since PR 50 a group is an index (``_Walk.over_groups``)
+    and they have not been taken again. One call of every head also lost
     the kernels their jitted names in the compiled step
     (``transpose_jvp_jit__delta_rule_backward___``), which the benchmark's
     readers find them by."""
     fitting = max(_TOKENS_PER_CALL // seq, 1)
     return max(n for n in range(1, heads + 1) if heads % n == 0 and n <= fitting)
+
+
+def _rule(q, k, v, log_alpha, beta, *, heads, chunk, interpret, kernels, log_alpha_bound):
+    """The rule over every (batch x head) row of the caller's arrays:
+    ``heads`` 0, q ``[batch, heads, seq, d_k]`` (heads first); else q
+    ``[batch, seq, heads, d_k]`` (token-major), and v, a channel decay and the
+    result alike; the scalar gates follow q's first three dims."""
+    seq_axis = 1 if heads else 2
+    seq = q.shape[seq_axis]
+    rows = q.shape[0] * q.shape[3 - seq_axis]
+    chunk = chunk or _default_chunk(seq)
+    padded = -(-seq // chunk) * chunk
+    channel = log_alpha.ndim == q.ndim
+
+    def pad(x):
+        """Tokens that write nothing (``beta`` 0, ``log_alpha`` 0) at the end."""
+        widths = [(0, 0)] * x.ndim
+        widths[seq_axis] = (0, padded - seq)
+        return jnp.pad(x, widths)
+
+    def flat(x):
+        """``_Held``'s three dims."""
+        x = pad(x)
+        return x.reshape(x.shape[0], padded, -1) if heads else x.reshape(rows, padded, -1)
+
+    def by_row(x):
+        """A scalar gate ``[rows, padded]``, a row's tokens together."""
+        return (jnp.swapaxes(pad(x), 1, 2) if heads else pad(x)).reshape(rows, padded)
+
+    shape = v.shape[:seq_axis] + (padded,) + v.shape[seq_axis + 1:]
+    q, k, v = (flat(x) for x in (q, k, v))
+    log_alpha, beta = flat(log_alpha) if channel else by_row(log_alpha), by_row(beta)
+    if not kernels:
+        prepare = _prepare_channel_xla if channel else _prepare
+        out = _scan_reference(*prepare(q, k, v, log_alpha, beta, chunk), chunk, v.dtype)
+    else:
+        per_call = _heads_per_call(rows, padded)
+        walk = _Walk(
+            chunk, resolve_interpret(interpret), _Held(heads, per_call), rows // per_call,
+            channel and carries_bound(log_alpha_bound),
+        )
+        if channel:
+            out = _chunked_channel(q, k, v, log_alpha, beta, walk)
+        else:
+            # the running sums are taken once, over every head, not once a group
+            out = _chunked(q, k, v, _gates(log_alpha, beta, chunk), walk)
+    return jax.lax.slice_in_dim(out.reshape(shape), 0, seq, axis=seq_axis)
 
 
 def gated_delta_rule(
@@ -1431,55 +1664,84 @@ def gated_delta_rule(
 ) -> jax.Array:
     """The gated delta rule over ``q, k [batch, heads, seq, d_k]``, ``v
     [batch, heads, seq, d_v]`` and ``log_alpha, beta [batch, heads, seq]``
-    (``log_alpha <= 0``), as chunks of ``chunk`` tokens (None: ``CHUNK``,
-    or the sequence where that is shorter): ``[batch, heads, seq, d_v]`` in
-    v's dtype, differentiable in all five. ``log_alpha [batch, heads, seq,
-    d_k]`` is a decay per key channel (the module docstring has what
-    changes), ANY ``log_alpha <= 0``: nothing the chunked form exponentiates
-    is above 0. ``log_alpha_bound`` is a caller's statement that no entry
-    lies below it; where ``carries_bound`` holds, the channel preparation
-    kernels multiply a diagonal sub-block as ONE product (a third fewer
-    tiles). It changes no value, and a scalar decay ignores it.
+    (``log_alpha <= 0``), HEADS FIRST, as chunks of ``chunk`` tokens (None:
+    ``CHUNK``, or the sequence where that is shorter): ``[batch, heads, seq,
+    d_v]`` in v's dtype, differentiable in all five. ``log_alpha [batch,
+    heads, seq, d_k]`` is a decay per key channel (the module docstring has
+    what changes), ANY ``log_alpha <= 0``: nothing the chunked form
+    exponentiates is above 0. ``log_alpha_bound`` is a caller's statement
+    that no entry lies below it; where ``carries_bound`` holds, the channel
+    preparation kernels multiply a diagonal sub-block as ONE product (a
+    third fewer tiles). It changes no value, and a scalar decay ignores it.
 
     A sequence that is no multiple of the chunk is padded at its end with
     tokens that write nothing (``beta`` 0, ``log_alpha`` 0). Heads need
-    nothing of one another: they are walked ``_heads_per_call`` at a time
-    (``lax.map``), and a call keeps nothing for its backward but its
-    inputs, so the kernels' operands, their gradients and the chunk-start
-    states live for one group of heads at a time, forward and backward.
-    ``kernels=False`` runs the scan over chunks in plain jax.numpy (the
-    chunked form with no kernel, for tests)."""
-    batch, heads, seq, _ = q.shape
-    chunk = chunk or _default_chunk(seq)
-    padded = -(-seq // chunk) * chunk
-    interpret = resolve_interpret(interpret)
+    nothing of one another: they are walked ``_heads_per_call`` at a time,
+    a group an INDEX the kernels' index maps add (``_Walk.over_groups``,
+    ``_Held``): the six kernels read q, k, v and a channel decay and write
+    the output and the five gradients where the caller's arrays hold the
+    group's rows, and a call keeps nothing for its backward but its inputs,
+    so only the kernels' own operands, their gradients and the chunk-start
+    states exist a group at a time, forward and backward. ``kernels=False``
+    runs the scan over chunks in plain jax.numpy (the chunked form with no
+    kernel, for tests).
 
-    channel = log_alpha.ndim == q.ndim
-    bounded = carries_bound(log_alpha_bound)
-
-    def one_call(q, k, v, *gates):
-        if kernels:
-            if channel:
-                return _chunked_channel(q, k, v, *gates, chunk, interpret, bounded)
-            return _chunked(q, k, v, *gates, chunk, interpret)
-        prepare = _prepare_channel_xla if channel else _prepare
-        return _scan_reference(*prepare(q, k, v, *gates, chunk), chunk, v.dtype)
-
-    rows = batch * heads
-    per_call = _heads_per_call(rows, padded)
-
-    def flat(x):
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, padded - seq)) + ((0, 0),) * (x.ndim - 3))
-        return x.reshape(rows, padded, *x.shape[3:])
-
-    q, k, v, log_alpha, beta = (flat(x) for x in (q, k, v, log_alpha, beta))
-    # the running sums are taken once, over every head, not once a group
-    gates = (_gates(log_alpha, beta, chunk),) if kernels and not channel else (log_alpha, beta)
-    groups = tuple(
-        x.reshape(rows // per_call, per_call, *x.shape[1:]) for x in (q, k, v, *gates)
+    ``gated_delta_rule_by_token`` is the same rule over token-major arrays."""
+    return _rule(
+        q, k, v, log_alpha, beta, heads=0, chunk=chunk, interpret=interpret, kernels=kernels,
+        log_alpha_bound=log_alpha_bound,
     )
-    if per_call == rows:
-        out = one_call(*(x[0] for x in groups))
-    else:
-        out = jax.lax.map(lambda group: one_call(*group), groups)
-    return out.reshape(batch, heads, padded, v.shape[-1])[:, :, :seq]
+
+
+# Lanes of a vector register.
+_LANES = 128
+
+
+def whole_lanes(*head_widths: int) -> bool:
+    """Whether every head width fills whole lanes: only then is a head's
+    block of a token-major array (``[.., seq, heads x d]``) whole tiles, and
+    only then do the kernels read that layout (``gated_delta_rule_by_token``)."""
+    return not any(width % _LANES for width in head_widths)
+
+
+def by_token(rule):
+    """``rule`` (heads first, as ``gated_delta_rule``) over token-major
+    arrays: ``[batch, seq, heads, .]`` in, ``[batch, seq, heads, d_v]`` out,
+    each turned on its way. The price of a rule that wants a head's tokens
+    together: one pass over every operand and one over the result."""
+    heads_first = lambda x: jnp.swapaxes(x, 1, 2)
+    return lambda *operands, **how: heads_first(rule(*map(heads_first, operands), **how))
+
+
+def gated_delta_rule_by_token(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    log_alpha: jax.Array,
+    beta: jax.Array,
+    *,
+    chunk: int | None = None,
+    interpret: bool | None = None,
+    kernels: bool = True,
+    log_alpha_bound: float | None = None,
+) -> jax.Array:
+    """``gated_delta_rule`` over TOKEN-MAJOR arrays, the layout a projection
+    writes: ``q, k [batch, seq, heads, d_k]``, ``v [batch, seq, heads,
+    d_v]``, ``log_alpha, beta [batch, seq, heads]`` or ``log_alpha [batch,
+    seq, heads, d_k]``; ``[batch, seq, heads, d_v]`` in v's dtype. The same
+    values and gradients.
+
+    Which layout the kernels read is decided HERE, from the shapes alone.
+    Where both head widths fill whole lanes (``d_k`` and ``d_v`` multiples of
+    128) the arrays are read and written as they stand: seen as ``[batch,
+    seq, heads x d]``, head ``i``'s block is ``(1, tokens, d)`` at ``(b, n,
+    i)`` (``_Held``), and nothing is transposed; only the scalar gates (4
+    bytes a head and token) are turned to a head's tokens together, which
+    the kernels read along the lanes. Elsewhere (Olmo-Hybrid's 96 | 192: a
+    head's block would begin inside a tile) and with ``kernels=False`` the
+    arrays are turned heads first (``by_token``), which is what unaligned
+    heads cost until someone pads them."""
+    how = dict(chunk=chunk, interpret=interpret, kernels=kernels, log_alpha_bound=log_alpha_bound)
+    if not kernels or not whole_lanes(q.shape[-1], v.shape[-1]):
+        return by_token(gated_delta_rule)(q, k, v, log_alpha, beta, **how)
+    return _rule(q, k, v, log_alpha, beta, heads=q.shape[2], **how)

@@ -87,6 +87,46 @@ def test_chunks_match_the_recurrence_in_output_and_every_gradient(decays, path):
         assert rel(mine, theirs) < 2e-5, (name, rel(mine, theirs))
 
 
+@pytest.mark.parametrize("form", ["bounded", "halving", "plain-scan"])
+@pytest.mark.parametrize("heads,d_k,d_v", [(2, 128, 128), (4, 128, 128), (2, 96, 192), (4, 96, 192)])
+def test_token_major_operands_give_the_heads_first_rule_and_the_recurrence(
+    heads, d_k, d_v, form, monkeypatch
+):
+    """``gated_delta_rule_by_token`` on ``[batch, seq, heads, .]`` with a decay
+    per channel against the heads-first call and the recurrence on the same
+    draw. Heads of 128 | 128 fill whole lanes and the kernels read the arrays
+    as they stand; 96 | 192 are turned heads first inside the rule. Batch 2,
+    100 tokens (no multiple of 64), two (batch x head) rows a call: a group
+    of heads is an index over two groups and over four."""
+    bound = BOUND if form == "bounded" else None
+    args = operands(
+        3, batch=2, heads=heads, seq=100, d_k=d_k, d_v=d_v,
+        decays="steep" if form == "halving" else "mixed",
+    )
+    monkeypatch.setattr(gdr, "_TOKENS_PER_CALL", 2 * 128)
+    assert gdr._heads_per_call(2 * heads, 128) == 2
+    how = dict(kernels=form != "plain-scan", log_alpha_bound=bound)
+    turned = lambda x: jnp.swapaxes(x, 1, 2)
+    assert turned(args[3]).shape == (2, 100, heads, d_k)
+    by_token = lambda *a: turned(gdr.gated_delta_rule_by_token(*map(turned, a), **how))
+    heads_first = lambda *a: gated_delta_rule(*a, **how)
+    weigh = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def value_and_gradients(rule):
+        out, vjp = jax.vjp(rule, *args)
+        return (out, *vjp(weigh))
+
+    got, same, want = (
+        jax.jit(functools.partial(value_and_gradients, rule))()
+        for rule in (by_token, heads_first, gated_delta_rule_reference)
+    )
+    assert got[0].shape == (2, heads, 100, d_v)
+    for name, mine, twin, theirs in zip(("out", "q", "k", "v", "log_alpha", "beta"), got, same, want):
+        assert mine.shape == theirs.shape, name
+        assert rel(mine, twin) < 1e-7, (name, rel(mine, twin))
+        assert rel(mine, theirs) < 2e-5, (name, rel(mine, theirs))
+
+
 def _unsplit(x, k, total):
     """``sum_c x_tc k_ic e^{G_tc - G_ic}`` as ONE matmul of ``x . e^G`` and
     ``k . e^{-G}`` over a whole chunk: the form that must fail."""

@@ -129,6 +129,49 @@ def test_heads_are_walked_in_groups_and_nothing_changes(monkeypatch):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+# heads, d_k, d_v: 128 | 128 fills whole lanes, and the kernels read the
+# token-major arrays as they stand; 96 | 192 (Olmo-Hybrid's) does not, and
+# the rule turns them heads first itself
+TOKEN_MAJOR = {
+    "two_heads_of_128": (2, 128, 128),
+    "four_heads_of_128": (4, 128, 128),
+    "two_heads_of_96_192": (2, 96, 192),
+    "four_heads_of_96_192": (4, 96, 192),
+}
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["chunked", "kernels"])
+@pytest.mark.parametrize("case", list(TOKEN_MAJOR))
+def test_token_major_operands_give_the_heads_first_rule_and_the_recurrence(
+    case, kernels, monkeypatch
+):
+    """``gated_delta_rule_by_token`` on ``[batch, seq, heads, .]`` against the
+    heads-first call and the recurrence on the same draw: batch 2, 80 tokens
+    (padded to two chunks of 64), two (batch x head) rows a call, so a group
+    of heads is an index over two groups and over four."""
+    heads, d_k, d_v = TOKEN_MAJOR[case]
+    args = inputs(80, d_k, d_v, "mixed", batch=2, heads=heads, seed=5)
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    monkeypatch.setattr(G, "_TOKENS_PER_CALL", 2 * 128)
+    assert G._heads_per_call(2 * heads, 128) == 2
+    turned = lambda x: jnp.swapaxes(x, 1, 2)
+    assert turned(args[0]).shape == (2, 80, heads, d_k)
+    by_token = lambda *a: turned(G.gated_delta_rule_by_token(*map(turned, a), kernels=kernels))
+    heads_first = lambda *a: G.gated_delta_rule(*a, kernels=kernels)
+    results = [
+        output_and_gradients(rule, args, weights)
+        for rule in (by_token, heads_first, G.gated_delta_rule_reference)
+    ]
+    (got, got_grads), (same, same_grads), (want, want_grads) = results
+    assert got.shape == (2, heads, 80, d_v)
+    close(got, same, "output, heads first", tol=1e-6)
+    close(got, want, "output")
+    names = ("q", "k", "v", "log_alpha", "beta")
+    for name, g, s, w in zip(names, got_grads, same_grads, want_grads):
+        close(g, s, f"d{name}, heads first", tol=1e-6)
+        close(g, w, f"d{name}")
+
+
 def test_what_is_kept_for_the_backward_is_the_output():
     # 30 heads x 16384 tokens x d_v 192 in bfloat16; the chunk-start states
     # (566 MB a layer in float32) are made again, not kept
