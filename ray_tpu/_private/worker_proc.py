@@ -1541,13 +1541,24 @@ class WorkerRuntime:
 
 
 def main() -> None:
+    entered_ns = _time.time_ns()
     from ray_tpu._private import chaos
+    from ray_tpu._private import worker as worker_mod
 
     # Before any user code can import jax (jax reads the variable then).
     accel.place_compile_cache()
     chaos.set_identity(f"worker:{os.environ.get('RAYTPU_WORKER_ID', '')}")
     runtime = WorkerRuntime()
     runtime.start()
+    # Lifecycle span, a root: this process from the OS's start of it to
+    # its registration with the agent. What lies before it under a
+    # train.form_gang is placement, lease and spawn; what lies after,
+    # actor creation and the ping.
+    started_ns = worker_mod.process_start_ns()
+    tracing.emit(
+        "worker.boot", start_ns=started_ns, lifecycle=True,
+        imports_s=(entered_ns - started_ns) / 1e9,
+    )
     # The main thread is the normal-task execution lane (cancellation via
     # SIGINT lands here); RPC/io stay on their own threads.
     runtime.run_main_loop()

@@ -37,6 +37,7 @@ def _start_session_fn(
     slice_topology=None,
     pipeline: dict | None = None,
     trace_parent: dict | None = None,
+    leased_chips: float = 0,
 ) -> bool:
     if pipeline is not None:
         # MPMD stage assignment: gang rank r is stage r // gang_per_stage
@@ -64,7 +65,8 @@ def _start_session_fn(
         pipeline=pipeline,
     )
     session = init_session(
-        ctx, lambda: train_fn(dict(train_loop_config)), trace_parent
+        ctx, lambda: train_fn(dict(train_loop_config)), trace_parent,
+        leased_chips,
     )
     gang_ctx.state["session"] = session
     session.start()
@@ -162,6 +164,9 @@ class BackendExecutor:
                 latest_checkpoint=latest_checkpoint,
                 dataset_shards_per_rank=dataset_shards_per_rank,
                 mesh_axes=dict(sc.mesh_axes),
+                # Above 0 the session reaches its devices itself, under
+                # the lifecycle span train.reach_device.
+                leased_chips=sc.worker_resources().get("TPU", 0),
                 slice_topology=sc.slice_topology,
                 pipeline=(
                     {

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 import traceback
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
@@ -67,10 +68,14 @@ class _Session:
         ctx: TrainContext,
         fn: Callable[[], Any],
         trace_parent: dict | None = None,
+        leased_chips: float = 0,
     ):
         self.ctx = ctx
         # The driver's train.fit span: parent of this worker's train.loop.
         self._trace_parent = trace_parent
+        # The TPU chips of this worker's lease: above 0 the session
+        # reaches its devices itself, before fn (_reach_device).
+        self._leased_chips = leased_chips
         # When fn started, until the first report has emitted
         # train.first_report from it; then None.
         self._loop_start_ns: int | None = None
@@ -105,6 +110,8 @@ class _Session:
                 rank=self.ctx.world_rank,
             ) as loop:
                 self._loop_start_ns = loop.start_ns
+                if self._leased_chips > 0:
+                    _reach_device(self._leased_chips)
                 fn()
         except Exception as exc:  # surfaced via next_result poll
             exc._traceback_str = traceback.format_exc()  # type: ignore[attr-defined]
@@ -179,6 +186,36 @@ class _Session:
         return None
 
 
+def _reach_device(leased: float) -> None:
+    """A worker whose lease holds chips reaches them here, on the loop's
+    own thread before the user's function: ``import jax``, the compile
+    watcher, the first ``jax.devices()`` (backend initialisation, on a TPU
+    host the largest single part of a warm start: jax emits no monitoring
+    event for it, so the program has to own the call). One lifecycle span,
+    ``train.reach_device``; the user's first ``jax.devices()`` then finds
+    a cached backend. A worker that was leased no chip never comes here:
+    it must not import jax, let alone take a chip (accel.live_jax). What
+    is found is an attribute, never an error: a lease may lie (tests lease
+    fake chips on a CPU host), and a backend that fails to come up fails
+    again, under its own name, where the user's function asks for it."""
+    try:
+        with tracing.span(
+            "train.reach_device", lifecycle=True, leased=leased
+        ) as reach:
+            t0 = time.perf_counter()
+            import jax
+
+            reach.attributes["import_s"] = time.perf_counter() - t0
+            from ray_tpu.train import jax_utils
+
+            jax_utils._watch_compiles()
+            devices = jax.devices()
+            reach.attributes["platform"] = devices[0].platform
+            reach.attributes["devices"] = len(devices)
+    except Exception:  # rtlint: disable=swallowed-exception - recorded on the span (status error); the user's own first jax call raises it where it always did
+        pass
+
+
 _session: _Session | None = None
 
 
@@ -186,9 +223,10 @@ def init_session(
     ctx: TrainContext,
     fn: Callable[[], Any],
     trace_parent: dict | None = None,
+    leased_chips: float = 0,
 ) -> _Session:
     global _session
-    _session = _Session(ctx, fn, trace_parent)
+    _session = _Session(ctx, fn, trace_parent, leased_chips)
     return _session
 
 

@@ -113,10 +113,11 @@ def step_annotation(name: str, phase: str | None = None):
 
 # -- worker-side compile watcher ----------------------------------------
 # The jax.monitoring listeners that call these are registered where the
-# train loop first reaches jax (train/jax_utils.py::_watch_compiles):
-# this module never imports jax. A compile-or-load is one backdated
-# ``jax.compile`` span under the thread's current span, and one count in
-# the StepStats record of its interval: "which step recompiled".
+# session reaches its leased chips or the train loop first reaches jax
+# (train/jax_utils.py::_watch_compiles): this module never imports jax.
+# A compile-or-load is one backdated ``jax.compile`` span under the
+# thread's current span, and one count in the StepStats record of its
+# interval: "which step recompiled".
 _compile_lock = threading.Lock()
 _compile_acc = [0, 0.0]            # compiles, seconds since the last drain
 _cache_hit = threading.local()     # set by a compile's hit event, read by its duration event

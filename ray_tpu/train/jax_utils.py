@@ -123,13 +123,16 @@ _compiles_watched = False
 
 
 def _watch_compiles() -> None:
-    """Register the train worker's compile watcher, once a process, where
-    the loop first reaches jax through this module (never import jax for
-    telemetry's sake). jax fires the duration event around every
-    ``compile_or_get_cached``, hit or miss, with the jitted function's
-    name, and the hit event inside it on the same thread; what they
-    become is ``step_stats.note_compile``'s. A loop that compiles before
-    it calls into this module is not seen until it does."""
+    """Register the train worker's compile watcher, once a process: by
+    the session before the user's function where the worker's lease holds
+    chips (``session._reach_device``, which has to import jax there
+    anyway), else where the loop first reaches jax through this module
+    (never import jax for telemetry's sake). jax fires the duration event
+    around every ``compile_or_get_cached``, hit or miss, with the jitted
+    function's name, and the hit event inside it on the same thread; what
+    they become is ``step_stats.note_compile``'s. On a worker that was
+    leased no chip, a loop that compiles before it calls into this module
+    is not seen until it does."""
     global _compiles_watched
     if _compiles_watched:
         return

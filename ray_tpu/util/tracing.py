@@ -26,7 +26,8 @@ Span taxonomy (see docs/observability.md for the full table):
 Those are per task, per collective or per request and gated by
 ``enabled()``. A span opened with ``lifecycle=True`` is recorded whenever
 an export directory is known, enabled or not: the dozen once-a-run spans
-from ``ray_tpu.init`` to a train worker's first ``train.report`` (and its
+from a process's boot (``driver.boot``, ``worker.boot``) and
+``ray_tpu.init`` to a train worker's first ``train.report`` (and its
 ``jax.compile`` spans up to there), which say where the time to the
 first step went. After the last of them the flusher thread exits, so a
 run with tracing off pays nothing in its steady state.

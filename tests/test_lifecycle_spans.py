@@ -34,18 +34,31 @@ TREE = {
     "train.start_sessions": ("train.fit", "driver"),
     "train.first_round": ("train.fit", "driver"),
     "train.loop": ("train.fit", "worker"),
+    "train.reach_device": ("train.loop", "worker"),
     "train.setup_state": ("train.loop", "worker"),
     "train.first_report": ("train.loop", "worker"),
 }
+# Once a PROCESS, not once a run: a boot span is not in TREE. The driver's
+# is in the session of its process's first init (an earlier test file's,
+# under xdist), and every worker process of the run writes its own.
+BOOTS = {"driver.boot", "worker.boot"}
 
 
 def _loop(config):
+    import jax
     import jax.numpy as jnp
     import optax
 
     from ray_tpu import train
     from ray_tpu.train import jax_utils
 
+    def before_jax_utils(x):
+        return x * 3 + 1
+
+    entered_ns = time.time_ns()
+    # A program compiled before the loop first calls into jax_utils, as a
+    # loop's own jax.random.PRNGKey(0) is.
+    jax.jit(before_jax_utils)(jnp.ones(3)).block_until_ready()
     setup = jax_utils.setup_sharded_training(
         lambda: {"w": jnp.ones((8, 8))}, optax.sgd(0.1),
         mesh=jax_utils.build_mesh({"dp": 1}),
@@ -60,13 +73,22 @@ def _loop(config):
         if i == 3:      # a loop that changes a shape: one recompile
             rows = np.concatenate([rows, rows])
         params, opt_state, loss = step(params, opt_state, setup.shard_batch({"x": rows}))
-        train.report({"loss": float(loss)})
+        train.report({"loss": float(loss), "entered_ns": entered_ns})
+
+
+def _loop_without_a_chip(config):
+    from ray_tpu import train
+
+    train.report({"jax_imported": "jax" in sys.modules})
 
 
 @pytest.fixture(scope="module")
 def run(tmp_path_factory):
-    """One untraced one-worker ``JaxTrainer.fit`` on the CPU, after one plain
-    task: the session's spans, its timeline and rank 0's StepStats records."""
+    """One untraced one-worker ``JaxTrainer.fit`` on the CPU whose worker is
+    leased one (asserted) chip, and one plain task: the session's spans,
+    its timeline and rank 0's StepStats records. Then, beside it, a second
+    fit whose worker is leased none: what it reported and the spans it
+    added."""
     import ray_tpu.data
     from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
     from ray_tpu.util import state
@@ -74,17 +96,12 @@ def run(tmp_path_factory):
     assert not ray_tpu.is_initialized()
     os.environ.pop("RAY_TPU_tracing_enabled", None)
     global_config().tracing_enabled = False
-    ray_tpu.init(num_cpus=4)
+    ray_tpu.init(num_cpus=4, resources={"TPU": 1})
     try:
-        @ray_tpu.remote
-        def add(a, b):
-            return a + b
-
-        assert ray_tpu.get(add.remote(1, 2), timeout=60) == 3
         result = JaxTrainer(
             _loop,
             train_loop_config={"steps": STEPS},
-            scaling_config=ScalingConfig(num_workers=1),
+            scaling_config=ScalingConfig(num_workers=1, use_tpu=True),
             run_config=RunConfig(
                 name="lifecycle", storage_path=str(tmp_path_factory.mktemp("run"))
             ),
@@ -92,6 +109,15 @@ def run(tmp_path_factory):
                 np.ones((64, 8), np.float32), column="x")},
         ).fit()
         assert result.error is None
+
+        # After the fit: before it, the task's worker would idle in the
+        # agent's pool and the gang's actor be handed it, warm, with its
+        # boot behind it.
+        @ray_tpu.remote
+        def add(a, b):
+            return a + b
+
+        assert ray_tpu.get(add.remote(1, 2), timeout=60) == 3
         session_dir = os.environ["RAYTPU_SESSION_DIR"]
         deadline = time.monotonic() + 20
         records = []
@@ -100,10 +126,28 @@ def run(tmp_path_factory):
                 "train/lifecycle/rank0", "raw").get("raw") or []
             time.sleep(0.2)
         time.sleep(0.5)     # the worker's flusher tick
+        spans = tracing.read_spans(session_dir)
+        timeline = ray_tpu.timeline()
+        no_chip = JaxTrainer(
+            _loop_without_a_chip,
+            scaling_config=ScalingConfig(num_workers=1),
+            run_config=RunConfig(
+                name="no-chip", storage_path=str(tmp_path_factory.mktemp("no-chip"))
+            ),
+        ).fit()
+        assert no_chip.error is None
+        time.sleep(0.5)
+        seen = {s["span_id"] for s in spans}
         yield {
-            "spans": tracing.read_spans(session_dir),
-            "timeline": ray_tpu.timeline(),
+            "spans": spans,
+            "timeline": timeline,
             "records": records,
+            "entered_ns": result.metrics["entered_ns"],
+            "no_chip": no_chip.metrics,
+            "no_chip_spans": [
+                s for s in tracing.read_spans(session_dir)
+                if s["span_id"] not in seen
+            ],
         }
     finally:
         ray_tpu.shutdown()
@@ -116,7 +160,7 @@ def _named(run, name):
 def test_lifecycle_spans_are_recorded_untraced_and_per_task_spans_are_not(run):
     names = {s["name"] for s in run["spans"]}
     assert set(TREE) <= names
-    assert names <= set(TREE) | {"jax.compile"}, (
+    assert names <= set(TREE) | BOOTS | {"jax.compile"}, (
         "a span gated by tracing.enabled() was recorded with tracing off"
     )
 
@@ -155,6 +199,45 @@ def test_the_workers_loop_hangs_under_the_drivers_fit(run):
     assert first_report["end_ns"] <= first_round["end_ns"]
 
 
+def test_the_session_reaches_its_leased_chip_before_the_users_function(run):
+    reach = _named(run, "train.reach_device")[0]
+    loop = _named(run, "train.loop")[0]
+    assert loop["start_ns"] <= reach["start_ns"]
+    assert reach["end_ns"] <= run["entered_ns"]
+    assert reach["status"] == "ok"
+    found = reach["attributes"]
+    assert set(found) == {"import_s", "platform", "devices", "leased"}
+    assert found["platform"] == "cpu" and found["leased"] == 1
+    # a lease may lie: what is found is an attribute, not an error
+    assert found["devices"] == 8
+    assert 0 <= found["import_s"] <= (reach["end_ns"] - reach["start_ns"]) / 1e9
+    # the first report still counts from the loop's start
+    assert _named(run, "train.first_report")[0]["start_ns"] == loop["start_ns"]
+
+
+def test_a_worker_that_was_leased_no_chip_reaches_none_and_imports_no_jax(run):
+    assert run["no_chip"]["jax_imported"] is False
+    names = [s["name"] for s in run["no_chip_spans"]]
+    assert "train.loop" in names and "train.first_report" in names
+    assert "train.reach_device" not in names and "jax.compile" not in names
+
+
+def test_the_gang_workers_boot_lies_inside_the_drivers_form_gang(run):
+    worker_pid = _named(run, "train.first_report")[0]["pid"]
+    boots = [s for s in _named(run, "worker.boot") if s["pid"] == worker_pid]
+    assert len(boots) == 1
+    boot, gang = boots[0], _named(run, "train.form_gang")[0]
+    assert boot["parent_id"] is None
+    slack = 20_000_000      # the OS counts a process's start in ticks of 10 ms
+    assert gang["start_ns"] - slack <= boot["start_ns"]
+    assert boot["end_ns"] <= gang["end_ns"]
+    assert 0 < boot["attributes"]["imports_s"] < (boot["end_ns"] - boot["start_ns"]) / 1e9
+    # every worker process of the run wrote one, its own
+    pids = [s["pid"] for s in _named(run, "worker.boot")]
+    assert len(pids) == len(set(pids)) > 1
+    assert _named(run, "ray_tpu.init")[0]["pid"] not in pids
+
+
 def test_start_up_compiles_are_spans_under_the_span_that_compiled(run):
     compiles = _named(run, "jax.compile")
     assert compiles
@@ -164,6 +247,11 @@ def test_start_up_compiles_are_spans_under_the_span_that_compiled(run):
     assert {s["pid"] for s in compiles} == {loop["pid"]}
     assert {s["parent_id"] for s in compiles} <= {loop["span_id"], setup["span_id"]}
     assert any(s["parent_id"] == setup["span_id"] for s in compiles)
+    # the watcher is registered where the session reaches its chip: what the
+    # loop compiles before it enters jax_utils is seen too
+    early = [s for s in compiles if "before_jax_utils" in s["attributes"]["fun_name"]]
+    assert len(early) == 1 and early[0]["parent_id"] == loop["span_id"]
+    assert early[0]["end_ns"] <= setup["start_ns"]
     for s in compiles:
         assert s["attributes"]["cache"] in ("hit", "miss")
         assert s["attributes"]["seconds"] > 0 and s["attributes"]["fun_name"]
@@ -299,6 +387,50 @@ def test_a_program_compiled_twice_is_a_miss_then_a_hit(tmp_path):
     assert seen["count"] == 2 and seen["seconds"] > 0
     assert seen["cache"] == ["miss", "hit"]
     assert "lambda" in seen["fun"]
+
+
+_INIT_TWICE = """
+import json, os
+import psutil
+import ray_tpu
+from ray_tpu.util import tracing
+sessions = []
+for _ in range(2):
+    ray_tpu.init(num_cpus=1)
+    session = os.environ["RAYTPU_SESSION_DIR"]
+    ray_tpu.shutdown()
+    sessions.append([s for s in tracing.read_spans(session)
+                     if s["name"] in ("driver.boot", "ray_tpu.init")])
+print(json.dumps({"created": psutil.Process().create_time(), "pid": os.getpid(),
+                  "sessions": sessions}))
+"""
+
+
+def test_the_drivers_boot_is_written_once_though_init_runs_twice():
+    import json
+
+    env = dict(os.environ, PYTHONPATH=ROOT)
+    env.pop("RAY_TPU_tracing_enabled", None)
+    env.pop("RAYTPU_SESSION_DIR", None)
+    before_ns = time.time_ns()
+    done = subprocess.run(
+        [sys.executable, "-c", _INIT_TWICE],
+        env=env, capture_output=True, text=True, timeout=180,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    seen = json.loads(done.stdout.strip().splitlines()[-1])
+    first, second = seen["sessions"]
+    assert sorted(s["name"] for s in first) == ["driver.boot", "ray_tpu.init"]
+    assert [s["name"] for s in second] == ["ray_tpu.init"]
+    boot = next(s for s in first if s["name"] == "driver.boot")
+    init = next(s for s in first if s["name"] == "ray_tpu.init")
+    assert boot["pid"] == seen["pid"] and boot["parent_id"] is None
+    # from the OS's start of the process, to a tick of 10 ms ...
+    assert before_ns - 20_000_000 <= boot["start_ns"] < init["start_ns"]
+    # ... where psutil's whole-second boot time reads up to a second early
+    assert -0.02 <= boot["start_ns"] / 1e9 - seen["created"] < 1.02
+    # to the entry of the first ray_tpu.init
+    assert boot["end_ns"] == init["start_ns"]
 
 
 def test_the_flusher_is_gone_a_second_after_the_last_span(tmp_path, monkeypatch):

@@ -140,20 +140,27 @@ def test_tracing_disabled_path_is_free(untraced_cluster):
     assert ray_tpu.get(actor.m.remote(), timeout=60) == 1
 
     # Disabled means NO per-task span plumbing anywhere: no context to
-    # inject, no span objects, and nothing in the session but the driver's
-    # once-a-run lifecycle spans of ray_tpu.init (recorded with tracing
-    # off too: docs/observability.md, "Lifecycle spans").
+    # inject, no span objects, and nothing in the session but the
+    # once-a-run lifecycle spans (recorded with tracing off too:
+    # docs/observability.md, "Lifecycle spans"): the driver's of
+    # ray_tpu.init (and its boot, where this is its process's first
+    # init), and ONE of each worker process, its boot.
     assert tracing.inject() is None
     with tracing.span("nope") as s:
         assert s is None
     time.sleep(1.0)
-    assert len(glob.glob(
-        os.path.join(untraced_cluster, "tracing", "spans-*.jsonl")
-    )) == 1
-    assert {s["name"] for s in tracing.read_spans(untraced_cluster)} == {
+    spans = tracing.read_spans(untraced_cluster)
+    driver = {s["name"] for s in spans if s["pid"] == os.getpid()}
+    assert driver - {"driver.boot"} == {
         "ray_tpu.init", "init.start_controller", "init.start_agent",
         "init.connect",
     }
+    workers = [s for s in spans if s["pid"] != os.getpid()]
+    assert workers and {s["name"] for s in workers} == {"worker.boot"}
+    assert len({s["pid"] for s in workers}) == len(workers)
+    assert len(glob.glob(
+        os.path.join(untraced_cluster, "tracing", "spans-*.jsonl")
+    )) == 1 + len(workers)
 
 
 def test_read_spans_leaves_an_append_in_flight_alone(tmp_path):
