@@ -2099,10 +2099,11 @@ def _head(params, x, config: TransformerConfig):
 
 def _remat_policy(remat: str) -> Callable:
     """What the layer scan's ``jax.checkpoint`` saves under ``remat``: the
-    flash kernel's and the delta-rule scan kernel's named residuals in
-    either case (a ``pallas_call`` is no dot, so "dots" alone would run the
-    forward kernels again too), and under "dots" the matmul outputs
-    besides."""
+    flash kernel's named residuals (output and ``lse``) and the delta
+    rule's (the scan kernel's output, ``delta_rule_out``, and the chunk
+    inverse ``T`` of its preparation, ``delta_rule_inverse``) in either case
+    (a ``pallas_call`` is no dot, so "dots" alone would run the forward
+    kernels again too), and under "dots" the matmul outputs besides."""
     policies = jax.checkpoint_policies
     flash = policies.save_only_these_names(
         *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES
@@ -2348,9 +2349,11 @@ def config_num_params(config: TransformerConfig) -> int:
 
 
 def linear_state_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
-    """Bytes the delta-rule scan kernels of one training step keep for the
-    backward (chunk-start states and outputs, every linear layer): 0 for a
-    model with no linear layer."""
+    """Bytes the delta rule's kernels of one training step keep for the
+    backward, every linear layer (``kept_bytes``: the outputs and the chunk
+    inverses' diagonal blocks; the chunk-start states, four times the
+    inverses at heads of 128 | 128, are made again): 0 for a model with no
+    linear layer."""
     layers = config.linear_layers()
     if not layers:
         return 0

@@ -62,15 +62,27 @@ held to:
   ``dS`` carried (``_scan_reference`` is the same scan in plain jax.numpy).
   Within a chunk everything is a matmul for the MXU.
 
-What the backward needs of the forward: the state at every chunk's START
-(``[chunks, d_k, d_v]`` float32 a head: 566 MB a layer at 30 heads x 16384
-tokens in chunks of 64). It is NOT kept: three layers' worth cost the
-Olmo-Hybrid cell 2.75 GiB of a v5e's 15.75 and the step then needs 17.8
-(compile for a described v5e, PR 32), so the backward runs the forward
-kernel once more, for the states alone (``_chunked_bwd``). The output
-carries ``RESIDUAL_NAMES`` (checkpoint_name): a layer checkpoint whose
-policy saves that name keeps ``O`` for the layers after and runs no kernel
-for it again. ``kept_bytes`` counts what is kept.
+What the backward needs of the forward, and what of it is KEPT. The chunk
+inverse ``T``: most of a preparation call is the doubling (10 of the bounded
+forward's 16 right-operand tiles, 10 of the scalar form's 14 products), and
+``T`` is small: block-diagonal in its product, so its diagonal blocks side
+by side are all of it (``_Masks.packed``: ``[rows, seq / chunks a product,
+chunks a product x chunk]`` float32, 256 bytes a head and token at a chunk
+of 64, what the output costs at ``d_v`` 128 in bfloat16: 128 MiB a layer at
+Ling's ``[32, 16384]``). A gradient's forward (``_chunked_fwd``) writes it,
+each group's call its rows of one array; the backward's preparation call
+reads it (``inverse="read"``: the same kernels with no ``A`` for the
+inverse and no level of the doubling), and so does the preparation's
+transpose. Float32, the bits the forward computed. The state at every
+chunk's START (``[chunks, d_k, d_v]`` float32 a head: ``d_k x d_v / chunk``
+a head and token, 1,024 bytes at 128 | 128, four times ``T``; 566 MB a layer
+at 30 heads x 16384 tokens of 96 | 192) is NOT kept: three layers' worth
+cost the Olmo-Hybrid cell 2.75 GiB of a v5e's 15.75 and the step then
+needs 17.8 (compile for a described v5e, PR 32), so the backward runs the
+scan's forward kernel once more, for the states alone (``_chunked_bwd``).
+The output and ``T`` carry ``RESIDUAL_NAMES`` (checkpoint_name): a layer
+checkpoint whose policy saves those names keeps both for the layers after
+and runs no kernel of the rule again. ``kept_bytes`` counts what is kept.
 
 The channel decay in chunks, ``G`` now ``[chunk, d_k]``: the decay no longer
 factors out of the dot products, ``A[t, i] = beta_t sum_c k_tc k_ic e^{G_tc -
@@ -122,11 +134,13 @@ inverse, and in the backward ``dT``, ``dA``, each level's or block's ``dX`` and
 stacks ``k . e^{G - R_r}`` and ``q . e^{G - R_r}`` into ONE ``[64, d_k]`` left
 operand against one right tile, ``k . e^{R_r - G}`` (``A`` and ``P`` read the
 same columns); right-operand tiles a pair of chunks: forward 16 (4 row blocks,
-10 for the inverse's five levels, 2 for ``W`` and ``U0``), backward 14 (``dT``
+10 for the inverse's five levels, 2 for ``W`` and ``U0``; 6 where ``T`` is
+read), backward 14 (``dT``
 2, ``T^T dW`` and ``T^T dU0`` 2, ``dA`` 2, ``dX_r = dM_r C_r`` 4, ``dC_r =
 dM_r^T X_r`` 4 at half the contraction). Halving: a level stacks ``k . rows``
 and ``q . rows`` into one ``[256, d_k]`` left operand against ``k . columns``:
-forward 6 such products beside the same 12, backward 6 levels of ``dX = dM C``
+forward 6 such products beside the same 12 (beside 2 where ``T`` is read: the
+stacks stay whole there, ``A``'s half with no reader), backward 6 levels of ``dX = dM C``
 and ``dC = dM^T X`` (contraction 256) beside the same 6. In both the split
 point drops out of the gradient exactly: what reaches ``G`` is the same
 expression either way. At the Ling cell's ``[32, 16384, 128 | 128]`` the bounded
@@ -154,8 +168,9 @@ add (a prefetched scalar, ``_Walk.over_groups``), never a slice of the
 operands or a stack of results: the forward's calls write their rows of one
 output array, the backward's write a group's gradients where the group's
 inputs were. What the kernels hand one another (``w``, ``u0``, ``qg``, ``p``,
-``kd``, ``gamma``, ``T``, the chunk-start states, their gradients) is theirs
-alone and stays ``[rows a call, seq, .]``.
+``kd``, ``gamma``, the chunk-start states, their gradients) is theirs alone
+and stays ``[rows a call, seq, .]``; ``T`` is the rule's own array of every
+row, heads first in either layout.
 
 On non-TPU backends the same kernels run in interpreter mode
 (ops.resolve_interpret), so tests exercise the code the TPU compiles.
@@ -173,9 +188,10 @@ from jax.experimental import pallas as pl
 
 from ray_tpu.ops import resolve_interpret
 
-# The name the forward kernel's output carries
-# (models/transformer.py::_remat_policy keeps it).
-RESIDUAL_NAMES = ("delta_rule_out",)
+# The names the forward's output and the chunk inverse ``T`` (its diagonal
+# blocks, ``_Masks.packed``) carry (models/transformer.py::_remat_policy
+# keeps both).
+RESIDUAL_NAMES = ("delta_rule_out", "delta_rule_inverse")
 
 # Tokens a chunk; a shorter sequence is one chunk, padded to a multiple of
 # ``_CHUNK_MULTIPLE`` (whole sublane tiles in either dtype).
@@ -238,10 +254,14 @@ def _default_chunk(seq: int) -> int:
 def kept_bytes(batch: int, heads: int, seq: int, d_v: int, itemsize: int,
                chunk: int | None = None) -> int:
     """Bytes one call keeps under ``RESIDUAL_NAMES`` from its forward to
-    its backward: the output in the values' dtype (the chunk-start states,
-    ``heads x chunks x d_k x d_v`` float32, are made again)."""
+    its backward: the output in the values' dtype and ``T``'s diagonal
+    blocks, ``chunk`` float32 a head and token (``kept_inverse_shape``). The
+    chunk-start states, ``heads x chunks x d_k x d_v`` float32 (``d_k x d_v
+    / chunk`` a head and token: 1,024 at 128 | 128 where ``T`` is 64), are
+    made again."""
     chunk = chunk or _default_chunk(seq)
-    return batch * heads * -(-seq // chunk) * chunk * d_v * itemsize
+    tokens = batch * heads * -(-seq // chunk) * chunk
+    return tokens * (d_v * itemsize + chunk * jnp.dtype(_STATE_DTYPE).itemsize)
 
 
 # ---------------------------------------------------------------------------
@@ -628,7 +648,7 @@ def _grouped_call(kernel, group, operands, *, grid, in_specs, out_specs, out_sha
     """``pallas_call`` of ``kernel`` over ``operands`` with ``group`` (``[1]``
     int32; None: the first) prefetched for the index maps. Results written
     IN PLACE, so that whatever the call's blocks do not cover keeps what it
-    held: ``into``, a buffer for each of the first results (aliased to them;
+    held: ``into``, a buffer for each of the LAST results (aliased to them;
     they reach the kernel as nothing), or ``in_place``, how many of the first
     operands the first results overwrite, block for block (their specs are
     the results' own: a block is read before the same block is written, and
@@ -636,7 +656,8 @@ def _grouped_call(kernel, group, operands, *, grid, in_specs, out_specs, out_sha
     from jax.experimental.pallas import tpu as pltpu
 
     taken = len(operands)
-    aliases = {1 + taken + n: n for n in range(len(into))}
+    first = len(jax.tree.leaves(out_shape)) - len(into)
+    aliases = {1 + taken + n: first + n for n in range(len(into))}
     aliases.update({1 + n: n for n in range(in_place)})
 
     def body(_group, *refs):
@@ -773,7 +794,7 @@ class _Masks:
 
     def __init__(self, chunk, together):
         rows = chunk * together
-        self.chunk = chunk
+        self.chunk, self.together = chunk, together
 
         def inside(shape, dim):
             """Each position's chunk and its place inside that chunk."""
@@ -807,6 +828,26 @@ class _Masks:
         """Entries inside one diagonal block of ``2 ** shift``."""
         return self.same & ((self.t >> shift) == (self.i >> shift))
 
+    def _of(self, c, x):
+        return x[c * self.chunk:(c + 1) * self.chunk]
+
+    def packed(self, inverse):
+        """The diagonal blocks of a block-diagonal ``[rows, rows]`` value side
+        by side, ``[chunk, rows]``: chunk ``c``'s block in chunk ``c``'s
+        lanes, selected (nothing added: the same bits). Everything else of
+        ``inverse`` is an exact zero, so this is all of it, in a
+        ``together``-th of the bytes: how ``T`` is kept."""
+        blocks = self._of(0, inverse)
+        for c in range(1, self.together):
+            blocks = jnp.where(self.lane_of == c, self._of(c, inverse), blocks)
+        return blocks
+
+    def unpacked(self, blocks):
+        """``packed``'s value back as the block-diagonal ``[rows, rows]``."""
+        return jnp.concatenate(
+            [jnp.where(self.lane_of == c, blocks, 0.0) for c in range(self.together)], axis=0
+        )
+
     def inverses(self, several):
         """``_unit_lower_inverse``'s doubling on VMEM values: block pairs,
         ``T_2b = T_b - T_b a_b T_b``, two products a level, for SEVERAL
@@ -837,39 +878,58 @@ class _Span:
     ``exp``, as in ``_prepare``; between two chunks it is 0."""
 
     def __init__(self, masks, total_row, beta_row):
-        self.total_row = total_row
+        self.masks, self.total_row = masks, total_row
         self.total, self.beta = masks.down(total_row), masks.down(beta_row)
-        gap = self.total - total_row                              # G_t - G_i
-        decay = lambda mask: jnp.exp(jnp.where(mask, gap, -jnp.inf))
-        self.strictly, self.upto = decay(masks.strictly), decay(masks.upto)
+        self.gap = self.total - total_row                         # G_t - G_i
+        self.upto = self.decay(masks.upto)
         self.grown = jnp.exp(self.total)                          # e^{G_t}
         # G_C of each row's own chunk, down the rows
         last = _rows_sum(jnp.where(masks.same & masks.ends, total_row, 0.0))
         self.left = jnp.exp(last - self.total)                    # e^{G_C - G_t}
 
+    def decay(self, mask):
+        return jnp.exp(jnp.where(mask, self.gap, -jnp.inf))
 
-def _prepare_forward_kernel(q_ref, k_ref, v_ref, gates_ref,
-                            w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, *inverse_ref,
-                            chunk, per_step, together):
+    @functools.cached_property
+    def strictly(self):
+        """``A``'s decay, taken where it is read: where ``T`` is given it is not."""
+        return self.decay(self.masks.strictly)
+
+
+def _kept_rows(s, chunk):
+    """Product ``s``'s rows of a grid step's block of the kept ``T``
+    (``_Masks.packed``: ``chunk`` rows a product)."""
+    return slice(s * chunk, (s + 1) * chunk)
+
+
+def _prepare_forward_kernel(*refs, chunk, per_step, together, inverse=None):
     """``_prepare`` for ``per_step`` chunks of one head, ``together`` to a
     product; ``gates_ref``: ``[1, per_step / together, 2, together x
-    chunk]``, the running sum ``G`` and ``beta`` along the lanes. With a
-    seventh result, ``T`` itself as the block-diagonal matrices it was
-    computed as (the backward's)."""
+    chunk]``, the running sum ``G`` and ``beta`` along the lanes.
+    ``inverse``: "write", a seventh result, ``T``'s diagonal blocks
+    (``_Masks.packed``; the forward of a gradient keeps it); "read", a fifth
+    operand, that array: ``T`` is unpacked from it, ``A`` is not formed and
+    no level of the doubling is multiplied (the backward's call)."""
     f32 = jnp.float32
+    q_ref, k_ref, v_ref, gates_ref, *refs = refs
+    kept_ref = refs.pop(0 if inverse == "read" else -1) if inverse else None
+    w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref = refs
     width = together * chunk
     masks = _Masks(chunk, together)
     products = range(per_step // together)
     rows = [slice(s * width, (s + 1) * width) for s in products]
     spans = [_Span(masks, gates_ref[0, s, 0:1, :], gates_ref[0, s, 1:2, :]) for s in products]
     keys = [k_ref[0, rows[s], :].astype(f32) for s in products]
-    inverses = masks.inverses(
-        [at.beta * at.strictly * _dot(k, k, _NT) for at, k in zip(spans, keys)]
-    )
-    for s, at, k, inverse in zip(products, spans, keys, inverses):
+    if inverse == "read":
+        inverses = [masks.unpacked(kept_ref[0, _kept_rows(s, chunk), :]) for s in products]
+    else:
+        inverses = masks.inverses(
+            [at.beta * at.strictly * _dot(k, k, _NT) for at, k in zip(spans, keys)]
+        )
+    for s, at, k, t in zip(products, spans, keys, inverses):
         q, v = q_ref[0, rows[s], :].astype(f32), v_ref[0, rows[s], :].astype(f32)
-        w_ref[0, rows[s], :] = _dot(inverse, at.beta * at.grown * k, _NN)
-        u0_ref[0, rows[s], :] = _dot(inverse, at.beta * v, _NN)
+        w_ref[0, rows[s], :] = _dot(t, at.beta * at.grown * k, _NN)
+        u0_ref[0, rows[s], :] = _dot(t, at.beta * v, _NN)
         qg_ref[0, rows[s], :] = at.grown * q
         kd_ref[0, rows[s], :] = at.left * k
         p = at.upto * _dot(q, k, _NT)
@@ -877,8 +937,8 @@ def _prepare_forward_kernel(q_ref, k_ref, v_ref, gates_ref,
             own = slice(c * chunk, (c + 1) * chunk)
             p_ref[0, s * width + c * chunk:s * width + (c + 1) * chunk, :] = p[own, own]
             gamma_ref[0, s * together + c] = jnp.exp(masks.of_chunk(c, at.total_row))
-        if inverse_ref:
-            inverse_ref[0][0, rows[s], :] = inverse
+        if inverse == "write":
+            kept_ref[0, _kept_rows(s, chunk), :] = masks.packed(t)
 
 
 def _prepare_backward_kernel(q_ref, k_ref, v_ref, gates_ref, inverse_ref,
@@ -887,7 +947,7 @@ def _prepare_backward_kernel(q_ref, k_ref, v_ref, gates_ref, inverse_ref,
     """The transpose of ``_prepare_forward_kernel`` by hand, ``together``
     chunks at a time: ``dT = dW (beta e^G K)^T + dU0 (beta V)^T``, ``dA =
     -T^T dT T^T`` (``_unit_lower_inverse_bwd``'s identity; ``T`` is read,
-    the forward call of the same backward wrote it), then the product rules
+    as the forward kept it: ``_Masks.packed``), then the product rules
     of ``A = beta . decay . K K^T`` and ``P = decay . Q K^T``: the gap ``G_t
     - G_i`` gets ``dA . A + dP . P``, whose row sums less column sums are
     ``dG``. ``dgates_ref``: ``dG`` and ``dbeta`` along the lanes."""
@@ -896,10 +956,11 @@ def _prepare_backward_kernel(q_ref, k_ref, v_ref, gates_ref, inverse_ref,
     masks = _Masks(chunk, together)
     for s in range(per_step // together):
         rows = slice(s * width, (s + 1) * width)
-        q, k, v, inverse, dw, du0, dqg, dp, dkd = (
+        q, k, v, dw, du0, dqg, dp, dkd = (
             ref[0, rows, :].astype(f32) for ref in
-            (q_ref, k_ref, v_ref, inverse_ref, dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref)
+            (q_ref, k_ref, v_ref, dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref)
         )
+        inverse = masks.unpacked(inverse_ref[0, _kept_rows(s, chunk), :])
         at = _Span(masks, gates_ref[0, s, 0:1, :], gates_ref[0, s, 1:2, :])
         kk, qk = _dot(k, k, _NT), _dot(q, k, _NT)
         dinverse = _dot(dw, at.beta * at.grown * k, _NT) + _dot(du0, at.beta * v, _NT)
@@ -956,35 +1017,79 @@ def _prepare_layout(held, q, v, gates, chunk):
     return (held.per_call, chunks // per_step), per_step, together, in_specs
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse", "heads", "rows"))
-def _delta_prepare_forward(q, k, v, gates, group=None, *, chunk, interpret, inverse=False,
-                           heads=0, rows=None):
-    """``_prepare``'s six operands ``[rows, seq, .]`` of ``rows`` (None:
-    all) of the (batch x head) rows of q, k, v (``_Held``'s layout under
-    ``heads``) and ``gates`` (``_gates``), those of group ``group``; with
-    ``inverse`` a seventh, ``T`` ``[rows, seq, chunks a product x chunk]``."""
-    held = _Held(heads, rows or q.shape[0])
-    seq, d_k, d_v = q.shape[1], held.width(q), held.width(v)
-    grid, per_step, together, in_specs = _prepare_layout(held, q, v, gates, chunk)
-    widths = (d_k, d_v, d_k, chunk, d_k) + ((gates.shape[-1],) if inverse else ())
-    specs = _specs(chunk, per_step, widths, lambda n: n)
-    shapes = [jax.ShapeDtypeStruct((held.per_call, seq, width), jnp.float32) for width in widths]
-    per_chunk = pl.BlockSpec((1, per_step, 1, 1), lambda i, n, _: (i, n, 0, 0))
+def kept_inverse_shape(rows: int, seq: int, chunk: int) -> tuple[int, int, int]:
+    """``T`` of ``rows`` (batch x head) rows as it is KEPT (``_Masks.packed``):
+    ``chunk`` rows of ``chunks a product x chunk`` lanes a product, float32:
+    ``4 x chunk`` bytes a row and token."""
+    width = _product_rows(seq, chunk)
+    return rows, seq // width * chunk, width
+
+
+def _kept_spec(held, chunk, per_step, together):
+    """The BlockSpec of a grid step's block of the kept ``T``, in the
+    caller's array of every row (heads first whatever ``held.heads`` says of
+    the other operands)."""
+    return pl.BlockSpec(
+        (1, per_step // together * chunk, together * chunk),
+        lambda i, n, group: (held.row(i, group), n, 0),
+    )
+
+
+def _prepare_forward_call(kernel, held, operands, layout, gamma_width, chunk, group, kept,
+                          inverse, interpret):
+    """The call both preparation forwards make (``kernel`` already told its
+    form): six results ``[rows a call, seq, .]`` of the rows ``held`` and
+    ``group`` name. ``inverse`` "write": ``T`` besides, as it is kept
+    (``kept_inverse_shape``), into ``kept``, the caller's array of EVERY
+    row, where the call's rows lie (None: one of the call's own); "read":
+    ``T`` is taken from ``kept`` there and not computed."""
+    grid, per_step, together, in_specs = layout
+    seq = operands[0].shape[1]
+    d_k, d_v = held.width(operands[0]), held.width(operands[2])
+    widths = (d_k, d_v, d_k, chunk, d_k)
+    kept_spec = _kept_spec(held, chunk, per_step, together)
+    out_specs = [
+        *_specs(chunk, per_step, widths, lambda n: n),
+        pl.BlockSpec((1, per_step, 1, gamma_width), lambda i, n, _: (i, n, 0, 0)),
+    ]
+    out_shape = [
+        *(jax.ShapeDtypeStruct((held.per_call, seq, width), jnp.float32) for width in widths),
+        jax.ShapeDtypeStruct((held.per_call, seq // chunk, 1, gamma_width), jnp.float32),
+    ]
+    if inverse == "write":
+        out_specs.append(kept_spec)
+        out_shape.append(jax.ShapeDtypeStruct(
+            kept_inverse_shape(held.per_call, seq, chunk) if kept is None else kept.shape,
+            jnp.float32,
+        ))
+    elif inverse == "read":
+        operands, in_specs = (*operands, kept), [*in_specs, kept_spec]
     return _grouped_call(
         functools.partial(
-            _prepare_forward_kernel, chunk=chunk, per_step=per_step, together=together
+            kernel, chunk=chunk, per_step=per_step, together=together, inverse=inverse
         ),
-        group, (q, k, v, gates),
+        group, operands,
         grid=grid,
         in_specs=in_specs,
-        out_specs=[*specs[:5], per_chunk, *specs[5:]],
-        out_shape=[
-            *shapes[:5],
-            jax.ShapeDtypeStruct((held.per_call, seq // chunk, 1, 1), jnp.float32),
-            *shapes[5:],
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        into=(kept,) if inverse == "write" and kept is not None else (),
         interpret=interpret,
         compiler_params=_parallel(),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "inverse", "heads", "rows"))
+def _delta_prepare_forward(q, k, v, gates, group=None, kept=None, *, chunk, interpret,
+                           inverse=None, heads=0, rows=None):
+    """``_prepare``'s six operands ``[rows, seq, .]`` of ``rows`` (None:
+    all) of the (batch x head) rows of q, k, v (``_Held``'s layout under
+    ``heads``) and ``gates`` (``_gates``), those of group ``group``.
+    ``inverse`` and ``kept``: ``_prepare_forward_call``'s."""
+    held = _Held(heads, rows or q.shape[0])
+    return _prepare_forward_call(
+        _prepare_forward_kernel, held, (q, k, v, gates),
+        _prepare_layout(held, q, v, gates, chunk), 1, chunk, group, kept, inverse, interpret,
     )
 
 
@@ -995,8 +1100,9 @@ def _delta_prepare_backward(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgam
     gradients in ``gates``' layout (of the running sum ``G``, not yet of
     ``log_alpha``) from the scan backward's six: of the rows of group
     ``group``, written where q, k, v and ``gates`` hold those rows (every
-    other row of the four results is its operand's)."""
-    held = _Held(heads, inverse.shape[0])
+    other row of the four results is its operand's). ``inverse``: ``T`` as
+    the forward kept it, every row's."""
+    held = _Held(heads, dw.shape[0])
     d_k, d_v = held.width(q), held.width(v)
     grid, per_step, together, in_specs = _prepare_layout(held, q, v, gates, chunk)
     return _grouped_call(
@@ -1007,7 +1113,8 @@ def _delta_prepare_backward(q, k, v, gates, inverse, dw, du0, dqg, dp, dkd, dgam
         grid=grid,
         in_specs=[
             *in_specs,
-            *_specs(chunk, per_step, (gates.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
+            _kept_spec(held, chunk, per_step, together),
+            *_specs(chunk, per_step, (d_k, d_v, d_k, chunk, d_k), lambda n: n),
             pl.BlockSpec((1, per_step, 1, 1), lambda i, n, _: (i, n, 0, 0)),
         ],
         out_specs=in_specs,
@@ -1172,18 +1279,20 @@ def _halved_products(at, masks, q, k, log_alpha):
     return a, jnp.where(masks.eye, _rows_sum(q * k), p)
 
 
-def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
-                            w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref, *inverse_ref,
-                            chunk, per_step, together, bounded):
+def _channel_forward_kernel(*refs, chunk, per_step, together, bounded, inverse=None):
     """``_prepare_channel_xla`` for ``per_step`` chunks of one head,
     ``together`` to a product. ``beta_ref``: ``[1, per_step / together, 1,
     together x chunk]``, along the lanes; the running sum ``G`` of
     ``log_alpha`` is taken here (``_Blocks.running``). ``A / beta`` and ``P``
     are ``_bounded_products``' where the caller stated a bound that form
     carries, else ``_halved_products``'; the decayed copies of K live and
-    die here. With a seventh result, ``T`` as the block-diagonal matrices it
-    was computed as."""
+    die here. ``inverse``: ``_prepare_forward_kernel``'s; where ``T`` is read
+    the stacked products are multiplied as they are and ``A``'s half of
+    them has no reader."""
     f32 = jnp.float32
+    q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, *refs = refs
+    kept_ref = refs.pop(0 if inverse == "read" else -1) if inverse else None
+    w_ref, u0_ref, qg_ref, p_ref, kd_ref, gamma_ref = refs
     width = together * chunk
     masks = _Masks(chunk, together)
     at = _Blocks(chunk, together, q_ref.shape[-1])
@@ -1202,7 +1311,8 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
         else:
             a, p = _halved_products(at, masks, q, k, log_alpha)
         left = at.to_end(log_alpha, total, bounded)
-        several.append(jnp.where(masks.strictly, betas[s] * a, 0.0))
+        if inverse != "read":
+            several.append(jnp.where(masks.strictly, betas[s] * a, 0.0))
         p = jnp.where(masks.upto, p, 0.0)
         qg_ref[0, rows[s], :] = grown * q
         kd_ref[0, rows[s], :] = left * k
@@ -1210,11 +1320,15 @@ def _channel_forward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref,
             own = slice(c * chunk, (c + 1) * chunk)
             p_ref[0, s * width + c * chunk:s * width + (c + 1) * chunk, :] = p[own, own]
             gamma_ref[0, s * together + c] = jnp.exp(total[(c + 1) * chunk - 1:(c + 1) * chunk])
-    for s, inverse in zip(products, masks.inverses(several)):
-        w_ref[0, rows[s], :] = _dot(inverse, weighed[s], _NN)
-        u0_ref[0, rows[s], :] = _dot(inverse, betas[s] * v_ref[0, rows[s], :].astype(f32), _NN)
-        if inverse_ref:
-            inverse_ref[0][0, rows[s], :] = inverse
+    if inverse == "read":
+        inverses = [masks.unpacked(kept_ref[0, _kept_rows(s, chunk), :]) for s in products]
+    else:
+        inverses = masks.inverses(several)
+    for s, t in zip(products, inverses):
+        w_ref[0, rows[s], :] = _dot(t, weighed[s], _NN)
+        u0_ref[0, rows[s], :] = _dot(t, betas[s] * v_ref[0, rows[s], :].astype(f32), _NN)
+        if inverse == "write":
+            kept_ref[0, _kept_rows(s, chunk), :] = masks.packed(t)
 
 
 def _bounded_transpose(at, q, k, beta, total, da, dp):
@@ -1280,11 +1394,11 @@ def _channel_backward_kernel(q_ref, k_ref, v_ref, log_alpha_ref, beta_ref, inver
     at = _Blocks(chunk, together, q_ref.shape[-1])
     for s in range(per_step // together):
         rows = slice(s * width, (s + 1) * width)
-        q, k, v, log_alpha, inverse, dw, du0, dqg, dp, dkd = (
+        q, k, v, log_alpha, dw, du0, dqg, dp, dkd = (
             ref[0, rows, :].astype(f32) for ref in
-            (q_ref, k_ref, v_ref, log_alpha_ref, inverse_ref, dw_ref, du0_ref, dqg_ref, dp_ref,
-             dkd_ref)
+            (q_ref, k_ref, v_ref, log_alpha_ref, dw_ref, du0_ref, dqg_ref, dp_ref, dkd_ref)
         )
+        inverse = masks.unpacked(inverse_ref[0, _kept_rows(s, chunk), :])
         total = at.running(log_alpha)
         beta = masks.down(beta_ref[0, s, 0:1, :])
         grown = jnp.exp(total)                                   # e^{G_t}
@@ -1341,37 +1455,19 @@ def _channel_layout(held, q, v, beta, chunk):
 @functools.partial(
     jax.jit, static_argnames=("chunk", "interpret", "inverse", "bounded", "heads", "rows")
 )
-def _channel_prepare_forward(q, k, v, log_alpha, beta, group=None, *, chunk, interpret,
-                             inverse=False, bounded=False, heads=0, rows=None):
+def _channel_prepare_forward(q, k, v, log_alpha, beta, group=None, kept=None, *, chunk,
+                             interpret, inverse=None, bounded=False, heads=0, rows=None):
     """``_prepare_channel_xla``'s six operands ``[rows, seq, .]`` of ``rows``
     (None: all) of the (batch x head) rows of q, k, ``log_alpha``, v
     (``_Held``'s layout under ``heads``) and ``beta`` (``_beta_lanes``),
-    those of group ``group``; with ``inverse`` a seventh, ``T`` ``[rows, seq,
-    chunks a product x chunk]``. ``bounded``: the caller's ``log_alpha``
+    those of group ``group``. ``inverse`` and ``kept``:
+    ``_prepare_forward_call``'s. ``bounded``: the caller's ``log_alpha``
     keeps a bound that ``carries_bound``."""
     held = _Held(heads, rows or q.shape[0])
-    seq, d_k, d_v = q.shape[1], held.width(q), held.width(v)
-    grid, per_step, together, in_specs = _channel_layout(held, q, v, beta, chunk)
-    widths = (d_k, d_v, d_k, chunk, d_k) + ((beta.shape[-1],) if inverse else ())
-    specs = _specs(chunk, per_step, widths, lambda n: n)
-    shapes = [jax.ShapeDtypeStruct((held.per_call, seq, width), jnp.float32) for width in widths]
-    per_chunk = pl.BlockSpec((1, per_step, 1, d_k), lambda i, n, _: (i, n, 0, 0))
-    return _grouped_call(
-        functools.partial(
-            _channel_forward_kernel, chunk=chunk, per_step=per_step, together=together,
-            bounded=bounded,
-        ),
-        group, (q, k, v, log_alpha, beta),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[*specs[:5], per_chunk, *specs[5:]],
-        out_shape=[
-            *shapes[:5],
-            jax.ShapeDtypeStruct((held.per_call, seq // chunk, 1, d_k), jnp.float32),
-            *shapes[5:],
-        ],
-        interpret=interpret,
-        compiler_params=_parallel(),
+    return _prepare_forward_call(
+        functools.partial(_channel_forward_kernel, bounded=bounded), held,
+        (q, k, v, log_alpha, beta), _channel_layout(held, q, v, beta, chunk), held.width(q),
+        chunk, group, kept, inverse, interpret,
     )
 
 
@@ -1382,8 +1478,8 @@ def _channel_prepare_backward(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, d
     ``dbeta`` in ``_beta_lanes``' layout from the scan backward's six: of
     the rows of group ``group``, written where q, k, v, ``log_alpha`` and
     ``beta`` hold those rows (every other row of the five results is its
-    operand's)."""
-    held = _Held(heads, inverse.shape[0])
+    operand's). ``inverse``: ``T`` as the forward kept it, every row's."""
+    held = _Held(heads, dw.shape[0])
     d_k, d_v = held.width(q), held.width(v)
     grid, per_step, together, in_specs = _channel_layout(held, q, v, beta, chunk)
     return _grouped_call(
@@ -1395,7 +1491,8 @@ def _channel_prepare_backward(q, k, v, log_alpha, beta, inverse, dw, du0, dqg, d
         grid=grid,
         in_specs=[
             *in_specs,
-            *_specs(chunk, per_step, (beta.shape[-1], d_k, d_v, d_k, chunk, d_k), lambda n: n),
+            _kept_spec(held, chunk, per_step, together),
+            *_specs(chunk, per_step, (d_k, d_v, d_k, chunk, d_k), lambda n: n),
             pl.BlockSpec((1, per_step, 1, d_k), lambda i, n, _: (i, n, 0, 0)),
         ],
         out_specs=in_specs,
@@ -1482,6 +1579,23 @@ def _unwritten(v):
     return jax.lax.empty(v.shape, v.dtype)
 
 
+def _scan_keeping(prepare, v, walk):
+    """The forward as a gradient traces it: ``prepare(group, kept)`` is a
+    group's preparation call with ``inverse="write"``, which also writes the
+    group's rows of ``T`` into ``kept``, ONE array of every row
+    (``kept_inverse_shape``), as the scan's call writes the output's. Both
+    under ``RESIDUAL_NAMES``."""
+    def one_group(group, carried):
+        out, kept = carried
+        *operands, kept = prepare(group, kept)
+        return _delta_rule_forward(*operands, group, out, out_dtype=v.dtype, **walk.static), kept
+
+    rows = walk.groups * walk.held.per_call
+    kept = jax.lax.empty(kept_inverse_shape(rows, v.shape[1], walk.chunk), jnp.float32)
+    out, kept = walk.over_groups(one_group, (_unwritten(v), kept))
+    return checkpoint_name(out, RESIDUAL_NAMES[0]), checkpoint_name(kept, RESIDUAL_NAMES[1])
+
+
 def _prepare_and_scan(q, k, v, gates, walk):
     def one_group(group, out):
         operands = _delta_prepare_forward(
@@ -1499,28 +1613,38 @@ _chunked = jax.custom_vjp(_prepare_and_scan, nondiff_argnums=(4,))
 
 
 def _chunked_fwd(q, k, v, gates, walk):
-    out = _prepare_and_scan(q, k, v, gates, walk)
-    return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, gates)
+    """``_prepare_and_scan`` as a gradient traces it (``_scan_keeping``)."""
+    def prepare(group, kept):
+        return _delta_prepare_forward(
+            q, k, v, gates, group, kept, rows=walk.held.per_call, inverse="write",
+            **walk.static,
+        )
+
+    out, kept = _scan_keeping(prepare, v, walk)
+    return out, (q, k, v, gates, kept)
 
 
-def _chunked_bwd(walk, inputs, dout):
-    """Nothing of the forward is kept but its inputs: group by group the
-    preparation kernel runs again (and hands over ``T``), the forward kernel
-    once more for the chunk-start states, then the backward kernel and the
-    preparation's own, which writes the group's rows of the four gradients
-    where the group's rows of the inputs were: ``held`` is the inputs in the
-    groups still to come and their gradients in those done (a group's rows
-    are read by that group's calls alone), so the gradients need no arrays
-    of their own and nothing to fill them."""
+def _chunked_bwd(walk, residuals, dout):
+    """Of the forward its inputs and ``T`` are kept: group by group the
+    preparation kernel runs again FROM ``T`` (no ``A``, no level of the
+    doubling), the forward kernel once more for the chunk-start states, then
+    the backward kernel and the preparation's own (which reads the same
+    ``T``), which writes the group's rows of the four gradients where the
+    group's rows of the inputs were: ``held`` is the inputs in the groups
+    still to come and their gradients in those done (a group's rows are
+    read by that group's calls alone), so the gradients need no arrays of
+    their own and nothing to fill them."""
+    *inputs, kept = residuals
+
     def one_group(group, held):
-        *operands, inverse = _delta_prepare_forward(
-            *held, group, rows=walk.held.per_call, inverse=True, **walk.static
+        operands = _delta_prepare_forward(
+            *held, group, kept, rows=walk.held.per_call, inverse="read", **walk.static
         )
         states = _delta_rule_forward(
             *operands, out_dtype=dout.dtype, states=True, **walk.static
         )
         inner = _delta_rule_backward(*operands, states, dout, group, **walk.static)
-        return tuple(_delta_prepare_backward(*held, inverse, *inner, group, **walk.static))
+        return tuple(_delta_prepare_backward(*held, kept, *inner, group, **walk.static))
 
     return walk.over_groups(one_group, tuple(inputs))
 
@@ -1546,29 +1670,39 @@ _chunked_channel = jax.custom_vjp(_channel_prepare_and_scan, nondiff_argnums=(5,
 
 
 def _chunked_channel_fwd(q, k, v, log_alpha, beta, walk):
-    out = _channel_prepare_and_scan(q, k, v, log_alpha, beta, walk)
-    return checkpoint_name(out, RESIDUAL_NAMES[0]), (q, k, v, log_alpha, beta)
+    """``_channel_prepare_and_scan`` as a gradient traces it (``_scan_keeping``)."""
+    lanes = _beta_lanes(beta, walk.chunk)
+
+    def prepare(group, kept):
+        with jax.named_scope("decay_prepare"):
+            return _channel_prepare_forward(
+                q, k, v, log_alpha, lanes, group, kept, rows=walk.held.per_call,
+                inverse="write", bounded=walk.bounded, **walk.static,
+            )
+
+    out, kept = _scan_keeping(prepare, v, walk)
+    return out, (q, k, v, log_alpha, beta, kept)
 
 
-def _chunked_channel_bwd(walk, inputs, dout):
-    """As ``_chunked_bwd``, nothing kept but the inputs: group by group the
-    preparation kernel runs again (and hands over ``T``), the forward kernel
-    for the chunk-start states, the backward kernel, then the preparation's
-    own, which writes the group's rows of the five gradients where the
-    group's rows of the inputs were (``held``, as in ``_chunked_bwd``)."""
-    *inputs, beta = inputs
+def _chunked_channel_bwd(walk, residuals, dout):
+    """As ``_chunked_bwd``, the inputs and ``T`` kept: group by group the
+    preparation kernel runs again from ``T``, the forward kernel for the
+    chunk-start states, the backward kernel, then the preparation's own,
+    which writes the group's rows of the five gradients where the group's
+    rows of the inputs were (``held``, as in ``_chunked_bwd``)."""
+    *inputs, beta, kept = residuals
     rule = walk.static
     prepare = dict(rule, bounded=walk.bounded)
 
     def one_group(group, held):
         with jax.named_scope("decay_prepare"):
-            *operands, inverse = _channel_prepare_forward(
-                *held, group, rows=walk.held.per_call, inverse=True, **prepare
+            operands = _channel_prepare_forward(
+                *held, group, kept, rows=walk.held.per_call, inverse="read", **prepare
             )
         states = _delta_rule_forward(*operands, out_dtype=dout.dtype, states=True, **rule)
         inner = _delta_rule_backward(*operands, states, dout, group, **rule)
         with jax.named_scope("decay_prepare"):
-            return tuple(_channel_prepare_backward(*held, inverse, *inner, group, **prepare))
+            return tuple(_channel_prepare_backward(*held, kept, *inner, group, **prepare))
 
     *grads, dlanes = walk.over_groups(one_group, (*inputs, _beta_lanes(beta, walk.chunk)))
     return (*grads, dlanes.reshape(beta.shape).astype(beta.dtype))
@@ -1583,7 +1717,8 @@ def _heads_per_call(heads: int, seq: int) -> int:
     ``_TOKENS_PER_CALL``. What a head and token costs between the calls of
     ``_chunked_bwd``: the six float32 operands and their gradients (2 x
     2,176 bytes as counted, 2 x 3,072 as HBM tiles them: 96 and 64 columns
-    take 128 lanes, 192 take 256), ``T`` (512) and the chunk-start states
+    take 128 lanes, 192 take 256), until PR 52 a copy of ``T`` (512; kept
+    from the forward since, for every row) and the chunk-start states
     (1,536); the rule's temporaries alone were 1.19 GiB two heads a call,
     1.79 six, 4.69 all thirty at once (compiles for a described v5e, PR
     33). Fewer at a time is still FASTER in the step, down to two: the
@@ -1680,9 +1815,10 @@ def gated_delta_rule(
     a group an INDEX the kernels' index maps add (``_Walk.over_groups``,
     ``_Held``): the six kernels read q, k, v and a channel decay and write
     the output and the five gradients where the caller's arrays hold the
-    group's rows, and a call keeps nothing for its backward but its inputs,
-    so only the kernels' own operands, their gradients and the chunk-start
-    states exist a group at a time, forward and backward. ``kernels=False``
+    group's rows, and a call keeps for its backward its inputs, its output
+    and ``T``'s diagonal blocks (``kept_bytes``), so only the kernels' own
+    operands, their gradients and the chunk-start states exist a group at a
+    time, forward and backward. ``kernels=False``
     runs the scan over chunks in plain jax.numpy (the chunked form with no
     kernel, for tests).
 

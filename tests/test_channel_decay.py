@@ -227,6 +227,45 @@ def test_the_state_kept_for_the_backward_is_the_output_alone():
     _, residuals = jax.vjp(lambda *a: gated_delta_rule(*a), q, k, v, g, beta)
     kept = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(residuals))
     inputs = sum(x.size * x.dtype.itemsize for x in (q, k, v, g, beta))
-    # the inputs (flattened and padded copies among them), never a [chunks, d_k, d_v] state
+    # the inputs (flattened and padded copies among them) and T's diagonal
+    # blocks (a chunk of 64 float32 a head and token, less than q and k
+    # together at d_k 32), never a [chunks, d_k, d_v] state
     assert kept <= 3 * inputs
-    assert gdr.kept_bytes(1, 2, 128, 48, 4) == 2 * 128 * 48 * 4
+    assert gdr.kept_bytes(1, 2, 128, 48, 4) == 2 * 128 * (48 * 4 + 64 * 4)
+
+
+@pytest.mark.parametrize("form", ["scalar", "bounded", "halving"])
+def test_a_layer_checkpoint_saves_the_two_named_residuals_and_kept_bytes_counts_them(form):
+    """One linear mixer under the layer scan's checkpoint (``_remat_policy("full")``):
+    of what its forward computes, the rule's output and ``T``'s diagonal
+    blocks are saved for the backward, by name, and nothing else; their bytes
+    are ``kept_bytes``'. Two sequences of 40 tokens: one padded chunk of 48."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from ray_tpu.models import transformer as T
+
+    linear = {
+        "scalar": {},
+        "bounded": dict(decay="channel", gate_lower_bound=BOUND, output_gate="sigmoid"),
+        "halving": dict(decay="channel", output_gate="sigmoid"),
+    }[form]
+    config = T.TransformerConfig(
+        vocab_size=64, dim=32, n_layers=1, n_heads=2, n_kv_heads=2, hidden_dim=64,
+        attention="flash", layer_pattern=("linear",), dtype=jnp.float32,
+        linear=T.LinearAttentionConfig(
+            num_key_heads=2, num_value_heads=2, key_head_dim=16, value_head_dim=8, **linear
+        ),
+    )
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 64))
+    layer = {
+        name: leaf.init(keys, leaf.shape, config.dtype)
+        for name, leaf in T._linear_leaves(config).items()
+    }
+    h = jax.random.normal(jax.random.PRNGKey(1), (2, 40, config.dim))
+    mixer = jax.checkpoint(
+        lambda h, layer: T._linear_mixer(h, layer, config), policy=T._remat_policy("full")
+    )
+    saved = [aval for aval, why in saved_residuals(mixer, h, layer) if "argument" not in why]
+    assert sorted(aval.shape for aval in saved) == [(4, 48, 8), (4, 48, 48)]
+    assert sum(aval.size * aval.dtype.itemsize for aval in saved) == T.kept_bytes(2, 2, 40, 8, 4)
+    assert T.linear_state_bytes(config, 2, 40) == T.kept_bytes(2, 2, 40, 8, 4) == 4 * 48 * 56 * 4

@@ -67,8 +67,8 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-def _mixer_text(one_chip, config, seq) -> str:
-    """The optimized program of ``sum(_linear_mixer(h) ^ 2)`` and its
+def _mixer(one_chip, config, seq):
+    """The compiled program of ``sum(_linear_mixer(h) ^ 2)`` and its
     gradients in ``h`` and every leaf of the layer, with the Mosaic kernels
     (the platform rule would pick the interpreter: the backend here is the
     CPU)."""
@@ -89,7 +89,7 @@ def _mixer_text(one_chip, config, seq) -> str:
 
     compiled = lambda module: mock.patch.object(module, "resolve_interpret", lambda _i: False)
     with compiled(gated_delta_rule), compiled(short_conv):
-        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(h, layer).compile().as_text()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(h, layer).compile()
 
 
 _MOSAIC = re.compile(
@@ -165,8 +165,8 @@ def _config(dim, heads, d_k, d_v, **linear):
 
 _CONVOLUTIONS = ["_short_conv_backward"] * 3 + ["_short_conv_forward"] * 3
 # A gradient runs the preparation and the scan forward twice (the forward,
-# and again in the backward for ``T`` and the chunk-start states), then the
-# scan's backward kernel and the preparation's.
+# and again in the backward for the scan's operands, from the kept ``T``, and
+# the chunk-start states), then the scan's backward kernel and the preparation's.
 _CHANNEL_RULE = [
     "_channel_prepare_backward", "_channel_prepare_forward", "_channel_prepare_forward",
     "_delta_rule_backward", "_delta_rule_forward", "_delta_rule_forward",
@@ -175,6 +175,41 @@ _SCALAR_RULE = [
     "_delta_prepare_backward", "_delta_prepare_forward", "_delta_prepare_forward",
     "_delta_rule_backward", "_delta_rule_forward", "_delta_rule_forward",
 ]
+# The compiler's ``peak_memory_in_bytes`` of the same programs at the parent
+# of PR 52 (commit ae8647f, which kept no ``T``; compile for a described v5e,
+# PR 52), in MiB: what the kept ``T`` is held against. (``temp_size_in_bytes``
+# rose by 256 and 64.2 MiB in the first two and by 0.03 in the third: the
+# heap's packing around one more long-lived buffer, not a second copy.)
+_PEAK_BEFORE_T_WAS_KEPT = {
+    "ling_32_heads_of_128_bounded": 3159.788,
+    "solar_64_heads_of_128_unbounded": 2033.194,
+    "olmo_hybrid_30_heads_of_96_192": 2754.728,
+}
+
+
+def _kept_t_alone(compiled, cell, heads, seq):
+    """The kept ``T`` (``heads x seq x 64`` float32: a chunk of 64) costs the
+    program its own bytes (8 MiB of play) and no pass: between the forward
+    call that writes it and the two backward calls that read it nothing
+    copies, slices, pads or fills an array of its size."""
+    text = compiled.as_text()
+    shape = f"f32[{heads},{seq // 2},128]"
+    assert any(shape in line and "AllocateBuffer" in line for line in text.splitlines())
+    moved = _rearranged(text, heads * seq * 64)
+    assert not moved, moved
+    held = set(re.findall(rf"%([\w.\-]+) = {re.escape(shape)}", text))
+    takers = sorted(
+        _MOSAIC.match(line).group(1) for line in text.splitlines()
+        if _MOSAIC.match(line)
+        and held & set(re.findall(r"%([\w.\-]+)", line.split("custom-call(", 1)[1].split(")", 1)[0]))
+    )
+    # the forward's call writes its rows of it; the backward's two read it
+    assert [name.rsplit("_", 1)[1] for name in takers] == ["backward", "forward", "forward"]
+    assert all("_prepare_" in name for name in takers), takers
+    grown = compiled.memory_analysis().peak_memory_in_bytes / 2**20 - _PEAK_BEFORE_T_WAS_KEPT[cell]
+    assert abs(grown - heads * seq * 256 / 2**20) < 8, grown
+
+
 # name: (sequence, hidden, heads, d_k, d_v, the linear mixer's own fields, its
 # kernels, the passes XLA leaves outside the rule's scope)
 CELLS = {
@@ -192,7 +227,9 @@ CELLS = {
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_no_pass_only_rearranges_what_the_rule_reads_and_writes(one_chip, cell):
     seq, dim, heads, d_k, d_v, linear, rule, left = CELLS[cell]
-    text = _mixer_text(one_chip, _config(dim, heads, d_k, d_v, **linear), seq)
+    compiled = _mixer(one_chip, _config(dim, heads, d_k, d_v, **linear), seq)
+    text = compiled.as_text()
+    _kept_t_alone(compiled, cell, heads, seq)
     assert sorted(_MOSAIC.findall(text)) == sorted(rule + _CONVOLUTIONS)
     assert text.count("tpu_custom_call") == 12
     moved = _rearranged(text, heads * seq * d_k)
@@ -203,7 +240,9 @@ def test_no_pass_only_rearranges_what_the_rule_reads_and_writes(one_chip, cell):
 def test_unaligned_heads_stay_heads_first_and_a_group_costs_no_pass(one_chip):
     """Olmo-Hybrid's ``[1, 16384, 30, 96 | 192]``, one scalar decay a head."""
     seq, heads, d_k, d_v = 16384, 30, 96, 192
-    text = _mixer_text(one_chip, _config(3840, heads, d_k, d_v), seq)
+    compiled = _mixer(one_chip, _config(3840, heads, d_k, d_v), seq)
+    text = compiled.as_text()
+    _kept_t_alone(compiled, "olmo_hybrid_30_heads_of_96_192", heads, seq)
     assert sorted(_MOSAIC.findall(text)) == sorted(_SCALAR_RULE + _CONVOLUTIONS)
     assert text.count("tpu_custom_call") == 12
     for elements in (heads * seq * d_k, heads * seq * d_v):

@@ -11,6 +11,8 @@ wrong term (a decay taken to the wrong token, a missing
 beta) is off by 1e-1 or more.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -173,11 +175,52 @@ def test_token_major_operands_give_the_heads_first_rule_and_the_recurrence(
 
 
 def test_what_is_kept_for_the_backward_is_the_output():
-    # 30 heads x 16384 tokens x d_v 192 in bfloat16; the chunk-start states
+    # 30 heads x 16384 tokens: d_v 192 in bfloat16 and T's diagonal blocks, a
+    # chunk of 64 float32 a head and token (120 MiB); the chunk-start states
     # (566 MB a layer in float32) are made again, not kept
-    assert G.kept_bytes(1, 30, 16384, 192, 2) == 30 * 16384 * 192 * 2
-    assert G.kept_bytes(1, 2, 50, 8, 4, chunk=16) == 2 * 64 * 8 * 4
-    assert G.RESIDUAL_NAMES == ("delta_rule_out",)
+    assert G.kept_bytes(1, 30, 16384, 192, 2) == 30 * 16384 * (192 * 2 + 64 * 4)
+    assert G.kept_bytes(1, 2, 50, 8, 4, chunk=16) == 2 * 64 * (8 * 4 + 16 * 4)
+    assert G.RESIDUAL_NAMES == ("delta_rule_out", "delta_rule_inverse")
+    # two chunks of 64 a product: half the rows, 128 lanes (Ling's [32, 8192, 128])
+    assert G.kept_inverse_shape(32, 16384, 64) == (32, 8192, 128)
+    assert G.kept_inverse_shape(2, 64, 16) == (2, 16, 64)              # four chunks a product
+    assert G.kept_inverse_shape(3, 192, 64) == (3, 192, 64)            # three chunks: one each
+
+
+@pytest.mark.parametrize("chunk,together", [(64, 2), (64, 1), (16, 4), (48, 2)])
+def test_t_is_kept_as_its_diagonal_blocks_bit_for_bit(chunk, together):
+    """``_Masks.packed`` of a block-diagonal value (exact zeros between two
+    chunks, as ``_Masks.inverses`` leaves them) holds every number of it once,
+    and ``unpacked`` gives the same bits back."""
+    masks, rows = G._Masks(chunk, together), chunk * together
+    full = jax.random.normal(jax.random.PRNGKey(chunk + together), (rows, rows))
+    full = jnp.where(masks.same, full, 0.0).at[0, 0].set(-0.0)
+    packed = masks.packed(full)
+    assert packed.shape == (chunk, rows)
+    for c in range(together):
+        own = slice(c * chunk, (c + 1) * chunk)
+        assert np.array_equal(np.asarray(packed[:, own]), np.asarray(full[own, own]))
+    back = masks.unpacked(packed)
+    assert np.asarray(back).tobytes() == np.asarray(full).tobytes()
+
+
+def unpacked(kept, chunk):
+    """The kept ``T`` ``[rows, products x chunk, width]`` as the block-diagonal
+    matrices it stands for, ``[rows, products, width, width]`` float64 (the
+    test's own unpacking, in numpy)."""
+    rows, _, width = kept.shape
+    blocks = np.asarray(kept, np.float64).reshape(rows, -1, chunk, width)
+    full = np.zeros((rows, blocks.shape[1], width, width))
+    for c in range(width // chunk):
+        own = slice(c * chunk, (c + 1) * chunk)
+        full[:, :, own, own] = blocks[..., own]
+    return full
+
+
+def same_bits(got, want, what):
+    assert len(got) == len(want)
+    for name, g, w in zip(OPERANDS, got, want):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes(), (what, name)
 
 
 # ---------------------------------------------------------------------------
@@ -246,6 +289,24 @@ def test_the_preparation_kernel_writes_the_oracles_six_operands(case):
     assert len(got) == 6 and all(x.dtype == jnp.float32 for x in got)
     for name, g, w in zip(OPERANDS, got, want):
         close(g, w, name, **NEAR)
+    # what a gradient's forward runs: the same six and T as it is kept ...
+    gates = G._gates(log_alpha, beta, chunk)
+    *same, kept = G._delta_prepare_forward(
+        q, k, v, gates, chunk=chunk, interpret=True, inverse="write"
+    )
+    same_bits(same, got, "T written")
+    assert kept.shape == G.kept_inverse_shape(3, q.shape[1], chunk) and kept.dtype == jnp.float32
+    # ... and what its backward runs: the six FROM the kept T, the same bits
+    # (the kernel unpacked the block-diagonal T the forward held)
+    again = G._delta_prepare_forward(
+        q, k, v, gates, None, kept, chunk=chunk, interpret=True, inverse="read"
+    )
+    same_bits(again, got, "T read")
+    # T is (I + A)^-1 of each chunk: U0 = T (beta V)
+    width = kept.shape[-1]
+    weighed = np.asarray(beta, np.float64)[..., None] * np.asarray(v, np.float64)
+    close(unpacked(kept, chunk) @ weighed.reshape(3, -1, width, v.shape[-1]),
+          np.asarray(want[1]).reshape(3, -1, width, v.shape[-1]), "T beta V", **NEAR)
 
 
 @pytest.mark.parametrize("case", list(PREPARE_CASES))
@@ -261,7 +322,7 @@ def test_the_preparations_transpose_by_hand_is_jaxs(case):
 
     gates, gates_vjp = jax.vjp(lambda *a: G._gates(*a, chunk), log_alpha, beta)
     *_, inverse = G._delta_prepare_forward(
-        q, k, v, gates, chunk=chunk, interpret=True, inverse=True
+        q, k, v, gates, chunk=chunk, interpret=True, inverse="write"
     )
     dq, dk, dv, dgates = G._delta_prepare_backward(
         q, k, v, gates, inverse, *cotangents, chunk=chunk, interpret=True
@@ -337,20 +398,35 @@ def test_the_channel_preparation_kernel_writes_the_oracles_six_operands_and_t(ca
     assert "{}x{}".format(*_layout(case)) == case.rsplit("_", 1)[1]
     lanes = G._beta_lanes(beta, chunk)
     *got, inverse = G._channel_prepare_forward(
-        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse=True
+        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse="write"
     )
     assert len(got) == 6 and all(x.dtype == jnp.float32 for x in (*got, inverse))
+    assert inverse.shape == G.kept_inverse_shape(3, q.shape[1], chunk)
     for name, g, w in zip(OPERANDS, got, want):
         close(g, w, name, **CHANNEL_NEAR)
     assert got[5].shape == (3, q.shape[1] // chunk, 1, q.shape[2])          # gamma: a row a chunk
     # without ``inverse`` the same six, bit for bit
     for g, w in zip(G._prepare_channel(q, k, v, log_alpha, beta, chunk, True), got):
         assert np.array_equal(np.asarray(g), np.asarray(w))
-    # T is (I + A)^-1 of each chunk, block-diagonal in its product: U0 = T (beta V)
+    # ... and FROM the kept T (what the backward's call runs): by halving, and
+    # split at a sub-block's first row where two chunks ride a product and
+    # where the last chunk is padded
+    how = dict(chunk=chunk, interpret=True)
+    same_bits(
+        G._channel_prepare_forward(q, k, v, log_alpha, lanes, None, inverse, inverse="read", **how),
+        got, "T read",
+    )
+    if case in ("at_the_bound_4x2", "no_multiple_of_the_chunk_4x2"):
+        *written, kept = G._channel_prepare_forward(
+            q, k, v, log_alpha, lanes, inverse="write", bounded=True, **how
+        )
+        read = G._channel_prepare_forward(
+            q, k, v, log_alpha, lanes, None, kept, inverse="read", bounded=True, **how
+        )
+        same_bits(read, written, "T read, bounded")
+    # T is (I + A)^-1 of each chunk, kept as its diagonal blocks: U0 = T (beta V)
     width = lanes.shape[-1]
-    blocks = np.asarray(inverse, np.float64).reshape(3, -1, width, width)
-    same = np.kron(np.eye(width // chunk), np.ones((chunk, chunk)))
-    assert np.all(blocks * (1 - same) == 0.0)
+    blocks = unpacked(inverse, chunk)
     weighed = (np.asarray(beta, np.float64)[..., None] * np.asarray(v, np.float64))
     close(blocks @ weighed.reshape(3, -1, width, v.shape[-1]),
           np.asarray(want[1]).reshape(3, -1, width, v.shape[-1]), "T beta V", **CHANNEL_NEAR)
@@ -382,7 +458,7 @@ def test_the_channel_preparations_transpose_by_hand_is_jaxs(case):
 
     lanes = G._beta_lanes(beta, chunk)
     *_, inverse = G._channel_prepare_forward(
-        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse=True
+        q, k, v, log_alpha, lanes, chunk=chunk, interpret=True, inverse="write"
     )
     *got, dlanes = G._channel_prepare_backward(
         q, k, v, log_alpha, lanes, inverse, *cotangents, chunk=chunk, interpret=True
@@ -392,3 +468,57 @@ def test_the_channel_preparations_transpose_by_hand_is_jaxs(case):
     for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
         # dv is rounded to v's bfloat16 on both sides: a last bit apart
         close(g, w, f"d{name}", **(dict(CHANNEL_NEAR, tol=1e-2) if name == "v" else CHANNEL_NEAR))
+
+
+# ---------------------------------------------------------------------------
+# The backward's preparation call multiplies no level of the doubling again.
+# ---------------------------------------------------------------------------
+def _kernel_products(call, *args) -> int:
+    """``dot_general``s in the traced text of ``call``, its kernels' bodies
+    among them."""
+    def count(jaxpr):
+        found = 0
+        for eqn in jaxpr.eqns:
+            found += eqn.primitive.name == "dot_general"
+            for inner in eqn.params.values():
+                inner = getattr(inner, "jaxpr", inner)          # a ClosedJaxpr's own
+                if hasattr(inner, "eqns"):
+                    found += count(inner)
+        return found
+
+    return count(jax.make_jaxpr(call)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("form", ["scalar", "bounded", "halving"])
+def test_the_call_that_reads_t_multiplies_no_level_of_the_inverse(form):
+    """256 tokens in chunks of 64: four chunks a grid step, two a product,
+    so two products a step. The inverse by doubling is five levels of two
+    products each (``_Masks.inverses``); the call that reads the kept ``T``
+    holds none of them, and the scalar one drops ``K K^T`` too (``A`` was its
+    only reader). The channel forms' stacked ``[k; q]`` products stay whole."""
+    case = "cell_widths_four_chunks_a_step" if form == "scalar" else "cell_widths_4x2"
+    if form == "scalar":
+        (q, k, v, log_alpha, beta), chunk, _, _ = prepared(case)
+        operands = (q, k, v, G._gates(log_alpha, beta, chunk))
+        forward = functools.partial(G._delta_prepare_forward, chunk=chunk, interpret=True)
+    else:
+        (q, k, v, log_alpha, beta), chunk, _, _ = channel_prepared(case)
+        operands = (q, k, v, log_alpha, G._beta_lanes(beta, chunk))
+        forward = functools.partial(
+            G._channel_prepare_forward, chunk=chunk, interpret=True, bounded=form == "bounded"
+        )
+    seq = q.shape[1]
+    per_step = G._per_step(seq // chunk, chunk)
+    products = per_step // G._together(chunk, per_step)
+    assert (chunk, per_step, products) == (64, 4, 2)
+    kept = jax.ShapeDtypeStruct(G.kept_inverse_shape(3, seq, chunk), jnp.float32)
+    whole = _kernel_products(forward, *operands)
+    written = _kernel_products(functools.partial(forward, inverse="write"), *operands)
+    read = _kernel_products(
+        lambda *a: forward(*a[:-1], None, a[-1], inverse="read"), *operands, kept
+    )
+    levels = G._halving_levels(chunk) - 1                        # pairs are written down, not multiplied
+    assert written == whole
+    # W and U0, P (or the stacked products), and in the scalar form K K^T
+    assert whole - read == products * (2 * levels + (form == "scalar")), (whole, read)
+    assert read == products * {"scalar": 3, "bounded": 2 + 4, "halving": 2 + 6}[form]

@@ -120,7 +120,8 @@ def test_logits_match_the_reference(fam, params):
     check = fam.check(jax.jit(fam.forward)(params, x)[:, -8:], params, x, last=8)
     assert check["ok"] and check["published"]["rel_rms"] < 1e-4
     # six linear layers keep [2, 4 heads, 48 (one padded chunk), 24] float32
-    assert check["linear_state_gib"] == 6 * 2 * 4 * 48 * 24 * 4 / 2**30
+    # and T's diagonal blocks, a chunk of 48 float32 a head and token
+    assert check["linear_state_gib"] == 6 * 2 * 4 * 48 * (24 + 48) * 4 / 2**30
 
 
 STEP = 0.5

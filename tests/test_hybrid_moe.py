@@ -127,7 +127,9 @@ def test_the_tree_is_stacked_by_period_and_counted(fam, params):
     counted = T.config_num_params(model)
     assert counted == T.num_params(params) == hybrid_moe_flops.parameters(fam.config)
     assert counted == fam.parameters()
+    # five linear layers: the rule's output and T's diagonal blocks (a padded chunk of 48)
     assert T.linear_state_bytes(model, 2, 40) == 5 * T.kept_bytes(2, 4, 40, 16, 4)
+    assert T.kept_bytes(2, 4, 40, 16, 4) == 2 * 4 * 48 * (16 * 4 + 48 * 4)
     dims = T.param_logical_dims(model)
     is_dims = lambda x: isinstance(x, tuple)
     assert jax.tree.structure(dims, is_leaf=is_dims) == jax.tree.structure(params)
