@@ -79,6 +79,21 @@ checkpoint policy, one head and loss):
     router reads the un-normed stream at the LAYER's input, before the
     attention norm) and ``router_precision``.
 
+  * the ninth, a learned sparse attention over experts (Keye-VL-2.0's
+    language model; DeepSeek-V3.2-Exp's index scorer over grouped-query
+    heads): a fifth kind of layer, "sparse" (``sparse=``; without a pattern
+    every layer is one), whose mixer (``_sparse_mixer``, scope
+    ``sparse_attention``) is grouped-query attention over the ``topk`` keys
+    a query's INDEX SCORER chose, one set for all the heads of a batch row:
+    ``I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])`` from three projections
+    of the layer's normed input DETACHED (scope ``indexer``), the selection
+    (``index_select``) a mask that is data, handed to the three flash
+    kernels as a fourth operand, and a SECOND OUTPUT no other mixer has: the
+    scorer's own loss term a layer (scope ``index_loss``; the KL of the
+    scorer's distribution over the chosen keys against the attention's mean
+    over its heads, ops/sparse_index.py), which rides the layer scan beside
+    the experts' routing and is added to the cross-entropy in ``loss_fn``.
+
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
     written down ONCE, in the table below the configs: one function a part
@@ -110,7 +125,11 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     over a patterned model with linear or conv layers (dp / fsdp work),
     ``norm_placement="post"`` over expert layers, a window layer under a
     callable ``attention`` or through decode (a ring cache of ``window``
-    rows), a stated ``head_dim`` through decode or the pipeline.
+    rows), a stated ``head_dim`` through decode or the pipeline, a sparse
+    layer through decode (the index keys are not cached), the pipeline (the
+    scorer's term is not carried across stages), a callable ``attention``,
+    a mesh with tp or sp (dp / fsdp work), or beside a dense prefix (whose
+    scan hands on the stream alone: the scorer's term would be lost).
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -140,6 +159,9 @@ from ray_tpu.ops.grouped_matmul import TILE, grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.ops.sparse_index import (
+    RESIDUAL_NAMES as INDEX_RESIDUAL_NAMES, index_loss, index_select,
+)
 from ray_tpu.parallel.mesh import LogicalRules
 
 # The names the model and the optimizer give their work (jax.named_scope:
@@ -188,7 +210,12 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # (a "window" layer's whole mixer: q / k / v, RoPE, the kernels, W_o), and
 # within it "window_flash" (the flash kernels called with a window, forward
 # and backward: they are the "full" layers' jitted functions, so the scope is
-# what tells a window layer's calls apart).
+# what tells a window layer's calls apart). And a "sparse" layer's four, all
+# inside "attention": "sparse_attention" (the whole mixer), and within it
+# "indexer" (the scorer's three projections, its key's norm, RoPE, and the
+# scores: products, ReLU, weighted sum), "index_select" (the k-th largest
+# score a row, the comparison, the mask handed to the kernels) and
+# "index_loss" (the scorer's term and, made in its forward, its gradient).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
@@ -365,6 +392,28 @@ class LinearAttentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttentionConfig:
+    """A "sparse" layer's index scorer and selection, as ``KeyeVL2``'s
+    ``sa_config`` states them (DeepSeek-V3.2-Exp's lightning indexer over
+    grouped-query heads; ops/sparse_index.py has the formulas)."""
+    index_heads: int = 16
+    index_head_dim: int = 64
+    # Keys a query's attention sees: the ``topk`` of largest index score
+    # among the keys up to its own (all of them for the first ``topk``
+    # queries).
+    topk: int = 2048
+    # Query rows whose scores are alive at once (``q_chunk_size``): nothing
+    # ``[seq, seq]`` in float32 outlives a chunk. No result depends on it.
+    score_chunk: int = 512
+
+    def __post_init__(self):
+        if min(self.index_heads, self.index_head_dim, self.topk, self.score_chunk) < 1:
+            raise ValueError(f"{self!r}: index heads, their size, topk and the chunk are >= 1")
+        if self.index_head_dim % 2:
+            raise ValueError(f"index_head_dim {self.index_head_dim}: RoPE turns pairs of dims")
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     dim: int = 4096
@@ -426,6 +475,10 @@ class TransformerConfig:
     layer_pattern: tuple[str, ...] | None = None
     # The mixer of the pattern's "linear" layers.
     linear: LinearAttentionConfig | None = None
+    # The index scorer of the "sparse" layers: grouped-query attention over
+    # the keys it chooses. Without a ``layer_pattern`` every layer is then
+    # sparse (``layer_kind``); a pattern may name the kind beside others.
+    sparse: SparseAttentionConfig | None = None
     # The taps of a "conv" layer's gated short convolution (``conv_L_cache``).
     conv_kernel: int = 3
     # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
@@ -464,6 +517,21 @@ class TransformerConfig:
                 f"rope_kinds {self.rope_kinds!r}: the attention kinds are 'full' and 'window', "
                 "and latent attention always turns its shared rope key"
             )
+        if self.sparse is not None:
+            if self.latent:
+                raise ValueError("sparse= chooses keys for grouped-query attention, not latent=")
+            if callable(self.attention):
+                raise NotImplementedError(
+                    "a sparse layer under a callable attention= (ring, ulysses) is not written: "
+                    "the selection is an operand of the flash kernels (attention='flash' | "
+                    "'reference'), and a shard of the sequence holds a shard of every query's keys"
+                )
+            if self.first_dense_layers:
+                raise NotImplementedError(
+                    "sparse= with first_dense_layers is not written: the dense prefix's scan "
+                    "hands on the stream alone, so a sparse layer there would lose its scorer's "
+                    "term and its scorer would never train"
+                )
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - set(LAYER_KINDS)
@@ -484,6 +552,8 @@ class TransformerConfig:
                     "a window layer under a callable attention= (ring, ulysses) is not written: "
                     "the window is the flash kernels' (attention='flash' | 'reference')"
                 )
+        if ("sparse" in self._kinds()) != (self.sparse is not None):
+            raise ValueError("a pattern names sparse layers exactly where sparse= describes them")
         if "linear" in self._kinds():
             la = self.linear
             if la is None:
@@ -506,9 +576,15 @@ class TransformerConfig:
         return self.output_gate or (self.latent.output_gate if self.latent else None)
 
     @property
+    def layer_kind(self) -> str:
+        """Every layer's mixer where there is no pattern."""
+        return "full" if self.sparse is None else "sparse"
+
+    @property
     def prefix_kind(self) -> str:
         """The mixer of the leading dense layers: ``first_dense_kind`` says
-        under a pattern, and without one every layer is "full"."""
+        under a pattern, and without one every layer is "full" (``sparse=``
+        refuses a prefix)."""
         return self.first_dense_kind if self.layer_pattern else "full"
 
     @property
@@ -600,6 +676,23 @@ def _gqa_leaves(config: TransformerConfig) -> dict:
         "wv": _Leaf((d, kv_out), ("embed", "kv")),
         "wo": _Leaf((q_out, d), ("heads", "embed")),
         **({"q_norm": _norm(q_norm), "k_norm": _norm(k_norm)} if normed else {}),
+    }
+
+
+def _sparse_leaves(config: TransformerConfig) -> dict:
+    """A "sparse" layer's mixer: grouped-query attention's leaves and the
+    index scorer's, ``index heads`` queries of ``index_head_dim``, ONE key
+    for them all with its norm's weight, and a weight an index head. The
+    scorer is one for all the heads of a row, so it is whole wherever a
+    layer's stream is (its columns are not cut over ``tp``, which a sparse
+    layer refuses anyway)."""
+    d, sa = config.dim, config.sparse
+    return {
+        **_gqa_leaves(config),
+        "wq_index": _Leaf((d, sa.index_heads * sa.index_head_dim), ("embed", None)),
+        "wk_index": _Leaf((d, sa.index_head_dim), ("embed", None)),
+        "k_index_norm": _norm(sa.index_head_dim),
+        "w_index": _Leaf((d, sa.index_heads), ("embed", None)),
     }
 
 
@@ -739,7 +832,7 @@ def _stacks(config: TransformerConfig) -> dict:
     prefix, experts = config.first_dense_layers, config.moe is not None
     stacks = {"dense_layers": stack(config.prefix_kind, False, prefix)} if prefix else {}
     if not config.layer_pattern:
-        return {**stacks, "layers": stack("full", experts, config.n_layers - prefix)}
+        return {**stacks, "layers": stack(config.layer_kind, experts, config.n_layers - prefix)}
     counts = {kind: config.layer_pattern.count(kind) for kind in config.layer_pattern}
     return {**stacks, "layers": {
         kind: stack(kind, experts, config.periods, count) for kind, count in counts.items()
@@ -809,7 +902,8 @@ def _is_dims(node) -> bool:
     )
 
 
-def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: tuple) -> Callable:
+def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: tuple,
+               what: str | None = None) -> Callable:
     """``kernel``, per shard when traced under a device mesh: GSPMD cannot
     partition a Mosaic kernel ("wrap the call in a shard_map"), so under the
     mesh that build_sharded_train_step traces in, each device runs it on its
@@ -822,17 +916,19 @@ def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: t
     runs on a slice of the heads' parameters). ``sums`` names the entries of
     the kernel's second result, a dict, that are summed over the data
     shards. No mesh in scope (one device, or a caller that places everything
-    itself): ``kernel`` itself, on the operands as they come."""
+    itself): ``kernel`` itself, on the operands as they come. ``what``: the refusal's
+    own words for who refuses an axis and why (None: the linear and conv
+    layers')."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return kernel
     for axis in refuse:
         if dict(mesh.shape).get(axis, 1) > 1:
-            raise NotImplementedError(
-                f"a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
+            raise NotImplementedError((what or (
+                "a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
                 "not written: the scan and convolution kernels run per data shard (dp / "
                 "fsdp) with every head, every channel and the whole sequence"
-            )
+            )).format(axis=axis))
     rules = LogicalRules()
     specs = lambda tree: jax.tree.map(
         lambda dims: jax.sharding.PartitionSpec() if dims is None else rules.spec(dims, mesh),
@@ -863,13 +959,18 @@ def _repeat_kv(x: jax.Array, repeats: int) -> jax.Array:
     return jnp.repeat(x, repeats, axis=1)
 
 
-def _head_shards() -> int:
-    """Into how many the mesh in scope cuts an array's "heads" (1: no mesh)."""
+def _shards(dim: str) -> int:
+    """Into how many the mesh in scope cuts an array's logical ``dim`` (1: no mesh)."""
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return 1
-    axes = LogicalRules().spec(("heads",), mesh)[0] or ()
+    axes = LogicalRules().spec((dim,), mesh)[0] or ()
     return math.prod(mesh.shape[axis] for axis in ((axes,) if isinstance(axes, str) else axes))
+
+
+def _head_shards() -> int:
+    """Into how many the mesh in scope cuts an array's "heads" (1: no mesh)."""
+    return _shards("heads")
 
 
 def _flash_over_mesh(q, k, v, causal, window=None):
@@ -1222,32 +1323,116 @@ def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attenti
     return _heads_out(o, layer)
 
 
+def _index_operands(h, layer, config: TransformerConfig, positions):
+    """The index scorer's operands of the DETACHED branch input ``h``:
+    ``qI`` ``[batch, seq, index heads, dim]`` and the ONE key ``kI`` ``[batch,
+    seq, dim]`` under an RMSNorm with a weight, both turned by RoPE over the
+    whole ``index_head_dim`` at the model's ``rope_theta`` (none where the
+    model has none), and ``w`` ``[batch, seq, index heads]`` float32 times
+    ``index_heads^-1/2 index_head_dim^-1/2``. The products in the model's
+    dtype with float32 accumulators, as the published code runs its scorer
+    in low precision."""
+    sa = config.sparse
+    batch, seq, _ = h.shape
+    project = lambda name: jnp.matmul(h, layer[name], preferred_element_type=jnp.float32)
+    q_index = project("wq_index").astype(h.dtype).reshape(batch, seq, sa.index_heads, -1)
+    k_index = rmsnorm_reference(
+        project("wk_index"), layer["k_index_norm"], eps=config.rms_norm_eps
+    ).astype(h.dtype)
+    if config.rope_theta is not None:
+        cos, sin = rope_frequencies(sa.index_head_dim, config.max_seq, config.rope_theta)
+        q_index = apply_rope(q_index.transpose(0, 2, 1, 3), cos, sin, positions).transpose(0, 2, 1, 3)
+        k_index = apply_rope(k_index[:, None], cos, sin, positions)[:, 0]
+    w = project("w_index") * (sa.index_heads ** -0.5 * sa.index_head_dim ** -0.5)
+    return q_index, k_index, w
+
+
+def _sparse_attend(config: TransformerConfig, q, k, v, q_index, k_index, w):
+    """One data shard's sparse attention: the selection, the attention over
+    it, and the scorer's term, a mean over THIS shard's tokens."""
+    sa = config.sparse
+    selection = index_select(q_index, k_index, w, topk=sa.topk, chunk=sa.score_chunk)
+    if config.attention == "flash":
+        out, lse = flash_attention(q, k, v, selection=selection, return_lse=True)
+    else:
+        repeats = q.shape[1] // k.shape[1]
+        out, lse = attention_reference(
+            q, _repeat_kv(k, repeats), _repeat_kv(v, repeats), selection=selection, return_lse=True
+        )
+    with jax.named_scope("index_loss"):
+        term = index_loss(
+            q_index, k_index, w, q, k, selection, lse,
+            scale=config.head_dim ** -0.5, chunk=sa.score_chunk,
+        )
+    return out, {"index_loss": term, "selection": selection}
+
+
+def _sparse_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
+    """A "sparse" layer's mixer: ``(out, terms)``. Grouped-query attention
+    (q / k / v, their norms, RoPE, ``W_o``: ``_gqa_mixer``'s) in which query
+    ``t`` sees the ``min(t + 1, topk)`` keys its index scorer scored highest,
+    one set for all the heads of its batch row; ``terms`` holds
+    ``index_loss``, the scorer's loss a layer, and the ``selection`` itself
+    (int8 ``[batch, seq, seq]``, for a check to read).
+    The scorer reads ``h`` DETACHED: the cross-entropy trains the attention
+    and never the scorer, ``index_loss`` the scorer's four leaves and
+    nothing else (ops/sparse_index.py). Per data shard under a mesh (dp,
+    fsdp); a mesh that cuts the heads or the sequence is refused."""
+    with jax.named_scope("sparse_attention"):
+        q, k, v = _qkv(h, layer, config)
+        if cos_sin is not None:
+            cos, sin = cos_sin
+            q, k = apply_rope(q, cos, sin, positions), apply_rope(k, cos, sin, positions)
+        with jax.named_scope("indexer"):
+            scorer = _index_operands(jax.lax.stop_gradient(h), layer, config, positions)
+        heads, rows = ("batch", "heads", None, None), ("batch", None, None)
+        attend = _over_mesh(
+            functools.partial(_sparse_attend, config),
+            (heads, heads, heads, ("batch", None, None, None), rows, rows),
+            (heads, {"index_loss": None, "selection": rows}), ("tp", "sp"), ("index_loss",),
+            what="a sparse layer over a mesh with {axis} > 1 is not written: a query's ONE "
+            "selection serves all its heads and reads every key before it, so the scorer, the "
+            "selection and its loss term run per data shard (dp / fsdp) with every head and "
+            "the whole sequence",
+        )
+        out, terms = attend(q, k, v, *scorer)
+        # the data shards' means were summed
+        term = terms["index_loss"] / _shards("batch")
+        return _heads_out(out, layer), {**terms, "index_loss": term}
+
+
 # The kinds of mixer: what a ``layer_pattern`` may name. A kind is one row:
 # its leaves (the table above) and ``apply(h, layer, config, cos_sin,
 # positions, attention_fn)``, the mixer on the branch input (a kind with no
-# use for the last three takes them as ``*_``). A layer is told its kind by
-# whoever walks the stack it lies in; nothing looks at its leaves to guess.
+# use for the last three takes them as ``*_``); a "sparse" layer's returns
+# ``(out, terms)``, its output and what it hands out beside it. A layer is told
+# its kind by whoever walks the stack it lies in; nothing looks at its
+# leaves to guess.
 _MIXERS = {
     "linear": (_linear_leaves, _linear_mixer),
     "full": (_full_leaves, _full_mixer),
     "conv": (_conv_leaves, _conv_mixer),
     "window": (_gqa_leaves, _window_mixer),
+    "sparse": (_sparse_leaves, _sparse_mixer),
 }
 LAYER_KINDS = tuple(_MIXERS)
 
 
 def _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn):
-    """``x + mixer(norm(x))``, or under ``norm_placement="post"`` ``x +
-    norm(mixer(x))``, the mixer that of the layer's ``kind``."""
+    """``(x + mixer(norm(x)), terms)``, or under ``norm_placement="post"`` ``x
+    + norm(mixer(x))``, the mixer that of the layer's ``kind``; ``terms`` is
+    what a "sparse" layer's mixer hands out beside its output (the scalar
+    ``index_loss`` the loss adds, and its ``selection``), None of any other."""
     post = config.norm_placement == "post"
     if config.rope_kinds is not None and kind not in config.rope_kinds:
         cos_sin = None
     with jax.named_scope("attention"):
         h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
         out = _MIXERS[kind][1](h, layer, config, cos_sin, positions, attention_fn)
+        out, terms = out if isinstance(out, tuple) else (out, None)
         if post:
             out = _rmsnorm_ckpt(out.astype(x.dtype), layer["attn_norm"], config.rms_norm_eps)
-        return x + out.astype(x.dtype)
+        return x + out.astype(x.dtype), terms
 
 
 def _rope_tables(config: TransformerConfig):
@@ -2106,7 +2291,7 @@ def _remat_policy(remat: str) -> Callable:
     kernels again too), and under "dots" the matmul outputs besides."""
     policies = jax.checkpoint_policies
     flash = policies.save_only_these_names(
-        *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES
+        *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *INDEX_RESIDUAL_NAMES
     )
     if remat == "full":
         return flash
@@ -2124,26 +2309,39 @@ def forward(
 
 def forward_with_routing(
     params: dict, tokens: jax.Array, config: TransformerConfig, positions: jax.Array | None = None,
+    selections: bool = False,
 ) -> tuple[jax.Array, dict | None]:
     """``forward`` and the layer scan's stacked MoE ``routing`` (leading
     dim: layers; see ``_moe_mlp``), None for a dense model: what a
-    reference check reads."""
-    x, routing = _hidden_with_routing(params, tokens, config, positions)
+    reference check reads. Over sparse layers it also holds ``index_loss``
+    ``[layers]`` and, with ``selections``, each layer's ``selection``
+    ``[layers, batch, seq, seq]`` int8."""
+    x, routing = _hidden_with_routing(params, tokens, config, positions, selections)
     return _head(params, x, config), routing
 
 
-def _hidden_with_routing(params, tokens, config, positions=None):
+def _hidden_with_routing(params, tokens, config, positions=None, selections=False):
     """The last layer's output ``[batch, seq, hidden]``, before the final
     norm, and the expert layers' stacked ``routing`` that ``loss_fn``'s
-    balancing loss reads. A dense prefix (``params["dense_layers"]``) is
-    scanned first, under the same checkpoint policy."""
+    balancing loss reads, with the sparse layers' ``index_loss`` (0 of a
+    layer of another kind) and, with ``selections``, their ``selection``. A
+    dense prefix (``params["dense_layers"]``) is scanned first, under the
+    same checkpoint policy."""
+    if selections and (config.sparse is None or config.layer_pattern):
+        raise NotImplementedError("selections=True reads an unpatterned sparse model's layers")
     attention_fn = _attention_impl(config)
     cos_sin = _rope_tables(config)
     x = _embed(params, tokens)
 
     def layer_step(kind, experts, carry, layer):
-        x = _attention_block(carry, layer, kind, config, cos_sin, positions, attention_fn)
-        return _mlp_block(x, layer, config, experts, carry)
+        x, terms = _attention_block(carry, layer, kind, config, cos_sin, positions, attention_fn)
+        x, routing = _mlp_block(x, layer, config, experts, carry)
+        if config.sparse is not None:
+            # the scorer's term rides the scan beside the experts' routing
+            terms = terms or {"index_loss": jnp.zeros((), jnp.float32)}
+            kept = ("index_loss", "selection") if selections else ("index_loss",)
+            routing = {**(routing or {}), **{name: terms[name] for name in kept}}
+        return x, routing
 
     policy = None if config.remat is None else _remat_policy(config.remat)
 
@@ -2159,7 +2357,7 @@ def _hidden_with_routing(params, tokens, config, positions=None):
     if config.layer_pattern:
         steps = {kind: step(kind, experts) for kind in params["layers"]}
         return _scan_periods(steps, x, params["layers"], config.layer_pattern)
-    return _scan_layers(step("full", experts), x, params["layers"])
+    return _scan_layers(step(config.layer_kind, experts), x, params["layers"])
 
 
 def logits_loss(
@@ -2330,6 +2528,9 @@ def loss_fn(
     if config.moe and config.moe.aux_loss_coef:
         with jax.named_scope("loss"):
             loss = loss + config.moe.aux_loss_coef * load_balancing_loss(routing, config.moe)
+    if config.sparse is not None:
+        with jax.named_scope("loss"):
+            loss = loss + jnp.sum(routing["index_loss"])
     return loss
 
 
@@ -2374,6 +2575,12 @@ def _refuse_stated_head_dim(config: TransformerConfig, what: str) -> None:
 
 def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
     _refuse_stated_head_dim(config, what)
+    if config.sparse is not None:
+        raise NotImplementedError(
+            f"{what} over sparse layers is not written: a layer's scorer term (the index "
+            "loss) is a second output of its stage that nothing carries to the last stage's "
+            "loss: train it fused (loss_fn)"
+        )
     if config.layer_pattern:
         raise NotImplementedError(
             f"{what} splits ONE stacked layer tree; a config with a layer_pattern stacks "
@@ -2452,7 +2659,7 @@ def stage_forward(
         x = _embed(stage_params, x)
 
     def layer_step(carry, layer):
-        h_in = _attention_block(carry, layer, "full", config, cos_sin, positions, attention_fn)
+        h_in, _ = _attention_block(carry, layer, "full", config, cos_sin, positions, attention_fn)
         # The MoE balancing loss is not carried across stages.
         return _mlp_block(h_in, layer, config, config.moe is not None, carry)[0], None
 
@@ -2466,6 +2673,12 @@ def stage_forward(
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 def _refuse_latent_cache(config: TransformerConfig) -> None:
+    if config.sparse is not None:
+        raise NotImplementedError(
+            "decode over a sparse layer needs the index keys cached beside K and V (one "
+            "[index_head_dim] key a token and layer, scored against each new query before its "
+            "attention reads the chosen rows), which is not written yet"
+        )
     _refuse_stated_head_dim(config, "decode")
     if config.layer_pattern and "window" in config._kinds():
         raise NotImplementedError(

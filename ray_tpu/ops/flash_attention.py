@@ -234,10 +234,11 @@ def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
 
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
-                   block_q, block_k, precision, causal_offset, window=None):
-    """scale * Q K^T with the causal mask (and the window's lower edge)
-    applied — shared by all three kernels so forward and backward can never
-    desynchronize."""
+                   block_q, block_k, precision, causal_offset, window=None,
+                   sel_ref=None):
+    """scale * Q K^T with the causal mask (and the window's lower edge, and
+    the ``selection``'s tile) applied — shared by all three kernels so
+    forward and backward can never desynchronize."""
     q = _mxu(q_ref[0], precision)                # [block_q, d]
     k = _mxu(k_ref[0], precision)                # [block_k, d]
     s = jax.lax.dot_general(
@@ -258,14 +259,33 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
         visible = q_pos >= k_pos
         if window is not None:
             visible &= k_pos > q_pos - window
+        if sel_ref is not None:
+            visible &= _chosen(sel_ref)
         s = jnp.where(visible, s, _NEG_INF)
+    elif sel_ref is not None:
+        s = jnp.where(_chosen(sel_ref), s, _NEG_INF)
     return s, q, k
+
+
+def _chosen(sel_ref):
+    """A selection tile ``[block_q, block_k]`` of int8 as a mask: widened
+    first, the comparison Mosaic takes on every generation."""
+    return sel_ref[0].astype(jnp.int32) != 0
+
+
+def _selected(kernel, operands: int):
+    """``kernel`` for a call with a ``selection``: its tile's ref comes
+    behind the ``operands`` refs the kernel has without one."""
+    def with_selection(*refs, **static):
+        return kernel(*refs[:operands], *refs[operands + 1:], sel_ref=refs[operands], **static)
+
+    return with_selection
 
 
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
     causal, block_q, block_k, num_kv_blocks, precision, causal_offset, window,
-    num_steps
+    num_steps, sel_ref=None
 ):
     step = pl.program_id(2)
     q_index = pl.program_id(1)
@@ -287,7 +307,7 @@ def _flash_fwd_kernel(
         s, _, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset, window=window,
+            causal_offset=causal_offset, window=window, sel_ref=sel_ref,
         )
 
         # Running max and sum are kept replicated across a vreg's lanes:
@@ -322,7 +342,7 @@ def _flash_fwd_kernel(
 def _flash_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
     scale, causal, block_q, block_k, num_kv_blocks, precision, causal_offset,
-    window, num_steps
+    window, num_steps, sel_ref=None
 ):
     step = pl.program_id(2)
     q_index = pl.program_id(1)
@@ -341,7 +361,7 @@ def _flash_dq_kernel(
         s, _, k = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset, window=window,
+            causal_offset=causal_offset, window=window, sel_ref=sel_ref,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)                     # [block_q, block_k] f32
@@ -367,7 +387,7 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_q_blocks,
-    precision, causal_offset, window, num_steps, group
+    precision, causal_offset, window, num_steps, group, sel_ref=None
 ):
     # One output row a KV head: the scratch sums its ``group`` query heads'
     # steps in float32 (grid axes 2 and 3, both sequential; the K / V tiles
@@ -396,7 +416,7 @@ def _flash_dkv_kernel(
         s, q, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
-            causal_offset=causal_offset, window=window,
+            causal_offset=causal_offset, window=window, sel_ref=sel_ref,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)
@@ -440,7 +460,9 @@ def flash_attention(
     interpret: bool | None = None,
     precision: jax.lax.Precision | None = None,
     window: int | None = None,
-) -> jax.Array:
+    selection: jax.Array | None = None,
+    return_lse: bool = False,
+) -> jax.Array | tuple[jax.Array, jax.Array]:
     """q: [batch, heads, seq_q, head_dim]; k: [batch, kv_heads, seq_k,
     head_dim]; v: [batch, kv_heads, seq_k, v_dim], ``kv_heads`` dividing
     ``heads`` (grouped-query attention: query head ``h`` reads KV head ``h //
@@ -467,47 +489,71 @@ def flash_attention(
     i`` (positions aligned to the END of the keys where ``seq_q < seq_k``,
     the decode convention). Static; the tiles wholly outside the band are
     neither computed nor fetched. Needs ``causal=True``.
+
+    selection: None, or a mask that is DATA: int8 ``[batch, seq_q, seq_k]``,
+    nonzero where the query may see the key, one set of keys a query for all
+    the heads of its batch row (a learned sparse attention's chosen keys).
+    It is a fourth operand of the three kernels: a tile of it ``[block_q,
+    block_k]`` masks the tile's scores beside the causal edge, so the
+    softmax, ``lse`` and the backward's ``delta`` run over the chosen keys
+    alone and no ``[heads, seq, seq]`` array or gathered K / V exists. It
+    carries no gradient. Every query needs one chosen key it may see.
+    ``return_lse``: also the float32 log-sum-exp ``[batch, heads, seq_q]`` of
+    the scaled scores over the keys seen, DETACHED (no gradient flows through
+    it: what a scorer's own loss term reads of the attention's distribution).
+    Without a selection the three Mosaic modules are what they were before
+    there was one.
     """
     if window is not None and (not causal or window < 1):
         raise ValueError(
             f"flash_attention: window={window!r} needs causal=True and window >= 1: the window "
             "is the causal mask's lower edge"
         )
+    if selection is not None and selection.shape != (q.shape[0], q.shape[2], k.shape[2]):
+        raise ValueError(
+            f"flash_attention: selection {selection.shape} is not [batch, seq_q, seq_k] "
+            f"{(q.shape[0], q.shape[2], k.shape[2])}: one set of keys a query and batch row"
+        )
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _flash_vjp(q, k, v, causal, float(scale), block_q, block_k,
-                      resolve_interpret(interpret), precision, window)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_vjp(q, k, v, causal, scale, block_q, block_k, interpret, precision,
-               window):
-    out, _ = _flash_forward(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, precision=precision, window=window,
+    out, lse = _flash_vjp(
+        q, k, v, selection, causal, float(scale), block_q, block_k,
+        resolve_interpret(interpret), precision, window,
     )
-    return out
+    return (out, lse) if return_lse else out
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   precision, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_vjp(q, k, v, selection, causal, scale, block_q, block_k, interpret,
+               precision, window):
+    """``(out, lse)`` under a ``selection`` (None, an empty pytree: none).
+    ``lse`` is handed out detached: its cotangent is dropped."""
+    return _flash_forward(
+        q, k, v, selection, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, precision=precision, window=window,
+    )
+
+
+def _flash_vjp_fwd(q, k, v, selection, causal, scale, block_q, block_k,
+                   interpret, precision, window):
     out, lse = _flash_forward(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret, precision=precision, window=window,
+        q, k, v, selection, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, precision=precision, window=window,
     )
     out = checkpoint_name(out, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
-    return out, (q, k, v, out, lse)
+    return (out, lse), (q, k, v, selection, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, precision,
                    window, residuals, g):
-    q, k, v, out, lse = residuals
-    return _flash_backward(
-        q, k, v, out, lse, g, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, precision=precision,
-        window=window,
+    q, k, v, selection, out, lse = residuals
+    grads = _flash_backward(
+        q, k, v, out, lse, g[0], selection, causal=causal, scale=scale,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        precision=precision, window=window,
     )
+    return (*grads, None)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -523,17 +569,24 @@ def _kv_group(q, k, v):
     return heads // kv_heads
 
 
-def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype):
+def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype, selection=False):
     """The kernels' block shape, from the shapes they see (``head_dim``:
-    the larger of q / k's and v's). ``block_q`` / ``block_k`` of None (the
-    default) ask for the block that measured fastest on a v5e (PERF.md,
-    PR 25); an int is an upper bound."""
+    the larger of q / k's and v's; ``selection``: whether a selection tile
+    rides along). ``block_q`` / ``block_k`` of None (the default) ask for
+    the block that measured fastest on a v5e (PERF.md, PR 25); an int is an
+    upper bound."""
     # 1024 x 1024 measured fastest at every length from 1024 to 16384
     # (head_dim 128, bfloat16): a grid step's fixed cost is spread over four
     # times the score elements of 512 x 512. Operand rows over 512 bytes
     # (head_dim 256 in float32) do not fit the 16 MiB of scoped VMEM beside
     # a 1024 x 1024 float32 score tile.
-    fitting = 1024 if head_dim * jnp.dtype(dtype).itemsize <= 512 else 512
+    # A selection's tile is block_q x block_k bytes, twice (an operand is
+    # double-buffered), and once more widened to int32 for the comparison: 6
+    # MiB at 1024 x 1024, which fit beside operand rows of 256 bytes (head_dim
+    # 128 in bfloat16: compiled for a v5e, tests/test_chip_compile.py) and
+    # are not tried beside wider ones.
+    row_bytes = 256 if selection else 512
+    fitting = 1024 if head_dim * jnp.dtype(dtype).itemsize <= row_bytes else 512
     if block_q is None:
         block_q = fitting
     if block_k is None:
@@ -564,6 +617,7 @@ def _flash_forward(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
+    selection: jax.Array | None = None,
     *,
     causal: bool = True,
     scale: float | None = None,
@@ -580,7 +634,7 @@ def _flash_forward(
     if scale is None:
         scale = dim ** -0.5
     block_q, block_k = _block_sizes(
-        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype
+        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype, selection is not None
     )
 
     bh = batch * heads
@@ -609,14 +663,24 @@ def _flash_forward(
     )
     from jax.experimental.pallas import tpu as pltpu
 
+    operands = [qr, kr, vr]
+    in_specs = [
+        pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_k, dim), kv_map),
+        pl.BlockSpec((1, block_k, v_dim), kv_map),
+    ]
+    if selection is not None:
+        # the batch row's one tile for each of its heads, at the kv block the
+        # K / V maps name (a skipped step fetches none)
+        kernel = _selected(kernel, len(operands))
+        operands.append(selection)
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k), lambda i, j, kv: (i // heads, j, kv_map(i, j, kv)[1])
+        ))
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_q_blocks, kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), kv_map),
-            pl.BlockSpec((1, block_k, v_dim), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
             pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
@@ -631,7 +695,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, v_dim), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
-    )(qr, kr, vr)
+    )(*operands)
     return out.reshape(batch, heads, seq_q, v_dim), lse.reshape(
         batch, heads, seq_q
     )
@@ -645,14 +709,14 @@ def _flash_forward(
     ),
 )
 def _flash_backward(
-    q, k, v, out, lse, g, *, causal, scale, block_q, block_k, interpret,
-    precision, window=None
+    q, k, v, out, lse, g, selection=None, *, causal, scale, block_q, block_k,
+    interpret, precision, window=None
 ):
     batch, heads, seq_q, dim = q.shape
     kv_heads, seq_k, v_dim = v.shape[1:]
     group = _kv_group(q, k, v)
     block_q, block_k = _block_sizes(
-        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype
+        seq_q, seq_k, block_q, block_k, max(dim, v_dim), q.dtype, selection is not None
     )
 
     bh, bkv = batch * heads, batch * kv_heads
@@ -685,22 +749,30 @@ def _flash_backward(
     kv_map = _kv_index_map(
         causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
     )
+    operands = [qr, kr, vr, dor, lser, delta]
+    selected = () if selection is None else (selection,)
+    dq_specs = [
+        pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_k, dim), kv_map),
+        pl.BlockSpec((1, block_k, v_dim), kv_map),
+        pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
+    ]
+    if selection is not None:
+        dq_kernel = _selected(dq_kernel, len(operands))
+        dq_specs.append(pl.BlockSpec(
+            (1, block_q, block_k), lambda i, j, kv: (i // heads, j, kv_map(i, j, kv)[1])
+        ))
     dq = pl.pallas_call(
         dq_kernel,
         grid=(bh, num_q_blocks, steps["kv"]),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_k, dim), kv_map),
-            pl.BlockSpec((1, block_k, v_dim), kv_map),
-            pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
-        ],
+        in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, dim), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
+    )(*operands, *selected)
 
     dkv_kernel = functools.partial(
         _flash_dkv_kernel,
@@ -713,18 +785,25 @@ def _flash_backward(
         causal, causal_offset, block_q, block_k, num_q_blocks, window, group
     )
     kv_row = lambda i, j, *g_qi: (i, j, 0)
+    dkv_specs = [
+        pl.BlockSpec((1, block_q, dim), q_map),
+        pl.BlockSpec((1, block_k, dim), kv_row),
+        pl.BlockSpec((1, block_k, v_dim), kv_row),
+        pl.BlockSpec((1, block_q, v_dim), q_map),
+        pl.BlockSpec((1, block_q, 1), q_map),
+        pl.BlockSpec((1, block_q, 1), q_map),
+    ]
+    if selection is not None:
+        dkv_kernel = _selected(dkv_kernel, len(operands))
+        dkv_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda i, j, *g_qi: (i // kv_heads, q_map(i, j, *g_qi)[1], j),
+        ))
     dk, dv = pl.pallas_call(
         dkv_kernel,
         # one K / V row a KV head; a group axis only where a group is
         grid=(bkv, num_kv_blocks, *((group,) if group > 1 else ()), steps["q"]),
-        in_specs=[
-            pl.BlockSpec((1, block_q, dim), q_map),
-            pl.BlockSpec((1, block_k, dim), kv_row),
-            pl.BlockSpec((1, block_k, v_dim), kv_row),
-            pl.BlockSpec((1, block_q, v_dim), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-        ],
+        in_specs=dkv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, dim), kv_row),
             pl.BlockSpec((1, block_k, v_dim), kv_row),
@@ -738,7 +817,7 @@ def _flash_backward(
             pltpu.VMEM((block_k, v_dim), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, delta)
+    )(*operands, *selected)
 
     return (
         dq.reshape(q.shape),
@@ -749,10 +828,14 @@ def _flash_backward(
 
 def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-    scale: float | None = None, window: int | None = None
-) -> jax.Array:
+    scale: float | None = None, window: int | None = None,
+    selection: jax.Array | None = None, return_lse: bool = False,
+) -> jax.Array | tuple[jax.Array, jax.Array]:
     """Pure-jax reference used for kernel numerics tests; ``window`` as
-    ``flash_attention``'s: the ``window`` keys up to the query's own."""
+    ``flash_attention``'s: the ``window`` keys up to the query's own;
+    ``selection`` and ``return_lse`` as there: ``[batch, seq_q, seq_k]``,
+    nonzero where the query may see the key, and the detached float32
+    log-sum-exp of the scores over the keys seen."""
     if window is not None and (not causal or window < 1):
         raise ValueError(f"attention_reference: window={window!r} needs causal=True and window >= 1")
     dim = q.shape[-1]
@@ -765,5 +848,10 @@ def attention_reference(
         if window is not None:
             mask &= ~jnp.tril(mask, seq_k - seq_q - window)
         s = jnp.where(mask, s, _NEG_INF)
+    if selection is not None:
+        s = jnp.where(selection[:, None] != 0, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+    if not return_lse:
+        return out
+    return out, jax.lax.stop_gradient(jax.nn.logsumexp(s, axis=-1))

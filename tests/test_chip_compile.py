@@ -205,6 +205,38 @@ def test_flash_under_a_window_compiles_for_v5e(one_chip):
     assert sum("_flash_backward" in name for name in calls) == 2      # dq, and dk + dv
 
 
+def test_flash_under_a_selection_compiles_for_v5e(one_chip):
+    """The sparse layers' call of ``keye-vl-2.0-30b-a3b``, q ``[1, 32, 16384,
+    128]`` on K / V of 4 heads in bfloat16 under a selection that is DATA
+    (int8 ``[1, 16384, 16384]``): forward with ``lse`` handed out, and forward
+    + dq + dkv, compile for the chip in the 1024 x 1024 blocks
+    ``_block_sizes`` keeps beside the selection's tile (an int8 tile of 1 MiB,
+    double-buffered and widened to int32 in the kernel, fits the scoped VMEM
+    at operand rows of 256 bytes); wider rows take 512."""
+    from ray_tpu.ops.flash_attention import _block_sizes
+
+    assert _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16, True) == (1024, 1024)
+    assert _block_sizes(16384, 16384, None, None, 128, jnp.float32, True) == (512, 512)
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    shapes = (shape(1, 32, 16384, 128), shape(1, 4, 16384, 128), shape(1, 4, 16384, 128),
+              shape(1, 16384, 16384, dtype=jnp.int8))
+
+    def selected(q, k, v, selection):
+        out, lse = flash_attention(q, k, v, selection=selection, return_lse=True, interpret=False)
+        return out.astype(jnp.float32).sum() + lse.sum()
+
+    assert _custom_calls(selected, *shapes) == 1
+    grads = jax.grad(selected, argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(*shapes).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 3
+    assert sum("_flash_forward" in name for name in calls) == 1
+    assert sum("_flash_backward" in name for name in calls) == 2      # dq, and dk + dv
+    assert "s8[1,16384,16384]" in text and "[32,16384,16384]" not in text
+    assert [g.shape for g in jax.eval_shape(grads, *shapes)] == [
+        (1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128)]
+
+
 # What the rule's value-and-gradient program may hold beside its arguments
 # and results at the cell's size: two heads a call need 1.41 GiB. Under PR
 # 33's ``lax.map`` they needed 1.19 (three 1.32, six 1.79, all thirty at once

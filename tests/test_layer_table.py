@@ -123,7 +123,7 @@ def test_the_count_from_shapes_is_the_count_of_the_arrays(shape):
 
 
 def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
-    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv", "window")
+    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv", "window", "sparse")
     with pytest.raises(ValueError, match="kinds are"):
         T.TransformerConfig.tiny(layer_pattern=("full", "sliding"))
 
