@@ -215,7 +215,9 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # "indexer" (the scorer's three projections, its key's norm, RoPE, and the
 # scores: products, ReLU, weighted sum), "index_select" (the k-th largest
 # score a row, the comparison, the mask handed to the kernels) and
-# "index_loss" (the scorer's term and, made in its forward, its gradient).
+# "index_loss" (the scorer's term and, made in its forward, its gradient: two
+# Mosaic kernels, ops/sparse_index.py's ``_index_loss_lse`` and
+# ``_index_loss_terms``, and the transposes around them).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack.
@@ -1360,10 +1362,7 @@ def _sparse_attend(config: TransformerConfig, q, k, v, q_index, k_index, w):
             q, _repeat_kv(k, repeats), _repeat_kv(v, repeats), selection=selection, return_lse=True
         )
     with jax.named_scope("index_loss"):
-        term = index_loss(
-            q_index, k_index, w, q, k, selection, lse,
-            scale=config.head_dim ** -0.5, chunk=sa.score_chunk,
-        )
+        term = index_loss(q_index, k_index, w, q, k, selection, lse, scale=config.head_dim ** -0.5)
     return out, {"index_loss": term, "selection": selection}
 
 

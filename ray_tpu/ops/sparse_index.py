@@ -23,24 +23,57 @@ heads of its row. The scorer is trained by its own term alone::
 (softmax_S(I) - pbar) / tokens`` on ``S`` and the selection itself carries no
 gradient.
 
-All of it is XLA's here, a CHUNK of query rows at a time (nothing ``[seq,
-seq]`` in float32 outlives a chunk: ``[index heads, chunk, keys]`` products
-and a KV group's ``[group, chunk, keys]`` probabilities are the largest
-arrays), the chunks walked in a few ROW GROUPS whose keys end where the
-group's last row does (a chunk of the first quarter of the rows reads a
-quarter of the keys). ``index_loss`` computes its own gradient in its forward
-(``jax.custom_vjp``: the chunk's products are at hand there, and a layer
-under ``jax.checkpoint`` that keeps ``RESIDUAL_NAMES`` then runs neither the
-scorer nor the attention's probabilities a second time).
+The scores of the selection pass and the selection are XLA's, a CHUNK of
+query rows at a time (``[index heads, chunk, keys]`` float32 products are the
+largest arrays there), the chunks walked in a few ROW GROUPS whose keys end
+where the group's last row does (a chunk of the first quarter of the rows
+reads a quarter of the keys).
+
+The term is two Pallas kernels written as ``ops/flash_attention.py``'s are
+(causal tiles ``[block_q, block_k]`` of pairs, a tile past the edge skipped
+and not fetched, the selection's int8 tile an operand, the MXU's operands in
+the inputs' dtype with float32 accumulators, everything else float32), both
+query-major over one sequential grid ``(batch, row blocks, key steps)``, so
+that nothing ``[.., rows, keys]`` in float32 reaches HBM::
+
+    _index_loss_lse    lseI[t] = log sum_{s in S[t]} exp I[t, s]     (the scorer alone)
+    _index_loss_terms  per tile: P_j, I, pbar from the heads' products, the
+                       terms xlogy(pbar, pbar) - pbar (I - lseI) summed by row,
+                       dI = exp(I - lseI) - pbar, then P_j once more for
+                       dw[t, j]  += sum_s dI ReLU(P_j)
+                       dqI[t, j] += sum_s (dI w[t, j] [P_j > 0]) kI[s]
+                       dkI[s]    += sum_t sum_j (dI w[t, j] [P_j > 0]) qI[t, j]
+
+``pbar`` (32 products of depth 128 and as many exponentials a pair at the
+published widths) is made once; the index heads' products three times (the
+MXU has the room, VMEM traffic for sixteen kept tiles has not). ``dqI`` is a
+row block's scratch; ``dkI`` is the whole row's, TRANSPOSED ``[key blocks,
+dim, block_k]`` float32 (4 MiB at 16,384 keys of 64), an output block resident
+across the grid; ``dw`` sums by lane and crosses lanes at a row block's last
+step. ``index_loss`` computes its own gradient in its forward
+(``jax.custom_vjp``: the tile's products are at hand there, and a layer under
+``jax.checkpoint`` that keeps ``RESIDUAL_NAMES`` then runs neither the scorer
+nor the attention's probabilities a second time). The benchmark's plain
+reference (``benchmarks/reference/sparse_gqa_moe_decoder.py``) is the oracle
+the tests hold it to.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+import operator
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import flash_attention as _flash
+from ray_tpu.ops.flash_attention import (
+    _LANES, _NEG_INF, _across, _chosen, _kv_index_map, _mxu, _tile_needed,
+)
 
 # What a checkpointed layer may keep (``checkpoint_name``): the selection,
 # PACKED eight keys a byte (``[batch, seq, seq / 8]``: 32 MiB a layer at
@@ -51,7 +84,6 @@ from jax.ad_checkpoint import checkpoint_name
 RESIDUAL_NAMES = ("index_selection", "index_loss_grads")
 _PACKED = 8
 
-_NEG_INF = -1e30
 # Row groups a sequence's chunks are walked in (see the module docstring).
 _ROW_GROUPS = 4
 
@@ -195,98 +227,304 @@ def index_select(q_index, k_index, w, *, topk: int, chunk: int = 512):
         return _unpack(checkpoint_name(_pack(selection), RESIDUAL_NAMES[0]))
 
 
-def _mean_attention(q, k, chosen, lse, scale):
-    """``pbar`` ``[batch, rows, keys]`` float32: the mean over the heads of
-    ``exp(q . k scale - lse)`` on the chosen keys, 0 elsewhere. ``q``
-    ``[batch, heads, rows, d]``, ``k`` ``[batch, kv_heads, keys, d]``, ``lse``
-    ``[batch, heads, rows]``; a KV group's heads at a time."""
-    batch, heads, rows, dim = q.shape
-    kv_heads = k.shape[1]
-    group = heads // kv_heads
-    by_group = lambda x: jnp.moveaxis(x.reshape(batch, kv_heads, group, *x.shape[2:]), 1, 0)
+def _index_blocks(seq: int, block_q: int | None, block_k: int | None) -> tuple[int, int]:
+    """The term's tile ``[block_q, block_k]`` of (query, key) pairs: 256 x 512
+    unless asked for less (an int is an upper bound, as ``flash_attention``'s),
+    halved until it divides the sequence. At 256 rows the cell's 32 heads'
+    ``q`` tile is 2 MiB and a float32 tile of pairs 512 KiB."""
+    return _chunk(seq, block_q or 256), _chunk(seq, block_k or 512)
 
-    def one_group(total, scanned):
-        q_group, k_group, lse_group = scanned
-        scores = jnp.einsum(
-            "bgcd,bkd->bgck", q_group, k_group, preferred_element_type=jnp.float32
-        ) * scale
-        return total + jnp.sum(jnp.exp(scores - lse_group[..., None]), axis=1), None
 
-    total, _ = jax.lax.scan(
-        one_group, jnp.zeros((batch, rows, k.shape[2]), jnp.float32),
-        (by_group(q), jnp.moveaxis(k, 1, 0), by_group(lse)),
+def _vmem_limit(blocks, scratch, block_q: int, block_k: int) -> int:
+    """What a call may hold in VMEM, from its shapes: every operand's and
+    result's block twice (the pipeline's two buffers), the scratch, and
+    sixteen float32 tiles of pairs for the values a tile's step keeps (its
+    scores, ``pbar``, the mask, ``dI``, a head's products and their
+    exponentials); the last two dimensions padded to whole vregs. Never under
+    Mosaic's own 16 MiB; a v5e holds 128."""
+    def padded(shape, dtype):
+        itemsize = jnp.dtype(dtype).itemsize
+        sublanes = 8 * 4 // itemsize
+        *lead, rows, lanes = shape
+        return math.prod(lead) * -(-rows // sublanes) * sublanes * -(-lanes // _LANES) * _LANES * itemsize
+
+    held = 2 * sum(padded(*block[:2]) for block in blocks) + sum(padded(*one) for one in scratch)
+    return max(held + 16 * padded((block_q, block_k), jnp.float32) + (4 << 20), 16 << 20)
+
+
+def _visible(sel_ref, q_index, kv_index, block_q, block_k):
+    """The tile's chosen pairs at or under the causal edge."""
+    q_pos = q_index * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = kv_index * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return (q_pos >= k_pos) & _chosen(sel_ref)
+
+
+def _dot(a, b, contract, precision):
+    """``a`` and ``b`` contracted over ``contract`` (a dimension of each) on
+    the MXU, a float32 accumulator."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
     )
-    return jnp.where(chosen, total / heads, 0.0)
 
 
-def _chunk_loss(q_index, w, k_index, q, k, selection, lse, scale):
-    """One chunk's ``sum_t sum_{s in S[t]} pbar (log pbar - log softmax_S(I))``."""
-    chosen = selection != 0
-    scores = jnp.where(chosen, index_scores(q_index, k_index, w), _NEG_INF)
-    log_scorer = scores - jax.nn.logsumexp(scores, axis=-1, keepdims=True)
-    pbar = _mean_attention(q, k, chosen, lse, scale)
-    terms = jax.scipy.special.xlogy(pbar, pbar) - pbar * jnp.where(chosen, log_scorer, 0.0)
-    return jnp.sum(jnp.where(chosen, terms, 0.0))
+def _sum(values):
+    """``values`` added up with no zero to start from (an ``x + 0.0`` is an
+    operation over a tile that no compiler may fold)."""
+    return functools.reduce(operator.add, values)
 
 
-def _loss_and_grads(q_index, k_index, w, q, k, selection, lse, scale, chunk, grads):
-    """``L_I`` and, with ``grads``, its gradients with respect to
-    ``(q_index, k_index, w)``, the chunks walked once."""
-    batch, seq = q_index.shape[:2]
-    chunk = _chunk(seq, chunk)
-    tokens = batch * seq
-    loss = jnp.zeros((), jnp.float32)
-    grad_k = jnp.zeros(k_index.shape, jnp.float32)
-    grad_q, grad_w = [], []
-    for first_row, rows in _row_groups(seq, chunk):
-        keys = first_row + rows
-        seen_index, seen_k = k_index[:, :keys], k[:, :, :keys]
-        chunks = functools.partial(_by_chunk, first_row=first_row, rows=rows, chunk=chunk)
-        scanned = (
-            chunks(q_index), chunks(w), chunks(q, axis=2),
-            chunks(selection[:, :, :keys]), chunks(lse, axis=2),
+def _by_lane(x):
+    """``x`` ``[rows, width]`` summed to one vreg of lanes a row where
+    ``width`` is whole vregs (the sum across the lanes is left to a row
+    block's last step), else to ``[rows, 1]``."""
+    width = x.shape[1]
+    if width % _LANES:
+        return jnp.sum(x, axis=1, keepdims=True)
+    return _sum(x[:, at:at + _LANES] for at in range(0, width, _LANES))
+
+
+def _tile_products(qi_ref, j, ki, precision):
+    """Index head ``j``'s ``qI[t, j] . kI[s]`` of a tile, float32."""
+    return _dot(_mxu(qi_ref[0, j], precision), ki, (1, 1), precision)
+
+
+def _tile_scores(qi_ref, ki, w, precision):
+    """``I`` of a tile, ``[block_q, block_k]`` float32."""
+    return _sum(
+        jnp.maximum(_tile_products(qi_ref, j, ki, precision), 0.0) * w[:, j:j + 1]
+        for j in range(qi_ref.shape[1])
+    )
+
+
+def _index_lse_kernel(qi_ref, ki_ref, w_ref, sel_ref, lse_ref, m_scr, l_scr, *,
+                      block_q, block_k, precision):
+    """``lseI[t]``, the log-sum-exp of ``I[t, .]`` over ``S[t]``: the flash
+    forward's running maximum and sum over the key steps of a row block."""
+    q_index, step = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(step == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+
+    @pl.when(_tile_needed(True, 0, q_index, step, block_q, block_k))
+    def _compute():
+        scores = _tile_scores(qi_ref, _mxu(ki_ref[0], precision), w_ref[0], precision)
+        scores = jnp.where(_visible(sel_ref, q_index, step, block_q, block_k), scores, _NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        l_scr[:] = jnp.exp(m_prev - m_new) * l_scr[:] + jnp.sum(
+            jnp.exp(scores - _across(m_new, block_k)), axis=1, keepdims=True
         )
+        m_scr[:] = m_new
 
-        def one_chunk(carry, scanned, seen_index=seen_index, seen_k=seen_k):
-            q_index_c, w_c, q_c, selection_c, lse_c = scanned
-            term = functools.partial(
-                _chunk_loss, q=q_c, k=seen_k, selection=selection_c, lse=lse_c, scale=scale
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _finalize():
+        lse_ref[0] = (m_scr[:] + jnp.log(l_scr[:]))[:, :1]
+
+
+def _index_loss_kernel(qi_ref, qit_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, lsei_ref, sel_ref,
+                       rows_ref, dqi_ref, dw_ref, dkit_ref, rows_scr, dqi_scr, dw_scr, *,
+                       scale, inv_tokens, block_q, block_k, precision):
+    """A tile of the term and of its three gradients (module docstring):
+    ``I`` and ``pbar`` from their products, the tile's terms summed by row,
+    ``dI``, and the index heads' products once more for ``dw``, ``dqI`` (a row
+    block's scratch) and ``dkI`` (TRANSPOSED, ``[key blocks, dim, block_k]``
+    float32: the whole row's, resident across the sequential grid)."""
+    q_index, step = pl.program_id(1), pl.program_id(2)
+    index_heads, heads, kv_heads = qi_ref.shape[1], q_ref.shape[1], k_ref.shape[1]
+    group = heads // kv_heads
+
+    @pl.when((q_index == 0) & (step == 0))
+    def _init_row():
+        dkit_ref[...] = jnp.zeros_like(dkit_ref)
+
+    @pl.when(step == 0)
+    def _init():
+        rows_scr[:] = jnp.zeros_like(rows_scr)
+        dqi_scr[:] = jnp.zeros_like(dqi_scr)
+        dw_scr[:] = jnp.zeros_like(dw_scr)
+
+    @pl.when(_tile_needed(True, 0, q_index, step, block_q, block_k))
+    def _compute():
+        visible = _visible(sel_ref, q_index, step, block_q, block_k)
+        ki = _mxu(ki_ref[0], precision)
+        w = w_ref[0]
+        scores = _tile_scores(qi_ref, ki, w, precision)
+
+        def exponentials():
+            for g in range(kv_heads):
+                k = _mxu(k_ref[0, g], precision)
+                lse = lse_ref[0, g]                              # [block_q, group]
+                for a in range(group):
+                    q = _mxu(q_ref[0, g * group + a], precision)
+                    yield jnp.exp(_dot(q, k, (1, 1), precision) * scale - lse[:, a:a + 1])
+
+        pbar = jnp.where(visible, _sum(exponentials()) / heads, 0.0)
+        log_scorer = scores - lsei_ref[0]
+        # xlogy(pbar, pbar): 0 where the exponentials underflowed
+        entropy = jnp.where(pbar > 0.0, pbar * jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)), 0.0)
+        rows_scr[:] = rows_scr[:] + _by_lane(jnp.where(visible, entropy - pbar * log_scorer, 0.0))
+        d_scores = jnp.where(visible, jnp.exp(log_scorer) - pbar, 0.0)
+
+        for j in range(index_heads):
+            products = _tile_products(qi_ref, j, ki, precision)
+            dw_scr[j] = dw_scr[j] + _by_lane(d_scores * jnp.maximum(products, 0.0))
+            # rounded to the operands' dtype for the MXU, as the flash
+            # backward's ``ds`` and as XLA's default precision did the walk's
+            d_products = _mxu(
+                jnp.where(products > 0.0, d_scores * w[:, j:j + 1], 0.0).astype(ki_ref.dtype),
+                precision,
             )
-            if not grads:
-                return (carry[0] + term(q_index_c, w_c, seen_index), carry[1]), None
-            value, (dq, dw, dk) = jax.value_and_grad(term, argnums=(0, 1, 2))(
-                q_index_c, w_c, seen_index
+            dqi_scr[j] = dqi_scr[j] + _dot(d_products, ki, (1, 0), precision)
+            dkit_ref[0, step] = dkit_ref[0, step] + _dot(      # [dim, block_k]
+                _mxu(qit_ref[0, j], precision), d_products, (1, 0), precision
             )
-            return (carry[0] + value, carry[1] + dk.astype(jnp.float32)), (dq, dw)
 
-        (loss, seen_grad), per_chunk = jax.lax.scan(
-            one_chunk, (loss, jnp.zeros(seen_index.shape, jnp.float32)), scanned
-        )
-        if grads:
-            grad_k = grad_k.at[:, :keys].add(seen_grad)
-            unchunk = lambda x: jnp.moveaxis(x, 0, 1).reshape(batch, rows, *x.shape[3:])
-            grad_q.append(unchunk(per_chunk[0]))
-            grad_w.append(unchunk(per_chunk[1]))
-    loss = loss / tokens
-    if not grads:
-        return loss, None
-    join = lambda parts, like: (jnp.concatenate(parts, axis=1) / tokens).astype(like.dtype)
-    return loss, (join(grad_q, q_index), (grad_k / tokens).astype(k_index.dtype), join(grad_w, w))
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _finalize():
+        rows_ref[0] = jnp.sum(rows_scr[:], axis=1, keepdims=True)
+        dqi_ref[0] = (dqi_scr[:] * inv_tokens).astype(dqi_ref.dtype)
+        column = jax.lax.broadcasted_iota(jnp.int32, dw_ref.shape[1:], 1)
+        dw = jnp.zeros(dw_ref.shape[1:], jnp.float32)
+        for j in range(index_heads):
+            dw = jnp.where(column == j, jnp.sum(dw_scr[j], axis=1, keepdims=True), dw)
+        dw_ref[0] = (dw * inv_tokens).astype(dw_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _index_loss(q_index, k_index, w, q, k, selection, lse, scale, chunk):
-    return _loss_and_grads(q_index, k_index, w, q, k, selection, lse, scale, chunk, grads=False)[0]
+def _tiles(seq, block_q, block_k):
+    """The grid's key axis and the key block of step ``(b, i, s)``: a row
+    block's skipped steps name its last needed block, which is resident."""
+    kv_blocks = seq // block_k
+    kv_map = _kv_index_map(True, 0, block_q, block_k, kv_blocks)
+    return kv_blocks, lambda b, i, s: kv_map(b, i, s)[1]
 
 
-def _index_loss_fwd(q_index, k_index, w, q, k, selection, lse, scale, chunk):
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret", "precision"))
+def _index_loss_lse(qi, k_index, w, selection, *, block_q, block_k, interpret, precision):
+    """``lseI`` ``[batch, seq, 1]`` float32; ``qi`` ``[batch, index heads,
+    seq, dim]``."""
+    batch, index_heads, seq, dim = qi.shape
+    kv_blocks, kv_block = _tiles(seq, block_q, block_k)
+    by_row = lambda b, i, s: (b, i, 0)
+    operands = [
+        ((1, index_heads, block_q, dim), qi.dtype, lambda b, i, s: (b, 0, i, 0)),
+        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, kv_block(b, i, s), 0)),
+        ((1, block_q, index_heads), w.dtype, by_row),
+        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, kv_block(b, i, s))),
+    ]
+    result = ((1, block_q, 1), jnp.float32, by_row)
+    scratch = [((block_q, _LANES), jnp.float32)] * 2
+    return pl.pallas_call(
+        functools.partial(_index_lse_kernel, block_q=block_q, block_k=block_k, precision=precision),
+        grid=(batch, seq // block_q, kv_blocks),
+        in_specs=[pl.BlockSpec(block, at) for block, _, at in operands],
+        out_specs=pl.BlockSpec(result[0], result[2]),
+        out_shape=jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM(*one) for one in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit([*operands, result], scratch, block_q, block_k)
+        ),
+        interpret=interpret,
+    )(qi, k_index, w, selection)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret", "precision")
+)
+def _index_loss_terms(qi, k_index, w, q, k, selection, lse, lse_index, *, scale, block_q,
+                      block_k, interpret, precision):
+    """The term by row ``[batch, seq, 1]`` float32 and its gradients over the
+    tokens: ``dqI`` as ``qi`` is, ``[batch, index heads, seq, dim]``, ``dw``,
+    and ``dkI`` TRANSPOSED by key block, ``[batch, key blocks, dim, block_k]``
+    float32 not yet divided by the tokens. ``lse`` ``[batch, kv_heads, seq,
+    group]``."""
+    batch, index_heads, seq, dim = qi.shape
+    heads, head_dim = q.shape[1], q.shape[3]
+    kv_heads, group = lse.shape[1], lse.shape[3]
+    kv_blocks, kv_block = _tiles(seq, block_q, block_k)
+    by_row = lambda b, i, s: (b, i, 0)
+    by_head_row = lambda b, i, s: (b, 0, i, 0)
+    operands = [
+        ((1, index_heads, block_q, dim), qi.dtype, by_head_row),
+        ((1, index_heads, dim, block_q), qi.dtype, lambda b, i, s: (b, 0, 0, i)),
+        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, kv_block(b, i, s), 0)),
+        ((1, block_q, index_heads), w.dtype, by_row),
+        ((1, heads, block_q, head_dim), q.dtype, by_head_row),
+        ((1, kv_heads, block_k, head_dim), k.dtype, lambda b, i, s: (b, 0, kv_block(b, i, s), 0)),
+        ((1, kv_heads, block_q, group), lse.dtype, by_head_row),
+        ((1, block_q, 1), lse_index.dtype, by_row),
+        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, kv_block(b, i, s))),
+    ]
+    results = [
+        ((1, block_q, 1), jnp.float32, by_row),
+        ((1, index_heads, block_q, dim), qi.dtype, by_head_row),
+        ((1, block_q, index_heads), w.dtype, by_row),
+        ((1, kv_blocks, dim, block_k), jnp.float32, lambda b, i, s: (b, 0, 0, 0)),
+    ]
+    lanes = _LANES if block_k % _LANES == 0 else 1           # what ``_by_lane`` sums a tile to
+    scratch = [
+        ((block_q, lanes), jnp.float32), ((index_heads, block_q, dim), jnp.float32),
+        ((index_heads, block_q, lanes), jnp.float32),
+    ]
+    return pl.pallas_call(
+        functools.partial(
+            _index_loss_kernel, scale=scale, inv_tokens=1.0 / (batch * seq), block_q=block_q,
+            block_k=block_k, precision=precision,
+        ),
+        grid=(batch, seq // block_q, kv_blocks),
+        in_specs=[pl.BlockSpec(block, at) for block, _, at in operands],
+        out_specs=[pl.BlockSpec(block, at) for block, _, at in results],
+        out_shape=[
+            jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32),
+            jax.ShapeDtypeStruct(qi.shape, qi.dtype),
+            jax.ShapeDtypeStruct(w.shape, w.dtype),
+            jax.ShapeDtypeStruct((batch, kv_blocks, dim, block_k), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM(*one) for one in scratch],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit([*operands, *results], scratch, block_q, block_k)
+        ),
+        interpret=interpret,
+    )(qi, jnp.swapaxes(qi, 2, 3), k_index, w, q, k, lse, lse_index, selection)
+
+
+def _loss_and_grads(q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k,
+                    interpret, precision):
+    """``L_I`` and its gradients with respect to ``(q_index, k_index, w)``:
+    two walks of the causal tiles, ``lseI`` and then everything else."""
+    batch, seq, index_heads, dim = q_index.shape
+    kv_heads = k.shape[1]
+    block_q, block_k = _index_blocks(seq, block_q, block_k)
+    static = dict(block_q=block_q, block_k=block_k, interpret=interpret, precision=precision)
+    qi = jnp.swapaxes(q_index, 1, 2)                                  # [batch, index heads, seq, dim]
+    lse_index = _index_loss_lse(qi, k_index, w, selection, **static)
+    by_group = jnp.swapaxes(lse.reshape(batch, kv_heads, -1, seq), 2, 3)
+    rows, dqi, dw, dkit = _index_loss_terms(
+        qi, k_index, w, q, k, selection, by_group, lse_index, scale=scale, **static
+    )
+    dk = jnp.swapaxes(dkit, 2, 3).reshape(batch, seq, dim) / (batch * seq)
+    return jnp.sum(rows) / (batch * seq), (jnp.swapaxes(dqi, 1, 2), dk.astype(k_index.dtype), dw)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
+def _index_loss(q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k, interpret,
+                precision):
+    return _loss_and_grads(
+        q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k, interpret, precision
+    )[0]
+
+
+def _index_loss_fwd(q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k,
+                    interpret, precision):
     loss, grads = _loss_and_grads(
-        q_index, k_index, w, q, k, selection, lse, scale, chunk, grads=True
+        q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k, interpret, precision
     )
     return loss, checkpoint_name(grads, RESIDUAL_NAMES[1])
 
 
-def _index_loss_bwd(scale, chunk, grads, g):
+def _index_loss_bwd(scale, block_q, block_k, interpret, precision, grads, g):
     scaled = tuple((g * grad.astype(jnp.float32)).astype(grad.dtype) for grad in grads)
     return (*scaled, None, None, None, None)
 
@@ -294,11 +532,20 @@ def _index_loss_bwd(scale, chunk, grads, g):
 _index_loss.defvjp(_index_loss_fwd, _index_loss_bwd)
 
 
-def index_loss(q_index, k_index, w, q, k, selection, lse, *, scale: float, chunk: int = 512):
+def index_loss(q_index, k_index, w, q, k, selection, lse, *, scale: float,
+               block_q: int | None = None, block_k: int | None = None,
+               interpret: bool | None = None, precision: jax.lax.Precision | None = None):
     """``L_I`` (module docstring), a float32 scalar whose gradient reaches
     ``q_index``, ``k_index`` and ``w`` and nothing else: the attention's
     ``q`` ``[batch, heads, seq, d]``, ``k`` ``[batch, kv_heads, seq, d]`` and
     ``lse`` ``[batch, heads, seq]`` (``flash_attention``'s, under the same
-    ``selection`` and ``scale``) are read detached."""
+    ``selection`` and ``scale``) are read detached. ``block_q``, ``block_k``,
+    ``interpret`` and ``precision`` as ``flash_attention``'s, ``interpret``
+    resolved under the flash module's own name: whoever steers the kernels
+    that made ``lse`` off the interpreter (a compile for a described chip)
+    steers the term's with them."""
     q, k, lse = jax.lax.stop_gradient((q, k, lse))
-    return _index_loss(q_index, k_index, w, q, k, selection, lse, float(scale), chunk)
+    return _index_loss(
+        q_index, k_index, w, q, k, selection, lse, float(scale), block_q, block_k,
+        _flash.resolve_interpret(interpret), precision,
+    )

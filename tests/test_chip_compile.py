@@ -17,6 +17,7 @@ skips when it cannot be — never at import, in a ``skipif`` or in
 
 import contextlib
 import functools
+import pathlib
 import re
 from unittest import mock
 
@@ -235,6 +236,73 @@ def test_flash_under_a_selection_compiles_for_v5e(one_chip):
     assert "s8[1,16384,16384]" in text and "[32,16384,16384]" not in text
     assert [g.shape for g in jax.eval_shape(grads, *shapes)] == [
         (1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128)]
+
+
+def test_the_index_term_s_kernels_compile_for_v5e(one_chip):
+    """The scorer's term of ``keye-vl-2.0-30b-a3b`` beside the masked flash
+    kernels above: ``index_loss`` at q ``[1, 32, 16384, 128]`` on K of 4 heads,
+    16 index heads of 64 on one key, all bfloat16, under the int8 selection
+    ``[1, 16384, 16384]``: its value and the three gradients made in its
+    forward are TWO Mosaic calls (``lseI``; then the term, ``dqI``, ``dw`` and
+    ``dkI``), in the 256 x 512 tiles ``_index_blocks`` gives, under the VMEM
+    ``_vmem_limit`` counts from the shapes (the 32 heads' ``q`` tile and the
+    whole row's ``dkI`` resident: more than Mosaic's own 16 MiB, far under a
+    v5e's 128), and nothing ``[.., rows, keys]`` exists in float32."""
+    from ray_tpu.ops import sparse_index
+
+    assert sparse_index._index_blocks(16384, None, None) == (256, 512)
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    shapes = (
+        shape(1, 16384, 16, 64), shape(1, 16384, 64), shape(1, 16384, 16, dtype=jnp.float32),
+        shape(1, 32, 16384, 128), shape(1, 4, 16384, 128),
+        shape(1, 16384, 16384, dtype=jnp.int8), shape(1, 32, 16384, dtype=jnp.float32),
+    )
+    term = functools.partial(sparse_index.index_loss, scale=128 ** -0.5, interpret=False)
+    grads = jax.value_and_grad(term, argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(*shapes).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2
+    assert "_index_loss_lse" in calls[0] and "_index_loss_terms" in calls[1]
+    assert not any("_flash" in name for name in calls)             # flash_ms reads by that name
+    limits = [
+        int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line).group(1))
+        for line in text.splitlines() if "tpu_custom_call" in line
+    ]
+    assert len(limits) == 2 and 16 * 2**20 <= min(limits) and max(limits) <= 64 * 2**20
+    assert "s8[1,16384,16384]" in text
+    assert not re.search(r"f32\[[\d,]*(?:256|512|16384),16384\]", text)
+    loss, (dq_index, dk_index, dw) = jax.eval_shape(grads, *shapes)
+    assert (loss.shape, loss.dtype) == ((), jnp.float32)
+    assert [(g.shape, g.dtype) for g in (dq_index, dk_index, dw)] == [
+        (s.shape, s.dtype) for s in shapes[:3]]
+
+
+def test_the_sparse_step_needs_no_more_of_the_chip_than_its_parent_s(topo):
+    """``keye-vl2-seq16k-fixed``'s whole step (the benchmark's own
+    configuration and traffic: six sparse layers over 16 held experts, 1 x
+    16384, full remat) with the term as kernels: one call of each a layer's
+    forward, none in its backward (the gradients are kept by name), no KV
+    group's ``[8, 512, keys]`` float32 probabilities left, and no more of the
+    chip than the parent's step, whose term was XLA's walk of chunks
+    (``hbm_step_gib`` 12.515: ledger, PR 53)."""
+    import importlib
+
+    import ray_tpu.ops.grouped_matmul as gm
+    from benchmarks.harness import described
+    from benchmarks.harness.manifest import Manifest
+
+    manifest = Manifest(str(pathlib.Path(__file__).resolve().parents[1]))
+    cell = manifest.cell("keye-vl2-seq16k-fixed")
+    config, traffic = manifest.config(cell["config"]), manifest.traffic(cell["traffic"])
+    family = importlib.import_module(f"benchmarks.families.{config['family']}").build(config, traffic)
+    with mock.patch.object(gm, "resolve_interpret", lambda _i: False):
+        compiled = described.compile_step(
+            family, topo.devices, config["mesh_axes"], traffic["batch_size"], traffic["seq_len"])[1]
+    calls = _mosaic_calls(compiled.as_text())
+    assert calls.count("_index_loss_lse") == 1 and calls.count("_index_loss_terms") == 1
+    assert sum("_flash" in name for name in calls) == 3
+    assert "f32[1,8,512," not in compiled.as_text()
+    assert described.step_memory(compiled)["total_bytes"] / 2**30 <= 12.52
 
 
 # What the rule's value-and-gradient program may hold beside its arguments

@@ -197,7 +197,7 @@ def test_the_index_loss_and_its_gradient_are_the_reference_s():
         return total / (2 * 64)
 
     ours = lambda *scorer: sparse_index.index_loss(
-        *scorer, q, k, selection, lse, scale=16 ** -0.5, chunk=16)
+        *scorer, q, k, selection, lse, scale=16 ** -0.5, block_q=16, block_k=16)
     (got, grads), (want, wanted) = (
         jax.jit(jax.value_and_grad(fn, (0, 1, 2)))(*scorer) for fn in (ours, plain)
     )
@@ -206,8 +206,74 @@ def test_the_index_loss_and_its_gradient_are_the_reference_s():
         close(got, want, 2e-4)
     # nothing flows into what the term reads detached
     into_q = jax.grad(lambda q: sparse_index.index_loss(
-        *scorer, q, k, selection, lse, scale=0.25, chunk=16))(q)
+        *scorer, q, k, selection, lse, scale=0.25, block_q=16, block_k=16))(q)
     assert float(jnp.max(jnp.abs(into_q))) == 0.0
+
+
+@pytest.mark.parametrize("case", [
+    dict(id="topk_or_fewer_keys", seq=32, topk=64, blocks=(16, 16)),
+    dict(id="causal_tiles_skipped", seq=64, topk=16, blocks=(16, 16)),
+    dict(id="row_blocks_wider_than_key_blocks", seq=64, topk=16, blocks=(32, 16)),
+    dict(id="one_tile_one_row", seq=64, topk=16, blocks=(None, None), batch=1),
+    dict(id="group_of_1", seq=64, topk=16, blocks=(16, 32), heads=2, kv_heads=2),
+    dict(id="group_of_8", seq=64, topk=16, blocks=(16, 32), heads=8, kv_heads=1),
+    dict(id="bfloat16_operands", seq=64, topk=16, blocks=(16, 16), dtype=jnp.bfloat16),
+    dict(id="exponentials_underflow", seq=64, topk=16, blocks=(16, 16), sharpen=100.0),
+], ids=lambda case: case["id"])
+def test_the_term_s_kernels_and_their_three_gradients_are_the_reference_s(case):
+    """``index_loss``'s two kernels (interpret mode) against the benchmark's
+    plain reference, which knows no tile: the term and the gradients made in
+    its forward, over block shapes that skip whole causal tiles, both group
+    sizes, a batch of 2 and of 1, float32 operands at ``Precision.HIGHEST``
+    (the file's tolerances) and bfloat16 ones (products exact in the float32
+    accumulator, so the term holds to the float32 tolerance; ``dP`` and two of
+    the results are rounded to bfloat16, 2^-8 a value: 1e-2 of the largest);
+    attention so sharp that ``exp`` underflows for every head of a chosen
+    pair, where ``xlogy(0, 0)`` is 0; and nothing reaches ``q``, ``k``,
+    ``lse``."""
+    seq, topk, dtype = case["seq"], case["topk"], case.get("dtype", jnp.float32)
+    heads, kv_heads = case.get("heads", 4), case.get("kv_heads", 2)
+    q, k, v, scorer, _ = _operands(
+        seq, topk, seed=5, batch=case.get("batch", 2), heads=heads, kv_heads=kv_heads)
+    rounded = lambda x: x.astype(dtype).astype(jnp.float32)
+    q, k, v = rounded(q * case.get("sharpen", 1.0)), rounded(k), rounded(v)
+    scorer = (rounded(scorer[0]), rounded(scorer[1]), scorer[2])
+    selection = sparse_index.index_select(*scorer, topk=topk, chunk=16)
+    batch, group = q.shape[0], heads // kv_heads
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, group, axis=1)) * 16 ** -0.5
+    lse = jax.nn.logsumexp(jnp.where(selection[:, None] != 0, scores, -jnp.inf), axis=-1)
+    by_token = lambda x: jnp.swapaxes(x, 1, 2)
+    def plain(q_index, k_index, w):
+        total, underflowed = 0.0, False
+        for start in range(0, seq, 16):
+            scores = reference.index_scores_block(q_index, k_index, w, start, 16)
+            mask = reference.select_block(jax.lax.stop_gradient(scores), start, topk)
+            _, probs = reference.attention_block(by_token(q), by_token(k), by_token(v), mask, start, 16)
+            underflowed |= jnp.any(mask & (jnp.mean(probs, axis=1) == 0.0))
+            total = total + reference.index_loss_block(scores, mask, probs)
+        return total / (batch * seq), underflowed
+
+    exact = dtype == jnp.float32
+    static = dict(
+        scale=16 ** -0.5, block_q=case["blocks"][0], block_k=case["blocks"][1],
+        precision=jax.lax.Precision.HIGHEST if exact else None,
+    )
+    cast = lambda x: x.astype(dtype)
+
+    def ours(q_index, k_index, w, q, k, lse):
+        return sparse_index.index_loss(
+            cast(q_index), cast(k_index), w, cast(q), cast(k), selection, lse, **static)
+
+    (want, underflowed), wanted = jax.jit(jax.value_and_grad(plain, (0, 1, 2), has_aux=True))(*scorer)
+    got, grads = jax.jit(jax.value_and_grad(ours, tuple(range(6))))(*scorer, q, k, lse)
+    assert bool(underflowed) == ("sharpen" in case)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for got, want, name in zip(grads, wanted, ("dq_index", "dk_index", "dw")):
+        close(got, want, 2e-4 if exact else 1e-2, name)
+    assert all(float(jnp.max(jnp.abs(detached))) == 0.0 for detached in grads[3:])
+    if case["blocks"] == (16, 16):
+        from ray_tpu.ops.flash_attention import causal_tile_counts
+        assert causal_tile_counts(seq, seq, 16, 16)["skipped"] == (seq // 16) * (seq // 16 - 1) // 2
 
 
 # -- the model against the benchmark's reference -----------------------------
