@@ -94,6 +94,24 @@ checkpoint policy, one head and loss):
     over its heads, ops/sparse_index.py), which rides the layer scan beside
     the experts' routing and is added to the cross-entropy in ``loss_fn``.
 
+  * the tenth, state-space mixers and layers that are ONE block each over a
+    latent mixture of experts (NVIDIA-Nemotron-3-Super's language model): a
+    sixth kind of mixer, "ssm" (``ssm=``, ``_ssm_mixer``, scope
+    ``ssm_mixer``), Mamba-2's: ``[z | xBC | dt]`` projections, a causal
+    depthwise convolution WITH a bias over ``xBC`` (ops/short_conv.py), the
+    recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t
+    + D x_t`` as a chunked scan (ops/ssd.py, scope ``ssd``; ``B`` and ``C``
+    shared by the heads of a group), ``RMSNorm_groups(y * SiLU(z))``, ``W_out``.
+    A ``layer_pattern`` that names ``"mlp"`` beside the mixer kinds makes every
+    layer ONE residual block with ONE norm: a mixer's layer carries its mixer
+    and ``attn_norm`` and no MLP, an "mlp" layer its MLP and ``mlp_norm`` and
+    no mixer. And in ``MoEConfig``: ``activation="relu2"``, an UN-GATED expert
+    of two matrices, ``W_2 relu(u W_1)^2``; ``latent_dim``, experts whose
+    input and output width is a latent's, between one down-projection before
+    the dispatch and one up-projection after the combine (scope
+    ``moe_latent``; router and shared expert stay on the stream); and
+    ``shared_dim``, the shared expert's own width.
+
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
     written down ONCE, in the table below the configs: one function a part
@@ -129,7 +147,11 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     layer through decode (the index keys are not cached), the pipeline (the
     scorer's term is not carried across stages), a callable ``attention``,
     a mesh with tp or sp (dp / fsdp work), or beside a dense prefix (whose
-    scan hands on the stream alone: the scorer's term would be lost).
+    scan hands on the stream alone: the scorer's term would be lost); an
+    "ssm" or "mlp" layer through decode (the state-space layer's state and
+    its convolution's last inputs are not cached) or the pipeline, an "ssm"
+    layer over tp or sp, and a pattern of one-block layers behind a dense
+    prefix.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -162,6 +184,7 @@ from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.sparse_index import (
     RESIDUAL_NAMES as INDEX_RESIDUAL_NAMES, index_loss, index_select,
 )
+from ray_tpu.ops.ssd import RESIDUAL_NAMES as SSD_RESIDUAL_NAMES, ssd, ssd_reference
 from ray_tpu.parallel.mesh import LogicalRules
 
 # The names the model and the optimizer give their work (jax.named_scope:
@@ -217,10 +240,18 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # score a row, the comparison, the mask handed to the kernels) and
 # "index_loss" (the scorer's term and, made in its forward, its gradient: two
 # Mosaic kernels, ops/sparse_index.py's ``_index_loss_lse`` and
-# ``_index_loss_terms``, and the transposes around them).
+# ``_index_loss_terms``, and the transposes around them). And a state-space
+# layer's: "ssm_mixer", inside "attention" (the whole Mamba-2 block: the three
+# projections, the convolution under "short_conv", the decay, the scan, the
+# gated group norm, W_out), within it "ssd" (the chunked scan, forward and
+# backward, opened in ops/ssd.py), and "moe_latent", inside "mlp" (a latent
+# mixture of experts' two projections, down before the dispatch and up after
+# the combine).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
-# [experts, k, n] each, [layers, experts, k, n] in the layer stack.
+# [experts, k, n] each, [layers, experts, k, n] in the layer stack. An
+# un-gated expert (``MoEConfig.activation="relu2"``) has no ``w_gate``: what
+# walks these names takes the leaves that exist.
 _EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
 
 
@@ -272,8 +303,17 @@ class MoEConfig:
     held: tuple[int, int] | None = None
     # The gate's activation in every expert (routed and shared): "silu"
     # (SwiGLU), or "relu" (ReGLU: ``relu(h W_gate) * (h W_up)``, whose zeros
-    # are what a sparse inference engine skips).
+    # are what a sparse inference engine skips); or "relu2", an UN-GATED
+    # expert of two matrices, ``relu(h W_up)^2 W_down`` (Nemotron-H's
+    # ``mlp_hidden_act``): no ``w_gate`` / ``shared_gate`` leaf exists.
     activation: str = "silu"
+    # The width the ROUTED experts read and write; None: the stream's. With a
+    # width, ``u = h W_latent_down`` goes through the dispatch and the experts
+    # (``[latent, expert_dim]`` and back), and the combined sum through
+    # ``W_latent_up``; the router and the shared experts stay on the stream.
+    latent_dim: int | None = None
+    # The shared branch's width; None: ``shared_experts * expert_dim``.
+    shared_dim: int | None = None
     # What the router reads: "normed", the block's own normed input, as the
     # experts do; or "layer_input", the residual stream at the LAYER's
     # input, before the attention norm and before attention (a router placed
@@ -317,6 +357,10 @@ class MoEConfig:
     @property
     def num_held(self) -> int:
         return self.held[1] if self.held else self.num_experts
+
+    @property
+    def gated(self) -> bool:
+        return self.activation != "relu2"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -416,6 +460,42 @@ class SparseAttentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """An "ssm" layer's Mamba-2 mixer as ``nemotron_h``'s ``config.json``
+    states it (``mamba_num_heads``, ``mamba_head_dim``, ``ssm_state_size``,
+    ``n_groups``, ``conv_kernel``, ``chunk_size``, the ``time_step_*`` keys of
+    the initialisation). The convolution always carries its bias
+    (``use_conv_bias`` true is the one published form)."""
+    num_heads: int = 128
+    head_dim: int = 64
+    state_dim: int = 128
+    # ``B`` and ``C`` are shared by the ``num_heads / n_groups`` heads of a
+    # group; the gated norm runs over each group's ``inner_dim / n_groups``.
+    n_groups: int = 8
+    conv_kernel: int = 4
+    # Tokens a chunk of the scan holds (ops/ssd.py): no result depends on it.
+    chunk: int = 128
+    # ``dt_bias`` is the inverse softplus of a step log-uniform in
+    # ``[dt_min, dt_max]``, floored at ``dt_floor``.
+    dt_min: float = 1e-3
+    dt_max: float = 1e-1
+    dt_floor: float = 1e-4
+
+    def __post_init__(self):
+        if self.num_heads % self.n_groups:
+            raise ValueError(f"{self.num_heads} ssm heads are no multiple of {self.n_groups} groups")
+
+    @property
+    def inner_dim(self) -> int:
+        return self.num_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """The convolved channels: ``x`` and, at the groups, ``B`` and ``C``."""
+        return self.inner_dim + 2 * self.n_groups * self.state_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     dim: int = 4096
@@ -473,8 +553,13 @@ class TransformerConfig:
     # "linear", "full"); ``n_layers`` less the dense prefix is a multiple of
     # it. "full" is latent attention where ``latent`` is set; the MLPs are
     # expert layers where ``moe`` is. None: every layer is the one kind the
-    # fields above describe.
+    # fields above describe. A pattern may name ``"mlp"`` beside the mixer
+    # kinds, e.g. ("ssm", "mlp", "full", "mlp"): every layer is then ONE
+    # residual block with one norm, a mixer alone or an MLP alone
+    # (``one_block``; Nemotron-H's ``hybrid_override_pattern``).
     layer_pattern: tuple[str, ...] | None = None
+    # The mixer of the pattern's "ssm" layers.
+    ssm: SSMConfig | None = None
     # The mixer of the pattern's "linear" layers.
     linear: LinearAttentionConfig | None = None
     # The index scorer of the "sparse" layers: grouped-query attention over
@@ -536,9 +621,11 @@ class TransformerConfig:
                 )
         if self.layer_pattern is None:
             return
-        unknown = set(self.layer_pattern) - set(LAYER_KINDS)
+        unknown = set(self.layer_pattern) - {*LAYER_KINDS, MLP_KIND}
         if unknown or not self.layer_pattern:
-            raise ValueError(f"layer_pattern {self.layer_pattern!r}: kinds are {LAYER_KINDS}")
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r}: kinds are {LAYER_KINDS} and {MLP_KIND!r}"
+            )
         if (self.n_layers - self.first_dense_layers) % len(self.layer_pattern):
             raise ValueError(
                 f"n_layers={self.n_layers} after {self.first_dense_layers} dense layers is no "
@@ -546,6 +633,13 @@ class TransformerConfig:
             )
         if self.first_dense_kind not in LAYER_KINDS:
             raise ValueError(f"first_dense_kind {self.first_dense_kind!r}: kinds are {LAYER_KINDS}")
+        if self.one_block and (self.first_dense_layers or self.norm_placement == "post"):
+            raise NotImplementedError(
+                'a pattern of one-block layers ("mlp" beside the mixers) behind a dense prefix '
+                'or under norm_placement="post" is not written'
+            )
+        if ("ssm" in self._kinds()) != (self.ssm is not None):
+            raise ValueError("a pattern names ssm layers exactly where ssm= describes them")
         if "window" in self._kinds():
             if self.window is None or self.window < 1:
                 raise ValueError("a pattern with window layers needs window= (keys a query sees)")
@@ -590,6 +684,22 @@ class TransformerConfig:
         return self.first_dense_kind if self.layer_pattern else "full"
 
     @property
+    def one_block(self) -> bool:
+        """Whether every layer is ONE residual block: the pattern names
+        ``"mlp"`` layers beside its mixers."""
+        return bool(self.layer_pattern) and MLP_KIND in self.layer_pattern
+
+    @property
+    def layers_by_place(self) -> bool:
+        """Whether ``params["layers"]`` holds the pattern's layers by PLACE in
+        the period (a list) and not by kind (a dict): ``_stacks`` has the two
+        layouts and ``_scan_periods`` the reason. Today the patterns of
+        one-block layers, and no older one, because another layout would change
+        what a seed gives the cells that run them: one layout for every
+        pattern is ROADMAP Queue 1's debt."""
+        return self.one_block
+
+    @property
     def periods(self) -> int:
         return (self.n_layers - self.first_dense_layers) // len(self.layer_pattern)
 
@@ -618,6 +728,11 @@ def _normal(keys, shape, dtype, scale=None):
     before the last), made in float32 and rounded to ``dtype``."""
     scale = shape[-2] ** -0.5 if scale is None else scale
     return (jax.random.normal(next(keys), shape, jnp.float32) * scale).astype(dtype)
+
+
+def _uniform(keys, shape, low, high):
+    """Float32 draws uniform in ``(low, high)``."""
+    return jax.random.uniform(next(keys), shape, jnp.float32, low, high)
 
 
 def _filters(keys, shape, dtype):
@@ -739,15 +854,12 @@ def _linear_leaves(config: TransformerConfig) -> dict:
     channel = la.decay == "channel"
     decays = la.key_dim if channel else la.num_value_heads
     conv = lambda channels: _Leaf((la.conv_kernel, channels), (None, "heads"), _filters)
-    uniform = lambda keys, shape, low, high: jax.random.uniform(
-        next(keys), shape, jnp.float32, low, high
-    )
 
     def dt_bias(keys, shape, dtype):
-        dt = jnp.exp(uniform(keys, shape, math.log(1e-3), math.log(1e-1)))
+        dt = jnp.exp(_uniform(keys, shape, math.log(1e-3), math.log(1e-1)))
         return dt + jnp.log(-jnp.expm1(-dt))
 
-    a_log = lambda keys, shape, dtype: jnp.log(uniform(keys, shape, 1e-3, 16.0))
+    a_log = lambda keys, shape, dtype: jnp.log(_uniform(keys, shape, 1e-3, 16.0))
 
     def gate(name, out, columns):
         """A gate's projection: ``name`` whole, or ``name_down``, ``name_up``."""
@@ -784,6 +896,44 @@ def _conv_leaves(config: TransformerConfig) -> dict:
     }
 
 
+def _ssm_leaves(config: TransformerConfig) -> dict:
+    """An "ssm" layer's own leaves (Mamba-2): the in-projection as its three
+    column blocks ``W_z`` ``[hidden, inner]``, ``W_xbc`` ``[hidden, inner + 2
+    groups x state]`` (x, B, C in that order: the convolved channels) and
+    ``W_dt`` ``[hidden, heads]``; the convolution's filters and its
+    bias, both uniform in +-taps^-1/2 (a depthwise Conv1d's
+    defaults); ``dt_bias`` the inverse softplus of a step log-uniform in
+    ``[dt_min, dt_max]`` floored at ``dt_floor``; ``a_log = log(A)`` with A
+    uniform in (1, 16); ``d_skip`` ones; those three in float32; the gated
+    norm's weight ones; ``W_out``."""
+    sm, d = config.ssm, config.dim
+
+    def dt_bias(keys, shape, dtype):
+        dt = jnp.exp(_uniform(keys, shape, math.log(sm.dt_min), math.log(sm.dt_max)))
+        dt = jnp.maximum(dt, sm.dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    def conv_bias(keys, shape, dtype):
+        bound = sm.conv_kernel ** -0.5
+        return _uniform(keys, shape, -bound, bound).astype(dtype)
+
+    a_log = lambda keys, shape, dtype: jnp.log(_uniform(keys, shape, 1.0, 16.0))
+    ones = lambda keys, shape, dtype: jnp.ones(shape, jnp.float32)
+
+    return {
+        "w_z": _Leaf((d, sm.inner_dim), ("embed", "heads")),
+        "w_xbc": _Leaf((d, sm.conv_dim), ("embed", "heads")),
+        "w_dt": _Leaf((d, sm.num_heads), ("embed", None)),
+        "conv": _Leaf((sm.conv_kernel, sm.conv_dim), (None, "heads"), _filters),
+        "conv_bias": _Leaf((sm.conv_dim,), ("heads",), conv_bias),
+        "dt_bias": _Leaf((sm.num_heads,), (None,), dt_bias),
+        "a_log": _Leaf((sm.num_heads,), (None,), a_log),
+        "d_skip": _Leaf((sm.num_heads,), (None,), ones),
+        "y_norm": _norm(sm.inner_dim),
+        "w_out": _Leaf((sm.inner_dim, d), ("heads", "embed")),
+    }
+
+
 def _expert_dim(config: TransformerConfig) -> int:
     return config.moe.expert_dim or config.hidden_dim
 
@@ -791,32 +941,45 @@ def _expert_dim(config: TransformerConfig) -> int:
 def _mlp_leaves(config: TransformerConfig, experts: bool) -> tuple[dict, dict]:
     """``(mlp, shared)``: a dense SwiGLU and nothing or, with ``experts``,
     the router over ALL experts with the weights of those held here, and
-    the shared experts (sharded as a dense MLP is: GSPMD partitions them,
-    outside the per-shard call of the routed experts)."""
+    what runs OUTSIDE the per-shard call of the routed experts, sharded as a
+    dense MLP is (GSPMD partitions it): the latent projections, where the
+    experts live in a latent, and the shared experts. An un-gated expert
+    (``activation="relu2"``) has no gate leaf."""
     d, moe = config.dim, config.moe
 
-    def swiglu(names, width, *count):
-        """Gate and up ``[d, width]`` and down ``[width, d]``, behind ``count`` experts."""
+    def mlp_of(names, width, *count, stream=d, gated=True):
+        """Gate and up ``[stream, width]`` and down ``[width, stream]`` under
+        ``names`` (gate, up, down; no gate if not ``gated``), behind ``count``
+        experts."""
         of_expert = ("expert",) * len(count)
-        wide = _Leaf((*count, d, width), (*of_expert, "embed", "mlp"))
-        narrow = _Leaf((*count, width, d), (*of_expert, "mlp", "embed"))
-        return dict(zip(names, (wide, wide, narrow)))
+        wide = _Leaf((*count, stream, width), (*of_expert, "embed", "mlp"))
+        narrow = _Leaf((*count, width, stream), (*of_expert, "mlp", "embed"))
+        leaves = dict(zip(names, (wide, wide, narrow)))
+        return leaves if gated else {name: leaves[name] for name in names[1:]}
 
     if not experts:
-        return swiglu(_EXPERT_WEIGHTS, config.hidden_dim), {}
+        return mlp_of(_EXPERT_WEIGHTS, config.hidden_dim), {}
     # the router is drawn and rounded as every weight is, and kept in float32
     router = lambda keys, shape, dtype: _normal(keys, shape, dtype).astype(jnp.float32)
     no_bias = lambda keys, shape, dtype: jnp.zeros(shape, jnp.float32)
     mlp = {
         "router": _Leaf((d, moe.num_experts), ("embed", None), router),
-        **swiglu(_EXPERT_WEIGHTS, _expert_dim(config), moe.num_held),
+        **mlp_of(
+            _EXPERT_WEIGHTS, _expert_dim(config), moe.num_held,
+            stream=moe.latent_dim or d, gated=moe.gated,
+        ),
     }
     if moe.scoring == "sigmoid":
         mlp["router_bias"] = _Leaf((moe.num_experts,), (None,), no_bias)
-    if not moe.shared_experts:
-        return mlp, {}
-    names = ("shared_gate", "shared_up", "shared_down")
-    return mlp, swiglu(names, moe.shared_experts * _expert_dim(config))
+    outside = {}
+    if moe.latent_dim:
+        outside["latent_down"] = _Leaf((d, moe.latent_dim), ("embed", "mlp"))
+        outside["latent_up"] = _Leaf((moe.latent_dim, d), ("mlp", "embed"))
+    if moe.shared_experts:
+        names = ("shared_gate", "shared_up", "shared_down")
+        width = moe.shared_dim or moe.shared_experts * _expert_dim(config)
+        outside.update(mlp_of(names, width, gated=moe.gated))
+    return mlp, outside
 
 
 def _stacks(config: TransformerConfig) -> dict:
@@ -825,17 +988,29 @@ def _stacks(config: TransformerConfig) -> dict:
     parts ``init_params`` draws (the block norms go with the mixer).
     ``dense_layers`` is the dense prefix, ``[count, ...]``; ``layers`` is
     ``[count, ...]`` or, under a pattern, ``{kind: [periods, count in a
-    period, ...]}``, the kinds in the order the pattern first names them."""
+    period, ...]}``, the kinds in the order the pattern first names them; a
+    pattern of one-block layers stacks its layers by PLACE in the period, a
+    list ``[place: [periods, ...]]`` (``layer_order`` walks either layout in
+    the model's order), a mixer's place ``attn_norm`` and the mixer and no
+    MLP, an "mlp" place ``mlp_norm`` and the MLP and no mixer."""
     def stack(kind, experts, *lead):
         norm = _norm(config.dim)
-        mixer = {"attn_norm": norm, "mlp_norm": norm, **_MIXERS[kind][0](config)}
-        return lead, mixer, *_mlp_leaves(config, experts)
+        if kind == MLP_KIND:
+            return lead, {"mlp_norm": norm}, *_mlp_leaves(config, experts)
+        mixer = {"attn_norm": norm, **_MIXERS[kind][0](config)}
+        if config.one_block:
+            return lead, mixer, {}, {}
+        return lead, {**mixer, "mlp_norm": norm}, *_mlp_leaves(config, experts)
 
     prefix, experts = config.first_dense_layers, config.moe is not None
     stacks = {"dense_layers": stack(config.prefix_kind, False, prefix)} if prefix else {}
     if not config.layer_pattern:
         return {**stacks, "layers": stack(config.layer_kind, experts, config.n_layers - prefix)}
-    counts = {kind: config.layer_pattern.count(kind) for kind in config.layer_pattern}
+    pattern = config.layer_pattern
+    if config.layers_by_place:
+        # ``[periods, ...]`` each: ``_scan_periods`` says why
+        return {**stacks, "layers": [stack(kind, experts, config.periods) for kind in pattern]}
+    counts = {kind: pattern.count(kind) for kind in pattern}
     return {**stacks, "layers": {
         kind: stack(kind, experts, config.periods, count) for kind, count in counts.items()
     }}
@@ -875,12 +1050,16 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
         params = draw(embed, keys)
         kinds_key = next(keys)
         params.update(draw(model, keys), layers={})
-        for number, (kind, (lead, mixer, mlp, shared)) in enumerate(stacks["layers"].items()):
+        # (by place: place number n, where a kind stands above)
+        by_place = config.layers_by_place
+        named = enumerate(stacks["layers"]) if by_place else stacks["layers"].items()
+        params["layers"] = [None] * len(stacks["layers"]) if by_place else {}
+        for number, (name, (lead, mixer, mlp, shared)) in enumerate(named):
             kind_key = jax.random.fold_in(kinds_key, number)
             kind_keys = split(kind_key, 16)
             leaves = draw(mixer, kind_keys, *lead)
             mlp_keys = split(jax.random.fold_in(kind_key, 1), 8) if config.moe else kind_keys
-            params["layers"][kind] = {**leaves, **draw({**mlp, **shared}, mlp_keys, *lead)}
+            params["layers"][name] = {**leaves, **draw({**mlp, **shared}, mlp_keys, *lead)}
     else:
         # the MLP first, then embed, the mixer, lm_head, the shared experts
         lead, mixer, mlp, shared = stacks["layers"]
@@ -927,8 +1106,8 @@ def _over_mesh(kernel: Callable, operands: tuple, result, refuse: tuple, sums: t
     for axis in refuse:
         if dict(mesh.shape).get(axis, 1) > 1:
             raise NotImplementedError((what or (
-                "a layer_pattern with linear or conv layers over a mesh with {axis} > 1 is "
-                "not written: the scan and convolution kernels run per data shard (dp / "
+                "a layer_pattern with linear, conv or ssm layers over a mesh with {axis} > 1 "
+                "is not written: the scan and convolution kernels run per data shard (dp / "
                 "fsdp) with every head, every channel and the whole sequence"
             )).format(axis=axis))
     rules = LogicalRules()
@@ -1063,11 +1242,12 @@ def _latent_qkv(h, layer, config: TransformerConfig, cos_sin, positions):
         return q, k, kv[..., nope:]
 
 
-def _short_conv(x, filters, activation="silu"):
+def _short_conv(x, filters, bias=None, activation="silu"):
     """``SiLU(conv(x))``, or ``conv(x)`` with ``activation=None``: a causal
     depthwise convolution over time, one filter ``filters[:, c]`` a channel,
-    no bias; the LAST tap multiplies the current token (a Conv1d padded on
-    the left). ``x``: [batch, seq, channels]; float32 math, the model
+    a ``bias`` ``[channels]`` ahead of the activation where one is given; the
+    LAST tap multiplies the current token (a Conv1d padded on the left).
+    ``x``: [batch, seq, channels]; float32 math, the model
     dtype's residency. In XLA: the ``attention="reference"`` path, and the
     oracle of the kernels that compute it everywhere else
     (ops/short_conv.py)."""
@@ -1075,18 +1255,21 @@ def _short_conv(x, filters, activation="silu"):
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
     filters = filters.astype(jnp.float32)
     out = sum(padded[:, j:j + seq] * filters[j] for j in range(taps))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return (jax.nn.silu(out) if activation == "silu" else out).astype(x.dtype)
 
 
-def _short_conv_over_mesh(config: TransformerConfig, activation="silu") -> Callable:
-    """A linear or conv layer's convolutions: ``_short_conv`` under
+def _short_conv_over_mesh(config: TransformerConfig, activation="silu", bias=False) -> Callable:
+    """A linear, conv or ssm layer's convolutions, ``conv(x, filters)`` or
+    with ``bias`` ``conv(x, filters, bias)``: ``_short_conv`` under
     ``attention="reference"``, else the kernels of ops/short_conv.py, per
-    data shard with the filters whole on each."""
+    data shard with the filters (and the bias) whole on each."""
     if config.attention == "reference":
         return functools.partial(_short_conv, activation=activation)
     rows = ("batch", None, None)
     kernel = functools.partial(short_conv, activation=activation)
-    return _over_mesh(kernel, (rows, None), rows, ("tp", "sp"), ())
+    return _over_mesh(kernel, (rows, None) + (None,) * bias, rows, ("tp", "sp"), ())
 
 
 def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
@@ -1263,6 +1446,53 @@ def _conv_mixer(h, layer, config: TransformerConfig, *_):
         return (c * z) @ layer["w_out"]
 
 
+def _ssm_mixer(h, layer, config: TransformerConfig, *_):
+    """An "ssm" layer's mixer on the branch input ``h`` [batch, seq, hidden],
+    before the residual add: Mamba-2 (heads ``i`` of ``head_dim`` P on a state
+    of ``state_dim`` N; ``B``, ``C`` in ``n_groups`` groups, head ``i`` reading
+    group ``i // (heads / groups)``)::
+
+        z, xBC, dt~ = h W_z, h W_xbc, h W_dt
+        [x | B | C] = SiLU(conv(xBC) + conv_bias)          (causal, depthwise)
+        dt = softplus(dt~ + dt_bias),  A = -exp(a_log)     (float32, a head)
+        S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T,  y_t = S_t C_t + D x_t
+        out = RMSNorm_groups(y * SiLU(z); y_norm) W_out
+
+    the gate FIRST and then the norm, over each of ``n_groups`` groups of
+    ``inner_dim / n_groups`` channels with one learned weight of ``inner_dim``
+    (``MambaRMSNormGated``). The convolution is the Mosaic kernel pair of
+    ops/short_conv.py with its bias (per data shard under a mesh) and the
+    recurrence the chunked scan of ops/ssd.py, unless
+    ``attention="reference"``, which keeps both in XLA's plain forms
+    (``_short_conv``, the recurrence a token at a time). Everything is
+    token-major, the reshape of what a projection returns."""
+    sm = config.ssm
+    batch, seq, _ = h.shape
+    f32 = jnp.float32
+    with jax.named_scope("ssm_mixer"):
+        z, xbc, dt = (h @ layer[name] for name in ("w_z", "w_xbc", "w_dt"))
+        with jax.named_scope("short_conv"):
+            xbc = _short_conv_over_mesh(config, bias=True)(xbc, layer["conv"], layer["conv_bias"])
+        at = (sm.inner_dim, sm.inner_dim + sm.n_groups * sm.state_dim)
+        x, b, c = (part.reshape(batch, seq, heads, -1) for part, heads in zip(
+            jnp.split(xbc, at, axis=-1), (sm.num_heads, sm.n_groups, sm.n_groups)
+        ))
+        dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
+        a = -jnp.exp(layer["a_log"].astype(f32))
+        scan = ssd_reference if config.attention == "reference" else functools.partial(
+            ssd, chunk=sm.chunk
+        )
+        y = scan(x, dt, a, b, c, layer["d_skip"].astype(f32))      # [batch, seq, heads, P]
+        gated = y.reshape(batch, seq, sm.n_groups, -1).astype(f32) * jax.nn.silu(
+            z.reshape(batch, seq, sm.n_groups, -1).astype(f32)
+        )
+        normed = gated * jax.lax.rsqrt(
+            jnp.mean(gated * gated, axis=-1, keepdims=True) + config.rms_norm_eps
+        )
+        y = (normed.reshape(batch, seq, -1) * layer["y_norm"].astype(f32)).astype(h.dtype)
+        return y @ layer["w_out"]
+
+
 def _gqa_heads(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
     """Grouped-query attention's output by head ``[batch, heads, seq,
     head_dim]`` on the branch input ``h``: q / k / v, RoPE where ``cos_sin``
@@ -1413,8 +1643,12 @@ _MIXERS = {
     "conv": (_conv_leaves, _conv_mixer),
     "window": (_gqa_leaves, _window_mixer),
     "sparse": (_sparse_leaves, _sparse_mixer),
+    "ssm": (_ssm_leaves, _ssm_mixer),
 }
 LAYER_KINDS = tuple(_MIXERS)
+# What a ``layer_pattern`` may name beside the mixers: a layer that is its MLP
+# alone. Every layer of such a pattern is ONE block (``one_block``).
+MLP_KIND = "mlp"
 
 
 def _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn):
@@ -1466,8 +1700,18 @@ def _relu_mul(gate, up):
     return (act * up.astype(jnp.float32)).astype(gate.dtype)
 
 
-# ``MoEConfig.activation`` -> the gated product of an expert's two halves.
-_GATE_MUL = {"silu": _silu_mul, "relu": _relu_mul}
+@functools.partial(jax.checkpoint, prevent_cse=False)
+def _relu2(up):
+    """relu(up)^2 (an un-gated expert's activation), ``_silu_mul``'s way:
+    float32 math, the model dtype's residency, nothing kept for the backward."""
+    act = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return (act * act).astype(up.dtype)
+
+
+# ``MoEConfig.activation`` -> what stands between an expert's first matrices
+# and its last: the gated product of its two halves (gate, up), or of an
+# un-gated expert the activation of its one (up).
+_GATE_MUL = {"silu": _silu_mul, "relu": _relu_mul, "relu2": _relu2}
 
 
 # Same trick for the norm: backward recomputes the f32 normalize from the
@@ -1482,10 +1726,10 @@ def _rmsnorm_ckpt(x, weight, eps):
 def _dense_mlp(h, w_gate, w_up, w_down, gate_mul=_silu_mul):
     # silu math in f32 for accuracy but residuals stored in the model dtype
     # (bf16): halves the dominant activation-memory term vs keeping the
-    # f32 intermediates live for backward.
-    gate = (h @ w_gate).astype(h.dtype)
-    up = (h @ w_up).astype(h.dtype)
-    return gate_mul(gate, up) @ w_down
+    # f32 intermediates live for backward. ``w_gate`` None: an un-gated MLP,
+    # ``gate_mul`` its activation.
+    halves = ((h @ w).astype(h.dtype) for w in (w_gate, w_up) if w is not None)
+    return gate_mul(*halves) @ w_down
 
 
 def _sum_of_choices(rows, written, weights=None):
@@ -1718,14 +1962,16 @@ _sum_by_token.defvjp(_sum_by_token_fwd, _sum_by_token_bwd)
 
 
 def _expert_mlps(gate_mul, rows, experts, group_sizes, stacks):
-    """The experts' gated MLP (``gate_mul``: SwiGLU's or ReGLU's product) on
-    ``rows`` sorted into ``group_sizes``."""
+    """The experts' MLP on ``rows`` sorted into ``group_sizes``: gated
+    (``gate_mul``: SwiGLU's or ReGLU's product) or, of ``experts`` without a
+    gate, ``gate_mul`` of the one first matrix's output."""
 
     def expert(rows, name):
         return grouped_matmul(rows, experts[name], group_sizes, within=stacks.get(name))
 
     with jax.named_scope("experts"):
-        return expert(gate_mul(expert(rows, "w_gate"), expert(rows, "w_up")), "w_down")
+        first = [expert(rows, name) for name in _EXPERT_WEIGHTS[:2] if name in experts]
+        return expert(gate_mul(*first), "w_down")
 
 
 def _by_every_pair(gate_mul, ht, weights, experts, sorting, stacks):
@@ -1828,15 +2074,15 @@ def _by_held_pair(bound, gate_mul, ht, weights, experts, sorting, stacks):
 def _by_held_expert(first_expert, gate_mul, expert, ht, weights, chosen, w_gate_up, w_down):
     """One held expert's part of the sum, float32 ``[tokens, d]``, the plain
     way: its gated MLP over EVERY token (``w_gate_up`` ``[2, d, width]``: gate
-    and up as one matmul), times the weight of the token's choice of it (0
-    where it was not chosen). No sort, no gather: a trip of the worst case's
-    loop (``_held_experts``)."""
+    and up as one matmul; ``[1, d, width]`` of an un-gated expert), times the
+    weight of the token's choice of it (0 where it was not chosen). No sort,
+    no gather: a trip of the worst case's loop (``_held_experts``)."""
     with jax.named_scope("dispatch"):
         mine = chosen == first_expert + expert
         share = jnp.sum(jnp.where(mine, weights.astype(ht.dtype), 0).astype(jnp.float32), axis=-1)
     with jax.named_scope("experts"):
         both = jnp.einsum("td,gdf->tgf", ht, w_gate_up).astype(ht.dtype)
-        out = gate_mul(both[:, 0], both[:, 1]) @ w_down
+        out = gate_mul(*(both[:, half] for half in range(both.shape[1]))) @ w_down
     with jax.named_scope("dispatch"):
         return share[:, None] * out.astype(jnp.float32)
 
@@ -1853,10 +2099,12 @@ def _experts_like(stacks):
 
 def _one_experts_weights(stacks, expert):
     """``(w_gate_up [2, d, width], w_down)`` of held expert ``expert``, out
-    of the stacks."""
+    of the stacks (``[1, d, width]``: an un-gated expert's ``w_up`` alone)."""
     with jax.named_scope("experts"):
-        gate, up, down = (stacks[name][0][stacks[name][1], expert] for name in _EXPERT_WEIGHTS)
-        return jnp.stack([gate, up]), down
+        *first, down = (
+            stacks[name][0][stacks[name][1], expert] for name in _EXPERT_WEIGHTS if name in stacks
+        )
+        return jnp.stack(first), down
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
@@ -1939,7 +2187,9 @@ def _held_experts_bwd(bound, first_expert, gate_mul, operands, g):
         more_dht, more_dweights, dgate_up, ddown = pull(g)
         dexperts = {
             name: jax.lax.dynamic_update_index_in_dim(dexperts[name], dleaf, expert, 0)
-            for name, dleaf in zip(_EXPERT_WEIGHTS, (*dgate_up, ddown))
+            for name, dleaf in zip(
+                (name for name in _EXPERT_WEIGHTS if name in dexperts), (*dgate_up, ddown)
+            )
         }
         return dht + more_dht, dweights + more_dweights, dexperts
 
@@ -1956,9 +2206,11 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
     """Dropless mixture of experts: every token reaches each of its
     ``top_k`` experts whatever the routing. Returns ``(out, routing)``.
-    The router reads ``routed_by`` ``[batch, seq, d]`` where one is given
-    (``MoEConfig.router_input="layer_input"``: the layer's input), else
-    ``h``, the experts' normed input.
+    The router reads ``routed_by`` ``[batch, seq, .]`` where one is given
+    (``MoEConfig.router_input="layer_input"``: the layer's input; under
+    ``latent_dim`` the normed stream, where ``h`` is its latent projection
+    and the sum comes back at the latent's width), else ``h``, the experts'
+    normed input.
 
     The ``tokens x top_k`` (token, choice) pairs are numbered CHOICE-MAJOR,
     pair ``p = choice x tokens + token`` (a pair's token is ``p % tokens``):
@@ -2022,7 +2274,7 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
     bounded = bound < tokens * moe.top_k
     gate_mul = _GATE_MUL[moe.activation]
     with jax.named_scope("router"):
-        read = ht if routed_by is None else routed_by.reshape(tokens, d)
+        read = ht if routed_by is None else routed_by.reshape(tokens, -1)
         logits = jnp.matmul(
             read.astype(jnp.float32), layer["router"].astype(jnp.float32),
             precision=moe.router_precision,
@@ -2091,7 +2343,7 @@ def _moe_mlp(h, layer, config: TransformerConfig, routed_by=None):
             }
             if moe.held:
                 sorting["held_pairs"] = routing["held_pairs"]
-    expert_weights = {name: layer[name] for name in _EXPERT_WEIGHTS}
+    expert_weights = {name: layer[name] for name in _EXPERT_WEIGHTS if name in layer}
     stacks = layer.get("stack", {})
     if bounded:
         # a layer that comes with no stack is a stack of one
@@ -2174,19 +2426,30 @@ def _mlp_block(x, layer, config: TransformerConfig, experts: bool, layer_input=N
             raise NotImplementedError(
                 'norm_placement="post" over a mixture-of-experts layer is not written'
             )
+        moe = config.moe
         routed_by = None
-        if config.moe.router_input == "layer_input":
+        if moe.router_input == "layer_input":
             if layer_input is None:
                 raise ValueError("router_input='layer_input': the block needs the layer's input")
             routed_by = layer_input
-        out, routing = _moe_over_mesh(h, layer, config, routed_by)
-        if config.moe.shared_experts:
-            # Outside the per-shard call: a plain gated MLP that GSPMD shards
-            # as it shards a dense MLP.
+        routed = h
+        if moe.latent_dim:
+            # Outside the per-shard call, as the shared branch is: the experts
+            # read the latent, the router still the stream.
+            with jax.named_scope("moe_latent"):
+                routed = h @ layer["latent_down"]
+            routed_by = h if routed_by is None else routed_by
+        out, routing = _moe_over_mesh(routed, layer, config, routed_by)
+        if moe.latent_dim:
+            with jax.named_scope("moe_latent"):
+                out = out @ layer["latent_up"]
+        if moe.shared_experts:
+            # Outside the per-shard call: a plain MLP that GSPMD shards as it
+            # shards a dense MLP.
             with jax.named_scope("shared"):
                 out = out + _dense_mlp(
-                    h, layer["shared_gate"], layer["shared_up"], layer["shared_down"],
-                    _GATE_MUL[config.moe.activation],
+                    h, layer.get("shared_gate"), layer["shared_up"], layer["shared_down"],
+                    _GATE_MUL[moe.activation],
                 ).astype(out.dtype)
         return x + out.astype(x.dtype), routing
 
@@ -2208,7 +2471,9 @@ def _scan_layers(step, carry, layers, *xs):
     time as before; their values are dead code."""
     if "router" not in layers:
         return jax.lax.scan(lambda carry, scanned: step(carry, *scanned), carry, (layers, *xs))
-    stacks = {name: jax.lax.stop_gradient(layers[name]) for name in _EXPERT_WEIGHTS}
+    stacks = {
+        name: jax.lax.stop_gradient(layers[name]) for name in _EXPERT_WEIGHTS if name in layers
+    }
 
     def body(carry, scanned):
         index, layer, *rest = scanned
@@ -2219,47 +2484,97 @@ def _scan_layers(step, carry, layers, *xs):
     return jax.lax.scan(body, carry, (index, layers, *xs))
 
 
-def _scan_periods(steps, carry, layers, pattern):
+def _scan_periods(steps, carry, layers, config):
     """``jax.lax.scan`` over the PERIODS of a patterned model: ``layers`` is
     ``{kind: leaves of [periods, count in a period, ...]}`` and the body
-    runs ``steps[kind](carry, layer)`` for the period's layers in
-    ``pattern``'s order, each layer the next of its kind. A kind's step is
+    runs ``steps[kind](carry, layer)`` for the period's layers in the
+    pattern's order, each layer the next of its kind. A kind's step is
     the one (possibly checkpointed) layer step of every other model, so a
     period keeps what one layer keeps, once a layer.
 
     Over mixture-of-experts layers the body also hands out each layer's
-    ``routing``, stacked in the layers' order (``[periods x period, ...]``
-    as ``_scan_layers`` stacks them; None over dense MLPs), and the expert
+    ``routing``, stacked in the order of the layers that route (``[periods x
+    routing layers in a period, ...]`` as ``_scan_layers`` stacks them; None
+    over dense MLPs; a one-block mixer layer has no routing), and the expert
     kernels read a layer's weights where they lie, as there: the body closes
     over each kind's expert stacks as ``[periods x count, experts, k, n]``
     (a bitcast) under ``stop_gradient`` and finds layer ``period x count +
-    number`` in them."""
-    stacks = {
-        kind: {
-            name: jax.lax.stop_gradient(leaves[name]).reshape(-1, *leaves[name].shape[2:])
-            for name in (_EXPERT_WEIGHTS if "router" in leaves else ())
-        }
-        for kind, leaves in layers.items()
+    number`` in them.
+
+    Under ``config.layers_by_place`` (today the patterns of ONE-BLOCK layers)
+    the layers lie by PLACE in the period (``layers`` a list, a tree of
+    ``[periods, ...]`` leaves a place), and ONE period is walked in line, with
+    no loop of one trip around it: the one path by place that a cell runs.
+    More periods by place go under the scan as the older patterns do, and
+    tests alone cover that (tests/test_ssm_moe.py's two periods). Stacked by
+    kind, a leaf of five layers has its gradient whole only when the LAST of
+    the five backward passes is through, and the fused step, which updates
+    the donated weights and moments in place, then keeps every gradient to
+    the end and writes the period's program three times over: 6.8 GB of
+    temporaries and 325 MB of generated code for a described v5e, where by
+    place a layer's leaves are updated as soon as its own backward is through
+    (PERF.md section 6, PR 55). The older patterns keep their layout and their
+    programs; that there are two layouts is a debt (ROADMAP Queue 1)."""
+    pattern = config.layer_pattern
+    in_place = lambda leaves, lead: {
+        name: jax.lax.stop_gradient(leaves[name]).reshape(-1, *leaves[name].shape[lead:])
+        for name in _EXPERT_WEIGHTS if "router" in leaves and name in leaves
     }
 
-    def body(carry, scanned):
-        index, period = scanned
-        taken = dict.fromkeys(period, 0)
+    def walk(carry, one_by_one):
+        """``(kind, layer, its expert stacks in place, its number in them)`` in
+        order. Returns the routings stacked, or None."""
         routings = []
-        for kind in pattern:
-            number = taken[kind]
-            taken[kind] += 1
-            layer = jax.tree.map(lambda leaf: leaf[number], period[kind])
-            if stacks[kind]:
-                at = index * pattern.count(kind) + number
-                layer["stack"] = {name: (stack, at) for name, stack in stacks[kind].items()}
+        for kind, layer, stacks, at in one_by_one:
+            if stacks:
+                layer = {**layer, "stack": {name: (stack, at) for name, stack in stacks.items()}}
             carry, routing = steps[kind](carry, layer)
-            routings.append(routing)
+            if routing is not None:
+                routings.append(routing)
+        if not routings:
+            return carry, None
         return carry, jax.tree.map(lambda *leaves: jnp.stack(leaves), *routings)
 
+    if config.layers_by_place:
+        stacks = [in_place(leaves, 1) for leaves in layers]
+
+        def body(carry, scanned):
+            index, period = scanned
+            return walk(carry, zip(pattern, period, stacks, [index] * len(pattern)))
+    else:
+        stacks = {kind: in_place(leaves, 2) for kind, leaves in layers.items()}
+
+        def one_by_one(index, period):
+            taken = dict.fromkeys(period, 0)
+            for kind in pattern:
+                number = taken[kind]
+                taken[kind] += 1
+                layer = jax.tree.map(lambda leaf: leaf[number], period[kind])
+                yield kind, layer, stacks[kind], index * pattern.count(kind) + number
+
+        def body(carry, scanned):
+            return walk(carry, one_by_one(*scanned))
+
     periods = next(iter(jax.tree.leaves(layers))).shape[0]
+    if periods == 1 and config.layers_by_place:
+        return body(carry, (jnp.int32(0), jax.tree.map(lambda leaf: leaf[0], layers)))
     carry, routing = jax.lax.scan(body, carry, (jnp.arange(periods, dtype=jnp.int32), layers))
     return carry, jax.tree.map(lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), routing)
+
+
+def layer_order(params: dict, config: TransformerConfig):
+    """A patterned model's layers in the order the stream passes them, ``(kind,
+    the layer's own leaves)``, one at a time, out of either layout of
+    ``params["layers"]`` (``_stacks``)."""
+    for period in range(config.periods):
+        taken = dict.fromkeys(config.layer_pattern, 0)
+        for place, kind in enumerate(config.layer_pattern):
+            if config.layers_by_place:
+                yield kind, jax.tree.map(lambda leaf: leaf[period], params["layers"][place])
+            else:
+                number = taken[kind]
+                yield kind, jax.tree.map(lambda leaf: leaf[period, number], params["layers"][kind])
+            taken[kind] += 1
 
 
 def _embed(params, tokens):
@@ -2290,7 +2605,8 @@ def _remat_policy(remat: str) -> Callable:
     kernels again too), and under "dots" the matmul outputs besides."""
     policies = jax.checkpoint_policies
     flash = policies.save_only_these_names(
-        *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *INDEX_RESIDUAL_NAMES
+        *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *INDEX_RESIDUAL_NAMES,
+        *SSD_RESIDUAL_NAMES,
     )
     if remat == "full":
         return flash
@@ -2333,8 +2649,12 @@ def _hidden_with_routing(params, tokens, config, positions=None, selections=Fals
     x = _embed(params, tokens)
 
     def layer_step(kind, experts, carry, layer):
-        x, terms = _attention_block(carry, layer, kind, config, cos_sin, positions, attention_fn)
-        x, routing = _mlp_block(x, layer, config, experts, carry)
+        # under ``one_block`` a layer is its mixer alone or its MLP alone
+        x, terms, routing = carry, None, None
+        if kind != MLP_KIND:
+            x, terms = _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn)
+        if kind == MLP_KIND or not config.one_block:
+            x, routing = _mlp_block(x, layer, config, experts, carry)
         if config.sparse is not None:
             # the scorer's term rides the scan beside the experts' routing
             terms = terms or {"index_loss": jnp.zeros((), jnp.float32)}
@@ -2354,8 +2674,8 @@ def _hidden_with_routing(params, tokens, config, positions=None, selections=Fals
     if "dense_layers" in params:
         x, _ = _scan_layers(step(config.prefix_kind, False), x, params["dense_layers"])
     if config.layer_pattern:
-        steps = {kind: step(kind, experts) for kind in params["layers"]}
-        return _scan_periods(steps, x, params["layers"], config.layer_pattern)
+        steps = {kind: step(kind, experts) for kind in dict.fromkeys(config.layer_pattern)}
+        return _scan_periods(steps, x, params["layers"], config)
     return _scan_layers(step(config.layer_kind, experts), x, params["layers"])
 
 
@@ -2583,7 +2903,8 @@ def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
     if config.layer_pattern:
         raise NotImplementedError(
             f"{what} splits ONE stacked layer tree; a config with a layer_pattern stacks "
-            "its layers by period and kind: train it fused (loss_fn)"
+            f"its layers by period and kind ({', '.join(config._kinds())}): train it fused "
+            "(loss_fn)"
         )
     if config.first_dense_layers:
         raise NotImplementedError(
@@ -2683,6 +3004,13 @@ def _refuse_latent_cache(config: TransformerConfig) -> None:
         raise NotImplementedError(
             "decode with window layers needs a ring cache of `window` rows a window layer "
             "beside the full layers' caches, under one allocator, which is not written yet"
+        )
+    if config.layer_pattern and {"ssm", MLP_KIND} & set(config._kinds()):
+        raise NotImplementedError(
+            'decode with "ssm" layers (and the one-block "mlp" layers beside them) needs a '
+            "state cache beside the KV cache (a [head_dim, state_dim] state a head and the "
+            "last conv_kernel - 1 rows of xBC an ssm layer) and a step that runs one block "
+            "a layer, which is not written yet"
         )
     if config.layer_pattern and "conv" in config._kinds():
         raise NotImplementedError(

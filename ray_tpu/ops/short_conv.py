@@ -7,9 +7,10 @@ operands.
 
 With ``x`` ``[batch, seq, channels]`` and one filter ``filters[:, c]`` of
 ``taps`` weights a channel, the LAST tap on the current token (a Conv1d
-padded on the left, no bias)::
+padded on the left), and where a ``bias`` ``[channels]`` is given (Mamba-2's
+``use_conv_bias``) that bias ahead of the activation::
 
-    pre[t] = sum_j filters[j] x[t - (taps - 1 - j)]       (x[t < 0] = 0)
+    pre[t] = sum_j filters[j] x[t - (taps - 1 - j)] (+ bias)   (x[t < 0] = 0)
     y[t]   = pre[t] sigmoid(pre[t])                       (activation "silu")
     y[t]   = pre[t]                                       (activation None)
 
@@ -37,7 +38,11 @@ forward again: 80.3 ms a step of the Olmo-Hybrid cell for 9.7 ms of bytes
   (``g`` past the sequence's end is zero), and ``dfilters[j] = sum_t g[t]
   x[t - (taps - 1 - j)]`` accumulates in float32 in an output block that
   stays resident across the sequence axis (eight partial rows a tap,
-  folded with the batch outside).
+  folded with the batch outside). The bias rides in the filters' block, the
+  row after the last tap (``_padded``), and its gradient ``sum_t g[t]`` is one
+  more group of partial rows: a call without one traces the kernels it
+  traced before there was one (tests/test_ssd.py holds their Mosaic modules
+  to the ones recorded then).
 
 Channels are independent, so a last channel block that overhangs the array
 (2880 is 22.5 lane tiles) needs no mask: what it computes from the overhang
@@ -104,9 +109,10 @@ def _conv(shifted, filters, keep):
     return pre
 
 
-def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps, activation):
+def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps, activation, bias=False):
     """``xe``: float32 scratch ``[_HALO + rows, lanes]``, the block under
-    the last rows of the one before it."""
+    the last rows of the one before it. With ``bias`` row ``taps`` of the
+    filters' block is the bias."""
     rows = x_ref.shape[1]
     before = before_ref[0].astype(jnp.float32)[_HALO_BLOCK - _HALO:]
     xe[:_HALO] = jnp.where(pl.program_id(2) == 0, 0.0, before)
@@ -117,6 +123,8 @@ def _forward_kernel(before_ref, x_ref, filters_ref, y_ref, xe, *, taps, activati
         start = pl.multiple_of(i * _STRIP, _STRIP)
         shifted = _shifted(xe[pl.ds(start, _HALO + _STRIP)], taps)
         pre = _conv(shifted, filters, slice(_HALO, None))
+        if bias:
+            pre = pre + filters[taps:taps + 1]
         y = pre * jax.nn.sigmoid(pre) if activation == "silu" else pre
         y_ref[0, pl.ds(start, _STRIP)] = y.astype(y_ref.dtype)
         return carry
@@ -134,11 +142,12 @@ def _fold(x):
 
 
 def _backward_kernel(before_ref, x_ref, after_ref, dy_ref, dy_after_ref, filters_ref,
-                     dx_ref, dfilters_ref, xe, dye, *, taps, seq, activation):
+                     dx_ref, dfilters_ref, xe, dye, *, taps, seq, activation, bias=False):
     """``xe``: float32 scratch ``[_HALO + rows + _HALO, lanes]``, the block
     between its neighbours' rows; ``dye``: ``[rows + _HALO, lanes]``, the
     block of ``dy`` over the rows after it. Rows outside the sequence are
-    zero in both."""
+    zero in both. With ``bias`` (row ``taps`` of the filters' block) the
+    partial sums hold one group more, the bias's own: ``sum_t g[t]``."""
     from jax.experimental.pallas import tpu as pltpu
 
     f32 = jnp.float32
@@ -168,6 +177,8 @@ def _backward_kernel(before_ref, x_ref, after_ref, dy_ref, dy_after_ref, filters
         g = dye[pl.ds(start, _STRIP + _HALO)]
         if activation == "silu":
             pre = _conv(shifted, filters, slice(_HALO, None))
+            if bias:
+                pre = pre + filters[taps:taps + 1]
             sig = jax.nn.sigmoid(pre)
             g = g * (sig * (1.0 + pre * (1.0 - sig)))
         dx = None
@@ -178,13 +189,14 @@ def _backward_kernel(before_ref, x_ref, after_ref, dy_ref, dy_after_ref, filters
             dx = term if dx is None else dx + term
         dx_ref[0, pl.ds(start, _STRIP)] = dx.astype(dx_ref.dtype)
         own = g[:_STRIP]
-        return tuple(
+        of_taps = tuple(
             sums[j] + _fold(own * shifted[taps - 1 - j][_HALO:_HALO + _STRIP])
             for j in range(taps)
         )
+        return of_taps + ((sums[taps] + _fold(own),) if bias else ())
 
     zeros = jnp.zeros((_HALO, x_ref.shape[2]), f32)
-    sums = jax.lax.fori_loop(0, rows // _STRIP, one_strip, (zeros,) * taps)
+    sums = jax.lax.fori_loop(0, rows // _STRIP, one_strip, (zeros,) * (taps + bias))
 
     @pl.when(step == 0)
     def _start():
@@ -213,23 +225,32 @@ def _layout(x, filters):
     return grid, block, before, after, taps
 
 
-def _padded(filters):
-    """float32 ``[_HALO, channels]``: the taps over zero rows."""
+def _padded(filters, bias=None):
+    """float32 ``[_HALO, channels]``: the taps over zero rows, the first of
+    which holds the ``bias`` where there is one."""
     taps = filters.shape[0]
-    if taps - 1 > _HALO:
-        raise NotImplementedError(f"{taps} taps: a block reads {_HALO} rows of its neighbour")
-    return jnp.pad(filters.astype(jnp.float32), ((0, _HALO - taps), (0, 0)))
+    if taps - 1 > _HALO or (bias is not None and taps >= _HALO):
+        raise NotImplementedError(
+            f"{taps} taps: a block reads {_HALO} rows of its neighbour, and a bias takes the "
+            "filters' next row"
+        )
+    filters = filters.astype(jnp.float32)
+    if bias is not None:
+        filters = jnp.concatenate([filters, bias.astype(jnp.float32)[None]])
+    return jnp.pad(filters, ((0, _HALO - filters.shape[0]), (0, 0)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "activation"))
-def _short_conv_forward(x, filters, *, interpret, activation="silu"):
+def _short_conv_forward(x, filters, bias=None, *, interpret, activation="silu"):
     from jax.experimental.pallas import tpu as pltpu
 
-    padded = _padded(filters)
+    padded = _padded(filters, bias)
     grid, block, before, _, taps = _layout(x, padded)
     _, rows, lanes = block.block_shape
     return pl.pallas_call(
-        functools.partial(_forward_kernel, taps=filters.shape[0], activation=activation),
+        functools.partial(
+            _forward_kernel, taps=filters.shape[0], activation=activation, bias=bias is not None
+        ),
         grid=grid,
         in_specs=[before, block, taps],
         out_specs=block,
@@ -243,23 +264,27 @@ def _short_conv_forward(x, filters, *, interpret, activation="silu"):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "activation"))
-def _short_conv_backward(x, filters, dy, *, interpret, activation="silu"):
-    """``dx`` in ``x``'s dtype and ``dfilters`` in float32."""
+def _short_conv_backward(x, filters, dy, bias=None, *, interpret, activation="silu"):
+    """``dx`` in ``x``'s dtype and ``dfilters`` in float32 (with a ``bias``,
+    ``(dx, dfilters, dbias)``)."""
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq, channels = x.shape
     taps = filters.shape[0]
-    padded = _padded(filters)
+    padded = _padded(filters, bias)
     grid, block, before, after, taps_spec = _layout(x, padded)
     _, rows, lanes = block.block_shape
+    sums = taps + (bias is not None)
     dx, partial = pl.pallas_call(
-        functools.partial(_backward_kernel, taps=taps, seq=seq, activation=activation),
+        functools.partial(
+            _backward_kernel, taps=taps, seq=seq, activation=activation, bias=bias is not None
+        ),
         grid=grid,
         in_specs=[before, block, after, block, after, taps_spec],
-        out_specs=[block, pl.BlockSpec((1, taps * _HALO, lanes), lambda b, c, s: (b, 0, c))],
+        out_specs=[block, pl.BlockSpec((1, sums * _HALO, lanes), lambda b, c, s: (b, 0, c))],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct((batch, taps * _HALO, channels), jnp.float32),
+            jax.ShapeDtypeStruct((batch, sums * _HALO, channels), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((rows + 2 * _HALO, lanes), jnp.float32),
@@ -270,34 +295,38 @@ def _short_conv_backward(x, filters, dy, *, interpret, activation="silu"):
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
     )(x, x, x, dy, dy, padded)
-    return dx, partial.reshape(batch, taps, _HALO, channels).sum(axis=(0, 2))
+    sums = partial.reshape(batch, sums, _HALO, channels).sum(axis=(0, 2))
+    return (dx, sums) if bias is None else (dx, sums[:taps], sums[taps])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _vjp(x, filters, interpret, activation):
-    return _short_conv_forward(x, filters, interpret=interpret, activation=activation)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _vjp(x, filters, bias, interpret, activation):
+    return _short_conv_forward(x, filters, bias, interpret=interpret, activation=activation)
 
 
-def _vjp_fwd(x, filters, interpret, activation):
-    y = _short_conv_forward(x, filters, interpret=interpret, activation=activation)
-    return y, (x, filters)
+def _vjp_fwd(x, filters, bias, interpret, activation):
+    y = _short_conv_forward(x, filters, bias, interpret=interpret, activation=activation)
+    return y, (x, filters, bias)
 
 
 def _vjp_bwd(interpret, activation, kept, dy):
-    x, filters = kept
-    dx, dfilters = _short_conv_backward(
-        x, filters, dy, interpret=interpret, activation=activation
+    x, filters, bias = kept
+    dx, dfilters, *dbias = _short_conv_backward(
+        x, filters, dy, bias, interpret=interpret, activation=activation
     )
-    return dx, dfilters.astype(filters.dtype)
+    dbias = dbias[0].astype(bias.dtype) if dbias else None
+    return dx, dfilters.astype(filters.dtype), dbias
 
 
 _vjp.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def short_conv(x, filters, *, activation: str | None = "silu", interpret: bool | None = None):
+def short_conv(x, filters, bias=None, *, activation: str | None = "silu",
+               interpret: bool | None = None):
     """``SiLU(conv(x))`` of the module docstring, or with ``activation=None``
-    ``conv(x)`` alone. ``x``: [batch, seq, channels]; ``filters``: [taps,
-    channels]. Returns ``x``'s shape and dtype; differentiable in both."""
+    ``conv(x)`` alone; with ``bias`` ``[channels]`` that is added before the
+    activation. ``x``: [batch, seq, channels]; ``filters``: [taps, channels].
+    Returns ``x``'s shape and dtype; differentiable in all it is given."""
     if activation not in ("silu", None):
         raise ValueError(f"unknown activation {activation!r}: 'silu' or None")
-    return _vjp(x, filters, resolve_interpret(interpret), activation)
+    return _vjp(x, filters, bias, resolve_interpret(interpret), activation)

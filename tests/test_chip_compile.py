@@ -577,6 +577,50 @@ def test_short_conv_kernels_compile_at_the_conv_mixers_size_for_v5e(one_chip, ac
     assert sum("_short_conv_backward" in name for name in calls) == 1
 
 
+def test_short_conv_with_a_bias_and_the_state_space_scan_compile_for_v5e(one_chip):
+    """A Mamba-2 layer's two device programs at the Nemotron-3-Super cell's
+    sizes. The convolution over ``xBC`` ``[1, 8192, 10240]`` with four taps and
+    a BIAS (the filters' block carries it as its fifth row): the same two Mosaic
+    calls under the same names, the backward handing back ``dbias`` too. The
+    scan (``ops/ssd.py``: XLA einsums under one custom VJP, no Mosaic call) for
+    128 heads of 64 on a state of 128 with B / C in 8 groups: its program holds
+    no array that repeats B or C to the heads (``[.., 8192, 128, 128]``), no
+    state a token, and under 1.5 GiB of temporaries beside its operands."""
+    from ray_tpu.ops import short_conv as SC
+    from ray_tpu.ops.ssd import ssd
+
+    x = jax.ShapeDtypeStruct((1, 8192, 10240), jnp.bfloat16, sharding=one_chip)
+    filters = jax.ShapeDtypeStruct((4, 10240), jnp.bfloat16, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((10240,), jnp.bfloat16, sharding=one_chip)
+
+    def value_and_grads(x, filters, bias):
+        conv = functools.partial(SC.short_conv, interpret=False)
+        loss = lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, filters, bias)
+
+    compiled = jax.jit(value_and_grads).lower(x, filters, bias).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert len(calls) == 2
+    assert sum("_short_conv_forward" in name for name in calls) == 1
+    assert sum("_short_conv_backward" in name for name in calls) == 1
+    _, (dx, dfilters, dbias) = jax.eval_shape(value_and_grads, x, filters, bias)
+    assert (dx.shape, dfilters.shape, dbias.shape) == (x.shape, filters.shape, bias.shape)
+
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    operands = (
+        shaped((1, 8192, 128, 64)), shaped((1, 8192, 128), jnp.float32), shaped((128,), jnp.float32),
+        shaped((1, 8192, 8, 128)), shaped((1, 8192, 8, 128)), shaped((128,), jnp.float32),
+    )
+    grads = jax.grad(lambda *a: jnp.sum(ssd(*a).astype(jnp.float32)), argnums=tuple(range(6)))
+    compiled = jax.jit(grads).lower(*operands).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "8192,128,128]" not in text and "[1,8192,128,64,128]" not in text
+    # the chunk-start states of the backward's first pass: 16 trips x 4 chunks x (8 x 16) heads
+    assert "f32[16,1,4,8,16,64,128]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+
+
 def test_rmsnorm_compiles_for_v5e(one_chip):
     x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)

@@ -123,7 +123,11 @@ def test_the_count_from_shapes_is_the_count_of_the_arrays(shape):
 
 
 def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
-    assert T.LAYER_KINDS == tuple(T._MIXERS) == ("linear", "full", "conv", "window", "sparse")
+    assert T.LAYER_KINDS == tuple(T._MIXERS) == (
+        "linear", "full", "conv", "window", "sparse", "ssm",
+    )
+    # beside the mixers a pattern may name layers that are their MLP alone
+    assert T.MLP_KIND == "mlp" and T.MLP_KIND not in T._MIXERS
     with pytest.raises(ValueError, match="kinds are"):
         T.TransformerConfig.tiny(layer_pattern=("full", "sliding"))
 

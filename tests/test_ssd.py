@@ -1,0 +1,214 @@
+"""``ops/ssd.py``: Mamba-2's recurrence in its chunked form against the
+recurrence a token at a time (``ssd_reference``, the same file's oracle), on
+the CPU in float32 at small sizes: value and every gradient; what the module's
+docstring promises of its arrays (``B`` / ``C`` at the groups, the state at
+chunk boundaries, nothing ``[seq, seq]``) read off the jaxpr; and
+``ops/short_conv.py``'s bias: against the XLA oracle plus a bias, and the calls
+WITHOUT one held to the Mosaic modules they lowered to before there was one.
+"""
+
+import base64
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import transformer as T
+from ray_tpu.ops.short_conv import short_conv
+from ray_tpu.ops.ssd import ssd, ssd_reference
+
+ARGUMENTS = ("x", "dt", "A", "B", "C", "D")
+
+
+def operands(batch, seq, heads, groups, width, state, decays, seed=0, dtype=jnp.float32):
+    """Seeded operands; ``decays`` ``(low, high)``: ``dt A`` a token is spread
+    log-uniformly between them (``A`` in (1, 16) a head, ``dt`` the rest)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
+    low, high = (np.log(value) for value in decays)
+    A = -jnp.exp(jax.random.uniform(keys[0], (heads,), minval=0.0, maxval=np.log(16.0)))
+    a = jnp.exp(jax.random.uniform(keys[1], (batch, seq, heads), minval=low, maxval=high))
+    return (
+        normal(keys[2], batch, seq, heads, width).astype(dtype), a / -A, A,
+        normal(keys[3], batch, seq, groups, state).astype(dtype),
+        normal(keys[4], batch, seq, groups, state).astype(dtype), normal(keys[5], heads),
+    )
+
+
+def close(got, want, tol, what=""):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.all(np.isfinite(got)), what
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
+        what, np.max(np.abs(got - want)), np.max(np.abs(want))
+    )
+
+
+@pytest.mark.parametrize("shape,chunk,decays", [
+    # 8 heads on 2 groups, three chunks, the decays of fresh weights
+    ((2, 48, 8, 2, 4, 6), 16, (1e-3, 1.6)),
+    # ONE chunk holding -0.01 to -30 a token: a split that exponentiated a
+    # positive difference would pass e^88 after three steep tokens
+    ((1, 32, 4, 2, 8, 4), 32, (1e-2, 30.0)),
+    # five chunks, a block of four and one more trip; heads = groups
+    ((1, 80, 2, 2, 4, 4), 16, (1e-2, 30.0)),
+], ids=["groups", "steep_in_one_chunk", "blocks"])
+def test_the_chunked_form_is_the_recurrence_in_value_and_every_gradient(shape, chunk, decays):
+    args = operands(*shape, decays)
+    assert float(jnp.min(args[1] * args[2])) < -0.5 * decays[1]
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    loss = lambda scan: lambda *a: jnp.sum(scan(*a) * weights)
+    chunked = jax.jit(lambda *a: ssd(*a, chunk=chunk))
+    close(chunked(*args), ssd_reference(*args), 2e-5, "value")
+    got = jax.jit(jax.grad(loss(chunked), argnums=range(6)))(*args)
+    want = jax.grad(loss(ssd_reference), argnums=range(6))(*args)
+    for name, mine, theirs in zip(ARGUMENTS, got, want):
+        assert mine.shape == theirs.shape and mine.dtype == theirs.dtype, name
+        close(mine, theirs, 5e-5, name)
+    # under a layer checkpoint that keeps the named output: the same gradients
+    policy = T._remat_policy("full")
+    kept = jax.jit(jax.grad(jax.checkpoint(loss(chunked), policy=policy), argnums=range(6)))(*args)
+    for name, mine, theirs in zip(ARGUMENTS, kept, got):
+        close(mine, theirs, 1e-6, ("checkpointed", name))
+
+
+def _shapes(jaxpr, seen=None):
+    """The shape of every variable of ``jaxpr`` and of the jaxprs inside it."""
+    seen = set() if seen is None else seen
+    for eqn in jaxpr.eqns:
+        seen.update(tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape"))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _shapes(inner, seen)
+    return seen
+
+
+def test_b_and_c_stay_at_the_groups_and_the_state_at_chunk_boundaries():
+    batch, seq, heads, groups, width, state, chunk = 1, 64, 10, 2, 3, 7, 16     # all unlike
+    args = operands(batch, seq, heads, groups, width, state, (1e-3, 1.6))
+    loss = lambda *a: jnp.sum(ssd(*a, chunk=chunk) ** 2)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(*args)
+    grads = jaxpr.out_avals[1:]
+    assert [g.shape for g in grads] == [a.shape for a in args]       # dB, dC at the groups
+    shapes = _shapes(jaxpr.jaxpr)
+    sizes = {int(np.prod(shape)) for shape in shapes}
+    # nothing as large as B or C repeated to the heads, or a state a token
+    tokens = batch * seq
+    assert max(sizes) < tokens * heads * state * min(width, chunk // 2)
+    for shape in shapes:
+        assert shape.count(seq) <= 1, shape                          # nothing [seq, seq]
+        if state in shape and width not in shape:                    # B, C and their like
+            assert heads not in shape and heads // groups not in shape, shape
+    # the chunk-start states of the backward's first pass, and nothing finer
+    states = [s for s in shapes if s[-2:] == (width, state)]
+    assert states and max(int(np.prod(s)) for s in states) == (seq // chunk) * heads * width * state
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner)
+
+
+def test_the_bfloat16_instantiation_keeps_state_sums_and_decays_in_float32():
+    """What the timed step compiles (bfloat16 ``x``, ``B``, ``C``): every scan's
+    carry (the state and its cotangent), every running sum and every exponent
+    is float32 there too, forward and backward. A check of the output cannot
+    hold the carry's dtype: the products round the chunk-start state to
+    bfloat16 once anyway, and carrying it so reads the same to three digits
+    (benchmarks/reference/ssm_moe_decoder.py, the "timed" reading)."""
+    args = operands(1, 64, 4, 2, 8, 16, (1e-3, 1.6), dtype=jnp.bfloat16)
+    loss = lambda *a: jnp.sum(ssd(*a, chunk=16).astype(jnp.float32) ** 2)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(*args)
+    found = {"scan": 0, "exp": 0, "cumsum": 0}
+    for eqn in _equations(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name == "scan":
+            first = eqn.params["num_consts"]
+            carry = eqn.invars[first:first + eqn.params["num_carry"]]
+            assert carry and all(v.aval.dtype == jnp.float32 for v in carry), carry
+        elif name in ("exp", "cumsum"):
+            assert all(v.aval.dtype == jnp.float32 for v in eqn.invars), eqn
+        if name in found:
+            found[name] += 1
+    assert found["scan"] == 3 and found["exp"] >= 3 and found["cumsum"] >= 3, found
+
+
+def test_products_in_the_operands_dtype_accumulate_in_float32():
+    args = operands(1, 64, 4, 2, 8, 16, (1e-3, 1.6), dtype=jnp.bfloat16)
+    got = ssd(*args, chunk=16)
+    assert got.dtype == jnp.bfloat16
+    close(got.astype(jnp.float32), ssd_reference(*args).astype(jnp.float32), 3e-2)
+    with pytest.raises(ValueError, match="multiple of the chunk"):
+        ssd(*operands(1, 40, 4, 2, 8, 16, (1e-3, 1.6)), chunk=16)
+
+
+@pytest.mark.parametrize("activation", ["silu", None])
+def test_the_convolutions_bias_against_the_oracle_plus_a_bias(activation):
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    x = jax.random.normal(keys[0], (2, 200, 160))
+    filters = jax.random.uniform(keys[1], (4, 160), minval=-0.5, maxval=0.5)
+    bias = jax.random.uniform(keys[2], (160,), minval=-0.5, maxval=0.5)
+    weights = jax.random.normal(keys[3], x.shape)
+    kernels = lambda *a: short_conv(*a, activation=activation)
+    oracle = lambda *a: T._short_conv(*a, activation=activation)
+    close(kernels(x, filters, bias), oracle(x, filters, bias), 1e-5, "value")
+    assert float(jnp.max(jnp.abs(oracle(x, filters, bias) - oracle(x, filters)))) > 0.1
+    loss = lambda conv: lambda *a: jnp.sum(conv(*a) * weights)
+    got = jax.grad(loss(kernels), argnums=(0, 1, 2))(x, filters, bias)
+    want = jax.grad(loss(oracle), argnums=(0, 1, 2))(x, filters, bias)
+    for name, mine, theirs in zip(("x", "filters", "bias"), got, want):
+        assert mine.shape == theirs.shape
+        close(mine, theirs, 1e-4, name)
+    with pytest.raises(NotImplementedError, match="next row"):
+        short_conv(x, jnp.zeros((8, 160)), bias)
+
+
+def _mosaic_modules(text: str) -> list[str]:
+    """The Mosaic modules of a program lowered for a TPU, as MLIR without
+    their debug locations (which hold this checkout's path and line numbers)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    modules = []
+    for body in re.findall(r"\\22body\\22: \\22(.*?)\\22", text):
+        context = mlir.make_ir_context()
+        tpu.register_dialect(context)
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            modules.append(module.operation.get_asm(enable_debug_info=False))
+    return modules
+
+
+# The forward and the backward kernel of a call with NO bias, at three of the
+# sizes the cells that shared ``ops/short_conv.py`` before the bias run them
+# (Olmo-Hybrid's q and k; LFM2's gated convolution, three taps and no
+# activation; Solar-Open2's 64 heads of 128): sha256 of each Mosaic module,
+# recorded on the parent of the commit that brought the bias (5c961df).
+_BEFORE_THE_BIAS = {
+    (2880, 4, "silu"): ("9ba89bc37879cf6c", "07472cf7de39b61b"),
+    (2048, 3, None): ("fbf8a86bd7ac8d22", "71b5f2f2e62d6bca"),
+    (8192, 4, "silu"): ("d5e7bc826a1e0806", "f2afe722799e73f1"),
+}
+
+
+@pytest.mark.parametrize("channels,taps,activation", list(_BEFORE_THE_BIAS))
+def test_a_call_without_a_bias_lowers_to_the_kernels_it_lowered_to(channels, taps, activation):
+    x = jax.ShapeDtypeStruct((1, 16384, channels), jnp.bfloat16)
+    filters = jax.ShapeDtypeStruct((taps, channels), jnp.float32)
+
+    def value_and_grads(x, filters):
+        conv = lambda *a: short_conv(*a, activation=activation, interpret=False)
+        loss = lambda *a: jnp.sum(conv(*a).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(loss, argnums=(0, 1))(x, filters)
+
+    lowered = jax.jit(value_and_grads).trace(x, filters).lower(lowering_platforms=("tpu",))
+    digests = tuple(
+        hashlib.sha256(module.encode()).hexdigest()[:16]
+        for module in _mosaic_modules(lowered.as_text())
+    )
+    assert digests == _BEFORE_THE_BIAS[channels, taps, activation]
