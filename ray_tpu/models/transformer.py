@@ -100,8 +100,9 @@ checkpoint policy, one head and loss):
     ``ssm_mixer``), Mamba-2's: ``[z | xBC | dt]`` projections, a causal
     depthwise convolution WITH a bias over ``xBC`` (ops/short_conv.py), the
     recurrence ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t
-    + D x_t`` as a chunked scan (ops/ssd.py, scope ``ssd``; ``B`` and ``C``
-    shared by the heads of a group), ``RMSNorm_groups(y * SiLU(z))``, ``W_out``.
+    + D x_t`` as a chunked scan in Mosaic kernels (ops/ssd.py, scope ``ssd``;
+    ``B`` and ``C`` shared by the heads of a group), ``RMSNorm_groups(y *
+    SiLU(z))``, ``W_out``.
     A ``layer_pattern`` that names ``"mlp"`` beside the mixer kinds makes every
     layer ONE residual block with ONE norm: a mixer's layer carries its mixer
     and ``attn_norm`` and no MLP, an "mlp" layer its MLP and ``mlp_norm`` and
@@ -243,8 +244,9 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # ``_index_loss_terms``, and the transposes around them). And a state-space
 # layer's: "ssm_mixer", inside "attention" (the whole Mamba-2 block: the three
 # projections, the convolution under "short_conv", the decay, the scan, the
-# gated group norm, W_out), within it "ssd" (the chunked scan, forward and
-# backward, opened in ops/ssd.py), and "moe_latent", inside "mlp" (a latent
+# gated group norm, W_out), within it "ssd" (the chunked scan's three Mosaic
+# kernels and the running sums in front of them, forward and backward, opened
+# in ops/ssd.py), and "moe_latent", inside "mlp" (a latent
 # mixture of experts' two projections, down before the dispatch and up after
 # the combine).
 
@@ -1292,6 +1294,19 @@ def _delta_rule_over_mesh(config: TransformerConfig) -> Callable:
     return _over_mesh(kernel, operands, rows, ("tp", "sp"), ())
 
 
+def _ssd_over_mesh(config: TransformerConfig) -> Callable:
+    """The state-space scan of an "ssm" layer over token-major operands (``x``
+    ``[batch, seq, heads, P]``, ``dt`` ``[batch, seq, heads]``, ``B`` / ``C``
+    ``[batch, seq, groups, N]``; ``A`` and ``D`` a head): the recurrence a
+    token at a time under ``attention="reference"``, else the kernels of
+    ops/ssd.py, per data shard with ``A`` and ``D`` whole on each."""
+    if config.attention == "reference":
+        return ssd_reference
+    rows, gates = ("batch", None, None, None), ("batch", None, None)
+    kernel = functools.partial(ssd, chunk=config.ssm.chunk)
+    return _over_mesh(kernel, (rows, gates, None, rows, rows, None), rows, ("tp", "sp"), ())
+
+
 # The epsilon under the square root of q's and k's L2 norm.
 _L2_EPS = 1e-6
 
@@ -1461,8 +1476,9 @@ def _ssm_mixer(h, layer, config: TransformerConfig, *_):
     the gate FIRST and then the norm, over each of ``n_groups`` groups of
     ``inner_dim / n_groups`` channels with one learned weight of ``inner_dim``
     (``MambaRMSNormGated``). The convolution is the Mosaic kernel pair of
-    ops/short_conv.py with its bias (per data shard under a mesh) and the
-    recurrence the chunked scan of ops/ssd.py, unless
+    ops/short_conv.py with its bias and the recurrence the chunked scan of
+    ops/ssd.py, three Mosaic kernels that read and write these layouts as
+    they stand (both per data shard under a mesh), unless
     ``attention="reference"``, which keeps both in XLA's plain forms
     (``_short_conv``, the recurrence a token at a time). Everything is
     token-major, the reshape of what a projection returns."""
@@ -1479,10 +1495,8 @@ def _ssm_mixer(h, layer, config: TransformerConfig, *_):
         ))
         dt = jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32))
         a = -jnp.exp(layer["a_log"].astype(f32))
-        scan = ssd_reference if config.attention == "reference" else functools.partial(
-            ssd, chunk=sm.chunk
-        )
-        y = scan(x, dt, a, b, c, layer["d_skip"].astype(f32))      # [batch, seq, heads, P]
+        # [batch, seq, heads, P]
+        y = _ssd_over_mesh(config)(x, dt, a, b, c, layer["d_skip"].astype(f32))
         gated = y.reshape(batch, seq, sm.n_groups, -1).astype(f32) * jax.nn.silu(
             z.reshape(batch, seq, sm.n_groups, -1).astype(f32)
         )

@@ -582,10 +582,15 @@ def test_short_conv_with_a_bias_and_the_state_space_scan_compile_for_v5e(one_chi
     sizes. The convolution over ``xBC`` ``[1, 8192, 10240]`` with four taps and
     a BIAS (the filters' block carries it as its fifth row): the same two Mosaic
     calls under the same names, the backward handing back ``dbias`` too. The
-    scan (``ops/ssd.py``: XLA einsums under one custom VJP, no Mosaic call) for
-    128 heads of 64 on a state of 128 with B / C in 8 groups: its program holds
-    no array that repeats B or C to the heads (``[.., 8192, 128, 128]``), no
-    state a token, and under 1.5 GiB of temporaries beside its operands."""
+    scan (``ops/ssd.py``: three Mosaic kernels under one custom VJP, a grid
+    step a group's 16 heads for one chunk of 128, the state in a VMEM scratch)
+    for 128 heads of 64 on a state of 128 with B / C in 8 groups: a gradient
+    is the states pass and the backward kernel (the forward kernel too where
+    the output is read), in bfloat16 as the step compiles it and in float32 as
+    the benchmark's check does; its program holds no array that repeats B or C
+    to the heads (``[.., 8192, 128, 128]``), no state a token, the chunk-start
+    states as ONE float32 array, and under 1.5 GiB of temporaries beside its
+    operands."""
     from ray_tpu.ops import short_conv as SC
     from ray_tpu.ops.ssd import ssd
 
@@ -611,14 +616,22 @@ def test_short_conv_with_a_bias_and_the_state_space_scan_compile_for_v5e(one_chi
         shaped((1, 8192, 128, 64)), shaped((1, 8192, 128), jnp.float32), shaped((128,), jnp.float32),
         shaped((1, 8192, 8, 128)), shaped((1, 8192, 8, 128)), shaped((128,), jnp.float32),
     )
-    grads = jax.grad(lambda *a: jnp.sum(ssd(*a).astype(jnp.float32)), argnums=tuple(range(6)))
+    scan = functools.partial(ssd, interpret=False)
+    # the loss reads the output: the forward kernel stays in the gradient's program
+    grads = jax.grad(lambda *a: jnp.sum(scan(*a).astype(jnp.float32) ** 2), argnums=tuple(range(6)))
     compiled = jax.jit(grads).lower(*operands).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
+    assert _mosaic_calls(text) == ["_ssd_forward", "_ssd_states", "_ssd_backward"]
     assert "8192,128,128]" not in text and "[1,8192,128,64,128]" not in text
-    # the chunk-start states of the backward's first pass: 16 trips x 4 chunks x (8 x 16) heads
-    assert "f32[16,1,4,8,16,64,128]" in text
+    # the chunk-start states of the backward's first pass: 64 chunks x (128 heads x 64) rows of 128
+    assert len(set(re.findall(r"f32\[1,64,8192,128\]", text))) == 1
+    assert not re.search(r"f32\[1,(8192|4096|2048|1024|512|256|128),8192,128\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
+    assert [g.shape for g in jax.eval_shape(grads, *operands)] == [a.shape for a in operands]
+    # float32, as the benchmark's check hands the scan its operands: Mosaic's fp32 products
+    exact = tuple(jax.ShapeDtypeStruct(a.shape, jnp.float32, sharding=one_chip) for a in operands)
+    text = jax.jit(grads).lower(*exact).compile().as_text()
+    assert _mosaic_calls(text) == ["_ssd_forward", "_ssd_states", "_ssd_backward"]
 
 
 def test_rmsnorm_compiles_for_v5e(one_chip):

@@ -1,8 +1,10 @@
-"""``ops/ssd.py``: Mamba-2's recurrence in its chunked form against the
-recurrence a token at a time (``ssd_reference``, the same file's oracle), on
-the CPU in float32 at small sizes: value and every gradient; what the module's
+"""``ops/ssd.py``: Mamba-2's recurrence in its chunked form, three Mosaic
+kernels run here by the interpreter, against the recurrence a token at a time
+(``ssd_reference``, the same file's oracle): value and every gradient at small
+sizes and at the published tile, float32 and bfloat16; what the module's
 docstring promises of its arrays (``B`` / ``C`` at the groups, the state at
-chunk boundaries, nothing ``[seq, seq]``) read off the jaxpr; and
+chunk boundaries, nothing ``[seq, seq]``) read off the calls' operands and
+results, and of its dtypes off the kernels' jaxprs; and
 ``ops/short_conv.py``'s bias: against the XLA oracle plus a bias, and the calls
 WITHOUT one held to the Mosaic modules they lowered to before there was one.
 """
@@ -10,6 +12,7 @@ WITHOUT one held to the Mosaic modules they lowered to before there was one.
 import base64
 import hashlib
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +22,7 @@ import pytest
 from ray_tpu.models import transformer as T
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import ssd, ssd_reference
+from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
 
 ARGUMENTS = ("x", "dt", "A", "B", "C", "D")
 
@@ -46,63 +50,46 @@ def close(got, want, tol, what=""):
     )
 
 
-@pytest.mark.parametrize("shape,chunk,decays", [
+# the published tile: a group's 16 heads of 64 (two heads a product) on a state of 128, chunks of 128
+PUBLISHED = (1, 256, 16, 1, 64, 128)
+
+
+@pytest.mark.parametrize("shape,chunk,decays,dtype", [
     # 8 heads on 2 groups, three chunks, the decays of fresh weights
-    ((2, 48, 8, 2, 4, 6), 16, (1e-3, 1.6)),
+    ((2, 48, 8, 2, 4, 6), 16, (1e-3, 1.6), jnp.float32),
     # ONE chunk holding -0.01 to -30 a token: a split that exponentiated a
     # positive difference would pass e^88 after three steep tokens
-    ((1, 32, 4, 2, 8, 4), 32, (1e-2, 30.0)),
-    # five chunks, a block of four and one more trip; heads = groups
-    ((1, 80, 2, 2, 4, 4), 16, (1e-2, 30.0)),
-], ids=["groups", "steep_in_one_chunk", "blocks"])
-def test_the_chunked_form_is_the_recurrence_in_value_and_every_gradient(shape, chunk, decays):
-    args = operands(*shape, decays)
+    ((1, 32, 4, 2, 8, 4), 32, (1e-2, 30.0), jnp.float32),
+    # five chunks; heads = groups, one head a product
+    ((1, 80, 2, 2, 4, 4), 16, (1e-2, 30.0), jnp.float32),
+    (PUBLISHED, 128, (1e-3, 1.6), jnp.float32),
+    (PUBLISHED, 128, (1e-3, 1.6), jnp.bfloat16),
+], ids=["groups", "steep_in_one_chunk", "chunks", "published_tile", "published_tile_bfloat16"])
+def test_the_kernels_are_the_recurrence_in_value_and_every_gradient(shape, chunk, decays, dtype):
+    """The three kernels in the interpreter against the recurrence a token at
+    a time. bfloat16: against the float32 recurrence on the same rounded
+    operands, to what one rounding of every product's operands leaves."""
+    args = operands(*shape, decays, dtype=dtype)
     assert float(jnp.min(args[1] * args[2])) < -0.5 * decays[1]
+    exact = dtype == jnp.float32
+    oracle = ssd_reference if exact else lambda *a: ssd_reference(*(t.astype(jnp.float32) for t in a))
     weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
-    loss = lambda scan: lambda *a: jnp.sum(scan(*a) * weights)
+    loss = lambda scan: lambda *a: jnp.sum(scan(*a).astype(jnp.float32) * weights)
     chunked = jax.jit(lambda *a: ssd(*a, chunk=chunk))
-    close(chunked(*args), ssd_reference(*args), 2e-5, "value")
+    assert chunked(*args).dtype == dtype
+    close(chunked(*args), oracle(*args), 2e-5 if exact else 2e-2, "value")
     got = jax.jit(jax.grad(loss(chunked), argnums=range(6)))(*args)
-    want = jax.grad(loss(ssd_reference), argnums=range(6))(*args)
+    want = jax.grad(loss(oracle), argnums=range(6))(*args)
     for name, mine, theirs in zip(ARGUMENTS, got, want):
         assert mine.shape == theirs.shape and mine.dtype == theirs.dtype, name
-        close(mine, theirs, 5e-5, name)
+        close(mine, theirs, 5e-5 if exact else 3e-2, name)
+    if shape == PUBLISHED:
+        return
     # under a layer checkpoint that keeps the named output: the same gradients
     policy = T._remat_policy("full")
     kept = jax.jit(jax.grad(jax.checkpoint(loss(chunked), policy=policy), argnums=range(6)))(*args)
     for name, mine, theirs in zip(ARGUMENTS, kept, got):
         close(mine, theirs, 1e-6, ("checkpointed", name))
-
-
-def _shapes(jaxpr, seen=None):
-    """The shape of every variable of ``jaxpr`` and of the jaxprs inside it."""
-    seen = set() if seen is None else seen
-    for eqn in jaxpr.eqns:
-        seen.update(tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape"))
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            _shapes(inner, seen)
-    return seen
-
-
-def test_b_and_c_stay_at_the_groups_and_the_state_at_chunk_boundaries():
-    batch, seq, heads, groups, width, state, chunk = 1, 64, 10, 2, 3, 7, 16     # all unlike
-    args = operands(batch, seq, heads, groups, width, state, (1e-3, 1.6))
-    loss = lambda *a: jnp.sum(ssd(*a, chunk=chunk) ** 2)
-    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(*args)
-    grads = jaxpr.out_avals[1:]
-    assert [g.shape for g in grads] == [a.shape for a in args]       # dB, dC at the groups
-    shapes = _shapes(jaxpr.jaxpr)
-    sizes = {int(np.prod(shape)) for shape in shapes}
-    # nothing as large as B or C repeated to the heads, or a state a token
-    tokens = batch * seq
-    assert max(sizes) < tokens * heads * state * min(width, chunk // 2)
-    for shape in shapes:
-        assert shape.count(seq) <= 1, shape                          # nothing [seq, seq]
-        if state in shape and width not in shape:                    # B, C and their like
-            assert heads not in shape and heads // groups not in shape, shape
-    # the chunk-start states of the backward's first pass, and nothing finer
-    states = [s for s in shapes if s[-2:] == (width, state)]
-    assert states and max(int(np.prod(s)) for s in states) == (seq // chunk) * heads * width * state
 
 
 def _equations(jaxpr):
@@ -112,28 +99,82 @@ def _equations(jaxpr):
             yield from _equations(inner)
 
 
+def _kernels(jaxpr):
+    """The ``pallas_call`` equations of ``jaxpr``, in order."""
+    return [eqn for eqn in _equations(jaxpr.jaxpr) if eqn.primitive.name == "pallas_call"]
+
+
+def test_b_and_c_stay_at_the_groups_and_the_state_at_chunk_boundaries():
+    """Read off the three calls' operands and results: the forward, the
+    backward's states pass and its gradients pass."""
+    batch, seq, heads, groups, width, state, chunk = 1, 64, 10, 2, 3, 7, 16     # all unlike
+    args = operands(batch, seq, heads, groups, width, state, (1e-3, 1.6))
+    loss = lambda *a: jnp.sum(ssd(*a, chunk=chunk) ** 2)
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(*args)
+    assert [g.shape for g in jaxpr.out_avals[1:]] == [a.shape for a in args]    # dB, dC at the groups
+    forward, states, backward = _kernels(jaxpr)
+    shapes = lambda variables: [tuple(v.aval.shape) for v in variables]
+    token_major, grouped = (batch, seq, heads * width), (batch, seq, groups * state)
+    scalars = lambda kinds: (batch, groups, kinds, heads // groups, seq)      # a row a head and kind
+    operands_ = [token_major, scalars(3), grouped, grouped, (groups, 1, heads // groups * width)]
+    at_boundaries = (batch, seq // chunk, heads * width, state)
+    assert shapes(forward.invars) == shapes(states.invars) == operands_
+    assert shapes(forward.outvars) == [token_major]
+    # the chunk-start states and nothing finer: one float32 array, seq / chunk of them a head
+    assert shapes(states.outvars) == [at_boundaries] and states.outvars[0].aval.dtype == jnp.float32
+    assert shapes(backward.invars) == [*operands_, at_boundaries, token_major]
+    assert shapes(backward.outvars) == [
+        token_major, scalars(4), grouped, grouped, (batch, groups, 1, heads // groups * width),
+    ]
+    # and around the calls: nothing as large as B or C repeated to the heads or
+    # a state a token, nothing [seq, seq]
+    tokens = batch * seq
+    for eqn in _equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for shape in shapes((*eqn.invars, *eqn.outvars)):
+            assert int(np.prod(shape)) < tokens * heads * state * min(width, chunk // 2), shape
+            assert shape.count(seq) <= 1, shape
+
+
 def test_the_bfloat16_instantiation_keeps_state_sums_and_decays_in_float32():
-    """What the timed step compiles (bfloat16 ``x``, ``B``, ``C``): every scan's
-    carry (the state and its cotangent), every running sum and every exponent
-    is float32 there too, forward and backward. A check of the output cannot
-    hold the carry's dtype: the products round the chunk-start state to
-    bfloat16 once anyway, and carrying it so reads the same to three digits
+    """What the timed step compiles (bfloat16 ``x``, ``B``, ``C``): inside the
+    three kernels the carried state (the scratch; backward: its cotangent),
+    every exponent and every sum is float32, every product takes bfloat16
+    operands into a float32 accumulator; outside them the running sums and
+    their triangle products are float32. A check of the output cannot hold
+    the carry's dtype: the products round the chunk-start state to bfloat16
+    once anyway, and carrying it so reads the same to three digits
     (benchmarks/reference/ssm_moe_decoder.py, the "timed" reading)."""
     args = operands(1, 64, 4, 2, 8, 16, (1e-3, 1.6), dtype=jnp.bfloat16)
     loss = lambda *a: jnp.sum(ssd(*a, chunk=16).astype(jnp.float32) ** 2)
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=range(6)))(*args)
-    found = {"scan": 0, "exp": 0, "cumsum": 0}
-    for eqn in _equations(jaxpr.jaxpr):
-        name = eqn.primitive.name
-        if name == "scan":
-            first = eqn.params["num_consts"]
-            carry = eqn.invars[first:first + eqn.params["num_carry"]]
-            assert carry and all(v.aval.dtype == jnp.float32 for v in carry), carry
-        elif name in ("exp", "cumsum"):
-            assert all(v.aval.dtype == jnp.float32 for v in eqn.invars), eqn
-        if name in found:
-            found[name] += 1
-    assert found["scan"] == 3 and found["exp"] >= 3 and found["cumsum"] >= 3, found
+    kernels = _kernels(jaxpr)
+    assert len(kernels) == 3
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    for call in kernels:
+        body, found = call.params["jaxpr"], {"exp": 0, "reduce_sum": 0, "dot_general": 0}
+        scratch = body.invars[-call.params["grid_mapping"].num_scratch_operands:]
+        assert [(v.aval.shape, v.aval.dtype) for v in scratch] == [((2 * 8, 16), f32)]    # two heads' states
+        for eqn in _equations(body):
+            name = eqn.primitive.name
+            if name in ("exp", "reduce_sum"):
+                assert all(v.aval.dtype == f32 for v in eqn.invars), eqn
+            elif name == "dot_general":
+                assert [v.aval.dtype for v in eqn.invars] == [bf16, bf16], eqn
+                assert eqn.outvars[0].aval.dtype == f32, eqn
+            found[name] = found.get(name, 0) + 1
+        assert found["exp"] >= 1 and found["dot_general"] >= 1, found
+    # the backward's kernel sums its [chunk, chunk] products by row and by column
+    assert sum(e.primitive.name == "reduce_sum" for e in _equations(kernels[2].params["jaxpr"])) >= 4
+    inside = {id(eqn) for call in kernels for eqn in _equations(call.params["jaxpr"])}
+    outside = [
+        eqn for eqn in _equations(jaxpr.jaxpr)
+        if id(eqn) not in inside and eqn.primitive.name in ("exp", "cumsum", "dot_general")
+    ]
+    assert len(outside) >= 4          # the triangle products and the decays to a chunk's end, both passes
+    for eqn in outside:
+        assert all(v.aval.dtype == f32 for v in eqn.invars), eqn
 
 
 def test_products_in_the_operands_dtype_accumulate_in_float32():
@@ -143,6 +184,33 @@ def test_products_in_the_operands_dtype_accumulate_in_float32():
     close(got.astype(jnp.float32), ssd_reference(*args).astype(jnp.float32), 3e-2)
     with pytest.raises(ValueError, match="multiple of the chunk"):
         ssd(*operands(1, 40, 4, 2, 8, 16, (1e-3, 1.6)), chunk=16)
+
+
+def test_under_a_mesh_the_kernels_run_per_data_shard(cpu_mesh_devices):
+    """GSPMD cannot partition a Mosaic call: traced under the step's mesh the
+    mixer's scan (``transformer._ssd_over_mesh``) runs in a shard_map, each
+    data shard its own sequences with ``A`` and ``D`` whole, and value and all
+    six gradients (``A``'s and ``D``'s summed over the shards) are one device's."""
+    args = operands(2, 32, 4, 2, 4, 6, (1e-3, 1.6))
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    config = types.SimpleNamespace(attention="flash", ssm=types.SimpleNamespace(chunk=16))
+    mesh, rules = MeshSpec({"dp": 2}).build(cpu_mesh_devices), LogicalRules()
+
+    def under_mesh(*a):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jnp.sum(T._ssd_over_mesh(config)(*a) * weights)
+
+    text = jax.jit(under_mesh).lower(*args).as_text()
+    assert "shard_map" in text or "manual" in text
+    placed = [
+        jax.device_put(a, rules.sharding(["batch"] + [None] * (a.ndim - 1) if a.ndim > 1 else [None], mesh))
+        for a in args
+    ]
+    got = jax.jit(jax.value_and_grad(under_mesh, argnums=range(6)))(*placed)
+    want = jax.value_and_grad(lambda *a: jnp.sum(ssd(*a, chunk=16) * weights), argnums=range(6))(*args)
+    for name, mine, theirs in zip(("value",) + ARGUMENTS, jax.tree.leaves(got), jax.tree.leaves(want)):
+        close(mine, theirs, 1e-5, name)
+    assert T._ssd_over_mesh(types.SimpleNamespace(attention="reference")) is ssd_reference
 
 
 @pytest.mark.parametrize("activation", ["silu", None])

@@ -208,8 +208,9 @@ def test_the_new_scopes_name_forward_and_backward(fam, params):
     names = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
     under = lambda *path: [n for n in names if "/".join(path) in n]
     assert under("attention", "ssm_mixer", "ssd") and under("attention", "ssm_mixer", "short_conv")
-    # the backward's reversed scan and its transposed products, under the same path
-    assert under("attention", "ssm_mixer", "ssd", "while") and under("ssm_mixer", "ssd", "transpose")
+    # the forward kernel's call and the backward's two (the chunk-start states, the gradients), under the same path
+    assert under("attention", "ssm_mixer", "ssd", "jit(_ssd_forward)")
+    assert under("ssm_mixer", "ssd", "jit(_ssd_states)") and under("ssm_mixer", "ssd", "jit(_ssd_backward)")
     assert under("mlp", "moe_latent", "dot_general") and under("mlp", "moe_latent", "transpose")
     assert under("mlp", "shared") and under("mlp", "router") and under("mlp", "experts")
     assert not [n for n in under("ssd/") if "ssm_mixer/ssd/" not in n]
