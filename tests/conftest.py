@@ -9,6 +9,9 @@ Mirrors the reference's python/ray/tests/conftest.py patterns:
                         (SURVEY §4.4), including resource lying for TPUs.
   * time_limit        — every test's whole protocol runs under TEST_LIMIT_S:
                         a wait that never ends fails that one test by name.
+  * topo / one_chip   — a TPU v5e described, not attached, for the
+                        ``test_chip_compile_*`` files (functions they share:
+                        ``model_helpers.py``).
 """
 
 import contextlib
@@ -122,3 +125,39 @@ def cpu_mesh_devices():
     devices = jax.devices()
     assert len(devices) >= 8, f"expected 8 virtual cpu devices, got {devices}"
     return devices
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; the next one would warn. Off
+    for the module that asks for ``topo``, and put back after it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    """Four v5e chips described from the installed TPU compiler; skips where
+    they cannot be. Each xdist worker's process loads the TPU's library for
+    itself (the tier-1 command sets ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])

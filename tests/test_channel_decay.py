@@ -60,28 +60,44 @@ def rel(got, want):
     return float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
 
 
+def _output_and_gradients(rule, args):
+    """``rule``'s output and its five operands' gradients under one weighing,
+    in ONE program: the forward is compiled once, not alone and again under the gradient."""
+    weigh = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def weighed(*a):
+        out = rule(*a)
+        return jnp.sum(out * weigh), out
+
+    grads, out = jax.jit(jax.grad(weighed, argnums=(0, 1, 2, 3, 4), has_aux=True))(*args)
+    return out, grads
+
+
+@functools.cache
+def _the_recurrence(decays):
+    """The draw of ``decays`` and what the recurrence gives on it, once for the three paths."""
+    args = operands(0, decays=decays)
+    return args, _output_and_gradients(gated_delta_rule_reference, args)
+
+
 @pytest.mark.parametrize("decays", ["mixed", "bound", "steep"])
 @pytest.mark.parametrize("path", ["kernels", "kernels-bounded", "plain-scan"])
 def test_chunks_match_the_recurrence_in_output_and_every_gradient(decays, path):
-    args = operands(0, decays=decays)
+    args, (want, want_grads) = _the_recurrence(decays)
     kernels = path != "plain-scan"
     # the bounded form is for a caller that states its bound; told a bound the
     # draw does not keep (``steep``: -200), it is the caller that is wrong,
     # and the output shows it: the halving form is what such gates need
     bound = BOUND if path == "kernels-bounded" else None
     rule = lambda *a: gated_delta_rule(*a, kernels=kernels, log_alpha_bound=bound)
-    want = gated_delta_rule_reference(*args)
-    got = rule(*args)
-    assert got.shape == want.shape == (1, 2, 200, 48)           # 200 is no multiple of 64
     if (decays, path) == ("steep", "kernels-bounded"):
+        got = rule(*args)
         assert float(jnp.min(args[3])) == -200.0 and float(jnp.max(args[4])) > 1.5
         assert not np.all(np.isfinite(np.asarray(got)))        # e^{15 x 200}: what PR 48 lifted
         return
+    got, got_grads = _output_and_gradients(rule, args)
+    assert got.shape == want.shape == (1, 2, 200, 48)           # 200 is no multiple of 64
     assert rel(got, want) < 5e-6
-    weigh = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    loss = lambda rule: lambda *a: jnp.sum(rule(*a) * weigh)
-    want_grads = jax.grad(loss(gated_delta_rule_reference), argnums=(0, 1, 2, 3, 4))(*args)
-    got_grads = jax.grad(loss(rule), argnums=(0, 1, 2, 3, 4))(*args)
     for name, mine, theirs in zip(("q", "k", "v", "log_alpha", "beta"), got_grads, want_grads):
         assert mine.shape == theirs.shape, name
         assert rel(mine, theirs) < 2e-5, (name, rel(mine, theirs))
@@ -145,6 +161,14 @@ def _exact(x, k, total):
     )
 
 
+@functools.cache
+def _gradients_at_the_bound():
+    """Of the rule as it is called (the halving preparation), whichever preparation a case holds: once."""
+    return jax.grad(
+        lambda *a: jnp.sum(gated_delta_rule(*a)), argnums=(0, 1, 2, 3, 4)
+    )(*operands(1, decays="bound"))
+
+
 @pytest.mark.parametrize("prepare", ["_prepare_channel_xla", "_prepare_channel", "bounded"])
 def test_at_the_bound_the_unsplit_product_fails_and_the_subchunked_one_does_not(prepare):
     _, k, _, g, _ = operands(1, decays="bound")
@@ -175,10 +199,7 @@ def test_at_the_bound_the_unsplit_product_fails_and_the_subchunked_one_does_not(
     assert np.max(np.abs(np.asarray(want)[:, lower])) > 0.1      # not a comparison of zeros
     assert gamma.shape == (2, 3, 1, 32) and float(gamma[:, 1].max()) == 0.0   # e^-320
     # gradients through the chunk at the bound are finite too
-    grads = jax.grad(
-        lambda *a: jnp.sum(gated_delta_rule(*a)), argnums=(0, 1, 2, 3, 4)
-    )(*operands(1, decays="bound"))
-    assert all(bool(jnp.all(jnp.isfinite(x))) for x in grads)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in _gradients_at_the_bound())
 
 
 def test_a_decay_equal_in_every_channel_is_the_scalar_rule():

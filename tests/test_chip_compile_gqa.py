@@ -8,74 +8,15 @@ What it holds: K and V reach the three kernels at ``n_kv_heads`` and ``dK`` /
 head_dim`` exists for them, physical or not; a flash layer is three Mosaic
 calls under the jitted names ``benchmarks/harness/xplane.py`` finds them by.
 
-A file of its own beside ``tests/test_chip_compile.py`` (that file is the
-run's longest under ``--dist loadfile``); the topology is described inside
-a fixture that skips when it cannot be, never at import.
+One of the ``test_chip_compile_*`` files, a kernel family each (see
+``tests/test_chip_compile_flash.py``); ``topo`` is ``conftest.py``'s.
 """
 
 import collections
 import math
 import re
-from unittest import mock
 
-import jax
-import jax.numpy as jnp
-import pytest
-
-
-@pytest.fixture(scope="module")
-def topo():
-    import os
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module", autouse=True)
-def no_persistent_cache():
-    """A compile for a described chip is written to the persistent cache but
-    cannot be read back without the chip; the next one would warn."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
-
-
-def _loss_and_grads_text(topo, config, axes, batch, seq) -> str:
-    """The optimized program of ``loss_fn`` and its gradients for the
-    described chips under ``axes``, traced under the mesh as
-    ``build_sharded_train_step`` traces it, with the Mosaic kernels (the
-    platform rule would pick the interpreter: the backend here is the CPU)."""
-    import ray_tpu.ops.flash_attention as flash_mod
-    from ray_tpu.models import transformer as T
-    from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
-
-    spec = MeshSpec(axes)
-    mesh = spec.build(topo.devices[:spec.size])
-    rules = LogicalRules()
-    params = jax.tree.map(
-        lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
-        jax.eval_shape(lambda: T.init_params(config, jax.random.PRNGKey(0))),
-        rules.tree_shardings(T.param_logical_dims(config), mesh),
-    )
-    tokens = jax.ShapeDtypeStruct(
-        (batch, seq), jnp.int32, sharding=rules.sharding(["batch", None], mesh))
-
-    def loss(params, tokens):
-        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-            return T.loss_fn(params, tokens, tokens, config)
-
-    with mock.patch.object(flash_mod, "resolve_interpret", lambda _i: False):
-        return jax.jit(jax.value_and_grad(loss)).lower(params, tokens).compile().as_text()
+from model_helpers import loss_and_grads_text
 
 
 _CALL = re.compile(
@@ -150,7 +91,7 @@ def test_window_and_full_layers_at_28_over_4_heads(topo):
         hidden_dim=256, max_seq=16384, attention="flash", remat="full",
         layer_pattern=("window", "full"), window=4096, rope_kinds=("window",),
     )
-    text = _loss_and_grads_text(topo, config, {"dp": 1}, 1, 16384)
+    text = loss_and_grads_text(topo, config, {"dp": 1}, 1, 16384)
     _flash_layers(text, 2, 1, 28, 4, 16384, 128)
 
 
@@ -163,5 +104,5 @@ def test_scanned_layers_at_32_over_8_heads(topo):
         vocab_size=512, dim=4096, n_layers=2, n_heads=32, n_kv_heads=8,
         hidden_dim=256, max_seq=16384, attention="flash", remat="full",
     )
-    text = _loss_and_grads_text(topo, config, {"dp": 1}, 1, 16384)
+    text = loss_and_grads_text(topo, config, {"dp": 1}, 1, 16384)
     _flash_layers(text, 1, 1, 32, 8, 16384, 128)

@@ -23,9 +23,8 @@ turns its operands heads first as it did (``gated_delta_rule_by_token``):
 there the transposes stay and what is held is that a GROUP of heads costs
 no pass (no slice into the operands, no stacking of the results).
 
-A file of its own beside ``tests/test_chip_compile.py`` (that file is the
-run's longest under ``--dist loadfile``); the topology is described inside a
-fixture that skips when it cannot be, never at import.
+One of the ``test_chip_compile_*`` files, a kernel family each (see
+``tests/test_chip_compile_flash.py``); ``one_chip`` is ``conftest.py``'s.
 """
 
 import collections
@@ -36,35 +35,6 @@ from unittest import mock
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    import os
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module", autouse=True)
-def no_persistent_cache():
-    """A compile for a described chip is written to the persistent cache but
-    cannot be read back without the chip; the next one would warn."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    before = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", before)
-    compilation_cache.reset_cache()
 
 
 def _mixer(one_chip, config, seq):

@@ -6,8 +6,12 @@ HELD, behind a dense prefix with a conv mixer, under ``tie_embeddings``:
 LFM2-8B-A1B's shape) against an oracle WRITTEN HERE: the same mathematics in
 plain ``jax.numpy`` on the program's own parameter tree, float32, no kernel,
 no sort, no scan, the experts a loop. On the CPU at tiny widths with seeded
-weights: a dense conv layer, then ONE period (full, conv, conv, conv), 8
-experts of which 4 are held, 2 a token, head size 16, a tied head.
+weights: a dense conv layer, then TWO periods of (full, conv), 8 experts of
+which 4 are held, 2 a token, head size 16, a tied head. (One layer a kind a
+period: the scan's body is one period, so the programs these cases compile
+grow with it, and a second or third conv layer in a row claims nothing the
+first does not. ``test_the_tree_is_stacked_by_period_and_counted`` builds the
+published period of four, which compiles no step.)
 
 Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
 gradients 2e-3 (``tests/test_hybrid_moe.py``'s and for its reasons: both
@@ -19,19 +23,21 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
-from ray_tpu import train
 from ray_tpu.models import transformer as T
-from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils
+
+from model_helpers import (
+    close, forward, forward_with_routing, ids, layers_in_order, loss_and_grads,
+    trains_through_jax_trainer,
+)
 
 EPS, THETA, HELD = 1e-5, 1e6, (0, 4)
 MODEL = T.TransformerConfig(
     vocab_size=256, dim=64, n_layers=5, n_heads=4, n_kv_heads=2, hidden_dim=96, max_seq=40,
     rope_theta=THETA, rms_norm_eps=EPS, qk_head_norm=True, tie_embeddings=True,
     dtype=jnp.float32, first_dense_layers=1, first_dense_kind="conv",
-    layer_pattern=("full", "conv", "conv", "conv"), conv_kernel=3,
+    layer_pattern=("full", "conv"), conv_kernel=3,
     moe=T.MoEConfig(
         num_experts=8, top_k=2, norm_topk_prob=True, renorm_eps=1e-6, expert_dim=32,
         scoring="sigmoid", routed_scaling=1.0, held=HELD,
@@ -53,18 +59,6 @@ def seeded(model=MODEL, seed=3):
             tree["router_bias"] = 0.1 * jax.random.normal(next(keys), tree["router_bias"].shape)
     params["final_norm"] = params["final_norm"] + 0.2 * jax.random.normal(next(keys), (64,))
     return params
-
-
-def ids(seed=1, batch=2, seq=40):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert got.shape == want.shape and np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
 
 
 # -- the oracle ---------------------------------------------------------------
@@ -148,12 +142,9 @@ def oracle_logits(params, tokens, model=MODEL):
             return x + _oracle_experts(h, layer, model.moe)
         return x + _oracle_swiglu(h, layer["w_gate"], layer["w_up"], layer["w_down"])
 
-    at = lambda tree, *index: jax.tree.map(lambda leaf: leaf[index], tree)
-    x = layer_forward(x, at(params["dense_layers"], 0))
-    taken = dict.fromkeys(model.layer_pattern, 0)
-    for kind in model.layer_pattern:
-        x = layer_forward(x, at(params["layers"][kind], 0, taken[kind]))
-        taken[kind] += 1
+    x = layer_forward(x, jax.tree.map(lambda leaf: leaf[0], params["dense_layers"]))
+    for _kind, layer in layers_in_order(params, model):
+        x = layer_forward(x, layer)
     return _norm(x, params["final_norm"]) @ params["embed"].T
 
 
@@ -168,8 +159,13 @@ def params():
 
 
 # -- the model ----------------------------------------------------------------
-def test_the_tree_is_stacked_by_period_and_counted(params):
-    assert (MODEL.periods, MODEL.first_dense_kind, MODEL.head_dim) == (1, "conv", 16)
+def test_the_tree_is_stacked_by_period_and_counted():
+    """At the PUBLISHED period, one grouped-query layer then three conv
+    layers: the one case that is about the period itself, and it compiles no
+    step."""
+    model = dataclasses.replace(MODEL, layer_pattern=("full", "conv", "conv", "conv"))
+    params = seeded(model)
+    assert (model.periods, model.first_dense_kind, model.head_dim) == (1, "conv", 16)
     assert "lm_head" not in params                                   # tied
     assert params["dense_layers"]["w_in"].shape == (1, 64, 192)
     assert params["dense_layers"]["conv"].shape == (1, 3, 64)
@@ -179,10 +175,10 @@ def test_the_tree_is_stacked_by_period_and_counted(params):
     assert params["layers"]["conv"]["router"].shape == (1, 3, 64, 8)         # all are scored
     assert params["layers"]["full"]["q_norm"].shape == (1, 1, 16)            # one weight a head dim
     assert "wq" not in params["layers"]["conv"] and "w_in" not in params["layers"]["full"]
-    assert T.config_num_params(MODEL) == T.num_params(params)
-    untied = dataclasses.replace(MODEL, tie_embeddings=False)
-    assert T.config_num_params(untied) - T.config_num_params(MODEL) == 256 * 64
-    dims = T.param_logical_dims(MODEL)
+    assert T.config_num_params(model) == T.num_params(params)
+    untied = dataclasses.replace(model, tie_embeddings=False)
+    assert T.config_num_params(untied) - T.config_num_params(model) == 256 * 64
+    dims = T.param_logical_dims(model)
     is_dims = lambda x: isinstance(x, tuple)
     assert jax.tree.structure(dims, is_leaf=is_dims) == jax.tree.structure(params)
     for leaf, names in zip(jax.tree.leaves(params), jax.tree.leaves(dims, is_leaf=is_dims)):
@@ -195,8 +191,9 @@ def test_logits_match_the_oracle_on_both_paths(params):
         want = oracle_logits(params, x)
     for attention in ("flash", "reference"):
         model = dataclasses.replace(MODEL, attention=attention)
-        got, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, model))(params, x)
+        got, routing = forward_with_routing(model)(params, x)
         close(got, want, 5e-4, attention)
+        assert model.periods == 2
         assert routing["experts"].shape == (4, TOKENS, 2)
         assert all(0 < int(n) < TOKENS * 2 for n in routing["held_pairs"])
 
@@ -207,7 +204,7 @@ def test_loss_and_every_gradient_leaf_match_the_oracle(params):
         want, want_grads = jax.value_and_grad(oracle_loss)(params, x, y)
     for attention, remat in (("flash", None), ("flash", "full"), ("reference", None)):
         model = dataclasses.replace(MODEL, attention=attention, remat=remat)
-        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        got, grads = loss_and_grads(model)(params, x, y)
         assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), (attention, remat)
         assert jax.tree.structure(grads) == jax.tree.structure(want_grads)
         mine, theirs = (jax.tree_util.tree_leaves_with_path(g) for g in (grads, want_grads))
@@ -224,15 +221,13 @@ def test_the_embeddings_gradient_is_the_sum_of_its_two_uses(params):
     transposed table): the table's gradient is the gather's plus the head's,
     and each of the two is far from the sum."""
     x, y = ids(), ids(seed=2)
-    tied = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, MODEL)))(params)["embed"]
+    tied = loss_and_grads(MODEL)(params, x, y)[1]["embed"]
     untied_model = dataclasses.replace(MODEL, tie_embeddings=False)
-    untied = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, untied_model)))(
-        dict(params, lm_head=params["embed"].T)
-    )
+    untied = loss_and_grads(untied_model)(dict(params, lm_head=params["embed"].T), x, y)[1]
     close(tied, untied["embed"] + untied["lm_head"].T, 1e-5, "gather + head")
     for part in (untied["embed"], untied["lm_head"].T):
         assert np.max(np.abs(np.asarray(tied - part))) > 0.1 * np.max(np.abs(np.asarray(tied)))
-    logits = jax.jit(lambda p, t: T.forward(p, t, MODEL))(params, x)
+    logits = forward(MODEL)(params, x)
     head = T.rmsnorm_reference(
         jax.jit(lambda p, t: T._hidden_with_routing(p, t, MODEL)[0])(params, x),
         params["final_norm"], eps=EPS,
@@ -375,7 +370,7 @@ def test_a_changed_term_moves_the_logits(params):
                 patch.setattr(T, "_short_conv_over_mesh", lambda config, activation: T._short_conv)
                 got = T.forward(params, x, replace(MODEL, attention="reference"))
         else:
-            got = jax.jit(lambda p, t: T.forward(p, t, model))(weights, x)
+            got = forward(model)(weights, x)
         off = np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want))
         assert off > 100 * 5e-4, (what, off)
 
@@ -415,37 +410,9 @@ def test_a_tied_head_decodes_through_the_kv_cache():
         close(logits, want[:, t], 1e-4, t)
 
 
-def _conv_moe_loop(config):
-    model = dataclasses.replace(MODEL, remat="full")
-    optimizer = optax.adamw(3e-3)
-    setup = jax_utils.setup_sharded_training(
-        lambda: T.init_params(model, jax.random.PRNGKey(0)), optimizer,
-        logical_dims=T.param_logical_dims(model),
-    )
-    step = jax_utils.build_sharded_train_step(
-        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], model), optimizer, setup
-    )
-    x = np.asarray(ids(seed=8, batch=4, seq=41))
-    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
-    params, opt_state = setup.params, setup.opt_state
-    for _ in range(config["steps"]):
-        params, opt_state, loss = step(params, opt_state, batch)
-        train.report({"loss": float(loss), "factorization": setup.factorization})
-
-
 def test_the_tiny_preset_trains_through_jax_trainer(ray_start_shared, tmp_path):
     """The normal path: JaxTrainer -> setup_sharded_training ->
     build_sharded_train_step -> loss_fn, over a dp 2 x fsdp 2 mesh (the
     convolution kernels, flash and the held experts' block per data shard
     under shard_map; the tied table sharded once), full remat."""
-    trainer = JaxTrainer(
-        _conv_moe_loop,
-        train_loop_config={"steps": 3},
-        scaling_config=ScalingConfig(num_workers=1, mesh_axes={"dp": 2, "fsdp": 2}),
-        run_config=RunConfig(name="conv-moe", storage_path=str(tmp_path)),
-    )
-    result = trainer.fit()
-    assert result.error is None, result.error
-    assert result.metrics["factorization"] == {"dp": 2, "fsdp": 2, "tp": 1, "pp": 1}
-    losses = [m["loss"] for m in result.metrics_history]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    trains_through_jax_trainer(dataclasses.replace(MODEL, remat="full"), "conv-moe", tmp_path, seq=41)

@@ -20,6 +20,8 @@ import pytest
 
 from ray_tpu.ops import gated_delta_rule as G
 
+from model_helpers import close
+
 TOL = 2e-4
 DECAYS = {
     # alpha per token: a mix of nearly 0 (the state is wiped), nearly 1
@@ -41,15 +43,10 @@ def inputs(seq, d_k, d_v, decay, batch=1, heads=2, seed=0):
     return q, k, v, log_alpha, beta
 
 
-def close(got, want, what, tol=TOL, floor=1e-6):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert got.shape == want.shape, what
-    assert np.all(np.isfinite(got)), what
+def near(got, want, what, tol=TOL, floor=1e-6):
     # the floor: a gradient that is itself 1e-3 (log_alpha's where the decay
     # wipes the state) is a float32 sum of terms of order 1
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)) + floor, (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
+    close(got, want, tol, what, floor=floor)
 
 
 CASES = {
@@ -88,9 +85,9 @@ def test_output_and_five_gradients_match_the_recurrence(case, kernels):
     assert float(jnp.min(args[4])) > 0.0 and float(jnp.max(args[4])) < 2.0
     chunked = lambda *a: G.gated_delta_rule(*a, chunk=CASES[case][1], kernels=kernels)
     got, got_grads = output_and_gradients(chunked, args, weights)
-    close(got, want, "output")
+    near(got, want, "output")
     for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got_grads, want_grads):
-        close(g, w, f"d{name}")
+        near(g, w, f"d{name}")
 
 
 def test_the_recurrence_is_the_equations():
@@ -166,12 +163,12 @@ def test_token_major_operands_give_the_heads_first_rule_and_the_recurrence(
     ]
     (got, got_grads), (same, same_grads), (want, want_grads) = results
     assert got.shape == (2, heads, 80, d_v)
-    close(got, same, "output, heads first", tol=1e-6)
-    close(got, want, "output")
+    near(got, same, "output, heads first", tol=1e-6)
+    near(got, want, "output")
     names = ("q", "k", "v", "log_alpha", "beta")
     for name, g, s, w in zip(names, got_grads, same_grads, want_grads):
-        close(g, s, f"d{name}, heads first", tol=1e-6)
-        close(g, w, f"d{name}")
+        near(g, s, f"d{name}, heads first", tol=1e-6)
+        near(g, w, f"d{name}")
 
 
 def test_what_is_kept_for_the_backward_is_the_output():
@@ -288,7 +285,7 @@ def test_the_preparation_kernel_writes_the_oracles_six_operands(case):
     )
     assert len(got) == 6 and all(x.dtype == jnp.float32 for x in got)
     for name, g, w in zip(OPERANDS, got, want):
-        close(g, w, name, **NEAR)
+        near(g, w, name, **NEAR)
     # what a gradient's forward runs: the same six and T as it is kept ...
     gates = G._gates(log_alpha, beta, chunk)
     *same, kept = G._delta_prepare_forward(
@@ -305,7 +302,7 @@ def test_the_preparation_kernel_writes_the_oracles_six_operands(case):
     # T is (I + A)^-1 of each chunk: U0 = T (beta V)
     width = kept.shape[-1]
     weighed = np.asarray(beta, np.float64)[..., None] * np.asarray(v, np.float64)
-    close(unpacked(kept, chunk) @ weighed.reshape(3, -1, width, v.shape[-1]),
+    near(unpacked(kept, chunk) @ weighed.reshape(3, -1, width, v.shape[-1]),
           np.asarray(want[1]).reshape(3, -1, width, v.shape[-1]), "T beta V", **NEAR)
 
 
@@ -331,7 +328,7 @@ def test_the_preparations_transpose_by_hand_is_jaxs(case):
     assert [g.dtype for g in got] == [w.dtype for w in want]
     for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
         # dv is rounded to v's bfloat16 on both sides: a last bit apart
-        close(g, w, f"d{name}", **(dict(NEAR, tol=1e-2) if name == "v" else NEAR))
+        near(g, w, f"d{name}", **(dict(NEAR, tol=1e-2) if name == "v" else NEAR))
 
 
 # ---------------------------------------------------------------------------
@@ -403,7 +400,7 @@ def test_the_channel_preparation_kernel_writes_the_oracles_six_operands_and_t(ca
     assert len(got) == 6 and all(x.dtype == jnp.float32 for x in (*got, inverse))
     assert inverse.shape == G.kept_inverse_shape(3, q.shape[1], chunk)
     for name, g, w in zip(OPERANDS, got, want):
-        close(g, w, name, **CHANNEL_NEAR)
+        near(g, w, name, **CHANNEL_NEAR)
     assert got[5].shape == (3, q.shape[1] // chunk, 1, q.shape[2])          # gamma: a row a chunk
     # without ``inverse`` the same six, bit for bit
     for g, w in zip(G._prepare_channel(q, k, v, log_alpha, beta, chunk, True), got):
@@ -428,7 +425,7 @@ def test_the_channel_preparation_kernel_writes_the_oracles_six_operands_and_t(ca
     width = lanes.shape[-1]
     blocks = unpacked(inverse, chunk)
     weighed = (np.asarray(beta, np.float64)[..., None] * np.asarray(v, np.float64))
-    close(blocks @ weighed.reshape(3, -1, width, v.shape[-1]),
+    near(blocks @ weighed.reshape(3, -1, width, v.shape[-1]),
           np.asarray(want[1]).reshape(3, -1, width, v.shape[-1]), "T beta V", **CHANNEL_NEAR)
     # P is the exact sum term by term, every exponent a difference taken
     # BEFORE the exp: finite and right at the bound, where e^{-G} overflows
@@ -467,7 +464,7 @@ def test_the_channel_preparations_transpose_by_hand_is_jaxs(case):
     assert [g.dtype for g in got] == [w.dtype for w in want]
     for name, g, w in zip(("q", "k", "v", "log_alpha", "beta"), got, want):
         # dv is rounded to v's bfloat16 on both sides: a last bit apart
-        close(g, w, f"d{name}", **(dict(CHANNEL_NEAR, tol=1e-2) if name == "v" else CHANNEL_NEAR))
+        near(g, w, f"d{name}", **(dict(CHANNEL_NEAR, tol=1e-2) if name == "v" else CHANNEL_NEAR))
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,8 @@ import pytest
 
 from ray_tpu.models import transformer as T
 
+from model_helpers import close
+
 DIM, WIDTH, EXPERTS, TOP_K, HELD = 64, 48, 32, 2, (4, 3)
 BATCH, SEQ = 2, 40
 TOKENS = BATCH * SEQ
@@ -150,14 +152,6 @@ def value_and_grads(moe_mlp, layer, h=H):
         jax.value_and_grad(probed, argnums=(0, 1), has_aux=True)
     )(h, layer)
     return out, routing, grads
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert got.shape == want.shape, (what, got.shape, want.shape)
-    assert np.all(np.isfinite(got)), what
-    off = np.max(np.abs(got - want)) / max(np.max(np.abs(want)), 1e-30)
-    assert off <= tol, (what, off)
 
 
 def agrees(got, want, overflow=None):

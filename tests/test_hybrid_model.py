@@ -3,11 +3,15 @@
 block) against the plain reference (``benchmarks/reference/hybrid_decoder.py``:
 the per-token recurrence, explicit softmax attention, no layer scan), on
 the CPU in float32 at tiny widths with seeded weights: TWO periods of
-(linear, linear, linear, full).
+(linear, full). (One layer a kind a period: the scan's body is one period, so
+the programs these cases compile grow with it, and a second or third linear
+layer in a row claims nothing the first does not.
+``test_the_tree_is_stacked_by_period_and_counted`` builds the published period
+of four, which compiles no step.)
 
 Tolerances, each of the largest value compared. Logits 5e-4 and loss 1e-5:
 both sides compute in float32; what is left is the order of the sums (a
-chunk at once against a token at a time) through eight post-norm layers,
+chunk at once against a token at a time) through post-norm layers,
 which at these widths grows a 1e-6 difference about a hundredfold (a head of
 16 key dims whose SiLU outputs are all near zero is normalised from
 rounding). Gradients 2e-3: the same, through the backward. A wrong term (the
@@ -32,15 +36,19 @@ if ROOT not in sys.path:
 from benchmarks.families import hybrid_decoder  # noqa: E402
 from benchmarks.harness import hybrid_flops  # noqa: E402
 from benchmarks.reference import hybrid_decoder as reference  # noqa: E402
-from ray_tpu import train  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
 from ray_tpu.parallel.mesh import MeshSpec  # noqa: E402
-from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils  # noqa: E402
+from ray_tpu.train import jax_utils  # noqa: E402
 
-PERIOD = ["linear_attention", "linear_attention", "linear_attention", "full_attention"]
+from model_helpers import (  # noqa: E402
+    close, forward, ids, listed, loss_and_grads, trains_through_jax_trainer,
+)
+
+PUBLISHED_PERIOD = ["linear_attention", "linear_attention", "linear_attention", "full_attention"]
+PERIOD = ["linear_attention", "full_attention"]
 CFG = {
     "name": "tiny-hybrid", "family": "hybrid_decoder", "model_type": "olmo_hybrid",
-    "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 8,
+    "hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 4,
     "num_attention_heads": 4, "num_key_value_heads": 4, "layer_types": PERIOD * 2,
     "linear_num_key_heads": 4, "linear_num_value_heads": 4, "linear_key_head_dim": 16,
     "linear_value_head_dim": 24, "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
@@ -68,22 +76,6 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=40):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
-
-
 @pytest.fixture(scope="module")
 def fam():
     return build()
@@ -94,8 +86,11 @@ def params(fam):
     return seeded(fam)
 
 
-def test_the_tree_is_stacked_by_period_and_counted(fam, params):
-    model = fam.model
+def test_the_tree_is_stacked_by_period_and_counted():
+    """At the PUBLISHED period, three linear layers then a full one: the one
+    case that is about the period itself, and it compiles no step."""
+    fam = build(num_hidden_layers=8, layer_types=PUBLISHED_PERIOD * 2)
+    model, params = fam.model, seeded(fam)
     assert model.layer_pattern == ("linear", "linear", "linear", "full") and model.periods == 2
     assert params["layers"]["linear"]["wq"].shape == (2, 3, 64, 64)
     assert params["layers"]["linear"]["conv_v"].shape == (2, 3, 4, 96)
@@ -114,14 +109,16 @@ def test_the_tree_is_stacked_by_period_and_counted(fam, params):
 def test_logits_match_the_reference(fam, params):
     x = ids()
     want = reference.logits(fam.reference_weights(params), x, fam.config)
-    close(jax.jit(fam.forward)(params, x), want, 5e-4, "kernels")
+    got = forward(fam.model)(params, x)              # what ``fam.forward`` is
+    assert fam.model.layer_pattern == ("linear", "full") and fam.model.periods == 2
+    close(got, want, 5e-4, "kernels")
     recurrence = T.forward(params, x, T.dataclasses.replace(fam.model, attention="reference"))
     close(recurrence, want, 5e-4, "the per-token recurrence")
-    check = fam.check(jax.jit(fam.forward)(params, x)[:, -8:], params, x, last=8)
+    check = fam.check(got[:, -8:], params, x, last=8)
     assert check["ok"] and check["published"]["rel_rms"] < 1e-4
-    # six linear layers keep [2, 4 heads, 48 (one padded chunk), 24] float32
+    # two linear layers keep [2, 4 heads, 48 (one padded chunk), 24] float32
     # and T's diagonal blocks, a chunk of 48 float32 a head and token
-    assert check["linear_state_gib"] == 6 * 2 * 4 * 48 * (24 + 48) * 4 / 2**30
+    assert check["linear_state_gib"] == 2 * 2 * 4 * 48 * (24 + 48) * 4 / 2**30
 
 
 STEP = 0.5
@@ -181,8 +178,7 @@ def test_no_logit_before_a_changed_token_moves(fam, params):
     x = ids(seed=7)
     at = 17
     changed = x.at[:, at].set((x[:, at] + 1) % 256)
-    forward = jax.jit(fam.forward)
-    before, after = forward(params, x), forward(params, changed)
+    before, after = forward(fam.model)(params, x), forward(fam.model)(params, changed)
     np.testing.assert_array_equal(np.asarray(before[:, :at]), np.asarray(after[:, :at]))
     assert float(jnp.max(jnp.abs(before[:, at:] - after[:, at:]))) > 1e-3
 
@@ -206,39 +202,11 @@ def test_the_compiled_step_names_the_linear_mixers_work(params):
         assert any("transpose(" in n for n in found) and any("rematted_computation" in n for n in found)
 
 
-def _hybrid_loop(config):
-    cfg = hybrid_decoder.build(CFG, dict(TRAFFIC, remat="full")).model
-    optimizer = optax.adamw(3e-3)
-    setup = jax_utils.setup_sharded_training(
-        lambda: T.init_params(cfg, jax.random.PRNGKey(0)), optimizer,
-        logical_dims=T.param_logical_dims(cfg),
-    )
-    step = jax_utils.build_sharded_train_step(
-        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], cfg), optimizer, setup
-    )
-    x = np.asarray(ids(seed=8, batch=4))
-    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
-    params, opt_state = setup.params, setup.opt_state
-    for _ in range(config["steps"]):
-        params, opt_state, loss = step(params, opt_state, batch)
-        train.report({"loss": float(loss), "factorization": setup.factorization})
-
-
 def test_the_tiny_preset_trains_through_jax_trainer(ray_start_shared, tmp_path):
     """The normal path: JaxTrainer -> setup_sharded_training ->
     build_sharded_train_step -> loss_fn, over a dp 2 x fsdp 2 mesh (the scan
     kernels per data shard under shard_map)."""
-    trainer = JaxTrainer(
-        _hybrid_loop,
-        train_loop_config={"steps": 3},
-        scaling_config=ScalingConfig(num_workers=1, mesh_axes={"dp": 2, "fsdp": 2}),
-        run_config=RunConfig(name="hybrid", storage_path=str(tmp_path)),
-    )
-    result = trainer.fit()
-    assert result.error is None, result.error
-    assert result.metrics["factorization"] == {"dp": 2, "fsdp": 2, "tp": 1, "pp": 1}
-    losses = [m["loss"] for m in result.metrics_history]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    trains_through_jax_trainer(build(remat="full").model, "hybrid", tmp_path)
 
 
 def test_what_a_patterned_model_cannot_do_yet_is_refused_by_name(fam, params):
@@ -264,7 +232,7 @@ def test_what_a_patterned_model_cannot_do_yet_is_refused_by_name(fam, params):
             jax.random.PRNGKey(0), ids(),
         )
     with pytest.raises(ValueError, match="no multiple of the period"):
-        T.dataclasses.replace(model, n_layers=6)
+        T.dataclasses.replace(model, n_layers=5)
     with pytest.raises(ValueError, match="tie_word_embeddings"):
         build(tie_word_embeddings=True)
 
@@ -287,7 +255,7 @@ def test_a_changed_term_fails_the_check(what, fam, params):
         "reversed_taps": fam.model,
     }[what]
     weights = _reversed_taps(params) if what == "reversed_taps" else params
-    changed = jax.jit(lambda p, t: T.forward(p, t, model))(weights, x)
+    changed = forward(model)(weights, x)
     check = fam.check(changed, params, x)
     assert not check["ok"] and check["published"]["rel_rms"] > 1e-1
 
@@ -324,8 +292,8 @@ def test_every_allowed_setting_of_the_mixer_matches_the_recurrence(decay, bound,
     tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 81), 0, 256)
 
     def loss_and_logits(config):
-        logits = T.forward(params, tokens[:, :-1], config)
-        loss, grads = jax.value_and_grad(T.loss_fn)(params, tokens[:, :-1], tokens[:, 1:], config)
+        logits = forward(config)(params, tokens[:, :-1])
+        loss, grads = loss_and_grads(config)(params, tokens[:, :-1], tokens[:, 1:])
         return logits, loss, grads
 
     got, want = (loss_and_logits(configs[a]) for a in ("flash", "reference"))

@@ -6,8 +6,12 @@ language model) against the plain reference (``benchmarks/reference/
 hybrid_moe_decoder.py``: the per-token recurrence, explicit softmax, the
 group routine written out, the experts a loop over the same held block), on
 the CPU in float32 at tiny widths with seeded weights: a dense linear layer,
-then TWO periods of (full, linear, linear), 32 experts in 4 groups of which
-2, 4 a token, 8 held.
+then TWO periods of (full, linear), 32 experts in 4 groups of which 2, 4 a
+token, 8 held. (One layer a kind a period: the scan's body is one period, so
+the programs these cases compile grow with it, and the second linear layer in
+a row claims nothing the first does not.
+``test_the_tree_is_stacked_by_period_and_counted`` builds the published
+period of three, ``layer_group_size`` 3, which compiles no step.)
 
 Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
 gradients 2e-3, ``tests/test_hybrid_model.py``'s and for its reasons (both
@@ -15,13 +19,13 @@ sides float32; a chunk at once against a token at a time). A wrong term is
 off by far more: the last test holds the comparison to that, term by term.
 """
 
+import functools
 import os
 import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -31,15 +35,17 @@ if ROOT not in sys.path:
 from benchmarks.families import hybrid_moe_decoder  # noqa: E402
 from benchmarks.harness import hybrid_moe_flops  # noqa: E402
 from benchmarks.reference import hybrid_moe_decoder as reference  # noqa: E402
-from ray_tpu import train  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
 from ray_tpu.ops.rmsnorm import rmsnorm_reference  # noqa: E402
-from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils  # noqa: E402
+
+from model_helpers import (  # noqa: E402
+    close, forward_with_routing, ids, listed, loss_and_grads, trains_through_jax_trainer,
+)
 
 CFG = {
     "name": "tiny-hybrid-moe", "family": "hybrid_moe_decoder", "model_type": "bailing_hybrid",
-    "hidden_size": 64, "intermediate_size": 96, "num_hidden_layers": 7, "layer_offset": 1,
-    "layer_group_size": 3, "first_k_dense_replace": 1, "num_attention_heads": 4,
+    "hidden_size": 64, "intermediate_size": 96, "num_hidden_layers": 5, "layer_offset": 0,
+    "layer_group_size": 2, "first_k_dense_replace": 1, "num_attention_heads": 4,
     "num_key_value_heads": 4, "head_dim": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 16,
     "qk_rope_head_dim": 8, "v_head_dim": 16, "rotary_dim": 8, "partial_rotary_factor": 0.5,
     "q_lora_rank": None, "rope_theta": 10000, "rms_norm_eps": 1e-6, "vocab_size": 256,
@@ -81,22 +87,6 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=40):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
-
-
 @pytest.fixture(scope="module")
 def fam():
     return build()
@@ -107,8 +97,12 @@ def params(fam):
     return seeded(fam)
 
 
-def test_the_tree_is_stacked_by_period_and_counted(fam, params):
-    model = fam.model
+def test_the_tree_is_stacked_by_period_and_counted():
+    """At the PUBLISHED period, a latent layer then two linear ones behind the
+    dense prefix: the one case that is about the period itself, and it
+    compiles no step."""
+    fam = build(num_hidden_layers=7, layer_offset=1, layer_group_size=3)
+    model, params = fam.model, seeded(fam)
     assert reference.layer_kinds(fam.config) == [
         "linear_attention", "full_attention", "linear_attention", "linear_attention",
         "full_attention", "linear_attention", "linear_attention",
@@ -140,9 +134,10 @@ def test_the_tree_is_stacked_by_period_and_counted(fam, params):
 def test_logits_and_routing_match_the_reference(fam, params):
     x = ids()
     want, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    got, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, fam.model))(params, x)
+    got, routing = forward_with_routing(fam.model)(params, x)
     close(got, want, 5e-4, "kernels")
-    assert routing["experts"].shape == (6, TOKENS, TOP_K)
+    assert fam.model.layer_pattern == ("full", "linear") and fam.model.periods == 2
+    assert routing["experts"].shape == (4, TOKENS, TOP_K)
     for i, r in enumerate(routings):
         assert np.array_equal(np.sort(routing["experts"][i], -1), np.sort(r["experts"], -1)), i
         held = np.sum((np.asarray(r["experts"]) >= 8) & (np.asarray(r["experts"]) < 16))
@@ -164,7 +159,7 @@ def test_a_best_expert_outside_the_kept_groups_is_not_chosen(fam, params):
     that is not among its two best groups, and neither side chooses it."""
     x = ids()
     _, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    _, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, fam.model))(params, x)
+    _, routing = forward_with_routing(fam.model)(params, x)
     outside = 0
     for i, r in enumerate(routings):
         best = np.argmax(np.asarray(r["biased"]), axis=-1)                     # [T]
@@ -184,12 +179,12 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fam, params):
     )
     for remat in (None, "full"):
         model = T.dataclasses.replace(fam.model, remat=remat)
-        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        got, grads = loss_and_grads(model)(params, x, y)
         assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), remat
         named = listed(fam.reference_weights(grads))
         for name in ("embed_tokens", "norm", "lm_head"):
             close(named[name], want_grads[name], 2e-3, name)
-        assert len(named["layers"]) == len(want_grads["layers"]) == 7
+        assert len(named["layers"]) == len(want_grads["layers"]) == 5
         for i, (mine, theirs) in enumerate(zip(named["layers"], want_grads["layers"])):
             assert set(mine) == set(theirs), i
             for name in mine:
@@ -264,7 +259,7 @@ def test_routed_wholly_here_or_wholly_away_is_exact_under_one_trace(fam, params,
     changed = biased(params)
     (got, routing), grads = step(changed, x, y)
     assert step._cache_size() == 1
-    assert [int(n) for n in routing["held_pairs"]] == [pairs] * 6
+    assert [int(n) for n in routing["held_pairs"]] == [pairs] * 4
     want, want_grads = jax.value_and_grad(reference.loss)(
         listed(fam.reference_weights(changed)), x, y, fam.config
     )
@@ -295,40 +290,12 @@ def _value_and_grad(fam):
     return _STEPS[id(fam)]
 
 
-def _hybrid_moe_loop(config):
-    cfg = hybrid_moe_decoder.build(CFG, dict(TRAFFIC, remat="full")).model
-    optimizer = optax.adamw(3e-3)
-    setup = jax_utils.setup_sharded_training(
-        lambda: T.init_params(cfg, jax.random.PRNGKey(0)), optimizer,
-        logical_dims=T.param_logical_dims(cfg),
-    )
-    step = jax_utils.build_sharded_train_step(
-        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], cfg), optimizer, setup
-    )
-    x = np.asarray(ids(seed=8, batch=4))
-    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
-    params, opt_state = setup.params, setup.opt_state
-    for _ in range(config["steps"]):
-        params, opt_state, loss = step(params, opt_state, batch)
-        train.report({"loss": float(loss), "factorization": setup.factorization})
-
-
 def test_the_tiny_preset_trains_through_jax_trainer(ray_start_shared, tmp_path):
     """The normal path: JaxTrainer -> setup_sharded_training ->
     build_sharded_train_step -> loss_fn, over a dp 2 x fsdp 2 mesh (the
     convolutions, the scan kernels and the held experts' block per data
     shard under shard_map), full remat."""
-    trainer = JaxTrainer(
-        _hybrid_moe_loop,
-        train_loop_config={"steps": 3},
-        scaling_config=ScalingConfig(num_workers=1, mesh_axes={"dp": 2, "fsdp": 2}),
-        run_config=RunConfig(name="hybrid-moe", storage_path=str(tmp_path)),
-    )
-    result = trainer.fit()
-    assert result.error is None, result.error
-    assert result.metrics["factorization"] == {"dp": 2, "fsdp": 2, "tp": 1, "pp": 1}
-    losses = [m["loss"] for m in result.metrics_history]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    trains_through_jax_trainer(build(remat="full").model, "hybrid-moe", tmp_path)
 
 
 def test_what_this_model_cannot_do_yet_is_refused_by_name(fam, params):
@@ -408,6 +375,13 @@ def test_a_changed_term_fails_the_check(what, fam, params):
         assert off > 1.5, (what, off)
 
 
+@functools.cache
+def _at_192_tokens():
+    """The family at four chunks of 48 and its fresh weights, drawn once for the four cases below."""
+    fam = hybrid_moe_decoder.build(dict(CFG), dict(TRAFFIC, seq_len=192))
+    return fam, jax.jit(fam.init)(jax.random.PRNGKey(11))
+
+
 @pytest.mark.parametrize("name", [
     "program", "head_mean_decay", "log_decay_bfloat16", "chunk_operands_bfloat16",
 ])
@@ -423,9 +397,8 @@ def test_a_wrong_decay_or_a_lower_precision_fails_the_scan_check(name):
     both alike.)"""
     from benchmarks.harness import scan_controls
 
-    fam = hybrid_moe_decoder.build(dict(CFG), dict(TRAFFIC, seq_len=192))
-    params = jax.jit(fam.init)(jax.random.PRNGKey(11))
-    x = jax.random.randint(jax.random.PRNGKey(12), (2, 192), 0, 256)
+    fam, params = _at_192_tokens()
+    x = ids(seed=12, seq=192)
     scan = fam.scan if name == "program" else scan_controls.control(name)
     weights = scan_controls.open_gates(fam.reference_weights(params))
     found = reference.check_scan(scan, weights, x, fam.config, last=64)

@@ -5,8 +5,12 @@ experts of which a block is HELD beside one shared (``models/transformer.py``:
 Solar-Open2's language model) against the plain reference
 (``benchmarks/reference/kda_gqa_moe_decoder.py``: the per-token recurrence,
 explicit softmax, the experts a loop over the same held block), on the CPU in
-float32 at tiny widths with seeded weights: TWO periods of (full, linear,
-linear, linear), 4 / 2 heads of 16, 40 experts of which 8 held, 4 a token.
+float32 at tiny widths with seeded weights: TWO periods of (full, linear),
+4 / 2 heads of 16, 40 experts of which 8 held, 4 a token. (One layer a kind a
+period: the scan's body is one period, so the programs these cases compile
+grow with it, and a second or third linear layer in a row claims nothing the
+first does not. ``test_the_tree_is_the_tables_and_counted`` builds the
+published period of four, which compiles no step.)
 
 Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
 gradients 2e-3, ``tests/test_hybrid_moe.py``'s and for its reasons (both
@@ -32,16 +36,18 @@ from benchmarks.reference import kda_gqa_moe_decoder as reference  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
 from ray_tpu.ops.rmsnorm import rmsnorm_reference  # noqa: E402
 
+from model_helpers import close, forward_with_routing, ids, listed, loss_and_grads  # noqa: E402
+
 CFG = {
     "name": "tiny-kda-gqa-moe", "family": "kda_gqa_moe_decoder", "model_type": "solar_open2",
     "linear_attn_config": {
         "short_conv_kernel_size": 4, "head_dim": 16, "num_heads": 4, "num_kv_heads": None,
     },
-    "hidden_size": 64, "intermediate_size": 96, "num_hidden_layers": 8, "layer_offset": 0,
+    "hidden_size": 64, "intermediate_size": 96, "num_hidden_layers": 4, "layer_offset": 0,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
     "moe_intermediate_size": 32, "rms_norm_eps": 1e-5, "rope_theta": 10000,
     "tie_word_embeddings": False, "first_k_dense_replace": 0, "use_rope": False,
-    "gqa_interval": 3, "gqa_layers": [0, 4, 8], "use_gqa_gate": True,
+    "gqa_interval": 1, "gqa_layers": [0, 2, 4], "use_gqa_gate": True,
     "kda_use_full_proj": False, "kda_allow_neg_eigval": True,
     "n_routed_experts": 8, "first_expert_held": 8, "published": {"n_routed_experts": 40},
     "n_shared_experts": 1, "norm_topk_prob": True, "routed_scaling_factor": 1,
@@ -69,22 +75,6 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=40):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
-
-
 @pytest.fixture(scope="module")
 def fam():
     return build()
@@ -95,8 +85,12 @@ def params(fam):
     return seeded(fam)
 
 
-def test_the_tree_is_the_tables_and_counted(fam, params):
-    model = fam.model
+def test_the_tree_is_the_tables_and_counted():
+    """At the PUBLISHED period, a grouped-query layer then three of Kimi Delta
+    Attention: the one case that is about the period itself, and it compiles
+    no step."""
+    fam = build(num_hidden_layers=8, gqa_interval=3, gqa_layers=[0, 4, 8])
+    model, params = fam.model, seeded(fam)
     assert reference.layer_kinds(fam.config) == ["full_attention"] + ["linear_attention"] * 3 + [
         "full_attention"] + ["linear_attention"] * 3
     assert model.layer_pattern == ("full", "linear", "linear", "linear") and model.periods == 2
@@ -120,9 +114,10 @@ def test_the_tree_is_the_tables_and_counted(fam, params):
 def test_logits_and_routing_match_the_reference(fam, params):
     x = ids()
     want, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    got, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, fam.model))(params, x)
+    got, routing = forward_with_routing(fam.model)(params, x)
     close(got, want, 5e-4, "kernels")
-    assert routing["experts"].shape == (8, TOKENS, TOP_K)
+    assert fam.model.layer_pattern == ("full", "linear") and fam.model.periods == 2
+    assert routing["experts"].shape == (4, TOKENS, TOP_K)
     for i, r in enumerate(routings):
         assert np.array_equal(np.sort(routing["experts"][i], -1), np.sort(r["experts"], -1)), i
         held = np.sum((np.asarray(r["experts"]) >= 8) & (np.asarray(r["experts"]) < 16))
@@ -145,12 +140,12 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fam, params):
     )
     for remat in (None, "full"):
         model = T.dataclasses.replace(fam.model, remat=remat)
-        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        got, grads = loss_and_grads(model)(params, x, y)
         assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), remat
         named = listed(fam.reference_weights(grads))
         for name in ("embed_tokens", "norm", "lm_head"):
             close(named[name], want_grads[name], 2e-3, name)
-        assert len(named["layers"]) == len(want_grads["layers"]) == 8
+        assert len(named["layers"]) == len(want_grads["layers"]) == 4
         for i, (mine, theirs) in enumerate(zip(named["layers"], want_grads["layers"])):
             assert set(mine) == set(theirs), i
             for name in mine:
@@ -163,9 +158,9 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fam, params):
 def test_the_routers_weights_are_held_still_and_the_rest_is_loss_fn(fam, params):
     x, y = ids(), ids(seed=2)
     batch = {"x": x, "y": y}
-    whole = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, fam.model)))(params)
-    held = jax.jit(jax.grad(fam.loss))(params, batch)
-    assert float(fam.loss(params, batch)) == float(T.loss_fn(params, x, y, fam.model))
+    whole_loss, whole = loss_and_grads(fam.model)(params, x, y)
+    held_loss, held = jax.jit(jax.value_and_grad(fam.loss))(params, batch)
+    assert float(held_loss) == float(whole_loss)
     for kind in ("full", "linear"):
         assert np.any(np.asarray(whole["layers"][kind]["router"]))
         assert not np.any(np.asarray(held["layers"][kind]["router"]))

@@ -10,6 +10,7 @@ compute in float32, what is left is summation order. A wrong term is off
 by 1e-2 or more: the last test holds the comparison to that, term by term.
 """
 
+import functools
 import os
 import sys
 
@@ -27,6 +28,9 @@ from benchmarks.harness import mla_moe_flops  # noqa: E402
 from benchmarks.reference import mla_moe_decoder as reference  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention  # noqa: E402
+
+import model_helpers  # noqa: E402
+from model_helpers import close, forward_with_routing, listed, loss_and_grads  # noqa: E402
 
 CFG = {
     "name": "tiny-moonlight", "family": "mla_moe_decoder", "hidden_size": 64,
@@ -64,29 +68,19 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=64):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.max(np.abs(got - want)) <= TOL * np.max(np.abs(want)), what
+ids = functools.partial(model_helpers.ids, seq=64)
 
 
 def test_logits_and_routing_match_the_reference():
     fam = build()
     params, x = seeded(fam), ids()
     want, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    close(jax.jit(fam.forward)(params, x), want)
-    routing = fam.routing(params, x)
+    got, routing = forward_with_routing(fam.model)(params, x)    # ``fam.forward`` and ``fam.routing`` in one
+    close(got, want, TOL)
     assert routing["experts"].shape == (2, 128, 3)          # the two EXPERT layers
     for i, theirs in enumerate(routings):
         assert np.array_equal(np.sort(routing["experts"][i], -1), np.sort(theirs["experts"], -1))
-        close(jnp.sort(routing["weights"][i], -1), jnp.sort(theirs["weights"], -1))
+        close(jnp.sort(routing["weights"][i], -1), jnp.sort(theirs["weights"], -1), TOL)
         # renormalised, then scaled
         assert np.allclose(np.asarray(jnp.sum(routing["weights"][i], -1)), 2.446, atol=1e-5)
         # the bias took part in the choice: without it some tokens choose otherwise
@@ -97,22 +91,22 @@ def test_logits_and_routing_match_the_reference():
 def test_loss_has_the_balance_term_and_matches():
     fam, plain = build(), build(aux_loss_alpha=0.0)
     params, x, y = seeded(fam), ids(), ids(2)
-    batch = {"x": x, "y": y}
     want = reference.loss(listed(fam.reference_weights(params)), x, y, fam.config)
-    got = jax.jit(fam.loss)(params, batch)
-    close(got, want)
+    got, _ = loss_and_grads(fam.model)(params, x, y)             # what ``fam.loss`` is
+    close(got, want, TOL)
     # 0.001 x a loss that is 1 for a perfectly even router and more here
-    aux = float(got - jax.jit(plain.loss)(params, batch))
+    aux = float(got - loss_and_grads(plain.model)(params, x, y)[0])
     assert 0.001 * 1.0 <= aux < 0.001 * 8.0
     # one sequence at a time: the loss is sequence-wise, the mean of the two
-    alone = [float(jax.jit(fam.loss)(params, {"x": x[i:i + 1], "y": y[i:i + 1]})) for i in (0, 1)]
-    close(got, sum(alone) / 2)
+    one = jax.jit(fam.loss)
+    alone = [float(one(params, {"x": x[i:i + 1], "y": y[i:i + 1]})) for i in (0, 1)]
+    close(got, sum(alone) / 2, TOL)
 
 
 def test_every_gradient_leaf_matches():
     fam = build()
     params, x, y = seeded(fam), ids(), ids(2)
-    got = jax.jit(jax.grad(fam.loss))(params, {"x": x, "y": y})
+    _, got = loss_and_grads(fam.model)(params, x, y)
     want = jax.grad(reference.loss)(listed(fam.reference_weights(params)), x, y, fam.config)
     names = {**mla_moe_decoder.ATTENTION, **mla_moe_decoder.MLP}
     assert set(got["dense_layers"]) == set(names.values())
@@ -125,10 +119,10 @@ def test_every_gradient_leaf_matches():
                 # through a top_k's indices, which carry none either)
                 assert float(jnp.abs(got[stack][own][at]).max()) == 0.0
                 continue
-            close(got[stack][own][at], layer[published], (i, published))
+            close(got[stack][own][at], layer[published], TOL, (i, published))
             assert float(jnp.abs(got[stack][own][at]).max()) > 0, (i, published)
     for published, own in (("embed_tokens", "embed"), ("norm", "final_norm"), ("lm_head", "lm_head")):
-        close(got[own], want[published], published)
+        close(got[own], want[published], TOL, published)
 
 
 def test_one_compiled_program_serves_two_routings():
@@ -210,10 +204,10 @@ def test_flash_kernels_with_two_head_dims(qk_dim, v_dim, seq, block):
         got, got_out = jax.grad(flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
         want, want_out = jax.grad(plain, argnums=(0, 1, 2), has_aux=True)(q, k, v)
     assert got_out.shape == (1, 2, seq, v_dim)
-    close(got_out, want_out, "out")
+    close(got_out, want_out, TOL, "out")
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         assert a.shape == b.shape
-        close(a, b, name)
+        close(a, b, TOL, name)
     # the default scale is q's dim's, not v's
     scaled = flash_attention(q, k, v, scale=v_dim ** -0.5, block_q=block, block_k=block, precision=highest)
     assert float(jnp.abs(scaled - want_out).max()) > 1e-2
@@ -287,7 +281,7 @@ def test_a_changed_term_fails_the_check(what, monkeypatch):
     program, told, patches = WRONG[what]
     fam = build(**program)
     params, x = seeded(fam), ids()
-    logits, routing = jax.jit(fam.forward)(params, x), fam.routing(params, x)
+    logits, routing = forward_with_routing(fam.model)(params, x)
     weights_fn = lambda: listed(fam.reference_weights(params))   # noqa: E731
     if not program:
         sound = reference.check(logits, routing, weights_fn, x, fam.config)

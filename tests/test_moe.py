@@ -12,6 +12,7 @@ layers and their backward. A wrong term (a dropped pair, a renormalised
 weight, a norm in the wrong place) is off by 1e-2 or more.
 """
 
+import functools
 import os
 import sys
 
@@ -27,6 +28,9 @@ if ROOT not in sys.path:
 from benchmarks.families import moe_decoder  # noqa: E402
 from benchmarks.reference import moe_decoder as reference  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
+
+import model_helpers  # noqa: E402
+from model_helpers import close, forward, listed, loss_and_grads  # noqa: E402
 
 CFG = {
     "name": "tiny-olmoe", "family": "moe_decoder", "hidden_size": 64, "intermediate_size": 32,
@@ -55,17 +59,7 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=64):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.max(np.abs(got - want)) <= TOL * np.max(np.abs(want)), what
+ids = functools.partial(model_helpers.ids, seq=64)
 
 
 @pytest.mark.parametrize("norm_topk_prob", [False, True])
@@ -73,11 +67,11 @@ def test_logits_match_the_reference(norm_topk_prob):
     fam = build(norm_topk_prob=norm_topk_prob)
     params, x = seeded(fam), ids()
     want, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    close(jax.jit(fam.forward)(params, x), want)
+    close(forward(fam.model)(params, x), want, TOL)          # what ``fam.forward`` is
     # the top-k weights: renormalised they sum to 1, published they do not
-    sums = np.asarray(jnp.sum(fam.routing(params, x)["weights"], axis=-1))
-    assert np.allclose(sums, 1.0, atol=1e-6) == norm_topk_prob
-    close(fam.routing(params, x)["weights"][1], routings[1]["weights"])
+    weights = fam.routing(params, x)["weights"]
+    assert np.allclose(np.asarray(jnp.sum(weights, axis=-1)), 1.0, atol=1e-6) == norm_topk_prob
+    close(weights[1], routings[1]["weights"], TOL)
 
 
 def test_loss_has_the_balancing_term_and_matches():
@@ -85,36 +79,37 @@ def test_loss_has_the_balancing_term_and_matches():
     params, x, y = seeded(fam), ids(), ids(2)
     batch = {"x": x, "y": y}
     want = reference.loss(listed(fam.reference_weights(params)), x, y, fam.config)
-    got = jax.jit(fam.loss)(params, batch)
-    close(got, want)
+    got, _ = loss_and_grads(fam.model)(params, x, y)           # what ``fam.loss`` is
+    assert float(got) == float(jax.jit(fam.loss)(params, batch))
+    close(got, want, TOL)
     # 0.01 x a balancing loss that is 2 for a perfectly even router and more here
-    aux = float(got - jax.jit(plain.loss)(params, batch))
+    aux = float(got - loss_and_grads(plain.model)(params, x, y)[0])
     assert 0.01 * 2.0 <= aux < 0.01 * 8.0
 
 
 def test_every_gradient_leaf_matches():
     fam = build()
     params, x, y = seeded(fam), ids(), ids(2)
-    got = jax.jit(jax.grad(fam.loss))(params, {"x": x, "y": y})
+    _, got = loss_and_grads(fam.model)(params, x, y)
     want = jax.grad(reference.loss)(listed(fam.reference_weights(params)), x, y, fam.config)
     assert set(got["layers"]) == set(moe_decoder.NAMES.values())
     for i, layer in enumerate(want["layers"]):
         for published, own in moe_decoder.NAMES.items():
-            close(got["layers"][own][i], layer[published], (i, published))
+            close(got["layers"][own][i], layer[published], TOL, (i, published))
             assert float(jnp.abs(got["layers"][own][i]).max()) > 0, (i, published)
     for published, own in (("embed_tokens", "embed"), ("norm", "final_norm"), ("lm_head", "lm_head")):
-        close(got[own], want[published], published)
+        close(got[own], want[published], TOL, published)
 
 
 def test_the_balancing_loss_reaches_the_router_only_through_the_probabilities():
     fam, plain = build(), build(router_aux_loss_coef=0.0)
-    params, batch = seeded(fam), {"x": ids(), "y": ids(2)}
-    with_aux = jax.grad(fam.loss)(params, batch)["layers"]
-    without = jax.grad(plain.loss)(params, batch)["layers"]
+    params, x, y = seeded(fam), ids(), ids(2)
+    with_aux = loss_and_grads(fam.model)(params, x, y)[1]["layers"]
+    without = loss_and_grads(plain.model)(params, x, y)[1]["layers"]
     assert float(jnp.abs(with_aux["router"] - without["router"]).max()) > 1e-6
     # the LAST layer's experts see the cross-entropy alone (the first
     # layer's feed the second's router)
-    close(with_aux["w_down"][-1], without["w_down"][-1])
+    close(with_aux["w_down"][-1], without["w_down"][-1], TOL)
     assert float(jnp.abs(with_aux["w_down"][0] - without["w_down"][0]).max()) > 0
 
 
@@ -147,7 +142,7 @@ def test_a_batch_routed_entirely_to_one_expert_keeps_every_token():
             "gate_proj": layer["w_gate"], "up_proj": layer["w_up"], "down_proj": layer["w_down"]},
         fam.config,
     )
-    close(got, want)
+    close(got, want, TOL)
     # every token got both its experts: none equals the residual alone
     assert float(jnp.min(jnp.max(jnp.abs(got - x), axis=-1))) > 1e-3
 
@@ -190,8 +185,8 @@ def test_qk_norm_is_over_the_whole_projection_before_the_heads():
 
     want_q = whole(h @ layer["wq"], layer["q_norm"]).reshape(2, 16, 4, 16).transpose(0, 2, 1, 3)
     want_k = whole(h @ layer["wk"], layer["k_norm"]).reshape(2, 16, 2, 16).transpose(0, 2, 1, 3)
-    close(q, want_q)
-    close(k, want_k)
+    close(q, want_q, TOL)
+    close(k, want_k, TOL)
     plain = T.TransformerConfig.tiny(attention="reference")
     x = ids(seq=32)
     logits = T.forward(params, x, config)
@@ -256,8 +251,8 @@ def test_grouped_matmul_is_each_row_against_its_own_expert(sizes):
     assert rows.max() <= TOL * np.max(np.abs(want)), f"worst row {rows.argmax()} of expert {owner[rows.argmax()]}"
     got_grads = jax.jit(jax.grad(lambda a, b: jnp.sum(grouped_matmul(a, b, group_sizes) * weigh), (0, 1)))(lhs, rhs)
     want_grads = jax.grad(lambda a, b: jnp.sum(dense(a, b) * weigh), (0, 1))(lhs, rhs)
-    close(got_grads[0], want_grads[0], "d lhs")
-    close(got_grads[1], want_grads[1], "d rhs")   # an empty group's gradient is zero, not stale memory
+    close(got_grads[0], want_grads[0], TOL, "d lhs")
+    close(got_grads[1], want_grads[1], TOL, "d rhs")   # an empty group's gradient is zero, not stale memory
 
 
 # A layer scan's stack of expert weights, and which layer reads it. The

@@ -98,13 +98,14 @@ LFM2_SHAPED = dict(
 )
 
 
-# Window layers three to one with a global layer that carries no rotary
-# embedding, a head size stated apart from the stream's width, ReLU experts
-# routed from the layer's input, half of them held; what it names is
-# ``window_attention`` and, inside it, ``window_flash``.
+# A window layer beside a global layer that carries no rotary embedding (one
+# layer a kind, as the two shapes above: a second and third window layer would
+# name nothing the first does not), a head size stated apart from the stream's
+# width, ReLU experts routed from the layer's input, half of them held; what it
+# names is ``window_attention`` and, inside it, ``window_flash``.
 WINDOW_SHAPED = dict(
-    dim=48, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, hidden_dim=96,
-    layer_pattern=("full", "window", "window", "window"), window=16, rope_kinds=("window",),
+    dim=48, n_layers=2, n_heads=4, n_kv_heads=2, head_dim=16, hidden_dim=96,
+    layer_pattern=("full", "window"), window=16, rope_kinds=("window",),
     moe=T.MoEConfig(
         num_experts=8, top_k=2, norm_topk_prob=True, expert_dim=32, held=(0, 4),
         activation="relu", router_input="layer_input"),

@@ -18,6 +18,8 @@ from ray_tpu.parallel.ring_attention import (
     make_ring_attention, make_ulysses_attention,
 )
 
+from model_helpers import loss_and_grads
+
 
 def test_mesh_spec_axes_and_build(cpu_mesh_devices):
     spec = MeshSpec({"dp": 2, "tp": 2, "sp": 2})
@@ -138,9 +140,7 @@ def test_moe_under_the_step_mesh_matches_one_device(cpu_mesh_devices, axes):
     )
     params = init_params(config, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0, 256)
-    want_loss, want = jax.jit(jax.value_and_grad(loss_fn), static_argnums=3)(
-        params, tokens, tokens, config
-    )
+    want_loss, want = loss_and_grads(config)(params, tokens, tokens)   # one device's, once for the four meshes
     mesh = MeshSpec(axes).build(cpu_mesh_devices)
     rules = LogicalRules()
 

@@ -16,6 +16,7 @@ sums in another order). A wrong term is off by far more.
 
 import base64
 import dataclasses
+import functools
 import hashlib
 import json
 import re
@@ -30,6 +31,9 @@ from benchmarks.reference import sparse_gqa_moe_decoder as reference
 from ray_tpu.models import transformer as T
 from ray_tpu.ops import sparse_index
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention
+
+import model_helpers
+from model_helpers import close, listed, loss_and_grads
 
 SEQ, TOPK = 32, 8
 CONFIG = {
@@ -63,18 +67,11 @@ def params():
     return params
 
 
-def ids(seed=1, batch=2, seq=SEQ):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
+ids = functools.partial(model_helpers.ids, seq=SEQ)
 
 
 def reference_weights(params):
-    weights = FAMILY.reference_weights(params)
-    return {**weights, "layers": list(weights["layers"])}
-
-
-def close(got, want, tolerance, what=""):
-    scale = float(jnp.max(jnp.abs(want)))
-    assert float(jnp.max(jnp.abs(got - want))) <= tolerance * max(scale, 1e-30), what
+    return listed(FAMILY.reference_weights(params))
 
 
 # -- the masked kernels ----------------------------------------------------
@@ -316,7 +313,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference(params):
         reference_weights(params)
     )
     model = dataclasses.replace(MODEL, remat="full")
-    got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+    got, grads = loss_and_grads(model)(params, x, y)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for published, own in {**family_module.ATTENTION, **family_module.MOE}.items():
         stacked = jnp.stack([layer[published] for layer in wanted["layers"]])

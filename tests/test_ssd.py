@@ -24,6 +24,8 @@ from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.ssd import ssd, ssd_reference
 from ray_tpu.parallel.mesh import LogicalRules, MeshSpec
 
+from model_helpers import close
+
 ARGUMENTS = ("x", "dt", "A", "B", "C", "D")
 
 
@@ -39,14 +41,6 @@ def operands(batch, seq, heads, groups, width, state, decays, seed=0, dtype=jnp.
         normal(keys[2], batch, seq, heads, width).astype(dtype), a / -A, A,
         normal(keys[3], batch, seq, groups, state).astype(dtype),
         normal(keys[4], batch, seq, groups, state).astype(dtype), normal(keys[5], heads),
-    )
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
     )
 
 

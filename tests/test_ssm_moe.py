@@ -5,9 +5,13 @@ Nemotron-3-Super's language model) against the plain reference
 (``benchmarks/reference/ssm_moe_decoder.py``: the recurrence a token at a time,
 the convolution as shifted sums, explicit softmax, the experts a loop over the
 same held block), on the CPU in float32 at tiny widths with seeded weights:
-TWO periods of ``MEM*E``, 8 state-space heads of 4 on a state of 8 in 2 groups,
+TWO periods of ``M*E``, 8 state-space heads of 4 on a state of 8 in 2 groups,
 4 / 2 attention heads of 8, 16 experts of width 24 in a latent of 16 of which
-4 held, 3 a token scaled by 5.
+4 held, 3 a token scaled by 5. (One layer a kind a period: the scan's body is
+one period, so the programs these cases compile grow with it, and the second
+Mamba-2 and the second expert layer of ``MEM*E`` claim nothing the first do not.
+``test_every_layer_is_one_block_with_one_norm`` builds ``MEM*E`` twice, the
+published order, which compiles no step.)
 
 Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
 gradients 2e-3, ``tests/test_kda_gqa_moe.py``'s and for its reasons (both
@@ -15,6 +19,7 @@ sides float32; a chunk at once against a token at a time). A wrong term is off
 by far more: the last test holds the comparison to that, term by term.
 """
 
+import functools
 import os
 import re
 import sys
@@ -34,13 +39,16 @@ from benchmarks.reference import ssm_moe_decoder as reference  # noqa: E402
 from ray_tpu.models import transformer as T  # noqa: E402
 from ray_tpu.ops.rmsnorm import rmsnorm_reference  # noqa: E402
 
+import model_helpers  # noqa: E402
+from model_helpers import close, forward_with_routing, listed, loss_and_grads  # noqa: E402
+
 CFG = {
     "name": "tiny-ssm-moe", "family": "ssm_moe_decoder", "model_type": "nemotron_h",
     "hidden_size": 32, "expand": 1, "mamba_num_heads": 8, "mamba_head_dim": 4,
     "ssm_state_size": 8, "n_groups": 2, "conv_kernel": 4, "chunk_size": 8, "use_conv_bias": True,
     "mamba_hidden_act": "silu", "mamba_proj_bias": False, "time_step_min": 0.001,
     "time_step_max": 0.1, "time_step_floor": 1e-4,
-    "num_hidden_layers": 10, "hybrid_override_pattern": "MEM*EMEM*E", "layer_offset": 0,
+    "num_hidden_layers": 6, "hybrid_override_pattern": "M*EM*E", "layer_offset": 0,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 8, "attention_bias": False,
     "rope_theta": 10000, "vocab_size": 64, "intermediate_size": 24, "layer_norm_epsilon": 1e-5,
     "mlp_hidden_act": "relu2", "mlp_bias": False, "use_bias": False,
@@ -75,20 +83,7 @@ def seeded(fam, seed=3):
     return params
 
 
-def ids(seed=1, batch=2, seq=24):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 64)
-
-
-def listed(weights):
-    return dict(weights, layers=list(weights["layers"]))
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
+ids = functools.partial(model_helpers.ids, seq=24, vocab=64)
 
 
 @pytest.fixture(scope="module")
@@ -101,8 +96,17 @@ def params(fam):
     return seeded(fam)
 
 
-def test_every_layer_is_one_block_with_one_norm(fam, params):
-    model = fam.model
+@pytest.fixture(scope="module")
+def logits(fam, params):
+    """The family's own forward on ``ids()``, compiled and run once for the cases that read it."""
+    return jax.jit(fam.forward)(params, ids())
+
+
+def test_every_layer_is_one_block_with_one_norm():
+    """At ``MEM*E`` twice, the published order of a period: the one case that
+    is about the period itself, and it compiles no step."""
+    fam = build(num_hidden_layers=10, hybrid_override_pattern="MEM*EMEM*E")
+    model, params = fam.model, seeded(fam)
     assert reference.layer_kinds(fam.config) == ["mamba", "moe", "mamba", "attention", "moe"] * 2
     assert model.layer_pattern == ("ssm", "mlp", "ssm", "full", "mlp") and model.periods == 2
     assert model.one_block and model.rope_theta is None
@@ -128,10 +132,11 @@ def test_every_layer_is_one_block_with_one_norm(fam, params):
     assert mlp["shared_up"].shape == (2, 32, 40) and mlp["latent_down"].shape == (2, 32, 16)
     assert set(params["layers"][2]) == set(ssm) and set(params["layers"][4]) == set(mlp)
     # the cell's own weights steer every token to the same top_k experts, two of them held
-    steered = np.asarray(jax.jit(fam.init)(jax.random.PRNGKey(0))["layers"][1]["router_bias"])
+    fresh = jax.jit(fam.init)(jax.random.PRNGKey(0))["layers"]
+    steered = np.asarray(fresh[1]["router_bias"])
     assert steered.shape == (2, 16) and np.all(steered[0] == steered[1])
     assert steered[0].sum() == 3 and list(np.nonzero(steered[0])[0]) == [6, 7, 8]   # held: 4-7
-    fresh = jax.jit(fam.init)(jax.random.PRNGKey(0))["layers"][0]
+    fresh = fresh[0]
     steps = np.asarray(jax.nn.softplus(fresh["dt_bias"]))
     assert steps.min() >= 1e-4 - 1e-7 and steps.max() <= 0.1 + 1e-6
     rates = np.exp(np.asarray(fresh["a_log"]))
@@ -146,13 +151,14 @@ def test_every_layer_is_one_block_with_one_norm(fam, params):
     assert {"attn_norm", "mlp_norm"} <= set(both[1]) and set(both[2]) == {"w_gate", "w_up", "w_down"}
 
 
-def test_logits_and_routing_match_the_reference(fam, params):
+def test_logits_and_routing_match_the_reference(fam, params, logits):
     x = ids()
     want, routings = reference.logits(fam.reference_weights(params), x, fam.config)
-    got, routing = jax.jit(lambda p, t: T.forward_with_routing(p, t, fam.model))(params, x)
+    got, routing = forward_with_routing(fam.model)(params, x)
     close(got, want, 5e-4, "kernels")
+    assert fam.model.layer_pattern == ("ssm", "full", "mlp") and fam.model.periods == 2
     # a routing from the layers that route, and from those alone
-    assert routing["experts"].shape == (4, TOKENS, TOP_K) and len(routings) == 4
+    assert routing["experts"].shape == (2, TOKENS, TOP_K) and len(routings) == 2
     for i, r in enumerate(routings):
         assert np.array_equal(np.sort(routing["experts"][i], -1), np.sort(r["experts"], -1)), i
         close(jnp.sum(routing["weights"][i], -1), np.full(TOKENS, 5.0), 1e-5, "scaled by 5")
@@ -160,7 +166,7 @@ def test_logits_and_routing_match_the_reference(fam, params):
         assert int(routing["held_pairs"][i]) == held
     recurrence = T.forward(params, x, T.dataclasses.replace(fam.model, attention="reference"))
     close(recurrence, want, 5e-4, "attention='reference': the recurrence and XLA's forms")
-    check = fam.check(jax.jit(fam.forward)(params, x), params, x)
+    check = fam.check(logits, params, x)
     assert check["ok"], check
     assert check["scan"]["layer"] == 0 and check["scan"]["own"]["rel_rms"] < 1e-5
     assert check["scan"]["opened"]["rel_rms"] < 1e-5
@@ -175,12 +181,12 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fam, params):
     )
     for remat in (None, "full"):
         model = T.dataclasses.replace(fam.model, remat=remat)
-        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        got, grads = loss_and_grads(model)(params, x, y)
         assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), remat
         named = listed(fam.reference_weights(grads))
         for name in ("embed_tokens", "norm_f", "lm_head"):
             close(named[name], want_grads[name], 2e-3, name)
-        assert len(named["layers"]) == len(want_grads["layers"]) == 10
+        assert len(named["layers"]) == len(want_grads["layers"]) == 6
         for i, (mine, theirs) in enumerate(zip(named["layers"], want_grads["layers"])):
             assert set(mine) == set(theirs), i
             for name in mine:
@@ -190,10 +196,9 @@ def test_loss_and_every_gradient_leaf_match_the_reference(fam, params):
                     close(mine[name], theirs[name], 2e-3, (remat, i, name))
     # the cell's loss: the routers' weights held still, everything else loss_fn's
     held = jax.jit(jax.grad(fam.loss))(params, {"x": x, "y": y})
-    for place in (1, 4):
-        assert np.any(np.asarray(grads["layers"][place]["router"]))
-        assert not np.any(np.asarray(held["layers"][place]["router"]))
-    for place, name in ((1, "w_down"), (4, "latent_down"), (0, "a_log"), (3, "wo")):
+    assert np.any(np.asarray(grads["layers"][2]["router"]))
+    assert not np.any(np.asarray(held["layers"][2]["router"]))
+    for place, name in ((2, "w_down"), (2, "latent_down"), (0, "a_log"), (1, "wo")):
         close(held["layers"][place][name], grads["layers"][place][name], 1e-5, (place, name))
 
 
@@ -204,7 +209,7 @@ def test_the_new_scopes_name_forward_and_backward(fam, params):
     search a device trace for."""
     x, y = ids(), ids(seed=2)
     model = T.dataclasses.replace(fam.model, remat="full")
-    lowered = jax.jit(jax.grad(lambda p: T.loss_fn(p, x, y, model))).lower(params)
+    lowered = loss_and_grads(model).lower(params, x, y)
     names = set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
     under = lambda *path: [n for n in names if "/".join(path) in n]
     assert under("attention", "ssm_mixer", "ssd") and under("attention", "ssm_mixer", "short_conv")
@@ -219,7 +224,7 @@ def test_the_new_scopes_name_forward_and_backward(fam, params):
 def _one_layer(fam, params, held):
     """The first expert layer's leaves as a model holding ``held`` would
     store them, all 16 experts drawn."""
-    layer = {k: v[0] for k, v in params["layers"][1].items()}
+    layer = {k: v[0] for k, v in params["layers"][2].items()}
     key = jax.random.PRNGKey(11)
     full = {
         name: jax.random.normal(jax.random.fold_in(key, n), (16, *layer[name].shape[1:]))
@@ -269,12 +274,12 @@ def test_what_this_model_cannot_do_yet_is_refused_by_name(fam, params):
     model = fam.model
     with pytest.raises(NotImplementedError, match='"ssm" layers'):
         T.init_kv_cache(model, 1, 8)
-    with pytest.raises(NotImplementedError, match="ssm, mlp, full"):
+    with pytest.raises(NotImplementedError, match="ssm, full, mlp"):
         T.partition_stages(params, model, 2)
     with pytest.raises(ValueError, match="exactly where ssm="):
         T.TransformerConfig.tiny(layer_pattern=("ssm", "mlp"))
     with pytest.raises(NotImplementedError, match="one-block layers"):
-        T.dataclasses.replace(model, first_dense_layers=5)
+        T.dataclasses.replace(model, first_dense_layers=3)
     with pytest.raises(ValueError, match="kinds are"):
         T.dataclasses.replace(model, first_dense_kind="mlp", layer_pattern=("full", "mlp"), ssm=None)
     mesh = jax.sharding.AbstractMesh((1, 2), ("dp", "tp"))
@@ -284,13 +289,11 @@ def test_what_this_model_cannot_do_yet_is_refused_by_name(fam, params):
 
 
 @pytest.mark.parametrize("what", reference.CONTROLS)
-def test_a_changed_term_fails_the_check(what, fam, params):
+def test_a_changed_term_fails_the_check(what, fam, params, logits):
     """Each wrong model of ``reference.CONTROLS`` moves the logits past the
     tolerance the cell holds them to (and far past this file's)."""
-    x = ids()
-    got = jax.jit(fam.forward)(params, x)
-    wrong, _ = reference.logits(fam.reference_weights(params), x, dict(fam.config, control=what))
-    assert reference.compare(got, wrong)["rel_rms"] > 2 * reference.TOLERANCE, what
+    wrong, _ = reference.logits(fam.reference_weights(params), ids(), dict(fam.config, control=what))
+    assert reference.compare(logits, wrong)["rel_rms"] > 2 * reference.TOLERANCE, what
 
 
 @pytest.mark.parametrize("name", ("program",) + controls.CONTROLS)

@@ -8,9 +8,13 @@ SmallThinker-21BA3B's shape) against an oracle WRITTEN HERE: the same
 mathematics in plain ``jax.numpy`` on the program's own parameter tree,
 float32, an explicit ``(j <= i) & (j > i - window)`` mask, no kernel, no
 sort, no scan, the experts a loop. On the CPU at tiny widths with seeded
-weights: ONE period (full, window, window, window), 4 / 2 heads of 16 on a
-stream of 48 (q is 64 wide), a window of 8 keys over 40 positions, 8 experts
-of which 4 are held, 2 a token.
+weights: TWO periods of (full, window), 4 / 2 heads of 16 on a stream of 48
+(q is 64 wide), a window of 8 keys over 40 positions, 8 experts of which 4 are
+held, 2 a token. (One layer a kind a period: the scan's body is one period, so
+the programs these cases compile grow with it, and a second or third window
+layer in a row claims nothing the first does not.
+``test_the_tree_is_stacked_by_period_and_counted`` builds the published period
+of four, which compiles no step.)
 
 Tolerances, each of the largest value compared: logits 5e-4, loss 1e-5,
 gradients 2e-3 (``tests/test_conv_moe.py``'s and for its reasons: both sides
@@ -18,6 +22,7 @@ float32, sums in another order). A wrong term is off by far more.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,16 +30,20 @@ import numpy as np
 import optax
 import pytest
 
-from ray_tpu import train
 from ray_tpu.models import transformer as T
 from ray_tpu.ops.flash_attention import flash_attention
-from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig, jax_utils
+from ray_tpu.train import jax_utils
+
+from model_helpers import (
+    close, forward, forward_with_routing, ids, layers_in_order, loss_and_grads,
+    trains_through_jax_trainer,
+)
 
 EPS, THETA, HELD, WINDOW = 1e-6, 1.5e6, (0, 4), 8
 MODEL = T.TransformerConfig(
     vocab_size=256, dim=48, n_layers=4, n_heads=4, n_kv_heads=2, head_dim=16, hidden_dim=96,
     max_seq=40, rope_theta=THETA, rope_kinds=("window",), window=WINDOW, rms_norm_eps=EPS,
-    dtype=jnp.float32, layer_pattern=("full", "window", "window", "window"),
+    dtype=jnp.float32, layer_pattern=("full", "window"),
     moe=T.MoEConfig(
         num_experts=8, top_k=2, norm_topk_prob=True, expert_dim=24, held=HELD,
         activation="relu", router_input="layer_input",
@@ -53,18 +62,6 @@ def seeded(model=MODEL, seed=3):
             tree[name] = tree[name] + 0.2 * jax.random.normal(next(keys), tree[name].shape)
     params["final_norm"] = params["final_norm"] + 0.2 * jax.random.normal(next(keys), (model.dim,))
     return params
-
-
-def ids(seed=1, batch=2, seq=40):
-    return jax.random.randint(jax.random.PRNGKey(seed), (batch, seq), 0, 256)
-
-
-def close(got, want, tol, what=""):
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    assert got.shape == want.shape and np.all(np.isfinite(got)), what
-    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want)), (
-        what, np.max(np.abs(got - want)), np.max(np.abs(want))
-    )
 
 
 # -- the oracle ---------------------------------------------------------------
@@ -124,11 +121,7 @@ def oracle_logits(params, tokens, model=MODEL, *, window_on_full=None, rope_on_f
                   window=WINDOW, normed_router=False, act=jax.nn.relu):
     """The keyword arguments are the CONTROLS: each computes another model."""
     x = params["embed"][tokens]
-    at = lambda tree, *index: jax.tree.map(lambda leaf: leaf[index], tree)
-    taken = dict.fromkeys(model.layer_pattern, 0)
-    for kind in model.layer_pattern:
-        layer = at(params["layers"][kind], 0, taken[kind])
-        taken[kind] += 1
+    for kind, layer in layers_in_order(params, model):
         layer_input = x
         h = _norm(x, layer["attn_norm"])
         if kind == "window":
@@ -151,8 +144,12 @@ def params():
 
 
 # -- the model ----------------------------------------------------------------
-def test_the_tree_is_stacked_by_period_and_counted(params):
-    assert (MODEL.periods, MODEL.head_dim, MODEL.n_heads * MODEL.head_dim) == (1, 16, 64)
+def test_the_tree_is_stacked_by_period_and_counted():
+    """At the PUBLISHED period, one global layer then three window layers: the
+    one case that is about the period itself, and it compiles no step."""
+    model = dataclasses.replace(MODEL, layer_pattern=("full", "window", "window", "window"))
+    params = seeded(model)
+    assert (model.periods, model.head_dim, model.n_heads * model.head_dim) == (1, 16, 64)
     assert params["layers"]["full"]["wq"].shape == (1, 1, 48, 64)      # q wider than the stream
     assert params["layers"]["window"]["wq"].shape == (1, 3, 48, 64)
     assert params["layers"]["window"]["wk"].shape == (1, 3, 48, 32)
@@ -161,8 +158,8 @@ def test_the_tree_is_stacked_by_period_and_counted(params):
     assert params["layers"]["window"]["router"].shape == (1, 3, 48, 8)      # all are scored
     assert params["layers"]["window"]["router"].dtype == jnp.float32
     assert "router_bias" not in params["layers"]["window"]                   # softmax routing
-    assert T.config_num_params(MODEL) == T.num_params(params)
-    dims = T.param_logical_dims(MODEL)
+    assert T.config_num_params(model) == T.num_params(params)
+    dims = T.param_logical_dims(model)
     is_dims = lambda x: isinstance(x, tuple)
     assert jax.tree.structure(dims, is_leaf=is_dims) == jax.tree.structure(params)
 
@@ -174,10 +171,9 @@ def test_logits_match_the_oracle_on_both_paths(params):
     got = {}
     for attention in ("flash", "reference"):
         model = dataclasses.replace(MODEL, attention=attention)
-        got[attention], routing = jax.jit(
-            lambda p, t: T.forward_with_routing(p, t, model)
-        )(params, x)
+        got[attention], routing = forward_with_routing(model)(params, x)
         close(got[attention], want, 5e-4, attention)
+        assert model.periods == 2
         assert routing["experts"].shape == (4, TOKENS, 2)
         assert all(0 < int(n) < TOKENS * 2 for n in routing["held_pairs"])
     close(got["flash"], got["reference"], 2e-5, "kernel path against attention='reference'")
@@ -189,7 +185,7 @@ def test_loss_and_every_gradient_leaf_match_the_oracle(params):
         want, want_grads = jax.value_and_grad(oracle_loss)(params, x, y)
     for attention, remat in (("flash", None), ("flash", "full"), ("reference", None)):
         model = dataclasses.replace(MODEL, attention=attention, remat=remat)
-        got, grads = jax.jit(jax.value_and_grad(lambda p: T.loss_fn(p, x, y, model)))(params)
+        got, grads = loss_and_grads(model)(params, x, y)
         assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want)), (attention, remat)
         assert jax.tree.structure(grads) == jax.tree.structure(want_grads)
         mine, theirs = (jax.tree_util.tree_leaves_with_path(g) for g in (grads, want_grads))
@@ -229,7 +225,7 @@ def _expert_layer(params, held):
     SAME 8 experts (None: all): the whole layer drawn once, shares sliced."""
     whole_model = dataclasses.replace(MODEL, moe=dataclasses.replace(MODEL.moe, held=None))
     whole = jax.jit(lambda key: T.init_params(whole_model, key))(jax.random.PRNGKey(9))
-    layer = jax.tree.map(lambda leaf: leaf[0, 1], whole["layers"]["window"])
+    layer = jax.tree.map(lambda leaf: leaf[1, 0], whole["layers"]["window"])
     if held is not None:
         first, count = held
         layer = {
@@ -275,7 +271,7 @@ def test_a_changed_term_moves_the_logits(params, what):
     """What the comparison above would catch, term by term: the PROGRAM
     against the oracle computing another model reads over twenty tolerances."""
     x = ids()
-    got = jax.jit(lambda p, t: T.forward(p, t, MODEL))(params, x)
+    got = forward(MODEL)(params, x)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(oracle_logits(params, x, **CONTROLS[what]))
     off = np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want))
@@ -292,7 +288,7 @@ def test_the_programs_own_switches_are_the_oracles(params):
         (replace(MODEL, rope_kinds=None), dict(rope_on_full=True)),
         (replace(MODEL, window=40), dict(window=None)),
     ):
-        got = jax.jit(lambda p, t: T.forward(p, t, model))(params, x)
+        got = forward(model)(params, x)
         with jax.default_matmul_precision("highest"):
             close(got, oracle_logits(params, x, **control), 5e-4, control)
 
@@ -342,6 +338,12 @@ def _two_steps(model, mesh_axes, x):
     return float(first), float(step(params, opt_state, batch)[2])
 
 
+@functools.cache
+def _one_devices_two_steps():
+    """What both meshes below are held to, stepped once."""
+    return _two_steps(MODEL, {"dp": 1}, np.asarray(ids(seed=8, batch=4, seq=41)))
+
+
 @pytest.mark.parametrize("axes", [{"fsdp": 2, "tp": 2}, {"dp": 2, "sp": 2}])
 def test_the_step_is_the_one_devices_over_a_tp_and_an_sp_mesh(axes):
     """A pattern of full and window layers has no kernel that refuses a mesh
@@ -350,9 +352,9 @@ def test_the_step_is_the_one_devices_over_a_tp_and_an_sp_mesh(axes):
     window through ``parallel/``'s sequence-parallel attention is what is NOT
     written), and two steps give the one-device losses."""
     x = np.asarray(ids(seed=8, batch=4, seq=41))
-    losses = {name: _two_steps(MODEL, mesh_axes, x) for name, mesh_axes in (("one", {"dp": 1}), ("mesh", axes))}
-    assert losses["mesh"][1] < losses["mesh"][0]
-    np.testing.assert_allclose(losses["mesh"], losses["one"], rtol=2e-6)
+    mesh = _two_steps(MODEL, axes, x)
+    assert mesh[1] < mesh[0]
+    np.testing.assert_allclose(mesh, _one_devices_two_steps(), rtol=2e-6)
 
 
 @pytest.mark.parametrize("kv_heads", [2, 1])
@@ -379,38 +381,10 @@ def test_flash_and_the_oracle_agree_on_grouped_heads_over_tp(kv_heads, monkeypat
     np.testing.assert_allclose(losses["flash"], losses["reference"], rtol=1e-5)
 
 
-def _window_moe_loop(config):
-    model = dataclasses.replace(MODEL, remat="full")
-    optimizer = optax.adamw(3e-3)
-    setup = jax_utils.setup_sharded_training(
-        lambda: T.init_params(model, jax.random.PRNGKey(0)), optimizer,
-        logical_dims=T.param_logical_dims(model),
-    )
-    step = jax_utils.build_sharded_train_step(
-        lambda params, batch: T.loss_fn(params, batch["x"], batch["y"], model), optimizer, setup
-    )
-    x = np.asarray(ids(seed=8, batch=4, seq=41))
-    batch = setup.shard_batch({"x": x[:, :-1], "y": x[:, 1:]})
-    params, opt_state = setup.params, setup.opt_state
-    for _ in range(config["steps"]):
-        params, opt_state, loss = step(params, opt_state, batch)
-        train.report({"loss": float(loss), "factorization": setup.factorization})
-
-
 def test_the_tiny_preset_trains_through_jax_trainer(ray_start_shared, tmp_path):
     """The normal path: JaxTrainer -> setup_sharded_training ->
     build_sharded_train_step -> loss_fn, over a dp 2 x fsdp 2 mesh (flash
     with and without a window and the held experts' block per data shard
     under shard_map, the router's second stream sharded as the first), full
     remat."""
-    trainer = JaxTrainer(
-        _window_moe_loop,
-        train_loop_config={"steps": 3},
-        scaling_config=ScalingConfig(num_workers=1, mesh_axes={"dp": 2, "fsdp": 2}),
-        run_config=RunConfig(name="window-moe", storage_path=str(tmp_path)),
-    )
-    result = trainer.fit()
-    assert result.error is None, result.error
-    assert result.metrics["factorization"] == {"dp": 2, "fsdp": 2, "tp": 1, "pp": 1}
-    losses = [m["loss"] for m in result.metrics_history]
-    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    trains_through_jax_trainer(dataclasses.replace(MODEL, remat="full"), "window-moe", tmp_path, seq=41)
