@@ -27,7 +27,11 @@ The scores of the selection pass and the selection are XLA's, a CHUNK of
 query rows at a time (``[index heads, chunk, keys]`` float32 products are the
 largest arrays there), the chunks walked in a few ROW GROUPS whose keys end
 where the group's last row does (a chunk of the first quarter of the rows
-reads a quarter of the keys).
+reads a quarter of the keys). A chunk's chosen keys leave it PACKED, eight a
+byte (``_pack``: one row's bits in that row's byte, no tiled dimension
+reshaped), written into its row group's bytes; the int8 mask is those bytes'
+eight slabs of lanes, each a shift and a mask written where it belongs
+(``_unpack``), in the forward and again in a checkpointed layer's backward.
 
 The term is two Pallas kernels written as ``ops/flash_attention.py``'s are
 (causal tiles ``[block_q, block_k]`` of pairs, a tile past the edge skipped
@@ -76,11 +80,11 @@ from ray_tpu.ops.flash_attention import (
 )
 
 # What a checkpointed layer may keep (``checkpoint_name``): the selection,
-# PACKED eight keys a byte (``[batch, seq, seq / 8]``: 32 MiB a layer at
-# 16,384 positions where the mask the kernels read is 256; the backward's
-# second forward unpacks it and runs neither the scorer nor the top-k again),
-# and the index loss's gradients with respect to the scorer's three
-# operands, made in its forward.
+# PACKED eight keys a byte, a row group a piece (``[batch, rows, keys / 8]``
+# each: 20 MiB a layer at 16,384 positions where the mask the kernels read
+# is 256; the backward's second forward unpacks them and runs neither the
+# scorer nor the top-k again), and the index loss's gradients with respect
+# to the scorer's three operands, made in its forward.
 RESIDUAL_NAMES = ("index_selection", "index_loss_grads")
 _PACKED = 8
 
@@ -124,18 +128,27 @@ def _kth_largest(keyed, k: int):
     """The ``k``-th largest of each row of ``keyed`` ``[..., keys]`` float32,
     ``[..., 1]`` (``-inf`` where a row has fewer entries above it): a
     bisection on the floats' bit patterns, 32 passes of a comparison and a
-    count over the row, most significant bit first. The pattern of a float as
-    an unsigned integer whose order is the floats' (a negative's bits
-    inverted, a positive's sign bit set; -0.0 lies one under +0.0 there, and
-    the comparisons that follow are the floats' own, where they are equal).
-    On a v5e 0.31 ms a ``[512, 16384]`` chunk where ``lax.top_k`` takes 7.7
-    and a values-only sort 5.3 (PERF.md section 6, PR 53)."""
-    bits = jax.lax.bitcast_convert_type(keyed, jnp.uint32)
-    ordered = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    count over the row, most significant bit first. The threshold grows in
+    the pattern of a float as an unsigned integer whose order is the floats'
+    (a negative's bits inverted, a positive's sign bit set; -0.0 lies one
+    under +0.0 there, and the comparisons that follow are the floats' own,
+    where they are equal); the row's entries are held as that pattern with
+    its sign bit flipped, int32 in the same order, because the comparison a
+    pass makes of every entry is then a SIGNED one, which is what the VPU
+    has: a chunk's ``[512, 16384]`` lies in VMEM across the passes and a
+    pass is bound by its vector operations, not by bytes. On a v5e 0.26 ms
+    such a chunk, 0.30 with unsigned comparisons; two bits a pass 0.33, four
+    0.77, a 256-bin histogram a pass of eight 1.7 as a product and 18 as
+    comparisons, ``lax.top_k`` 7.7, a values-only sort 5.3 (PERF.md section
+    6, PR 53 and PR 58)."""
+    bits = jax.lax.bitcast_convert_type(keyed, jnp.int32)
+    entries = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    sign = jnp.uint32(1 << 31)
 
     def one_bit(i, prefix):
-        trial = prefix | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
-        reached = jnp.sum(ordered >= trial, axis=-1, keepdims=True, dtype=jnp.int32)
+        trial = prefix | (sign >> i.astype(jnp.uint32))
+        signed = jax.lax.bitcast_convert_type(trial ^ sign, jnp.int32)
+        reached = jnp.sum(entries >= signed, axis=-1, keepdims=True, dtype=jnp.int32)
         return jnp.where(reached >= k, trial, prefix)
 
     prefix = jax.lax.fori_loop(0, 32, one_bit, jnp.zeros((*keyed.shape[:-1], 1), jnp.uint32))
@@ -143,15 +156,53 @@ def _kth_largest(keyed, k: int):
     return jax.lax.bitcast_convert_type(back, jnp.float32)
 
 
+def _pack(chosen):
+    """``chosen`` ``[..., keys]`` of booleans as uint8 ``[..., width]``,
+    ``width = ceil(keys / 8)``: bit ``j`` of byte ``c`` is key ``j x width +
+    c``, the keys in eight contiguous slabs, so that a byte and its eight
+    keys lie in ONE row and neither way crosses rows or reshapes a tiled
+    dimension: eight slices along the keys, shifted and OR-ed (whole tiles
+    where ``width`` is whole vregs of lanes, as at a multiple of 1,024
+    keys)."""
+    keys = chosen.shape[-1]
+    width = -(-keys // _PACKED)
+    chosen = jnp.pad(chosen, [(0, 0)] * (chosen.ndim - 1) + [(0, _PACKED * width - keys)])
+    return functools.reduce(operator.or_, (
+        chosen[..., j * width:(j + 1) * width].astype(jnp.uint8) << j for j in range(_PACKED)
+    ))
+
+
+def _slabs(packed, keys: int):
+    """``_pack``'s inverse a slab: ``[(first key, int8 [..., width or
+    fewer])]``, a shift and a mask of the packed bytes each."""
+    width = packed.shape[-1]
+    return [
+        (first, ((packed[..., :min(width, keys - first)] >> j) & 1).astype(jnp.int8))
+        for j, first in enumerate(range(0, keys, width))
+    ]
+
+
+def _unpack(pieces, seq: int, chunk: int):
+    """The selection int8 ``[batch, seq, seq]`` of ``_select_packed``'s
+    pieces: a row group's slabs written side by side along the keys where
+    they belong, zeros past the group's last row."""
+    selection = jnp.zeros((pieces[0].shape[0], seq, seq), jnp.int8)
+    for (first_row, rows), packed in zip(_row_groups(seq, chunk), pieces):
+        for first, slab in _slabs(packed, first_row + rows):
+            selection = jax.lax.dynamic_update_slice(selection, slab, (0, first_row, first))
+    return selection
+
+
 def select_keys(scores, first_row, topk: int):
-    """The chosen keys of a chunk: int8 ``[batch, rows, keys]``, 1 where key
-    ``s`` is among the ``min(t + 1, topk)`` largest ``scores[t, s]`` over ``s
-    <= t`` (``t = first_row + row``), ties towards the lower key."""
+    """The chosen keys of a chunk, PACKED (``_pack``): uint8 ``[batch, rows,
+    ceil(keys / 8)]``, key ``s``'s bit set where it is among the ``min(t +
+    1, topk)`` largest ``scores[t, s]`` over ``s <= t`` (``t = first_row +
+    row``), ties towards the lower key."""
     _, rows, keys = scores.shape
     row = first_row + jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 0)
     causal = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1) <= row
     if keys <= topk:
-        return jnp.broadcast_to(causal, scores.shape).astype(jnp.int8)
+        return _pack(jnp.broadcast_to(causal, scores.shape))
     keyed = jnp.where(causal, scores, -jnp.inf)
     kth = _kth_largest(keyed, topk)
     above = keyed > kth
@@ -160,26 +211,8 @@ def select_keys(scores, first_row, topk: int):
     # a row with topk keys or fewer keeps them all: ``kth`` is -inf there
     need = jnp.where(kth == -jnp.inf, 0, topk - count(above))
     # exact zeros tie (every index head cut by its ReLU); the lower keys win
-    by_rank = lambda: above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need))
-    chosen = jax.lax.cond(jnp.any(count(tied) != need), by_rank, lambda: above | tied)
-    return chosen.astype(jnp.int8)
-
-
-def _pack(selection):
-    """``selection`` ``[batch, seq, keys]`` of 0 / 1 with bit ``j`` of byte
-    ``c`` key ``j x keys / 8 + c``: the keys in eight contiguous slabs, so
-    that neither way moves the lane dimension."""
-    batch, seq, keys = selection.shape
-    slabs = selection.reshape(batch, seq, _PACKED, keys // _PACKED).astype(jnp.uint8)
-    bit = jnp.arange(_PACKED, dtype=jnp.uint8)[None, None, :, None]
-    return jnp.sum(slabs << bit, axis=2, dtype=jnp.uint8)
-
-
-def _unpack(packed):
-    batch, seq, width = packed.shape
-    bit = jnp.arange(_PACKED, dtype=jnp.uint8)[None, None, :, None]
-    slabs = (packed[:, :, None, :] >> bit) & jnp.uint8(1)
-    return slabs.astype(jnp.int8).reshape(batch, seq, _PACKED * width)
+    by_rank = lambda: _pack(above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= need)))
+    return jax.lax.cond(jnp.any(count(tied) != need), by_rank, lambda: _pack(above | tied))
 
 
 def _by_chunk(x, first_row: int, rows: int, chunk: int, axis: int = 1):
@@ -188,6 +221,31 @@ def _by_chunk(x, first_row: int, rows: int, chunk: int, axis: int = 1):
     x = jax.lax.slice_in_dim(x, first_row, first_row + rows, axis=axis)
     shape = (*x.shape[:axis], rows // chunk, chunk, *x.shape[axis + 1:])
     return jnp.moveaxis(x.reshape(shape), axis, 0)
+
+
+def _select_packed(q_index, k_index, w, topk: int, chunk: int):
+    """Every query's chosen keys, packed, a ROW GROUP a piece: uint8
+    ``[batch, rows, ceil(keys / 8)]`` each, the scan's buffer that a chunk
+    writes its rows of."""
+    batch, seq = q_index.shape[:2]
+    pieces = []
+    for first_row, rows in _row_groups(seq, chunk):
+        keys = first_row + rows
+        seen = k_index[:, :keys]
+
+        def one_chunk(packed, scanned, seen=seen, first_row=first_row):
+            first, q_chunk, w_chunk = scanned
+            with jax.named_scope("indexer"):
+                scores = index_scores(q_chunk, seen, w_chunk)
+            with jax.named_scope("index_select"):
+                chosen = select_keys(scores, first, topk)
+                return jax.lax.dynamic_update_slice_in_dim(packed, chosen, first - first_row, 1), None
+
+        firsts = first_row + chunk * jnp.arange(rows // chunk, dtype=jnp.int32)
+        pieces.append(jax.lax.scan(one_chunk, jnp.zeros((batch, rows, -(-keys // _PACKED)), jnp.uint8), (
+            firsts, _by_chunk(q_index, first_row, rows, chunk), _by_chunk(w, first_row, rows, chunk),
+        ))[0])
+    return pieces
 
 
 def index_select(q_index, k_index, w, *, topk: int, chunk: int = 512):
@@ -201,30 +259,9 @@ def index_select(q_index, k_index, w, *, topk: int, chunk: int = 512):
         return jnp.broadcast_to(causal, (batch, seq, seq))
     q_index, k_index, w = jax.lax.stop_gradient((q_index, k_index, w))
     chunk = _chunk(seq, chunk)
-    pieces = []
-    for first_row, rows in _row_groups(seq, chunk):
-        keys = first_row + rows
-        seen = k_index[:, :keys]
-
-        def one_chunk(scanned, seen=seen):
-            first, q_chunk, w_chunk = scanned
-            with jax.named_scope("indexer"):
-                scores = index_scores(q_chunk, seen, w_chunk)
-            with jax.named_scope("index_select"):
-                return select_keys(scores, first, topk)
-
-        firsts = first_row + chunk * jnp.arange(rows // chunk, dtype=jnp.int32)
-        chosen = jax.lax.map(one_chunk, (
-            firsts, _by_chunk(q_index, first_row, rows, chunk), _by_chunk(w, first_row, rows, chunk),
-        ))                                                   # [chunks, batch, chunk, keys]
-        with jax.named_scope("index_select"):
-            chosen = jnp.moveaxis(chosen, 0, 1).reshape(batch, rows, keys)
-            pieces.append(jnp.pad(chosen, ((0, 0), (0, 0), (0, seq - keys))))
+    pieces = checkpoint_name(_select_packed(q_index, k_index, w, topk, chunk), RESIDUAL_NAMES[0])
     with jax.named_scope("index_select"):
-        selection = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=1)
-        if seq % _PACKED:
-            return selection
-        return _unpack(checkpoint_name(_pack(selection), RESIDUAL_NAMES[0]))
+        return _unpack(pieces, seq, chunk)
 
 
 def _index_blocks(seq: int, block_q: int | None, block_k: int | None) -> tuple[int, int]:

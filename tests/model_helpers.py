@@ -122,6 +122,19 @@ def mosaic_calls(text: str) -> list[str]:
     return re.findall(r"^\s*(?:ROOT )?%(\w+?)[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"", text, re.M)
 
 
+def unfused_instructions(text: str) -> list[str]:
+    """The lines of a compiled program's instructions that run as
+    themselves: those of every computation but the fused ones (a fusion's
+    line stands for its body)."""
+    lines, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused and " = " in line:
+            lines.append(line)
+    return lines
+
+
 def loss_and_grads_text(topo, config, axes, batch, seq) -> str:
     """The optimized program of ``loss_fn`` and its gradients, compiled from
     shapes for the described chips under ``axes``, traced under the mesh as

@@ -178,6 +178,84 @@ def test_the_selection_is_the_reference_top_k_and_ties_go_to_the_lower_key():
     assert jnp.array_equal(selection, sparse_index.index_select(*scorer, topk=16, chunk=64))
 
 
+def _unpacked(packed, keys):
+    return jnp.concatenate([slab for _, slab in sparse_index._slabs(packed, keys)], axis=-1)
+
+
+def _selection_case(name):
+    """``(scores [1, rows, keys] float32, first row, topk)`` of one hard case
+    of the selection; every row sees every key unless the case says
+    otherwise."""
+    rows, keys, topk = 8, 96, 16
+    first_row = keys                                            # every key is causal
+    random = np.random.default_rng(11)
+    if name == "all_equal":
+        scores = np.full((rows, keys), 0.5, np.float32)
+    elif name == "exactly_k_distinct_values":
+        scores = np.tile(np.arange(topk, dtype=np.float32), keys // topk)[None].repeat(rows, 0)
+    elif name == "ties_straddle_the_threshold":
+        # 10 above, then 20 equal entries of which 6 are taken, scattered over the row
+        scores = np.stack([random.permutation(np.r_[np.arange(10) + 2.0, np.ones(20), -np.arange(66.0)])
+                           for _ in range(rows)]).astype(np.float32)
+    elif name == "zeros_of_both_signs_and_denormals":
+        pool = np.array([0.0, -0.0, 1e-40, -1e-40, 1e-45, -1e-45, 3e-39, 1.0, -1.0], np.float32)
+        scores = pool[random.integers(0, len(pool), (rows, keys))]
+    elif name == "masked_keys_are_minus_infinity":
+        # the chunk's own rows: row t sees t + 1 keys, some rows fewer than topk
+        first_row, scores = 12, random.standard_normal((rows, keys)).astype(np.float32)
+    elif name == "fewer_keys_than_topk":
+        keys = first_row = 12
+        scores = random.standard_normal((rows, keys)).astype(np.float32)
+    elif name == "a_chunk_at_the_cell_s_shape":
+        rows, keys, topk, first_row = 512, 16384, 2048, 16384 - 512
+        scores = random.standard_normal((rows, keys)).astype(np.float32)
+        scores = np.where(random.random((rows, keys)) < 0.1, 0.0, scores)
+    return jnp.asarray(scores)[None], first_row, topk
+
+
+@pytest.mark.parametrize("name", [
+    "all_equal", "exactly_k_distinct_values", "ties_straddle_the_threshold",
+    "zeros_of_both_signs_and_denormals", "masked_keys_are_minus_infinity", "fewer_keys_than_topk",
+    "a_chunk_at_the_cell_s_shape",
+])
+def test_the_threshold_is_the_sorted_one_bit_for_bit_and_the_chosen_set_is_top_k_s(name):
+    """``_kth_largest`` against a SORT of the floats' ordered bit patterns
+    (the k-th largest entry itself, -0.0 one under +0.0, -inf where a row
+    has fewer entries above it), bit for bit; and ``select_keys``' chosen set
+    against ``jax.lax.top_k`` over the causal keys, whose equal values come
+    lower index first: the parent's tie rule."""
+    scores, first_row, topk = _selection_case(name)
+    _, rows, keys = scores.shape
+    causal = jnp.arange(keys)[None, :] <= first_row + jnp.arange(rows)[:, None]
+    keyed = jnp.where(causal, scores, -jnp.inf)
+    if keys > topk:
+        bits = np.asarray(keyed).view(np.uint32)
+        ordered = np.where(bits >> 31 == 1, ~bits, bits | np.uint32(1 << 31))
+        kth = np.sort(ordered, axis=-1)[..., -topk][..., None]
+        want = np.where(kth >> 31 == 1, kth & np.uint32(0x7FFFFFFF), ~kth).astype(np.uint32)
+        got = np.asarray(jax.jit(sparse_index._kth_largest, static_argnums=1)(keyed, topk))
+        assert np.array_equal(got.view(np.uint32), want)
+    chosen = _unpacked(jax.jit(sparse_index.select_keys, static_argnums=2)(scores, first_row, topk), keys)
+    # top_k over zeros of ONE sign: the selection compares floats, where -0.0 == 0.0
+    best = jax.lax.top_k(keyed + 0.0, min(topk, keys))[1]
+    want = jnp.zeros((1, rows, keys), bool).at[0, jnp.arange(rows)[:, None], best[0]].set(True) & causal
+    assert chosen.dtype == jnp.int8 and jnp.array_equal(chosen != 0, want)
+    seen = jnp.minimum(first_row + jnp.arange(rows) + 1, keys)
+    assert jnp.array_equal(jnp.sum(chosen, axis=-1)[0], jnp.minimum(seen, topk))
+
+
+@pytest.mark.parametrize("shape", [(1, 512, 16384), (2, 7, 1003), (1, 5, 3)],
+                         ids=["a_chunk_at_the_cell_s_keys", "ragged_tail", "fewer_keys_than_bits"])
+def test_packing_and_unpacking_give_the_mask_back(shape):
+    """``_pack`` and its inverse ``_slabs``: eight keys a byte within one row,
+    whole vregs of lanes a slab at the cell's 16,384 keys, a last slab cut
+    short (or none at all) where 8 does not divide the keys."""
+    mask = jax.random.bernoulli(jax.random.PRNGKey(shape[-1]), 0.3, shape)
+    packed = jax.jit(sparse_index._pack)(mask)
+    assert packed.dtype == jnp.uint8 and packed.shape == (*shape[:-1], -(-shape[-1] // 8))
+    assert jnp.array_equal(_unpacked(packed, shape[-1]) != 0, mask)
+
+
 def test_the_index_loss_and_its_gradient_are_the_reference_s():
     q, k, v, scorer, selection = _operands(64, 16)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, 2, axis=1)) * 16 ** -0.5
