@@ -112,6 +112,15 @@ checkpoint policy, one head and loss):
     the dispatch and one up-projection after the combine (scope
     ``moe_latent``; router and shared expert stay on the stream); and
     ``shared_dim``, the shared expert's own width.
+    ``block_diffusion=`` (SDAR-30B-A3B-Chat) states a second training
+    OBJECTIVE beside next-token prediction, ``block_diffusion_loss_fn``
+    (BD3-LM's): the step draws a noise level a block and a mask a position
+    from one integer a sequence in the batch (scope ``noise``), runs the
+    clean sequence and its noised copy as ONE stream of ``2 L`` rows whose
+    halves both count their rotary positions from 0, under a block-structured
+    mask that is a fourth mode of the flash kernels (ops/flash_attention.py),
+    and puts head and loss on the noised half alone, each masked position
+    weighted ``1 / t``, row ``i`` predicting token ``i``.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
@@ -152,7 +161,9 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     "ssm" or "mlp" layer through decode (the state-space layer's state and
     its convolution's last inputs are not cached) or the pipeline, an "ssm"
     layer over tp or sp, and a pattern of one-block layers behind a dense
-    prefix.
+    prefix; beside ``block_diffusion=``, any mixer but grouped-query
+    attention, a callable ``attention``, sp, decode (a step that yields a
+    block, not a token) and ``loss_fn`` unless ``next_token=True``.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -172,7 +183,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import (
-    RESIDUAL_NAMES, attention_reference, flash_attention,
+    RESIDUAL_NAMES, _block_sizes, attention_reference, block_diffusion_tile_counts,
+    flash_attention,
 )
 from ray_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as DELTA_RULE_RESIDUAL_NAMES,
@@ -221,7 +233,7 @@ LATENT_SCOPES = ("latent", "shared")
 # gates, the chunk preparation and the two scan kernels) and "gate_norm"
 # (the per-head RMSNorm and its SiLU gate).
 LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
-# Six names outside the four vocabularies, read by name
+# Names outside the four vocabularies, read by name
 # (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
 # (the chunk preparation under a decay per channel, opened in
 # ops/gated_delta_rule.py: forward, and backward through its custom VJP),
@@ -248,7 +260,10 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # kernels and the running sums in front of them, forward and backward, opened
 # in ops/ssd.py), and "moe_latent", inside "mlp" (a latent
 # mixture of experts' two projections, down before the dispatch and up after
-# the combine).
+# the combine). And the block-diffusion objective's "noise", inside "embed"
+# (``_block_diffusion_stream``: drawing ``t`` and ``m`` from the batch's
+# integer, ``xt``, the concatenation ``[x0 ; xt]`` and the repeated positions;
+# the flash calls under the block-diffusion mask stay under "attention").
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack. An
@@ -498,6 +513,29 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockDiffusionConfig:
+    """The block-diffusion TRAINING objective (BD3-LM, arXiv:2503.09573; SDAR,
+    arXiv:2510.06303) of ``block_diffusion_loss_fn``: a sequence of ``L``
+    tokens is cut into blocks of ``block_length``, each block draws one noise
+    level ``t ~ U(t_min, 1]`` and each of its tokens is replaced by
+    ``mask_token_id`` with probability ``t``; the clean sequence and the
+    noised one run as ONE stream of ``2 L`` rows under the block-diffusion
+    mask (ops/flash_attention.py), and the masked positions' cross-entropy is
+    weighted ``1 / t``."""
+
+    block_length: int = 4
+    mask_token_id: int = 0
+    t_min: float = 1e-3
+
+    def __post_init__(self):
+        if self.block_length < 1 or not 0.0 < self.t_min < 1.0:
+            raise ValueError(
+                f"block_diffusion: block_length {self.block_length} >= 1 and 0 < t_min "
+                f"{self.t_min} < 1"
+            )
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     dim: int = 4096
@@ -568,6 +606,9 @@ class TransformerConfig:
     # the keys it chooses. Without a ``layer_pattern`` every layer is then
     # sparse (``layer_kind``); a pattern may name the kind beside others.
     sparse: SparseAttentionConfig | None = None
+    # The block-diffusion training objective (``block_diffusion_loss_fn``):
+    # every layer is then grouped-query attention ("full", no ``latent``).
+    block_diffusion: BlockDiffusionConfig | None = None
     # The taps of a "conv" layer's gated short convolution (``conv_L_cache``).
     conv_kernel: int = 3
     # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
@@ -621,6 +662,8 @@ class TransformerConfig:
                     "hands on the stream alone, so a sparse layer there would lose its scorer's "
                     "term and its scorer would never train"
                 )
+        if self.block_diffusion is not None:
+            self._refuse_beside_block_diffusion()
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - {*LAYER_KINDS, MLP_KIND}
@@ -662,6 +705,27 @@ class TransformerConfig:
                     "to the value heads) is not written: num_key_heads "
                     f"{la.num_key_heads} != num_value_heads {la.num_value_heads}"
                 )
+
+    def _refuse_beside_block_diffusion(self) -> None:
+        """The block-diffusion mask is grouped-query attention's, in the flash
+        kernels and their oracle: every other mixer is refused by name."""
+        other = sorted(set(self._kinds() if self.layer_pattern else ()) - {"full", MLP_KIND})
+        if self.sparse is not None:
+            other.append("sparse")
+        if self.latent is not None:
+            other.append("latent")
+        if other:
+            raise NotImplementedError(
+                f"block_diffusion= beside a {' / '.join(other)} mixer is not written: the "
+                "block-diffusion mask is grouped-query attention's (\"full\" layers without "
+                "latent=); a recurrence, a convolution, a window or a selection has no clean "
+                "and noised half"
+            )
+        if callable(self.attention):
+            raise NotImplementedError(
+                "block_diffusion= under a callable attention= (ring, ulysses) is not written: "
+                "the mask is the flash kernels' (attention='flash' | 'reference')"
+            )
 
     def _kinds(self) -> tuple[str, ...]:
         """The kinds of mixer a patterned model holds, the prefix's first."""
@@ -1156,9 +1220,12 @@ def _head_shards() -> int:
     return _shards("heads")
 
 
-def _flash_over_mesh(q, k, v, causal, window=None):
+def _flash_over_mesh(q, k, v, causal, window=None, block_diffusion=None):
     """The flash kernels, on each device's own [batch, heads] block under a
-    mesh: attention needs nothing from another batch row or head. K and V
+    mesh: attention needs nothing from another batch row or head (under
+    ``block_diffusion`` the mask is that one's and ``causal`` is not read; a
+    mesh that cuts the sequence is refused: a row's keys lie in both halves
+    of the stream). K and V
     come with their own heads (``n_kv_heads``: the kernels' index maps pick a
     query head's group) and are cut over ``tp`` as q is, a shard's KV heads
     being its query heads' groups (8 over tp 2: 4 a shard beside 48 of 96
@@ -1168,21 +1235,34 @@ def _flash_over_mesh(q, k, v, causal, window=None):
     shards = _head_shards()
     repeats = shards // math.gcd(k.shape[1], shards)
     k, v = _repeat_kv(k, repeats), _repeat_kv(v, repeats)
-    kernel = functools.partial(flash_attention, causal=causal, window=window)
-    return _over_mesh(kernel, (block, block, block), block, (), ())(q, k, v)
+    if block_diffusion is None:
+        kernel = functools.partial(flash_attention, causal=causal, window=window)
+        return _over_mesh(kernel, (block, block, block), block, (), ())(q, k, v)
+    kernel = functools.partial(flash_attention, causal=False, block_diffusion=block_diffusion)
+    return _over_mesh(
+        kernel, (block, block, block), block, ("sp",), (),
+        what="block_diffusion over a mesh with {axis} > 1 is not written: a row of the doubled "
+        "stream sees keys of the clean half and of the noised half, so a shard of the sequence "
+        "holds a part of every row's keys (dp, fsdp and tp by heads work)",
+    )(q, k, v)
 
 
-def _attention_impl(config: TransformerConfig, window: int | None = None) -> Callable:
+def _attention_impl(config: TransformerConfig, window: int | None = None,
+                    block_diffusion: tuple[int, int] | None = None) -> Callable:
     """``attend(q, k, v, causal)`` as ``config.attention`` says, K and V with
     ``n_kv_heads``; with ``window``, a window layer's (a callable is refused
     when the config is made: the window is the flash kernels' and their
-    oracle's). The flash kernels take the grouped heads as they are; the
-    oracle and a callable (ring, ulysses) are handed K and V repeated to the
-    query heads, the contract they have."""
+    oracle's); with ``block_diffusion`` ``(clean_len, block)``, the doubled
+    stream's mask in place of the causal one. The flash kernels take the
+    grouped heads as they are; the oracle and a callable (ring, ulysses) are
+    handed K and V repeated to the query heads, the contract they have."""
     if config.attention == "flash":
-        return functools.partial(_flash_over_mesh, window=window)
+        return functools.partial(_flash_over_mesh, window=window, block_diffusion=block_diffusion)
     if callable(config.attention):
         attend = config.attention
+    elif block_diffusion is not None:
+        attend = lambda q, k, v, causal: attention_reference(
+            q, k, v, causal=False, block_diffusion=block_diffusion)
     else:
         attend = lambda q, k, v, causal: attention_reference(q, k, v, causal=causal, window=window)
 
@@ -2649,16 +2729,19 @@ def forward_with_routing(
     return _head(params, x, config), routing
 
 
-def _hidden_with_routing(params, tokens, config, positions=None, selections=False):
+def _hidden_with_routing(params, tokens, config, positions=None, selections=False,
+                         block_diffusion=None):
     """The last layer's output ``[batch, seq, hidden]``, before the final
     norm, and the expert layers' stacked ``routing`` that ``loss_fn``'s
     balancing loss reads, with the sparse layers' ``index_loss`` (0 of a
     layer of another kind) and, with ``selections``, their ``selection``. A
     dense prefix (``params["dense_layers"]``) is scanned first, under the
-    same checkpoint policy."""
+    same checkpoint policy. ``block_diffusion`` ``(clean_len, block)``:
+    ``tokens`` are the doubled stream ``[x0 ; xt]`` and every attention layer
+    runs under that mask (``_block_diffusion_stream`` makes the three)."""
     if selections and (config.sparse is None or config.layer_pattern):
         raise NotImplementedError("selections=True reads an unpatterned sparse model's layers")
-    attention_fn = _attention_impl(config)
+    attention_fn = _attention_impl(config, block_diffusion=block_diffusion)
     cos_sin = _rope_tables(config)
     x = _embed(params, tokens)
 
@@ -2829,9 +2912,12 @@ _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 def head_loss(
     params: dict, x: jax.Array, targets: jax.Array, config: TransformerConfig,
-    mask: jax.Array | None = None,
+    mask: jax.Array | None = None, weights: jax.Array | None = None,
 ) -> jax.Array:
-    """``logits_loss(_head(params, x, config), targets, mask)`` for training:
+    """``logits_loss(_head(params, x, config), targets, mask)`` for training
+    (or, under ``weights`` ``[batch, seq]``, ``sum(weights * nll)`` as they
+    are given: an objective that weighs its positions itself; ``mask`` and
+    ``weights`` exclude each other):
     final norm, lm_head, cross-entropy and the gradient of the logits as
     ONE function, walked along the sequence in chunks (``_head_chunks``).
 
@@ -2843,7 +2929,11 @@ def head_loss(
     The backward is then two matmuls and the norm's: nothing reads a
     ``[batch, seq, vocab]`` array again to derive the softmax a second and
     a third time, and no float32 array of that size exists."""
-    if mask is None:
+    if weights is not None:
+        if mask is not None:
+            raise ValueError("head_loss: mask= (a mean over its positions) or weights= (as given), one")
+        weights = weights.astype(jnp.float32)
+    elif mask is None:
         weights = jnp.full(targets.shape, 1.0 / targets.size, jnp.float32)
     else:
         weights = mask.astype(jnp.float32) / jnp.maximum(jnp.sum(mask), 1.0)
@@ -2854,8 +2944,17 @@ def head_loss(
 
 def loss_fn(
     params: dict, tokens: jax.Array, targets: jax.Array, config: TransformerConfig,
-    mask: jax.Array | None = None,
+    mask: jax.Array | None = None, next_token: bool = False,
 ) -> jax.Array:
+    """Next-token cross-entropy under the causal mask. A ``block_diffusion``
+    config trains through ``block_diffusion_loss_fn``; its causal next-token
+    loss is computed only where ``next_token=True`` asks for it by name."""
+    if config.block_diffusion is not None and not next_token:
+        raise ValueError(
+            "loss_fn is the next-token objective and this config states block_diffusion=: "
+            "train it through block_diffusion_loss_fn(params, tokens, noise, config), or ask "
+            "for the causal loss explicitly with next_token=True"
+        )
     x, routing = _hidden_with_routing(params, tokens, config)
     loss = head_loss(params, x, targets, config, mask)
     if config.moe and config.moe.aux_loss_coef:
@@ -2864,6 +2963,107 @@ def loss_fn(
     if config.sparse is not None:
         with jax.named_scope("loss"):
             loss = loss + jnp.sum(routing["index_loss"])
+    return loss
+
+
+def _block_diffusion_of(config: TransformerConfig, what: str) -> BlockDiffusionConfig:
+    if config.block_diffusion is None:
+        raise ValueError(f"{what} needs a config with block_diffusion=")
+    return config.block_diffusion
+
+
+def block_diffusion_noise(
+    tokens: jax.Array, noise: jax.Array, config: TransformerConfig,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``(xt, m, t)`` of ``tokens`` ``[batch, L]`` from ``noise`` ``int32
+    [batch]`` and nothing else: a sequence's integer is its PRNG key; each of
+    its ``L / block_length`` blocks draws one ``t ~ U(t_min, 1]``, each
+    position is masked with probability its block's ``t`` (``m``, bool), and
+    ``xt`` holds ``mask_token_id`` there. ``t`` comes back a position,
+    float32 ``[batch, L]``."""
+    bd = _block_diffusion_of(config, "block_diffusion_noise")
+    length = tokens.shape[1]
+    if length % bd.block_length:
+        raise ValueError(f"block_diffusion: {length} positions are no multiple of block_length {bd.block_length}")
+
+    def one(seed):
+        by_block, by_position = jax.random.split(jax.random.PRNGKey(seed))
+        # uniform is [0, 1): 1 - u is (0, 1]
+        level = 1.0 - jax.random.uniform(by_block, (length // bd.block_length,), jnp.float32)
+        t = jnp.repeat(bd.t_min + (1.0 - bd.t_min) * level, bd.block_length)
+        return t, jax.random.uniform(by_position, (length,), jnp.float32) < t
+
+    t, m = jax.vmap(one)(noise)
+    return jnp.where(m, jnp.asarray(bd.mask_token_id, tokens.dtype), tokens), m, t
+
+
+def block_diffusion_pairs(config: TransformerConfig, length: int) -> dict:
+    """What the flash kernels walk under the block-diffusion mask at ``length``
+    trained positions, a head: ``block_diffusion_tile_counts`` at the block
+    shape the kernels pick from the model's shapes (static)."""
+    bd = _block_diffusion_of(config, "block_diffusion_pairs")
+    tile = _block_sizes(2 * length, 2 * length, None, None, config.head_dim, config.dtype)
+    return block_diffusion_tile_counts(length, bd.block_length, *tile)
+
+
+def _block_diffusion_stream(tokens, noise, config):
+    """``(ids, positions, mask mode, m, t)``: the doubled stream ``[x0 ; xt]``
+    ``[batch, 2 L]``, its rotary positions (both halves count ``0 .. L - 1``)
+    and the flash kernels' ``(clean_len, block)``."""
+    bd = _block_diffusion_of(config, "the block-diffusion objective")
+    batch, length = tokens.shape
+    with jax.named_scope("embed"), jax.named_scope("noise"):
+        xt, m, t = block_diffusion_noise(tokens, noise, config)
+        ids = jnp.concatenate([tokens, xt], axis=1)
+        positions = jnp.broadcast_to(
+            jnp.tile(jnp.arange(length, dtype=jnp.int32), 2)[None], (batch, 2 * length))
+    return ids, positions, (length, bd.block_length), m, t
+
+
+def block_diffusion_forward(
+    params: dict, tokens: jax.Array, noise: jax.Array, config: TransformerConfig,
+) -> tuple[jax.Array, dict | None, dict]:
+    """``(logits, routing, drawn)``: the NOISED half's logits ``[batch, L,
+    vocab]`` (float32; row ``i`` predicts token ``i``: no shift) of the
+    doubled stream, the expert layers' stacked ``routing`` over its ``2 L``
+    rows, and what the step drew: ``{"xt", "m", "t"}``. What a reference check
+    reads; training goes through ``block_diffusion_loss_fn``."""
+    ids, positions, mode, m, t = _block_diffusion_stream(tokens, noise, config)
+    x, routing = _hidden_with_routing(params, ids, config, positions, block_diffusion=mode)
+    return _head(params, x[:, tokens.shape[1]:], config), routing, {"xt": ids[:, tokens.shape[1]:], "m": m, "t": t}
+
+
+def _block_diffusion_weights(m, t, mask):
+    """A position's weight in the objective: ``m / t`` over the count of the
+    positions that count (all of them; under ``mask``, its own)."""
+    if mask is None:
+        return m.astype(jnp.float32) / (t * m.size)
+    mask = mask.astype(jnp.float32)
+    return m * mask / (t * jnp.maximum(jnp.sum(mask), 1.0))
+
+
+def block_diffusion_loss_fn(
+    params: dict, tokens: jax.Array, noise: jax.Array, config: TransformerConfig,
+    mask: jax.Array | None = None,
+) -> jax.Array:
+    """The block-diffusion objective of ``tokens`` ``[batch, L]`` under the
+    noise drawn from ``noise`` ``int32 [batch]``: ``mean over sequences of (1
+    / L) sum_i m[i] / t[i] * CE(logits[i], tokens[i])``, the logits those of
+    the noised half of ONE pass over ``[x0 ; xt]`` (``_block_diffusion_stream``)
+    under the block-diffusion mask; head and loss run on the noised half
+    alone, float32 statistics, no shift. ``mask`` ``[batch, L]``, as
+    ``loss_fn``'s: the positions that count (a prompt's do not), the mean
+    then over those. The experts' balancing term, where the config has one,
+    is over the stream's ``2 L`` rows."""
+    length = tokens.shape[1]
+    ids, positions, mode, m, t = _block_diffusion_stream(tokens, noise, config)
+    x, routing = _hidden_with_routing(params, ids, config, positions, block_diffusion=mode)
+    with jax.named_scope("loss"):
+        weights = _block_diffusion_weights(m, t, mask)
+    loss = head_loss(params, x[:, length:], tokens, config, weights=weights)
+    if config.moe and config.moe.aux_loss_coef:
+        with jax.named_scope("loss"):
+            loss = loss + config.moe.aux_loss_coef * load_balancing_loss(routing, config.moe)
     return loss
 
 
@@ -3007,6 +3207,13 @@ def stage_forward(
 # KV-cache decode (serving path)
 # ---------------------------------------------------------------------------
 def _refuse_latent_cache(config: TransformerConfig) -> None:
+    if config.block_diffusion is not None:
+        raise NotImplementedError(
+            "decode of a block_diffusion= config is not written: a block-diffusion decode "
+            "denoises a block of block_length positions over several passes against a cache of "
+            "the clean blocks, a step that yields a block and not a token (init_kv_cache / "
+            "decode_step are next-token)"
+        )
     if config.sparse is not None:
         raise NotImplementedError(
             "decode over a sparse layer needs the index keys cached beside K and V (one "

@@ -48,6 +48,23 @@ walks 80 grid steps a head where a global layer walks 256 (a skipped step
 costs 0.42 us a kernel on a v5e: PERF.md section 6, PR 45). ``window=None``
 is the program it was before there was a window.
 
+``block_diffusion=(clean_len, block)`` is a fourth mask, structural and NOT
+causal (block-diffusion training, BD3-LM: a clean copy of a sequence of
+``clean_len`` positions and behind it a noised copy, ``2 x clean_len`` rows,
+in blocks of ``block`` positions). A clean query sees the clean keys of its
+own block and of earlier ones (so up to ``block - 1`` keys AFTER itself); a
+noised query the clean keys of EARLIER blocks and the noised keys of its own
+block, both directions; a clean query never a noised key
+(``block_diffusion_visible``). The mask is computed in the kernels from the
+tile's indices like the causal one; WHICH tiles run is a static table
+(``_block_diffusion_schedule``: a q row's needed kv tiles in order, the
+dkv kernel's q tiles a kv row), prefetched as scalars, that the kernels and
+the index maps read: a step past a row's count is skipped and names the
+row's last needed tile, already resident, and the grid's sequential axis is
+the longest row of needed tiles. At 8,192 clean positions and 1024-tiles:
+80 tiles a head (rows of 1 .. 8 and 2 .. 9) where a causal 16,384 runs 136.
+The causal, window and selection programs are what they were before it.
+
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
 ``flash_attention`` resolves ``interpret`` once (ops.resolve_interpret); the
@@ -120,6 +137,145 @@ def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
         for kv_index in range(seq_k // block_k)
     )
     return {"skipped": tiles - executed, "executed": executed}
+
+
+def block_diffusion_visible(q_pos, k_pos, clean_len, block):
+    """Whether row ``q_pos`` sees row ``k_pos`` of a block-diffusion stream
+    (``[clean ; noised]``, ``clean_len`` positions each, blocks of ``block``):
+    the definition, for numpy and jax integer ARRAYS alike (they broadcast:
+    the kernels hand a column of rows and a row of keys). ``pos = i mod
+    clean_len``, ``blk = pos // block``: clean -> clean ``blk(k) <= blk(q)``;
+    noised -> clean ``blk(k) < blk(q)``; noised -> noised ``blk(k) == blk(q)``;
+    clean -> noised never. Two comparisons a pair: a key's code is its block,
+    a noised key's beyond every clean one; a row sees the codes up to its last
+    clean block and the one code of its own noised block."""
+    blocks = clean_len // block
+    if block & (block - 1):
+        block_of = lambda pos: pos // block
+    else:   # a shift: Mosaic has no cheap vector division
+        block_of = lambda pos: pos >> (block.bit_length() - 1)
+    q_noised = (q_pos >= clean_len) * 1
+    k_noised = (k_pos >= clean_len) * 1
+    q_block = block_of(q_pos - q_noised * clean_len)
+    k_code = block_of(k_pos - k_noised * clean_len) + k_noised * blocks
+    last_clean = q_block - q_noised
+    own_noised = q_noised * (blocks + q_block + 1) - 1       # clean rows: -1, no key's code
+    return (k_code <= last_clean) | (k_code == own_noised)
+
+
+def _block_diffusion_tiles(clean_len, block, block_q, block_k):
+    """``[q tiles, kv tiles]`` of bool: whether a tile holds one allowed pair,
+    in closed form from the tile's first and last rows (no ``[2L, 2L]`` array
+    at any size; tests hold it to the enumeration). A tile may straddle the
+    clean / noised boundary where a block does not divide ``clean_len``."""
+    q0 = np.arange(2 * clean_len // block_q)[:, None] * block_q
+    k0 = np.arange(2 * clean_len // block_k)[None, :] * block_k
+    q1, k1 = q0 + block_q - 1, k0 + block_k - 1
+    last = clean_len - 1
+    clean_rows, noised_rows = q0 <= last, q1 > last
+    clean_keys, noised_keys = k0 <= last, k1 > last
+    first_clean_key = k0 // block
+    # clean rows: the last of them sees the most clean keys
+    clean_clean = clean_rows & clean_keys & (first_clean_key <= np.minimum(q1, last) // block)
+    noised_clean = noised_rows & clean_keys & (first_clean_key < (q1 - clean_len) // block)
+    # noised rows and noised keys: the two ranges of blocks meet
+    rows = (np.maximum(q0, clean_len) - clean_len) // block, (q1 - clean_len) // block
+    keys = (np.maximum(k0, clean_len) - clean_len) // block, (k1 - clean_len) // block
+    noised_noised = noised_rows & noised_keys & (rows[0] <= keys[1]) & (keys[0] <= rows[1])
+    return clean_clean | noised_clean | noised_noised
+
+
+def block_diffusion_tile_counts(clean_len, block, block_q, block_k):
+    """``causal_tile_counts`` for the block-diffusion mask, per head instance,
+    and the pairs: ``allowed_pairs`` the mask lets through (``L^2 + L B``),
+    ``executed_pairs`` the executed tiles compute."""
+    needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
+    executed = int(needed.sum())
+    return {
+        "skipped": needed.size - executed, "executed": executed,
+        "allowed_pairs": clean_len * clean_len + clean_len * block,
+        "executed_pairs": executed * block_q * block_k,
+    }
+
+
+def _block_diffusion_schedule(clean_len, block, block_q, block_k):
+    """The kernels' walk under the block-diffusion mask, ``{"kv": .., "q":
+    ..}``: ``(tiles, counts, steps)`` each. ``"kv"`` (fwd and dq): q row
+    ``j``'s needed kv tiles in order at ``tiles[j * steps : (j + 1) * steps]``,
+    ``counts[j]`` of them, the rest repeating the last (a skipped step names
+    the tile already resident: no DMA); ``steps`` the longest row. ``"q"``
+    (dkv): the same by kv row, of q tiles."""
+    needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
+
+    def walk(rows):
+        counts = rows.sum(axis=1)
+        steps = int(counts.max())
+        tiles = np.empty((rows.shape[0], steps), np.int32)
+        for row, wanted in enumerate(rows):
+            found = np.flatnonzero(wanted)
+            tiles[row, :found.size] = found
+            tiles[row, found.size:] = found[-1]
+        return tiles.reshape(-1), counts.astype(np.int32), steps
+
+    return {"kv": walk(needed), "q": walk(needed.T)}
+
+
+def _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion):
+    """``(schedules, steps)`` by sequential axis (``"kv"``: fwd and dq;
+    ``"q"``: dkv): the arrays a call prefetches (none for the causal and
+    window bands, whose tiles the kernels compute) and the axis' extent."""
+    if block_diffusion is None:
+        return {"kv": (), "q": ()}, band_steps(seq_q, seq_k, block_q, block_k, window)
+    walks = _block_diffusion_schedule(*block_diffusion, block_q, block_k)
+    return ({axis: walk[:2] for axis, walk in walks.items()},
+            {axis: walk[2] for axis, walk in walks.items()})
+
+
+def _scheduled_tile(schedule, row, step, num_steps):
+    """The tile of grid step ``step`` of ``row`` from a prefetched
+    ``_block_diffusion_schedule`` walk ``(tiles, counts)``: kernels and index
+    maps read the same array. Needed while ``step < counts[row]``."""
+    return schedule[0][row * num_steps + step]
+
+
+def _scheduled_kv_map(num_steps, group=1):
+    """``_kv_index_map`` under a schedule: the K / V tile of grid step (i, j,
+    step) is the table's, which repeats a row's last needed tile behind it."""
+    def index_map(i, j, step, *schedule):
+        return (i if group == 1 else i // group, _scheduled_tile(schedule, j, step, num_steps), 0)
+
+    return index_map
+
+
+def _scheduled_q_map(num_steps, group=1):
+    """``_q_index_map`` under a schedule: grid step (i, j, [g,] step) of the
+    dkv kernel reads query row ``i * group + g`` at the table's q tile."""
+    def index_map(i, j, *rest):
+        g_step, schedule = rest[:-2], rest[-2:]
+        tile = _scheduled_tile(schedule, j, g_step[-1], num_steps)
+        return (i if group == 1 else i * group + g_step[0], tile, 0)
+
+    return index_map
+
+
+def _grid(schedule, *, grid, in_specs, out_specs, scratch_shapes):
+    """``pallas_call``'s grid arguments: as they are, or with the
+    ``schedule``'s arrays prefetched as scalars ahead of the operands."""
+    spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
+    if not schedule:
+        return spec
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"grid_spec": pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(schedule), **spec)}
+
+
+def _scheduled(kernel):
+    """``kernel`` for a call whose two schedule arrays are prefetched: they
+    come ahead of every other ref."""
+    def with_schedule(tiles, counts, *refs, **static):
+        return kernel(*refs, schedule=(tiles, counts), **static)
+
+    return with_schedule
 
 
 def _first_kv_block(causal_offset, q_index, block_q, block_k, num_kv_blocks,
@@ -235,10 +391,10 @@ def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
                    block_q, block_k, precision, causal_offset, window=None,
-                   sel_ref=None):
+                   sel_ref=None, block_diffusion=None):
     """scale * Q K^T with the causal mask (and the window's lower edge, and
-    the ``selection``'s tile) applied — shared by all three kernels so
-    forward and backward can never desynchronize."""
+    the ``selection``'s tile) or the block-diffusion mask applied — shared
+    by all three kernels so forward and backward can never desynchronize."""
     q = _mxu(q_ref[0], precision)                # [block_q, d]
     k = _mxu(k_ref[0], precision)                # [block_k, d]
     s = jax.lax.dot_general(
@@ -264,6 +420,11 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
         s = jnp.where(visible, s, _NEG_INF)
     elif sel_ref is not None:
         s = jnp.where(_chosen(sel_ref), s, _NEG_INF)
+    elif block_diffusion is not None:
+        # a column of the tile's rows against a row of its keys
+        q_pos = q_index * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+        k_pos = kv_index * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+        s = jnp.where(block_diffusion_visible(q_pos, k_pos, *block_diffusion), s, _NEG_INF)
     return s, q, k
 
 
@@ -285,12 +446,15 @@ def _selected(kernel, operands: int):
 def _flash_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
     causal, block_q, block_k, num_kv_blocks, precision, causal_offset, window,
-    num_steps, sel_ref=None
+    num_steps, sel_ref=None, block_diffusion=None, schedule=None
 ):
     step = pl.program_id(2)
     q_index = pl.program_id(1)
-    kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
-                              num_kv_blocks, window)
+    if schedule is None:
+        kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
+                                  num_kv_blocks, window)
+    else:
+        kv_index = _scheduled_tile(schedule, q_index, step, num_steps)
 
     @pl.when(step == 0)
     def _init():
@@ -299,8 +463,11 @@ def _flash_fwd_kernel(
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # Entirely-masked tiles contribute nothing: skip their compute.
-    needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k, window)
+    if schedule is None:
+        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
+                              block_q, block_k, window)
+    else:
+        needed = step < schedule[1][q_index]
 
     @pl.when(needed)
     def _compute():
@@ -308,6 +475,7 @@ def _flash_fwd_kernel(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
+            block_diffusion=block_diffusion,
         )
 
         # Running max and sum are kept replicated across a vreg's lanes:
@@ -342,19 +510,25 @@ def _flash_fwd_kernel(
 def _flash_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
     scale, causal, block_q, block_k, num_kv_blocks, precision, causal_offset,
-    window, num_steps, sel_ref=None
+    window, num_steps, sel_ref=None, block_diffusion=None, schedule=None
 ):
     step = pl.program_id(2)
     q_index = pl.program_id(1)
-    kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
-                              num_kv_blocks, window)
+    if schedule is None:
+        kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
+                                  num_kv_blocks, window)
+    else:
+        kv_index = _scheduled_tile(schedule, q_index, step, num_steps)
 
     @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k, window)
+    if schedule is None:
+        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
+                              block_q, block_k, window)
+    else:
+        needed = step < schedule[1][q_index]
 
     @pl.when(needed)
     def _compute():
@@ -362,6 +536,7 @@ def _flash_dq_kernel(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
+            block_diffusion=block_diffusion,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)                     # [block_q, block_k] f32
@@ -387,15 +562,19 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_q_blocks,
-    precision, causal_offset, window, num_steps, group, sel_ref=None
+    precision, causal_offset, window, num_steps, group, sel_ref=None,
+    block_diffusion=None, schedule=None
 ):
     # One output row a KV head: the scratch sums its ``group`` query heads'
     # steps in float32 (grid axes 2 and 3, both sequential; the K / V tiles
     # stay resident across them). group == 1: no such axis.
     step = pl.program_id(2 if group == 1 else 3)
     kv_index = pl.program_id(1)
-    q_index = _band_q_index(step, kv_index, causal_offset, block_q, block_k,
-                            num_q_blocks, window)
+    if schedule is None:
+        q_index = _band_q_index(step, kv_index, causal_offset, block_q, block_k,
+                                num_q_blocks, window)
+    else:
+        q_index = _scheduled_tile(schedule, kv_index, step, num_steps)
 
     first = step == 0
     if group > 1:
@@ -406,10 +585,13 @@ def _flash_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                          block_q, block_k, window)
-    if window is not None:
-        needed &= q_index < num_q_blocks     # a step past the last q block
+    if schedule is not None:
+        needed = step < schedule[1][kv_index]
+    else:
+        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
+                              block_q, block_k, window)
+        if window is not None:
+            needed &= q_index < num_q_blocks     # a step past the last q block
 
     @pl.when(needed)
     def _compute():
@@ -417,6 +599,7 @@ def _flash_dkv_kernel(
             q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
+            block_diffusion=block_diffusion,
         )
         lse = lse_ref[0]
         p = jnp.exp(s - lse)
@@ -462,6 +645,7 @@ def flash_attention(
     window: int | None = None,
     selection: jax.Array | None = None,
     return_lse: bool = False,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array | tuple[jax.Array, jax.Array]:
     """q: [batch, heads, seq_q, head_dim]; k: [batch, kv_heads, seq_k,
     head_dim]; v: [batch, kv_heads, seq_k, v_dim], ``kv_heads`` dividing
@@ -484,11 +668,27 @@ def flash_attention(
     precision=None keeps the MXU's fast bf16 multiply for bf16 inputs;
     tests pass Precision.HIGHEST for tight reference comparison.
 
+    The mask is one of four modes. ``causal`` alone; ``causal`` with a
+    ``window`` (which needs ``causal=True``); a ``selection``, with or without
+    ``causal`` and ``window``; or ``block_diffusion``, which excludes the
+    three others (``causal=False``, no ``window``, no ``selection``).
+    ``causal=False`` with none of them is no mask at all.
+
     window: None (every key up to the query's own), or how many keys a
     query sees, its own position counted: query i sees ``i - window < j <=
     i`` (positions aligned to the END of the keys where ``seq_q < seq_k``,
     the decode convention). Static; the tiles wholly outside the band are
     neither computed nor fetched. Needs ``causal=True``.
+
+    block_diffusion: None, or ``(clean_len, block)``, static: the operands
+    are ``2 x clean_len`` rows, a clean copy of a sequence and behind it a
+    noised one, in blocks of ``block`` positions, and a row sees what
+    ``block_diffusion_visible`` says (clean rows their own and earlier clean
+    blocks; noised rows earlier clean blocks and their own noised block).
+    The mask is made in the kernels from the tile's indices; tiles without
+    an allowed pair are neither computed nor fetched
+    (``block_diffusion_tile_counts``). ``clean_len`` is a multiple of
+    ``block`` and ``seq_q == seq_k == 2 * clean_len``.
 
     selection: None, or a mask that is DATA: int8 ``[batch, seq_q, seq_k]``,
     nonzero where the query may see the key, one set of keys a query for all
@@ -507,8 +707,25 @@ def flash_attention(
     if window is not None and (not causal or window < 1):
         raise ValueError(
             f"flash_attention: window={window!r} needs causal=True and window >= 1: the window "
-            "is the causal mask's lower edge"
+            "is the causal mask's lower edge (the four modes: causal; causal with window; "
+            "selection, beside either; block_diffusion, which excludes the three)"
         )
+    if block_diffusion is not None:
+        block_diffusion = tuple(int(size) for size in block_diffusion)
+        clean_len, block = block_diffusion
+        excluded = [name for name, given in (
+            ("causal", causal), ("window", window is not None), ("selection", selection is not None),
+        ) if given]
+        if excluded:
+            raise ValueError(
+                f"flash_attention: block_diffusion={block_diffusion} excludes {', '.join(excluded)}: "
+                "its mask is neither causal nor a window nor data (pass causal=False)"
+            )
+        if block < 1 or clean_len % block or not q.shape[2] == k.shape[2] == 2 * clean_len:
+            raise ValueError(
+                f"flash_attention: block_diffusion={block_diffusion} needs clean_len a multiple "
+                f"of block and seq_q == seq_k == 2 * clean_len, got {q.shape[2]} and {k.shape[2]}"
+            )
     if selection is not None and selection.shape != (q.shape[0], q.shape[2], k.shape[2]):
         raise ValueError(
             f"flash_attention: selection {selection.shape} is not [batch, seq_q, seq_k] "
@@ -518,27 +735,29 @@ def flash_attention(
         scale = q.shape[-1] ** -0.5
     out, lse = _flash_vjp(
         q, k, v, selection, causal, float(scale), block_q, block_k,
-        resolve_interpret(interpret), precision, window,
+        resolve_interpret(interpret), precision, window, block_diffusion,
     )
     return (out, lse) if return_lse else out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_vjp(q, k, v, selection, causal, scale, block_q, block_k, interpret,
-               precision, window):
+               precision, window, block_diffusion):
     """``(out, lse)`` under a ``selection`` (None, an empty pytree: none).
     ``lse`` is handed out detached: its cotangent is dropped."""
     return _flash_forward(
         q, k, v, selection, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, interpret=interpret, precision=precision, window=window,
+        block_diffusion=block_diffusion,
     )
 
 
 def _flash_vjp_fwd(q, k, v, selection, causal, scale, block_q, block_k,
-                   interpret, precision, window):
+                   interpret, precision, window, block_diffusion):
     out, lse = _flash_forward(
         q, k, v, selection, causal=causal, scale=scale, block_q=block_q,
         block_k=block_k, interpret=interpret, precision=precision, window=window,
+        block_diffusion=block_diffusion,
     )
     out = checkpoint_name(out, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
@@ -546,12 +765,12 @@ def _flash_vjp_fwd(q, k, v, selection, causal, scale, block_q, block_k,
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, precision,
-                   window, residuals, g):
+                   window, block_diffusion, residuals, g):
     q, k, v, selection, out, lse = residuals
     grads = _flash_backward(
         q, k, v, out, lse, g[0], selection, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        precision=precision, window=window,
+        precision=precision, window=window, block_diffusion=block_diffusion,
     )
     return (*grads, None)
 
@@ -610,7 +829,7 @@ def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype, selection=Fals
     jax.jit,
     static_argnames=(
         "causal", "scale", "block_q", "block_k", "interpret", "precision",
-        "window",
+        "window", "block_diffusion",
     ),
 )
 def _flash_forward(
@@ -626,6 +845,7 @@ def _flash_forward(
     interpret: bool,
     precision: jax.lax.Precision | None = None,
     window: int | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     batch, heads, seq_q, dim = q.shape
     _, kv_heads, seq_k, _ = k.shape
@@ -644,7 +864,8 @@ def _flash_forward(
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
-    kv_steps = band_steps(seq_q, seq_k, block_q, block_k, window)["kv"]
+    schedules, steps = _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion)
+    schedule, kv_steps = schedules["kv"], steps["kv"]
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -657,15 +878,22 @@ def _flash_forward(
         causal_offset=causal_offset,
         window=window,
         num_steps=kv_steps,
+        block_diffusion=block_diffusion,
     )
-    kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
-    )
+    if block_diffusion is None:
+        kv_map = _kv_index_map(
+            causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
+        )
+    else:
+        kernel = _scheduled(kernel)
+        kv_map = _scheduled_kv_map(kv_steps, group)
     from jax.experimental.pallas import tpu as pltpu
 
+    # under a schedule every index map is handed its two prefetched arrays too
+    q_row = lambda i, j, kv, *_: (i, j, 0)
     operands = [qr, kr, vr]
     in_specs = [
-        pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_q, dim), q_row),
         pl.BlockSpec((1, block_k, dim), kv_map),
         pl.BlockSpec((1, block_k, v_dim), kv_map),
     ]
@@ -679,23 +907,26 @@ def _flash_forward(
         ))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, num_q_blocks, kv_steps),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
-        ],
+        **_grid(
+            schedule,
+            grid=(bh, num_q_blocks, kv_steps),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_q, v_dim), q_row),
+                pl.BlockSpec((1, block_q, 1), q_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
+                pltpu.VMEM((block_q, v_dim), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, seq_q, v_dim), q.dtype),
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, v_dim), jnp.float32),  # output accumulator
-        ],
         interpret=interpret,
-    )(*operands)
+    )(*schedule, *operands)
     return out.reshape(batch, heads, seq_q, v_dim), lse.reshape(
         batch, heads, seq_q
     )
@@ -705,12 +936,12 @@ def _flash_forward(
     jax.jit,
     static_argnames=(
         "causal", "scale", "block_q", "block_k", "interpret", "precision",
-        "window",
+        "window", "block_diffusion",
     ),
 )
 def _flash_backward(
     q, k, v, out, lse, g, selection=None, *, causal, scale, block_q, block_k,
-    interpret, precision, window=None
+    interpret, precision, window=None, block_diffusion=None
 ):
     batch, heads, seq_q, dim = q.shape
     kv_heads, seq_k, v_dim = v.shape[1:]
@@ -736,7 +967,7 @@ def _flash_backward(
     num_q_blocks = seq_q // block_q
     num_kv_blocks = seq_k // block_k
     causal_offset = seq_k - seq_q
-    steps = band_steps(seq_q, seq_k, block_q, block_k, window)
+    schedules, steps = _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion)
 
     from jax.experimental.pallas import tpu as pltpu
 
@@ -745,19 +976,25 @@ def _flash_backward(
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         num_kv_blocks=num_kv_blocks, precision=precision,
         causal_offset=causal_offset, window=window, num_steps=steps["kv"],
+        block_diffusion=block_diffusion,
     )
-    kv_map = _kv_index_map(
-        causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
-    )
+    if block_diffusion is None:
+        kv_map = _kv_index_map(
+            causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
+        )
+    else:
+        dq_kernel = _scheduled(dq_kernel)
+        kv_map = _scheduled_kv_map(steps["kv"], group)
     operands = [qr, kr, vr, dor, lser, delta]
     selected = () if selection is None else (selection,)
+    q_row = lambda i, j, kv, *_: (i, j, 0)
     dq_specs = [
-        pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_q, dim), q_row),
         pl.BlockSpec((1, block_k, dim), kv_map),
         pl.BlockSpec((1, block_k, v_dim), kv_map),
-        pl.BlockSpec((1, block_q, v_dim), lambda i, j, kv: (i, j, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda i, j, kv: (i, j, 0)),
+        pl.BlockSpec((1, block_q, v_dim), q_row),
+        pl.BlockSpec((1, block_q, 1), q_row),
+        pl.BlockSpec((1, block_q, 1), q_row),
     ]
     if selection is not None:
         dq_kernel = _selected(dq_kernel, len(operands))
@@ -766,24 +1003,31 @@ def _flash_backward(
         ))
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(bh, num_q_blocks, steps["kv"]),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, dim), lambda i, j, kv: (i, j, 0)),
+        **_grid(
+            schedules["kv"],
+            grid=(bh, num_q_blocks, steps["kv"]),
+            in_specs=dq_specs,
+            out_specs=pl.BlockSpec((1, block_q, dim), q_row),
+            scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, dim), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         interpret=interpret,
-    )(*operands, *selected)
+    )(*schedules["kv"], *operands, *selected)
 
     dkv_kernel = functools.partial(
         _flash_dkv_kernel,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         num_q_blocks=num_q_blocks, precision=precision,
         causal_offset=causal_offset, window=window, num_steps=steps["q"],
-        group=group,
+        group=group, block_diffusion=block_diffusion,
     )
-    q_map = _q_index_map(
-        causal, causal_offset, block_q, block_k, num_q_blocks, window, group
-    )
+    if block_diffusion is None:
+        q_map = _q_index_map(
+            causal, causal_offset, block_q, block_k, num_q_blocks, window, group
+        )
+    else:
+        dkv_kernel = _scheduled(dkv_kernel)
+        q_map = _scheduled_q_map(steps["q"], group)
     kv_row = lambda i, j, *g_qi: (i, j, 0)
     dkv_specs = [
         pl.BlockSpec((1, block_q, dim), q_map),
@@ -801,23 +1045,26 @@ def _flash_backward(
         ))
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        # one K / V row a KV head; a group axis only where a group is
-        grid=(bkv, num_kv_blocks, *((group,) if group > 1 else ()), steps["q"]),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, dim), kv_row),
-            pl.BlockSpec((1, block_k, v_dim), kv_row),
-        ],
+        **_grid(
+            schedules["q"],
+            # one K / V row a KV head; a group axis only where a group is
+            grid=(bkv, num_kv_blocks, *((group,) if group > 1 else ()), steps["q"]),
+            in_specs=dkv_specs,
+            out_specs=[
+                pl.BlockSpec((1, block_k, dim), kv_row),
+                pl.BlockSpec((1, block_k, v_dim), kv_row),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, dim), jnp.float32),
+                pltpu.VMEM((block_k, v_dim), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bkv, seq_k, dim), k.dtype),
             jax.ShapeDtypeStruct((bkv, seq_k, v_dim), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dim), jnp.float32),
-            pltpu.VMEM((block_k, v_dim), jnp.float32),
-        ],
         interpret=interpret,
-    )(*operands, *selected)
+    )(*schedules["q"], *operands, *selected)
 
     return (
         dq.reshape(q.shape),
@@ -830,12 +1077,15 @@ def attention_reference(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     scale: float | None = None, window: int | None = None,
     selection: jax.Array | None = None, return_lse: bool = False,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array | tuple[jax.Array, jax.Array]:
     """Pure-jax reference used for kernel numerics tests; ``window`` as
     ``flash_attention``'s: the ``window`` keys up to the query's own;
     ``selection`` and ``return_lse`` as there: ``[batch, seq_q, seq_k]``,
     nonzero where the query may see the key, and the detached float32
-    log-sum-exp of the scores over the keys seen."""
+    log-sum-exp of the scores over the keys seen; ``block_diffusion`` as
+    there, ``(clean_len, block)``: the explicit ``[2L, 2L]`` mask, for small
+    sizes."""
     if window is not None and (not causal or window < 1):
         raise ValueError(f"attention_reference: window={window!r} needs causal=True and window >= 1")
     dim = q.shape[-1]
@@ -850,6 +1100,9 @@ def attention_reference(
         s = jnp.where(mask, s, _NEG_INF)
     if selection is not None:
         s = jnp.where(selection[:, None] != 0, s, _NEG_INF)
+    if block_diffusion is not None:
+        rows = np.arange(s.shape[-2])[:, None]
+        s = jnp.where(block_diffusion_visible(rows, rows.T, *block_diffusion), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
     if not return_lse:

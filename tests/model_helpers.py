@@ -182,3 +182,37 @@ def one_chip_step(topo, config, batch, seq):
     # the grouped matmul asks the same platform rule from its own module.
     with mock.patch.object(gm, "resolve_interpret", lambda _i: False):
         return described.compile_step(family, topo.devices, {"dp": 1}, batch, seq)[1]
+
+
+def flash_mosaic_modules(kv_heads=2, selection=False, **mode) -> list[str]:
+    """The three Mosaic modules of ``jax.grad(flash_attention)`` (fwd, dq,
+    dkv; q ``[1, 4, 256, 128]`` beside K / V at ``kv_heads``, bfloat16) under
+    ``mode`` (``window=``, ``causal=``, ``block_diffusion=``; ``selection``:
+    an int8 ``[1, 256, 256]`` operand), lowered for a TPU, parsed and printed
+    WITHOUT source locations: what a digest of the kernels is taken over."""
+    import base64
+    import json
+
+    import jax.numpy as jnp
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 4, 256, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, kv_heads, 256, 128), jnp.bfloat16)
+    chosen = (jax.ShapeDtypeStruct((1, 256, 256), jnp.int8),) if selection else ()
+    loss = lambda q, k, v, *chosen: flash_attention(
+        q, k, v, interpret=False, selection=chosen[0] if chosen else None, **mode
+    ).astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k, *chosen).lower(
+        lowering_platforms=("tpu",)).as_text()
+    modules = []
+    for config in re.findall(r'backend_config = "(\{.*?\})"', text):
+        body = json.loads(config.replace("\\22", '"'))["custom_call_config"]["body"]
+        context = jax_mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            modules.append(module.operation.get_asm(enable_debug_info=False))
+    return modules

@@ -14,12 +14,9 @@ gradients 2e-4 (``tests/test_ops.py``'s), logits 5e-4, loss 1e-5, gradients
 sums in another order). A wrong term is off by far more.
 """
 
-import base64
 import dataclasses
 import functools
 import hashlib
-import json
-import re
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +30,7 @@ from ray_tpu.ops import sparse_index
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention
 
 import model_helpers
-from model_helpers import close, listed, loss_and_grads
+from model_helpers import close, flash_mosaic_modules, listed, loss_and_grads
 
 SEQ, TOPK = 32, 8
 CONFIG = {
@@ -132,29 +129,8 @@ def test_the_masked_kernels_match_the_oracle(topk):
 MODULES_WITHOUT_A_SELECTION = "e2e62e8e6434f1e5a4e3118ddf94922cd1f7fb60e0b1c176110cf9ae81a6ed41"
 
 
-def _mosaic_modules(window=None, kv_heads=2):
-    from jax._src.interpreters import mlir as jax_mlir
-    from jax._src.lib.mlir import ir
-
-    q = jax.ShapeDtypeStruct((1, 4, 256, 128), jnp.bfloat16)
-    k = jax.ShapeDtypeStruct((1, kv_heads, 256, 128), jnp.bfloat16)
-    loss = lambda q, k, v: flash_attention(
-        q, k, v, interpret=False, window=window).astype(jnp.float32).sum()
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k).lower(
-        lowering_platforms=("tpu",)).as_text()
-    modules = []
-    for config in re.findall(r'backend_config = "(\{.*?\})"', text):
-        body = json.loads(config.replace("\\22", '"'))["custom_call_config"]["body"]
-        context = jax_mlir.make_ir_context()
-        context.allow_unregistered_dialects = True
-        with context:
-            module = ir.Module.parse(base64.b64decode(body))
-            modules.append(module.operation.get_asm(enable_debug_info=False))
-    return modules
-
-
 def test_without_a_selection_the_mosaic_modules_are_the_parents():
-    modules = _mosaic_modules() + _mosaic_modules(64) + _mosaic_modules(None, 4)
+    modules = flash_mosaic_modules() + flash_mosaic_modules(window=64) + flash_mosaic_modules(kv_heads=4)
     assert len(modules) == 9
     assert hashlib.sha256("\n".join(modules).encode()).hexdigest() == MODULES_WITHOUT_A_SELECTION
 
@@ -421,9 +397,23 @@ def test_the_term_trains_the_scorer_alone_and_the_output_never(params):
     assert moved(by_output[1]) and not moved(by_term[1])
 
 
-def test_the_shares_add_up(params):
+def _block_diffusion_family():
+    """SDAR's family at this file's sizes: the same backbone under another
+    objective (tests/test_block_diffusion.py), its expert layer cut the same way."""
+    from benchmarks.families import block_diffusion_moe_decoder
+
+    config = {k: v for k, v in CONFIG.items() if k != "sa_config"}
+    config.update(rope_scaling=None, block_length=4, mask_token_id=255, t_min=1e-3)
+    return block_diffusion_moe_decoder.build(config, TRAFFIC)
+
+
+@pytest.mark.parametrize("family", ["sparse_gqa_moe_decoder", "block_diffusion_moe_decoder"])
+def test_the_shares_add_up(params, family):
     """The parts of an expert layer that the two shares of its 8 experts
-    give add up to what the uncut reference gives for the whole layer."""
+    give add up to what the uncut reference gives for the whole layer, for
+    each configuration that holds a share of this backbone's experts (a
+    block-diffusion stream's rows are rows like any other to the experts)."""
+    MODEL = FAMILY.model if family == "sparse_gqa_moe_decoder" else _block_diffusion_family().model
     layer = jax.tree.map(lambda leaf: leaf[0], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(8), (2, SEQ, MODEL.dim))
     experts = {name: jax.random.normal(jax.random.PRNGKey(9 + i), (8, *layer[name].shape[1:])) * 0.1
