@@ -35,6 +35,24 @@ masked (``_tile_needed``) skip all compute, and fetch nothing either: the
 index maps clamp a skipped step to its row's nearest needed block, which is
 already resident, so Pallas issues no DMA for it (≈2× for causal training).
 
+A tile a mask CUTS is walked in sub-blocks (``_walk``): the tile stays what
+Pallas fetches (1024 x 1024: a grid step's fixed cost and the K / V re-reads
+are why it won), and inside it a sub-block (``_sub_block``: half the tile's
+side, in whole lane groups, so a tile under 256 a side is one block as
+before) is needed or not by the rule that decides a tile, at the sub-block's
+size. A sub-block the mask empties is skipped; one it crosses is masked at
+its own offsets; the forward carries a row group's running max, sum and
+accumulator from block to block inside a tile as it carries them across
+tiles. The walk goes by halves of the axis the kernel's accumulator holds
+(query rows in fwd and dq, key rows in dkv) and runs the other axis' needed
+halves as one block, so a causal diagonal tile is a 512 x 512 block and a 512
+x 1024 one, three quarters of a whole tile's pairs where it ran all of them
+for half. A tile whose four sub-blocks are all needed (an interior tile)
+keeps the one whole body: walked, it pays for partial sums it does not need
+(PERF.md section 6, PR 60, has the kernels alone, both forms and both
+widths). ``causal=False`` with no mask at all has nothing to skip and is one
+body.
+
 A sliding ``window`` (query i sees keys j with ``i - window < j <= i``, the
 query's own position counted) is the same mechanism with a second edge: a
 tile whose LAST key lies at or before its first query's ``i - window`` is
@@ -45,8 +63,11 @@ of 5), 44 % of the causal half's pairs. And the grid's sequential axis is
 as long as the band's longest row (``band_steps``: 5 steps there, not 16):
 step ``s`` of a row is its first needed block + ``s``, so a window layer
 walks 80 grid steps a head where a global layer walks 256 (a skipped step
-costs 0.42 us a kernel on a v5e: PERF.md section 6, PR 45). ``window=None``
-is the program it was before there was a window.
+costs 0.42 us a kernel on a v5e: PERF.md section 6, PR 45). The band's
+lower-edge tiles (12 of the 70, each half masked from the other corner) are
+walked like the diagonal ones and cost three quarters of a tile each: 63
+tiles' worth of sub-blocks for the band's 59.5 of pairs. ``window=None`` is
+the program it was before there was a window.
 
 ``block_diffusion=(clean_len, block)`` is a fourth mask, structural and NOT
 causal (block-diffusion training, BD3-LM: a clean copy of a sequence of
@@ -63,7 +84,13 @@ the index maps read: a step past a row's count is skipped and names the
 row's last needed tile, already resident, and the grid's sequential axis is
 the longest row of needed tiles. At 8,192 clean positions and 1024-tiles:
 80 tiles a head (rows of 1 .. 8 and 2 .. 9) where a causal 16,384 runs 136.
-The causal, window and selection programs are what they were before it.
+Which SUB-BLOCKS of a needed tile hold an allowed pair is a third prefetched
+array beside the tiles and the counts, a bit a sub-block a step, from the
+tiles' own closed form at the sub-block's size: the 16 half-masked diagonal
+tiles (clean rows, and noised rows on the clean keys) run three of their
+four, the 8 noised-diagonal tiles (a 4-wide block diagonal: 4,096 allowed
+pairs of 1,048,576) two, 72 tiles' worth for the 64.03 the mask allows
+(``block_diffusion_tile_counts``: ``executed_pairs``).
 
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
@@ -127,16 +154,33 @@ def _tile_needed(causal, causal_offset, q_index, kv_index, block_q, block_k,
     return needed & ((kv_index + 1) * block_k - 1 > first_query - window)
 
 
+def _sub_block(block):
+    """The side of the sub-blocks a tile of side ``block`` is walked in where
+    a mask cuts it (``_walk``): half the tile's, in whole lane groups (a
+    score sub-block is ``[rows, keys]``), so a tile under 256 a side, or one
+    whose half is no multiple of 128, is walked whole. Halves and not
+    quarters: on a v5e a 256 x 256 sub-block costs the three kernels 1.9 to
+    2.7 times its share of a tile (PERF.md section 6, PR 60)."""
+    half = block // 2
+    return half if half and block % 2 == 0 and half % _LANES == 0 else block
+
+
 def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
     """How many tiles of one causal call (per head instance) are executed
-    and how many skipped: a property of the shapes (and the window) alone."""
-    tiles = (seq_q // block_q) * (seq_k // block_k)
-    executed = sum(
-        bool(_tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window))
-        for q_index in range(seq_q // block_q)
-        for kv_index in range(seq_k // block_k)
-    )
-    return {"skipped": tiles - executed, "executed": executed}
+    and how many skipped, and the pairs the executed tiles compute
+    (``executed_pairs``: their SUB-BLOCKS that hold a visible pair, where a
+    tile is walked in them): a property of the shapes (and the window) alone."""
+    def executed(block_q, block_k):
+        return sum(
+            bool(_tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window))
+            for q_index in range(seq_q // block_q)
+            for kv_index in range(seq_k // block_k)
+        )
+
+    tiles, run = (seq_q // block_q) * (seq_k // block_k), executed(block_q, block_k)
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    return {"skipped": tiles - run, "executed": run,
+            "executed_pairs": executed(sub_q, sub_k) * sub_q * sub_k}
 
 
 def block_diffusion_visible(q_pos, k_pos, clean_len, block):
@@ -188,36 +232,49 @@ def _block_diffusion_tiles(clean_len, block, block_q, block_k):
 def block_diffusion_tile_counts(clean_len, block, block_q, block_k):
     """``causal_tile_counts`` for the block-diffusion mask, per head instance,
     and the pairs: ``allowed_pairs`` the mask lets through (``L^2 + L B``),
-    ``executed_pairs`` the executed tiles compute."""
+    ``executed_pairs`` the executed tiles compute: their sub-blocks that hold
+    an allowed pair, where a tile is walked in them."""
     needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
     executed = int(needed.sum())
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
     return {
         "skipped": needed.size - executed, "executed": executed,
         "allowed_pairs": clean_len * clean_len + clean_len * block,
-        "executed_pairs": executed * block_q * block_k,
+        "executed_pairs": int(_block_diffusion_tiles(clean_len, block, sub_q, sub_k).sum()) * sub_q * sub_k,
     }
 
 
 def _block_diffusion_schedule(clean_len, block, block_q, block_k):
     """The kernels' walk under the block-diffusion mask, ``{"kv": .., "q":
-    ..}``: ``(tiles, counts, steps)`` each. ``"kv"`` (fwd and dq): q row
+    ..}``: ``(tiles, counts, subs, steps)`` each. ``"kv"`` (fwd and dq): q row
     ``j``'s needed kv tiles in order at ``tiles[j * steps : (j + 1) * steps]``,
     ``counts[j]`` of them, the rest repeating the last (a skipped step names
-    the tile already resident: no DMA); ``steps`` the longest row. ``"q"``
-    (dkv): the same by kv row, of q tiles."""
+    the tile already resident: no DMA); ``steps`` the longest row. ``subs``:
+    beside each needed tile, which of its sub-blocks hold an allowed pair
+    (``_walk``): bit ``a * parts_k + b`` for q part ``a`` and kv part ``b``,
+    the tiles' own closed form at the sub-block's size. ``"q"`` (dkv): the
+    same by kv row, of q tiles."""
     needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    parts = (block_q // sub_q) * (block_k // sub_k)
+    bits = _block_diffusion_tiles(clean_len, block, sub_q, sub_k).reshape(
+        needed.shape[0], block_q // sub_q, needed.shape[1], block_k // sub_k
+    ).transpose(0, 2, 1, 3).reshape(*needed.shape, parts)
+    bits = (bits.astype(np.int32) << np.arange(parts, dtype=np.int32)).sum(axis=-1, dtype=np.int32)
 
-    def walk(rows):
+    def walk(rows, bits):
         counts = rows.sum(axis=1)
         steps = int(counts.max())
         tiles = np.empty((rows.shape[0], steps), np.int32)
+        subs = np.zeros((rows.shape[0], steps), np.int32)
         for row, wanted in enumerate(rows):
             found = np.flatnonzero(wanted)
             tiles[row, :found.size] = found
             tiles[row, found.size:] = found[-1]
-        return tiles.reshape(-1), counts.astype(np.int32), steps
+            subs[row, :found.size] = bits[row, found]
+        return tiles.reshape(-1), counts.astype(np.int32), subs.reshape(-1), steps
 
-    return {"kv": walk(needed), "q": walk(needed.T)}
+    return {"kv": walk(needed, bits), "q": walk(needed.T, bits.T)}
 
 
 def _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion):
@@ -227,14 +284,14 @@ def _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion):
     if block_diffusion is None:
         return {"kv": (), "q": ()}, band_steps(seq_q, seq_k, block_q, block_k, window)
     walks = _block_diffusion_schedule(*block_diffusion, block_q, block_k)
-    return ({axis: walk[:2] for axis, walk in walks.items()},
-            {axis: walk[2] for axis, walk in walks.items()})
+    return ({axis: walk[:3] for axis, walk in walks.items()},
+            {axis: walk[3] for axis, walk in walks.items()})
 
 
 def _scheduled_tile(schedule, row, step, num_steps):
     """The tile of grid step ``step`` of ``row`` from a prefetched
-    ``_block_diffusion_schedule`` walk ``(tiles, counts)``: kernels and index
-    maps read the same array. Needed while ``step < counts[row]``."""
+    ``_block_diffusion_schedule`` walk ``(tiles, counts, subs)``: kernels and
+    index maps read the same array. Needed while ``step < counts[row]``."""
     return schedule[0][row * num_steps + step]
 
 
@@ -251,7 +308,7 @@ def _scheduled_q_map(num_steps, group=1):
     """``_q_index_map`` under a schedule: grid step (i, j, [g,] step) of the
     dkv kernel reads query row ``i * group + g`` at the table's q tile."""
     def index_map(i, j, *rest):
-        g_step, schedule = rest[:-2], rest[-2:]
+        g_step, schedule = rest[:-3], rest[-3:]
         tile = _scheduled_tile(schedule, j, g_step[-1], num_steps)
         return (i if group == 1 else i * group + g_step[0], tile, 0)
 
@@ -270,10 +327,10 @@ def _grid(schedule, *, grid, in_specs, out_specs, scratch_shapes):
 
 
 def _scheduled(kernel):
-    """``kernel`` for a call whose two schedule arrays are prefetched: they
+    """``kernel`` for a call whose three schedule arrays are prefetched: they
     come ahead of every other ref."""
-    def with_schedule(tiles, counts, *refs, **static):
-        return kernel(*refs, schedule=(tiles, counts), **static)
+    def with_schedule(tiles, counts, subs, *refs, **static):
+        return kernel(*refs, schedule=(tiles, counts, subs), **static)
 
     return with_schedule
 
@@ -389,14 +446,17 @@ def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
     return index_map
 
 
-def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
+def _masked_scores(q_ref, k_ref, q_index, kv_index, rows, cols, *, scale, causal,
                    block_q, block_k, precision, causal_offset, window=None,
                    sel_ref=None, block_diffusion=None):
     """scale * Q K^T with the causal mask (and the window's lower edge, and
     the ``selection``'s tile) or the block-diffusion mask applied — shared
-    by all three kernels so forward and backward can never desynchronize."""
-    q = _mxu(q_ref[0], precision)                # [block_q, d]
-    k = _mxu(k_ref[0], precision)                # [block_k, d]
+    by all three kernels so forward and backward can never desynchronize.
+    One sub-block of a tile (``_walk``): ``rows`` of the q-side ref against
+    ``cols`` of the k-side ref, ``q_index`` / ``kv_index`` its indices at ITS
+    size ``block_q`` x ``block_k``, so the mask is made at its own offsets."""
+    q = _mxu(q_ref[0, rows, :], precision)       # [block_q, d]
+    k = _mxu(k_ref[0, cols, :], precision)       # [block_k, d]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32, precision=precision,
@@ -416,10 +476,10 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
         if window is not None:
             visible &= k_pos > q_pos - window
         if sel_ref is not None:
-            visible &= _chosen(sel_ref)
+            visible &= _chosen(sel_ref, rows, cols)
         s = jnp.where(visible, s, _NEG_INF)
     elif sel_ref is not None:
-        s = jnp.where(_chosen(sel_ref), s, _NEG_INF)
+        s = jnp.where(_chosen(sel_ref, rows, cols), s, _NEG_INF)
     elif block_diffusion is not None:
         # a column of the tile's rows against a row of its keys
         q_pos = q_index * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
@@ -428,10 +488,16 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, *, scale, causal,
     return s, q, k
 
 
-def _chosen(sel_ref):
-    """A selection tile ``[block_q, block_k]`` of int8 as a mask: widened
-    first, the comparison Mosaic takes on every generation."""
-    return sel_ref[0].astype(jnp.int32) != 0
+def _chosen(sel_ref, rows=slice(None), cols=slice(None)):
+    """A selection tile ``[block_q, block_k]`` of int8 (its ``rows`` and
+    ``cols``: whole lane groups) as a mask: widened first, the comparison
+    Mosaic takes on every generation. ``cols`` at a TRACED offset (``_walk``:
+    one half of the keys) is taken from the two static halves: a slice along
+    lanes starts where the program says, not where a scalar does."""
+    if isinstance(cols, slice) or isinstance(cols.start, int):
+        return sel_ref[0, rows, cols].astype(jnp.int32) != 0
+    tile = sel_ref[0, rows, :].astype(jnp.int32)
+    return jnp.where(cols.start == 0, tile[:, :cols.size], tile[:, cols.size:]) != 0
 
 
 def _selected(kernel, operands: int):
@@ -441,6 +507,92 @@ def _selected(kernel, operands: int):
         return kernel(*refs[:operands], *refs[operands + 1:], sel_ref=refs[operands], **static)
 
     return with_selection
+
+
+def _sub_needed(a, b, q_index, kv_index, *, causal, causal_offset, block_q, block_k,
+                window, subs=None):
+    """Whether sub-block ``(a, b)`` of tile ``(q_index, kv_index)`` (q half
+    ``a``, kv half ``b``; ``_sub_block``) holds a visible pair: the rule that
+    decides a TILE, at the sub-block's size. ``_tile_needed`` for the causal
+    diagonal and the window's lower edge, bit ``a * parts_k + b`` of the
+    schedule's ``subs`` for the block-diffusion mask. For Python integers
+    (the tests enumerate it against the mask) and traced ones alike."""
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    if subs is not None:
+        return ((subs >> (a * parts_k + b)) & 1) != 0
+    return _tile_needed(causal, causal_offset, q_index * parts_q + a, kv_index * parts_k + b,
+                        sub_q, sub_k, window)
+
+
+def _walk(body, needed, q_index, kv_index, *, carried, causal, causal_offset, block_q,
+          block_k, window, schedule=None, row=None, step=None, num_steps=None):
+    """The body of a needed tile, shared by the three kernels: ``body(rows,
+    cols, q_index, kv_index, block_q, block_k)`` computes rows ``rows`` of the
+    q-side refs against rows ``cols`` of the k-side refs (each ``slice(None)``
+    or a ``pl.ds``), a block of the given size at the given indices (at that
+    size). A tile no structural mask can cut (``causal=False`` without a
+    schedule), or one too small to halve (``_sub_block``), is one such block.
+    Any other is read in four sub-blocks, each needed or not by
+    ``_sub_needed``. A tile whose every sub-block is needed runs as the one
+    block it was: walked, it would pay for partial sums it does not need. A
+    tile the mask CUTS is walked: a sub-block the mask empties is skipped, one
+    it crosses is masked by ``_masked_scores`` at its own offsets.
+
+    The walk goes by halves of the ``carried`` axis, the one whose rows the
+    kernel's accumulator holds (``"q"``: fwd and dq; ``"kv"``: dkv), and runs
+    the other axis' needed halves as ONE block, so what the mask leaves of a
+    half is contracted once and not in two partial sums. Three bodies a
+    kernel, not seven: the halves are a ``fori_loop`` and a single needed
+    half sits at a traced offset, because every body is traced and lowered
+    again in every program that holds the kernel (PERF.md section 6, PR 60)."""
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    whole = functools.partial(body, slice(None), slice(None), q_index, kv_index, block_q, block_k)
+    if parts_q * parts_k == 1 or not (causal or schedule is not None):
+        pl.when(needed)(whole)
+        return
+    groups, along = (parts_q, parts_k) if carried == "q" else (parts_k, parts_q)
+
+    def half(index, parts, sub, lo):
+        """Rows, index and size of half ``lo`` of a side."""
+        if parts == 1:
+            return slice(None), index, sub
+        return pl.ds(pl.multiple_of(lo * sub, sub), sub), index * parts + lo, sub
+
+    @pl.when(needed)
+    def _tile():
+        subs = None if schedule is None else schedule[2][row * num_steps + step]
+        held = functools.partial(
+            _sub_needed, q_index=q_index, kv_index=kv_index, causal=causal,
+            causal_offset=causal_offset, block_q=block_q, block_k=block_k, window=window, subs=subs)
+        every = functools.reduce(
+            lambda one, other: one & other,
+            (held(a, b) for a in range(parts_q) for b in range(parts_k)))
+        pl.when(every)(whole)
+
+        def group(g, carry):
+            across = [held(g, x) if carried == "q" else held(x, g) for x in range(along)]
+            both = functools.reduce(lambda one, other: one & other, across)
+            # one needed half of the other axis, at its own offset; or all of it
+            lo = 0 if along == 1 else jnp.where(across[0], 0, 1)
+            blocks = [(across[0] if along == 1 else across[0] ^ across[1], half(
+                *((kv_index, parts_k, sub_k) if carried == "q" else (q_index, parts_q, sub_q)), lo))]
+            if along > 1:
+                blocks.append((both, (slice(None), kv_index, block_k) if carried == "q" else (
+                    slice(None), q_index, block_q)))
+            mine = half(*((q_index, parts_q, sub_q) if carried == "q" else (kv_index, parts_k, sub_k)), g)
+            for wanted, other in blocks:
+                (rows, q_at, size_q), (cols, kv_at, size_k) = (mine, other) if carried == "q" else (other, mine)
+                pl.when(wanted)(functools.partial(body, rows, cols, q_at, kv_at, size_q, size_k))
+            return carry
+
+        @pl.when(every ^ True)
+        def _cut():
+            if groups == 1:
+                group(0, 0)
+            else:
+                jax.lax.fori_loop(0, groups, group, 0)
 
 
 def _flash_fwd_kernel(
@@ -469,10 +621,9 @@ def _flash_fwd_kernel(
     else:
         needed = step < schedule[1][q_index]
 
-    @pl.when(needed)
-    def _compute():
+    def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, _, _ = _masked_scores(
-            q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
+            q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
             block_diffusion=block_diffusion,
@@ -481,22 +632,27 @@ def _flash_fwd_kernel(
         # Running max and sum are kept replicated across a vreg's lanes:
         # a [block_q, 1] statistic would cost the same 64 vregs an
         # operation with one lane in 128 used, plus a lane broadcast
-        # against every score tile.
-        m_prev = m_scr[:]                        # [block_q, _LANES]
+        # against every score tile. A row group carries them across its kv
+        # sub-blocks as across tiles.
+        m_prev = m_scr[rows, :]                  # [block_q, _LANES]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - _across(m_new, block_k))  # [block_q, block_k] f32
         correction = jnp.exp(m_prev - m_new)     # [block_q, _LANES]
-        l_scr[:] = correction * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0]                             # [block_k, d]
-        acc_scr[:] = acc_scr[:] * _across(
+        l_scr[rows, :] = correction * l_scr[rows, :] + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, cols, :]                    # [block_k, d]
+        acc_scr[rows, :] = acc_scr[rows, :] * _across(
             correction, acc_scr.shape[1]
         ) + jax.lax.dot_general(
             _mxu(p.astype(v.dtype), precision), _mxu(v, precision),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
-        m_scr[:] = m_new
+        m_scr[rows, :] = m_new
+
+    _walk(compute, needed, q_index, kv_index, carried="q", causal=causal, causal_offset=causal_offset,
+          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=q_index,
+          step=step, num_steps=num_steps)
 
     @pl.when(step == num_steps - 1)
     def _finalize():
@@ -530,29 +686,32 @@ def _flash_dq_kernel(
     else:
         needed = step < schedule[1][q_index]
 
-    @pl.when(needed)
-    def _compute():
+    def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, _, k = _masked_scores(
-            q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
+            q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
             block_diffusion=block_diffusion,
         )
-        lse = lse_ref[0]
+        lse = lse_ref[0, rows, :]
         p = jnp.exp(s - lse)                     # [block_q, block_k] f32
-        do = do_ref[0]
+        do = do_ref[0, rows, :]
         dp = jax.lax.dot_general(
-            _mxu(do, precision), _mxu(v_ref[0], precision),
+            _mxu(do, precision), _mxu(v_ref[0, cols, :], precision),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_q, block_k]
-        delta = delta_ref[0]
+        delta = delta_ref[0, rows, :]
         ds = p * (dp - delta) * scale            # f32
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+        dq_scr[rows, :] = dq_scr[rows, :] + jax.lax.dot_general(
             _mxu(ds.astype(do.dtype), precision), k,
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
+
+    _walk(compute, needed, q_index, kv_index, carried="q", causal=causal, causal_offset=causal_offset,
+          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=q_index,
+          step=step, num_steps=num_steps)
 
     @pl.when(step == num_steps - 1)
     def _finalize():
@@ -593,33 +752,36 @@ def _flash_dkv_kernel(
         if window is not None:
             needed &= q_index < num_q_blocks     # a step past the last q block
 
-    @pl.when(needed)
-    def _compute():
+    def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, q, _ = _masked_scores(
-            q_ref, k_ref, q_index, kv_index, scale=scale, causal=causal,
+            q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
             block_diffusion=block_diffusion,
         )
-        lse = lse_ref[0]
+        lse = lse_ref[0, rows, :]
         p = jnp.exp(s - lse)
-        do = do_ref[0]
+        do = do_ref[0, rows, :]
         pt = _mxu(p.astype(do.dtype), precision)  # [block_q, block_k]
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+        dv_scr[cols, :] = dv_scr[cols, :] + jax.lax.dot_general(
             pt, _mxu(do, precision), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
         dp = jax.lax.dot_general(
-            _mxu(do, precision), _mxu(v_ref[0], precision),
+            _mxu(do, precision), _mxu(v_ref[0, cols, :], precision),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )
-        delta = delta_ref[0]
+        delta = delta_ref[0, rows, :]
         ds = (p * (dp - delta) * scale).astype(do.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+        dk_scr[cols, :] = dk_scr[cols, :] + jax.lax.dot_general(
             _mxu(ds, precision), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
+
+    _walk(compute, needed, q_index, kv_index, carried="kv", causal=causal, causal_offset=causal_offset,
+          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=kv_index,
+          step=step, num_steps=num_steps)
 
     last = step == num_steps - 1
     if group > 1:
@@ -889,7 +1051,7 @@ def _flash_forward(
         kv_map = _scheduled_kv_map(kv_steps, group)
     from jax.experimental.pallas import tpu as pltpu
 
-    # under a schedule every index map is handed its two prefetched arrays too
+    # under a schedule every index map is handed its three prefetched arrays too
     q_row = lambda i, j, kv, *_: (i, j, 0)
     operands = [qr, kr, vr]
     in_specs = [

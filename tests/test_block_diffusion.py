@@ -124,49 +124,69 @@ def test_the_mask_is_the_definition():
 @pytest.mark.parametrize("clean_len,block,block_q,block_k", [
     (32, 4, 16, 16), (48, 4, 32, 32), (96, 16, 64, 64), (64, 16, 16, 32), (128, 4, 32, 64),
     (96, 4, 64, 32), (24, 12, 16, 16), (64, 4, 128, 128),
+    # tiles walked in sub-blocks of 128: blocks that divide one and that straddle its edges
+    (512, 4, 256, 256), (384, 96, 256, 256), (512, 32, 256, 512),
 ])
 def test_the_tile_counts_and_the_walk_are_the_enumeration_s(clean_len, block, block_q, block_k):
     rows = 2 * clean_len
     mask = _explicit(clean_len, block)
     needed = mask.reshape(rows // block_q, block_q, rows // block_k, block_k).any(axis=(1, 3))
+    sub_q, sub_k = flash._sub_block(block_q), flash._sub_block(block_k)
+    assert (sub_q, sub_k) == (block_q // 2 if block_q >= 256 else block_q, block_k // 2 if block_k >= 256 else block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    # [q tile, kv tile, q part, kv part]: whether the sub-block holds an allowed pair
+    held = mask.reshape(rows // block_q, parts_q, sub_q, rows // block_k, parts_k, sub_k).any(axis=(2, 5))
+    held = held.transpose(0, 2, 1, 3)
     counts = flash.block_diffusion_tile_counts(clean_len, block, block_q, block_k)
     assert counts == {
         "skipped": int((~needed).sum()), "executed": int(needed.sum()),
-        "allowed_pairs": int(mask.sum()), "executed_pairs": int(needed.sum()) * block_q * block_k,
+        "allowed_pairs": int(mask.sum()), "executed_pairs": int(held.sum()) * sub_q * sub_k,
     }
     assert counts["allowed_pairs"] == clean_len ** 2 + clean_len * block
     walks = flash._block_diffusion_schedule(clean_len, block, block_q, block_k)
-    for axis, wanted in (("kv", needed), ("q", needed.T)):
-        tiles, row_counts, steps = walks[axis]
-        assert steps == wanted.sum(axis=1).max() and tiles.shape == (wanted.shape[0] * steps,)
+    for axis, wanted, parts in (("kv", needed, held), ("q", needed.T, held.transpose(1, 0, 2, 3))):
+        tiles, row_counts, subs, steps = walks[axis]
+        assert steps == wanted.sum(axis=1).max() and tiles.shape == subs.shape == (wanted.shape[0] * steps,)
         for row, (taken, count) in enumerate(zip(tiles.reshape(-1, steps), row_counts)):
             assert list(taken[:count]) == list(np.flatnonzero(wanted[row]))   # each needed tile once, in order
             assert np.all(taken[count:] == taken[count - 1])                  # then the one already resident
+            for step in range(count):                                         # bit a * parts_k + b: part (a, b)
+                bits = int(subs[row * steps + step])
+                got = [[bits >> (a * parts_k + b) & 1 for b in range(parts_k)] for a in range(parts_q)]
+                assert bits and np.array_equal(got, parts[row, taken[step]]), (axis, row, step)
 
 
 def test_the_cell_s_walk():
     """8,192 trained positions in blocks of 4 under 1024 x 1024 tiles: 80 of
-    256 tiles a head (a causal 16,384 runs 136), rows of 1 .. 8 and 2 .. 9."""
+    256 tiles a head (a causal 16,384 runs 136), rows of 1 .. 8 and 2 .. 9.
+    In sub-blocks of 512: the 16 half-masked diagonal tiles (clean rows and
+    noised rows on the clean keys) run 3 of their 4, the 8 noised-diagonal
+    tiles 2: 72 tiles' worth of pairs for the 64.03 the mask allows."""
     counts = flash.block_diffusion_tile_counts(8192, 4, 1024, 1024)
     assert counts == {"skipped": 176, "executed": 80, "allowed_pairs": 8192 ** 2 + 8192 * 4,
-                      "executed_pairs": 80 * 1024 ** 2}
+                      "executed_pairs": (80 * 4 - 16 - 2 * 8) * 512 ** 2}
+    assert 100 * counts["allowed_pairs"] / counts["executed_pairs"] == pytest.approx(88.9, abs=0.05)
     assert flash.causal_tile_counts(16384, 16384, 1024, 1024)["executed"] == 136
     walks = flash._block_diffusion_schedule(8192, 4, 1024, 1024)
-    assert list(walks["kv"][1]) == [*range(1, 9), *range(2, 10)] and walks["kv"][2] == 9
+    assert list(walks["kv"][1]) == [*range(1, 9), *range(2, 10)] and walks["kv"][3] == 9
     # a clean key tile: the clean rows from it on and the noised rows behind it; a noised one: one
-    assert list(walks["q"][1]) == [2 * (8 - c) for c in range(8)] + [1] * 8 and walks["q"][2] == 16
+    assert list(walks["q"][1]) == [2 * (8 - c) for c in range(8)] + [1] * 8 and walks["q"][3] == 16
+    # q row 9 (noised rows 1024 ..): clean tile 0 whole, clean tile 1 its lower half, its own noised diagonal
+    assert list(walks["kv"][2][9 * 9: 9 * 9 + 3]) == [0b1111, 0b1101, 0b1001]
 
 
 # -- the three older masks lower to what they did -----------------------------
 # sha256 of the three Mosaic modules of ``jax.grad(flash_attention)`` (fwd,
 # dq, dkv; [1, 4 / 2, 256, 128] bfloat16), lowered for a TPU, parsed and
-# printed WITHOUT source locations, by mode: what the parent commit of PR 59
-# lowers (computed there by this very function). A change to the kernels that
-# is meant changes these lines; the fourth mask's arrival must not.
+# printed WITHOUT source locations, by mode. PR 59 pinned what ITS parent
+# lowered, to show the fourth mask's arrival changed none; PR 60 changed the
+# kernels on purpose (a 256 x 256 tile a mask cuts is walked in sub-blocks of
+# 128) and these are what its tree lowers. A change to the kernels that is
+# meant changes these lines; one that is not must not.
 MODULES_OF_THE_PARENT = {
-    "causal": "5868803e74aa8ea2942642e1924a5d1d28f207d7cf472d2eeaea309e2f03e633",
-    "window": "8975a13163504d331a2784298bf88636b58dffd6071a1778eff269c6ba8248e8",
-    "selection": "bf11ac2e7a26f1a87a462106dc16e5fb468c4bff4c28de562c3f2e89315f78e9",
+    "causal": "c77e71cbae6d20c7ebffa3a501ebcd3694841f86f312967ffa8bb1fba9a6e10f",
+    "window": "2d53cd7477f663d1a6d1cf4a9815c950081adebb8b341865befad5061c5bd43a",
+    "selection": "b2d0a0883fd7c1eedc4bc7529d4e8ce90b10ba3322848f0201d64a66f3c76ca2",
 }
 
 

@@ -1,6 +1,7 @@
 """The flash-attention kernels (forward, backward, a long sequence, a block
-refused and a small one, two head dims, heads of 64, a window, a selection)
-and the index scorer's term at the cells' shapes.
+refused and a small one, two head dims, heads of 64, a window, a selection,
+the walked bodies at the seven shapes they were read alone at) and the index
+scorer's term at the cells' shapes.
 
 Compiled for a TPU v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2): the TPU's compiler is installed
@@ -198,6 +199,59 @@ def test_flash_under_a_selection_compiles_for_v5e(one_chip):
     assert "s8[1,16384,16384]" in text and "[32,16384,16384]" not in text
     assert [g.shape for g in jax.eval_shape(grads, *shapes)] == [
         (1, 32, 16384, 128), (1, 4, 16384, 128), (1, 4, 16384, 128)]
+
+
+# The shapes the kernels were read alone at (PERF.md section 6, PR 60), one a
+# cell's call: (heads, kv_heads, seq, head_dim, v_dim, mode).
+_WALKED = {
+    "causal_16k": (32, 32, 16384, 128, 128, {}),
+    "window_16k_group7": (28, 4, 16384, 128, 128, {"window": 4096}),
+    "block_diffusion_8k_group8": (32, 4, 16384, 128, 128, {"causal": False, "block_diffusion": (8192, 4)}),
+    "selection_16k_group8": (32, 4, 16384, 128, 128, {"selection": True}),
+    "causal_4k_group8": (64, 8, 4096, 128, 128, {}),
+    "two_head_dims_8k": (16, 16, 8192, 192, 128, {}),
+    "head_64_16k": (32, 32, 16384, 64, 64, {}),
+}
+
+
+@pytest.mark.parametrize("name", list(_WALKED))
+def test_the_walked_bodies_compile_for_v5e(one_chip, name):
+    """A tile of 1024 x 1024 that a mask cuts is walked in sub-blocks of 512
+    (``_walk``: three bodies a kernel, the whole tile's and, in a
+    ``fori_loop`` over the carried axis' halves, one half of the other axis at
+    a traced offset or both; the selection's int8 tile is sliced with them):
+    forward + dq + dkv stay three Mosaic calls under the jitted names the
+    trace reads, ask for no scoped VMEM beyond Mosaic's own 16 MiB and use
+    under it."""
+    from ray_tpu.ops.flash_attention import _block_sizes, _sub_block
+
+    heads, kv_heads, seq, dim, v_dim, mode = _WALKED[name]
+    mode = dict(mode)
+    chosen = mode.pop("selection", False)
+    blocks = _block_sizes(seq, seq, None, None, max(dim, v_dim), jnp.bfloat16, chosen)
+    assert blocks == (1024, 1024) and _sub_block(1024) == 512
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    shapes = [shape(1, heads, seq, dim), shape(1, kv_heads, seq, dim), shape(1, kv_heads, seq, v_dim)]
+    if chosen:
+        shapes.append(shape(1, seq, seq, dtype=jnp.int8))
+
+    def loss(q, k, v, *selection):
+        return flash_attention(
+            q, k, v, interpret=False, selection=selection[0] if selection else None, **mode
+        ).astype(jnp.float32).sum()
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(*shapes).compile().as_text()
+    calls = mosaic_calls(text)
+    assert len(calls) == 3
+    assert sum("_flash_forward" in call for call in calls) == 1
+    assert sum("_flash_backward" in call for call in calls) == 2      # dq, and dk + dv
+    lines = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert all('"scoped_memory_configs":[]' in line for line in lines)
+    used = [int(size) for line in lines
+            for size in re.findall(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"', line)]
+    assert len(used) == 3 and max(used) < 16 * 2**20, used
+    assert [g.shape for g in jax.eval_shape(grads, *shapes)] == [s.shape for s in shapes[:3]]
 
 
 def test_the_index_term_s_kernels_compile_for_v5e(one_chip):

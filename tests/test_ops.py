@@ -193,6 +193,17 @@ _TILE_CASES = [
 ]
 
 
+def _executed_pairs(mask, block_q, block_k):
+    """The pairs the kernels compute under ``mask``, by enumeration: those of
+    the sub-blocks (``_sub_block``: a tile under 256 a side is its own one)
+    that hold a visible pair."""
+    from ray_tpu.ops.flash_attention import _sub_block
+
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    held = mask.reshape(mask.shape[0] // sub_q, sub_q, mask.shape[1] // sub_k, sub_k).any(axis=(1, 3))
+    return int(held.sum()) * sub_q * sub_k
+
+
 def _grouped_maps(kv_map, q_map, group):
     """Both index maps as ``(j, step) -> block``, the rows they name held to
     the grouping on the way: query row ``i = b * heads + h`` reads K / V row
@@ -256,12 +267,14 @@ def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, gro
                 assert fetched_kv == (row[-1] if row.size else 0)
                 assert fetched_q == (col[0] if col.size else nq - 1)
     assert causal_tile_counts(seq_q, seq_k, block_q, block_k) == {
-        "skipped": int((~needed).sum()), "executed": int(needed.sum())}
+        "skipped": int((~needed).sum()), "executed": int(needed.sum()),
+        "executed_pairs": _executed_pairs(mask, block_q, block_k)}
 
 
+# ``executed_pairs``: a diagonal tile runs 3 of its 4 sub-blocks of 512 x 512
 @pytest.mark.parametrize("seq,counts", [
-    (16384, {"skipped": 120, "executed": 136}),
-    (4096, {"skipped": 6, "executed": 10}),
+    (16384, {"skipped": 120, "executed": 136, "executed_pairs": (136 * 4 - 16) * 512 ** 2}),
+    (4096, {"skipped": 6, "executed": 10, "executed_pairs": (10 * 4 - 4) * 512 ** 2}),
 ])
 def test_causal_tile_counts_of_the_benchmark_cells(seq, counts):
     """At the block shape the cells run (head_dim 128, bfloat16)."""
@@ -354,7 +367,8 @@ def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, win
     assert max(len(np.flatnonzero(r)) for r in needed) <= steps["kv"] <= nk
     assert max(len(np.flatnonzero(c)) for c in needed.T) <= steps["q"] <= nq
     assert causal_tile_counts(seq_q, seq_k, block_q, block_k, window) == {
-        "skipped": int((~needed).sum()), "executed": int(needed.sum())}
+        "skipped": int((~needed).sum()), "executed": int(needed.sum()),
+        "executed_pairs": _executed_pairs(mask, block_q, block_k)}
 
 
 def test_window_tile_counts_of_the_benchmark_cell():
@@ -365,7 +379,9 @@ def test_window_tile_counts_of_the_benchmark_cell():
     from ray_tpu.ops.flash_attention import band_steps
 
     blocks = _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16)
-    assert causal_tile_counts(16384, 16384, *blocks, 4096) == {"skipped": 186, "executed": 70}
+    # 16 diagonal tiles run 3 of their 4 sub-blocks, the 12 lower-edge tiles too
+    assert causal_tile_counts(16384, 16384, *blocks, 4096) == {
+        "skipped": 186, "executed": 70, "executed_pairs": (70 * 4 - 16 - 12) * 512 ** 2}
     # the grid walks 16 x 5 steps a head, 10 of them skipped, not 16 x 16
     assert band_steps(16384, 16384, *blocks, 4096) == {"kv": 5, "q": 5}
     mask = _window_mask(16384, 16384, 4096)
@@ -498,3 +514,130 @@ def test_flash_attention_skipping_matches_reference(shape, causal, dtype):
         # a KV head's gradient is the sum of its group's: so is its rounding
         sums = heads // kv_heads if name != "dq" else 1
         assert err < (2e-4 if exact else 0.15) * sums, (name, err)
+
+
+# -- a cut tile is walked in sub-blocks -----------------------------------------
+# Tiles of 256 in sub-blocks of 128 (``_sub_block``), by mode: seq_q != seq_k
+# (a causal offset of one tile: a row's diagonal tile is its second), a window whose lower
+# edge falls inside a tile AND inside a sub-block, a selection beside the
+# causal edge, and a block-diffusion block (96) that straddles sub-block edges.
+_WALK_MODES = {
+    "causal_rect": dict(seq_q=512, seq_k=768, causal=True),
+    "window": dict(seq_q=768, seq_k=768, causal=True, window=200),
+    "selection": dict(seq_q=512, seq_k=512, causal=True, selection=True),
+    "block_diffusion": dict(seq_q=768, seq_k=768, causal=False, block_diffusion=(384, 96)),
+}
+# (heads, kv_heads, head_dim, v_dim)
+_WALK_HEADS = [(2, 2, 64, 64), (4, 1, 128, 128), (4, 1, 192, 128), (1, 1, 128, 128)]
+
+
+def _walk_selection(seq_q, seq_k):
+    """Every third key, and a query's own: each query has a key it may see."""
+    import numpy as np
+
+    q_pos = (seq_k - seq_q) + np.arange(seq_q)[:, None]
+    k_pos = np.arange(seq_k)[None, :]
+    return jnp.asarray(((k_pos % 3 == 0) | (k_pos == q_pos)).astype(np.int8))[None]
+
+
+@pytest.mark.parametrize("heads", _WALK_HEADS, ids=lambda h: "h{}kv{}d{}v{}".format(*h))
+@pytest.mark.parametrize("mode", list(_WALK_MODES))
+def test_a_cut_tile_s_walk_matches_reference(mode, heads):
+    """Value and all three gradients of the kernels in the interpreter against
+    ``attention_reference``, at tiles of 256 x 256: the ones a mask cuts are
+    walked in sub-blocks of 128, the interior ones run whole, in one call."""
+    from ray_tpu.ops.flash_attention import _sub_block
+
+    assert _sub_block(256) == 128
+    kwargs = dict(_WALK_MODES[mode])
+    seq_q, seq_k = kwargs.pop("seq_q"), kwargs.pop("seq_k")
+    if kwargs.pop("selection", False):
+        kwargs["selection"] = _walk_selection(seq_q, seq_k)
+    n_heads, kv_heads, dim, v_dim = heads
+    keys = jax.random.split(jax.random.PRNGKey(seq_k + dim), 4)
+    q = jax.random.normal(keys[0], (1, n_heads, seq_q, dim), jnp.float32)
+    k = jax.random.normal(keys[1], (1, kv_heads, seq_k, dim), jnp.float32)
+    v = jax.random.normal(keys[2], (1, kv_heads, seq_k, v_dim), jnp.float32)
+    w = jax.random.normal(keys[3], (1, n_heads, seq_q, v_dim), jnp.float32)
+    kernels = lambda q, k, v: jnp.sum(w * flash_attention(
+        q, k, v, block_q=256, block_k=256, precision=jax.lax.Precision.HIGHEST, **kwargs))
+    oracle = lambda q, k, v: jnp.sum(w * _grouped_reference(q, k, v, **kwargs))
+    got = jax.jit(jax.value_and_grad(kernels, (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.value_and_grad(oracle, (0, 1, 2)))(q, k, v)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5, abs=1e-3)
+    for name, mine, theirs in zip(("dq", "dk", "dv"), got[1], want[1]):
+        assert mine.shape == theirs.shape, name
+        err = float(jnp.max(jnp.abs(mine - theirs)))
+        assert err < 2e-4 * (n_heads // kv_heads if name != "dq" else 1), (name, err)
+
+
+# (seq_q, seq_k, block_q, block_k, window or None, block_diffusion or None):
+# square and rectangular tiles, seq_q < seq_k, windows under, at and over a
+# sub-block, a tile walked on one side only (64 rows are one part), the
+# block-diffusion mask with blocks that divide and that straddle a sub-block,
+# and its tiles across the clean / noised boundary.
+_SUB_BLOCK_CASES = [
+    (1024, 1024, 256, 256, None, None),
+    (512, 1024, 256, 256, None, None),
+    (1024, 1024, 512, 256, None, None),
+    (768, 768, 64, 256, None, None),
+    (768, 1024, 256, 512, None, None),      # the diagonal crosses sub-blocks off their corners
+    (768, 1024, 256, 512, 300, None),
+    (1024, 1024, 256, 256, 100, None),
+    (1024, 1024, 256, 256, 128, None),
+    (1024, 1024, 256, 256, 129, None),
+    (1024, 1024, 256, 512, 300, None),
+    (512, 1024, 256, 256, 400, None),
+    (1024, 1024, 256, 256, None, (512, 4)),
+    (768, 768, 256, 256, None, (384, 96)),
+    (1024, 1024, 256, 512, None, (512, 32)),
+    (1536, 1536, 512, 512, None, (768, 256)),
+]
+
+
+@pytest.mark.parametrize("axis", ["kv", "q"])
+@pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,window,block_diffusion", _SUB_BLOCK_CASES)
+def test_the_sub_block_rule_is_the_mask_s(seq_q, seq_k, block_q, block_k, window, block_diffusion, axis):
+    """``_sub_needed``, the rule ``_walk`` skips a sub-block by, for every
+    needed tile against the mask by enumeration: a sub-block it skips holds no
+    visible pair, one it runs holds at least one. Under block diffusion the
+    bits are read as the kernels of either axis read them (``"kv"``: fwd and
+    dq, a q row's steps; ``"q"``: dkv, a kv row's), each needed tile once. The
+    counters count what it runs."""
+    import numpy as np
+
+    from ray_tpu.ops import flash_attention as flash
+
+    nq, nk = seq_q // block_q, seq_k // block_k
+    sub_q, sub_k = flash._sub_block(block_q), flash._sub_block(block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    offset = seq_k - seq_q
+    if block_diffusion is None:
+        mask = _window_mask(seq_q, seq_k, window or seq_k)
+        counts = flash.causal_tile_counts(seq_q, seq_k, block_q, block_k, window)
+        tiles = [((j, kv), None) for j in range(nq) for kv in range(nk)
+                 if flash._tile_needed(True, offset, j, kv, block_q, block_k, window)]
+    else:
+        rows = np.arange(seq_q)[:, None]
+        mask = flash.block_diffusion_visible(rows, rows.T, *block_diffusion)
+        counts = flash.block_diffusion_tile_counts(*block_diffusion, block_q, block_k)
+        walk, row_counts, subs, steps = flash._block_diffusion_schedule(*block_diffusion, block_q, block_k)[axis]
+        tiles = [
+            ((row, int(walk[row * steps + step])) if axis == "kv" else (int(walk[row * steps + step]), row),
+             int(subs[row * steps + step]))
+            for row in range(len(row_counts)) for step in range(row_counts[row])]
+        assert len(set(tile for tile, _ in tiles)) == len(tiles) == counts["executed"]
+    ran = np.zeros_like(mask)
+    for (j, kv), bits in tiles:
+        for a in range(parts_q):
+            for b in range(parts_k):
+                at = (slice(j * block_q + a * sub_q, j * block_q + (a + 1) * sub_q),
+                      slice(kv * block_k + b * sub_k, kv * block_k + (b + 1) * sub_k))
+                held = flash._sub_needed(
+                    a, b, j, kv, causal=block_diffusion is None, causal_offset=offset,
+                    block_q=block_q, block_k=block_k, window=window, subs=bits)
+                assert bool(held) == bool(mask[at].any()), ((j, kv), a, b)
+                ran[at] = bool(held)
+    assert not (mask & ~ran).any()                            # skipped: no visible pair
+    assert counts["executed_pairs"] == int(ran.sum())
+    assert int(mask.sum()) <= counts["executed_pairs"] <= counts["executed"] * block_q * block_k

@@ -123,10 +123,12 @@ def test_the_masked_kernels_match_the_oracle(topk):
 
 # The three Mosaic modules of ``jax.grad(flash_attention)`` with NO selection
 # (plain, under a window of 64, at a group of 1), lowered for a TPU, parsed
-# and printed WITHOUT source locations: what the parent commit of PR 53
-# lowers (computed there by this very function). A change to the kernels
-# that is meant changes this line; a selection's arrival must not.
-MODULES_WITHOUT_A_SELECTION = "e2e62e8e6434f1e5a4e3118ddf94922cd1f7fb60e0b1c176110cf9ae81a6ed41"
+# and printed WITHOUT source locations. PR 53 pinned what ITS parent lowered
+# (a selection's arrival changed none of them); PR 60 changed the kernels on
+# purpose (a tile a mask cuts is walked in sub-blocks) and this is what its
+# tree lowers. A change to the kernels that is meant changes this line; one
+# that is not must not.
+MODULES_WITHOUT_A_SELECTION = "969cf1a2793673d1e9803b9c231ec1746070b0c2c9507ab7c7f892228dd5a5f1"
 
 
 def test_without_a_selection_the_mosaic_modules_are_the_parents():
