@@ -4,70 +4,86 @@ The hot op of the flagship models (SURVEY §2.9 SP row: the reference has no
 native attention kernels at all — attention arrives via user engines; here it
 is in-tree). Blocked online-softmax attention:
 
-  forward:  grid = (batch*heads, q_blocks, kv_blocks)   # kv sequential
-            VMEM scratch carries running max/sum/accumulator across kv steps;
-            emits O and the logsumexp (LSE) residual.
+  forward:  grid = (batch*heads, tiles)          # tiles sequential
+            VMEM scratch carries running max/sum/accumulator across a q
+            row's tiles; emits O and the logsumexp (LSE) residual.
   backward: two kernels (the standard flash-v2 split):
-              dq:  grid = (batch*heads, q_blocks, kv_blocks)  # kv sequential
-              dkv: grid = (batch*kv_heads, kv_blocks, [group,] q_blocks)
-                                                      # group, q sequential
+              dq:  grid = (batch*heads, tiles)            # tiles sequential
+              dkv: grid = (batch*kv_heads, tiles, group)  # tiles, group sequential
             Both recompute P = exp(S - LSE) blockwise from (q, k) — O(S²)
             probabilities are never materialized in HBM, so long sequences
             train in memory linear in S.
+
+ONE walk for every mask: the sequential axis of each grid is the list of the
+tiles that RUN, a static table (``_tile_table``) prefetched as scalars
+(``PrefetchScalarGridSpec``), which the index maps and the kernels read: a
+grid step is an entry, an entry is a tile ``(row, col)`` with the flags
+``first`` / ``last`` of its row (the kernels zero and write their
+accumulators there) and the bits ``subs`` of its sub-blocks that hold a
+visible pair. fwd and dq walk it by q row, a row's kv tiles ascending; dkv by
+kv row, a row's q tiles ascending. The table comes from the mask's ONE
+predicate (``_needed_tiles``: ``_tile_needed`` for the causal mask and its
+window, ``_block_diffusion_tiles`` for block diffusion, every tile where
+there is no mask), evaluated in numpy from the shapes and the mask's Python
+ints (``causal_offset`` among them): it is a constant of the program, 4 bytes
+a tile (544 bytes for a causal 16,384 in 1024-tiles, 33 KiB at 131,072). A
+tile the mask empties is no grid step at all: nothing is computed or fetched
+for it, and no step exists only to find that out (≈2× for causal training).
+``causal_tile_counts`` / ``block_diffusion_tile_counts`` say so in
+``grid_steps == executed``. What it is worth on a v5e (the kernels alone,
+PERF.md section 6, PR 61): an entry read from the table costs every step
+0.06 (fwd), 0.11 (dq) and 0.14 us (dkv) more than index maps in closed form
+did (a call with no mask, which skipped nothing, is 2.2 % slower), and with
+that taken out a step that only found out it had nothing to do had cost 0.31,
+0.42 and 0.12 us at 16,384 causal: fwd + dq + dkv -3.0 % at ``[32 / 4, 16384,
+128]`` (dkv alone unmoved), -6.2 % under a selection, -6.8 % at 4,096, and
+-10.9 % under block diffusion, whose dkv grid was the longest row of very
+unequal rows.
 
 Grouped KV heads (GQA) are native: K and V come ``[batch, kv_heads, seq, d]``
 beside q's ``[batch, heads, seq, d]``, ``group = heads // kv_heads`` from the
 shapes, and nobody repeats them. In the forward and dq kernels the K / V
 index map of query row ``i = b * heads + h`` names row ``i // group = b *
 kv_heads + h // group``; the bodies are the ungrouped ones. The dkv kernel
-writes ONE row a KV head: its grid gains a sequential ``group`` axis whose
-step ``g`` reads query row ``kv_row * group + g``, the K / V tiles stay
-resident across the whole group, and the float32 scratch sums the group's
-``dK`` / ``dV`` and is rounded to the operand dtype once. ``group == 1`` has
-no such axis and no division: the three Mosaic modules are what they were.
+writes ONE row a KV head: the group is its grid's INNERMOST axis, step ``(i,
+t, g)`` reads query row ``i * group + g`` at the q tile of entry ``t``; the K
+/ V tiles stay resident across a whole kv row, a selection's tile across the
+group's heads (it is fetched once a tile, not once a head), and the float32
+scratch sums the row's ``dK`` / ``dV`` and is rounded to the operand dtype
+once. The table does not depend on the group.
 
 MXU discipline: matmul operands stay in the input dtype (bfloat16 on TPU —
 the MXU's native multiply) with float32 accumulation via
 preferred_element_type; only softmax/statistics math runs in f32 vectors.
-
-Causal block skipping: grid steps whose (q_block, kv_block) tile is entirely
-masked (``_tile_needed``) skip all compute, and fetch nothing either: the
-index maps clamp a skipped step to its row's nearest needed block, which is
-already resident, so Pallas issues no DMA for it (≈2× for causal training).
 
 A tile a mask CUTS is walked in sub-blocks (``_walk``): the tile stays what
 Pallas fetches (1024 x 1024: a grid step's fixed cost and the K / V re-reads
 are why it won), and inside it a sub-block (``_sub_block``: half the tile's
 side, in whole lane groups, so a tile under 256 a side is one block as
 before) is needed or not by the rule that decides a tile, at the sub-block's
-size. A sub-block the mask empties is skipped; one it crosses is masked at
-its own offsets; the forward carries a row group's running max, sum and
-accumulator from block to block inside a tile as it carries them across
-tiles. The walk goes by halves of the axis the kernel's accumulator holds
-(query rows in fwd and dq, key rows in dkv) and runs the other axis' needed
-halves as one block, so a causal diagonal tile is a 512 x 512 block and a 512
-x 1024 one, three quarters of a whole tile's pairs where it ran all of them
-for half. A tile whose four sub-blocks are all needed (an interior tile)
-keeps the one whole body: walked, it pays for partial sums it does not need
-(PERF.md section 6, PR 60, has the kernels alone, both forms and both
-widths). ``causal=False`` with no mask at all has nothing to skip and is one
-body.
+size: its bit of the entry's ``subs``. A sub-block the mask empties is
+skipped; one it crosses is masked at its own offsets; the forward carries a
+row group's running max, sum and accumulator from block to block inside a
+tile as it carries them across tiles. The walk goes by halves of the axis the
+kernel's accumulator holds (query rows in fwd and dq, key rows in dkv) and
+runs the other axis' needed halves as one block, so a causal diagonal tile is
+a 512 x 512 block and a 512 x 1024 one, three quarters of a whole tile's
+pairs where it ran all of them for half. A tile whose four sub-blocks are all
+needed (an interior tile) keeps the one whole body: walked, it pays for
+partial sums it does not need (PERF.md section 6, PR 60, has the kernels
+alone, both forms and both widths). ``causal=False`` with no mask at all has
+nothing to skip: every tile is in its table and each is one body.
 
 A sliding ``window`` (query i sees keys j with ``i - window < j <= i``, the
-query's own position counted) is the same mechanism with a second edge: a
-tile whose LAST key lies at or before its first query's ``i - window`` is
-skipped as a tile above the diagonal is, and the mask gains ``k_pos > q_pos
-- window``. What runs is the band: at 16,384 positions, 1024-blocks and a
-window of 4096, 70 of the 136 causal tiles (rows of 1, 2, 3, 4, then twelve
-of 5), 44 % of the causal half's pairs. And the grid's sequential axis is
-as long as the band's longest row (``band_steps``: 5 steps there, not 16):
-step ``s`` of a row is its first needed block + ``s``, so a window layer
-walks 80 grid steps a head where a global layer walks 256 (a skipped step
-costs 0.42 us a kernel on a v5e: PERF.md section 6, PR 45). The band's
+query's own position counted) is a second edge of the same predicate: a tile
+whose LAST key lies at or before its first query's ``i - window`` is not in
+the table, as a tile above the diagonal is not, and the mask gains ``k_pos >
+q_pos - window``. What runs is the band: at 16,384 positions, 1024-blocks and
+a window of 4096, 70 of the 136 causal tiles (rows of 1, 2, 3, 4, then twelve
+of 5), 44 % of the causal half's pairs, in 70 grid steps a head. The band's
 lower-edge tiles (12 of the 70, each half masked from the other corner) are
 walked like the diagonal ones and cost three quarters of a tile each: 63
-tiles' worth of sub-blocks for the band's 59.5 of pairs. ``window=None`` is
-the program it was before there was a window.
+tiles' worth of sub-blocks for the band's 59.5 of pairs.
 
 ``block_diffusion=(clean_len, block)`` is a fourth mask, structural and NOT
 causal (block-diffusion training, BD3-LM: a clean copy of a sequence of
@@ -77,20 +93,13 @@ own block and of earlier ones (so up to ``block - 1`` keys AFTER itself); a
 noised query the clean keys of EARLIER blocks and the noised keys of its own
 block, both directions; a clean query never a noised key
 (``block_diffusion_visible``). The mask is computed in the kernels from the
-tile's indices like the causal one; WHICH tiles run is a static table
-(``_block_diffusion_schedule``: a q row's needed kv tiles in order, the
-dkv kernel's q tiles a kv row), prefetched as scalars, that the kernels and
-the index maps read: a step past a row's count is skipped and names the
-row's last needed tile, already resident, and the grid's sequential axis is
-the longest row of needed tiles. At 8,192 clean positions and 1024-tiles:
-80 tiles a head (rows of 1 .. 8 and 2 .. 9) where a causal 16,384 runs 136.
-Which SUB-BLOCKS of a needed tile hold an allowed pair is a third prefetched
-array beside the tiles and the counts, a bit a sub-block a step, from the
-tiles' own closed form at the sub-block's size: the 16 half-masked diagonal
-tiles (clean rows, and noised rows on the clean keys) run three of their
-four, the 8 noised-diagonal tiles (a 4-wide block diagonal: 4,096 allowed
-pairs of 1,048,576) two, 72 tiles' worth for the 64.03 the mask allows
-(``block_diffusion_tile_counts``: ``executed_pairs``).
+tile's indices like the causal one. At 8,192 clean positions and 1024-tiles:
+80 tiles a head (rows of 1 .. 8 and 2 .. 9) where a causal 16,384 runs 136;
+the 16 half-masked diagonal tiles (clean rows, and noised rows on the clean
+keys) run three of their four sub-blocks, the 8 noised-diagonal tiles (a
+4-wide block diagonal: 4,096 allowed pairs of 1,048,576) two, 72 tiles' worth
+for the 64.03 the mask allows (``block_diffusion_tile_counts``:
+``executed_pairs``).
 
 On non-TPU backends the same kernels run in interpreter mode (the CPU twin,
 SURVEY §4.4), so tests exercise the identical code path the TPU compiles.
@@ -141,10 +150,9 @@ def _tile_needed(causal, causal_offset, q_index, kv_index, block_q, block_k,
                  window=None):
     """False only for tiles that the mask zeroes entirely: the tile's last
     query sits before its first key (causal), or its last key at or before
-    its first query's ``i - window``. A comparison only, so it serves the
-    kernels' ``program_id``s and ``causal_tile_counts``'s Python ints alike;
-    the index maps below hold the same boundaries in closed form
-    (tests/test_ops.py holds them to it)."""
+    its first query's ``i - window``. A comparison only: Python ints or numpy
+    arrays of tile indices (``_needed_tiles``: the causal and window masks'
+    one predicate, at a tile's size and at a sub-block's)."""
     if not causal:
         return True
     needed = causal_offset + (q_index + 1) * block_q - 1 >= kv_index * block_k
@@ -163,24 +171,6 @@ def _sub_block(block):
     2.7 times its share of a tile (PERF.md section 6, PR 60)."""
     half = block // 2
     return half if half and block % 2 == 0 and half % _LANES == 0 else block
-
-
-def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
-    """How many tiles of one causal call (per head instance) are executed
-    and how many skipped, and the pairs the executed tiles compute
-    (``executed_pairs``: their SUB-BLOCKS that hold a visible pair, where a
-    tile is walked in them): a property of the shapes (and the window) alone."""
-    def executed(block_q, block_k):
-        return sum(
-            bool(_tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window))
-            for q_index in range(seq_q // block_q)
-            for kv_index in range(seq_k // block_k)
-        )
-
-    tiles, run = (seq_q // block_q) * (seq_k // block_k), executed(block_q, block_k)
-    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
-    return {"skipped": tiles - run, "executed": run,
-            "executed_pairs": executed(sub_q, sub_k) * sub_q * sub_k}
 
 
 def block_diffusion_visible(q_pos, k_pos, clean_len, block):
@@ -229,221 +219,117 @@ def _block_diffusion_tiles(clean_len, block, block_q, block_k):
     return clean_clean | noised_clean | noised_noised
 
 
+def _needed_tiles(seq_q, seq_k, block_q, block_k, causal=True, window=None,
+                  block_diffusion=None):
+    """``[q tiles, kv tiles]`` of bool: whether a ``block_q x block_k`` tile (a
+    tile's size or a sub-block's) holds a visible pair, by the mask's ONE
+    predicate: ``_block_diffusion_tiles`` under that mask, ``_tile_needed``
+    under the causal one and its window, every tile where there is no mask."""
+    if block_diffusion is not None:
+        return _block_diffusion_tiles(*block_diffusion, block_q, block_k)
+    q_index = np.arange(seq_q // block_q)[:, None]
+    kv_index = np.arange(seq_k // block_k)[None, :]
+    return np.broadcast_to(
+        _tile_needed(causal, seq_k - seq_q, q_index, kv_index, block_q, block_k, window),
+        (q_index.size, kv_index.size),
+    )
+
+
+# An entry of a call's table (``_tile_table``) is one int32: the tile's index
+# along the rows the walk goes by, its index along the other axis, whether it
+# is its row's last and first entry, and which of its sub-blocks run.
+_INDEX_BITS = 13
+_LAST_BIT, _FIRST_BIT, _SUBS_SHIFT = 2 * _INDEX_BITS, 2 * _INDEX_BITS + 1, 2 * _INDEX_BITS + 2
+
+
+def _tile_table(seq_q, seq_k, block_q, block_k, *, by, causal=True, window=None,
+                block_diffusion=None):
+    """The tiles a call runs, in the order it runs them: the sequential axis
+    of a kernel's grid is THIS list, prefetched as scalars, and a grid step is
+    an entry. ``by="q"`` (fwd and dq): row-major by q tile, a row's kv tiles
+    ascending; ``by="kv"`` (dkv): by kv tile, its q tiles ascending. An entry
+    (``_entry``) packs ``(row, col, first, last, subs)`` in one int32:
+    ``first`` / ``last`` on a row's first / last entry (the kernels zero and
+    write their accumulators there), ``subs`` the tile's sub-blocks that hold
+    a visible pair (``_walk``), bit ``a * parts_k + b`` for q part ``a`` and
+    kv part ``b`` in either order, from the tiles' own predicate at the
+    sub-block's size (``_needed_tiles``). A tile is listed where ``subs`` is
+    not 0; a row with no such tile (keys no query sees) keeps ONE entry with
+    ``subs == 0``, which runs nothing and writes the row's zeros.
+
+    Static: numpy, from the shapes and the mask's Python ints alone, a
+    constant of the program. 4 bytes a tile: 544 bytes for a causal 16,384 in
+    1024-tiles (136 entries), 33 KiB at 131,072 (8,256)."""
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    held = _needed_tiles(seq_q, seq_k, sub_q, sub_k, causal, window, block_diffusion)
+    held = held.reshape(seq_q // block_q, parts_q, seq_k // block_k, parts_k).transpose(0, 2, 1, 3)
+    subs = (held.reshape(*held.shape[:2], -1) << np.arange(parts_q * parts_k)).sum(axis=-1)
+    if by == "kv":
+        subs = subs.T
+    assert max(subs.shape) <= 1 << _INDEX_BITS, f"{subs.shape} tiles do not fit an entry's {_INDEX_BITS} bits"
+    rows, cols = np.nonzero(subs)
+    empty = np.flatnonzero(~subs.any(axis=1))
+    rows, cols = np.concatenate([rows, empty]), np.concatenate([cols, np.zeros_like(empty)])
+    order = np.argsort(rows, kind="stable")
+    rows, cols = rows[order], cols[order]
+    edge = rows[1:] != rows[:-1]
+    first, last = np.concatenate([[True], edge]), np.concatenate([edge, [True]])
+    packed = (rows | cols << _INDEX_BITS | last << _LAST_BIT | first << _FIRST_BIT
+              | subs[rows, cols] << _SUBS_SHIFT)
+    return packed.astype(np.uint32).view(np.int32)
+
+
+def _entry(table, step):
+    """``(row, col, first, last, subs)`` of entry ``step`` of a ``_tile_table``:
+    for the prefetched ref (the kernels and the index maps read the same
+    word) and for the numpy array alike."""
+    word = table[step]
+    index = (1 << _INDEX_BITS) - 1
+    return (word & index, (word >> _INDEX_BITS) & index, (word >> _FIRST_BIT) & 1 != 0,
+            (word >> _LAST_BIT) & 1 != 0, (word >> _SUBS_SHIFT) & 15)
+
+
+def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
+    """How many tiles of one causal call (per head instance) are executed
+    and how many skipped, and the pairs the executed tiles compute
+    (``executed_pairs``: their SUB-BLOCKS that hold a visible pair, where a
+    tile is walked in them): a property of the shapes (and the window) alone.
+    ``grid_steps``: the sequential steps the forward call walks, the length
+    of the table it prefetches: ``executed``, unless a q row sees no key."""
+    return _tile_counts(seq_q, seq_k, block_q, block_k, window=window)
+
+
+def _tile_counts(seq_q, seq_k, block_q, block_k, **mask):
+    needed = _needed_tiles(seq_q, seq_k, block_q, block_k, **mask)
+    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
+    executed = int(needed.sum())
+    return {
+        "skipped": needed.size - executed, "executed": executed,
+        "executed_pairs": int(_needed_tiles(seq_q, seq_k, sub_q, sub_k, **mask).sum()) * sub_q * sub_k,
+        "grid_steps": len(_tile_table(seq_q, seq_k, block_q, block_k, by="q", **mask)),
+    }
+
+
 def block_diffusion_tile_counts(clean_len, block, block_q, block_k):
     """``causal_tile_counts`` for the block-diffusion mask, per head instance,
     and the pairs: ``allowed_pairs`` the mask lets through (``L^2 + L B``),
     ``executed_pairs`` the executed tiles compute: their sub-blocks that hold
     an allowed pair, where a tile is walked in them."""
-    needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
-    executed = int(needed.sum())
-    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
-    return {
-        "skipped": needed.size - executed, "executed": executed,
-        "allowed_pairs": clean_len * clean_len + clean_len * block,
-        "executed_pairs": int(_block_diffusion_tiles(clean_len, block, sub_q, sub_k).sum()) * sub_q * sub_k,
-    }
+    counts = _tile_counts(2 * clean_len, 2 * clean_len, block_q, block_k, causal=False,
+                          block_diffusion=(clean_len, block))
+    return {**counts, "allowed_pairs": clean_len * clean_len + clean_len * block}
 
 
-def _block_diffusion_schedule(clean_len, block, block_q, block_k):
-    """The kernels' walk under the block-diffusion mask, ``{"kv": .., "q":
-    ..}``: ``(tiles, counts, subs, steps)`` each. ``"kv"`` (fwd and dq): q row
-    ``j``'s needed kv tiles in order at ``tiles[j * steps : (j + 1) * steps]``,
-    ``counts[j]`` of them, the rest repeating the last (a skipped step names
-    the tile already resident: no DMA); ``steps`` the longest row. ``subs``:
-    beside each needed tile, which of its sub-blocks hold an allowed pair
-    (``_walk``): bit ``a * parts_k + b`` for q part ``a`` and kv part ``b``,
-    the tiles' own closed form at the sub-block's size. ``"q"`` (dkv): the
-    same by kv row, of q tiles."""
-    needed = _block_diffusion_tiles(clean_len, block, block_q, block_k)
-    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
-    parts = (block_q // sub_q) * (block_k // sub_k)
-    bits = _block_diffusion_tiles(clean_len, block, sub_q, sub_k).reshape(
-        needed.shape[0], block_q // sub_q, needed.shape[1], block_k // sub_k
-    ).transpose(0, 2, 1, 3).reshape(*needed.shape, parts)
-    bits = (bits.astype(np.int32) << np.arange(parts, dtype=np.int32)).sum(axis=-1, dtype=np.int32)
-
-    def walk(rows, bits):
-        counts = rows.sum(axis=1)
-        steps = int(counts.max())
-        tiles = np.empty((rows.shape[0], steps), np.int32)
-        subs = np.zeros((rows.shape[0], steps), np.int32)
-        for row, wanted in enumerate(rows):
-            found = np.flatnonzero(wanted)
-            tiles[row, :found.size] = found
-            tiles[row, found.size:] = found[-1]
-            subs[row, :found.size] = bits[row, found]
-        return tiles.reshape(-1), counts.astype(np.int32), subs.reshape(-1), steps
-
-    return {"kv": walk(needed, bits), "q": walk(needed.T, bits.T)}
-
-
-def _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion):
-    """``(schedules, steps)`` by sequential axis (``"kv"``: fwd and dq;
-    ``"q"``: dkv): the arrays a call prefetches (none for the causal and
-    window bands, whose tiles the kernels compute) and the axis' extent."""
-    if block_diffusion is None:
-        return {"kv": (), "q": ()}, band_steps(seq_q, seq_k, block_q, block_k, window)
-    walks = _block_diffusion_schedule(*block_diffusion, block_q, block_k)
-    return ({axis: walk[:3] for axis, walk in walks.items()},
-            {axis: walk[3] for axis, walk in walks.items()})
-
-
-def _scheduled_tile(schedule, row, step, num_steps):
-    """The tile of grid step ``step`` of ``row`` from a prefetched
-    ``_block_diffusion_schedule`` walk ``(tiles, counts, subs)``: kernels and
-    index maps read the same array. Needed while ``step < counts[row]``."""
-    return schedule[0][row * num_steps + step]
-
-
-def _scheduled_kv_map(num_steps, group=1):
-    """``_kv_index_map`` under a schedule: the K / V tile of grid step (i, j,
-    step) is the table's, which repeats a row's last needed tile behind it."""
-    def index_map(i, j, step, *schedule):
-        return (i if group == 1 else i // group, _scheduled_tile(schedule, j, step, num_steps), 0)
-
-    return index_map
-
-
-def _scheduled_q_map(num_steps, group=1):
-    """``_q_index_map`` under a schedule: grid step (i, j, [g,] step) of the
-    dkv kernel reads query row ``i * group + g`` at the table's q tile."""
-    def index_map(i, j, *rest):
-        g_step, schedule = rest[:-3], rest[-3:]
-        tile = _scheduled_tile(schedule, j, g_step[-1], num_steps)
-        return (i if group == 1 else i * group + g_step[0], tile, 0)
-
-    return index_map
-
-
-def _grid(schedule, *, grid, in_specs, out_specs, scratch_shapes):
-    """``pallas_call``'s grid arguments: as they are, or with the
-    ``schedule``'s arrays prefetched as scalars ahead of the operands."""
-    spec = dict(grid=grid, in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
-    if not schedule:
-        return spec
-    from jax.experimental.pallas import tpu as pltpu
-
-    return {"grid_spec": pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=len(schedule), **spec)}
-
-
-def _scheduled(kernel):
-    """``kernel`` for a call whose three schedule arrays are prefetched: they
-    come ahead of every other ref."""
-    def with_schedule(tiles, counts, subs, *refs, **static):
-        return kernel(*refs, schedule=(tiles, counts, subs), **static)
-
-    return with_schedule
-
-
-def _first_kv_block(causal_offset, q_index, block_q, block_k, num_kv_blocks,
-                    window):
-    """The kv block a q row's band starts in under ``window``: its first
-    query's key ``i - window + 1``'s."""
-    first_key = jnp.maximum(causal_offset + q_index * block_q - window + 1, 0)
-    return jnp.minimum(first_key // block_k, num_kv_blocks - 1)
-
-
-def _first_q_block(causal_offset, kv_index, block_q, block_k, num_q_blocks):
-    """The q block a kv row's causal tiles start in: its first key's own
-    query's."""
-    first_query = jnp.maximum(kv_index * block_k - causal_offset, 0)
-    return jnp.minimum(first_query // block_q, num_q_blocks - 1)
-
-
-def band_steps(seq_q, seq_k, block_q, block_k, window):
-    """The grid's sequential extent, ``{"kv": .., "q": ..}``. Without a
-    window every block of a row is a step. Under one: the most blocks from a
-    q row's first needed kv block to its last (the fwd and dq kernels), and
-    from a kv row's first needed q block to its last (dkv); from
-    ``_tile_needed`` itself, over the whole tile grid."""
-    if window is None:
-        return {"kv": seq_k // block_k, "q": seq_q // block_q}
-    q_index = np.arange(seq_q // block_q)[:, None]
-    kv_index = np.arange(seq_k // block_k)[None, :]
-    needed = np.broadcast_to(
-        _tile_needed(True, seq_k - seq_q, q_index, kv_index, block_q, block_k, window),
-        (q_index.size, kv_index.size),
-    )
-
-    def longest(rows):
-        spans = [np.flatnonzero(row) for row in rows]
-        return max([int(span[-1] - span[0]) + 1 for span in spans if span.size] + [1])
-
-    return {"kv": longest(needed), "q": longest(needed.T)}
-
-
-def _band_kv_index(step, q_index, causal_offset, block_q, block_k,
-                   num_kv_blocks, window):
-    """The kv block of grid step ``step`` of q row ``q_index`` in the fwd
-    and dq kernels: the step itself, or under a window the row's first needed
-    block + the step (a block past the last is needed by no query:
-    ``_tile_needed``'s causal edge)."""
-    if window is None:
-        return step
-    return step + _first_kv_block(
-        causal_offset, q_index, block_q, block_k, num_kv_blocks, window
-    )
-
-
-def _band_q_index(step, kv_index, causal_offset, block_q, block_k,
-                  num_q_blocks, window):
-    """The q block of grid step ``step`` of kv row ``kv_index`` in the dkv
-    kernel: the step itself, or under a window the row's first needed block
-    + the step, which may lie past the last q block (the kernel skips such a
-    step, the index map clamps it)."""
-    if window is None:
-        return step
-    return step + _first_q_block(
-        causal_offset, kv_index, block_q, block_k, num_q_blocks
-    )
-
-
-def _kv_index_map(causal, causal_offset, block_q, block_k, num_kv_blocks,
-                  window=None, group=1):
-    """K/V block of grid step (i, j, kv) in the fwd and dq kernels. A q
-    row's skipped steps are clamped to its last needed kv block: they name
-    the block already resident, and Pallas issues no DMA for them. Under a
-    window the third grid index is a STEP along the row's band: the row's
-    first needed block + the step, clamped the same way. With ``group``
-    query heads to a KV head, query row ``i = b * heads + h`` reads K / V
-    row ``i // group = b * kv_heads + h // group``."""
-    def index_map(i, j, kv):
-        if causal:
-            kv = _band_kv_index(kv, j, causal_offset, block_q, block_k,
-                                num_kv_blocks, window)
-            last_key = jnp.maximum(causal_offset + (j + 1) * block_q - 1, 0)
-            kv = jnp.minimum(
-                kv, jnp.minimum(last_key // block_k, num_kv_blocks - 1)
-            )
-        return (i if group == 1 else i // group, kv, 0)
-
-    return index_map
-
-
-def _q_index_map(causal, causal_offset, block_q, block_k, num_q_blocks,
-                 window=None, group=1):
-    """Q/dO/lse/delta block of grid step (i, j, qi) in the dkv kernel: a kv
-    row's skipped steps come first, clamped to its first needed q block.
-    Under a window the third grid index is a STEP from that first needed
-    block on, and those behind the band are clamped to its last. With
-    ``group`` query heads to a KV head the grid step is (i, j, g, qi), ``i``
-    a K / V row, and names query row ``i * group + g``: the band is per
-    ``g`` what it is per head."""
-    def index_map(i, j, *g_qi):
-        qi = g_qi[-1]
-        if causal and window is None:
-            qi = jnp.maximum(
-                qi, _first_q_block(causal_offset, j, block_q, block_k, num_q_blocks)
-            )
-        elif causal:
-            qi = _band_q_index(qi, j, causal_offset, block_q, block_k,
-                               num_q_blocks, window)
-            # the last query that sees the row's last key
-            last_query = (j + 1) * block_k - 1 + window - 1 - causal_offset
-            qi = jnp.minimum(qi, jnp.clip(last_query // block_q, 0, num_q_blocks - 1))
-        return (i if group == 1 else i * group + g_qi[0], qi, 0)
-
-    return index_map
+def _by_q_row(heads, group):
+    """The index maps of grid step ``(i, t)`` in fwd and dq, each handed the
+    prefetched table behind the grid's indices: query row ``i`` at the q tile
+    of the table's entry ``t``; K / V row ``i // group`` at its kv tile; the
+    batch row's one selection tile, for each of its heads, at both."""
+    q_row = lambda i, t, table: (i, _entry(table, t)[0], 0)
+    kv_map = lambda i, t, table: (i // group, _entry(table, t)[1], 0)
+    chosen = lambda i, t, table: (i // heads, *_entry(table, t)[:2])
+    return q_row, kv_map, chosen
 
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, rows, cols, *, scale, causal,
@@ -509,35 +395,28 @@ def _selected(kernel, operands: int):
     return with_selection
 
 
-def _sub_needed(a, b, q_index, kv_index, *, causal, causal_offset, block_q, block_k,
-                window, subs=None):
-    """Whether sub-block ``(a, b)`` of tile ``(q_index, kv_index)`` (q half
-    ``a``, kv half ``b``; ``_sub_block``) holds a visible pair: the rule that
-    decides a TILE, at the sub-block's size. ``_tile_needed`` for the causal
-    diagonal and the window's lower edge, bit ``a * parts_k + b`` of the
-    schedule's ``subs`` for the block-diffusion mask. For Python integers
-    (the tests enumerate it against the mask) and traced ones alike."""
-    sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
-    parts_q, parts_k = block_q // sub_q, block_k // sub_k
-    if subs is not None:
-        return ((subs >> (a * parts_k + b)) & 1) != 0
-    return _tile_needed(causal, causal_offset, q_index * parts_q + a, kv_index * parts_k + b,
-                        sub_q, sub_k, window)
+def _sub_needed(a, b, subs, parts_k):
+    """Whether sub-block ``(a, b)`` of a tile (q half ``a``, kv half ``b``;
+    ``_sub_block``) holds a visible pair: bit ``a * parts_k + b`` of its
+    entry's ``subs``. For Python integers (the tests enumerate it against the
+    mask) and traced ones alike."""
+    return ((subs >> (a * parts_k + b)) & 1) != 0
 
 
-def _walk(body, needed, q_index, kv_index, *, carried, causal, causal_offset, block_q,
-          block_k, window, schedule=None, row=None, step=None, num_steps=None):
-    """The body of a needed tile, shared by the three kernels: ``body(rows,
+def _walk(body, subs, q_index, kv_index, *, carried, cut, block_q, block_k):
+    """The body of a grid step's tile, shared by the three kernels: ``body(rows,
     cols, q_index, kv_index, block_q, block_k)`` computes rows ``rows`` of the
     q-side refs against rows ``cols`` of the k-side refs (each ``slice(None)``
     or a ``pl.ds``), a block of the given size at the given indices (at that
-    size). A tile no structural mask can cut (``causal=False`` without a
-    schedule), or one too small to halve (``_sub_block``), is one such block.
-    Any other is read in four sub-blocks, each needed or not by
-    ``_sub_needed``. A tile whose every sub-block is needed runs as the one
-    block it was: walked, it would pay for partial sums it does not need. A
-    tile the mask CUTS is walked: a sub-block the mask empties is skipped, one
-    it crosses is masked by ``_masked_scores`` at its own offsets.
+    size). A tile no structural mask can ``cut`` (``causal=False`` without a
+    block-diffusion mask) is one such block. Any other is read in its
+    sub-blocks (four, or one where it is too small to halve: ``_sub_block``),
+    each needed or not by its bit of the entry's ``subs``. A tile whose every
+    sub-block is needed runs as the one block it was: walked, it would pay for
+    partial sums it does not need. A tile the mask CUTS is walked: a sub-block
+    the mask empties is skipped, one it crosses is masked by
+    ``_masked_scores`` at its own offsets. (``subs == 0``, the one entry of a
+    row without a tile, runs nothing.)
 
     The walk goes by halves of the ``carried`` axis, the one whose rows the
     kernel's accumulator holds (``"q"``: fwd and dq; ``"kv"``: dkv), and runs
@@ -549,9 +428,14 @@ def _walk(body, needed, q_index, kv_index, *, carried, causal, causal_offset, bl
     sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
     parts_q, parts_k = block_q // sub_q, block_k // sub_k
     whole = functools.partial(body, slice(None), slice(None), q_index, kv_index, block_q, block_k)
-    if parts_q * parts_k == 1 or not (causal or schedule is not None):
-        pl.when(needed)(whole)
+    if not cut:
+        whole()
         return
+    every = subs == (1 << parts_q * parts_k) - 1
+    pl.when(every)(whole)
+    if parts_q * parts_k == 1:
+        return
+    held = functools.partial(_sub_needed, subs=subs, parts_k=parts_k)
     groups, along = (parts_q, parts_k) if carried == "q" else (parts_k, parts_q)
 
     def half(index, parts, sub, lo):
@@ -560,66 +444,43 @@ def _walk(body, needed, q_index, kv_index, *, carried, causal, causal_offset, bl
             return slice(None), index, sub
         return pl.ds(pl.multiple_of(lo * sub, sub), sub), index * parts + lo, sub
 
-    @pl.when(needed)
-    def _tile():
-        subs = None if schedule is None else schedule[2][row * num_steps + step]
-        held = functools.partial(
-            _sub_needed, q_index=q_index, kv_index=kv_index, causal=causal,
-            causal_offset=causal_offset, block_q=block_q, block_k=block_k, window=window, subs=subs)
-        every = functools.reduce(
-            lambda one, other: one & other,
-            (held(a, b) for a in range(parts_q) for b in range(parts_k)))
-        pl.when(every)(whole)
+    def group(g, carry):
+        across = [held(g, x) if carried == "q" else held(x, g) for x in range(along)]
+        both = functools.reduce(lambda one, other: one & other, across)
+        # one needed half of the other axis, at its own offset; or all of it
+        lo = 0 if along == 1 else jnp.where(across[0], 0, 1)
+        blocks = [(across[0] if along == 1 else across[0] ^ across[1], half(
+            *((kv_index, parts_k, sub_k) if carried == "q" else (q_index, parts_q, sub_q)), lo))]
+        if along > 1:
+            blocks.append((both, (slice(None), kv_index, block_k) if carried == "q" else (
+                slice(None), q_index, block_q)))
+        mine = half(*((q_index, parts_q, sub_q) if carried == "q" else (kv_index, parts_k, sub_k)), g)
+        for wanted, other in blocks:
+            (rows, q_at, size_q), (cols, kv_at, size_k) = (mine, other) if carried == "q" else (other, mine)
+            pl.when(wanted)(functools.partial(body, rows, cols, q_at, kv_at, size_q, size_k))
+        return carry
 
-        def group(g, carry):
-            across = [held(g, x) if carried == "q" else held(x, g) for x in range(along)]
-            both = functools.reduce(lambda one, other: one & other, across)
-            # one needed half of the other axis, at its own offset; or all of it
-            lo = 0 if along == 1 else jnp.where(across[0], 0, 1)
-            blocks = [(across[0] if along == 1 else across[0] ^ across[1], half(
-                *((kv_index, parts_k, sub_k) if carried == "q" else (q_index, parts_q, sub_q)), lo))]
-            if along > 1:
-                blocks.append((both, (slice(None), kv_index, block_k) if carried == "q" else (
-                    slice(None), q_index, block_q)))
-            mine = half(*((q_index, parts_q, sub_q) if carried == "q" else (kv_index, parts_k, sub_k)), g)
-            for wanted, other in blocks:
-                (rows, q_at, size_q), (cols, kv_at, size_k) = (mine, other) if carried == "q" else (other, mine)
-                pl.when(wanted)(functools.partial(body, rows, cols, q_at, kv_at, size_q, size_k))
-            return carry
-
-        @pl.when(every ^ True)
-        def _cut():
-            if groups == 1:
-                group(0, 0)
-            else:
-                jax.lax.fori_loop(0, groups, group, 0)
+    @pl.when(every ^ True)
+    def _cut():
+        if groups == 1:
+            group(0, 0)
+        else:
+            jax.lax.fori_loop(0, groups, group, 0)
 
 
 def _flash_fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
-    causal, block_q, block_k, num_kv_blocks, precision, causal_offset, window,
-    num_steps, sel_ref=None, block_diffusion=None, schedule=None
+    table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
+    causal, block_q, block_k, precision, causal_offset, window, sel_ref=None,
+    block_diffusion=None
 ):
-    step = pl.program_id(2)
-    q_index = pl.program_id(1)
-    if schedule is None:
-        kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
-                                  num_kv_blocks, window)
-    else:
-        kv_index = _scheduled_tile(schedule, q_index, step, num_steps)
+    # grid (batch * heads, the table's entries): a step is a tile that runs
+    q_index, kv_index, first, last, subs = _entry(table_ref, pl.program_id(1))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # Entirely-masked tiles contribute nothing: skip their compute.
-    if schedule is None:
-        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                              block_q, block_k, window)
-    else:
-        needed = step < schedule[1][q_index]
 
     def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, _, _ = _masked_scores(
@@ -650,11 +511,10 @@ def _flash_fwd_kernel(
         )
         m_scr[rows, :] = m_new
 
-    _walk(compute, needed, q_index, kv_index, carried="q", causal=causal, causal_offset=causal_offset,
-          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=q_index,
-          step=step, num_steps=num_steps)
+    _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
+          block_q=block_q, block_k=block_k)
 
-    @pl.when(step == num_steps - 1)
+    @pl.when(last)
     def _finalize():
         l = jnp.maximum(l_scr[:], 1e-30)
         o_ref[0] = (acc_scr[:] / _across(l, acc_scr.shape[1])).astype(
@@ -664,27 +524,15 @@ def _flash_fwd_kernel(
 
 
 def _flash_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
-    scale, causal, block_q, block_k, num_kv_blocks, precision, causal_offset,
-    window, num_steps, sel_ref=None, block_diffusion=None, schedule=None
+    table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
+    scale, causal, block_q, block_k, precision, causal_offset, window, sel_ref=None,
+    block_diffusion=None
 ):
-    step = pl.program_id(2)
-    q_index = pl.program_id(1)
-    if schedule is None:
-        kv_index = _band_kv_index(step, q_index, causal_offset, block_q, block_k,
-                                  num_kv_blocks, window)
-    else:
-        kv_index = _scheduled_tile(schedule, q_index, step, num_steps)
+    q_index, kv_index, first, last, subs = _entry(table_ref, pl.program_id(1))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    if schedule is None:
-        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                              block_q, block_k, window)
-    else:
-        needed = step < schedule[1][q_index]
 
     def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, _, k = _masked_scores(
@@ -709,48 +557,30 @@ def _flash_dq_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )
 
-    _walk(compute, needed, q_index, kv_index, carried="q", causal=causal, causal_offset=causal_offset,
-          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=q_index,
-          step=step, num_steps=num_steps)
+    _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
+          block_q=block_q, block_k=block_k)
 
-    @pl.when(step == num_steps - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr, *, scale, causal, block_q, block_k, num_q_blocks,
-    precision, causal_offset, window, num_steps, group, sel_ref=None,
-    block_diffusion=None, schedule=None
+    table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+    dk_scr, dv_scr, *, scale, causal, block_q, block_k, precision, causal_offset,
+    window, group, sel_ref=None, block_diffusion=None
 ):
-    # One output row a KV head: the scratch sums its ``group`` query heads'
-    # steps in float32 (grid axes 2 and 3, both sequential; the K / V tiles
-    # stay resident across them). group == 1: no such axis.
-    step = pl.program_id(2 if group == 1 else 3)
-    kv_index = pl.program_id(1)
-    if schedule is None:
-        q_index = _band_q_index(step, kv_index, causal_offset, block_q, block_k,
-                                num_q_blocks, window)
-    else:
-        q_index = _scheduled_tile(schedule, kv_index, step, num_steps)
+    # grid (batch * kv_heads, the table's entries, group): one output row a KV
+    # head. The scratch sums a kv row's tiles, and under each its ``group``
+    # query heads, in float32; the K / V tiles stay resident across the row,
+    # a selection's tile across the group.
+    kv_index, q_index, first, last, subs = _entry(table_ref, pl.program_id(1))
+    head = pl.program_id(2)
 
-    first = step == 0
-    if group > 1:
-        first &= pl.program_id(2) == 0
-
-    @pl.when(first)
+    @pl.when(first & (head == 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    if schedule is not None:
-        needed = step < schedule[1][kv_index]
-    else:
-        needed = _tile_needed(causal, causal_offset, q_index, kv_index,
-                              block_q, block_k, window)
-        if window is not None:
-            needed &= q_index < num_q_blocks     # a step past the last q block
 
     def compute(rows, cols, q_index, kv_index, block_q, block_k):
         s, q, _ = _masked_scores(
@@ -779,15 +609,10 @@ def _flash_dkv_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
 
-    _walk(compute, needed, q_index, kv_index, carried="kv", causal=causal, causal_offset=causal_offset,
-          block_q=block_q, block_k=block_k, window=window, schedule=schedule, row=kv_index,
-          step=step, num_steps=num_steps)
+    _walk(compute, subs, q_index, kv_index, carried="kv", cut=causal or block_diffusion is not None,
+          block_q=block_q, block_k=block_k)
 
-    last = step == num_steps - 1
-    if group > 1:
-        last &= pl.program_id(2) == group - 1
-
-    @pl.when(last)
+    @pl.when(last & (head == group - 1))
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -1023,11 +848,8 @@ def _flash_forward(
     qr = q.reshape(bh, seq_q, dim)
     kr = k.reshape(batch * kv_heads, seq_k, dim)
     vr = v.reshape(batch * kv_heads, seq_k, v_dim)
-    num_q_blocks = seq_q // block_q
-    num_kv_blocks = seq_k // block_k
-    causal_offset = seq_k - seq_q
-    schedules, steps = _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion)
-    schedule, kv_steps = schedules["kv"], steps["kv"]
+    table = _tile_table(seq_q, seq_k, block_q, block_k, by="q", causal=causal, window=window,
+                        block_diffusion=block_diffusion)
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -1035,24 +857,14 @@ def _flash_forward(
         causal=causal,
         block_q=block_q,
         block_k=block_k,
-        num_kv_blocks=num_kv_blocks,
         precision=precision,
-        causal_offset=causal_offset,
+        causal_offset=seq_k - seq_q,
         window=window,
-        num_steps=kv_steps,
         block_diffusion=block_diffusion,
     )
-    if block_diffusion is None:
-        kv_map = _kv_index_map(
-            causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
-        )
-    else:
-        kernel = _scheduled(kernel)
-        kv_map = _scheduled_kv_map(kv_steps, group)
     from jax.experimental.pallas import tpu as pltpu
 
-    # under a schedule every index map is handed its three prefetched arrays too
-    q_row = lambda i, j, kv, *_: (i, j, 0)
+    q_row, kv_map, chosen = _by_q_row(heads, group)
     operands = [qr, kr, vr]
     in_specs = [
         pl.BlockSpec((1, block_q, dim), q_row),
@@ -1060,18 +872,14 @@ def _flash_forward(
         pl.BlockSpec((1, block_k, v_dim), kv_map),
     ]
     if selection is not None:
-        # the batch row's one tile for each of its heads, at the kv block the
-        # K / V maps name (a skipped step fetches none)
-        kernel = _selected(kernel, len(operands))
+        kernel = _selected(kernel, 1 + len(operands))
         operands.append(selection)
-        in_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda i, j, kv: (i // heads, j, kv_map(i, j, kv)[1])
-        ))
+        in_specs.append(pl.BlockSpec((1, block_q, block_k), chosen))
     out, lse = pl.pallas_call(
         kernel,
-        **_grid(
-            schedule,
-            grid=(bh, num_q_blocks, kv_steps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,      # the table, ahead of the operands
+            grid=(bh, len(table)),
             in_specs=in_specs,
             out_specs=[
                 pl.BlockSpec((1, block_q, v_dim), q_row),
@@ -1088,7 +896,7 @@ def _flash_forward(
             jax.ShapeDtypeStruct((bh, seq_q, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(*schedule, *operands)
+    )(table, *operands)
     return out.reshape(batch, heads, seq_q, v_dim), lse.reshape(
         batch, heads, seq_q
     )
@@ -1126,30 +934,16 @@ def _flash_backward(
         axis=-1,
         keepdims=True,
     )
-    num_q_blocks = seq_q // block_q
-    num_kv_blocks = seq_k // block_k
-    causal_offset = seq_k - seq_q
-    schedules, steps = _walks(seq_q, seq_k, block_q, block_k, window, block_diffusion)
+    mask = dict(causal=causal, window=window, block_diffusion=block_diffusion)
+    static = dict(scale=scale, block_q=block_q, block_k=block_k, precision=precision,
+                  causal_offset=seq_k - seq_q, **mask)
 
     from jax.experimental.pallas import tpu as pltpu
 
-    dq_kernel = functools.partial(
-        _flash_dq_kernel,
-        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        num_kv_blocks=num_kv_blocks, precision=precision,
-        causal_offset=causal_offset, window=window, num_steps=steps["kv"],
-        block_diffusion=block_diffusion,
-    )
-    if block_diffusion is None:
-        kv_map = _kv_index_map(
-            causal, causal_offset, block_q, block_k, num_kv_blocks, window, group
-        )
-    else:
-        dq_kernel = _scheduled(dq_kernel)
-        kv_map = _scheduled_kv_map(steps["kv"], group)
+    dq_kernel = functools.partial(_flash_dq_kernel, **static)
     operands = [qr, kr, vr, dor, lser, delta]
     selected = () if selection is None else (selection,)
-    q_row = lambda i, j, kv, *_: (i, j, 0)
+    q_row, kv_map, chosen = _by_q_row(heads, group)
     dq_specs = [
         pl.BlockSpec((1, block_q, dim), q_row),
         pl.BlockSpec((1, block_k, dim), kv_map),
@@ -1159,38 +953,27 @@ def _flash_backward(
         pl.BlockSpec((1, block_q, 1), q_row),
     ]
     if selection is not None:
-        dq_kernel = _selected(dq_kernel, len(operands))
-        dq_specs.append(pl.BlockSpec(
-            (1, block_q, block_k), lambda i, j, kv: (i // heads, j, kv_map(i, j, kv)[1])
-        ))
+        dq_kernel = _selected(dq_kernel, 1 + len(operands))
+        dq_specs.append(pl.BlockSpec((1, block_q, block_k), chosen))
+    table = _tile_table(seq_q, seq_k, block_q, block_k, by="q", **mask)
     dq = pl.pallas_call(
         dq_kernel,
-        **_grid(
-            schedules["kv"],
-            grid=(bh, num_q_blocks, steps["kv"]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,      # the table, ahead of the operands
+            grid=(bh, len(table)),
             in_specs=dq_specs,
             out_specs=pl.BlockSpec((1, block_q, dim), q_row),
             scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bh, seq_q, dim), q.dtype),
         interpret=interpret,
-    )(*schedules["kv"], *operands, *selected)
+    )(table, *operands, *selected)
 
-    dkv_kernel = functools.partial(
-        _flash_dkv_kernel,
-        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        num_q_blocks=num_q_blocks, precision=precision,
-        causal_offset=causal_offset, window=window, num_steps=steps["q"],
-        group=group, block_diffusion=block_diffusion,
-    )
-    if block_diffusion is None:
-        q_map = _q_index_map(
-            causal, causal_offset, block_q, block_k, num_q_blocks, window, group
-        )
-    else:
-        dkv_kernel = _scheduled(dkv_kernel)
-        q_map = _scheduled_q_map(steps["q"], group)
-    kv_row = lambda i, j, *g_qi: (i, j, 0)
+    # grid step (i, t, g): K / V row i at the kv tile of the table's entry t,
+    # query row i * group + g at its q tile
+    dkv_kernel = functools.partial(_flash_dkv_kernel, group=group, **static)
+    q_map = lambda i, t, g, table: (i * group + g, _entry(table, t)[1], 0)
+    kv_row = lambda i, t, g, table: (i, _entry(table, t)[0], 0)
     dkv_specs = [
         pl.BlockSpec((1, block_q, dim), q_map),
         pl.BlockSpec((1, block_k, dim), kv_row),
@@ -1200,17 +983,18 @@ def _flash_backward(
         pl.BlockSpec((1, block_q, 1), q_map),
     ]
     if selection is not None:
-        dkv_kernel = _selected(dkv_kernel, len(operands))
-        dkv_specs.append(pl.BlockSpec(
-            (1, block_q, block_k),
-            lambda i, j, *g_qi: (i // kv_heads, q_map(i, j, *g_qi)[1], j),
-        ))
+        dkv_kernel = _selected(dkv_kernel, 1 + len(operands))
+        def chosen(i, t, g, table):
+            kv_tile, q_tile = _entry(table, t)[:2]
+            return (i // kv_heads, q_tile, kv_tile)
+
+        dkv_specs.append(pl.BlockSpec((1, block_q, block_k), chosen))
+    table = _tile_table(seq_q, seq_k, block_q, block_k, by="kv", **mask)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        **_grid(
-            schedules["q"],
-            # one K / V row a KV head; a group axis only where a group is
-            grid=(bkv, num_kv_blocks, *((group,) if group > 1 else ()), steps["q"]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bkv, len(table), group),
             in_specs=dkv_specs,
             out_specs=[
                 pl.BlockSpec((1, block_k, dim), kv_row),
@@ -1226,7 +1010,7 @@ def _flash_backward(
             jax.ShapeDtypeStruct((bkv, seq_k, v_dim), v.dtype),
         ],
         interpret=interpret,
-    )(*schedules["q"], *operands, *selected)
+    )(table, *operands, *selected)
 
     return (
         dq.reshape(q.shape),

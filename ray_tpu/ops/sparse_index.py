@@ -34,11 +34,12 @@ eight slabs of lanes, each a shift and a mask written where it belongs
 (``_unpack``), in the forward and again in a checkpointed layer's backward.
 
 The term is two Pallas kernels written as ``ops/flash_attention.py``'s are
-(causal tiles ``[block_q, block_k]`` of pairs, a tile past the edge skipped
-and not fetched, the selection's int8 tile an operand, the MXU's operands in
-the inputs' dtype with float32 accumulators, everything else float32), both
-query-major over one sequential grid ``(batch, row blocks, key steps)``, so
-that nothing ``[.., rows, keys]`` in float32 reaches HBM::
+(causal tiles ``[block_q, block_k]`` of pairs, the grid's steps the tiles at
+or under the edge and no other, from the flash kernels' own prefetched table
+(``_tile_table``), the selection's int8 tile an operand, the MXU's operands
+in the inputs' dtype with float32 accumulators, everything else float32),
+both query-major over one sequential grid ``(batch, causal tiles)``, so that
+nothing ``[.., rows, keys]`` in float32 reaches HBM::
 
     _index_loss_lse    lseI[t] = log sum_{s in S[t]} exp I[t, s]     (the scorer alone)
     _index_loss_terms  per tile: P_j, I, pbar from the heads' products, the
@@ -76,7 +77,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import flash_attention as _flash
 from ray_tpu.ops.flash_attention import (
-    _LANES, _NEG_INF, _across, _chosen, _kv_index_map, _mxu, _tile_needed,
+    _LANES, _NEG_INF, _across, _chosen, _entry, _mxu, _tile_table,
 )
 
 # What a checkpointed layer may keep (``checkpoint_name``): the selection,
@@ -334,92 +335,88 @@ def _tile_scores(qi_ref, ki, w, precision):
     )
 
 
-def _index_lse_kernel(qi_ref, ki_ref, w_ref, sel_ref, lse_ref, m_scr, l_scr, *,
+def _index_lse_kernel(table_ref, qi_ref, ki_ref, w_ref, sel_ref, lse_ref, m_scr, l_scr, *,
                       block_q, block_k, precision):
     """``lseI[t]``, the log-sum-exp of ``I[t, .]`` over ``S[t]``: the flash
-    forward's running maximum and sum over the key steps of a row block."""
-    q_index, step = pl.program_id(1), pl.program_id(2)
+    forward's running maximum and sum over the key tiles of a row block."""
+    q_index, kv_index, first, last, _ = _entry(table_ref, pl.program_id(1))
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    @pl.when(_tile_needed(True, 0, q_index, step, block_q, block_k))
-    def _compute():
-        scores = _tile_scores(qi_ref, _mxu(ki_ref[0], precision), w_ref[0], precision)
-        scores = jnp.where(_visible(sel_ref, q_index, step, block_q, block_k), scores, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
-        l_scr[:] = jnp.exp(m_prev - m_new) * l_scr[:] + jnp.sum(
-            jnp.exp(scores - _across(m_new, block_k)), axis=1, keepdims=True
-        )
-        m_scr[:] = m_new
+    scores = _tile_scores(qi_ref, _mxu(ki_ref[0], precision), w_ref[0], precision)
+    scores = jnp.where(_visible(sel_ref, q_index, kv_index, block_q, block_k), scores, _NEG_INF)
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+    l_scr[:] = jnp.exp(m_prev - m_new) * l_scr[:] + jnp.sum(
+        jnp.exp(scores - _across(m_new, block_k)), axis=1, keepdims=True
+    )
+    m_scr[:] = m_new
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finalize():
         lse_ref[0] = (m_scr[:] + jnp.log(l_scr[:]))[:, :1]
 
 
-def _index_loss_kernel(qi_ref, qit_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, lsei_ref, sel_ref,
-                       rows_ref, dqi_ref, dw_ref, dkit_ref, rows_scr, dqi_scr, dw_scr, *,
+def _index_loss_kernel(table_ref, qi_ref, qit_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, lsei_ref,
+                       sel_ref, rows_ref, dqi_ref, dw_ref, dkit_ref, rows_scr, dqi_scr, dw_scr, *,
                        scale, inv_tokens, block_q, block_k, precision):
     """A tile of the term and of its three gradients (module docstring):
     ``I`` and ``pbar`` from their products, the tile's terms summed by row,
     ``dI``, and the index heads' products once more for ``dw``, ``dqI`` (a row
     block's scratch) and ``dkI`` (TRANSPOSED, ``[key blocks, dim, block_k]``
     float32: the whole row's, resident across the sequential grid)."""
-    q_index, step = pl.program_id(1), pl.program_id(2)
+    q_index, kv_index, first, last, _ = _entry(table_ref, pl.program_id(1))
     index_heads, heads, kv_heads = qi_ref.shape[1], q_ref.shape[1], k_ref.shape[1]
     group = heads // kv_heads
 
-    @pl.when((q_index == 0) & (step == 0))
+    @pl.when(pl.program_id(1) == 0)
     def _init_row():
         dkit_ref[...] = jnp.zeros_like(dkit_ref)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         rows_scr[:] = jnp.zeros_like(rows_scr)
         dqi_scr[:] = jnp.zeros_like(dqi_scr)
         dw_scr[:] = jnp.zeros_like(dw_scr)
 
-    @pl.when(_tile_needed(True, 0, q_index, step, block_q, block_k))
-    def _compute():
-        visible = _visible(sel_ref, q_index, step, block_q, block_k)
-        ki = _mxu(ki_ref[0], precision)
-        w = w_ref[0]
-        scores = _tile_scores(qi_ref, ki, w, precision)
+    visible = _visible(sel_ref, q_index, kv_index, block_q, block_k)
+    ki = _mxu(ki_ref[0], precision)
+    w = w_ref[0]
+    scores = _tile_scores(qi_ref, ki, w, precision)
 
-        def exponentials():
-            for g in range(kv_heads):
-                k = _mxu(k_ref[0, g], precision)
-                lse = lse_ref[0, g]                              # [block_q, group]
-                for a in range(group):
-                    q = _mxu(q_ref[0, g * group + a], precision)
-                    yield jnp.exp(_dot(q, k, (1, 1), precision) * scale - lse[:, a:a + 1])
+    def exponentials():
+        for g in range(kv_heads):
+            k = _mxu(k_ref[0, g], precision)
+            lse = lse_ref[0, g]                              # [block_q, group]
+            for a in range(group):
+                q = _mxu(q_ref[0, g * group + a], precision)
+                yield jnp.exp(_dot(q, k, (1, 1), precision) * scale - lse[:, a:a + 1])
 
-        pbar = jnp.where(visible, _sum(exponentials()) / heads, 0.0)
-        log_scorer = scores - lsei_ref[0]
-        # xlogy(pbar, pbar): 0 where the exponentials underflowed
-        entropy = jnp.where(pbar > 0.0, pbar * jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)), 0.0)
-        rows_scr[:] = rows_scr[:] + _by_lane(jnp.where(visible, entropy - pbar * log_scorer, 0.0))
-        d_scores = jnp.where(visible, jnp.exp(log_scorer) - pbar, 0.0)
+    pbar = jnp.where(visible, _sum(exponentials()) / heads, 0.0)
+    log_scorer = scores - lsei_ref[0]
+    # xlogy(pbar, pbar): 0 where the exponentials underflowed
+    entropy = jnp.where(pbar > 0.0, pbar * jnp.log(jnp.where(pbar > 0.0, pbar, 1.0)), 0.0)
+    rows_scr[:] = rows_scr[:] + _by_lane(jnp.where(visible, entropy - pbar * log_scorer, 0.0))
+    d_scores = jnp.where(visible, jnp.exp(log_scorer) - pbar, 0.0)
 
-        for j in range(index_heads):
-            products = _tile_products(qi_ref, j, ki, precision)
-            dw_scr[j] = dw_scr[j] + _by_lane(d_scores * jnp.maximum(products, 0.0))
-            # rounded to the operands' dtype for the MXU, as the flash
-            # backward's ``ds`` and as XLA's default precision did the walk's
-            d_products = _mxu(
-                jnp.where(products > 0.0, d_scores * w[:, j:j + 1], 0.0).astype(ki_ref.dtype),
-                precision,
-            )
-            dqi_scr[j] = dqi_scr[j] + _dot(d_products, ki, (1, 0), precision)
-            dkit_ref[0, step] = dkit_ref[0, step] + _dot(      # [dim, block_k]
-                _mxu(qit_ref[0, j], precision), d_products, (1, 0), precision
-            )
+    for j in range(index_heads):
+        products = _tile_products(qi_ref, j, ki, precision)
+        dw_scr[j] = dw_scr[j] + _by_lane(d_scores * jnp.maximum(products, 0.0))
+        # rounded to the operands' dtype for the MXU, as the flash
+        # backward's ``ds`` and as XLA's default precision did the walk's
+        d_products = _mxu(
+            jnp.where(products > 0.0, d_scores * w[:, j:j + 1], 0.0).astype(ki_ref.dtype),
+            precision,
+        )
+        dqi_scr[j] = dqi_scr[j] + _dot(d_products, ki, (1, 0), precision)
+        dkit_ref[0, kv_index] = dkit_ref[0, kv_index] + _dot(   # [dim, block_k]
+            _mxu(qit_ref[0, j], precision), d_products, (1, 0), precision
+        )
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finalize():
         rows_ref[0] = jnp.sum(rows_scr[:], axis=1, keepdims=True)
         dqi_ref[0] = (dqi_scr[:] * inv_tokens).astype(dqi_ref.dtype)
@@ -430,12 +427,28 @@ def _index_loss_kernel(qi_ref, qit_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, ls
         dw_ref[0] = (dw * inv_tokens).astype(dw_ref.dtype)
 
 
-def _tiles(seq, block_q, block_k):
-    """The grid's key axis and the key block of step ``(b, i, s)``: a row
-    block's skipped steps name its last needed block, which is resident."""
-    kv_blocks = seq // block_k
-    kv_map = _kv_index_map(True, 0, block_q, block_k, kv_blocks)
-    return kv_blocks, lambda b, i, s: kv_map(b, i, s)[1]
+def _tiles(batch, seq, block_q, block_k, operands, results, scratch):
+    """A walk of the causal tiles: the flash kernels' table of them
+    (``_tile_table``: by row block, a row's key tiles ascending; a grid step
+    is a tile that runs) and ``pallas_call``'s grid arguments with it
+    prefetched, the grid ``(batch, its entries)``. An operand or result
+    ``(block, dtype, at)`` names its block by ``at(b, i, s)``: batch row, row
+    block, key block."""
+    table = _tile_table(seq, seq, block_q, block_k, by="q")
+
+    def spec(block, _, at):
+        return pl.BlockSpec(block, lambda b, t, table: at(b, *_entry(table, t)[:2]))
+
+    return table, dict(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch, len(table)),
+            in_specs=[spec(*one) for one in operands], out_specs=[spec(*one) for one in results],
+            scratch_shapes=[pltpu.VMEM(*one) for one in scratch],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit([*operands, *results], scratch, block_q, block_k)
+        ),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret", "precision"))
@@ -443,28 +456,22 @@ def _index_loss_lse(qi, k_index, w, selection, *, block_q, block_k, interpret, p
     """``lseI`` ``[batch, seq, 1]`` float32; ``qi`` ``[batch, index heads,
     seq, dim]``."""
     batch, index_heads, seq, dim = qi.shape
-    kv_blocks, kv_block = _tiles(seq, block_q, block_k)
     by_row = lambda b, i, s: (b, i, 0)
     operands = [
         ((1, index_heads, block_q, dim), qi.dtype, lambda b, i, s: (b, 0, i, 0)),
-        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, kv_block(b, i, s), 0)),
+        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, s, 0)),
         ((1, block_q, index_heads), w.dtype, by_row),
-        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, kv_block(b, i, s))),
+        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, s)),
     ]
-    result = ((1, block_q, 1), jnp.float32, by_row)
+    results = [((1, block_q, 1), jnp.float32, by_row)]
     scratch = [((block_q, _LANES), jnp.float32)] * 2
+    table, tiles = _tiles(batch, seq, block_q, block_k, operands, results, scratch)
     return pl.pallas_call(
         functools.partial(_index_lse_kernel, block_q=block_q, block_k=block_k, precision=precision),
-        grid=(batch, seq // block_q, kv_blocks),
-        in_specs=[pl.BlockSpec(block, at) for block, _, at in operands],
-        out_specs=pl.BlockSpec(result[0], result[2]),
-        out_shape=jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32),
-        scratch_shapes=[pltpu.VMEM(*one) for one in scratch],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit([*operands, result], scratch, block_q, block_k)
-        ),
+        out_shape=[jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32)],
         interpret=interpret,
-    )(qi, k_index, w, selection)
+        **tiles,
+    )(table, qi, k_index, w, selection)[0]
 
 
 @functools.partial(
@@ -480,19 +487,19 @@ def _index_loss_terms(qi, k_index, w, q, k, selection, lse, lse_index, *, scale,
     batch, index_heads, seq, dim = qi.shape
     heads, head_dim = q.shape[1], q.shape[3]
     kv_heads, group = lse.shape[1], lse.shape[3]
-    kv_blocks, kv_block = _tiles(seq, block_q, block_k)
+    kv_blocks = seq // block_k
     by_row = lambda b, i, s: (b, i, 0)
     by_head_row = lambda b, i, s: (b, 0, i, 0)
     operands = [
         ((1, index_heads, block_q, dim), qi.dtype, by_head_row),
         ((1, index_heads, dim, block_q), qi.dtype, lambda b, i, s: (b, 0, 0, i)),
-        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, kv_block(b, i, s), 0)),
+        ((1, block_k, dim), k_index.dtype, lambda b, i, s: (b, s, 0)),
         ((1, block_q, index_heads), w.dtype, by_row),
         ((1, heads, block_q, head_dim), q.dtype, by_head_row),
-        ((1, kv_heads, block_k, head_dim), k.dtype, lambda b, i, s: (b, 0, kv_block(b, i, s), 0)),
+        ((1, kv_heads, block_k, head_dim), k.dtype, lambda b, i, s: (b, 0, s, 0)),
         ((1, kv_heads, block_q, group), lse.dtype, by_head_row),
         ((1, block_q, 1), lse_index.dtype, by_row),
-        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, kv_block(b, i, s))),
+        ((1, block_q, block_k), selection.dtype, lambda b, i, s: (b, i, s)),
     ]
     results = [
         ((1, block_q, 1), jnp.float32, by_row),
@@ -505,26 +512,21 @@ def _index_loss_terms(qi, k_index, w, q, k, selection, lse, lse_index, *, scale,
         ((block_q, lanes), jnp.float32), ((index_heads, block_q, dim), jnp.float32),
         ((index_heads, block_q, lanes), jnp.float32),
     ]
+    table, tiles = _tiles(batch, seq, block_q, block_k, operands, results, scratch)
     return pl.pallas_call(
         functools.partial(
             _index_loss_kernel, scale=scale, inv_tokens=1.0 / (batch * seq), block_q=block_q,
             block_k=block_k, precision=precision,
         ),
-        grid=(batch, seq // block_q, kv_blocks),
-        in_specs=[pl.BlockSpec(block, at) for block, _, at in operands],
-        out_specs=[pl.BlockSpec(block, at) for block, _, at in results],
         out_shape=[
             jax.ShapeDtypeStruct((batch, seq, 1), jnp.float32),
             jax.ShapeDtypeStruct(qi.shape, qi.dtype),
             jax.ShapeDtypeStruct(w.shape, w.dtype),
             jax.ShapeDtypeStruct((batch, kv_blocks, dim, block_k), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM(*one) for one in scratch],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit([*operands, *results], scratch, block_q, block_k)
-        ),
         interpret=interpret,
-    )(qi, jnp.swapaxes(qi, 2, 3), k_index, w, q, k, lse, lse_index, selection)
+        **tiles,
+    )(table, qi, jnp.swapaxes(qi, 2, 3), k_index, w, q, k, lse, lse_index, selection)
 
 
 def _loss_and_grads(q_index, k_index, w, q, k, selection, lse, scale, block_q, block_k,

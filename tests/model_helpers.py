@@ -216,3 +216,35 @@ def flash_mosaic_modules(kv_heads=2, selection=False, **mode) -> list[str]:
             module = ir.Module.parse(base64.b64decode(body))
             modules.append(module.operation.get_asm(enable_debug_info=False))
     return modules
+
+
+def flash_tile_tables(mask, block_q, block_k, **mode) -> dict[str, list[tuple]]:
+    """Both orientations of the flash kernels' ``_tile_table`` under ``mode``
+    held to the explicit ``mask`` ``[seq_q, seq_k]`` of bool by enumeration:
+    every tile that holds a visible pair ONCE, row-major (``"q"``: by q tile,
+    fwd and dq; ``"kv"``: by kv tile, dkv), a row's tiles ascending,
+    ``first`` and ``last`` on a row's first and last entry and nowhere else,
+    ``subs`` the tile's sub-blocks that hold a visible pair (bit ``a *
+    parts_k + b``, q part ``a``, kv part ``b``, in either orientation); a row
+    with no such tile keeps one entry that runs nothing. Returns the entries
+    ``(row, col, first, last, subs)`` by orientation."""
+    from ray_tpu.ops import flash_attention as flash
+
+    seq_q, seq_k = mask.shape
+    sub_q, sub_k = flash._sub_block(block_q), flash._sub_block(block_k)
+    parts_q, parts_k = block_q // sub_q, block_k // sub_k
+    held = mask.reshape(seq_q // block_q, parts_q, sub_q, seq_k // block_k, parts_k, sub_k).any(axis=(2, 5))
+    bits = sum(held[:, a, :, b].astype(int) << (a * parts_k + b)
+               for a in range(parts_q) for b in range(parts_k))
+    tables = {}
+    for by, wanted in (("q", bits), ("kv", bits.T)):
+        table = flash._tile_table(seq_q, seq_k, block_q, block_k, by=by, **mode)
+        assert table.dtype == np.int32 and table.ndim == 1
+        expected = []
+        for row, line in enumerate(wanted):
+            cols = np.flatnonzero(line)
+            expected += [(row, int(col), int(col == cols[0]), int(col == cols[-1]), int(line[col]))
+                         for col in cols] or [(row, 0, 1, 1, 0)]
+        tables[by] = [tuple(int(field) for field in flash._entry(table, step)) for step in range(len(table))]
+        assert tables[by] == expected, by
+    return tables

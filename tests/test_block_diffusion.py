@@ -27,7 +27,7 @@ from ray_tpu.ops import flash_attention as flash
 from ray_tpu.ops.flash_attention import attention_reference, flash_attention
 
 import model_helpers
-from model_helpers import close, flash_mosaic_modules, listed
+from model_helpers import close, flash_mosaic_modules, flash_tile_tables, listed
 
 SEQ, BLOCK = 32, 4
 CONFIG = {
@@ -141,19 +141,16 @@ def test_the_tile_counts_and_the_walk_are_the_enumeration_s(clean_len, block, bl
     assert counts == {
         "skipped": int((~needed).sum()), "executed": int(needed.sum()),
         "allowed_pairs": int(mask.sum()), "executed_pairs": int(held.sum()) * sub_q * sub_k,
+        "grid_steps": int(needed.sum()),
     }
     assert counts["allowed_pairs"] == clean_len ** 2 + clean_len * block
-    walks = flash._block_diffusion_schedule(clean_len, block, block_q, block_k)
-    for axis, wanted, parts in (("kv", needed, held), ("q", needed.T, held.transpose(1, 0, 2, 3))):
-        tiles, row_counts, subs, steps = walks[axis]
-        assert steps == wanted.sum(axis=1).max() and tiles.shape == subs.shape == (wanted.shape[0] * steps,)
-        for row, (taken, count) in enumerate(zip(tiles.reshape(-1, steps), row_counts)):
-            assert list(taken[:count]) == list(np.flatnonzero(wanted[row]))   # each needed tile once, in order
-            assert np.all(taken[count:] == taken[count - 1])                  # then the one already resident
-            for step in range(count):                                         # bit a * parts_k + b: part (a, b)
-                bits = int(subs[row * steps + step])
-                got = [[bits >> (a * parts_k + b) & 1 for b in range(parts_k)] for a in range(parts_q)]
-                assert bits and np.array_equal(got, parts[row, taken[step]]), (axis, row, step)
+    # the table of either orientation: each needed tile once, a row's in order, its sub-blocks' bits
+    tables = flash_tile_tables(mask, block_q, block_k, causal=False, block_diffusion=(clean_len, block))
+    assert counts["grid_steps"] == len(tables["q"]) == len(tables["kv"]) == counts["executed"]
+    assert all(bits for _, _, _, _, bits in tables["q"] + tables["kv"])
+    for row, col, _, _, bits in tables["q"]:                      # bit a * parts_k + b: part (a, b)
+        got = [[bits >> (a * parts_k + b) & 1 for b in range(parts_k)] for a in range(parts_q)]
+        assert np.array_equal(got, held[row, col]), (row, col)
 
 
 def test_the_cell_s_walk():
@@ -164,29 +161,39 @@ def test_the_cell_s_walk():
     tiles 2: 72 tiles' worth of pairs for the 64.03 the mask allows."""
     counts = flash.block_diffusion_tile_counts(8192, 4, 1024, 1024)
     assert counts == {"skipped": 176, "executed": 80, "allowed_pairs": 8192 ** 2 + 8192 * 4,
-                      "executed_pairs": (80 * 4 - 16 - 2 * 8) * 512 ** 2}
+                      "executed_pairs": (80 * 4 - 16 - 2 * 8) * 512 ** 2, "grid_steps": 80}
     assert 100 * counts["allowed_pairs"] / counts["executed_pairs"] == pytest.approx(88.9, abs=0.05)
     assert flash.causal_tile_counts(16384, 16384, 1024, 1024)["executed"] == 136
-    walks = flash._block_diffusion_schedule(8192, 4, 1024, 1024)
-    assert list(walks["kv"][1]) == [*range(1, 9), *range(2, 10)] and walks["kv"][3] == 9
+    rows = lambda by: [[entry for entry in _entries(by) if entry[0] == row] for row in range(16)]
+    assert [len(row) for row in rows("q")] == [*range(1, 9), *range(2, 10)]
     # a clean key tile: the clean rows from it on and the noised rows behind it; a noised one: one
-    assert list(walks["q"][1]) == [2 * (8 - c) for c in range(8)] + [1] * 8 and walks["q"][3] == 16
+    assert [len(row) for row in rows("kv")] == [2 * (8 - c) for c in range(8)] + [1] * 8
     # q row 9 (noised rows 1024 ..): clean tile 0 whole, clean tile 1 its lower half, its own noised diagonal
-    assert list(walks["kv"][2][9 * 9: 9 * 9 + 3]) == [0b1111, 0b1101, 0b1001]
+    assert [(col, bits) for _, col, _, _, bits in rows("q")[9]] == [(0, 0b1111), (1, 0b1101), (9, 0b1001)]
+    # the grid: 80 steps a head in all three kernels, where the longest rows walked 16 x 9 and 16 x 16
+    assert counts["grid_steps"] == len(_entries("q")) == len(_entries("kv")) == 80
+
+
+def _entries(by):
+    table = flash._tile_table(16384, 16384, 1024, 1024, by=by, causal=False, block_diffusion=(8192, 4))
+    return [tuple(int(field) for field in flash._entry(table, step)) for step in range(len(table))]
 
 
 # -- the three older masks lower to what they did -----------------------------
 # sha256 of the three Mosaic modules of ``jax.grad(flash_attention)`` (fwd,
 # dq, dkv; [1, 4 / 2, 256, 128] bfloat16), lowered for a TPU, parsed and
-# printed WITHOUT source locations, by mode. PR 59 pinned what ITS parent
-# lowered, to show the fourth mask's arrival changed none; PR 60 changed the
-# kernels on purpose (a 256 x 256 tile a mask cuts is walked in sub-blocks of
-# 128) and these are what its tree lowers. A change to the kernels that is
-# meant changes these lines; one that is not must not.
+# printed WITHOUT source locations, by mode. What they hold: that ONE mask's
+# arrival or change leaves the others' modules alone, not that the modules
+# never change. PR 59 pinned what ITS parent lowered, to show the fourth
+# mask's arrival changed none; PR 60 changed the kernels on purpose (a 256 x
+# 256 tile a mask cuts is walked in sub-blocks of 128); PR 61 again (every
+# mask's grid is its prefetched table of tiles: ``_tile_table``) and these are
+# what its tree lowers. A change to the kernels that is meant changes these
+# lines; one that is not must not.
 MODULES_OF_THE_PARENT = {
-    "causal": "c77e71cbae6d20c7ebffa3a501ebcd3694841f86f312967ffa8bb1fba9a6e10f",
-    "window": "2d53cd7477f663d1a6d1cf4a9815c950081adebb8b341865befad5061c5bd43a",
-    "selection": "b2d0a0883fd7c1eedc4bc7529d4e8ce90b10ba3322848f0201d64a66f3c76ca2",
+    "causal": "cee61e46bb6d140a815cf836607ff0a5377bf4dce77da9d0468e2863488430a3",
+    "window": "ebca63f44de39cc43cd377ca9544cfb495d8873193574259cca05401e685ce8b",
+    "selection": "3f73944085b5ef09926e5236639819a60ed3c3170f6d54c529ce9ba93507f6f0",
 }
 
 

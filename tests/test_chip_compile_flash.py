@@ -148,15 +148,15 @@ def test_flash_at_head_size_64_compiles_for_v5e(one_chip):
 def test_flash_under_a_window_compiles_for_v5e(one_chip):
     """The window layers' call of ``smallthinker-21b-a3b``, ``[1, 28, 16384,
     128]`` in bfloat16 under a window of 4096 keys: forward, and forward + dq
-    + dkv, compile for the chip in the 1024 x 1024 blocks (the index maps'
-    two clamps and the second mask are scalar and vector code Mosaic has to
-    take); the band is 70 of a head's 256 tiles, walked in a grid of 16 x 5
-    steps."""
-    from ray_tpu.ops.flash_attention import _block_sizes, band_steps, causal_tile_counts
+    + dkv, compile for the chip in the 1024 x 1024 blocks (the table's reads
+    in the index maps and the second mask are scalar and vector code Mosaic
+    has to take); the band is 70 of a head's 256 tiles, and the grid walks
+    those 70 and no other step."""
+    from ray_tpu.ops.flash_attention import _block_sizes, causal_tile_counts
 
     blocks = _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16)
-    assert causal_tile_counts(16384, 16384, *blocks, 4096)["executed"] == 70
-    assert band_steps(16384, 16384, *blocks, 4096) == {"kv": 5, "q": 5}
+    counts = causal_tile_counts(16384, 16384, *blocks, 4096)
+    assert counts["executed"] == counts["grid_steps"] == 70
     shapes = [jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16, sharding=one_chip)] * 3
     windowed = functools.partial(_flash, window=4096)
     assert custom_calls(windowed, *shapes) == 1

@@ -11,6 +11,8 @@ from ray_tpu.ops.flash_attention import attention_reference, flash_attention
 from ray_tpu.ops.rmsnorm import rmsnorm, rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
+from model_helpers import flash_tile_tables
+
 
 def _grouped_reference(q, k, v, **kwargs):
     """``attention_reference`` on K and V repeated by hand to q's heads: the
@@ -204,38 +206,18 @@ def _executed_pairs(mask, block_q, block_k):
     return int(held.sum()) * sub_q * sub_k
 
 
-def _grouped_maps(kv_map, q_map, group):
-    """Both index maps as ``(j, step) -> block``, the rows they name held to
-    the grouping on the way: query row ``i = b * heads + h`` reads K / V row
-    ``i // group``; in the dkv grid K / V row ``i``'s step ``g`` of the group
-    axis (absent at a group of 1) names query row ``i * group + g``. The
-    BLOCK a step names does not depend on the row, so the callers hold it to
-    ``_tile_needed`` for every row at once."""
-    def kv_block(j, step):
-        rows, blocks = zip(*(kv_map(i, j, step)[:2] for i in range(2 * group)))
-        assert list(rows) == [i // group for i in range(2 * group)]
-        assert len({int(block) for block in blocks}) == 1
-        return int(blocks[0])
-
-    def q_block(j, step):
-        steps = [(g,) for g in range(group)] if group > 1 else [()]
-        named = [q_map(i, j, *g, step)[:2] for i in range(2) for g in steps]
-        assert [row for row, _ in named] == list(range(2 * group))
-        assert len({int(block) for _, block in named}) == 1
-        return int(named[0][1])
-
-    return kv_block, q_block
-
-
-@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("by", ["q", "kv"])
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k", _TILE_CASES)
-def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, group):
+def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, by):
+    """``_tile_needed``, the table a call prefetches (``by="q"``: fwd and dq,
+    ``"kv"``: dkv; it does not depend on the group, whose heads are the dkv
+    grid's innermost axis) and ``causal_tile_counts`` against the mask itself:
+    a grid step is a tile that holds a visible pair, each once, and there is
+    no other step but the one of a row that sees nothing (``seq_q > seq_k``:
+    the first q rows)."""
     import numpy as np
 
-    from ray_tpu.ops.flash_attention import (
-        _block_sizes, _kv_index_map, _q_index_map, _tile_needed,
-        causal_tile_counts,
-    )
+    from ray_tpu.ops.flash_attention import _block_sizes, _tile_needed, causal_tile_counts
 
     block_q, block_k = _block_sizes(
         seq_q, seq_k, block_q, block_k, 128, jnp.bfloat16)
@@ -247,34 +229,24 @@ def test_causal_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, gro
               kv * block_k:(kv + 1) * block_k].any() for kv in range(nk)]
         for j in range(nq)
     ])
-    kv_block, q_block = _grouped_maps(
-        _kv_index_map(True, offset, block_q, block_k, nk, group=group),
-        _q_index_map(True, offset, block_q, block_k, nq, group=group), group)
     for j in range(nq):
         for kv in range(nk):
             assert _tile_needed(
                 True, offset, j, kv, block_q, block_k) == needed[j, kv], (j, kv)
-            # An executed step fetches its own blocks; a skipped one names
-            # a block of the same row that is needed (or, in a row with
-            # none, one block for the whole row): no new fetch.
-            fetched_kv = kv_block(j, kv)
-            fetched_q = q_block(kv, j)
-            if needed[j, kv]:
-                assert (fetched_kv, fetched_q) == (kv, j)
-            else:
-                row = np.flatnonzero(needed[j])
-                col = np.flatnonzero(needed[:, kv])
-                assert fetched_kv == (row[-1] if row.size else 0)
-                assert fetched_q == (col[0] if col.size else nq - 1)
+    entries = flash_tile_tables(mask, block_q, block_k)[by]
+    rows = needed if by == "q" else needed.T
+    assert len(entries) == int(needed.sum()) + int((~rows.any(axis=1)).sum())
     assert causal_tile_counts(seq_q, seq_k, block_q, block_k) == {
         "skipped": int((~needed).sum()), "executed": int(needed.sum()),
-        "executed_pairs": _executed_pairs(mask, block_q, block_k)}
+        "executed_pairs": _executed_pairs(mask, block_q, block_k),
+        "grid_steps": int(needed.sum()) + int((~needed.any(axis=1)).sum())}
 
 
-# ``executed_pairs``: a diagonal tile runs 3 of its 4 sub-blocks of 512 x 512
+# ``executed_pairs``: a diagonal tile runs 3 of its 4 sub-blocks of 512 x 512;
+# ``grid_steps``: the forward's grid walks the executed tiles and no other step
 @pytest.mark.parametrize("seq,counts", [
-    (16384, {"skipped": 120, "executed": 136, "executed_pairs": (136 * 4 - 16) * 512 ** 2}),
-    (4096, {"skipped": 6, "executed": 10, "executed_pairs": (10 * 4 - 4) * 512 ** 2}),
+    (16384, {"skipped": 120, "executed": 136, "executed_pairs": (136 * 4 - 16) * 512 ** 2, "grid_steps": 136}),
+    (4096, {"skipped": 6, "executed": 10, "executed_pairs": (10 * 4 - 4) * 512 ** 2, "grid_steps": 10}),
 ])
 def test_causal_tile_counts_of_the_benchmark_cells(seq, counts):
     """At the block shape the cells run (head_dim 128, bfloat16)."""
@@ -314,21 +286,16 @@ _WINDOW_TILE_CASES = [
 ]
 
 
-@pytest.mark.parametrize("group", [1, 4])
+@pytest.mark.parametrize("by", ["q", "kv"])
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,window", _WINDOW_TILE_CASES)
-def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, window, group):
-    """``_tile_needed``, ``band_steps``, both index maps and
-    ``causal_tile_counts`` under a window against the mask itself. The grid's
-    last index is a STEP along a row's band: the steps of a row reach every
-    needed tile of it, each fetching its own blocks, and a step outside the
-    band names a needed block of the row (its last): no fetch. Under a
-    ``group`` the band is per query head of the group what it is per head."""
+def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, window, by):
+    """``_tile_needed``, the table of either orientation and
+    ``causal_tile_counts`` under a window against the mask itself: the grid's
+    steps are the band's tiles, a row's in order, each once; a kv row no
+    query sees (``seq_q << seq_k``) keeps the one step that writes its zeros."""
     import numpy as np
 
-    from ray_tpu.ops.flash_attention import (
-        _first_kv_block, _first_q_block, _kv_index_map, _q_index_map, _tile_needed, band_steps,
-        causal_tile_counts,
-    )
+    from ray_tpu.ops.flash_attention import _tile_needed, causal_tile_counts
 
     nq, nk = seq_q // block_q, seq_k // block_k
     offset = seq_k - seq_q
@@ -342,51 +309,169 @@ def test_window_tile_skip_matches_dense_mask(seq_q, seq_k, block_q, block_k, win
         for kv in range(nk):
             assert bool(_tile_needed(
                 True, offset, j, kv, block_q, block_k, window)) == needed[j, kv], (j, kv)
-    steps = band_steps(seq_q, seq_k, block_q, block_k, window)
-    kv_block, q_block = _grouped_maps(
-        _kv_index_map(True, offset, block_q, block_k, nk, window, group),
-        _q_index_map(True, offset, block_q, block_k, nq, window, group), group)
-    for j in range(nq):                      # the fwd and dq kernels: a q row's steps
-        row = np.flatnonzero(needed[j])
-        first = int(_first_kv_block(offset, j, block_q, block_k, nk, window))
-        assert first == row[0] and row[-1] - row[0] + 1 <= steps["kv"]
-        for step in range(steps["kv"]):
-            fetched = kv_block(j, step)
-            assert fetched == min(first + step, row[-1]), (j, step)
-            if first + step < nk:            # what the kernel computes is what it fetched
-                assert (fetched == first + step) or not needed[j, first + step]
-    for kv in range(nk):                     # the dkv kernel: a kv row's steps
-        col = np.flatnonzero(needed[:, kv])
-        first = int(_first_q_block(offset, kv, block_q, block_k, nq))
-        fetched = [q_block(kv, step) for step in range(steps["q"])]
-        if not col.size:                     # a kv row no query sees: one q block, no fetch
-            assert len(set(fetched)) == 1
-            continue
-        assert first == col[0] and col[-1] - col[0] + 1 <= steps["q"]
-        assert fetched == [min(first + step, col[-1]) for step in range(steps["q"])]
-    assert max(len(np.flatnonzero(r)) for r in needed) <= steps["kv"] <= nk
-    assert max(len(np.flatnonzero(c)) for c in needed.T) <= steps["q"] <= nq
+    entries = flash_tile_tables(mask, block_q, block_k, window=window)[by]
+    rows = needed if by == "q" else needed.T
+    assert needed.any(axis=1).all()          # a q row sees its own position
+    assert len(entries) == int(needed.sum()) + int((~rows.any(axis=1)).sum())
     assert causal_tile_counts(seq_q, seq_k, block_q, block_k, window) == {
         "skipped": int((~needed).sum()), "executed": int(needed.sum()),
-        "executed_pairs": _executed_pairs(mask, block_q, block_k)}
+        "executed_pairs": _executed_pairs(mask, block_q, block_k), "grid_steps": int(needed.sum())}
 
 
 def test_window_tile_counts_of_the_benchmark_cell():
     """[16384, 16384] in 1024-blocks under a window of 4096: rows of 1, 2,
     3, 4, then twelve of 5 tiles; by pairs 43.75 % of the causal half."""
-    from ray_tpu.ops.flash_attention import _block_sizes, causal_tile_counts
-
-    from ray_tpu.ops.flash_attention import band_steps
+    from ray_tpu.ops.flash_attention import _block_sizes, _entry, _tile_table, causal_tile_counts
 
     blocks = _block_sizes(16384, 16384, None, None, 128, jnp.bfloat16)
-    # 16 diagonal tiles run 3 of their 4 sub-blocks, the 12 lower-edge tiles too
+    # 16 diagonal tiles run 3 of their 4 sub-blocks, the 12 lower-edge tiles too;
+    # the grid walks the band's 70 tiles a head, where a global layer walks 136
     assert causal_tile_counts(16384, 16384, *blocks, 4096) == {
-        "skipped": 186, "executed": 70, "executed_pairs": (70 * 4 - 16 - 12) * 512 ** 2}
-    # the grid walks 16 x 5 steps a head, 10 of them skipped, not 16 x 16
-    assert band_steps(16384, 16384, *blocks, 4096) == {"kv": 5, "q": 5}
+        "skipped": 186, "executed": 70, "executed_pairs": (70 * 4 - 16 - 12) * 512 ** 2,
+        "grid_steps": 70}
+    for by in ("q", "kv"):
+        table = _tile_table(16384, 16384, *blocks, by=by, window=4096)
+        rows = [int(_entry(table, step)[0]) for step in range(len(table))]
+        longest = max(rows.count(row) for row in set(rows))
+        assert (len(table), longest) == (70, 5)
     mask = _window_mask(16384, 16384, 4096)
     assert int(mask.sum()) == 58_722_304
     assert int(mask.sum()) / (16384 * 16385 // 2) == pytest.approx(0.4375, abs=2e-4)
+
+
+def test_a_call_without_a_mask_walks_every_tile():
+    """``causal=False`` and nothing else: every tile in the table of either
+    orientation, every sub-block of it, the step count it always had."""
+    import numpy as np
+
+    from ray_tpu.ops.flash_attention import _tile_table
+
+    for block_q, block_k in ((64, 32), (256, 512)):
+        tables = flash_tile_tables(np.ones((512, 1024), bool), block_q, block_k, causal=False)
+        assert len(tables["q"]) == len(tables["kv"]) == (512 // block_q) * (1024 // block_k)
+    # static and small: 4 bytes a tile, 8,256 entries for a causal 131,072 in 1024-tiles
+    assert _tile_table(131072, 131072, 1024, 1024, by="q").nbytes == 4 * 8256
+
+
+# The grid of each of the three calls IS its table: ``(batch * heads, T)`` for
+# fwd and dq, ``(batch * kv_heads, T, group)`` for dkv, whose table does not
+# depend on the group. (batch, heads, kv_heads): groups of 1, 4 and 8.
+@pytest.mark.parametrize("heads", [(2, 2, 2), (1, 8, 2), (1, 8, 1)])
+@pytest.mark.parametrize("mode", [
+    {}, {"window": 100}, {"causal": False}, {"causal": False, "block_diffusion": (128, 4)},
+], ids=["causal", "window", "no_mask", "block_diffusion"])
+def test_the_grids_are_the_tables(mode, heads):
+    import numpy as np
+
+    from ray_tpu.ops.flash_attention import _tile_table
+
+    batch, heads, kv_heads = heads
+    q = jnp.zeros((batch, heads, 256, 16))
+    k = jnp.zeros((batch, kv_heads, 256, 16))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=32, **mode).sum(), argnums=(0, 1, 2),
+    ))(q, k, k)
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(inner)
+
+    grids = [tuple(eqn.params["grid_mapping"].grid) for eqn in calls(jaxpr.jaxpr)]
+    by_q, by_kv = (len(_tile_table(256, 256, 64, 32, by=by, **mode)) for by in ("q", "kv"))
+    assert grids == [(batch * heads, by_q), (batch * heads, by_q), (batch * kv_heads, by_kv, heads // kv_heads)]
+    mask = mode.get("causal", True) or "block_diffusion" in mode
+    assert (by_q == by_kv) and (by_q < 4 * 8 if mask else by_q == 4 * 8)
+
+
+# -- the same work as the parent's kernels -------------------------------------
+# name: (batch, heads, kv_heads, seq_q, seq_k, dim, dtype, block, mode). Every
+# mask, ``seq_q < seq_k``, groups of 1 to 4, and tiles of 256 that the masks
+# cut and ``_walk`` reads in sub-blocks of 128.
+_PARENT_CASES = {
+    "causal": (1, 4, 2, 256, 256, 32, "float32", 64, {}),
+    "causal_offset": (1, 4, 4, 128, 256, 32, "float32", 64, {}),
+    "window": (2, 8, 2, 256, 256, 32, "float32", 64, {"window": 100}),
+    "window_offset": (1, 2, 2, 64, 128, 16, "float32", 32, {"window": 8}),
+    "block_diffusion": (1, 4, 1, 256, 256, 32, "float32", 64, {"causal": False, "block_diffusion": (128, 4)}),
+    "no_mask": (1, 2, 2, 128, 256, 32, "float32", 64, {"causal": False}),
+    "selection": (2, 4, 1, 256, 256, 32, "float32", 64, {"selection": True}),
+    "cut_tiles": (1, 2, 1, 512, 512, 128, "bfloat16", 256, {}),
+    "cut_window": (1, 2, 1, 512, 512, 128, "bfloat16", 256, {"window": 300}),
+    "cut_block_diffusion": (1, 2, 1, 1024, 1024, 128, "bfloat16", 256,
+                            {"causal": False, "block_diffusion": (512, 4)}),
+}
+# sha256 (16 hex digits) of ``out`` and ``dq`` as float32 bytes, from PR 61's
+# PARENT (commit b5b6ded: the full ``rows x blocks`` grids, the closed-form
+# index maps, the longest-row schedule) on the interpreter, made by running
+# ``parent_outputs`` below against that commit's ``ops/flash_attention.py``.
+_OUTPUTS_OF_THE_PARENT = {
+    "causal": ("41a34330bb5b4685", "5b7e29715359041e"),
+    "causal_offset": ("96d372ab238e2b5f", "04eb9c97233f1c62"),
+    "window": ("669f502d73090d9a", "3dec9d182049fe10"),
+    "window_offset": ("bf7354ba50c29268", "2dc77715762aa93a"),
+    "block_diffusion": ("f9a8206aef204e35", "554bbf632f37d18f"),
+    "no_mask": ("9a20f88a411c035e", "0eae0197e1e93d04"),
+    "selection": ("01db79723a61ed5c", "c475c03c417f3252"),
+    "cut_tiles": ("c38a12bf813af51b", "1d85f43f2bdc8d5a"),
+    "cut_window": ("fb1786f7299b4ba7", "941b41d9e891df94"),
+    "cut_block_diffusion": ("3193d763ab70baad", "95852dc36122c32d"),
+}
+# ``_rounding_of_this_cpu()`` on the machine that made them: the digests are
+# of ITS float32 rounding (XLA's CPU code for a dot and an exponential)
+_ROUNDING_OF_THE_PARENT_S_CPU = "40b30aef80e03dd4"
+
+
+def _digest(x):
+    import hashlib
+
+    import numpy as np
+
+    return hashlib.sha256(np.asarray(x.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+
+
+@functools.cache
+def _rounding_of_this_cpu():
+    key = jax.random.PRNGKey(61)
+    a = jax.random.normal(key, (128, 128), jnp.float32)
+    return _digest(jnp.exp(a @ a.T * 0.125) @ a)
+
+
+def parent_outputs(flash, name):
+    """``(out, dq, dk, dv)`` of ``flash`` (a ``flash_attention``) on case
+    ``name``'s operands, made from the name alone."""
+    batch, heads, kv_heads, seq_q, seq_k, dim, dtype, block, mode = _PARENT_CASES[name]
+    mode = dict(mode)
+    keys = jax.random.split(jax.random.PRNGKey(sum(map(ord, name))), 5)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    q, g = normal(keys[0], batch, heads, seq_q, dim), normal(keys[3], batch, heads, seq_q, dim)
+    k, v = normal(keys[1], batch, kv_heads, seq_k, dim), normal(keys[2], batch, kv_heads, seq_k, dim)
+    if mode.pop("selection", False):
+        chosen = jax.random.uniform(keys[4], (batch, seq_q, seq_k)) < 0.3
+        mode["selection"] = (chosen | jnp.eye(seq_q, seq_k, dtype=bool)).astype(jnp.int8)
+    out, vjp = jax.vjp(lambda q, k, v: flash(q, k, v, **mode), q, k, v)
+    return (out, *vjp(g))
+
+
+@pytest.mark.parametrize("name", list(_PARENT_CASES))
+def test_forward_and_dq_are_bit_equal_to_the_parent_s(name):
+    """The table changes which grid steps exist, not what a step does: the
+    same tiles in the same order inside a q row, so ``out`` and ``dq`` are
+    the parent's bit for bit. ``dk`` / ``dv`` add a kv row's tiles with the
+    group's heads innermost (the parent: a head's tiles, then the next
+    head's): another order of float32 sums, held to the oracle."""
+    if _rounding_of_this_cpu() != _ROUNDING_OF_THE_PARENT_S_CPU:
+        pytest.skip("the pinned digests are of another CPU's float32 rounding")
+    block = _PARENT_CASES[name][7]
+    out, dq, dk, dv = parent_outputs(
+        functools.partial(flash_attention, block_q=block, block_k=block), name)
+    assert (_digest(out), _digest(dq)) == _OUTPUTS_OF_THE_PARENT[name]
+    _, _, want_dk, want_dv = parent_outputs(_grouped_reference, name)
+    tolerance = 2e-4 if dk.dtype == jnp.float32 else 0.15
+    assert float(jnp.max(jnp.abs(dk.astype(jnp.float32) - want_dk.astype(jnp.float32)))) < tolerance
+    assert float(jnp.max(jnp.abs(dv.astype(jnp.float32) - want_dv.astype(jnp.float32)))) < tolerance
 
 
 # One block is 32 keys here: windows of 1, 8, a block, a block +- 1, and the
@@ -595,47 +680,41 @@ _SUB_BLOCK_CASES = [
 ]
 
 
-@pytest.mark.parametrize("axis", ["kv", "q"])
+@pytest.mark.parametrize("by", ["q", "kv"])
 @pytest.mark.parametrize("seq_q,seq_k,block_q,block_k,window,block_diffusion", _SUB_BLOCK_CASES)
-def test_the_sub_block_rule_is_the_mask_s(seq_q, seq_k, block_q, block_k, window, block_diffusion, axis):
+def test_the_sub_block_rule_is_the_mask_s(seq_q, seq_k, block_q, block_k, window, block_diffusion, by):
     """``_sub_needed``, the rule ``_walk`` skips a sub-block by, for every
-    needed tile against the mask by enumeration: a sub-block it skips holds no
-    visible pair, one it runs holds at least one. Under block diffusion the
-    bits are read as the kernels of either axis read them (``"kv"``: fwd and
-    dq, a q row's steps; ``"q"``: dkv, a kv row's), each needed tile once. The
+    entry of the table against the mask by enumeration: a sub-block it skips
+    holds no visible pair, one it runs holds at least one. The bits are read
+    as the kernels of either orientation read them (``"q"``: fwd and dq, a q
+    row's steps; ``"kv"``: dkv, a kv row's), each needed tile once. The
     counters count what it runs."""
     import numpy as np
 
     from ray_tpu.ops import flash_attention as flash
 
-    nq, nk = seq_q // block_q, seq_k // block_k
     sub_q, sub_k = flash._sub_block(block_q), flash._sub_block(block_k)
     parts_q, parts_k = block_q // sub_q, block_k // sub_k
-    offset = seq_k - seq_q
     if block_diffusion is None:
         mask = _window_mask(seq_q, seq_k, window or seq_k)
+        mode = {"window": window}
         counts = flash.causal_tile_counts(seq_q, seq_k, block_q, block_k, window)
-        tiles = [((j, kv), None) for j in range(nq) for kv in range(nk)
-                 if flash._tile_needed(True, offset, j, kv, block_q, block_k, window)]
     else:
         rows = np.arange(seq_q)[:, None]
         mask = flash.block_diffusion_visible(rows, rows.T, *block_diffusion)
+        mode = {"causal": False, "block_diffusion": block_diffusion}
         counts = flash.block_diffusion_tile_counts(*block_diffusion, block_q, block_k)
-        walk, row_counts, subs, steps = flash._block_diffusion_schedule(*block_diffusion, block_q, block_k)[axis]
-        tiles = [
-            ((row, int(walk[row * steps + step])) if axis == "kv" else (int(walk[row * steps + step]), row),
-             int(subs[row * steps + step]))
-            for row in range(len(row_counts)) for step in range(row_counts[row])]
-        assert len(set(tile for tile, _ in tiles)) == len(tiles) == counts["executed"]
+    entries = flash_tile_tables(mask, block_q, block_k, **mode)[by]
+    tiles = [((row, col) if by == "q" else (col, row), bits) for row, col, _, _, bits in entries if bits]
+    assert len(set(tile for tile, _ in tiles)) == len(tiles) == counts["executed"]
+    assert counts["grid_steps"] == len(flash_tile_tables(mask, block_q, block_k, **mode)["q"])
     ran = np.zeros_like(mask)
     for (j, kv), bits in tiles:
         for a in range(parts_q):
             for b in range(parts_k):
                 at = (slice(j * block_q + a * sub_q, j * block_q + (a + 1) * sub_q),
                       slice(kv * block_k + b * sub_k, kv * block_k + (b + 1) * sub_k))
-                held = flash._sub_needed(
-                    a, b, j, kv, causal=block_diffusion is None, causal_offset=offset,
-                    block_q=block_q, block_k=block_k, window=window, subs=bits)
+                held = flash._sub_needed(a, b, bits, parts_k)
                 assert bool(held) == bool(mask[at].any()), ((j, kv), a, b)
                 ran[at] = bool(held)
     assert not (mask & ~ran).any()                            # skipped: no visible pair

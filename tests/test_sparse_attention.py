@@ -123,12 +123,14 @@ def test_the_masked_kernels_match_the_oracle(topk):
 
 # The three Mosaic modules of ``jax.grad(flash_attention)`` with NO selection
 # (plain, under a window of 64, at a group of 1), lowered for a TPU, parsed
-# and printed WITHOUT source locations. PR 53 pinned what ITS parent lowered
-# (a selection's arrival changed none of them); PR 60 changed the kernels on
-# purpose (a tile a mask cuts is walked in sub-blocks) and this is what its
-# tree lowers. A change to the kernels that is meant changes this line; one
-# that is not must not.
-MODULES_WITHOUT_A_SELECTION = "969cf1a2793673d1e9803b9c231ec1746070b0c2c9507ab7c7f892228dd5a5f1"
+# and printed WITHOUT source locations. What the line holds: that a
+# selection's arrival or change leaves the calls without one alone, not that
+# their modules never change. PR 53 pinned what ITS parent lowered; PR 60
+# changed the kernels on purpose (a tile a mask cuts is walked in sub-blocks),
+# PR 61 again (every grid is its prefetched table of the tiles that run) and
+# this is what its tree lowers. A change to the kernels that is meant changes
+# this line; one that is not must not.
+MODULES_WITHOUT_A_SELECTION = "68d0f130dedf4ed6d72ec57daf99a4cb6889d222595cdfe2cbbe9f02d22034cf"
 
 
 def test_without_a_selection_the_mosaic_modules_are_the_parents():
