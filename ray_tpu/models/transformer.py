@@ -1,4 +1,4 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the eight
+"""Flagship model: a decoder-only transformer, TPU-first, in the eleven
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
@@ -121,6 +121,17 @@ checkpoint policy, one head and loss):
     mask that is a fourth mode of the flash kernels (ops/flash_attention.py),
     and puts head and loss on the noised half alone, each masked position
     weighted ``1 / t``, row ``i`` predicting token ``i``.
+  * the eleventh, gated window / global attention under FOUR norms a layer
+    over experts whose selection bias a RULE moves (AFMoE: Trinity-Mini):
+    ``output_gate`` on "window" layers as on "full" ones (one routine,
+    ``_gated_out``); ``norm_placement="both"``, a norm before AND after each
+    branch (``attn_post_norm`` / ``mlp_post_norm``, scope ``post_norm``),
+    over dense and expert layers alike; ``embed_scale``; and
+    ``MoEConfig.bias_update_rate``: ``loss_fn`` returns ``(loss, moved)``,
+    the routers' selection biases after auxiliary-loss-free balancing's rule
+    on the counts of its own forward pass (``router_bias_update``, scope
+    ``router_bias``), which ``build_sharded_train_step`` writes in place of
+    the optimizer's result: state of the train step that no gradient moves.
 
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
@@ -151,7 +162,8 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     step), the pipeline (partition_stages /
     stage_forward) over a dense prefix, a pattern or a tied head, tp or sp
     over a patterned model with linear or conv layers (dp / fsdp work),
-    ``norm_placement="post"`` over expert layers, a window layer under a
+    ``norm_placement="post"`` over expert layers ("both" is written), a
+    branch-output norm through decode, a window layer under a
     callable ``attention`` or through decode (a ring cache of ``window``
     rows), a stated ``head_dim`` through decode or the pipeline, a sparse
     layer through decode (the index keys are not cached), the pipeline (the
@@ -237,9 +249,9 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # (benchmarks/harness/named_scope.py): "decay_prepare", inside "delta_rule"
 # (the chunk preparation under a decay per channel, opened in
 # ops/gated_delta_rule.py: forward, and backward through its custom VJP),
-# "attn_gate", inside "attention" (a "full" layer's output gate, by head or
-# by element), "kda_gate", inside "delta_rule" and "gate_norm" (a linear
-# layer's gate projections through ``gate_rank``),
+# "attn_gate", inside "attention" (a "full" or "window" layer's output gate,
+# by head or by element), "kda_gate", inside "delta_rule" and "gate_norm" (a
+# linear layer's gate projections through ``gate_rank``),
 # and "conv_mixer", inside "attention" (a "conv" layer's whole mixer: W_in,
 # the two gates, W_out, and within it "short_conv", the convolution's two
 # kernels called with no activation), "window_attention", inside "attention"
@@ -263,7 +275,12 @@ LINEAR_SCOPES = ("linear_attention", "short_conv", "delta_rule", "gate_norm")
 # the combine). And the block-diffusion objective's "noise", inside "embed"
 # (``_block_diffusion_stream``: drawing ``t`` and ``m`` from the batch's
 # integer, ``xt``, the concatenation ``[x0 ; xt]`` and the repeated positions;
-# the flash calls under the block-diffusion mask stay under "attention").
+# the flash calls under the block-diffusion mask stay under "attention"). And
+# "post_norm", inside "attention" and "mlp" (the norm on a branch's OUTPUT
+# under ``norm_placement`` "post" / "both": ``_branch_out``), and
+# "router_bias", inside "optimizer" (``router_bias_update``: the rule that
+# moves the routers' selection biases, traced in the loss after the forward
+# pass and named beside the update it belongs to).
 
 # A mixture-of-experts layer's leaves that the grouped matmuls read:
 # [experts, k, n] each, [layers, experts, k, n] in the layer stack. An
@@ -292,9 +309,18 @@ class MoEConfig:
     # "softmax": probabilities over all experts, the top-k of them are the
     # weights (OLMoE, Mixtral). "sigmoid" (DeepSeek-V3 ``noaux_tc`` with one
     # group): per-expert sigmoid scores; the top-k is taken of score +
-    # ``router_bias`` (a buffer no gradient reaches), the weights are the
-    # unbiased scores of the chosen.
+    # ``router_bias`` (a float32 buffer no gradient reaches: a RULE moves it
+    # where ``bias_update_rate`` is set, else nothing does), the weights are
+    # the unbiased scores of the chosen.
     scoring: str = "softmax"
+    # The rate ``c`` of auxiliary-loss-free balancing (torchtitan's
+    # ``load_balance_coeff``), under "sigmoid": once a step, a layer's
+    # ``router_bias`` moves by ``c sign(mean(n) - n)`` less that vector's mean,
+    # ``n[e]`` the (token, choice) pairs of the step that chose expert ``e``
+    # (``router_bias_update``). ``loss_fn`` then returns ``(loss, moved)``,
+    # the biases' new values beside the loss, and ``build_sharded_train_step``
+    # writes them where the optimizer's result would go. 0: a frozen buffer.
+    bias_update_rate: float = 0.0
     # What ``norm_topk_prob`` adds to the chosen weights' sum before it
     # divides by it (DeepSeek-V3's routine 1e-20, LFM2's 1e-6).
     renorm_eps: float = 1e-20
@@ -352,6 +378,8 @@ class MoEConfig:
                 f"unknown activation {self.activation!r} (one of {tuple(_GATE_MUL)}) or "
                 f"router_input {self.router_input!r} ('normed' | 'layer_input')"
             )
+        if self.bias_update_rate and self.scoring != "sigmoid":
+            raise ValueError("bias_update_rate moves router_bias, which scoring='sigmoid' alone reads")
         if self.n_group > 1 or self.topk_group > 1:
             if self.scoring != "sigmoid":
                 raise ValueError("routing in groups is DeepSeek-V3's sigmoid routine: scoring='sigmoid'")
@@ -574,9 +602,9 @@ class TransformerConfig:
     # Latent attention in place of the q / k / v projections (then
     # ``n_kv_heads`` and ``qk_norm`` mean nothing); None = grouped-query.
     latent: LatentAttentionConfig | None = None
-    # A gate on a "full" layer's attention output before ``W_o``, computed
-    # from the layer's normed input ``h``, whichever attention the layer is
-    # (scope ``attn_gate``). "head": each head's output times ``sigmoid(h
+    # A gate on a "full" or "window" layer's attention output before ``W_o``,
+    # computed from the layer's normed input ``h``, whichever attention the
+    # layer is (scope ``attn_gate``). "head": each head's output times ``sigmoid(h
     # w_i)``, one scalar a head and position (leaf ``wg_head`` ``[hidden,
     # heads]``). "element": the heads' outputs times ``sigmoid(h W_g)``
     # element by element (leaf ``wg`` ``[hidden, heads x value head dim]``,
@@ -612,8 +640,16 @@ class TransformerConfig:
     # The taps of a "conv" layer's gated short convolution (``conv_L_cache``).
     conv_kernel: int = 3
     # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
-    # ``x + norm(branch(x))``, the norm on the branch's OUTPUT.
+    # ``x + norm(branch(x))``, the norm on the branch's OUTPUT. "both"
+    # (AFMoE): ``x + post_norm(branch(norm(x)))``, four norm leaves a layer
+    # (``attn_post_norm`` / ``mlp_post_norm`` beside the two there are), over
+    # dense and expert layers alike; a branch-output norm runs under scope
+    # ``post_norm``.
     norm_placement: str = "pre"
+    # The embedding's OUTPUT times this (AFMoE's ``mup_enabled``: the square
+    # root of the stream's width; Gemma's); None: as gathered. A tied head
+    # reads the matrix itself, unscaled.
+    embed_scale: float | None = None
     # "flash" | "reference" | callable(q,k,v,causal)->o supplied by
     # parallel/ (ring attention, ulysses). "reference" also selects the
     # per-token recurrence for a linear layer's delta rule.
@@ -632,7 +668,7 @@ class TransformerConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
-        if self.norm_placement not in ("pre", "post"):
+        if self.norm_placement not in ("pre", "post", "both"):
             raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
         stated = self.latent.output_gate if self.latent else None
         if stated and self.output_gate not in (None, stated):
@@ -664,6 +700,11 @@ class TransformerConfig:
                 )
         if self.block_diffusion is not None:
             self._refuse_beside_block_diffusion()
+        if self.moe and self.moe.bias_update_rate and self.one_block:
+            raise NotImplementedError(
+                "bias_update_rate over a pattern of one-block layers (stacked by place) is not "
+                "written: moved_router_biases walks the stacks by kind"
+            )
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - {*LAYER_KINDS, MLP_KIND}
@@ -678,10 +719,10 @@ class TransformerConfig:
             )
         if self.first_dense_kind not in LAYER_KINDS:
             raise ValueError(f"first_dense_kind {self.first_dense_kind!r}: kinds are {LAYER_KINDS}")
-        if self.one_block and (self.first_dense_layers or self.norm_placement == "post"):
+        if self.one_block and (self.first_dense_layers or self.norm_placement != "pre"):
             raise NotImplementedError(
                 'a pattern of one-block layers ("mlp" beside the mixers) behind a dense prefix '
-                'or under norm_placement="post" is not written'
+                'or under norm_placement="post" / "both" is not written'
             )
         if ("ssm" in self._kinds()) != (self.ssm is not None):
             raise ValueError("a pattern names ssm layers exactly where ssm= describes them")
@@ -734,7 +775,7 @@ class TransformerConfig:
 
     @property
     def full_gate(self) -> str | None:
-        """The "full" layers' output gate, wherever it was stated."""
+        """The "full" and "window" layers' output gate, wherever it was stated."""
         return self.output_gate or (self.latent.output_gate if self.latent else None)
 
     @property
@@ -836,7 +877,7 @@ def _model_leaves(config: TransformerConfig) -> dict:
 
 
 def _gate_leaves(config: TransformerConfig, value_head_dim: int) -> dict:
-    """A "full" layer's output gate (``TransformerConfig.full_gate``)."""
+    """A "full" or "window" layer's output gate (``TransformerConfig.full_gate``)."""
     d, heads = config.dim, config.n_heads
     return {
         None: {},
@@ -847,8 +888,8 @@ def _gate_leaves(config: TransformerConfig, value_head_dim: int) -> dict:
 
 def _gqa_leaves(config: TransformerConfig) -> dict:
     """Grouped-query attention's leaves with its q / k norms' widths: a
-    "window" layer's mixer, and a "full" layer's without ``latent`` (which
-    adds its output gate's)."""
+    "window" layer's mixer and a "full" layer's without ``latent`` are these
+    and their output gate's (``_window_leaves``, ``_full_leaves``)."""
     d, heads = config.dim, config.n_heads
     q_out, kv_out = heads * config.head_dim, config.n_kv_heads * config.head_dim
     q_norm, k_norm = (q_out, kv_out) if config.qk_norm else (config.head_dim, config.head_dim)
@@ -879,12 +920,17 @@ def _sparse_leaves(config: TransformerConfig) -> dict:
     }
 
 
+def _window_leaves(config: TransformerConfig) -> dict:
+    """A "window" layer's mixer: grouped-query attention and its output gate."""
+    return {**_gqa_leaves(config), **_gate_leaves(config, config.head_dim)}
+
+
 def _full_leaves(config: TransformerConfig) -> dict:
     """A "full" layer's mixer: latent attention where ``latent`` is set,
     else grouped-query attention."""
     d, heads, la = config.dim, config.n_heads, config.latent
     if not la:
-        return {**_gqa_leaves(config), **_gate_leaves(config, config.head_dim)}
+        return _window_leaves(config)
     # tp shards whole heads (W_q's and W_kv_b's columns are laid out head by
     # head) and leaves the latent and the shared rope key whole.
     kv_out = heads * (la.qk_nope_head_dim + la.v_head_dim)
@@ -1066,7 +1112,10 @@ def _stacks(config: TransformerConfig) -> dict:
         mixer = {"attn_norm": norm, **_MIXERS[kind][0](config)}
         if config.one_block:
             return lead, mixer, {}, {}
-        return lead, {**mixer, "mlp_norm": norm}, *_mlp_leaves(config, experts)
+        # norm_placement="both": a norm on each branch's output beside the two
+        both = config.norm_placement == "both"
+        post = {"attn_post_norm": norm, "mlp_post_norm": norm} if both else {}
+        return lead, {**mixer, "mlp_norm": norm, **post}, *_mlp_leaves(config, experts)
 
     prefix, experts = config.first_dense_layers, config.moe is not None
     stacks = {"dense_layers": stack(config.prefix_kind, False, prefix)} if prefix else {}
@@ -1606,37 +1655,11 @@ def _heads_out(o, layer):
     return o.transpose(0, 2, 1, 3).reshape(batch, seq, heads * width) @ layer["wo"]
 
 
-def _gqa_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
-    """Grouped-query attention on the branch input ``h``, ungated; ``W_o``."""
-    return _heads_out(_gqa_heads(h, layer, config, cos_sin, positions, attention_fn), layer)
-
-
-def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
-    """A "window" layer's mixer: grouped-query attention whose query i sees
-    the ``config.window`` keys up to its own (``i - window < j <= i``), the
-    band native in the flash kernels (ops/flash_attention.py: the tiles
-    outside it are neither computed nor fetched). The kernel calls are the
-    "full" layers' jitted functions; scope ``window_flash`` tells them apart."""
-    window_fn = _attention_impl(config, config.window)
-
-    def attention_fn(q, k, v, causal):
-        with jax.named_scope("window_flash"):
-            return window_fn(q, k, v, causal)
-
-    with jax.named_scope("window_attention"):
-        return _gqa_mixer(h, layer, config, cos_sin, positions, attention_fn)
-
-
-def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
-    """A "full" layer's mixer on the branch input ``h``: latent attention
-    where ``latent`` is set, else grouped-query attention; under
-    ``output_gate`` (either attention) each head's output times ``sigmoid(h
-    w_i)`` ("head") or the heads' outputs times ``sigmoid(h W_g)`` element
-    by element ("element"), float32, scope ``attn_gate``; ``W_o``."""
-    if config.latent:
-        o = attention_fn(*_latent_qkv(h, layer, config, cos_sin, positions), True)
-    else:
-        o = _gqa_heads(h, layer, config, cos_sin, positions, attention_fn)
+def _gated_out(o, h, layer, config: TransformerConfig):
+    """``concat_heads(gate * o) W_o`` of a "full" or "window" layer's heads
+    ``o``: under ``output_gate`` each head's output times ``sigmoid(h w_i)``
+    ("head") or the heads' outputs times ``sigmoid(h W_g)`` element by
+    element ("element"), float32, scope ``attn_gate``; no gate: ``o`` as it is."""
     if config.full_gate:
         with jax.named_scope("attn_gate"):
             batch, heads, seq, _ = o.shape
@@ -1647,6 +1670,35 @@ def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attenti
             gate = jax.nn.sigmoid(gate.astype(jnp.float32)).transpose(0, 2, 1, 3)
             o = (o.astype(jnp.float32) * gate).astype(o.dtype)
     return _heads_out(o, layer)
+
+
+def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
+    """A "window" layer's mixer: grouped-query attention whose query i sees
+    the ``config.window`` keys up to its own (``i - window < j <= i``), the
+    band native in the flash kernels (ops/flash_attention.py: the tiles
+    outside it are neither computed nor fetched), under the output gate the
+    "full" layers have (``_gated_out``). The kernel calls are the "full"
+    layers' jitted functions; scope ``window_flash`` tells them apart."""
+    window_fn = _attention_impl(config, config.window)
+
+    def attention_fn(q, k, v, causal):
+        with jax.named_scope("window_flash"):
+            return window_fn(q, k, v, causal)
+
+    with jax.named_scope("window_attention"):
+        o = _gqa_heads(h, layer, config, cos_sin, positions, attention_fn)
+        return _gated_out(o, h, layer, config)
+
+
+def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """A "full" layer's mixer on the branch input ``h``: latent attention
+    where ``latent`` is set, else grouped-query attention; the output gate
+    (either attention) and ``W_o``: ``_gated_out``."""
+    if config.latent:
+        o = attention_fn(*_latent_qkv(h, layer, config, cos_sin, positions), True)
+    else:
+        o = _gqa_heads(h, layer, config, cos_sin, positions, attention_fn)
+    return _gated_out(o, h, layer, config)
 
 
 def _index_operands(h, layer, config: TransformerConfig, positions):
@@ -1692,7 +1744,7 @@ def _sparse_attend(config: TransformerConfig, q, k, v, q_index, k_index, w):
 
 def _sparse_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
     """A "sparse" layer's mixer: ``(out, terms)``. Grouped-query attention
-    (q / k / v, their norms, RoPE, ``W_o``: ``_gqa_mixer``'s) in which query
+    (q / k / v, their norms, RoPE, ``W_o``: ``_gqa_heads``' and ``_heads_out``'s) in which query
     ``t`` sees the ``min(t + 1, topk)`` keys its index scorer scored highest,
     one set for all the heads of its batch row; ``terms`` holds
     ``index_loss``, the scorer's loss a layer, and the ``selection`` itself
@@ -1735,7 +1787,7 @@ _MIXERS = {
     "linear": (_linear_leaves, _linear_mixer),
     "full": (_full_leaves, _full_mixer),
     "conv": (_conv_leaves, _conv_mixer),
-    "window": (_gqa_leaves, _window_mixer),
+    "window": (_window_leaves, _window_mixer),
     "sparse": (_sparse_leaves, _sparse_mixer),
     "ssm": (_ssm_leaves, _ssm_mixer),
 }
@@ -1747,19 +1799,17 @@ MLP_KIND = "mlp"
 
 def _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn):
     """``(x + mixer(norm(x)), terms)``, or under ``norm_placement="post"`` ``x
-    + norm(mixer(x))``, the mixer that of the layer's ``kind``; ``terms`` is
-    what a "sparse" layer's mixer hands out beside its output (the scalar
-    ``index_loss`` the loss adds, and its ``selection``), None of any other."""
-    post = config.norm_placement == "post"
+    + norm(mixer(x))``, or under "both" ``x + post_norm(mixer(norm(x)))``, the
+    mixer that of the layer's ``kind``; ``terms`` is what a "sparse" layer's
+    mixer hands out beside its output (the scalar ``index_loss`` the loss
+    adds, and its ``selection``), None of any other."""
     if config.rope_kinds is not None and kind not in config.rope_kinds:
         cos_sin = None
     with jax.named_scope("attention"):
-        h = x if post else _rmsnorm_ckpt(x, layer["attn_norm"], config.rms_norm_eps)
+        h = _branch_in(x, layer, "attn_norm", config)
         out = _MIXERS[kind][1](h, layer, config, cos_sin, positions, attention_fn)
         out, terms = out if isinstance(out, tuple) else (out, None)
-        if post:
-            out = _rmsnorm_ckpt(out.astype(x.dtype), layer["attn_norm"], config.rms_norm_eps)
-        return x + out.astype(x.dtype), terms
+        return x + _branch_out(out.astype(x.dtype), layer, "attn", config), terms
 
 
 def _rope_tables(config: TransformerConfig):
@@ -1815,6 +1865,25 @@ _GATE_MUL = {"silu": _silu_mul, "relu": _relu_mul, "relu2": _relu2}
 @functools.partial(jax.checkpoint, prevent_cse=False, static_argnums=(2,))
 def _rmsnorm_ckpt(x, weight, eps):
     return rmsnorm_reference(x, weight, eps=eps)
+
+
+def _branch_in(x, layer, norm: str, config: TransformerConfig):
+    """What a residual branch reads: ``norm(x)``, or ``x`` itself under
+    ``norm_placement="post"`` (whose one norm is on the branch's output)."""
+    if config.norm_placement == "post":
+        return x
+    return _rmsnorm_ckpt(x, layer[norm], config.rms_norm_eps)
+
+
+def _branch_out(out, layer, branch: str, config: TransformerConfig):
+    """What a residual branch ("attn" | "mlp") adds to the stream: its output,
+    under ``norm_placement="post"`` normed by the branch's one norm and under
+    "both" by ``<branch>_post_norm`` (scope ``post_norm``)."""
+    if config.norm_placement == "pre":
+        return out
+    name = f"{branch}_norm" if config.norm_placement == "post" else f"{branch}_post_norm"
+    with jax.named_scope("post_norm"):
+        return _rmsnorm_ckpt(out, layer[name], config.rms_norm_eps)
 
 
 def _dense_mlp(h, w_gate, w_up, w_down, gate_mul=_silu_mul):
@@ -2503,20 +2572,64 @@ def load_balancing_loss(routing: dict, moe: MoEConfig) -> jax.Array:
     return moe.num_experts * jnp.sum(f * p[None, :])
 
 
+def router_bias_update(bias: jax.Array, counts: jax.Array, rate: float) -> jax.Array:
+    """Auxiliary-loss-free balancing's rule (``MoEConfig.bias_update_rate``):
+    the new ``router_bias`` ``[..., experts]`` from this step's ``counts``
+    ``[..., top_k, experts]`` (``routing["counts"]``: under a mesh already
+    summed over the data shards). ``n[e]`` the (token, choice) pairs that
+    chose ``e``: ``d = rate * sign(mean(n) - n)``, ``d -= mean(d)``, ``bias +
+    d``; ``sign(0)`` is 0. Under ``MoEConfig.held`` it runs over all
+    ``num_experts`` as written: a chip knows every count its own tokens made,
+    and nothing stands in for the tokens of absent chips. Float32, scope
+    ``router_bias``; no gradient passes."""
+    with jax.named_scope("router_bias"):
+        n = jnp.sum(jax.lax.stop_gradient(counts), axis=-2).astype(jnp.float32)
+        step = rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+        step = step - jnp.mean(step, axis=-1, keepdims=True)
+        return jax.lax.stop_gradient(bias) + step
+
+
+def moved_router_biases(params: dict, routing: dict, config: TransformerConfig) -> dict:
+    """The expert layers' ``router_bias`` after ``router_bias_update`` on the
+    forward pass's stacked ``routing``, as a tree that mirrors ``params`` down
+    to those leaves and holds nothing else: what a loss hands
+    ``build_sharded_train_step`` beside its value (state a RULE moves)."""
+    rate, counts, layers = config.moe.bias_update_rate, routing["counts"], params["layers"]
+    if not config.layer_pattern:
+        return {"layers": {"router_bias": router_bias_update(layers["router_bias"], counts, rate)}}
+    pattern = config.layer_pattern
+    counts = counts.reshape(config.periods, len(pattern), *counts.shape[1:])
+    return {"layers": {
+        kind: {"router_bias": router_bias_update(
+            leaves["router_bias"],
+            counts[:, [place for place, named in enumerate(pattern) if named == kind]], rate,
+        )}
+        for kind, leaves in layers.items()
+    }}
+
+
+def _with_moved(loss, params, routing, config: TransformerConfig):
+    """``loss``, or ``(loss, moved)`` where the config states a rule that moves
+    state from what the forward pass counted (``MoEConfig.bias_update_rate``)."""
+    if config.moe and config.moe.bias_update_rate:
+        # the step's own bookkeeping: named beside the optimizer's update
+        with jax.named_scope("optimizer"):
+            return loss, moved_router_biases(params, routing, config)
+    return loss
+
+
 def _mlp_block(x, layer, config: TransformerConfig, experts: bool, layer_input=None):
-    """``(x + mlp(norm(x)), routing)``: a mixture of experts with
+    """``(x + mlp(norm(x)), routing)`` (``norm_placement``: ``_branch_in`` /
+    ``_branch_out``): a mixture of experts with
     ``experts``, else a dense MLP, whose ``routing`` is None. ``layer_input``
     is the stream before the layer's attention block: what the router reads
     under ``MoEConfig.router_input="layer_input"``."""
-    post = config.norm_placement == "post"
     with jax.named_scope("mlp"):
-        h = x if post else _rmsnorm_ckpt(x, layer["mlp_norm"], config.rms_norm_eps)
+        h = _branch_in(x, layer, "mlp_norm", config)
         if not experts:
             out = _dense_mlp(h, layer["w_gate"], layer["w_up"], layer["w_down"]).astype(x.dtype)
-            if post:
-                out = _rmsnorm_ckpt(out, layer["mlp_norm"], config.rms_norm_eps)
-            return x + out, None
-        if post:
+            return x + _branch_out(out, layer, "mlp", config), None
+        if config.norm_placement == "post":
             raise NotImplementedError(
                 'norm_placement="post" over a mixture-of-experts layer is not written'
             )
@@ -2545,7 +2658,7 @@ def _mlp_block(x, layer, config: TransformerConfig, experts: bool, layer_input=N
                     h, layer.get("shared_gate"), layer["shared_up"], layer["shared_down"],
                     _GATE_MUL[moe.activation],
                 ).astype(out.dtype)
-        return x + out.astype(x.dtype), routing
+        return x + _branch_out(out.astype(x.dtype), layer, "mlp", config), routing
 
 
 def _scan_layers(step, carry, layers, *xs):
@@ -2671,9 +2784,12 @@ def layer_order(params: dict, config: TransformerConfig):
             taken[kind] += 1
 
 
-def _embed(params, tokens):
+def _embed(params, tokens, config: TransformerConfig):
     with jax.named_scope("embed"):
-        return params["embed"][tokens]
+        x = params["embed"][tokens]
+        if config.embed_scale is None:
+            return x
+        return (x.astype(jnp.float32) * config.embed_scale).astype(x.dtype)
 
 
 def _lm_head(params, config: TransformerConfig):
@@ -2743,7 +2859,7 @@ def _hidden_with_routing(params, tokens, config, positions=None, selections=Fals
         raise NotImplementedError("selections=True reads an unpatterned sparse model's layers")
     attention_fn = _attention_impl(config, block_diffusion=block_diffusion)
     cos_sin = _rope_tables(config)
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, config)
 
     def layer_step(kind, experts, carry, layer):
         # under ``one_block`` a layer is its mixer alone or its MLP alone
@@ -2948,7 +3064,11 @@ def loss_fn(
 ) -> jax.Array:
     """Next-token cross-entropy under the causal mask. A ``block_diffusion``
     config trains through ``block_diffusion_loss_fn``; its causal next-token
-    loss is computed only where ``next_token=True`` asks for it by name."""
+    loss is computed only where ``next_token=True`` asks for it by name.
+    Under ``MoEConfig.bias_update_rate`` it returns ``(loss, moved)``, the
+    expert layers' new ``router_bias`` beside the loss (``moved_router_biases``:
+    ``build_sharded_train_step`` writes them; ``jax.value_and_grad`` takes it
+    with ``has_aux=True``)."""
     if config.block_diffusion is not None and not next_token:
         raise ValueError(
             "loss_fn is the next-token objective and this config states block_diffusion=: "
@@ -2963,7 +3083,7 @@ def loss_fn(
     if config.sparse is not None:
         with jax.named_scope("loss"):
             loss = loss + jnp.sum(routing["index_loss"])
-    return loss
+    return _with_moved(loss, params, routing, config)
 
 
 def _block_diffusion_of(config: TransformerConfig, what: str) -> BlockDiffusionConfig:
@@ -3054,7 +3174,8 @@ def block_diffusion_loss_fn(
     alone, float32 statistics, no shift. ``mask`` ``[batch, L]``, as
     ``loss_fn``'s: the positions that count (a prompt's do not), the mean
     then over those. The experts' balancing term, where the config has one,
-    is over the stream's ``2 L`` rows."""
+    is over the stream's ``2 L`` rows, and so are the counts of ``(loss,
+    moved)`` under ``MoEConfig.bias_update_rate`` (as ``loss_fn``)."""
     length = tokens.shape[1]
     ids, positions, mode, m, t = _block_diffusion_stream(tokens, noise, config)
     x, routing = _hidden_with_routing(params, ids, config, positions, block_diffusion=mode)
@@ -3064,7 +3185,7 @@ def block_diffusion_loss_fn(
     if config.moe and config.moe.aux_loss_coef:
         with jax.named_scope("loss"):
             loss = loss + config.moe.aux_loss_coef * load_balancing_loss(routing, config.moe)
-    return loss
+    return _with_moved(loss, params, routing, config)
 
 
 def num_params(params: dict) -> int:
@@ -3190,7 +3311,7 @@ def stage_forward(
     attention_fn = _attention_impl(config)
     cos_sin = _rope_tables(config)
     if first:
-        x = _embed(stage_params, x)
+        x = _embed(stage_params, x, config)
 
     def layer_step(carry, layer):
         h_in, _ = _attention_block(carry, layer, "full", config, cos_sin, positions, attention_fn)
@@ -3245,6 +3366,11 @@ def _refuse_latent_cache(config: TransformerConfig) -> None:
             "(a [d_k, d_v] state and the convolution's last inputs a linear layer and "
             "head), which is not written yet"
         )
+    if config.norm_placement != "pre":
+        raise NotImplementedError(
+            f"decode_step's attention block is pre-norm; norm_placement={config.norm_placement!r} "
+            "(a norm on the branch's output) on a cached step is not written yet"
+        )
     if config.full_gate and not config.latent:
         raise NotImplementedError(
             "decode_step's grouped-query layer computes no output gate (output_gate="
@@ -3280,7 +3406,7 @@ def decode_step(
     hd = config.head_dim
     length = cache["length"]
     positions = jnp.full((batch, 1), length, jnp.int32)
-    x = _embed(params, tokens)
+    x = _embed(params, tokens, config)
 
     def layer_step(carry, layer, k_cache, v_cache):
         x = carry

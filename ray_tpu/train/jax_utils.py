@@ -491,12 +491,30 @@ def setup_sharded_training(
         )
 
 
+def _overlaid(tree: Any, over: Any) -> Any:
+    """``tree`` with the leaves that the nested dict ``over`` names replaced
+    by ``over``'s (``over`` mirrors ``tree``'s dicts down to those leaves)."""
+    if not isinstance(over, dict):
+        return over
+    return {**tree, **{key: _overlaid(tree[key], sub) for key, sub in over.items()}}
+
+
 def build_sharded_train_step(
     loss_fn: Callable[[Any, Any], Any],
     optimizer: Any,
     setup: ShardedTrainSetup,
 ) -> Callable[[Any, Any, Any], tuple[Any, Any, Any]]:
     """Compile ``loss_fn(params, batch) -> scalar`` into one train step.
+
+    A loss may return ``(scalar, moved)`` instead: ``moved`` mirrors
+    ``params`` down to the leaves a RULE moves (a router's selection bias,
+    from the counts the forward pass made:
+    ``models.transformer.moved_router_biases``) and holds their new values.
+    The step then takes no gradient of those leaves (their moments stay
+    where they are), drops the optimizer's result for them, weight decay
+    and all, and writes the rule's values in their place, in the same
+    donated buffers. A loss that returns the scalar alone compiles the
+    program it always did.
 
     Returns ``step(params, opt_state, batch) -> (params, opt_state,
     loss)``: grads, cross-device reductions and the optimizer update are
@@ -515,7 +533,8 @@ def build_sharded_train_step(
         # (a Pallas kernel, which GSPMD cannot partition and which so
         # runs per shard under shard_map) reads get_abstract_mesh().
         with jax.sharding.use_abstract_mesh(setup.mesh.abstract_mesh):
-            return loss_fn(params, batch)
+            out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, None)
 
     def apply_update(params, opt_state, grads):
         # The last name of models.transformer.SCOPES.
@@ -527,8 +546,12 @@ def build_sharded_train_step(
             return new_params, new_opt
 
     def fused(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(meshed_loss)(params, batch)
+        (loss, moved), grads = jax.value_and_grad(meshed_loss, has_aux=True)(params, batch)
+        if moved is not None:
+            grads = _overlaid(grads, jax.tree.map(jax.numpy.zeros_like, moved))
         new_params, new_opt = apply_update(params, opt_state, grads)
+        if moved is not None:
+            new_params = _overlaid(new_params, moved)
         return new_params, new_opt, loss
 
     return jax.jit(

@@ -1,5 +1,5 @@
 """The table of leaves in ``models/transformer.py`` (``_Leaf``, one function
-a part of a layer, ``_MIXERS``) and its three readers, over the seven shapes
+a part of a layer, ``_MIXERS``) and its three readers, over eight of the shapes
 the model takes, at tiny widths.
 
 ``WEIGHTS`` holds a digest of ``init_params(config, PRNGKey(0))`` for each
@@ -11,7 +11,8 @@ tolerances on them, so a digest that moves is a different benchmark. They
 may be regenerated only after a jax upgrade that moves the ``dense`` case
 too (one ``jax.random.normal`` a leaf: then the generator changed, not the
 table); print them with ``python tests/test_layer_table.py``. The seventh
-shape's ("window" layers) was recorded on the commit that added the kind.
+shape's ("window" layers) was recorded on the commit that added the kind, and
+so was the eighth's (a gate on window layers, four norms a layer).
 """
 
 import hashlib
@@ -71,6 +72,16 @@ SHAPES = {
             activation="relu", router_input="layer_input",
         ),
     ),
+    "gated_window_four_norms_bias_rule": lambda: T.TransformerConfig.tiny(
+        dim=48, n_layers=5, n_heads=4, n_kv_heads=2, head_dim=16, dtype=jnp.bfloat16,
+        first_dense_layers=1, first_dense_kind="window",
+        layer_pattern=("window", "full", "window", "window"), window=8, rope_kinds=("window",),
+        qk_head_norm=True, output_gate="element", norm_placement="both", embed_scale=48 ** 0.5,
+        moe=T.MoEConfig(
+            **{**_SIGMOID, "expert_dim": 24}, shared_experts=1, routed_scaling=2.826, held=(0, 4),
+            bias_update_rate=0.001,
+        ),
+    ),
 }
 
 WEIGHTS = {
@@ -81,6 +92,7 @@ WEIGHTS = {
     "channel_decay_gated_latent_held": "6798f5e27bb456497e13f0f96b1816a60a28249a33df5ed3ec69b94390055015",
     "conv_tied_head_norm": "33de65ffcdb6b1acf14b8bbbbeadcecb6eed535fa11dd9eac67c51e7272e6b74",
     "window_stated_head_relu_held": "9e6e378b84504b61a8b994a8d36f507845d99ce51eac031e5045934926a2247e",
+    "gated_window_four_norms_bias_rule": "5bd9f0e82cac0cf53d51b437b061d956b00bb8250ba16b69127b92b7a5e41a4b",
 }
 
 
@@ -133,11 +145,13 @@ def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
 
 
 def test_a_window_layers_leaves_are_grouped_query_attentions_at_the_stated_head():
-    """The "window" row: ``_gqa_leaves``, which is a "full" layer's too
-    without ``latent``; q is ``n_heads x head_dim`` wide, not the stream's
+    """The "window" row: ``_window_leaves``, grouped-query attention's leaves
+    (``_gqa_leaves``) and the output gate's where one is stated, which is a
+    "full" layer's too without ``latent``; q is ``n_heads x head_dim`` wide, not the stream's
     width; a pattern may name the kind only with ``window=`` set."""
     config = SHAPES["window_stated_head_relu_held"]()
-    assert T._MIXERS["window"][0] is T._gqa_leaves
+    assert T._MIXERS["window"][0] is T._window_leaves
+    assert T._window_leaves(config) == T._gqa_leaves(config)          # no gate stated: the same
     assert (config.head_dim, config.n_heads * config.head_dim, config.dim) == (16, 64, 48)
     shapes = {name: leaf.shape for name, leaf in T._gqa_leaves(config).items()}
     assert shapes == {"wq": (48, 64), "wk": (48, 32), "wv": (48, 32), "wo": (64, 48)}
