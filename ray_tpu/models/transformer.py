@@ -202,7 +202,7 @@ from ray_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as DELTA_RULE_RESIDUAL_NAMES,
     by_token, gated_delta_rule_by_token, gated_delta_rule_reference, kept_bytes, whole_lanes,
 )
-from ray_tpu.ops.grouped_matmul import TILE, grouped_matmul
+from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.ops.short_conv import short_conv
@@ -2054,7 +2054,7 @@ def held_row_bound(tokens: int, top_k: int, held: int, num_experts: int) -> int:
     section 6, PR 40)."""
     pairs = tokens * top_k
     bound = _HELD_ROWS_OVER_EVEN * (pairs * held // num_experts)
-    tile = TILE[0] if bound > TILE[0] else 8
+    tile = ROW_TILE if bound > ROW_TILE else 8
     return min(pairs, -(-bound // tile) * tile)
 
 

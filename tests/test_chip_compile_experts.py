@@ -23,50 +23,79 @@ from model_helpers import custom_calls, loss_and_grads_text, one_chip_step
 def _grouped_loss(lhs, rhs, group_sizes, tile=None):
     import ray_tpu.ops.grouped_matmul as gm
 
-    ctx = mock.patch.object(gm, "TILE", tile) if tile else contextlib.nullcontext()
-    with ctx:
+    forced = mock.patch.object(gm, "_tiling", lambda *shape, **kind: tile)
+    with forced if tile else contextlib.nullcontext():
         out = gm.grouped_matmul(lhs, rhs, group_sizes, interpret=False)
     return jnp.sum(out.astype(jnp.float32))
 
 
-@pytest.mark.parametrize("rows,k,n", [
-    (65536, 2048, 1024), (65536, 1024, 2048),     # OLMoE
-    (49152, 2048, 1408), (49152, 1408, 2048),     # Moonlight: 1408 = 11 x 128
-    (65536, 2048, 1792), (65536, 1792, 2048),     # LFM2: 1792 = 2 x 896, tiles of 896
+@pytest.mark.parametrize("rows,k,n,groups", [
+    (65536, 2048, 1024, 64), (65536, 1024, 2048, 64),     # OLMoE
+    (49152, 2048, 1408, 64), (49152, 1408, 2048, 64),     # Moonlight: 1408 = 11 x 128
+    (65536, 2048, 1792, 16), (65536, 1792, 2048, 16),     # LFM2: 1792 = 2 x 896
+    (98304, 2560, 768, 32), (98304, 768, 2560, 32),       # SmallThinker: 2560 = 2 x 1280
+    (131072, 2048, 768, 16), (131072, 768, 2048, 16),     # Keye, sdar
+    (131072, 2048, 1024, 16), (131072, 1024, 2048, 16),   # Trinity
+    (45056, 1024, 2688, 16), (45056, 2688, 1024, 16),     # Nemotron: 2688 = 3 x 896
+    (6656, 4096, 1280, 8), (6656, 1280, 4096, 8),         # Solar
+    (32768, 2560, 768, 16), (32768, 768, 2560, 16),       # Ling: the bounded buffer
 ])
-def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n):
-    """The expert matmuls at the benchmark cells' sizes: OLMoE's 65,536
-    (token, choice) rows over 64 experts of width 1024, Moonlight's 49,152
-    over 64 of width 1408 and LFM2's 65,536 at width 1792 (in tiles of 896,
-    which must fit the scoped VMEM in all three calls), gate / up and down, forward, and both
-    gradients (the input's is ``gmm`` on the transposed experts, the
-    weights' is ``tgmm``), at the tiles ``grouped_matmul`` picks."""
+def test_grouped_matmul_compiles_for_v5e(one_chip, rows, k, n, groups):
+    """The expert matmuls at the benchmark cells' sizes, gate / up and down:
+    the forward call and both gradients (the input's is ``gmm`` on the
+    transposed experts, the weights' is ``tgmm``), each at the tile
+    ``grouped_matmul`` counts out for it. What fits a v5e's VMEM is the
+    compiler's to say: ``_fits`` is a fit to its verdicts, and this holds
+    every tile the rule picks at a cell's shape to the compiler's own word."""
+    import ray_tpu.ops.grouped_matmul as gm
+
     shapes = (
         jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((64, k, n), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip),
     )
-    assert custom_calls(_grouped_loss, *shapes) == 1
-    grads = jax.grad(_grouped_loss, argnums=(0, 1))
-    text = jax.jit(grads).lower(*shapes).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
+    calls = ((rows, k, n, groups), (rows, n, k, groups), (rows, k, n, groups, True))
+    assert all(gm._fits(gm._tiling(*call), weight_grad=len(call) == 5) for call in calls)
+    both = jax.value_and_grad(_grouped_loss, argnums=(0, 1))
+    text = jax.jit(both).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
     assert len(re.findall(r"%\S*tgmm\S* = \S+ custom-call", text)) == 1
 
 
-def test_grouped_matmul_tile_too_large_for_vmem_is_refused(one_chip):
-    """Why TILE stops at 512 x 1024 x 1024: the next size up needs more
-    VMEM than a kernel may use on a v5e (the chip refused it too: my chip
-    run, PR 26)."""
+def test_grouped_matmul_in_float32_compiles_for_v5e(one_chip):
+    """``_fits`` was fitted on bfloat16 and scales its sum by the element's
+    width, which over-counts the float32 accumulator: the float32 tiles it
+    admits at OLMoE's widths are ones the compiler takes, in all three calls."""
+    import ray_tpu.ops.grouped_matmul as gm
+
+    rows, k, n, groups = 16384, 2048, 1024, 8
     shapes = (
-        jax.ShapeDtypeStruct((65536, 2048), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16, sharding=one_chip),
-        jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((rows, k), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((groups, k, n), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip),
     )
-    big = functools.partial(_grouped_loss, tile=(1024, 2048, 1024))
+    assert gm._tiling(rows, k, n, groups, itemsize=4) != gm._tiling(rows, k, n, groups)
+    both = jax.value_and_grad(_grouped_loss, argnums=(0, 1))
+    assert jax.jit(both).lower(*shapes).compile().as_text().count("tpu_custom_call") == 3
+
+
+def test_grouped_matmul_tile_too_large_for_vmem_is_refused(one_chip):
+    """Why the row tile stops at 512: at Keye's forward call the rule's
+    (512, 2048, 768) with 1024 rows needs more VMEM than a kernel may use on
+    a v5e, as ``_fits`` says of it (the chip refused 1024 x 2048 x 1024 too:
+    my chip run, PR 26)."""
+    import ray_tpu.ops.grouped_matmul as gm
+
+    shapes = (
+        jax.ShapeDtypeStruct((131072, 2048), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((16, 2048, 768), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip),
+    )
+    assert gm._tiling(131072, 2048, 768, 16) == (512, 2048, 768)
+    assert not gm._fits((1024, 2048, 768))
+    big = functools.partial(_grouped_loss, tile=(1024, 2048, 768))
     with pytest.raises(Exception, match="(?i)vmem|memory"):
-        # the value keeps the forward call, the one tiled 1024 x 2048 x 1024
-        # (each gradient is tiled for its own dimensions)
-        jax.jit(jax.value_and_grad(big, argnums=(0, 1))).lower(*shapes).compile()
+        jax.jit(big).lower(*shapes).compile()
 
 
 @pytest.mark.parametrize("axes", [

@@ -19,6 +19,7 @@ sides float32, sums in another order). A wrong term is off by far more.
 """
 
 import dataclasses
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -333,23 +334,158 @@ def test_the_expert_bias_changes_the_choice_and_not_the_weights(params):
     assert T.MoEConfig().renorm_eps == 1e-20 and model.moe.renorm_eps == 1e-6
 
 
-def test_the_expert_width_of_1792_is_tiled_in_two_and_no_other_cell_moves():
-    """``ops/grouped_matmul.py``'s tile choice at each MoE cell's three
-    calls (forward and weight gradient ``(m, k, n)``, input gradient ``(m,
-    n, k)``): 1792 = 2 x 896 where halving from 1024 found 256; OLMoE's,
-    Moonlight's and Ling's tiles are what they were."""
-    from ray_tpu.ops.grouped_matmul import _tile, _tiling
+def _capped_tiling(m, k, n):
+    """The tile choice before PR 64, kept to compare against: every call
+    capped at (512, 1024, 1024), halved until it divides."""
+    limit = (512, 1024, 1024)
 
-    assert (_tiling(65536, 2048, 1792), _tiling(65536, 1792, 2048)) == (
-        (512, 1024, 896), (512, 896, 1024))
-    assert (_tiling(65536, 2048, 1024), _tiling(65536, 1024, 2048)) == (
-        (512, 1024, 1024), (512, 1024, 1024))
-    assert (_tiling(49152, 2048, 1408), _tiling(49152, 1408, 2048)) == (
-        (256, 1024, 1408), (256, 1408, 1024))
-    assert (_tiling(131072, 2560, 768), _tiling(131072, 768, 2560)) == (
-        (512, 512, 768), (512, 768, 512))
-    # half the limit is kept as it is; where no share of the limit divides, the old rule
-    assert _tile(2560, 1024) == 512 and _tile(1152, 1024) == 1152 and _tile(4224, 1024) == 128
+    def tile(size, limit, least=128):
+        if size <= limit:
+            return size
+        tile = limit
+        while tile > least and size % tile:
+            tile //= 2
+        if tile > least:
+            if 2 * tile >= limit:
+                return tile
+            wider = (w for w in range(limit - limit % least, tile, -least) if size % w == 0)
+            return next(wider, tile)
+        return least if 2 * size > 3 * limit and size % least == 0 else size
+
+    tk, tn = tile(k, limit[1]), tile(n, limit[2])
+    rows = limit[0] // 2 if tk > limit[1] or tn > limit[2] else limit[0]
+    return tile(m, rows, 8), tk, tn
+
+
+# cell -> (rows of the expert buffers, model width, expert width, groups a layer),
+# then the tiles of gate / up forward, down forward, gate / up input gradient,
+# down input gradient (``gmm``), gate / up and down weight gradient (``tgmm``)
+EXPERT_CALLS = {
+    "olmoe": ((65536, 2048, 1024, 64), (
+        (256, 2048, 1024), (512, 1024, 2048), (512, 1024, 2048), (256, 2048, 1024),
+        (512, 1024, 1024), (512, 1024, 1024))),
+    "moonlight": ((49152, 2048, 1408, 64), (
+        (256, 2048, 1408), (256, 1408, 2048), (256, 1408, 2048), (256, 2048, 1408),
+        (256, 1024, 1408), (256, 1408, 1024))),
+    "lfm2": ((65536, 2048, 1792, 16), (
+        (512, 2048, 896), (512, 1792, 1024), (512, 1792, 1024), (512, 2048, 896),
+        (512, 1024, 896), (512, 896, 1024))),
+    "smallthinker": ((98304, 2560, 768, 32), (
+        (256, 2560, 768), (512, 768, 2560), (512, 768, 2560), (256, 2560, 768),
+        (512, 1280, 768), (512, 768, 1280))),
+    "keye-sdar": ((131072, 2048, 768, 16), (
+        (512, 2048, 768), (512, 768, 2048), (512, 768, 2048), (512, 2048, 768),
+        (256, 2048, 768), (256, 768, 2048))),
+    "trinity": ((131072, 2048, 1024, 16), (
+        (256, 2048, 1024), (512, 1024, 2048), (512, 1024, 2048), (256, 2048, 1024),
+        (512, 1024, 1024), (512, 1024, 1024))),
+    "nemotron": ((45056, 1024, 2688, 16), (
+        (256, 1024, 2688), (256, 2688, 1024), (256, 2688, 1024), (256, 1024, 2688),
+        (512, 1024, 896), (512, 896, 1024))),
+    "solar": ((6656, 4096, 1280, 8), (
+        (512, 1024, 1280), (512, 1280, 1024), (512, 1280, 1024), (512, 1024, 1280),
+        (512, 1024, 1280), (512, 1280, 1024))),
+    "ling": ((32768, 2560, 768, 16), (
+        (256, 2560, 768), (512, 768, 2560), (512, 768, 2560), (256, 2560, 768),
+        (512, 1280, 768), (512, 768, 1280))),
+}
+CALL_NAMES = ("gate_fwd", "down_fwd", "gate_dx", "down_dx", "gate_dw", "down_dw")
+
+
+@pytest.mark.parametrize("cell,call", [(c, i) for c in EXPERT_CALLS for i in range(6)],
+                         ids=[f"{c}-{name}" for c in EXPERT_CALLS for name in CALL_NAMES])
+def test_every_expert_call_gets_the_tile_that_moves_least(cell, call):
+    """``ops/grouped_matmul.py``'s tile at each MoE cell's six calls (forward
+    and weight gradient contract over the first width named, the input
+    gradient over the second): whole tiles that the fitted bound admits, the
+    contraction (or the columns) in one tile wherever that fits, and by the
+    file's own count no more bytes, and no more cost (bytes and grid steps),
+    than the capped tiles it replaces at that call. 2688 is cut in 896s or
+    not at all where it was cut in 128s. ``gmm`` takes 256 rows only with
+    both sides whole: Solar's (6656, 1280, 4096) keeps 512 (PERF.md section
+    6, PR 64: what its warm start paid for (256, 1280, 2048))."""
+    from ray_tpu.ops.grouped_matmul import _cost, _fits, _moved_bytes, _tiling
+
+    (m, width, expert, g), tiles = EXPERT_CALLS[cell]
+    k, n = (width, expert) if call in (0, 3, 4) else (expert, width)
+    weight_grad = call >= 4
+    tile = _tiling(m, k, n, g, weight_grad)
+    assert tile == tiles[call]
+    assert m % tile[0] == 0 and k % tile[1] == 0 and n % tile[2] == 0
+    assert _fits(tile, weight_grad) and min(tile[1:]) > 128
+    before = _capped_tiling(m, k, n)
+    for count in (_moved_bytes, _cost):
+        assert count(m, k, n, g, tile, weight_grad) <= count(m, k, n, g, before, weight_grad)
+    if not weight_grad:     # gmm: the weights once wherever one side is whole
+        assert tile[1] == k or tile[2] == n
+        assert tile[0] == 512 or tile[1:] == (k, n)
+
+
+def test_a_size_is_tiled_by_its_divisors():
+    """No "no share of the limit divides" branch is left: the candidates of a
+    side are its divisors in whole lanes, and a side with none is one tile."""
+    from ray_tpu.ops.grouped_matmul import _rows, _sides
+
+    assert _sides(2688) == [128, 384, 896, 2688]
+    assert _sides(1408) == [128, 1408] and _sides(1792) == [128, 256, 896, 1792]
+    assert _sides(2560) == [128, 256, 512, 640, 1280, 2560]
+    assert _sides(200) == [200] and _sides(96) == [96]
+    # no side wider than the VMEM bound was fitted for, so none it would have to extrapolate to
+    assert _sides(8192)[-1] == 4096 and _sides(10240)[-1] == 2560
+    assert _rows(131072) == [512, 256] and _rows(6656) == [512, 256] and _rows(300) == [300]
+    assert _rows(768) == [256] and _rows(1000) == [8] and _rows(1001) == [1001]
+
+
+# The compiler's own verdicts for a described v5e that ISSUE 64 lists, at
+# 131,072 rows: (tile, the weight gradient's kernel?, taken?).
+VMEM_VERDICTS = [
+    ((512, 2048, 768), False, True), ((256, 2048, 1024), False, True), ((512, 768, 2560), False, True),
+    ((512, 2048, 896), False, True), ((512, 1792, 1024), False, True), ((512, 1024, 896), False, True),
+    ((512, 896, 1024), False, True), ((512, 1280, 768), True, True), ((512, 1024, 896), True, True),
+    ((512, 2048, 1024), False, False), ((1024, 2048, 768), False, False), ((512, 2560, 768), False, False),
+    ((512, 2688, 1024), False, False), ((512, 2048, 768), True, False), ((512, 2560, 768), True, False),
+]
+
+
+@pytest.mark.parametrize("tile,weight_grad,taken", VMEM_VERDICTS,
+                         ids=[f"{'tgmm' if w else 'gmm'}-{'x'.join(map(str, t))}" for t, w, _ in VMEM_VERDICTS])
+def test_the_vmem_bound_says_what_the_compiler_said(tile, weight_grad, taken):
+    """``_fits`` against the verdicts the issue gives, and what it does
+    outside what it was fitted over: a narrower side is priced as the
+    narrowest fitted, wider elements cost more, never less."""
+    from ray_tpu.ops.grouped_matmul import _fits
+
+    assert _fits(tile, weight_grad) is taken
+    tm, tk, tn = tile
+    assert _fits((tm, 128, tn), weight_grad) == _fits((tm, 384, tn), weight_grad)
+    assert not (_fits(tile, weight_grad, itemsize=4) and not taken)
+
+
+def test_of_two_tiles_that_cost_the_same_the_smaller_is_taken():
+    """The issue's rule for what a start pays: within a few percent of the
+    least cost the SMALLEST tile wins, and outside it the cheaper one does,
+    whatever its size. Ling's bounded buffer of 32,768 rows keeps 512 rows
+    (PR 48: a smaller row tile there was slower), since halving the rows
+    adds 7 % of steps at equal bytes."""
+    import ray_tpu.ops.grouped_matmul as gm
+    from ray_tpu.ops.grouped_matmul import _NEAR, _cost, _tiling
+
+    m, k, n, g = 131072, 2048, 768, 16            # Keye's weight gradient: rows are free to halve
+    small, large = (256, 2048, 768), (512, 1024, 768)
+    assert _tiling(m, k, n, g, True) == small
+    assert _cost(m, k, n, g, small, True) < _cost(m, k, n, g, large, True)
+    m, k, n, g = 32768, 768, 2560, 16             # Ling's down forward
+    assert _tiling(m, k, n, g) == (512, 768, 2560)
+    assert _cost(m, k, n, g, (256, 768, 2560)) > _NEAR * _cost(m, k, n, g, (512, 768, 2560))
+    # a tie inside the few percent: were a grid step free, Ling's two row tiles would
+    # move the same bytes for the same cost, and the rule takes the one with half the body
+    with mock.patch.object(gm, "_STEP_S", 0.0):
+        gm._tiling.cache_clear()
+        try:
+            assert _cost(m, k, n, g, (256, 768, 2560)) == _cost(m, k, n, g, (512, 768, 2560))
+            assert _tiling(m, k, n, g) == (256, 768, 2560)
+        finally:
+            gm._tiling.cache_clear()
 
 
 def test_a_changed_term_moves_the_logits(params):

@@ -212,9 +212,10 @@ def test_rms_norm_eps_comes_from_the_config():
     assert float(jnp.abs(a - b).max()) > 1e-3
 
 
-# Group sizes against a 512-row tile (ops.grouped_matmul.TILE[0]): groups
-# that end inside a tile, on a tile's edge, span three tiles, are empty, or
-# are one row. What the kernel computed, not what the router counted.
+# Group sizes against a 512-row tile (ops.grouped_matmul.ROW_TILE, which the
+# tile count picks at these 2,048 rows): groups that end inside a tile, on a
+# tile's edge, span three tiles, are empty, or are one row. What the kernel
+# computed, not what the router counted.
 RAGGED = {
     "straddling": [500, 30, 1006, 0, 1, 511],
     "on_the_edges": [512, 0, 1024, 512, 0, 0],
@@ -229,10 +230,13 @@ def test_grouped_matmul_is_each_row_against_its_own_expert(sizes):
     experts, forward and both gradients: a row lost or given to a
     neighbouring expert at a tile boundary shows here, where the
     benchmark's check only counts what the router chose."""
-    from ray_tpu.ops.grouped_matmul import TILE, grouped_matmul
+    from ray_tpu.ops.grouped_matmul import ROW_TILE, _tiling, grouped_matmul
 
     m, k, n = sum(sizes), 128, 256
-    assert m > 2 * TILE[0]
+    # four row tiles in all three calls, so a group spans several and tiles straddle
+    assert m == 4 * ROW_TILE and all(
+        _tiling(m, *sides, len(sizes), weight_grad, itemsize=4)[0] == ROW_TILE
+        for *sides, weight_grad in ((k, n, False), (n, k, False), (k, n, True)))
     keys = jax.random.split(jax.random.PRNGKey(len(sizes) + sizes[0]), 3)
     lhs = jax.random.normal(keys[0], (m, k), jnp.float32)
     rhs = jax.random.normal(keys[1], (len(sizes), k, n), jnp.float32)
