@@ -1,4 +1,4 @@
-"""Flagship model: a decoder-only transformer, TPU-first, in the eleven
+"""Flagship model: a decoder-only transformer, TPU-first, in the twelve
 shapes today's open models take.
 
 What one layer computes, by configuration (all under one layer scan, one
@@ -133,6 +133,31 @@ checkpoint policy, one head and loss):
     ``router_bias``), which ``build_sharded_train_step`` writes in place of
     the optimizer's result: state of the train step that no gradient moves.
 
+  * the twelfth, a decoder in SEGMENTS whose later layers read what one
+    earlier layer made (SambaY under differential attention:
+    Phi-4-mini-flash-reasoning): ``segments=`` states the stack as segments of
+    periods, there ``("mamba", "window")`` pairs, a BRIDGE ``("mamba", "full")``
+    and ``("gmu", "cross")`` pairs (``sambay_segments``: every pair a segment
+    of one period, walked in line), each one ``_scan_periods`` over its own
+    stacks by place (``_scan_segments``). Three kinds of mixer
+    are the segments' own: "mamba" (``mamba=``, ``_mamba_mixer``, scope
+    ``mamba_mixer``), Mamba-1: a convolution with a bias (ops/short_conv.py)
+    and the SELECTIVE scan, a decay for every (channel, state) pair, a
+    recurrence a token at a time in Mosaic kernels (ops/selective_scan.py,
+    scope ``selective_scan``); "gmu" (``_gmu_mixer``, scope ``gmu``), a gated
+    memory unit ``(SiLU(h W_1) * M) W_2`` on the scan output ``M`` of the
+    bridge's mamba layer; "cross" (``_cross_mixer``, scope
+    ``cross_attention``), attention of the layer's own queries on the keys and
+    values of the bridge's full layer. The bridge's step returns ``(M, k1, k2,
+    V)`` beside the stream and the segments behind read them as operands their
+    scan does not carry: ``jax.grad`` through the scan sums the readers'
+    gradients. ``differential`` on the "window", "full" and "cross" kinds: the
+    heads in pairs, two calls of the flash kernels that share one V of twice
+    the head's width, ``softmax(q1 k1^T) V - lam softmax(q2 k2^T) V`` under a
+    norm a pair (``_diff_heads``, scope ``diff_attention``; ``lam0`` a
+    constant of the layer's ``depth_index``). ``norm="layer"``: LayerNorm with
+    a bias in every block norm and the final norm; ``attention_bias``.
+
 Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
   * functional: params are a pytree of jnp arrays. What a layer holds is
     written down ONCE, in the table below the configs: one function a part
@@ -175,7 +200,14 @@ Design notes (SURVEY §7.0.3 "parallelism is mesh axes"):
     layer over tp or sp, and a pattern of one-block layers behind a dense
     prefix; beside ``block_diffusion=``, any mixer but grouped-query
     attention, a callable ``attention``, sp, decode (a step that yields a
-    block, not a token) and ``loss_fn`` unless ``next_token=True``.
+    block, not a token) and ``loss_fn`` unless ``next_token=True``; ``segments``
+    ("mamba", "gmu" and "cross" layers) and ``differential`` through
+    ``init_kv_cache`` / ``decode_step`` (no cache of a selective scan's state,
+    no key-value cache that many layers read), through the pipeline (a stage
+    boundary does not carry the shared operands), beside a ``layer_pattern``,
+    a dense prefix, ``moe=``, a rotary embedding or a callable ``attention``;
+    a "mamba" layer or ``differential`` over tp or sp; ``differential``
+    outside ``segments``; ``norm="layer"`` on a branch's output.
 
 Reference parity: the reference has no model zoo of its own (models arrive
 via torch); this model family is the TPU build's equivalent of the LLM
@@ -205,6 +237,10 @@ from ray_tpu.ops.gated_delta_rule import (
 from ray_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 from ray_tpu.ops.rmsnorm import rmsnorm_reference
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.selective_scan import (
+    RESIDUAL_NAMES as SELECTIVE_SCAN_RESIDUAL_NAMES, kept_bytes as selective_scan_kept_bytes,
+    selective_scan, selective_scan_reference,
+)
 from ray_tpu.ops.short_conv import short_conv
 from ray_tpu.ops.sparse_index import (
     RESIDUAL_NAMES as INDEX_RESIDUAL_NAMES, index_loss, index_select,
@@ -541,6 +577,24 @@ class SSMConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class MambaConfig:
+    """A "mamba" layer's Mamba-1 mixer (Gu and Dao, arXiv:2312.00752) as
+    ``phi4flash``'s code states it (Mamba's defaults: ``expand`` 2, ``d_state``
+    16, ``dt_rank`` ``ceil(hidden / 16)``, ``d_conv`` 4, a convolution bias and
+    no projection bias): ``inner_dim`` channels, each with ``state_dim`` states
+    whose decay differs for every (channel, state) pair. A "gmu" layer's gated
+    memory unit is as wide: it reads a mamba layer's scan output."""
+    inner_dim: int = 5120
+    state_dim: int = 16
+    dt_rank: int = 160
+    conv_kernel: int = 4
+
+    def __post_init__(self):
+        if min(self.inner_dim, self.state_dim, self.dt_rank, self.conv_kernel) < 1:
+            raise ValueError(f"{self!r}: sizes >= 1")
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockDiffusionConfig:
     """The block-diffusion TRAINING objective (BD3-LM, arXiv:2503.09573; SDAR,
     arXiv:2510.06303) of ``block_diffusion_loss_fn``: a sequence of ``L``
@@ -628,6 +682,9 @@ class TransformerConfig:
     layer_pattern: tuple[str, ...] | None = None
     # The mixer of the pattern's "ssm" layers.
     ssm: SSMConfig | None = None
+    # The mixer of the "mamba" layers, and the width of the "gmu" layers that
+    # read a mamba layer's scan output.
+    mamba: MambaConfig | None = None
     # The mixer of the pattern's "linear" layers.
     linear: LinearAttentionConfig | None = None
     # The index scorer of the "sparse" layers: grouped-query attention over
@@ -637,6 +694,26 @@ class TransformerConfig:
     # The block-diffusion training objective (``block_diffusion_loss_fn``):
     # every layer is then grouped-query attention ("full", no ``latent``).
     block_diffusion: BlockDiffusionConfig | None = None
+    # The stack as SEGMENTS of periods, ``((pattern, periods), ...)``, each
+    # pattern a period of ``SEGMENT_KINDS``: a stack whose pattern changes along
+    # the depth (``sambay_segments`` builds SambaY's). ``layer_pattern``,
+    # a dense prefix and ``moe`` exclude it. A "gmu" layer reads the scan output
+    # and a "cross" layer the keys and values of the LAST "mamba" / "full" layer
+    # of the segment before the first one that names them, which is one period
+    # (the bridge).
+    segments: tuple[tuple[tuple[str, ...], int], ...] | None = None
+    # Differential attention (arXiv:2410.05258) on the "window", "full" and
+    # "cross" kinds: the heads in PAIRS, ``softmax(q1 k1^T) V - lam softmax(q2
+    # k2^T) V`` on a V of twice the head's width, a norm a pair (``_diff_heads``).
+    differential: bool = False
+    # The depth a layer's ``lam0 = 0.8 - 0.6 exp(-0.3 depth)`` is a constant of,
+    # a layer (None: its own index): a cut stack states the published indices.
+    depth_index: tuple[int, ...] | None = None
+    # A bias on the attention kinds' ``W_q``, ``W_k``, ``W_v`` and ``W_o``.
+    attention_bias: bool = False
+    # "rms": RMSNorm. "layer": LayerNorm, mean-centred, a weight AND a bias
+    # (``<norm>_bias`` leaves), in every block norm and the final norm.
+    norm: str = "rms"
     # The taps of a "conv" layer's gated short convolution (``conv_L_cache``).
     conv_kernel: int = 3
     # "pre": ``x + branch(norm(x))``. "post" (OLMo 2 / 3's reordered norm):
@@ -668,8 +745,8 @@ class TransformerConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
-        if self.norm_placement not in ("pre", "post", "both"):
-            raise ValueError(f"unknown norm_placement {self.norm_placement!r}")
+        if self.norm_placement not in ("pre", "post", "both") or self.norm not in ("rms", "layer"):
+            raise ValueError(f"unknown norm_placement {self.norm_placement!r} or norm {self.norm!r}")
         stated = self.latent.output_gate if self.latent else None
         if stated and self.output_gate not in (None, stated):
             raise ValueError(f"output_gate {self.output_gate!r} beside latent's {stated!r}: one")
@@ -705,6 +782,10 @@ class TransformerConfig:
                 "bias_update_rate over a pattern of one-block layers (stacked by place) is not "
                 "written: moved_router_biases walks the stacks by kind"
             )
+        if self.norm == "layer" and self.norm_placement != "pre":
+            raise NotImplementedError('norm="layer" on a branch\'s output (norm_placement "post" / "both") is not written')
+        if self.differential or self.segments is not None:
+            self._check_segments()
         if self.layer_pattern is None:
             return
         unknown = set(self.layer_pattern) - {*LAYER_KINDS, MLP_KIND}
@@ -747,6 +828,63 @@ class TransformerConfig:
                     f"{la.num_key_heads} != num_value_heads {la.num_value_heads}"
                 )
 
+    def _check_segments(self) -> None:
+        """``segments`` and ``differential``: what the stack may hold and what
+        neither is written beside, each by name."""
+        if callable(self.attention):
+            raise NotImplementedError(
+                "segments / differential attention under a callable attention= (ring, ulysses) "
+                "are not written: a pair's two softmaxes are two calls of the flash kernels "
+                "(attention='flash' | 'reference')"
+            )
+        if self.differential and (self.rope_theta is not None or self.qk_norm or self.qk_head_norm):
+            raise NotImplementedError(
+                "differential attention under a rotary embedding (rope_theta) or q / k norms is "
+                "not written: the pairs are projected as they stand (rope_theta=None)"
+            )
+        if self.differential and (
+            self.latent or self.sparse or self.block_diffusion or self.full_gate
+            or self.n_heads % 2 or self.n_kv_heads % 2 or (self.n_heads // 2) % (self.n_kv_heads // 2)
+        ):
+            raise NotImplementedError(
+                "differential attention is grouped-query attention's, in pairs of heads (an even "
+                "number of query and of key-value heads): latent=, sparse=, block_diffusion= or an "
+                "output gate beside it is not written"
+            )
+        if self.segments is None:
+            raise NotImplementedError(
+                "differential= outside segments= is not written: lam0 is a constant of a layer's "
+                "depth, which the segments' scans hand their layers"
+            )
+        if self.layer_pattern or self.first_dense_layers or self.moe or self.sparse or self.block_diffusion:
+            raise NotImplementedError(
+                "segments beside a layer_pattern, a dense prefix, moe=, sparse= or block_diffusion= "
+                "is not written: a segment is a period scan of two-block dense layers"
+            )
+        kinds = self._kinds()
+        unknown = set(kinds) - set(SEGMENT_KINDS)
+        if unknown or not all(pattern and periods >= 1 for pattern, periods in self.segments):
+            raise ValueError(f"segments {self.segments!r}: periods >= 1 of kinds {SEGMENT_KINDS}")
+        if sum(len(pattern) * periods for pattern, periods in self.segments) != self.n_layers:
+            raise ValueError(f"segments {self.segments!r} are not n_layers={self.n_layers} layers")
+        if ({"mamba", "gmu"} & set(kinds)) and self.mamba is None:
+            raise ValueError("segments with mamba or gmu layers need mamba=")
+        if "window" in kinds and (self.window is None or self.window < 1):
+            raise ValueError("segments with window layers need window= (keys a query sees)")
+        if self.depth_index is not None and len(self.depth_index) != self.n_layers:
+            raise ValueError(f"depth_index states {len(self.depth_index)} of {self.n_layers} layers")
+        readers = [n for n, (pattern, _) in enumerate(self.segments) if {"gmu", "cross"} & set(pattern)]
+        if readers:
+            read = {"gmu": "mamba", "cross": "full"}
+            wanted = {read[kind] for n in readers for kind in self.segments[n][0] if kind in read}
+            bridge = self.segments[readers[0] - 1] if readers[0] else ((), 0)
+            if bridge[1] != 1 or not wanted <= set(bridge[0]) or "cross" in kinds and not self.differential:
+                raise ValueError(
+                    f"segments {self.segments!r}: gmu / cross layers read the mamba / full layer of "
+                    "the segment before the first of them, ONE period (the bridge); a cross layer "
+                    "is differential attention's (differential=True)"
+                )
+
     def _refuse_beside_block_diffusion(self) -> None:
         """The block-diffusion mask is grouped-query attention's, in the flash
         kernels and their oracle: every other mixer is refused by name."""
@@ -769,9 +907,18 @@ class TransformerConfig:
             )
 
     def _kinds(self) -> tuple[str, ...]:
-        """The kinds of mixer a patterned model holds, the prefix's first."""
+        """The kinds of mixer a patterned model holds, the prefix's first; of
+        ``segments``, theirs in the stack's order."""
+        if self.segments is not None:
+            return tuple(dict.fromkeys(kind for pattern, _ in self.segments for kind in pattern))
         prefix = (self.first_dense_kind,) if self.first_dense_layers else ()
         return tuple(dict.fromkeys(prefix + self.layer_pattern))
+
+    def lam_init(self, layer: int) -> float:
+        """Differential attention's ``lam0`` of layer ``layer``: a constant of
+        its depth (``depth_index``: the published index of a cut stack's layer)."""
+        depth = layer if self.depth_index is None else self.depth_index[layer]
+        return 0.8 - 0.6 * math.exp(-0.3 * depth)
 
     @property
     def full_gate(self) -> str | None:
@@ -827,6 +974,31 @@ class TransformerConfig:
         return TransformerConfig(**base)
 
 
+def sambay_segments(n_layers: int) -> tuple[tuple[tuple[str, ...], int], ...]:
+    """SambaY's stack for ``n_layers`` layers (arXiv:2507.06607's
+    decoder-hybrid-decoder at ``mb_per_layer`` 2), ``s = n_layers / 2``: even
+    layers up to ``s`` are "mamba" and odd ones below it "window" (the
+    self-decoder), layer ``s`` and ``s + 1`` the bridge ("mamba", whose scan
+    output, and "full", whose keys and values, the rest read), then "gmu" on
+    the even and "cross" on the odd layers (the cross-decoder). Every pair of
+    layers is a segment of ONE period, which ``_scan_periods`` walks in line: a
+    layer's leaves are then its own arrays, and the fused step updates them as
+    soon as the layer's backward is through. (With the pairs of one pattern
+    stacked under a scan a stacked gradient is whole only when the loop ends:
+    17.32 GiB for a described v5e at 12 layers of the published widths and
+    16,384 tokens, where this layout reads 13.43; PERF.md section 6, PR 65.)"""
+    if n_layers % 4 or n_layers < 8:
+        raise ValueError(
+            f"n_layers={n_layers}: SambaY's rule needs a multiple of 4, and 8 or more for a "
+            "gmu and a cross layer"
+        )
+    pairs = n_layers // 4
+    return (
+        *[(("mamba", "window"), 1)] * pairs, (("mamba", "full"), 1),
+        *[(("gmu", "cross"), 1)] * (pairs - 1),
+    )
+
+
 # ---------------------------------------------------------------------------
 # The table: every leaf of every part of a layer, written down once
 # ---------------------------------------------------------------------------
@@ -868,12 +1040,28 @@ def _norm(width: int) -> _Leaf:
     return _Leaf((width,), (None,), lambda keys, shape, dtype: jnp.ones(shape, dtype))
 
 
+def _zeros(width: int) -> _Leaf:
+    """A bias: zeros, whole on every shard."""
+    return _Leaf((width,), (None,), lambda keys, shape, dtype: jnp.zeros(shape, dtype))
+
+
+def _norms(config: TransformerConfig, *names: str) -> dict:
+    """The stream's norms under ``names``: a weight each and, under
+    ``norm="layer"``, a bias ``<name>_bias`` behind it."""
+    leaves = {}
+    for name in names:
+        leaves[name] = _norm(config.dim)
+        if config.norm == "layer":
+            leaves[f"{name}_bias"] = _zeros(config.dim)
+    return leaves
+
+
 def _model_leaves(config: TransformerConfig) -> dict:
     """The leaves outside the layer stacks."""
     d, vocab = config.dim, config.vocab_size
     head = {} if config.tie_embeddings else {"lm_head": _Leaf((d, vocab), ("embed", "vocab"))}
     embed = _Leaf((vocab, d), ("vocab", "embed"), functools.partial(_normal, scale=0.02))
-    return {"embed": embed, "final_norm": _norm(d), **head}
+    return {"embed": embed, **_norms(config, "final_norm"), **head}
 
 
 def _gate_leaves(config: TransformerConfig, value_head_dim: int) -> dict:
@@ -900,6 +1088,92 @@ def _gqa_leaves(config: TransformerConfig) -> dict:
         "wv": _Leaf((d, kv_out), ("embed", "kv")),
         "wo": _Leaf((q_out, d), ("heads", "embed")),
         **({"q_norm": _norm(q_norm), "k_norm": _norm(k_norm)} if normed else {}),
+        **(_bias_leaves(config, ("q", "k", "v", "o")) if config.attention_bias else {}),
+        **(_differential_leaves(config) if config.differential else {}),
+    }
+
+
+def _bias_leaves(config: TransformerConfig, of: tuple[str, ...]) -> dict:
+    """``attention_bias``: ``b<name>`` of the projections ``of``, zeros."""
+    widths = {
+        "q": config.n_heads * config.head_dim, "k": config.n_kv_heads * config.head_dim,
+        "v": config.n_kv_heads * config.head_dim, "o": config.dim,
+    }
+    return {f"b{name}": _zeros(widths[name]) for name in of}
+
+
+def _differential_leaves(config: TransformerConfig) -> dict:
+    """Differential attention's own leaves a layer: the four vectors of
+    ``head_dim`` that ``lam`` is made of (float32, normal of scale 0.1, as the
+    Differential Transformer's code draws them) and the weight of the norm a
+    pair's difference goes through, ``2 head_dim`` wide, ones."""
+    vector = lambda keys, shape, dtype: _normal(keys, shape, jnp.float32, scale=0.1)
+    lam = {name: _Leaf((config.head_dim,), (None,), vector) for name in ("lq1", "lk1", "lq2", "lk2")}
+    return {**lam, "sub_norm": _norm(2 * config.head_dim)}
+
+
+def _cross_leaves(config: TransformerConfig) -> dict:
+    """A "cross" layer's mixer: differential attention of its OWN queries on
+    another layer's keys and values: ``W_q``, ``W_o`` and the pair's leaves, no
+    ``W_k`` and no ``W_v``."""
+    d, q_out = config.dim, config.n_heads * config.head_dim
+    return {
+        "wq": _Leaf((d, q_out), ("embed", "heads")),
+        "wo": _Leaf((q_out, d), ("heads", "embed")),
+        **(_bias_leaves(config, ("q", "o")) if config.attention_bias else {}),
+        **_differential_leaves(config),
+    }
+
+
+# A fresh Mamba-1 layer's step: log-uniform in ``[min, max]``, floored (Mamba's
+# ``dt_min``, ``dt_max``, ``dt_init_floor``).
+_MAMBA_DT = (1e-3, 1e-1, 1e-4)
+
+
+def _mamba_leaves(config: TransformerConfig) -> dict:
+    """A "mamba" layer's own leaves (Mamba-1): ``W_in`` ``[hidden, 2 inner]``
+    (``u`` then the gate ``z``); the convolution's filters and bias, uniform in
+    +-taps^-1/2 (a depthwise Conv1d's defaults); ``W_x`` ``[inner, dt_rank + 2
+    states]`` (the step's low rank, then ``B`` and ``C``); ``W_dt`` ``[dt_rank,
+    inner]`` with ``dt_bias`` the inverse softplus of a step log-uniform in
+    ``_MAMBA_DT``'s bounds; ``a_log = log(1 .. states)`` a channel (S4D-real);
+    ``d_skip`` ones; those three in float32; ``W_out``."""
+    mb, d = config.mamba, config.dim
+    dt_min, dt_max, dt_floor = _MAMBA_DT
+
+    def dt_bias(keys, shape, dtype):
+        dt = jnp.exp(_uniform(keys, shape, math.log(dt_min), math.log(dt_max)))
+        dt = jnp.maximum(dt, dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    def conv_bias(keys, shape, dtype):
+        bound = mb.conv_kernel ** -0.5
+        return _uniform(keys, shape, -bound, bound).astype(dtype)
+
+    a_log = lambda keys, shape, dtype: jnp.broadcast_to(
+        jnp.log(jnp.arange(1, mb.state_dim + 1, dtype=jnp.float32)), shape
+    )
+    ones = lambda keys, shape, dtype: jnp.ones(shape, jnp.float32)
+    return {
+        "w_in": _Leaf((d, 2 * mb.inner_dim), ("embed", "heads")),
+        "conv": _Leaf((mb.conv_kernel, mb.inner_dim), (None, "heads"), _filters),
+        "conv_bias": _Leaf((mb.inner_dim,), ("heads",), conv_bias),
+        "w_x": _Leaf((mb.inner_dim, mb.dt_rank + 2 * mb.state_dim), ("heads", None)),
+        "w_dt": _Leaf((mb.dt_rank, mb.inner_dim), (None, "heads")),
+        "dt_bias": _Leaf((mb.inner_dim,), ("heads",), dt_bias),
+        "a_log": _Leaf((mb.inner_dim, mb.state_dim), ("heads", None), a_log),
+        "d_skip": _Leaf((mb.inner_dim,), ("heads",), ones),
+        "w_out": _Leaf((mb.inner_dim, d), ("heads", "embed")),
+    }
+
+
+def _gmu_leaves(config: TransformerConfig) -> dict:
+    """A "gmu" layer's gated memory unit: ``W_1`` ``[hidden, inner]`` and
+    ``W_2`` ``[inner, hidden]``, no bias."""
+    d, inner = config.dim, config.mamba.inner_dim
+    return {
+        "w_in": _Leaf((d, inner), ("embed", "heads")),
+        "w_out": _Leaf((inner, d), ("heads", "embed")),
     }
 
 
@@ -1104,19 +1378,25 @@ def _stacks(config: TransformerConfig) -> dict:
     pattern of one-block layers stacks its layers by PLACE in the period, a
     list ``[place: [periods, ...]]`` (``layer_order`` walks either layout in
     the model's order), a mixer's place ``attn_norm`` and the mixer and no
-    MLP, an "mlp" place ``mlp_norm`` and the MLP and no mixer."""
+    MLP, an "mlp" place ``mlp_norm`` and the MLP and no mixer. Under
+    ``segments`` ``layers`` is a list of segments, each its layers by place:
+    ``[segment: [place: [periods, ...]]]``."""
     def stack(kind, experts, *lead):
-        norm = _norm(config.dim)
         if kind == MLP_KIND:
-            return lead, {"mlp_norm": norm}, *_mlp_leaves(config, experts)
-        mixer = {"attn_norm": norm, **_MIXERS[kind][0](config)}
+            return lead, _norms(config, "mlp_norm"), *_mlp_leaves(config, experts)
+        mixer = {**_norms(config, "attn_norm"), **_MIXERS[kind][0](config)}
         if config.one_block:
             return lead, mixer, {}, {}
         # norm_placement="both": a norm on each branch's output beside the two
         both = config.norm_placement == "both"
-        post = {"attn_post_norm": norm, "mlp_post_norm": norm} if both else {}
-        return lead, {**mixer, "mlp_norm": norm, **post}, *_mlp_leaves(config, experts)
+        post = _norms(config, "attn_post_norm", "mlp_post_norm") if both else {}
+        return lead, {**mixer, **_norms(config, "mlp_norm"), **post}, *_mlp_leaves(config, experts)
 
+    if config.segments is not None:
+        # a segment's layers by PLACE in its period, ``[periods, ...]`` each
+        return {"layers": [
+            [stack(kind, False, periods) for kind in pattern] for pattern, periods in config.segments
+        ]}
     prefix, experts = config.first_dense_layers, config.moe is not None
     stacks = {"dense_layers": stack(config.prefix_kind, False, prefix)} if prefix else {}
     if not config.layer_pattern:
@@ -1158,6 +1438,21 @@ def init_params(config: TransformerConfig, key: jax.Array) -> dict:
     split = lambda key, count: iter(jax.random.split(key, count))
     model, stacks, keys = _model_leaves(config), _stacks(config), split(key, 16)
     embed = {"embed": model.pop("embed")}
+    if config.segments is not None:
+        # embed, one key for all the segments; segment s, place n draws its
+        # mixer and its MLP from one stream of its own
+        params = draw(embed, keys)
+        segments_key = next(keys)
+        params.update(draw(model, keys))
+        params["layers"] = [
+            [
+                draw({**mixer, **mlp}, split(jax.random.fold_in(jax.random.fold_in(
+                    segments_key, number), place), 16), *lead)
+                for place, (lead, mixer, mlp, _) in enumerate(segment)
+            ]
+            for number, segment in enumerate(stacks["layers"])
+        ]
+        return params
     if config.layer_pattern:
         # embed, one key for all the kinds, lm_head; kind number n draws its
         # mixer (and a dense MLP) from one stream of its own and its experts
@@ -1686,6 +1981,8 @@ def _window_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
             return window_fn(q, k, v, causal)
 
     with jax.named_scope("window_attention"):
+        if config.differential:
+            return _diff_self_attention(h, layer, config, attention_fn)[0]
         o = _gqa_heads(h, layer, config, cos_sin, positions, attention_fn)
         return _gated_out(o, h, layer, config)
 
@@ -1694,6 +1991,8 @@ def _full_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attenti
     """A "full" layer's mixer on the branch input ``h``: latent attention
     where ``latent`` is set, else grouped-query attention; the output gate
     (either attention) and ``W_o``: ``_gated_out``."""
+    if config.differential:
+        return _diff_self_attention(h, layer, config, attention_fn)
     if config.latent:
         o = attention_fn(*_latent_qkv(h, layer, config, cos_sin, positions), True)
     else:
@@ -1776,6 +2075,151 @@ def _sparse_mixer(h, layer, config: TransformerConfig, cos_sin, positions, _):
         return _heads_out(out, layer), {**terms, "index_loss": term}
 
 
+def _refuse_axes(axes: tuple[str, ...], what: str) -> None:
+    """Raise ``what`` (formatted with ``axis``) where the mesh in scope cuts one of ``axes``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    for axis in axes:
+        if not mesh.empty and dict(mesh.shape).get(axis, 1) > 1:
+            raise NotImplementedError(what.format(axis=axis))
+
+
+def _selective_scan_over_mesh(config: TransformerConfig) -> Callable:
+    """The selective scan of a "mamba" layer over token-major operands (``u``
+    and ``dt`` ``[batch, seq, inner]``, ``B`` / ``C`` ``[batch, seq, states]``;
+    ``A`` ``[inner, states]`` and ``D`` a channel): the recurrence as an XLA scan
+    under ``attention="reference"``, else the kernels of ops/selective_scan.py,
+    per data shard with ``A`` and ``D`` whole on each."""
+    if config.attention == "reference":
+        return selective_scan_reference
+    rows = ("batch", None, None)
+    return _over_mesh(
+        selective_scan, (rows, rows, None, rows, rows, None), rows, ("tp", "sp"), (),
+        what="a mamba layer over a mesh with {axis} > 1 is not written: the selective scan and "
+        "its convolution run per data shard (dp / fsdp) with every channel, every state and the "
+        "whole sequence (a channel's recurrence needs every token, and tp over the channels would "
+        "cut W_x's contraction)",
+    )
+
+
+def _mamba_mixer(h, layer, config: TransformerConfig, *_):
+    """A "mamba" layer's mixer on the branch input ``h`` [batch, seq, hidden],
+    before the residual add: Mamba-1 (``inner`` channels ``c`` of ``state_dim``
+    states ``n``, ``R = dt_rank``); ``(out, {"memory": y})``, the scan's output
+    BEFORE the gate beside it, which a later segment's "gmu" layers read::
+
+        [u | z] = h W_in
+        u' = SiLU(conv(u) + conv_bias)                     (causal, depthwise)
+        [d | B | C] = u' W_x                               (R | N | N; B_t, C_t for all channels)
+        dt = softplus(d W_dt + dt_bias),  A = -exp(a_log)  (float32; A a channel AND state)
+        S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] u'_t[c]
+        y_t[c] = sum_n C_t[n] S_t[c, n] + D[c] u'_t[c]
+        out = (y * SiLU(z)) W_out
+
+    The convolution is the Mosaic kernel pair of ops/short_conv.py with its
+    bias and the recurrence that of ops/selective_scan.py (both per data shard
+    under a mesh), unless ``attention="reference"``, which keeps both in XLA's
+    plain forms. Everything is token-major, as the projections return it."""
+    mb = config.mamba
+    f32 = jnp.float32
+    with jax.named_scope("mamba_mixer"):
+        # made first: a mesh it is not written for is refused in ITS words
+        scan = _selective_scan_over_mesh(config)
+        u, z = jnp.split(h @ layer["w_in"], 2, axis=-1)
+        with jax.named_scope("short_conv"):
+            u = _short_conv_over_mesh(config, bias=True)(u, layer["conv"], layer["conv_bias"])
+        step, b, c = jnp.split(u @ layer["w_x"], (mb.dt_rank, mb.dt_rank + mb.state_dim), axis=-1)
+        dt = jax.nn.softplus(
+            jnp.matmul(step, layer["w_dt"], preferred_element_type=f32) + layer["dt_bias"].astype(f32)
+        )
+        y = scan(u, dt, -jnp.exp(layer["a_log"].astype(f32)), b, c, layer["d_skip"].astype(f32))
+        return _silu_mul(z, y) @ layer["w_out"], {"memory": y}
+
+
+def _gmu_mixer(h, layer, config: TransformerConfig, *_):
+    """A "gmu" layer's gated memory unit on the branch input ``h``: ``(SiLU(h
+    W_1) * M) W_2``, ``M`` the scan output ``[batch, seq, inner]`` that the
+    bridge's "mamba" layer handed on (``layer["shared"]``): no convolution, no
+    scan of its own."""
+    with jax.named_scope("gmu"):
+        return _silu_mul(h @ layer["w_in"], layer["shared"]["memory"]) @ layer["w_out"]
+
+
+# The epsilon of the norm a pair's difference goes through (the Differential
+# Transformer's ``subln``).
+_DIFF_NORM_EPS = 1e-5
+
+
+def _pairs(x, heads: int, halves: bool):
+    """``x`` ``[batch, seq, heads x head_dim]`` by PAIRS of heads, heads first:
+    with ``halves`` the pair's two members apart, ``[batch, heads / 2, seq,
+    head_dim]`` each (``q1, q2`` or ``k1, k2``); else ONE ``[batch, heads / 2,
+    seq, 2 head_dim]``, the pair's heads side by side (``V``)."""
+    batch, seq, _ = x.shape
+    if not halves:
+        return x.reshape(batch, seq, heads // 2, -1).transpose(0, 2, 1, 3)
+    x = x.reshape(batch, seq, heads // 2, 2, -1).transpose(0, 2, 3, 1, 4)
+    return x[:, :, 0], x[:, :, 1]
+
+
+def _projected(h, layer, name: str):
+    """``h W_name``, and its bias where the layer has one (``attention_bias``)."""
+    out = h @ layer[f"w{name}"]
+    return out + layer[f"b{name}"] if f"b{name}" in layer else out
+
+
+def _diff_heads(q, keys_values, layer, config: TransformerConfig, attention_fn):
+    """Differential attention's output ``concat_j(o_j) W_o`` of the pairs'
+    queries ``q = (q1, q2)`` on ``keys_values = (k1, k2, V)``, pair ``j``
+    reading key-value pair ``j // (pairs / kv pairs)``::
+
+        a1_j = softmax(q1_j k1^T / sqrt(d) + mask) V,  a2_j = softmax(q2_j k2^T / sqrt(d) + mask) V
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0       (float32, one scalar a layer)
+        o_j = RMSNorm(a1_j - lam a2_j; sub_norm) * (1 - lam0)
+
+    TWO calls of ``attention_fn`` (the flash kernels with q / k of ``head_dim``
+    and v of twice that, under the layer's mask), whose ``out`` and ``lse`` a
+    layer checkpoint keeps as it keeps one call's; what differential attention
+    costs BESIDE them runs under scope ``diff_attention``. ``lam0`` is
+    ``layer["lam_init"]``, a constant of the layer's depth that the segments'
+    scan hands it."""
+    _refuse_axes(("tp", "sp"), (
+        "differential attention over a mesh with {axis} > 1 is not written: a pair's two query "
+        "heads and its one V must lie on one shard, and the kernels run on whole sequences"
+    ))
+    (q1, q2), (k1, k2, v) = q, keys_values
+    a1, a2 = attention_fn(q1, k1, v, True), attention_fn(q2, k2, v, True)
+    with jax.named_scope("diff_attention"):
+        f32 = jnp.float32
+        dot = lambda a, b: jnp.sum(layer[a].astype(f32) * layer[b].astype(f32))
+        lam0 = layer["lam_init"].astype(f32)
+        lam = jnp.exp(dot("lq1", "lk1")) - jnp.exp(dot("lq2", "lk2")) + lam0
+        o = a1.astype(f32) - lam * a2.astype(f32)
+        o = rmsnorm_reference(o, layer["sub_norm"], eps=_DIFF_NORM_EPS).astype(f32) * (1.0 - lam0)
+    out = _heads_out(o.astype(a1.dtype), layer)
+    return out + layer["bo"] if "bo" in layer else out
+
+
+def _diff_self_attention(h, layer, config: TransformerConfig, attention_fn):
+    """A "window" or "full" layer under ``differential``: ``(out, {"k1", "k2",
+    "v"})``, the layer's own keys and values beside its output (what a later
+    segment's "cross" layers read)."""
+    q = _pairs(_projected(h, layer, "q"), config.n_heads, True)
+    k1, k2 = _pairs(_projected(h, layer, "k"), config.n_kv_heads, True)
+    v = _pairs(_projected(h, layer, "v"), config.n_kv_heads, False)
+    out = _diff_heads(q, (k1, k2, v), layer, config, attention_fn)
+    return out, {"k1": k1, "k2": k2, "v": v}
+
+
+def _cross_mixer(h, layer, config: TransformerConfig, cos_sin, positions, attention_fn):
+    """A "cross" layer's mixer: differential attention of the layer's OWN
+    queries on the keys and values the bridge's "full" layer handed on
+    (``layer["shared"]``), causal over the whole context; no ``W_k``, no ``W_v``."""
+    with jax.named_scope("cross_attention"):
+        shared = layer["shared"]
+        q = _pairs(_projected(h, layer, "q"), config.n_heads, True)
+        return _diff_heads(q, (shared["k1"], shared["k2"], shared["v"]), layer, config, attention_fn)
+
+
 # The kinds of mixer: what a ``layer_pattern`` may name. A kind is one row:
 # its leaves (the table above) and ``apply(h, layer, config, cos_sin,
 # positions, attention_fn)``, the mixer on the branch input (a kind with no
@@ -1790,8 +2234,16 @@ _MIXERS = {
     "window": (_window_leaves, _window_mixer),
     "sparse": (_sparse_leaves, _sparse_mixer),
     "ssm": (_ssm_leaves, _ssm_mixer),
+    "mamba": (_mamba_leaves, _mamba_mixer),
+    "gmu": (_gmu_leaves, _gmu_mixer),
+    "cross": (_cross_leaves, _cross_mixer),
 }
-LAYER_KINDS = tuple(_MIXERS)
+# What ``segments`` may name: the kinds whose layers read nothing but the
+# stream ("mamba", "window", "full"), and the two that read what the bridge
+# handed on.
+SEGMENT_KINDS = ("mamba", "window", "full", "gmu", "cross")
+# What a ``layer_pattern`` may name: every kind but the segments' own three.
+LAYER_KINDS = tuple(kind for kind in _MIXERS if kind not in ("mamba", "gmu", "cross"))
 # What a ``layer_pattern`` may name beside the mixers: a layer that is its MLP
 # alone. Every layer of such a pattern is ONE block (``one_block``).
 MLP_KIND = "mlp"
@@ -1867,11 +2319,43 @@ def _rmsnorm_ckpt(x, weight, eps):
     return rmsnorm_reference(x, weight, eps=eps)
 
 
+def layernorm_reference(x, weight, bias, eps):
+    """LayerNorm, mean-centred, with a weight and a bias: float32 statistics,
+    ``x``'s dtype out."""
+    x32 = x.astype(jnp.float32)
+    centred = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    normed = centred * jax.lax.rsqrt(jnp.mean(centred * centred, axis=-1, keepdims=True) + eps)
+    return (normed * weight.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+# ... and for LayerNorm, as for RMSNorm above.
+@functools.partial(jax.checkpoint, prevent_cse=False, static_argnums=(3,))
+def _layernorm_ckpt(x, weight, bias, eps):
+    return layernorm_reference(x, weight, bias, eps)
+
+
+def _stream_norm(x, norm, eps):
+    """The stream's norm of ``x`` as ``norm`` says: a weight (RMSNorm), or a
+    ``(weight, bias)`` pair (LayerNorm: ``norm="layer"``)."""
+    if isinstance(norm, tuple):
+        return layernorm_reference(x, *norm, eps)
+    return rmsnorm_reference(x, norm, eps=eps)
+
+
+def _final_norm(params, config: TransformerConfig):
+    """The final norm's leaves as ``_stream_norm`` takes them."""
+    if config.norm == "layer":
+        return params["final_norm"], params["final_norm_bias"]
+    return params["final_norm"]
+
+
 def _branch_in(x, layer, norm: str, config: TransformerConfig):
     """What a residual branch reads: ``norm(x)``, or ``x`` itself under
     ``norm_placement="post"`` (whose one norm is on the branch's output)."""
     if config.norm_placement == "post":
         return x
+    if config.norm == "layer":
+        return _layernorm_ckpt(x, layer[norm], layer[f"{norm}_bias"], config.rms_norm_eps)
     return _rmsnorm_ckpt(x, layer[norm], config.rms_norm_eps)
 
 
@@ -2691,8 +3175,10 @@ def _scan_layers(step, carry, layers, *xs):
     return jax.lax.scan(body, carry, (index, layers, *xs))
 
 
-def _scan_periods(steps, carry, layers, config):
-    """``jax.lax.scan`` over the PERIODS of a patterned model: ``layers`` is
+def _scan_periods(steps, carry, layers, pattern, by_place, hands_on=False):
+    """``jax.lax.scan`` over the PERIODS of a patterned model (``pattern``:
+    its ``layer_pattern``, or a segment's period; ``by_place``: its
+    ``layers_by_place``): ``layers`` is
     ``{kind: leaves of [periods, count in a period, ...]}`` and the body
     runs ``steps[kind](carry, layer)`` for the period's layers in the
     pattern's order, each layer the next of its kind. A kind's step is
@@ -2721,8 +3207,12 @@ def _scan_periods(steps, carry, layers, config):
     temporaries and 325 MB of generated code for a described v5e, where by
     place a layer's leaves are updated as soon as its own backward is through
     (PERF.md section 6, PR 55). The older patterns keep their layout and their
-    programs; that there are two layouts is a debt (ROADMAP Queue 1)."""
-    pattern = config.layer_pattern
+    programs; that there are two layouts is a debt (ROADMAP Queue 1).
+
+    ``hands_on`` (a segment that is ONE period by place, the bridge): what the
+    steps return beside the stream is not stacked but merged, ``{name:
+    array}`` of whichever layers returned any, a later layer's over an
+    earlier's: what the segments behind read."""
     in_place = lambda leaves, lead: {
         name: jax.lax.stop_gradient(leaves[name]).reshape(-1, *leaves[name].shape[lead:])
         for name in _EXPERT_WEIGHTS if "router" in leaves and name in leaves
@@ -2738,11 +3228,13 @@ def _scan_periods(steps, carry, layers, config):
             carry, routing = steps[kind](carry, layer)
             if routing is not None:
                 routings.append(routing)
+        if hands_on:
+            return carry, {name: array for handed in routings for name, array in handed.items()}
         if not routings:
             return carry, None
         return carry, jax.tree.map(lambda *leaves: jnp.stack(leaves), *routings)
 
-    if config.layers_by_place:
+    if by_place:
         stacks = [in_place(leaves, 1) for leaves in layers]
 
         def body(carry, scanned):
@@ -2763,16 +3255,60 @@ def _scan_periods(steps, carry, layers, config):
             return walk(carry, one_by_one(*scanned))
 
     periods = next(iter(jax.tree.leaves(layers))).shape[0]
-    if periods == 1 and config.layers_by_place:
+    if periods == 1 and by_place:
         return body(carry, (jnp.int32(0), jax.tree.map(lambda leaf: leaf[0], layers)))
     carry, routing = jax.lax.scan(body, carry, (jnp.arange(periods, dtype=jnp.int32), layers))
     return carry, jax.tree.map(lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), routing)
 
 
+def _scan_segments(step, x, segments, config: TransformerConfig):
+    """The stream through ``config.segments``, each ONE ``_scan_periods`` over
+    its own stacks by place (``segments``: ``params["layers"]``).
+    ``step(kind, experts, hands_on)`` makes a kind's checkpointed layer step
+    ``(carry, layer, shared)``. The BRIDGE, the segment before the first that
+    names a "gmu" or "cross" layer, is one period walked in line and hands on
+    what its mixers return beside the stream (``memory``: its "mamba" layer's
+    scan output; ``k1``, ``k2``, ``v``: its "full" layer's keys and values); the
+    segments behind it get those as ``shared``, an operand their scan does NOT
+    carry: every reader's cotangent of it is summed by ``jax.grad`` through the
+    scan, so ``W_k`` and ``W_v`` of the bridge and everything behind ``memory``
+    get the sum of their readers' gradients with no hand-written sum. Under
+    ``differential`` a layer's ``lam0`` rides in beside its leaves
+    (``lam_init``, a constant of the layer's depth, scanned with the stack)."""
+    reads = lambda number: number < len(config.segments) and bool(
+        {"gmu", "cross"} & set(config.segments[number][0])
+    )
+    shared, first = {}, 0
+    for number, ((pattern, periods), places) in enumerate(zip(config.segments, segments)):
+        hands_on = reads(number + 1) and not shared
+        if config.differential:
+            depth = lambda place: [
+                config.lam_init(first + period * len(pattern) + place) for period in range(periods)
+            ]
+            places = [
+                {**leaves, "lam_init": jnp.asarray(depth(place), jnp.float32)}
+                for place, leaves in enumerate(places)
+            ]
+        def reading(run, handed=shared):
+            return lambda carry, layer: run(carry, layer, handed)
+
+        steps = {kind: reading(step(kind, False, hands_on)) for kind in dict.fromkeys(pattern)}
+        x, handed = _scan_periods(steps, x, places, pattern, True, hands_on)
+        shared = handed if hands_on else shared
+        first += periods * len(pattern)
+    return x
+
+
 def layer_order(params: dict, config: TransformerConfig):
     """A patterned model's layers in the order the stream passes them, ``(kind,
     the layer's own leaves)``, one at a time, out of either layout of
-    ``params["layers"]`` (``_stacks``)."""
+    ``params["layers"]`` (``_stacks``); the segments' one after the other."""
+    if config.segments is not None:
+        for (pattern, periods), places in zip(config.segments, params["layers"]):
+            for period in range(periods):
+                for kind, leaves in zip(pattern, places):
+                    yield kind, jax.tree.map(lambda leaf: leaf[period], leaves)
+        return
     for period in range(config.periods):
         taken = dict.fromkeys(config.layer_pattern, 0)
         for place, kind in enumerate(config.layer_pattern):
@@ -2802,7 +3338,7 @@ def _lm_head(params, config: TransformerConfig):
 def _head(params, x, config: TransformerConfig):
     """final_norm + lm_head: f32 logits."""
     with jax.named_scope("head"):
-        x = rmsnorm_reference(x, params["final_norm"], eps=config.rms_norm_eps)
+        x = _stream_norm(x, _final_norm(params, config), config.rms_norm_eps)
         return (x @ _lm_head(params, config)).astype(jnp.float32)
 
 
@@ -2816,7 +3352,7 @@ def _remat_policy(remat: str) -> Callable:
     policies = jax.checkpoint_policies
     flash = policies.save_only_these_names(
         *RESIDUAL_NAMES, *DELTA_RULE_RESIDUAL_NAMES, *MOE_RESIDUAL_NAMES, *INDEX_RESIDUAL_NAMES,
-        *SSD_RESIDUAL_NAMES,
+        *SSD_RESIDUAL_NAMES, *SELECTIVE_SCAN_RESIDUAL_NAMES,
     )
     if remat == "full":
         return flash
@@ -2861,13 +3397,19 @@ def _hidden_with_routing(params, tokens, config, positions=None, selections=Fals
     cos_sin = _rope_tables(config)
     x = _embed(params, tokens, config)
 
-    def layer_step(kind, experts, carry, layer):
+    def layer_step(kind, experts, hands_on, carry, layer, shared=None):
         # under ``one_block`` a layer is its mixer alone or its MLP alone
         x, terms, routing = carry, None, None
+        if shared is not None:
+            # a segment's layer: what the bridge handed on rides in beside its
+            # leaves, and what its own mixer hands on goes out beside the stream
+            layer = {**layer, "shared": shared}
         if kind != MLP_KIND:
             x, terms = _attention_block(x, layer, kind, config, cos_sin, positions, attention_fn)
         if kind == MLP_KIND or not config.one_block:
             x, routing = _mlp_block(x, layer, config, experts, carry)
+        if shared is not None:
+            return x, (terms if hands_on else None)
         if config.sparse is not None:
             # the scorer's term rides the scan beside the experts' routing
             terms = terms or {"index_loss": jnp.zeros((), jnp.float32)}
@@ -2877,18 +3419,22 @@ def _hidden_with_routing(params, tokens, config, positions=None, selections=Fals
 
     policy = None if config.remat is None else _remat_policy(config.remat)
 
-    def step(kind, experts):
+    def step(kind, experts, hands_on=False):
         """The one layer step under the one policy, for a stack of ``kind``
-        layers."""
-        of_kind = functools.partial(layer_step, kind, experts)
+        layers (``hands_on``: a bridge's, ``_scan_segments``)."""
+        of_kind = functools.partial(layer_step, kind, experts, hands_on)
         return of_kind if policy is None else jax.checkpoint(of_kind, policy=policy)
 
     experts = config.moe is not None
+    if config.segments is not None:
+        return _scan_segments(step, x, params["layers"], config), None
     if "dense_layers" in params:
         x, _ = _scan_layers(step(config.prefix_kind, False), x, params["dense_layers"])
     if config.layer_pattern:
         steps = {kind: step(kind, experts) for kind in dict.fromkeys(config.layer_pattern)}
-        return _scan_periods(steps, x, params["layers"], config)
+        return _scan_periods(
+            steps, x, params["layers"], config.layer_pattern, config.layers_by_place
+        )
     return _scan_layers(step(config.layer_kind, experts), x, params["layers"])
 
 
@@ -2966,7 +3512,7 @@ def _head_loss_fwd(eps, final_norm, lm_head, x, targets, weights):
         total, nll, dlogits = carry
         x, wanted, weight = (jax.lax.dynamic_index_in_dim(a, i, keepdims=False) for a in by_chunk)
         with jax.named_scope("head"):
-            h = rmsnorm_reference(x, final_norm, eps=eps)
+            h = _stream_norm(x, final_norm, eps)
             logits = (h @ lm_head).astype(jnp.float32)
         with jax.named_scope("loss"):
             wanted, weight = wanted[:, None], weight[:, None]
@@ -2998,7 +3544,7 @@ def _head_loss_bwd(eps, residuals, g):
     chunks, rows, _ = dlogits.shape
     with jax.named_scope("head"):
         h, norm_vjp = jax.vjp(
-            lambda x, weight: rmsnorm_reference(x, weight, eps=eps),
+            lambda x, norm: _stream_norm(x, norm, eps),
             _by_chunk(x, chunks, rows // batch), final_norm,
         )
         # Two plain matmuls over every token at once, in the chunks' order,
@@ -3055,7 +3601,7 @@ def head_loss(
         weights = mask.astype(jnp.float32) / jnp.maximum(jnp.sum(mask), 1.0)
     with jax.named_scope("head"):
         lm_head = _lm_head(params, config)
-    return _head_loss(config.rms_norm_eps, params["final_norm"], lm_head, x, targets, weights)
+    return _head_loss(config.rms_norm_eps, _final_norm(params, config), lm_head, x, targets, weights)
 
 
 def loss_fn(
@@ -3218,6 +3764,20 @@ def linear_state_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
     )
 
 
+def selective_scan_bytes(config: TransformerConfig, batch: int, seq: int) -> int:
+    """Bytes the selective scans of one training step keep for the backward,
+    every "mamba" layer (ops/selective_scan.py's ``kept_bytes``: the outputs
+    and the chunk-start states; a chunk's other states are made again): 0 for
+    a model with no mamba layer."""
+    if config.segments is None or config.mamba is None:
+        return 0
+    layers = sum(pattern.count("mamba") * periods for pattern, periods in config.segments)
+    mb = config.mamba
+    return layers * selective_scan_kept_bytes(
+        batch, seq, mb.inner_dim, mb.state_dim, jnp.dtype(config.dtype).itemsize
+    )
+
+
 def _refuse_stated_head_dim(config: TransformerConfig, what: str) -> None:
     if config.n_heads * config.head_dim != config.dim:
         raise NotImplementedError(
@@ -3229,6 +3789,13 @@ def _refuse_stated_head_dim(config: TransformerConfig, what: str) -> None:
 
 def _refuse_dense_prefix(config: TransformerConfig, what: str) -> None:
     _refuse_stated_head_dim(config, what)
+    if config.segments is not None:
+        raise NotImplementedError(
+            f"{what} over segments ({', '.join(config._kinds())}) is not written: a stage "
+            "boundary would have to carry the bridge's shared operands (a mamba layer's scan output, "
+            "a full layer's keys and values) to every stage behind it and their readers' gradients "
+            "back: train it fused (loss_fn)"
+        )
     if config.sparse is not None:
         raise NotImplementedError(
             f"{what} over sparse layers is not written: a layer's scorer term (the index "
@@ -3342,6 +3909,14 @@ def _refuse_latent_cache(config: TransformerConfig) -> None:
             "attention reads the chosen rows), which is not written yet"
         )
     _refuse_stated_head_dim(config, "decode")
+    if config.segments is not None or config.differential:
+        raise NotImplementedError(
+            'decode over segments ("mamba", "gmu" and "cross" layers) or differential attention is '
+            "not written: it needs a cache of each selective scan's state and its convolution's "
+            "last inputs, ONE key-value cache (k1, k2 and a V of twice the head's width) that "
+            "the cross layers read beside the bridge's scan output, and the pair's subtraction "
+            "on a cached step (init_kv_cache / decode_step are ungated grouped-query attention's)"
+        )
     if config.layer_pattern and "window" in config._kinds():
         raise NotImplementedError(
             "decode with window layers needs a ring cache of `window` rows a window layer "

@@ -1,5 +1,5 @@
 """The table of leaves in ``models/transformer.py`` (``_Leaf``, one function
-a part of a layer, ``_MIXERS``) and its three readers, over eight of the shapes
+a part of a layer, ``_MIXERS``) and its three readers, over nine of the shapes
 the model takes, at tiny widths.
 
 ``WEIGHTS`` holds a digest of ``init_params(config, PRNGKey(0))`` for each
@@ -12,7 +12,8 @@ may be regenerated only after a jax upgrade that moves the ``dense`` case
 too (one ``jax.random.normal`` a leaf: then the generator changed, not the
 table); print them with ``python tests/test_layer_table.py``. The seventh
 shape's ("window" layers) was recorded on the commit that added the kind, and
-so was the eighth's (a gate on window layers, four norms a layer).
+so was the eighth's (a gate on window layers, four norms a layer) and the
+ninth's (segments: Mamba-1, differential attention, LayerNorm, PR 65).
 """
 
 import hashlib
@@ -82,6 +83,12 @@ SHAPES = {
             bias_update_rate=0.001,
         ),
     ),
+    "segments_mamba_differential_layer_norm": lambda: T.TransformerConfig.tiny(
+        dim=64, n_layers=8, n_heads=4, n_kv_heads=2, hidden_dim=96, dtype=jnp.bfloat16,
+        rope_theta=None, window=8, differential=True, attention_bias=True, norm="layer",
+        tie_embeddings=True, segments=T.sambay_segments(8), depth_index=(0, 1, 2, 3, 16, 17, 18, 19),
+        mamba=T.MambaConfig(inner_dim=128, state_dim=16, dt_rank=4, conv_kernel=4),
+    ),
 }
 
 WEIGHTS = {
@@ -93,6 +100,7 @@ WEIGHTS = {
     "conv_tied_head_norm": "33de65ffcdb6b1acf14b8bbbbeadcecb6eed535fa11dd9eac67c51e7272e6b74",
     "window_stated_head_relu_held": "9e6e378b84504b61a8b994a8d36f507845d99ce51eac031e5045934926a2247e",
     "gated_window_four_norms_bias_rule": "5bd9f0e82cac0cf53d51b437b061d956b00bb8250ba16b69127b92b7a5e41a4b",
+    "segments_mamba_differential_layer_norm": "699eef6de6cf733b3cc34c21309f57479f342a20d3ea4312d91f1befde4656db",
 }
 
 
@@ -135,9 +143,11 @@ def test_the_count_from_shapes_is_the_count_of_the_arrays(shape):
 
 
 def test_the_kinds_a_pattern_may_name_are_the_tables_rows():
-    assert T.LAYER_KINDS == tuple(T._MIXERS) == (
-        "linear", "full", "conv", "window", "sparse", "ssm",
-    )
+    assert T.LAYER_KINDS == ("linear", "full", "conv", "window", "sparse", "ssm")
+    # ... the table's first six rows; the last three are the segments' own
+    # (they read what another layer made: ``segments=`` alone names them)
+    assert tuple(T._MIXERS) == T.LAYER_KINDS + ("mamba", "gmu", "cross")
+    assert T.SEGMENT_KINDS == ("mamba", "window", "full", "gmu", "cross")
     # beside the mixers a pattern may name layers that are their MLP alone
     assert T.MLP_KIND == "mlp" and T.MLP_KIND not in T._MIXERS
     with pytest.raises(ValueError, match="kinds are"):

@@ -112,9 +112,24 @@ WINDOW_SHAPED = dict(
 )
 
 
+# A decoder in segments: a Mamba-1 / window pair, the bridge and a gated memory
+# unit / cross pair under differential attention, LayerNorm and a tied head;
+# what it names: ``mamba_mixer`` and inside it ``selective_scan`` (and
+# ``short_conv``), ``gmu``, ``cross_attention`` and, wherever a pair's two
+# outputs meet, ``diff_attention``.
+SAMBAY_SHAPED = dict(
+    dim=64, n_layers=8, n_heads=4, n_kv_heads=2, hidden_dim=96, rope_theta=None, window=16,
+    differential=True, attention_bias=True, norm="layer", tie_embeddings=True,
+    segments=T.sambay_segments(8),
+    mamba=T.MambaConfig(inner_dim=128, state_dim=16, dt_rank=4, conv_kernel=4),
+)
+SAMBAY_SCOPES = ("mamba_mixer", "selective_scan", "gmu", "cross_attention", "diff_attention")
+SAMBAY = re.compile(r"(?:^|[/(])(" + "|".join(SAMBAY_SCOPES) + r")(?=[/)]|$)")
+
+
 @functools.lru_cache(maxsize=None)
 def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True, latent=False,
-                 ling=False, lfm2=False, window=False):
+                 ling=False, lfm2=False, window=False, sambay=False):
     """``[(operation, op_name)]`` of the tiny configuration's compiled fused
     step on one device; ``scoped=False`` compiles the same step with every
     ``jax.named_scope`` of the program turned into a no-op; ``moe`` the
@@ -126,9 +141,9 @@ def instructions(remat, scoped=True, moe=False, axes=ONE_DEVICE, keep_flash=True
     (latent attention, a dense first layer, sigmoid-routed experts with
     shared experts); ``ling`` the patterned one over held experts; ``lfm2``
     the one with conv mixers under a tied head; ``window`` the one with
-    window layers."""
+    window layers; ``sambay`` the decoder in segments."""
     shaped = (
-        WINDOW_SHAPED if window else LFM2_SHAPED if lfm2 else LING_SHAPED if ling else MOONLIGHT_SHAPED if latent
+        SAMBAY_SHAPED if sambay else WINDOW_SHAPED if window else LFM2_SHAPED if lfm2 else LING_SHAPED if ling else MOONLIGHT_SHAPED if latent
         else OLMOE_SHAPED if moe else {}
     )
     config = T.TransformerConfig.tiny(remat=remat, **shaped)
@@ -467,6 +482,44 @@ def test_the_worst_case_in_its_loop_names_its_work(remat):
     assert not [n for _op, n in instructions(remat, lfm2=True) if _WORST_CASE.search(n)]
 
 
+@pytest.mark.parametrize("remat", POLICIES)
+def test_a_decoder_in_segments_names_its_mixers(remat):
+    """``mamba_mixer``, ``gmu`` and ``cross_attention`` lie inside ``attention``
+    and hold their mixers' matmuls forward and backward; ``selective_scan`` (and
+    ``short_conv``) lie inside ``mamba_mixer``; ``diff_attention`` lies inside
+    ``attention`` on window, full and cross layers (inside ``window_attention``
+    and ``cross_attention`` there) and holds no matmul; every matmul has a block."""
+    named = instructions(remat, sambay=True)
+    matmuls = [n for op, n in named if op in ("dot", "convolution") and n]
+    assert not [n for n in matmuls if not BLOCKS.search(n)]
+    for scope in SAMBAY_SCOPES:
+        mine = [n for _op, n in named if scope in SAMBAY.findall(n)]
+        assert [n for n in mine if "transpose(" not in n], scope
+        assert [n for n in mine if "transpose(" in n], scope
+        assert {BLOCKS.search(n).group(1) for n in mine} == {"attention"}, scope
+    scans = [n for _op, n in named if "selective_scan" in SAMBAY.findall(n)]
+    assert all("mamba_mixer" in SAMBAY.findall(n) for n in scans)
+    assert [n for n in scans if "_selective_scan_forward" in n] and [
+        n for n in scans if "_selective_scan_backward" in n]
+    convs = [n for _op, n in named if LINEAR.search(n)]
+    assert convs and all("mamba_mixer" in SAMBAY.findall(n) for n in convs)
+    diffs = [n for _op, n in named if "diff_attention" in SAMBAY.findall(n)]
+    assert [n for n in diffs if NEW.search(n) and NEW.search(n).group(1) == "window_attention"]
+    assert [n for n in diffs if "cross_attention" in SAMBAY.findall(n)]
+    assert [n for n in diffs if not NEW.search(n) and "cross_attention" not in SAMBAY.findall(n)]
+    assert not [n for n in matmuls if "diff_attention" in SAMBAY.findall(n)]
+    for scope, least in (("mamba_mixer", 4 * 3), ("gmu", 2 * 3), ("cross_attention", 2 * 3)):
+        assert len([n for n in matmuls if scope in SAMBAY.findall(n)]) >= least, scope
+    assert [n for n in matmuls if BLOCKS.search(n).group(1) == "head"]
+
+
+def test_the_segments_scopes_change_names_never_the_program():
+    scoped = instructions(None, sambay=True)
+    plain = instructions(None, scoped=False, sambay=True)
+    assert [op for op, _ in scoped] == [op for op, _ in plain]
+    assert not any(SAMBAY.search(n) for _op, n in plain)
+
+
 def test_the_new_scopes_change_names_never_the_program():
     scoped, plain = instructions(None, ling=True), instructions(None, scoped=False, ling=True)
     assert [op for op, _ in scoped] == [op for op, _ in plain]
@@ -490,4 +543,10 @@ def test_vocabulary():
     assert T.LINEAR_SCOPES == ("linear_attention", "short_conv", "delta_rule", "gate_norm")
     assert not set(NEW_SCOPES) & (
         set(T.SCOPES) | set(T.MOE_SCOPES) | set(T.LATENT_SCOPES) | set(T.LINEAR_SCOPES)
+    )
+    # PR 65's five, read by name too (``mamba_mixer_ms``, ``selective_scan_ms``,
+    # ``gmu_ms``, ``cross_attn_ms``, ``diff_attn_ms``)
+    assert SAMBAY_SCOPES == ("mamba_mixer", "selective_scan", "gmu", "cross_attention", "diff_attention")
+    assert not set(SAMBAY_SCOPES) & (
+        set(T.SCOPES) | set(T.MOE_SCOPES) | set(T.LATENT_SCOPES) | set(T.LINEAR_SCOPES) | set(NEW_SCOPES)
     )
