@@ -85,6 +85,25 @@ lower-edge tiles (12 of the 70, each half masked from the other corner) are
 walked like the diagonal ones and cost three quarters of a tile each: 63
 tiles' worth of sub-blocks for the band's 59.5 of pairs.
 
+A window NARROWER than a tile is a BAND call (``_band``, decided by a count
+of pairs and grid steps from the shapes and the mask alone). Aligned tiles
+cannot follow such a band: a 512 x 512 sub-block walk runs ``512 + window``
+keys a query whatever the key tile's width, two pairs for each one a window
+of 512 allows. In a band call the OTHER axis' block has the band's own width
+and begins where the band does: a q tile of fwd / dq reads the ``block_q +
+reach`` keys its band crosses as ONE block at an element offset
+(``pl.Element``; ``reach``: the window in whole parts), a kv tile of dkv the
+``block_k + reach`` queries that see it. A grid step is then a whole row (the
+table holds one entry a row, its ``col`` where that block begins), and inside
+it ``_band_walk`` runs each ``part`` rows of the tile against the ``part +
+reach`` rows their own band crosses: ONE body a kernel, the three kernels'
+``compute`` as they are, the mask made at the body's offset. Under 512 keys
+at 16,384: 16 steps a head where the walk took 31, fwd and dq 256 x 768 bodies
+(1.5 pairs for one allowed), dkv 1024 x 512 ones; the three kernels 19 %
+faster on a v5e (PERF.md section 6, PR 66). A window of two tiles or more
+keeps the walk: its interior tiles run whole, and a band body as wide as the
+window lost on the chip.
+
 ``block_diffusion=(clean_len, block)`` is a fourth mask, structural and NOT
 causal (block-diffusion training, BD3-LM: a clean copy of a sequence of
 ``clean_len`` positions and behind it a noised copy, ``2 x clean_len`` rows,
@@ -243,7 +262,7 @@ _LAST_BIT, _FIRST_BIT, _SUBS_SHIFT = 2 * _INDEX_BITS, 2 * _INDEX_BITS + 1, 2 * _
 
 
 def _tile_table(seq_q, seq_k, block_q, block_k, *, by, causal=True, window=None,
-                block_diffusion=None):
+                block_diffusion=None, band=None):
     """The tiles a call runs, in the order it runs them: the sequential axis
     of a kernel's grid is THIS list, prefetched as scalars, and a grid step is
     an entry. ``by="q"`` (fwd and dq): row-major by q tile, a row's kv tiles
@@ -260,6 +279,18 @@ def _tile_table(seq_q, seq_k, block_q, block_k, *, by, causal=True, window=None,
     Static: numpy, from the shapes and the mask's Python ints alone, a
     constant of the program. 4 bytes a tile: 544 bytes for a causal 16,384 in
     1024-tiles (136 entries), 33 KiB at 131,072 (8,256)."""
+    if band:
+        # one entry a row: the tile of the carried axis and, as ``col``, where
+        # the other axis' block of ``block + reach`` rows begins, in parts
+        part, reach = band
+        if by == "q":
+            rows = np.arange(seq_q // block_q)
+            cols = np.maximum(rows * block_q - reach, 0) // part
+        else:
+            rows = np.arange(seq_k // block_k)
+            cols = np.minimum(rows * block_k, seq_q - block_k - reach) // part
+        return (rows | cols << _INDEX_BITS | 1 << _LAST_BIT | 1 << _FIRST_BIT
+                | 1 << _SUBS_SHIFT).astype(np.uint32).view(np.int32)
     sub_q, sub_k = _sub_block(block_q), _sub_block(block_k)
     parts_q, parts_k = block_q // sub_q, block_k // sub_k
     held = _needed_tiles(seq_q, seq_k, sub_q, sub_k, causal, window, block_diffusion)
@@ -296,7 +327,14 @@ def causal_tile_counts(seq_q, seq_k, block_q, block_k, window=None):
     (``executed_pairs``: their SUB-BLOCKS that hold a visible pair, where a
     tile is walked in them): a property of the shapes (and the window) alone.
     ``grid_steps``: the sequential steps the forward call walks, the length
-    of the table it prefetches: ``executed``, unless a q row sees no key."""
+    of the table it prefetches: ``executed``, unless a q row sees no key.
+    Under a window narrow enough for a band call (``_band``) the forward's
+    tiles are its q rows, each against the keys its band crosses: a step a
+    row, and the pairs of its bodies."""
+    band = _band(seq_q, seq_k, block_q, block_k, window)
+    if band:
+        rows = seq_q // block_q
+        return {"skipped": 0, "executed": rows, "executed_pairs": seq_q * sum(band), "grid_steps": rows}
     return _tile_counts(seq_q, seq_k, block_q, block_k, window=window)
 
 
@@ -334,7 +372,7 @@ def _by_q_row(heads, group):
 
 def _masked_scores(q_ref, k_ref, q_index, kv_index, rows, cols, *, scale, causal,
                    block_q, block_k, precision, causal_offset, window=None,
-                   sel_ref=None, block_diffusion=None):
+                   sel_ref=None, block_diffusion=None, q_start=None, k_start=None):
     """scale * Q K^T with the causal mask (and the window's lower edge, and
     the ``selection``'s tile) or the block-diffusion mask applied — shared
     by all three kernels so forward and backward can never desynchronize.
@@ -352,10 +390,10 @@ def _masked_scores(q_ref, k_ref, q_index, kv_index, rows, cols, *, scale, causal
         # key sequence (decode convention; matches attention_reference's
         # tril(..., seq_k - seq_q)).
         q_pos = (
-            causal_offset + q_index * block_q
+            (causal_offset + q_index * block_q if q_start is None else q_start)
             + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         )
-        k_pos = kv_index * block_k + jax.lax.broadcasted_iota(
+        k_pos = (kv_index * block_k if k_start is None else k_start) + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1
         )
         visible = q_pos >= k_pos
@@ -468,10 +506,42 @@ def _walk(body, subs, q_index, kv_index, *, carried, cut, block_q, block_k):
             jax.lax.fori_loop(0, groups, group, 0)
 
 
+def _band_walk(body, index, start, *, carried, block, band):
+    """The body of a grid step of a BAND call (``_band``), shared by the three
+    kernels: the ``carried`` axis' tile ``index`` (``block`` rows) in parts of
+    ``part`` rows, each against the ``part + reach`` rows of the other axis
+    that its band crosses, ONE body in a ``fori_loop``. The other axis' block
+    starts at row ``start * part`` (the entry's ``col``: ``_tile_table``); a
+    part's rows of it begin at the part's own first row less ``reach``
+    (``"q"``: the keys a query part sees) or at it (``"kv"``: the queries
+    that see a key part), held inside the block, which the table has already
+    held inside the sequence (none before key 0, none past the last query);
+    ``_masked_scores`` makes the mask at that offset."""
+    part, reach = band
+    width = part + reach
+    begins = start * part
+
+    def one(a, carry):
+        mine = index * (block // part) + a
+        if carried == "q":
+            first = jnp.maximum(mine * part - reach, begins)
+        else:
+            first = jnp.minimum(mine * part, begins + block - part)
+        own = pl.ds(pl.multiple_of(a * part, part), part)
+        other = pl.ds(pl.multiple_of(first - begins, part), width)
+        if carried == "q":
+            body(own, other, mine, None, part, width, k_start=first)
+        else:
+            body(other, own, None, mine, width, part, q_start=first)
+        return carry
+
+    jax.lax.fori_loop(0, block // part, one, 0)
+
+
 def _flash_fwd_kernel(
     table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *, scale,
     causal, block_q, block_k, precision, causal_offset, window, sel_ref=None,
-    block_diffusion=None
+    block_diffusion=None, band=None
 ):
     # grid (batch * heads, the table's entries): a step is a tile that runs
     q_index, kv_index, first, last, subs = _entry(table_ref, pl.program_id(1))
@@ -482,12 +552,12 @@ def _flash_fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def compute(rows, cols, q_index, kv_index, block_q, block_k):
+    def compute(rows, cols, q_index, kv_index, block_q, block_k, **start):
         s, _, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
-            block_diffusion=block_diffusion,
+            block_diffusion=block_diffusion, **start,
         )
 
         # Running max and sum are kept replicated across a vreg's lanes:
@@ -511,8 +581,11 @@ def _flash_fwd_kernel(
         )
         m_scr[rows, :] = m_new
 
-    _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
-          block_q=block_q, block_k=block_k)
+    if band:
+        _band_walk(compute, q_index, kv_index, carried="q", block=block_q, band=band)
+    else:
+        _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
+              block_q=block_q, block_k=block_k)
 
     @pl.when(last)
     def _finalize():
@@ -526,7 +599,7 @@ def _flash_fwd_kernel(
 def _flash_dq_kernel(
     table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
     scale, causal, block_q, block_k, precision, causal_offset, window, sel_ref=None,
-    block_diffusion=None
+    block_diffusion=None, band=None
 ):
     q_index, kv_index, first, last, subs = _entry(table_ref, pl.program_id(1))
 
@@ -534,12 +607,12 @@ def _flash_dq_kernel(
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def compute(rows, cols, q_index, kv_index, block_q, block_k):
+    def compute(rows, cols, q_index, kv_index, block_q, block_k, **start):
         s, _, k = _masked_scores(
             q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
-            block_diffusion=block_diffusion,
+            block_diffusion=block_diffusion, **start,
         )
         lse = lse_ref[0, rows, :]
         p = jnp.exp(s - lse)                     # [block_q, block_k] f32
@@ -557,8 +630,11 @@ def _flash_dq_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )
 
-    _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
-          block_q=block_q, block_k=block_k)
+    if band:
+        _band_walk(compute, q_index, kv_index, carried="q", block=block_q, band=band)
+    else:
+        _walk(compute, subs, q_index, kv_index, carried="q", cut=causal or block_diffusion is not None,
+              block_q=block_q, block_k=block_k)
 
     @pl.when(last)
     def _finalize():
@@ -568,7 +644,7 @@ def _flash_dq_kernel(
 def _flash_dkv_kernel(
     table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr, *, scale, causal, block_q, block_k, precision, causal_offset,
-    window, group, sel_ref=None, block_diffusion=None
+    window, group, sel_ref=None, block_diffusion=None, band=None
 ):
     # grid (batch * kv_heads, the table's entries, group): one output row a KV
     # head. The scratch sums a kv row's tiles, and under each its ``group``
@@ -582,12 +658,12 @@ def _flash_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def compute(rows, cols, q_index, kv_index, block_q, block_k):
+    def compute(rows, cols, q_index, kv_index, block_q, block_k, **start):
         s, q, _ = _masked_scores(
             q_ref, k_ref, q_index, kv_index, rows, cols, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, precision=precision,
             causal_offset=causal_offset, window=window, sel_ref=sel_ref,
-            block_diffusion=block_diffusion,
+            block_diffusion=block_diffusion, **start,
         )
         lse = lse_ref[0, rows, :]
         p = jnp.exp(s - lse)
@@ -609,8 +685,11 @@ def _flash_dkv_kernel(
             preferred_element_type=jnp.float32, precision=precision,
         )                                        # [block_k, d]
 
-    _walk(compute, subs, q_index, kv_index, carried="kv", cut=causal or block_diffusion is not None,
-          block_q=block_q, block_k=block_k)
+    if band:
+        _band_walk(compute, kv_index, q_index, carried="kv", block=block_k, band=band)
+    else:
+        _walk(compute, subs, q_index, kv_index, carried="kv", cut=causal or block_diffusion is not None,
+              block_q=block_q, block_k=block_k)
 
     @pl.when(last & (head == group - 1))
     def _finalize():
@@ -775,6 +854,69 @@ def _kv_group(q, k, v):
     return heads // kv_heads
 
 
+# A band call's bodies (``_band``): the rows of its carried axis a body
+# computes, by kernel, and the largest score block of one. Measured on a v5e
+# (PERF.md section 6, PR 66, kernels alone at ``[20 / 10, 16384, 64 | 128]``
+# under a window of 512): fwd and dq are fastest at 256 rows (a body of 256 x
+# 768 runs 1.5 pairs for one the mask allows where 512 x 1024 runs 2; at 128
+# they lose what the pairs gain), dkv at 512 keys (its 256-key body, ``[768,
+# 256]`` scores contracted over the 768, costs 1.6 times its pairs). 512 x 1024
+# is the largest body that was read to win; 256 x 2304 (a window of 2,048)
+# lost in fwd and dkv, and dkv at 512 x 2560 does not fit the scoped VMEM.
+_BAND_PART = {"fwd": 256, "dq": 256, "dkv": 512}
+_BAND_SCORES = 512 * 1024
+# What a grid step costs beside its pairs, in pairs: about 0.3 us, a quarter
+# of a 512 x 512 block (PR 61's readings, and PR 66's of one band at 31, 47
+# and 63 steps a head).
+_STEP_PAIRS = 1 << 16
+
+
+def _band(seq_q, seq_k, block_q, block_k, window, selection=False, block_diffusion=None,
+          kernel="fwd"):
+    """None, or ``(part, reach)`` where a window is narrow enough that the
+    OTHER axis' block of a kernel should follow the band and not the tile
+    grid: a q tile of fwd / dq then reads the ``block_q + reach`` keys its
+    band crosses as ONE block at the element offset where the band begins (a
+    kv tile of dkv the ``block_k + reach`` queries that see it), a grid step a
+    row, and inside it each ``part`` rows run against the ``part + reach``
+    rows their band crosses (``_band_walk``), ``reach`` the window rounded up
+    to whole parts. Aligned tiles cannot do that: a 512 x 512 sub-block walk
+    runs ``512 + window`` keys a query whatever the key tile's width.
+
+    Decided by a count, from the call's shapes and mask alone: the pairs the
+    band's bodies execute and its grid steps against the tile walk's
+    (``_tile_counts``), a step priced at ``_STEP_PAIRS``; only where a body's
+    scores stay within ``_BAND_SCORES`` and the shapes allow it (self
+    attention over whole parts, no selection's tile to slice, the sequence
+    longer than a block)."""
+    if window is None or selection or block_diffusion is not None or seq_q != seq_k:
+        return None
+    part = _BAND_PART[kernel]
+    block = block_k if kernel == "dkv" else block_q
+    reach = -(-(window - 1) // part) * part
+    if block % part or block + reach > seq_k or part * (part + reach) > _BAND_SCORES:
+        return None
+    walked = _tile_counts(seq_q, seq_k, block_q, block_k, window=window)["executed_pairs"]
+    steps = len(_tile_table(seq_q, seq_k, block_q, block_k, by="kv" if kernel == "dkv" else "q",
+                            window=window))
+    band = seq_q * (part + reach) + seq_q // block * _STEP_PAIRS
+    return (part, reach) if band < walked + steps * _STEP_PAIRS else None
+
+
+def _band_spec(rows, width, index_map):
+    """A BlockSpec of ``rows`` rows of one head at the ELEMENT offset the
+    index map gives: the other axis' block of a band call (``_band``)."""
+    return pl.BlockSpec((pl.Element(1), pl.Element(rows), pl.Element(width)), index_map)
+
+
+def _band_kv_specs(band, block_q, dim, v_dim, group):
+    """K's and V's specs in fwd and dq of a band call: the ``block_q + reach``
+    keys a q tile's band crosses, from the key the entry's ``col`` names."""
+    part, reach = band
+    keys_at = lambda i, t, table: (i // group, _entry(table, t)[1] * part, 0)
+    return [_band_spec(block_q + reach, dim, keys_at), _band_spec(block_q + reach, v_dim, keys_at)]
+
+
 def _block_sizes(seq_q, seq_k, block_q, block_k, head_dim, dtype, selection=False):
     """The kernels' block shape, from the shapes they see (``head_dim``:
     the larger of q / k's and v's; ``selection``: whether a selection tile
@@ -848,8 +990,9 @@ def _flash_forward(
     qr = q.reshape(bh, seq_q, dim)
     kr = k.reshape(batch * kv_heads, seq_k, dim)
     vr = v.reshape(batch * kv_heads, seq_k, v_dim)
+    band = _band(seq_q, seq_k, block_q, block_k, window, selection is not None, block_diffusion, "fwd")
     table = _tile_table(seq_q, seq_k, block_q, block_k, by="q", causal=causal, window=window,
-                        block_diffusion=block_diffusion)
+                        block_diffusion=block_diffusion, band=band)
 
     kernel = functools.partial(
         _flash_fwd_kernel,
@@ -861,6 +1004,7 @@ def _flash_forward(
         causal_offset=seq_k - seq_q,
         window=window,
         block_diffusion=block_diffusion,
+        band=band,
     )
     from jax.experimental.pallas import tpu as pltpu
 
@@ -871,6 +1015,8 @@ def _flash_forward(
         pl.BlockSpec((1, block_k, dim), kv_map),
         pl.BlockSpec((1, block_k, v_dim), kv_map),
     ]
+    if band:
+        in_specs[1:] = _band_kv_specs(band, block_q, dim, v_dim, group)
     if selection is not None:
         kernel = _selected(kernel, 1 + len(operands))
         operands.append(selection)
@@ -937,10 +1083,13 @@ def _flash_backward(
     mask = dict(causal=causal, window=window, block_diffusion=block_diffusion)
     static = dict(scale=scale, block_q=block_q, block_k=block_k, precision=precision,
                   causal_offset=seq_k - seq_q, **mask)
+    band_of = lambda kernel: _band(seq_q, seq_k, block_q, block_k, window, selection is not None,
+                                   block_diffusion, kernel)
 
     from jax.experimental.pallas import tpu as pltpu
 
-    dq_kernel = functools.partial(_flash_dq_kernel, **static)
+    band = band_of("dq")
+    dq_kernel = functools.partial(_flash_dq_kernel, band=band, **static)
     operands = [qr, kr, vr, dor, lser, delta]
     selected = () if selection is None else (selection,)
     q_row, kv_map, chosen = _by_q_row(heads, group)
@@ -952,10 +1101,12 @@ def _flash_backward(
         pl.BlockSpec((1, block_q, 1), q_row),
         pl.BlockSpec((1, block_q, 1), q_row),
     ]
+    if band:
+        dq_specs[1:3] = _band_kv_specs(band, block_q, dim, v_dim, group)
     if selection is not None:
         dq_kernel = _selected(dq_kernel, 1 + len(operands))
         dq_specs.append(pl.BlockSpec((1, block_q, block_k), chosen))
-    table = _tile_table(seq_q, seq_k, block_q, block_k, by="q", **mask)
+    table = _tile_table(seq_q, seq_k, block_q, block_k, by="q", **mask, band=band)
     dq = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -971,7 +1122,8 @@ def _flash_backward(
 
     # grid step (i, t, g): K / V row i at the kv tile of the table's entry t,
     # query row i * group + g at its q tile
-    dkv_kernel = functools.partial(_flash_dkv_kernel, group=group, **static)
+    band = band_of("dkv")
+    dkv_kernel = functools.partial(_flash_dkv_kernel, group=group, band=band, **static)
     q_map = lambda i, t, g, table: (i * group + g, _entry(table, t)[1], 0)
     kv_row = lambda i, t, g, table: (i, _entry(table, t)[0], 0)
     dkv_specs = [
@@ -982,6 +1134,12 @@ def _flash_backward(
         pl.BlockSpec((1, block_q, 1), q_map),
         pl.BlockSpec((1, block_q, 1), q_map),
     ]
+    if band:
+        # the block_k + reach queries that see a kv tile, from the entry's col
+        part, reach = band
+        rows_at = lambda i, t, g, table: (i * group + g, _entry(table, t)[1] * part, 0)
+        for at, width in ((0, dim), (3, v_dim), (4, 1), (5, 1)):
+            dkv_specs[at] = _band_spec(block_k + reach, width, rows_at)
     if selection is not None:
         dkv_kernel = _selected(dkv_kernel, 1 + len(operands))
         def chosen(i, t, g, table):
@@ -989,7 +1147,7 @@ def _flash_backward(
             return (i // kv_heads, q_tile, kv_tile)
 
         dkv_specs.append(pl.BlockSpec((1, block_q, block_k), chosen))
-    table = _tile_table(seq_q, seq_k, block_q, block_k, by="kv", **mask)
+    table = _tile_table(seq_q, seq_k, block_q, block_k, by="kv", **mask, band=band)
     dk, dv = pl.pallas_call(
         dkv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -1,7 +1,8 @@
 """The flash-attention kernels (forward, backward, a long sequence, a block
 refused and a small one, two head dims, heads of 64, a window, a selection,
-the walked bodies at the seven shapes they were read alone at) and the index
-scorer's term at the cells' shapes.
+the walked bodies at the seven shapes they were read alone at and under the
+two narrower windows, one of them a band call) and the index scorer's term at
+the cells' shapes.
 
 Compiled for a TPU v5e that is described, not attached
 (``on-chip-measurement`` guide, section 2): the TPU's compiler is installed
@@ -211,6 +212,10 @@ _WALKED = {
     "causal_4k_group8": (64, 8, 4096, 128, 128, {}),
     "two_head_dims_8k": (16, 16, 8192, 192, 128, {}),
     "head_64_16k": (32, 32, 16384, 64, 64, {}),
+    # a window narrower than the tile takes the band (``_band``: one body a
+    # kernel); one two tiles wide keeps the walk
+    "window_512_differential": (20, 10, 16384, 64, 128, {"window": 512}),
+    "window_2048_group8": (32, 4, 16384, 128, 128, {"window": 2048}),
 }
 
 
@@ -222,14 +227,21 @@ def test_the_walked_bodies_compile_for_v5e(one_chip, name):
     a traced offset or both; the selection's int8 tile is sliced with them):
     forward + dq + dkv stay three Mosaic calls under the jitted names the
     trace reads, ask for no scoped VMEM beyond Mosaic's own 16 MiB and use
-    under it."""
-    from ray_tpu.ops.flash_attention import _block_sizes, _sub_block
+    under it. Under Phi-4-flash's window of 512 keys the three kernels are
+    BAND calls (``_band``: the other axis' block ``1024 + 512`` rows at an
+    element offset, fwd and dq in parts of 256 rows against 768 keys, dkv in
+    parts of 512 keys against 1024 queries, one body each); under Trinity's
+    2,048 they walk the tiles."""
+    from ray_tpu.ops.flash_attention import _band, _block_sizes, _sub_block
 
     heads, kv_heads, seq, dim, v_dim, mode = _WALKED[name]
     mode = dict(mode)
     chosen = mode.pop("selection", False)
     blocks = _block_sizes(seq, seq, None, None, max(dim, v_dim), jnp.bfloat16, chosen)
     assert blocks == (1024, 1024) and _sub_block(1024) == 512
+    bands = [_band(seq, seq, *blocks, mode.get("window"), chosen, mode.get("block_diffusion"), kernel)
+             for kernel in ("fwd", "dq", "dkv")]
+    assert bands == ([(256, 512), (256, 512), (512, 512)] if mode.get("window") == 512 else [None] * 3)
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
     shapes = [shape(1, heads, seq, dim), shape(1, kv_heads, seq, dim), shape(1, kv_heads, seq, v_dim)]
     if chosen:
