@@ -115,12 +115,43 @@ def step_annotation(name: str, phase: str | None = None):
 # The jax.monitoring listeners that call these are registered where the
 # session reaches its leased chips or the train loop first reaches jax
 # (train/jax_utils.py::_watch_compiles): this module never imports jax.
-# A compile-or-load is one backdated ``jax.compile`` span under the
-# thread's current span, and one count in the StepStats record of its
-# interval: "which step recompiled".
+# jax times three stages of a program on the thread that builds it, each
+# with one event: the function to a jaxpr, the jaxpr to an MLIR module
+# (Mosaic kernels' bodies included), and compile_or_get_cached (compile,
+# or load from the persistent cache). Every inner jit and every lowering
+# rule that traces fires the first two again, nested: thousands of events
+# a program. So the thread keeps ONE depth over the three events together.
+# A trace or a lowering that ends at depth 0 is a span (``jax.trace``,
+# ``jax.lower``), one that ends deeper a count in the outermost span's
+# ``inner``; a compile-or-load is a ``jax.compile`` span at ANY depth (an
+# eager operation inside a traced function compiles there) and one count
+# in the StepStats record of its interval: "which step recompiled". The
+# spans' edges are jax's own ``time.time()`` readings around the stage,
+# not this module's clock at the callback; their parent is the thread's
+# current span.
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+STAGES = {TRACE: "jax.trace", LOWER: "jax.lower", COMPILE: "jax.compile"}
 _compile_lock = threading.Lock()
 _compile_acc = [0, 0.0]            # compiles, seconds since the last drain
-_cache_hit = threading.local()     # set by a compile's hit event, read by its duration event
+
+
+class _Stages(threading.local):
+    """One thread's place in jax's compile pipeline."""
+
+    depth = 0               # stages entered and not left
+    inner = 0               # stages entered under the outermost one
+    cache_hit = False       # set by a compile's hit event, read at its end
+    retrieval_s = 0.0       # and the seconds its cache read took
+
+    def __init__(self):
+        # (thread_time, process_time, inner) at the entry of every stage
+        # that will be a span: the outermost one and any compile.
+        self.entered = []
+
+
+_stages = _Stages()
 # From the start of a session's loop to its first train.report the compile
 # spans are lifecycle spans (part of the time to the first step); after it
 # they are per-step spans, gated by tracing.enabled() like any other.
@@ -139,24 +170,65 @@ def end_startup() -> None:
 
 def note_cache_hit() -> None:
     """The persistent cache served the compile this thread is inside."""
-    _cache_hit.seen = True
+    _stages.cache_hit = True
 
 
-def note_compile(seconds: float, fun_name: str | None = None) -> None:
-    """One ``compile_or_get_cached`` ended on this thread, hit or miss. A
-    compile that no cache served is a miss, whether or not one is set up:
-    the program was built."""
-    hit = getattr(_cache_hit, "seen", False)
-    _cache_hit.seen = False
-    with _compile_lock:
-        _compile_acc[0] += 1
-        _compile_acc[1] += seconds
-    end_ns = time.time_ns()
-    attributes = {"fun_name": fun_name} if fun_name else {}
+def note_cache_read(seconds: float) -> None:
+    """... and reading and deserialising its entry took this long."""
+    _stages.retrieval_s = seconds
+
+
+def note_stage_entered(event: str) -> None:
+    """This thread entered a trace, a lowering or a compile-or-load. A
+    nested one costs two attribute stores; the CPU clocks are read only for
+    a stage that will be a span."""
+    here = _stages
+    if here.depth:
+        here.inner += 1
+    else:
+        here.inner = 0
+    if not here.depth or event == COMPILE:
+        here.entered.append((time.thread_time(), time.process_time(), here.inner))
+    here.depth += 1
+
+
+def note_stage_left(
+    event: str, start: float, end: float, fun_name: str | None = None
+) -> None:
+    """... and left it, by return or by exception, ``start`` and ``end``
+    being jax's own epoch seconds around it. ``cpu_s`` is what this thread
+    held a CPU for meanwhile and ``proc_cpu_s`` what all threads of the
+    process did: ``cpu_s`` well under ``seconds`` says the thread waited
+    (the GIL, the disk), ``proc_cpu_s`` well over ``cpu_s`` that whoever it
+    waited for is in this process. A compile that no cache served is a
+    miss, whether or not one is set up: the program was built."""
+    here = _stages
+    here.depth = max(0, here.depth - 1)
+    compiled = event == COMPILE
+    if here.depth and not compiled:
+        return
+    seconds = end - start
+    attributes: dict[str, Any] = {"seconds": seconds}
+    if fun_name:
+        attributes["fun_name"] = fun_name
+    if here.entered:      # not so for a stage the watcher was registered inside
+        thread_s, process_s, inner = here.entered.pop()
+        attributes.update(
+            inner=here.inner - inner,
+            cpu_s=time.thread_time() - thread_s,
+            proc_cpu_s=time.process_time() - process_s,
+        )
+    if compiled:
+        attributes["cache"] = "hit" if here.cache_hit else "miss"
+        if here.cache_hit:
+            attributes["retrieval_s"] = here.retrieval_s
+        here.cache_hit, here.retrieval_s = False, 0.0
+        with _compile_lock:
+            _compile_acc[0] += 1
+            _compile_acc[1] += seconds
     tracing.emit(
-        "jax.compile", start_ns=end_ns - int(seconds * 1e9), end_ns=end_ns,
-        lifecycle=_startup, seconds=seconds,
-        cache="hit" if hit else "miss", **attributes,
+        STAGES[event], start_ns=int(start * 1e9), end_ns=int(end * 1e9),
+        lifecycle=_startup, thread=threading.get_native_id(), **attributes,
     )
 
 

@@ -127,12 +127,16 @@ def _watch_compiles() -> None:
     the session before the user's function where the worker's lease holds
     chips (``session._reach_device``, which has to import jax there
     anyway), else where the loop first reaches jax through this module
-    (never import jax for telemetry's sake). jax fires the duration event
-    around every ``compile_or_get_cached``, hit or miss, with the jitted
-    function's name, and the hit event inside it on the same thread; what
-    they become is ``step_stats.note_compile``'s. On a worker that was
-    leased no chip, a loop that compiles before it calls into this module
-    is not seen until it does."""
+    (never import jax for telemetry's sake). jax brackets three stages of
+    every program on the thread that builds it (the trace, the lowering,
+    ``compile_or_get_cached`` hit or miss), each with a scalar event when
+    the stage is entered and a time-span event, with jax's own start and
+    end and the jitted function's name, when it is left; inside the third
+    it fires the cache's hit event and how long the read took. What they
+    become (``jax.trace``, ``jax.lower`` and ``jax.compile`` spans, the
+    nested ones a count) is ``step_stats``'s. On a worker that was leased
+    no chip, a loop that compiles before it calls into this module is not
+    seen until it does."""
     global _compiles_watched
     if _compiles_watched:
         return
@@ -145,12 +149,22 @@ def _watch_compiles() -> None:
         if name == "/jax/compilation_cache/cache_hits":
             step_stats.note_cache_hit()
 
-    def on_duration(name: str, seconds: float, **kw) -> None:
-        if name == "/jax/core/compile/backend_compile_duration":
-            step_stats.note_compile(seconds, kw.get("fun_name"))
+    def on_duration(name: str, seconds: float, **_kw) -> None:
+        if name == "/jax/compilation_cache/cache_retrieval_time_sec":
+            step_stats.note_cache_read(seconds)
+
+    def on_entered(name: str, _start: float, **_kw) -> None:
+        if name in step_stats.STAGES:
+            step_stats.note_stage_entered(name)
+
+    def on_left(name: str, start: float, end: float, **kw) -> None:
+        if name in step_stats.STAGES:
+            step_stats.note_stage_left(name, start, end, kw.get("fun_name"))
 
     jax.monitoring.register_event_listener(on_event)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_scalar_listener(on_entered)
+    jax.monitoring.register_event_time_span_listener(on_left)
 
 
 def build_mesh(axes: dict[str, int] | None = None, topology=None):

@@ -28,8 +28,8 @@ Those are per task, per collective or per request and gated by
 an export directory is known, enabled or not: the dozen once-a-run spans
 from a process's boot (``driver.boot``, ``worker.boot``) and
 ``ray_tpu.init`` to a train worker's first ``train.report`` (and its
-``jax.compile`` spans up to there), which say where the time to the
-first step went. After the last of them the flusher thread exits, so a
+programs' ``jax.trace`` / ``jax.lower`` / ``jax.compile`` spans up to
+there, three a program), which say where the time to the first step went. After the last of them the flusher thread exits, so a
 run with tracing off pays nothing in its steady state.
 
 The exporter is a per-process JSONL file under

@@ -42,6 +42,9 @@ TREE = {
 # is in the session of its process's first init (an earlier test file's,
 # under xdist), and every worker process of the run writes its own.
 BOOTS = {"driver.boot", "worker.boot"}
+# The compile watcher's three spans a program, in the order jax runs them.
+STAGES = ("jax.trace", "jax.lower", "jax.compile")
+TICK = 0.01     # the CPU clocks are read a few listener calls after jax's own
 
 
 def _loop(config):
@@ -55,10 +58,30 @@ def _loop(config):
     def before_jax_utils(x):
         return x * 3 + 1
 
+    @jax.jit
+    def inner_jit(x):
+        return jnp.sin(x) * 2
+
+    def staged(x):
+        # An eager operation on a concrete value: it compiles INSIDE the trace.
+        with jax.ensure_compile_time_eval():
+            scale = jnp.cumsum(jnp.arange(5.0))[-1]
+        return inner_jit(x) * scale
+
+    def raises(x):
+        raise ValueError("while it is traced")
+
+    def after_raise(x):
+        return x - 7
+
     entered_ns = time.time_ns()
     # A program compiled before the loop first calls into jax_utils, as a
     # loop's own jax.random.PRNGKey(0) is.
     jax.jit(before_jax_utils)(jnp.ones(3)).block_until_ready()
+    jax.jit(staged)(jnp.ones(3)).block_until_ready()
+    with pytest.raises(ValueError):
+        jax.jit(raises)(jnp.ones(3))
+    jax.jit(after_raise)(jnp.ones(3)).block_until_ready()
     setup = jax_utils.setup_sharded_training(
         lambda: {"w": jnp.ones((8, 8))}, optax.sgd(0.1),
         mesh=jax_utils.build_mesh({"dp": 1}),
@@ -160,7 +183,7 @@ def _named(run, name):
 def test_lifecycle_spans_are_recorded_untraced_and_per_task_spans_are_not(run):
     names = {s["name"] for s in run["spans"]}
     assert set(TREE) <= names
-    assert names <= set(TREE) | BOOTS | {"jax.compile"}, (
+    assert names <= set(TREE) | BOOTS | set(STAGES), (
         "a span gated by tracing.enabled() was recorded with tracing off"
     )
 
@@ -258,6 +281,187 @@ def test_start_up_compiles_are_spans_under_the_span_that_compiled(run):
         # with tracing off they stop at the first report: step 3's recompile
         # is a counter of its record, not a span
         assert s["end_ns"] <= first_report["end_ns"]
+
+
+def _stages_of(spans, function):
+    """The watcher's spans of one jitted function: jax names the trace by
+    the function and the other two ``jit(<function>)``."""
+    return [s for s in spans if s["name"] in STAGES
+            and s["attributes"].get("fun_name") in (function, f"jit({function})")]
+
+
+@pytest.mark.parametrize("function", ["before_jax_utils", "staged", "after_raise"])
+def test_a_program_is_three_spans_once_each_in_order_on_one_thread(run, function):
+    found = sorted(_stages_of(run["spans"], function), key=lambda s: s["start_ns"])
+    assert [s["name"] for s in found] == list(STAGES)
+    trace, lower, compile_ = found
+    assert trace["attributes"]["fun_name"] == function
+    assert lower["attributes"]["fun_name"] == compile_["attributes"]["fun_name"] == f"jit({function})"
+    assert len({s["pid"] for s in found}) == len({s["attributes"]["thread"] for s in found}) == 1
+    assert trace["end_ns"] <= lower["start_ns"] and lower["end_ns"] <= compile_["start_ns"]
+    assert len({s["parent_id"] for s in found}) == 1
+
+
+def test_an_inner_jit_is_a_count_on_the_outermost_span_and_no_span_of_its_own(run):
+    assert not _stages_of(run["spans"], "inner_jit")
+    (trace,) = [s for s in _stages_of(run["spans"], "staged") if s["name"] == "jax.trace"]
+    # inner_jit's trace, and the eager operations' traces, lowerings and compiles
+    assert trace["attributes"]["inner"] > 3
+    # jax.numpy's own operators are jits too: x - 7 is one
+    (plain,) = [s for s in _stages_of(run["spans"], "after_raise") if s["name"] == "jax.trace"]
+    assert 0 <= plain["attributes"]["inner"] < trace["attributes"]["inner"]
+
+
+def test_an_eager_operation_inside_a_trace_still_writes_its_compile_span(run):
+    (trace,) = [s for s in _stages_of(run["spans"], "staged") if s["name"] == "jax.trace"]
+    eager = _stages_of(run["spans"], "cumsum")
+    assert [s["name"] for s in eager] == ["jax.compile"]      # its trace and lowering: counts
+    (compile_,) = eager
+    assert trace["start_ns"] <= compile_["start_ns"] and compile_["end_ns"] <= trace["end_ns"]
+    assert compile_["attributes"]["thread"] == trace["attributes"]["thread"]
+    assert compile_["attributes"]["cache"] in ("hit", "miss")
+
+
+def test_a_function_that_raises_in_its_trace_leaves_the_depth_at_zero(run):
+    """jax's ``__exit__`` fires the event on the way out of an exception
+    too: the trace is a span, nothing follows it, and the next program
+    (``after_raise``, above) gets its three."""
+    assert [s["name"] for s in _stages_of(run["spans"], "raises")] == ["jax.trace"]
+
+
+@pytest.mark.parametrize("kind", STAGES)
+def test_the_cpu_clocks_lie_beside_the_wall(run, kind):
+    found = _named(run, kind)
+    assert found
+    for s in found:
+        a = s["attributes"]
+        assert a["seconds"] == pytest.approx((s["end_ns"] - s["start_ns"]) / 1e9, abs=1e-5)
+        assert 0 <= a["cpu_s"] <= a["seconds"] + TICK
+        assert a["proc_cpu_s"] >= a["cpu_s"] - TICK
+        assert a["inner"] >= 0 and a["fun_name"]
+
+
+@pytest.mark.parametrize("kind", ["jax.trace", "jax.lower"])
+def test_no_span_of_a_kind_lies_inside_another_of_its_kind(run, kind):
+    found = sorted(_named(run, kind), key=lambda s: s["start_ns"])
+    assert len({s["attributes"]["thread"] for s in found}) == 1
+    assert all(a["end_ns"] <= b["start_ns"] for a, b in zip(found, found[1:]))
+
+
+@pytest.mark.parametrize("kind", STAGES)
+def test_with_tracing_off_the_stages_stop_at_the_first_report(run, kind):
+    """Step 3's recompile traced, lowered and compiled a program after it:
+    counters of its record, no spans."""
+    first_report = _named(run, "train.first_report")[0]
+    assert all(s["end_ns"] <= first_report["end_ns"] for s in _named(run, kind))
+    assert run["records"][3]["compiles"] >= 1
+
+
+def test_a_stage_the_watcher_was_registered_inside_ends_without_its_clocks(tmp_path, monkeypatch):
+    """A loop on a worker that was leased no chip may first call into
+    ``jax_utils`` from INSIDE a traced function: that trace's end arrives
+    with no entry before it. It must not raise into jax's ``__exit__``."""
+    monkeypatch.setattr(tracing, "_dir", str(tmp_path / "tracing"))
+    monkeypatch.setattr(step_stats, "_startup", True)
+    seen = {}
+
+    def unentered():
+        now = time.time()
+        step_stats.note_stage_left(step_stats.TRACE, now - 1.0, now, "outer")
+        seen["depth"] = step_stats._stages.depth
+        step_stats.note_stage_entered(step_stats.TRACE)
+        step_stats.note_stage_left(step_stats.TRACE, now, now + 0.5, "next")
+
+    worker = threading.Thread(target=unentered)
+    worker.start()
+    worker.join()
+    tracing.flush()
+    outer, following = tracing.read_spans(str(tmp_path))
+    assert seen["depth"] == 0
+    assert outer["attributes"] == {"seconds": pytest.approx(1.0), "fun_name": "outer",
+                                   "thread": outer["attributes"]["thread"]}
+    assert following["attributes"]["inner"] == 0 and "cpu_s" in following["attributes"]
+
+
+_TRACED_FIT = """
+import json, os, sys, time
+import numpy as np
+import ray_tpu
+from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+from ray_tpu.util import state, tracing
+
+def loop(config):
+    import jax
+    from ray_tpu import train
+    def recompiled(x):
+        return x * 2 + 1
+    double = jax.jit(recompiled)
+    for i in range(4):      # step 2 changes a shape
+        double(np.ones(8 if i == 2 else 4, np.float32)).block_until_ready()
+        train.report({"step": i})
+
+ray_tpu.init(num_cpus=2, resources={"TPU": 1})
+try:
+    fit = JaxTrainer(loop, scaling_config=ScalingConfig(num_workers=1, use_tpu=True),
+                     run_config=RunConfig(name="traced", storage_path=sys.argv[1])).fit()
+    assert fit.error is None, fit.error
+    records, deadline = [], time.monotonic() + 20
+    while time.monotonic() < deadline and len(records) < 4:
+        records = state.get_workload_timeline("train/traced/rank0", "raw").get("raw") or []
+        time.sleep(0.2)
+    time.sleep(0.5)
+    spans = tracing.read_spans(os.environ["RAYTPU_SESSION_DIR"])
+finally:
+    ray_tpu.shutdown()
+(report,) = [s for s in spans if s["name"] == "train.first_report"]
+print(json.dumps({
+    "compiles": [r.get("compiles", 0) for r in records],
+    "late": [[s["name"], s["attributes"]["fun_name"]] for s in spans
+             if s["name"].startswith("jax.") and s["start_ns"] >= report["end_ns"]],
+    "early": sorted({s["name"] for s in spans
+                     if s["name"].startswith("jax.") and s["end_ns"] <= report["end_ns"]}),
+}))
+"""
+
+
+def _run_script(script, *args, timeout=180, **extra):
+    """A process of its own, off this session and untraced unless ``extra``
+    says otherwise; what its last line of output holds."""
+    import json
+
+    env = dict(os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu")
+    env.pop("RAY_TPU_tracing_enabled", None)
+    env.pop("RAYTPU_SESSION_DIR", None)
+    env.update(extra)
+    done = subprocess.run(
+        [sys.executable, "-c", script, *args],
+        env=env, capture_output=True, text=True, timeout=timeout,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+@pytest.fixture(scope="module")
+def traced_fit(tmp_path_factory):
+    """The one fit more: a cluster of its own with ``RAY_TPU_tracing_enabled=1``
+    whose loop recompiles after its first report."""
+    return _run_script(
+        _TRACED_FIT, str(tmp_path_factory.mktemp("traced")), RAY_TPU_tracing_enabled="1"
+    )
+
+
+@pytest.mark.parametrize("kind, fun_name", [
+    ("jax.trace", "recompiled"), ("jax.lower", "jit(recompiled)"), ("jax.compile", "jit(recompiled)"),
+])
+def test_with_tracing_on_a_recompile_after_the_first_report_is_a_span(traced_fit, kind, fun_name):
+    assert [kind, fun_name] in traced_fit["late"]
+    assert [name for name, _fun in traced_fit["late"]].count(kind) == 1
+    assert kind in traced_fit["early"]
+
+
+def test_stepstats_counts_the_recompile_traced_or_not(run, traced_fit):
+    assert traced_fit["compiles"][2] == 1 and traced_fit["compiles"][3] == 0
+    assert run["records"][3]["compiles"] >= 1
 
 
 def test_the_timeline_shows_driver_and_worker_on_one_axis(run):
@@ -362,31 +566,35 @@ for _ in range(2):
 count, seconds = step_stats._drain_compiles()
 spans = [s for s in tracing.read_spans(sys.argv[1]) if s["name"] == "jax.compile"]
 print(json.dumps({"count": count, "seconds": seconds,
-                  "cache": [s["attributes"]["cache"] for s in spans][-2:],
-                  "fun": spans[-1]["attributes"]["fun_name"]}))
+                  "attributes": [s["attributes"] for s in spans][-2:]}))
 """
 
 
-def test_a_program_compiled_twice_is_a_miss_then_a_hit(tmp_path):
-    import json
-
-    env = dict(
-        os.environ, PYTHONPATH=ROOT, JAX_PLATFORMS="cpu",
+@pytest.fixture(scope="module")
+def twice(tmp_path_factory):
+    """One process with a cache directory placed that compiles one program,
+    forgets it and compiles it again."""
+    tmp_path = tmp_path_factory.mktemp("twice")
+    return _run_script(
+        _TWICE, str(tmp_path / "session"), timeout=120,
         JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
         JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
     )
-    env.pop("RAY_TPU_tracing_enabled", None)
-    env.pop("RAYTPU_SESSION_DIR", None)
-    done = subprocess.run(
-        [sys.executable, "-c", _TWICE, str(tmp_path / "session")],
-        env=env, capture_output=True, text=True, timeout=120,
-    )
-    assert done.returncode == 0, done.stderr[-2000:]
-    seen = json.loads(done.stdout.strip().splitlines()[-1])
-    assert seen["count"] == 2 and seen["seconds"] > 0
-    assert seen["cache"] == ["miss", "hit"]
-    assert "lambda" in seen["fun"]
+
+
+def test_a_program_compiled_twice_is_a_miss_then_a_hit(twice):
+    assert twice["count"] == 2 and twice["seconds"] > 0
+    assert [a["cache"] for a in twice["attributes"]] == ["miss", "hit"]
+    assert "lambda" in twice["attributes"][-1]["fun_name"]
+
+
+def test_a_hit_carries_what_the_read_of_its_entry_took(twice):
+    miss, hit = twice["attributes"]
+    assert "retrieval_s" not in miss        # it read nothing
+    assert 0 < hit["retrieval_s"] <= hit["seconds"]
+    # the rest of a hit is the key: the module's and the options' hashing
+    assert hit["cpu_s"] <= hit["seconds"] + TICK
 
 
 _INIT_TWICE = """
@@ -407,18 +615,8 @@ print(json.dumps({"created": psutil.Process().create_time(), "pid": os.getpid(),
 
 
 def test_the_drivers_boot_is_written_once_though_init_runs_twice():
-    import json
-
-    env = dict(os.environ, PYTHONPATH=ROOT)
-    env.pop("RAY_TPU_tracing_enabled", None)
-    env.pop("RAYTPU_SESSION_DIR", None)
     before_ns = time.time_ns()
-    done = subprocess.run(
-        [sys.executable, "-c", _INIT_TWICE],
-        env=env, capture_output=True, text=True, timeout=180,
-    )
-    assert done.returncode == 0, done.stderr[-2000:]
-    seen = json.loads(done.stdout.strip().splitlines()[-1])
+    seen = _run_script(_INIT_TWICE)
     first, second = seen["sessions"]
     assert sorted(s["name"] for s in first) == ["driver.boot", "ray_tpu.init"]
     assert [s["name"] for s in second] == ["ray_tpu.init"]
